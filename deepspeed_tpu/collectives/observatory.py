@@ -568,9 +568,7 @@ class CollectiveObservatory:
                     r = f(x)
                 finally:
                     self._tls.probing = False
-                # the sweep's sync idiom: fetch a scalar (block_until_ready
-                # is a no-op on some platforms)
-                np.asarray(jax.tree_util.tree_leaves(r)[0].ravel()[0])
+                jax.block_until_ready(r)
                 entry[1] = "warm"
             except Exception as e:  # noqa: BLE001 — must not kill the worker
                 entry[1] = "failed"

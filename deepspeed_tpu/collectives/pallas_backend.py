@@ -101,6 +101,24 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# The fused quantized hop does not compile on the installed Mosaic (libtpu
+# 0.0.34): its wire rides (1, B) row blocks, (2, B) 1-byte and (2, nb) fp32
+# VMEM wire slots sliced one row at a time, and every one of those breaks
+# the compiler's rule that a block or DMA slice of the two minor dims be
+# (8, 128)-aligned (rehearsed on a described v5e:2x2; interpret mode cannot
+# see it). Until the wire format is rebuilt around aligned tiles these
+# codecs are NOT supported on a pallas algorithm in compiled mode: the
+# selector never picks the pair on its own (``compiled_ok``) and asking
+# for it by name raises (``_fused_hop``) — it never falls back in silence.
+FUSED_CODECS = ("int8", "fp8")
+
+
+def compiled_ok(algorithm, codec) -> bool:
+    """False for an (algorithm, codec) pair the chip's compiler refuses:
+    a pallas algorithm with a fused-hop codec, outside interpret mode."""
+    return _interpret() or not (is_pallas(algorithm) and codec in FUSED_CODECS)
+
+
 def fusable(codec: Codec, dtype) -> bool:
     """The in-kernel dequant-accumulate-requant fusion speaks the 1-byte
     block-quant wires (int8/fp8) over float payloads; everything else runs
@@ -208,8 +226,7 @@ def _compiler_params():
     """Mosaic params for compiled mode (interpret mode takes none):
     collective kernels sharing the barrier semaphore need a
     ``collective_id`` (one id — every hop kernel of a step participates in
-    the same gang). Routed through the compat shim so the
-    TPUCompilerParams -> CompilerParams rename cannot break compiled hops."""
+    the same gang)."""
     if _interpret():
         return None
     from deepspeed_tpu.utils.compat import tpu_compiler_params
@@ -266,8 +283,8 @@ def remote_permute_leaves(leaves: Sequence[jax.Array], axis,
     k = len(leaves)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY) for _ in range(k)],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY) for _ in range(k)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY) for _ in range(k)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY) for _ in range(k)],
         scratch_shapes=[pltpu.SemaphoreType.DMA] * (2 * k),
     )
     out = pl.pallas_call(
@@ -417,6 +434,13 @@ def _fused_hop(acc: jax.Array, send_idx, recv_idx, dst, src, *,
     neighbor."""
     encode, decode, wdtype = _block_math(codec)
     interpret = _interpret()
+    if not interpret:
+        raise NotImplementedError(
+            f"pallas collectives with the fused {codec.name} hop are not "
+            "supported on this compiler (Mosaic refuses the kernel's (1, B) "
+            "wire blocks — see pallas_backend.FUSED_CODECS); use "
+            f"algorithm='ring' with codec={codec.name!r}, or a pallas "
+            "algorithm with codec='none'")
     nb = B // qb
     idx = jnp.stack([send_idx.astype(jnp.int32), recv_idx.astype(jnp.int32),
                      dst, src])

@@ -340,6 +340,8 @@ def _model_pick(op: str, nbytes: int, n: int, codec: Optional[str],
         if alg == "rhd" and (not pow2 or op == "all_to_all"):
             continue
         for cd in codecs:
+            if not pallas_backend.compiled_ok(alg, cd):
+                continue  # the chip's compiler refuses the fused hop
             est = estimate_us(op, alg, cd, nbytes, n, cfg, itemsize)
             if best is None or est < best.est_us:
                 best = Decision(op, alg, cd, est, "model")
@@ -437,7 +439,7 @@ def _row_backend_ok(r: dict) -> bool:
     if alg == "lax":
         return True
     implied = "pallas" if pallas_backend.is_pallas(alg) else "ppermute"
-    if stamp != implied:
+    if stamp != implied or not pallas_backend.compiled_ok(alg, r.get("codec")):
         return False
     return implied != "pallas" or pallas_backend.available()
 
@@ -453,7 +455,8 @@ def pick_codec(op: str, nbytes: int, axis_size: int, algorithm: str,
     if algorithm not in ALGORITHMS + PALLAS_ALGORITHMS:
         algorithm = "ring"
     alg = algorithm
-    candidates = tuple(cfg.codecs) or ("none",)
+    candidates = tuple(cd for cd in cfg.codecs
+                       if pallas_backend.compiled_ok(alg, cd)) or ("none",)
     return min(candidates,
                key=lambda cd: estimate_us(op, alg, cd, nbytes, axis_size, cfg, itemsize))
 

@@ -6,8 +6,7 @@ runs inside a jitted ``shard_map`` over the requested mesh axis; algorithmic
 bus bandwidth uses the standard ring-collective factors (the same formulas as
 ``utils/comms_logging.calc_bw_log``).
 
-Timing note: syncs via scalar fetch, not ``block_until_ready`` (a no-op on
-some experimental platforms — see PERF.md).
+Timing ends in ``jax.block_until_ready``.
 """
 
 from __future__ import annotations
@@ -44,17 +43,17 @@ _busbw_factor = CommsLogger._bus_factor
 
 
 def _time_collective(f, x, iters: int, warmup: int) -> float:
-    """Compile + warm up, then mean seconds/call. Syncs by fetching a scalar
-    (block_until_ready is a no-op on some experimental platforms — PERF.md);
-    the ONE timing idiom for bench and sweep rows."""
+    """Compile + warm up, then mean seconds/call, timed to
+    ``jax.block_until_ready`` — the ONE timing idiom for bench and sweep
+    rows."""
     r = f(x)  # compile + first run (counts as warmup)
     for _ in range(max(warmup - 1, 0)):
         r = f(x)
-    np.asarray(jax.tree_util.tree_leaves(r)[0].ravel()[0])
+    jax.block_until_ready(r)
     t0 = time.perf_counter()
     for _ in range(iters):
         r = f(x)
-    np.asarray(jax.tree_util.tree_leaves(r)[0].ravel()[0])
+    jax.block_until_ready(r)
     return (time.perf_counter() - t0) / iters
 
 

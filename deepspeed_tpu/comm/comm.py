@@ -464,7 +464,8 @@ def init_distributed(
 
     Env-driven like the reference's MASTER_ADDR/RANK/WORLD_SIZE discovery:
     honors ``COORDINATOR_ADDRESS``/``NUM_PROCESSES``/``PROCESS_ID`` or the
-    jax-native auto-detection on TPU pods. Idempotent; returns True when a
+    jax-native auto-detection on TPU pods (more than one host in
+    ``TPU_WORKER_HOSTNAMES``; a single host is a no-op). Idempotent; returns True when a
     multi-process runtime is active.
     """
     global _initialized
@@ -481,7 +482,7 @@ def init_distributed(
                 process_id=process_id,
                 initialization_timeout=timeout_s,
             )
-        elif jax.default_backend() == "tpu" and os.environ.get("TPU_WORKER_HOSTNAMES"):
+        elif jax.default_backend() == "tpu" and _tpu_worker_count() > 1:
             jax.distributed.initialize()  # auto-detect on TPU pods
     except RuntimeError as e:
         if "already initialized" in str(e).lower():
@@ -493,6 +494,16 @@ def init_distributed(
             raise
     _initialized = True
     return jax.process_count() > 1
+
+
+def _tpu_worker_count() -> int:
+    """Hosts named in ``TPU_WORKER_HOSTNAMES``. A one-host machine still
+    sets the variable (the chip tool's v5e host sets ``localhost``): one
+    host is NOT a pod, there is nobody to rendezvous with, and an argument-
+    less ``jax.distributed.initialize()`` there may look for a metadata
+    server and hang or raise."""
+    return len([h for h in os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")
+                if h.strip()])
 
 
 def _int_env(name: str) -> Optional[int]:

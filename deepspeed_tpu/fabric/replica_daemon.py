@@ -415,7 +415,11 @@ def main(argv: Optional[List[str]] = None) -> int:
                    help="directory for the trace JSONL export on shutdown")
     args = p.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # no platform default: a daemon started with no JAX_PLATFORMS runs on the
+    # accelerator jax finds (one daemon per chip); CPU fleets pass it in env
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     fleet.configure_identity(run_id=args.run_id, process_index=args.index,
                              role="replica")
     tracer = get_tracer()

@@ -106,9 +106,8 @@ class InferenceConfig(DeepSpeedConfigModel):
     # distinct compiled generate programs before the cache-growth warning
     max_generate_buckets: int = 16
     # Pre-flight HBM-fit check (utils/hbm.py) before param placement:
-    # "warn" | "refuse" | "off". An over-budget materialization on this
-    # platform wedges the device without raising (PERF.md round 5), so the
-    # bench extras run "refuse". With WOQ enabled the estimate uses the
+    # "warn" | "refuse" | "off"; the bench extras and chip_smoke.py run
+    # "refuse". With WOQ enabled the estimate uses the
     # quantized byte formula (woq.quantized_bytes_estimate — values + scales
     # through the same eligibility predicate the real pass applies), so a
     # model that only fits quantized is admitted; zero_inference keeps the

@@ -73,8 +73,8 @@ class InferenceEngine:
         # dense shards, then quantize in place.
         pre_quant = woq_on and tp_size == 1
         if config.hbm_check != "off" and not config.zero_inference.enabled:
-            # refuse/warn BEFORE placement (an over-budget materialization
-            # wedges this platform without raising); skipped when
+            # refuse/warn BEFORE placement, with the estimate in the
+            # message; skipped when
             # zero_inference keeps the big weights off-device. With
             # pre-placement WOQ the estimate is the QUANTIZED byte formula
             # (values + scales through the same eligibility predicate the

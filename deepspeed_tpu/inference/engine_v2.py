@@ -310,7 +310,7 @@ class InferenceEngineV2:
             # shards over tp only when kv_heads divides — plus a
             # [rows, vocab] logits buffer. Quantized storage enters with its
             # REAL byte formulas: a pool/model that only fits quantized is
-            # admitted, an over-budget one refused before the wedge.
+            # admitted, an over-budget one refused before placement.
             from deepspeed_tpu.utils.hbm import check_hbm_fit
 
             tp = max(mesh.shape["tp"], 1)

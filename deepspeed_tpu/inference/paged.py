@@ -304,8 +304,7 @@ def ragged_decode_chain(
 
     The serving fast path: the host dispatches once and fetches once per K
     decoded tokens instead of shipping [N, vocab] logits to the host for
-    every token (each dispatched program carries ~6-7 ms fixed relay overhead
-    on this platform — see PERF.md "secondary platform facts"). A
+    every token. A
     ``lax.scan`` runs the single-token forward, samples the next token with
     the threaded PRNG key, writes the input token's KV through the
     pre-extended block table, and masks finished rows in-scan: a row goes

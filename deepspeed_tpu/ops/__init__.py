@@ -1,9 +1,10 @@
 """deepspeed_tpu.ops: the kernel layer (op_builder + csrc analog).
 
-Ops are registered per-backend (xla fallback, pallas TPU kernels) and resolved
-through the registry at call time. Import order matters only in that the
-pallas module registers its implementations on import; it degrades gracefully
-off-TPU.
+Ops are registered per-backend ('xla' plain jnp, 'pallas' TPU kernels) and
+resolved through the registry at call time. The pallas package registers its
+implementations on import — interpret mode off-TPU, so the CPU tests run the
+same kernels. A kernel module that fails to import is an error, not a
+warning: on a TPU it would silently hand every op to XLA.
 """
 
 from deepspeed_tpu.ops.registry import available_impls, dispatch, op_report, register
@@ -12,17 +13,6 @@ from deepspeed_tpu.ops.norms import layer_norm, rms_norm
 from deepspeed_tpu.ops.rope import rope
 from deepspeed_tpu.ops.quant import dequantize_int8, quantize_int8
 
-# Pallas kernels register themselves when importable (TPU or interpret mode).
-try:  # pragma: no cover - exercised on TPU
-    from deepspeed_tpu.ops.pallas import register_all as _register_pallas
+from deepspeed_tpu.ops.pallas import register_all as _register_pallas
 
-    _register_pallas()
-except ModuleNotFoundError:
-    pass  # pallas kernel package not built yet
-except Exception as _e:  # noqa: BLE001 - degrade to xla impls, but say so
-    from deepspeed_tpu.utils.logging import logger as _logger
-
-    _logger.warning(
-        f"pallas kernel registration failed ({type(_e).__name__}: {_e}); "
-        f"all ops fall back to XLA implementations"
-    )
+_register_pallas()

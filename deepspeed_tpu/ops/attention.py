@@ -95,11 +95,11 @@ def causal_attention(q, k, v, mask=None, impl: str = "auto",
         return _xla_causal_attention(q, k, v, mask=mask,
                                      alibi_slopes=alibi_slopes, bias=bias)
     fn = dispatch("causal_attention", impl)
-    if kernel_kwargs and fn is not available_impls("causal_attention").get("pallas"):
-        kernel_kwargs = {}
+    if fn is available_impls("causal_attention").get("pallas"):
+        return _per_shard_flash(fn, q, k, v, mask, alibi_slopes, kernel_kwargs)
     if alibi_slopes is not None:
-        return fn(q, k, v, mask=mask, alibi_slopes=alibi_slopes, **kernel_kwargs)
-    return fn(q, k, v, mask=mask, **kernel_kwargs)
+        return fn(q, k, v, mask=mask, alibi_slopes=alibi_slopes)
+    return fn(q, k, v, mask=mask)
 
 
 def evoformer_attention(q, k, v, pair_bias=None, mask=None):
@@ -112,3 +112,31 @@ def evoformer_attention(q, k, v, pair_bias=None, mask=None):
     q/k/v: [B, S, H, D]; pair_bias: [H, S, S] or [B, H, S, S]; mask: [B, S].
     """
     return _xla_causal_attention(q, k, v, mask=mask, bias=pair_bias, causal=False)
+
+
+def _per_shard_flash(fn, q, k, v, mask, alibi_slopes, kernel_kwargs):
+    """The flash kernel under GSPMD (``ops/partition.py``): attention is
+    independent per (batch row, kv-head group), so batch splits over the
+    data axes and heads over sp (the Ulysses head shard) and tp."""
+    from jax.sharding import PartitionSpec as P
+
+    from deepspeed_tpu.ops.partition import kernel_mesh, live_axes, per_shard
+    from deepspeed_tpu.topology.mesh import BATCH_AXES
+
+    def call(q, k, v, mask, slopes):
+        kw = dict(kernel_kwargs)
+        if slopes is not None:
+            kw["alibi_slopes"] = slopes
+        return fn(q, k, v, mask=mask, **kw)
+
+    ctx = kernel_mesh()
+    if ctx is None:
+        return call(q, k, v, mask, alibi_slopes)
+    mesh, free = ctx
+    b = live_axes(mesh, free, BATCH_AXES, q.shape[0])
+    # kv heads must split like q heads: GQA groups stay whole on a device
+    h = live_axes(mesh, free, ("sp", "tp"), q.shape[2], k.shape[2])
+    qkv = P(b, None, h, None)
+    in_specs = (qkv, qkv, qkv, None if mask is None else P(b, None),
+                None if alibi_slopes is None else P(h))
+    return per_shard(call, mesh, free, in_specs, qkv)(q, k, v, mask, alibi_slopes)

@@ -62,8 +62,7 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-# out_shape structs carry the inputs' varying-manual-axes where this jax
-# tracks them (jax>=0.9 check_vma); plain structs on 0.4.x
+# out_shape structs carry the inputs' varying-manual-axes (check_vma)
 from deepspeed_tpu.utils.compat import shape_dtype_struct as _sds
 
 

@@ -56,24 +56,22 @@ def _cdiv(a: int, b: int) -> int:
     return (a + b - 1) // b
 
 
-def _decode_kernel(bt_ref, ap_ref, *refs, bs, ppcb, alibi=False, quantized=False):
+def _decode_kernel(bt_ref, ap_ref, *refs, ppcb, alibi=False, quantized=False):
     refs = list(refs)
     q_ref, qpos_ref = refs.pop(0), refs.pop(0)
     slopes_ref = refs.pop(0) if alibi else None
     (k_hbm, v_hbm) = refs.pop(0), refs.pop(0)
-    ks_hbm = vs_hbm = None
+    ks_ref = vs_ref = None
     if quantized:
-        ks_hbm, vs_hbm = refs.pop(0), refs.pop(0)
+        ks_ref, vs_ref = refs.pop(0), refs.pop(0)
     o_ref = refs.pop(0)
-    kbuf, vbuf = refs.pop(0), refs.pop(0)
-    ksbuf = vsbuf = None
-    if quantized:
-        ksbuf, vsbuf = refs.pop(0), refs.pop(0)
-    acc_ref, m_ref, l_ref, sem_k, sem_v = refs
+    kbuf, vbuf, acc_ref, m_ref, l_ref, sem_k, sem_v = refs
     n = pl.program_id(0)
-    kh = pl.program_id(1)
-    pc = pl.program_id(2)
-    npc = pl.num_programs(2)
+    pc = pl.program_id(1)
+    npc = pl.num_programs(1)
+    _, kvH, Cgp, hd = q_ref.shape
+    bs = kbuf.shape[1]
+    T = ppcb * bs
 
     @pl.when(pc == 0)
     def _init():
@@ -82,63 +80,66 @@ def _decode_kernel(bt_ref, ap_ref, *refs, bs, ppcb, alibi=False, quantized=False
         l_ref[:] = jnp.zeros_like(l_ref)
 
     def _compute():
+        # one DMA per live page, ALL kv heads at once: a page is a contiguous
+        # lane-dense [bs, kvH*hd] slab of the kernel's pool view, so the copy
+        # slices only the leading (untiled) page dim — Mosaic refuses any
+        # slice of the tiled minor dims that is not (8, 128)-aligned, which
+        # a per-head [bs, 1, hd] copy never is
         copies = []
         for i in range(ppcb):
             page = bt_ref[n, pc * ppcb + i]
-            copies.append(pltpu.make_async_copy(
-                k_hbm.at[pl.ds(page * bs, bs), pl.ds(kh, 1)],
-                kbuf.at[pl.ds(i * bs, bs)], sem_k))
-            copies.append(pltpu.make_async_copy(
-                v_hbm.at[pl.ds(page * bs, bs), pl.ds(kh, 1)],
-                vbuf.at[pl.ds(i * bs, bs)], sem_v))
-            if quantized:
-                # the per-(slot, head) scales ride the same page DMAs — the
-                # fp-precision pool never exists anywhere, the dequant below
-                # happens on the VMEM tiles right after the block load
-                copies.append(pltpu.make_async_copy(
-                    ks_hbm.at[pl.ds(page * bs, bs), pl.ds(kh, 1)],
-                    ksbuf.at[pl.ds(i * bs, bs)], sem_k))
-                copies.append(pltpu.make_async_copy(
-                    vs_hbm.at[pl.ds(page * bs, bs), pl.ds(kh, 1)],
-                    vsbuf.at[pl.ds(i * bs, bs)], sem_v))
+            copies.append(pltpu.make_async_copy(k_hbm.at[page], kbuf.at[i], sem_k))
+            copies.append(pltpu.make_async_copy(v_hbm.at[page], vbuf.at[i], sem_v))
         for c in copies:
             c.start()
         for c in copies:
             c.wait()
 
-        q = q_ref[0, 0]  # [Cg, hd] (pre-scaled)
+        cdt = q_ref.dtype
+        k_all, v_all = kbuf[...], vbuf[...]  # [ppcb, bs, kvH*hd]
         if quantized:
-            # fused block-load dequant: int8/e4m3 tile * its per-slot scale,
-            # cast to the compute dtype (matches the XLA fallback's math)
-            k = (kbuf[:, 0].astype(jnp.float32) * ksbuf[:, 0][:, None]).astype(q_ref.dtype)
-            v = (vbuf[:, 0].astype(jnp.float32) * vsbuf[:, 0][:, None]).astype(q_ref.dtype)
-        else:
-            k = kbuf[:, 0]  # [ppcb*bs, hd]
-            v = vbuf[:, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [Cg, T]
-        # causality over SEQUENCE positions: token j of this page-chunk is at
-        # global position pc*ppcb*bs + j; visible iff <= the query's position
-        j = pc * ppcb * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if alibi:
-            # bloom convention slope * key-position (slot index == position);
-            # slopes arrive row-aligned with the (c, g) query layout
-            s = s + slopes_ref[0][:, None] * j.astype(jnp.float32)
-        qpos = qpos_ref[0]  # [Cg]
-        s = jnp.where(j <= qpos[:, None], s, _NEG_INF)
+            # widen before the page-merge reshape: a 1-byte tile is 32 rows,
+            # a 16-slot page is not, fp32's 8-row tile divides any page
+            k_all, v_all = k_all.astype(jnp.float32), v_all.astype(jnp.float32)
+        k_all = k_all.reshape(T, kvH * hd).astype(cdt)
+        v_all = v_all.reshape(T, kvH * hd).astype(cdt)
 
-        m_prev = jnp.max(m_ref[:], axis=-1, keepdims=True)
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        m_safe = jnp.where(m_cur == _NEG_INF, 0.0, m_cur)
-        p = jnp.exp(s - m_safe)
-        alpha = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_safe))
-        l_prev = jnp.max(l_ref[:], axis=-1, keepdims=True)
-        l_ref[:] = jnp.broadcast_to(alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-        m_ref[:] = jnp.broadcast_to(m_cur, m_ref.shape)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        # causality over SEQUENCE positions: token j of this page-chunk is at
+        # global position pc*T + j; visible iff <= the query's position
+        j = pc * T + jax.lax.broadcasted_iota(jnp.int32, (Cgp, T), 1)
+        visible = j <= qpos_ref[0]  # qpos: [Cgp, 1] column
+        for kh in range(kvH):
+            q = q_ref[0, kh]  # [Cgp, hd] (pre-scaled)
+            k = k_all[:, kh * hd:(kh + 1) * hd]  # [T, hd]
+            v = v_all[:, kh * hd:(kh + 1) * hd]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )  # [Cgp, T]
+            if quantized:
+                # fused dequant: int8/e4m3 values are exact in the compute
+                # dtype, so the per-slot scale factors out of the head-dim
+                # contraction — one [1, T] row multiply on the scores
+                s = s * ks_ref[0, pl.ds(kh, 1), :]
+            if alibi:
+                # bloom convention slope * key-position (slot index ==
+                # position); slopes arrive row-aligned with the (c, g) layout
+                s = s + slopes_ref[kh] * j.astype(jnp.float32)
+            s = jnp.where(visible, s, _NEG_INF)
+
+            m_prev = jnp.max(m_ref[kh], axis=-1, keepdims=True)
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            m_safe = jnp.where(m_cur == _NEG_INF, 0.0, m_cur)
+            p = jnp.exp(s - m_safe)
+            alpha = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_safe))
+            l_prev = jnp.max(l_ref[kh], axis=-1, keepdims=True)
+            l_ref[kh] = jnp.broadcast_to(
+                alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape[1:])
+            m_ref[kh] = jnp.broadcast_to(m_cur, m_ref.shape[1:])
+            if quantized:
+                p = p * vs_ref[0, pl.ds(kh, 1), :]  # value scales fold into p
+            acc_ref[kh] = acc_ref[kh] * alpha + jax.lax.dot_general(
+                p.astype(cdt), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
 
     # skip page-chunks entirely beyond the row's live pages (guard wraps the
     # DMAs too — dead pages cost no bandwidth)
@@ -147,7 +148,17 @@ def _decode_kernel(bt_ref, ap_ref, *refs, bs, ppcb, alibi=False, quantized=False
     @pl.when(pc == npc - 1)
     def _finalize():
         l = jnp.max(l_ref[:], axis=-1, keepdims=True)
-        o_ref[0, 0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _page_view(pool_l: jax.Array, num_pages: int, bs: int) -> jax.Array:
+    """``[S_flat, kvH, hd] -> [pages, bs, kvH*hd]``: the layout the kernel
+    DMAs from (trash slot dropped; no block table points at it). On the chip
+    this is a layout-changing COPY of the layer's pool — the stored layout pads
+    (kvH, hd) to the (8, 128) tile, which no DMA may slice. Storing the pool
+    page-major and lane-dense would make it free; that is a layout change of
+    ``inference/paged.py`` and everything that exports pages, not a repair."""
+    return pool_l[:num_pages * bs].reshape(num_pages, bs, -1)
 
 
 @register("paged_attention", "pallas")
@@ -165,7 +176,7 @@ def flash_decode_paged(
     v_scale: jax.Array = None,
 ) -> jax.Array:
     N, C, H, hd = q.shape
-    kvH = pool_k_l.shape[1]
+    S_flat, kvH = pool_k_l.shape[:2]
     G = H // kvH
     P = block_tables.shape[1]
     bs = block_size
@@ -174,6 +185,7 @@ def flash_decode_paged(
     if Pp != P:
         block_tables = jnp.pad(block_tables, ((0, 0), (0, Pp - P)))
     npc = Pp // ppcb
+    T = ppcb * bs
 
     Cg = C * G
     Cgp = _cdiv(Cg, _LANES) * _LANES
@@ -196,10 +208,13 @@ def flash_decode_paged(
     active_pages = (max_pos + 1 + bs - 1) // bs  # [N]
 
     alibi = alibi_slopes is not None
-    extra = ()
+    operands = [q5, qpos_rows[:, :, None]]
     in_specs = [
-        pl.BlockSpec((1, 1, Cgp, hd), lambda n, kh, pc, bt, ap: (n, kh, 0, 0)),
-        pl.BlockSpec((1, Cgp), lambda n, kh, pc, bt, ap: (n, 0)),
+        pl.BlockSpec((1, kvH, Cgp, hd), lambda n, pc, bt, ap: (n, 0, 0, 0)),
+        # per-row scalars ride as [.., Cgp, 1] COLUMNS: a (1, Cgp) row block
+        # breaks Mosaic's (8, 128) block rule; (Cgp, 1) has Cgp % 8 == 0 and
+        # a full last dim, and is already the broadcast shape the mask needs
+        pl.BlockSpec((1, Cgp, 1), lambda n, pc, bt, ap: (n, 0, 0)),
     ]
     if alibi:
         # row-aligned slopes: row (c, g) of kv head kh uses slope[kh*G + g]
@@ -208,55 +223,49 @@ def flash_decode_paged(
         ).reshape(kvH, Cg)
         if Cgp != Cg:
             srows = jnp.pad(srows, ((0, 0), (0, Cgp - Cg)))
-        extra = (srows,)
-        in_specs.append(pl.BlockSpec((1, Cgp), lambda n, kh, pc, bt, ap: (kh, 0)))
+        operands.append(srows[:, :, None])
+        in_specs.append(pl.BlockSpec((kvH, Cgp, 1), lambda n, pc, bt, ap: (0, 0, 0)))
+    num_pages = (S_flat - 1) // bs
+    operands += [_page_view(pool_k_l, num_pages, bs), _page_view(pool_v_l, num_pages, bs)]
     in_specs += [
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     quantized = k_scale is not None
-    pools = (pool_k_l, pool_v_l)
-    scratch = [
-        pltpu.VMEM((ppcb * bs, 1, hd), pool_k_l.dtype),
-        pltpu.VMEM((ppcb * bs, 1, hd), pool_v_l.dtype),
-    ]
     if quantized:
-        # scales stream with their pages: [S_flat, kvH] fp32 in HBM, [bs, 1]
-        # slices DMA'd next to each value page
-        in_specs += [
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ]
-        pools = pools + (k_scale.reshape(k_scale.shape[0], kvH),
-                         v_scale.reshape(v_scale.shape[0], kvH))
-        scratch += [
-            pltpu.VMEM((ppcb * bs, 1), jnp.float32),
-            pltpu.VMEM((ppcb * bs, 1), jnp.float32),
-        ]
+        # scales are 1/hd of the pool: gather the rows' pages in XLA into
+        # [N, kvH, Pp*bs] ROWS (slot index == position) and let the pipeline
+        # fetch the page-chunk's [kvH, T] block — the kernel multiplies the
+        # scores / probabilities by them, never the value tiles
+        slot = (block_tables[:, :, None] * bs + jnp.arange(bs)[None, None, :]).reshape(N, Pp * bs)
+        for sc in (k_scale, v_scale):
+            operands.append(sc.reshape(S_flat, kvH)[slot].transpose(0, 2, 1))
+            in_specs.append(pl.BlockSpec((1, kvH, T), lambda n, pc, bt, ap: (n, 0, pc)))
 
-    kernel = functools.partial(_decode_kernel, bs=bs, ppcb=ppcb, alibi=alibi,
-                               quantized=quantized)
+    kernel = functools.partial(_decode_kernel, ppcb=ppcb, alibi=alibi, quantized=quantized)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # block_tables, active_pages
-            grid=(N, kvH, npc),
+            grid=(N, npc),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, Cgp, hd), lambda n, kh, pc, bt, ap: (n, kh, 0, 0)),
-            scratch_shapes=scratch + [
-                pltpu.VMEM((Cgp, hd), jnp.float32),
-                pltpu.VMEM((Cgp, _LANES), jnp.float32),
-                pltpu.VMEM((Cgp, _LANES), jnp.float32),
+            out_specs=pl.BlockSpec((1, kvH, Cgp, hd), lambda n, pc, bt, ap: (n, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((ppcb, bs, kvH * hd), pool_k_l.dtype),
+                pltpu.VMEM((ppcb, bs, kvH * hd), pool_v_l.dtype),
+                pltpu.VMEM((kvH, Cgp, hd), jnp.float32),
+                pltpu.VMEM((kvH, Cgp, _LANES), jnp.float32),
+                pltpu.VMEM((kvH, Cgp, _LANES), jnp.float32),
                 pltpu.SemaphoreType.DMA,
                 pltpu.SemaphoreType.DMA,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((N, kvH, Cgp, hd), q.dtype),
         compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=_interpret(),
-    )(block_tables, active_pages, q5, qpos_rows, *extra, *pools)
+    )(block_tables, active_pages, *operands)
 
     out = out[:, :, :Cg].reshape(N, kvH, C, G, hd).transpose(0, 2, 1, 3, 4)
     return out.reshape(N, C, H, hd)

@@ -34,14 +34,19 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Dict, Optional
 
-#: conservative peak envelopes per ledger backend: bf16 matmul flops and
-#: HBM bandwidth for v5e (datasheet); the cpu-smoke figure matches
-#: bench.py's PEAK_FLOPS_CPU_SMOKE convention (MFU on CPU is a smoke
-#: number, not a claim)
-PEAK_FLOPS: Dict[str, float] = {"cpu": 1e12, "tpu-v5e": 197e12,
-                                "interpret": 1e12}
-PEAK_BYTES_PER_S: Dict[str, float] = {"cpu": 50e9, "tpu-v5e": 819e9,
-                                      "interpret": 50e9}
+from deepspeed_tpu.profiling.flops_profiler import DEVICE_PEAKS
+from deepspeed_tpu.telemetry.perfledger import backend_stamp
+
+#: peak envelopes per ledger backend stamp. Accelerator rows are DERIVED from
+#: the one ``device_kind``-keyed table (profiling/flops_profiler.DEVICE_PEAKS);
+#: the cpu / interpret rows are nominal envelopes for the CPU test lane, where
+#: an attribution shows the decomposition works and is never a device claim
+PEAK_FLOPS: Dict[str, float] = {
+    "cpu": 1e12, "interpret": 1e12,
+    **{backend_stamp(k): p.bf16_flops for k, p in DEVICE_PEAKS.items()}}
+PEAK_BYTES_PER_S: Dict[str, float] = {
+    "cpu": 50e9, "interpret": 50e9,
+    **{backend_stamp(k): p.hbm_bytes_per_s for k, p in DEVICE_PEAKS.items()}}
 
 
 @dataclass

@@ -1,50 +1,29 @@
-"""Version compatibility shims for the jax API surface.
+"""The jax API surface this package leans on, spelled once.
 
-``shard_map`` is the one symbol this package needs whose location AND
-signature moved across jax releases:
-
-  - new jax exports ``jax.shard_map(f, mesh=..., in_specs=..., out_specs=...,
-    axis_names=..., check_vma=...)`` as a function
-  - some intermediate versions expose ``jax.shard_map`` as a MODULE holding
-    the function
-  - jax 0.4.x (this environment) only has
-    ``jax.experimental.shard_map.shard_map(f, mesh, in_specs, out_specs,
-    check_rep=..., auto=...)`` — no ``axis_names``/``check_vma`` kwargs
-
-Every call site in the package imports ``shard_map`` from HERE and writes the
-new-API spelling; this wrapper translates to whatever the installed jax
-understands (``check_vma`` -> ``check_rep``; ``axis_names={manual}`` ->
-``auto = mesh_axes - manual``). A tier-1 lint (tests/unit/
-test_no_bare_shard_map.py) greps the tree so bare ``jax.shard_map`` /
-``from jax import shard_map`` imports cannot regress.
+Written for the ONE installation there is (jax/jaxlib 0.9.0, libtpu 0.0.34):
+``jax.shard_map``, ``jax.lax.axis_size``, ``jax.typeof``, ``jax.memory`` and
+``pltpu.CompilerParams`` all exist there, so nothing here chooses between
+versions. What remains is (a) one import point for the symbols that have
+moved before — ``shard_map`` and ``axis_size``; a tier-1 lint
+(tests/unit/test_no_bare_shard_map.py) keeps call sites from reaching past
+it — (b) the one private reach the Pallas collectives need
+(``axis_env_sizes``), and (c) helpers that are about BACK-ENDS, not versions
+(``with_memory_kind``, ``device_put_unaliased``, ``host_copy_unaliased``).
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+from typing import Optional
 
 import jax
 
-
-def _resolve_native() -> Optional[Callable]:
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None and not callable(sm):  # module-valued on some versions
-        sm = getattr(sm, "shard_map", None)
-    return sm
-
-
-_NATIVE = _resolve_native()
-if _NATIVE is None:
-    from jax.experimental.shard_map import shard_map as _EXPERIMENTAL
-else:
-    _EXPERIMENTAL = None
+shard_map = jax.shard_map
 
 
 def axis_size(axis, default: Optional[int] = None) -> int:
-    """``jax.lax.axis_size`` with the pre-0.5 fallback: a unit psum over a
-    bound axis is statically the axis size at trace time. Accepts an axis
-    name or a tuple of them. This is THE axis-size helper — the comm facade,
-    zeropp, and the collectives algorithms all route here.
+    """``jax.lax.axis_size`` over an axis name or a tuple of them. This is
+    THE axis-size helper — the comm facade, zeropp, and the collectives
+    algorithms all route here.
 
     Outside a bound-axis context the size is unknowable; pass ``default`` to
     get it back instead of the NameError (the comm facade's record path uses
@@ -55,10 +34,7 @@ def axis_size(axis, default: Optional[int] = None) -> int:
             out *= axis_size(a, default=default)
         return out
     try:
-        try:
-            return int(jax.lax.axis_size(axis))
-        except (AttributeError, TypeError):
-            return int(jax.lax.psum(1, axis))
+        return int(jax.lax.axis_size(axis))
     except Exception:
         if default is not None:
             return int(default)
@@ -67,73 +43,35 @@ def axis_size(axis, default: Optional[int] = None) -> int:
 
 def shape_dtype_struct(shape, dtype, *like):
     """``jax.ShapeDtypeStruct`` for a Pallas ``out_shape``, stamped with the
-    union of the varying-manual-axes of ``like`` where this jax tracks them
-    (``jax.typeof(x).vma`` + the ``vma=`` kwarg, new-jax ``check_vma``);
-    0.4.x has neither, and shard_map composition is governed by
-    ``check_rep``/``check_vma=False`` at the shard_map call instead."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
+    union of the varying-manual-axes of ``like`` (``jax.typeof(x).vma``) so
+    the kernel composes with ``check_vma`` shard_maps."""
     vma = frozenset()
     for a in like:
-        vma = vma | getattr(typeof(a), "vma", frozenset())
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:  # typeof exists but ShapeDtypeStruct predates vma=
-        return jax.ShapeDtypeStruct(shape, dtype)
+        vma = vma | getattr(jax.typeof(a), "vma", frozenset())
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def axis_env_sizes() -> "dict[str, int]":
     """(name -> size) of every mesh axis bound in the trace-time axis env,
-    in binding order (full-manual shard_map binds them all). The axis env
-    lives behind private jax internals that have moved across releases —
-    try each known spelling (same pattern as ``tpu_compiler_params`` /
-    ``shape_dtype_struct``) so a rename cannot break every caller at trace
-    time. Returns ``{}`` outside any bound-axis context."""
+    in binding order (full-manual shard_map binds them all); ``{}`` outside
+    any bound-axis context. The axis env has no public accessor — this is
+    the package's one reach into ``jax._src``."""
     from jax._src import core as _core
 
-    get_env = getattr(_core, "get_axis_env", None)
-    if get_env is not None:  # jax >= 0.4.3x: AxisEnv with .axis_sizes
-        sizes = getattr(get_env(), "axis_sizes", None)
-        if sizes is not None:
-            return {str(k): int(v) for k, v in dict(sizes).items()}
-    # older spelling: thread-local AxisEnvFrame(name, size, ...) records
-    tls = getattr(_core, "thread_local_state", None)
-    frames = getattr(getattr(tls, "trace_state", None), "axis_env", None)
-    if frames is not None:
-        return {str(f.name): int(f.size) for f in frames
-                if f.name is not None}
-    raise RuntimeError(
-        "cannot locate the jax axis env on this version — "
-        "utils/compat.axis_env_sizes needs a new spelling")
+    return {str(k): int(v) for k, v in dict(_core.get_axis_env().axis_sizes).items()}
 
 
 def tpu_compiler_params(**kwargs):
-    """Pallas TPU compiler params across the class rename
-    (``pltpu.CompilerParams`` on new jax, ``pltpu.TPUCompilerParams`` on
-    0.4.x); kwargs the installed class does not know are dropped rather
-    than raising, so call sites can write the full new-API surface."""
+    """``pltpu.CompilerParams`` (imported lazily: Pallas TPU is heavy)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    fields = getattr(cls, "__dataclass_fields__", None)
-    if fields is not None:
-        kwargs = {k: v for k, v in kwargs.items() if k in fields}
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def memory_space(space: str):
-    """A ``jax.device_put`` target selecting host vs device memory.
-
-    New jax spells it ``jax.memory.Space.Host/Device``; 0.4.x spells it
-    ``TransferToMemoryKind('pinned_host'|'device')``. Both work inside jit
-    (sharding-preserving memory-kind transfer)."""
-    mem = getattr(jax, "memory", None)
-    if mem is not None:
-        return mem.Space.Host if space == "host" else mem.Space.Device
-    from jax._src.sharding_impls import TransferToMemoryKind
-
-    return TransferToMemoryKind("pinned_host" if space == "host" else "device")
+    """A ``jax.device_put`` target selecting host vs device memory
+    (sharding-preserving memory-kind transfer, works inside jit)."""
+    return jax.memory.Space.Host if space == "host" else jax.memory.Space.Device
 
 
 def with_memory_kind(sharding, kind: str):
@@ -154,45 +92,6 @@ def with_memory_kind(sharding, kind: str):
         # requested kind unaddressable on this backend: keep the sharding's
         # current (default) memory kind — placement becomes the identity
         return sharding
-
-
-def shard_map(
-    f: Callable,
-    *,
-    mesh: Any,
-    in_specs: Any,
-    out_specs: Any,
-    axis_names: Any = None,
-    check_vma: Optional[bool] = None,
-    check_rep: Optional[bool] = None,
-) -> Callable:
-    """``jax.shard_map`` with the NEW keyword surface on every jax version.
-
-    ``axis_names``: the axes the body handles manually (default: all mesh
-    axes). ``check_vma`` (new spelling) and ``check_rep`` (old spelling) are
-    the same knob; pass at most one.
-    """
-    if check_vma is not None and check_rep is not None:
-        raise TypeError("pass only one of check_vma / check_rep")
-    check = check_vma if check_vma is not None else check_rep
-
-    if _NATIVE is not None:
-        kwargs: dict = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        if check is not None:
-            kwargs["check_vma"] = check
-        return _NATIVE(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-    kwargs = {}
-    if check is not None:
-        kwargs["check_rep"] = check
-    if axis_names is not None:
-        manual = set(axis_names)
-        auto = frozenset(a for a in mesh.axis_names if a not in manual)
-        if auto:
-            kwargs["auto"] = auto
-    return _EXPERIMENTAL(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
 
 
 def device_put_unaliased(arr, sharding):

@@ -549,6 +549,9 @@ def test_ring2d_int8_train_step_with_hop_spans(mesh8, tmp_path):
         telemetry.configure(enabled=False)
 
 
+from jax.extend.core import ClosedJaxpr, Jaxpr  # noqa: E402
+
+
 def _count_primitives(jaxpr, counts=None):
     """Recursive primitive census of a (closed) jaxpr — the structural
     evidence for 'one fused program per hop'."""
@@ -557,11 +560,10 @@ def _count_primitives(jaxpr, counts=None):
         counts[eqn.primitive.name] = counts.get(eqn.primitive.name, 0) + 1
         for v in eqn.params.values():
             for j in jax.tree_util.tree_leaves(
-                    v, is_leaf=lambda x: isinstance(
-                        x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                if isinstance(j, jax.core.ClosedJaxpr):
+                    v, is_leaf=lambda x: isinstance(x, (Jaxpr, ClosedJaxpr))):
+                if isinstance(j, ClosedJaxpr):
                     _count_primitives(j.jaxpr, counts)
-                elif isinstance(j, jax.core.Jaxpr):
+                elif isinstance(j, Jaxpr):
                     _count_primitives(j, counts)
     return counts
 

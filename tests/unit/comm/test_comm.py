@@ -132,6 +132,20 @@ def test_host_api_single_process():
     assert dist.init_distributed() is False  # single-process => not multi
 
 
+@pytest.mark.parametrize("hostnames,count", [
+    (None, 0), ("", 0), ("localhost", 1), ("10.0.0.1,10.0.0.2", 2)])
+def test_single_tpu_host_is_not_a_pod(monkeypatch, hostnames, count):
+    """The chip tool's one-host v5e sets TPU_WORKER_HOSTNAMES=localhost; only
+    more than one host may trigger the argument-less rendezvous."""
+    from deepspeed_tpu.comm.comm import _tpu_worker_count
+
+    if hostnames is None:
+        monkeypatch.delenv("TPU_WORKER_HOSTNAMES", raising=False)
+    else:
+        monkeypatch.setenv("TPU_WORKER_HOSTNAMES", hostnames)
+    assert _tpu_worker_count() == count
+
+
 def test_collective_bench_rows(devices):
     """ds_bench analog: sweeps run on the CPU mesh and busbw factors hold."""
     from deepspeed_tpu.comm.benchmark import run_collective_bench

@@ -318,6 +318,36 @@ def test_sweep_cli_writes_envelope_and_merges(mesh8, tmp_path):
     assert ("all_gather", "rhd") in algs and ("all_reduce", "ring") in algs
 
 
+def test_sweep_leaves_tracked_perf_ledger_untouched(mesh8, tmp_path):
+    """A ``comm.benchmark --sweep`` run appends perf-ledger rows; under the
+    test harness they go to ``$DSTPU_PERF_LEDGER_DIR`` (a per-test tmp dir,
+    tests/conftest.py) and the tracked ``perf/ledger/`` stays byte-identical
+    — tier-1 used to dirty ``perf/ledger/coll-sweep.jsonl`` on every run."""
+    import hashlib
+    import os
+
+    from deepspeed_tpu.comm import benchmark
+    from deepspeed_tpu.telemetry import perfledger
+
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    tracked = os.path.join(repo, "perf", "ledger")
+
+    def digest():
+        return {f: hashlib.sha256(open(os.path.join(tracked, f), "rb").read()).hexdigest()
+                for f in sorted(os.listdir(tracked))}
+
+    before = digest()
+    assert os.path.abspath(perfledger.default_ledger_root()) != os.path.abspath(tracked)
+    rc = benchmark.main(["--sweep", "--op", "all_reduce", "--sizes-mb", "0.01",
+                         "--iters", "1", "--algorithms", "lax,ring",
+                         "--output", str(tmp_path / "sweep.json")])
+    assert rc == 0
+    assert digest() == before
+    swept = os.path.join(perfledger.default_ledger_root(), "coll-sweep.jsonl")
+    assert os.path.getsize(swept) > 0  # the rows did land — in the tmp ledger
+
+
 def test_measured_pick_prefers_matching_itemsize(tmp_path):
     """A mixed-itemsize table answers each query from rows measured at the
     querying payload's element width: the bf16 rows (where int8 is only 2x
@@ -440,6 +470,10 @@ def test_drift_warns_arms_profiler_and_traces(mesh8, tmp_path, caplog, dslog):
                                 probe_alternatives=False, async_compile=False)
     obs.install(mesh=mesh8)
     _route_ring_int8(mesh8)
+    # both phases read an INJECTED clock: calibrating against real CPU
+    # timings made the baseline depend on what the other xdist workers were
+    # doing (a loaded box calibrates slow, and 5 s was then < 3x of it)
+    obs._timer = lambda f, x, iters, warmup: 1e-3  # healthy hop: 1 ms
     for s in range(1, 4):  # calibrate first (drift needs a trusted model)
         obs.on_step(s)
     assert "ppermute" in obs.calibration

@@ -42,15 +42,30 @@ def _engine(cfg, params, **over):
 # ------------------------------------------------------------------ accuracy
 def test_kv_int8_greedy_token_identical():
     """int8 KV (per-head-vector blocks) is accurate enough that greedy decode
-    through the chained fast path matches the fp32 pool token-for-token."""
+    through the chained fast path matches the fp32 pool token for token —
+    except where fp32 itself is at a near-tie. A random-init 2-layer model
+    HAS near-ties, and which side of one a run lands on moves with the jax
+    version, so the first divergent token (if any) must be one the fp32
+    logits rank within the int8 logit-error bound (3% of the logit range,
+    ``test_kv_quant_logit_error_bounded``) of their own argmax."""
     cfg, _, params = make_model()
     rng = np.random.RandomState(0)
     prompts = [rng.randint(0, cfg.vocab_size, (n,)) for n in (7, 3, 5)]
     outs_fp = _engine(cfg, params).generate(prompts, max_new_tokens=12)
     outs_q = _engine(cfg, params, kv_cache_dtype="int8").generate(
         prompts, max_new_tokens=12)
-    for a, b in zip(outs_q, outs_fp):
-        np.testing.assert_array_equal(a, b)
+    scorer = _engine(cfg, params)
+    for uid, (p, a, b) in enumerate(zip(prompts, outs_q, outs_fp)):
+        a, b = np.asarray(a)[-12:], np.asarray(b)[-12:]
+        assert a.shape == b.shape == (12,)
+        diverged = np.nonzero(a != b)[0]
+        if diverged.size == 0:
+            continue
+        j = int(diverged[0])  # everything before j agrees: score that context
+        logits = scorer.put([uid], [np.concatenate([p, b[:j]])])[0]
+        assert int(np.argmax(logits)) == b[j]
+        assert logits[b[j]] - logits[a[j]] < 0.03 * np.abs(logits).max(), (
+            f"prompt {uid}: int8 token {a[j]} at step {j} is no near-tie of fp32's {b[j]}")
 
 
 @pytest.mark.parametrize("kvd,bound", [("int8", 0.03), ("fp8", 0.15)])

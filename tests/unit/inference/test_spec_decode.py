@@ -89,8 +89,14 @@ def test_spec_with_int8_pool_parity():
 def test_spec_accepts_on_repetitive_text():
     """The acceptance benchmark shape: greedy output that self-repeats lets
     the n-gram proposer lock on — >= 1.3 accepted tokens per model forward
-    (the bench corpus's acceptance bar) and fewer dispatches than plain."""
-    cfg, _, params = make_model(seed=1)
+    (the bench corpus's acceptance bar) and fewer dispatches than plain.
+
+    Model seed 5 on this prompt settles into one repeated token from its
+    first step (3.75 tokens per forward) — an attractor with wide logit
+    margins, not a near-tie a jax upgrade can flip. (Seed 1 emitted a
+    barely-repeating sequence at 1.25-1.36: the bound then tested the
+    numerics of a random 2-layer model, not the proposer.)"""
+    cfg, _, params = make_model(seed=5)
     rng = np.random.RandomState(1)
     pat = rng.randint(0, cfg.vocab_size, (4,))
     prompts = [np.tile(pat, 6)[:20] for _ in range(2)]

@@ -1,0 +1,182 @@
+"""Compile the main path's kernels for the REAL chip, without the chip.
+
+The TPU's compiler is installed in the sandbox and compiles for a chip that
+is described, not attached (``on-chip-measurement`` guide, section 2). The
+interpret-mode tests cannot see what Mosaic refuses — block shapes off the
+(8, 128) rule, DMA slices off the tiling, too much VMEM — and every kernel
+here was refused at least once before ISSUE 22. Nothing runs: a compile that
+passes is not a chip run (``chip_smoke.py`` is), and nothing here is a time.
+
+Rules of this file (the guide's): the topology is described INSIDE a
+module-scoped fixture that skips when it cannot be — never at import, in a
+``skipif``, in ``parametrize`` or in ``conftest.py`` (only one process may
+hold libtpu, and every xdist worker imports every test file); ``_interpret``
+is steered by ``monkeypatch`` in the test, not by an option of the program;
+the persistent compile cache is off around the compiles (an entry written
+for a described chip cannot be read back without one). All in ONE file: a
+second file could land on another worker, whose fixture would skip.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+GPT2 = dict(H=12, kvH=12, hd=64, E=768)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu / lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def _compiled_kernels(fn, *shapes) -> int:
+    """Compile ``fn`` for the described chip; number of Mosaic kernels in it."""
+    return jax.jit(fn).lower(*shapes).compile().as_text().count("tpu_custom_call")
+
+
+def test_flash_attention_fwd_bwd_gpt2_width(one_chip, monkeypatch):
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    x = jax.ShapeDtypeStruct((8, 1024, GPT2["H"], GPT2["hd"]), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return fa.flash_causal_attention(q, k, v).astype(jnp.float32).sum()
+
+    assert _compiled_kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) >= 2
+
+
+def test_flash_attention_partitions_over_four_chips(topo, monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel; ``ops.causal_attention`` runs
+    it per shard. This is the program the fsdp=4 train step traces."""
+    from deepspeed_tpu.ops import causal_attention
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    from deepspeed_tpu.topology.mesh import build_mesh, set_mesh
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")  # what 'auto' sees there
+    mesh = build_mesh(devices=topo.devices, axis_sizes={"fsdp": 4})
+    set_mesh(mesh)
+    x = jax.ShapeDtypeStruct((8, 1024, GPT2["H"], GPT2["hd"]), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P(("dp", "fsdp"))))
+
+    def loss(q, k, v):
+        return causal_attention(q, k, v).astype(jnp.float32).sum()
+
+    assert _compiled_kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) >= 2
+
+
+def _paged_shapes(sh, N, C, kv_quant, H, kvH, hd, pages=64, bs=16, num_blocks=512):
+    S = num_blocks * bs + 1
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=sh)
+    pool = sds((S, kvH, hd), jnp.int8 if kv_quant else jnp.bfloat16)
+    shapes = [sds((N, C, H, hd), jnp.bfloat16), pool, pool, sds((N, pages), jnp.int32),
+              sds((N, C), jnp.int32), sds((N,), jnp.int32)]
+    if kv_quant:
+        shapes += [sds((S, kvH, 1), jnp.float32)] * 2
+    return shapes, bs
+
+
+@pytest.mark.parametrize("C,kv_quant,H,kvH,hd", [
+    (1, False, 12, 12, 64),    # GPT-2 decode, bf16 pool
+    (1, True, 12, 12, 64),     # GPT-2 decode, int8 pool
+    (128, False, 12, 12, 64),  # GPT-2 chunked prefill
+    (1, True, 32, 8, 128),     # GQA at hd=128, int8 pool
+], ids=["decode-bf16", "decode-int8", "chunk128-bf16", "gqa-hd128-int8"])
+def test_paged_attention_compiles(one_chip, monkeypatch, C, kv_quant, H, kvH, hd):
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    shapes, bs = _paged_shapes(one_chip, 8, C, kv_quant, H, kvH, hd)
+
+    def fn(q, pk, pv, bt, qpos, lens, *scales):
+        kw = dict(zip(("k_scale", "v_scale"), scales))
+        return pa.flash_decode_paged(q, pk, pv, bt, qpos, bs, new_lens=lens, **kw)
+
+    assert _compiled_kernels(fn, *shapes) == 1
+
+
+def test_layer_norm_gpt2_width(one_chip, monkeypatch):
+    from deepspeed_tpu.ops.pallas import norms
+
+    monkeypatch.setattr(norms, "_interpret", lambda: False)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    E = GPT2["E"]
+    n = _compiled_kernels(norms.pallas_layer_norm, sds((8, 1024, E), jnp.bfloat16),
+                          sds((E,), jnp.float32), sds((E,), jnp.float32))
+    assert n == 1
+
+
+def test_rms_norm_partitions_over_four_chips(topo, monkeypatch):
+    """The per-row norm kernels ride the same per-shard wrapper as flash."""
+    from deepspeed_tpu.ops import registry, rms_norm
+    from deepspeed_tpu.ops.pallas import norms
+    from deepspeed_tpu.topology.mesh import build_mesh, set_mesh
+
+    monkeypatch.setattr(norms, "_interpret", lambda: False)
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")
+    mesh = build_mesh(devices=topo.devices, axis_sizes={"fsdp": 4})
+    set_mesh(mesh)
+    x = jax.ShapeDtypeStruct((8, 1024, GPT2["E"]), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P(("dp", "fsdp"))))
+    scale = jax.ShapeDtypeStruct((GPT2["E"],), jnp.float32, sharding=NamedSharding(mesh, P()))
+    assert _compiled_kernels(rms_norm, x, scale) == 1
+
+
+def _ring_all_reduce(topo, monkeypatch, codec):
+    """Lower+compile ``pallas_ring`` all-reduce of [4, 1Mi] fp32 on the
+    described 4-chip mesh; returns the compiled HLO text."""
+    from deepspeed_tpu import collectives
+    from deepspeed_tpu.collectives import pallas_backend as pb
+    from deepspeed_tpu.utils.compat import shard_map
+
+    monkeypatch.setattr(pb, "_interpret", lambda: False)
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("dp",))
+    x = jax.ShapeDtypeStruct((4, 1 << 20), jnp.float32, sharding=NamedSharding(mesh, P("dp")))
+    body = lambda row: collectives.all_reduce(row, "dp", algorithm="pallas_ring", codec=codec)
+    fn = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"), check_vma=False)
+    return jax.jit(fn).lower(x).compile().as_text()
+
+
+def test_pallas_ring_all_reduce_four_chips(topo, monkeypatch):
+    text = _ring_all_reduce(topo, monkeypatch, "none")
+    assert "tpu_custom_call" in text
+    assert "collective-permute" not in text  # every hop is a remote-DMA kernel
+
+
+def test_pallas_ring_fused_int8_hop_is_refused_by_name(topo, monkeypatch):
+    """The quantized fused hop does not compile on this Mosaic; until it is
+    rebuilt it must say so — not fall back — and the selector must not pick
+    the pair on its own."""
+    from deepspeed_tpu.collectives import pallas_backend as pb
+
+    with pytest.raises(NotImplementedError, match="fused int8 hop"):
+        _ring_all_reduce(topo, monkeypatch, "int8")
+    assert not pb.compiled_ok("pallas_ring", "int8")
+    assert pb.compiled_ok("pallas_ring", "none") and pb.compiled_ok("ring", "int8")

@@ -136,6 +136,6 @@ def test_attribute_program_without_capture_is_all_stall():
 
 
 def test_peak_envelopes_cover_all_ledger_backends():
-    for backend in ("cpu", "tpu-v5e", "interpret"):
+    for backend in ("cpu", "tpu-v5-lite", "interpret"):
         assert PEAK_FLOPS[backend] > 0
         assert PEAK_BYTES_PER_S[backend] > 0

@@ -30,6 +30,10 @@ def test_cache_dir_is_keyed_by_head_sha(tmp_path):
 def test_active_jax_config_points_into_keyed_dir():
     configured = jax.config.jax_compilation_cache_dir
     assert configured == conftest._CACHE_DIR
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:  # a cache placed from outside wins over the per-commit keying
+        assert configured == placed
+        return
     # the configured dir is a CHILD of the cache root, never the root
     # itself (the root held flat entries before keying landed)
     assert os.path.dirname(os.path.abspath(configured)) == os.path.abspath(

@@ -1,11 +1,11 @@
 """Tier-1 lint: no bare ``jax.shard_map`` / ``from jax import shard_map``.
 
-jax 0.4.x has no ``jax.shard_map`` (experimental-only, with a different
-signature) — the seed-wide breakage that took out dozens of tests at import
-time. Every call site must go through ``deepspeed_tpu.utils.compat.shard_map``
-(which also translates ``axis_names``/``check_vma`` to the experimental API);
-this grep makes the regression impossible to land quietly. Same story for
-``jax.lax.axis_size`` (absent pre-0.5): use ``compat.axis_size``.
+``shard_map`` and ``axis_size`` have both moved between jax releases, once
+taking out dozens of tests at import time. The package is written for the
+one installed jax, and every call site imports the two names from
+``deepspeed_tpu.utils.compat`` — so the next move is a one-line change there,
+and ``compat.axis_size`` keeps its tuple/``default=`` semantics in one place.
+This grep keeps call sites from reaching past it.
 """
 
 import os
@@ -64,7 +64,7 @@ def test_no_bare_shard_map_or_axis_size():
                 line = src.count("\n", 0, m.start()) + 1
                 offenders.append(f"{rel}:{line}: {label}")
     assert not offenders, (
-        "bare shard_map/axis_size usage (breaks on jax 0.4.x; import from "
+        "bare shard_map/axis_size usage (import from "
         "deepspeed_tpu.utils.compat instead):\n  " + "\n  ".join(offenders))
 
 
@@ -83,7 +83,8 @@ def test_lint_scans_collectives_package():
 
 
 def test_compat_shard_map_resolves():
-    """The shim must resolve on the installed jax (both kw spellings)."""
+    """The import point resolves on the installed jax, and the retired
+    ``check_rep`` spelling is refused rather than ignored."""
     from deepspeed_tpu.utils.compat import shard_map
 
     assert callable(shard_map)

@@ -87,7 +87,7 @@ def test_history_sorts_and_respects_before_round(tmp_path):
 # -------------------------------------------------------------- migration
 #: pinned per-family row counts — changing a loader changes these on purpose
 EXPECTED_COUNTS = {
-    "bench": 10,      # BENCH_r01..r05: headline + vs_baseline each
+    "bench": 6,       # BENCH_r03..r05: headline + vs_baseline each
     "multichip": 5,   # MULTICHIP_r01..r05: n_devices each
     "serving": 376,   # SERVING_r06/07/10/12/14 full metric trees
     "coll": 12,       # COLL_r11 worst-of-three + runs + probe table
@@ -130,8 +130,8 @@ def test_migrate_pinned_values(migrated):
     assert r["value"] == 8602.1
     assert (r["direction"], r["unit"]) == ("higher", "tokens/s/chip")
 
-    r = _one(rows, "bench", 1, "tokens_per_sec_per_chip_gpt2_125m_bf16_seq1024")
-    assert r["value"] == 10354.6
+    r = _one(rows, "bench", 3, "tokens_per_sec_cpu_smoke_seq128")
+    assert r["value"] == 11710.5
 
     r = _one(rows, "coll", 11, "overhead_pct")
     assert r["value"] == 1.53

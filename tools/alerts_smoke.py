@@ -21,6 +21,11 @@ actually stays quiet (``tools/run_nightly.sh`` commits ``ALERTS_rNN.log``):
      against the collector MUST emit a timeline naming both events.
 
 Prints one JSON line of evidence (the committed-log artifact).
+
+CPU-ONLY: the parent touches jax and then starts children that do too, so
+every process here pins ``JAX_PLATFORMS=cpu``. On a TPU a chip belongs to one
+process at a time — a fleet on chips is one daemon process per chip with a
+parent that stays off jax; this tool never asks for a device.
 """
 
 from __future__ import annotations

@@ -10,6 +10,11 @@ The multi-process proof of ISSUE 18's cross-process serving fabric, run by
 deterministic tiny model (flax init from PRNGKey(0) is bit-identical across
 processes), so token comparisons against a local reference engine are exact.
 
+CPU-ONLY: the parent touches jax and then starts children that do too, so
+every process here pins ``JAX_PLATFORMS=cpu``. On a TPU a chip belongs to one
+process at a time — a fleet on chips is one daemon process per chip with a
+parent that stays off jax; this tool never asks for a device.
+
 ``--smoke`` legs (tier-1):
   1. disagg serve, bf16 AND int8 KV: admit → prefill on one process →
      wire-migrate across the process boundary → decode on another; greedy
@@ -475,9 +480,12 @@ def main() -> int:
     out_dir = args.out or tempfile.mkdtemp(prefix="fabric_smoke_")
     os.makedirs(out_dir, exist_ok=True)
     # children share one persistent XLA compile cache (env-inherited):
-    # daemons 2..N and every trainer relaunch reuse daemon 1's compiles
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          os.path.join(out_dir, "jax_cache"))
+    # daemons 2..N and every trainer relaunch reuse daemon 1's compiles.
+    # The directory is the package's one fixed place, never under out_dir —
+    # a path that moves is a cache that never hits
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = enable_compile_cache()
     run_id = f"fabric-smoke-{os.getpid():x}"
     fleet.configure_identity(run_id=run_id, process_index=0, role="router")
     telemetry.get_tracer().configure(enabled=True)

@@ -16,6 +16,11 @@ integration test (``tests/unit/test_fleet.py``). Three processes on CPU:
            flow STEP), push their registry dump + heartbeat to the
            collector over HTTP, and export their tracer stream as JSONL.
 
+CPU-ONLY: the parent touches jax and then starts children that do too, so
+every process here pins ``JAX_PLATFORMS=cpu``. On a TPU a chip belongs to one
+process at a time — a fleet on chips is one daemon process per chip with a
+parent that stays off jax; this tool never asks for a device.
+
 Exit gates (any failure => exit 1):
   1. federated counters BIT-EXACTLY equal the sum of the per-process
      dumps the collector holds (counters sum, histogram counts add);

@@ -142,9 +142,9 @@ def main(argv: Optional[list] = None) -> int:
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
-    from deepspeed_tpu.utils.cpu_backend import force_cpu_backend
+    import jax
 
-    force_cpu_backend()
+    jax.config.update("jax_platforms", "cpu")
 
     gates = {**_train_gates(), **_decode_gates()}
     ok = all(gates[k] for k in (
