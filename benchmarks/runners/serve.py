@@ -1,0 +1,286 @@
+"""Serving cells: ``InferenceEngineV2.generate`` under open-loop arrivals
+(``traffic.kind: open_loop``) or closed waves (``closed_waves``).
+
+The workload file gives the engine's own config (``engine``) and the traffic.
+Set-up: bf16 weights made on the device from ``--seed`` in one jitted call,
+the engine with its KV pool, the check against the plain reference, then one
+run of every program the cell's traffic can reach (``warm``). Per-request
+times are the engine's own ``RequestRecord`` stamps (``perf_counter``:
+arrival = the time the request was DUE, first token, finish), which need
+``flight_recorder`` on in the engine config. With a trace directory, the
+calls into the engine's two dispatch methods are wrapped to record rows and
+context per call, and a few seconds in the middle of the window run under
+the profiler.
+"""
+
+from __future__ import annotations
+
+import shutil
+import time
+
+import numpy as np
+
+from benchmarks.lib import harness, program, stats, traffic
+
+CHECK_PROMPTS = 4
+CHECK_DECODE_STEPS = 2
+CHECK_GENERATED = 10
+TRACE_START_SHARE = 0.3  # of --seconds
+TRACE_SECONDS = 3.0
+# Relative L2 error of last-position logits, bf16 weights, activations and KV
+# pool against the fp32 reference on the same (bf16-rounded) weights, through
+# 24 layers. The chip read 0.0078-0.0083 in each of the 44 runs of this path
+# (PERF.md, section 2); the bound is 1.2 times the largest reading, so an error
+# a quarter larger than bf16's own fails. What an int8 KV pool reads on the
+# chip is in PERF.md, section 2.
+LOGIT_REL_TOL = 0.010
+# How far below the reference's best logit the reference's logit of a token
+# that ``generate`` picked may lie, in units of that row's RMS in the
+# reference's own fp32 logits. Logits within LOGIT_REL_TOL of the reference's
+# are off by LOGIT_REL_TOL x RMS each on average and by 4.5 times that at the
+# worst of 50,304 (Gaussian errors), and a wrong pick needs two of them.
+TOKEN_GAP_TOL = 2 * 4.5 * LOGIT_REL_TOL
+
+
+def make_weights(model_cfg, seed):
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import CausalLM
+
+    @jax.jit
+    def make(key):
+        params = CausalLM(model_cfg).init(
+            {"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)}, train=False)["params"]
+        return jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), params)
+
+    return make(jax.random.PRNGKey(seed & 0x7FFFFFFF))
+
+
+def check(engine, reference, config, seed):
+    """Prefill logits and logits of further tokens fed through the cache (the
+    ``put`` path), then the tokens ``generate`` picks (the fused prefill and
+    decode-chain programs), each against the reference's full forward."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng([seed & 0xFFFFFFFF, 7])
+    bucket = engine.config.chunk_bucket
+    # the lengths come from the seed, the shapes do not: a shape of its own for
+    # every seed would compile the reference anew in every run
+    lens = rng.integers(bucket // 2, bucket - CHECK_DECODE_STEPS, CHECK_PROMPTS)
+    total = bucket + CHECK_GENERATED
+    seqs = rng.integers(0, config["vocab_size"], (CHECK_PROMPTS, total), dtype=np.int32)
+    ref_forward = jax.jit(lambda w, t: reference.forward(w, program.published(config), t))
+    weights = program.reference_weights(engine.params)
+    want = np.asarray(ref_forward(weights, jnp.asarray(seqs)))
+
+    uids = list(range(10_000, 10_000 + CHECK_PROMPTS))
+    errs = []
+    for step in range(CHECK_DECODE_STEPS + 1):
+        fed = [seqs[i, :lens[i]] if step == 0 else seqs[i, lens[i] + step - 1:lens[i] + step]
+               for i in range(CHECK_PROMPTS)]
+        got = np.asarray(engine.put(uids, fed), np.float32)
+        ref = np.stack([want[i, lens[i] + step - 1] for i in range(CHECK_PROMPTS)])
+        errs.append(program.relative_error(got, ref))
+    for uid in uids:
+        engine.flush(uid)
+
+    prompts = [seqs[i, :lens[i]] for i in range(CHECK_PROMPTS)]
+    outs = engine.generate(prompts, max_new_tokens=CHECK_GENERATED)
+    full = seqs.copy()
+    for i, (p, o) in enumerate(zip(prompts, outs)):
+        full[i, len(p):len(p) + len(o)] = o
+    want = np.asarray(ref_forward(weights, jnp.asarray(full)))
+    worst_gap = 0.0  # in units of the reference row's RMS
+    for i, (p, o) in enumerate(zip(prompts, outs)):
+        for j, tok in enumerate(o):
+            row = want[i, len(p) + j - 1]
+            worst_gap = max(worst_gap, float((row.max() - row[tok]) / np.sqrt(np.mean(row ** 2))))
+    ok = bool(max(errs) <= LOGIT_REL_TOL and worst_gap <= TOKEN_GAP_TOL
+              and all(len(o) == CHECK_GENERATED for o in outs))
+    harness.say(check_logit_rel_err=errs, tol=LOGIT_REL_TOL, generated_token_gap=worst_gap,
+                gap_tol=TOKEN_GAP_TOL, ok=ok)
+    return ok
+
+
+def warm(engine, workload, vocab):
+    """One run of every program the window can reach: fused prefill at each
+    (rows, chunk) the file lists, the decode chain at each row bucket."""
+    rng = np.random.default_rng(0)
+    w = workload["warm"]
+    k = engine.config.decode_chain
+
+    def prompts(n, length):
+        return [rng.integers(0, vocab, length, dtype=np.int32) for _ in range(n)]
+
+    for rows, chunk in w["prefill"]:
+        engine.generate(prompts(rows, chunk), max_new_tokens=1)
+    for rows in w["chain_rows"]:
+        # the first token comes from the prefill, the next k from one chain
+        engine.generate(prompts(rows, w["chain_prompt_len"]), max_new_tokens=1 + k)
+    # The engine cuts each program's padded outputs down to its n live rows
+    # eagerly (toks[:n], out[:n], emitted[:n]), one tiny compiled slice per
+    # (rows, n). Make each once here, on arrays placed as the programs' outputs
+    # are, or the first batch of every new size compiles inside the window.
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    placed = NamedSharding(engine.mesh, PartitionSpec())
+    bucket = engine.config.row_bucket
+    for rows in sorted({r for r, _ in w["prefill"]} | set(w["chain_rows"])):
+        flat = jax.device_put(np.zeros((rows,), np.int32), placed)
+        wide = jax.device_put(np.zeros((rows, k), np.int32), placed)
+        for n in range(rows - bucket + 1, rows + 1):
+            jax.block_until_ready((flat[:n], wide[:n]))
+
+
+class Spans:
+    """The benchmark's own spans around the engine's two dispatch methods,
+    and the profiler switched on for a few seconds of the window."""
+
+    def __init__(self, engine, trace_dir, start_after_s, trace_seconds):
+        self.calls = []
+        self.engine, self.traced = engine, harness.TraceWindow(trace_dir)
+        self.start_after_s, self.trace_seconds = start_after_s, trace_seconds
+        self.t0 = None
+        self.state = "before"
+        self.trace_started_s = None
+        # the profiler's first start takes seconds: pay them here, in set-up
+        self.traced.start()
+        self.traced.stop()
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        self._wrap("decode_chain", "decode_chain", self._chain_rows)
+        self._wrap("_put_sample", "prefill", self._prefill_rows)
+
+    def _chain_rows(self, args, out):
+        uids, emitted = args[0], out[1]
+        seen = np.asarray([self.engine.state.get(u).seen_tokens for u in uids]) - emitted
+        context = float((emitted * seen + emitted * (emitted + 1) / 2).sum())
+        return {"rows": len(uids), "row_steps": int(emitted.sum()), "context_tokens": context}
+
+    def _prefill_rows(self, args, out):
+        return {"rows": len(args[0]), "tokens": int(sum(len(t) for t in args[1]))}
+
+    def _wrap(self, method, label, describe):
+        import jax
+
+        inner = getattr(self.engine, method)
+
+        def wrapped(*args, **kwargs):
+            self._switch()
+            traced = self.state == "tracing"
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("bench:" + label):
+                out = inner(*args, **kwargs)
+            self.calls.append({"kind": label, "t0": t0, "t1": time.perf_counter(),
+                               "traced": traced, **describe(args, out)})
+            return out
+
+        setattr(self.engine, method, wrapped)
+
+    def _switch(self):
+        if self.t0 is None:
+            return
+        now = time.perf_counter() - self.t0
+        if self.state == "before" and now >= self.start_after_s:
+            self.trace_started_s = now
+            self.traced.start()
+            harness.say(profiler_start_s=time.perf_counter() - self.t0 - now)
+            self.state, self.stop_at = "tracing", now + self.trace_seconds
+        elif self.state == "tracing" and now >= self.stop_at:
+            self.stop()
+
+    def stop(self):
+        self.traced.stop()
+        self.state = "done"
+
+
+def request_rows(records, outs, output_tokens, t_origin):
+    rows = []
+    for i, out in enumerate(outs):
+        r = records[i]
+        done = r.finish is not None and len(out) == output_tokens
+        rows.append({
+            "due_s": r.arrival - t_origin, "ok": done,
+            "ttft_s": r.ttft_s, "queue_wait_s": r.queue_wait_s,
+            "tpot_s": stats.tpot_s(r.first_token, r.finish, len(out)) if done else None,
+            "finish_s": (r.finish - t_origin) if done else None,
+            "tokens": len(out), "preemptions": r.preemptions})
+    return rows
+
+
+def run(*, workload, config, reference, seed, seconds, devices, trace_dir, compiles,
+        t_process_start):
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
+    from deepspeed_tpu.topology.mesh import build_mesh
+
+    tr = workload["traffic"]
+    vocab = config["vocab_size"]
+    model_cfg = program.model_config(config, jnp.bfloat16)
+    phases = harness.Phases(t_process_start)
+    params = make_weights(model_cfg, seed)
+    mesh = build_mesh(devices=devices, axis_sizes={"tp": 1, "dp": len(devices)})
+    engine = InferenceEngineV2(model_cfg, params, dict(workload["engine"]), mesh=mesh)
+    del params
+    phases.done("weights_and_engine")
+    correct = check(engine, reference, config, seed)
+    phases.done("check_against_reference")
+    warm(engine, workload, vocab)
+    phases.done("warm_up")
+
+    spans = Spans(engine, trace_dir, TRACE_START_SHARE * seconds, TRACE_SECONDS) if trace_dir else None
+    compiles.mark()
+    setup_s = time.perf_counter() - t_process_start
+    t0 = time.perf_counter()
+    if spans:
+        spans.t0 = t0
+    rows = []
+    if tr["kind"] == "open_loop":
+        reqs = traffic.open_loop(tr, vocab, seed, seconds)
+        outs = engine.generate(reqs.prompts, max_new_tokens=reqs.output_tokens,
+                               arrival_times=list(reqs.arrival_s), seed=seed & 0x7FFFFFFF)
+        rows = request_rows(engine.lifecycle.records(), outs, reqs.output_tokens, t0)
+    elif tr["kind"] == "closed_waves":
+        for reqs in traffic.closed_waves(tr, vocab, seed):
+            if time.perf_counter() - t0 >= seconds:
+                break
+            outs = engine.generate(reqs.prompts, max_new_tokens=reqs.output_tokens,
+                                   seed=seed & 0x7FFFFFFF)
+            rows += request_rows(engine.lifecycle.records(), outs, reqs.output_tokens, t0)
+    else:
+        raise ValueError(f"the serve runner has no traffic kind {tr['kind']!r}")
+    elapsed = time.perf_counter() - t0
+    if spans:
+        spans.stop()
+    in_window = compiles.since_mark()
+    memory = harness.memory_held(devices)
+
+    ok = [r for r in rows if r["ok"]]
+    failed = len(rows) - len(ok)
+    first_due = min(r["due_s"] for r in rows)
+    out_tokens = sum(r["tokens"] for r in ok)
+    span_s = max(r["finish_s"] for r in ok) - first_due
+    ttft_ms = [1e3 * r["ttft_s"] for r in ok]
+    tpot_ms = [1e3 * r["tpot_s"] for r in ok]
+    end_to_end = {
+        "setup_s": setup_s,
+        "serve_ttft_p95_ms": stats.percentile(ttft_ms, 95),
+        "serve_tpot_p95_ms": stats.percentile(tpot_ms, 95),
+        "serve_out_tokens_per_s": stats.rate(out_tokens, span_s),
+    }
+    harness.say(requests=len(rows), failed=failed, window_s=elapsed,
+                ttft_ms=stats.describe(ttft_ms), ttft_p95_ms=end_to_end["serve_ttft_p95_ms"],
+                tpot_ms=stats.describe(tpot_ms), tpot_p95_ms=end_to_end["serve_tpot_p95_ms"],
+                out_tokens_per_s=end_to_end["serve_out_tokens_per_s"],
+                preemptions=sum(r["preemptions"] for r in rows),
+                compiles_in_window=in_window, setup_s=setup_s)
+    return {
+        "correct": bool(correct and failed == 0), "attempted": len(rows), "failed": failed,
+        "end_to_end": end_to_end, "requests": rows, "compiles_in_window": in_window,
+        "calls": spans.calls if spans else [], "chips": len(devices), "elapsed_s": elapsed,
+        "trace_started_s": spans.trace_started_s if spans else None,
+        "kv_block_size": engine.config.kv_block_size, "memory": memory,
+    }

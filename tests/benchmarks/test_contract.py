@@ -1,0 +1,111 @@
+"""BENCHMARK.json against the files it names and the builder's contract."""
+
+import json
+import os
+import re
+
+import pytest
+
+from benchmarks.lib import harness
+
+REPO = os.path.dirname(harness.BENCH_DIR)
+BENCH = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+WIDTHS = ("hidden_size", "intermediate_size", "num_attention_heads", "rotary_pct")
+CELLS = [w["name"] for w in BENCH["workloads"]]
+E2E = {m["name"]: m for m in BENCH["end_to_end"]}
+
+
+def cells_of(metric):
+    return metric.get("workloads", CELLS)
+
+
+def test_top_level_keys_and_limits():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs", "workloads",
+                          "end_to_end", "per_layer"}
+    assert BENCH["command"] == ["python3", "benchmarks/run.py"]
+    assert BENCH["paths"] == ["benchmarks", "tests/benchmarks"]
+    assert 1 <= BENCH["run_seconds"] <= 51
+    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) <= max(1, len(CELLS) // 4)
+    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 * 1024
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"] + BENCH["workloads"] + BENCH["end_to_end"]
+                         + BENCH["per_layer"], ids=lambda e: e["name"])
+def test_names_units_and_lines(entry):
+    assert NAME.match(entry["name"])
+    for key in ("why", "layer", "source"):
+        if key in entry:
+            assert 1 <= len(entry[key]) <= 200 and "\n" not in entry[key] and "\t" not in entry[key]
+    if "unit" in entry:
+        assert UNIT.match(entry["unit"]) and entry["better"] in ("lower", "higher")
+        assert entry["source"] in SOURCES
+
+
+@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
+def test_configs_are_the_published_ones_uncut(config):
+    assert set(config) == {"name", "source", "file", "reduced", "why"}
+    assert config["file"] == f"benchmarks/configs/{config['name']}.json"
+    held = harness.load_config(config["name"])
+    assert held["source"] == config["source"] and held["reduced"] == config["reduced"] == []
+    assert held["model_type"] == "gpt_neox" and held["num_hidden_layers"] == 24
+    assert held["hidden_size"] // held["num_attention_heads"] in (64, 128)
+    assert all(k in held for k in WIDTHS)
+    assert os.path.isfile(os.path.join(REPO, held["reference"]))
+    assert any(w["config"] == config["name"] for w in BENCH["workloads"])
+
+
+@pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda c: c["name"])
+def test_each_cell_has_its_files_and_its_metrics(cell):
+    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
+    held = harness.load_workload(cell["name"])
+    assert (held["name"], held["config"], held["chips"], held["why"]) == (
+        cell["name"], cell["config"], cell["chips"], cell["why"])
+    assert cell["name"] == f"{cell['config']}.{cell['traffic']}"
+    assert os.path.isfile(os.path.join(harness.BENCH_DIR, "runners", held["kind"] + ".py"))
+    reported = harness.cell_metrics(BENCH, "end_to_end", cell["name"])
+    assert "setup_s" in {m["name"] for m in reported} and len(reported) >= 2
+    assert harness.cell_metrics(BENCH, "per_layer", cell["name"])
+
+
+def test_end_to_end_metrics():
+    assert E2E["setup_s"]["bound"] <= 0.1 and "workloads" not in E2E["setup_s"]
+    for m in BENCH["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
+        assert 0.01 <= m["bound"] <= 0.1 and m["source"] in ("host_clock", "device_trace")
+        assert set(cells_of(m)) <= set(CELLS)
+
+
+@pytest.mark.parametrize("metric", BENCH["per_layer"], ids=lambda m: m["name"])
+def test_per_layer_metric_has_its_reader_and_its_end_to_end_metric(metric):
+    assert set(metric) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}
+    assert callable(harness.load_reader(metric["name"]))
+    assert cells_of(metric) and set(cells_of(metric)) <= set(CELLS)
+    for cell in cells_of(metric):  # the metric it moves is reported wherever it is
+        assert cell in cells_of(E2E[metric["moves"]])
+    if "roofline" in metric["name"] or "mfu" in metric["name"]:
+        assert metric["unit"] == "%" and metric["better"] == "higher"
+
+
+# what waits under benchmarks/ for the chat cell (PERF.md, section 7)
+WAITING_READERS = {"prefill_ms", "queue_wait_p95_ms"}
+WAITING_CELLS = {"pythia-1.4b.serve.chat"}
+
+
+def test_every_reader_is_listed_or_waits_for_its_cell():
+    stems = {f[:-3] for f in os.listdir(os.path.join(harness.BENCH_DIR, "metrics"))
+             if f.endswith(".py")}
+    used = {m["name"] for m in BENCH["per_layer"]} | {m["name"].rpartition(".")[0]
+                                                     for m in BENCH["per_layer"]}
+    assert stems - used == WAITING_READERS
+
+
+def test_every_workload_file_is_a_cell_or_says_why_not():
+    files = {f[:-5] for f in os.listdir(os.path.join(harness.BENCH_DIR, "workloads"))}
+    assert files - set(CELLS) == WAITING_CELLS
+    for name in WAITING_CELLS:
+        assert "NOT a cell" in harness.load_workload(name)["status"]
+    with pytest.raises(KeyError, match="not a cell"):
+        harness.cell_metrics(BENCH, "end_to_end", "pythia-1.4b.serve.chat")
