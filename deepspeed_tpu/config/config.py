@@ -221,10 +221,6 @@ class TelemetryConfig(DeepSpeedConfigModel):
     sharing one registry here. Zero overhead when disabled (the default)."""
 
     enabled: bool = False
-    # Drain the device queue at span boundaries so spans measure true device
-    # time instead of async dispatch. Serializes the dispatch pipeline — for
-    # diagnosis runs, not production steps.
-    sync_spans: bool = False
     # Bounded in-memory event buffer; overflow counts dropped_events.
     max_events: int = 100_000
     # Chrome trace-event JSON (open at https://ui.perfetto.dev), written at

@@ -905,6 +905,13 @@ class InferenceEngineV2:
         self.host_sync_count += 1
         return np.asarray(logits[: len(uids)])
 
+    def _span_rids(self, rids: Optional[Sequence[int]]) -> str:
+        """Request indices as one span arg (spans of one request share an
+        identifier); formatted only while somebody records spans."""
+        if rids is None or not self._tracer.recording():
+            return ""
+        return " ".join(map(str, rids))
+
     def _put_sample(self, uids, token_lists, rng, sample_kw: Tuple,
                     tracker: Optional[LifecycleTracker] = None,
                     rids: Optional[Sequence[int]] = None) -> Tuple[np.ndarray, jax.Array]:
@@ -913,7 +920,8 @@ class InferenceEngineV2:
         logits transfer."""
         batch = self._build_batch(uids, token_lists)
         step = self._sample_step_fn(batch.n_rows, batch.tokens.shape[1], sample_kw)
-        with self._tracer.span("serve:dispatch", kind="prefill", rows=batch.n_rows):
+        with self._tracer.span("serve:dispatch", kind="prefill", rows=batch.n_rows,
+                               live=len(uids), rids=self._span_rids(rids)):
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "prefill")
             toks, rng, self.pool = step(
@@ -970,7 +978,8 @@ class InferenceEngineV2:
         """
         n = len(uids)
         rows = -(-n // self.config.row_bucket) * self.config.row_bucket
-        with self._tracer.span("serve:assemble", kind="chain", rows=rows):
+        chain_id = self.chain_steps  # shared by every span of this chain
+        with self._tracer.span("serve:assemble", kind="chain", rows=rows, chain=chain_id):
             # pre-extend every row's block table for its share of the K-token
             # window (capped by the row's remaining budget — no KV slots are
             # reserved past max_new_tokens) so the compiled program never
@@ -984,7 +993,8 @@ class InferenceEngineV2:
             buf["active"][:n] = True
             buf["budgets"][:n] = np.minimum(budgets, k)
         chain = self._chain_fn(rows, k, eos_id, sample_kw)
-        with self._tracer.span("serve:dispatch", kind="chain", rows=rows, k=k):
+        with self._tracer.span("serve:dispatch", kind="chain", rows=rows, live=n,
+                               k=k, chain=chain_id):
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "chain")
             out, emitted, _, rng, self.pool = chain(
@@ -994,7 +1004,7 @@ class InferenceEngineV2:
                 jnp.asarray(buf["budgets"]), rng,
             )
         self.dispatch_count += 1
-        with self._tracer.span("serve:fetch", kind="chain"):
+        with self._tracer.span("serve:fetch", kind="chain", chain=chain_id):
             out = np.asarray(out[:n])
             emitted = np.asarray(emitted[:n])
         self.host_sync_count += 1
@@ -1028,7 +1038,9 @@ class InferenceEngineV2:
         n_spec = self.config.spec_decode
         m = 1 + n_spec
         rows = -(-n // self.config.row_bucket) * self.config.row_bucket
-        with self._tracer.span("serve:assemble", kind="spec_chain", rows=rows):
+        chain_id = self.chain_steps
+        with self._tracer.span("serve:assemble", kind="spec_chain", rows=rows,
+                               chain=chain_id):
             buf = self._chain_arrays(rows)
             sb = self._spec_buf.get(rows)
             if sb is None:
@@ -1051,7 +1063,7 @@ class InferenceEngineV2:
             buf["budgets"][:n] = np.minimum(budgets, k * m)
         chain = self._spec_chain_fn(rows, k, eos_id)
         with self._tracer.span("serve:dispatch", kind="spec_chain", rows=rows,
-                               k=k, n_spec=n_spec):
+                               live=n, k=k, n_spec=n_spec, chain=chain_id):
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "chain")
             out, emitted, _, steps, rng, self.pool = chain(
@@ -1062,7 +1074,7 @@ class InferenceEngineV2:
                 jnp.asarray(sb["hist"]), jnp.asarray(sb["hist_len"]),
             )
         self.dispatch_count += 1
-        with self._tracer.span("serve:fetch", kind="spec_chain"):
+        with self._tracer.span("serve:fetch", kind="spec_chain", chain=chain_id):
             out = np.asarray(out[:n])
             emitted = np.asarray(emitted[:n])
             steps = np.asarray(steps[:n])
@@ -1141,91 +1153,101 @@ class InferenceEngineV2:
         served it (``inference/lifecycle.py``). Disabled, no per-request
         records are allocated and the loop is unchanged.
         """
-        prompts = [np.asarray(p, np.int32) for p in prompts]
-        pool_tokens = self.num_kv_blocks * self.config.kv_block_size
-        n_spec = self.config.spec_decode
-        if n_spec > 0 and do_sample:
-            raise ValueError(
-                "spec_decode is greedy-only (verify-and-accept compares "
-                "argmax targets); disable do_sample or set spec_decode=0")
-        # spec chains write up to n_spec transient (rejected-draft) KV slots
-        # past the last emitted token — the length guards carry that margin
-        margin = n_spec
-        for i, p in enumerate(prompts):
-            if len(p) + max_new_tokens + margin > self.max_seq_len:
-                raise ValueError(
-                    f"prompt {i} ({len(p)} tokens) + max_new_tokens={max_new_tokens} "
-                    f"(+{margin} speculative slack) exceeds engine "
-                    f"max_seq_len={self.max_seq_len}"
-                )
-            if len(p) + max_new_tokens + margin > pool_tokens:
-                raise ValueError(
-                    f"prompt {i} ({len(p)} tokens) + max_new_tokens={max_new_tokens} "
-                    f"cannot ever fit the KV pool ({pool_tokens} slots); no amount of "
-                    f"preemption can complete it"
-                )
-        sample_kw = (("do_sample", do_sample), ("temperature", temperature),
-                     ("top_k", top_k), ("top_p", top_p))
-        t_start = time.perf_counter()
-        arr: Optional[List[float]] = None
-        if arrival_times is not None:
-            if len(arrival_times) != len(prompts):
-                raise ValueError(
-                    f"arrival_times has {len(arrival_times)} entries for "
-                    f"{len(prompts)} prompts")
-            arr = [float(a) for a in arrival_times]
-            queue: deque = deque(sorted(range(len(prompts)), key=lambda i: arr[i]))
-        else:
-            queue = deque(range(len(prompts)))  # idx, FIFO
-        gen: Dict[int, List[int]] = {i: [] for i in range(len(prompts))}
-        active: Dict[int, int] = {}  # uid -> idx
-        order: Dict[int, None] = {}  # admission order (insertion-ordered set)
-        outputs: Dict[int, np.ndarray] = {}
-        # committed key, replicated like every step output: a fresh PRNGKey
-        # is uncommitted, but the key a chain returns carries
-        # NamedSharding(mesh, P()) — jit caches on that difference, so an
-        # uncommitted first key makes the SECOND admission wave recompile
-        # the prefill program mid-serving (a ~0.4s TTFT cliff under bursts)
-        rng = jax.device_put(jax.random.PRNGKey(seed),
-                             NamedSharding(self.mesh, P()))
-        next_uid = 0
-        registry = self._tracer.registry if self._tracer.enabled else None
+        with self._tracer.span("serve:generate", requests=len(prompts),
+                               max_new_tokens=max_new_tokens):
+            return self._generate_loop(
+                prompts, max_new_tokens, eos_token_id, do_sample, temperature,
+                top_k, top_p, seed, arrival_times)
 
-        # ---- per-request lifecycle tracking (None = nothing allocated)
-        tracker: Optional[LifecycleTracker] = None
-        if self._tracer.enabled or self._recorder is not None:
-            tracker = LifecycleTracker(
-                self._tracer, slo=self.config.serving_slo,
-                labels={"k": self.config.decode_chain},
-                recorder=self._recorder)
-            for i in range(len(prompts)):
-                tracker.arrive(i, now=t_start + (arr[i] if arr is not None else 0.0))
-        self.lifecycle = tracker
-        if registry is not None:
-            # the cheap scheduler/pool gauges, refreshed at chain boundaries
-            # (handles resolved once — the loop pays plain attribute sets)
-            g_queue = registry.gauge("serving/queue_depth")
-            g_occ = registry.gauge("serving/batch_occupancy")
-            g_free = registry.gauge("serving/kv_pool_free_blocks")
-            kv_name = self.config.kv_dtype_name
-            g_util = registry.gauge("serving/kv_pool_utilization", dtype=kv_name)
-            # quantized-serving capacity facts (set once — they are config,
-            # not chain-boundary state): which storage the pool runs and what
-            # one token slot costs, the number capacity plans divide HBM by
-            registry.gauge("serving/kv_pool_dtype", dtype=kv_name).set(1.0)
-            registry.gauge("serving/kv_bytes_per_token").set(
-                float(self.kv_bytes_per_token))
-            c_preempt = registry.counter("serving/preemptions")
-            c_tokens = registry.counter("serving/tokens_decoded")
-            c_chains = registry.counter("serving/chains")
-            h_chain_len = registry.histogram("serving/chain_len")
-            g_pfx_hit = g_pfx_blocks = g_spec_acc = g_spec_tpf = None
-            if self.prefix_cache is not None:
-                g_pfx_hit = registry.gauge("serving/prefix_hit_rate")
-                g_pfx_blocks = registry.gauge("serving/prefix_cached_blocks")
-            if self.config.spec_decode > 0:
-                g_spec_acc = registry.gauge("serving/spec_accept_rate")
-                g_spec_tpf = registry.gauge("serving/spec_tokens_per_forward")
+    def _generate_loop(self, prompts, max_new_tokens, eos_token_id, do_sample,
+                       temperature, top_k, top_p, seed, arrival_times) -> List[np.ndarray]:
+        # the call's own set-up: validation, the queue, the key, lifecycle records
+        with self._tracer.span("serve:setup", requests=len(prompts)):
+            prompts = [np.asarray(p, np.int32) for p in prompts]
+            pool_tokens = self.num_kv_blocks * self.config.kv_block_size
+            n_spec = self.config.spec_decode
+            if n_spec > 0 and do_sample:
+                raise ValueError(
+                    "spec_decode is greedy-only (verify-and-accept compares "
+                    "argmax targets); disable do_sample or set spec_decode=0")
+            # spec chains write up to n_spec transient (rejected-draft) KV slots
+            # past the last emitted token — the length guards carry that margin
+            margin = n_spec
+            for i, p in enumerate(prompts):
+                if len(p) + max_new_tokens + margin > self.max_seq_len:
+                    raise ValueError(
+                        f"prompt {i} ({len(p)} tokens) + max_new_tokens={max_new_tokens} "
+                        f"(+{margin} speculative slack) exceeds engine "
+                        f"max_seq_len={self.max_seq_len}"
+                    )
+                if len(p) + max_new_tokens + margin > pool_tokens:
+                    raise ValueError(
+                        f"prompt {i} ({len(p)} tokens) + max_new_tokens={max_new_tokens} "
+                        f"cannot ever fit the KV pool ({pool_tokens} slots); no amount of "
+                        f"preemption can complete it"
+                    )
+            sample_kw = (("do_sample", do_sample), ("temperature", temperature),
+                         ("top_k", top_k), ("top_p", top_p))
+            t_start = time.perf_counter()
+            arr: Optional[List[float]] = None
+            if arrival_times is not None:
+                if len(arrival_times) != len(prompts):
+                    raise ValueError(
+                        f"arrival_times has {len(arrival_times)} entries for "
+                        f"{len(prompts)} prompts")
+                arr = [float(a) for a in arrival_times]
+                queue: deque = deque(sorted(range(len(prompts)), key=lambda i: arr[i]))
+            else:
+                queue = deque(range(len(prompts)))  # idx, FIFO
+            gen: Dict[int, List[int]] = {i: [] for i in range(len(prompts))}
+            active: Dict[int, int] = {}  # uid -> idx
+            order: Dict[int, None] = {}  # admission order (insertion-ordered set)
+            outputs: Dict[int, np.ndarray] = {}
+            # committed key, replicated like every step output: a fresh PRNGKey
+            # is uncommitted, but the key a chain returns carries
+            # NamedSharding(mesh, P()) — jit caches on that difference, so an
+            # uncommitted first key makes the SECOND admission wave recompile
+            # the prefill program mid-serving (a ~0.4s TTFT cliff under bursts)
+            rng = jax.device_put(jax.random.PRNGKey(seed),
+                                 NamedSharding(self.mesh, P()))
+            next_uid = 0
+            registry = self._tracer.registry if self._tracer.enabled else None
+
+            # ---- per-request lifecycle tracking (None = nothing allocated)
+            tracker: Optional[LifecycleTracker] = None
+            if self._tracer.enabled or self._recorder is not None:
+                tracker = LifecycleTracker(
+                    self._tracer, slo=self.config.serving_slo,
+                    labels={"k": self.config.decode_chain},
+                    recorder=self._recorder)
+                for i in range(len(prompts)):
+                    tracker.arrive(i, now=t_start + (arr[i] if arr is not None else 0.0))
+            self.lifecycle = tracker
+            if registry is not None:
+                # the cheap scheduler/pool gauges, refreshed at chain boundaries
+                # (handles resolved once — the loop pays plain attribute sets)
+                g_queue = registry.gauge("serving/queue_depth")
+                g_occ = registry.gauge("serving/batch_occupancy")
+                g_free = registry.gauge("serving/kv_pool_free_blocks")
+                kv_name = self.config.kv_dtype_name
+                g_util = registry.gauge("serving/kv_pool_utilization", dtype=kv_name)
+                # quantized-serving capacity facts (set once — they are config,
+                # not chain-boundary state): which storage the pool runs and what
+                # one token slot costs, the number capacity plans divide HBM by
+                registry.gauge("serving/kv_pool_dtype", dtype=kv_name).set(1.0)
+                registry.gauge("serving/kv_bytes_per_token").set(
+                    float(self.kv_bytes_per_token))
+                c_preempt = registry.counter("serving/preemptions")
+                c_tokens = registry.counter("serving/tokens_decoded")
+                c_chains = registry.counter("serving/chains")
+                h_chain_len = registry.histogram("serving/chain_len")
+                g_pfx_hit = g_pfx_blocks = g_spec_acc = g_spec_tpf = None
+                if self.prefix_cache is not None:
+                    g_pfx_hit = registry.gauge("serving/prefix_hit_rate")
+                    g_pfx_blocks = registry.gauge("serving/prefix_cached_blocks")
+                if self.config.spec_decode > 0:
+                    g_spec_acc = registry.gauge("serving/spec_accept_rate")
+                    g_spec_tpf = registry.gauge("serving/spec_tokens_per_forward")
 
         def context(idx: int) -> np.ndarray:
             return np.concatenate([prompts[idx], np.asarray(gen[idx], np.int32)])
@@ -1245,52 +1267,58 @@ class InferenceEngineV2:
                     tracker.finish(idx)
 
         pc = self.prefix_cache
+        span = self._tracer.span
         while queue or active:
             # ---- admit pending prompts (fused prefill + first-token sample)
             adm_uids: List[int] = []
             adm_tokens: List[np.ndarray] = []
             adm_counts: List[int] = []
             adm_full: List[np.ndarray] = []  # full contexts, for cache insert
-            decoding = list(active.keys())  # reserve 1-token decode headroom
-            while queue and len(active) < self.config.max_seqs:
-                idx = queue[0]
-                if arr is not None and time.perf_counter() - t_start < arr[idx]:
-                    break  # open-loop workload: not arrived yet
-                cand = context(idx)
-                suffix = self.try_admit(
-                    next_uid, cand, decoding + adm_uids,
-                    [1] * len(decoding) + adm_counts)
-                if suffix is None:
-                    break
-                queue.popleft()
-                adm_uids.append(next_uid)
-                adm_tokens.append(suffix)
-                adm_counts.append(len(suffix))
-                adm_full.append(cand)
-                if tracker is not None:
-                    tracker.admit(idx, next_uid)
-                active[next_uid] = idx
-                order[next_uid] = None
-                next_uid += 1
-            if adm_uids:
+            with span("serve:admit", queue_len=len(queue)) as admit_span:
+                decoding = list(active.keys())  # reserve 1-token decode headroom
+                while queue and len(active) < self.config.max_seqs:
+                    idx = queue[0]
+                    if arr is not None and time.perf_counter() - t_start < arr[idx]:
+                        break  # open-loop workload: not arrived yet
+                    cand = context(idx)
+                    suffix = self.try_admit(
+                        next_uid, cand, decoding + adm_uids,
+                        [1] * len(decoding) + adm_counts)
+                    if suffix is None:
+                        break
+                    queue.popleft()
+                    adm_uids.append(next_uid)
+                    adm_tokens.append(suffix)
+                    adm_counts.append(len(suffix))
+                    adm_full.append(cand)
+                    if tracker is not None:
+                        tracker.admit(idx, next_uid)
+                    active[next_uid] = idx
+                    order[next_uid] = None
+                    next_uid += 1
                 adm_rids = [active[u] for u in adm_uids]
+                admit_span.set_metadata(requests=len(adm_uids), tokens=sum(adm_counts),
+                                        rids=self._span_rids(adm_rids))
+            if adm_uids:
                 toks, rng = self._put_sample(adm_uids, adm_tokens, rng, sample_kw,
                                              tracker=tracker, rids=adm_rids)
-                if pc is not None:
-                    # index the freshly written full blocks (quantized bytes
-                    # are in the pool now — hashes snapshot them as written)
-                    for u, full in zip(adm_uids, adm_full):
-                        self._insert_prefix(u, full)
-                if tracker is not None:
-                    tracker.emitted_batch(adm_rids, (1,) * len(adm_rids))
-                for u, t in zip(adm_uids, toks):
-                    accept(u, t)
+                with span("serve:accept", kind="prefill", emitted=len(adm_uids)):
+                    if pc is not None:
+                        # index the freshly written full blocks (quantized bytes
+                        # are in the pool now — hashes snapshot them as written)
+                        for u, full in zip(adm_uids, adm_full):
+                            self._insert_prefix(u, full)
+                    if tracker is not None:
+                        tracker.emitted_batch(adm_rids, (1,) * len(adm_rids))
+                    for u, t in zip(adm_uids, toks):
+                        accept(u, t)
             if not active:
                 if queue and not adm_uids:
                     if arr is not None:
                         wait = t_start + arr[queue[0]] - time.perf_counter()
                         if wait > 0:  # idle until the next synthetic arrival
-                            time.sleep(min(wait, 0.05))
+                            with span("serve:idle_wait", queue_len=len(queue)):
+                                time.sleep(min(wait, 0.05))
                             continue
                     raise RuntimeError(
                         f"KV pool too small for a single sequence "
@@ -1305,37 +1333,42 @@ class InferenceEngineV2:
             # speculative decoding each of the K forwards may emit up to
             # 1+n_spec tokens, so the KV window scales by that factor plus
             # the n_spec transient-write slack.
-            uids = list(active.keys())
-            budgets = [max_new_tokens - len(gen[active[u]]) for u in uids]
-            k = self.config.decode_chain
-            while True:
-                while k > 1 and not self._can_schedule_evicting(
-                        uids, self.chain_window(budgets, k)):
-                    k -= 1
-                if self._can_schedule_evicting(uids, self.chain_window(budgets, k)):
-                    break
-                victim = next(reversed(order))
-                del order[victim]
-                i = uids.index(victim)
-                uids.pop(i)
-                budgets.pop(i)
-                idx = active.pop(victim)
-                self.flush(victim)
-                queue.appendleft(idx)
-                if tracker is not None:
-                    tracker.preempt(idx)
-                if registry is not None:
-                    c_preempt.add(1.0)
-                if not uids:
-                    raise RuntimeError(
-                        f"KV pool too small for a single sequence "
-                        f"({self.num_kv_blocks} blocks x {self.config.kv_block_size})"
-                    )
+            chain_id = self.chain_steps  # every span of this chain carries it
+            with span("serve:schedule", chain=chain_id) as schedule_span:
+                uids = list(active.keys())
+                budgets = [max_new_tokens - len(gen[active[u]]) for u in uids]
                 k = self.config.decode_chain
-            last = [gen[active[u]][-1] for u in uids]
-            chain_rids = [active[u] for u in uids]
+                preempted = 0
+                while True:
+                    while k > 1 and not self._can_schedule_evicting(
+                            uids, self.chain_window(budgets, k)):
+                        k -= 1
+                    if self._can_schedule_evicting(uids, self.chain_window(budgets, k)):
+                        break
+                    victim = next(reversed(order))
+                    del order[victim]
+                    i = uids.index(victim)
+                    uids.pop(i)
+                    budgets.pop(i)
+                    idx = active.pop(victim)
+                    self.flush(victim)
+                    queue.appendleft(idx)
+                    preempted += 1
+                    if tracker is not None:
+                        tracker.preempt(idx)
+                    if registry is not None:
+                        c_preempt.add(1.0)
+                    if not uids:
+                        raise RuntimeError(
+                            f"KV pool too small for a single sequence "
+                            f"({self.num_kv_blocks} blocks x {self.config.kv_block_size})"
+                        )
+                    k = self.config.decode_chain
+                last = [gen[active[u]][-1] for u in uids]
+                chain_rids = [active[u] for u in uids]
+                histories = [context(active[u]) for u in uids] if n_spec > 0 else None
+                schedule_span.set_metadata(active=len(uids), k=k, preempted=preempted)
             if n_spec > 0:
-                histories = [context(active[u]) for u in uids]
                 out, emitted, rng = self.decode_spec_chain(
                     uids, last, budgets, k, rng, histories,
                     eos_id=eos_token_id, tracker=tracker, rids=chain_rids)
@@ -1344,45 +1377,47 @@ class InferenceEngineV2:
                     uids, last, budgets, k, rng, eos_id=eos_token_id,
                     sample_kw=sample_kw, tracker=tracker, rids=chain_rids)
             n_emitted = int(emitted.sum())
-            self.tokens_decoded += n_emitted
-            # serving liveness for /healthz + fleet heartbeats: a decode
-            # chain is this engine's "step" (two plain writes)
-            self.chain_steps += 1
-            _fleet_note_step(self.chain_steps)
+            with span("serve:accept", kind="chain", emitted=n_emitted, chain=chain_id):
+                self.tokens_decoded += n_emitted
+                # serving liveness for /healthz + fleet heartbeats: a decode
+                # chain is this engine's "step" (two plain writes)
+                self.chain_steps += 1
+                _fleet_note_step(self.chain_steps)
+                if tracker is not None:
+                    # ONE stamp per chain boundary; TPOT = boundary delta / tokens
+                    now = time.perf_counter()
+                    tracker.emitted_batch(chain_rids, emitted, now=now)
+                    tracker.sample_gauges(now=now)
+                if registry is not None:
+                    c_tokens.add(n_emitted)
+                    c_chains.add(1.0)
+                    h_chain_len.observe(float(k))
+                    g_queue.set(float(len(queue)))
+                    g_occ.set(len(active) / self.config.max_seqs)
+                    g_free.set(float(self.state.free_blocks))
+                    g_util.set(self.state.utilization)
+                    if g_pfx_hit is not None:
+                        g_pfx_hit.set(pc.hit_rate)
+                        g_pfx_blocks.set(float(len(pc)))
+                    if g_spec_acc is not None and self.spec_model_steps:
+                        g_spec_acc.set(
+                            (self.spec_tokens_emitted - self.spec_model_steps)
+                            / (self.spec_model_steps * n_spec))
+                        g_spec_tpf.set(
+                            self.spec_tokens_emitted / self.spec_model_steps)
+                self._numerics_probe_chain(n_spec)
+                for i, u in enumerate(uids):
+                    for t in out[i, : emitted[i]]:
+                        if u in active:
+                            accept(u, t)
+        with span("serve:finish", requests=len(prompts)):
             if tracker is not None:
-                # ONE stamp per chain boundary; TPOT = boundary delta / tokens
-                now = time.perf_counter()
-                tracker.emitted_batch(chain_rids, emitted, now=now)
-                tracker.sample_gauges(now=now)
+                # final refresh: the last finishes land after the last chain
+                # boundary's sample, so goodput/tokens-per-s see them here
+                tracker.sample_gauges()
             if registry is not None:
-                c_tokens.add(n_emitted)
-                c_chains.add(1.0)
-                h_chain_len.observe(float(k))
-                g_queue.set(float(len(queue)))
-                g_occ.set(len(active) / self.config.max_seqs)
+                g_queue.set(0.0)
+                g_occ.set(0.0)
                 g_free.set(float(self.state.free_blocks))
                 g_util.set(self.state.utilization)
-                if g_pfx_hit is not None:
-                    g_pfx_hit.set(pc.hit_rate)
-                    g_pfx_blocks.set(float(len(pc)))
-                if g_spec_acc is not None and self.spec_model_steps:
-                    g_spec_acc.set(
-                        (self.spec_tokens_emitted - self.spec_model_steps)
-                        / (self.spec_model_steps * n_spec))
-                    g_spec_tpf.set(
-                        self.spec_tokens_emitted / self.spec_model_steps)
-            self._numerics_probe_chain(n_spec)
-            for i, u in enumerate(uids):
-                for t in out[i, : emitted[i]]:
-                    if u in active:
-                        accept(u, t)
-        if tracker is not None:
-            # final refresh: the last finishes land after the last chain
-            # boundary's sample, so goodput/tokens-per-s see them here
-            tracker.sample_gauges()
-        if registry is not None:
-            g_queue.set(0.0)
-            g_occ.set(0.0)
-            g_free.set(float(self.state.free_blocks))
-            g_util.set(self.state.utilization)
-        return [outputs[i] for i in range(len(prompts))]
+            return [outputs[i] for i in range(len(prompts))]

@@ -381,6 +381,7 @@ def _layer_stack(params, cfg, x, cache: KVCache, positions, write_start, kv_mask
     return x, cache._replace(k=k_new, v=v_new)
 
 
+@jax.named_scope("lm_head")  # names the serving head in a device trace
 def _logits(params, cfg: TransformerConfig, x):
     x = _apply_norm(params["final_norm"], cfg, x)
     if cfg.tie_embeddings:

@@ -190,11 +190,12 @@ def _forward_hidden(
     slot = _slot_ids(block_tables, positions, valid, bs, trash)  # [N, C]
     flat_slot = slot.reshape(-1)
 
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(cfg.dtype)
-    if cfg.embed_norm:
-        x = _apply_norm(params["embed_norm"], cfg, x)
-    if cfg.position == "learned":
-        x = x + jnp.take(params["pos_embed"], positions, axis=0).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(cfg.dtype)
+        if cfg.embed_norm:
+            x = _apply_norm(params["embed_norm"], cfg, x)
+        if cfg.position == "learned":
+            x = x + jnp.take(params["pos_embed"], positions, axis=0).astype(cfg.dtype)
     alibi = None
     if cfg.position == "alibi":
         from deepspeed_tpu.models.transformer import alibi_slopes
@@ -206,6 +207,12 @@ def _forward_hidden(
 
     quant = pool.quant  # static at trace time (value dtype + scale presence)
 
+    # Scopes for a device trace (HLO metadata only). ``pool_scan`` encloses
+    # the layer scan; everything the body computes sits under ``layer`` (or
+    # under ``kv_write``, ``page_view`` or the kernel's own name inside it),
+    # so what reads ``pool_scan`` innermost is the scan's own traffic: each
+    # layer's pool sliced out of the carried stack and written back into it.
+    @jax.named_scope("layer")
     def body(x, xs):
         lp, pk, pv, psk, psv = xs
         h = _apply_norm(lp["attn_norm"], cfg, x)
@@ -215,19 +222,20 @@ def _forward_hidden(
 
             q, k = apply_qk_rope(cfg, q, k, positions)
         kvH, hd = k.shape[-2], k.shape[-1]
-        if quant is not None:
-            # quantized KV write: the same one-scatter-per-array shape, plus
-            # one scale scatter per array (pad rows route to the trash slot
-            # for values AND scales alike)
-            kq, ks = _kv_block_quant(k.reshape(-1, kvH, hd), quant)
-            vq, vs = _kv_block_quant(v.reshape(-1, kvH, hd), quant)
-            pk = pk.at[flat_slot].set(kq.astype(pk.dtype), mode="drop")
-            pv = pv.at[flat_slot].set(vq.astype(pv.dtype), mode="drop")
-            psk = psk.at[flat_slot].set(ks, mode="drop")
-            psv = psv.at[flat_slot].set(vs, mode="drop")
-        else:
-            pk = pk.at[flat_slot].set(k.astype(pk.dtype).reshape(-1, kvH, hd), mode="drop")
-            pv = pv.at[flat_slot].set(v.astype(pv.dtype).reshape(-1, kvH, hd), mode="drop")
+        with jax.named_scope("kv_write"):
+            if quant is not None:
+                # quantized KV write: the same one-scatter-per-array shape,
+                # plus one scale scatter per array (pad rows route to the
+                # trash slot for values AND scales alike)
+                kq, ks = _kv_block_quant(k.reshape(-1, kvH, hd), quant)
+                vq, vs = _kv_block_quant(v.reshape(-1, kvH, hd), quant)
+                pk = pk.at[flat_slot].set(kq.astype(pk.dtype), mode="drop")
+                pv = pv.at[flat_slot].set(vq.astype(pv.dtype), mode="drop")
+                psk = psk.at[flat_slot].set(ks, mode="drop")
+                psv = psv.at[flat_slot].set(vs, mode="drop")
+            else:
+                pk = pk.at[flat_slot].set(k.astype(pk.dtype).reshape(-1, kvH, hd), mode="drop")
+                pv = pv.at[flat_slot].set(v.astype(pv.dtype).reshape(-1, kvH, hd), mode="drop")
         ctx = paged_attention(q, pk, pv, block_tables, positions, bs,
                               new_lens=new_lens, alibi_slopes=alibi,
                               k_scale=psk, v_scale=psv)
@@ -246,8 +254,9 @@ def _forward_hidden(
             x = x + _mlp(lp["mlp"], cfg, h)
         return x, (pk, pv, psk, psv)
 
-    x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-        body, x, (params["layers"], pool.k, pool.v, pool.k_scale, pool.v_scale))
+    with jax.named_scope("pool_scan"):
+        x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
+            body, x, (params["layers"], pool.k, pool.v, pool.k_scale, pool.v_scale))
     pool = pool._replace(k=k_new, v=v_new, k_scale=ks_new, v_scale=vs_new)
 
     if all_positions:

@@ -27,6 +27,7 @@ def greedy_tokens(logits: jax.Array) -> jax.Array:
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
+@jax.named_scope("sample")  # names on-device sampling in a device trace
 def sample_logits(
     logits: jax.Array,
     rng: jax.Array,

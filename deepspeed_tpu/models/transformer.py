@@ -650,25 +650,28 @@ class CausalLM(nn.Module):
 
         use_fused = (train and cfg.fused_ce and cfg.vocab_size >= cfg.fused_ce_min_vocab
                      and not cfg.lm_head_bias)
-        if use_fused:
-            # fused chunked-vocab LM head + CE: no [B,S,V] logits in HBM
-            # (see ops/cross_entropy.py). Training returns logits=None.
-            from deepspeed_tpu.ops.cross_entropy import lm_head_cross_entropy
+        # ``lm_head_ce`` names head matmul + cross-entropy, fused or not, in
+        # a device trace (jvp(..) and transpose(jvp(..)) under autodiff)
+        with jax.named_scope("lm_head_ce"):
+            if use_fused:
+                # fused chunked-vocab LM head + CE: no [B,S,V] logits in HBM
+                # (see ops/cross_entropy.py). Training returns logits=None.
+                from deepspeed_tpu.ops.cross_entropy import lm_head_cross_entropy
 
-            if cfg.tie_embeddings:
-                head = self.variables["params"]["embed"]["embedding"]  # [V, h]
+                if cfg.tie_embeddings:
+                    head = self.variables["params"]["embed"]["embedding"]  # [V, h]
+                else:
+                    head = _HeadKernel(cfg.hidden_size, cfg.vocab_size, name="lm_head")().T
+                loss = lm_head_cross_entropy(x, head.astype(cfg.dtype), labels, pad_mask)
+                logits = None
             else:
-                head = _HeadKernel(cfg.hidden_size, cfg.vocab_size, name="lm_head")().T
-            loss = lm_head_cross_entropy(x, head.astype(cfg.dtype), labels, pad_mask)
-            logits = None
-        else:
-            if cfg.tie_embeddings:
-                embed = self.variables["params"]["embed"]["embedding"]
-                logits = x @ embed.T.astype(cfg.dtype)
-            else:
-                logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias,
-                                  dtype=cfg.dtype, name="lm_head")(x)
-            loss = cross_entropy_loss(logits, labels, pad_mask)
+                if cfg.tie_embeddings:
+                    embed = self.variables["params"]["embed"]["embedding"]
+                    logits = x @ embed.T.astype(cfg.dtype)
+                else:
+                    logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias,
+                                      dtype=cfg.dtype, name="lm_head")(x)
+                loss = cross_entropy_loss(logits, labels, pad_mask)
         if cfg.has_moe:
             # aux is pre-weighted by MoELayer; average over layers
             loss = loss + aux / cfg.num_layers
@@ -680,6 +683,7 @@ class CausalLM(nn.Module):
 
 
 # --------------------------------------------------- pipelined execution
+@jax.named_scope("embed")  # the scope flax's ``embed`` module gives CausalLM
 def _embed_tokens(params, cfg: TransformerConfig, ids):
     """Functional twin of the embedding front-end of ``CausalLM.__call__``."""
     x = jnp.take(params["embed"]["embedding"], ids, axis=0).astype(cfg.dtype)
@@ -706,18 +710,19 @@ def _apply_norm(norm_params, cfg: TransformerConfig, x):
 
 def _lm_head_and_loss(params, cfg: TransformerConfig, x, batch, aux):
     x = _apply_norm(params["final_norm"], cfg, x)
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"]["embedding"].T.astype(cfg.dtype)
-    else:
-        logits = x @ params["lm_head"]["kernel"].astype(cfg.dtype)
-        if "bias" in params["lm_head"]:
-            logits = logits + params["lm_head"]["bias"].astype(cfg.dtype)
     ids = batch["input_ids"]
     labels = batch.get("labels")
     if labels is None:
         B = ids.shape[0]
         labels = jnp.concatenate([ids[:, 1:], jnp.full((B, 1), -100, dtype=ids.dtype)], axis=1)
-    loss = cross_entropy_loss(logits, labels, batch.get("attention_mask"))
+    with jax.named_scope("lm_head_ce"):
+        if cfg.tie_embeddings:
+            logits = x @ params["embed"]["embedding"].T.astype(cfg.dtype)
+        else:
+            logits = x @ params["lm_head"]["kernel"].astype(cfg.dtype)
+            if "bias" in params["lm_head"]:
+                logits = logits + params["lm_head"]["bias"].astype(cfg.dtype)
+        loss = cross_entropy_loss(logits, labels, batch.get("attention_mask"))
     if cfg.has_moe:
         loss = loss + aux / cfg.num_layers
     return loss, logits

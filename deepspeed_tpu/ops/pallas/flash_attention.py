@@ -328,6 +328,7 @@ def _flash_fwd(q, k, v, mask, slopes, block_q: int, block_k: int, causal: bool,
         qm, km = _tri_maps(nq)
         out, lse = pl.pallas_call(
             kernel,
+            name="flash_fwd",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,  # qmap, kmap
                 grid=(B, H, qm.shape[0]),
@@ -344,6 +345,7 @@ def _flash_fwd(q, k, v, mask, slopes, block_q: int, block_k: int, causal: bool,
 
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(B, H, nq, nk),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -536,6 +538,7 @@ def _flash_bwd(q, k, v, mask, slopes, out, lse, do, block_q: int, block_k: int,
         qm, km = _tri_maps(nq)
         dq = pl.pallas_call(
             dq_kernel,
+            name="flash_bwd_dq",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(B, H, qm.shape[0]),
@@ -552,6 +555,7 @@ def _flash_bwd(q, k, v, mask, slopes, out, lse, do, block_q: int, block_k: int,
         wqm, wkm = _wedge_maps(nk)
         dk, dv = pl.pallas_call(
             dkv_kernel,
+            name="flash_bwd_dkv",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(B, H, wqm.shape[0]),
@@ -566,6 +570,7 @@ def _flash_bwd(q, k, v, mask, slopes, out, lse, do, block_q: int, block_k: int,
     else:
         dq = pl.pallas_call(
             dq_kernel,
+            name="flash_bwd_dq",
             grid=(B, H, nq, nk),
             in_specs=bwd_in_specs(_DEC_DENSE),
             out_specs=_qrow_specs(_DEC_DENSE, block_q, D)["qD"],
@@ -580,6 +585,7 @@ def _flash_bwd(q, k, v, mask, slopes, out, lse, do, block_q: int, block_k: int,
         # the canonical (qi, ki) order for the shared specs.
         dk, dv = pl.pallas_call(
             dkv_kernel,
+            name="flash_bwd_dkv",
             grid=(B, H, nk, nq),
             in_specs=bwd_in_specs(_DEC_DENSE_KQ),
             out_specs=[_kcol_spec(_DEC_DENSE_KQ, block_k, D)] * 2,

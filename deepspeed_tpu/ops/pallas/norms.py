@@ -53,6 +53,7 @@ def _rms_fwd(x2, scale, eps):
     br = _row_blocks(R)
     return pl.pallas_call(
         functools.partial(_rms_fwd_kernel, eps=eps),
+        name="rms_norm",
         grid=(pl.cdiv(R, br),),
         in_specs=[
             pl.BlockSpec((br, Dm), lambda i: (i, 0)),
@@ -103,6 +104,7 @@ def _ln_fwd(x2, scale, bias, eps):
     br = _row_blocks(R)
     return pl.pallas_call(
         functools.partial(_ln_fwd_kernel, eps=eps),
+        name="layer_norm",
         grid=(pl.cdiv(R, br),),
         in_specs=[
             pl.BlockSpec((br, Dm), lambda i: (i, 0)),

@@ -157,8 +157,10 @@ def _page_view(pool_l: jax.Array, num_pages: int, bs: int) -> jax.Array:
     this is a layout-changing COPY of the layer's pool — the stored layout pads
     (kvH, hd) to the (8, 128) tile, which no DMA may slice. Storing the pool
     page-major and lane-dense would make it free; that is a layout change of
-    ``inference/paged.py`` and everything that exports pages, not a repair."""
-    return pool_l[:num_pages * bs].reshape(num_pages, bs, -1)
+    ``inference/paged.py`` and everything that exports pages, not a repair.
+    The ``page_view`` scope names that copy in a device trace."""
+    with jax.named_scope("page_view"):
+        return pool_l[:num_pages * bs].reshape(num_pages, bs, -1)
 
 
 @register("paged_attention", "pallas")
@@ -245,6 +247,7 @@ def flash_decode_paged(
     kernel = functools.partial(_decode_kernel, ppcb=ppcb, alibi=alibi, quantized=quantized)
     out = pl.pallas_call(
         kernel,
+        name="paged_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # block_tables, active_pages
             grid=(N, npc),

@@ -77,6 +77,7 @@ def pallas_quantize_int8(x: jax.Array, block_size: int = DEFAULT_BLOCK, stochast
         seed_arr = jnp.asarray([seed], jnp.int32)
         vals, scales = pl.pallas_call(
             _quant_kernel_stochastic,
+            name="quantize_int8",
             grid=(pl.cdiv(nb, rows),),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -94,6 +95,7 @@ def pallas_quantize_int8(x: jax.Array, block_size: int = DEFAULT_BLOCK, stochast
     else:
         vals, scales = pl.pallas_call(
             _quant_kernel,
+            name="quantize_int8",
             grid=(pl.cdiv(nb, rows),),
             in_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0))],
             out_specs=[
@@ -121,6 +123,7 @@ def pallas_dequantize_int8(values: jax.Array, scales: jax.Array, shape, dtype=jn
     rows = min(_ROWS_PER_STEP, nb)
     out = pl.pallas_call(
         functools.partial(_dequant_kernel, dtype=dtype),
+        name="dequantize_int8",
         grid=(pl.cdiv(nb, rows),),
         in_specs=[
             pl.BlockSpec((rows, block), lambda i: (i, 0)),

@@ -138,6 +138,7 @@ def _sparse_fwd(q, k, v, cols, ncols, block, causal):
 
     out, lse = pl.pallas_call(
         functools.partial(_sparse_fwd_kernel, block=block, causal=causal),
+        name="sparse_attn_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # cols, ncols
             grid=(B, H, n, A),
@@ -261,6 +262,7 @@ def _sparse_bwd(q, k, v, do, out, lse, cols, ncols, rows, nrows, block, causal):
     kcol = lambda b, h, qi, j, cols, ncols: (b, h, cols[h, qi, j], 0)  # noqa: E731
     dq = pl.pallas_call(
         functools.partial(_sparse_dq_kernel, block=block, causal=causal),
+        name="sparse_attn_bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # cols, ncols
             grid=(B, H, n, A),
@@ -287,6 +289,7 @@ def _sparse_bwd(q, k, v, do, out, lse, cols, ncols, rows, nrows, block, causal):
     kcol_t = lambda b, h, ki, t, rows, nrows: (b, h, ki, 0)  # noqa: E731
     dk, dv = pl.pallas_call(
         functools.partial(_sparse_dkv_kernel, block=block, causal=causal),
+        name="sparse_attn_bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # rows, nrows
             grid=(B, H, n, Ar),

@@ -369,7 +369,7 @@ class DeepSpeedTPUEngine:
         self._metrics_server = None
         if tcfg.enabled:
             telemetry_mod.configure(
-                enabled=True, sync_spans=tcfg.sync_spans,
+                enabled=True,
                 max_events=tcfg.max_events,
                 memory_watermarks=tcfg.memory_watermarks,
                 trace_path=tcfg.trace_path, jsonl_path=tcfg.jsonl_path,
@@ -1457,6 +1457,7 @@ class DeepSpeedTPUEngine:
             return out[0], out[1:]
         return out, ()
 
+    @jax.named_scope("optimizer")  # the 16-bit recast is the update's last part
     def _compute_params(self, master_params):
         compute = cast_floating(master_params, self.compute_dtype)
         if self.offload_mode == "memories":
@@ -1919,11 +1920,14 @@ class DeepSpeedTPUEngine:
             new_health, health_metrics, hskip, _habort = self._health.probe(
                 state.health, grads, gnorm, loss=loss, finite=finite)
             apply_ok = finite & ~hskip
-        if clip and clip > 0:
-            grads, gnorm = clip_by_global_norm(grads, clip, norm=gnorm)
+        # ``optimizer`` names clip + update + apply in a device trace; the
+        # unscale, norm and health probes above stay outside it
+        with jax.named_scope("optimizer"):
+            if clip and clip > 0:
+                grads, gnorm = clip_by_global_norm(grads, clip, norm=gnorm)
 
-        updates, new_opt = self.tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+            updates, new_opt = self.tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
 
         # overflow / unhealthy => skip the update (reference
         # FP16_Optimizer.step overflow path, extended to health verdicts)
@@ -2107,6 +2111,7 @@ class DeepSpeedTPUEngine:
                 g, _ = clip_by_global_norm(g, clip, norm=gnorm)
             return g
 
+        @jax.named_scope("optimizer")
         def dev_update(params_sub, opt_dev, grads_sub, inv, finite, gnorm):
             g = _clipped(grads_sub, inv, gnorm)
             updates, new_opt = self._tf_tx_dev.update(g, opt_dev, params_sub)
@@ -2117,6 +2122,7 @@ class DeepSpeedTPUEngine:
             new_opt = sel(new_opt, opt_dev)
             return new_params, new_opt, cast_floating(new_params, self.compute_dtype)
 
+        @jax.named_scope("optimizer")
         def host_update(params_sub, opt_host, grads_sub, step, ls_state, rng_data,
                         finite, gnorm):
             rng = jax.random.wrap_key_data(rng_data)
@@ -2372,7 +2378,7 @@ class DeepSpeedTPUEngine:
         config_fire = (fp_cfg.enabled and prof.result is None
                        and self._batch_count >= fp_cfg.profile_step)
         # step wall-clock for the anomaly detector (same honesty caveat as the
-        # spans: dispatch time under async dispatch unless sync_spans drains)
+        # spans: dispatch time under async dispatch)
         diag_t0 = time.perf_counter() if self.diagnostics is not None else None
         if self.diagnostics is not None:
             # an armed profiler-capture window starts here so the device
@@ -2402,11 +2408,19 @@ class DeepSpeedTPUEngine:
         else:
             self.throughput_timer.start()
             # the fused program has no separable fwd/bwd/step phases — this
-            # span is the whole optimizer step (dispatch time unless
-            # telemetry.sync_spans drains the device queue)
+            # span is the whole optimizer step's dispatch; its device time
+            # is in the device rows of the same jax.profiler trace
             with self._tracer.span("step", fused=True):
                 self.state, metrics = self._train_step(self.state, placed)
             self.throughput_timer.stop()
+        with self._tracer.span("post_step"):
+            self._post_step(metrics, diag_t0)
+        return metrics
+
+    def _post_step(self, metrics: Dict[str, Any], diag_t0: Optional[float]) -> None:
+        """Everything between the step's dispatch and ``return metrics``:
+        fleet note, diagnostics, snapshot, observatories, monitor buffering
+        and the ``steps_per_print`` fetch."""
         # Metrics stay device-side: fetching them here would block the host on
         # the step and break JAX async dispatch (measured 743 ms -> 102 ms per
         # step on v5e for the 125M bench). Callers that want numbers call
@@ -2479,7 +2493,6 @@ class DeepSpeedTPUEngine:
                 from deepspeed_tpu.utils.memory import see_memory_usage
 
                 see_memory_usage(f"after step {step}", force=True)
-        return metrics
 
     def flush_monitor(self) -> None:
         """Write buffered scalars to the monitor (one bulk device fetch) and

@@ -2,16 +2,21 @@
 
 The temporal half of the telemetry subsystem. Design constraints, in order:
 
-1. **Zero overhead when disabled.** ``span()`` on a disabled tracer returns a
-   shared no-op context after one attribute check — no allocation, no lock.
-   Engine hot paths call it unconditionally.
-2. **Honest on an async-dispatch runtime.** JAX dispatch is asynchronous, so a
+1. **The device trace is the clock.** Every ``span()`` opens a
+   ``jax.profiler.TraceAnnotation("dstpu:<name>", **args)``. Whenever a
+   ``jax.profiler`` session is on (the benchmark's ``--trace 1``, or
+   ``profiling/capture.py`` armed by SIGUSR2 or an anomaly) the span is an
+   event of the trace's ``/host:CPU`` plane, its args the event's stats, on
+   the same clock as the device operations — no config switch. With no
+   session on, the annotation is inactive (under a microsecond).
+2. **Nothing else when disabled.** ``span()`` on a disabled tracer returns
+   the bare annotation: no event buffered, no histogram, no lock. Engine hot
+   paths call it unconditionally.
+3. **Honest on an async-dispatch runtime.** JAX dispatch is asynchronous, so a
    host-side span around a compiled-step call measures *dispatch*, not device
-   time, unless the device queue is drained. ``sync_spans=True`` drains at
-   both span boundaries (the ``utils/timer.py`` ``_sync`` contract) — true
-   device-time spans at the cost of serializing the pipeline. The default
-   (False) keeps spans free and labels what they are.
-3. **Bounded memory.** At most ``max_events`` events are buffered; overflow
+   time. Device time is read from the device rows of the same trace, beside
+   the span; nothing here drains the device queue.
+4. **Bounded memory.** At most ``max_events`` events are buffered; overflow
    increments ``dropped_events`` instead of growing without bound.
 
 Spans on the same thread nest by timestamp containment, which is exactly how
@@ -28,22 +33,16 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from deepspeed_tpu.telemetry.registry import MetricsRegistry
 
-
-def _drain_device() -> None:
-    """Drain async dispatch so host wall-clock brackets device work
-    (same contract as ``utils/timer.py:_sync``)."""
-    try:
-        import jax
-
-        (jax.device_put(0.0) + 0).block_until_ready()
-    except Exception:  # pragma: no cover - backendless environments
-        pass
+SPAN_PREFIX = "dstpu:"  # every span's name in a jax.profiler trace
 
 
 class _NoopSpan:
-    """Shared do-nothing context manager returned by disabled tracers."""
+    """Shared do-nothing context manager for callers that gate their own
+    spans on ``tracer.enabled`` (the trace-time comm/collective spans)."""
 
     __slots__ = ()
 
@@ -58,30 +57,36 @@ NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    """A live span; records itself into the tracer on ``__exit__``."""
+    """A live span of an enabled tracer: the trace annotation, and the
+    tracer's own record of it on ``__exit__``."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args: Optional[Dict[str, Any]]):
+    def __init__(self, tracer: "Tracer", name: str, cat: str, args: Dict[str, Any]):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._annotation = TraceAnnotation(SPAN_PREFIX + name, **args)
 
     def __enter__(self):
-        if self._tracer.sync_spans:
-            _drain_device()
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
+    def set_metadata(self, **args: Any) -> None:
+        """Args known only once the span's work is done (counts measured
+        where the work happens); same call as the bare annotation's."""
+        self.args.update(args)
+        self._annotation.set_metadata(**args)
+
     def __exit__(self, *exc):
         # close is inlined (no helper-call indirection): the serving loop
-        # closes three spans per decode chain, so every fixed cost here is
+        # closes six spans per decode chain, so every fixed cost here is
         # paid on the hot path
         tracer = self._tracer
-        if tracer.sync_spans:
-            _drain_device()
         t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
         dur_s = t1 - self._t0
         ev = {
             "kind": "span",
@@ -114,10 +119,9 @@ class Tracer:
     the same pattern as ``comm.comms_logger``.
     """
 
-    def __init__(self, enabled: bool = False, sync_spans: bool = False,
+    def __init__(self, enabled: bool = False,
                  max_events: int = 100_000, memory_watermarks: bool = True):
         self.enabled = enabled
-        self.sync_spans = sync_spans
         self.max_events = max_events
         self.memory_watermarks = memory_watermarks
         self.trace_path: Optional[str] = None
@@ -141,15 +145,13 @@ class Tracer:
         self._span_hists: Dict[str, Any] = {}
 
     # ------------------------------------------------------------ config
-    def configure(self, enabled: bool = True, sync_spans: Optional[bool] = None,
+    def configure(self, enabled: bool = True,
                   max_events: Optional[int] = None,
                   memory_watermarks: Optional[bool] = None,
                   trace_path: Optional[str] = None,
                   jsonl_path: Optional[str] = None,
                   prometheus_path: Optional[str] = None) -> "Tracer":
         self.enabled = enabled
-        if sync_spans is not None:
-            self.sync_spans = sync_spans
         if max_events is not None:
             self.max_events = max_events
         if memory_watermarks is not None:
@@ -176,10 +178,18 @@ class Tracer:
 
     # ------------------------------------------------------------- spans
     def span(self, name: str, cat: str = "span", **args: Any):
-        """Context manager recording one named span; no-op when disabled."""
+        """Context manager for one named span: always a ``dstpu:<name>``
+        annotation of the ``jax.profiler`` trace (inactive when no session is
+        on), and with the tracer enabled also an event of its own buffer."""
         if not self.enabled:
-            return NOOP_SPAN
-        return _Span(self, name, cat, args or None)
+            return TraceAnnotation(SPAN_PREFIX + name, **args)
+        return _Span(self, name, cat, args)
+
+    def recording(self) -> bool:
+        """True when a span's args are read by anyone: the tracer is enabled
+        or a ``jax.profiler`` session is on. Callers check it before
+        formatting an arg that costs more than a count."""
+        return self.enabled or TraceAnnotation.is_enabled()
 
     def instant(self, name: str, cat: str = "event", **args: Any) -> None:
         """Record a zero-duration marker event."""

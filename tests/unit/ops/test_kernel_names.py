@@ -1,0 +1,61 @@
+"""Every Pallas kernel of ``ops/pallas`` carries its own name into the
+lowered program (``pallas_call(name=)`` opens a scope of that name, which on
+the chip also names the Mosaic custom-call's instruction; PERF.md, section 3),
+so a trace reader finds a kernel by name and not by operand shape."""
+
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.ops.pallas.flash_attention import flash_causal_attention
+from deepspeed_tpu.ops.pallas.norms import pallas_layer_norm, pallas_rms_norm
+from deepspeed_tpu.ops.pallas.paged_attention import flash_decode_paged
+from deepspeed_tpu.ops.pallas.quantizer import pallas_dequantize_int8, pallas_quantize_int8
+from deepspeed_tpu.ops.pallas.sparse_attention import block_sparse_attention_pallas
+
+QKV = jnp.ones((1, 32, 2, 16), jnp.float32)
+LAYOUT = np.tril(np.ones((2, 4, 4), np.int64))
+
+
+def _flash(q):
+    return flash_causal_attention(q, q, q).sum()
+
+
+def _sparse(q):
+    return block_sparse_attention_pallas(q, q, q, LAYOUT, block=8).sum()
+
+
+def _paged(q):
+    pool = jnp.ones((4 * 8 + 1, 2, 16), jnp.float32)
+    return flash_decode_paged(q, pool, pool, jnp.zeros((2, 2), jnp.int32),
+                              jnp.zeros((2, 1), jnp.int32), 8)
+
+
+KERNELS = [
+    ("flash_fwd", _flash, (QKV,)),
+    ("flash_bwd_dq", jax.grad(_flash), (QKV,)),
+    ("flash_bwd_dkv", jax.grad(_flash), (QKV,)),
+    ("sparse_attn_fwd", _sparse, (QKV,)),
+    ("sparse_attn_bwd_dq", jax.grad(_sparse), (QKV,)),
+    ("sparse_attn_bwd_dkv", jax.grad(_sparse), (QKV,)),
+    ("paged_attn", _paged, (jnp.ones((2, 1, 2, 16), jnp.float32),)),
+    ("page_view", _paged, (jnp.ones((2, 1, 2, 16), jnp.float32),)),  # the scope beside the kernel
+    ("rms_norm", lambda x: pallas_rms_norm(x, jnp.ones((32,))), (jnp.ones((8, 32)),)),
+    ("layer_norm", lambda x: pallas_layer_norm(x, jnp.ones((32,)), jnp.zeros((32,))),
+     (jnp.ones((8, 32)),)),
+    ("quantize_int8", lambda x: pallas_quantize_int8(x, block_size=64), (jnp.ones((256,)),)),
+    ("dequantize_int8", lambda v: pallas_dequantize_int8(v, jnp.ones((4,)), (256,), block_size=64),
+     (jnp.ones((256,), jnp.int8),)),
+]
+
+
+@pytest.mark.parametrize("name,fn,args", KERNELS, ids=[k[0] for k in KERNELS])
+def test_lowered_text_carries_the_kernel_s_name(name, fn, args):
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    # a path component of an op_name; transpose(jvp(<name>)) in a backward pass
+    assert re.search(r"[/(]%s[/)]" % name, text), (
+        f"no op_name with the path component {name!r}")
