@@ -46,6 +46,7 @@ from deepspeed_tpu.inference.paged import (
     PagedKVPool,
     copy_pool_blocks,
     export_pool_blocks,
+    fetch_pool_block,
     import_pool_blocks,
     init_pool,
     ragged_decode_chain,
@@ -330,7 +331,7 @@ class InferenceEngineV2:
                 # after — see below), so the dense tp-shard bytes ARE the
                 # placement peak
                 param_bytes = n_params * dtype_b // tp
-            kv_bytes = (num_blocks * config.kv_block_size + 1) * self.kv_bytes_per_token
+            kv_bytes = num_blocks * config.kv_block_size * self.kv_bytes_per_token
             # per-step attention workspace of the gather fallback: one
             # layer's gathered (dequantized) KV blocks + fp32 score/prob
             # arrays for a bucketed step (round-10 calibration: without it
@@ -372,7 +373,8 @@ class InferenceEngineV2:
             classes = config.quant.tensor_classes
             self.params = jax.jit(lambda p: quantize_params(
                 p, fmt, min_size=min_size, classes=classes))(self.params)
-        # KV pool: kv-head dim over tp, slots replicated over dp
+        # KV pool: the merged kvH*hd dim over tp (contiguous head groups, so
+        # each rank holds its own heads' lanes), pages replicated over dp
         pool = init_pool(model_config, num_blocks, config.kv_block_size, kv_dtype,
                          kv_quant=kv_quant)
         if not kv_on_tp and mesh.shape["tp"] > 1:
@@ -384,11 +386,12 @@ class InferenceEngineV2:
                 "per-chip KV memory; pick tp dividing kv_heads to shard it",
                 ranks=[0],
             )
-        kv_spec = NamedSharding(mesh, P(None, None, "tp" if kv_on_tp else None, None))
+        kv_spec = NamedSharding(mesh, P(None, None, "tp" if kv_on_tp else None))
+        replicated = NamedSharding(mesh, P())  # scales: 4/hd of the values, slot-major rows
         self.pool = PagedKVPool(
             k=jax.device_put(pool.k, kv_spec), v=jax.device_put(pool.v, kv_spec),
-            k_scale=None if pool.k_scale is None else jax.device_put(pool.k_scale, kv_spec),
-            v_scale=None if pool.v_scale is None else jax.device_put(pool.v_scale, kv_spec))
+            k_scale=None if pool.k_scale is None else jax.device_put(pool.k_scale, replicated),
+            v_scale=None if pool.v_scale is None else jax.device_put(pool.v_scale, replicated))
         log_dist(
             f"InferenceEngineV2: {n_params/1e6:.1f}M params, "
             f"{num_blocks}x{config.kv_block_size} KV slots "
@@ -556,11 +559,11 @@ class InferenceEngineV2:
         as traced scalars, so ONE compiled program serves every COW event."""
         key = ("cow",)
         if key not in self._step_cache:
-            bs = self.config.kv_block_size
+            layers = self.model_config.num_layers
 
             @functools.partial(jax.jit, donate_argnums=(0,))
             def cow(pool, src, dst):
-                return copy_pool_blocks(pool, src, dst, bs)
+                return copy_pool_blocks(pool, src, dst, layers)
 
             self._step_cache[key] = self._watch(cow, "cow")
         return self._step_cache[key]
@@ -573,21 +576,16 @@ class InferenceEngineV2:
 
     # ---------------------------------------------------------- prefix cache
     def _block_fetch_fn(self):
-        """One jitted dynamic-slice program fetching a block's pool pages
-        (the slot offset rides as a traced scalar — eager slicing would
-        compile a fresh XLA program per distinct block offset)."""
+        """One jitted gather program fetching a block's pool pages, every
+        layer's (the block id rides as a traced scalar — eager indexing
+        would compile a fresh XLA program per distinct block)."""
         key = ("blockfetch",)
         if key not in self._step_cache:
-            bs = self.config.kv_block_size
+            layers = self.model_config.num_layers
 
             @jax.jit
-            def fetch(pool, start):
-                def sl(a):
-                    if a is None:
-                        return None
-                    return jax.lax.dynamic_slice_in_dim(a, start, bs, axis=1)
-
-                return (sl(pool.k), sl(pool.v), sl(pool.k_scale), sl(pool.v_scale))
+            def fetch(pool, block):
+                return fetch_pool_block(pool, block, layers)
 
             self._step_cache[key] = fetch
         return self._step_cache[key]
@@ -599,11 +597,12 @@ class InferenceEngineV2:
         identity: tests and the nightly smoke compare it at hit time against
         the insert-time digest to prove sharing/COW/eviction never touched
         the stored bytes, and it is taken over exactly the bytes the
-        paged-attention block loads read (a hit is never re-quantized)."""
+        paged-attention block loads read (a hit is never re-quantized).
+        Row-major, layers outermost, then the block's slots, heads and
+        ``hd``: the order every pool layout so far has had."""
         import hashlib
 
-        bs = self.config.kv_block_size
-        parts = self._block_fetch_fn()(self.pool, jnp.int32(block * bs))
+        parts = self._block_fetch_fn()(self.pool, jnp.int32(block))
         h = hashlib.blake2b(digest_size=16)
         for arr in parts:
             if arr is not None:
@@ -730,11 +729,11 @@ class InferenceEngineV2:
         (the source keeps serving while the pages stream out)."""
         key = ("export", pages)
         if key not in self._step_cache:
-            bs = self.config.kv_block_size
+            mc = self.model_config
 
             @jax.jit
             def export(pool, blocks):
-                return export_pool_blocks(pool, blocks, bs)
+                return export_pool_blocks(pool, blocks, mc.num_layers, mc.kv_heads)
 
             self._step_cache[key] = self._watch(export, "export", f"p{pages}")
         return self._step_cache[key]
@@ -744,11 +743,10 @@ class InferenceEngineV2:
         destination pool is donated like every other pool-mutating step."""
         key = ("import", pages)
         if key not in self._step_cache:
-            bs = self.config.kv_block_size
 
             @functools.partial(jax.jit, donate_argnums=(0,))
             def imp(pool, buf, blocks, n_valid):
-                return import_pool_blocks(pool, buf, blocks, n_valid, bs)
+                return import_pool_blocks(pool, buf, blocks, n_valid)
 
             self._step_cache[key] = self._watch(imp, "import", f"p{pages}")
         return self._step_cache[key]
@@ -818,11 +816,13 @@ class InferenceEngineV2:
                 f"(bs={self.config.kv_block_size}, quant={self.pool.quant}, "
                 f"dtype={jnp.dtype(self.pool.k.dtype)})")
         buf: MigrationBuffer = export["buffer"]
-        if buf.k.shape[0] != self.pool.k.shape[0] or \
-                buf.k.shape[2:] != self.pool.k.shape[2:]:
+        mc = self.model_config
+        if buf.k.shape[0] != mc.num_layers or \
+                tuple(buf.k.shape[2:]) != (mc.kv_heads, mc.dims_per_head):
             raise ValueError(
                 f"migration layout mismatch: buffer pages {buf.k.shape} vs "
-                f"pool {self.pool.k.shape}")
+                f"pool of {mc.num_layers} layers x {mc.kv_heads} heads x "
+                f"{mc.dims_per_head}")
         n = export["n_blocks"]
         if not self.can_import(n):
             return False

@@ -2,17 +2,25 @@
 
 TPU-native analog of the reference FastGen kernel suite
 (``inference/v2/kernels/ragged_ops/``: ``blocked_flash`` paged attention,
-``linear_blocked_kv_rotary`` fused KV-insert+RoPE): the KV pool is a flat
-``[L, NB*bs + 1, kvH, hd]`` array (last slot = trash for pad-row writes), a
-sequence's cache is addressed through its block table, and one jitted step
-processes a mixed prefill/decode ragged batch:
+``linear_blocked_kv_rotary`` fused KV-insert+RoPE): the KV pool is ONE
+page-major array ``[L*NB, bs, kvH*hd]`` — every layer's pages in a row, a
+page being a lane-dense ``[bs, kvH*hd]`` slab, the layout the paged kernel
+DMAs from, so nothing re-lays it out between the write and the read. Layer
+``l``'s page ``p`` is row ``l*NB + p``. A sequence's cache is addressed
+through its block table, and one jitted step processes a mixed
+prefill/decode ragged batch:
 
-  - KV insert = one scatter per layer (``.at[idx].set``) at
-    ``block_table[pos // bs] * bs + pos % bs`` — the fused-KV-copy+RoPE kernel
-  - paged attention = gather the row's pages to ``[P*bs, kvH, hd]`` then
-    masked GQA attention (slot index within the gathered view == global
-    position, so causality is ``slot <= q_pos``). A Pallas flash-decode kernel
-    that skips the materialized gather is the registered fast path upgrade.
+  - the layer scan CARRIES the pool and updates it in place: no per-layer
+    slice of it is taken and no second pool is stacked up
+  - KV insert = one scatter per layer (``.at[row, slot].set``) of the new
+    tokens' ``[kvH*hd]`` rows at ``(layer*NB + block_table[pos // bs],
+    pos % bs)`` — the fused-KV-copy+RoPE kernel. Pad-row writes index one
+    past the last page and drop (``mode="drop"``): there is no trash slot
+  - paged attention reads the same array through ``block_table + layer*NB``:
+    the Pallas flash-decode kernel (``ops/pallas/paged_attention.py``) DMAs
+    the row's live pages, the XLA fallback gathers them to ``[P*bs, kvH, hd]``
+    then runs masked GQA attention (slot index within the gathered view ==
+    global position, so causality is ``slot <= q_pos``).
 
 Static shapes everywhere: (rows, chunk, pages) are bucketed by the host layer
 (``ragged.py``), so XLA compiles a handful of step programs.
@@ -31,27 +39,34 @@ from deepspeed_tpu.models.transformer import TransformerConfig
 
 
 class PagedKVPool(NamedTuple):
-    """k/v: ``[L, NB*bs + 1, kvH, hd]`` flat slot-major pool; the final slot is
-    the trash slot (reference: FastGen preallocates the KV arena up front from
-    a memory budget, ``DSStateManager`` + ``KVCacheConfig``). ``block_size``
-    is carried by the engine, not here — this NamedTuple is a jit pytree and
-    must hold only arrays.
+    """k/v: ``[L*NB, bs, kvH*hd]`` page-major pool, layer ``l``'s block ``b``
+    at row ``l*NB + b`` (reference: FastGen preallocates the KV arena up front
+    from a memory budget, ``DSStateManager`` + ``KVCacheConfig``). Row-major
+    this is the byte order of ``[L, NB*bs, kvH, hd]``; what the shape fixes is
+    the TPU's tiling: the two minor dims ``(bs, kvH*hd)`` are a page, so a
+    page is addressed by the leading index alone and the kernel takes the
+    array as it is. The layers are NOT a dimension of their own: merging
+    ``[L, NB]`` is free for the values but re-lays the scales out (their
+    tiled second-minor dim would be ``NB``). This NamedTuple is a jit pytree
+    and holds only arrays; ``L`` comes from the model config.
 
     Quantized storage (``kv_quant='int8'|'fp8'``): k/v hold int8/e4m3 values
     and ``k_scale``/``v_scale`` carry one fp32 scale per (layer, slot, kv-head)
     — the quantization block is the ``hd`` head vector, so a token's KV write
     is one ``ops.quant`` block-math call and dequant needs only the slot's own
-    scale (fused into the paged-attention block loads). ``None`` scales mean a
-    full-precision pool (the pre-quantization layout, unchanged)."""
+    scale (fused into the paged-attention block loads). A page's scales are
+    ONE lane-dense row ``[bs*kvH]`` (slot-major, the values' own order): fp32
+    with a minor dim of ``kvH`` alone would pad to 128 lanes on the TPU.
+    ``None`` scales mean a full-precision pool."""
 
     k: jax.Array
     v: jax.Array
-    k_scale: Optional[jax.Array] = None  # [L, S_flat, kvH, 1] fp32, or None
+    k_scale: Optional[jax.Array] = None  # [L*NB, bs*kvH] fp32, or None
     v_scale: Optional[jax.Array] = None
 
     @property
-    def num_slots(self) -> int:  # excludes trash
-        return self.k.shape[1] - 1
+    def block_size(self) -> int:
+        return self.k.shape[1]
 
     @property
     def quant(self) -> Optional[str]:
@@ -69,20 +84,20 @@ def init_pool(
     cfg: TransformerConfig, num_blocks: int, block_size: int, dtype: Any = jnp.bfloat16,
     kv_quant: Optional[str] = None,
 ) -> PagedKVPool:
-    shape = (cfg.num_layers, num_blocks * block_size + 1, cfg.kv_heads, cfg.dims_per_head)
+    shape = (cfg.num_layers * num_blocks, block_size, cfg.kv_heads * cfg.dims_per_head)
     if kv_quant is None:
         return PagedKVPool(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
     if kv_quant not in _KV_QUANT_DTYPES:
         raise ValueError(f"kv_quant must be None|'int8'|'fp8', got {kv_quant!r}")
     qdt = _KV_QUANT_DTYPES[kv_quant]
-    sshape = shape[:3] + (1,)
+    sshape = (shape[0], block_size * cfg.kv_heads)
     return PagedKVPool(k=jnp.zeros(shape, qdt), v=jnp.zeros(shape, qdt),
                        k_scale=jnp.zeros(sshape, jnp.float32),
                        v_scale=jnp.zeros(sshape, jnp.float32))
 
 
 def _kv_block_quant(x: jax.Array, quant: str):
-    """``[T, kvH, hd] float -> (values [T, kvH, hd], scales [T, kvH, 1])``
+    """``[T, kvH, hd] float -> (values [T, kvH*hd], scales [T, kvH])``
     through THE shared block math (``ops.quant``): one symmetric absmax block
     per (token, head) ``hd`` vector, so pool scatters stay one-scatter-per-
     array and dequant is a per-slot multiply."""
@@ -91,45 +106,74 @@ def _kv_block_quant(x: jax.Array, quant: str):
     T, kvH, hd = x.shape
     x2 = x.astype(jnp.float32).reshape(T * kvH, hd)
     q, s = int8_block_math(x2) if quant == "int8" else fp8_block_math(x2)
-    return q.reshape(T, kvH, hd), s.reshape(T, kvH, 1)
+    return q.reshape(T, kvH * hd), s.reshape(T, kvH)
 
 
-def _slot_ids(block_tables: jax.Array, positions: jax.Array, valid: jax.Array,
-              block_size: int, trash: int) -> jax.Array:
-    """Flat pool slot for each (row, token): bt[pos//bs]*bs + pos%bs, or trash."""
-    page = jnp.take_along_axis(block_tables, positions // block_size, axis=1)
-    slot = page * block_size + positions % block_size
-    return jnp.where(valid, slot, trash)
+def _page_writer(block_tables, positions, new_lens, bs: int, num_rows: int):
+    """``put(a, new, first_page)``: the new tokens' rows ``new`` [N*C, X] into
+    a layer's pages of ``a`` [num_rows, bs, X] (or [num_rows, bs*X]), a whole
+    page at a time.
+
+    The chip scatters whole pages well and part-pages one token at a time. A
+    sequence's new tokens are consecutive positions from ``positions[:, 0]``,
+    so they touch at most ``J`` of its pages; each touched page is gathered,
+    the new tokens laid over their slots, and the page put back. Pages are
+    private to a sequence wherever it writes, so no two pages of one call
+    are the same; pages no token touches (and pad sequences') index
+    ``num_rows`` and drop. Everything but the layer's offset is computed
+    here, once for all layers and arrays.
+    """
+    N, C = positions.shape
+    J = (C + bs - 2) // bs + 1
+    start = positions[:, 0]
+    blk = jnp.clip(start[:, None] // bs + jnp.arange(J), 0, block_tables.shape[1] - 1)
+    page = jnp.take_along_axis(block_tables, blk, axis=1)  # [N, J]
+    # the chunk's token that lands in slot s of touched page j, if any
+    tok = jnp.arange(J * bs) - start[:, None] % bs  # [N, J*bs]
+    new_here = (tok >= 0) & (tok < new_lens[:, None])
+    page = jnp.where(new_here.reshape(N, J, bs).any(axis=2), page, num_rows)
+    tok = jnp.clip(tok, 0, C - 1)[:, :, None]
+
+    def put(a, new, first_page):
+        rows = (first_page + page).reshape(-1)
+        X = new.shape[-1]
+        old = a.at[rows].get(mode="clip").reshape(N, J * bs, X)
+        laid = jnp.take_along_axis(new.reshape(N, C, X), tok, axis=1)
+        both = jnp.where(new_here[:, :, None], laid, old)
+        return a.at[rows].set(both.reshape((N * J,) + a.shape[1:]), mode="drop")
+
+    return put
 
 
 from deepspeed_tpu.ops.registry import dispatch, register
 
 
 @register("paged_attention", "xla")
-def _xla_paged_attention(q, pool_k_l, pool_v_l, block_tables, q_positions, block_size,
+def _xla_paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_size,
                          new_lens=None, alibi_slopes=None, k_scale=None, v_scale=None):
     """Masked GQA attention of new queries against paged caches (dense-gather
     fallback; the Pallas flash-decode kernel in
     ``ops/pallas/paged_attention.py`` wins dispatch on TPU).
 
-    q: [N, C, H, hd]; pool_{k,v}_l: [S_flat, kvH, hd] (one layer's pool);
-    block_tables: [N, P]; q_positions: [N, C]. Returns [N, C, H, hd].
+    q: [N, C, H, hd]; pool_{k,v}: [pages, bs, kvH*hd] (the whole pool, every
+    layer's pages); block_tables: [N, P] page indices INTO that array (the
+    caller adds the layer's offset); q_positions: [N, C]. Returns [N, C, H, hd].
 
-    ``k_scale``/``v_scale`` ([S_flat, kvH, 1] fp32) mark a quantized pool:
+    ``k_scale``/``v_scale`` ([pages, bs*kvH] fp32) mark a quantized pool:
     dequantization happens on the GATHERED blocks ([N, P*bs, ...], bounded by
-    the batch's block tables) — the full-precision [S_flat, kvH, hd] pool is
-    never materialized.
+    the batch's block tables) — the full-precision pool is never materialized.
     """
     N, C, H, hd = q.shape
     P = block_tables.shape[1]
-    slot = block_tables[:, :, None] * block_size + jnp.arange(block_size)[None, None, :]
-    slot = slot.reshape(N, P * block_size)  # global position j -> pool slot
-    ck = pool_k_l[slot]  # [N, P*bs, kvH, hd]
-    cv = pool_v_l[slot]
+    kvH = pool_k.shape[-1] // hd
+
+    def rows(a):  # a row's pages, in block-table order: slot index == position
+        return a[block_tables].reshape(N, P * block_size, kvH, -1)
+
+    ck, cv = rows(pool_k), rows(pool_v)  # [N, P*bs, kvH, hd]
     if k_scale is not None:
-        ck = (ck.astype(jnp.float32) * k_scale[slot]).astype(q.dtype)
-        cv = (cv.astype(jnp.float32) * v_scale[slot]).astype(q.dtype)
-    kvH = ck.shape[2]
+        ck = (ck.astype(jnp.float32) * rows(k_scale)).astype(q.dtype)
+        cv = (cv.astype(jnp.float32) * rows(v_scale)).astype(q.dtype)
     G = H // kvH
     qg = q.reshape(N, C, kvH, G, hd)
     scores = jnp.einsum("nckgd,ntkd->nkgct", qg, ck).astype(jnp.float32)
@@ -147,7 +191,7 @@ def _xla_paged_attention(q, pool_k_l, pool_v_l, block_tables, q_positions, block
     return ctx.reshape(N, C, H, hd)
 
 
-def paged_attention(q, pool_k_l, pool_v_l, block_tables, q_positions, block_size,
+def paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_size,
                     new_lens=None, impl: str = "auto", alibi_slopes=None,
                     k_scale=None, v_scale=None):
     import deepspeed_tpu.ops.pallas.paged_attention  # noqa: F401  (registers the kernel)
@@ -158,7 +202,7 @@ def paged_attention(q, pool_k_l, pool_v_l, block_tables, q_positions, block_size
     # dequant: the kernel fuses it into its VMEM block loads, the XLA
     # fallback applies it to the gathered blocks.
     return dispatch("paged_attention", impl)(
-        q, pool_k_l, pool_v_l, block_tables, q_positions, block_size,
+        q, pool_k, pool_v, block_tables, q_positions, block_size,
         new_lens=new_lens, alibi_slopes=alibi_slopes,
         k_scale=k_scale, v_scale=v_scale,
     )
@@ -182,13 +226,20 @@ def _forward_hidden(
     ``all_positions=True`` returns the full ``[N, C, E]`` hidden states
     instead of the last-token selection — the speculative verify step needs
     a logit at EVERY draft position to accept/reject in one pass.
+
+    A row's ``positions`` are consecutive from ``positions[:, 0]`` (a chunk of
+    a prompt, one decode token, or a token and its drafts).
     """
     N, C = tokens.shape
     bs = block_size
-    trash = pool.k.shape[1] - 1
+    L = cfg.num_layers
+    NB = pool.k.shape[0] // L
     valid = jnp.arange(C)[None, :] < new_lens[:, None]  # [N, C]
-    slot = _slot_ids(block_tables, positions, valid, bs, trash)  # [N, C]
-    flat_slot = slot.reshape(-1)
+    # where each new token's row goes: (page of its layer-0 pool, slot in the
+    # page). Pad tokens get page L*NB, out of range in every layer: dropped.
+    page = jnp.take_along_axis(block_tables, positions // bs, axis=1)
+    w_page = jnp.where(valid, page, L * NB).reshape(-1)
+    w_slot = (positions % bs).reshape(-1)
 
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(cfg.dtype)
@@ -207,14 +258,29 @@ def _forward_hidden(
 
     quant = pool.quant  # static at trace time (value dtype + scale presence)
 
+    # Values go in a token's row at a time when a sequence brings fewer
+    # tokens than a page holds (decode, drafts: a 4 KB row against a 64 KB
+    # page), and a whole page at a time when it brings a chunk of a prompt.
+    # Scales are a lane-dense row a PAGE, so they always go a page at a time.
+    by_page = C >= bs
+    put_pages = None
+    if by_page or quant is not None:
+        put_pages = _page_writer(block_tables, positions, new_lens, bs, L * NB)
+
+    def put_values(a, new, first_page):
+        if by_page:
+            return put_pages(a, new, first_page)
+        return a.at[first_page + w_page, w_slot].set(new, mode="drop")
+
     # Scopes for a device trace (HLO metadata only). ``pool_scan`` encloses
     # the layer scan; everything the body computes sits under ``layer`` (or
-    # under ``kv_write``, ``page_view`` or the kernel's own name inside it),
-    # so what reads ``pool_scan`` innermost is the scan's own traffic: each
-    # layer's pool sliced out of the carried stack and written back into it.
+    # under ``kv_write`` or the kernel's own name inside it), so what reads
+    # ``pool_scan`` innermost is the scan's own traffic. The pool rides in
+    # the carry and is updated in place, so that should be next to nothing.
     @jax.named_scope("layer")
-    def body(x, xs):
-        lp, pk, pv, psk, psv = xs
+    def body(carry, xs):
+        x, pk, pv, psk, psv = carry
+        lp, first_page = xs  # the layer's params, and layer * NB
         h = _apply_norm(lp["attn_norm"], cfg, x)
         q, k, v = _qkv(lp["attn"], cfg, h)
         if cfg.position == "rope":
@@ -225,18 +291,18 @@ def _forward_hidden(
         with jax.named_scope("kv_write"):
             if quant is not None:
                 # quantized KV write: the same one-scatter-per-array shape,
-                # plus one scale scatter per array (pad rows route to the
-                # trash slot for values AND scales alike)
+                # plus one scatter of scale pages per array (pad rows drop
+                # for values AND scales alike)
                 kq, ks = _kv_block_quant(k.reshape(-1, kvH, hd), quant)
                 vq, vs = _kv_block_quant(v.reshape(-1, kvH, hd), quant)
-                pk = pk.at[flat_slot].set(kq.astype(pk.dtype), mode="drop")
-                pv = pv.at[flat_slot].set(vq.astype(pv.dtype), mode="drop")
-                psk = psk.at[flat_slot].set(ks, mode="drop")
-                psv = psv.at[flat_slot].set(vs, mode="drop")
+                pk = put_values(pk, kq.astype(pk.dtype), first_page)
+                pv = put_values(pv, vq.astype(pv.dtype), first_page)
+                psk = put_pages(psk, ks, first_page)
+                psv = put_pages(psv, vs, first_page)
             else:
-                pk = pk.at[flat_slot].set(k.astype(pk.dtype).reshape(-1, kvH, hd), mode="drop")
-                pv = pv.at[flat_slot].set(v.astype(pv.dtype).reshape(-1, kvH, hd), mode="drop")
-        ctx = paged_attention(q, pk, pv, block_tables, positions, bs,
+                pk = put_values(pk, k.astype(pk.dtype).reshape(-1, kvH * hd), first_page)
+                pv = put_values(pv, v.astype(pv.dtype).reshape(-1, kvH * hd), first_page)
+        ctx = paged_attention(q, pk, pv, block_tables + first_page, positions, bs,
                               new_lens=new_lens, alibi_slopes=alibi,
                               k_scale=psk, v_scale=psv)
         attn_out = _attn_out(lp["attn"], cfg, ctx)
@@ -245,19 +311,19 @@ def _forward_hidden(
             # gpt-neox-style (parallel_mlp_norm): FFN reads its own ln2(x)
             ffn_in = _apply_norm(lp["mlp_norm"], cfg, x) if cfg.parallel_mlp_norm else h
             ffn = _moe(lp["moe"], cfg, ffn_in) if cfg.num_experts > 0 else _mlp(lp["mlp"], cfg, ffn_in)
-            return x + attn_out + ffn, (pk, pv, psk, psv)
+            return (x + attn_out + ffn, pk, pv, psk, psv), None
         x = x + attn_out
         h = _apply_norm(lp["mlp_norm"], cfg, x)
         if cfg.num_experts > 0:
             x = x + _moe(lp["moe"], cfg, h)
         else:
             x = x + _mlp(lp["mlp"], cfg, h)
-        return x, (pk, pv, psk, psv)
+        return (x, pk, pv, psk, psv), None
 
     with jax.named_scope("pool_scan"):
-        x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-            body, x, (params["layers"], pool.k, pool.v, pool.k_scale, pool.v_scale))
-    pool = pool._replace(k=k_new, v=v_new, k_scale=ks_new, v_scale=vs_new)
+        (x, *pool), _ = jax.lax.scan(
+            body, (x, *pool), (params["layers"], jnp.arange(L, dtype=jnp.int32) * NB))
+    pool = PagedKVPool(*pool)
 
     if all_positions:
         return x, pool  # [N, C, E]
@@ -318,7 +384,7 @@ def ragged_decode_chain(
     the threaded PRNG key, writes the input token's KV through the
     pre-extended block table, and masks finished rows in-scan: a row goes
     inactive when it samples ``eos_id`` or exhausts its ``budgets`` entry,
-    after which its KV writes route to the trash slot and its emitted slots
+    after which its KV writes drop (out-of-range page) and its emitted slots
     are -1.
 
     Returns ``(out_tokens [N, K], emitted [N], active [N], rng, pool)`` where
@@ -366,7 +432,12 @@ class MigrationBuffer(NamedTuple):
     with the block table rewritten. The bytes are the pool's bytes verbatim
     (int8/fp8 values stay int8/fp8, fp32 scales stay fp32): migration never
     re-quantizes, so the blake2b content identity of every block survives
-    and prefix-cache entries stay valid at the destination."""
+    and prefix-cache entries stay valid at the destination.
+
+    The shapes are the WIRE's, token-major with the heads apart, and older
+    than the pool's page-major layout: a replica built before that change and
+    one built after exchange the same documents. Row-major both are the same
+    bytes, so export and import only reshape."""
 
     k: jax.Array  # [L, pages*bs, kvH, hd], pool value dtype
     v: jax.Array
@@ -374,27 +445,31 @@ class MigrationBuffer(NamedTuple):
     v_scale: Optional[jax.Array] = None
 
 
-def export_pool_blocks(pool: PagedKVPool, blocks: jax.Array,
-                       block_size: int) -> MigrationBuffer:
+def _block_rows(pool: PagedKVPool, num_layers: int, blocks: jax.Array) -> jax.Array:
+    """``[L, B]`` rows of the pool's arrays holding ``blocks`` [B] in every layer."""
+    first = jnp.arange(num_layers, dtype=jnp.int32) * (pool.k.shape[0] // num_layers)
+    return first[:, None] + blocks[None, :]
+
+
+def export_pool_blocks(pool: PagedKVPool, blocks: jax.Array, num_layers: int,
+                       kv_heads: int) -> MigrationBuffer:
     """Gather ``blocks`` (block ids, block-table order, [B] int32 traced) out
     of the pool into one contiguous :class:`MigrationBuffer`. A pure gather —
     the quantized bytes move verbatim; block ids ride as traced values so ONE
     compiled program serves every migration of the same page bucket. Pad
     entries (callers bucket B) may repeat any valid block; the host slices
     the valid prefix by ``n_blocks``."""
-    slots = (blocks[:, None] * block_size
-             + jnp.arange(block_size)[None, :]).reshape(-1)
+    rows = _block_rows(pool, num_layers, blocks)
+    tokens = blocks.shape[0] * pool.block_size
 
     def g(a):
-        return None if a is None else a[:, slots]
+        return None if a is None else a[rows].reshape(num_layers, tokens, kv_heads, -1)
 
-    return MigrationBuffer(k=g(pool.k), v=g(pool.v),
-                           k_scale=g(pool.k_scale), v_scale=g(pool.v_scale))
+    return MigrationBuffer(*map(g, pool))
 
 
 def import_pool_blocks(pool: PagedKVPool, buf: MigrationBuffer,
-                       blocks: jax.Array, n_valid: jax.Array,
-                       block_size: int) -> PagedKVPool:
+                       blocks: jax.Array, n_valid: jax.Array) -> PagedKVPool:
     """Scatter a :class:`MigrationBuffer` into ``blocks`` of the destination
     pool — the block-table rewrite made physical. ``blocks`` is the
     DESTINATION allocation (any fragmentation; ids need not be contiguous or
@@ -402,39 +477,38 @@ def import_pool_blocks(pool: PagedKVPool, buf: MigrationBuffer,
     out of bounds and drop). Dtypes must match the destination pool exactly:
     the scatter is verbatim bytes, never a convert — the caller validates
     layout compatibility so quantized pages are never re-quantized."""
-    B = blocks.shape[0]
-    slots = blocks[:, None] * block_size + jnp.arange(block_size)[None, :]
-    valid = jnp.arange(B)[:, None] < n_valid
-    oob = pool.k.shape[1]  # one past the trash slot: dropped by the scatter
-    slots = jnp.where(valid, slots, oob).reshape(-1)
+    L, B = buf.k.shape[0], blocks.shape[0]
+    rows = jnp.where(jnp.arange(B) < n_valid, _block_rows(pool, L, blocks), pool.k.shape[0])
 
     def s(dst, src):
         if dst is None:
             return None
-        return dst.at[:, slots].set(src, mode="drop")
+        return dst.at[rows].set(src.reshape((L, B) + dst.shape[1:]), mode="drop")
 
-    return PagedKVPool(k=s(pool.k, buf.k), v=s(pool.v, buf.v),
-                       k_scale=s(pool.k_scale, buf.k_scale),
-                       v_scale=s(pool.v_scale, buf.v_scale))
+    return PagedKVPool(*map(s, pool, buf))
 
 
 def copy_pool_blocks(pool: PagedKVPool, src: jax.Array, dst: jax.Array,
-                     block_size: int) -> PagedKVPool:
-    """Copy one block's slots (values + scale pages together — the PR-10
+                     num_layers: int) -> PagedKVPool:
+    """Copy one block's page (values + scale pages together — the PR-10
     layout travels as a unit) from block ``src`` to block ``dst`` across
     every layer. The prefix cache's copy-on-write: a shared block diverging
     mid-block is cloned into a private block before the divergent token's
     KV write. ``src``/``dst`` are traced scalars, so ONE jitted program
     serves every COW event."""
+    rows = _block_rows(pool, num_layers, jnp.stack([src, dst]))  # [L, 2]
 
-    def cp(arr):
-        if arr is None:
-            return None
-        sl = jax.lax.dynamic_slice_in_dim(arr, src * block_size, block_size, axis=1)
-        return jax.lax.dynamic_update_slice_in_dim(arr, sl, dst * block_size, axis=1)
+    def cp(a):
+        return None if a is None else a.at[rows[:, 1]].set(a[rows[:, 0]])
 
-    return PagedKVPool(k=cp(pool.k), v=cp(pool.v),
-                       k_scale=cp(pool.k_scale), v_scale=cp(pool.v_scale))
+    return PagedKVPool(*map(cp, pool))
+
+
+def fetch_pool_block(pool: PagedKVPool, block: jax.Array, num_layers: int):
+    """One block's pages of every layer, ``[L, bs, kvH*hd]`` (scales
+    ``[L, bs*kvH]``): the bytes the prefix cache's content digest is over."""
+    rows = _block_rows(pool, num_layers, block[None])[:, 0]
+    return tuple(None if a is None else a[rows] for a in pool)
 
 
 def _ngram_propose(hist: jax.Array, hist_len: jax.Array, n_spec: int,

@@ -8,8 +8,8 @@ TPU-native analog of the reference FastGen ragged layer
 All of this is host-side bookkeeping (numpy, no device work): the device sees
 only the dense arrays a ``RaggedBatch`` assembles — padded token/position
 matrices plus per-sequence block tables into the paged KV pool. Static shape
-buckets keep XLA recompiles rare; the pad rows write to a dedicated trash slot
-in the pool (see ``paged.py``).
+buckets keep XLA recompiles rare; the pad rows' writes index past the pool's
+last page and are dropped (see ``paged.py``).
 
 Because this layer sits on the serving hot path (one assembly per dispatched
 step), everything here is O(1)-per-item and vectorized:
@@ -479,8 +479,8 @@ class RaggedBatch:
 
     Rows are sequences; pad rows have ``new_lens == 0``. ``tokens`` is
     right-padded to the chunk bucket; ``block_tables`` is padded with 0 (pad
-    slots never read: masked by position; never written: writes route to the
-    trash slot).
+    slots never read: masked by position; never written: pad writes index out
+    of the pool and drop).
 
     When assembled through a ``BatchStaging``, the arrays are views into that
     staging pool and are overwritten by the next assembly of the same

@@ -5,12 +5,16 @@ TPU-native analog of FastGen's ``blocked_flash`` kernel
 blocked KV cache) — the kernel the reference's 2.3x-vs-vLLM claim lives in
 (``blogs/deepspeed-fastgen/README.md:28``).
 
-Design: the KV pool stays in HBM (``memory_space=ANY``); the block table rides
-scalar prefetch so the kernel issues manual DMAs of exactly the pages each
-sequence owns — no dense gather ever materializes. Grid is
-``(rows, kv_heads, page_chunks)``; each step copies ``pages_per_block`` pages
-into VMEM, runs one online-softmax update for all query heads in the GQA
-group, and page-chunks past a row's live length are skipped entirely
+Design: the WHOLE KV pool, every layer's pages in one page-major
+``[pages, bs, kvH*hd]`` array, stays in HBM (``memory_space=ANY``) exactly as
+``inference/paged.py`` stores it: the kernel takes it as it is, with no view
+or copy made for it. The block table rides scalar prefetch and already points
+into that array (the caller adds the layer's first page), so the kernel
+issues manual DMAs of exactly the pages each sequence owns — no dense gather
+ever materializes. Grid is ``(rows, page_chunks)``; each step copies
+``pages_per_block`` pages (all kv heads of a page in one lane-dense slab)
+into VMEM, runs one online-softmax update per kv head for all query heads in
+its GQA group, and page-chunks past a row's live length are skipped entirely
 (compute AND DMA — the guard wraps the copies).
 
 Against the XLA fallback (gather pages to dense then masked attention) this
@@ -19,9 +23,9 @@ round-trip: decode becomes one streaming read of the live KV pages, which is
 the bandwidth floor for paged attention.
 
 The KV-insert+RoPE side of the reference's kernel pair
-(``linear_blocked_kv_rotary``) stays an XLA scatter: ``.at[slots].set`` with
-the RoPE rotation feeding it fuses into a single scatter program under XLA,
-so a hand kernel buys nothing there.
+(``linear_blocked_kv_rotary``) is an XLA scatter of the new tokens' rows into
+that same array at ``(page, slot in page)``, in place in the layer scan's
+carry (``inference/paged.py``, scope ``kv_write``).
 
 Quantized KV pools (int8/e4m3 values + per-(slot, head) fp32 scales — see
 ``inference/paged.py``): the scale pages DMA alongside the value pages and
@@ -81,7 +85,7 @@ def _decode_kernel(bt_ref, ap_ref, *refs, ppcb, alibi=False, quantized=False):
 
     def _compute():
         # one DMA per live page, ALL kv heads at once: a page is a contiguous
-        # lane-dense [bs, kvH*hd] slab of the kernel's pool view, so the copy
+        # lane-dense [bs, kvH*hd] slab of the pool as it is stored, so the copy
         # slices only the leading (untiled) page dim — Mosaic refuses any
         # slice of the tiled minor dims that is not (8, 128)-aligned, which
         # a per-head [bs, 1, hd] copy never is
@@ -151,34 +155,22 @@ def _decode_kernel(bt_ref, ap_ref, *refs, ppcb, alibi=False, quantized=False):
         o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-def _page_view(pool_l: jax.Array, num_pages: int, bs: int) -> jax.Array:
-    """``[S_flat, kvH, hd] -> [pages, bs, kvH*hd]``: the layout the kernel
-    DMAs from (trash slot dropped; no block table points at it). On the chip
-    this is a layout-changing COPY of the layer's pool — the stored layout pads
-    (kvH, hd) to the (8, 128) tile, which no DMA may slice. Storing the pool
-    page-major and lane-dense would make it free; that is a layout change of
-    ``inference/paged.py`` and everything that exports pages, not a repair.
-    The ``page_view`` scope names that copy in a device trace."""
-    with jax.named_scope("page_view"):
-        return pool_l[:num_pages * bs].reshape(num_pages, bs, -1)
-
-
 @register("paged_attention", "pallas")
 def flash_decode_paged(
     q: jax.Array,  # [N, C, H, hd]
-    pool_k_l: jax.Array,  # [S_flat, kvH, hd]
-    pool_v_l: jax.Array,
-    block_tables: jax.Array,  # [N, P] int32
+    pool_k: jax.Array,  # [pages, bs, kvH*hd]: the whole pool, all layers
+    pool_v: jax.Array,
+    block_tables: jax.Array,  # [N, P] int32 pages of pool_k (layer offset added)
     q_positions: jax.Array,  # [N, C] int32
     block_size: int,
     new_lens: jax.Array = None,  # [N] live tokens (for page skipping)
     pages_per_block: int = DEFAULT_PAGES_PER_BLOCK,
     alibi_slopes: jax.Array = None,  # [H] fp32 (bloom ALiBi, fused in-kernel)
-    k_scale: jax.Array = None,  # [S_flat, kvH, 1] fp32 — quantized pool scales
+    k_scale: jax.Array = None,  # [pages, bs*kvH] fp32 — quantized pool scales
     v_scale: jax.Array = None,
 ) -> jax.Array:
     N, C, H, hd = q.shape
-    S_flat, kvH = pool_k_l.shape[:2]
+    kvH = pool_k.shape[2] // hd
     G = H // kvH
     P = block_tables.shape[1]
     bs = block_size
@@ -227,8 +219,7 @@ def flash_decode_paged(
             srows = jnp.pad(srows, ((0, 0), (0, Cgp - Cg)))
         operands.append(srows[:, :, None])
         in_specs.append(pl.BlockSpec((kvH, Cgp, 1), lambda n, pc, bt, ap: (0, 0, 0)))
-    num_pages = (S_flat - 1) // bs
-    operands += [_page_view(pool_k_l, num_pages, bs), _page_view(pool_v_l, num_pages, bs)]
+    operands += [pool_k, pool_v]
     in_specs += [
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
@@ -239,9 +230,8 @@ def flash_decode_paged(
         # [N, kvH, Pp*bs] ROWS (slot index == position) and let the pipeline
         # fetch the page-chunk's [kvH, T] block — the kernel multiplies the
         # scores / probabilities by them, never the value tiles
-        slot = (block_tables[:, :, None] * bs + jnp.arange(bs)[None, None, :]).reshape(N, Pp * bs)
         for sc in (k_scale, v_scale):
-            operands.append(sc.reshape(S_flat, kvH)[slot].transpose(0, 2, 1))
+            operands.append(sc[block_tables].reshape(N, Pp * bs, kvH).transpose(0, 2, 1))
             in_specs.append(pl.BlockSpec((1, kvH, T), lambda n, pc, bt, ap: (n, 0, pc)))
 
     kernel = functools.partial(_decode_kernel, ppcb=ppcb, alibi=alibi, quantized=quantized)
@@ -254,8 +244,8 @@ def flash_decode_paged(
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, kvH, Cgp, hd), lambda n, pc, bt, ap: (n, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((ppcb, bs, kvH * hd), pool_k_l.dtype),
-                pltpu.VMEM((ppcb, bs, kvH * hd), pool_v_l.dtype),
+                pltpu.VMEM((ppcb, bs, kvH * hd), pool_k.dtype),
+                pltpu.VMEM((ppcb, bs, kvH * hd), pool_v.dtype),
                 pltpu.VMEM((kvH, Cgp, hd), jnp.float32),
                 pltpu.VMEM((kvH, Cgp, _LANES), jnp.float32),
                 pltpu.VMEM((kvH, Cgp, _LANES), jnp.float32),

@@ -49,8 +49,8 @@ def kv_slot_bytes(num_layers: int, kv_heads: int, head_dim: int,
 
 def kv_pool_bytes(num_layers: int, num_slots: int, kv_heads: int, head_dim: int,
                   dtype_bytes: int = 2, kv_quant: Optional[str] = None) -> int:
-    """Bytes of a paged pool holding ``num_slots`` token slots (pass
-    ``num_blocks * block_size + 1`` to include the trash slot)."""
+    """Bytes of a paged pool holding ``num_slots`` token slots
+    (``num_blocks * block_size``: the pool is whole pages, no trash slot)."""
     return num_slots * kv_slot_bytes(num_layers, kv_heads, head_dim,
                                      dtype_bytes, kv_quant)
 

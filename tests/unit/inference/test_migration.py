@@ -65,7 +65,7 @@ def _engine(cfg, params, **over):
 
 def _block_bytes(eng, block):
     """Raw host bytes of one block's pool pages (values + scales)."""
-    parts = eng._block_fetch_fn()(eng.pool, jnp.int32(block * eng.config.kv_block_size))
+    parts = eng._block_fetch_fn()(eng.pool, jnp.int32(block))
     return tuple(None if p is None else np.asarray(p).tobytes() for p in parts)
 
 
@@ -138,28 +138,31 @@ def test_import_into_fragmented_allocator():
 
 def test_migration_never_requantizes_jaxpr_census():
     """The PR-8/PR-10 census pattern: the export+import programs of an int8
-    pool contain NO floating tensor carrying the head dimension — the
-    quantized bytes (and their fp32 [.., 1] scale pages) move verbatim;
+    pool contain NO floating tensor but the scale pages — the
+    quantized bytes (and their fp32 scale pages) move verbatim;
     there is no dequant, no requant, no convert anywhere."""
     cfg, _, params = make_model()
     eng = _engine(cfg, params, kv_cache_dtype="int8")
-    bs = eng.config.kv_block_size
     blocks = jnp.arange(4, dtype=jnp.int32)
 
     def roundtrip(pool, blocks):
-        buf = export_pool_blocks(pool, blocks, bs)
-        return import_pool_blocks(pool, buf, blocks, jnp.int32(4), bs)
+        buf = export_pool_blocks(pool, blocks, cfg.num_layers, cfg.kv_heads)
+        return import_pool_blocks(pool, buf, blocks, jnp.int32(4))
 
     jaxpr = jax.make_jaxpr(roundtrip)(eng.pool, blocks)
-    avals = _all_avals(jaxpr.jaxpr, [])
+    avals = [a for a in _all_avals(jaxpr.jaxpr, []) if hasattr(a, "shape")]
+    # the only floating tensors are scale pages: rows of bs*kvH (the pool's)
+    # or [.., kvH, 1] (the buffer's), 1/head_dim of the values they ride with
+    bs = eng.config.kv_block_size
     offenders = [a for a in avals
-                 if hasattr(a, "shape") and a.shape
-                 and a.shape[-1] == cfg.dims_per_head
-                 and jnp.issubdtype(a.dtype, jnp.floating)]
+                 if jnp.issubdtype(a.dtype, jnp.floating)
+                 and tuple(a.shape[-2:]) != (cfg.kv_heads, 1)
+                 and a.shape[-1:] != (bs * cfg.kv_heads,)]
     assert not offenders, [f"{a.dtype} {a.shape}" for a in offenders[:5]]
-    # ...and int8 pages really flow through the programs
-    assert any(hasattr(a, "shape") and a.dtype == jnp.int8 and a.shape
-               and a.shape[-1] == cfg.dims_per_head for a in avals)
+    # ...and int8 pages really flow through the programs, in both shapes
+    assert any(a.dtype == jnp.int8 and a.shape[-1:] == (cfg.dims_per_head,) for a in avals)
+    assert any(a.dtype == jnp.int8 and a.shape[-1:] == (cfg.kv_heads * cfg.dims_per_head,)
+               for a in avals)
 
 
 def test_refcounted_prefix_blocks_export_without_double_free():

@@ -8,7 +8,7 @@ The tentpole's correctness contract, pinned three ways:
   2. kernel parity — the fused-dequant Pallas block loads (interpret mode)
      match the XLA per-gathered-block fallback bit-tightly
   3. structure — a jaxpr census of the decode-chain program proves the
-     full-precision ``[S_flat, kvH, hd]`` pool NEVER materializes: every
+     full-precision pool NEVER materializes, whole or one layer of it: every
      pool-sized tensor in the program is int8/fp8 (the PR-8 program-census
      pattern applied to storage instead of wires)
 
@@ -159,21 +159,23 @@ def test_fused_pallas_loads_match_xla_fallback(quant):
     """Interpret-mode parity of the fused-dequant Pallas block loads vs the
     XLA gather-then-dequant fallback on an identically quantized pool."""
     cfg, _, _ = make_model()
-    pool = init_pool(cfg, 8, 4, jnp.float32, kv_quant=quant)
-    S = pool.k.shape[1]
-    kvH, hd = cfg.kv_heads, cfg.dims_per_head
+    NB, bs = 8, 4
+    pool = init_pool(cfg, NB, bs, jnp.float32, kv_quant=quant)
+    L, kvH, hd = cfg.num_layers, cfg.kv_heads, cfg.dims_per_head
     rng = np.random.RandomState(3)
-    kv = rng.randn(S - 1, kvH, hd).astype(np.float32)
-    kq, ks = _kv_block_quant(jnp.asarray(kv), quant)
-    vv = rng.randn(S - 1, kvH, hd).astype(np.float32)
-    vq, vs = _kv_block_quant(jnp.asarray(vv), quant)
-    pk = pool.k[0].at[: S - 1].set(kq.astype(pool.k.dtype))
-    psk = pool.k_scale[0].at[: S - 1].set(ks)
-    pv = pool.v[0].at[: S - 1].set(vq.astype(pool.v.dtype))
-    psv = pool.v_scale[0].at[: S - 1].set(vs)
+
+    def filled(values, scales):
+        """The whole pool, zeros but for the LAST layer's pages."""
+        q, s_ = _kv_block_quant(jnp.asarray(rng.randn(NB * bs, kvH, hd), jnp.float32), quant)
+        first = (L - 1) * NB
+        return (values.at[first:].set(q.astype(values.dtype).reshape(NB, bs, -1)),
+                scales.at[first:].set(s_.reshape(NB, -1)))
+
+    pk, psk = filled(pool.k, pool.k_scale)
+    pv, psv = filled(pool.v, pool.v_scale)
     N, C, H = 2, 1, cfg.num_heads
     q = jnp.asarray(rng.randn(N, C, H, hd), jnp.float32)
-    bt = jnp.asarray(rng.randint(0, 8, (N, 4)), jnp.int32)
+    bt = jnp.asarray(rng.randint(0, NB, (N, 4)), jnp.int32) + (L - 1) * NB
     qpos = jnp.asarray([[5], [9]], jnp.int32)
     o_x = paged_attention(q, pk, pv, bt, qpos, 4, impl="xla",
                           k_scale=psk, v_scale=psv)
@@ -204,11 +206,11 @@ def _all_avals(jaxpr, acc):
 
 def test_decode_program_never_materializes_fp_pool():
     """Jaxpr census of the quantized decode-chain program (the PR-8 pattern):
-    no floating-dtype tensor anywhere in the program carries the pool's
-    S_flat slot dimension — dequant happens per gathered block (XLA path) or
+    no floating-dtype tensor anywhere in the program is as large as one
+    layer's dense pool — dequant happens per gathered block (XLA path) or
     inside the kernel's VMEM loads (Pallas path), never on the pool."""
     cfg, _, params = make_model()
-    eng = _engine(cfg, params, kv_cache_dtype="int8")
+    eng = _engine(cfg, params, kv_cache_dtype="int8", max_seq_len=32)
     bs = eng.config.kv_block_size
     rows, k = 4, 4
 
@@ -222,22 +224,24 @@ def test_decode_program_never_materializes_fp_pool():
         jnp.zeros((rows, eng.max_pages), jnp.int32),
         jnp.ones((rows,), bool), jnp.full((rows,), k, jnp.int32),
         jax.random.PRNGKey(0))
-    s_flat = eng.pool.k.shape[1]
-    # the batch's gathered view must be smaller than the pool, or the census
-    # couldn't tell "gathered block" from "whole pool"
-    assert eng.max_pages * bs != s_flat
-    avals = _all_avals(jaxpr.jaxpr, [])
-    # offender = a floating [.., S_flat, .., head_dim] tensor: the dense pool
-    # (the fp32 [.., S_flat, kvH, 1] SCALES are pool-sized by design — they
-    # are 1/head_dim the bytes and exactly what quantized storage stores)
+    L, D = cfg.num_layers, eng.pool.k.shape[-1]
+    NB = eng.pool.k.shape[0] // L
+    layer_elems = NB * bs * D
+    # the batch's gathered view must be smaller than one layer's pool, or the
+    # census couldn't tell "gathered block" from "a layer's pool"
+    assert rows * eng.max_pages < NB
+    avals = [a for a in _all_avals(jaxpr.jaxpr, []) if hasattr(a, "shape")]
+    # offender = a floating tensor with a layer's pool of elements or more
+    # whose rows are kvH*hd or hd wide: the dense pool, in either layout.
+    # (The fp32 SCALES are 1/head_dim the elements, exactly what quantized
+    # storage stores, and fall under the size.)
+    assert eng.pool.k_scale.size < layer_elems
     offenders = [a for a in avals
-                 if hasattr(a, "shape") and s_flat in tuple(a.shape)
-                 and a.shape and a.shape[-1] == cfg.dims_per_head
+                 if a.size >= layer_elems and a.shape[-1] in (D, cfg.dims_per_head)
                  and jnp.issubdtype(a.dtype, jnp.floating)]
     assert not offenders, [f"{a.dtype} {a.shape}" for a in offenders[:5]]
     # and the quantized pool IS in the program (the census has teeth)
-    assert any(hasattr(a, "shape") and s_flat in tuple(a.shape)
-               and a.dtype == jnp.int8 for a in avals)
+    assert any(a.size == L * layer_elems and a.dtype == jnp.int8 for a in avals)
 
 
 # ----------------------------------------------------------- capacity & gauges

@@ -92,14 +92,15 @@ def test_flash_attention_partitions_over_four_chips(topo, monkeypatch):
     assert _compiled_kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) >= 2
 
 
-def _paged_shapes(sh, N, C, kv_quant, H, kvH, hd, pages=64, bs=16, num_blocks=512):
-    S = num_blocks * bs + 1
+def _paged_shapes(sh, N, C, kv_quant, H, kvH, hd, pages=64, bs=16, num_blocks=512, layers=12):
+    """The kernel's operands as ``inference/paged.py`` hands them over: the
+    whole pool, every layer's pages, page-major in one rank-3 array."""
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=sh)
-    pool = sds((S, kvH, hd), jnp.int8 if kv_quant else jnp.bfloat16)
+    pool = sds((layers * num_blocks, bs, kvH * hd), jnp.int8 if kv_quant else jnp.bfloat16)
     shapes = [sds((N, C, H, hd), jnp.bfloat16), pool, pool, sds((N, pages), jnp.int32),
               sds((N, C), jnp.int32), sds((N,), jnp.int32)]
     if kv_quant:
-        shapes += [sds((S, kvH, 1), jnp.float32)] * 2
+        shapes += [sds((layers * num_blocks, bs * kvH), jnp.float32)] * 2
     return shapes, bs
 
 
@@ -120,6 +121,64 @@ def test_paged_attention_compiles(one_chip, monkeypatch, C, kv_quant, H, kvH, hd
         return pa.flash_decode_paged(q, pk, pv, bt, qpos, bs, new_lens=lens, **kw)
 
     assert _compiled_kernels(fn, *shapes) == 1
+
+
+@pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["bf16", "int8"])
+def test_decode_chain_updates_the_pool_in_place(one_chip, monkeypatch, kv_quant):
+    """Whether a carried array is updated in place is the chip's compiler's
+    decision, not the jaxpr's (ISSUE 26). The whole decode chain at head_dim
+    128, compiled for the described v5e: the donated pool comes back aliased,
+    the temporaries stay far under one pool (a second pool, or a layer sliced
+    out of it, would not), and the kernel reads the pool's own rank-3 array."""
+    import re
+
+    from deepspeed_tpu.inference import paged
+    from deepspeed_tpu.models import CausalLM, TransformerConfig
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")  # what 'auto' sees there
+    cfg = TransformerConfig(vocab_size=1024, hidden_size=512, intermediate_size=1024, num_layers=4,
+                            num_heads=4, num_kv_heads=4, max_seq_len=512, dtype=jnp.bfloat16)
+    NB, bs, rows, k = 2048, 16, 8, 2
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda key: CausalLM(cfg).init({"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)},
+                                       train=False)["params"], jax.random.PRNGKey(0)))
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: paged.init_pool(cfg, NB, bs, jnp.bfloat16, kv_quant=kv_quant)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def chain(params, pool, tokens, start_pos, tables, active, budgets, rng):
+        return paged.ragged_decode_chain(params, cfg, pool, tokens, start_pos, tables, bs,
+                                         active, budgets, rng, k, None)
+
+    compiled = chain.lower(
+        params, pool, i32(rows), i32(rows), i32(rows, cfg.max_seq_len // bs),
+        jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip), i32(rows),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)).compile()
+    values = pool.k.size * pool.k.dtype.itemsize  # one of the pool's two value arrays
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pool))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < values // 4, (mem.temp_size_in_bytes, values)
+    text = compiled.as_text()
+    dt = "s8" if kv_quant else "bf16"
+    whole = r"%s\[%d,%d,%d\]" % (dt, cfg.num_layers * NB, bs, cfg.kv_heads * cfg.dims_per_head)
+    kernel = next(line for line in text.splitlines()
+                  if "tpu_custom_call" in line and "paged_attn" in line)
+    assert len(re.findall(whole, kernel.split("operand_layout_constraints")[1])) == 2, kernel
+    # nothing copies, slices or re-lays the pool's values or a layer of them
+    # (the scales, 4/hd of the bytes, answer to the temporaries' bound above:
+    # stored [.., bs, kvH] they pad kvH to 128 lanes and are re-laid every
+    # layer, which read 69.8 MB of temporaries here beside a 67 MB pool)
+    layer = r"%s\[(%d|%d),%d,%d\]" % (dt, cfg.num_layers * NB, NB, bs,
+                                        cfg.kv_heads * cfg.dims_per_head)
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= %s\S* (copy|dynamic-slice|reshape|transpose)\(" % layer, line)]
+    assert not moved, moved
 
 
 def test_layer_norm_gpt2_width(one_chip, monkeypatch):

@@ -30,7 +30,7 @@ def _sparse(q):
 
 
 def _paged(q):
-    pool = jnp.ones((4 * 8 + 1, 2, 16), jnp.float32)
+    pool = jnp.ones((4, 8, 2 * 16), jnp.float32)  # [pages, bs, kvH*hd]
     return flash_decode_paged(q, pool, pool, jnp.zeros((2, 2), jnp.int32),
                               jnp.zeros((2, 1), jnp.int32), 8)
 
@@ -43,7 +43,6 @@ KERNELS = [
     ("sparse_attn_bwd_dq", jax.grad(_sparse), (QKV,)),
     ("sparse_attn_bwd_dkv", jax.grad(_sparse), (QKV,)),
     ("paged_attn", _paged, (jnp.ones((2, 1, 2, 16), jnp.float32),)),
-    ("page_view", _paged, (jnp.ones((2, 1, 2, 16), jnp.float32),)),  # the scope beside the kernel
     ("rms_norm", lambda x: pallas_rms_norm(x, jnp.ones((32,))), (jnp.ones((8, 32)),)),
     ("layer_norm", lambda x: pallas_layer_norm(x, jnp.ones((32,)), jnp.zeros((32,))),
      (jnp.ones((8, 32)),)),

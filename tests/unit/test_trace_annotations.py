@@ -155,7 +155,7 @@ def test_nothing_is_recorded_without_a_session_and_with_the_tracer_off(trainer, 
 
 
 # ------------------------------------------------------------ device scopes
-# (the Pallas kernels' names, and page_view beside the paged kernel, are in
+# (the Pallas kernels' names are in
 # tests/unit/ops/test_kernel_names.py: on the CPU the engines take XLA attention)
 TRAIN_SCOPES = ("embed", "layers", "lm_head_ce", "optimizer")
 CHAIN_SCOPES = ("embed", "pool_scan", "layer", "kv_write", "lm_head", "sample")
