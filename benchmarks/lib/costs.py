@@ -3,7 +3,9 @@
 These are the yardstick for utilisation and roofline shares, so they count
 what the mathematics requires and nothing an implementation adds: no
 recomputation, no padding, no masked-out half of a causal score matrix.
-``cfg`` is a published ``gpt_neox`` config dict (``benchmarks/configs``).
+Nothing here reads a configuration: layers, heads, head size and parameter
+counts come in as numbers, from the configuration's architecture file
+(``benchmarks/architectures/<architecture>.py``).
 """
 
 from __future__ import annotations
@@ -13,35 +15,17 @@ from typing import Tuple
 from benchmarks.lib.peaks import Peaks
 
 
-def head_dim(cfg: dict) -> int:
-    return cfg["hidden_size"] // cfg["num_attention_heads"]
-
-
-def matmul_params(cfg: dict) -> int:
-    """Parameters that sit in a matrix multiplication once per token: the
-    four attention projections and the two MLP matrices of every layer, and
-    the output head. The embedding table is a lookup and is left out."""
-    h, f = cfg["hidden_size"], cfg["intermediate_size"]
-    return cfg["num_hidden_layers"] * (4 * h * h + 2 * h * f) + h * cfg["vocab_size"]
-
-
-def total_params(cfg: dict) -> int:
-    h, f, L, V = (cfg["hidden_size"], cfg["intermediate_size"],
-                  cfg["num_hidden_layers"], cfg["vocab_size"])
-    per_layer = 4 * h * h + 4 * h + 2 * h * f + f + h + 4 * h  # weights, biases, two norms
-    return L * per_layer + 2 * h + (1 if cfg.get("tie_word_embeddings") else 2) * V * h
-
-
-def attention_flops_per_token(cfg: dict, seq: int, causal: bool = True) -> float:
+def attention_flops_per_token(layers: int, heads: int, dim: int, seq: int,
+                              causal: bool = True) -> float:
     """Forward score and value products of all layers, per token of a
-    sequence of ``seq``: 2*seq*h each, halved by the causal mask."""
-    per_layer = 4.0 * seq * cfg["hidden_size"] * (0.5 if causal else 1.0)
-    return cfg["num_hidden_layers"] * per_layer
+    sequence of ``seq``: 2*seq*heads*dim each, halved by the causal mask."""
+    return layers * 4.0 * seq * heads * dim * (0.5 if causal else 1.0)
 
 
-def train_flops_per_token(cfg: dict, seq: int) -> float:
-    """Forward plus backward (twice the forward), per trained token."""
-    return 3.0 * (2.0 * matmul_params(cfg) + attention_flops_per_token(cfg, seq))
+def train_flops_per_token(matmul_params: int, layers: int, heads: int, dim: int, seq: int) -> float:
+    """Forward plus backward (twice the forward), per trained token;
+    ``matmul_params`` are the parameters a token meets in a matrix product."""
+    return 3.0 * (2.0 * matmul_params + attention_flops_per_token(layers, heads, dim, seq))
 
 
 def flash_forward_cost(batch: int, heads: int, seq: int, dim: int, itemsize: int = 2,

@@ -48,6 +48,13 @@ def load_reference(architecture: str, bench_dir: str = BENCH_DIR):
                         f"bench_reference_{architecture}")
 
 
+def load_architecture(architecture: str, bench_dir: str = BENCH_DIR):
+    """What the benchmark knows of how the program lays an architecture out
+    and how its work is counted: ``architectures/<architecture>.py``."""
+    return _load_module(os.path.join(bench_dir, "architectures", architecture + ".py"),
+                        f"bench_architecture_{architecture}")
+
+
 def load_benchmark(bench_dir: str = BENCH_DIR) -> dict:
     """``BENCHMARK.json`` beside ``benchmarks/``: the one place that says which
     metrics a cell reports, in which unit."""

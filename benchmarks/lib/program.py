@@ -1,20 +1,23 @@
 """The seam to the system under test: its model config from a published
-``config.json``, and its parameter tree relabelled for the plain reference.
-Everything else the benchmark knows about the program is in the runners."""
+``config.json``, through the mapping it applies to checkpoints of every
+architecture it loads. How an architecture's parameter tree is relabelled for
+its plain reference, and how its work is counted, is in that architecture's
+own file, ``benchmarks/architectures/<architecture>.py``; everything else the
+benchmark knows about the program is in the runners."""
 
 from __future__ import annotations
 
 import dataclasses
 
-PUBLISHED_KEYS = (
-    "model_type", "vocab_size", "hidden_size", "intermediate_size", "num_hidden_layers",
-    "num_attention_heads", "max_position_embeddings", "hidden_act", "rotary_pct",
-    "rotary_emb_base", "layer_norm_eps", "use_parallel_residual", "tie_word_embeddings")
+# what a configuration file holds beside the published config: the
+# benchmark's own notes, which neither the program nor the reference sees
+NOTE_KEYS = ("name", "source", "architecture", "reference", "deployment", "reduced", "assumed",
+             "check")
 
 
 def published(config: dict) -> dict:
     """The configuration file without the benchmark's own notes."""
-    return {k: config[k] for k in PUBLISHED_KEYS if k in config}
+    return {k: v for k, v in config.items() if k not in NOTE_KEYS}
 
 
 def model_config(config: dict, dtype):
@@ -25,27 +28,14 @@ def model_config(config: dict, dtype):
     return dataclasses.replace(config_from_hf(published(config)), dtype=dtype)
 
 
-def reference_weights(params) -> dict:
-    """The program's (scan-stacked) parameter tree under the names
-    ``benchmarks/reference/gpt_neox.py`` reads. Relabelling only: the arrays
-    are the program's own, whatever their dtype and placement."""
-    layers, attn, mlp = params["layers"], params["layers"]["attn"], params["layers"]["mlp"]
-    return {
-        "embed_in": params["embed"]["embedding"],
-        "embed_out": params["lm_head"]["kernel"],
-        "final_ln_scale": params["final_norm"]["scale"],
-        "final_ln_bias": params["final_norm"]["bias"],
-        "layers": {
-            "ln1_scale": layers["attn_norm"]["scale"], "ln1_bias": layers["attn_norm"]["bias"],
-            "ln2_scale": layers["mlp_norm"]["scale"], "ln2_bias": layers["mlp_norm"]["bias"],
-            "wq": attn["wq"]["kernel"], "bq": attn["wq"]["bias"],
-            "wk": attn["wk"]["kernel"], "bk": attn["wk"]["bias"],
-            "wv": attn["wv"]["kernel"], "bv": attn["wv"]["bias"],
-            "wo": attn["wo"]["kernel"], "bo": attn["wo"]["bias"],
-            "w_in": mlp["w_up"]["kernel"], "b_in": mlp["w_up"]["bias"],
-            "w_out": mlp["w_down"]["kernel"], "b_out": mlp["w_down"]["bias"],
-        },
-    }
+def tolerance(config: dict, key: str) -> float:
+    """A tolerance of the configuration's own ``check`` block. There is no
+    default: a configuration that never measured one does not inherit one."""
+    try:
+        return float(config["check"][key])
+    except KeyError:
+        raise KeyError(f"configuration {config.get('name')!r} states no check.{key}: measure it "
+                       "(PERF.md, section 7) and write it with its readings into the file") from None
 
 
 def relative_error(got, want) -> float:

@@ -3,7 +3,7 @@
 Since PR 25 the program names its layers with ``jax.named_scope`` and its
 Pallas kernels with ``pallas_call(name=)``; both end up as path components of
 an HLO instruction's ``op_name`` (``jit(chain)/while/body/pool_scan/while/
-body/layer/page_view/reshape``; under autodiff ``jvp(lm_head_ce)`` and
+body/layer/kv_write/scatter``; under autodiff ``jvp(lm_head_ce)`` and
 ``transpose(jvp(lm_head_ce))``). ``jax.profiler.ProfileData`` does not expose
 the ``/host:metadata`` plane that maps a traced instruction to its
 ``op_name``; xprof's ``hlo_stats`` tool does (column ``tf_op_name``), with
@@ -33,7 +33,7 @@ from benchmarks.lib import harness, spans, xplane
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_attn", "rms_norm", "layer_norm",
            "quantize_int8", "dequantize_int8", "sparse_attn_fwd", "sparse_attn_bwd_dq",
            "sparse_attn_bwd_dkv")
-SCOPES = ("embed", "layers", "layer", "page_view", "kv_write", "pool_scan", "lm_head", "sample",
+SCOPES = ("embed", "layers", "layer", "kv_write", "pool_scan", "lm_head", "sample",
           "lm_head_ce", "optimizer") + KERNELS
 UNSCOPED = "(no scope)"
 _WRAPPED = re.compile(r"^(?:[a-z_]+\()+(.*?)\)+$")  # transpose(jvp(x)) -> x

@@ -9,7 +9,8 @@ token ids. A bound measured on one set of seeds then holds for the driver's.
 ``traffic`` keys:
 
   kind           "token_batches" (training), "open_loop" or "closed_waves"
-  token_batches  sequences, seq_len
+  token_batches  sequences, seq_len; steps_in_flight (how many steps the train
+                 runner keeps dispatched before it waits for the oldest)
   open_loop      rate_per_s (Poisson arrivals); prompt_len; output_tokens
   closed_waves   wave (requests submitted at once; the next wave when the last
                  request of this one is done); prompt_len; output_tokens

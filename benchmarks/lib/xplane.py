@@ -12,9 +12,10 @@ holds, as looked at by hand on ``tests/benchmarks/data/*.xplane.pb``:
   ``Async XLA Ops`` (one event per asynchronous operation, lasting from its
   ``-start`` to its ``-done``: copies, slices and, across chips, collectives);
 * a Pallas kernel is a ``custom-call`` whose text has
-  ``custom_call_target="tpu_custom_call"``. The trace does not carry the
-  kernel's Python name, so a metric picks its kernel by the operand shapes in
-  that text;
+  ``custom_call_target="tpu_custom_call"``. Since PR 25 the program gives
+  every ``pallas_call`` a ``name=``, which is the instruction's name
+  (``%paged_attn.12``), and ``lib/kernels.py`` picks a kernel by it; a kernel
+  without one is ``%custom-call.N`` and can only be told by its operand shapes;
 * ``/host:CPU`` holds the host threads; ``jax.profiler.TraceAnnotation`` spans
   made by the benchmark land there under their own names. Host and device
   clocks of one trace differ by about a millisecond.

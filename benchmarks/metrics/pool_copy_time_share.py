@@ -1,27 +1,24 @@
-"""Device time of the KV pool's copies over device busy time: what runs under
-the program's scopes ``page_view``, ``pool_scan`` and ``kv_write`` (kernels
-have scopes of their own and are not in it), plus the whole-pool ``copy``
-instructions the compiler inserts, which carry no ``op_name`` and are matched
-by the pool's own shape ``[layers, slots, kv_heads, head_dim]`` in the two
-serving programs. Both parts are printed."""
+"""Device time that moves the KV pool over device busy time: what runs under
+the program's scope ``kv_write`` (the scatters of new keys and values into the
+pool, in place since PR 26), plus any data-formatting instruction of the two
+serving programs under no scope of ours whose result has the pool's own shape:
+a copy of the whole pool, which the compiler gives no ``op_name``. The shape is
+the engine's own pool's, recorded by the serve runner (``run["kv_pool_shape"]``),
+not one made up from a configuration's keys. Both parts are printed."""
 
-import re
-
-from benchmarks.lib import costs, harness, kernels, scopes, spans
+from benchmarks.lib import harness, kernels, scopes, spans, xplane
 
 
 def read(run, trace):
-    scoped = scopes.seconds_under(run, trace, "page_view", "pool_scan", "kv_write")
-    if not scoped:
+    scoped = scopes.seconds_under(run, trace, "kv_write")
+    if not scoped or not run.get("kv_pool_shape"):
         return None
-    cfg = run["config"]
-    pool = re.compile(r"\[%d,\d+,%d,%d\]" % (
-        cfg["num_hidden_layers"], cfg.get("num_key_value_heads", cfg["num_attention_heads"]),
-        costs.head_dim(cfg)))
+    pool = "[%s]" % ",".join(str(n) for n in run["kv_pool_shape"])
     by_shape = sum(i.seconds for i in scopes.instructions(spans.trace_file(run))
                    if i.program in (kernels.CHAIN_PROGRAM, kernels.PREFILL_PROGRAM)
-                   and i.category == "data formatting" and pool.search(i.text)
+                   and i.category == "data formatting"
+                   and xplane.split_instruction(i.text)[2].endswith(pool)
                    and scopes.innermost_scope(i.op_name) == scopes.UNSCOPED) / trace.n_devices
     harness.say(pool_copy_under_scopes_s=scoped, pool_copy_by_shape_s=by_shape,
-                busy_s=trace.busy_s)
+                pool_shape=pool, busy_s=trace.busy_s)
     return 100.0 * (scoped + by_shape) / trace.busy_s

@@ -7,7 +7,9 @@ and in which units. Its file is ``benchmarks/workloads/<name>.json``, which
 names its configuration (``benchmarks/configs/<config>.json``) and its
 ``kind``, whose runner is ``benchmarks/runners/<kind>.py``. The runner builds the system under
 test from ``--seed``, checks it against the configuration's plain reference
-(``benchmarks/reference/<architecture>.py``), warms the cell's own shapes up
+(``benchmarks/reference/<architecture>.py``, given the program's weights
+relabelled by ``benchmarks/architectures/<architecture>.py``) within the
+tolerances of the configuration's own ``check`` block, warms the cell's own shapes up
 and measures for ``--seconds``. Human-readable lines come first; the last line
 of stdout is one JSON object: with ``--trace 0`` the cell's end-to-end
 metrics, with ``--trace 1`` its per-layer metrics (``benchmarks/metrics/``),
@@ -70,11 +72,12 @@ def main(argv=None) -> int:
         # inside the checkout, at a fixed path, emptied before use
         trace_dir = os.path.join(harness.BENCH_DIR, os.pardir, ".bench_trace", args.workload)
         shutil.rmtree(trace_dir, ignore_errors=True)
+    architecture = harness.load_architecture(config["architecture"])
     run = runner.run(
         workload=workload, config=config, reference=harness.load_reference(config["architecture"]),
-        seed=args.seed, seconds=args.seconds, devices=devices, trace_dir=trace_dir,
+        architecture=architecture, seed=args.seed, seconds=args.seconds, devices=devices, trace_dir=trace_dir,
         compiles=harness.CompileCounter(), t_process_start=T_PROCESS_START)
-    run["workload"], run["config"] = workload, config
+    run["workload"], run["config"], run["architecture"] = workload, config, architecture
     run["device_kind"] = devices[0].device_kind
 
     device = harness.device_report(devices, run["memory"])

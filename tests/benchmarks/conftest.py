@@ -1,16 +1,26 @@
 """Tiny cells for the CPU: the published Pythia files with every size cut, so
 that each runner goes end to end in seconds. Nothing here describes a chip."""
 
+import gzip
 import json
 import os
+import re
 import shutil
 
 import pytest
 
 from benchmarks.lib import harness, peaks
 
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+# what an architecture file gives the runners and the readers
+ARCHITECTURE_FILE = ("WIDTH_KEYS", "reference_weights", "matmul_params", "total_params", "layers",
+                     "heads", "kv_heads", "head_dim")
 TINY_MODEL = dict(hidden_size=64, intermediate_size=256, num_hidden_layers=2,
                   num_attention_heads=4, vocab_size=512, max_position_embeddings=256)
+# the tiny configuration's own tolerances, for the CPU: Pythia's on the chip,
+# which its bf16 error at two layers and hidden 64 stays well inside
+TINY_CHECK = {"logit_rel_tol": 0.010, "loss_rel_tol": 2e-4,
+              "why": "tests/benchmarks: a tiny gpt_neox on the CPU"}
 
 
 @pytest.fixture
@@ -26,8 +36,26 @@ def cpu_counts_as_chip(monkeypatch):
 @pytest.fixture
 def tiny_config():
     cfg = harness.load_config("pythia-410m")
-    cfg.update(TINY_MODEL)
+    cfg.update(TINY_MODEL, check=TINY_CHECK)
     return cfg
+
+
+def run_cell(workload, config, seed=2**31 + 5, seconds=1.5, bench_dir=harness.BENCH_DIR):
+    """One run of a cell's runner, its files found by name under ``bench_dir``
+    as ``run.py`` finds them, with what ``run.py`` adds for the readers."""
+    import time
+
+    runner = harness.load_runner(workload["kind"], bench_dir)
+    architecture = harness.load_architecture(config["architecture"], bench_dir)
+    devices = harness.require_devices(workload["chips"])
+    run = runner.run(workload=workload, config=config,
+                     reference=harness.load_reference(config["architecture"], bench_dir),
+                     architecture=architecture, seed=seed, seconds=seconds, devices=devices,
+                     trace_dir=None, compiles=harness.CompileCounter(),
+                     t_process_start=time.perf_counter())
+    run.update(workload=workload, config=config, architecture=architecture,
+               device_kind=devices[0].device_kind)
+    return run
 
 
 def tiny_train_workload(chips=1, mesh=None):
@@ -67,7 +95,38 @@ def bench_copy(tmp_path):
     return str(dst)
 
 
+def unpack_span_trace(directory) -> str:
+    """The v5e trace ``record_span_trace.py`` took, unpacked into ``directory``."""
+    path = os.path.join(str(directory), "v5e_1chip_spans.xplane.pb")
+    packed = os.path.join(os.path.dirname(__file__), "data", "v5e_1chip_spans.xplane.pb.gz")
+    with gzip.open(packed, "rb") as src, open(path, "wb") as dst:
+        shutil.copyfileobj(src, dst)
+    return path
+
+
 def write_json(path, obj):
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:
         json.dump(obj, f)
+
+
+def config_rules(entry, held, bench, bench_dir=harness.BENCH_DIR):
+    """What holds for a configuration of ANY architecture: its entry in
+    ``BENCHMARK.json`` against its file and the files that file names."""
+    assert set(entry) == {"name", "source", "file", "reduced", "why"}
+    assert entry["file"] == f"benchmarks/configs/{entry['name']}.json"
+    assert held["name"] == entry["name"] and held["source"] == entry["source"]
+    # the file says of every cut: key, published value, value used; the entry lists the keys
+    assert [set(r) for r in held["reduced"]] == [{"key", "published", "used"}] * len(entry["reduced"])
+    assert [r["key"] for r in held["reduced"]] == entry["reduced"]
+    architecture = harness.load_architecture(held["architecture"], bench_dir)
+    assert all(hasattr(architecture, name) for name in ARCHITECTURE_FILE)
+    for cut in held["reduced"]:
+        assert NAME.match(cut["key"]) and cut["key"] not in architecture.WIDTH_KEYS  # a width is never cut
+        assert held[cut["key"]] == cut["used"] != cut["published"]
+    assert all(k in held for k in architecture.WIDTH_KEYS)
+    assert held["reference"] == f"benchmarks/reference/{held['architecture']}.py"
+    assert os.path.isfile(os.path.join(bench_dir, "reference", held["architecture"] + ".py"))
+    assert isinstance(held["check"]["why"], str) and len(held["check"]) >= 2
+    assert all(0 < v < 0.1 for k, v in held["check"].items() if k != "why")
+    assert any(w["config"] == entry["name"] for w in bench["workloads"])

@@ -11,7 +11,7 @@ size cut, through the same seam the runners use. So the file holds the
 program's ``dstpu:`` host spans with their args, its named Pallas kernels
 (``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``paged_attn``) and its
 scopes (``embed``, ``layers``, ``lm_head_ce``, ``optimizer``, ``pool_scan``,
-``layer``, ``kv_write``, ``page_view``, ``lm_head``, ``sample``). It writes
+``layer``, ``kv_write``, ``lm_head``, ``sample``). It writes
 the ``.xplane.pb`` gzipped to ``<out>.xplane.pb.gz`` (the HLO of three real
 programs, which maps an instruction to its ``op_name``, is 1.9 MB of a 2.9 MB
 file; gzipped it is 0.4 MB) and what a reader needs to see by hand to
@@ -46,8 +46,9 @@ SERVE = {"dtype": "bf16", "kv_cache_dtype": "bf16", "max_seqs": 8, "decode_chain
 PROMPTS, PROMPT_LEN, NEW_TOKENS = 6, 24, 9  # one prefill, then two chains of 4
 
 
-def describe(path: str) -> str:
-    out = ["# dstpu: spans of the host planes, clipped to bench:window: name start_s seconds args"]
+def describe(path: str, pool_shape) -> str:
+    out = ["# the serving engine's KV pool (engine.pool.k.shape): %s" % list(pool_shape),
+           "# dstpu: spans of the host planes, clipped to bench:window: name start_s seconds args"]
     t0 = None
     for s in spans.read_spans(path):
         t0 = s.start_s if t0 is None else t0
@@ -120,7 +121,7 @@ def main(argv=None) -> int:
         generate()
     jax.profiler.stop_trace()
     trace = xplane.find_xplane(trace_dir)
-    text = describe(trace)
+    text = describe(trace, server.pool.k.shape)
     with open(trace, "rb") as raw, \
             gzip.GzipFile(args.out + ".xplane.pb.gz", "wb", compresslevel=9, mtime=0) as packed:
         shutil.copyfileobj(raw, packed)

@@ -37,15 +37,19 @@ def test_rate_and_tpot():
 ])
 def test_parameter_counts_of_the_published_configs(name, params, matmul):
     cfg = harness.load_config(name)
-    assert costs.total_params(cfg) == pytest.approx(params, rel=0.01)
-    assert costs.matmul_params(cfg) == pytest.approx(matmul, rel=0.01)
+    arch = harness.load_architecture(cfg["architecture"])
+    assert arch.total_params(cfg) == pytest.approx(params, rel=0.01)
+    assert arch.matmul_params(cfg) == pytest.approx(matmul, rel=0.01)
 
 
 def test_train_flops_per_token():
     cfg = harness.load_config("pythia-410m")
-    want = 3 * (2 * costs.matmul_params(cfg) + 24 * 2 * 2048 * 1024)
-    assert costs.train_flops_per_token(cfg, 2048) == pytest.approx(want)
-    assert costs.train_flops_per_token(cfg, 2048) == pytest.approx(2.42e9, rel=0.01)
+    arch = harness.load_architecture(cfg["architecture"])
+    shape = (arch.layers(cfg), arch.heads(cfg), arch.head_dim(cfg))
+    assert shape == (24, 16, 64) and arch.kv_heads(cfg) == 16
+    want = 3 * (2 * arch.matmul_params(cfg) + 24 * 2 * 2048 * 1024)
+    assert costs.train_flops_per_token(arch.matmul_params(cfg), *shape, 2048) == pytest.approx(want)
+    assert want == pytest.approx(2.42e9, rel=0.01)
 
 
 def test_flash_costs_and_bounds():

@@ -7,13 +7,12 @@ import re
 import pytest
 
 from benchmarks.lib import harness
+from tests.benchmarks.conftest import NAME, config_rules
 
 REPO = os.path.dirname(harness.BENCH_DIR)
 BENCH = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-WIDTHS = ("hidden_size", "intermediate_size", "num_attention_heads", "rotary_pct")
 CELLS = [w["name"] for w in BENCH["workloads"]]
 E2E = {m["name"]: m for m in BENCH["end_to_end"]}
 
@@ -45,16 +44,53 @@ def test_names_units_and_lines(entry):
 
 
 @pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
-def test_configs_are_the_published_ones_uncut(config):
-    assert set(config) == {"name", "source", "file", "reduced", "why"}
-    assert config["file"] == f"benchmarks/configs/{config['name']}.json"
-    held = harness.load_config(config["name"])
-    assert held["source"] == config["source"] and held["reduced"] == config["reduced"] == []
-    assert held["model_type"] == "gpt_neox" and held["num_hidden_layers"] == 24
-    assert held["hidden_size"] // held["num_attention_heads"] in (64, 128)
-    assert all(k in held for k in WIDTHS)
-    assert os.path.isfile(os.path.join(REPO, held["reference"]))
-    assert any(w["config"] == config["name"] for w in BENCH["workloads"])
+def test_config_rules_hold_for_any_architecture(config):
+    config_rules(config, harness.load_config(config["name"]), BENCH)
+
+
+@pytest.mark.parametrize("name,head_dim", [("pythia-410m", 64), ("pythia-1.4b", 128)])
+def test_pythia_is_published_uncut(name, head_dim):
+    """Pythia's own facts, which were the rule while the benchmark had one architecture."""
+    entry = next(c for c in BENCH["configs"] if c["name"] == name)
+    held = harness.load_config(name)
+    assert held["reduced"] == entry["reduced"] == []
+    assert held["architecture"] == held["model_type"] == "gpt_neox" and held["num_hidden_layers"] == 24
+    assert held["hidden_size"] // held["num_attention_heads"] == head_dim
+    assert harness.load_architecture("gpt_neox").WIDTH_KEYS == (
+        "hidden_size", "intermediate_size", "num_attention_heads", "rotary_pct")
+    assert held["check"]["loss_rel_tol"] == 2e-4  # PR 24's; the serving one where it serves
+    assert held["check"].get("logit_rel_tol") == (0.010 if name == "pythia-1.4b" else None)
+
+
+DEPTH_CUT = {"key": "num_hidden_layers", "published": 24, "used": 12}
+
+
+@pytest.mark.parametrize("why,entry_reduced,file_reduced,used", [
+    ("a depth cut said in both places passes", ["num_hidden_layers"], [DEPTH_CUT], 12),
+    ("a width is never cut", ["hidden_size"], [{"key": "hidden_size", "published": 2048, "used": 1024}], 1024),
+    ("the entry does not list the file's cut", [], [DEPTH_CUT], 12),
+    ("the file does not say the published value", ["num_hidden_layers"],
+     [{"key": "num_hidden_layers", "used": 12}], 12),
+    ("the file runs another value than it says", ["num_hidden_layers"], [DEPTH_CUT], 16),
+])
+def test_config_rules_on_a_cut_configuration(why, entry_reduced, file_reduced, used):
+    entry = dict(next(c for c in BENCH["configs"] if c["name"] == "pythia-1.4b"), reduced=entry_reduced)
+    held = dict(harness.load_config("pythia-1.4b"), reduced=file_reduced)
+    held[file_reduced[0]["key"]] = used
+    if why.endswith("passes"):
+        config_rules(entry, held, BENCH)
+    else:
+        with pytest.raises(AssertionError):
+            config_rules(entry, held, BENCH)
+
+
+def test_a_configuration_without_a_tolerance_inherits_none():
+    from benchmarks.lib import program
+
+    held = harness.load_config("pythia-410m")  # trains only: no serving tolerance was measured
+    assert program.tolerance(held, "loss_rel_tol") == 2e-4
+    with pytest.raises(KeyError, match="states no check.logit_rel_tol"):
+        program.tolerance(held, "logit_rel_tol")
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda c: c["name"])

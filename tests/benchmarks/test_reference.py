@@ -8,6 +8,8 @@ import pytest
 
 from benchmarks.lib import harness, program
 
+ARCH = harness.load_architecture("gpt_neox")
+
 
 @pytest.fixture(scope="module")
 def tiny():
@@ -37,13 +39,39 @@ def test_program_config_comes_from_the_published_keys(tiny):
     assert (full.hidden_size, full.num_layers, full.num_heads, full.rotary_dim) == (2048, 24, 16, 32)
 
 
+# the thirteen gpt_neox keys that ``published()`` let through until PR 27,
+# when it became the file minus the benchmark's own notes
+PARENT_WHITELIST = (
+    "model_type", "vocab_size", "hidden_size", "intermediate_size", "num_hidden_layers",
+    "num_attention_heads", "max_position_embeddings", "hidden_act", "rotary_pct",
+    "rotary_emb_base", "layer_norm_eps", "use_parallel_residual", "tie_word_embeddings")
+
+
+@pytest.mark.parametrize("name", ["pythia-410m", "pythia-1.4b"])
+def test_program_and_reference_get_what_the_whitelist_gave_them(name):
+    import dataclasses
+
+    from deepspeed_tpu.checkpoint.hf import config_from_hf
+
+    config = harness.load_config(name)
+    through_whitelist = {k: config[k] for k in PARENT_WHITELIST}
+    assert program.model_config(config, jnp.bfloat16) == dataclasses.replace(
+        config_from_hf(through_whitelist), dtype=jnp.bfloat16)
+    # the reference reads keys by name: every one it had it still has, with the
+    # same value; what is new to it are published keys it does not read
+    now = program.published(config)
+    assert through_whitelist.items() <= now.items()
+    assert set(now) - set(PARENT_WHITELIST) == {"initializer_range", "bos_token_id", "eos_token_id"}
+    assert not set(now) & set(program.NOTE_KEYS) and set(config) - set(now) == set(program.NOTE_KEYS)
+
+
 def test_logits_agree_with_the_programs_model(tiny):
     from deepspeed_tpu.models import CausalLM
 
     config, model_cfg, params, tokens = tiny
     reference = harness.load_reference("gpt_neox")
     _, want = CausalLM(model_cfg).apply({"params": params}, {"input_ids": jnp.asarray(tokens)})
-    got = reference.forward(program.reference_weights(params), program.published(config),
+    got = reference.forward(ARCH.reference_weights(params), program.published(config),
                             jnp.asarray(tokens))
     assert program.relative_error(got, want) < 1e-5
 
@@ -54,7 +82,7 @@ def test_loss_agrees_with_the_programs_model(tiny):
     config, model_cfg, params, tokens = tiny
     reference = harness.load_reference("gpt_neox")
     want, _ = CausalLM(model_cfg).apply({"params": params}, {"input_ids": jnp.asarray(tokens)})
-    got = reference.loss(program.reference_weights(params), program.published(config),
+    got = reference.loss(ARCH.reference_weights(params), program.published(config),
                          jnp.asarray(tokens))
     assert float(got) == pytest.approx(float(want), rel=1e-5)
 

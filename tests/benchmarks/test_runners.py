@@ -5,26 +5,14 @@ import json
 import os
 import subprocess
 import sys
-import time
 
 import jax
 import pytest
 
 from benchmarks.lib import harness
-from tests.benchmarks.conftest import tiny_serve_workload, tiny_train_workload
+from tests.benchmarks.conftest import run_cell, tiny_serve_workload, tiny_train_workload
 
 REPO = os.path.dirname(harness.BENCH_DIR)
-
-
-def run_cell(workload, config, seed=2**31 + 5, seconds=1.5):
-    runner = harness.load_runner(workload["kind"])
-    return runner.run(workload=workload, config=config,
-                      reference=harness.load_reference(config["architecture"]),
-                      seed=seed, seconds=seconds, devices=harness.require_devices(workload["chips"]),
-                      trace_dir=None, compiles=harness.CompileCounter(),
-                      t_process_start=time.perf_counter())
-
-
 BENCH = harness.load_benchmark()
 UNITS = {m["name"]: m["unit"] for m in BENCH["end_to_end"]}
 UNITS.update(serve_ttft_p95_ms="ms", serve_tpot_p95_ms="ms")  # wait for the chat cell
@@ -43,15 +31,18 @@ def check_last_line(run, wanted):
     return out
 
 
-def test_train_runner(cpu_counts_as_chip, tiny_config):
+@pytest.mark.parametrize("in_flight", [1, 8])
+def test_train_runner(cpu_counts_as_chip, tiny_config, in_flight):
     workload = tiny_train_workload()
+    workload["traffic"]["steps_in_flight"] = in_flight
     run = run_cell(workload, tiny_config)
     wanted = [m["name"] for m in harness.cell_metrics(BENCH, "end_to_end", workload["name"])]
     out = check_last_line(run, wanted)
     assert set(out["metrics"]) == {"setup_s", "train_tokens_per_s_chip"}
     assert run["compiles_in_window"] == 0 and len(run["step_s"]) == run["attempted"]
     tokens = run["attempted"] * 4 * 64
-    assert run["end_to_end"]["train_tokens_per_s_chip"] <= tokens / sum(run["step_s"])
+    # step_s runs from one step's end to the next one's, so it adds up to the window
+    assert run["end_to_end"]["train_tokens_per_s_chip"] == pytest.approx(tokens / sum(run["step_s"]))
 
 
 def test_train_runner_zero3_over_four_devices(cpu_counts_as_chip, tiny_config):
@@ -122,7 +113,7 @@ def test_a_wrong_model_is_not_correct(cpu_counts_as_chip, tiny_config, monkeypat
     real = reference.layer
     monkeypatch.setattr(reference, "layer",
                         lambda x, w, cfg: real(x, w, dict(cfg, hidden_act="relu")))
-    monkeypatch.setattr(harness, "load_reference", lambda arch: reference)
+    monkeypatch.setattr(harness, "load_reference", lambda arch, bench_dir=None: reference)
     assert run_cell(tiny_train_workload(), tiny_config)["correct"] is False
 
 

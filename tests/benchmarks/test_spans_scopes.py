@@ -1,43 +1,41 @@
-"""``benchmarks/lib/spans.py``, ``scopes.py`` and the seven readers built on
-them, against the trace ``record_span_trace.py`` took on one v5e chip: two
-steps of a tiny trainer, then one ``generate`` of a tiny v2 engine (a prefill
-of 6 prompts and two decode chains of 4), all under ``bench:window``. What the
+"""``benchmarks/lib/spans.py``, ``scopes.py`` and the six readers built on
+them, against the trace ``record_span_trace.py`` took on one v5e chip (again
+in PR 27, on the one page-major pool of PR 26: no ``page_view``, and a pool of
+``[layers * blocks, block, kvH * hd]`` = ``[128, 16, 256]``): two steps of a
+tiny trainer, then one ``generate`` of a tiny v2 engine (a prefill of 6
+prompts and two decode chains of 4), all under ``bench:window``. What the
 file holds, as looked at by hand, is in ``data/v5e_1chip_spans.txt``."""
 
-import gzip
 import os
-import shutil
 
 import pytest
 
 from benchmarks.lib import harness, kernels, scopes, spans, xplane
+from tests.benchmarks.conftest import unpack_span_trace
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 BENCH = harness.load_benchmark()
 NEW = [m["name"] for m in BENCH["per_layer"] if m["name"].split(".")[0] in {
-    "pool_copy_time_share", "paged_kernel_time_share", "sched_host_ms", "chain_live_rows",
+    "pool_copy_time_share", "sched_host_ms", "chain_live_rows",
     "optimizer_time_share", "lm_head_ce_time_share", "host_data_ms"}]
 
 
 @pytest.fixture(scope="module")
 def sample(tmp_path_factory):
-    path = str(tmp_path_factory.mktemp("spans") / "v5e_1chip_spans.xplane.pb")
-    with gzip.open(os.path.join(DATA, "v5e_1chip_spans.xplane.pb.gz"), "rb") as packed, \
-            open(path, "wb") as raw:
-        shutil.copyfileobj(packed, raw)
-    return path
+    return unpack_span_trace(tmp_path_factory.mktemp("spans"))
 
 
 @pytest.fixture
 def run_of(monkeypatch):
     """A run whose traced file is the one given, as ``run.py`` leaves it for the readers."""
-    def make(path):
+    def make(path, kv_pool_shape=(128, 16, 256)):  # the recorded engine's pool, from the .txt
         monkeypatch.setattr(xplane, "find_xplane", lambda trace_dir: path)
         scopes.report.cache_clear(), spans.report_idle.cache_clear()  # each prints once a trace
         config = harness.load_config("pythia-410m")
         config.update(hidden_size=256, intermediate_size=512, num_hidden_layers=2,
                       num_attention_heads=2, vocab_size=512)
-        return {"workload": {"name": "tiny"}, "config": config, "kv_block_size": 16}
+        return {"workload": {"name": "tiny"}, "config": config, "kv_pool_shape": kv_pool_shape,
+                "architecture": harness.load_architecture(config["architecture"])}
     return make
 
 
@@ -77,8 +75,9 @@ def test_idle_gaps_go_to_the_innermost_span_over_them(sample):
 @pytest.mark.parametrize("op_name,scope", [
     ("jit(chain)/while/body/closed_call/pool_scan/while/body/closed_call/layer/paged_attn/pallas_call",
      "paged_attn"),
+    # a scope the program no longer opens (``page_view`` went with PR 26) is no scope of ours
     ("jit(chain)/while/body/closed_call/pool_scan/while/body/closed_call/layer/page_view/reshape",
-     "page_view"),
+     "layer"),
     ("jit(chain)/while/body/closed_call/pool_scan/while/body/dynamic_update_slice", "pool_scan"),
     ("jit(step)/pool_scan/while/body/closed_call/layer/kv_write/scatter:", "kv_write"),
     ("jit(train_step)/while/body/closed_call/transpose(jvp(CausalLM))/while/body/closed_call/"
@@ -98,8 +97,8 @@ def test_device_seconds_by_scope(sample):
     by_scope = scopes.scope_seconds(sample)
     assert set(by_scope) == {"embed", "layers", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                              "lm_head_ce", "optimizer", "pool_scan", "layer", "kv_write",
-                             "page_view", "paged_attn", "lm_head", scopes.UNSCOPED}
-    assert by_scope["paged_attn"] == pytest.approx(89.1e-6, rel=0.01)
+                             "paged_attn", "lm_head", scopes.UNSCOPED}
+    assert by_scope["paged_attn"] == pytest.approx(143.8e-6, rel=0.01)
     assert by_scope["layers"] == pytest.approx(159.5e-6, rel=0.01)
     # hlo_stats' self times add up to the device's busy time (whole trace against
     # the window's clip: within a few percent)
@@ -118,17 +117,16 @@ def test_kernels_are_instructions_of_their_own_names(sample):
 
 @pytest.mark.parametrize("name", NEW)
 def test_new_reader_on_the_recorded_trace(name, sample, run_of, capsys):
-    assert len(NEW) == 7
+    assert len(NEW) == 6
     run, trace = run_of(sample), xplane.reduce_trace(sample)
     value = harness.load_reader(name)(run, trace)
     want = {
-        "pool_copy_time_share.batch": 23.6,      # 202 us under the scopes + 37 us by shape
-        "paged_kernel_time_share.batch": 100.0 * kernels.paged_seconds(run, trace) / trace.busy_s,
-        "sched_host_ms.batch": 0.1224,           # of two chains: 0.104 and 0.140 ms
+        "pool_copy_time_share.batch": 7.76,      # 72.4 us under kv_write + nothing of the pool's shape
+        "sched_host_ms.batch": 0.1468,           # of two chains: 0.137 and 0.156 ms
         "chain_live_rows.batch": 6.0,
-        "optimizer_time_share.train": 4.22,
-        "lm_head_ce_time_share.train": 3.08,
-        "host_data_ms.train": 0.7778,           # of two steps: 0.849 and 0.706 ms
+        "optimizer_time_share.train": 4.58,
+        "lm_head_ce_time_share.train": 3.35,
+        "host_data_ms.train": 0.9325,           # of two steps: 0.992 and 0.873 ms
     }[name]
     assert value == pytest.approx(want, rel=0.01)
     said = capsys.readouterr().out
@@ -136,6 +134,30 @@ def test_new_reader_on_the_recorded_trace(name, sample, run_of, capsys):
         assert "idle_in_span=serve:dispatch" in said and "share_inside_a_dstpu_span=" in said
     elif not name.startswith("chain_live_rows"):
         assert "scope=(no_scope)" in said and "scope=pool_scan" in said
+
+
+def test_kernels_are_picked_by_name_and_pool_copies_by_the_pool_s_shape(sample, run_of, capsys):
+    """The readers take no shape from a configuration: a kernel is the
+    instruction of its name, the pool's shape is the engine's own."""
+    run, trace = run_of(sample), xplane.reduce_trace(sample)
+    by_name = {(i.program, i.name.split(".")[0]): i.seconds for i in scopes.instructions(sample)
+               if xplane.PALLAS_TARGET in i.text}
+    # the window's clip of the trace against hlo_stats' whole trace: the same events
+    assert kernels.paged_seconds(run, trace) == pytest.approx(by_name["chain", "paged_attn"], rel=1e-3)
+    assert kernels.flash_seconds(run, trace) == pytest.approx(
+        sum(by_name["train_step", k] for k in kernels.FLASH_KERNELS), rel=1e-3)
+    assert harness.load_reader("paged_time_share.batch")(run, trace) == pytest.approx(14.20, rel=0.01)
+    assert harness.load_reader("flash_time_share.train")(run, trace) == pytest.approx(8.40, rel=0.01)
+    # no copy of the whole pool is left since PR 26; given the shape of the two
+    # stacked projections the chain re-lays (copy.44, copy.45: 1.57 + 1.58 us),
+    # the by-shape part finds them, so it would find a copy of the pool
+    capsys.readouterr()
+    pool_copy = harness.load_reader("pool_copy_time_share.batch")
+    assert pool_copy(run, trace) == pytest.approx(7.76, rel=0.01)
+    assert "pool_copy_by_shape_s=0.0 pool_shape=[128,16,256]" in capsys.readouterr().out
+    as_weights = run_of(sample, kv_pool_shape=(2, 256, 2, 128))
+    assert pool_copy(as_weights, trace) == pytest.approx(8.10, rel=0.01)
+    assert pool_copy(run_of(sample, kv_pool_shape=None), trace) is None  # a run without a pool
 
 
 @pytest.mark.parametrize("name", NEW)
