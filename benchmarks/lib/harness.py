@@ -211,9 +211,12 @@ class TraceWindow:
 
 
 def last_line(correct: bool, attempted: int, failed: int, metrics: Dict[str, dict],
-              device: dict, breakdown: Optional[dict] = None) -> str:
+              device: dict, compared: Dict[str, list], breakdown: Optional[dict] = None) -> str:
+    """``compared``: each number that decided ``correct`` as ``[found, limit]``,
+    under a key that comes last, where a record cut to its end still has it."""
     out = {"correct": bool(correct), "attempted": int(attempted), "failed": int(failed),
            "metrics": metrics, "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
+    out["compared"] = compared
     return json.dumps(out)

@@ -13,7 +13,11 @@ tolerances of the configuration's own ``check`` block, warms the cell's own shap
 and measures for ``--seconds``. Human-readable lines come first; the last line
 of stdout is one JSON object: with ``--trace 0`` the cell's end-to-end
 metrics, with ``--trace 1`` its per-layer metrics (``benchmarks/metrics/``),
-the device's busy seconds and a breakdown from the profiler's trace.
+the device's busy seconds and a breakdown from the profiler's trace. Every
+runner returns, beside its readings, ``compared``: each number that decided
+``correct`` as ``name: [found, limit]``, printed last in that line and as the
+last lines of standard error, where the record of a run that is not correct
+keeps it.
 
 It runs on the TPU only: with no TPU, or fewer chips than the cell asks for,
 it exits with a code other than 0 and prints nothing that looks like a result.
@@ -101,7 +105,10 @@ def main(argv=None) -> int:
         metrics = {m["name"]: {"value": float(run["end_to_end"][m["name"]]), "unit": m["unit"]}
                    for m in wanted}
     print(harness.last_line(run["correct"], run["attempted"], run["failed"], metrics,
-                            device, breakdown), flush=True)
+                            device, run["compared"], breakdown), flush=True)
+    # the end of standard error is what the driver keeps of a run that is not correct
+    for name, (found, limit) in run["compared"].items():
+        print(f"compared {name}={found} limit={limit}", file=sys.stderr)
     return 0
 
 

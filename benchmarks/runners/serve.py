@@ -54,15 +54,65 @@ def make_weights(model_cfg, seed):
     return make(jax.random.PRNGKey(seed & 0x7FFFFFFF))
 
 
+class Pinned:
+    """The reference at the program's own expert picks, and the audit of
+    those picks, for an architecture that says it is routed. A rounding that
+    changes which experts a token visits moves the plain comparison by many
+    times bf16's own error (PERF.md, section 2), so the reference is sent
+    where the program went, and the picks are held to the reference's own
+    scores: a router that could not have made them fails by its shortfall."""
+
+    def __init__(self, reference, routing, cfg, weights, shape):
+        import jax
+
+        self.routing, self.weights = routing, weights
+        self.run = jax.jit(lambda w, t, p: (reference.forward(w, cfg, t, p),
+                                            reference.route_shortfall(w, cfg, t, p)))
+        # a position that was not fed keeps experts 0..k-1: the model is causal,
+        # so it cannot reach a compared row, and it is left out of the audit
+        self.blank = np.broadcast_to(np.arange(routing.k, dtype=np.int32),
+                                     tuple(shape) + (routing.layers, routing.k))
+        self.shortfall, self.flips, self.audited = -np.inf, 0, 0
+        self.clear()
+
+    def clear(self):
+        self.picks = self.blank.copy()
+        self.fed = np.zeros(self.blank.shape[:2], bool)
+
+    def record(self, starts, lengths, picks):
+        """``picks[i]``: the experts of the ``lengths[i]`` tokens fed to row i from ``starts[i]`` on."""
+        if len(picks) != len(starts):
+            raise ValueError(f"picks for {len(picks)} rows, {len(starts)} were fed")
+        for i, (start, n) in enumerate(zip(starts, lengths)):
+            self.picks[i, start:start + n] = program.checked_picks(picks[i], n, self.routing)
+            self.fed[i, start:start + n] = True
+
+    def logits(self, tokens):
+        """The pinned reference's logits, the audit taken along the same pass."""
+        import jax.numpy as jnp
+
+        want, shortfall = self.run(self.weights, jnp.asarray(tokens), jnp.asarray(self.picks))
+        shortfall = np.asarray(shortfall)[self.fed]  # [positions fed, routed layers]
+        self.shortfall = max(self.shortfall, float(shortfall.max()))
+        self.flips += int((shortfall > 0).sum())
+        self.audited += shortfall.size
+        return np.asarray(want)
+
+
 def check(engine, reference, architecture, config, seed):
     """Prefill logits and logits of further tokens fed through the cache (the
     ``put`` path), then the tokens ``generate`` picks (the fused prefill and
-    decode-chain programs), each against the reference's full forward."""
+    decode-chain programs), each against the reference's full forward; where
+    the architecture is routed, against the reference at the program's own
+    expert picks, and the picks against the reference's own scores. Returns
+    ``correct`` and every number compared beside its limit."""
     import jax
     import jax.numpy as jnp
 
     logit_tol = program.tolerance(config, "logit_rel_tol")
-    gap_tol = TOKEN_GAP_PER_LOGIT_TOL * logit_tol
+    limits = {"logit_rel_err": logit_tol, "token_gap": TOKEN_GAP_PER_LOGIT_TOL * logit_tol}
+    routing = program.routing(architecture, config)
+    cfg = program.published(config)
     rng = np.random.default_rng([seed & 0xFFFFFFFF, 7])
     bucket = engine.config.chunk_bucket
     # the lengths come from the seed, the shapes do not: a shape of its own for
@@ -70,37 +120,65 @@ def check(engine, reference, architecture, config, seed):
     lens = rng.integers(bucket // 2, bucket - CHECK_DECODE_STEPS, CHECK_PROMPTS)
     total = bucket + CHECK_GENERATED
     seqs = rng.integers(0, config["vocab_size"], (CHECK_PROMPTS, total), dtype=np.int32)
-    ref_forward = jax.jit(lambda w, t: reference.forward(w, program.published(config), t))
     weights = architecture.reference_weights(engine.params)
-    want = np.asarray(ref_forward(weights, jnp.asarray(seqs)))
+    ref_forward = jax.jit(lambda w, t: reference.forward(w, cfg, t))
+
+    def plain(tokens):
+        return np.asarray(ref_forward(weights, jnp.asarray(tokens)))
+
+    pinned = None
+    if routing:
+        limits["route_shortfall"] = program.tolerance(config, "route_shortfall_tol")
+        pinned = Pinned(reference, routing, cfg, weights, seqs.shape)
+    wanted = pinned.logits if pinned else plain
 
     uids = list(range(10_000, 10_000 + CHECK_PROMPTS))
-    errs = []
+    got = []
     for step in range(CHECK_DECODE_STEPS + 1):
-        fed = [seqs[i, :lens[i]] if step == 0 else seqs[i, lens[i] + step - 1:lens[i] + step]
-               for i in range(CHECK_PROMPTS)]
-        got = np.asarray(engine.put(uids, fed), np.float32)
-        ref = np.stack([want[i, lens[i] + step - 1] for i in range(CHECK_PROMPTS)])
-        errs.append(program.relative_error(got, ref))
+        starts = [0 if step == 0 else lens[i] + step - 1 for i in range(CHECK_PROMPTS)]
+        fed = [seqs[i, starts[i]:lens[i] + step] for i in range(CHECK_PROMPTS)]
+        if pinned:
+            logits, picks = routing.put(engine, uids, fed)
+            pinned.record(starts, [len(f) for f in fed], picks)
+        else:
+            logits = engine.put(uids, fed)
+        got.append(np.asarray(logits, np.float32))
     for uid in uids:
         engine.flush(uid)
+    # one pass for the three steps: a row at position p sees positions up to p alone
+    want = wanted(seqs)
+    errs = [program.relative_error(got[step], np.stack([want[i, lens[i] + step - 1]
+                                                        for i in range(CHECK_PROMPTS)]))
+            for step in range(CHECK_DECODE_STEPS + 1)]
 
     prompts = [seqs[i, :lens[i]] for i in range(CHECK_PROMPTS)]
-    outs = engine.generate(prompts, max_new_tokens=CHECK_GENERATED)
+    if pinned:
+        outs, picks = routing.generate(engine, prompts, CHECK_GENERATED)
+        pinned.clear()
+        # the prompt and every token generated but the last, which is fed to nothing
+        pinned.record([0] * CHECK_PROMPTS, [len(p) + len(o) - 1 for p, o in zip(prompts, outs)], picks)
+    else:
+        outs = engine.generate(prompts, max_new_tokens=CHECK_GENERATED)
     full = seqs.copy()
     for i, (p, o) in enumerate(zip(prompts, outs)):
         full[i, len(p):len(p) + len(o)] = o
-    want = np.asarray(ref_forward(weights, jnp.asarray(full)))
+    want = wanted(full)
     worst_gap = 0.0  # in units of the reference row's RMS
     for i, (p, o) in enumerate(zip(prompts, outs)):
         for j, tok in enumerate(o):
             row = want[i, len(p) + j - 1]
             worst_gap = max(worst_gap, float((row.max() - row[tok]) / np.sqrt(np.mean(row ** 2))))
-    ok = bool(max(errs) <= logit_tol and worst_gap <= gap_tol
+    found = {"logit_rel_err": max(errs), "token_gap": worst_gap}
+    said = dict(check_logit_rel_err=errs, tol=logit_tol, generated_token_gap=worst_gap,
+                gap_tol=limits["token_gap"])
+    if pinned:
+        found["route_shortfall"] = pinned.shortfall
+        said.update(check_route_shortfall=pinned.shortfall, shortfall_tol=limits["route_shortfall"],
+                    check_flip_share=pinned.flips / pinned.audited)
+    ok = bool(all(found[k] <= limits[k] for k in limits)
               and all(len(o) == CHECK_GENERATED for o in outs))
-    harness.say(check_logit_rel_err=errs, tol=logit_tol, generated_token_gap=worst_gap,
-                gap_tol=gap_tol, ok=ok)
-    return ok
+    harness.say(**said, ok=ok)
+    return ok, {k: [found[k], limits[k]] for k in limits}
 
 
 def warm(engine, workload, vocab):
@@ -225,7 +303,7 @@ def run(*, workload, config, reference, architecture, seed, seconds, devices, tr
     engine = InferenceEngineV2(model_cfg, params, dict(workload["engine"]), mesh=mesh)
     del params
     phases.done("weights_and_engine")
-    correct = check(engine, reference, architecture, config, seed)
+    correct, compared = check(engine, reference, architecture, config, seed)
     phases.done("check_against_reference")
     warm(engine, workload, vocab)
     phases.done("warm_up")
@@ -286,5 +364,5 @@ def run(*, workload, config, reference, architecture, seed, seconds, devices, tr
         "end_to_end": end_to_end, "requests": rows, "compiles_in_window": in_window,
         "calls": spans.calls if spans else [], "chips": len(devices), "elapsed_s": elapsed,
         "trace_started_s": spans.trace_started_s if spans else None,
-        "kv_pool_shape": tuple(engine.pool.k.shape), "memory": memory,
+        "kv_pool_shape": tuple(engine.pool.k.shape), "memory": memory, "compared": compared,
     }

@@ -139,5 +139,5 @@ def run(*, workload, config, reference, architecture, seed, seconds, devices, tr
         "step_s": step_s, "compiles_in_window": in_window, "chips": chips,
         "traced_steps": TRACED_STEPS[1] - TRACED_STEPS[0],
         "micro_batch": micro, "micro_batches_per_step": sequences // (micro * chips),
-        "seq_len": seq, "memory": memory,
+        "seq_len": seq, "memory": memory, "compared": {"loss_rel_err": [loss_err, loss_tol]},
     }

@@ -7,7 +7,7 @@ import re
 import pytest
 
 from benchmarks.lib import harness
-from tests.benchmarks.conftest import NAME, config_rules
+from tests.benchmarks.conftest import NAME, ROUTED, add_routed_toy, config_rules
 
 REPO = os.path.dirname(harness.BENCH_DIR)
 BENCH = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
@@ -82,6 +82,56 @@ def test_config_rules_on_a_cut_configuration(why, entry_reduced, file_reduced, u
     else:
         with pytest.raises(AssertionError):
             config_rules(entry, held, BENCH)
+
+
+def _without(key):
+    def edit(check):
+        del check[key]
+    return edit
+
+
+def _with(key, value):
+    def edit(check):
+        check[key] = value
+    return edit
+
+
+@pytest.mark.parametrize("why,renamed,edit,error,says", [
+    ("the routed members, both tolerances between their readings and a reference that takes picks pass",
+     None, None, None, None),
+    ("the architecture file hands no picks out of put", "put_with_picks", None, AttributeError,
+     "lacks put_with_picks: a routed model is checked at the program's own expert picks"),
+    ("nor out of the fused prefill and the chain", "generate_with_picks", None, AttributeError,
+     "lacks generate_with_picks: a routed model is checked at the program's own expert picks"),
+    ("the configuration states no tolerance for the audit", None, _without("route_shortfall_tol"), KeyError,
+     "states no check.route_shortfall_tol"),
+    ("an audit of five sigmas guards nothing", None, _with("route_shortfall_tol", 5.0), AssertionError, None),
+    ("a tolerance for the chain's tokens apart is not part of the protocol: it is held as a share",
+     None, _with("token_gap_tol", 2.0), AssertionError, None),
+    ("the readings behind the tolerances are not given", None, _without("readings"), AssertionError,
+     "check.readings.logit_rel_tol has to give sound_max"),
+    ("a tolerance the control would pass", None, _with("logit_rel_tol", 0.04), AssertionError,
+     "check.logit_rel_tol 0.04 does not lie between its readings"),
+    ("a tolerance a sound run would fail", None, _with("route_shortfall_tol", 0.02), AssertionError,
+     "check.route_shortfall_tol 0.02 does not lie between its readings"),
+])
+def test_a_listed_routed_configuration_that_serves_hands_out_its_picks(bench_copy, why, renamed, edit, error,
+                                                                       says):
+    """The rule for every configuration ``BENCHMARK.json`` lists whose
+    architecture file says ``routed_layers(cfg) > 0`` and which has a serving
+    cell; the toy ``mixtral`` of ``test_data_driven.py`` says nothing and is not held to it."""
+    source = open(os.path.join(ROUTED, "architecture.py")).read()
+    if renamed:
+        source = source.replace(f"def {renamed}", f"def not_{renamed}")
+    bench = add_routed_toy(bench_copy, source)
+    held = harness.load_config("routed-toy", bench_copy)
+    if edit:
+        edit(held["check"])
+    if error is None:
+        config_rules(bench["configs"][-1], held, bench, bench_copy)
+    else:
+        with pytest.raises(error, match=says):
+            config_rules(bench["configs"][-1], held, bench, bench_copy)
 
 
 def test_a_configuration_without_a_tolerance_inherits_none():

@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 
 from benchmarks.lib import costs, harness, kernels, peaks, program, xplane
-from tests.benchmarks.conftest import (TINY_CHECK, TINY_MODEL, config_rules, run_cell,
+from tests.benchmarks.conftest import (ROUTED_TOY, TINY_CHECK, TINY_MODEL, add_routed_toy,
+                                       add_to_benchmark, config_rules, routed_stand_in, run_cell,
                                        tiny_serve_workload, tiny_train_workload, unpack_span_trace,
                                        write_json)
 
@@ -22,26 +23,6 @@ NEW_READER = '''
 def read(run, trace):
     return len(run["step_s"]) if trace.n_devices else None
 '''
-
-
-def add_to_benchmark(bench_copy, cell, config, like, metric=None, source="test"):
-    """Entries added to the copy's BENCHMARK.json, none there changed but the
-    lists of cells that the metrics of the cell ``like`` are reported in."""
-    path = os.path.join(os.path.dirname(bench_copy), "BENCHMARK.json")
-    bench = harness.load_json(path)
-    bench["configs"].append({"name": config, "source": source, "reduced": [], "why": "test",
-                             "file": f"benchmarks/configs/{config}.json"})
-    bench["workloads"].append({"name": cell, "config": config, "chips": 1, "why": "test",
-                               "traffic": cell.partition(".")[2]})
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if like in m.get("workloads", []):
-            m["workloads"].append(cell)
-    if metric:
-        bench["per_layer"].append({"name": metric, "unit": "count", "better": "higher",
-                                   "source": "program_counter", "layer": "engine",
-                                   "moves": "train_tokens_per_s_chip", "workloads": [cell]})
-    write_json(path, bench)
-    return bench
 
 
 def test_a_new_cell_config_and_metric_are_found_by_name(cpu_counts_as_chip, bench_copy):
@@ -156,6 +137,42 @@ def test_a_second_architecture_is_served_from_new_files_alone(cpu_counts_as_chip
     added = {name: sub.right_only for name, sub in same.subdirs.items() if sub.right_only}
     assert added == {"architectures": ["mixtral.py"], "reference": ["mixtral.py"],
                      "configs": ["toy-moe.json"], "workloads": ["toy-moe.serve.batch.json"]}
+    assert not any(sub.diff_files or sub.left_only for sub in same.subdirs.values())
+
+
+def test_a_routed_architecture_is_checked_from_new_files_alone(bench_copy, capsys):
+    """What the next ``model_config`` PR does for a routed model that serves:
+    an architecture file WITH the routed members, a reference that takes the
+    picks and audits them, a configuration with both tolerances, a cell, and
+    entries appended to ``BENCHMARK.json``. The runner of the copy, found by
+    name, decides ``correct`` at the picks; the program's half is the stand-in
+    engine (the program cannot run this architecture yet: PERF.md, section 7)."""
+    add_routed_toy(bench_copy)
+    bench = harness.load_benchmark(bench_copy)
+    held = harness.load_workload("routed-toy.serve.batch", bench_copy)
+    cfg = harness.load_config(held["config"], bench_copy)
+    assert cfg == ROUTED_TOY
+    config_rules(bench["configs"][-1], cfg, bench, bench_copy)  # the routed rules among them
+    architecture = harness.load_architecture(cfg["architecture"], bench_copy)
+    reference = harness.load_reference(cfg["architecture"], bench_copy)
+    assert program.routing(architecture, cfg)[:3] == (4, 32, 4)
+    assert [m["name"] for m in harness.cell_metrics(bench, "end_to_end", held["name"])] == [
+        "serve_out_tokens_per_s", "setup_s"]
+
+    seed = 2**31 + 29
+    ok, compared = harness.load_runner(held["kind"], bench_copy).check(
+        routed_stand_in(seed), reference, architecture, cfg, seed)
+    assert ok and compared["logit_rel_err"][0] <= cfg["check"]["logit_rel_tol"]
+    assert 0 < compared["route_shortfall"][0] <= cfg["check"]["route_shortfall_tol"]
+    said = capsys.readouterr().out
+    assert all(word in said for word in ("check_logit_rel_err=", "check_route_shortfall=",
+                                         "check_flip_share=", "ok=True"))
+
+    same = filecmp.dircmp(harness.BENCH_DIR, bench_copy, ignore=["__pycache__"])
+    assert not same.diff_files and not same.left_only and not same.right_only
+    added = {name: sub.right_only for name, sub in same.subdirs.items() if sub.right_only}
+    assert added == {"architectures": ["routed_toy.py"], "reference": ["routed_toy.py"],
+                     "configs": ["routed-toy.json"], "workloads": ["routed-toy.serve.batch.json"]}
     assert not any(sub.diff_files or sub.left_only for sub in same.subdirs.values())
 
 

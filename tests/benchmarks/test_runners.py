@@ -21,9 +21,10 @@ UNITS.update(serve_ttft_p95_ms="ms", serve_tpot_p95_ms="ms")  # wait for the cha
 def check_last_line(run, wanted):
     metrics = {n: {"value": run["end_to_end"][n], "unit": UNITS[n]} for n in wanted}
     line = harness.last_line(run["correct"], run["attempted"], run["failed"], metrics,
-                             harness.device_report(jax.devices()[:1], run["memory"]))
+                             harness.device_report(jax.devices()[:1], run["memory"]),
+                             compared=run["compared"])
     out = json.loads(line)
-    assert set(out) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(out) == ["correct", "attempted", "failed", "metrics", "device", "compared"]
     assert set(out["device"]) >= {"platform", "kind", "count", "memory_peak_bytes"}
     assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
     for m in out["metrics"].values():
@@ -86,7 +87,12 @@ def test_run_py_prints_the_cell_s_metrics_last(cpu_counts_as_chip, tiny_config, 
         REPO, "tests", "benchmarks", "data", "v5e_1chip_sample.xplane.pb"))
     assert run_py.main(["--workload", cell, "--seed", str(2**31 + 5), "--seconds", "1",
                         "--trace", str(trace)]) == 0
-    out = json.loads(capsys.readouterr().out.splitlines()[-1])
+    printed = capsys.readouterr()
+    out = json.loads(printed.out.splitlines()[-1])
+    # every number compared beside its limit: last in the line, and the end of standard error
+    assert list(out)[-1] == "compared" and all(found <= limit for found, limit in out["compared"].values())
+    assert printed.err.splitlines()[-len(out["compared"]):] == [
+        f"compared {name}={found} limit={limit}" for name, (found, limit) in out["compared"].items()]
     group = "per_layer" if trace else "end_to_end"
     listed = {m["name"]: m["unit"] for m in harness.cell_metrics(BENCH, group, cell)}
     assert out["correct"] is True and out["metrics"]
@@ -97,6 +103,40 @@ def test_run_py_prints_the_cell_s_metrics_last(cpu_counts_as_chip, tiny_config, 
         assert len(out["breakdown"]["device_ops"]) <= 10 and out["breakdown"]["idle_gaps"]
     else:
         assert set(out["metrics"]) == set(listed)
+
+
+# what PR 27's ``check`` printed for the tiny gpt_neox engine on the CPU, read from a copy of that
+# commit under this suite's XLA flags (``tests/conftest.py``: backend optimization level 1, which moves
+# the sixth digit; two runs alike), before PR 29 taught ``check`` a routed architecture's picks
+PARENT_DENSE_CHECK = {
+    2**31 + 5: "check_logit_rel_err=[0.006228420417755842, 0.006386684253811836, 0.006014551036059856] "
+               "tol=0.01 generated_token_gap=0.0 gap_tol=0.09 ok=True",
+    41: "check_logit_rel_err=[0.005732002668082714, 0.0060546803288161755, 0.005712674930691719] "
+        "tol=0.01 generated_token_gap=0.016517234966158867 gap_tol=0.09 ok=True",
+}
+
+
+@pytest.mark.parametrize("seed", PARENT_DENSE_CHECK)
+def test_the_dense_check_prints_the_parents_numbers_to_the_last_digit(tiny_config, capsys, seed):
+    """An architecture file that says nothing of routing is checked by the
+    code that always checked it."""
+    import jax.numpy as jnp
+
+    from benchmarks.lib import program
+    from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
+    from deepspeed_tpu.topology.mesh import build_mesh
+
+    serve = harness.load_runner("serve")
+    model_cfg = program.model_config(tiny_config, jnp.bfloat16)
+    mesh = build_mesh(devices=jax.devices()[:1], axis_sizes={"tp": 1, "dp": 1})
+    engine = InferenceEngineV2(model_cfg, serve.make_weights(model_cfg, seed),
+                               dict(tiny_serve_workload("batch")["engine"]), mesh=mesh)
+    architecture = harness.load_architecture("gpt_neox")
+    assert program.routing(architecture, tiny_config) is None
+    ok, compared = serve.check(engine, harness.load_reference("gpt_neox"), architecture, tiny_config, seed)
+    said = [line for line in capsys.readouterr().out.splitlines() if line.startswith("check_")]
+    assert said == [PARENT_DENSE_CHECK[seed]]
+    assert ok and list(compared) == ["logit_rel_err", "token_gap"]  # no audit of a router it has not
 
 
 def test_run_py_refuses_what_is_not_a_cell(capsys):
