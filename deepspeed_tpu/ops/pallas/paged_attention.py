@@ -8,14 +8,38 @@ blocked KV cache) — the kernel the reference's 2.3x-vs-vLLM claim lives in
 Design: the WHOLE KV pool, every layer's pages in one page-major
 ``[pages, bs, kvH*hd]`` array, stays in HBM (``memory_space=ANY``) exactly as
 ``inference/paged.py`` stores it: the kernel takes it as it is, with no view
-or copy made for it. The block table rides scalar prefetch and already points
-into that array (the caller adds the layer's first page), so the kernel
-issues manual DMAs of exactly the pages each sequence owns — no dense gather
-ever materializes. Grid is ``(rows, page_chunks)``; each step copies
-``pages_per_block`` pages (all kv heads of a page in one lane-dense slab)
-into VMEM, runs one online-softmax update per kv head for all query heads in
-its GQA group, and page-chunks past a row's live length are skipped entirely
-(compute AND DMA — the guard wraps the copies).
+or copy made for it. The block table and each row's context length ride
+scalar prefetch, and the table already points into that array (the caller
+adds the layer's first page), so the kernel issues manual DMAs of exactly the
+pages each sequence owns — no dense gather ever materializes.
+
+The walk: the grid is the rows, in order; inside a step a loop runs over the
+row's LIVE page-chunks, ``cdiv(pages of the row, pages a chunk)`` of them, so
+a row of 130 tokens costs two iterations under a table of 128 columns or of
+8,192, and a row with no page costs an empty step that writes zeros. A chunk
+is at most ``pages_per_block`` pages (fewer where the query block leaves less
+VMEM, ``_VMEM_BUDGET``), each page one DMA of a lane-dense ``[bs, kvH*hd]``
+slab with all kv heads. K and V have TWO slots each: before chunk ``i`` is
+waited for, chunk ``i + 1`` is started into the other slot, and at a row's
+last chunk the NEXT row's first chunk (its pages are in SMEM already), so one
+fetch a call is exposed and the rest hide behind compute. Of a partial last
+chunk only the pages the row holds are fetched; the values past the row's
+length (dead slots of its last page, whatever the slot's other pages held
+before) are zeroed in the buffer, because the scores there are masked but
+``0 * NaN`` in ``p @ v`` is not.
+
+The compute has two forms, chosen from the shapes alone. With a sublane tile
+or more of query rows a kv head (``C*G >= 8``: prompts, chunks) each chunk
+runs one online-softmax update per kv head for all query rows of its GQA
+group. With fewer (decode, a few drafts) such a product is mostly padding and
+its bookkeeping is paid ``kvH`` times a chunk; there every (query row, kv
+head) pair becomes one row of a block-diagonal query ``[C*G*kvH, kvH*hd]``,
+so ONE product against the lane-dense chunk gives every head's scores, one
+update serves all heads, and the output is the diagonal blocks of one
+``p @ v``. bf16 products, fp32 accumulation and fp32 statistics in both; a
+head's running max and sum share one ref (the low and the high lanes), since
+each alone pads to 128 lanes and a lane-dense layout costs more to address
+than it saves.
 
 Against the XLA fallback (gather pages to dense then masked attention) this
 removes the gathered-copy write+read and the [rows, tokens] fp32 score
@@ -28,10 +52,11 @@ that same array at ``(page, slot in page)``, in place in the layer scan's
 carry (``inference/paged.py``, scope ``kv_write``).
 
 Quantized KV pools (int8/e4m3 values + per-(slot, head) fp32 scales — see
-``inference/paged.py``): the scale pages DMA alongside the value pages and
-dequantization happens on the VMEM tiles right after the block load, so the
-full-precision pool never materializes anywhere — HBM holds the quantized
-bytes, VMEM holds one dequantized page-chunk at a time.
+``inference/paged.py``): a chunk's scale rows are fetched beside its pages,
+into slots of their own, and dequantization happens on the VMEM tiles right
+after the load (the scales multiply scores and probabilities, never the value
+tiles), so the full-precision pool never materializes anywhere — HBM holds
+the quantized bytes, VMEM holds one dequantized page-chunk at a time.
 """
 
 from __future__ import annotations
@@ -48,8 +73,12 @@ from deepspeed_tpu.utils.compat import tpu_compiler_params
 from deepspeed_tpu.ops.registry import register
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
-_LANES = 8
+_SUBLANES = 8
 DEFAULT_PAGES_PER_BLOCK = 8
+# What the kernel's own buffers may take of Mosaic's 16 MiB of scoped VMEM, by
+# ``flash_decode_paged``'s count; the rest is the compiler's. The (64, 256)
+# prefill of a 16 x 128 model counts 10 MiB with two 8-page slots of K and V.
+_VMEM_BUDGET = 11 << 20
 
 
 def _interpret() -> bool:
@@ -60,99 +89,190 @@ def _cdiv(a: int, b: int) -> int:
     return (a + b - 1) // b
 
 
-def _decode_kernel(bt_ref, ap_ref, *refs, ppcb, alibi=False, quantized=False):
+def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, quantized):
     refs = list(refs)
     q_ref, qpos_ref = refs.pop(0), refs.pop(0)
     slopes_ref = refs.pop(0) if alibi else None
-    (k_hbm, v_hbm) = refs.pop(0), refs.pop(0)
-    ks_ref = vs_ref = None
+    k_hbm, v_hbm = refs.pop(0), refs.pop(0)
+    ks_hbm = vs_hbm = ksbuf = vsbuf = None
     if quantized:
-        ks_ref, vs_ref = refs.pop(0), refs.pop(0)
-    o_ref = refs.pop(0)
-    kbuf, vbuf, acc_ref, m_ref, l_ref, sem_k, sem_v = refs
+        ks_hbm, vs_hbm = refs.pop(0), refs.pop(0)
+    o_ref, kbuf, vbuf = refs.pop(0), refs.pop(0), refs.pop(0)
+    if quantized:
+        ksbuf, vsbuf = refs.pop(0), refs.pop(0)
+    acc_ref, ml_ref, sems, slot_ref = refs
     n = pl.program_id(0)
-    pc = pl.program_id(1)
-    npc = pl.num_programs(1)
-    _, kvH, Cgp, hd = q_ref.shape
-    bs = kbuf.shape[1]
+    N = pl.num_programs(0)
+    D = kvH * hd
     T = ppcb * bs
+    cdt = q_ref.dtype
 
-    @pl.when(pc == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def pages_of(row):  # a row's live pages
+        return _cdiv(ctx_ref[row], bs)
 
-    def _compute():
-        # one DMA per live page, ALL kv heads at once: a page is a contiguous
-        # lane-dense [bs, kvH*hd] slab of the pool as it is stored, so the copy
-        # slices only the leading (untiled) page dim — Mosaic refuses any
-        # slice of the tiled minor dims that is not (8, 128)-aligned, which
-        # a per-head [bs, 1, hd] copy never is
-        copies = []
-        for i in range(ppcb):
-            page = bt_ref[n, pc * ppcb + i]
-            copies.append(pltpu.make_async_copy(k_hbm.at[page], kbuf.at[i], sem_k))
-            copies.append(pltpu.make_async_copy(v_hbm.at[page], vbuf.at[i], sem_v))
-        for c in copies:
-            c.start()
-        for c in copies:
-            c.wait()
+    def chunk_dma(row, chunk, slot, start):
+        """Start, or wait for, the copies of one page-chunk of ``row`` into
+        ``slot``: one DMA per LIVE page (all kv heads at once: a page is a
+        contiguous lane-dense [bs, kvH*hd] slab of the pool as it is stored, so
+        the copy slices only the leading, untiled page dim), and for a
+        quantized pool the chunk's [kvH, T] scale rows."""
+        live = jnp.clip(pages_of(row) - chunk * ppcb, 0, ppcb)
 
-        cdt = q_ref.dtype
-        k_all, v_all = kbuf[...], vbuf[...]  # [ppcb, bs, kvH*hd]
+        def page(i):
+            p = bt_ref[row, chunk * ppcb + i]
+            for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                c = pltpu.make_async_copy(hbm.at[p], buf.at[slot, i], sems.at[slot, which])
+                c.start() if start else c.wait()
+
+        pl.loop(0, live)(page)
+        if quantized:
+            for hbm, buf, which in ((ks_hbm, ksbuf, 2), (vs_hbm, vsbuf, 3)):
+                c = pltpu.make_async_copy(hbm.at[row, chunk], buf.at[slot], sems.at[slot, which])
+                pl.when(live > 0)(c.start if start else c.wait)
+
+    ctx = ctx_ref[n]
+    nc = _cdiv(pages_of(n), ppcb)  # the row's page-chunks: the walk's trip count
+    slot0 = jnp.where(n == 0, 0, slot_ref[0])  # where the row's first chunk was sent
+
+    @pl.when(n == 0)
+    def _first():
+        chunk_dma(0, 0, 0, start=True)
+
+    # A head's running max and sum share one ref (a [rows, 1] column pads to
+    # 128 lanes whatever it holds, so two refs are twice the VMEM): the max in
+    # the low half of its 8 lanes, the sum, which is never negative, in the
+    # high half, each read back by a masked max over the lanes.
+    low = jax.lax.broadcasted_iota(jnp.int32, ml_ref.shape[1:], 1) < _SUBLANES // 2
+
+    def stats(g):
+        ml = ml_ref[g]
+        return (jnp.max(jnp.where(low, ml, _NEG_INF), axis=-1, keepdims=True),
+                jnp.max(jnp.where(low, 0.0, ml), axis=-1, keepdims=True))
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    ml_ref[...] = jnp.broadcast_to(jnp.where(low, _NEG_INF, 0.0), ml_ref.shape)
+
+    if dense:
+        # every (query row, kv head) pair is one row of a block-diagonal query
+        # [W, kvH*hd]: row w = r*kvH + kh holds query row r's head kh in lanes
+        # [kh*hd, (kh+1)*hd) and zeros elsewhere, so ONE product against the
+        # lane-dense chunk gives every head's scores
+        W = acc_ref.shape[1]
+        row = jax.lax.broadcasted_iota(jnp.int32, (W, D), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (W, D), 1)
+        qbd = jnp.zeros((W, D), jnp.float32)  # a select on 32-bit lanes; narrowed once
+        diag = []
+        for r in range(Cg):
+            kh = row - r * kvH
+            on = (kh >= 0) & (kh < kvH) & (lane >= kh * hd) & (lane < (kh + 1) * hd)
+            diag.append(on)
+            q_r = q_ref[0, r:r + 1, :].astype(jnp.float32)
+            qbd = jnp.where(on, jnp.broadcast_to(q_r, (W, D)), qbd)
+        qbd = qbd.astype(cdt)
+
+    def update(g, q, k, v, visible, j, scales, slopes):
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [rows, T]
+        if quantized:
+            # fused dequant: int8/e4m3 values are exact in the compute dtype,
+            # so the per-slot scale factors out of the head-dim contraction —
+            # one row multiply on the scores
+            s = s * scales[0]
+        if alibi:
+            # bloom convention slope * key-position (slot index == position)
+            s = s + slopes * j.astype(jnp.float32)
+        s = jnp.where(visible, s, _NEG_INF)
+
+        m_prev, l_prev = stats(g)  # [rows, 1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_safe = jnp.where(m_cur == _NEG_INF, 0.0, m_cur)
+        p = jnp.exp(s - m_safe)
+        alpha = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_safe))
+        ml_ref[g] = jnp.where(low, m_cur, alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True))
+        if quantized:
+            # value scales fold into p; a dead slot's scale is whatever its
+            # page holds, and 0 * NaN is NaN
+            p = jnp.where(visible, p * scales[1], 0.0)
+        acc_ref[g] = acc_ref[g] * alpha + jax.lax.dot_general(
+            p.astype(cdt), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+
+    def compute(c, slot):
+        # What lies past the row's length is masked out of the scores below,
+        # but 0 * NaN is NaN in p @ v. Only a row's last chunk holds any: the
+        # dead slots of its last page, and pages of the chunk that were not
+        # fetched (whatever the slot held before). Zero the values there.
+        @pl.when(c == nc - 1)
+        def _():
+            @pl.loop((ctx - c * T) // bs, ppcb)
+            def _(i):
+                pos = c * T + i * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, D), 0)
+                vbuf[slot, i] = jnp.where(pos < ctx, vbuf[slot, i], jnp.zeros((), vbuf.dtype))
+
+        k_all, v_all = kbuf[slot], vbuf[slot]  # [ppcb, bs, kvH*hd]
         if quantized:
             # widen before the page-merge reshape: a 1-byte tile is 32 rows,
             # a 16-slot page is not, fp32's 8-row tile divides any page
             k_all, v_all = k_all.astype(jnp.float32), v_all.astype(jnp.float32)
-        k_all = k_all.reshape(T, kvH * hd).astype(cdt)
-        v_all = v_all.reshape(T, kvH * hd).astype(cdt)
+        k_all = k_all.reshape(T, D).astype(cdt)
+        v_all = v_all.reshape(T, D).astype(cdt)
 
         # causality over SEQUENCE positions: token j of this page-chunk is at
-        # global position pc*T + j; visible iff <= the query's position
-        j = pc * T + jax.lax.broadcasted_iota(jnp.int32, (Cgp, T), 1)
-        visible = j <= qpos_ref[0]  # qpos: [Cgp, 1] column
-        for kh in range(kvH):
-            q = q_ref[0, kh]  # [Cgp, hd] (pre-scaled)
-            k = k_all[:, kh * hd:(kh + 1) * hd]  # [T, hd]
-            v = v_all[:, kh * hd:(kh + 1) * hd]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )  # [Cgp, T]
-            if quantized:
-                # fused dequant: int8/e4m3 values are exact in the compute
-                # dtype, so the per-slot scale factors out of the head-dim
-                # contraction — one [1, T] row multiply on the scores
-                s = s * ks_ref[0, pl.ds(kh, 1), :]
-            if alibi:
-                # bloom convention slope * key-position (slot index ==
-                # position); slopes arrive row-aligned with the (c, g) layout
-                s = s + slopes_ref[kh] * j.astype(jnp.float32)
-            s = jnp.where(visible, s, _NEG_INF)
+        # global position c*T + j; visible iff <= the query row's position
+        rows = qpos_ref.shape[1]
+        j = c * T + jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
+        visible = j <= qpos_ref[0]  # qpos: [rows, 1] column
+        for g in range(acc_ref.shape[0]):
+            if dense:
+                # row r*kvH + kh takes head kh's scales
+                pad = [jnp.zeros((W - Cg * kvH, T), jnp.float32)] * (W > Cg * kvH)
+                q, lanes = qbd, slice(None)
+                tile = lambda b: jnp.concatenate([b[slot]] * Cg + pad)  # noqa: E731
+            else:
+                q, lanes = q_ref[0, g], slice(g * hd, (g + 1) * hd)  # [Cgp, hd] (pre-scaled)
+                tile = lambda b: b[slot, pl.ds(g, 1), :]  # noqa: E731
+            update(g, q, k_all[:, lanes], v_all[:, lanes], visible, j,
+                   [tile(b) for b in (ksbuf, vsbuf)] if quantized else None,
+                   slopes_ref[g] if alibi else None)
 
-            m_prev = jnp.max(m_ref[kh], axis=-1, keepdims=True)
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            m_safe = jnp.where(m_cur == _NEG_INF, 0.0, m_cur)
-            p = jnp.exp(s - m_safe)
-            alpha = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_safe))
-            l_prev = jnp.max(l_ref[kh], axis=-1, keepdims=True)
-            l_ref[kh] = jnp.broadcast_to(
-                alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape[1:])
-            m_ref[kh] = jnp.broadcast_to(m_cur, m_ref.shape[1:])
-            if quantized:
-                p = p * vs_ref[0, pl.ds(kh, 1), :]  # value scales fold into p
-            acc_ref[kh] = acc_ref[kh] * alpha + jax.lax.dot_general(
-                p.astype(cdt), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
+    @pl.loop(0, nc)
+    def _walk(c):
+        slot = (slot0 + c) % 2
+        # the next chunk to fetch: this row's, or at its last chunk the next
+        # row's first (its pages are in SMEM already)
+        more = c + 1 < nc
+        nrow = jnp.where(more, n, n + 1)
 
-    # skip page-chunks entirely beyond the row's live pages (guard wraps the
-    # DMAs too — dead pages cost no bandwidth)
-    pl.when(pc * ppcb < ap_ref[n])(_compute)
+        @pl.when(nrow < N)
+        def _():
+            chunk_dma(nrow, jnp.where(more, c + 1, 0), 1 - slot, start=True)
 
-    @pl.when(pc == npc - 1)
-    def _finalize():
-        l = jnp.max(l_ref[:], axis=-1, keepdims=True)
-        o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        chunk_dma(n, c, slot, start=False)
+        compute(c, slot)
+
+    # a row with no page fetches nothing; it still owes the next row its
+    # first chunk
+    @pl.when((nc == 0) & (n + 1 < N))
+    def _():
+        chunk_dma(n + 1, 0, slot0, start=True)
+
+    slot_ref[0] = (slot0 + nc) % 2
+
+    def normalised(g):
+        l = stats(g)[1]
+        return acc_ref[g] / jnp.where(l == 0.0, 1.0, l)
+
+    if dense:
+        # row w's output is its own head's lanes of out[w]: keep the diagonal
+        # blocks, and fold the kv heads' rows of query row r into one row
+        out = normalised(0)
+        for r in range(Cg):
+            o_ref[0, r:r + 1, :] = jnp.sum(
+                jnp.where(diag[r], out, 0.0), axis=0, keepdims=True).astype(o_ref.dtype)
+    else:
+        for g in range(kvH):
+            o_ref[0, g] = normalised(g).astype(o_ref.dtype)
 
 
 @register("paged_attention", "pallas")
@@ -170,95 +290,123 @@ def flash_decode_paged(
     v_scale: jax.Array = None,
 ) -> jax.Array:
     N, C, H, hd = q.shape
-    kvH = pool_k.shape[2] // hd
+    D = pool_k.shape[2]
+    kvH = D // hd
     G = H // kvH
     P = block_tables.shape[1]
     bs = block_size
-    ppcb = min(pages_per_block, P)
-    Pp = _cdiv(P, ppcb) * ppcb
-    if Pp != P:
-        block_tables = jnp.pad(block_tables, ((0, 0), (0, Pp - P)))
-    npc = Pp // ppcb
-    T = ppcb * bs
-
     Cg = C * G
-    Cgp = _cdiv(Cg, _LANES) * _LANES
+    alibi = alibi_slopes is not None
+    quantized = k_scale is not None
 
-    # [N, kvH, Cg, hd] query layout; rows are (c, g) pairs, padded to sublanes
+    # The compute's form, from the shapes alone (module docstring): one
+    # block-diagonal query for all heads where a head has under a sublane tile
+    # of query rows and the (row, head) pairs fit one 128-row pass.
+    dense = Cg < _SUBLANES and Cg * kvH <= 128
     scale = jnp.asarray(hd ** -0.5, q.dtype)
-    q5 = (q * scale).reshape(N, C, kvH, G, hd).transpose(0, 2, 1, 3, 4).reshape(N, kvH, Cg, hd)
+    qg = (q * scale).reshape(N, C, kvH, G, hd)
     qpos_rows = jnp.broadcast_to(q_positions[:, :, None], (N, C, G)).reshape(N, Cg)
-    if Cgp != Cg:
-        q5 = jnp.pad(q5, ((0, 0), (0, 0), (0, Cgp - Cg), (0, 0)))
-        # padded rows see nothing (position -1 masks every token)
-        qpos_rows = jnp.pad(qpos_rows, ((0, 0), (0, Cgp - Cg)), constant_values=-1)
+    srows = None
+    if alibi:
+        # row-aligned slopes: row (c, g) of kv head kh uses slope[kh*G + g]
+        srows = jnp.broadcast_to(
+            alibi_slopes.astype(jnp.float32).reshape(kvH, 1, G), (kvH, C, G)).reshape(kvH, Cg)
+    if dense:
+        # [N, Cg, kvH*hd]: rows are (c, g) pairs, lanes (kv head, dim): for
+        # G == 1 the query as it comes
+        rows = _cdiv(Cg * kvH, _SUBLANES) * _SUBLANES  # (r, kh) pairs, padded to sublanes
+        q_op = qg.transpose(0, 1, 3, 2, 4).reshape(N, Cg, D)
+        q_block, heads = (1, Cg, D), 1
+        qpos_rows = jnp.repeat(qpos_rows, kvH, axis=1)  # row r*kvH + kh
+        if alibi:
+            srows = srows.T.reshape(1, Cg * kvH)
+    else:
+        # [N, kvH, Cg, hd]: rows are (c, g) pairs, padded to sublanes
+        rows = _cdiv(Cg, _SUBLANES) * _SUBLANES
+        q_op = qg.transpose(0, 2, 1, 3, 4).reshape(N, kvH, Cg, hd)
+        q_op = jnp.pad(q_op, ((0, 0), (0, 0), (0, rows - Cg), (0, 0)))
+        q_block, heads = (1, kvH, rows, hd), kvH
+    pad = rows - qpos_rows.shape[1]
+    # padded rows see nothing (position -1 masks every token)
+    qpos_rows = jnp.pad(qpos_rows, ((0, 0), (0, pad)), constant_values=-1)
+    zeros = (0,) * (len(q_block) - 1)
 
-    # live pages per row: positions are ascending within the live prefix
+    # a row's context, in tokens: positions are ascending within the live prefix
     if new_lens is None:
         max_pos = jnp.max(q_positions, axis=1)
     else:
         last = jnp.maximum(new_lens - 1, 0)
         max_pos = jnp.take_along_axis(q_positions, last[:, None], axis=1)[:, 0]
-    active_pages = (max_pos + 1 + bs - 1) // bs  # [N]
+    ctx_lens = (max_pos + 1).astype(jnp.int32)  # [N]
 
-    alibi = alibi_slopes is not None
-    operands = [q5, qpos_rows[:, :, None]]
+    # The page-chunk: at most ``pages_per_block`` pages, fewer where the query
+    # side (its block and the output's, double-buffered by the pipeline; the
+    # fp32 accumulator; the statistics, which pad to 128 lanes) leaves less of
+    # the budget for the two slots of K and of V.
+    query_side = (4 * q_op[0].size * q.dtype.itemsize  # blocks of q and out, each twice
+                  + heads * rows * (D // heads) * 4  # the fp32 accumulator
+                  + heads * rows * 128 * 4)  # the statistics, padded to 128 lanes
+    page_bytes = bs * D * pool_k.dtype.itemsize
+    ppcb = max(1, min(pages_per_block, P))
+    while ppcb > 1 and query_side + 4 * ppcb * page_bytes > _VMEM_BUDGET:
+        ppcb //= 2
+    T = ppcb * bs
+
+    operands = [q_op, qpos_rows[:, :, None]]
     in_specs = [
-        pl.BlockSpec((1, kvH, Cgp, hd), lambda n, pc, bt, ap: (n, 0, 0, 0)),
-        # per-row scalars ride as [.., Cgp, 1] COLUMNS: a (1, Cgp) row block
-        # breaks Mosaic's (8, 128) block rule; (Cgp, 1) has Cgp % 8 == 0 and
+        pl.BlockSpec(q_block, lambda n, bt, cl: (n,) + zeros),
+        # per-row scalars ride as [.., rows, 1] COLUMNS: a (1, rows) row block
+        # breaks Mosaic's (8, 128) block rule; (rows, 1) has rows % 8 == 0 and
         # a full last dim, and is already the broadcast shape the mask needs
-        pl.BlockSpec((1, Cgp, 1), lambda n, pc, bt, ap: (n, 0, 0)),
+        pl.BlockSpec((1, rows, 1), lambda n, bt, cl: (n, 0, 0)),
     ]
     if alibi:
-        # row-aligned slopes: row (c, g) of kv head kh uses slope[kh*G + g]
-        srows = jnp.broadcast_to(
-            alibi_slopes.astype(jnp.float32).reshape(kvH, 1, G), (kvH, C, G)
-        ).reshape(kvH, Cg)
-        if Cgp != Cg:
-            srows = jnp.pad(srows, ((0, 0), (0, Cgp - Cg)))
+        srows = jnp.pad(srows, ((0, 0), (0, rows - srows.shape[1])))
         operands.append(srows[:, :, None])
-        in_specs.append(pl.BlockSpec((kvH, Cgp, 1), lambda n, pc, bt, ap: (0, 0, 0)))
+        in_specs.append(pl.BlockSpec((heads, rows, 1), lambda n, bt, cl: (0, 0, 0)))
     operands += [pool_k, pool_v]
-    in_specs += [
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    scratch = [
+        pltpu.VMEM((2, ppcb, bs, D), pool_k.dtype),  # two slots: one computes, one fills
+        pltpu.VMEM((2, ppcb, bs, D), pool_v.dtype),
     ]
-    quantized = k_scale is not None
     if quantized:
         # scales are 1/hd of the pool: gather the rows' pages in XLA into
-        # [N, kvH, Pp*bs] ROWS (slot index == position) and let the pipeline
-        # fetch the page-chunk's [kvH, T] block — the kernel multiplies the
-        # scores / probabilities by them, never the value tiles
+        # [N, chunks, kvH, T] ROWS (slot index == position); the kernel fetches
+        # a chunk's [kvH, T] beside its pages and multiplies the scores /
+        # probabilities by them, never the value tiles
+        npc = _cdiv(P, ppcb)
+        bt_pad = jnp.pad(block_tables, ((0, 0), (0, npc * ppcb - P)))
         for sc in (k_scale, v_scale):
-            operands.append(sc[block_tables].reshape(N, Pp * bs, kvH).transpose(0, 2, 1))
-            in_specs.append(pl.BlockSpec((1, kvH, T), lambda n, pc, bt, ap: (n, 0, pc)))
+            operands.append(sc[bt_pad].reshape(N, npc, T, kvH).transpose(0, 1, 3, 2))
+            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+            scratch.append(pltpu.VMEM((2, kvH, T), jnp.float32))
 
-    kernel = functools.partial(_decode_kernel, ppcb=ppcb, alibi=alibi, quantized=quantized)
+    kernel = functools.partial(_decode_kernel, ppcb=ppcb, bs=bs, kvH=kvH, hd=hd, Cg=Cg,
+                               dense=dense, alibi=alibi, quantized=quantized)
     out = pl.pallas_call(
         kernel,
         name="paged_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # block_tables, active_pages
-            grid=(N, npc),
+            num_scalar_prefetch=2,  # block_tables, ctx_lens
+            grid=(N,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, kvH, Cgp, hd), lambda n, pc, bt, ap: (n, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((ppcb, bs, kvH * hd), pool_k.dtype),
-                pltpu.VMEM((ppcb, bs, kvH * hd), pool_v.dtype),
-                pltpu.VMEM((kvH, Cgp, hd), jnp.float32),
-                pltpu.VMEM((kvH, Cgp, _LANES), jnp.float32),
-                pltpu.VMEM((kvH, Cgp, _LANES), jnp.float32),
-                pltpu.SemaphoreType.DMA,
-                pltpu.SemaphoreType.DMA,
+            out_specs=pl.BlockSpec(q_block, lambda n, bt, cl: (n,) + zeros),
+            scratch_shapes=scratch + [
+                pltpu.VMEM((heads, rows, D // heads), jnp.float32),
+                pltpu.VMEM((heads, rows, _SUBLANES), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)),
+                pltpu.SMEM((1,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((N, kvH, Cgp, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
+        out_shape=jax.ShapeDtypeStruct(q_op.shape, q.dtype),
+        # rows in order: each starts the next one's first fetch
+        compiler_params=tpu_compiler_params(dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
-    )(block_tables, active_pages, *operands)
+    )(block_tables, ctx_lens, *operands)
 
-    out = out[:, :, :Cg].reshape(N, kvH, C, G, hd).transpose(0, 2, 1, 3, 4)
+    if dense:
+        out = out.reshape(N, C, G, kvH, hd).transpose(0, 1, 3, 2, 4)
+    else:
+        out = out[:, :, :Cg].reshape(N, kvH, C, G, hd).transpose(0, 2, 1, 3, 4)
     return out.reshape(N, C, H, hd)

@@ -104,17 +104,24 @@ def _paged_shapes(sh, N, C, kv_quant, H, kvH, hd, pages=64, bs=16, num_blocks=51
     return shapes, bs
 
 
-@pytest.mark.parametrize("C,kv_quant,H,kvH,hd", [
-    (1, False, 12, 12, 64),    # GPT-2 decode, bf16 pool
-    (1, True, 12, 12, 64),     # GPT-2 decode, int8 pool
-    (128, False, 12, 12, 64),  # GPT-2 chunked prefill
-    (1, True, 32, 8, 128),     # GQA at hd=128, int8 pool
-], ids=["decode-bf16", "decode-int8", "chunk128-bf16", "gqa-hd128-int8"])
-def test_paged_attention_compiles(one_chip, monkeypatch, C, kv_quant, H, kvH, hd):
+@pytest.mark.parametrize("N,C,kv_quant,H,kvH,hd,pages", [
+    (8, 1, False, 12, 12, 64, 64),    # GPT-2 decode, bf16 pool
+    (8, 1, True, 12, 12, 64, 64),     # GPT-2 decode, int8 pool
+    (8, 128, False, 12, 12, 64, 64),  # GPT-2 chunked prefill
+    (8, 1, True, 32, 8, 128, 64),     # GQA at hd=128, int8 pool
+    # pythia-1.4b.serve.batch's own shapes (ISSUE 30): the decode chain's call
+    # under the cell's table of 2048 / 16 pages, and its one prefill program,
+    # whose query block leaves the K/V slots the least VMEM
+    (64, 1, False, 16, 16, 128, 128),
+    (64, 256, False, 16, 16, 128, 128),
+    (8, 5, False, 16, 16, 128, 128),  # a token and four drafts
+], ids=["decode-bf16", "decode-int8", "chunk128-bf16", "gqa-hd128-int8",
+        "cell-decode-64x1", "cell-prefill-64x256", "drafts-k4"])
+def test_paged_attention_compiles(one_chip, monkeypatch, N, C, kv_quant, H, kvH, hd, pages):
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
     monkeypatch.setattr(pa, "_interpret", lambda: False)
-    shapes, bs = _paged_shapes(one_chip, 8, C, kv_quant, H, kvH, hd)
+    shapes, bs = _paged_shapes(one_chip, N, C, kv_quant, H, kvH, hd, pages=pages)
 
     def fn(q, pk, pv, bt, qpos, lens, *scales):
         kw = dict(zip(("k_scale", "v_scale"), scales))

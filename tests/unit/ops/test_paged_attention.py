@@ -138,3 +138,123 @@ def test_paged_pallas_alibi_matches_xla(kvH, ppcb):
     got = pallas(q, pk, pv, bt, pos, bs, new_lens=lens, alibi_slopes=slopes,
                  pages_per_block=ppcb)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The walk over a row's live pages (ISSUE 30). Everything no row may read is
+# NaN: every page no row owns (the table's dead entries point at one), and the
+# dead slots of every row's last page. The XLA fallback gathers those and is
+# not proof against them (0 * NaN in p @ v), so the plain reference, which
+# reads live positions only, is what the kernel is held to.
+
+
+def _planted(ends, C=1, H=8, kvH=8, hd=16, bs=16, layer=1, wide=1, quant=None, seed=0):
+    """Rows that end at positions ``ends`` (-1: a row with no page), their
+    pages scattered over ``layer``'s share of the whole pool, the table
+    ``wide`` times as wide as the longest row needs. Returns the kernel's
+    arguments, its keywords, and the pool in floats for the plain reference."""
+    from deepspeed_tpu.inference.paged import _kv_block_quant
+
+    rng = np.random.default_rng(seed)
+    N, D = len(ends), kvH * hd
+    ends = np.asarray(ends)
+    need = np.maximum(ends, 0) // bs + 1
+    P = int(need.max()) * wide
+    free = rng.permutation(PAGES) + layer * PAGES
+    dead, free = free[0], free[1:]
+    bt = np.full((N, P), dead, np.int32)
+    live = np.zeros((LAYERS * PAGES, bs), bool)
+    for n, e in enumerate(ends):
+        bt[n, :need[n]], free = free[:need[n]], free[need[n]:]
+        js = np.arange(e + 1)
+        live[bt[n, js // bs], js % bs] = True
+    q = jnp.asarray(rng.standard_normal((N, C, H, hd)), jnp.float32)
+    pos = np.stack([np.arange(C) + e - C + 1 for e in ends]).astype(np.int32)
+    pos = np.where(ends[:, None] < 0, -1, pos)
+    kw, pools, floats = {"new_lens": jnp.full((N,), C, jnp.int32)}, [], []
+    for name in ("k_scale", "v_scale"):
+        x = jnp.asarray(rng.standard_normal((LAYERS * PAGES * bs, kvH, hd)), jnp.float32)
+        if quant is None:
+            values = np.asarray(x).reshape(-1, bs, D)
+            floats.append(values)
+            pools.append(jnp.asarray(np.where(live[:, :, None], values, np.nan)))
+            continue
+        vq, sc = _kv_block_quant(x, quant)
+        vq, sc = np.asarray(vq.astype(jnp.float32)), np.asarray(sc)
+        floats.append((vq.reshape(-1, kvH, hd) * sc[:, :, None]).reshape(-1, bs, D))
+        # an int8 cannot be NaN: its dead slots hold the largest value there is
+        vq = np.where(live.reshape(-1, 1), vq, np.nan if quant == "fp8" else 127.0)
+        pools.append(jnp.asarray(vq.reshape(-1, bs, D)).astype(
+            jnp.float8_e4m3fn if quant == "fp8" else jnp.int8))
+        kw[name] = jnp.asarray(np.where(live.reshape(-1, 1), sc, np.nan).reshape(-1, bs * kvH))
+    return (q, *pools, jnp.asarray(bt), jnp.asarray(pos), bs), kw, floats
+
+
+def _held_to_plain_reference(args, kw, floats, slopes=None, **more):
+    q, _, _, bt, pos, bs = args
+    got = np.asarray(dispatch("paged_attention", "pallas")(*args, alibi_slopes=slopes, **kw, **more))
+    rows = np.asarray(pos)[:, -1] >= 0
+    assert not got[~rows].any()  # a row with no page writes zeros
+    want = _plain_reference(q[rows], *floats, bt[rows], pos[rows], bs, slopes)
+    np.testing.assert_allclose(got[rows], want, rtol=2e-5, atol=2e-5)
+    return got
+
+
+@pytest.mark.parametrize("ppcb", [1, 2, 8])
+def test_lengths_at_every_edge(ppcb):
+    """No page, one token, exactly a page, one over, exactly a chunk, one
+    over, two chunks, one over: side by side, so each row's first fetch is
+    started by the row before it."""
+    bs, T = 16, 16 * ppcb
+    ends = [-1, 0, bs - 1, bs, T - 1, T, 2 * T - 1, 2 * T, -1, 1]
+    _held_to_plain_reference(*_planted(ends), pages_per_block=ppcb)
+
+
+@pytest.mark.parametrize("C,H,kvH,alibi,quant", [
+    (1, 8, 8, False, None), (1, 8, 2, False, None), (4, 8, 8, False, None),
+    (3, 8, 2, False, None), (32, 8, 8, False, None), (1, 8, 8, True, None),
+    (1, 8, 2, True, None), (4, 8, 8, True, None), (1, 8, 8, False, "int8"),
+    (1, 8, 2, False, "fp8"), (4, 8, 4, False, "int8"), (16, 8, 2, False, "fp8"),
+], ids=["decode-mha", "decode-gqa", "drafts-mha", "drafts-gqa", "chunk-mha", "decode-mha-alibi",
+        "decode-gqa-alibi", "drafts-mha-alibi", "decode-mha-int8", "decode-gqa-e4m3",
+        "drafts-gqa-int8", "chunk-gqa-e4m3"])
+def test_rows_of_very_different_lengths_side_by_side(C, H, kvH, alibi, quant):
+    """Both forms of the compute (a product a kv head; one block-diagonal
+    query for every head, where a head has fewer than 8 query rows), with
+    scales and ALiBi, at a nonzero layer offset, under a table twice as wide
+    as the longest row."""
+    from deepspeed_tpu.models.transformer import alibi_slopes
+
+    ends = [C - 1, 250, C + 4, 129, 31]
+    slopes = alibi_slopes(H) if alibi else None
+    args, kw, floats = _planted(ends, C=C, H=H, kvH=kvH, layer=2, wide=2, quant=quant)
+    _held_to_plain_reference(args, kw, floats, slopes)
+
+
+@pytest.mark.parametrize("C", [1, 4, 16])
+def test_a_rows_output_does_not_depend_on_the_tables_width(C):
+    ends = [C + 40, 200, C - 1]
+    narrow = _held_to_plain_reference(*_planted(ends, C=C, wide=1))
+    wide = _held_to_plain_reference(*_planted(ends, C=C, wide=8))
+    np.testing.assert_array_equal(narrow, wide)
+
+
+@pytest.mark.parametrize("C,kvH,quant", [(1, 8, None), (16, 2, None), (1, 2, "fp8")],
+                         ids=["decode", "chunk", "decode-e4m3"])
+def test_under_the_chips_own_rules_for_memory_and_dma(monkeypatch, C, kvH, quant):
+    """Pallas' TPU interpreter, not the plain one: scratch memory starts as
+    NaN (the plain one zeroes it), a DMA lands when it is waited for and not
+    when it is started, and a read of a buffer that a copy still in flight
+    writes is a race. So a slot read before its wait, or a page of a slot that
+    was never fetched, shows."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    import deepspeed_tpu.ops.pallas.paged_attention as pa
+
+    monkeypatch.setattr(pa, "_interpret", lambda: pltpu.InterpretParams(
+        detect_races=True, dma_execution_mode="on_wait", uninitialized_memory="nan"))
+    ends = [C - 1, 250, -1, C + 4, 129]
+    _held_to_plain_reference(*_planted(ends, C=C, kvH=kvH, layer=2, wide=2, quant=quant))
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as interpreter
+
+    assert not interpreter.races.races_found
