@@ -35,7 +35,7 @@ import time
 
 import numpy as np
 
-# GPT-2-125M — the repo's flagship dims (bench.py GPT2_HEADLINE_DIMS)
+# GPT-2-125M — the repo's flagship dims
 GPT2_DIMS = dict(
     vocab_size=50304, hidden_size=768, intermediate_size=3072,
     num_layers=12, num_heads=12, max_seq_len=1024,

@@ -263,35 +263,6 @@ def run_sweep(
     return rows
 
 
-def _emit_perf_ledger(rows: List[Dict]) -> None:
-    """Append the sweep's decision rows to the unified perf ledger, suite
-    ``coll-sweep`` (ISSUE 16): one latency + one busbw row per measured
-    (op, world, size, algorithm, codec) point. The ledger row's ``backend``
-    stays the ACCELERATOR (cpu / tpu-v5e — gate isolation is per chip);
-    the hop backend the row was measured with (ppermute / pallas) rides
-    inside the metric path. Best-effort: a read-only ledger dir must not
-    fail the sweep."""
-    try:
-        from deepspeed_tpu.telemetry.perfledger import PerfLedger, make_row
-
-        out = []
-        for r in rows:
-            stem = (f"{r['op']}/{r['algorithm']}/{r['codec']}/"
-                    f"{r['backend']}/w{r['world']}/mb{r['size_mb']:g}")
-            samples = int(r.get("samples", 1))
-            out.append(make_row("coll-sweep", f"{stem}/latency_ms",
-                                r["latency_ms"], "ms", direction="lower",
-                                samples=samples))
-            out.append(make_row("coll-sweep", f"{stem}/busbw_gbps",
-                                r["busbw_gbps"], "GB/s", direction="higher",
-                                samples=samples))
-        PerfLedger().append(out)
-    except Exception as e:  # noqa: BLE001 — evidence plane, not the sweep
-        from deepspeed_tpu.utils.logging import logger
-
-        logger.warning(f"collectives sweep: perf-ledger append skipped: {e}")
-
-
 def main(argv=None) -> int:  # pragma: no cover - CLI body exercised via run_collective_bench
     import argparse
     import json
@@ -363,7 +334,6 @@ def main(argv=None) -> int:  # pragma: no cover - CLI body exercised via run_col
         else:
             print(json.dumps({"schema": table_mod.SCHEMA_VERSION,
                               "source": source, "rows": rows}, indent=1))
-        _emit_perf_ledger(rows)
         return 0
     ops = OPS if a.op == "all" else (a.op,)
     for op in ops:

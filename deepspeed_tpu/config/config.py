@@ -449,8 +449,7 @@ class CollObserveConfig(DeepSpeedConfigModel):
 
     enabled: bool = False
     # 1-in-N train steps runs probe work (the steady-state path is untouched
-    # between samples; amortized overhead guarded <2% by bench.py's
-    # coll_observability extra); <= 0 disables sampling while keeping
+    # between samples); <= 0 disables sampling while keeping
     # route registration + the trace-time census live
     sample_every: int = 16
     probes_per_sample: int = 1

@@ -20,8 +20,7 @@ produces the same fault — flaky fault tests are worse than none):
     state is touched and the loader must fall back to the previous tag.
 
 Used by ``tests/unit/checkpoint/test_snapshot.py``,
-``tests/unit/aux/test_resilience.py`` and the nightly smoke stage
-(``tools/fault_smoke.py``).
+``tests/unit/aux/test_resilience.py`` and ``tools/fault_smoke.py``.
 """
 
 from __future__ import annotations

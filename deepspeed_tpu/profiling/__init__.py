@@ -5,11 +5,6 @@ jaxpr traversal provide exact compiled-program numbers (see
 ``flops_profiler.py``).
 """
 
-from deepspeed_tpu.profiling.attribution import (
-    Attribution,
-    attribute,
-    attribute_program,
-)
 from deepspeed_tpu.profiling.flops_profiler import (
     FlopsProfiler,
     ProfileResult,

@@ -49,17 +49,6 @@ def _sigusr2_handler(signum, frame):
         prev(signum, frame)
 
 
-def arm_all(reason: str = "manual") -> int:
-    """Arm every live capture in the process (same dispatch as the SIGUSR2
-    hook, callable from code): the perf gate uses this so a detected
-    regression leaves a profiler trace of the very next step window, not
-    just a red exit code. Returns the number of captures reached."""
-    caps = list(_CAPTURES)
-    for cap in caps:
-        cap.arm(reason=reason)
-    return len(caps)
-
-
 def install_sigusr2() -> None:
     """Install the SIGUSR2 → arm-capture hook (process-wide, once, main
     thread only — signal.signal raises elsewhere)."""

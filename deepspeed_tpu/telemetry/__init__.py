@@ -81,10 +81,6 @@ from deepspeed_tpu.telemetry.fleet import (
     configure_identity,
     get_identity,
 )
-from deepspeed_tpu.telemetry.perfledger import (
-    PerfLedger,
-    make_row,
-)
 from deepspeed_tpu.telemetry.registry import (
     Counter,
     Gauge,
@@ -112,7 +108,6 @@ __all__ = [
     "MetricsRegistry",
     "MetricsServer",
     "NOOP_SPAN",
-    "PerfLedger",
     "ProcessIdentity",
     "TraceContext",
     "Tracer",
@@ -135,7 +130,6 @@ __all__ = [
     "get_event_stream",
     "get_identity",
     "get_tracer",
-    "make_row",
     "render_json_snapshot",
     "render_prometheus",
     "serve_metrics",

@@ -457,7 +457,7 @@ class AlertEngine:
 def default_rules() -> List[AlertRule]:
     """The stock rule pack covering the repo's detectors: quiet on a clean
     run (every threshold is on a *defect* counter that stays zero), loud on
-    the faults the nightly injects."""
+    the faults ``tools/alerts_smoke.py`` injects."""
     return [
         AlertRule(name="numerics_divergence", metric="numerics/divergence_events",
                   op=">", value=0, severity="critical",
@@ -465,9 +465,6 @@ def default_rules() -> List[AlertRule]:
         AlertRule(name="collective_drift", metric="coll/drift_events",
                   op=">", value=0, severity="warn",
                   summary="collective observed-vs-predicted drift events: {value}"),
-        AlertRule(name="perf_regression", metric="perf/regression_events",
-                  op=">", value=0, severity="warn",
-                  summary="perf-gate regressions: {value}"),
         AlertRule(name="replica_dead", kind="event_rate", subsystem="fabric",
                   event_kind="replica_dead", window_s=300.0, op=">", value=0,
                   severity="critical",

@@ -41,7 +41,7 @@ The incident plane (ISSUE 20) rides the same transport:
                          ids stable across repeated reads
   - ``GET  /console``    one self-contained stdlib HTML ops page: health
                          ledger, firing alerts, recent incidents, SLO
-                         rollups, perf-ledger sparklines
+                         rollups
 
 Merging happens at READ time from the latest dump per process: pushes carry
 cumulative process-local snapshots, so the collector must replace a
@@ -185,16 +185,13 @@ class FleetCollector:
                  straggler_mads: float = 6.0,
                  table_path: Optional[str] = None,
                  events_capacity: int = 4096,
-                 incident_window_s: float = 30.0,
-                 ledger_root: Optional[str] = None):
+                 incident_window_s: float = 30.0):
         self._host = host
         self._requested_port = port
         self.stale_after_s = float(stale_after_s)
         self.straggler_mads = float(straggler_mads)
         self.table_path = table_path
         self.incident_window_s = float(incident_window_s)
-        # perf-ledger root for the console sparklines (None = repo default)
-        self.ledger_root = ledger_root
         self._server = None  # exposition.RouteServer, built at start()
         self._lock = threading.Lock()
         # proc key -> {"identity", "dump", "heartbeat", "coll_rows",
@@ -297,7 +294,7 @@ class FleetCollector:
 
     def dumps(self) -> Dict[str, Dict[str, Any]]:
         """proc key -> the latest registry dump that process pushed — the
-        raw inputs of the federated merge, for verifiers (the nightly
+        raw inputs of the federated merge, for verifiers (the fleet
         smoke's bit-exactness gate sums these independently)."""
         with self._lock:
             return {k: e["dump"] for k, e in self._procs.items()
@@ -478,48 +475,10 @@ class FleetCollector:
                            "incidents": incs}).encode()
 
     # ------------------------------------------------------------- console
-    def _ledger_sparklines(self, width: int = 160, height: int = 28,
-                           max_series: int = 8) -> List[Dict[str, str]]:
-        """Inline-SVG sparklines of the perf ledger's headline series —
-        best-effort: no ledger on disk renders as no section, never an
-        error page."""
-        try:
-            from deepspeed_tpu.telemetry.perfgate import is_headline, GateConfig
-            from deepspeed_tpu.telemetry.perfledger import PerfLedger, row_key
-
-            ledger = PerfLedger(self.ledger_root)
-            cfg = GateConfig()
-            series: Dict[tuple, List[tuple]] = {}
-            for row in ledger.rows():
-                if not is_headline(row, cfg):
-                    continue
-                series.setdefault(row_key(row), []).append(
-                    (int(row["round"]), float(row["value"])))
-        except Exception:  # noqa: BLE001 - console stays up without a ledger
-            return []
-        out = []
-        for key in sorted(series)[:max_series]:
-            pts = sorted(series[key])
-            vals = [v for _r, v in pts]
-            if len(vals) < 2:
-                continue
-            lo, hi = min(vals), max(vals)
-            span = (hi - lo) or 1.0
-            step = width / max(len(vals) - 1, 1)
-            poly = " ".join(
-                f"{i * step:.1f},{height - 3 - (v - lo) / span * (height - 6):.1f}"
-                for i, v in enumerate(vals))
-            svg = (f'<svg width="{width}" height="{height}">'
-                   f'<polyline fill="none" stroke="#2b7" stroke-width="1.5" '
-                   f'points="{poly}"/></svg>')
-            out.append({"label": "/".join(key), "svg": svg,
-                        "last": f"{vals[-1]:.6g}", "n": str(len(vals))})
-        return out
-
     def _console_html(self) -> bytes:
-        """GET /console: ONE self-contained page (inline CSS, inline SVG,
-        zero external assets — it must render from a curl dump during the
-        exact outage it exists for)."""
+        """GET /console: ONE self-contained page (inline CSS, zero external
+        assets — it must render from a curl dump during the exact outage it
+        exists for)."""
         esc = html.escape
         now = time.time()
         led = self.ledger()
@@ -616,17 +575,6 @@ class FleetCollector:
             parts.append("</table>")
         else:
             parts.append("<p>no rollups yet</p>")
-        # perf sparklines
-        sparks = self._ledger_sparklines()
-        if sparks:
-            parts.append("<h2>perf ledger (headline trajectories)</h2>"
-                         "<table><tr><th>series</th><th>trend</th>"
-                         "<th>last</th><th>rounds</th></tr>")
-            for s in sparks:
-                parts.append(f"<tr><td>{esc(s['label'])}</td><td>{s['svg']}"
-                             f"</td><td>{esc(s['last'])}</td>"
-                             f"<td>{esc(s['n'])}</td></tr>")
-            parts.append("</table>")
         # recent events
         parts.append("<h2>recent events</h2>")
         if recent:

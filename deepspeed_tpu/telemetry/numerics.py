@@ -41,10 +41,7 @@ Disabled (the default) every hook is an attribute check and the sentinel is
 absent from the train step — the program is jaxpr-identical to a build
 without this module (pinned by ``tests/unit/test_numerics.py``).
 
-Accuracy trajectories land in the perf ledger under the ``numerics`` suite
-(``tools/numerics_smoke.py``, ``bench_serving.py --kv-dtype``) so the PR-16
-gate's MAD machinery gates them exactly like latency. See docs/telemetry.md
-"Numerics observatory".
+See docs/telemetry.md "Numerics observatory".
 """
 
 from __future__ import annotations

@@ -3,8 +3,8 @@
 The numeric half of the telemetry subsystem (the ``Tracer`` in ``tracer.py``
 is the temporal half). Closest reference analogs are the scattered aggregates
 in ``utils/comms_logging.py`` (bytes/counts per op) and the monitor scalars —
-here they share ONE registry so the ``MonitorMaster`` backends, ``bench.py``'s
-phase breakdown, and the exporters all read the same numbers.
+here they share ONE registry so the ``MonitorMaster`` backends and the
+exporters all read the same numbers.
 
 Labels (serving SLO observability): every factory accepts keyword labels —
 ``registry.histogram("serving/ttft_ms", k=8)`` — producing one child metric

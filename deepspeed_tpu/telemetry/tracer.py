@@ -326,7 +326,7 @@ class Tracer:
     # --------------------------------------------------------- summaries
     def phase_summary(self) -> Dict[str, Dict[str, float]]:
         """``{span_name: {count, total_ms, mean_ms, min_ms, max_ms}}`` from
-        the registry — the single source of truth ``bench.py`` reports."""
+        the registry."""
         out: Dict[str, Dict[str, float]] = {}
         for name, val in self.registry.snapshot().items():
             if not name.startswith("span/") or not isinstance(val, dict):
@@ -421,8 +421,8 @@ class Tracer:
 
 def env_enabled() -> bool:
     """True when DSTPU_TELEMETRY opts telemetry in from the environment —
-    the ONE place the accepted truthy spellings live (bench.py consults
-    this too; don't re-implement the parse)."""
+    the ONE place the accepted truthy spellings live (don't re-implement
+    the parse)."""
     return os.environ.get("DSTPU_TELEMETRY", "").lower() in ("1", "true", "yes")
 
 

@@ -2,11 +2,11 @@
 
 The cache directory is part of every entry's key, so a directory that moves
 (a ``mkdtemp``, a pid, a clock) never hits twice. One rule, used by every
-entry point that compiles (``chip_smoke.py``, ``bench.py``, the replica
-daemon, the tools): ``JAX_COMPILATION_CACHE_DIR`` wins when the environment
-sets it — jax reads that variable itself, nothing here touches the config —
-and otherwise the cache sits at the fixed ``<checkout>/.jax_cache``
-(git-ignored).
+entry point that compiles (``chip_smoke.py``, ``benchmarks/run.py``, the
+replica daemon, ``tools/fabric_smoke.py``): ``JAX_COMPILATION_CACHE_DIR``
+wins when the environment sets it — jax reads that variable itself, nothing
+here touches the config — and otherwise the cache sits at the fixed
+``<checkout>/.jax_cache`` (git-ignored).
 """
 
 from __future__ import annotations

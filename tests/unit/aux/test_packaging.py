@@ -76,8 +76,7 @@ def test_dstpu_help_runs_outside_checkout(venv_bin):
 
 
 def test_entry_point_targets_importable():
-    """Default-tier packaging check (the real `pip install -e .` + venv run
-    is nightly — it costs ~20 s of the cold budget): every [project.scripts]
+    """Packaging check without a `pip install -e .`: every [project.scripts]
     target in pyproject.toml must resolve to a callable."""
     import importlib
 

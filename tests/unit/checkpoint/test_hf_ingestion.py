@@ -194,7 +194,7 @@ def test_gpt2_ingestion_logits_parity(tmp_path):
 def test_gpt_bigcode_ingestion_logits_parity(tmp_path, multi_query):
     """starcoder/santacoder-style (round 5; reference module_inject bigcode
     containers): Linear-oriented c_attn, one shared KV head when multi_query.
-    The MHA variant (nightly) pins the [3h, h]-vs-[h, 3h] family detection."""
+    The MHA variant pins the [3h, h]-vs-[h, 3h] family detection."""
     cfg_hf = transformers.GPTBigCodeConfig(
         vocab_size=96, n_embd=32, n_layer=2, n_head=4, n_positions=64,
         multi_query=multi_query, activation_function="gelu_pytorch_tanh")

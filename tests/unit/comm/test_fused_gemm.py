@@ -83,7 +83,6 @@ def test_matmul_reduce_scatter_exact_bit_identity(mesh):
                                   np.asarray(a) @ np.asarray(w))
 
 
-@pytest.mark.nightly
 def test_int8_wire_bounded(mesh):
     rng = np.random.default_rng(3)
     xf = jnp.asarray(rng.normal(size=(M, K)).astype(np.float32))
@@ -146,7 +145,6 @@ def test_knob_routes_default_path(mesh):
         fused_gemm.configure(enabled=False)
 
 
-@pytest.mark.nightly
 def test_sharded_matmul_grads_fused_matches_unfused(mesh):
     rng = np.random.default_rng(6)
     x, w = _ints(rng, (M, K)), _ints(rng, (K, N))
@@ -169,7 +167,6 @@ def test_sharded_matmul_grads_fused_matches_unfused(mesh):
                                   np.asarray(grads[False][1]))
 
 
-@pytest.mark.nightly
 def test_zero3_sgd_trajectory_fused_tracks_unfused(mesh):
     # batch-sharded x, parameter-sharded w: the fused forward gathers w on
     # the fly, the fused backward reduce-scatters dw to each rank's shard

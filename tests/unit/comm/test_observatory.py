@@ -318,34 +318,54 @@ def test_sweep_cli_writes_envelope_and_merges(mesh8, tmp_path):
     assert ("all_gather", "rhd") in algs and ("all_reduce", "ring") in algs
 
 
-def test_sweep_leaves_tracked_perf_ledger_untouched(mesh8, tmp_path):
-    """A ``comm.benchmark --sweep`` run appends perf-ledger rows; under the
-    test harness they go to ``$DSTPU_PERF_LEDGER_DIR`` (a per-test tmp dir,
-    tests/conftest.py) and the tracked ``perf/ledger/`` stays byte-identical
-    — tier-1 used to dirty ``perf/ledger/coll-sweep.jsonl`` on every run."""
-    import hashlib
+def _checkout_files(repo):
+    """``{path: (mtime_ns, size)}`` of the checkout, less ``.git`` and what
+    ``.gitignore`` names (caches and run outputs, which other xdist workers
+    write meanwhile)."""
+    import fnmatch
+    import os
+
+    with open(os.path.join(repo, ".gitignore"), encoding="utf-8") as f:
+        ignored = [ln.strip().rstrip("/") for ln in f
+                   if ln.strip() and ln[0] not in "#!"] + [".git"]
+
+    def skip(rel):
+        return any(fnmatch.fnmatch(rel, pat)
+                   or fnmatch.fnmatch(os.path.basename(rel), pat)
+                   for pat in ignored)
+
+    seen = {}
+    for dirpath, dirnames, filenames in os.walk(repo):
+        rel_dir = os.path.relpath(dirpath, repo)
+        dirnames[:] = [d for d in dirnames
+                       if not skip(os.path.normpath(os.path.join(rel_dir, d)))]
+        for name in filenames:
+            rel = os.path.normpath(os.path.join(rel_dir, name))
+            if not skip(rel):
+                st = os.stat(os.path.join(dirpath, name))
+                seen[rel] = (st.st_mtime_ns, st.st_size)
+    return seen
+
+
+def test_sweep_writes_its_output_and_nothing_else(mesh8, tmp_path):
+    """A ``comm.benchmark --sweep`` run writes its ``--output`` file and
+    creates or changes nothing else under the checkout — tier-1 used to
+    dirty a tracked ledger file on every run."""
     import os
 
     from deepspeed_tpu.comm import benchmark
-    from deepspeed_tpu.telemetry import perfledger
 
     repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))))
-    tracked = os.path.join(repo, "perf", "ledger")
-
-    def digest():
-        return {f: hashlib.sha256(open(os.path.join(tracked, f), "rb").read()).hexdigest()
-                for f in sorted(os.listdir(tracked))}
-
-    before = digest()
-    assert os.path.abspath(perfledger.default_ledger_root()) != os.path.abspath(tracked)
+    out = tmp_path / "sweep.json"
+    before = _checkout_files(repo)
     rc = benchmark.main(["--sweep", "--op", "all_reduce", "--sizes-mb", "0.01",
                          "--iters", "1", "--algorithms", "lax,ring",
-                         "--output", str(tmp_path / "sweep.json")])
+                         "--output", str(out)])
     assert rc == 0
-    assert digest() == before
-    swept = os.path.join(perfledger.default_ledger_root(), "coll-sweep.jsonl")
-    assert os.path.getsize(swept) > 0  # the rows did land — in the tmp ledger
+    assert out.stat().st_size > 0
+    assert sorted(os.listdir(tmp_path)) == ["sweep.json"]
+    assert _checkout_files(repo) == before
 
 
 def test_measured_pick_prefers_matching_itemsize(tmp_path):

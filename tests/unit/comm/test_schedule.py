@@ -65,7 +65,6 @@ def test_compiled_all_reduce_1d_bit_identical(alg):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.nightly
 def test_compiled_all_reduce_nondivisible_payload():
     # L=333 per shard is not divisible by the sub-ring sizes -> pad path
     mesh = _mesh((8,), ("dp",))
@@ -98,7 +97,6 @@ def test_compiled_two_axis_mesh_bit_identical():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.nightly
 def test_compiled_three_axis_mesh_bit_identical():
     mesh = _mesh((2, 2, 2), ("a", "b", "c"))
     axes = ("a", "b", "c")
@@ -119,7 +117,6 @@ def test_compiled_three_axis_mesh_bit_identical():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.nightly
 def test_compiled_mixed_codec_placement_bounded():
     # ZeRO++ shape by hand: exact 2-ring on b, int8 4-ring on a
     mesh = _mesh((4, 2), ("a", "b"))

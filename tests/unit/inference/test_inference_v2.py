@@ -162,7 +162,7 @@ def test_preemption_under_kv_pressure():
 def test_v2_moe_generate_matches_v1():
     """The ragged v2 engine serves MoE models (FastGen serves Mixtral): the
     paged forward routes each layer through the expert mixer, and greedy
-    output matches the dense v1 engine on the same params (nightly)."""
+    output matches the dense v1 engine on the same params."""
     cfg, _, params = make_model(num_experts=4, moe_top_k=2)
     eng = InferenceEngineV2(cfg, params, {"dtype": "fp32", "kv_block_size": 4,
                                           "num_kv_blocks": 64})

@@ -143,7 +143,7 @@ def test_admission_none_admits_everything():
 # ------------------------------------------------------------ loop-level SLO
 def test_router_sheds_unmeetable_budget_before_dispatch():
     """ttft budget no real machine can meet: every request sheds (output
-    None), nothing is dispatched, and — the nightly gate's invariant —
+    None), nothing is dispatched, and — the invariant —
     nothing was dropped AFTER admission."""
     cfg, _, params = make_model()
     rng = np.random.RandomState(2)

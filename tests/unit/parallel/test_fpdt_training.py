@@ -62,8 +62,8 @@ def test_fpdt_attention_fwd_and_grad_parity():
 
 
 def test_fpdt_attention_noncausal_parity():
-    """Non-causal chunked parity (nightly: the causal combos above exercise
-    the same kernel with the strictly harder tile-skip logic)."""
+    """Non-causal chunked parity (the causal combos above exercise the same
+    kernel with the strictly harder tile-skip logic)."""
     _fpdt_parity_combos([(False, False)])
 
 
@@ -172,7 +172,6 @@ def test_fpdt_engine_sp2_trajectory(devices):
     np.testing.assert_allclose(sp, base, rtol=2e-4)
 
 
-@pytest.mark.nightly
 def test_fpdt_memory_linear_in_seq():
     """Compiled fwd+bwd peak temp bytes at fixed chunk size must scale ~O(S),
     not O(S²): the per-tile score buffer is Cq x Ck regardless of S. The

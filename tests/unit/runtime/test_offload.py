@@ -80,7 +80,7 @@ def test_twin_flow_partial_offload_structure():
 def test_twin_flow_trajectory_matches_fused():
     """ratio=0.5 partial offload reproduces the fused non-offload trajectory
     (same split semantics: one global grad norm, one loss-scale/step
-    bookkeeping; nightly depth for the new feature)."""
+    bookkeeping)."""
     twin, *_ = deepspeed_tpu.initialize(
         model=_model(),
         config=_cfg({"offload_optimizer": {"device": "cpu", "ratio": 0.5}}),
@@ -100,7 +100,7 @@ def test_twin_flow_trajectory_matches_fused():
 def test_twin_flow_fp16_dynamic_scale_matches_fused():
     """fp16 dynamic loss scaling under Twin-Flow: the shared bookkeeping
     (one finite flag, one loss-scale state) must reproduce the fused fp16
-    trajectory including any scale adjustments (nightly depth)."""
+    trajectory including any scale adjustments."""
     fp16 = {"fp16": {"enabled": True, "initial_scale_power": 8, "loss_scale_window": 2}}
 
     twin, *_ = deepspeed_tpu.initialize(
@@ -118,7 +118,7 @@ def test_twin_flow_fp16_dynamic_scale_matches_fused():
 def test_offload_bf16_grad_transfer_close_to_fp32():
     """bf16 grad accumulation x CPU offload: grads cross to the host in bf16
     (half the D2H bytes — what the offload bench configs use) and the
-    trajectory stays close to the fp32-accumulated offload run (nightly)."""
+    trajectory stays close to the fp32-accumulated offload run."""
     import jax.numpy as jnp
 
     def run(accum_fp32):

@@ -38,7 +38,6 @@ ALLOWED_SUBSYSTEMS = {
     "mem",
     "moe",
     "numerics",
-    "perf",
     "program",
     "recompile",
     "router",
@@ -57,7 +56,6 @@ TRACER_COUNT_RE = re.compile(
 NAME_RE = re.compile(r"^[a-z0-9_]+/[a-z0-9_/.:{}]*$")
 
 SCAN_DIRS = ("deepspeed_tpu", "tools")
-SCAN_FILES = ("bench.py",)
 
 
 def _python_files():
@@ -68,10 +66,6 @@ def _python_files():
             for f in files:
                 if f.endswith(".py"):
                     yield os.path.join(root, f)
-    for f in SCAN_FILES:
-        p = os.path.join(REPO_ROOT, f)
-        if os.path.exists(p):
-            yield p
 
 
 def _check_name(name: str):
@@ -120,18 +114,12 @@ def test_lint_scans_telemetry_and_serving_sources():
                   # fleet telemetry plane (ISSUE 13): the federation layer
                   # mints the fleet/* rollup series
                   "fleet.py", "collector.py",
-                  # perf observatory (ISSUE 16): the gate mints the
-                  # perf/trajectory + perf/regression_events series
-                  "perfgate.py",
                   # numerics observatory (ISSUE 17): wire/serving fidelity
                   # + divergence series
                   "numerics.py",
                   # incident plane (ISSUE 20): the event stream mints the
                   # events/* series, the alert engine the alerts/* series
                   "events.py", "alerts.py")
-    } | {
-        # step-time attribution gauges (ISSUE 16)
-        os.path.join("deepspeed_tpu", "profiling", "attribution.py"),
     } | {
         os.path.join("deepspeed_tpu", "inference", f)
         for f in ("engine_v2.py", "lifecycle.py", "router.py",
@@ -148,7 +136,6 @@ def test_lint_scans_telemetry_and_serving_sources():
         # coll/schedule_* search census
         os.path.join("deepspeed_tpu", "collectives", "schedule.py"),
     } | {os.path.join("tools", "alerts_smoke.py"),
-         os.path.join("tools", "bench_serving.py"),
          os.path.join("tools", "fabric_smoke.py"),
          os.path.join("tools", "incident_report.py"),
          os.path.join("tools", "fleet_smoke.py"),
@@ -182,12 +169,6 @@ def test_known_names_pass_and_bad_names_fail():
                  # timings ride the existing coll/* histograms
                  "moe/capacity_factor_applied", "moe/capacity_factor_target",
                  "moe/token_drop_rate", "coll/hop_ms", "coll/achieved_gbps",
-                 # perf observatory (ISSUE 16): gate trajectory/regression
-                 # series and the step-time attribution gauges
-                 "perf/trajectory", "perf/regression_events",
-                 "perf/attribution_wall_ms", "perf/attribution_compute_ms",
-                 "perf/attribution_stall_ms", "perf/attribution_bound",
-                 "perf/roofline_flops_fraction", "perf/roofline_bw_fraction",
                  # numerics observatory (ISSUE 17): wire/serving fidelity,
                  # the divergence sentinel, and the fleet digest comparator
                  "numerics/wire_rel_err", "numerics/wire_drift_events",

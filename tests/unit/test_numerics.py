@@ -366,26 +366,6 @@ def test_trend_alarm_needs_quorum():
     assert alarm.alarms == 0
 
 
-# ----------------------------------------------------------------- perf gate
-def test_numerics_suite_is_headline_gated():
-    from deepspeed_tpu.telemetry.perfgate import GateConfig, gate_row
-    from deepspeed_tpu.telemetry.perfledger import make_row
-
-    hist = [make_row("numerics", "wire_rel_err/int8", 0.010, "rel",
-                     direction="lower", method="probe", samples=1,
-                     backend="cpu", round=r) for r in (1, 2, 3)]
-    good = make_row("numerics", "wire_rel_err/int8", 0.0101, "rel",
-                    direction="lower", method="probe", samples=1,
-                    backend="cpu", round=4)
-    bad = make_row("numerics", "wire_rel_err/int8", 0.10, "rel",
-                   direction="lower", method="probe", samples=1,
-                   backend="cpu", round=4)
-    cfg = GateConfig()
-    assert gate_row(good, hist, cfg).status == "ok"
-    v = gate_row(bad, hist, cfg)
-    assert v.status == "regression" and v.mode == "mad"
-
-
 # ------------------------------------------------------------------ EF gauges
 def test_ef_residual_norm_gauges():
     obs = numerics.configure(enabled=True)

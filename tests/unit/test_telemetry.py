@@ -243,27 +243,3 @@ def test_checkpoint_and_dataloader_spans(tmp_path):
     assert "checkpoint:save" in names
     assert "checkpoint:load" in names
     assert "data:materialize" in names
-
-
-def test_bench_telemetry_section(tmp_path, monkeypatch):
-    """bench.py's phase breakdown comes from the telemetry registry and its
-    trace satisfies the Perfetto contract: fwd/bwd/step spans + at least one
-    comm collective span with payload-bytes metadata."""
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))))
-    import bench
-
-    monkeypatch.setenv("DSTPU_TELEMETRY_DIR", str(tmp_path))
-    eng = _tiny_engine({"telemetry": {"enabled": True}})
-    out = bench._telemetry_section(eng, _batch(eng), steps=2)
-    assert {"fwd", "bwd", "step"} <= set(out["phases"])
-    assert out["phases"]["step"]["count"] >= 2
-    assert out["comm"]["comm/bytes"] > 0
-    with open(out["trace"]) as f:
-        doc = json.load(f)
-    names = {e["name"] for e in doc["traceEvents"]}
-    assert {"fwd", "bwd", "step"} <= names
-    comm = [e for e in doc["traceEvents"] if e.get("cat") == "comm"]
-    assert comm and comm[0]["args"]["bytes"] > 0

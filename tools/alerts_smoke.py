@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Incident-plane smoke: alerts + incidents, exit-gated BOTH ways.
 
-The nightly's proof that ISSUE 20's incident plane actually fires and
-actually stays quiet (``tools/run_nightly.sh`` commits ``ALERTS_rNN.log``):
+The proof that ISSUE 20's incident plane actually fires and actually
+stays quiet:
 
   1. **Clean run MUST be quiet** — a 20-step train run with the numerics
      sentinel sampling every step, the default alert rule pack evaluating,
@@ -20,7 +20,7 @@ actually stays quiet (``tools/run_nightly.sh`` commits ``ALERTS_rNN.log``):
      MUST reach the firing state; and ``tools/incident_report.py`` run
      against the collector MUST emit a timeline naming both events.
 
-Prints one JSON line of evidence (the committed-log artifact).
+Prints one JSON line of evidence.
 
 CPU-ONLY: the parent touches jax and then starts children that do too, so
 every process here pins ``JAX_PLATFORMS=cpu``. On a TPU a chip belongs to one
@@ -210,8 +210,7 @@ def run_smoke() -> dict:
     import incident_report
 
     report_path = os.path.join(tmp, "incident_report.md")
-    rc = incident_report.main(["--url", collector.url, "--ledger-root", "",
-                               "--out", report_path])
+    rc = incident_report.main(["--url", collector.url, "--out", report_path])
     with open(report_path, encoding="utf-8") as f:
         report = f.read()
     gates["report_names_both"] = (

@@ -7,13 +7,12 @@ Two uses:
     observatory + selector hold — the measured-vs-model latency curves per
     hop backend, the calibrated alpha/beta constants, drift counters, and a
     staleness check on the persisted decision table.
-  - **CLI / nightly stage**: run standalone it forces an 8-device CPU mesh,
+  - **CLI**: run standalone it forces an 8-device CPU mesh,
     routes the four algorithmic collectives (all_to_all included) through the comm facade,
     drains the observatory's probe queue (real timed hop-scope dispatches),
     refits alpha/beta, injects one deliberately slow sample to prove the
-    drift alarm arms, and persists the online table — proving on every
-    nightly that the selector's feedback loop closes end to end
-    (``tools/run_nightly.sh`` commits the output as COLL_rNN.log).
+    drift alarm arms, and persists the online table — proving that the
+    selector's feedback loop closes end to end.
 
 Exit 0 iff probes ran for every op, the table holds at least two algorithm
 families per op, the refit produced finite constants the selector consumes,

@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Serving-fabric smoke: REAL replica-daemon processes, exit-gated.
 
-The multi-process proof of ISSUE 18's cross-process serving fabric, run by
-``tools/run_nightly.sh`` (committing ``FABRIC_rNN.log``) and — in its
-``--smoke`` subset — by the tier-1 integration test
+The multi-process proof of ISSUE 18's cross-process serving fabric; its
+``--smoke`` subset is run by the tier-1 integration test
 (``tests/unit/test_fabric.py``). The parent drives an UNCHANGED
 :class:`ServingRouter` whose roster is :class:`RemoteReplica` proxies over
 ``fabric/replica_daemon.py`` processes; every daemon builds the same
@@ -29,7 +28,7 @@ parent that stays off jax; this tool never asks for a device.
      ``tools/trace_merge.py`` — at least one request flow links >= 2 pids
      and ``serve:dispatch`` spans appear from >= 2 pids.
 
-Full (nightly) adds:
+Without ``--smoke`` it adds:
   5. SIGKILL mid-burst (``faultinject.kill_replica_daemon``): the router
      detects the death (heartbeat / dispatch failure), re-admits the dead
      replica's admitted requests on the survivor, and completes ALL of them;

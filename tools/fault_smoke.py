@@ -1,8 +1,7 @@
 #!/usr/bin/env python
-"""Nightly fault-injection smoke: prove the resilience stack end to end.
+"""Fault-injection smoke: prove the resilience stack end to end.
 
-One run on the CPU bench model (the tiny causal LM ``bench.py`` falls back
-to) with BOTH headline faults injected (``diagnostics/faultinject.py``):
+One run on a tiny causal LM on the CPU with BOTH headline faults injected (``diagnostics/faultinject.py``):
 
   - **NaN at step K** — params poisoned on device (a causal LM batch is
     integer-only, so the injection point is the model, not the data); the
@@ -14,9 +13,8 @@ to) with BOTH headline faults injected (``diagnostics/faultinject.py``):
     durable snapshot (crash-mid-save atomicity) and training must keep going
     forward (a save failure never rewinds healthy state).
 
-Prints one JSON line and exits 0 iff every claim held — wired into
-``tools/run_nightly.sh`` so the committed nightly log carries the proof
-(ISSUE 6; see docs/elastic.md).
+Prints one JSON line and exits 0 iff every claim held (ISSUE 6; see
+docs/elastic.md).
 """
 
 from __future__ import annotations

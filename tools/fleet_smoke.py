@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Fleet telemetry smoke: collector + N worker PROCESSES, exit-gated.
 
-The multi-process proof of ISSUE 13's federation semantics, run by
-``tools/run_nightly.sh`` (committing ``FLEET_rNN.log``) and by the tier-1
-integration test (``tests/unit/test_fleet.py``). Three processes on CPU:
+The multi-process proof of ISSUE 13's federation semantics, run by the
+tier-1 integration test (``tests/unit/test_fleet.py``). Three processes on CPU:
 
   parent   role=router: starts an in-process :class:`FleetCollector`,
            mints one ``fleet.TraceContext`` per synthetic request, emits
@@ -32,7 +31,7 @@ Exit gates (any failure => exit 1):
      merge at the collector and a fresh selector consumes them in
      measured mode.
 
-Prints one JSON line of evidence (the committed-log artifact).
+Prints one JSON line of evidence.
 """
 
 from __future__ import annotations

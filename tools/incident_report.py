@@ -12,9 +12,7 @@ Joins the incident plane's artifacts around a correlated incident id:
     timestamp falls inside the incident window (+/- margin), with the
     step records nearest the incident inlined;
   - **profiler-capture trace dirs** (``profiling/capture.py`` writes
-    ``step{N}`` dirs) whose mtime falls inside the window;
-  - **perf-ledger rows** (``telemetry/perfledger.py``) stamped inside
-    the window.
+    ``step{N}`` dirs) whose mtime falls inside the window.
 
 Usage:
   python tools/incident_report.py --events telemetry_out/event_log.jsonl \
@@ -33,7 +31,7 @@ import os
 import sys
 import time
 import urllib.request
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -111,22 +109,12 @@ def _capture_dirs(roots: List[str]) -> List[Dict[str, Any]]:
     return out
 
 
-def _ledger_rows(root: Optional[str]) -> List[Dict[str, Any]]:
-    try:
-        from deepspeed_tpu.telemetry.perfledger import PerfLedger
-
-        return PerfLedger(root).rows()
-    except Exception:  # noqa: BLE001 - ledger is optional evidence
-        return []
-
-
 def _ts(t: float) -> str:
     return time.strftime("%Y-%m-%d %H:%M:%S", time.localtime(t)) + f".{int((t % 1) * 1000):03d}"
 
 
 def render_incident(inc: Dict[str, Any], dumps: List[Dict[str, Any]],
                     captures: List[Dict[str, Any]],
-                    ledger: List[Dict[str, Any]],
                     margin_s: float = 60.0) -> str:
     """One incident -> one markdown section: the event timeline plus every
     artifact whose timestamp lands inside the widened window."""
@@ -185,15 +173,6 @@ def render_incident(inc: Dict[str, Any], dumps: List[Dict[str, Any]],
         for c in sorted(near_caps, key=lambda c: c["mtime"]):
             lines.append(f"- `{c['path']}` ({_ts(c['mtime'])})")
 
-    near_rows = [r for r in ledger
-                 if lo <= float(r.get("time_unix") or 0.0) <= hi]
-    if near_rows:
-        lines += ["", "### Perf-ledger rows in window", ""]
-        for r in near_rows[:20]:
-            lines.append(
-                f"- [{r.get('backend')}] {r.get('suite')}/{r.get('metric')}"
-                f" = {r.get('value')} {r.get('unit', '')}"
-                f" (r{r.get('round')})")
     lines.append("")
     return "\n".join(lines)
 
@@ -201,7 +180,6 @@ def render_incident(inc: Dict[str, Any], dumps: List[Dict[str, Any]],
 def build_report(incidents: List[Dict[str, Any]],
                  dumps: List[Dict[str, Any]],
                  captures: List[Dict[str, Any]],
-                 ledger: List[Dict[str, Any]],
                  margin_s: float = 60.0) -> str:
     head = [
         "# Incident report",
@@ -212,7 +190,7 @@ def build_report(incidents: List[Dict[str, Any]],
     if not incidents:
         head.append("No incidents correlated from the provided events.")
         head.append("")
-    body = [render_incident(inc, dumps, captures, ledger, margin_s)
+    body = [render_incident(inc, dumps, captures, margin_s)
             for inc in incidents]
     return "\n".join(head + body)
 
@@ -227,9 +205,6 @@ def main(argv=None) -> int:
                     help="flight_record*.jsonl path(s)/glob(s)")
     ap.add_argument("--captures", nargs="*", default=[],
                     help="dir(s) scanned for profiler capture stepN dirs")
-    ap.add_argument("--ledger-root", default=None,
-                    help="perf ledger root (default <repo>/perf/ledger; "
-                         "'' skips the ledger join)")
     ap.add_argument("--incident", default=None,
                     help="report only this incident id")
     ap.add_argument("--window", type=float, default=30.0,
@@ -265,8 +240,7 @@ def main(argv=None) -> int:
 
     dumps = _flight_dumps(args.flight_records)
     captures = _capture_dirs(args.captures)
-    ledger = [] if args.ledger_root == "" else _ledger_rows(args.ledger_root)
-    report = build_report(incidents, dumps, captures, ledger, args.margin)
+    report = build_report(incidents, dumps, captures, args.margin)
     if args.out:
         with open(args.out, "w", encoding="utf-8") as f:
             f.write(report)

@@ -1,9 +1,8 @@
 #!/usr/bin/env python
-"""MoE-at-scale nightly smoke (ISSUE 15).
+"""MoE-at-scale smoke (ISSUE 15).
 
-Exit-gated evidence, one JSON line (committed as MOE_rNN.log by
-``tools/run_nightly.sh``; ``--output`` also writes the machine-readable
-MOE_rNN.json artifact):
+Exit-gated evidence, one JSON line (``--output`` also writes it to a
+file):
 
   1. **ep x tp interpret smoke** — a dp2 x ep2 x tp2 CPU-mesh MoE engine
      (the composition the engine used to refuse) trains finite steps

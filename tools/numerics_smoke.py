@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Numerics observatory smoke: detection + quiet, exit-gated BOTH ways.
 
-The nightly's proof that ISSUE 17's sentinel actually fires and actually
-stays quiet (``tools/run_nightly.sh`` commits ``NUMERICS_rNN.log``):
+The proof that ISSUE 17's sentinel actually fires and actually stays
+quiet:
 
   1. **Clean run MUST be quiet** — a 20-step train run with the sentinel
      sampling every step raises ZERO divergence events and ZERO wire-drift
@@ -22,12 +22,7 @@ stays quiet (``tools/run_nightly.sh`` commits ``NUMERICS_rNN.log``):
   4. **Abort policy MUST raise** — with ``divergence_policy="abort"`` the
      same injected flip must surface as ``TrainingHealthError``.
 
-Accuracy trajectories land in the perf ledger (``--ledger``), suite
-``numerics``: ``wire_rel_err/<codec>`` (direction=lower) and
-``divergence_detect_steps`` (direction=lower) — gated by the PR-16
-median+MAD machinery exactly like latency (see perfgate.HEADLINE_PATTERNS).
-
-Prints one JSON line of evidence (the committed-log artifact).
+Prints one JSON line of evidence.
 """
 
 from __future__ import annotations
@@ -36,7 +31,6 @@ import argparse
 import json
 import os
 import sys
-import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -174,44 +168,9 @@ def run_smoke() -> dict:
     return evidence
 
 
-def emit_ledger(evidence: dict) -> int:
-    """Append the accuracy trajectories to the unified perf ledger (suite
-    ``numerics``). Best-effort like bench_serving: the smoke verdict never
-    depends on the ledger dir being writable."""
-    try:
-        from deepspeed_tpu.telemetry.fleet import get_identity
-        from deepspeed_tpu.telemetry.perfledger import (
-            PerfLedger, default_backend, default_round, make_row,
-            resolve_git_sha,
-        )
-
-        common = dict(backend=default_backend(), round=default_round(),
-                      run_id=get_identity().run_id,
-                      git_sha=resolve_git_sha(), time_unix=time.time())
-        rows = [make_row("numerics", "divergence_detect_steps",
-                         float(evidence["inject"]["detect_steps"]), "steps",
-                         direction="lower", method="probe", samples=1,
-                         **common)]
-        for key, rel in evidence["wire"]["rel_err"].items():
-            codec = key.split("/", 1)[1]
-            rows.append(make_row("numerics", f"wire_rel_err/{codec}",
-                                 float(rel), "rel", direction="lower",
-                                 method="probe", samples=1, **common))
-        return PerfLedger().append(rows)
-    except Exception as e:  # noqa: BLE001 — evidence plane, not the gate
-        print(f"[numerics_smoke] perf-ledger append skipped: {e}",
-              file=sys.stderr)
-        return 0
-
-
 def main() -> None:
-    ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--ledger", action="store_true",
-                    help="append accuracy rows to the unified perf ledger")
-    args = ap.parse_args()
+    argparse.ArgumentParser(description=__doc__).parse_args()
     evidence = run_smoke()
-    if args.ledger:
-        evidence["ledger_rows"] = emit_ledger(evidence)
     print(json.dumps(evidence, sort_keys=True))
     sys.exit(0 if evidence["pass"] else 1)
 

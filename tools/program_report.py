@@ -7,12 +7,11 @@ Two uses:
     ``ProgramRegistry`` has captured — call it at the end of any run with
     telemetry enabled to see every program XLA built, its cost/memory
     analysis, collective content, and the HBM estimate-vs-actual ratio.
-  - **CLI / nightly stage**: run standalone it builds the tiny CPU bench
-    engines (one training engine, one v2 serving engine), drives a few
-    steps through each, and dumps the inventory — proving on every nightly
-    that the capture path records real train-step and decode-chain programs
-    with nonzero flops/peak-HBM and a computed calibration ratio
-    (``tools/run_nightly.sh`` commits the output as PROGRAMS_rNN.log).
+  - **CLI**: run standalone it builds tiny CPU engines (one training
+    engine, one v2 serving engine), drives a few steps through each, and
+    dumps the inventory — proving that the capture path records real
+    train-step and decode-chain programs with nonzero flops/peak-HBM and a
+    computed calibration ratio.
 
 Exit 0 iff the inventory holds a captured training step AND a v2 serving
 program, each with nonzero flops and peak HBM, and an ``hbm/estimate_ratio``
@@ -88,7 +87,7 @@ def render_report(registry=None) -> str:
 
 
 def _drive_probe_engines(steps: int, decode_tokens: int) -> None:
-    """Build the tiny CPU bench engines and step them so the registry holds
+    """Build the tiny CPU engines and step them so the registry holds
     a real train-step and a real v2 decode-chain program."""
     import numpy as np
 
@@ -163,7 +162,7 @@ def main(argv: Optional[list] = None) -> int:
 
     if args.no_probe:
         return 0
-    # nightly gate: real programs, real costs, calibrated against the guard
+    # the gate: real programs, real costs, calibrated against the guard
     train = [r for r in registry.records() if r.label == "train_step"]
     serving = [r for r in registry.records() if r.label.startswith("v2:")]
     ok = {

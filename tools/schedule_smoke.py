@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Collective schedule compiler + fused GEMM smoke, exit-gated (ISSUE 19).
 
-The nightly's proof that the GC3/T3 stack holds its two contracts
-(``tools/run_nightly.sh`` commits ``SCHED_rNN.log``):
+The proof that the GC3/T3 stack holds its contracts:
 
   1. **Compiled programs MUST execute bit-identically** — the synthesized
      hop programs (``algorithm="compiled[:sig]"``) round-trip through the
@@ -27,13 +26,7 @@ The nightly's proof that the GC3/T3 stack holds its two contracts
      must keep its loss trajectory within tolerance of the config-off
      lax composition over every step.
 
-Headline trajectories land in the perf ledger (``--ledger``), suite
-``schedule``: ``compiled_vs_hand/pred_ratio`` and
-``fused_gemm/step_time_ratio`` (both direction=lower, gated by the PR-16
-median+MAD machinery via ``perfgate.HEADLINE_PATTERNS``), plus the
-trajectory-only ``fused_gemm/traj_rel_err``.
-
-Prints one JSON line of evidence (the committed-log artifact).
+Prints one JSON line of evidence.
 """
 
 from __future__ import annotations
@@ -250,48 +243,9 @@ def run_smoke() -> dict:
     return evidence
 
 
-def emit_ledger(evidence: dict) -> int:
-    """Append the headline trajectories to the unified perf ledger (suite
-    ``schedule``). Best-effort like the other smokes: the verdict never
-    depends on the ledger dir being writable."""
-    try:
-        from deepspeed_tpu.telemetry.fleet import get_identity
-        from deepspeed_tpu.telemetry.perfledger import (
-            PerfLedger, default_backend, default_round, make_row,
-            resolve_git_sha,
-        )
-
-        common = dict(backend=default_backend(), round=default_round(),
-                      run_id=get_identity().run_id,
-                      git_sha=resolve_git_sha(), time_unix=time.time())
-        rows = [
-            make_row("schedule", "compiled_vs_hand/pred_ratio",
-                     float(evidence["parity"]["pred_ratio"]), "ratio",
-                     direction="lower", method="probe", samples=1, **common),
-            make_row("schedule", "fused_gemm/step_time_ratio",
-                     float(evidence["fused_traj"]["step_time_ratio"]),
-                     "ratio", direction="lower", method="probe",
-                     samples=TRAJ_STEPS, **common),
-            make_row("schedule", "fused_gemm/traj_rel_err",
-                     float(evidence["fused_traj"]["max_loss_rel_err"]),
-                     "rel", direction="lower", method="probe",
-                     samples=TRAJ_STEPS, **common),
-        ]
-        return PerfLedger().append(rows)
-    except Exception as e:  # noqa: BLE001 — evidence plane, not the gate
-        print(f"[schedule_smoke] perf-ledger append skipped: {e}",
-              file=sys.stderr)
-        return 0
-
-
 def main() -> None:
-    ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--ledger", action="store_true",
-                    help="append headline rows to the unified perf ledger")
-    args = ap.parse_args()
+    argparse.ArgumentParser(description=__doc__).parse_args()
     evidence = run_smoke()
-    if args.ledger:
-        evidence["ledger_rows"] = emit_ledger(evidence)
     print(json.dumps(evidence, sort_keys=True))
     sys.exit(0 if evidence["pass"] else 1)
 

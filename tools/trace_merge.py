@@ -175,7 +175,7 @@ def migration_links(trace: Dict[str, Any]) -> Dict[int, List[int]]:
     stream and steps inside the decode replica's ``serve:migrate`` import
     slice, so the merged trace draws the prefill->decode migration arrow.
     The disagg smoke gates on at least one such link."""
-    # one pass each over slices and flow events (a nightly merge can carry
+    # one pass each over slices and flow events (a merge can carry
     # thousands of migrations — no per-step rescans of the whole stream)
     slices: Dict[Any, List[Any]] = {}
     for e in trace["traceEvents"]:
