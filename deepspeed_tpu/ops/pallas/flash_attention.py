@@ -12,7 +12,23 @@ Layout: inputs are [B, S, H, D] (framework-native); the kernel works on
 [B, H, S, D]. GQA/MQA is handled in the index maps (kv head = q head // G),
 so grouped heads re-read the same KV tile — no KV replication in HBM.
 
+Backward: a score block costs what its [block_q, block_k] passes cost,
+whatever the head size, so the backward visits each block ONCE while it can.
+``flash_bwd_dkv`` walks key columns (outer) over query rows (inner), forms
+s, p, dp and ds once a block, accumulates dv and dk in scratch and adds
+ds @ k into the head's whole fp32 dq, which stays in VMEM over the walk and
+is written back once a head: five products and one exp2 a block. That dq
+slab is S x max(D, 128) x 4 bytes, twice; past ``_ONE_PASS_DQ_BYTES`` of it
+(S over 8,192 at head_dim up to 128) ``flash_bwd_dq`` makes dq on the
+row-major walk and ``flash_bwd_dkv`` only dk and dv, each forming the scores
+for itself (seven products, two exp2). ``_flash_bwd`` chooses from S and D
+alone; there is no option.
+
 Performance notes (measured on v5e):
+  - the one-pass backward takes 30% less than the pair at the train cells'
+    shapes (0.847 against 1.213 ms at [2, 2048, 16, 64], 0.386 against 0.549
+    at [1, 2048, 16, 128]: tools/flash_kernel_bench.py; PERF.md, PR 32), the
+    slab's read-add-write costing nothing measurable beside the fifth product
   - every matmul is input-dtype (bf16) with fp32 accumulation; fp32 operands
     run the MXU at ~1/4 rate
   - blocks that sit strictly below the causal diagonal skip ALL mask work
@@ -431,22 +447,32 @@ def _bwd_dq_kernel(*refs, block_q, block_k, causal, masked, squashed, alibi=Fals
 
 
 def _bwd_dkv_kernel(*refs, block_q, block_k, causal, masked, squashed, nq_total,
-                    alibi=False, k_splits=1):
+                    alibi=False, k_splits=1, one_pass=False):
+    """dk and dv of a key column, accumulated over its query rows (the grid
+    runs key column outer, query rows inner). With ``one_pass`` the same
+    ``ds`` also makes dq: ``dq_ref`` is a whole head's fp32 ``[S, D]`` slab,
+    resident in VMEM over both inner grid axes, and each block adds its
+    ``ds @ k`` into the slab's rows. A row block's terms still arrive in the
+    order ki = 0, 1, ..., qi, as in ``_bwd_dq_kernel``."""
     if squashed:
         (qm_ref, km_ref, mask_ref, *rest) = refs
-        slopes_ref = rest.pop(0) if alibi else None
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = rest
         t = pl.program_id(2)
         qi, ki = qm_ref[t], km_ref[t]
-        first, last = qi == ki, qi == nq_total - 1
+        first, last, first_of_head = qi == ki, qi == nq_total - 1, t == 0
     else:
         (mask_ref, *rest) = refs
-        slopes_ref = rest.pop(0) if alibi else None
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = rest
         ki, qi = pl.program_id(2), pl.program_id(3)
         first, last = qi == 0, qi == pl.num_programs(3) - 1
+        first_of_head = first & (ki == 0)
+    slopes_ref = rest.pop(0) if alibi else None
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *outs, dk_acc, dv_acc) = rest
+    dq_ref = outs.pop(0) if one_pass else None
+    dk_ref, dv_ref = outs
+
+    if one_pass:
+        @pl.when(first_of_head)
+        def _init_dq():
+            dq_ref[...] = jnp.zeros_like(dq_ref)
 
     @pl.when(first)
     def _init():
@@ -482,11 +508,17 @@ def _bwd_dkv_kernel(*refs, block_q, block_k, causal, masked, squashed, nq_total,
             )
             dp = jax.lax.dot_general(do, v[off:off + c], (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
-            ds = p * (dp - delta)
+            ds = (p * (dp - delta)).astype(q.dtype)
             dk_acc[off:off + c] += jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
+            if one_pass:
+                rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+                dq_ref[0, 0, rows, :] += jax.lax.dot_general(
+                    ds, k[off:off + c], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
 
     if causal and squashed:
         pl.when(qi > ki)(lambda: _compute(False))
@@ -504,96 +536,106 @@ def _bwd_dkv_kernel(*refs, block_q, block_k, causal, masked, squashed, nq_total,
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+# The one-pass backward keeps a whole head's dq, fp32 [S, D] with D padded to
+# the 128 lanes of a vreg row, in VMEM, twice (the pipeline double-buffers an
+# output block). This much a buffer (S = 8,192 at head_dim 64 and 128, 4,096
+# at 256) compiles for a v5e beside the [512, 512] temporaries in 12 MiB of
+# the 16 MiB a kernel may scope by default; 6 MiB is the most that does.
+# Longer sequences run the dq kernel and the dkv kernel.
+_ONE_PASS_DQ_BYTES = 4 * 1024 * 1024
+# The one-pass kernel takes a key block in sub-chunks of this many columns
+# (k_splits: the next chunk's q k^T ahead of this one's exp2 and four other
+# products). On a v5e at blocks of 512 that is 4.2-4.8% off a call at head_dim
+# 64 and 128, at S = 2,048 and 8,192; at blocks of 1,024 it changes nothing;
+# chunks of 128 cost 30%, and the PAIR loses 5% to any split (PERF.md, PR 32).
+_ONE_PASS_CHUNK = 256
+
+
+def _one_pass_fits(S: int, D: int) -> bool:
+    return S * _cdiv(D, 128) * 128 * 4 <= _ONE_PASS_DQ_BYTES
+
+
 def _flash_bwd(q, k, v, mask, slopes, out, lse, do, block_q: int, block_k: int,
                causal: bool, masked: bool, alibi: bool, k_splits: int = 1):
+    """dq, dk, dv (fp32, dk and dv per QUERY head summed over the group here).
+    One kernel, ``flash_bwd_dkv``, makes all three from one set of scores
+    while a head's dq fits VMEM (``_one_pass_fits``: a choice from S and D
+    alone); past that ``flash_bwd_dq`` makes dq and ``flash_bwd_dkv`` dk and
+    dv, each forming the scores for itself."""
     B, H, S, D = q.shape
     Hkv = k.shape[1]
     G = H // Hkv
     nq, nk = _cdiv(S, block_q), _cdiv(S, block_k)
     squashed = _squash_ok(nq, nk, block_q, block_k, causal)
+    one_pass = _one_pass_fits(S, D)
+    if one_pass and block_k % _ONE_PASS_CHUNK == 0:
+        k_splits = max(k_splits, block_k // _ONE_PASS_CHUNK)
 
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B,H,S]
     delta = jnp.broadcast_to(delta[..., None], (*delta.shape, _LANES))
 
-    dq_kernel = functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                                  causal=causal, masked=masked, squashed=squashed,
-                                  alibi=alibi, k_splits=k_splits)
-    dkv_kernel = functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-                                   causal=causal, masked=masked, squashed=squashed,
-                                   nq_total=nq, alibi=alibi, k_splits=k_splits)
+    static = dict(block_q=block_q, block_k=block_k, causal=causal, masked=masked,
+                  squashed=squashed, alibi=alibi, k_splits=k_splits)
     extra = (slopes,) if alibi else ()
-    dq_scratch = [pltpu.VMEM((block_q, D), jnp.float32)]
-    dkv_scratch = [pltpu.VMEM((block_k, D), jnp.float32),
-                   pltpu.VMEM((block_k, D), jnp.float32)]
-    dq_shape = _sds((B, H, S, D), jnp.float32, q, k, v, mask, do)
-    dkv_shape = [dq_shape, dq_shape]
+    grad = _sds((B, H, S, D), jnp.float32, q, k, v, mask, do)
 
-    def bwd_in_specs(dec):
+    def call(kernel, name, walk, dense_semantics, out_specs, scratch):
+        """One backward kernel over ``walk`` = (the squashed grid's (qi, ki)
+        enumeration, the dense grid's decoder to canonical (qi, ki) for the
+        shared specs, the dense grid)."""
+        maps, dense_dec, dense_grid = walk
+        dec = _DEC_SQUASHED if squashed else dense_dec
         qrow = _qrow_specs(dec, block_q, D)
-        return (_qkv_in_specs(dec, block_q, block_k, D, G, alibi=alibi)
-                + [qrow["qD"], qrow["qL"], qrow["qL"]])
+        in_specs = (_qkv_in_specs(dec, block_q, block_k, D, G, alibi=alibi)
+                    + [qrow["qD"], qrow["qL"], qrow["qL"]])
+        out_specs = out_specs(dec)
+        args = (mask, *extra, q, k, v, do, lse, delta)
+        common = dict(name=name, out_shape=[grad] * len(out_specs), interpret=_interpret())
+        if squashed:
+            qm, km = maps(nq)
+            return pl.pallas_call(
+                kernel,
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=2,
+                    grid=(B, H, qm.shape[0]),
+                    in_specs=in_specs,
+                    out_specs=out_specs,
+                    scratch_shapes=scratch,
+                ),
+                compiler_params=tpu_compiler_params(
+                    dimension_semantics=("parallel", "parallel", "arbitrary")),
+                **common,
+            )(qm, km, *args)
+        return pl.pallas_call(
+            kernel,
+            grid=dense_grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+            compiler_params=tpu_compiler_params(dimension_semantics=dense_semantics),
+            **common,
+        )(*args)
 
-    if squashed:
-        arb = tpu_compiler_params(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        qm, km = _tri_maps(nq)
-        dq = pl.pallas_call(
-            dq_kernel,
-            name="flash_bwd_dq",
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(B, H, qm.shape[0]),
-                in_specs=bwd_in_specs(_DEC_SQUASHED),
-                out_specs=_qrow_specs(_DEC_SQUASHED, block_q, D)["qD"],
-                scratch_shapes=dq_scratch,
-            ),
-            out_shape=dq_shape,
-            compiler_params=arb,
-            interpret=_interpret(),
-        )(qm, km, mask, *extra, q, k, v, do, lse, delta)
+    if not one_pass:
+        (dq,) = call(
+            functools.partial(_bwd_dq_kernel, **static), "flash_bwd_dq",
+            (_tri_maps, _DEC_DENSE, (B, H, nq, nk)), _PARALLEL_SEMANTICS,
+            lambda dec: [_qrow_specs(dec, block_q, D)["qD"]],
+            [pltpu.VMEM((block_q, D), jnp.float32)])
 
-        # dk/dv are per *query* head here; grouped heads are summed below.
-        wqm, wkm = _wedge_maps(nk)
-        dk, dv = pl.pallas_call(
-            dkv_kernel,
-            name="flash_bwd_dkv",
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(B, H, wqm.shape[0]),
-                in_specs=bwd_in_specs(_DEC_SQUASHED),
-                out_specs=[_kcol_spec(_DEC_SQUASHED, block_k, D)] * 2,
-                scratch_shapes=dkv_scratch,
-            ),
-            out_shape=dkv_shape,
-            compiler_params=arb,
-            interpret=_interpret(),
-        )(wqm, wkm, mask, *extra, q, k, v, do, lse, delta)
-    else:
-        dq = pl.pallas_call(
-            dq_kernel,
-            name="flash_bwd_dq",
-            grid=(B, H, nq, nk),
-            in_specs=bwd_in_specs(_DEC_DENSE),
-            out_specs=_qrow_specs(_DEC_DENSE, block_q, D)["qD"],
-            out_shape=dq_shape,
-            scratch_shapes=dq_scratch,
-            compiler_params=tpu_compiler_params(dimension_semantics=_PARALLEL_SEMANTICS),
-            interpret=_interpret(),
-        )(mask, *extra, q, k, v, do, lse, delta)
+    def dkv_out_specs(dec):
+        slab = [_spec((1, 1, S, D), lambda b, h, qi, ki: (b, h, 0, 0), dec)] if one_pass else []
+        return slab + [_kcol_spec(dec, block_k, D)] * 2
 
-        # dk/dv are per *query* head here; grouped heads are summed below. The
-        # dense dkv grid iterates (ki outer, qi inner) — _DEC_DENSE_KQ restores
-        # the canonical (qi, ki) order for the shared specs.
-        dk, dv = pl.pallas_call(
-            dkv_kernel,
-            name="flash_bwd_dkv",
-            grid=(B, H, nk, nq),
-            in_specs=bwd_in_specs(_DEC_DENSE_KQ),
-            out_specs=[_kcol_spec(_DEC_DENSE_KQ, block_k, D)] * 2,
-            out_shape=dkv_shape,
-            scratch_shapes=dkv_scratch,
-            compiler_params=tpu_compiler_params(dimension_semantics=_PARALLEL_SEMANTICS),
-            interpret=_interpret(),
-        )(mask, *extra, q, k, v, do, lse, delta)
+    # The dense dkv grid iterates (ki outer, qi inner), as the wedge does; a
+    # dq slab gathers over the key columns too, so only b and h stay parallel.
+    *dq_slab, dk, dv = call(
+        functools.partial(_bwd_dkv_kernel, nq_total=nq, one_pass=one_pass, **static),
+        "flash_bwd_dkv", (_wedge_maps, _DEC_DENSE_KQ, (B, H, nk, nq)),
+        ("parallel", "parallel", "arbitrary", "arbitrary") if one_pass else _PARALLEL_SEMANTICS,
+        dkv_out_specs, [pltpu.VMEM((block_k, D), jnp.float32)] * 2)
+    if one_pass:
+        (dq,) = dq_slab
 
     if G > 1:
         dk = dk.reshape(B, Hkv, G, S, D).sum(axis=2)
@@ -668,9 +710,11 @@ def flash_causal_attention(
     block_k = min(block_k, max(S, 8))
     # k_splits > 1 processes each block_k tile as k_splits sub-chunks with the
     # next sub-chunk's QK^T hoisted ahead of the previous one's softmax, so the
-    # MXU matmul overlaps the VPU exp2/renormalize passes (the named TF/s
-    # bottleneck, PERF.md). Pure instruction-level restructuring: identical
-    # math, A/B via tools/profile_bench.py --stage attn-sweep. A fixed k_splits must stay
+    # MXU matmul can overlap the VPU exp2/renormalize passes. Pure
+    # instruction-level restructuring: identical math. Measured on a v5e
+    # (PERF.md, PR 32) it pays only in the one-pass backward, which takes its
+    # chunks of _ONE_PASS_CHUNK columns by itself; the forward and the
+    # two-pass backward are not known to gain from it. A fixed k_splits must stay
     # valid when short sequences clamp block_k, so degrade to the largest
     # compatible divisor (sub-chunks divide block_k; >=128 lanes on hardware).
     while k_splits > 1 and (block_k % k_splits != 0

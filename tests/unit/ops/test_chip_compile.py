@@ -71,6 +71,27 @@ def test_flash_attention_fwd_bwd_gpt2_width(one_chip, monkeypatch):
     assert _compiled_kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) >= 2
 
 
+@pytest.mark.parametrize("shape,kernels", [
+    ((2, 2048, 16, 64), 2),   # pythia-410m.train.seq2048's call
+    ((1, 2048, 16, 128), 2),  # pythia-1.4b.train.zero3-4chip's, a chip
+    ((1, 8192, 4, 128), 2),   # the longest whose dq slab the one pass keeps in VMEM
+    ((1, 8192 + 512, 4, 128), 3),  # past it: the dq kernel and the dkv kernel
+], ids=["cell-410m", "cell-1.4b", "longest-one-pass", "first-two-pass"])
+def test_flash_backward_is_one_kernel_while_dq_fits_vmem(one_chip, monkeypatch, shape, kernels):
+    """Forward and backward: two Mosaic kernels while a head's fp32 dq
+    (double-buffered, beside the [512, 512] temporaries) fits the VMEM a
+    kernel may scope, three past that. A VMEM refusal shows here."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return fa.flash_causal_attention(q, k, v).astype(jnp.float32).sum()
+
+    assert _compiled_kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == kernels
+
+
 def test_flash_attention_partitions_over_four_chips(topo, monkeypatch):
     """GSPMD cannot partition a Mosaic kernel; ``ops.causal_attention`` runs
     it per shard. This is the program the fsdp=4 train step traces."""

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu.ops as ops
+from deepspeed_tpu.ops.pallas import flash_attention as fa
 from deepspeed_tpu.ops.pallas import register_all
 
 register_all()
@@ -167,6 +168,129 @@ class TestFlashAttention:
                                 num_heads=2, max_seq_len=32, attn_kwargs=kw)
         assert cfg.attn_kwargs == tuple(sorted(kw.items()))
         assert hash(cfg.attn_kwargs) is not None
+
+
+class TestFlashOnePassBackward:
+    """While a head's dq fits VMEM, ONE kernel makes dq, dk and dv from one
+    set of scores (``_one_pass_fits``: from S and D alone); past that the dq
+    kernel and the dkv kernel each form the scores. The test reaches the pair
+    at small shapes by shrinking the module's budget, never by an option."""
+
+    CASES = {"plain": {}, "gqa": dict(Hkv=2), "mask": dict(masked=True),
+             "alibi": dict(alibi=True), "gqa-mask-alibi": dict(Hkv=2, masked=True, alibi=True)}
+
+    @staticmethod
+    def _grads(q, k, v, **kw):
+        def loss(q, k, v):
+            out = fa.flash_causal_attention(q, k, v, **kw)
+            return jnp.sum(out * jnp.cos(out.astype(jnp.float32)))
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    @pytest.mark.parametrize("k_splits", [1, 2])
+    @pytest.mark.parametrize("bq,bk", [(8, 8), (16, 8)])  # squashed + dense grids
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_one_pass_gradients_are_the_two_pass_kernels(
+            self, case, bq, bk, k_splits, monkeypatch):
+        """Every block forms s, p, dp and ds by the same expressions in all
+        three kernels, and a dq row block's terms arrive in the order ki = 0,
+        1, ..., qi (sub-chunk by sub-chunk) under the wedge as under the
+        triangle. So dq, dk and dv agree TO THE BIT wherever one compiler
+        builds both sides alike: on the chip in bf16 at the cells' shapes
+        (PERF.md, PR 32) and here at XLA's default level in all 20 cases. This
+        harness compiles at ``--xla_backend_optimization_level=1`` (conftest),
+        where one case of the 20 contracts a multiply-add in one kernel's
+        fusion and not in the other's: dq off by one ulp (4.8e-7), which is
+        the tolerance. An order of summation gone wrong costs far more."""
+        from deepspeed_tpu.models.transformer import alibi_slopes
+        c = self.CASES[case]
+        B, S, H, D = 2, 40, 4, 8  # 40 pads to 48 under (16, 8)
+        q, k, v = (_rand(i, (B, S, h, D)) for i, h in enumerate((H, c.get("Hkv", H), c.get("Hkv", H))))
+        kw = dict(block_q=bq, block_k=bk, k_splits=k_splits)
+        if c.get("masked"):
+            kw["mask"] = jnp.asarray(
+                np.random.default_rng(2).integers(0, 2, (B, S)), jnp.int32).at[:, 0].set(1)
+        if c.get("alibi"):
+            kw["alibi_slopes"] = alibi_slopes(H)
+        one = self._grads(q, k, v, **kw)
+        monkeypatch.setattr(fa, "_ONE_PASS_DQ_BYTES", 0)
+        pair = self._grads(q, k, v, **kw)
+        for name, a, b in zip(("dq", "dk", "dv"), one, pair):
+            assert np.abs(np.asarray(b)).max() > 0, name
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-6, atol=2e-6,
+                                       err_msg=name)
+
+    def test_one_pass_sub_chunks_a_block_of_512_in_two(self, monkeypatch):
+        """The one pass takes a key block in chunks of 256 columns, from the
+        block's size alone; ``k_splits=2`` asks the pair for the same chunks."""
+        q, k, v, do = (_rand(i, (1, 1, 512, 8)) for i in range(4))
+        mask, slopes = jnp.ones((1, 1, 512), jnp.int32), jnp.zeros((1, fa._LANES))
+        out, lse = fa._flash_fwd(q, k, v, mask, slopes, 512, 512, True, False, False)
+        bwd = lambda k_splits: fa._flash_bwd(  # noqa: E731
+            q, k, v, mask, slopes, out, lse, do, 512, 512, True, False, False, k_splits)
+        one = bwd(1)
+        # five products a chunk, two chunks, in the diagonal's and the plain block's code
+        assert str(jax.make_jaxpr(lambda: bwd(1))()).count("dot_general") == 5 * 2 * 2
+        monkeypatch.setattr(fa, "_ONE_PASS_DQ_BYTES", 0)
+        for a, b in zip(one, bwd(2)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-6, atol=2e-6)
+
+    def test_bf16_one_pass_matches_the_pair_and_xla(self, monkeypatch):
+        B, S, H, D = 1, 64, 2, 16
+        q, k, v = (_rand(i, (B, S, H, D), jnp.bfloat16) for i in range(3))
+        one = self._grads(q, k, v, block_q=16, block_k=16)
+        f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+        ref = jax.grad(lambda q, k, v: jnp.sum((o := ops.causal_attention(q, k, v, impl="xla")) * jnp.cos(o)),
+                       argnums=(0, 1, 2))(f32(q), f32(k), f32(v))
+        monkeypatch.setattr(fa, "_ONE_PASS_DQ_BYTES", 0)
+        pair = self._grads(q, k, v, block_q=16, block_k=16)
+        for a, b, r in zip(one, pair, ref):
+            np.testing.assert_allclose(np.asarray(f32(a)), np.asarray(f32(b)), rtol=2e-2, atol=2e-2)
+            np.testing.assert_allclose(np.asarray(f32(a)), np.asarray(r), atol=0.06, rtol=0.06)
+
+    @pytest.mark.parametrize("S,D,fits", [
+        (2048, 64, True), (2048, 128, True),    # the two train cells
+        (8192, 128, True), (8192, 64, True),    # head_dim 64 pads to 128 lanes: the same slab
+        (8704, 128, False), (4096, 256, True), (4608, 256, False),
+    ])
+    def test_the_rule_reads_the_shapes_alone(self, S, D, fits):
+        """Both sides of the rule, in the lowered program: under the budget
+        ``flash_bwd_dkv`` and no ``flash_bwd_dq``; over it, both."""
+        import re
+
+        assert fa._one_pass_fits(S, D) is fits
+        x = jax.ShapeDtypeStruct((1, S, 1, D), jnp.bfloat16)
+        text = jax.jit(jax.grad(lambda q: fa.flash_causal_attention(q, q, q).astype(jnp.float32).sum())
+                       ).lower(x).as_text(debug_info=True)
+        has = lambda name: bool(re.search(r"[/(]%s[/)]" % name, text))  # noqa: E731
+        assert has("flash_fwd") and has("flash_bwd_dkv")
+        assert has("flash_bwd_dq") is (not fits)
+
+
+@pytest.mark.parametrize("reading,kernels", [
+    ("fwd", ["flash_fwd"]),
+    ("bwd", ["flash_fwd", "flash_bwd_dkv"]),
+    ("bwd_pair", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
+])
+def test_flash_kernel_bench_runs_each_reading(reading, kernels):
+    """``tools/flash_kernel_bench.py`` (PERF.md reads its chip runs) at a toy
+    shape in interpret mode: every reading runs, times the kernels it says,
+    and counts blocks and the roofline's FLOPs as the benchmark does. The
+    times themselves mean nothing here; ``main`` refuses to run off a chip."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "..", "tools", "flash_kernel_bench.py")
+    spec = importlib.util.spec_from_file_location("flash_kernel_bench", path)
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    got = bench.measure(reading, (1, 32, 2, 16), block=8, calls=2, repeats=1,
+                        device_kind="TPU v5 lite")
+    assert got["kernels"] == kernels and got["finite"]
+    assert got["blocks"] == 2 * 4 * 5 // 2  # heads x the causal triangle of 4 x 4 blocks
+    assert got["us_per_block"] == pytest.approx(1e3 * got["ms_per_call"] / got["blocks"])
+    assert got["roofline_pct"] == pytest.approx(100.0 * got["least_ms"] / got["ms_per_call"])
+    assert set(bench.SHAPES.values()) == {(2, 2048, 16, 64), (1, 2048, 16, 128)}
 
 
 class TestNorms:

@@ -1,0 +1,122 @@
+"""The flash attention kernels ALONE, at the shapes the two train cells run
+them: `pythia-410m.train.seq2048` calls them on [2, 2048, 16, 64] bf16 (micro
+batch 2, head_dim 64), `pythia-1.4b.train.zero3-4chip` on [1, 2048, 16, 128]
+a chip. Causal, no padding mask, blocks of 512: 10 score blocks a head.
+
+    chiprun -- python tools/flash_kernel_bench.py
+
+Three readings a shape: `fwd` (`_flash_fwd`), `bwd` (`_flash_bwd` as it
+chooses from the shape: one pass while a head's dq fits its VMEM budget) and
+`bwd_pair` (the same call with the budget set to nothing, so the dq kernel
+and the dkv kernel run, as they do for sequences past the budget). The
+backward readings include the call's XLA glue (delta = sum(do * o), and the
+three adds that chain one call to the next: 0.25 us a block beside the
+trace's kernel seconds). Several
+hundred calls under one jit (each call's input depends on the call before,
+so nothing is hoisted), timed on the host's clock around
+`block_until_ready`; one JSON line a reading with the time a call, the time a
+score block and the share of the roofline by the benchmark's own count and
+peaks (`benchmarks/lib/costs.py::flash_forward_cost`, `flash_backward_cost`:
+five products a block, however often a kernel forms the scores). A time comes
+only from a chip: without one this exits 1.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import sys
+import time
+from unittest import mock
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+SHAPES = {  # [B, S, H, D] of one call, by the cell that makes it
+    "pythia-410m.train.seq2048": (2, 2048, 16, 64),
+    "pythia-1.4b.train.zero3-4chip": (1, 2048, 16, 128),
+}
+READINGS = ("fwd", "bwd", "bwd_pair")
+
+
+def measure(reading: str, shape, block: int = 512, seed: int = 0, calls: int = 200,
+            repeats: int = 5, device_kind: str = "") -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.lib import costs, peaks
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    B, S, H, D = shape
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q, k, v, do = (jax.random.normal(kk, (B, H, S, D), jnp.bfloat16) for kk in keys)
+    q = q * jnp.asarray(D ** -0.5 * fa._LOG2E, q.dtype)  # as _flash_core hands it over
+    mask = jnp.ones((B, 1, S), jnp.int32)
+    slopes = jnp.zeros((H, fa._LANES), jnp.float32)
+    small = jnp.asarray(1e-3, q.dtype)
+
+    def fwd(q):
+        return fa._flash_fwd(q, k, v, mask, slopes, block, block, True, False, False)
+
+    @jax.jit
+    def many(q, do):
+        if reading == "fwd":
+            return jax.lax.fori_loop(0, calls, lambda _, q: q + fwd(q)[0] * small, q)
+        out, lse = fwd(q)
+
+        def one(_, do):
+            dq, dk, dv = fa._flash_bwd(q, k, v, mask, slopes, out, lse, do, block, block,
+                                       True, False, False)
+            return do + (dq + dk + dv).astype(do.dtype) * small
+
+        return jax.lax.fori_loop(0, calls, one, do)
+
+    # the pair is what _flash_bwd runs when the dq slab is over its budget
+    budget = 0 if reading == "bwd_pair" else fa._ONE_PASS_DQ_BYTES
+    with mock.patch.object(fa, "_ONE_PASS_DQ_BYTES", budget):
+        lowered = many.lower(q, do).as_text(debug_info=True)
+        result = jax.block_until_ready(many(q, do))
+        times = []
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            jax.block_until_ready(many(q, do))
+            times.append((time.perf_counter() - t0) / calls)
+
+    cost = costs.flash_forward_cost if reading == "fwd" else costs.flash_backward_cost
+    least, bound = costs.roofline_seconds(
+        *cost(B, H, S, D), peaks.device_peaks(device_kind or jax.devices()[0].device_kind))
+    n = -(-S // block)
+    blocks = B * H * n * (n + 1) // 2
+    call = float(np.median(times))
+    return {"reading": reading, "shape": list(shape), "block": block, "seed": seed,
+            "calls": calls, "ms_per_call": 1e3 * call, "ms_per_call_min": 1e3 * min(times),
+            "blocks": blocks, "us_per_block": 1e6 * call / blocks,
+            "least_ms": 1e3 * least, "bound": bound, "roofline_pct": 100.0 * least / call,
+            "kernels": [name for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+                        if re.search(r'[/("]%s[/)]' % name, lowered)],
+            "finite": bool(jnp.isfinite(result.astype(jnp.float32)).all())}
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--calls", type=int, default=200)
+    a = ap.parse_args()
+
+    import jax
+
+    if jax.default_backend() != "tpu":
+        print("no chip: a kernel's time comes only from a chip run", file=sys.stderr)
+        return 1
+    for cell, shape in SHAPES.items():
+        for reading in READINGS:
+            print(json.dumps({"cell": cell, **measure(reading, shape, seed=a.seed, calls=a.calls)}),
+                  flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
