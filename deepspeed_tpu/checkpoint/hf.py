@@ -27,6 +27,7 @@ import json
 import os
 from typing import Any, Callable, Dict, Optional, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.models.transformer import TransformerConfig
@@ -110,6 +111,52 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
         # qwen2 always does
         kw["qkv_bias"] = True if mt == "qwen2" else bool(hf_config.get("attention_bias", False))
         return TransformerConfig(**kw)
+    if mt == "glm4_moe_lite":
+        # latent attention, leading dense layers before the routed stack, a
+        # sigmoid router with a correction bias and a shared expert. The
+        # next-token-prediction layers (num_nextn_predict_layers) are not
+        # built: no serving path runs them
+        if hf_config.get("rope_scaling"):
+            raise ValueError("glm4_moe_lite with rope_scaling is unsupported")
+        if hf_config.get("n_group", 1) != 1 or hf_config.get("topk_group", 1) != 1:
+            raise ValueError("glm4_moe_lite with grouped expert choice (n_group > 1) is unsupported")
+        if hf_config.get("partial_rotary_factor", 1) != 1:
+            raise ValueError("glm4_moe_lite with partial_rotary_factor != 1 is unsupported")
+        dtype = hf_config.get("dtype", hf_config.get("torch_dtype"))
+        return TransformerConfig(
+            vocab_size=hf_config["vocab_size"],
+            hidden_size=hf_config["hidden_size"],
+            intermediate_size=hf_config["intermediate_size"],
+            num_layers=hf_config["num_hidden_layers"],
+            num_heads=hf_config["num_attention_heads"],
+            max_seq_len=hf_config.get("max_position_embeddings", 4096),
+            norm="rmsnorm",
+            activation="silu_glu",
+            position="rope",
+            rope_theta=float(hf_config.get("rope_theta", 10000.0)),
+            # the family's stored layout rotates adjacent pairs (its modelling
+            # code de-interleaves q and k alike before a half-split rotation:
+            # the same scores)
+            rope_interleaved=bool(hf_config.get("rope_interleave", True)),
+            norm_eps=float(hf_config.get("rms_norm_eps", 1e-5)),
+            tie_embeddings=bool(hf_config.get("tie_word_embeddings", False)),
+            qkv_bias=bool(hf_config.get("attention_bias", False)),
+            q_lora_rank=hf_config["q_lora_rank"],
+            kv_lora_rank=hf_config["kv_lora_rank"],
+            qk_nope_head_dim=hf_config["qk_nope_head_dim"],
+            qk_rope_head_dim=hf_config["qk_rope_head_dim"],
+            v_head_dim=hf_config["v_head_dim"],
+            first_dense_layers=hf_config.get("first_k_dense_replace", 0),
+            num_experts=hf_config["n_routed_experts"],
+            moe_top_k=hf_config["num_experts_per_tok"],
+            moe_intermediate_size=hf_config["moe_intermediate_size"],
+            moe_shared_experts=hf_config.get("n_shared_experts") or 0,
+            moe_router="sigmoid",
+            moe_renormalize=bool(hf_config.get("norm_topk_prob", True)),
+            moe_routed_scale=float(hf_config.get("routed_scaling_factor", 1.0)),
+            moe_drop_tokens=False,
+            param_dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(dtype, jnp.float32),
+        )
     if mt == "opt":
         if not hf_config.get("do_layer_norm_before", True):
             raise ValueError("OPT post-layernorm variants (do_layer_norm_before=false) are unsupported")
@@ -308,11 +355,14 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
         )
     raise ValueError(
         f"unsupported HF model_type {mt!r} (supported: llama/mistral/mixtral/"
-        "qwen2/gpt2/opt/falcon/phi/gpt_neox/bloom/gptj/codegen/gpt_bigcode)")
+        "qwen2/gpt2/opt/falcon/phi/gpt_neox/bloom/gptj/codegen/gpt_bigcode/"
+        "glm4_moe_lite)")
 
 
 def detect_family(state: Dict[str, np.ndarray]) -> str:
     keys = state.keys()
+    if any("kv_a_proj_with_mqa" in k for k in keys) and any("e_score_correction_bias" in k for k in keys):
+        return "glm4_moe_lite"
     if any("block_sparse_moe" in k for k in keys):
         return "mixtral"
     if any("decoder.embed_positions" in k for k in keys) and not any("encoder." in k for k in keys):
@@ -753,7 +803,121 @@ def _convert_gpt_bigcode(state, cfg: TransformerConfig) -> Dict[str, Any]:
     }
 
 
+_GLU_MATRICES = (("gate_proj", "w_gate"), ("up_proj", "w_up"), ("down_proj", "w_down"))  # HF, ours
+
+
+def _latent_moe_layer_names(cfg: TransformerConfig, dense: bool):
+    """One layer of a latent-attention routed decoder as ``(HF name under
+    model.layers.<i>., path in the layer's tree, shape of the leaf)``: torch
+    ``Linear`` stores ``[out, in]``, so a matrix is the leaf transposed and
+    reshaped. ``kv_b_proj`` is kept WHOLE (``wkv_b`` [rank, H, nope + v]);
+    serving slices its two parts where it absorbs them."""
+    h, H = cfg.hidden_size, cfg.num_heads
+    qk, nope, vd = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.qk_nope_head_dim, cfg.v_head_dim
+    rank, rope_d = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    names = [
+        ("input_layernorm.weight", ("attn_norm", "scale"), (h,)),
+        ("post_attention_layernorm.weight", ("mlp_norm", "scale"), (h,)),
+        ("self_attn.kv_a_proj_with_mqa.weight", ("attn", "wkv_a", "kernel"), (h, rank + rope_d)),
+        ("self_attn.kv_a_layernorm.weight", ("attn", "kv_norm", "scale"), (rank,)),
+        ("self_attn.kv_b_proj.weight", ("attn", "wkv_b", "kernel"), (rank, H, nope + vd)),
+        # the one matrix whose INPUT is the split side: [hidden, H*v] stored
+        ("self_attn.o_proj.weight", ("attn", "wo", "kernel"), (H * vd, h)),
+        ("self_attn.q_a_proj.weight", ("attn", "wq_a", "kernel"), (h, cfg.q_lora_rank)),
+        ("self_attn.q_a_layernorm.weight", ("attn", "q_norm", "scale"), (cfg.q_lora_rank,)),
+        ("self_attn.q_b_proj.weight", ("attn", "wq_b", "kernel"), (cfg.q_lora_rank, H, qk)),
+    ]
+    if dense:
+        f = cfg.intermediate_size
+        return names + [(f"mlp.{hf}.weight", ("mlp", ours, "kernel"), (f, h) if ours == "w_down" else (h, f))
+                        for hf, ours in _GLU_MATRICES]
+    f = cfg.expert_width
+    fs = f * cfg.moe_shared_experts
+    names += [("mlp.gate.weight", ("moe", "gate", "wg", "kernel"), (h, cfg.num_experts)),
+              ("mlp.gate.e_score_correction_bias", ("moe", "gate", "e_bias"), (cfg.num_experts,))]
+    if fs:
+        names += [(f"mlp.shared_experts.{hf}.weight", ("moe", "shared", ours, "kernel"),
+                   (fs, h) if ours == "w_down" else (h, fs)) for hf, ours in _GLU_MATRICES]
+    return names
+
+
+def _to_leaf(w, shape):
+    """A torch tensor as a leaf: a matrix [out, in] transposed to ``shape``
+    [in, out...], a vector as it is. (``wo`` is then split into heads by the
+    caller's reshape: its leaf is [H, v, hidden].)"""
+    w = np.asarray(w)
+    return (w.T if w.ndim == 2 else w).reshape(shape)
+
+
+def _from_leaf(a, shape):
+    """The inverse of :func:`_to_leaf`: ``shape[0]`` is the matrix's input side."""
+    a = np.asarray(a)
+    return a.reshape(shape) if len(shape) == 1 else a.reshape(shape[0], -1).T
+
+
+def _set(tree, path, value):
+    for key in path[:-1]:
+        tree = tree.setdefault(key, {})
+    tree[path[-1]] = value
+
+
+def _convert_glm4_moe_lite(state, cfg: TransformerConfig) -> Dict[str, Any]:
+    """Leading dense layers as ``dense_<i>``, the routed stack stacked under
+    ``layers``; the keys of layers past ``num_layers`` (the next-token-
+    prediction layer) are not read."""
+    g = _getter(state, ("",))
+
+    def layer(i):
+        p, dense = f"model.layers.{i}.", i < cfg.first_dense_layers
+        blk: Dict[str, Any] = {}
+        for hf, path, shape in _latent_moe_layer_names(cfg, dense):
+            _set(blk, path, _to_leaf(g(p + hf), shape))
+        wo = blk["attn"]["wo"]
+        wo["kernel"] = wo["kernel"].reshape(cfg.num_heads, cfg.v_head_dim, cfg.hidden_size)
+        if not dense:
+            for hf, ours in _GLU_MATRICES:
+                blk["moe"].setdefault("experts", {})[ours] = np.stack(
+                    [g(f"{p}mlp.experts.{e}.{hf}.weight").T for e in range(cfg.num_experts)])
+        return blk
+
+    D = cfg.first_dense_layers
+    params: Dict[str, Any] = {
+        "embed": {"embedding": g("model.embed_tokens.weight")},
+        "final_norm": {"scale": g("model.norm.weight")},
+        "layers": _stack(lambda i: layer(D + i), cfg.num_layers - D),
+    }
+    for i in range(D):
+        params[f"dense_{i}"] = layer(i)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = {"kernel": g("lm_head.weight").T}
+    return params
+
+
+def latent_moe_hf_state(params, cfg: TransformerConfig) -> Dict[str, np.ndarray]:
+    """The way back: a parameter tree of that family under its HF names."""
+    D = cfg.first_dense_layers
+    state = {"model.embed_tokens.weight": np.asarray(params["embed"]["embedding"]),
+             "model.norm.weight": np.asarray(params["final_norm"]["scale"])}
+    if not cfg.tie_embeddings:
+        state["lm_head.weight"] = np.asarray(params["lm_head"]["kernel"]).T
+    for i in range(cfg.num_layers):
+        dense = i < D
+        p = f"model.layers.{i}."
+        for hf, path, shape in _latent_moe_layer_names(cfg, dense):
+            leaf = params[f"dense_{i}"] if dense else params["layers"]
+            for key in path:
+                leaf = leaf[key]
+            state[p + hf] = _from_leaf(leaf if dense else leaf[i - D], shape)
+        if not dense:
+            for hf, ours in _GLU_MATRICES:
+                stacked = np.asarray(params["layers"]["moe"]["experts"][ours][i - D])
+                for e in range(cfg.num_experts):
+                    state[f"{p}mlp.experts.{e}.{hf}.weight"] = stacked[e].T
+    return state
+
+
 _CONVERTERS = {
+    "glm4_moe_lite": _convert_glm4_moe_lite,
     "llama": _convert_llama,
     "mistral": _convert_llama,
     "mixtral": _convert_llama,

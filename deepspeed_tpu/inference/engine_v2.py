@@ -262,7 +262,7 @@ class InferenceEngineV2:
         self.max_seq_len = max_len
         self.max_pages = -(-max_len // config.kv_block_size)
 
-        from deepspeed_tpu.utils.hbm import kv_blocks_for_bytes, kv_slot_bytes
+        from deepspeed_tpu.utils.hbm import kv_slot_bytes
 
         dtype = config.jax_dtype
         kv_quant = config.kv_quant
@@ -273,14 +273,27 @@ class InferenceEngineV2:
         self.kv_bytes_per_token = kv_slot_bytes(
             model_config.num_layers, model_config.kv_heads,
             model_config.dims_per_head, kv_dtype_b, kv_quant)
+        # a routed model's programs hand out the experts they sent each token
+        # to, as one more output (fetched only by *_with_picks)
+        self._routed = model_config.num_experts > 0
+        self.picks_log: Optional[List[Dict[str, Any]]] = None
+        self.last_experts_touched: Optional[float] = None
+        if model_config.latent_attention:
+            from deepspeed_tpu.inference.paged import latent_pool_width
+
+            if kv_quant is not None:
+                raise ValueError(
+                    f"kv_cache_dtype={config.kv_dtype_name!r} with latent attention: the latent "
+                    "pool has no quantized form; use a bf16 or fp32 pool")
+            # one slab a token a layer, shared by all heads (PagedKVPool)
+            self.kv_bytes_per_token = (
+                model_config.num_layers * latent_pool_width(model_config) * kv_dtype_b)
         if config.kv_pool_bytes is not None:
             # byte-budget sizing: admission capacity follows the REAL block
             # bytes, so an int8 pool at the same budget admits ~1.9x the
             # concurrent requests of a bf16 one
-            num_blocks = kv_blocks_for_bytes(
-                config.kv_pool_bytes, model_config.num_layers,
-                config.kv_block_size, model_config.kv_heads,
-                model_config.dims_per_head, kv_dtype_b, kv_quant)
+            num_blocks = max(int(config.kv_pool_bytes)
+                             // (config.kv_block_size * self.kv_bytes_per_token), 1)
         else:
             num_blocks = config.num_kv_blocks
         self.num_kv_blocks = num_blocks
@@ -297,7 +310,9 @@ class InferenceEngineV2:
                     num_blocks, config.prefix_cache_fraction))
 
         n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
-        kv_on_tp = model_config.kv_heads % mesh.shape["tp"] == 0
+        # a latent pool's row is one slab for all heads: nothing to split over tp
+        kv_on_tp = (model_config.kv_heads % mesh.shape["tp"] == 0
+                    and not model_config.latent_attention)
         # Compiled-program registry (telemetry/programs.py): the v2 step
         # programs are wrapped at build time when capture is live, and the
         # pre-flight byte estimate below doubles as the serving-scope
@@ -389,7 +404,8 @@ class InferenceEngineV2:
         kv_spec = NamedSharding(mesh, P(None, None, "tp" if kv_on_tp else None))
         replicated = NamedSharding(mesh, P())  # scales: 4/hd of the values, slot-major rows
         self.pool = PagedKVPool(
-            k=jax.device_put(pool.k, kv_spec), v=jax.device_put(pool.v, kv_spec),
+            k=jax.device_put(pool.k, kv_spec),
+            v=None if pool.v is None else jax.device_put(pool.v, kv_spec),
             k_scale=None if pool.k_scale is None else jax.device_put(pool.k_scale, replicated),
             v_scale=None if pool.v_scale is None else jax.device_put(pool.v_scale, replicated))
         log_dist(
@@ -478,10 +494,12 @@ class InferenceEngineV2:
         if key not in self._step_cache:
             cfg = self.model_config
             bs = self.config.kv_block_size
+            picks = self._routed
 
             @functools.partial(jax.jit, donate_argnums=(1,))
             def step(params, pool, tokens, positions, new_lens, block_tables):
-                return ragged_forward(params, cfg, pool, tokens, positions, new_lens, block_tables, bs)
+                return ragged_forward(params, cfg, pool, tokens, positions, new_lens, block_tables, bs,
+                                      with_picks=picks)
 
             self._step_cache[key] = self._watch(step, "step", f"r{rows}", f"c{chunk}")
         return self._step_cache[key]
@@ -497,14 +515,16 @@ class InferenceEngineV2:
             cfg = self.model_config
             bs = self.config.kv_block_size
             kw = dict(sample_kw)
+            picks = self._routed
 
             @functools.partial(jax.jit, donate_argnums=(1,))
             def step(params, pool, tokens, positions, new_lens, block_tables, rng):
-                logits, pool = ragged_forward(
-                    params, cfg, pool, tokens, positions, new_lens, block_tables, bs)
+                logits, pool, *picked = ragged_forward(
+                    params, cfg, pool, tokens, positions, new_lens, block_tables, bs,
+                    with_picks=picks)
                 rng, sub = jax.random.split(rng)
                 toks = sample_logits(logits, sub, **kw)
-                return toks, rng, pool
+                return (toks, rng, pool, *picked)
 
             self._step_cache[key] = self._watch(
                 step, "prefill", f"r{rows}", f"c{chunk}", self._kw_tag(sample_kw))
@@ -516,7 +536,7 @@ class InferenceEngineV2:
         if key not in self._step_cache:
             cfg = self.model_config
             bs = self.config.kv_block_size
-            kw = dict(sample_kw)
+            kw = dict(sample_kw, with_picks=self._routed)
 
             @functools.partial(jax.jit, donate_argnums=(1,))
             def chain(params, pool, tokens, start_pos, block_tables, active, budgets, rng):
@@ -894,16 +914,76 @@ class InferenceEngineV2:
         batch = self._build_batch(uids, token_lists)
         step = self._step_fn(batch.n_rows, batch.tokens.shape[1])
         with self._tracer.span("serve:dispatch", kind="put", rows=batch.n_rows):
-            logits, self.pool = step(
+            logits, self.pool, *picks = step(
                 self.params, self.pool,
                 jnp.asarray(batch.tokens), jnp.asarray(batch.positions),
                 jnp.asarray(batch.new_lens), jnp.asarray(batch.block_tables),
             )
         self.dispatch_count += 1
+        self._log_picks(picks, uids, None, token_lists)
         for uid, toks in zip(uids, token_lists):
             self.state.get(uid).seen_tokens += len(toks)
         self.host_sync_count += 1
         return np.asarray(logits[: len(uids)])
+
+    def _log_picks(self, picks, uids, rids, token_lists=None, emitted=None) -> None:
+        """While somebody asked (``picks_log`` is a list), note one dispatch's
+        picks, still on the device, with what places them: each row's first
+        position and how many tokens it fed (``emitted`` for a chain, whose
+        picks are ``[K, rows, routed layers, k]``). Called before
+        ``seen_tokens`` advances. Costs the serving loop one comparison."""
+        if self.picks_log is None or not picks:
+            return
+        self.picks_log.append({
+            "picks": picks[-1], "chain": emitted is not None,
+            "rids": list(range(len(uids))) if rids is None else list(rids),
+            "starts": [self.state.get(u).seen_tokens for u in uids],
+            "counts": [int(e) for e in emitted] if emitted is not None
+            else [len(t) for t in token_lists]})
+
+    def _picks_by_request(self, n_requests: int) -> List[np.ndarray]:
+        """The logged dispatches' picks, fetched and laid out a request:
+        ``[tokens fed to it, routed layers, k]`` in the order fed. A position
+        computed twice (a request preempted and prefilled again) keeps the
+        later picks, which are the ones its output came from."""
+        rows: List[Dict[int, np.ndarray]] = [{} for _ in range(n_requests)]
+        for rec in self.picks_log:
+            picks = np.asarray(rec["picks"])
+            for i, (rid, start, n) in enumerate(zip(rec["rids"], rec["starts"], rec["counts"])):
+                for t in range(n):
+                    rows[rid][start + t] = picks[t, i] if rec["chain"] else picks[i, t]
+        width = (self.model_config.routed_layers, self.model_config.moe_top_k)
+        return [np.stack([r[p] for p in sorted(r)]).astype(np.int32) if r
+                else np.zeros((0,) + width, np.int32) for r in rows]
+
+    def _with_picks(self, call, n_requests: int):
+        """``call()``'s result and, out of the dispatches it made, the picks a request."""
+        if not self._routed:
+            raise ValueError("picks asked of a model with no routed layer")
+        self.picks_log = []
+        try:
+            out = call()
+            return out, self._picks_by_request(n_requests)
+        finally:
+            self.picks_log = None
+
+    def put_with_picks(self, uids: Sequence[int], token_lists: Sequence[np.ndarray]
+                       ) -> Tuple[np.ndarray, List[np.ndarray]]:
+        """``put``, and out of the same call and compiled program the experts
+        it sent each token fed to: ``picks[i]`` int32 ``[len(token_lists[i]),
+        routed layers, k]``, leading dense layers not counted, the experts'
+        own numbers. Only a routed model has any."""
+        return self._with_picks(lambda: self.put(uids, token_lists), len(uids))
+
+    def generate_with_picks(self, prompts: Sequence[np.ndarray], max_new_tokens: int = 32,
+                            **kwargs) -> Tuple[List[np.ndarray], List[np.ndarray]]:
+        """``generate``, and out of its own fused prefill and decode chains
+        the picks of every token it fed: ``picks[i]`` int32 ``[len(prompt i) +
+        len(out i) - 1, routed layers, k]`` (the last token generated is fed
+        to nothing). The serving loop runs as it always does; the picks stay
+        on the device until it has returned."""
+        return self._with_picks(
+            lambda: self.generate(prompts, max_new_tokens=max_new_tokens, **kwargs), len(prompts))
 
     def _span_rids(self, rids: Optional[Sequence[int]]) -> str:
         """Request indices as one span arg (spans of one request share an
@@ -924,13 +1004,14 @@ class InferenceEngineV2:
                                live=len(uids), rids=self._span_rids(rids)):
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "prefill")
-            toks, rng, self.pool = step(
+            toks, rng, self.pool, *picks = step(
                 self.params, self.pool,
                 jnp.asarray(batch.tokens), jnp.asarray(batch.positions),
                 jnp.asarray(batch.new_lens), jnp.asarray(batch.block_tables),
                 rng,
             )
         self.dispatch_count += 1
+        self._log_picks(picks, uids, rids, token_lists)
         for uid, t in zip(uids, token_lists):
             self.state.get(uid).seen_tokens += len(t)
         with self._tracer.span("serve:fetch", kind="prefill"):
@@ -997,7 +1078,7 @@ class InferenceEngineV2:
                                k=k, chain=chain_id):
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "chain")
-            out, emitted, _, rng, self.pool = chain(
+            out, emitted, _, rng, self.pool, *routed = chain(
                 self.params, self.pool,
                 jnp.asarray(buf["tokens"]), jnp.asarray(buf["pos"]),
                 jnp.asarray(buf["tables"]), jnp.asarray(buf["active"]),
@@ -1007,7 +1088,13 @@ class InferenceEngineV2:
         with self._tracer.span("serve:fetch", kind="chain", chain=chain_id):
             out = np.asarray(out[:n])
             emitted = np.asarray(emitted[:n])
+            if routed:
+                # [K, routed layers] beside the tokens: the steps some row was
+                # live at read that many distinct experts a layer, on average
+                live_steps = max(int(emitted.max(initial=0)), 1)
+                self.last_experts_touched = float(np.asarray(routed[0])[:live_steps].mean())
         self.host_sync_count += 1
+        self._log_picks(routed, uids, rids, emitted=emitted)
         for uid, e in zip(uids, emitted):
             self.state.get(uid).seen_tokens += int(e)
         return out, emitted, rng
@@ -1377,7 +1464,10 @@ class InferenceEngineV2:
                     uids, last, budgets, k, rng, eos_id=eos_token_id,
                     sample_kw=sample_kw, tracker=tracker, rids=chain_rids)
             n_emitted = int(emitted.sum())
-            with span("serve:accept", kind="chain", emitted=n_emitted, chain=chain_id):
+            routed_args = ({"experts_touched": self.last_experts_touched}
+                           if self._routed and n_spec == 0 else {})
+            with span("serve:accept", kind="chain", emitted=n_emitted, chain=chain_id,
+                      **routed_args):
                 self.tokens_decoded += n_emitted
                 # serving liveness for /healthz + fleet heartbeats: a decode
                 # chain is this engine's "step" (two plain writes)
@@ -1396,6 +1486,9 @@ class InferenceEngineV2:
                     g_occ.set(len(active) / self.config.max_seqs)
                     g_free.set(float(self.state.free_blocks))
                     g_util.set(self.state.utilization)
+                    if routed_args:
+                        registry.gauge("serving/moe_experts_touched").set(
+                            self.last_experts_touched)
                     if g_pfx_hit is not None:
                         g_pfx_hit.set(pc.hit_rate)
                         g_pfx_blocks.set(float(len(pc)))

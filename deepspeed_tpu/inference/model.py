@@ -110,29 +110,58 @@ def _mlp(lp, cfg: TransformerConfig, x):
 
 
 def _moe(lp, cfg: TransformerConfig, x):
-    """MoE FFN at inference: exact top-k routing with no capacity drops.
+    """The routed feed-forward layer's output alone (``_moe_with_picks``)."""
+    return _moe_with_picks(lp, cfg, x)[0]
+
+
+def _moe_with_picks(lp, cfg: TransformerConfig, x):
+    """Routed feed-forward layer, drop-free: every token reaches its top-k
+    experts. Returns ``(out [B, S, M], picks [B*S, k] int32)``, the picks by
+    the experts' own numbers 0..E-1, in the order the tokens were given.
+
+    The router is ``parallel/moe.py::route`` (the one the flax layer
+    :class:`DropFreeMoE` runs, through this very function): softmax top-k
+    renormalised, or sigmoid scores chosen by score + ``gate/e_bias`` and
+    weighed by the unbiased scores, renormalised and scaled, as the config
+    says. A shared expert (``lp["shared"]``), where the layer has one, takes
+    every token and is added unweighted.
 
     Two dispatch regimes, chosen by the (static) token count:
 
     - decode (few tokens): compute every expert and combine with the gate
       weights — one einsum over the stacked expert params (reference
       ``moe/sharded_moe.py`` combine). At T ~ batch size, gathering by
-      expert costs more than the E/top_k extra FLOPs it saves.
+      expert costs more than the E/top_k extra FLOPs it saves, and the
+      weights of nearly every expert are read either way.
     - prefill (T >= 2E tokens): RAGGED dispatch (round 5; reference FastGen's
       ``inference/v2/kernels/ragged_ops`` moe_gather/moe_scatter +
       ``cutlass_ops`` grouped GEMM) — sort the (token, expert) pairs by
       expert and run grouped matmuls via ``lax.ragged_dot``, so prompt FFN
       FLOPs scale with top_k, not E (8x2 Mixtral-style: 4x fewer).
+
+    Device-trace scopes: ``moe_router``, ``moe_experts``, ``moe_shared``
+    (the caller opens ``moe`` around the layer).
     """
+    from deepspeed_tpu.parallel.moe import route
+
     B, S, M = x.shape
     tokens = x.reshape(B * S, M)
     T, E, k = tokens.shape[0], cfg.num_experts, cfg.moe_top_k
-    logits = tokens.astype(jnp.float32) @ lp["gate"]["wg"]["kernel"].astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)  # [T, E]
-    top_p, top_i = jax.lax.top_k(probs, k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe_router"):
+        logits = tokens.astype(jnp.float32) @ lp["gate"]["wg"]["kernel"].astype(jnp.float32)
+        top_p, top_i = route(logits, k, kind=cfg.moe_router, bias=lp["gate"].get("e_bias"),
+                             renormalize=cfg.moe_renormalize, scale=cfg.moe_routed_scale)
+    with jax.named_scope("moe_experts"):
+        out = _experts(lp["experts"], cfg, tokens, top_p, top_i)
+    if "shared" in lp:
+        with jax.named_scope("moe_shared"):
+            out = out + _mlp(lp["shared"], cfg, tokens)
+    return out.reshape(B, S, M), top_i
 
-    ep = lp["experts"]
+
+def _experts(ep, cfg: TransformerConfig, tokens, top_p, top_i):
+    """The picked experts' weighted sum for ``tokens`` [T, M]."""
+    T, E = tokens.shape[0], cfg.num_experts
     if _moe_ep_size() > 1:
         # expert-parallel serving (ISSUE 15): the ep-sharded experts are
         # reached through the explicit collective dispatch — the SAME
@@ -142,23 +171,23 @@ def _moe(lp, cfg: TransformerConfig, x):
         # reshards the ep-sharded kernels) only on non-divisible shapes.
         out = _moe_ep_collective(cfg, ep, tokens, top_p, top_i)
         if out is not None:
-            return out.reshape(B, S, M)
+            return out
     if T >= 2 * E:
-        return _moe_ragged(cfg, ep, tokens, top_p, top_i).reshape(B, S, M)
+        return _moe_ragged(cfg, ep, tokens, top_p, top_i)
 
-    gate = jnp.zeros_like(probs).at[jnp.arange(T)[:, None], top_i].set(top_p)
+    gate = jnp.zeros((T, E), jnp.float32).at[jnp.arange(T)[:, None], top_i].set(top_p)
     h1 = jnp.einsum("tm,emh->teh", tokens, ep["w_up"].astype(cfg.dtype))
     if cfg.activation == "silu_glu":
         h1 = jax.nn.silu(jnp.einsum("tm,emh->teh", tokens, ep["w_gate"].astype(cfg.dtype))) * h1
     else:
         h1 = act_fn(cfg.activation)(h1)
     out_e = jnp.einsum("teh,ehm->tem", h1, ep["w_down"].astype(cfg.dtype))
-    out = jnp.einsum("te,tem->tm", gate.astype(cfg.dtype), out_e)
-    return out.reshape(B, S, M)
+    return jnp.einsum("te,tem->tm", gate.astype(cfg.dtype), out_e)
 
 
 # The no-drop collective dispatch materializes [T*k, E, T*k] routing
-# one-hots (capacity = T*k for exactness) — quadratic in the token count.
+# one-hots (capacity = T*k for exactness) — quadratic in the token count —
+# from the shared router's picks and weights, whatever kind it is.
 # Fine at decode/short-prefill shapes; a long prefill would OOM on the
 # one-hots alone, so beyond this bound the ep>1 engine falls back to the
 # replicated ragged/dense paths (GSPMD reshards the ep-sharded kernels —

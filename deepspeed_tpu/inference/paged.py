@@ -33,7 +33,8 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.model import _apply_norm, _attn_out, _logits, _mlp, _moe, _qkv
+from deepspeed_tpu.inference.model import (_apply_norm, _attn_out, _logits, _mlp,
+                                           _moe_with_picks, _qkv)
 from deepspeed_tpu.inference.sampling import greedy_tokens, sample_logits
 from deepspeed_tpu.models.transformer import TransformerConfig
 
@@ -57,10 +58,19 @@ class PagedKVPool(NamedTuple):
     scale (fused into the paged-attention block loads). A page's scales are
     ONE lane-dense row ``[bs*kvH]`` (slot-major, the values' own order): fp32
     with a minor dim of ``kvH`` alone would pad to 128 lanes on the TPU.
-    ``None`` scales mean a full-precision pool."""
+    ``None`` scales mean a full-precision pool.
+
+    A LATENT pool (latent attention, ``TransformerConfig.kv_lora_rank > 0``)
+    is ``k`` alone, ``[L*NB, bs, W]``, and ``v`` is ``None``: a token's row is
+    one slab shared by every head, ``[latent after its norm | rotary key after
+    RoPE | zeros]``, ``W = latent_pool_width(cfg)`` = rank + rope width
+    rounded up to whole 128-lane tiles (512 + 64 -> 640: a minor dim of 576
+    pads to 640 in HBM either way, so the padding is said, not hidden). The
+    keys are the slab, the values its first ``kv_lora_rank`` columns; nothing
+    else of a token is cached. It has no quantized form."""
 
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array] = None
     k_scale: Optional[jax.Array] = None  # [L*NB, bs*kvH] fp32, or None
     v_scale: Optional[jax.Array] = None
 
@@ -78,12 +88,25 @@ class PagedKVPool(NamedTuple):
 
 
 _KV_QUANT_DTYPES = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
+_LANES = 128
+
+
+def latent_pool_width(cfg: TransformerConfig) -> int:
+    """Columns of a latent pool's row: latent + rotary key, in whole lane tiles."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // _LANES) * _LANES
 
 
 def init_pool(
     cfg: TransformerConfig, num_blocks: int, block_size: int, dtype: Any = jnp.bfloat16,
     kv_quant: Optional[str] = None,
 ) -> PagedKVPool:
+    if cfg.latent_attention:
+        if kv_quant is not None:
+            raise ValueError(
+                f"kv_quant={kv_quant!r} with latent attention: a latent pool has no quantized "
+                "form (one scale a token a layer is not carried); use a bf16/fp32 pool")
+        return PagedKVPool(k=jnp.zeros(
+            (cfg.num_layers * num_blocks, block_size, latent_pool_width(cfg)), dtype))
     shape = (cfg.num_layers * num_blocks, block_size, cfg.kv_heads * cfg.dims_per_head)
     if kv_quant is None:
         return PagedKVPool(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
@@ -208,6 +231,78 @@ def paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_size,
     )
 
 
+@register("latent_paged_attention", "xla")
+def _xla_latent_paged_attention(q, pool, block_tables, q_positions, block_size, scale, v_width,
+                                new_lens=None):
+    """Dense-gather fallback of the latent kernel (``mla_paged_attn`` in
+    ``ops/pallas/paged_attention.py``). q: [N, C, H, W] against the whole
+    slab; pool: [pages, bs, W]; a token's value is its slab's first
+    ``v_width`` columns. Returns [N, C, H, v_width]."""
+    N, C, H, W = q.shape
+    P = block_tables.shape[1]
+    slab = pool[block_tables].reshape(N, P * block_size, W)  # slot index == position
+    scores = jnp.einsum("nchw,ntw->nhct", q, slab).astype(jnp.float32) * scale
+    ok = jnp.arange(P * block_size)[None, None, :] <= q_positions[:, :, None]
+    scores = jnp.where(ok[:, None, :, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(slab.dtype)
+    return jnp.einsum("nhct,ntv->nchv", probs, slab[..., :v_width])
+
+
+def latent_paged_attention(q, pool, block_tables, q_positions, block_size, scale, v_width,
+                           new_lens=None, impl: str = "auto"):
+    import deepspeed_tpu.ops.pallas.paged_attention  # noqa: F401  (registers the kernel)
+
+    return dispatch("latent_paged_attention", impl)(
+        q, pool, block_tables, q_positions, block_size, scale, v_width, new_lens=new_lens)
+
+
+def _rms(x, scale, eps):
+    from deepspeed_tpu.ops import rms_norm
+
+    # XLA's, which fuses into its neighbours: a kernel of its own for a
+    # [rows, 512] norm costs a decode step more than the norm
+    return rms_norm(x, scale, eps=eps, impl="xla")
+
+
+def _latent_attention(ap, cfg: TransformerConfig, h, positions, new_lens, block_tables, bs,
+                      pk, put_values, first_page):
+    """Latent attention of new tokens against the latent pool, in the
+    ABSORBED form: the pool holds a token's normed latent and its rotary key,
+    the key up-projection is folded into the query (``q_lat = q_nope @
+    W_UK^T``) and the value up-projection applied to the attended latent
+    (``o = (P @ c_kv) @ W_UV``), so no per-head key or value of a cached token
+    is ever formed. The same mathematics as ``models/transformer.py``'s
+    ``LatentAttention``. Returns (attention output [N, C, E], the pool)."""
+    from deepspeed_tpu.models.transformer import rope_at
+
+    rank, nope, rope_d = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    W = pk.shape[-1]
+    dt = cfg.dtype
+    with jax.named_scope("mla"):
+        c_q = _rms(h @ ap["wq_a"]["kernel"].astype(dt), ap["q_norm"]["scale"], cfg.norm_eps)
+        q = jnp.einsum("ncr,rhd->nchd", c_q, ap["wq_b"]["kernel"].astype(dt))
+        kv = h @ ap["wkv_a"]["kernel"].astype(dt)  # [N, C, rank + rope]
+        c_kv = _rms(kv[..., :rank], ap["kv_norm"]["scale"], cfg.norm_eps)
+        k_rope = rope_at(kv[..., None, rank:], positions, cfg.rope_theta, cfg.rope_interleaved)[..., 0, :]
+        q_rope = rope_at(q[..., nope:], positions, cfg.rope_theta, cfg.rope_interleaved)
+        w_kvb = ap["wkv_b"]["kernel"].astype(dt)  # [rank, H, nope + v], kept whole
+        q_lat = jnp.einsum("nchd,rhd->nchr", q[..., :nope], w_kvb[..., :nope])
+
+        def slab(latent, rope):  # [latent | rotary | zeros up to the pool's width]
+            pad = [jnp.zeros(latent.shape[:-1] + (W - rank - rope_d,), dt)] * (W > rank + rope_d)
+            return jnp.concatenate([latent, rope] + pad, axis=-1)
+
+        q_slab, row = slab(q_lat, q_rope), slab(c_kv, k_rope)
+    with jax.named_scope("kv_write"):
+        pk = put_values(pk, row.astype(pk.dtype).reshape(-1, W), first_page)
+    o_lat = latent_paged_attention(q_slab, pk, block_tables + first_page, positions, bs,
+                                   (nope + rope_d) ** -0.5, rank, new_lens=new_lens)
+    with jax.named_scope("mla"):
+        o = jnp.einsum("nchr,rhv->nchv", o_lat, w_kvb[..., nope:])
+        out = jnp.einsum("nchv,hve->nce", o, ap["wo"]["kernel"].astype(dt))
+    return out, pk
+
+
 def _forward_hidden(
     params,
     cfg: TransformerConfig,
@@ -218,10 +313,23 @@ def _forward_hidden(
     block_tables: jax.Array,  # [N, P] int32
     block_size: int,
     all_positions: bool = False,
-) -> Tuple[jax.Array, PagedKVPool]:
+    with_picks: bool = False,
+) -> Tuple[jax.Array, ...]:
     """One mixed prefill/decode layer-stack pass -> (last-token hidden [N, E],
     pool). Shared by the single-step ``ragged_forward`` and the K-step
     ``ragged_decode_chain`` — one definition of the serving transformer math.
+
+    ``with_picks=True`` on a routed model (``num_experts > 0``) returns a
+    third value, ``picks`` int32 ``[N, C, routed layers, k]``: the experts
+    each token fed was sent to in each routed layer, leading dense layers not
+    counted, by the experts' own numbers (pad tokens' entries are garbage).
+    A model with no routed layer returns the pair whatever is asked.
+
+    A model with ``first_dense_layers`` runs those first, each from its own
+    ``params["dense_<i>"]``, then scans the routed stack ``params["layers"]``
+    with the pool in the carry; layer ``l``'s pages are rows ``l*NB ...`` of
+    the pool either way. With latent attention the pool is the latent pool
+    (:class:`PagedKVPool`) and attention runs absorbed (``_latent_attention``).
 
     ``all_positions=True`` returns the full ``[N, C, E]`` hidden states
     instead of the last-token selection — the speculative verify step needs
@@ -257,6 +365,9 @@ def _forward_hidden(
         raise ValueError("ragged inference requires scan_layers=True stacked params")
 
     quant = pool.quant  # static at trace time (value dtype + scale presence)
+    latent = cfg.latent_attention
+    routed = with_picks and cfg.num_experts > 0
+    D = cfg.first_dense_layers  # leading dense layers, run before the scan
 
     # Values go in a token's row at a time when a sequence brings fewer
     # tokens than a page holds (decode, drafts: a 4 KB row against a 64 KB
@@ -272,16 +383,11 @@ def _forward_hidden(
             return put_pages(a, new, first_page)
         return a.at[first_page + w_page, w_slot].set(new, mode="drop")
 
-    # Scopes for a device trace (HLO metadata only). ``pool_scan`` encloses
-    # the layer scan; everything the body computes sits under ``layer`` (or
-    # under ``kv_write`` or the kernel's own name inside it), so what reads
-    # ``pool_scan`` innermost is the scan's own traffic. The pool rides in
-    # the carry and is updated in place, so that should be next to nothing.
-    @jax.named_scope("layer")
-    def body(carry, xs):
-        x, pk, pv, psk, psv = carry
-        lp, first_page = xs  # the layer's params, and layer * NB
-        h = _apply_norm(lp["attn_norm"], cfg, x)
+    def attention(lp, h, x, pk, pv, psk, psv, first_page):
+        if latent:
+            out, pk = _latent_attention(lp["attn"], cfg, h, positions, new_lens, block_tables,
+                                        bs, pk, put_values, first_page)
+            return out, pk, pv, psk, psv
         q, k, v = _qkv(lp["attn"], cfg, h)
         if cfg.position == "rope":
             from deepspeed_tpu.models.transformer import apply_qk_rope
@@ -305,32 +411,55 @@ def _forward_hidden(
         ctx = paged_attention(q, pk, pv, block_tables + first_page, positions, bs,
                               new_lens=new_lens, alibi_slopes=alibi,
                               k_scale=psk, v_scale=psv)
-        attn_out = _attn_out(lp["attn"], cfg, ctx)
+        return _attn_out(lp["attn"], cfg, ctx), pk, pv, psk, psv
+
+    def ffn(lp, h, dense):
+        """(output, picks or None): the dense MLP, or the routed layer with
+        the experts it sent each token to."""
+        if cfg.num_experts > 0 and not dense:
+            with jax.named_scope("moe"):
+                out, picks = _moe_with_picks(lp["moe"], cfg, h)
+            return out, picks if routed else None
+        return _mlp(lp["mlp"], cfg, h), None
+
+    # Scopes for a device trace (HLO metadata only). ``pool_scan`` encloses
+    # the layer scan; everything the body computes sits under ``layer`` (or
+    # under ``kv_write`` or the kernel's own name inside it), so what reads
+    # ``pool_scan`` innermost is the scan's own traffic. The pool rides in
+    # the carry and is updated in place, so that should be next to nothing.
+    @jax.named_scope("layer")
+    def layer(carry, lp, first_page, dense=False):
+        x, pk, pv, psk, psv = carry
+        h = _apply_norm(lp["attn_norm"], cfg, x)
+        attn_out, pk, pv, psk, psv = attention(lp, h, x, pk, pv, psk, psv, first_page)
         if cfg.parallel_block:
             # falcon/phi-style: attn and FFN read the shared input norm;
             # gpt-neox-style (parallel_mlp_norm): FFN reads its own ln2(x)
             ffn_in = _apply_norm(lp["mlp_norm"], cfg, x) if cfg.parallel_mlp_norm else h
-            ffn = _moe(lp["moe"], cfg, ffn_in) if cfg.num_experts > 0 else _mlp(lp["mlp"], cfg, ffn_in)
-            return (x + attn_out + ffn, pk, pv, psk, psv), None
+            out, picks = ffn(lp, ffn_in, dense)
+            return (x + attn_out + out, pk, pv, psk, psv), picks
         x = x + attn_out
-        h = _apply_norm(lp["mlp_norm"], cfg, x)
-        if cfg.num_experts > 0:
-            x = x + _moe(lp["moe"], cfg, h)
-        else:
-            x = x + _mlp(lp["mlp"], cfg, h)
-        return (x, pk, pv, psk, psv), None
+        out, picks = ffn(lp, _apply_norm(lp["mlp_norm"], cfg, x), dense)
+        return (x + out, pk, pv, psk, psv), picks
 
+    carry = (x, *pool)
+    for i in range(D):
+        # a leading dense layer of a routed model: its own parameters, its own
+        # pages (layer i's), outside the scan
+        carry, _ = layer(carry, params[f"dense_{i}"], jnp.int32(i * NB), dense=True)
     with jax.named_scope("pool_scan"):
-        (x, *pool), _ = jax.lax.scan(
-            body, (x, *pool), (params["layers"], jnp.arange(L, dtype=jnp.int32) * NB))
+        (x, *pool), picks = jax.lax.scan(
+            lambda c, xs: layer(c, *xs), carry,
+            (params["layers"], jnp.arange(D, L, dtype=jnp.int32) * NB))
     pool = PagedKVPool(*pool)
+    # picks: [routed layers, N*C, k] -> [N, C, routed layers, k]
+    picks = None if picks is None else jnp.moveaxis(picks, 0, 1).reshape(N, C, L - D, -1)
 
-    if all_positions:
-        return x, pool  # [N, C, E]
-    last = jnp.take_along_axis(
-        x, jnp.maximum(new_lens - 1, 0)[:, None, None], axis=1
-    )[:, 0]  # [N, E]
-    return last, pool
+    if not all_positions:
+        x = jnp.take_along_axis(
+            x, jnp.maximum(new_lens - 1, 0)[:, None, None], axis=1
+        )[:, 0]  # [N, E]
+    return (x, pool, picks) if routed else (x, pool)
 
 
 def ragged_forward(
@@ -342,8 +471,11 @@ def ragged_forward(
     new_lens: jax.Array,  # [N] int32
     block_tables: jax.Array,  # [N, P] int32
     block_size: int,
-) -> Tuple[jax.Array, PagedKVPool]:
-    """One mixed prefill/decode step -> (last-token logits [N, V], pool).
+    with_picks: bool = False,
+) -> Tuple[jax.Array, ...]:
+    """One mixed prefill/decode step -> (last-token logits [N, V], pool), and
+    for a routed model asked ``with_picks`` the picks ``[N, C, routed layers,
+    k]`` as a third value (``_forward_hidden``).
 
     Reference analog: the whole FastGen model forward over a
     ``RaggedBatchWrapper`` (``inference/v2/engine_v2.py:107`` → model
@@ -351,9 +483,10 @@ def ragged_forward(
     LM head run on the [N, E] last-token hiddens only (norm is positionwise,
     so selecting first is the same math at 1/C the head cost).
     """
-    last, pool = _forward_hidden(
-        params, cfg, pool, tokens, positions, new_lens, block_tables, block_size)
-    return _logits(params, cfg, last), pool
+    last, pool, *picks = _forward_hidden(
+        params, cfg, pool, tokens, positions, new_lens, block_tables, block_size,
+        with_picks=with_picks)
+    return (_logits(params, cfg, last), pool, *picks)
 
 
 def ragged_decode_chain(
@@ -374,7 +507,8 @@ def ragged_decode_chain(
     temperature: float = 1.0,
     top_k: int = 0,
     top_p: float = 1.0,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, PagedKVPool]:
+    with_picks: bool = False,
+) -> Tuple[jax.Array, ...]:
     """K decode iterations + on-device sampling as ONE compiled program.
 
     The serving fast path: the host dispatches once and fetches once per K
@@ -391,6 +525,12 @@ def ragged_decode_chain(
     ``out_tokens[i, :emitted[i]]`` are valid and ``emitted[i]`` is also the
     number of KV slots row i consumed (== seen_tokens advance).
 
+    A routed model asked ``with_picks`` returns two more: ``touched`` int32
+    ``[K, routed layers]``, how many distinct experts the rows live at a step
+    picked in each routed layer (what a step has to read of the experts), and
+    ``picks`` int32 ``[K, N, routed layers, k]``, the experts each step's
+    input token was sent to (rows not live at a step: garbage).
+
     Observability contract: the chain boundary is the host's ONLY visibility
     quantum — the K in-scan tokens carry no host timestamps by design, so
     per-token latency (TPOT) is derived as (boundary delta) / ``emitted``
@@ -402,9 +542,9 @@ def ragged_decode_chain(
     def step(carry, _):
         pool, tok, pos, live, emitted, key = carry
         new_lens = live.astype(jnp.int32)
-        last, pool = _forward_hidden(
+        last, pool, *picks = _forward_hidden(
             params, cfg, pool, tok[:, None], pos[:, None], new_lens,
-            block_tables, block_size)
+            block_tables, block_size, with_picks=with_picks)
         logits = _logits(params, cfg, last)
         key, sub = jax.random.split(key)
         nxt = sample_logits(logits, sub, do_sample=do_sample,
@@ -414,13 +554,21 @@ def ragged_decode_chain(
         still = live & (emitted < budgets)
         if eos_id is not None:
             still = still & (nxt != eos_id)
-        return (pool, jnp.where(live, nxt, tok), pos + new_lens, still,
-                emitted, key), out
+        carry = (pool, jnp.where(live, nxt, tok), pos + new_lens, still, emitted, key)
+        if not picks:
+            return carry, out
+        picked = picks[0][:, 0]  # [N, routed layers, k]
+        hit = jax.nn.one_hot(picked, cfg.num_experts, dtype=jnp.bool_) & live[:, None, None, None]
+        touched = hit.any(axis=(0, 2)).sum(axis=-1).astype(jnp.int32)  # [routed layers]
+        return carry, (out, touched, picked)
 
     carry0 = (pool, tokens, start_pos, active,
               jnp.zeros_like(start_pos), rng)
     (pool, _, _, active, emitted, rng), outs = jax.lax.scan(
         step, carry0, None, length=k_steps)
+    if isinstance(outs, tuple):
+        outs, touched, picks = outs
+        return outs.T, emitted, active, rng, pool, touched, picks
     return outs.T, emitted, active, rng, pool
 
 

@@ -20,6 +20,7 @@ model is a first-class Flax module designed for TPU:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -146,8 +147,50 @@ class TransformerConfig:
     # the engine's controller) — capacity moves between steps with the jit
     # cache staying at one program.
     moe_capacity_factor_max: Optional[float] = None
+    # How a routed layer scores and weighs its experts (parallel/moe.py
+    # ``route``): "softmax" takes the top-k of a softmax and renormalises
+    # them; "sigmoid" takes sigmoid scores, CHOOSES by score + a per-expert
+    # correction bias (a parameter, ``e_bias``) and weighs by the unbiased
+    # scores, renormalised if ``moe_renormalize``, times ``moe_routed_scale``.
+    # A sigmoid-routed layer is drop-free in training and serving alike.
+    moe_router: str = "softmax"
+    moe_renormalize: bool = True
+    moe_routed_scale: float = 1.0
+    # width of one routed (and one shared) expert; None => intermediate_size
+    moe_intermediate_size: Optional[int] = None
+    # experts every token visits beside its routed ones, as ONE silu-GLU of
+    # moe_shared_experts x the expert width, added unweighted
+    moe_shared_experts: int = 0
+    # leading layers with a dense MLP (width intermediate_size) before the
+    # routed stack: built outside the layer scan as ``dense_<i>``, the
+    # ``num_layers - first_dense_layers`` routed ones scanned as ``layers``
+    first_dense_layers: int = 0
+    # Latent attention (kv_lora_rank > 0): queries through a rank-q_lora_rank
+    # bottleneck with a norm, keys and values
+    # up-projected per head from ONE normed rank-kv_lora_rank latent a token,
+    # beside ONE rotary key of qk_rope_head_dim shared by all heads. A head's
+    # query/key is [qk_nope_head_dim | qk_rope_head_dim], its value
+    # v_head_dim; scores scale by the whole query width. Serving caches the
+    # latent and the rotary key alone (inference/paged.py).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # dtype the parameters are CREATED in (a checkpoint's own, where it says)
+    param_dtype: Any = jnp.float32
 
     def __post_init__(self):
+        if self.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_router must be softmax|sigmoid, got {self.moe_router!r}")
+        if self.kv_lora_rank and not self.q_lora_rank:
+            raise ValueError("latent attention (kv_lora_rank > 0) needs q_lora_rank > 0: a plain "
+                             "query projection beside latent keys and values is not built")
+        if self.first_dense_layers and not (0 < self.first_dense_layers < self.num_layers
+                                            and self.num_experts > 0):
+            raise ValueError(
+                f"first_dense_layers={self.first_dense_layers} needs a routed stack after it "
+                f"(num_layers={self.num_layers}, num_experts={self.num_experts})")
         if self.moe_layer_experts is not None and len(self.moe_layer_experts) != self.num_layers:
             raise ValueError(
                 f"moe_layer_experts has {len(self.moe_layer_experts)} entries "
@@ -179,7 +222,7 @@ class TransformerConfig:
     def experts_for_layer(self, i: int) -> int:
         if self.moe_layer_experts is not None:
             return self.moe_layer_experts[i]
-        return self.num_experts
+        return 0 if i < self.first_dense_layers else self.num_experts
 
     @property
     def has_moe(self) -> bool:
@@ -203,6 +246,21 @@ class TransformerConfig:
         return self.num_kv_heads or self.num_heads
 
     @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def routed_layers(self) -> int:
+        """Layers with a router, in the order their picks are handed out."""
+        if self.moe_layer_experts is not None:
+            return self.num_moe_layers
+        return self.num_layers - self.first_dense_layers if self.num_experts > 0 else 0
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
     def dims_per_head(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
@@ -214,22 +272,36 @@ class TransformerConfig:
         attn = 12 * self.num_layers * self.hidden_size * seq_len  # score+value matmuls
         return 6 * n + attn
 
-    def _mlp_params(self) -> int:
+    def _mlp_params(self, width: Optional[int] = None) -> int:
         """One MLP's (one expert's) parameter count."""
         proj = 3 if self.activation == "silu_glu" else 2
-        return proj * self.hidden_size * self.intermediate_size
+        return proj * self.hidden_size * (width or self.intermediate_size)
+
+    def _attention_params(self) -> int:
+        h, H = self.hidden_size, self.num_heads
+        if self.latent_attention:
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            q = h * self.q_lora_rank + self.q_lora_rank * (H * qk + 1)
+            kv = (h * (self.kv_lora_rank + self.qk_rope_head_dim) + self.kv_lora_rank
+                  + self.kv_lora_rank * H * (self.qk_nope_head_dim + self.v_head_dim))
+            return q + kv + H * self.v_head_dim * h
+        hd = self.dims_per_head
+        return h * hd * (H + 2 * self.kv_heads) + hd * H * h
 
     def num_params(self) -> int:
         h, v, l = self.hidden_size, self.vocab_size, self.num_layers
-        hd = self.dims_per_head
-        qkv = h * hd * (self.num_heads + 2 * self.kv_heads) + hd * self.num_heads * h
+        qkv = self._attention_params()
         mlp = self._mlp_params()
+        expert = self._mlp_params(self.expert_width)
         total = v * h * (1 if self.tie_embeddings else 2)  # embedding (+ head)
         total += h  # final norm
         for i in range(l):
             n_exp = self.experts_for_layer(i)
             if n_exp > 0:
-                layer_mlp = n_exp * mlp + h * n_exp  # experts + router
+                layer_mlp = n_exp * expert + h * n_exp  # experts + router
+                layer_mlp += self.moe_shared_experts * expert
+                if self.moe_router == "sigmoid":
+                    layer_mlp += n_exp  # the correction bias
                 if self.moe_use_residual:
                     layer_mlp += mlp + 2 * h + 2  # residual MLP + coefficient gate
             else:
@@ -241,7 +313,7 @@ class TransformerConfig:
         """Params a single token touches (top-k experts instead of all)."""
         if not self.has_moe:
             return self.num_params()
-        mlp = self._mlp_params()
+        mlp = self._mlp_params(self.expert_width)
         dead = 0
         for i in range(self.num_layers):
             n_exp = self.experts_for_layer(i)
@@ -279,19 +351,20 @@ def act_fn(name: str):
 
 class RMSNorm(nn.Module):
     eps: float = 1e-5
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x):
         from deepspeed_tpu.ops import rms_norm
 
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), self.param_dtype)
         return rms_norm(x, scale, eps=self.eps)
 
 
 def _norm(config: TransformerConfig, name: str):
     if config.norm == "rmsnorm":
-        return RMSNorm(eps=config.norm_eps, name=name)
-    return nn.LayerNorm(epsilon=config.norm_eps, name=name)
+        return RMSNorm(eps=config.norm_eps, param_dtype=config.param_dtype, name=name)
+    return nn.LayerNorm(epsilon=config.norm_eps, param_dtype=config.param_dtype, name=name)
 
 
 def rope_tables(seq_len: int, dim: int, theta: float) -> Tuple[jax.Array, jax.Array]:
@@ -367,11 +440,11 @@ class Attention(nn.Module):
         hd = cfg.dims_per_head
         qkv_bias = cfg.qkv_bias if cfg.qkv_bias is not None else cfg.norm == "layernorm"
         q = nn.DenseGeneral((cfg.num_heads, hd), use_bias=qkv_bias,
-                            dtype=cfg.dtype, name="wq")(x)
+                            dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wq")(x)
         k = nn.DenseGeneral((cfg.kv_heads, hd), use_bias=qkv_bias,
-                            dtype=cfg.dtype, name="wk")(x)
+                            dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wk")(x)
         v = nn.DenseGeneral((cfg.kv_heads, hd), use_bias=qkv_bias,
-                            dtype=cfg.dtype, name="wv")(x)
+                            dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wv")(x)
 
         if cfg.position == "rope":
             q, k = apply_qk_rope(cfg, q, k, positions)
@@ -448,10 +521,70 @@ class Attention(nn.Module):
             out = ulysses_unshard(out)
         dense_bias = cfg.dense_bias if cfg.dense_bias is not None else cfg.norm == "layernorm"
         out = nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=dense_bias,
-                              dtype=cfg.dtype, name="wo")(out)
+                              dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wo")(out)
         if cfg.dropout > 0:
             out = nn.Dropout(cfg.dropout, deterministic=not train)(out)
         return out
+
+
+def rope_at(x: jax.Array, positions: jax.Array, theta: float, interleaved: bool) -> jax.Array:
+    """Rotary embedding over the whole last dim of ``x`` [..., S, H, D] at
+    ``positions`` [..., S], the angles computed from the positions themselves
+    (no table of ``max_seq_len`` rows: a context of 200k would make one of
+    6.5M entries for every call). fp32 inside, ``x``'s dtype out."""
+    d = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angles = positions.astype(jnp.float32)[..., None, None] * inv_freq  # [..., S, 1, D/2]
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).reshape(x.shape)
+    else:
+        x1, x2 = xf[..., : d // 2], xf[..., d // 2:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
+class LatentAttention(nn.Module):
+    """Latent attention over a full sequence, the plain (non-absorbed) way:
+    keys and values are up-projected per head from the normed latent and the
+    heads attend as usual (``TransformerConfig.kv_lora_rank``). Serving keeps
+    the latent and the shared rotary key alone and absorbs the up-projections
+    into the query and the output (``inference/paged.py``); both read these
+    parameters: ``wq_a``/``q_norm``/``wq_b``, ``wkv_a``/``kv_norm``,
+    ``wkv_b`` [rank, H, nope + v] kept whole, ``wo``."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, mask, positions, train: bool):
+        cfg = self.config
+        H, nope, rope_d, vd = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        dense = functools.partial(nn.DenseGeneral, use_bias=False, dtype=cfg.dtype,
+                                  param_dtype=cfg.param_dtype)
+        c_q = _norm(cfg, "q_norm")(dense(cfg.q_lora_rank, name="wq_a")(x))
+        q = dense((H, nope + rope_d), name="wq_b")(c_q)
+        kv = dense(cfg.kv_lora_rank + rope_d, name="wkv_a")(x)
+        c_kv = _norm(cfg, "kv_norm")(kv[..., : cfg.kv_lora_rank])
+        k_rope = rope_at(kv[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta,
+                         cfg.rope_interleaved)  # ONE head, shared by all
+        q_rope = rope_at(q[..., nope:], positions, cfg.rope_theta, cfg.rope_interleaved)
+        kv_up = dense((H, nope + vd), name="wkv_b")(c_kv)
+        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        k = jnp.concatenate([kv_up[..., :nope], jnp.broadcast_to(k_rope, q_rope.shape)], axis=-1)
+        v = kv_up[..., nope:]
+
+        from deepspeed_tpu.ops import causal_attention
+
+        # the kernels take one head size for q, k and v: pad the values up to
+        # the keys' width where they differ (zeros add nothing to p @ v)
+        width = nope + rope_d
+        if vd < width:
+            v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, width - vd)))
+        out = causal_attention(q, k, v, mask=mask, impl=cfg.attn_impl,
+                               **dict(cfg.attn_kwargs or ()))[..., :vd]
+        return dense(cfg.hidden_size, axis=(-2, -1), name="wo")(out)
 
 
 class MLP(nn.Module):
@@ -463,13 +596,17 @@ class MLP(nn.Module):
         bias = cfg.mlp_bias if cfg.mlp_bias is not None else (
             cfg.dense_bias if cfg.dense_bias is not None else cfg.norm == "layernorm")
         if cfg.activation == "silu_glu":
-            gate = nn.Dense(cfg.intermediate_size, use_bias=bias, dtype=cfg.dtype, name="w_gate")(x)
-            up = nn.Dense(cfg.intermediate_size, use_bias=bias, dtype=cfg.dtype, name="w_up")(x)
+            gate = nn.Dense(cfg.intermediate_size, use_bias=bias, dtype=cfg.dtype,
+                            param_dtype=cfg.param_dtype, name="w_gate")(x)
+            up = nn.Dense(cfg.intermediate_size, use_bias=bias, dtype=cfg.dtype,
+                            param_dtype=cfg.param_dtype, name="w_up")(x)
             h = nn.silu(gate) * up
         else:
-            h = nn.Dense(cfg.intermediate_size, use_bias=bias, dtype=cfg.dtype, name="w_up")(x)
+            h = nn.Dense(cfg.intermediate_size, use_bias=bias, dtype=cfg.dtype,
+                            param_dtype=cfg.param_dtype, name="w_up")(x)
             h = act_fn(cfg.activation)(h)
-        out = nn.Dense(cfg.hidden_size, use_bias=bias, dtype=cfg.dtype, name="w_down")(h)
+        out = nn.Dense(cfg.hidden_size, use_bias=bias, dtype=cfg.dtype,
+                            param_dtype=cfg.param_dtype, name="w_down")(h)
         if cfg.dropout > 0:
             out = nn.Dropout(cfg.dropout, deterministic=not train)(out)
         return out
@@ -481,10 +618,12 @@ class Block(nn.Module):
     config: TransformerConfig
     train: bool = False
     layer_idx: int = 0  # selects the pyramid expert count (PR-MoE)
+    dense: bool = False  # a leading dense layer of a routed model (first_dense_layers)
 
     @nn.compact
     def __call__(self, carry, _=None):
         cfg = self.config
+        attn_cls = LatentAttention if cfg.latent_attention else Attention
         cap_scale = None
         if cfg.moe_dynamic_capacity:
             # dynamic capacity rides the carry as a traced fp32 scalar (the
@@ -498,20 +637,29 @@ class Block(nn.Module):
             # separate ln2(x) (gpt-neox parallel_mlp_norm)
             x_in = x
             h = _norm(cfg, "attn_norm")(x_in)
-            x = x + Attention(cfg, name="attn")(h, mask, positions, self.train)
+            x = x + attn_cls(cfg, name="attn")(h, mask, positions, self.train)
             if cfg.parallel_mlp_norm:
                 h = _norm(cfg, "mlp_norm")(x_in)
         else:
-            x = x + Attention(cfg, name="attn")(
+            x = x + attn_cls(cfg, name="attn")(
                 _norm(cfg, "attn_norm")(x), mask, positions, self.train
             )
             h = _norm(cfg, "mlp_norm")(x)
-        n_exp = cfg.experts_for_layer(self.layer_idx)
+        # the scanned stack is built with layer_idx 0, so a leading dense
+        # layer says so itself and is not looked up by its index
+        n_exp = 0 if self.dense else (cfg.moe_layer_experts[self.layer_idx]
+                                      if cfg.moe_layer_experts is not None else cfg.num_experts)
         # moe_metrics rides the aux carry as (scalar, stats-dict) — the
         # structure is decided once by CausalLM (dense layers pass it through
         # untouched, so the scan carry stays consistent across the stack)
         collect = cfg.moe_metrics and self.train and cfg.has_moe
-        if n_exp > 0:
+        if n_exp > 0 and cfg.moe_router == "sigmoid":
+            # drop-free by construction: no capacity, no auxiliary loss
+            from deepspeed_tpu.parallel.moe import DropFreeMoE
+
+            with jax.named_scope("moe"):
+                x = x + DropFreeMoE(cfg, name="moe")(h)
+        elif n_exp > 0:
             from deepspeed_tpu.parallel.moe import MoEConfig, MoELayer
 
             moe_cfg = MoEConfig(
@@ -557,11 +705,12 @@ class _HeadKernel(nn.Module):
 
     hidden: int
     vocab: int
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self):
         return self.param(
-            "kernel", nn.initializers.lecun_normal(), (self.hidden, self.vocab)
+            "kernel", nn.initializers.lecun_normal(), (self.hidden, self.vocab), self.param_dtype
         )
 
 
@@ -584,7 +733,8 @@ class CausalLM(nn.Module):
         pad_mask = batch.get("attention_mask")  # [B, S] 1=keep
 
         embed_cls = _SparseGradEmbed if cfg.sparse_embedding_grads else nn.Embed
-        x = embed_cls(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype, name="embed")(ids)
+        x = embed_cls(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+                      param_dtype=cfg.param_dtype, name="embed")(ids)
         if cfg.embed_norm:
             x = _norm(cfg, "embed_norm")(x)
         if cfg.position == "learned":
@@ -623,18 +773,20 @@ class CausalLM(nn.Module):
                    else jnp.asarray(cap, jnp.float32).reshape(()))
             carry = carry + (cap,)
         if cfg.scan_layers:
+            for i in range(cfg.first_dense_layers):  # outside the scan, each its own tree
+                carry, _ = block_cls(cfg, train, dense=True, name=f"dense_{i}")(carry, None)
             stack = nn.scan(
                 block_cls,
                 variable_axes={"params": 0},
                 split_rngs={"params": True, "dropout": True},
-                length=cfg.num_layers,
+                length=cfg.num_layers - cfg.first_dense_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(cfg, train, name="layers")
             carry, _ = stack(carry, None)
         else:
             for i in range(cfg.num_layers):
-                carry, _ = block_cls(cfg, train, layer_idx=i, name=f"layer_{i}")(
-                    carry, None)
+                carry, _ = block_cls(cfg, train, layer_idx=i, dense=i < cfg.first_dense_layers,
+                                     name=f"layer_{i}")(carry, None)
         x, aux = carry[0], carry[3]
 
         moe_stats = None
@@ -661,7 +813,7 @@ class CausalLM(nn.Module):
                 if cfg.tie_embeddings:
                     head = self.variables["params"]["embed"]["embedding"]  # [V, h]
                 else:
-                    head = _HeadKernel(cfg.hidden_size, cfg.vocab_size, name="lm_head")().T
+                    head = _HeadKernel(cfg.hidden_size, cfg.vocab_size, cfg.param_dtype, name="lm_head")().T
                 loss = lm_head_cross_entropy(x, head.astype(cfg.dtype), labels, pad_mask)
                 logits = None
             else:
@@ -669,8 +821,8 @@ class CausalLM(nn.Module):
                     embed = self.variables["params"]["embed"]["embedding"]
                     logits = x @ embed.T.astype(cfg.dtype)
                 else:
-                    logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias,
-                                      dtype=cfg.dtype, name="lm_head")(x)
+                    logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias, dtype=cfg.dtype,
+                                      param_dtype=cfg.param_dtype, name="lm_head")(x)
                 loss = cross_entropy_loss(logits, labels, pad_mask)
         if cfg.has_moe:
             # aux is pre-weighted by MoELayer; average over layers

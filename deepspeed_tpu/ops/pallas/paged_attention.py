@@ -410,3 +410,184 @@ def flash_decode_paged(
     else:
         out = out[:, :, :Cg].reshape(N, kvH, C, G, hd).transpose(0, 2, 1, 3, 4)
     return out.reshape(N, C, H, hd)
+
+
+# --------------------------------------------------------------- latent pages
+#
+# Latent attention in its absorbed form (``inference/paged.py``): a token's
+# cache row is ONE slab shared by every head, ``[latent | rotary key | pad]``;
+# a head's key is the whole slab and its value the slab's first ``v_width``
+# columns. So a page is fetched ONCE for all heads, into one buffer that the
+# score product reads whole and the value product reads the front of: this is
+# multi-query attention whose values alias its keys. The walk over a row's
+# live pages and the two-slot prefetch are the kernel's above; what differs is
+# the one buffer, and the query side: all heads of a few query tokens are the
+# rows of one product, and a chunk of a prompt is cut into tiles of
+# ``_LATENT_Q_TILE`` tokens, each a grid step with its own context length
+# (what the tile's last live token may see), because ``C * H`` rows of a
+# 256-token chunk do not fit VMEM. A row's later tiles fetch its pages again:
+# at 1.25 KB a token that is noise beside a prompt's other work.
+_LATENT_Q_TILE = 16
+LATENT_PAGES_PER_BLOCK = 16
+
+
+def _latent_kernel(bt_ref, ctx_ref, q_ref, qpos_ref, k_hbm, o_ref, kbuf, acc_ref, m_ref, l_ref,
+                   sems, slot_ref, *, ppcb, bs, tiles, v_width):
+    i = pl.program_id(0)
+    steps = pl.num_programs(0)
+    T = ppcb * bs
+    W = kbuf.shape[-1]
+    cdt = q_ref.dtype
+
+    def pages_of(step):  # the pages a grid step's queries may see
+        return _cdiv(ctx_ref[step], bs)
+
+    def chunk_dma(step, chunk, slot, start):
+        """Start, or wait for, the copies of one page-chunk of ``step``'s
+        sequence into ``slot``: one DMA per LIVE page, a contiguous lane-dense
+        ``[bs, W]`` slab of the pool as it is stored."""
+        live = jnp.clip(pages_of(step) - chunk * ppcb, 0, ppcb)
+
+        def page(j):
+            p = bt_ref[step // tiles, chunk * ppcb + j]
+            c = pltpu.make_async_copy(k_hbm.at[p], kbuf.at[slot, j], sems.at[slot])
+            c.start() if start else c.wait()
+
+        pl.loop(0, live)(page)
+
+    ctx = ctx_ref[i]
+    nc = _cdiv(pages_of(i), ppcb)
+    slot0 = jnp.where(i == 0, 0, slot_ref[0])
+
+    @pl.when(i == 0)
+    def _first():
+        chunk_dma(0, 0, 0, start=True)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    q = q_ref[0]  # [rows, W], pre-scaled
+    rows = q.shape[0]
+
+    def compute(c, slot):
+        # past the context the scores are masked, but 0 * NaN is NaN in p @ v:
+        # zero the dead slots of the last page and the pages never fetched
+        @pl.when(c == nc - 1)
+        def _():
+            @pl.loop((ctx - c * T) // bs, ppcb)
+            def _(j):
+                pos = c * T + j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, W), 0)
+                kbuf[slot, j] = jnp.where(pos < ctx, kbuf[slot, j], jnp.zeros((), kbuf.dtype))
+
+        k = kbuf[slot].reshape(T, W).astype(cdt)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # [rows, T]
+        j = c * T + jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
+        s = jnp.where(j <= qpos_ref[0], s, _NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_safe = jnp.where(m_cur == _NEG_INF, 0.0, m_cur)
+        p = jnp.exp(s - m_safe)
+        alpha = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_safe))
+        l_ref[...] = jnp.broadcast_to(alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True),
+                                      l_ref.shape)
+        m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(cdt), k[:, :v_width], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.loop(0, nc)
+    def _walk(c):
+        slot = (slot0 + c) % 2
+        # the next chunk to fetch: this step's, or at its last the next step's first
+        more = c + 1 < nc
+        nstep = jnp.where(more, i, i + 1)
+
+        @pl.when(nstep < steps)
+        def _():
+            chunk_dma(nstep, jnp.where(more, c + 1, 0), 1 - slot, start=True)
+
+        chunk_dma(i, c, slot, start=False)
+        compute(c, slot)
+
+    # a step with nothing to see fetches nothing; it still owes the next its first chunk
+    @pl.when((nc == 0) & (i + 1 < steps))
+    def _():
+        chunk_dma(i + 1, 0, slot0, start=True)
+
+    slot_ref[0] = (slot0 + nc) % 2
+    l = l_ref[:, :1]
+    o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _latent_context(q_positions, new_lens, tile: int):
+    """``[N, C // tile]``: how many tokens each tile of queries may see, the
+    position after its last LIVE token's; 0 for a tile with none."""
+    N, C = q_positions.shape
+    live = jnp.ones((N, C), bool) if new_lens is None else jnp.arange(C)[None, :] < new_lens[:, None]
+    seen = jnp.where(live, q_positions + 1, 0).reshape(N, C // tile, tile)
+    return seen.max(axis=-1).astype(jnp.int32)
+
+
+@register("latent_paged_attention", "pallas")
+def flash_decode_latent(
+    q: jax.Array,  # [N, C, H, W]: a head's query against the whole slab, NOT yet scaled
+    pool: jax.Array,  # [pages, bs, W]: the whole latent pool, all layers
+    block_tables: jax.Array,  # [N, P] int32 pages of pool (layer offset added)
+    q_positions: jax.Array,  # [N, C] int32
+    block_size: int,
+    scale: float,
+    v_width: int,  # a token's value: the slab's first v_width columns
+    new_lens: jax.Array = None,  # [N] live tokens
+    pages_per_block: int = LATENT_PAGES_PER_BLOCK,
+) -> jax.Array:
+    """-> [N, C, H, v_width]. One fetch of a page serves every head."""
+    N, C, H, W = q.shape
+    P = block_tables.shape[1]
+    bs = block_size
+    tq = min(C, _LATENT_Q_TILE)
+    Cp = _cdiv(C, tq) * tq
+    tiles = Cp // tq
+    rows = _cdiv(tq * H, 16) * 16  # whole sublane tiles of the 16-bit query
+    q = (q * jnp.asarray(scale, q.dtype))
+    if Cp != C:
+        q = jnp.pad(q, ((0, 0), (0, Cp - C), (0, 0), (0, 0)))
+        q_positions = jnp.pad(q_positions, ((0, 0), (0, Cp - C)), constant_values=-1)
+        if new_lens is None:
+            new_lens = jnp.full((N,), C, jnp.int32)
+    ctx = _latent_context(q_positions, new_lens, tq).reshape(N * tiles)
+    q_op = q.reshape(N * tiles, tq * H, W)
+    qpos = jnp.broadcast_to(q_positions.reshape(N * tiles, tq, 1), (N * tiles, tq, H))
+    qpos = qpos.reshape(N * tiles, tq * H)
+    pad = rows - tq * H
+    q_op = jnp.pad(q_op, ((0, 0), (0, pad), (0, 0)))
+    qpos = jnp.pad(qpos, ((0, 0), (0, pad)), constant_values=-1)  # padded rows see nothing
+    ppcb = max(1, min(pages_per_block, P))
+
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, ppcb=ppcb, bs=bs, tiles=tiles, v_width=v_width),
+        name="mla_paged_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # block_tables, the tiles' contexts
+            grid=(N * tiles,),
+            in_specs=[
+                pl.BlockSpec((1, rows, W), lambda i, bt, cl: (i, 0, 0)),
+                pl.BlockSpec((1, rows, 1), lambda i, bt, cl: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, rows, v_width), lambda i, bt, cl: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, ppcb, bs, W), pool.dtype),  # two slots: one computes, one fills
+                pltpu.VMEM((rows, v_width), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((N * tiles, rows, v_width), q.dtype),
+        # steps in order: each starts the next one's first fetch
+        compiler_params=tpu_compiler_params(dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+    )(block_tables, ctx, q_op, qpos[:, :, None], pool)
+    return out[:, : tq * H].reshape(N, Cp, H, v_width)[:, :C]

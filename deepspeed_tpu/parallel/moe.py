@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -111,6 +111,36 @@ def _capacity(num_tokens: int, num_experts: int, factor: float, min_capacity: in
     # ceil, matching the reference's _capacity (sharded_moe.py ceil semantics)
     cap = math.ceil(num_tokens * top_k * factor / num_experts)
     return max(cap, min_capacity)
+
+
+def route(logits: jax.Array, top_k: int, *, kind: str = "softmax",
+          bias: Optional[jax.Array] = None, renormalize: bool = True,
+          scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+    """THE router of a drop-free routed layer: ``logits`` [T, E] ->
+    ``(weights [T, k] fp32, picks [T, k] int32)``, one definition for the flax
+    layer below (:class:`DropFreeMoE`) and the serving twin
+    (``inference/model.py::_moe``).
+
+    ``softmax``: the top-k of the softmax, renormalised over the k.
+    ``sigmoid``: scores are sigmoids; the experts are CHOSEN by score plus the
+    per-expert correction ``bias`` and WEIGHED by the unbiased scores, which
+    are renormalised over the k if ``renormalize``; ``scale`` multiplies the
+    weights. The bias picks, it never weighs. fp32 throughout."""
+    logits = logits.astype(jnp.float32)
+    if kind == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        select = scores if bias is None else scores + bias.astype(jnp.float32)
+        picks = jax.lax.top_k(select, top_k)[1]
+        weights = jnp.take_along_axis(scores, picks, axis=-1)
+        if renormalize:
+            weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    elif kind == "softmax":
+        weights, picks = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        if renormalize:
+            weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    else:
+        raise ValueError(f"unknown router kind {kind!r} (softmax | sigmoid)")
+    return weights * scale, picks.astype(jnp.int32)
 
 
 def top_k_gating(
@@ -294,14 +324,18 @@ class Experts(nn.Module):
     hidden_dim: int
     activation: str = "silu_glu"
     dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    # lecun_normal on [E, M, H] counts the experts into the fan-in (std
+    # 1/sqrt(E*M)); True draws each expert as a matrix of its own (1/sqrt(M))
+    per_expert_init: bool = False
 
     def setup(self):
         E, M, H = self.num_experts, self.model_dim, self.hidden_dim
-        init = nn.initializers.lecun_normal()
+        init = nn.initializers.lecun_normal(batch_axis=(0,) if self.per_expert_init else ())
         if self.activation == "silu_glu":
-            self.w_gate = self.param("w_gate", init, (E, M, H))
-        self.w_up = self.param("w_up", init, (E, M, H))
-        self.w_down = self.param("w_down", init, (E, H, M))
+            self.w_gate = self.param("w_gate", init, (E, M, H), self.param_dtype)
+        self.w_up = self.param("w_up", init, (E, M, H), self.param_dtype)
+        self.w_down = self.param("w_down", init, (E, H, M), self.param_dtype)
 
     def kernels(self) -> Tuple[Optional[jax.Array], jax.Array, jax.Array]:
         """(w_gate | None, w_up, w_down) — raw stacked kernels."""
@@ -542,6 +576,94 @@ class MoELayer(nn.Module):
         if self.config.collect_metrics:
             return weighted, out.reshape(B, S, M), stats
         return weighted, out.reshape(B, S, M)
+
+
+def _nonzero_normal(stddev: float):
+    """Normal around 0 with every draw pushed off it: a routing leaf left at
+    zero is a leaf no check can see (a bias of zero picks what no bias picks)."""
+
+    def init(key, shape, dtype=jnp.float32):
+        x = stddev * jax.random.normal(key, shape, jnp.float32)
+        return (x + jnp.where(x >= 0, 0.1 * stddev, -0.1 * stddev)).astype(dtype)
+
+    return init
+
+
+class _Kernel(nn.Module):
+    """One matrix under ``<name>/kernel``, where ``nn.Dense`` would put it,
+    declared without the product: the math is ``_moe``'s."""
+
+    shape: Tuple[int, ...]
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self) -> jax.Array:
+        return self.param("kernel", nn.initializers.lecun_normal(), self.shape, self.param_dtype)
+
+
+class _Router(nn.Module):
+    """``gate/wg/kernel`` as :class:`TopKGate` names it, and for a sigmoid
+    router the correction bias ``gate/e_bias``."""
+
+    config: Any
+
+    @nn.compact
+    def __call__(self) -> dict:
+        cfg = self.config
+        out = {"wg": {"kernel": _Kernel((cfg.hidden_size, cfg.num_experts), cfg.param_dtype,
+                                        name="wg")()}}
+        if cfg.moe_router == "sigmoid":
+            # sigmoid scores of lecun-normal logits spread by about a quarter
+            # over the experts: a bias of a fifth of that changes picks
+            # without taking the choice over
+            out["e_bias"] = self.param("e_bias", _nonzero_normal(0.05), (cfg.num_experts,),
+                                       cfg.param_dtype)
+        return out
+
+
+class _SharedExpert(nn.Module):
+    """The shared expert's matrices, named as the dense ``MLP`` names its own."""
+
+    config: Any
+
+    @nn.compact
+    def __call__(self) -> dict:
+        cfg = self.config
+        M, H = cfg.hidden_size, cfg.expert_width * cfg.moe_shared_experts
+        names = {"w_up": (M, H), "w_down": (H, M)}
+        if cfg.activation == "silu_glu":
+            names["w_gate"] = (M, H)
+        return {n: {"kernel": _Kernel(shape, cfg.param_dtype, name=n)()}
+                for n, shape in names.items()}
+
+
+class DropFreeMoE(nn.Module):
+    """A routed feed-forward layer with no capacity and no drops: every token
+    reaches its ``moe_top_k`` experts (:func:`route`) and the shared expert,
+    if any. The flax twin of ``inference/model.py::_moe``: it declares the
+    parameters under the names :class:`MoELayer` gives them (``gate/wg``,
+    ``experts/w_*``; beside them ``gate/e_bias`` and ``shared/w_*``), every
+    routing leaf drawn nonzero, and runs that one definition of the math, so
+    the full-sequence forward and serving cannot drift. ``config`` is a
+    TransformerConfig."""
+
+    config: Any
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        from deepspeed_tpu.inference.model import _moe
+
+        cfg = self.config
+        experts = Experts(cfg.num_experts, cfg.hidden_size, cfg.expert_width, cfg.activation,
+                          cfg.dtype, cfg.param_dtype, per_expert_init=True, name="experts")
+        w_gate, w_up, w_down = experts.kernels()
+        lp = {"gate": _Router(cfg, name="gate")(),
+              "experts": {"w_up": w_up, "w_down": w_down}}
+        if w_gate is not None:
+            lp["experts"]["w_gate"] = w_gate
+        if cfg.moe_shared_experts:
+            lp["shared"] = _SharedExpert(cfg, name="shared")()
+        return _moe(lp, cfg, x)
 
 
 def moe_partition_rules(path: str, shape: tuple) -> Optional[P]:

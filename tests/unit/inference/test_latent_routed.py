@@ -1,0 +1,361 @@
+"""A latent-attention routed decoder at a toy size with the published
+structure (one dense layer, then routed ones: a sigmoid router with a
+correction bias large enough to change picks, a shared expert), against the
+benchmark's plain reference ``benchmarks/reference/glm4_moe_lite.py``: the
+flax forward, ``InferenceEngineV2.put`` through the latent cache in the
+absorbed form, the picks the programs hand out, the router's two dispatch
+regimes, the HF mapping. And the pin on what must NOT have moved: a model
+with no routed layer and no latent rank runs the parent's programs."""
+
+import collections
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.lib import harness, program
+from deepspeed_tpu.checkpoint.hf import config_from_hf, convert_hf_state, latent_moe_hf_state
+from deepspeed_tpu.inference import paged
+from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
+from deepspeed_tpu.models import CausalLM
+
+TOY = dict(
+    model_type="glm4_moe_lite", vocab_size=128, hidden_size=64, intermediate_size=160,
+    num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=4, max_position_embeddings=256,
+    moe_intermediate_size=32, n_routed_experts=8, n_shared_experts=1, num_experts_per_tok=2,
+    first_k_dense_replace=1, routed_scaling_factor=1.8, norm_topk_prob=True, q_lora_rank=24,
+    kv_lora_rank=32, qk_nope_head_dim=12, qk_rope_head_dim=8, v_head_dim=16, rms_norm_eps=1e-5,
+    rope_theta=1e6, topk_method="noaux_tc", n_group=1, topk_group=1, rope_scaling=None,
+    partial_rotary_factor=1, tie_word_embeddings=False, attention_bias=False, hidden_act="silu",
+    num_nextn_predict_layers=1)
+LENS = (20, 31, 7)
+DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16}
+
+
+def toy_params(dtype, seed=0):
+    """The flax initialiser's parameters with EVERY leaf perturbed (norm
+    scales off one, the correction bias too), as ``toy_moe_in_fp32`` does."""
+    cfg = dataclasses.replace(config_from_hf(TOY), dtype=dtype)
+    params = CausalLM(cfg).init({"params": jax.random.PRNGKey(seed)},
+                                {"input_ids": jnp.zeros((1, 8), jnp.int32)}, train=False)["params"]
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
+    return cfg, jax.tree_util.tree_unflatten(
+        tree, [(a + 0.05 * jax.random.normal(k, a.shape)).astype(dtype) for a, k in zip(leaves, keys)])
+
+
+@pytest.fixture(scope="module")
+def files():
+    return harness.load_reference("glm4_moe_lite"), harness.load_architecture("glm4_moe_lite")
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.random.default_rng(0).integers(0, TOY["vocab_size"], (3, 40), dtype=np.int32)
+
+
+def engine_of(cfg, params, dtype_name, **kw):
+    conf = dict(dtype=dtype_name, kv_cache_dtype=dtype_name, max_seqs=8, decode_chain=4, kv_block_size=16,
+                num_kv_blocks=64, row_bucket=4, chunk_bucket=32, hbm_check="off", max_seq_len=256)
+    return InferenceEngineV2(cfg, params, dict(conf, **kw))
+
+
+def only_the_latent_kernel(monkeypatch):
+    """``auto`` as the chip resolves it for the latent attention alone: the
+    Pallas kernel (in interpret mode here), everything else XLA's."""
+    from deepspeed_tpu.ops import registry
+
+    import deepspeed_tpu.ops.pallas.paged_attention  # noqa: F401  (registers the kernel)
+
+    def dispatch(op, impl="auto"):
+        if op == "latent_paged_attention":
+            return registry.available_impls(op)["pallas"]
+        return registry.dispatch(op, impl)
+
+    monkeypatch.setattr(paged, "dispatch", dispatch)
+
+
+def put_three_steps(engine, tokens):
+    """Prefill, then two tokens through the cache: logits and picks a step."""
+    uids, out = [1, 2, 3], []
+    for step in range(3):
+        fed = [tokens[i, (0 if step == 0 else n + step - 1):n + step] for i, n in enumerate(LENS)]
+        out.append(engine.put_with_picks(uids, fed))
+    for uid in uids:
+        engine.flush(uid)
+    return out
+
+
+def pinned(picks_by_step, shape, routed_layers, k):
+    all_picks = np.broadcast_to(np.arange(k, dtype=np.int32), shape + (routed_layers, k)).copy()
+    for step, (_, picks) in enumerate(picks_by_step):
+        for i, n in enumerate(LENS):
+            start = 0 if step == 0 else n + step - 1
+            all_picks[i, start:start + len(picks[i])] = picks[i]
+    return all_picks
+
+
+# (a) the flax forward against the reference
+@pytest.mark.parametrize("dtype,limit", [("fp32", 1e-5), ("bf16", 0.12)])
+def test_flax_forward_against_the_reference(files, tokens, dtype, limit):
+    reference, architecture = files
+    cfg, params = toy_params(DTYPES[dtype])
+    _, logits = CausalLM(cfg).apply({"params": params}, {"input_ids": jnp.asarray(tokens)})
+    want = reference.forward(architecture.reference_weights(params), TOY, jnp.asarray(tokens))
+    assert program.relative_error(np.asarray(logits, np.float32), want) <= limit
+
+
+# (b) put: prefill, then two tokens through the latent cache, absorbed
+@pytest.mark.parametrize("dtype,impl,limit", [("fp32", "xla", 1e-5), ("fp32", "kernel", 1e-5),
+                                              ("bf16", "xla", 0.03), ("bf16", "kernel", 0.03)])
+def test_put_through_the_latent_cache_against_the_reference(files, tokens, monkeypatch, dtype, impl, limit):
+    reference, architecture = files
+    if impl == "kernel":
+        only_the_latent_kernel(monkeypatch)
+    cfg, params = toy_params(DTYPES[dtype])
+    engine = engine_of(cfg, params, dtype)
+    assert engine.pool.v is None and engine.pool.k.shape == (3 * 64, 16, 128)  # one slab, 32 + 8 -> 128
+    steps = put_three_steps(engine, tokens)
+    # at the program's own picks: a flipped pick is not an error of the arithmetic
+    picks = pinned(steps, tokens.shape, cfg.routed_layers, cfg.moe_top_k)
+    want = np.asarray(reference.forward(architecture.reference_weights(engine.params), TOY,
+                                        jnp.asarray(tokens), jnp.asarray(picks)))
+    for step, (logits, _) in enumerate(steps):
+        rows = np.stack([want[i, n + step - 1] for i, n in enumerate(LENS)])
+        assert program.relative_error(logits, rows) <= limit, step
+
+
+# the kernel alone: a row without pages, a table wider than a row's pages,
+# a chunk cut into query tiles, a token and its drafts
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)], ids=["fp32", "bf16"])
+@pytest.mark.parametrize("C,lens,starts", [(1, (1, 1, 0, 1), (0, 37, 0, 63)),
+                                           (5, (5, 3, 0, 5), (11, 0, 0, 40)),
+                                           (32, (32, 7, 0, 20), (0, 16, 0, 30))],
+                         ids=["decode", "drafts", "chunk-in-tiles"])
+def test_latent_kernel_against_the_gather(dtype, tol, C, lens, starts):
+    from deepspeed_tpu.ops.pallas.paged_attention import flash_decode_latent
+
+    N, H, W, V, bs, P = 4, 4, 128, 96, 16, 12  # 12 columns, rows hold at most 5 pages
+    rng = np.random.default_rng(C)
+    pool = jnp.asarray(rng.normal(size=(40, bs, W)), dtype)
+    q = jnp.asarray(rng.normal(size=(N, C, H, W)), dtype)
+    tables = jnp.asarray(rng.permutation(40)[:N * P].reshape(N, P) if N * P <= 40
+                         else rng.integers(0, 40, (N, P)), jnp.int32)
+    positions = jnp.asarray(np.asarray(starts)[:, None] + np.arange(C)[None, :], jnp.int32)
+    new_lens = jnp.asarray(lens, jnp.int32)
+    args = (q, pool, tables, positions, bs, 0.25, V)
+    got = np.asarray(flash_decode_latent(*args, new_lens=new_lens), np.float32)
+    want = np.asarray(paged._xla_latent_paged_attention(*args, new_lens=new_lens), np.float32)
+    assert np.isfinite(got).all()
+    for n in range(N):  # live tokens only: a dead one attends to nothing in the kernel
+        np.testing.assert_allclose(got[n, :lens[n]], want[n, :lens[n]], atol=tol, rtol=tol)
+    assert not got[2].any()  # the row without pages writes zeros
+
+
+# (c) the picks
+def test_picks_are_the_reference_s_own_and_come_out_of_the_same_programs(files, tokens):
+    reference, architecture = files
+    cfg, params = toy_params(jnp.float32)
+    engine = engine_of(cfg, params, "fp32")
+    plain = [engine.put([1, 2, 3], [tokens[i, :n] for i, n in enumerate(LENS)])]
+    for uid in (1, 2, 3):
+        engine.flush(uid)
+    compiled = engine.jit_cache_size()
+    steps = put_three_steps(engine, tokens)
+    assert engine.jit_cache_size() == compiled  # put's own program, chunk bucket and all
+    np.testing.assert_array_equal(plain[0], steps[0][0])
+    for (_, picks), fed in zip(steps, (LENS, (1, 1, 1), (1, 1, 1))):
+        assert [p.shape for p in picks] == [(n, 2, 2) for n in fed] and picks[0].dtype == np.int32
+    weights = architecture.reference_weights(engine.params)
+    all_picks = pinned(steps, tokens.shape, 2, 2)
+    shortfall = np.asarray(reference.route_shortfall(weights, TOY, jnp.asarray(tokens), jnp.asarray(all_picks)))
+    fed = np.zeros(tokens.shape, bool)
+    for i, n in enumerate(LENS):
+        fed[i, :n + 2] = True
+    assert shortfall[fed].max() <= 1e-5  # its own top_k(s + b), in fp32
+    # the bias changes picks: the reference without it would have gone elsewhere
+    no_bias = dict(weights, routed=dict(weights["routed"], router_bias=0 * weights["routed"]["router_bias"]))
+    assert np.asarray(reference.route_shortfall(no_bias, TOY, jnp.asarray(tokens),
+                                                jnp.asarray(all_picks)))[fed].max() > 0.5
+
+
+def test_a_router_that_drops_the_bias_fails_the_audit(files, tokens):
+    reference, architecture = files
+    cfg, params = toy_params(jnp.float32)
+    weights = architecture.reference_weights(params)
+    params = jax.tree_util.tree_map(lambda a: a, params)
+    params["layers"]["moe"]["gate"]["e_bias"] = 0 * params["layers"]["moe"]["gate"]["e_bias"]
+    steps = put_three_steps(engine_of(cfg, params, "fp32"), tokens)
+    shortfall = np.asarray(reference.route_shortfall(
+        weights, TOY, jnp.asarray(tokens), jnp.asarray(pinned(steps, tokens.shape, 2, 2))))
+    assert shortfall[0, :LENS[0]].max() > 0.5
+
+
+def test_generate_with_picks_covers_every_token_fed(files, tokens):
+    reference, architecture = files
+    cfg, params = toy_params(jnp.float32)
+    engine = engine_of(cfg, params, "fp32")
+    prompts = [tokens[i, :n] for i, n in enumerate(LENS)]
+    plain = engine.generate(prompts, max_new_tokens=6)
+    compiled = engine.jit_cache_size()
+    outs, picks = engine.generate_with_picks(prompts, max_new_tokens=6)
+    assert engine.jit_cache_size() == compiled and engine.picks_log is None
+    full, all_picks = tokens.copy(), np.broadcast_to(np.arange(2, dtype=np.int32), tokens.shape + (2, 2)).copy()
+    for i, (p, o) in enumerate(zip(prompts, outs)):
+        np.testing.assert_array_equal(o, plain[i])
+        assert picks[i].shape == (len(p) + len(o) - 1, 2, 2)
+        full[i, len(p):len(p) + len(o)] = o
+        all_picks[i, :len(picks[i])] = picks[i]
+    weights = architecture.reference_weights(engine.params)
+    want = np.asarray(reference.forward(weights, TOY, jnp.asarray(full), jnp.asarray(all_picks)))
+    shortfall = np.asarray(reference.route_shortfall(weights, TOY, jnp.asarray(full), jnp.asarray(all_picks)))
+    for i, (p, o) in enumerate(zip(prompts, outs)):
+        assert [int(want[i, len(p) + j - 1].argmax()) for j in range(len(o))] == list(o)
+        assert shortfall[i, :len(picks[i])].max() <= 1e-5
+    assert 2 <= engine.last_experts_touched <= 6  # 3 rows x 2 picks over 8 experts
+
+
+def test_picks_are_for_routed_models_only():
+    cfg = config_from_hf(dict(model_type="gpt_neox", vocab_size=64, hidden_size=32, intermediate_size=64,
+                              num_hidden_layers=1, num_attention_heads=2, max_position_embeddings=64))
+    params = CausalLM(cfg).init({"params": jax.random.PRNGKey(0)},
+                                {"input_ids": jnp.zeros((1, 8), jnp.int32)}, train=False)["params"]
+    engine = engine_of(cfg, params, "fp32", max_seq_len=64)
+    with pytest.raises(ValueError, match="no routed layer"):
+        engine.put_with_picks([1], [np.arange(4, dtype=np.int32)])
+
+
+# (d) absorbed = non-absorbed attention, on one layer
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 5e-2)], ids=["fp32", "bf16"])
+def test_absorbed_attention_is_the_plain_one(dtype, tol):
+    from deepspeed_tpu.models.transformer import LatentAttention
+
+    cfg, params = toy_params(dtype)
+    attn = params["dense_0"]["attn"]
+    N, C, bs = 2, 24, 16
+    x = jax.random.normal(jax.random.PRNGKey(3), (N, C, cfg.hidden_size), dtype)
+    positions = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32), (N, C))
+    plain = LatentAttention(cfg).apply({"params": attn}, x, None, positions, False)
+    pool = paged.init_pool(cfg, 8, bs, dtype)
+    tables = jnp.asarray([[0, 1, 7, 7], [2, 3, 7, 7]], jnp.int32)
+    new_lens = jnp.full((N,), C, jnp.int32)
+    put = paged._page_writer(tables, positions, new_lens, bs, pool.k.shape[0])
+    absorbed, pk = paged._latent_attention(attn, cfg, x, positions, new_lens, tables, bs, pool.k, put,
+                                           jnp.int32(0))
+    np.testing.assert_allclose(np.asarray(absorbed, np.float32), np.asarray(plain, np.float32),
+                               atol=tol, rtol=tol)
+    assert np.asarray(pk[:4], np.float32).any() and not np.asarray(pk[4:], np.float32).any()
+    assert not np.asarray(pk[..., cfg.kv_lora_rank + cfg.qk_rope_head_dim:], np.float32).any()  # the lane padding
+
+
+# (e) the two dispatch regimes of the routed layer agree across T = 2E
+@pytest.mark.parametrize("router", ["sigmoid", "softmax"])
+def test_dense_and_grouped_dispatch_agree(router):
+    from deepspeed_tpu.inference.model import _moe_with_picks
+
+    cfg, params = toy_params(jnp.float32)
+    cfg = dataclasses.replace(cfg, moe_router=router)
+    lp = jax.tree_util.tree_map(lambda a: a[0], params["layers"]["moe"])
+    E = cfg.num_experts
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, 2 * E, cfg.hidden_size))
+    grouped, picks_g = _moe_with_picks(lp, cfg, x)  # T = 2E: grouped
+    dense, picks_d = _moe_with_picks(lp, cfg, x[:, :2 * E - 1])  # T = 2E - 1: every expert
+    np.testing.assert_allclose(grouped[:, :-1], dense, atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(picks_g[:-1], picks_d)
+    assert picks_g.shape == (2 * E, cfg.moe_top_k) and picks_g.dtype == jnp.int32
+
+
+def test_the_router_is_the_published_one():
+    from deepspeed_tpu.parallel.moe import route
+
+    logits = jnp.asarray([[2.0, 1.0, 0.0, -1.0]])
+    bias = jnp.asarray([-1.0, 0.0, 0.0, 0.5])
+    weights, picks = route(logits, 2, kind="sigmoid", bias=bias, renormalize=True, scale=1.8)
+    s = jax.nn.sigmoid(logits[0])
+    assert sorted(np.asarray(picks[0]).tolist()) == [1, 3]  # by s + b: expert 0 loses its lead
+    np.testing.assert_allclose(np.sort(np.asarray(weights[0])),
+                               np.sort(1.8 * np.asarray(s[jnp.asarray([1, 3])] / (s[1] + s[3]))), rtol=1e-6)
+    weights, picks = route(logits, 2, kind="softmax")
+    assert sorted(np.asarray(picks[0]).tolist()) == [0, 1] and abs(float(weights.sum()) - 1) < 1e-6
+
+
+# (f) the HF mapping
+def test_config_from_hf_on_the_published_config():
+    config = harness.load_config("glm-4.7-flash")
+    cfg = config_from_hf(program.published(config))
+    assert (cfg.num_layers, cfg.first_dense_layers, cfg.routed_layers) == (8, 1, 7)
+    assert (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+            cfg.v_head_dim, cfg.num_heads) == (768, 512, 192, 64, 256, 20)
+    assert (cfg.num_experts, cfg.moe_top_k, cfg.moe_shared_experts, cfg.expert_width,
+            cfg.intermediate_size) == (64, 4, 1, 1536, 10240)
+    assert (cfg.moe_router, cfg.moe_renormalize, cfg.moe_routed_scale) == ("sigmoid", True, 1.8)
+    assert cfg.param_dtype == jnp.bfloat16 and cfg.rope_theta == 1e6 and not cfg.tie_embeddings
+    assert paged.latent_pool_width(cfg) == 640
+    architecture = harness.load_architecture("glm4_moe_lite")
+    assert cfg.num_params() == architecture.total_params(program.published(config)) == 5_166_248_384
+
+
+def test_hf_names_there_and_back_without_the_mtp_layer():
+    cfg, params = toy_params(jnp.float32)
+    state = latent_moe_hf_state(jax.tree_util.tree_map(np.asarray, params), cfg)
+    assert state["model.layers.1.self_attn.kv_b_proj.weight"].shape == (4 * (12 + 16), 32)
+    assert state["model.layers.2.mlp.experts.7.down_proj.weight"].shape == (64, 32)
+    assert "model.layers.0.mlp.gate_proj.weight" in state and "model.layers.0.mlp.gate.weight" not in state
+    state["model.layers.3.eh_proj.weight"] = np.zeros((4, 4))  # the MTP layer's keys are not read
+    state["model.layers.3.self_attn.kv_b_proj.weight"] = np.zeros((4, 4))
+    back = convert_hf_state(state, cfg)  # family detected from the keys
+    there = dict(jax.tree_util.tree_leaves_with_path(back))
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+        np.testing.assert_array_equal(np.asarray(leaf), there[path])
+    with pytest.raises(ValueError, match="glm4_moe_lite"):
+        config_from_hf({"model_type": "no_such_family"})
+
+
+def test_a_quantized_latent_pool_is_refused():
+    cfg, params = toy_params(jnp.bfloat16)
+    with pytest.raises(ValueError, match="no quantized form"):
+        engine_of(cfg, params, "bf16", kv_cache_dtype="int8")
+
+
+# (g) a model with no routed layer and no latent rank runs the parent's programs
+def _census(jaxpr, counts):
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] += 1
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple)) else [value]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _census(inner, counts)
+    return counts
+
+
+@pytest.mark.parametrize("name", ["step", "chain"])
+def test_gpt_neox_programs_are_the_parents(name):
+    """The census of the jaxpr's primitives, the number of operands and the
+    outputs' shapes, recorded on the parent commit (PR 32) by this very code."""
+    cfg = config_from_hf(dict(
+        model_type="gpt_neox", vocab_size=256, hidden_size=64, intermediate_size=256, num_hidden_layers=2,
+        num_attention_heads=4, max_position_embeddings=128, rotary_pct=0.25, rotary_emb_base=10000,
+        layer_norm_eps=1e-5, use_parallel_residual=True, hidden_act="gelu", tie_word_embeddings=False))
+    params = jax.eval_shape(lambda k: CausalLM(cfg).init(
+        {"params": k}, {"input_ids": jnp.zeros((1, 8), jnp.int32)}, train=False)["params"], jax.random.PRNGKey(0))
+    pool = jax.eval_shape(lambda: paged.init_pool(cfg, 32, 16, jnp.float32))
+    assert pool.k.shape == pool.v.shape == (64, 16, 64)  # keys AND values
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    if name == "step":
+        jaxpr = jax.make_jaxpr(lambda p, pool, t, pos, n, bt: paged.ragged_forward(
+            p, cfg, pool, t, pos, n, bt, 16))(params, pool, i32(4, 32), i32(4, 32), i32(4), i32(4, 8))
+    else:
+        jaxpr = jax.make_jaxpr(lambda p, pool, t, pos, bt, a, b, r: paged.ragged_decode_chain(
+            p, cfg, pool, t, pos, bt, 16, a, b, r, 4, None))(
+            params, pool, i32(4), i32(4), i32(4, 8), jax.ShapeDtypeStruct((4,), jnp.bool_), i32(4),
+            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    with open(os.path.join(os.path.dirname(__file__), "data", "gpt_neox_programs_at_pr32.json")) as f:
+        parent = json.load(f)[name]
+    assert dict(sorted(_census(jaxpr.jaxpr, collections.Counter()).items())) == parent["primitives"]
+    assert len(jaxpr.jaxpr.invars) == parent["inputs"]  # no new operand
+    assert [[list(v.aval.shape), str(v.aval.dtype)] for v in jaxpr.jaxpr.outvars] == parent["outputs"]  # no picks

@@ -151,6 +151,26 @@ def test_paged_attention_compiles(one_chip, monkeypatch, N, C, kv_quant, H, kvH,
     assert _compiled_kernels(fn, *shapes) == 1
 
 
+@pytest.mark.parametrize("N,C", [(64, 1), (64, 256), (8, 5)],
+                         ids=["cell-decode-64x1", "cell-prefill-64x256", "drafts-k4"])
+def test_latent_paged_attention_compiles(one_chip, monkeypatch, N, C):
+    """``mla_paged_attn`` at glm-4.7-flash.serve.batch's own shapes: 20 heads
+    against one 640-column slab a token (512 latent + 64 rotary + 64 of lane
+    padding), values its first 512 columns, a table of 2048 / 16 pages; the
+    256-token chunk goes in query tiles of 16 tokens x 20 heads."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    bf16 = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)  # noqa: E731
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+
+    def fn(q, pool, bt, qpos, lens):
+        return pa.flash_decode_latent(q, pool, bt, qpos, 16, 1 / 16, 512, new_lens=lens)
+
+    assert _compiled_kernels(fn, bf16(N, C, 20, 640), bf16(8 * 1024, 16, 640), i32(N, 128),
+                             i32(N, C), i32(N)) == 1
+
+
 @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["bf16", "int8"])
 def test_decode_chain_updates_the_pool_in_place(one_chip, monkeypatch, kv_quant):
     """Whether a carried array is updated in place is the chip's compiler's
