@@ -20,6 +20,7 @@ Layout decisions (TPU-first):
 
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
@@ -136,8 +137,10 @@ def _moe_with_picks(lp, cfg: TransformerConfig, x):
     - prefill (T >= 2E tokens): RAGGED dispatch (round 5; reference FastGen's
       ``inference/v2/kernels/ragged_ops`` moe_gather/moe_scatter +
       ``cutlass_ops`` grouped GEMM) — sort the (token, expert) pairs by
-      expert and run grouped matmuls via ``lax.ragged_dot``, so prompt FFN
-      FLOPs scale with top_k, not E (8x2 Mixtral-style: 4x fewer).
+      expert and run grouped matmuls (:func:`_grouped_matmul`: on the TPU
+      the megablox Pallas kernel at tiles picked from the call's shapes,
+      elsewhere ``lax.ragged_dot``), so prompt FFN FLOPs scale with top_k,
+      not E (8x2 Mixtral-style: 4x fewer).
 
     Device-trace scopes: ``moe_router``, ``moe_experts``, ``moe_shared``
     (the caller opens ``moe`` around the layer).
@@ -260,23 +263,168 @@ def _moe_ep_collective(cfg: TransformerConfig, ep, tokens, top_p, top_i):
         codec=cfg.moe_wire_codec)
 
 
+# A kernel may scope 16 MiB of the chip's VMEM unless it asks for more, and the
+# megablox calls ask for nothing. `_gmm_vmem_bytes` is an ESTIMATE of what
+# Mosaic scopes for a forward step and bounds it from neither side (Mosaic
+# refused a step that counts 13.5 MiB there as 16.7 of its own, and took one
+# that counts 17), so its budget is a heuristic. `_tgmm_vmem_bytes` gave
+# Mosaic's own figure at each of the three steps it refused (17.50, 17.00 and
+# 18.25 MiB) and 14.75 at the largest it took. Either way the guard is
+# `tests/unit/ops/test_chip_compile.py`, which compiles both passes.
+_GMM_VMEM_BUDGET = 12 * 2 ** 20
+_TGMM_VMEM_BUDGET = 15 * 2 ** 20
+
+
+def _gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """About what one ``(tm, tk, tn)`` step of megablox ``gmm`` holds in
+    VMEM: the lhs, rhs and out blocks double-buffered by the pipeline, the
+    fp32 accumulator and the fp32 product before it is added."""
+    return 2 * (tm * tk + tk * tn + tm * tn) * itemsize + 2 * tm * tn * 4
+
+
+def _tgmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """What one step of megablox ``tgmm`` (``lhs[rows of g].T @ grad[rows of
+    g]`` a group) scopes: the ``[tm, tk]`` and ``[tm, tn]`` operand blocks and
+    the ``[tk, tn]`` out block double-buffered, and the fp32 accumulator."""
+    return 2 * (tm * tk + tm * tn + tk * tn) * itemsize + tk * tn * 4
+
+
+def _lane_divisors(n: int, cap: int) -> list:
+    """Divisors of ``n`` that are multiples of 128 and at most ``cap``, largest
+    first; ``[n]`` where it has none (a block may always span a whole dim)."""
+    return [d for d in range(min(cap, n) // 128 * 128, 0, -128) if n % d == 0] or [n]
+
+
+def _row_tile(m: int, E: int, share: int, itemsize: int, fits) -> int:
+    """The row tile of a grouped kernel: a tile that spans g groups is visited
+    g times under a mask, so the MXU's work is about ``m * (1 + tm / (m / E))``.
+    The largest power of two within ``1 / share`` of the mean group ``m / E``,
+    in [128, 512], that ``fits``; a tiny ``m`` takes one tile of its own rows,
+    rounded up to the dtype's sublane packing."""
+    sublanes = 32 // itemsize  # rows of one packed (sublanes, 128) tile
+    tm = 128
+    while tm * 2 <= min(m // E // share, 512) and fits(tm * 2):
+        tm *= 2
+    return min(tm, -(-m // sublanes) * sublanes)
+
+
+def _gmm_tiles(m: int, K: int, N: int, E: int, itemsize: int) -> Tuple[int, int, int]:
+    """Tiles ``(tm, tk, tn)`` of the grouped matmul ``[m, K] x [E, K, N]``,
+    a pure function of the call's static shapes.
+
+    The kernel's grid is ``(N / tn, m / tm + boundary tiles, K / tk)``, n
+    outermost and k innermost, and a step costs about a third of a microsecond
+    whatever it multiplies (110,208 steps of 128 x 128 x 128 were 5% of the
+    MXU's peak), so the tiles are as large as the work allows. Measured on
+    the v5e at ``[65536, 2048] x [64, 2048, 1536]`` (PERF.md, PR 34):
+
+    - ``tk`` is the whole K where a step then fits ``_GMM_VMEM_BUDGET``: no k
+      loop, no remainder mask, one store a tile, and a group's ``[K, tn]``
+      weights stay in VMEM over all of its row tiles. Halving it cost 30-60%:
+      the weights are then fetched again at every row tile.
+    - ``tn`` is the largest divisor of N that is a multiple of 128, up to
+      1024, that fits beside it (the lhs is read ``N / tn`` times; 256 to
+      512 gained 7-24%, wider another 3-5%).
+    - ``tm`` follows the mean rows a group, ``m / E``: a tile that spans g
+      groups is visited g times under a mask, so the MXU's work is about
+      ``m * (1 + tm / (m / E))``. The largest power of two within a quarter
+      of the mean group, in [128, 512] (at 1,024 rows a group 256 beat 128 by
+      2% and 512 by 8%); a tiny ``m`` takes one tile of its own rows, rounded
+      up to the dtype's sublane packing.
+    - Where K does not fit whole beside a ``tn`` of 256 or more (under that the
+      lhs' stream falls below the chip's 240 FLOPs a byte), K is split: the
+      weights' stream then has ``tm`` FLOPs a byte, so ``tm`` goes up to the
+      mean group itself, ``tn`` to 512, and ``tk`` is the largest 128-multiple
+      divisor of K that fits.
+    """
+    def fits(tm, tk, tn):
+        return _gmm_vmem_bytes(tm, tk, tn, itemsize) <= _GMM_VMEM_BUDGET
+
+    for tn in _lane_divisors(N, 1024):
+        if tn >= min(N, 256) and fits(128, K, tn):
+            return _row_tile(m, E, 4, itemsize, lambda tm: fits(tm, K, tn)), K, tn
+    tn = _lane_divisors(N, 512)[0]
+    tm = _row_tile(m, E, 1, itemsize, lambda tm: fits(tm, 128, tn))
+    tks = _lane_divisors(K, K)
+    return tm, next((tk for tk in tks if fits(tm, tk, tn)), tks[-1]), tn
+
+
+def _tgmm_tiles(m: int, K: int, N: int, E: int, itemsize: int) -> Tuple[int, int, int]:
+    """Tiles ``(tm, tk, tn)`` of the weights' gradient ``[E, K, N]``, from the
+    same static shapes. ``tgmm``'s grid is ``(N / tn, K / tk, row tiles)``,
+    rows innermost: a ``[tk, tn]`` fp32 accumulator a group stays in VMEM over
+    the group's rows while both operand blocks change at every step, so the
+    lhs is read ``N / tn`` times and the cotangent ``K / tk`` times: a step
+    has ``tk * tn / (tk + tn)`` FLOPs a byte of bf16 whatever ``tm`` is, and
+    the time follows it (v5e, ``[65536, 2048]`` and ``[65536, 1536]`` a group
+    of 1,024, PERF.md PR 34: 5.73 ms at 512 x 512, 4.29 at 1024 x 768, 3.97
+    at 2048 x 768). ``tm`` follows the mean group as in :func:`_gmm_tiles`
+    (256 beat 512 by 2-8%); ``tk`` and ``tn`` are the 128-multiple divisors
+    with the most FLOPs a byte that fit ``_TGMM_VMEM_BUDGET`` beside it."""
+    tm = _row_tile(m, E, 4, itemsize, lambda tm: True)
+    fit = [(tk * tn / (tk + tn), tk, tn) for tk in _lane_divisors(K, K) for tn in _lane_divisors(N, N)
+           if _tgmm_vmem_bytes(tm, tk, tn, itemsize) <= _TGMM_VMEM_BUDGET]
+    _, tk, tn = max(fit)
+    return tm, tk, tn
+
+
+def _pad_rows(x, group_sizes, tm: int):
+    """The grouped kernels require ``m % tm == 0``: pad ``x`` with zero rows
+    credited to the LAST group (zero rows give zero outputs and add nothing
+    to a weight's gradient)."""
+    pad = -x.shape[0] % tm
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+        group_sizes = group_sizes.at[-1].add(pad)
+    return x, group_sizes.astype(jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _gmm_padded(lhs, rhs, group_sizes, interpret: bool = False):
-    """megablox ``gmm`` with the row count padded to the m-tile: gmm requires
-    ``m % tm == 0``, so pad lhs with zero rows credited to the LAST group
-    (zero rows produce zero outputs, sliced off after)."""
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    """megablox ``gmm`` at the tiles :func:`_gmm_tiles` picks from the call's
+    shapes, with the row count padded to the m-tile (:func:`_pad_rows`; the
+    padding is sliced off after). bf16 (or whatever ``lhs`` is) in and out,
+    fp32 accumulation.
+
+    The gradient is this function's own, not the library's ``custom_vjp``:
+    that one hands the forward's tiles to both backward kernels, where ``tk``
+    lands on N and ``tn`` on K in the transposed product and ``tgmm`` keeps a
+    ``[tk, tn]`` accumulator (Mosaic refused it at mixtral's widths). Here
+    each kernel's tiles come from its own shapes: ``d lhs = grad @ rhs[g].T``
+    is the forward's kernel at ``[m, N] x [E, N, K]``, so :func:`_gmm_tiles`
+    of those; ``d rhs`` is ``tgmm`` at :func:`_tgmm_tiles`."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     m, K = lhs.shape
-    tm = min(128, -(-m // 8) * 8)  # sublane-aligned tile, capped at 128
-    m_p = -(-m // tm) * tm
-    if m_p != m:
-        lhs = jnp.pad(lhs, ((0, m_p - m), (0, 0)))
-        group_sizes = group_sizes.at[-1].add(m_p - m)
-    out = gmm(lhs, rhs, group_sizes.astype(jnp.int32),
-              preferred_element_type=lhs.dtype,
-              tiling=(tm, min(128, K), min(128, rhs.shape[-1])),
-              interpret=interpret)
-    return out[:m]
+    E, _, N = rhs.shape
+    tiling = _gmm_tiles(m, K, N, E, lhs.dtype.itemsize)
+    lhs, sizes = _pad_rows(lhs, group_sizes, tiling[0])
+    return gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype, tiling=tiling,
+               interpret=interpret)[:m]
+
+
+def _gmm_padded_fwd(lhs, rhs, group_sizes, interpret):
+    return _gmm_padded(lhs, rhs, group_sizes, interpret), (lhs, rhs, group_sizes)
+
+
+def _gmm_padded_bwd(interpret, residual, grad):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    lhs, rhs, group_sizes = residual
+    m, K = lhs.shape
+    E, _, N = rhs.shape
+    tiling = _gmm_tiles(m, N, K, E, grad.dtype.itemsize)
+    g, sizes = _pad_rows(grad, group_sizes, tiling[0])
+    d_lhs = gmm(g, rhs, sizes, preferred_element_type=lhs.dtype, tiling=tiling,
+                transpose_rhs=True, interpret=interpret)[:m]
+    tiling = _tgmm_tiles(m, K, N, E, lhs.dtype.itemsize)
+    g, sizes = _pad_rows(grad, group_sizes, tiling[0])
+    d_rhs = tgmm(_pad_rows(lhs, group_sizes, tiling[0])[0].swapaxes(0, 1), g, sizes,
+                 preferred_element_type=rhs.dtype, tiling=tiling, interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+_gmm_padded.defvjp(_gmm_padded_fwd, _gmm_padded_bwd)
 
 
 def _grouped_matmul(lhs, rhs, group_sizes):
@@ -284,8 +432,9 @@ def _grouped_matmul(lhs, rhs, group_sizes):
 
     TPU (dims permitting): the megablox Pallas grouped-matmul kernel
     (tile-skips at group boundaries — the reference's ``cutlass_ops`` grouped
-    GEMM analog). Elsewhere: ``lax.ragged_dot`` (XLA-CPU lowers it densely
-    over groups; correct, and only the fallback)."""
+    GEMM analog) at the tiles :func:`_gmm_tiles` picks from the shapes.
+    Elsewhere: ``lax.ragged_dot`` (XLA-CPU lowers it densely over groups;
+    correct, and only the fallback)."""
     K, N = lhs.shape[1], rhs.shape[-1]
     if jax.default_backend() == "tpu" and K % 128 == 0 and N % 128 == 0:
         return _gmm_padded(lhs, rhs, group_sizes)

@@ -208,22 +208,3 @@ def test_sampling_shapes_and_determinism():
     b = engine.generate(ids, max_new_tokens=3, do_sample=True, temperature=0.8, top_k=10, seed=7)
     np.testing.assert_array_equal(a, b)
     assert a.shape == (2, 7)
-
-
-def test_gmm_padded_handles_nonmultiple_rows():
-    """megablox gmm requires rows % tile == 0; the wrapper pads rows into the
-    last group and slices them off (review r5: non-128-multiple prefills
-    crashed at trace time on TPU). Interpret mode exercises the real kernel
-    path on CPU."""
-    from deepspeed_tpu.inference.model import _gmm_padded
-
-    rng = np.random.default_rng(4)
-    m, K, N, G = 20, 128, 128, 3
-    lhs = jnp.asarray(rng.standard_normal((m, K)), jnp.float32)
-    rhs = jnp.asarray(rng.standard_normal((G, K, N)) * 0.1, jnp.float32)
-    gs = jnp.asarray([7, 9, 4], jnp.int32)
-    got = _gmm_padded(lhs, rhs, gs, interpret=True)
-    want = jax.lax.ragged_dot(lhs, rhs, gs)
-    assert got.shape == (m, N)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-4)

@@ -171,6 +171,37 @@ def test_latent_paged_attention_compiles(one_chip, monkeypatch, N, C):
                              i32(N, C), i32(N)) == 1
 
 
+@pytest.mark.parametrize("kernels", ["forward", "gradient"])
+@pytest.mark.parametrize("m,K,N,E,dtype", [
+    (65536, 2048, 1536, 64, jnp.bfloat16),  # glm-4.7-flash.serve.batch's (64, 256) prefill: gate and up
+    (65536, 1536, 2048, 64, jnp.bfloat16),  # and the down-projection
+    (512, 2048, 1536, 64, jnp.bfloat16),    # the smallest grouped call at 64 experts (T = 2E tokens x 4 picks)
+    (32768, 4096, 14336, 8, jnp.bfloat16),  # mixtral-shaped: 8 experts, groups of thousands
+    (32768, 14336, 4096, 8, jnp.bfloat16),  # and its down-projection, whose K does not fit whole: a k loop
+    (40, 128, 256, 4, jnp.bfloat16),        # fewer rows than a tile: one tile of the rows, padded
+    (65536, 2048, 1536, 64, jnp.float32),   # fp32 operands: blocks twice the bytes, sublanes of 8
+], ids=["cell-gate-up", "cell-down", "m512-e64", "mixtral-up", "mixtral-down", "m40", "cell-gate-up-fp32"])
+def test_grouped_matmul_compiles_at_the_chosen_tiles(one_chip, m, K, N, E, dtype, kernels):
+    """The routed prefill's grouped matmul at the tiles ``_gmm_tiles`` picks
+    from the call's shapes: Mosaic's VMEM refusal (16 MiB scoped, no limit of
+    the call's own) is seen here, before a chip call is spent. And its
+    gradient, which ``DropFreeMoE`` takes on a TPU: ``grad @ rhs.T`` through
+    the same kernel and ``tgmm`` for the weights, each at tiles from its own
+    shapes (the library's vjp at the forward's tiles was refused at
+    mixtral-up: 18.25 MiB); the forward's product is not asked for there, so
+    two kernels."""
+    from deepspeed_tpu.inference.model import _gmm_padded
+
+    lhs = jax.ShapeDtypeStruct((m, K), dtype, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((E, K, N), dtype, sharding=one_chip)
+    gs = jax.ShapeDtypeStruct((E,), jnp.int32, sharding=one_chip)
+    if kernels == "forward":
+        assert _compiled_kernels(_gmm_padded, lhs, rhs, gs) == 1
+    else:
+        grad = jax.grad(lambda a, b, g: _gmm_padded(a, b, g).astype(jnp.float32).sum(), argnums=(0, 1))
+        assert _compiled_kernels(grad, lhs, rhs, gs) == 2
+
+
 @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["bf16", "int8"])
 def test_decode_chain_updates_the_pool_in_place(one_chip, monkeypatch, kv_quant):
     """Whether a carried array is updated in place is the chip's compiler's
