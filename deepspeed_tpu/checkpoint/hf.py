@@ -111,6 +111,40 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
         # qwen2 always does
         kw["qkv_bias"] = True if mt == "qwen2" else bool(hf_config.get("attention_bias", False))
         return TransformerConfig(**kw)
+    if mt == "evabyte":
+        # a llama-shaped decoder over bytes with EVA attention: an exact
+        # window beside chunk summaries, norms that store their offset from
+        # one, an fp32 residual stream, num_pred_heads output heads in one
+        # kernel. Multi-byte self-speculation over the further heads is not built
+        if hf_config.get("attention_class", "eva") != "eva":
+            raise ValueError(f"evabyte with attention_class={hf_config['attention_class']!r} is unsupported")
+        if hf_config.get("rope_scaling"):
+            raise ValueError("evabyte with rope_scaling is unsupported")
+        if hf_config.get("num_chunks"):
+            raise ValueError("evabyte with num_chunks (a fixed number of chunks a window) is unsupported")
+        dtype = hf_config.get("dtype", hf_config.get("torch_dtype"))
+        return TransformerConfig(
+            vocab_size=hf_config["vocab_size"],
+            hidden_size=hf_config["hidden_size"],
+            intermediate_size=hf_config["intermediate_size"],
+            num_layers=hf_config["num_hidden_layers"],
+            num_heads=hf_config["num_attention_heads"],
+            num_kv_heads=hf_config.get("num_key_value_heads"),
+            max_seq_len=hf_config.get("max_position_embeddings", 32768),
+            norm="rmsnorm",
+            activation="silu_glu",
+            position="rope",
+            rope_theta=float(hf_config.get("rope_theta", 100000.0)),
+            norm_eps=float(hf_config.get("rms_norm_eps", 1e-5)),
+            tie_embeddings=bool(hf_config.get("tie_word_embeddings", False)),
+            qkv_bias=bool(hf_config.get("attention_bias", False)),
+            eva_window=hf_config["window_size"],
+            eva_chunk=hf_config["chunk_size"],
+            norm_unit_offset=bool(hf_config.get("norm_add_unit_offset", False)),
+            fp32_residual=bool(hf_config.get("fp32_skip_add", False)),
+            num_pred_heads=int(hf_config.get("num_pred_heads", 1)),
+            param_dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(dtype, jnp.float32),
+        )
     if mt == "glm4_moe_lite":
         # latent attention, leading dense layers before the routed stack, a
         # sigmoid router with a correction bias and a shared expert. The
@@ -356,13 +390,15 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
     raise ValueError(
         f"unsupported HF model_type {mt!r} (supported: llama/mistral/mixtral/"
         "qwen2/gpt2/opt/falcon/phi/gpt_neox/bloom/gptj/codegen/gpt_bigcode/"
-        "glm4_moe_lite)")
+        "glm4_moe_lite/evabyte)")
 
 
 def detect_family(state: Dict[str, np.ndarray]) -> str:
     keys = state.keys()
     if any("kv_a_proj_with_mqa" in k for k in keys) and any("e_score_correction_bias" in k for k in keys):
         return "glm4_moe_lite"
+    if any("adaptive_phi" in k for k in keys):
+        return "evabyte"
     if any("block_sparse_moe" in k for k in keys):
         return "mixtral"
     if any("decoder.embed_positions" in k for k in keys) and not any("encoder." in k for k in keys):
@@ -916,7 +952,79 @@ def latent_moe_hf_state(params, cfg: TransformerConfig) -> Dict[str, np.ndarray]
     return state
 
 
+def _evabyte_names(cfg: TransformerConfig):
+    """EvaByte under its HF names as ``(HF name, path in our tree, shape of
+    the leaf)``, ``{i}`` the layer: llama's names, plus the two pooling vectors
+    a head and the head over ``num_pred_heads * vocab`` columns."""
+    h, hd, H, Hkv, f = (cfg.hidden_size, cfg.dims_per_head, cfg.num_heads, cfg.kv_heads,
+                        cfg.intermediate_size)
+    attn = "model.layers.{i}.self_attn."
+    layer = [
+        ("model.layers.{i}.input_layernorm.weight", ("attn_norm", "scale"), (h,)),
+        ("model.layers.{i}.post_attention_layernorm.weight", ("mlp_norm", "scale"), (h,)),
+        (attn + "q_proj.weight", ("attn", "wq", "kernel"), (h, H, hd)),
+        (attn + "k_proj.weight", ("attn", "wk", "kernel"), (h, Hkv, hd)),
+        (attn + "v_proj.weight", ("attn", "wv", "kernel"), (h, Hkv, hd)),
+        # the one matrix whose INPUT is the split side: [hidden, H*hd] stored
+        (attn + "o_proj.weight", ("attn", "wo", "kernel"), (H * hd, h)),
+        (attn + "adaptive_phi", ("attn", "phi"), (Hkv, hd)),
+        (attn + "adaptive_mu_k", ("attn", "mu"), (Hkv, hd)),
+    ] + [("model.layers.{i}.mlp.%s.weight" % hf, ("mlp", ours, "kernel"), (f, h) if ours == "w_down" else (h, f))
+         for hf, ours in _GLU_MATRICES]
+    top = [("model.embed_tokens.weight", ("embed", "embedding"), (cfg.vocab_size, h)),
+           ("model.norm.weight", ("final_norm", "scale"), (h,)),
+           ("lm_head.weight", ("lm_head", "kernel"), (h, cfg.num_pred_heads * cfg.vocab_size))]
+    return top, layer
+
+
+def _convert_evabyte(state, cfg: TransformerConfig) -> Dict[str, Any]:
+    g = _getter(state, ("",))
+    top, per_layer = _evabyte_names(cfg)
+
+    def leaf(name, path, shape):
+        w = np.asarray(g(name))
+        if path[0] == "embed":
+            return w  # a lookup table, stored [vocab, hidden] as we keep it
+        return w.reshape(shape) if "adaptive" in name else _to_leaf(w, shape)
+
+    def layer(i):
+        blk: Dict[str, Any] = {}
+        for hf, path, shape in per_layer:
+            _set(blk, path, leaf(hf.format(i=i), path, shape))
+        wo = blk["attn"]["wo"]
+        wo["kernel"] = wo["kernel"].reshape(cfg.num_heads, cfg.dims_per_head, cfg.hidden_size)
+        return blk
+
+    params: Dict[str, Any] = {"layers": _stack(layer, cfg.num_layers)}
+    for hf, path, shape in top:
+        _set(params, path, leaf(hf, path, shape))
+    return params
+
+
+def evabyte_hf_state(params, cfg: TransformerConfig) -> Dict[str, np.ndarray]:
+    """The way back: an EvaByte parameter tree under its HF names."""
+    top, per_layer = _evabyte_names(cfg)
+
+    def stored(name, path, a, shape):
+        a = np.asarray(a)
+        if path[0] == "embed" or "adaptive" in name:
+            return a.reshape(shape)
+        return _from_leaf(a, shape)
+
+    def at(tree, path):
+        for key in path:
+            tree = tree[key]
+        return tree
+
+    state = {hf: stored(hf, path, at(params, path), shape) for hf, path, shape in top}
+    for i in range(cfg.num_layers):
+        for hf, path, shape in per_layer:
+            state[hf.format(i=i)] = stored(hf, path, at(params["layers"], path)[i], shape)
+    return state
+
+
 _CONVERTERS = {
+    "evabyte": _convert_evabyte,
     "glm4_moe_lite": _convert_glm4_moe_lite,
     "llama": _convert_llama,
     "mistral": _convert_llama,

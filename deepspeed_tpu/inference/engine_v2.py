@@ -103,6 +103,15 @@ class RaggedInferenceConfig(DeepSpeedConfigModel):
     max_seq_len: Optional[int] = None  # default: model max_seq_len
     row_bucket: int = 8
     chunk_bucket: int = 16
+    # Admission by tokens (reference ``RaggedInferenceEngineConfig``'s
+    # state-manager field of the same name): one prefill call of ``generate``
+    # takes queued prompts while the call's PADDED tokens (rows rounded to
+    # row_bucket x the longest prompt rounded to chunk_bucket) stay within
+    # it; the first prompt of a call is always taken, and the rest go into
+    # further calls, one after another, before the round's decode chain. None:
+    # every queued prompt that fits the pool goes into one call, whatever its
+    # length.
+    max_ragged_batch_size: Optional[int] = None
     # K decode iterations per dispatched program (paged.ragged_decode_chain):
     # one dispatch + one host sync per K decoded tokens. 1 = per-token loop
     # (same outputs, K× the dispatch/sync overhead). The effective chain
@@ -261,6 +270,35 @@ class InferenceEngineV2:
         max_len = config.max_seq_len or model_config.max_seq_len
         self.max_seq_len = max_len
         self.max_pages = -(-max_len // config.kv_block_size)
+        # EVA attention: a row's table holds summary pages and window pages of
+        # the one pool (ragged.WindowLayout), not a page a block of positions
+        self._layout = None
+        if model_config.eva_window:
+            from deepspeed_tpu.inference.ragged import WindowLayout
+
+            chunk, bs = model_config.eva_chunk, config.kv_block_size
+            missing = [
+                (chunk != bs, f"kv_block_size={bs}: a page of exact rows closes into ONE summary "
+                 f"row, which needs kv_block_size={chunk}, the model's chunk"),
+                (model_config.eva_window % (chunk * chunk) != 0, f"eva_window={model_config.eva_window}: "
+                 f"a closed window's summaries fill whole pages (a multiple of {chunk} x {chunk})"),
+                (config.spec_decode > 0, "spec_decode: drafts are a chunk of several tokens in the "
+                 "middle of a window, which the chunk path does not take"),
+                (config.kv_quant is not None, f"kv_cache_dtype={config.kv_dtype_name!r}: a summary row "
+                 "has no per-token scale"),
+                (config.prefix_cache, "prefix_cache: a page's content is not a function of a block "
+                 "of the prompt's tokens once windows close into summaries"),
+                (mesh.shape["tp"] > 1, f"tp={mesh.shape['tp']}: the pooling vectors and the "
+                 "closing are not partitioned over heads"),
+                (config.chunk_bucket % config.kv_block_size != 0,
+                 f"chunk_bucket={config.chunk_bucket}: a chunk is whole pages of {config.kv_block_size}"),
+            ]
+            missing = [what for bad, what in missing if bad]
+            if missing:
+                raise ValueError("EVA attention (eva_window > 0) does not serve with " + "; ".join(missing))
+            self._layout = WindowLayout(model_config.eva_window, config.kv_block_size, max_len)
+            self.max_pages = self._layout.width
+        self.windows_closed = 0  # EVA: windows pooled into summaries so far
 
         from deepspeed_tpu.utils.hbm import kv_slot_bytes
 
@@ -298,7 +336,7 @@ class InferenceEngineV2:
             num_blocks = config.num_kv_blocks
         self.num_kv_blocks = num_blocks
         self.state = StateManager(num_blocks, config.kv_block_size, config.max_seqs,
-                                  max_blocks_per_seq=self.max_pages)
+                                  max_blocks_per_seq=self.max_pages, layout=self._layout)
         self._staging = BatchStaging(self.max_pages)
         self.prefix_cache: Optional[PrefixCache] = None
         if config.prefix_cache:
@@ -357,6 +395,16 @@ class InferenceEngineV2:
             workspace = config.row_bucket * gathered * (
                 2 * model_config.kv_heads * model_config.dims_per_head * dtype_b
                 + 2 * model_config.num_heads * config.chunk_bucket * 4)
+            if self._layout is not None:
+                # the chunk program attends inside the chunk, a window at a
+                # time: its temporaries are a call's tokens x (two fp32
+                # residuals, q/k/v and the attention's fp32 merge, the GLU's
+                # pair), not a gathered context
+                tokens = config.max_ragged_batch_size or config.row_bucket * config.chunk_bucket
+                workspace = tokens * (
+                    2 * model_config.hidden_size * 4
+                    + 4 * model_config.num_heads * model_config.dims_per_head * dtype_b
+                    + 2 * model_config.intermediate_size * dtype_b)
             need = (param_bytes
                     + kv_bytes // (tp if kv_on_tp else 1)
                     + config.row_bucket * model_config.vocab_size * 4
@@ -789,6 +837,10 @@ class InferenceEngineV2:
         holders; the source releases its OWN reference only at ``flush``
         after the import commits). The dispatch is asynchronous: the pages
         stream out while the host assembles the next prefill."""
+        if self._layout is not None:
+            raise ValueError(
+                "KV-block migration of an EVA model: the wire format carries pages in position "
+                "order and knows one kind of row; summary and window pages are not told apart")
         seq = self.state.get(uid)
         if seq is None or seq.n_blocks == 0:
             raise ValueError(f"uid {uid} has no KV blocks to export")
@@ -891,6 +943,37 @@ class InferenceEngineV2:
                 return True
         return False
 
+    # ---------------------------------------------------------------- EVA
+    def _eva_args(self, positions: np.ndarray, lens: np.ndarray) -> Dict[str, Any]:
+        """What a dispatch of an EVA model reads, for its ``serve:dispatch``
+        span, while somebody records spans: ``positions`` [rows, steps] of the
+        tokens it feeds, the first ``lens`` [rows] of each row real (a chain's
+        as its budgets plan them).
+        ``attended_rows`` sums, over those tokens, the rows attention reads
+        (closed windows' summaries + the open window up to the token),
+        ``context_tokens`` what full attention would read, ``row_steps`` the
+        tokens, ``windows_closed`` those that end a window."""
+        if self._layout is None or not self._tracer.recording():
+            return {}
+        lay = self._layout
+        pos = positions.astype(np.int64)
+        fed = np.arange(pos.shape[1])[None, :] < np.asarray(lens)[:, None]
+        return {"attended_rows": int(lay.attended(pos)[fed].sum()), "context_tokens": int((pos + 1)[fed].sum()),
+                "row_steps": int(fed.sum()),
+                "windows_closed": int((pos % lay.window == lay.window - 1)[fed].sum())}
+
+    def _advance(self, uids, counts) -> None:
+        """``seen_tokens`` of each uid forward by its count; the windows that
+        closed on the way are counted (``serving/eva_windows_closed``)."""
+        if self._layout is None:
+            for uid, n in zip(uids, counts):
+                self.state.get(uid).seen_tokens += int(n)
+            return
+        closed = sum(self.state.advance(uid, int(n)) for uid, n in zip(uids, counts))
+        if closed:
+            self.windows_closed += closed
+            self._tracer.count("serving/eva_windows_closed", float(closed))
+
     # ---------------------------------------------------------------- put
     def _build_batch(self, uids, token_lists) -> RaggedBatch:
         with self._tracer.span("serve:assemble", rows=len(uids)):
@@ -913,7 +996,8 @@ class InferenceEngineV2:
             raise RuntimeError("insufficient KV blocks/slots; call can_schedule first")
         batch = self._build_batch(uids, token_lists)
         step = self._step_fn(batch.n_rows, batch.tokens.shape[1])
-        with self._tracer.span("serve:dispatch", kind="put", rows=batch.n_rows):
+        with self._tracer.span("serve:dispatch", kind="put", rows=batch.n_rows,
+                               **self._eva_args(batch.positions, batch.new_lens)):
             logits, self.pool, *picks = step(
                 self.params, self.pool,
                 jnp.asarray(batch.tokens), jnp.asarray(batch.positions),
@@ -921,8 +1005,7 @@ class InferenceEngineV2:
             )
         self.dispatch_count += 1
         self._log_picks(picks, uids, None, token_lists)
-        for uid, toks in zip(uids, token_lists):
-            self.state.get(uid).seen_tokens += len(toks)
+        self._advance(uids, map(len, token_lists))
         self.host_sync_count += 1
         return np.asarray(logits[: len(uids)])
 
@@ -1001,7 +1084,8 @@ class InferenceEngineV2:
         batch = self._build_batch(uids, token_lists)
         step = self._sample_step_fn(batch.n_rows, batch.tokens.shape[1], sample_kw)
         with self._tracer.span("serve:dispatch", kind="prefill", rows=batch.n_rows,
-                               live=len(uids), rids=self._span_rids(rids)):
+                               live=len(uids), rids=self._span_rids(rids),
+                               **self._eva_args(batch.positions, batch.new_lens)):
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "prefill")
             toks, rng, self.pool, *picks = step(
@@ -1012,8 +1096,7 @@ class InferenceEngineV2:
             )
         self.dispatch_count += 1
         self._log_picks(picks, uids, rids, token_lists)
-        for uid, t in zip(uids, token_lists):
-            self.state.get(uid).seen_tokens += len(t)
+        self._advance(uids, map(len, token_lists))
         with self._tracer.span("serve:fetch", kind="prefill"):
             out = np.asarray(toks[: len(uids)])
         self.host_sync_count += 1
@@ -1068,14 +1151,22 @@ class InferenceEngineV2:
             buf = self._chain_arrays(rows)
             for i, uid in enumerate(uids):
                 seq = self.state.extend(uid, min(k, int(budgets[i])))
-                buf["tables"][i, : seq.n_blocks] = seq.blocks
+                if self._layout is None:
+                    buf["tables"][i, : seq.n_blocks] = seq.blocks
+                else:
+                    seq.table_into(buf["tables"][i])
                 buf["pos"][i] = seq.seen_tokens
             buf["tokens"][:n] = last_tokens
             buf["active"][:n] = True
             buf["budgets"][:n] = np.minimum(budgets, k)
+            eva_args = {} if self._layout is None else self._eva_args(
+                buf["pos"][:n, None] + np.arange(k)[None, :], buf["budgets"][:n])
+            if eva_args and self._tracer.enabled:
+                self._tracer.registry.gauge("serving/eva_rows_per_context_token").set(
+                    eva_args["attended_rows"] / max(eva_args["context_tokens"], 1))
         chain = self._chain_fn(rows, k, eos_id, sample_kw)
         with self._tracer.span("serve:dispatch", kind="chain", rows=rows, live=n,
-                               k=k, chain=chain_id):
+                               k=k, chain=chain_id, **eva_args):
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "chain")
             out, emitted, _, rng, self.pool, *routed = chain(
@@ -1095,8 +1186,7 @@ class InferenceEngineV2:
                 self.last_experts_touched = float(np.asarray(routed[0])[:live_steps].mean())
         self.host_sync_count += 1
         self._log_picks(routed, uids, rids, emitted=emitted)
-        for uid, e in zip(uids, emitted):
-            self.state.get(uid).seen_tokens += int(e)
+        self._advance(uids, emitted)
         return out, emitted, rng
 
     def decode_spec_chain(
@@ -1267,7 +1357,15 @@ class InferenceEngineV2:
                         f"(+{margin} speculative slack) exceeds engine "
                         f"max_seq_len={self.max_seq_len}"
                     )
-                if len(p) + max_new_tokens + margin > pool_tokens:
+                if self._layout is not None:
+                    # summaries of the windows it closes + one window's rows, at the most
+                    total = len(p) + max_new_tokens
+                    held = (total // self._layout.window * self._layout.per_closed
+                            + min(self._layout.window_pages, -(-total // self.config.kv_block_size)))
+                    fits = held <= self.num_kv_blocks
+                else:
+                    fits = len(p) + max_new_tokens + margin <= pool_tokens
+                if not fits:
                     raise ValueError(
                         f"prompt {i} ({len(p)} tokens) + max_new_tokens={max_new_tokens} "
                         f"cannot ever fit the KV pool ({pool_tokens} slots); no amount of "
@@ -1355,52 +1453,70 @@ class InferenceEngineV2:
 
         pc = self.prefix_cache
         span = self._tracer.span
+        token_budget = self.config.max_ragged_batch_size
         while queue or active:
-            # ---- admit pending prompts (fused prefill + first-token sample)
-            adm_uids: List[int] = []
-            adm_tokens: List[np.ndarray] = []
-            adm_counts: List[int] = []
-            adm_full: List[np.ndarray] = []  # full contexts, for cache insert
-            with span("serve:admit", queue_len=len(queue)) as admit_span:
-                decoding = list(active.keys())  # reserve 1-token decode headroom
-                while queue and len(active) < self.config.max_seqs:
-                    idx = queue[0]
-                    if arr is not None and time.perf_counter() - t_start < arr[idx]:
-                        break  # open-loop workload: not arrived yet
-                    cand = context(idx)
-                    suffix = self.try_admit(
-                        next_uid, cand, decoding + adm_uids,
-                        [1] * len(decoding) + adm_counts)
-                    if suffix is None:
-                        break
-                    queue.popleft()
-                    adm_uids.append(next_uid)
-                    adm_tokens.append(suffix)
-                    adm_counts.append(len(suffix))
-                    adm_full.append(cand)
-                    if tracker is not None:
-                        tracker.admit(idx, next_uid)
-                    active[next_uid] = idx
-                    order[next_uid] = None
-                    next_uid += 1
-                adm_rids = [active[u] for u in adm_uids]
-                admit_span.set_metadata(requests=len(adm_uids), tokens=sum(adm_counts),
-                                        rids=self._span_rids(adm_rids))
-            if adm_uids:
-                toks, rng = self._put_sample(adm_uids, adm_tokens, rng, sample_kw,
-                                             tracker=tracker, rids=adm_rids)
-                with span("serve:accept", kind="prefill", emitted=len(adm_uids)):
-                    if pc is not None:
-                        # index the freshly written full blocks (quantized bytes
-                        # are in the pool now — hashes snapshot them as written)
-                        for u, full in zip(adm_uids, adm_full):
-                            self._insert_prefix(u, full)
-                    if tracker is not None:
-                        tracker.emitted_batch(adm_rids, (1,) * len(adm_rids))
-                    for u, t in zip(adm_uids, toks):
-                        accept(u, t)
+            # ---- admit pending prompts (fused prefill + first-token sample): one
+            # call, or under a token budget (max_ragged_batch_size) as many calls,
+            # one after another, as the queue and the pool allow, each within it
+            admitted = False
+            while True:
+                adm_uids: List[int] = []
+                adm_tokens: List[np.ndarray] = []
+                adm_counts: List[int] = []
+                adm_full: List[np.ndarray] = []  # full contexts, for cache insert
+                with span("serve:admit", queue_len=len(queue)) as admit_span:
+                    decoding = list(active.keys())  # reserve 1-token decode headroom
+                    longest = 0  # of this call's prompts, for the token budget
+                    call_full = False
+                    while queue and len(active) < self.config.max_seqs:
+                        idx = queue[0]
+                        if arr is not None and time.perf_counter() - t_start < arr[idx]:
+                            break  # open-loop workload: not arrived yet
+                        cand = context(idx)
+                        if token_budget is not None and adm_uids:
+                            longest = max(longest, len(cand))
+                            rows = -(-(len(adm_uids) + 1) // self.config.row_bucket) * self.config.row_bucket
+                            chunk = -(-longest // self.config.chunk_bucket) * self.config.chunk_bucket
+                            if rows * chunk > token_budget:
+                                call_full = True  # the rest go into the next call
+                                break
+                        longest = max(longest, len(cand))
+                        suffix = self.try_admit(
+                            next_uid, cand, decoding + adm_uids,
+                            [1] * len(decoding) + adm_counts)
+                        if suffix is None:
+                            break
+                        queue.popleft()
+                        adm_uids.append(next_uid)
+                        adm_tokens.append(suffix)
+                        adm_counts.append(len(suffix))
+                        adm_full.append(cand)
+                        if tracker is not None:
+                            tracker.admit(idx, next_uid)
+                        active[next_uid] = idx
+                        order[next_uid] = None
+                        next_uid += 1
+                    adm_rids = [active[u] for u in adm_uids]
+                    admit_span.set_metadata(requests=len(adm_uids), tokens=sum(adm_counts),
+                                            rids=self._span_rids(adm_rids))
+                if adm_uids:
+                    admitted = True
+                    toks, rng = self._put_sample(adm_uids, adm_tokens, rng, sample_kw,
+                                                 tracker=tracker, rids=adm_rids)
+                    with span("serve:accept", kind="prefill", emitted=len(adm_uids)):
+                        if pc is not None:
+                            # index the freshly written full blocks (quantized bytes
+                            # are in the pool now — hashes snapshot them as written)
+                            for u, full in zip(adm_uids, adm_full):
+                                self._insert_prefix(u, full)
+                        if tracker is not None:
+                            tracker.emitted_batch(adm_rids, (1,) * len(adm_rids))
+                        for u, t in zip(adm_uids, toks):
+                            accept(u, t)
+                if not (call_full and adm_uids):
+                    break
             if not active:
-                if queue and not adm_uids:
+                if queue and not admitted:
                     if arr is not None:
                         wait = t_start + arr[queue[0]] - time.perf_counter()
                         if wait > 0:  # idle until the next synthetic arrival

@@ -61,6 +61,10 @@ def init_cache(
     """Allocate an empty cache (reference ``InferenceContext`` workspace,
     ``csrc/transformer/inference/includes/inference_context.h`` — here it is
     just a pytree of preallocated arrays XLA can donate/alias)."""
+    if cfg.eva_window:
+        raise NotImplementedError(
+            "EVA attention (eva_window > 0) in the v1 engine: its dense cache holds one row a "
+            "position and has no summaries; serve it through InferenceEngineV2")
     hd = cfg.dims_per_head
     shape = (cfg.num_layers, batch_size, max_len, cfg.kv_heads, hd)
     return KVCache(
@@ -564,6 +568,10 @@ def _logits(params, cfg: TransformerConfig, x):
     x = _apply_norm(params["final_norm"], cfg, x)
     if cfg.tie_embeddings:
         return x @ params["embed"]["embedding"].T.astype(cfg.dtype)
+    if cfg.num_pred_heads > 1:
+        # head 0 (the next token's) of a [hidden, heads * vocab] kernel, fp32 logits
+        head = params["lm_head"]["kernel"][:, :cfg.vocab_size].astype(cfg.dtype)
+        return jnp.dot(x, head, preferred_element_type=jnp.float32)
     logits = x @ params["lm_head"]["kernel"].astype(cfg.dtype)
     if "bias" in params["lm_head"]:
         logits = logits + params["lm_head"]["bias"].astype(cfg.dtype)

@@ -100,6 +100,10 @@ def init_pool(
     cfg: TransformerConfig, num_blocks: int, block_size: int, dtype: Any = jnp.bfloat16,
     kv_quant: Optional[str] = None,
 ) -> PagedKVPool:
+    if cfg.eva_window and kv_quant is not None:
+        raise ValueError(
+            f"kv_quant={kv_quant!r} with EVA attention: a summary row is a weighted sum of "
+            "keys and has no per-token scale; use a bf16/fp32 pool")
     if cfg.latent_attention:
         if kv_quant is not None:
             raise ValueError(
@@ -303,6 +307,146 @@ def _latent_attention(ap, cfg: TransformerConfig, h, positions, new_lens, block_
     return out, pk
 
 
+def _eva_attention(cfg: TransformerConfig, positions, new_lens, block_tables, bs: int, num_rows: int):
+    """``attend(ap, h, pk, pv, first_page) -> (attention output [N, C, E], pk,
+    pv)``: EVA attention of a call's new tokens against a pool whose pages are
+    of two kinds (``ragged.WindowLayout`` is the host's account of the same
+    columns). Everything but the layer's offset is
+    computed here, once for all layers.
+
+    A row feeds either ONE token, anywhere (decode, ``put``), or a chunk that
+    starts at position 0 (a prompt; the host refuses anything else):
+
+    - one token at ``t`` (window ``w``, offset ``r``): its key and value go to
+      its window page; it attends through the paged kernel over the row's
+      table re-read as ``[w closed windows' summary pages | window pages]``
+      up to slot ``w * window / chunk + r``, which is one softmax over the
+      summaries and its window's exact rows; and if it is its window's last
+      token (``r == window - 1``) the window's pages are pooled, page for
+      row, into the summary pages that follow the closed windows'
+      (``eva_close``, under a ``cond``: a step in which no row closes pays
+      nothing, and one in which a row does pays for that row's window). No
+      exact row moves: the next window writes over the pages.
+    - a chunk: ``ops/eva.py`` computes its windows at once from the chunk's
+      own keys (``eva_prefill``); what is WRITTEN is the summaries of the
+      windows it closes and the exact rows of the window it leaves open, each
+      a whole page at a time. A closed window's exact rows never reach the
+      pool.
+    """
+    from deepspeed_tpu.models.transformer import rope_at
+    from deepspeed_tpu.ops.eva import eva_attention, pool_chunks
+
+    N, C = positions.shape
+    P = block_tables.shape[1]
+    W, c = cfg.eva_window, cfg.eva_chunk
+    if c != bs:
+        raise ValueError(f"EVA attention with eva_chunk={c} needs kv_block_size={c}, got {bs}")
+    w_pages, per_closed = W // bs, W // c // bs
+    s_cols = P - w_pages  # the summary pages' columns, the closed windows' first
+    kvH, hd = cfg.kv_heads, cfg.dims_per_head
+    X = kvH * hd
+    col = jnp.arange(P)
+
+    # ---- the one-token rows
+    single = new_lens == 1
+    t = positions[:, 0]
+    w, r = t // W, t % W
+    s_page = jnp.take_along_axis(block_tables, (s_cols + r // bs)[:, None], axis=1)[:, 0]
+    s_page = jnp.where(single, s_page, num_rows)  # other rows' writes drop
+    s_slot = r % bs
+    closed_cols = (per_closed * w)[:, None]
+    view = jnp.take_along_axis(
+        block_tables, jnp.clip(jnp.where(col < closed_cols, col, s_cols + col - closed_cols), 0, P - 1), axis=1)
+    view_pos = jnp.where(single, (W // c) * w + r, -1)[:, None]  # -1: no context, zeros out
+    closing = single & (r == W - 1)
+    close_to = jnp.take_along_axis(
+        block_tables, jnp.clip(closed_cols + jnp.arange(per_closed), 0, P - 1), axis=1)
+    close_to = jnp.where(closing[:, None], close_to, num_rows)
+    closing_first, n_closing = jnp.argsort(~closing), closing.sum()
+    window_cols = block_tables[:, s_cols:]
+
+    # ---- the chunk rows
+    if C > 1:
+        width = W if C > W else -(-C // c) * c
+        Cp = -(-C // width) * width
+        n_win = Cp // width
+        chunk_row = new_lens > 1
+        n_closed = jnp.where(chunk_row, new_lens // W, 0)
+        open_len = jnp.where(chunk_row, new_lens - n_closed * W, 0)
+        n_closable = Cp // W  # windows a chunk of this shape can close
+        sum_to = jnp.where(
+            jnp.repeat(jnp.arange(n_closable)[None, :] < n_closed[:, None], per_closed, axis=1),
+            block_tables[:, :n_closable * per_closed], num_rows)
+        open_pages = width // bs
+        open_to = jnp.where(jnp.arange(open_pages)[None, :] * bs < open_len[:, None],
+                            window_cols[:, :open_pages], num_rows)
+        open_at = jnp.clip(n_closed, 0, n_win - 1)
+
+        def open_rows(new):  # [N, Cp, kvH, hd] -> the pages of each row's open window
+            return jax.vmap(lambda a, i: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False))(
+                new.reshape(N, n_win, open_pages, bs, X), open_at)
+
+    def one_token(q, k, v, phi, mu, pk, pv, first_page):
+        with jax.named_scope("kv_write"):
+            pk = pk.at[first_page + s_page, s_slot].set(k.astype(pk.dtype).reshape(N, X), mode="drop")
+            pv = pv.at[first_page + s_page, s_slot].set(v.astype(pv.dtype).reshape(N, X), mode="drop")
+        ctx = paged_attention(q, pk, pv, view + first_page, view_pos, bs, new_lens=single.astype(jnp.int32))
+
+        @jax.named_scope("eva_close")
+        def close(pk, pv):
+            """The closing rows' windows, a row at a time (they come first in
+            ``closing_first``), page for summary row."""
+            def one(i, pools):
+                pk, pv = pools
+                row = closing_first[i]
+                pages = first_page + window_cols[row]
+                ks, vs = pool_chunks(pk[pages].reshape(w_pages, bs, kvH, hd),
+                                     pv[pages].reshape(w_pages, bs, kvH, hd), phi, mu)
+                to = first_page + close_to[row]
+                return (pk.at[to].set(ks.reshape(per_closed, bs, X), mode="drop"),
+                        pv.at[to].set(vs.reshape(per_closed, bs, X), mode="drop"))
+
+            return jax.lax.fori_loop(0, n_closing, one, (pk, pv))
+
+        # a step in which no row closes pays nothing, one in which a row does
+        # pays for that row's window (4 MB a layer at the published widths)
+        pk, pv = jax.lax.cond(n_closing > 0, close, lambda pk, pv: (pk, pv), pk, pv)
+        return ctx, pk, pv
+
+    @jax.named_scope("eva_prefill")
+    def chunk(q, k, v, phi, mu, pk, pv, first_page):
+        if Cp != C:
+            q, k, v = (jnp.pad(a, ((0, 0), (0, Cp - C), (0, 0), (0, 0))) for a in (q, k, v))
+        ctx, ks, vs = eva_attention(q, k, v, phi, mu, W, c)
+        with jax.named_scope("eva_close"):
+            if n_closable:
+                to = first_page + sum_to
+                pk = pk.at[to].set(ks[:, :n_closable * W // c].astype(pk.dtype).reshape(
+                    N, n_closable * per_closed, bs, X), mode="drop")
+                pv = pv.at[to].set(vs[:, :n_closable * W // c].astype(pv.dtype).reshape(
+                    N, n_closable * per_closed, bs, X), mode="drop")
+        with jax.named_scope("kv_write"):
+            to = first_page + open_to
+            pk = pk.at[to].set(open_rows(k).astype(pk.dtype), mode="drop")
+            pv = pv.at[to].set(open_rows(v).astype(pv.dtype), mode="drop")
+        return ctx[:, :C], pk, pv
+
+    @jax.named_scope("eva")
+    def attend(ap, h, pk, pv, first_page):
+        q, k, v = _qkv(ap, cfg, h)
+        q = rope_at(q, positions, cfg.rope_theta, cfg.rope_interleaved)
+        k = rope_at(k, positions, cfg.rope_theta, cfg.rope_interleaved)
+        phi, mu = ap["phi"], ap["mu"]
+        first, pk, pv = one_token(q[:, :1], k[:, :1], v[:, :1], phi, mu, pk, pv, first_page)
+        if C == 1:
+            return _attn_out(ap, cfg, first), pk, pv
+        ctx, pk, pv = chunk(q, k, v, phi, mu, pk, pv, first_page)
+        ctx = ctx.at[:, :1].set(jnp.where(single[:, None, None, None], first.astype(ctx.dtype), ctx[:, :1]))
+        return _attn_out(ap, cfg, ctx), pk, pv
+
+    return attend
+
+
 def _forward_hidden(
     params,
     cfg: TransformerConfig,
@@ -343,14 +487,17 @@ def _forward_hidden(
     L = cfg.num_layers
     NB = pool.k.shape[0] // L
     valid = jnp.arange(C)[None, :] < new_lens[:, None]  # [N, C]
-    # where each new token's row goes: (page of its layer-0 pool, slot in the
-    # page). Pad tokens get page L*NB, out of range in every layer: dropped.
-    page = jnp.take_along_axis(block_tables, positions // bs, axis=1)
-    w_page = jnp.where(valid, page, L * NB).reshape(-1)
-    w_slot = (positions % bs).reshape(-1)
+    eva = cfg.eva_window > 0
+    if not eva:
+        # where each new token's row goes: (page of its layer-0 pool, slot in the
+        # page). Pad tokens get page L*NB, out of range in every layer: dropped.
+        page = jnp.take_along_axis(block_tables, positions // bs, axis=1)
+        w_page = jnp.where(valid, page, L * NB).reshape(-1)
+        w_slot = (positions % bs).reshape(-1)
 
     with jax.named_scope("embed"):
-        x = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(cfg.dtype)
+        x = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(
+            jnp.float32 if cfg.fp32_residual else cfg.dtype)
         if cfg.embed_norm:
             x = _apply_norm(params["embed_norm"], cfg, x)
         if cfg.position == "learned":
@@ -375,7 +522,9 @@ def _forward_hidden(
     # Scales are a lane-dense row a PAGE, so they always go a page at a time.
     by_page = C >= bs
     put_pages = None
-    if by_page or quant is not None:
+    if eva:
+        eva_attend = _eva_attention(cfg, positions, new_lens, block_tables, bs, L * NB)
+    elif by_page or quant is not None:
         put_pages = _page_writer(block_tables, positions, new_lens, bs, L * NB)
 
     def put_values(a, new, first_page):
@@ -384,6 +533,9 @@ def _forward_hidden(
         return a.at[first_page + w_page, w_slot].set(new, mode="drop")
 
     def attention(lp, h, x, pk, pv, psk, psv, first_page):
+        if eva:
+            out, pk, pv = eva_attend(lp["attn"], h, pk, pv, first_page)
+            return out, pk, pv, psk, psv
         if latent:
             out, pk = _latent_attention(lp["attn"], cfg, h, positions, new_lens, block_tables,
                                         bs, pk, put_values, first_page)

@@ -165,12 +165,65 @@ class BlockedAllocator:
         return len(freed)
 
 
+@dataclasses.dataclass(frozen=True)
+class WindowLayout:
+    """An EVA row's block table (``paged._eva_attention`` is the device's
+    reading of the same columns): ``summary_cols`` columns of summary pages,
+    ``per_closed`` a closed window in the windows' order, then
+    ``window_pages`` columns for the open window's exact rows, position ``t``
+    in page ``(t % window) // block_size``. A page of exact
+    rows pools into one summary row (``block_size`` is the model's chunk), so
+    a closing adds ``per_closed`` pages and frees the window's own behind it.
+    """
+
+    window: int
+    block_size: int
+    max_seq_len: int
+
+    @property
+    def window_pages(self) -> int:
+        return self.window // self.block_size
+
+    @property
+    def per_closed(self) -> int:
+        return self.window // self.block_size // self.block_size
+
+    @property
+    def summary_cols(self) -> int:
+        return -(-self.max_seq_len // self.window) * self.per_closed
+
+    @property
+    def width(self) -> int:
+        return self.summary_cols + self.window_pages
+
+    def pages(self, seen: int, new: int) -> Tuple[int, int]:
+        """(summary pages, window pages) a row must hold while ``new`` tokens
+        are fed to it after ``seen``. A fresh row's tokens are a chunk, which
+        stores the summaries of the windows it closes and the rows of the one
+        it leaves open; after that tokens come one at a time (a decode chain's
+        steps) and fill the open window to its end before the next one writes
+        over its pages."""
+        chunk = new > 1 and not seen
+        rows = new % self.window if chunk else min(seen % self.window + new, self.window)
+        return (seen + new) // self.window * self.per_closed, -(-rows // self.block_size)
+
+    def attended(self, position):
+        """Rows the token at ``position`` (a number or an array of them)
+        attends to: the summaries of the closed windows and its own window's
+        rows up to itself."""
+        return (position // self.window * (self.window // self.block_size)
+                + position % self.window + 1)
+
+
 @dataclasses.dataclass
 class SequenceDescriptor:
     """Per-sequence tracking (reference ``DSSequenceDescriptor``).
 
     The block table is a preallocated int32 row (``_table[:n_blocks]``) so
-    batch assembly copies it with one vectorized write.
+    batch assembly copies it with one vectorized write. Under a
+    :class:`WindowLayout` the row is the layout's whole width, its live pages
+    are the first ``n_summary`` columns and ``n_window`` columns from
+    ``summary_cols`` on, and ``n_blocks`` is their sum.
     """
 
     uid: int
@@ -178,11 +231,43 @@ class SequenceDescriptor:
     n_blocks: int = 0
     _table: np.ndarray = dataclasses.field(
         default_factory=lambda: np.zeros((8,), np.int32))
+    layout: Optional[WindowLayout] = None
+    n_summary: int = 0
+    n_window: int = 0
 
     @property
     def blocks(self) -> np.ndarray:
-        """Live block ids (view — do not mutate)."""
+        """Live block ids (view — do not mutate; a copy under a layout)."""
+        if self.layout is not None:
+            first = self.layout.summary_cols
+            return np.concatenate([self._table[: self.n_summary],
+                                   self._table[first: first + self.n_window]])
         return self._table[: self.n_blocks]
+
+    def table_into(self, row: np.ndarray) -> None:
+        """Under a layout: the block table as the device reads it, every
+        column of it, into ``row``."""
+        row[: len(self._table)] = self._table
+
+    def hold(self, fresh: np.ndarray, summary: int, window: int) -> None:
+        """Under a layout: take ``fresh`` pages so that the row holds
+        ``summary`` summary pages and ``window`` window pages."""
+        first = self.layout.summary_cols
+        more = max(summary - self.n_summary, 0)
+        self._table[self.n_summary: self.n_summary + more] = fresh[:more]
+        self._table[first + self.n_window: first + self.n_window + len(fresh) - more] = fresh[more:]
+        self.n_summary += more
+        self.n_window += len(fresh) - more
+        self.n_blocks = self.n_summary + self.n_window
+
+    def pages_behind(self, keep: int) -> np.ndarray:
+        """Under a layout: give up the window pages past the first ``keep``."""
+        first = self.layout.summary_cols
+        gone = self._table[first + keep: first + self.n_window].copy()
+        self._table[first + keep: first + self.n_window] = 0
+        self.n_window = min(self.n_window, keep)
+        self.n_blocks = self.n_summary + self.n_window
+        return gone
 
     def append_blocks(self, new: np.ndarray) -> None:
         need = self.n_blocks + len(new)
@@ -205,11 +290,13 @@ class StateManager:
     inference/v2/ragged/ragged_manager.py:19)."""
 
     def __init__(self, num_blocks: int, block_size: int, max_seqs: int = 256,
-                 max_blocks_per_seq: Optional[int] = None):
+                 max_blocks_per_seq: Optional[int] = None,
+                 layout: Optional[WindowLayout] = None):
         self.allocator = BlockedAllocator(num_blocks)
         self.block_size = block_size
         self.max_seqs = max_seqs
         self.max_blocks_per_seq = max_blocks_per_seq
+        self.layout = layout  # None: a row's page is position // block_size
         self._seqs: Dict[int, SequenceDescriptor] = {}
 
     @property
@@ -234,14 +321,31 @@ class StateManager:
         if uid not in self._seqs:
             if len(self._seqs) >= self.max_seqs:
                 raise RuntimeError(f"max_seqs={self.max_seqs} active sequences reached")
-            cap = self.max_blocks_per_seq or 8
-            self._seqs[uid] = SequenceDescriptor(uid, _table=np.zeros((cap,), np.int32))
+            cap = self.layout.width if self.layout else self.max_blocks_per_seq or 8
+            self._seqs[uid] = SequenceDescriptor(uid, _table=np.zeros((cap,), np.int32),
+                                                 layout=self.layout)
         return self._seqs[uid]
+
+    def _pages_short(self, seq: Optional[SequenceDescriptor], new_tokens: int) -> Tuple[int, int, int]:
+        """Under a layout: (pages to allocate, summary pages, window pages)
+        for feeding ``new_tokens`` to ``seq`` (None: a fresh one)."""
+        seen, have_s, have_w = (seq.seen_tokens, seq.n_summary, seq.n_window) if seq else (0, 0, 0)
+        want_s, want_w = self.layout.pages(seen, new_tokens)
+        return max(want_s - have_s, 0) + max(want_w - have_w, 0), want_s, want_w
 
     def can_schedule(self, uids: Sequence[int], token_counts: Sequence[int]) -> bool:
         """Admission check (reference ``InferenceEngineV2.can_schedule`` :184)."""
         need = 0
         fresh = 0
+        if self.layout is not None:
+            for uid, n in zip(uids, token_counts):
+                seq = self._seqs.get(uid)
+                fresh += seq is None
+                if (seq.seen_tokens if seq else 0) + n > self.layout.max_seq_len:
+                    return False  # sequence would exceed engine max_seq_len
+                need += self._pages_short(seq, n)[0]
+            return (len(self._seqs) + fresh <= self.max_seqs
+                    and need <= self.allocator.free_blocks)
         for uid, n in zip(uids, token_counts):
             seq = self._seqs.get(uid)
             if seq is None:
@@ -260,10 +364,30 @@ class StateManager:
     def extend(self, uid: int, new_tokens: int) -> SequenceDescriptor:
         """Ensure blocks exist for ``new_tokens`` more tokens of ``uid``."""
         seq = self.get_or_create(uid)
+        if self.layout is not None:
+            need, summary, window = self._pages_short(seq, new_tokens)
+            if need:
+                seq.hold(self.allocator.allocate(need), summary, window)
+            return seq
         need = seq.blocks_needed(new_tokens, self.block_size)
         if need:
             seq.append_blocks(self.allocator.allocate(need))
         return seq
+
+    def advance(self, uid: int, tokens: int) -> int:
+        """Under a layout: ``uid`` has been fed ``tokens`` more. Returns how
+        many windows that closed, and gives the allocator back the window
+        pages behind the last one: the pooled rows are summaries now, and the
+        open window holds only what came after."""
+        seq = self._seqs[uid]
+        before = seq.seen_tokens
+        seq.seen_tokens = before + tokens
+        closed = seq.seen_tokens // self.layout.window - before // self.layout.window
+        if closed:
+            keep = -(-(seq.seen_tokens % self.layout.window) // self.block_size)
+            if keep < seq.n_window:
+                self.allocator.release(seq.pages_behind(keep))
+        return closed
 
     def flush(self, uid: int) -> None:
         """Release a finished sequence (reference ``flush_uid`` engine_v2.py).
@@ -587,21 +711,39 @@ def build_ragged_batch(
     # --- block allocation: one vectorized allocator call for the whole step
     seqs = [manager.get_or_create(uid) for uid in uids]
     seen_v = np.fromiter((s.seen_tokens for s in seqs), dtype=np.int32, count=n)
-    have_v = np.fromiter((s.n_blocks for s in seqs), dtype=np.int64, count=n)
-    bs = manager.block_size
-    need_v = np.maximum(-(-(seen_v.astype(np.int64) + lens) // bs) - have_v, 0)
-    over = (have_v + need_v) > max_pages
-    if over.any():
-        i = int(np.argmax(over))
-        raise RuntimeError(
-            f"uid {uids[i]}: {int(have_v[i] + need_v[i])} blocks exceeds "
-            f"max_pages={max_pages} (sequence longer than engine max_seq_len)"
-        )
-    fresh = manager.allocator.allocate(int(need_v.sum()))
-    ends = np.cumsum(need_v)
-    for i, s in enumerate(seqs):
-        if need_v[i]:
-            s.append_blocks(fresh[ends[i] - need_v[i]: ends[i]])
+    if manager.layout is not None:
+        # two kinds of pages a row: the layout says how many of each
+        over = seen_v.astype(np.int64) + lens > manager.layout.max_seq_len
+        if over.any():
+            i = int(np.argmax(over))
+            raise RuntimeError(
+                f"uid {uids[i]}: {int(seen_v[i] + lens[i])} tokens exceeds engine "
+                f"max_seq_len={manager.layout.max_seq_len}")
+        if ((lens > 1) & (seen_v > 0)).any():
+            i = int(np.argmax((lens > 1) & (seen_v > 0)))
+            raise ValueError(
+                f"uid {uids[i]}: a chunk of {int(lens[i])} tokens after {int(seen_v[i])}: with EVA "
+                "attention a chunk of more than one token starts a sequence (the chunk path does not "
+                "read earlier windows' summaries from the pool); feed the whole context at once, or "
+                "one token at a time")
+        for uid, length in zip(uids, lens):
+            manager.extend(uid, int(length))
+    else:
+        have_v = np.fromiter((s.n_blocks for s in seqs), dtype=np.int64, count=n)
+        bs = manager.block_size
+        need_v = np.maximum(-(-(seen_v.astype(np.int64) + lens) // bs) - have_v, 0)
+        over = (have_v + need_v) > max_pages
+        if over.any():
+            i = int(np.argmax(over))
+            raise RuntimeError(
+                f"uid {uids[i]}: {int(have_v[i] + need_v[i])} blocks exceeds "
+                f"max_pages={max_pages} (sequence longer than engine max_seq_len)"
+            )
+        fresh = manager.allocator.allocate(int(need_v.sum()))
+        ends = np.cumsum(need_v)
+        for i, s in enumerate(seqs):
+            if need_v[i]:
+                s.append_blocks(fresh[ends[i] - need_v[i]: ends[i]])
 
     # --- vectorized fills (no per-token Python loops)
     new_lens[:n] = lens
@@ -620,7 +762,10 @@ def build_ragged_batch(
         tokens[:n][valid] = np.concatenate(
             [np.asarray(t, np.int32) for t in token_lists])
     for i, s in enumerate(seqs):
-        block_tables[i, : s.n_blocks] = s._table[: s.n_blocks]
+        if manager.layout is None:
+            block_tables[i, : s.n_blocks] = s._table[: s.n_blocks]
+        else:
+            s.table_into(block_tables[i])
 
     return RaggedBatch(
         uids=list(uids), tokens=tokens, positions=positions,

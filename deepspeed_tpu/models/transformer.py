@@ -177,6 +177,25 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # EVA attention (eva_window > 0; EvaByte): token t attends, under ONE
+    # softmax, to the exact keys of its own window of eva_window positions
+    # (causally) and to one SUMMARY a chunk of eva_chunk positions of every
+    # window before it: per kv head, with learned ``phi`` and ``mu`` [kvH, hd],
+    # a chunk's summary key is sum_i a_i k_i + mu and its value sum_i a_i v_i,
+    # a = softmax over the chunk of k_i . phi (``ops/eva.py``). Keys carry
+    # RoPE at their own absolute positions before they are pooled. Serving
+    # caches a window's exact rows and earlier windows' summaries as two
+    # kinds of rows of one page pool (inference/paged.py).
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # RMSNorm multiplies by 1 + scale (the parameter is the offset from one)
+    norm_unit_offset: bool = False
+    # the residual stream stays fp32 between layers whatever ``dtype`` is
+    fp32_residual: bool = False
+    # output heads: the head's kernel is [hidden, num_pred_heads * vocab],
+    # head m (columns m*vocab ...) predicts token t + 1 + m. Loss and serving
+    # read head 0, the next-token distribution
+    num_pred_heads: int = 1
     # dtype the parameters are CREATED in (a checkpoint's own, where it says)
     param_dtype: Any = jnp.float32
 
@@ -186,6 +205,13 @@ class TransformerConfig:
         if self.kv_lora_rank and not self.q_lora_rank:
             raise ValueError("latent attention (kv_lora_rank > 0) needs q_lora_rank > 0: a plain "
                              "query projection beside latent keys and values is not built")
+        if bool(self.eva_window) != bool(self.eva_chunk) or (
+                self.eva_window and (self.eva_window % self.eva_chunk or self.latent_attention
+                                     or self.position != "rope")):
+            raise ValueError(
+                f"EVA attention needs eva_window a multiple of eva_chunk, rotary positions and plain "
+                f"keys and values (got eva_window={self.eva_window}, eva_chunk={self.eva_chunk}, "
+                f"position={self.position!r}, kv_lora_rank={self.kv_lora_rank})")
         if self.first_dense_layers and not (0 < self.first_dense_layers < self.num_layers
                                             and self.num_experts > 0):
             raise ValueError(
@@ -286,14 +312,15 @@ class TransformerConfig:
                   + self.kv_lora_rank * H * (self.qk_nope_head_dim + self.v_head_dim))
             return q + kv + H * self.v_head_dim * h
         hd = self.dims_per_head
-        return h * hd * (H + 2 * self.kv_heads) + hd * H * h
+        eva = 2 * self.kv_heads * hd if self.eva_window else 0  # phi and mu
+        return h * hd * (H + 2 * self.kv_heads) + hd * H * h + eva
 
     def num_params(self) -> int:
         h, v, l = self.hidden_size, self.vocab_size, self.num_layers
         qkv = self._attention_params()
         mlp = self._mlp_params()
         expert = self._mlp_params(self.expert_width)
-        total = v * h * (1 if self.tie_embeddings else 2)  # embedding (+ head)
+        total = v * h * (1 if self.tie_embeddings else 1 + self.num_pred_heads)  # embedding (+ head)
         total += h  # final norm
         for i in range(l):
             n_exp = self.experts_for_layer(i)
@@ -352,18 +379,26 @@ def act_fn(name: str):
 class RMSNorm(nn.Module):
     eps: float = 1e-5
     param_dtype: Any = jnp.float32
+    unit_offset: bool = False  # the weight is 1 + scale; scale is drawn off zero
+    out_dtype: Any = None  # None: the input's
 
     @nn.compact
     def __call__(self, x):
         from deepspeed_tpu.ops import rms_norm
 
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), self.param_dtype)
-        return rms_norm(x, scale, eps=self.eps)
+        init = nn.initializers.normal(0.05) if self.unit_offset else nn.initializers.ones
+        scale = self.param("scale", init, (x.shape[-1],), self.param_dtype)
+        if self.unit_offset:
+            scale = 1.0 + scale.astype(jnp.float32)
+        y = rms_norm(x, scale, eps=self.eps)
+        return y if self.out_dtype is None else y.astype(self.out_dtype)
 
 
 def _norm(config: TransformerConfig, name: str):
     if config.norm == "rmsnorm":
-        return RMSNorm(eps=config.norm_eps, param_dtype=config.param_dtype, name=name)
+        return RMSNorm(eps=config.norm_eps, param_dtype=config.param_dtype,
+                       unit_offset=config.norm_unit_offset,
+                       out_dtype=config.dtype if config.fp32_residual else None, name=name)
     return nn.LayerNorm(epsilon=config.norm_eps, param_dtype=config.param_dtype, name=name)
 
 
@@ -546,6 +581,49 @@ def rope_at(x: jax.Array, positions: jax.Array, theta: float, interleaved: bool)
     return out.astype(x.dtype)
 
 
+class EvaAttention(nn.Module):
+    """EVA attention (``TransformerConfig.eva_window``; ``ops/eva.py`` has the
+    mathematics): the projections are :class:`Attention`'s, under its names,
+    and each kv head has two more vectors, ``phi`` (a chunk's pooling weights)
+    and ``mu`` (added to a summary key). Both are drawn NONZERO: at zero the
+    pooling is a mean and ``mu`` is absent, and nothing could tell whether
+    either was computed. Sequences start at position 0 here (no cache)."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, mask, positions, train: bool):
+        from deepspeed_tpu.ops.eva import eva_attention
+
+        cfg = self.config
+        if mask is not None:
+            raise NotImplementedError("EVA attention with a padding mask: right-pad, the mask is causal")
+        hd, kvH = cfg.dims_per_head, cfg.kv_heads
+
+        def dense(features, name, **kw):
+            return nn.DenseGeneral(features, use_bias=False, dtype=cfg.dtype,
+                                   param_dtype=cfg.param_dtype, name=name, **kw)
+
+        q, k, v = dense((cfg.num_heads, hd), "wq")(x), dense((kvH, hd), "wk")(x), dense((kvH, hd), "wv")(x)
+        phi = self.param("phi", nn.initializers.normal(hd ** -0.5), (kvH, hd), cfg.param_dtype)
+        mu = self.param("mu", nn.initializers.normal(0.5), (kvH, hd), cfg.param_dtype)
+        q = rope_at(q, positions, cfg.rope_theta, cfg.rope_interleaved)
+        k = rope_at(k, positions, cfg.rope_theta, cfg.rope_interleaved)
+        # one window or less is plain causal attention (the flash kernel, with
+        # its gradient); past it the windows' exact parts go through the
+        # kernel's forward where there is one, which has no gradient: training
+        # past one window runs the XLA form
+        impl = "xla" if train else "auto"
+        with jax.named_scope("eva"):
+            if q.shape[1] <= cfg.eva_window:
+                from deepspeed_tpu.ops import causal_attention
+
+                out = causal_attention(q, k, v, impl=cfg.attn_impl)
+            else:
+                out = eva_attention(q, k, v, phi, mu, cfg.eva_window, cfg.eva_chunk, impl=impl)[0]
+        return dense(cfg.hidden_size, "wo", axis=(-2, -1))(out)
+
+
 class LatentAttention(nn.Module):
     """Latent attention over a full sequence, the plain (non-absorbed) way:
     keys and values are up-projected per head from the normed latent and the
@@ -623,7 +701,8 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, carry, _=None):
         cfg = self.config
-        attn_cls = LatentAttention if cfg.latent_attention else Attention
+        attn_cls = (LatentAttention if cfg.latent_attention
+                    else EvaAttention if cfg.eva_window else Attention)
         cap_scale = None
         if cfg.moe_dynamic_capacity:
             # dynamic capacity rides the carry as a traced fp32 scalar (the
@@ -735,6 +814,8 @@ class CausalLM(nn.Module):
         embed_cls = _SparseGradEmbed if cfg.sparse_embedding_grads else nn.Embed
         x = embed_cls(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                       param_dtype=cfg.param_dtype, name="embed")(ids)
+        if cfg.fp32_residual:
+            x = x.astype(jnp.float32)
         if cfg.embed_norm:
             x = _norm(cfg, "embed_norm")(x)
         if cfg.position == "learned":
@@ -813,13 +894,20 @@ class CausalLM(nn.Module):
                 if cfg.tie_embeddings:
                     head = self.variables["params"]["embed"]["embedding"]  # [V, h]
                 else:
-                    head = _HeadKernel(cfg.hidden_size, cfg.vocab_size, cfg.param_dtype, name="lm_head")().T
+                    head = _HeadKernel(cfg.hidden_size, cfg.vocab_size * cfg.num_pred_heads,
+                                       cfg.param_dtype, name="lm_head")()[:, :cfg.vocab_size].T
                 loss = lm_head_cross_entropy(x, head.astype(cfg.dtype), labels, pad_mask)
                 logits = None
             else:
                 if cfg.tie_embeddings:
                     embed = self.variables["params"]["embed"]["embedding"]
                     logits = x @ embed.T.astype(cfg.dtype)
+                elif cfg.num_pred_heads > 1:
+                    # head 0 of [hidden, heads * vocab], fp32 logits
+                    head = _HeadKernel(cfg.hidden_size, cfg.vocab_size * cfg.num_pred_heads,
+                                       cfg.param_dtype, name="lm_head")()
+                    logits = jnp.dot(x, head[:, :cfg.vocab_size].astype(cfg.dtype),
+                                     preferred_element_type=jnp.float32)
                 else:
                     logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias, dtype=cfg.dtype,
                                       param_dtype=cfg.param_dtype, name="lm_head")(x)
@@ -851,7 +939,11 @@ def _apply_norm(norm_params, cfg: TransformerConfig, x):
     if cfg.norm == "rmsnorm":
         from deepspeed_tpu.ops import rms_norm
 
-        return rms_norm(x, norm_params["scale"], eps=cfg.norm_eps)
+        scale = norm_params["scale"]
+        if cfg.norm_unit_offset:
+            scale = 1.0 + scale.astype(jnp.float32)
+        y = rms_norm(x, scale, eps=cfg.norm_eps)
+        return y.astype(cfg.dtype) if cfg.fp32_residual else y
     xf = x.astype(jnp.float32)
     mean = xf.mean(-1, keepdims=True)
     var = ((xf - mean) ** 2).mean(-1, keepdims=True)

@@ -751,3 +751,23 @@ def flash_causal_attention(
     out = _flash_attention(q, k, v, keep[:, None, :], slopes,
                            block_q, block_k, True, masked, alibi, k_splits)
     return out[:, :S]
+
+
+@register("causal_attention_lse", "pallas")
+def flash_causal_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
+                               block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K):
+    """The forward kernel alone, with what it already computes beside the
+    output: the log-sum-exp a (query, head), here in natural units, so that a
+    caller can merge this attention with attention over further keys
+    (``ops/eva.py``). q ``[B, S, H, D]``, k, v ``[B, S, Hkv, D]`` -> (out
+    ``[B, S, H, D]``, lse fp32 ``[B, S, H]``). No gradient: serving only."""
+    B, S, H, D = q.shape
+    block_q = min(block_q, max(S, 8))
+    block_k = min(block_k, max(S, 8))
+    Sp = _cdiv(S, max(block_q, block_k)) * max(block_q, block_k)
+    if Sp != S:  # padded keys reach padded queries alone (module header)
+        q, k, v = (jnp.pad(a, ((0, 0), (0, Sp - S), (0, 0), (0, 0))) for a in (q, k, v))
+    out, (_, _, _, lse, _) = _flash_core(
+        q, k, v, jnp.ones((B, 1, Sp), jnp.int32), jnp.zeros((H, _LANES), jnp.float32),
+        block_q, block_k, True, False, False)
+    return out[:, :S], (lse[..., 0] * _LN2).transpose(0, 2, 1)[:, :S]

@@ -333,6 +333,27 @@ def _census(jaxpr, counts):
     return counts
 
 
+def _programs(cfg, **kw):
+    """The jaxprs of ``step`` and ``chain`` for ``cfg`` at fixed toy shapes."""
+    params = jax.eval_shape(lambda k: CausalLM(cfg).init(
+        {"params": k}, {"input_ids": jnp.zeros((1, 8), jnp.int32)}, train=False)["params"], jax.random.PRNGKey(0))
+    pool = jax.eval_shape(lambda: paged.init_pool(cfg, 32, 16, jnp.float32))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    step = jax.make_jaxpr(lambda p, pool, t, pos, n, bt: paged.ragged_forward(
+        p, cfg, pool, t, pos, n, bt, 16, **kw))(params, pool, i32(4, 32), i32(4, 32), i32(4), i32(4, 8))
+    chain = jax.make_jaxpr(lambda p, pool, t, pos, bt, a, b, r: paged.ragged_decode_chain(
+        p, cfg, pool, t, pos, bt, 16, a, b, r, 4, None, **kw))(
+        params, pool, i32(4), i32(4), i32(4, 8), jax.ShapeDtypeStruct((4,), jnp.bool_), i32(4),
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    return pool, {"step": step, "chain": chain}
+
+
+def _same_as_recorded(jaxpr, parent):
+    assert dict(sorted(_census(jaxpr.jaxpr, collections.Counter()).items())) == parent["primitives"]
+    assert len(jaxpr.jaxpr.invars) == parent["inputs"]  # no new operand
+    assert [[list(v.aval.shape), str(v.aval.dtype)] for v in jaxpr.jaxpr.outvars] == parent["outputs"]
+
+
 @pytest.mark.parametrize("name", ["step", "chain"])
 def test_gpt_neox_programs_are_the_parents(name):
     """The census of the jaxpr's primitives, the number of operands and the
@@ -341,21 +362,18 @@ def test_gpt_neox_programs_are_the_parents(name):
         model_type="gpt_neox", vocab_size=256, hidden_size=64, intermediate_size=256, num_hidden_layers=2,
         num_attention_heads=4, max_position_embeddings=128, rotary_pct=0.25, rotary_emb_base=10000,
         layer_norm_eps=1e-5, use_parallel_residual=True, hidden_act="gelu", tie_word_embeddings=False))
-    params = jax.eval_shape(lambda k: CausalLM(cfg).init(
-        {"params": k}, {"input_ids": jnp.zeros((1, 8), jnp.int32)}, train=False)["params"], jax.random.PRNGKey(0))
-    pool = jax.eval_shape(lambda: paged.init_pool(cfg, 32, 16, jnp.float32))
+    pool, programs = _programs(cfg)
     assert pool.k.shape == pool.v.shape == (64, 16, 64)  # keys AND values
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    if name == "step":
-        jaxpr = jax.make_jaxpr(lambda p, pool, t, pos, n, bt: paged.ragged_forward(
-            p, cfg, pool, t, pos, n, bt, 16))(params, pool, i32(4, 32), i32(4, 32), i32(4), i32(4, 8))
-    else:
-        jaxpr = jax.make_jaxpr(lambda p, pool, t, pos, bt, a, b, r: paged.ragged_decode_chain(
-            p, cfg, pool, t, pos, bt, 16, a, b, r, 4, None))(
-            params, pool, i32(4), i32(4), i32(4, 8), jax.ShapeDtypeStruct((4,), jnp.bool_), i32(4),
-            jax.ShapeDtypeStruct((2,), jnp.uint32))
     with open(os.path.join(os.path.dirname(__file__), "data", "gpt_neox_programs_at_pr32.json")) as f:
-        parent = json.load(f)[name]
-    assert dict(sorted(_census(jaxpr.jaxpr, collections.Counter()).items())) == parent["primitives"]
-    assert len(jaxpr.jaxpr.invars) == parent["inputs"]  # no new operand
-    assert [[list(v.aval.shape), str(v.aval.dtype)] for v in jaxpr.jaxpr.outvars] == parent["outputs"]  # no picks
+        _same_as_recorded(programs[name], json.load(f)[name])  # no picks among the outputs
+
+
+@pytest.mark.parametrize("name", ["step", "chain"])
+def test_glm4_moe_lite_programs_are_the_parents(name):
+    """The routed, latent toy's two programs, picks and all, against the census
+    recorded on PR 34's commit: what PR 35 added for EVA attention is a branch
+    at trace time and reaches neither."""
+    pool, programs = _programs(config_from_hf(TOY), with_picks=True)
+    assert pool.v is None  # the latent pool
+    with open(os.path.join(os.path.dirname(__file__), "data", "glm4_moe_lite_programs_at_pr34.json")) as f:
+        _same_as_recorded(programs[name], json.load(f)[name])

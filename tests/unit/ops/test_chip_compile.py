@@ -202,6 +202,79 @@ def test_grouped_matmul_compiles_at_the_chosen_tiles(one_chip, m, K, N, E, dtype
         assert _compiled_kernels(grad, lhs, rhs, gs) == 2
 
 
+@pytest.mark.parametrize("name", ["prefill_4x8192", "chain_24"])
+def test_evabyte_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, name):
+    """``evabyte.serve.long-batch``'s two programs whole, for the described
+    v5e at the cell's own shapes (8 layers at the published widths, a 7.5 GiB
+    pool of 3,840 pages, a table of 40 summary + 128 window columns): the
+    ``(4, 8192)`` prefill (the flash kernel a window, the summaries' part, the
+    one-token rows' paged kernel, the closing under its ``cond``) and the
+    chain of 8 steps at 24 rows (the paged kernel over [summary pages | window
+    pages], the closing under its ``cond``). Each fits the chip beside the
+    weights and the pool, returns the donated pool aliased, and copies,
+    slices or re-lays neither the pool nor a layer of it: a second ``cond``
+    that carries the pool in the layer, a ``switch`` of three branches and a
+    ``lax.map`` nested in the layer scan each made the compiler copy it
+    (PR 35); one ``cond`` whose branch loops over the closing rows does not."""
+    import dataclasses
+    import re
+
+    from deepspeed_tpu.checkpoint.hf import config_from_hf
+    from deepspeed_tpu.inference import paged
+    from deepspeed_tpu.models import CausalLM
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import flash_attention as fa, norms, paged_attention as pa
+
+    for module in (pa, fa, norms):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")  # what 'auto' sees there
+    cfg = dataclasses.replace(config_from_hf(dict(
+        model_type="evabyte", attention_class="eva", vocab_size=320, hidden_size=4096, intermediate_size=11008,
+        num_hidden_layers=8, num_attention_heads=32, num_key_value_heads=32, max_position_embeddings=32768,
+        window_size=2048, chunk_size=16, num_pred_heads=8, rms_norm_eps=1e-5, rope_theta=100000,
+        norm_add_unit_offset=True, fp32_skip_add=True)), dtype=jnp.bfloat16)
+    NB, bs, table = 3840, 16, 5 * 8 + 128
+    bf16 = lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(bf16, jax.eval_shape(
+        lambda key: CausalLM(cfg).init({"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)},
+                                       train=False)["params"], jax.random.PRNGKey(0)))
+    pool = jax.tree_util.tree_map(bf16, jax.eval_shape(lambda: paged.init_pool(cfg, NB, bs, jnp.bfloat16)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    if name == "chain_24":
+        rows, limit_gb, kernels = 24, 2.6, ("paged_attn",)
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def program(params, pool, tokens, start_pos, tables, active, budgets, rng):
+            return paged.ragged_decode_chain(params, cfg, pool, tokens, start_pos, tables, bs,
+                                             active, budgets, rng, 8, None)
+
+        args = (i32(rows), i32(rows), i32(rows, table), jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip),
+                i32(rows), jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip))
+    else:
+        rows, limit_gb, kernels = 4, 4.2, ("paged_attn", "flash_fwd")
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def program(params, pool, tokens, positions, new_lens, tables):
+            return paged.ragged_forward(params, cfg, pool, tokens, positions, new_lens, tables, bs)
+
+        args = (i32(rows, 8192), i32(rows, 8192), i32(rows), i32(rows, table))
+    compiled = program.lower(params, pool, *args).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pool))
+    assert pool_bytes == 24 * 160 * 16 * 8 * 16384  # 7.5 GiB: 24 rows of at most 160 pages
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < limit_gb * 1e9, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    for kernel in kernels:
+        assert any("tpu_custom_call" in line and kernel in line for line in text.splitlines()), kernel
+    assert "eva_close" in text and "conditional(" in text  # the closing, taken only when a row closes
+    layer = r"bf16\[(%d|%d),%d,%d\]" % (cfg.num_layers * NB, NB, bs, cfg.kv_heads * cfg.dims_per_head)
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= %s\S* (copy|dynamic-slice|reshape|transpose)\(" % layer, line)]
+    assert not moved, moved
+
+
 @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["bf16", "int8"])
 def test_decode_chain_updates_the_pool_in_place(one_chip, monkeypatch, kv_quant):
     """Whether a carried array is updated in place is the chip's compiler's
