@@ -21,12 +21,17 @@ Serving fast path (the host leaves the per-token critical path):
     preempts at chain boundaries, and the chain length auto-shrinks to honor
     ``max_new_tokens`` and KV-pool pressure (``decode_chain=1`` reproduces
     the per-token loop's outputs exactly)
+  - one chain stays queued behind the running one (a chain ahead): chain N+1
+    is dispatched from chain N's own carry on the device before chain N's
+    tokens are fetched, wherever the next boundary has nothing to decide
+    (``generate``; ``decode_chain(..., ahead=True)``)
   - batch assembly writes into preallocated per-bucket staging buffers
     (``ragged.BatchStaging``), and all scheduler bookkeeping is O(1) amortized
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import time
 from collections import deque
@@ -209,6 +214,36 @@ class RaggedInferenceConfig(DeepSpeedConfigModel):
     def kv_dtype_name(self) -> str:
         """The label the serving gauges carry ('int8'/'fp8'/float name)."""
         return (self.kv_cache_dtype or self.dtype).lower()
+
+
+@dataclasses.dataclass
+class _ChainInFlight:
+    """A decode chain that is dispatched and not fetched
+    (``InferenceEngineV2.decode_chain``): what the host asked of it, and the
+    program's outputs, still on the device."""
+
+    chain_id: int
+    n_rows: int             # the program's rows, a row bucket
+    uids: List[int]         # the rows that hold a request, in the caller's order
+    at: np.ndarray          # [n] the program's row of each
+    start: np.ndarray       # [n] seen_tokens where the chain starts
+    budgets: np.ndarray     # [n] what each row may still emit, this chain and after
+    k: int
+    eos_id: Optional[int]
+    sample_kw: Tuple
+    rng_in: Any             # the key it was dispatched with, the caller's own object
+    dispatched_at: float    # perf_counter inside its serve:dispatch span
+    # the program's outputs, on the device
+    out: jax.Array          # [n_rows, k] tokens
+    emitted: jax.Array      # [n_rows]
+    carry: Tuple            # (tokens, start_pos, active) of the chain after it
+    rng_out: jax.Array
+    routed: Tuple           # a routed model's (touched, picks), else empty
+    # the rows whose seen_tokens already stand k further, where this chain
+    # ends unless an EOS ends them: set when the chain after it is planned
+    moved: np.ndarray       # [n] bool
+    # ahead only, set once the chain before it is fetched:
+    last: Optional[np.ndarray] = None   # [n] the token each row starts from
 
 
 def build_hf_engine(
@@ -450,7 +485,9 @@ class InferenceEngineV2:
                 ranks=[0],
             )
         kv_spec = NamedSharding(mesh, P(None, None, "tp" if kv_on_tp else None))
-        replicated = NamedSharding(mesh, P())  # scales: 4/hd of the values, slot-major rows
+        # scales: 4/hd of the values, slot-major rows. Also where every step
+        # program's small outputs land, so the host's operands are placed there too
+        self._replicated = replicated = NamedSharding(mesh, P())
         self.pool = PagedKVPool(
             k=jax.device_put(pool.k, kv_spec),
             v=None if pool.v is None else jax.device_put(pool.v, kv_spec),
@@ -464,6 +501,7 @@ class InferenceEngineV2:
         )
         self._step_cache: Dict[Tuple, Any] = {}
         self._chain_buf: Dict[int, Dict[str, np.ndarray]] = {}
+        self._ahead: Optional[_ChainInFlight] = None  # the chain dispatched ahead, if any
         self._spec_buf: Dict[int, Dict[str, np.ndarray]] = {}
         self._tracer = get_tracer()
         # Serving flight recorder (opt-in): per-request ring so a crash dump
@@ -492,6 +530,7 @@ class InferenceEngineV2:
         self.host_sync_count = 0       # host blocking fetches
         self.tokens_decoded = 0        # decode tokens produced by generate()
         self.chain_steps = 0           # decode-chain dispatches (fleet liveness)
+        self.chains_ahead = 0          # of the chains, dispatched while the one before was unfetched
         # prefix-cache + speculative accounting (plain int adds; the serving
         # benchmark and the router smoke read these)
         self.prefill_tokens_total = 0  # prompt tokens submitted for prefill
@@ -579,7 +618,9 @@ class InferenceEngineV2:
         return self._step_cache[key]
 
     def _chain_fn(self, rows: int, k: int, eos_id: Optional[int], sample_kw: Tuple):
-        """K-step decode chain program (paged.ragged_decode_chain)."""
+        """K-step decode chain program (paged.ragged_decode_chain): one a
+        ``(rows, k)``, whether a chain starts from the host's values or from
+        the carry the chain before it returned."""
         key = ("chain", rows, k, eos_id, sample_kw)
         if key not in self._step_cache:
             cfg = self.model_config
@@ -962,14 +1003,17 @@ class InferenceEngineV2:
                 "row_steps": int(fed.sum()),
                 "windows_closed": int((pos % lay.window == lay.window - 1)[fed].sum())}
 
-    def _advance(self, uids, counts) -> None:
-        """``seen_tokens`` of each uid forward by its count; the windows that
-        closed on the way are counted (``serving/eva_windows_closed``)."""
+    def _advance(self, uids, counts) -> int:
+        """``seen_tokens`` of each uid forward by its count. Returns the
+        windows that closed on the way (EVA), for ``_note_closed``."""
         if self._layout is None:
             for uid, n in zip(uids, counts):
                 self.state.get(uid).seen_tokens += int(n)
-            return
-        closed = sum(self.state.advance(uid, int(n)) for uid, n in zip(uids, counts))
+            return 0
+        return sum(self.state.advance(uid, int(n)) for uid, n in zip(uids, counts))
+
+    def _note_closed(self, closed: int) -> None:
+        """Count windows pooled into summaries (``serving/eva_windows_closed``)."""
         if closed:
             self.windows_closed += closed
             self._tracer.count("serving/eva_windows_closed", float(closed))
@@ -1005,24 +1049,28 @@ class InferenceEngineV2:
             )
         self.dispatch_count += 1
         self._log_picks(picks, uids, None, token_lists)
-        self._advance(uids, map(len, token_lists))
+        self._note_closed(self._advance(uids, map(len, token_lists)))
         self.host_sync_count += 1
         return np.asarray(logits[: len(uids)])
 
-    def _log_picks(self, picks, uids, rids, token_lists=None, emitted=None) -> None:
+    def _log_picks(self, picks, uids, rids, token_lists=None, flight=None, emitted=None) -> None:
         """While somebody asked (``picks_log`` is a list), note one dispatch's
-        picks, still on the device, with what places them: each row's first
-        position and how many tokens it fed (``emitted`` for a chain, whose
-        picks are ``[K, rows, routed layers, k]``). Called before
-        ``seen_tokens`` advances. Costs the serving loop one comparison."""
+        picks, still on the device, with what places them: the program's row
+        of each uid, its first position and how many tokens it fed (a chain's
+        picks are ``[K, rows, routed layers, k]``; its rows and where they
+        start are ``flight``'s, what they fed is ``emitted``). A ``put`` calls
+        it before ``seen_tokens`` advances. Costs the serving loop one
+        comparison."""
         if self.picks_log is None or not picks:
             return
+        chain = flight is not None
         self.picks_log.append({
-            "picks": picks[-1], "chain": emitted is not None,
+            "picks": picks[-1], "chain": chain,
             "rids": list(range(len(uids))) if rids is None else list(rids),
-            "starts": [self.state.get(u).seen_tokens for u in uids],
-            "counts": [int(e) for e in emitted] if emitted is not None
-            else [len(t) for t in token_lists]})
+            "rows": [int(i) for i in flight.at] if chain else list(range(len(uids))),
+            "starts": [int(p) for p in flight.start] if chain
+            else [self.state.get(u).seen_tokens for u in uids],
+            "counts": [int(e) for e in emitted] if chain else [len(t) for t in token_lists]})
 
     def _picks_by_request(self, n_requests: int) -> List[np.ndarray]:
         """The logged dispatches' picks, fetched and laid out a request:
@@ -1032,7 +1080,7 @@ class InferenceEngineV2:
         rows: List[Dict[int, np.ndarray]] = [{} for _ in range(n_requests)]
         for rec in self.picks_log:
             picks = np.asarray(rec["picks"])
-            for i, (rid, start, n) in enumerate(zip(rec["rids"], rec["starts"], rec["counts"])):
+            for rid, i, start, n in zip(rec["rids"], rec["rows"], rec["starts"], rec["counts"]):
                 for t in range(n):
                     rows[rid][start + t] = picks[t, i] if rec["chain"] else picks[i, t]
         width = (self.model_config.routed_layers, self.model_config.moe_top_k)
@@ -1096,7 +1144,7 @@ class InferenceEngineV2:
             )
         self.dispatch_count += 1
         self._log_picks(picks, uids, rids, token_lists)
-        self._advance(uids, map(len, token_lists))
+        self._note_closed(self._advance(uids, map(len, token_lists)))
         with self._tracer.span("serve:fetch", kind="prefill"):
             out = np.asarray(toks[: len(uids)])
         self.host_sync_count += 1
@@ -1120,6 +1168,116 @@ class InferenceEngineV2:
             buf["budgets"][:] = 0
         return buf
 
+    def _place(self, buf: Dict[str, np.ndarray], *names: str) -> Tuple[jax.Array, ...]:
+        """COPIES of a chain's staging arrays, placed where the step programs'
+        outputs land (jit keys its cache on placement, and a chain ahead takes
+        such outputs for the same operands). Copies, because the staging is
+        refilled for the next chain while this one may be unfetched, and a
+        placed array can be the host's own memory (the CPU backend's)."""
+        return jax.device_put(tuple(buf[name].copy() for name in names), self._replicated)
+
+    def _dispatch_chain(self, uids, at, n_rows, budgets, k, rng, eos_id, sample_kw, chain_id,
+                        last_tokens=None, before: Optional[_ChainInFlight] = None,
+                        tracker=None, rids=None) -> _ChainInFlight:
+        """Assemble and dispatch one chain over ``uids`` in the program's rows
+        ``at``, each to emit at most ``k`` and its entry of ``budgets``. It
+        starts from the host's ``last_tokens`` and ``seen_tokens``, or, ahead,
+        from the carry of ``before``, the unfetched chain over the same rows.
+        The program is given each row's WHOLE budget, not its share of this
+        chain: its outputs are the same, and the ``active`` it hands back then
+        means "goes on after this chain", which is what the next one needs."""
+        ahead = before is not None
+        with self._tracer.span("serve:assemble", kind="chain", rows=n_rows, chain=chain_id):
+            # pre-extend every row's block table for its share of the K-token
+            # window (capped by the row's remaining budget — no KV slots are
+            # reserved past max_new_tokens) so the compiled program never
+            # needs the allocator mid-chain
+            buf = self._chain_arrays(n_rows)
+            share = np.minimum(budgets, k)
+            for i, uid, b in zip(at, uids, share):
+                seq = self.state.extend(uid, int(b))
+                if self._layout is None:
+                    buf["tables"][i, : seq.n_blocks] = seq.blocks
+                else:
+                    seq.table_into(buf["tables"][i])
+                buf["pos"][i] = seq.seen_tokens
+            buf["budgets"][at] = budgets
+            start = buf["pos"][at]
+            if not ahead:
+                buf["tokens"][at] = last_tokens
+                buf["active"][at] = True
+            eva_args = {} if self._layout is None else self._eva_args(
+                start[:, None] + np.arange(k)[None, :], share)
+            if eva_args and self._tracer.enabled:
+                self._tracer.registry.gauge("serving/eva_rows_per_context_token").set(
+                    eva_args["attended_rows"] / max(eva_args["context_tokens"], 1))
+        chain = self._chain_fn(n_rows, k, eos_id, sample_kw)
+        with self._tracer.span("serve:dispatch", kind="chain", rows=n_rows, live=len(uids),
+                               k=k, chain=chain_id, ahead=int(ahead), **eva_args):
+            dispatched_at = time.perf_counter()
+            if ahead:
+                tokens, pos, active = before.carry
+                tables, chain_budgets = self._place(buf, "tables", "budgets")
+            else:
+                if tracker is not None and rids is not None:
+                    tracker.mark_dispatch(rids, "chain", now=dispatched_at)
+                tokens, pos, tables, active, chain_budgets = self._place(
+                    buf, "tokens", "pos", "tables", "active", "budgets")
+            out, emitted, active, tok, pos, rng_out, self.pool, *routed = chain(
+                self.params, self.pool, tokens, pos, tables, active, chain_budgets, rng)
+        self.dispatch_count += 1
+        if ahead:
+            self.chains_ahead += 1
+            self._tracer.count("serving/chains_ahead", 1.0)
+        return _ChainInFlight(
+            chain_id=chain_id, n_rows=n_rows, uids=list(uids), at=np.asarray(at), start=start,
+            budgets=np.asarray(budgets), k=k, eos_id=eos_id, sample_kw=sample_kw, rng_in=rng,
+            dispatched_at=dispatched_at, out=out, emitted=emitted, carry=(tok, pos, active),
+            rng_out=rng_out, routed=tuple(routed), moved=np.zeros(len(uids), bool))
+
+    def _chain_ahead(self, flight: _ChainInFlight, budgets: np.ndarray) -> Optional[_ChainInFlight]:
+        """The chain after ``flight``, dispatched from ``flight``'s carry
+        before ``flight``'s tokens are fetched; None where the boundary has to
+        be serial. ``budgets`` are the caller's for ``flight``'s rows, whole."""
+        k = flight.k
+        keep = budgets > k  # the rows that outlast this chain
+        n = int(keep.sum())
+        bucket = self.config.row_bucket
+        if n == 0 or -(-n // bucket) * bucket != flight.n_rows:
+            return None  # nothing to decode, or a smaller program would
+        # Such a row emits exactly k tokens in ``flight``, or ends in it at an
+        # EOS and rides the next chain dead: where it stands afterwards, its
+        # budget and its pages (EVA: the windows it closes on the way) are
+        # known now. The fetch sets an ended row back to where its tokens end.
+        uids = [u for u, kept in zip(flight.uids, keep) if kept]
+        self._advance(uids, [k] * n)
+        flight.moved = keep
+        left = budgets[keep] - k
+        if not self._can_schedule_evicting(uids, self.chain_window(left, k)):
+            return None  # the loop shrinks the chain or preempts, after the fetch
+        return self._dispatch_chain(
+            uids, flight.at[keep], flight.n_rows, left, k, flight.rng_out,
+            flight.eos_id, flight.sample_kw, flight.chain_id + 1, before=flight)
+
+    def _take_ahead(self, flight: _ChainInFlight, uids, last_tokens, budgets, k, rng, eos_id,
+                    sample_kw) -> None:
+        """The chain dispatched ahead has run (or is running) and has written
+        the pool: this call has to be the one it was built for."""
+        asked = {"uids": list(uids), "k": k, "eos_id": eos_id, "sample_kw": sample_kw,
+                 "budgets": budgets.tolist(),
+                 "last_tokens": np.asarray(last_tokens, np.int64).tolist(), "rng": id(rng)}
+        built = {"uids": flight.uids, "k": flight.k, "eos_id": flight.eos_id,
+                 "sample_kw": flight.sample_kw, "budgets": flight.budgets.tolist(),
+                 "last_tokens": flight.last.tolist(), "rng": id(flight.rng_in)}
+        wrong = [name for name in asked if asked[name] != built[name]]
+        if wrong:
+            raise RuntimeError(
+                "decode_chain: a chain was dispatched ahead for the next call (ahead=True), and "
+                "this call is not that chain: " + "; ".join(
+                    f"{name} {asked[name]!r}, dispatched with {built[name]!r}" for name in wrong)
+                + ". Pass the uids still live in the order given, their budgets less the chain's "
+                "share, and the rng the last call returned.")
+
     def decode_chain(
         self,
         uids: Sequence[int],
@@ -1131,6 +1289,7 @@ class InferenceEngineV2:
         sample_kw: Tuple = (("do_sample", False),),
         tracker: Optional[LifecycleTracker] = None,
         rids: Optional[Sequence[int]] = None,
+        ahead: bool = False,
     ) -> Tuple[np.ndarray, np.ndarray, jax.Array]:
         """Run one K-step chained decode over ``uids``.
 
@@ -1139,55 +1298,72 @@ class InferenceEngineV2:
         ``tokens[i, :emitted[i]]`` are the new tokens of ``uids[i]`` (the
         EOS token, when hit, is included and the row stops). seen_tokens
         advances by ``emitted[i]`` — exactly the KV slots written.
+
+        ``ahead=True`` says that the caller's next call will be the next chain
+        of the same rows: the uids this chain leaves live, in this order, their
+        budgets less ``k``, the same ``k``, ``eos_id`` and ``sample_kw``, the
+        tokens this call returns last and the rng it returns, and no ``put`` or
+        ``flush`` of a live row in between. The engine may then dispatch that
+        chain before it fetches this one's tokens, from this chain's own carry
+        on the device, so the chip goes from one chain to the next without
+        waiting for the host (a chain ahead). It does so where a row's budget
+        outlasts this chain, the survivors fill the same row bucket and the
+        pool covers their next window as it stands; else the boundary is
+        serial, as without the argument. The next call is given that chain:
+        it checks that it is the one described and raises if not, since the
+        chain has written the pool by then. A row that ends at an EOS in this
+        chain is dead in the next (the carry knows) and must not be passed
+        again: flush it. If every row ends so, the next call passes no uid and
+        fetches a chain that did nothing.
         """
-        n = len(uids)
-        rows = -(-n // self.config.row_bucket) * self.config.row_bucket
-        chain_id = self.chain_steps  # shared by every span of this chain
-        with self._tracer.span("serve:assemble", kind="chain", rows=rows, chain=chain_id):
-            # pre-extend every row's block table for its share of the K-token
-            # window (capped by the row's remaining budget — no KV slots are
-            # reserved past max_new_tokens) so the compiled program never
-            # needs the allocator mid-chain
-            buf = self._chain_arrays(rows)
-            for i, uid in enumerate(uids):
-                seq = self.state.extend(uid, min(k, int(budgets[i])))
-                if self._layout is None:
-                    buf["tables"][i, : seq.n_blocks] = seq.blocks
-                else:
-                    seq.table_into(buf["tables"][i])
-                buf["pos"][i] = seq.seen_tokens
-            buf["tokens"][:n] = last_tokens
-            buf["active"][:n] = True
-            buf["budgets"][:n] = np.minimum(budgets, k)
-            eva_args = {} if self._layout is None else self._eva_args(
-                buf["pos"][:n, None] + np.arange(k)[None, :], buf["budgets"][:n])
-            if eva_args and self._tracer.enabled:
-                self._tracer.registry.gauge("serving/eva_rows_per_context_token").set(
-                    eva_args["attended_rows"] / max(eva_args["context_tokens"], 1))
-        chain = self._chain_fn(rows, k, eos_id, sample_kw)
-        with self._tracer.span("serve:dispatch", kind="chain", rows=rows, live=n,
-                               k=k, chain=chain_id, **eva_args):
+        uids = list(uids)
+        budgets = np.asarray(budgets, np.int32).reshape(-1)
+        flight = self._ahead
+        if flight is not None:  # (a call it refuses leaves it where it is)
+            self._take_ahead(flight, uids, last_tokens, budgets, k, rng, eos_id, sample_kw)
+            self._ahead = None
             if tracker is not None and rids is not None:
-                tracker.mark_dispatch(rids, "chain")
-            out, emitted, _, rng, self.pool, *routed = chain(
-                self.params, self.pool,
-                jnp.asarray(buf["tokens"]), jnp.asarray(buf["pos"]),
-                jnp.asarray(buf["tables"]), jnp.asarray(buf["active"]),
-                jnp.asarray(buf["budgets"]), rng,
-            )
-        self.dispatch_count += 1
-        with self._tracer.span("serve:fetch", kind="chain", chain=chain_id):
-            out = np.asarray(out[:n])
-            emitted = np.asarray(emitted[:n])
+                tracker.mark_dispatch(rids, "chain", now=flight.dispatched_at)
+        else:
+            n = len(uids)
+            rows = -(-n // self.config.row_bucket) * self.config.row_bucket
+            flight = self._dispatch_chain(
+                uids, np.arange(n), rows, budgets, k, rng, eos_id, sample_kw,
+                self.chain_steps,  # shared by every span of this chain
+                last_tokens=last_tokens, tracker=tracker, rids=rids)
+        if ahead:
+            self._ahead = self._chain_ahead(flight, budgets)
+        routed = flight.routed
+        with self._tracer.span("serve:fetch", kind="chain", chain=flight.chain_id):
+            # the padded outputs themselves, cut down in numpy: a slice on the
+            # device is a program, and would wait behind the chain ahead
+            out = np.asarray(flight.out)[flight.at]
+            emitted = np.asarray(flight.emitted)[flight.at]
             if routed:
                 # [K, routed layers] beside the tokens: the steps some row was
                 # live at read that many distinct experts a layer, on average
                 live_steps = max(int(emitted.max(initial=0)), 1)
                 self.last_experts_touched = float(np.asarray(routed[0])[:live_steps].mean())
         self.host_sync_count += 1
-        self._log_picks(routed, uids, rids, emitted=emitted)
-        self._advance(uids, emitted)
-        return out, emitted, rng
+        self._log_picks(routed, uids, rids, flight=flight, emitted=emitted)
+        # a row moved already stands k further; one an EOS ended goes back
+        moved = flight.moved
+        for uid, short in zip(np.asarray(uids)[moved], (k - emitted)[moved]):
+            self.state.get(int(uid)).seen_tokens -= int(short)
+        self._advance([u for u, m in zip(uids, moved) if not m], emitted[~moved])
+        if self._layout is not None:
+            window = self._layout.window
+            self._note_closed(int(((flight.start + emitted) // window - flight.start // window).sum()))
+        if self._ahead is not None:
+            # the rows of the chain ahead that hold a request are those this
+            # chain left live; where each starts from is known only now
+            last = out[np.arange(len(uids)), np.maximum(emitted, 1) - 1]
+            live = (emitted == k) if eos_id is None else (emitted == k) & (last != eos_id)
+            nxt, live = self._ahead, live[moved]
+            nxt.uids = [u for u, alive in zip(nxt.uids, live) if alive]
+            nxt.at, nxt.start, nxt.budgets = nxt.at[live], nxt.start[live], nxt.budgets[live]
+            nxt.last, nxt.moved = last[moved][live], nxt.moved[live]
+        return out, emitted, flight.rng_out
 
     def decode_spec_chain(
         self,
@@ -1315,6 +1491,31 @@ class InferenceEngineV2:
         preempted (flushed and re-queued with its full context, reference
         FastGen scheduler behavior) rather than crashing mid-generation.
 
+        A chain ahead: where the next boundary has nothing to decide, the loop
+        lets ``decode_chain`` dispatch chain N+1 from chain N's own carry on
+        the device (each row's next token, position and whether it is still
+        live) BEFORE it fetches chain N's tokens, so the chip runs on while
+        the host fetches, accepts and plans. That is exact, not speculative: a
+        row that survives a chain emitted ``min(k, budget)`` tokens in it, so
+        its position, budget and pages afterwards are known beforehand, and
+        what is not (the tokens, an EOS) the carry holds. A boundary is serial,
+        as it always was, when the chain is speculative or was shrunk, when no
+        row goes on, when a prompt waits that the boundary could admit (a free
+        seat, or a budget that ends in this chain), when the rows left would
+        fit a smaller program, and when the pool does not cover the next
+        window as it stands (then the loop shrinks or preempts after the
+        fetch). While a chain is in flight ahead the loop admits and preempts
+        nothing, so a seat that an EOS frees while prompts wait is filled one
+        chain later than a serial loop fills it. Greedy tokens are the serial
+        loop's, always. Sampled tokens are too while the rows of a wave stay
+        the same; once a row has ended, the chain ahead keeps its (dead) row
+        where a serial loop closes the rows up, and after a late admission the
+        key has gone through one chain more: the draws after that are other
+        draws from the same distributions. ``chains_ahead`` of
+        ``chain_steps`` (counters ``serving/chains_ahead`` of
+        ``serving/chains``, ``ahead=1`` on the ``serve:dispatch`` span) says
+        how many boundaries went so.
+
         ``arrival_times`` (seconds relative to the call, one per prompt)
         turns the batch call into an open-loop workload: a prompt enters the
         admission queue only once its arrival time has passed — this is what
@@ -1332,9 +1533,12 @@ class InferenceEngineV2:
         """
         with self._tracer.span("serve:generate", requests=len(prompts),
                                max_new_tokens=max_new_tokens):
-            return self._generate_loop(
-                prompts, max_new_tokens, eos_token_id, do_sample, temperature,
-                top_k, top_p, seed, arrival_times)
+            try:
+                return self._generate_loop(
+                    prompts, max_new_tokens, eos_token_id, do_sample, temperature,
+                    top_k, top_p, seed, arrival_times)
+            finally:
+                self._ahead = None  # set here only if the loop raised: nobody will take it
 
     def _generate_loop(self, prompts, max_new_tokens, eos_token_id, do_sample,
                        temperature, top_k, top_p, seed, arrival_times) -> List[np.ndarray]:
@@ -1393,8 +1597,7 @@ class InferenceEngineV2:
             # NamedSharding(mesh, P()) — jit caches on that difference, so an
             # uncommitted first key makes the SECOND admission wave recompile
             # the prefill program mid-serving (a ~0.4s TTFT cliff under bursts)
-            rng = jax.device_put(jax.random.PRNGKey(seed),
-                                 NamedSharding(self.mesh, P()))
+            rng = jax.device_put(jax.random.PRNGKey(seed), self._replicated)
             next_uid = 0
             registry = self._tracer.registry if self._tracer.enabled else None
 
@@ -1454,12 +1657,14 @@ class InferenceEngineV2:
         pc = self.prefix_cache
         span = self._tracer.span
         token_budget = self.config.max_ragged_batch_size
-        while queue or active:
+        while queue or active or self._ahead is not None:
             # ---- admit pending prompts (fused prefill + first-token sample): one
             # call, or under a token budget (max_ragged_batch_size) as many calls,
-            # one after another, as the queue and the pool allow, each within it
+            # one after another, as the queue and the pool allow, each within it.
+            # Not while a chain is in flight ahead: its rows are settled, and a
+            # seat that an EOS freed is seen one chain later.
             admitted = False
-            while True:
+            while self._ahead is None:
                 adm_uids: List[int] = []
                 adm_tokens: List[np.ndarray] = []
                 adm_counts: List[int] = []
@@ -1515,7 +1720,7 @@ class InferenceEngineV2:
                             accept(u, t)
                 if not (call_full and adm_uids):
                     break
-            if not active:
+            if not active and self._ahead is None:
                 if queue and not admitted:
                     if arr is not None:
                         wait = t_start + arr[queue[0]] - time.perf_counter()
@@ -1542,7 +1747,9 @@ class InferenceEngineV2:
                 budgets = [max_new_tokens - len(gen[active[u]]) for u in uids]
                 k = self.config.decode_chain
                 preempted = 0
-                while True:
+                # (a chain in flight ahead was assembled when it was dispatched:
+                # nothing to shrink, nobody to preempt)
+                while self._ahead is None:
                     while k > 1 and not self._can_schedule_evicting(
                             uids, self.chain_window(budgets, k)):
                         k -= 1
@@ -1570,6 +1777,16 @@ class InferenceEngineV2:
                 last = [gen[active[u]][-1] for u in uids]
                 chain_rids = [active[u] for u in uids]
                 histories = [context(active[u]) for u in uids] if n_spec > 0 else None
+                # Ask for the next chain ahead of this one's fetch where the next
+                # boundary, as far as the host can foresee it, has nothing to do
+                # but dispatch it: a plain chain of the full length (a speculative
+                # one emits what it accepts), some row goes on after it, and no
+                # prompt can be admitted there (none waits, or every seat is taken
+                # and no row's budget ends in this chain). The engine adds what it
+                # knows of the pool (``decode_chain``).
+                ask = (n_spec == 0 and k == self.config.decode_chain and bool(uids)
+                       and max(budgets) > k
+                       and (not queue or (len(active) >= self.config.max_seqs and min(budgets) > k)))
                 schedule_span.set_metadata(active=len(uids), k=k, preempted=preempted)
             if n_spec > 0:
                 out, emitted, rng = self.decode_spec_chain(
@@ -1578,7 +1795,7 @@ class InferenceEngineV2:
             else:
                 out, emitted, rng = self.decode_chain(
                     uids, last, budgets, k, rng, eos_id=eos_token_id,
-                    sample_kw=sample_kw, tracker=tracker, rids=chain_rids)
+                    sample_kw=sample_kw, tracker=tracker, rids=chain_rids, ahead=ask)
             n_emitted = int(emitted.sum())
             routed_args = ({"experts_touched": self.last_experts_touched}
                            if self._routed and n_spec == 0 else {})

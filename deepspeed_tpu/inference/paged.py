@@ -650,7 +650,7 @@ def ragged_decode_chain(
     block_tables: jax.Array,  # [N, P] int32, pre-extended for the K-token window
     block_size: int,
     active: jax.Array,  # [N] bool — live rows (pad rows False)
-    budgets: jax.Array,  # [N] int32 — max tokens this chain may emit per row
+    budgets: jax.Array,  # [N] int32 — max tokens the row may still emit (the chain: k_steps of them)
     rng: jax.Array,  # PRNG key, threaded through the scan and returned
     k_steps: int,
     eos_id: Optional[int] = None,
@@ -673,9 +673,25 @@ def ragged_decode_chain(
     after which its KV writes drop (out-of-range page) and its emitted slots
     are -1.
 
-    Returns ``(out_tokens [N, K], emitted [N], active [N], rng, pool)`` where
-    ``out_tokens[i, :emitted[i]]`` are valid and ``emitted[i]`` is also the
-    number of KV slots row i consumed (== seen_tokens advance).
+    Returns ``(out_tokens [N, K], emitted [N], active [N], tok [N], pos [N],
+    rng, pool)`` where ``out_tokens[i, :emitted[i]]`` are valid and
+    ``emitted[i]`` is also the number of KV slots row i consumed (==
+    seen_tokens advance).
+
+    ``active``, ``tok`` and ``pos`` are the scan's carry as it ends: which
+    rows are still live (no EOS, and ``budgets`` not used up: give a row's
+    whole budget, not its share of this chain, for that to mean "goes on"),
+    the token each would feed next and its position. They are what the NEXT
+    chain over the same rows starts from, so a caller may dispatch it before
+    this one's tokens are fetched (a chain ahead,
+    ``InferenceEngineV2.decode_chain``): it passes them back as ``active``,
+    ``tokens`` and ``start_pos``, with a block table that covers the next
+    window and, in ``budgets``, what it knows by itself: what each row has
+    left, 0 for a row that does not go on. A row starts live only with
+    ``active`` AND a budget, so a row that ended here at an EOS or at its
+    budget rides the next chain dead, as a pad row does: nothing written,
+    slots -1, ``emitted`` 0. Started from the host's values or from a carry it
+    is one program.
 
     A routed model asked ``with_picks`` returns two more: ``touched`` int32
     ``[K, routed layers]``, how many distinct experts the rows live at a step
@@ -714,14 +730,14 @@ def ragged_decode_chain(
         touched = hit.any(axis=(0, 2)).sum(axis=-1).astype(jnp.int32)  # [routed layers]
         return carry, (out, touched, picked)
 
-    carry0 = (pool, tokens, start_pos, active,
+    carry0 = (pool, tokens, start_pos, active & (budgets > 0),
               jnp.zeros_like(start_pos), rng)
-    (pool, _, _, active, emitted, rng), outs = jax.lax.scan(
+    (pool, tok, pos, active, emitted, rng), outs = jax.lax.scan(
         step, carry0, None, length=k_steps)
     if isinstance(outs, tuple):
         outs, touched, picks = outs
-        return outs.T, emitted, active, rng, pool, touched, picks
-    return outs.T, emitted, active, rng, pool
+        return outs.T, emitted, active, tok, pos, rng, pool, touched, picks
+    return outs.T, emitted, active, tok, pos, rng, pool
 
 
 class MigrationBuffer(NamedTuple):

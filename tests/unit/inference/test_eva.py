@@ -106,6 +106,37 @@ def test_put_token_by_token_through_two_closings(model, tokens, wanted):
     assert closings == 2 and engine.windows_closed == 2 and pages_of(engine, 1) == (2 * PER_CLOSED, 2)
 
 
+def test_a_chain_ahead_through_window_boundaries_is_the_serial_drivers(model, tokens):
+    """Chains dispatched ahead in which rows close their window at steps 0, 3
+    and 7 (and a row that closed in the chain before, and one that does at
+    the last step of all): the tables of chain N+1 are built from where chain N WILL leave
+    each row, pages behind a closing given back and all, before chain N is
+    fetched. Tokens, windows counted and pages held are a driver's that runs
+    one chain at a time; ``generate`` makes the same tokens."""
+    from .test_chain_ahead import serial_driver
+
+    k = 8
+    # the token fed at step 0, 3, 7 of chain 2 is a window's last; at step 3 of chain 1; at the last of chain 3
+    lens = [2 * W - 1 - k, 2 * W - 4 - k, 2 * W - 8 - k, 2 * W - 4, W + 8]
+    prompts = [tokens[i % 4, :n] for i, n in enumerate(lens)]
+    n_new = 1 + 3 * k
+    ahead, serial = engine_of(model), engine_of(model)
+    got = serial_driver(ahead, prompts, n_new, ahead=True, flush=False)
+    want = serial_driver(serial, prompts, n_new, flush=False)
+    assert (ahead.chains_ahead, serial.chains_ahead) == (2, 0)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert ahead.windows_closed == serial.windows_closed == 5 + 5  # the prompts' own, and one a row
+    assert ahead.state.free_blocks == serial.state.free_blocks
+    uids = [100 + i for i in range(len(lens))]
+    assert [pages_of(ahead, u) for u in uids] == [pages_of(serial, u) for u in uids]
+    assert [ahead.state.get(u).seen_tokens for u in uids] == [n + n_new - 1 for n in lens]
+    engine = engine_of(model)
+    for g, w in zip(engine.generate(prompts, max_new_tokens=n_new), want):
+        np.testing.assert_array_equal(g, w)
+    assert engine.chains_ahead == 2 and engine.state.free_blocks == 256
+
+
 def test_a_chain_in_which_rows_close_at_steps_0_3_and_7_and_one_does_not(files, model, tokens):
     reference, architecture = files
     engine = engine_of(model)

@@ -219,6 +219,26 @@ def test_generate_with_picks_covers_every_token_fed(files, tokens):
     assert 2 <= engine.last_experts_touched <= 6  # 3 rows x 2 picks over 8 experts
 
 
+def test_generate_with_picks_with_chains_ahead_is_the_serial_drivers(tokens):
+    """Three chains, two of them dispatched ahead: the picks of a chain ahead
+    are logged with the rows and the positions it was dispatched with, and
+    come out as a driver's that runs one chain at a time."""
+    from .test_chain_ahead import serial_driver
+
+    cfg, params = toy_params(jnp.float32)
+    prompts = [tokens[i, :n] for i, n in enumerate(LENS)]
+    engine, serial = engine_of(cfg, params, "fp32"), engine_of(cfg, params, "fp32")
+    n_new = 1 + 3 * engine.config.decode_chain
+    outs, picks = engine.generate_with_picks(prompts, max_new_tokens=n_new)
+    want, want_picks = serial._with_picks(lambda: serial_driver(serial, prompts, n_new), len(prompts))
+    assert (engine.chain_steps, engine.chains_ahead, serial.chains_ahead) == (3, 2, 0)
+    for i, p in enumerate(prompts):
+        np.testing.assert_array_equal(outs[i], want[i])
+        assert picks[i].shape == (len(p) + n_new - 1, 2, 2)
+        np.testing.assert_array_equal(picks[i], want_picks[i])
+    assert 2 <= engine.last_experts_touched <= 6
+
+
 def test_picks_are_for_routed_models_only():
     cfg = config_from_hf(dict(model_type="gpt_neox", vocab_size=64, hidden_size=32, intermediate_size=64,
                               num_hidden_layers=1, num_attention_heads=2, max_position_embeddings=64))
@@ -357,23 +377,27 @@ def _same_as_recorded(jaxpr, parent):
 @pytest.mark.parametrize("name", ["step", "chain"])
 def test_gpt_neox_programs_are_the_parents(name):
     """The census of the jaxpr's primitives, the number of operands and the
-    outputs' shapes, recorded on the parent commit (PR 32) by this very code."""
+    outputs' shapes, recorded by this very code: ``step`` on PR 32's commit,
+    ``chain`` on PR 36's, which hands back the scan's carry (two more outputs,
+    each row's next token and position, and a row starts live only with a
+    budget: one ``gt``, one ``and``) and takes no new operand."""
     cfg = config_from_hf(dict(
         model_type="gpt_neox", vocab_size=256, hidden_size=64, intermediate_size=256, num_hidden_layers=2,
         num_attention_heads=4, max_position_embeddings=128, rotary_pct=0.25, rotary_emb_base=10000,
         layer_norm_eps=1e-5, use_parallel_residual=True, hidden_act="gelu", tie_word_embeddings=False))
     pool, programs = _programs(cfg)
     assert pool.k.shape == pool.v.shape == (64, 16, 64)  # keys AND values
-    with open(os.path.join(os.path.dirname(__file__), "data", "gpt_neox_programs_at_pr32.json")) as f:
+    with open(os.path.join(os.path.dirname(__file__), "data", "gpt_neox_programs_at_pr36.json")) as f:
         _same_as_recorded(programs[name], json.load(f)[name])  # no picks among the outputs
 
 
 @pytest.mark.parametrize("name", ["step", "chain"])
 def test_glm4_moe_lite_programs_are_the_parents(name):
     """The routed, latent toy's two programs, picks and all, against the census
-    recorded on PR 34's commit: what PR 35 added for EVA attention is a branch
-    at trace time and reaches neither."""
+    recorded on PR 34's commit (``step``) and PR 36's (``chain``: the carry
+    handed back, as above): what PR 35 added for EVA attention is a branch at
+    trace time and reaches neither."""
     pool, programs = _programs(config_from_hf(TOY), with_picks=True)
     assert pool.v is None  # the latent pool
-    with open(os.path.join(os.path.dirname(__file__), "data", "glm4_moe_lite_programs_at_pr34.json")) as f:
+    with open(os.path.join(os.path.dirname(__file__), "data", "glm4_moe_lite_programs_at_pr36.json")) as f:
         _same_as_recorded(programs[name], json.load(f)[name])
