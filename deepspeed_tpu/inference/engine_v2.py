@@ -1005,18 +1005,12 @@ class InferenceEngineV2:
 
     def _advance(self, uids, counts) -> int:
         """``seen_tokens`` of each uid forward by its count. Returns the
-        windows that closed on the way (EVA), for ``_note_closed``."""
+        windows that closed on the way (EVA), for ``windows_closed``."""
         if self._layout is None:
             for uid, n in zip(uids, counts):
                 self.state.get(uid).seen_tokens += int(n)
             return 0
         return sum(self.state.advance(uid, int(n)) for uid, n in zip(uids, counts))
-
-    def _note_closed(self, closed: int) -> None:
-        """Count windows pooled into summaries (``serving/eva_windows_closed``)."""
-        if closed:
-            self.windows_closed += closed
-            self._tracer.count("serving/eva_windows_closed", float(closed))
 
     # ---------------------------------------------------------------- put
     def _build_batch(self, uids, token_lists) -> RaggedBatch:
@@ -1049,7 +1043,7 @@ class InferenceEngineV2:
             )
         self.dispatch_count += 1
         self._log_picks(picks, uids, None, token_lists)
-        self._note_closed(self._advance(uids, map(len, token_lists)))
+        self.windows_closed += self._advance(uids, map(len, token_lists))
         self.host_sync_count += 1
         return np.asarray(logits[: len(uids)])
 
@@ -1144,7 +1138,7 @@ class InferenceEngineV2:
             )
         self.dispatch_count += 1
         self._log_picks(picks, uids, rids, token_lists)
-        self._note_closed(self._advance(uids, map(len, token_lists)))
+        self.windows_closed += self._advance(uids, map(len, token_lists))
         with self._tracer.span("serve:fetch", kind="prefill"):
             out = np.asarray(toks[: len(uids)])
         self.host_sync_count += 1
@@ -1208,9 +1202,6 @@ class InferenceEngineV2:
                 buf["active"][at] = True
             eva_args = {} if self._layout is None else self._eva_args(
                 start[:, None] + np.arange(k)[None, :], share)
-            if eva_args and self._tracer.enabled:
-                self._tracer.registry.gauge("serving/eva_rows_per_context_token").set(
-                    eva_args["attended_rows"] / max(eva_args["context_tokens"], 1))
         chain = self._chain_fn(n_rows, k, eos_id, sample_kw)
         with self._tracer.span("serve:dispatch", kind="chain", rows=n_rows, live=len(uids),
                                k=k, chain=chain_id, ahead=int(ahead), **eva_args):
@@ -1353,7 +1344,7 @@ class InferenceEngineV2:
         self._advance([u for u, m in zip(uids, moved) if not m], emitted[~moved])
         if self._layout is not None:
             window = self._layout.window
-            self._note_closed(int(((flight.start + emitted) // window - flight.start // window).sum()))
+            self.windows_closed += int(((flight.start + emitted) // window - flight.start // window).sum())
         if self._ahead is not None:
             # the rows of the chain ahead that hold a request are those this
             # chain left live; where each starts from is known only now
@@ -1819,9 +1810,6 @@ class InferenceEngineV2:
                     g_occ.set(len(active) / self.config.max_seqs)
                     g_free.set(float(self.state.free_blocks))
                     g_util.set(self.state.utilization)
-                    if routed_args:
-                        registry.gauge("serving/moe_experts_touched").set(
-                            self.last_experts_touched)
                     if g_pfx_hit is not None:
                         g_pfx_hit.set(pc.hit_rate)
                         g_pfx_blocks.set(float(len(pc)))

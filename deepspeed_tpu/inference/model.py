@@ -30,7 +30,9 @@ from deepspeed_tpu.models.transformer import (
     TransformerConfig,
     _apply_norm,
     _embed_tokens,
+    _norm_at,
     act_fn,
+    reading,
 )
 
 
@@ -76,6 +78,15 @@ def init_cache(
 
 
 # ------------------------------------------------------------------ layers
+def _dense(lp, key: str, cfg: TransformerConfig, x, einsum: Optional[str] = None):
+    """``x`` through the projection ``lp[key]`` (kernel, and bias where it has
+    one), under the device-trace scope of that key (``reading``)."""
+    with reading(lp, key) as p:
+        w = p["kernel"].astype(cfg.dtype)
+        out = x @ w if einsum is None else jnp.einsum(einsum, x, w)
+        return out + p["bias"].astype(cfg.dtype) if "bias" in p else out
+
+
 def _qkv(lp, cfg: TransformerConfig, x):
     """Project hidden states to q/k/v using the training params.
 
@@ -83,35 +94,19 @@ def _qkv(lp, cfg: TransformerConfig, x):
     kernel shapes wq [E,H,hd], wk/wv [E,kvH,hd]; bias present iff layernorm
     family (GPT-2 style).
     """
-    q = jnp.einsum("bse,ehd->bshd", x, lp["wq"]["kernel"].astype(cfg.dtype))
-    k = jnp.einsum("bse,ehd->bshd", x, lp["wk"]["kernel"].astype(cfg.dtype))
-    v = jnp.einsum("bse,ehd->bshd", x, lp["wv"]["kernel"].astype(cfg.dtype))
-    if "bias" in lp["wq"]:
-        q = q + lp["wq"]["bias"].astype(cfg.dtype)
-        k = k + lp["wk"]["bias"].astype(cfg.dtype)
-        v = v + lp["wv"]["bias"].astype(cfg.dtype)
-    return q, k, v
+    return tuple(_dense(lp, key, cfg, x, "bse,ehd->bshd") for key in ("wq", "wk", "wv"))
 
 
 def _attn_out(lp, cfg: TransformerConfig, ctx):
-    out = jnp.einsum("bshd,hde->bse", ctx, lp["wo"]["kernel"].astype(cfg.dtype))
-    if "bias" in lp["wo"]:
-        out = out + lp["wo"]["bias"].astype(cfg.dtype)
-    return out
+    return _dense(lp, "wo", cfg, ctx, "bshd,hde->bse")
 
 
 def _mlp(lp, cfg: TransformerConfig, x):
-    def dense(p, y):
-        o = y @ p["kernel"].astype(cfg.dtype)
-        if "bias" in p:
-            o = o + p["bias"].astype(cfg.dtype)
-        return o
-
     if cfg.activation == "silu_glu":
-        h = jax.nn.silu(dense(lp["w_gate"], x)) * dense(lp["w_up"], x)
+        h = jax.nn.silu(_dense(lp, "w_gate", cfg, x)) * _dense(lp, "w_up", cfg, x)
     else:
-        h = act_fn(cfg.activation)(dense(lp["w_up"], x))
-    return dense(lp["w_down"], h)
+        h = act_fn(cfg.activation)(_dense(lp, "w_up", cfg, x))
+    return _dense(lp, "w_down", cfg, h)
 
 
 def _moe(lp, cfg: TransformerConfig, x):
@@ -504,38 +499,41 @@ def _block_step(lp, cfg: TransformerConfig, x, ck, cv, kv_mask, positions, write
     Returns (x_out, new_k_slab, new_v_slab) where the slabs are the K/V of the
     new tokens (caller merges into the cache — keeps this fn scan-friendly).
     """
-    h = _apply_norm(lp["attn_norm"], cfg, x)
-    q, k, v = _qkv(lp["attn"], cfg, h)
-    alibi = None
-    if cfg.position == "rope":
-        from deepspeed_tpu.models.transformer import apply_qk_rope
+    def ffn(y):
+        if cfg.num_experts > 0:
+            with reading(lp, "moe") as p:
+                return _moe(p, cfg, y)
+        with reading(lp, "mlp") as p:
+            return _mlp(p, cfg, y)
 
-        q, k = apply_qk_rope(cfg, q, k, positions)
-    elif cfg.position == "alibi":
-        from deepspeed_tpu.models.transformer import alibi_slopes
+    h = _norm_at(lp, "attn_norm", cfg, x)
+    with reading(lp, "attn") as ap:
+        q, k, v = _qkv(ap, cfg, h)
+        alibi = None
+        if cfg.position == "rope":
+            from deepspeed_tpu.models.transformer import apply_qk_rope
 
-        alibi = alibi_slopes(cfg.num_heads)
+            with jax.named_scope("rope"):
+                q, k = apply_qk_rope(cfg, q, k, positions)
+        elif cfg.position == "alibi":
+            from deepspeed_tpu.models.transformer import alibi_slopes
 
-    # merge new K/V into cache at per-row write offsets
-    ck = _write_cache(ck, k.astype(ck.dtype), write_start)
-    cv = _write_cache(cv, v.astype(cv.dtype), write_start)
-    ctx = _cached_attention(q, ck, cv, kv_mask, positions, alibi=alibi)
-    attn_out = _attn_out(lp["attn"], cfg, ctx)
+            alibi = alibi_slopes(cfg.num_heads)
+
+        # merge new K/V into cache at per-row write offsets
+        ck = _write_cache(ck, k.astype(ck.dtype), write_start)
+        cv = _write_cache(cv, v.astype(cv.dtype), write_start)
+        ctx = _cached_attention(q, ck, cv, kv_mask, positions, alibi=alibi)
+        attn_out = _attn_out(ap, cfg, ctx)
 
     if cfg.parallel_block:
         # falcon-style: attn and FFN both read the shared input norm `h`;
         # gpt-neox-style (parallel_mlp_norm): FFN reads its own norm of x
         if cfg.parallel_mlp_norm:
-            h = _apply_norm(lp["mlp_norm"], cfg, x)
-        ffn = _moe(lp["moe"], cfg, h) if cfg.num_experts > 0 else _mlp(lp["mlp"], cfg, h)
-        return x + attn_out + ffn, ck, cv
+            h = _norm_at(lp, "mlp_norm", cfg, x)
+        return x + attn_out + ffn(h), ck, cv
     x = x + attn_out
-    h = _apply_norm(lp["mlp_norm"], cfg, x)
-    if cfg.num_experts > 0:
-        x = x + _moe(lp["moe"], cfg, h)
-    else:
-        x = x + _mlp(lp["mlp"], cfg, h)
-    return x, ck, cv
+    return x + ffn(_norm_at(lp, "mlp_norm", cfg, x)), ck, cv
 
 
 def _write_cache(cache: jax.Array, new: jax.Array, start: jax.Array) -> jax.Array:
@@ -565,7 +563,7 @@ def _layer_stack(params, cfg, x, cache: KVCache, positions, write_start, kv_mask
 
 @jax.named_scope("lm_head")  # names the serving head in a device trace
 def _logits(params, cfg: TransformerConfig, x):
-    x = _apply_norm(params["final_norm"], cfg, x)
+    x = _norm_at(params, "final_norm", cfg, x)
     if cfg.tie_embeddings:
         return x @ params["embed"]["embedding"].T.astype(cfg.dtype)
     if cfg.num_pred_heads > 1:

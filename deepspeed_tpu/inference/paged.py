@@ -33,10 +33,10 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.model import (_apply_norm, _attn_out, _logits, _mlp,
+from deepspeed_tpu.inference.model import (_apply_norm, _attn_out, _dense, _logits, _mlp,
                                            _moe_with_picks, _qkv)
 from deepspeed_tpu.inference.sampling import greedy_tokens, sample_logits
-from deepspeed_tpu.models.transformer import TransformerConfig
+from deepspeed_tpu.models.transformer import TransformerConfig, _norm_at, reading
 
 
 class PagedKVPool(NamedTuple):
@@ -282,15 +282,20 @@ def _latent_attention(ap, cfg: TransformerConfig, h, positions, new_lens, block_
     rank, nope, rope_d = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     W = pk.shape[-1]
     dt = cfg.dtype
-    with jax.named_scope("mla"):
-        c_q = _rms(h @ ap["wq_a"]["kernel"].astype(dt), ap["q_norm"]["scale"], cfg.norm_eps)
-        q = jnp.einsum("ncr,rhd->nchd", c_q, ap["wq_b"]["kernel"].astype(dt))
-        kv = h @ ap["wkv_a"]["kernel"].astype(dt)  # [N, C, rank + rope]
-        c_kv = _rms(kv[..., :rank], ap["kv_norm"]["scale"], cfg.norm_eps)
-        k_rope = rope_at(kv[..., None, rank:], positions, cfg.rope_theta, cfg.rope_interleaved)[..., 0, :]
-        q_rope = rope_at(q[..., nope:], positions, cfg.rope_theta, cfg.rope_interleaved)
-        w_kvb = ap["wkv_b"]["kernel"].astype(dt)  # [rank, H, nope + v], kept whole
-        q_lat = jnp.einsum("nchd,rhd->nchr", q[..., :nope], w_kvb[..., :nope])
+    with jax.named_scope("mla"):  # and inside it the parameter keys read (``reading``)
+        c_q = _dense(ap, "wq_a", cfg, h)
+        with reading(ap, "q_norm") as p:
+            c_q = _rms(c_q, p["scale"], cfg.norm_eps)
+        q = _dense(ap, "wq_b", cfg, c_q, "ncr,rhd->nchd")
+        kv = _dense(ap, "wkv_a", cfg, h)  # [N, C, rank + rope]
+        with reading(ap, "kv_norm") as p:
+            c_kv = _rms(kv[..., :rank], p["scale"], cfg.norm_eps)
+        with jax.named_scope("rope"):
+            k_rope = rope_at(kv[..., None, rank:], positions, cfg.rope_theta, cfg.rope_interleaved)[..., 0, :]
+            q_rope = rope_at(q[..., nope:], positions, cfg.rope_theta, cfg.rope_interleaved)
+        with reading(ap, "wkv_b") as p:
+            w_kvb = p["kernel"].astype(dt)  # [rank, H, nope + v], kept whole
+            q_lat = jnp.einsum("nchd,rhd->nchr", q[..., :nope], w_kvb[..., :nope])
 
         def slab(latent, rope):  # [latent | rotary | zeros up to the pool's width]
             pad = [jnp.zeros(latent.shape[:-1] + (W - rank - rope_d,), dt)] * (W > rank + rope_d)
@@ -302,8 +307,9 @@ def _latent_attention(ap, cfg: TransformerConfig, h, positions, new_lens, block_
     o_lat = latent_paged_attention(q_slab, pk, block_tables + first_page, positions, bs,
                                    (nope + rope_d) ** -0.5, rank, new_lens=new_lens)
     with jax.named_scope("mla"):
-        o = jnp.einsum("nchr,rhv->nchv", o_lat, w_kvb[..., nope:])
-        out = jnp.einsum("nchv,hve->nce", o, ap["wo"]["kernel"].astype(dt))
+        with reading(ap, "wkv_b"):  # the value half of the kernel read above
+            o = jnp.einsum("nchr,rhv->nchv", o_lat, w_kvb[..., nope:])
+        out = _dense(ap, "wo", cfg, o, "nchv,hve->nce")
     return out, pk
 
 
@@ -434,8 +440,9 @@ def _eva_attention(cfg: TransformerConfig, positions, new_lens, block_tables, bs
     @jax.named_scope("eva")
     def attend(ap, h, pk, pv, first_page):
         q, k, v = _qkv(ap, cfg, h)
-        q = rope_at(q, positions, cfg.rope_theta, cfg.rope_interleaved)
-        k = rope_at(k, positions, cfg.rope_theta, cfg.rope_interleaved)
+        with jax.named_scope("rope"):
+            q = rope_at(q, positions, cfg.rope_theta, cfg.rope_interleaved)
+            k = rope_at(k, positions, cfg.rope_theta, cfg.rope_interleaved)
         phi, mu = ap["phi"], ap["mu"]
         first, pk, pv = one_token(q[:, :1], k[:, :1], v[:, :1], phi, mu, pk, pv, first_page)
         if C == 1:
@@ -532,19 +539,20 @@ def _forward_hidden(
             return put_pages(a, new, first_page)
         return a.at[first_page + w_page, w_slot].set(new, mode="drop")
 
-    def attention(lp, h, x, pk, pv, psk, psv, first_page):
+    def attention(ap, h, pk, pv, psk, psv, first_page):
         if eva:
-            out, pk, pv = eva_attend(lp["attn"], h, pk, pv, first_page)
+            out, pk, pv = eva_attend(ap, h, pk, pv, first_page)
             return out, pk, pv, psk, psv
         if latent:
-            out, pk = _latent_attention(lp["attn"], cfg, h, positions, new_lens, block_tables,
+            out, pk = _latent_attention(ap, cfg, h, positions, new_lens, block_tables,
                                         bs, pk, put_values, first_page)
             return out, pk, pv, psk, psv
-        q, k, v = _qkv(lp["attn"], cfg, h)
+        q, k, v = _qkv(ap, cfg, h)
         if cfg.position == "rope":
             from deepspeed_tpu.models.transformer import apply_qk_rope
 
-            q, k = apply_qk_rope(cfg, q, k, positions)
+            with jax.named_scope("rope"):
+                q, k = apply_qk_rope(cfg, q, k, positions)
         kvH, hd = k.shape[-2], k.shape[-1]
         with jax.named_scope("kv_write"):
             if quant is not None:
@@ -563,35 +571,39 @@ def _forward_hidden(
         ctx = paged_attention(q, pk, pv, block_tables + first_page, positions, bs,
                               new_lens=new_lens, alibi_slopes=alibi,
                               k_scale=psk, v_scale=psv)
-        return _attn_out(lp["attn"], cfg, ctx), pk, pv, psk, psv
+        return _attn_out(ap, cfg, ctx), pk, pv, psk, psv
 
     def ffn(lp, h, dense):
         """(output, picks or None): the dense MLP, or the routed layer with
         the experts it sent each token to."""
         if cfg.num_experts > 0 and not dense:
-            with jax.named_scope("moe"):
-                out, picks = _moe_with_picks(lp["moe"], cfg, h)
+            with reading(lp, "moe") as p:
+                out, picks = _moe_with_picks(p, cfg, h)
             return out, picks if routed else None
-        return _mlp(lp["mlp"], cfg, h), None
+        with reading(lp, "mlp") as p:
+            return _mlp(p, cfg, h), None
 
     # Scopes for a device trace (HLO metadata only). ``pool_scan`` encloses
     # the layer scan; everything the body computes sits under ``layer`` (or
     # under ``kv_write`` or the kernel's own name inside it), so what reads
     # ``pool_scan`` innermost is the scan's own traffic. The pool rides in
     # the carry and is updated in place, so that should be next to nothing.
+    # Inside ``layer`` each piece sits under the parameter key it reads
+    # (``reading``): ``attn_norm``, ``attn`` > ``wq`` ..., ``mlp`` > ``w_up`` ...,
+    # the names flax gives the same modules in training.
     @jax.named_scope("layer")
     def layer(carry, lp, first_page, dense=False):
         x, pk, pv, psk, psv = carry
-        h = _apply_norm(lp["attn_norm"], cfg, x)
-        attn_out, pk, pv, psk, psv = attention(lp, h, x, pk, pv, psk, psv, first_page)
+        h = _norm_at(lp, "attn_norm", cfg, x)
+        with reading(lp, "attn") as ap:
+            attn_out, pk, pv, psk, psv = attention(ap, h, pk, pv, psk, psv, first_page)
         if cfg.parallel_block:
             # falcon/phi-style: attn and FFN read the shared input norm;
             # gpt-neox-style (parallel_mlp_norm): FFN reads its own ln2(x)
-            ffn_in = _apply_norm(lp["mlp_norm"], cfg, x) if cfg.parallel_mlp_norm else h
-            out, picks = ffn(lp, ffn_in, dense)
+            out, picks = ffn(lp, _norm_at(lp, "mlp_norm", cfg, x) if cfg.parallel_mlp_norm else h, dense)
             return (x + attn_out + out, pk, pv, psk, psv), picks
         x = x + attn_out
-        out, picks = ffn(lp, _apply_norm(lp["mlp_norm"], cfg, x), dense)
+        out, picks = ffn(lp, _norm_at(lp, "mlp_norm", cfg, x), dense)
         return (x + out, pk, pv, psk, psv), picks
 
     carry = (x, *pool)

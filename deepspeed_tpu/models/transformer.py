@@ -19,6 +19,7 @@ model is a first-class Flax module designed for TPU:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Optional, Tuple
@@ -863,7 +864,11 @@ class CausalLM(nn.Module):
                 length=cfg.num_layers - cfg.first_dense_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(cfg, train, name="layers")
-            carry, _ = stack(carry, None)
+            # flax names the BODY ``layers``; what the scan itself does (the
+            # stacking of saved residuals, the slices of stacked parameters)
+            # reads ``layer_scan/while/body`` with no ``layers`` in a trace
+            with jax.named_scope("layer_scan"):
+                carry, _ = stack(carry, None)
         else:
             for i in range(cfg.num_layers):
                 carry, _ = block_cls(cfg, train, layer_idx=i, dense=i < cfg.first_dense_layers,
@@ -934,6 +939,17 @@ def _embed_tokens(params, cfg: TransformerConfig, ids):
     return x
 
 
+@contextlib.contextmanager
+def reading(tree, key: str):
+    """``tree[key]``, inside the device-trace scope ``key``. The functional
+    twins of the flax modules name what they compute by the parameter key
+    they read, which is the name flax gives the module that owns it
+    (``layers/attn/wq/dot_general``): one vocabulary in training and serving,
+    taken from the tree ``CausalLM.init`` makes. HLO metadata only."""
+    with jax.named_scope(key):
+        yield tree[key]
+
+
 def _apply_norm(norm_params, cfg: TransformerConfig, x):
     """Functional twin of ``_norm`` (RMSNorm / flax LayerNorm)."""
     if cfg.norm == "rmsnorm":
@@ -952,8 +968,14 @@ def _apply_norm(norm_params, cfg: TransformerConfig, x):
     return y.astype(cfg.dtype)
 
 
+def _norm_at(tree, key: str, cfg: TransformerConfig, x):
+    """``_apply_norm`` with the parameters ``tree[key]``, under that key's scope."""
+    with reading(tree, key) as p:
+        return _apply_norm(p, cfg, x)
+
+
 def _lm_head_and_loss(params, cfg: TransformerConfig, x, batch, aux):
-    x = _apply_norm(params["final_norm"], cfg, x)
+    x = _norm_at(params, "final_norm", cfg, x)
     ids = batch["input_ids"]
     labels = batch.get("labels")
     if labels is None:

@@ -1803,15 +1803,18 @@ class DeepSpeedTPUEngine:
                     stats = None
                 else:
                     (_, (loss, stats)), grads = grad_fn(compute_params, micro_batch, jax.random.fold_in(step_rng, i))
-                    grads = cast_floating(grads, accum_dtype)
-                acc = jax.tree_util.tree_map(lambda a, g: (a + g).astype(accum_dtype), acc, grads)
+                with jax.named_scope("grad_accum"):  # the accumulator's casts and adds, in a device trace
+                    if zpp_fn is None:
+                        grads = cast_floating(grads, accum_dtype)
+                    acc = jax.tree_util.tree_map(lambda a, g: (a + g).astype(accum_dtype), acc, grads)
                 # shard the accumulator (stage>=2 => reduce-scatter per micro-batch)
                 acc = jax.lax.with_sharding_constraint(acc, grad_pspecs)
                 return (acc, i + 1), (loss, stats)
 
-            zero_grads = jax.tree_util.tree_map(
-                lambda p: jnp.zeros(p.shape, accum_dtype), state.params
-            )
+            with jax.named_scope("grad_accum"):
+                zero_grads = jax.tree_util.tree_map(
+                    lambda p: jnp.zeros(p.shape, accum_dtype), state.params
+                )
             zero_grads = jax.lax.with_sharding_constraint(zero_grads, grad_pspecs)
 
             if zpp_loco is not None:
@@ -1831,7 +1834,8 @@ class DeepSpeedTPUEngine:
                     grads, err, loss = zpp_fn(
                         compute_params, err, micro_batch, scale, inv_s,
                         jax.random.key_data(jax.random.fold_in(step_rng, i)))
-                    acc = jax.tree_util.tree_map(lambda a, g: a + g, acc, grads)
+                    with jax.named_scope("grad_accum"):
+                        acc = jax.tree_util.tree_map(lambda a, g: a + g, acc, grads)
                     acc = jax.lax.with_sharding_constraint(acc, grad_pspecs)
                     return (acc, err, i + 1), loss
 
@@ -1908,7 +1912,8 @@ class DeepSpeedTPUEngine:
             inv = 1.0 / (gas * scale)
             grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
         finite = all_finite(grads) if self.fp16 else jnp.asarray(True)
-        gnorm = global_norm(grads)
+        with jax.named_scope("grad_norm"):
+            gnorm = global_norm(grads)
         # Health probes (diagnostics/health.py) on the raw unscaled/unclipped
         # gradients — extends the finite/gnorm this step already computes,
         # never a second fetch. skip_step-policy signals gate the update off
@@ -2021,14 +2026,16 @@ class DeepSpeedTPUEngine:
             def micro_step(carry, micro_batch):
                 acc, i = carry
                 (_, loss), grads = grad_fn(compute_params, micro_batch, jax.random.fold_in(step_rng, i))
-                grads = cast_floating(grads, accum_dtype)
-                acc = jax.tree_util.tree_map(lambda a, g: (a + g).astype(accum_dtype), acc, grads)
+                with jax.named_scope("grad_accum"):
+                    grads = cast_floating(grads, accum_dtype)
+                    acc = jax.tree_util.tree_map(lambda a, g: (a + g).astype(accum_dtype), acc, grads)
                 acc = jax.lax.with_sharding_constraint(acc, grad_pspecs)
                 return (acc, i + 1), loss
 
-            zero_grads = jax.tree_util.tree_map(
-                lambda p: jnp.zeros(p.shape, accum_dtype), compute_params
-            )
+            with jax.named_scope("grad_accum"):
+                zero_grads = jax.tree_util.tree_map(
+                    lambda p: jnp.zeros(p.shape, accum_dtype), compute_params
+                )
             zero_grads = jax.lax.with_sharding_constraint(zero_grads, grad_pspecs)
             if gas == 1:
                 (grads, _), losses = micro_step((zero_grads, 0), jax.tree_util.tree_map(lambda x: x[0], batch))
@@ -2103,7 +2110,8 @@ class DeepSpeedTPUEngine:
         def stats(grads, inv):
             finite = all_finite(grads) if self.fp16 else jnp.asarray(True)
             # norm is 1-homogeneous: norm(g * inv) == norm(g) * inv
-            return finite, global_norm(grads) * inv
+            with jax.named_scope("grad_norm"):
+                return finite, global_norm(grads) * inv
 
         def _clipped(grads_sub, inv, gnorm):
             g = jax.tree_util.tree_map(lambda x: x * inv, grads_sub)
