@@ -10,6 +10,17 @@ model is a first-class Flax module designed for TPU:
   - ``nn.scan`` over layers: one compiled block, stacked params (fast compile,
     XLA-friendly), optional ``nn.remat`` for activation checkpointing
     (the analog of ``runtime/activation_checkpointing``)
+  - what a layer saves for its backward follows ONE rule, whatever ``remat``
+    says: *a layer saves what a product or a kernel made and the inputs they
+    need; what an elementwise function computed inside itself is made again
+    in the backward.* The norms (``_norm``) and the MLP's activation are
+    wrapped in ``_made_again`` so that only their inputs are kept: a norm's
+    fp32 copy, centred and normalised value and an activation's inner terms
+    cost a few VPU operations an element to make and twice their bytes of
+    memory traffic to keep. The one exception was measured: the erf of
+    ``gelu_exact`` is tens of VPU operations, so it keeps its slope
+    (``_gelu_exact``). ``remat: true`` is the other thing, the whole block
+    made again: every product and the attention kernel run twice
   - attention dispatches through the ops registry so the Pallas flash kernel
     replaces the XLA einsum path on TPU (``deepspeed_tpu/ops``)
   - ``partition_rules`` provide tensor-parallel placements (the AutoTP analog,
@@ -27,6 +38,7 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.runtime.model import ModelSpec
@@ -377,6 +389,38 @@ def act_fn(name: str):
     raise ValueError(f"unknown activation {name!r} (silu_glu | gelu | gelu_exact | relu)")
 
 
+@jax.custom_vjp
+def _gelu_exact(x):
+    """``jax.nn.gelu(x, approximate=False)`` whose backward keeps ONE array,
+    the slope, and makes nothing again: the erf is a long polynomial on the
+    VPU, and making it again cost the 410M train cell 0.18 ms a layer-step
+    where the two arrays it spared were read under a product (PERF.md, PR 38)."""
+    return jax.nn.gelu(x, approximate=False)
+
+
+def _gelu_exact_fwd(x):
+    twice_cdf = jax.lax.erfc(-x * np.sqrt(0.5).astype(x.dtype))  # ``jax.nn.gelu``'s own expression
+    xf = x.astype(jnp.float32)
+    slope = 0.5 * twice_cdf.astype(jnp.float32) + xf * jnp.exp(-0.5 * xf * xf) * np.float32(1 / np.sqrt(2 * np.pi))
+    return 0.5 * x * twice_cdf, slope.astype(x.dtype)
+
+
+_gelu_exact.defvjp(_gelu_exact_fwd, lambda slope, g: (g * slope,))
+
+
+def _made_again(what):
+    """The module docstring's rule, as code: ``what`` (an elementwise function,
+    or the flax class of one) keeps its inputs alone for the backward."""
+    return (nn.remat if isinstance(what, type) else jax.checkpoint)(what, prevent_cse=False)
+
+
+def _mlp_activation(name: str):
+    """``act_fn(name)`` as a dense MLP applies it between its products: made
+    again from its input in the backward, but for the erf form, which keeps
+    its slope instead."""
+    return _gelu_exact if name == "gelu_exact" else _made_again(act_fn(name))
+
+
 class RMSNorm(nn.Module):
     eps: float = 1e-5
     param_dtype: Any = jnp.float32
@@ -396,11 +440,12 @@ class RMSNorm(nn.Module):
 
 
 def _norm(config: TransformerConfig, name: str):
+    # the backward keeps ``x``: the fp32 copy, the centred and the normalised value are made again
     if config.norm == "rmsnorm":
-        return RMSNorm(eps=config.norm_eps, param_dtype=config.param_dtype,
-                       unit_offset=config.norm_unit_offset,
-                       out_dtype=config.dtype if config.fp32_residual else None, name=name)
-    return nn.LayerNorm(epsilon=config.norm_eps, param_dtype=config.param_dtype, name=name)
+        return _made_again(RMSNorm)(
+            eps=config.norm_eps, param_dtype=config.param_dtype, unit_offset=config.norm_unit_offset,
+            out_dtype=config.dtype if config.fp32_residual else None, name=name)
+    return _made_again(nn.LayerNorm)(epsilon=config.norm_eps, param_dtype=config.param_dtype, name=name)
 
 
 def rope_tables(seq_len: int, dim: int, theta: float) -> Tuple[jax.Array, jax.Array]:
@@ -679,11 +724,12 @@ class MLP(nn.Module):
                             param_dtype=cfg.param_dtype, name="w_gate")(x)
             up = nn.Dense(cfg.intermediate_size, use_bias=bias, dtype=cfg.dtype,
                             param_dtype=cfg.param_dtype, name="w_up")(x)
-            h = nn.silu(gate) * up
+            # ``gate``, ``up`` and ``h`` are kept (the products' own), the sigmoid is made again
+            h = _made_again(lambda gate, up: nn.silu(gate) * up)(gate, up)
         else:
             h = nn.Dense(cfg.intermediate_size, use_bias=bias, dtype=cfg.dtype,
                             param_dtype=cfg.param_dtype, name="w_up")(x)
-            h = act_fn(cfg.activation)(h)
+            h = _mlp_activation(cfg.activation)(h)
         out = nn.Dense(cfg.hidden_size, use_bias=bias, dtype=cfg.dtype,
                             param_dtype=cfg.param_dtype, name="w_down")(h)
         if cfg.dropout > 0:

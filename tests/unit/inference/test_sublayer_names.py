@@ -9,6 +9,7 @@ metadata of toy programs compiled on the CPU: the ``step`` and ``chain`` of a
 is metadata: that it adds no primitive is what ``test_latent_routed.py -k
 parents`` holds against the censuses recorded at PR 36."""
 
+import dataclasses
 import re
 
 import jax
@@ -177,3 +178,32 @@ def test_every_product_of_a_trained_layer_carries_its_weight_s_name(train_names)
     for wrapper in ("jvp(CausalLM)", "transpose(jvp(CausalLM))"):  # forward and transposed
         found = {sublayers.weight_of(n) for n in products(train_names) if wrapper + "/" in n} - {None}
         assert found == {"wq", "wk", "wv", "wo", "w_up", "w_down"}, wrapper
+
+
+def remade(names):
+    """The instructions of a backward that make a sub-layer's inside again
+    (``jax.checkpoint`` names its second run ``rematted_computation``)."""
+    return [n for n in names if "rematted_computation" in sublayers.components(n)]
+
+
+def test_what_a_trained_layer_makes_again_carries_its_sub_layer_s_name(train_names):
+    """Since PR 38 the norms and the activation keep their inputs alone and
+    run again in the backward: those instructions read ``attn_norm``,
+    ``mlp_norm`` (``final_norm``) and ``mlp``, under ``layers`` inside the
+    scan, so ``unnamed_time_share.train`` and ``scan_stack_time_share.train``
+    cannot fill with them. The erf form (the toy's) keeps its slope and makes
+    nothing again; a ``silu_glu`` layer's sigmoid is made again under ``mlp``."""
+    again = remade(train_names)
+    assert {sublayers.label(n) for n in again} == {"layers/attn_norm", "layers/mlp_norm", "final_norm"}
+    assert not any(sublayers.is_scan_stacking(n) for n in again)
+    # the slope is computed in the forward and applied in the backward, both under ``mlp``
+    under_mlp = {n.rsplit("/", 1)[1]: n for n in train_names if sublayers.label(n) == "layers/mlp"}
+    assert {"erfc", "exp"} <= set(under_mlp) and "jvp(CausalLM)/" in under_mlp["exp"]
+
+    llama = dataclasses.replace(config_from_hf(NEOX_TOY), norm="rmsnorm", activation="silu_glu", parallel_block=False,
+                                parallel_mlp_norm=False)
+    batch = {"input_ids": jnp.zeros((1, 16), jnp.int32)}
+    grad = jax.jit(jax.grad(lambda p: CausalLM(llama).apply({"params": p}, batch, train=True)[0]))
+    again = remade(op_names(grad.lower(shapes_of(llama)).compile().as_text()))
+    assert {sublayers.label(n) for n in again} == {"layers/attn_norm", "layers/mlp_norm", "layers/mlp", "final_norm"}
+    assert any(n.endswith("mlp/checkpoint/rematted_computation/jit(silu)/exp") for n in again)
