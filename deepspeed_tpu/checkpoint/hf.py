@@ -68,6 +68,51 @@ def load_safetensors_state(path: str) -> Dict[str, np.ndarray]:
     return state
 
 
+def _latent_routed(hf_config: Dict[str, Any], mt: str) -> Dict[str, Any]:
+    """What ``glm4_moe_lite`` and ``xing4_0`` share, as TransformerConfig
+    keywords: the DeepSeek-V3 family's latent attention and routed experts
+    under that family's key names."""
+    if hf_config.get("n_group", 1) != 1 or hf_config.get("topk_group", 1) != 1:
+        raise ValueError(f"{mt} with grouped expert choice (n_group > 1) is unsupported")
+    if hf_config.get("partial_rotary_factor", 1) != 1:
+        raise ValueError(f"{mt} with partial_rotary_factor != 1 is unsupported")
+    dtype = hf_config.get("dtype", hf_config.get("torch_dtype"))
+    return dict(
+        vocab_size=hf_config["vocab_size"],
+        hidden_size=hf_config["hidden_size"],
+        intermediate_size=hf_config["intermediate_size"],
+        num_layers=hf_config["num_hidden_layers"],
+        num_heads=hf_config["num_attention_heads"],
+        max_seq_len=hf_config.get("max_position_embeddings", 4096),
+        norm="rmsnorm",
+        activation="silu_glu",
+        position="rope",
+        rope_theta=float(hf_config.get("rope_theta", 10000.0)),
+        # the family's stored layout rotates adjacent pairs (its modelling
+        # code de-interleaves q and k alike before a half-split rotation:
+        # the same scores)
+        rope_interleaved=bool(hf_config.get("rope_interleave", True)),
+        norm_eps=float(hf_config.get("rms_norm_eps", 1e-5)),
+        tie_embeddings=bool(hf_config.get("tie_word_embeddings", False)),
+        qkv_bias=bool(hf_config.get("attention_bias", False)),
+        q_lora_rank=hf_config["q_lora_rank"],
+        kv_lora_rank=hf_config["kv_lora_rank"],
+        qk_nope_head_dim=hf_config["qk_nope_head_dim"],
+        qk_rope_head_dim=hf_config["qk_rope_head_dim"],
+        v_head_dim=hf_config["v_head_dim"],
+        first_dense_layers=hf_config.get("first_k_dense_replace", 0),
+        num_experts=hf_config["n_routed_experts"],
+        moe_top_k=hf_config["num_experts_per_tok"],
+        moe_intermediate_size=hf_config["moe_intermediate_size"],
+        moe_shared_experts=hf_config.get("n_shared_experts") or 0,
+        moe_router="sigmoid",
+        moe_renormalize=bool(hf_config.get("norm_topk_prob", True)),
+        moe_routed_scale=float(hf_config.get("routed_scaling_factor", 1.0)),
+        moe_drop_tokens=False,
+        param_dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(dtype, jnp.float32),
+    )
+
+
 def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
     """Map an HF ``config.json`` dict to a TransformerConfig."""
     mt = hf_config.get("model_type", "llama")
@@ -119,7 +164,8 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
         if hf_config.get("attention_class", "eva") != "eva":
             raise ValueError(f"evabyte with attention_class={hf_config['attention_class']!r} is unsupported")
         if hf_config.get("rope_scaling"):
-            raise ValueError("evabyte with rope_scaling is unsupported")
+            raise ValueError("evabyte with rope_scaling is unsupported (type 'yarn' is taken, by the "
+                             "latent attention of xing4_0 alone)")
         if hf_config.get("num_chunks"):
             raise ValueError("evabyte with num_chunks (a fixed number of chunks a window) is unsupported")
         dtype = hf_config.get("dtype", hf_config.get("torch_dtype"))
@@ -145,52 +191,26 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
             num_pred_heads=int(hf_config.get("num_pred_heads", 1)),
             param_dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(dtype, jnp.float32),
         )
-    if mt == "glm4_moe_lite":
+    if mt in ("glm4_moe_lite", "xing4_0"):
         # latent attention, leading dense layers before the routed stack, a
-        # sigmoid router with a correction bias and a shared expert. The
-        # next-token-prediction layers (num_nextn_predict_layers) are not
-        # built: no serving path runs them
-        if hf_config.get("rope_scaling"):
-            raise ValueError("glm4_moe_lite with rope_scaling is unsupported")
-        if hf_config.get("n_group", 1) != 1 or hf_config.get("topk_group", 1) != 1:
-            raise ValueError("glm4_moe_lite with grouped expert choice (n_group > 1) is unsupported")
-        if hf_config.get("partial_rotary_factor", 1) != 1:
-            raise ValueError("glm4_moe_lite with partial_rotary_factor != 1 is unsupported")
-        dtype = hf_config.get("dtype", hf_config.get("torch_dtype"))
-        return TransformerConfig(
-            vocab_size=hf_config["vocab_size"],
-            hidden_size=hf_config["hidden_size"],
-            intermediate_size=hf_config["intermediate_size"],
-            num_layers=hf_config["num_hidden_layers"],
-            num_heads=hf_config["num_attention_heads"],
-            max_seq_len=hf_config.get("max_position_embeddings", 4096),
-            norm="rmsnorm",
-            activation="silu_glu",
-            position="rope",
-            rope_theta=float(hf_config.get("rope_theta", 10000.0)),
-            # the family's stored layout rotates adjacent pairs (its modelling
-            # code de-interleaves q and k alike before a half-split rotation:
-            # the same scores)
-            rope_interleaved=bool(hf_config.get("rope_interleave", True)),
-            norm_eps=float(hf_config.get("rms_norm_eps", 1e-5)),
-            tie_embeddings=bool(hf_config.get("tie_word_embeddings", False)),
-            qkv_bias=bool(hf_config.get("attention_bias", False)),
-            q_lora_rank=hf_config["q_lora_rank"],
-            kv_lora_rank=hf_config["kv_lora_rank"],
-            qk_nope_head_dim=hf_config["qk_nope_head_dim"],
-            qk_rope_head_dim=hf_config["qk_rope_head_dim"],
-            v_head_dim=hf_config["v_head_dim"],
-            first_dense_layers=hf_config.get("first_k_dense_replace", 0),
-            num_experts=hf_config["n_routed_experts"],
-            moe_top_k=hf_config["num_experts_per_tok"],
-            moe_intermediate_size=hf_config["moe_intermediate_size"],
-            moe_shared_experts=hf_config.get("n_shared_experts") or 0,
-            moe_router="sigmoid",
-            moe_renormalize=bool(hf_config.get("norm_topk_prob", True)),
-            moe_routed_scale=float(hf_config.get("routed_scaling_factor", 1.0)),
-            moe_drop_tokens=False,
-            param_dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(dtype, jnp.float32),
-        )
+        # sigmoid router with a correction bias and a shared expert
+        # (``_latent_routed``); xing4_0 besides wraps every sublayer in a
+        # hyper-connection over hc_mult residual streams (mHC) and scales its
+        # rotary frequencies (YaRN). The next-token-prediction layers
+        # (num_nextn_predict_layers) are not built: no serving path runs them
+        kw = _latent_routed(hf_config, mt)
+        scaling = hf_config.get("rope_scaling")
+        if mt == "xing4_0":
+            kw.update(  # a rope_scaling of another type than yarn: TransformerConfig refuses it by name
+                hc_mult=hf_config["hc_mult"],
+                hc_sinkhorn_iters=hf_config["hc_sinkhorn_iters"],
+                hc_eps=float(hf_config["hc_eps"]),
+                hc_res_clamp=(float(hf_config["mhc_h_res_clamp_min"]), float(hf_config["mhc_h_res_clamp_max"])),
+                rope_scaling=dict(scaling) if scaling else None)
+        elif scaling:
+            raise ValueError("glm4_moe_lite with rope_scaling is unsupported (type 'yarn' is taken for "
+                             "xing4_0 alone)")
+        return TransformerConfig(**kw)
     if mt == "opt":
         if not hf_config.get("do_layer_norm_before", True):
             raise ValueError("OPT post-layernorm variants (do_layer_norm_before=false) are unsupported")
@@ -390,7 +410,7 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
     raise ValueError(
         f"unsupported HF model_type {mt!r} (supported: llama/mistral/mixtral/"
         "qwen2/gpt2/opt/falcon/phi/gpt_neox/bloom/gptj/codegen/gpt_bigcode/"
-        "glm4_moe_lite/evabyte)")
+        "glm4_moe_lite/evabyte/xing4_0)")
 
 
 def detect_family(state: Dict[str, np.ndarray]) -> str:

@@ -334,6 +334,11 @@ class InferenceEngineV2:
             self._layout = WindowLayout(model_config.eva_window, config.kv_block_size, max_len)
             self.max_pages = self._layout.width
         self.windows_closed = 0  # EVA: windows pooled into summaries so far
+        if model_config.hc_mult and mesh.shape["tp"] > 1:
+            raise ValueError(
+                f"hyper-connections (hc_mult={model_config.hc_mult}) with tp={mesh.shape['tp']}: the mix "
+                "is a statistic and a product over the whole hidden width of every stream, which the "
+                "partition rules replicate and nothing has needed split yet")
 
         from deepspeed_tpu.utils.hbm import kv_slot_bytes
 
@@ -1126,7 +1131,8 @@ class InferenceEngineV2:
         batch = self._build_batch(uids, token_lists)
         step = self._sample_step_fn(batch.n_rows, batch.tokens.shape[1], sample_kw)
         with self._tracer.span("serve:dispatch", kind="prefill", rows=batch.n_rows,
-                               live=len(uids), rids=self._span_rids(rids),
+                               live=len(uids), tokens=int(batch.new_lens.sum()),
+                               rids=self._span_rids(rids),
                                **self._eva_args(batch.positions, batch.new_lens)):
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "prefill")

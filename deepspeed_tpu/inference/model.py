@@ -67,6 +67,11 @@ def init_cache(
         raise NotImplementedError(
             "EVA attention (eva_window > 0) in the v1 engine: its dense cache holds one row a "
             "position and has no summaries; serve it through InferenceEngineV2")
+    if cfg.hc_mult or cfg.latent_attention:
+        raise NotImplementedError(
+            "hyper-connections (hc_mult > 0) and latent attention (kv_lora_rank > 0) in the v1 engine: "
+            "its block step is the one-stream residual over plain keys and values; serve them "
+            "through InferenceEngineV2, whose paged path reads ops/mhc.py and the latent pool")
     hd = cfg.dims_per_head
     shape = (cfg.num_layers, batch_size, max_len, cfg.kv_heads, hd)
     return KVCache(

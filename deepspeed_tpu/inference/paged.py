@@ -37,6 +37,7 @@ from deepspeed_tpu.inference.model import (_apply_norm, _attn_out, _dense, _logi
                                            _moe_with_picks, _qkv)
 from deepspeed_tpu.inference.sampling import greedy_tokens, sample_logits
 from deepspeed_tpu.models.transformer import TransformerConfig, _norm_at, reading
+from deepspeed_tpu.ops import mhc
 
 
 class PagedKVPool(NamedTuple):
@@ -290,9 +291,12 @@ def _latent_attention(ap, cfg: TransformerConfig, h, positions, new_lens, block_
         kv = _dense(ap, "wkv_a", cfg, h)  # [N, C, rank + rope]
         with reading(ap, "kv_norm") as p:
             c_kv = _rms(kv[..., :rank], p["scale"], cfg.norm_eps)
+        rot = cfg.latent_rotary  # frequencies and softmax scale: the flax module's own
         with jax.named_scope("rope"):
-            k_rope = rope_at(kv[..., None, rank:], positions, cfg.rope_theta, cfg.rope_interleaved)[..., 0, :]
-            q_rope = rope_at(q[..., nope:], positions, cfg.rope_theta, cfg.rope_interleaved)
+            k_rope = rope_at(kv[..., None, rank:], positions, cfg.rope_theta, cfg.rope_interleaved,
+                             rot.inv_freq)[..., 0, :]
+            q_rope = rope_at(q[..., nope:], positions, cfg.rope_theta, cfg.rope_interleaved,
+                             rot.inv_freq)
         with reading(ap, "wkv_b") as p:
             w_kvb = p["kernel"].astype(dt)  # [rank, H, nope + v], kept whole
             q_lat = jnp.einsum("nchd,rhd->nchr", q[..., :nope], w_kvb[..., :nope])
@@ -305,7 +309,7 @@ def _latent_attention(ap, cfg: TransformerConfig, h, positions, new_lens, block_
     with jax.named_scope("kv_write"):
         pk = put_values(pk, row.astype(pk.dtype).reshape(-1, W), first_page)
     o_lat = latent_paged_attention(q_slab, pk, block_tables + first_page, positions, bs,
-                                   (nope + rope_d) ** -0.5, rank, new_lens=new_lens)
+                                   rot.softmax_scale, rank, new_lens=new_lens)
     with jax.named_scope("mla"):
         with reading(ap, "wkv_b"):  # the value half of the kernel read above
             o = jnp.einsum("nchr,rhv->nchv", o_lat, w_kvb[..., nope:])
@@ -482,6 +486,11 @@ def _forward_hidden(
     the pool either way. With latent attention the pool is the latent pool
     (:class:`PagedKVPool`) and attention runs absorbed (``_latent_attention``).
 
+    A model with hyper-connections (``hc_mult > 0``) carries its ``hc_mult``
+    residual streams ``[n, N, C, E]`` through the layers, each sublayer reading
+    a learned mix of them and writing back through ``lp["attn_hc"]`` /
+    ``lp["mlp_hc"]`` (``ops/mhc.py``); they are summed before the selection.
+
     ``all_positions=True`` returns the full ``[N, C, E]`` hidden states
     instead of the last-token selection — the speculative verify step needs
     a logit at EVERY draft position to accept/reject in one pass.
@@ -509,6 +518,8 @@ def _forward_hidden(
             x = _apply_norm(params["embed_norm"], cfg, x)
         if cfg.position == "learned":
             x = x + jnp.take(params["pos_embed"], positions, axis=0).astype(cfg.dtype)
+        if cfg.hc_mult:
+            x = mhc.spread(x, cfg.hc_mult)  # [n, N, C, E]: the carry's x is the streams
     alibi = None
     if cfg.position == "alibi":
         from deepspeed_tpu.models.transformer import alibi_slopes
@@ -583,6 +594,17 @@ def _forward_hidden(
         with reading(lp, "mlp") as p:
             return _mlp(p, cfg, h), None
 
+    def mixed_at(lp, key, streams):
+        """A sublayer's hyper-connection ``lp[key]``: its mix of every token and its read."""
+        with reading(lp, key) as p:
+            mixed = mhc.mix(streams, p["phi"], p["b"], p["alpha"], norm_eps=cfg.norm_eps,
+                            iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps, clamp=cfg.hc_res_clamp)
+            return mixed, mhc.read(streams, mixed)
+
+    def written(lp, key, streams, out, mixed):
+        with reading(lp, key):
+            return mhc.write(streams, out, mixed)
+
     # Scopes for a device trace (HLO metadata only). ``pool_scan`` encloses
     # the layer scan; everything the body computes sits under ``layer`` (or
     # under ``kv_write`` or the kernel's own name inside it), so what reads
@@ -594,6 +616,17 @@ def _forward_hidden(
     @jax.named_scope("layer")
     def layer(carry, lp, first_page, dense=False):
         x, pk, pv, psk, psv = carry
+        if cfg.hc_mult:
+            # ``x`` is the streams: the two adds of a one-stream block become a
+            # mixed read before each sublayer and a write-back after it (``ops/mhc.py``)
+            mixed, u = mixed_at(lp, "attn_hc", x)
+            with reading(lp, "attn") as ap:
+                attn_out, pk, pv, psk, psv = attention(ap, _norm_at(lp, "attn_norm", cfg, u),
+                                                       pk, pv, psk, psv, first_page)
+            x = written(lp, "attn_hc", x, attn_out, mixed)
+            mixed, u = mixed_at(lp, "mlp_hc", x)
+            out, picks = ffn(lp, _norm_at(lp, "mlp_norm", cfg, u), dense)
+            return (written(lp, "mlp_hc", x, out, mixed), pk, pv, psk, psv), picks
         h = _norm_at(lp, "attn_norm", cfg, x)
         with reading(lp, "attn") as ap:
             attn_out, pk, pv, psk, psv = attention(ap, h, pk, pv, psk, psv, first_page)
@@ -616,6 +649,8 @@ def _forward_hidden(
             lambda c, xs: layer(c, *xs), carry,
             (params["layers"], jnp.arange(D, L, dtype=jnp.int32) * NB))
     pool = PagedKVPool(*pool)
+    if cfg.hc_mult:
+        x = mhc.collapse(x)  # the streams summed, before the last-token selection and the head
     # picks: [routed layers, N*C, k] -> [N, C, routed layers, k]
     picks = None if picks is None else jnp.moveaxis(picks, 0, 1).reshape(N, C, L - D, -1)
 

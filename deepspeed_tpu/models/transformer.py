@@ -33,7 +33,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -211,8 +211,34 @@ class TransformerConfig:
     num_pred_heads: int = 1
     # dtype the parameters are CREATED in (a checkpoint's own, where it says)
     param_dtype: Any = jnp.float32
+    # Hyper-connections (hc_mult > 0; mHC, ``ops/mhc.py``): the residual is
+    # hc_mult streams a token; each sublayer reads a learned per-token mix of
+    # them and writes back through a doubly stochastic hc_mult x hc_mult matrix
+    # made by hc_sinkhorn_iters Sinkhorn rounds (hc_eps added to each sum) of
+    # exp of logits clamped to hc_res_clamp. 0 is the one-stream residual
+    # ``x + f(norm(x))``, a static branch: such a model compiles as it did.
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    # a checkpoint's ``rope_scaling`` dict; type "yarn" alone is taken (the
+    # DeepSeek-V2/V3 formulae, ``yarn_frequencies``), by latent attention alone
+    rope_scaling: Optional[Any] = None
 
     def __post_init__(self):
+        if isinstance(self.rope_scaling, dict):
+            # frozen dataclass must stay hashable (configs are jit static args)
+            object.__setattr__(self, "rope_scaling", tuple(sorted(self.rope_scaling.items())))
+        if self.rope_scaling is not None:
+            kind = dict(self.rope_scaling).get("type", dict(self.rope_scaling).get("rope_type"))
+            if kind != "yarn" or not self.latent_attention:
+                raise ValueError(
+                    f"rope_scaling of type {kind!r}: only type 'yarn' is taken, and only by latent "
+                    "attention (kv_lora_rank > 0); every other rotary path computes plain frequencies")
+        if self.hc_mult and (self.hc_mult < 2 or self.parallel_block or self.eva_window):
+            raise ValueError(
+                f"hyper-connections (hc_mult={self.hc_mult}) need at least two streams around a "
+                "sequential block (no parallel_block) with a one-dtype residual (no EVA attention)")
         if self.moe_router not in ("softmax", "sigmoid"):
             raise ValueError(f"moe_router must be softmax|sigmoid, got {self.moe_router!r}")
         if self.kv_lora_rank and not self.q_lora_rank:
@@ -289,6 +315,24 @@ class TransformerConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def latent_rotary(self) -> "LatentRotary":
+        """What latent attention's scores are made with, in training and in
+        serving: the rotary frequencies over ``qk_rope_head_dim`` (None: plain
+        ``rope_theta``) and the softmax scale, which each hands to its
+        attention call. YaRN changes both (``yarn_frequencies``)."""
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        if self.rope_scaling is None:
+            return LatentRotary(None, scale)
+        inv_freq, softmax = yarn_frequencies(self.qk_rope_head_dim, self.rope_theta, self.rope_scaling)
+        return LatentRotary(inv_freq, scale * softmax)
+
+    @property
+    def hc_params(self) -> int:
+        """One sublayer's hyper-connection: ``phi``, ``b`` and the three ``alpha``."""
+        n = self.hc_mult
+        return (n * self.hidden_size + 1) * (n * n + 2 * n) + 3 if n else 0
+
+    @property
     def routed_layers(self) -> int:
         """Layers with a router, in the order their picks are handed out."""
         if self.moe_layer_experts is not None:
@@ -346,7 +390,7 @@ class TransformerConfig:
                     layer_mlp += mlp + 2 * h + 2  # residual MLP + coefficient gate
             else:
                 layer_mlp = mlp
-            total += qkv + layer_mlp + (h if self.parallel_block else 2 * h)
+            total += qkv + layer_mlp + (h if self.parallel_block else 2 * h) + 2 * self.hc_params
         return total
 
     def num_active_params(self) -> int:
@@ -608,13 +652,57 @@ class Attention(nn.Module):
         return out
 
 
-def rope_at(x: jax.Array, positions: jax.Array, theta: float, interleaved: bool) -> jax.Array:
+class LatentRotary(NamedTuple):
+    """``TransformerConfig.latent_rotary``: what ``LatentAttention`` and the
+    paged ``_latent_attention`` both make their scores with."""
+
+    inv_freq: Optional[np.ndarray]  # [qk_rope_head_dim / 2] float32; None: plain rope_theta
+    softmax_scale: float            # the scores' scale, YaRN's factor in it
+
+
+@functools.lru_cache(maxsize=8)
+def yarn_frequencies(dim: int, theta: float, scaling: tuple) -> Tuple[np.ndarray, float]:
+    """YaRN as the DeepSeek-V2/V3 modelling code has it, whose key names
+    ``scaling`` (a config's ``rope_scaling``, as sorted pairs) uses: with ``f_i
+    = theta^(-2i/dim)``, ``corr(r) = dim ln(L0 / (2 pi r)) / (2 ln theta)``,
+    ``low = max(floor(corr(beta_fast)), 0)``, ``high = min(ceil(corr(beta_slow)),
+    dim - 1)`` and ``ramp_i = clip((i - low) / (high - low), 0, 1)``, pair ``i``
+    turns by ``(f_i / factor) ramp_i + f_i (1 - ramp_i)`` a position: fast pairs
+    as before, slow ones ``factor`` times slower. Returns those frequencies and
+    what multiplies the softmax scale, ``ym(mscale_all_dim)^2``, with ``ym(s) =
+    0.1 s ln(factor) + 1``. Cos and sin are multiplied by ``ym(mscale) /
+    ym(mscale_all_dim)``, which is 1 wherever the two keys agree: a config
+    where they differ is refused, until one needs it."""
+    import math
+
+    sc = dict(scaling)
+    factor, original = float(sc["factor"]), float(sc["original_max_position_embeddings"])
+    if float(sc.get("mscale", 1)) != float(sc.get("mscale_all_dim", 0)):
+        raise ValueError(f"yarn with mscale {sc.get('mscale', 1)} != mscale_all_dim {sc.get('mscale_all_dim', 0)}: "
+                         "cos and sin would be multiplied by their ratio, which no rotary path here does")
+
+    def corr(rotations):
+        return dim * math.log(original / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(corr(float(sc.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(corr(float(sc.get("beta_slow", 1)))), dim - 1)
+    freq = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2) - low) / ((high - low) or 0.001), 0.0, 1.0)
+    all_dim = 0.1 * float(sc.get("mscale_all_dim", 0)) * math.log(factor) + 1.0 if factor > 1 else 1.0
+    return (freq / factor * ramp + freq * (1 - ramp)).astype(np.float32), all_dim * all_dim
+
+
+def rope_at(x: jax.Array, positions: jax.Array, theta: float, interleaved: bool,
+            inv_freq: Optional[np.ndarray] = None) -> jax.Array:
     """Rotary embedding over the whole last dim of ``x`` [..., S, H, D] at
     ``positions`` [..., S], the angles computed from the positions themselves
     (no table of ``max_seq_len`` rows: a context of 200k would make one of
-    6.5M entries for every call). fp32 inside, ``x``'s dtype out."""
+    6.5M entries for every call). ``inv_freq`` [D/2] replaces the plain
+    ``theta^(-2i/D)`` (scaled rotary: ``TransformerConfig.latent_rotary``).
+    fp32 inside, ``x``'s dtype out."""
     d = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = positions.astype(jnp.float32)[..., None, None] * inv_freq  # [..., S, 1, D/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     xf = x.astype(jnp.float32)
@@ -691,9 +779,11 @@ class LatentAttention(nn.Module):
         q = dense((H, nope + rope_d), name="wq_b")(c_q)
         kv = dense(cfg.kv_lora_rank + rope_d, name="wkv_a")(x)
         c_kv = _norm(cfg, "kv_norm")(kv[..., : cfg.kv_lora_rank])
-        k_rope = rope_at(kv[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta,
-                         cfg.rope_interleaved)  # ONE head, shared by all
-        q_rope = rope_at(q[..., nope:], positions, cfg.rope_theta, cfg.rope_interleaved)
+        rot = cfg.latent_rotary
+        rope = functools.partial(rope_at, positions=positions, theta=cfg.rope_theta,
+                                 interleaved=cfg.rope_interleaved, inv_freq=rot.inv_freq)
+        k_rope = rope(kv[..., None, cfg.kv_lora_rank:])  # ONE head, shared by all
+        q_rope = rope(q[..., nope:])
         kv_up = dense((H, nope + vd), name="wkv_b")(c_kv)
         q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
         k = jnp.concatenate([kv_up[..., :nope], jnp.broadcast_to(k_rope, q_rope.shape)], axis=-1)
@@ -706,7 +796,7 @@ class LatentAttention(nn.Module):
         width = nope + rope_d
         if vd < width:
             v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, width - vd)))
-        out = causal_attention(q, k, v, mask=mask, impl=cfg.attn_impl,
+        out = causal_attention(q, k, v, mask=mask, impl=cfg.attn_impl, softmax_scale=rot.softmax_scale,
                                **dict(cfg.attn_kwargs or ()))[..., :vd]
         return dense(cfg.hidden_size, axis=(-2, -1), name="wo")(out)
 
@@ -737,6 +827,41 @@ class MLP(nn.Module):
         return out
 
 
+def _hc_alpha_init(key, shape, dtype=jnp.float32):
+    """(a_pre, a_post, a_res) about (1, 1, 4): ``m`` has unit variance, so at
+    ``a_res`` 4 a token's ``exp(A)`` spans e^-8 .. e^8, far from doubly
+    stochastic: the Sinkhorn rounds do work that two rounds would not, which a
+    check on seeded weights can then tell (PERF.md, section 6, PR 39)."""
+    return (jnp.asarray([1.0, 1.0, 4.0]) * (1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32))
+            ).astype(dtype)
+
+
+class HyperConnection(nn.Module):
+    """One sublayer's hyper-connection (``TransformerConfig.hc_mult``;
+    ``ops/mhc.py`` has the mathematics): ``phi`` [n * hidden, n^2 + 2n], ``b``
+    [n^2 + 2n] and ``alpha`` = (a_pre, a_post, a_res), from which every token's
+    ``Mix`` of the ``[n, B, S, hidden]`` streams is made. Every leaf is drawn
+    NONZERO and spread: at zero the mix would be the same for every token and
+    the matrix uniform after one Sinkhorn round, and nothing could tell
+    whether the product with ``phi`` or the iterations were computed."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, streams):
+        from deepspeed_tpu.ops import mhc
+        from deepspeed_tpu.parallel.moe import _nonzero_normal
+
+        cfg = self.config
+        n = cfg.hc_mult
+        rows, cols = n * cfg.hidden_size, n * n + 2 * n
+        phi = self.param("phi", nn.initializers.normal(rows ** -0.5), (rows, cols), cfg.param_dtype)
+        b = self.param("b", _nonzero_normal(1.0), (cols,), cfg.param_dtype)
+        alpha = self.param("alpha", _hc_alpha_init, (3,), cfg.param_dtype)
+        return mhc.mix(streams, phi, b, alpha, norm_eps=cfg.norm_eps, iters=cfg.hc_sinkhorn_iters,
+                       eps=cfg.hc_eps, clamp=cfg.hc_res_clamp)
+
+
 class Block(nn.Module):
     # ``train`` is a module attribute (not a call kwarg) because nn.scan does
     # not forward kwargs through the scanned call.
@@ -747,6 +872,8 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, carry, _=None):
+        from deepspeed_tpu.ops import mhc
+
         cfg = self.config
         attn_cls = (LatentAttention if cfg.latent_attention
                     else EvaAttention if cfg.eva_window else Attention)
@@ -766,11 +893,32 @@ class Block(nn.Module):
             x = x + attn_cls(cfg, name="attn")(h, mask, positions, self.train)
             if cfg.parallel_mlp_norm:
                 h = _norm(cfg, "mlp_norm")(x_in)
+        elif cfg.hc_mult:
+            # ``x`` is the streams: a sublayer reads their mix and its output
+            # is written back through H_res and H_post (``ops/mhc.py``); the
+            # modules ``attn_hc`` and ``mlp_hc`` hold each one's phi, b, alpha
+            mixed = HyperConnection(cfg, name="attn_hc")(x)
+            with jax.named_scope("attn_hc"):
+                u = mhc.read(x, mixed)
+            out = attn_cls(cfg, name="attn")(_norm(cfg, "attn_norm")(u), mask, positions, self.train)
+            with jax.named_scope("attn_hc"):
+                x = mhc.write(x, out, mixed)
+            mixed = HyperConnection(cfg, name="mlp_hc")(x)
+            with jax.named_scope("mlp_hc"):
+                u = mhc.read(x, mixed)
+            h = _norm(cfg, "mlp_norm")(u)
         else:
             x = x + attn_cls(cfg, name="attn")(
                 _norm(cfg, "attn_norm")(x), mask, positions, self.train
             )
             h = _norm(cfg, "mlp_norm")(x)
+
+        def add(out):  # the feed-forward's write-back
+            if cfg.hc_mult:
+                with jax.named_scope("mlp_hc"):
+                    return mhc.write(x, out, mixed)
+            return x + out
+
         # the scanned stack is built with layer_idx 0, so a leading dense
         # layer says so itself and is not looked up by its index
         n_exp = 0 if self.dense else (cfg.moe_layer_experts[self.layer_idx]
@@ -784,7 +932,7 @@ class Block(nn.Module):
             from deepspeed_tpu.parallel.moe import DropFreeMoE
 
             with jax.named_scope("moe"):
-                x = x + DropFreeMoE(cfg, name="moe")(h)
+                x = add(DropFreeMoE(cfg, name="moe")(h))  # the add reads ``moe``, as it did
         elif n_exp > 0:
             from deepspeed_tpu.parallel.moe import MoEConfig, MoELayer
 
@@ -816,9 +964,9 @@ class Block(nn.Module):
             else:
                 l_aux, out = moe_out
                 aux = aux + l_aux
-            x = x + out
+            x = add(out)
         else:
-            x = x + MLP(cfg, name="mlp")(h, self.train)
+            x = add(MLP(cfg, name="mlp")(h, self.train))
         if cfg.moe_dynamic_capacity:
             return (x, mask, positions, aux, cap_scale), None
         return (x, mask, positions, aux), None
@@ -891,6 +1039,10 @@ class CausalLM(nn.Module):
                 "pyramid MoE (moe_layer_experts) needs scan_layers=False: "
                 "heterogeneous expert counts cannot stack into one scan"
             )
+        if cfg.hc_mult:
+            from deepspeed_tpu.ops import mhc
+
+            x = mhc.spread(x, cfg.hc_mult)  # [n, B, S, hidden]: every stream starts as the embedding
         carry = (x, pad_mask, positions, aux)
         if cfg.moe_dynamic_capacity:
             # the autotuning controller's knob: a traced fp32 scalar the
@@ -920,6 +1072,8 @@ class CausalLM(nn.Module):
                 carry, _ = block_cls(cfg, train, layer_idx=i, dense=i < cfg.first_dense_layers,
                                      name=f"layer_{i}")(carry, None)
         x, aux = carry[0], carry[3]
+        if cfg.hc_mult:
+            x = mhc.collapse(x)  # the streams summed: no learned collapse (the config has no key for one)
 
         moe_stats = None
         if collect_moe:

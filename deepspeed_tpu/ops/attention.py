@@ -28,13 +28,14 @@ def _xla_causal_attention(
     alibi_slopes: Optional[jax.Array] = None,  # [H] bloom-style score biases
     bias: Optional[jax.Array] = None,  # [H, S, S] or [B, H, S, S] additive
     causal: bool = True,
+    softmax_scale: Optional[float] = None,  # None: D^-0.5
 ) -> jax.Array:
     B, S, H, D = q.shape
     Hkv = k.shape[2]
     assert H % Hkv == 0, f"query heads {H} not a multiple of kv heads {Hkv}"
     G = H // Hkv
 
-    qg = q.reshape(B, S, Hkv, G, D).astype(jnp.float32) * (D**-0.5)
+    qg = q.reshape(B, S, Hkv, G, D).astype(jnp.float32) * (D**-0.5 if softmax_scale is None else softmax_scale)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(jnp.float32))
 
     if alibi_slopes is not None:
@@ -79,7 +80,7 @@ def resolves_to_flash(impl: str = "auto") -> bool:
 
 
 def causal_attention(q, k, v, mask=None, impl: str = "auto",
-                     alibi_slopes=None, bias=None, **kernel_kwargs):
+                     alibi_slopes=None, bias=None, softmax_scale=None, **kernel_kwargs):
     """Grouped-query causal attention with optional ALiBi slopes and additive
     pair bias. ALiBi is fused into the Pallas flash kernels (slope * column
     iota — no bias tiles) so bloom-style training keeps the flash path; the
@@ -88,18 +89,23 @@ def causal_attention(q, k, v, mask=None, impl: str = "auto",
     Dense pair bias rides the XLA path (fully differentiable — the evoformer
     training case needs d_bias).
 
+    ``softmax_scale`` replaces the scores' ``D^-0.5`` (YaRN's latent attention
+    states its own: ``TransformerConfig.latent_rotary``); it goes to whichever
+    implementation runs, and is handed over only where it is given.
+
     kernel_kwargs (block_q / block_k / k_splits) are Pallas scheduling knobs
     with identical math — they are forwarded only when dispatch resolves to
     the pallas kernel and dropped on the XLA path (which has no blocking)."""
+    scaled = {} if softmax_scale is None else {"softmax_scale": softmax_scale}
     if bias is not None:
         return _xla_causal_attention(q, k, v, mask=mask,
-                                     alibi_slopes=alibi_slopes, bias=bias)
+                                     alibi_slopes=alibi_slopes, bias=bias, **scaled)
     fn = dispatch("causal_attention", impl)
     if fn is available_impls("causal_attention").get("pallas"):
-        return _per_shard_flash(fn, q, k, v, mask, alibi_slopes, kernel_kwargs)
+        return _per_shard_flash(fn, q, k, v, mask, alibi_slopes, dict(kernel_kwargs, **scaled))
     if alibi_slopes is not None:
-        return fn(q, k, v, mask=mask, alibi_slopes=alibi_slopes)
-    return fn(q, k, v, mask=mask)
+        return fn(q, k, v, mask=mask, alibi_slopes=alibi_slopes, **scaled)
+    return fn(q, k, v, mask=mask, **scaled)
 
 
 def evoformer_attention(q, k, v, pair_bias=None, mask=None):

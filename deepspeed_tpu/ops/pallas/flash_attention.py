@@ -648,17 +648,21 @@ def _flash_bwd(q, k, v, mask, slopes, out, lse, do, block_q: int, block_k: int,
 # --------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash_attention(q, k, v, mask, slopes, block_q, block_k, causal, masked, alibi,
-                     k_splits=1):
+                     k_splits=1, softmax_scale=None):
     out, _ = _flash_core(q, k, v, mask, slopes, block_q, block_k, causal, masked,
-                         alibi, k_splits)
+                         alibi, k_splits, softmax_scale)
     return out
 
 
+def _scale(d: int, softmax_scale: Optional[float]) -> float:
+    return d ** -0.5 if softmax_scale is None else softmax_scale
+
+
 def _flash_core(q, k, v, mask, slopes, block_q, block_k, causal, masked, alibi,
-                k_splits=1):
-    scale = q.shape[-1] ** -0.5 * _LOG2E  # base-2 softmax (see module header)
+                k_splits=1, softmax_scale=None):
+    scale = _scale(q.shape[-1], softmax_scale) * _LOG2E  # base-2 softmax (see module header)
     qs = (q * jnp.asarray(scale, q.dtype)).transpose(0, 2, 1, 3)  # [B,H,S,D]
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
@@ -668,14 +672,14 @@ def _flash_core(q, k, v, mask, slopes, block_q, block_k, causal, masked, alibi,
 
 
 def _flash_vjp_fwd(q, k, v, mask, slopes, block_q, block_k, causal, masked, alibi,
-                   k_splits=1):
+                   k_splits=1, softmax_scale=None):
     out, (qs, kt, vt, lse, out_bhsd) = _flash_core(q, k, v, mask, slopes, block_q,
                                                    block_k, causal, masked, alibi,
-                                                   k_splits)
+                                                   k_splits, softmax_scale)
     return out, (qs, kt, vt, mask, slopes, lse, out_bhsd)
 
 
-def _flash_vjp_bwd(block_q, block_k, causal, masked, alibi, k_splits, res, g):
+def _flash_vjp_bwd(block_q, block_k, causal, masked, alibi, k_splits, softmax_scale, res, g):
     qs, kt, vt, mask, slopes, lse, out_bhsd = res
     do = g.transpose(0, 2, 1, 3)
     dq, dk, dv = _flash_bwd(qs, kt, vt, mask, slopes, out_bhsd, lse, do,
@@ -684,7 +688,7 @@ def _flash_vjp_bwd(block_q, block_k, causal, masked, alibi, k_splits, res, g):
     # dq needs scale*log2e*ln2 == plain scale (exact — no ln2 rounding), and
     # dk, accumulated against the log2e-pre-scaled q, needs ln2 applied here
     # in fp32 before the downcast.
-    scale = qs.shape[-1] ** -0.5
+    scale = _scale(qs.shape[-1], softmax_scale)
     dq = (dq * scale).transpose(0, 2, 1, 3).astype(qs.dtype)
     dk = (dk * _LN2).transpose(0, 2, 1, 3).astype(kt.dtype)
     dv = dv.transpose(0, 2, 1, 3).astype(vt.dtype)
@@ -704,6 +708,7 @@ def flash_causal_attention(
     block_k: int = DEFAULT_BLOCK_K,
     alibi_slopes: Optional[jax.Array] = None,  # [H] fp32 (bloom ALiBi)
     k_splits: int = 1,
+    softmax_scale: Optional[float] = None,  # None: D^-0.5
 ) -> jax.Array:
     B, S, H, D = q.shape
     block_q = min(block_q, max(S, 8))
@@ -749,7 +754,7 @@ def flash_causal_attention(
         slopes = jnp.zeros((H, _LANES), jnp.float32)
 
     out = _flash_attention(q, k, v, keep[:, None, :], slopes,
-                           block_q, block_k, True, masked, alibi, k_splits)
+                           block_q, block_k, True, masked, alibi, k_splits, softmax_scale)
     return out[:, :S]
 
 

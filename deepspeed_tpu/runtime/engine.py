@@ -253,6 +253,12 @@ class DeepSpeedTPUEngine:
                 "single-chip, or use attn_impl='fpdt' without offload (or "
                 "sp_impl='ring') for multi-chip long context")
 
+        if getattr(mcfg, "hc_mult", 0) and dict(self.mesh.shape).get("tp", 1) > 1:
+            raise NotImplementedError(
+                f"hyper-connections (hc_mult={mcfg.hc_mult}) with tp={self.mesh.shape['tp']}: the "
+                "partition rules replicate their leaves, and the mix is a statistic and a product "
+                "over the whole hidden width of every stream, which nothing has needed split yet")
+
         # MoE × TP (ISSUE 15): ep×tp meshes route the MoE block through the
         # explicit collective dispatch (parallel/moe.py collective_moe_apply
         # — the reference moe/mappings.py token gather/drop across the tp

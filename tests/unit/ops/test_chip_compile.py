@@ -171,16 +171,36 @@ def test_latent_paged_attention_compiles(one_chip, monkeypatch, N, C):
                              i32(N, C), i32(N)) == 1
 
 
+@pytest.mark.parametrize("N,C", [(64, 1), (8, 2048)], ids=["cell-decode-64x1", "cell-prefill-8x2048"])
+def test_latent_paged_attention_compiles_at_32_heads(one_chip, monkeypatch, N, C):
+    """``mla_paged_attn`` at xing4.0-29b-a4b.serve.long-prompt-batch's shapes:
+    32 heads (a query tile of 16 tokens is 512 rows, glm's 320), a table of
+    4096 / 16 pages, a 1.5 GiB pool of 7 layers, a whole 2,048-token prompt a row."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    bf16 = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)  # noqa: E731
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+
+    def fn(q, pool, bt, qpos, lens):
+        return pa.flash_decode_latent(q, pool, bt, qpos, 16, 192 ** -0.5 * 2.00474, 512, new_lens=lens)
+
+    assert _compiled_kernels(fn, bf16(N, C, 32, 640), bf16(7 * 11234, 16, 640), i32(N, 256),
+                             i32(N, C), i32(N)) == 1
+
+
 @pytest.mark.parametrize("kernels", ["forward", "gradient"])
 @pytest.mark.parametrize("m,K,N,E,dtype", [
     (65536, 2048, 1536, 64, jnp.bfloat16),  # glm-4.7-flash.serve.batch's (64, 256) prefill: gate and up
     (65536, 1536, 2048, 64, jnp.bfloat16),  # and the down-projection
+    (65536, 3584, 1024, 64, jnp.bfloat16),  # xing4.0-29b-a4b's (8, 2048) prefill: gate and up
+    (65536, 1024, 3584, 64, jnp.bfloat16),  # and its down-projection
     (512, 2048, 1536, 64, jnp.bfloat16),    # the smallest grouped call at 64 experts (T = 2E tokens x 4 picks)
     (32768, 4096, 14336, 8, jnp.bfloat16),  # mixtral-shaped: 8 experts, groups of thousands
     (32768, 14336, 4096, 8, jnp.bfloat16),  # and its down-projection, whose K does not fit whole: a k loop
     (40, 128, 256, 4, jnp.bfloat16),        # fewer rows than a tile: one tile of the rows, padded
     (65536, 2048, 1536, 64, jnp.float32),   # fp32 operands: blocks twice the bytes, sublanes of 8
-], ids=["cell-gate-up", "cell-down", "m512-e64", "mixtral-up", "mixtral-down", "m40", "cell-gate-up-fp32"])
+], ids=["cell-gate-up", "cell-down", "xing-gate-up", "xing-down", "m512-e64", "mixtral-up", "mixtral-down", "m40", "cell-gate-up-fp32"])
 def test_grouped_matmul_compiles_at_the_chosen_tiles(one_chip, m, K, N, E, dtype, kernels):
     """The routed prefill's grouped matmul at the tiles ``_gmm_tiles`` picks
     from the call's shapes: Mosaic's VMEM refusal (16 MiB scoped, no limit of

@@ -45,8 +45,9 @@ class TestFlashAttention:
         keep = np.asarray(mask, bool)
         np.testing.assert_allclose(np.asarray(out)[keep], np.asarray(ref)[keep], atol=2e-5, rtol=2e-5)
 
+    @pytest.mark.parametrize("scale", [None, 0.71])  # a softmax scale of the caller's own (YaRN's latent attention)
     @pytest.mark.parametrize("gqa", [False, True])
-    def test_grads_match_xla(self, gqa):
+    def test_grads_match_xla(self, gqa, scale):
         B, S, H, D = 2, 32, 4, 8
         Hkv = 2 if gqa else H
         q = _rand(0, (B, S, H, D))
@@ -60,8 +61,15 @@ class TestFlashAttention:
 
             return f
 
-        ref_fn = loss(lambda q, k, v: ops.causal_attention(q, k, v, impl="xla"))
-        pl_fn = loss(lambda q, k, v: ops.dispatch("causal_attention", "pallas")(q, k, v, block_q=16, block_k=16))
+        ref_fn = loss(lambda q, k, v: ops.causal_attention(q, k, v, impl="xla", softmax_scale=scale))
+        pl_fn = loss(lambda q, k, v: ops.causal_attention(q, k, v, impl="pallas", softmax_scale=scale,
+                                                          block_q=16, block_k=16))
+        if scale is not None:  # it is the scores' scale: D^-0.5 said outright changes nothing, another value does
+            np.testing.assert_allclose(ops.causal_attention(q, k, v, impl="xla", softmax_scale=D ** -0.5),
+                                       ops.causal_attention(q, k, v, impl="xla"), atol=1e-6)
+            assert float(jnp.abs(ref_fn(q, k, v) - loss(lambda q, k, v: ops.causal_attention(q, k, v, impl="xla"))(
+                q, k, v))) > 1e-3
+            np.testing.assert_allclose(pl_fn(q, k, v), ref_fn(q, k, v), rtol=2e-5)
         ref_grads = jax.grad(ref_fn, argnums=(0, 1, 2))(q, k, v)
         pl_grads = jax.grad(pl_fn, argnums=(0, 1, 2))(q, k, v)
         for rg, pg in zip(ref_grads, pl_grads):

@@ -11,7 +11,9 @@
   ``unnamed_time_share.batch``) whether or not ``BENCHMARK.json`` lists the
   cell for them. The glm and EVA cells are not listed (``PERF.md`` section 7
   says which two pinned counts keep them off the lists), so their readings
-  in ``PERF.md`` section 5 are this tool's and no driver's run checks them.
+  in ``PERF.md`` section 5 are this tool's and no driver's run checks them;
+- in a cell of a routed, latent-attention architecture that is not listed for
+  them, the five readers of ``benchmarks/lib/routed.py`` (``ROUTED``) likewise.
 
 Both are wrapped OUTSIDE the benchmark, before ``run.main`` runs; nothing
 here is read by the program or the benchmark, and a cell's listed metrics
@@ -25,6 +27,11 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 SERVING = ("layer_matmul_time_share.batch", "layer_matmul_roofline.batch", "unnamed_time_share.batch")
+# the routed, latent-attention readers, for a cell whose architecture file has their costs and which
+# is not listed for them (PR 39's cell: ``tests/benchmarks/test_routed_readers.py`` takes a metric for
+# the glm cell's own only while its ``workloads`` is that cell alone; PERF.md, section 7)
+ROUTED = ("moe_time_share.batch", "moe_experts_roofline.batch", "moe_experts_touched.batch",
+          "mla_paged_time_share.batch", "mla_paged_roofline.batch")
 
 
 def main(argv=None) -> int:
@@ -45,6 +52,9 @@ def main(argv=None) -> int:
         if group == "per_layer" and harness.load_workload(workload_name).get("kind") == "serve":
             names = {m["name"] for m in wanted}
             wanted = wanted + [m for m in bench["per_layer"] if m["name"] in SERVING and m["name"] not in names]
+            config = harness.load_config(harness.load_workload(workload_name)["config"])
+            if hasattr(harness.load_architecture(config["architecture"]), "routed_decode_cost"):
+                wanted = wanted + [m for m in bench["per_layer"] if m["name"] in ROUTED and m["name"] not in names]
         return wanted
 
     scopes._hlo_stats, harness.cell_metrics = timed, with_serving_readers
