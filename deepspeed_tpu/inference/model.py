@@ -141,13 +141,16 @@ def _moe_with_picks(lp, cfg: TransformerConfig, x):
     - prefill (T >= 2E tokens): RAGGED dispatch (round 5; reference FastGen's
       ``inference/v2/kernels/ragged_ops`` moe_gather/moe_scatter +
       ``cutlass_ops`` grouped GEMM) — sort the (token, expert) pairs by
-      expert and run grouped matmuls (:func:`_grouped_matmul`: on the TPU
-      the megablox Pallas kernel at tiles picked from the call's shapes,
-      elsewhere ``lax.ragged_dot``), so prompt FFN FLOPs scale with top_k,
-      not E (8x2 Mixtral-style: 4x fewer).
+      expert, gather the tokens' rows into that order, run grouped matmuls
+      (:func:`_grouped_matmul`: on the TPU the megablox Pallas kernel at
+      tiles picked from the call's shapes, elsewhere ``lax.ragged_dot``),
+      gather each token's k rows back and sum them over k
+      (:func:`_moe_ragged`), so prompt FFN FLOPs scale with top_k, not E
+      (8x2 Mixtral-style: 4x fewer).
 
-    Device-trace scopes: ``moe_router``, ``moe_experts``, ``moe_shared``
-    (the caller opens ``moe`` around the layer).
+    Device-trace scopes: ``moe_router``, ``moe_experts`` (in a prefill
+    ``moe_dispatch`` and ``moe_combine`` inside it, around the grouped
+    matmuls), ``moe_shared`` (the caller opens ``moe`` around the layer).
     """
     from deepspeed_tpu.parallel.moe import route
 
@@ -446,19 +449,20 @@ def _grouped_matmul(lhs, rhs, group_sizes):
 
 
 def _moe_ragged(cfg: TransformerConfig, ep, tokens, top_p, top_i):
-    """Grouped-GEMM expert dispatch: [T*k] (token, expert) pairs sorted by
-    expert, expert-contiguous matmuls via :func:`_grouped_matmul`, weighted
-    scatter-add combine. Exact same math as the dense-combine path (sum
-    reordering only)."""
-    T, M = tokens.shape
+    """Grouped-GEMM expert dispatch: sort the [T*k] (token, expert) pairs by
+    expert, gather the tokens' rows into that order, run the
+    expert-contiguous matmuls (:func:`_grouped_matmul`), gather each token's
+    k rows back and sum them over k (:func:`_gather_combine`). Exact same
+    math as the dense-combine path (sum reordering only). Device-trace
+    scopes: ``moe_dispatch`` and ``moe_combine`` (the caller opens
+    ``moe_experts`` around both and the matmuls between them)."""
     E, k = cfg.num_experts, cfg.moe_top_k
-    e_flat = top_i.reshape(-1)                       # [T*k]
-    order = jnp.argsort(e_flat, stable=True)
-    tok_idx = (jnp.arange(T * k) // k)[order]        # source token per pair
-    gates = top_p.reshape(-1)[order].astype(cfg.dtype)
-    group_sizes = jnp.bincount(e_flat, length=E)
+    with jax.named_scope("moe_dispatch"):
+        e_flat = top_i.reshape(-1)                   # [T*k]
+        order = jnp.argsort(e_flat, stable=True)     # sorted row -> pair t*k + j
+        group_sizes = jnp.bincount(e_flat, length=E)
+        xg = tokens[order // k]                      # [T*k, M] gather: each pair's token
 
-    xg = tokens[tok_idx]                             # [T*k, M] gather
     up = _grouped_matmul(xg, ep["w_up"].astype(cfg.dtype), group_sizes)
     if cfg.activation == "silu_glu":
         h = jax.nn.silu(_grouped_matmul(
@@ -466,8 +470,24 @@ def _moe_ragged(cfg: TransformerConfig, ep, tokens, top_p, top_i):
     else:
         h = act_fn(cfg.activation)(up)
     out_g = _grouped_matmul(h, ep["w_down"].astype(cfg.dtype), group_sizes)
-    out = jnp.zeros((T, M), out_g.dtype)
-    return out.at[tok_idx].add(out_g * gates[:, None])
+    with jax.named_scope("moe_combine"):
+        return _gather_combine(out_g, order, top_p)
+
+
+def _gather_combine(out_g, order, top_p):
+    """``out[t] = sum_j top_p[t, j] * out_g[inv[t*k + j]]`` for the experts'
+    rows ``out_g`` [T*k, M] in sorted order, ``order`` the sort's permutation
+    (sorted row -> pair) and ``inv`` its inverse, by a second sort: k gathers
+    of ``[T, M]``, the products and the sum over k in float32, ONE rounding to
+    ``out_g``'s dtype at the end. No activation is scattered: a scatter-add of
+    the weighted rows collides k times a row, and XLA serialises it (v5e,
+    ``[65536, 3584]`` bf16, PERF.md PR 40: 16.6 ms a call alone, this 4.5;
+    one gather of ``[T, k, M]`` and a sum 7.5, by a re-layout in float32)."""
+    T, k = top_p.shape
+    inv = jnp.argsort(order).reshape(T, k)           # pair -> sorted row
+    gates = top_p.astype(jnp.float32)
+    out = sum(gates[:, j:j + 1] * out_g[inv[:, j]].astype(jnp.float32) for j in range(k))
+    return out.astype(out_g.dtype)
 
 
 def _cached_attention(q, ck, cv, kv_mask, q_positions, alibi=None):

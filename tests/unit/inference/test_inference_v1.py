@@ -175,6 +175,178 @@ def test_moe_ragged_prefill_work_scales_with_top_k():
     assert (T * k, H) in ragged_shapes  # the routed-rows activation
 
 
+def _census(jaxpr, found):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it, as
+    ``(primitive, operand avals, result avals)``."""
+    for eqn in jaxpr.eqns:
+        found.append((eqn.primitive.name, [v.aval for v in eqn.invars], [v.aval for v in eqn.outvars]))
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple)) else [value]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _census(inner, found)
+    return found
+
+
+def test_moe_ragged_combine_scatters_no_activation():
+    """The combine is a gather and a sum over k (PR 40): the traced
+    ``_moe_ragged`` holds no scatter of rows. The one primitive left by that
+    name moves int32 alone and is named here: ``bincount``'s ``scatter-add``
+    into the ``[E]`` group sizes (the sort is inverted by a second sort, which
+    the chip runs in 0.20 ms where the index scatter took 0.36)."""
+    from deepspeed_tpu.inference.model import _moe_ragged
+
+    E, k, M, H, T = 8, 2, 64, 128, 256
+    cfg = TransformerConfig(vocab_size=64, hidden_size=M, intermediate_size=H, num_layers=1, num_heads=2,
+                            max_seq_len=64, num_experts=E, moe_top_k=k)
+    ep = _moe_layer_params(cfg)["experts"]
+    f32, i32 = (lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)), jax.ShapeDtypeStruct((T, k), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda t, p, i: _moe_ragged(cfg, ep, t, p, i))(f32(T, M), f32(T, k), i32)
+    eqns = _census(jaxpr.jaxpr, [])
+    scatters = [(name, [(a.shape, str(a.dtype)) for a in operands])
+                for name, operands, _ in eqns if name.startswith("scatter")]
+    assert scatters == [("scatter-add", [((E,), "int32"), ((T * k, 1), "int32"), ((T * k,), "int32")])], scatters
+    # the rows come back by gathers of [T, M], k of them, out of the [T*k, M] product
+    back = [results[0].shape for name, operands, results in eqns
+            if name == "gather" and operands[0].shape == (T * k, M)]
+    assert back == [(T, M)] * k, back
+
+
+def _dense_combine(ep, tokens, top_p, top_i):
+    """Every expert on every token, weighed by the gates: the decode path's
+    mathematics in float32, as the yardstick of the ragged one."""
+    T, E = tokens.shape[0], ep["w_up"].shape[0]
+    f32 = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
+    gate = jnp.zeros((T, E), jnp.float32).at[jnp.arange(T)[:, None], top_i].add(f32(top_p))
+    h = jax.nn.silu(jnp.einsum("tm,emh->teh", f32(tokens), f32(ep["w_gate"]))) * \
+        jnp.einsum("tm,emh->teh", f32(tokens), f32(ep["w_up"]))
+    return jnp.einsum("te,teh,ehm->tm", gate, h, f32(ep["w_down"]))
+
+
+@pytest.mark.parametrize("picks", ["crowded", "an_empty_expert", "ragged_rows"])
+def test_moe_ragged_colliding_picks_match_dense_combine(picks):
+    """Many tokens on one expert, an expert with no token at all, and a
+    ``T*k`` that no row tile divides: the gathered combine is the dense one."""
+    from deepspeed_tpu.inference.model import _moe_ragged
+
+    E, k, M, H = 8, 2, 32, 64
+    T = 83 if picks == "ragged_rows" else 64  # 166 pairs: no multiple of 8
+    cfg = TransformerConfig(vocab_size=64, hidden_size=M, intermediate_size=H, num_layers=1, num_heads=2,
+                            max_seq_len=64, num_experts=E, moe_top_k=k)
+    ep = _moe_layer_params(cfg, seed=5)["experts"]
+    rng = np.random.default_rng(6)
+    tokens = jnp.asarray(rng.standard_normal((T, M)), jnp.float32)
+    top_p = jnp.asarray(rng.uniform(0.1, 1.0, (T, k)), jnp.float32)
+    score = rng.standard_normal((T, E))
+    if picks == "crowded":
+        score[:, 3] += 10.0  # every token's first pick
+    elif picks == "an_empty_expert":
+        score[:, 5] -= 10.0  # nobody's
+    top_i = jnp.asarray(np.argsort(-score, axis=1)[:, :k].astype(np.int32))
+    counts = np.bincount(np.asarray(top_i).reshape(-1), minlength=E)
+    assert {"crowded": counts[3] == T, "an_empty_expert": counts[5] == 0, "ragged_rows": (T * k) % 8 != 0}[picks]
+    got = _moe_ragged(cfg, ep, tokens, top_p, top_i)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(_dense_combine(ep, tokens, top_p, top_i)),
+                               rtol=2e-5, atol=2e-6)
+
+
+def _scatter_add_combine(cfg, ep, tokens, top_p, top_i):
+    """The routed prefill as it was before PR 40, kept HERE as the yardstick
+    of the new combine's rounding: the weighted rows scatter-added into a
+    zero-filled ``[T, M]`` in ``cfg.dtype``, one rounding an add."""
+    from deepspeed_tpu.inference.model import _grouped_matmul
+
+    T, M = tokens.shape
+    E, k = cfg.num_experts, cfg.moe_top_k
+    e_flat = top_i.reshape(-1)
+    order = jnp.argsort(e_flat, stable=True)
+    tok_idx = (jnp.arange(T * k) // k)[order]
+    gates = top_p.reshape(-1)[order].astype(cfg.dtype)
+    group_sizes = jnp.bincount(e_flat, length=E)
+    xg = tokens[tok_idx]
+    up = _grouped_matmul(xg, ep["w_up"].astype(cfg.dtype), group_sizes)
+    h = jax.nn.silu(_grouped_matmul(xg, ep["w_gate"].astype(cfg.dtype), group_sizes)) * up
+    out_g = _grouped_matmul(h, ep["w_down"].astype(cfg.dtype), group_sizes)
+    return jnp.zeros((T, M), out_g.dtype).at[tok_idx].add(out_g * gates[:, None])
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_moe_ragged_bf16_combine_is_no_further_from_float32(seed):
+    """In bf16 the gathered combine (products and the sum over k in float32,
+    one rounding) is no further from the float32 dense combine than the
+    scatter-add was (a rounding a product and a rounding an add)."""
+    from deepspeed_tpu.inference.model import _moe_ragged
+
+    E, k, M, H, T = 8, 4, 64, 128, 256
+    cfg = TransformerConfig(vocab_size=64, hidden_size=M, intermediate_size=H, num_layers=1, num_heads=2,
+                            max_seq_len=64, num_experts=E, moe_top_k=k, dtype=jnp.bfloat16)
+    ep = _moe_layer_params(cfg, seed=seed)["experts"]
+    rng = np.random.default_rng(100 + seed)
+    tokens = jnp.asarray(rng.standard_normal((T, M)), jnp.bfloat16)
+    top_p = jax.nn.softmax(jnp.asarray(rng.standard_normal((T, k)), jnp.float32), axis=-1)
+    top_i = jnp.asarray(np.argsort(-rng.standard_normal((T, E)), axis=1)[:, :k].astype(np.int32))
+    want = np.asarray(_dense_combine(ep, tokens, top_p, top_i))
+    distance = lambda got: float(np.linalg.norm(np.asarray(got, np.float32) - want))  # noqa: E731
+    new = distance(_moe_ragged(cfg, ep, tokens, top_p, top_i))
+    old = distance(_scatter_add_combine(cfg, ep, tokens, top_p, top_i))
+    assert new <= old, (new, old)
+    assert new < 0.02 * float(np.linalg.norm(want))  # and both are bf16's own distance, no more
+
+
+def test_drop_free_moe_gradients_match_dense_combine():
+    """``jax.grad`` through the flax layer on the ragged path (a gather of
+    unique rows transposes to a scatter for d out_g) against the same module a
+    token at a time, which takes the dense-all-experts path: tokens, the
+    router's matrix and all three expert matrices, in float32."""
+    from deepspeed_tpu.parallel.moe import DropFreeMoE
+
+    cfg = TransformerConfig(vocab_size=64, hidden_size=16, intermediate_size=32, num_layers=1, num_heads=2,
+                            max_seq_len=64, num_experts=4, moe_top_k=2)
+    module = DropFreeMoE(cfg)
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.standard_normal((2, 16, 16)) * 0.5, jnp.float32)  # T = 32 >= 2E = 8
+    ct = jnp.asarray(rng.standard_normal((2, 16, 16)), jnp.float32)
+    params = module.init(jax.random.PRNGKey(0), x)
+
+    def ragged(params, x):
+        return jnp.sum(module.apply(params, x) * ct)
+
+    def a_token_at_a_time(params, x):
+        one = jax.vmap(lambda tok: module.apply(params, tok[None, None])[0, 0])  # T = 1 < 2E
+        return jnp.sum(one(x.reshape(-1, 16)).reshape(x.shape) * ct)
+
+    np.testing.assert_allclose(ragged(params, x), a_token_at_a_time(params, x), rtol=2e-5)
+    got = jax.grad(ragged, argnums=(0, 1))(params, x)
+    want = jax.grad(a_token_at_a_time, argnums=(0, 1))(params, x)
+    leaves = {jax.tree_util.keystr(path): g for path, g in jax.tree_util.tree_leaves_with_path(got)}
+    assert any("wg" in name for name in leaves) and sum("experts" in name for name in leaves) == 3, list(leaves)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)):
+        assert float(jnp.max(jnp.abs(w))) > 0, path  # a gradient that is there
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-5, atol=2e-6,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("spelling", ["program", "scatter_add", "one_gather_sum", "inverse_by_scatter"])
+def test_moe_combine_bench_runs_a_reading(spelling):
+    """``tools/moe_combine_bench.py`` (PERF.md reads its chip runs) at a toy
+    shape: the program's combine and each yardstick beside it give the float32
+    sum to bf16's rounding. The times mean nothing here; ``main`` refuses to
+    run off a chip."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "..", "tools", "moe_combine_bench.py")
+    spec = importlib.util.spec_from_file_location("moe_combine_bench", path)
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    got = bench.measure("toy", spelling, bench.operands((96, 4, 128, 8), 2 ** 31 + 5), repeats=1)
+    assert (got["T"], got["k"], got["M"]) == (96, 4, 128)
+    rounds = 1 if spelling != "scatter_add" else 8  # a rounding a product and an add there
+    assert got["max_abs_err"] <= rounds * 2 ** -8 * got["max_abs_ref"]
+    assert {name: shape[:3] for name, shape in bench.SHAPES.items()} == {
+        "xing": (16384, 4, 3584), "glm": (16384, 4, 2048)}
+
+
 def test_init_inference_generate_tp():
     """init_inference over a tp=2 mesh: generate matches the no-cache greedy
     baseline (TP sharding must not change results)."""

@@ -394,10 +394,13 @@ def test_gpt_neox_programs_are_the_parents(name):
 @pytest.mark.parametrize("name", ["step", "chain"])
 def test_glm4_moe_lite_programs_are_the_parents(name):
     """The routed, latent toy's two programs, picks and all, against the census
-    recorded on PR 34's commit (``step``) and PR 36's (``chain``: the carry
-    handed back, as above): what PR 35 added for EVA attention is a branch at
-    trace time and reaches neither."""
+    recorded on PR 40's commit (``step``: the toy's prefill takes the ragged
+    path, whose combine became k gathers and a sum where it was a scatter-add,
+    and whose sort is inverted by a second sort) and PR 36's (``chain``: the
+    carry handed back, as above): what PR 35 added for EVA attention is a
+    branch at trace time and reaches neither."""
     pool, programs = _programs(config_from_hf(TOY), with_picks=True)
     assert pool.v is None  # the latent pool
-    with open(os.path.join(os.path.dirname(__file__), "data", "glm4_moe_lite_programs_at_pr36.json")) as f:
+    recorded = {"step": "glm4_moe_lite_programs_at_pr40.json", "chain": "glm4_moe_lite_programs_at_pr36.json"}[name]
+    with open(os.path.join(os.path.dirname(__file__), "data", recorded)) as f:
         _same_as_recorded(programs[name], json.load(f)[name])

@@ -207,3 +207,53 @@ def test_what_a_trained_layer_makes_again_carries_its_sub_layer_s_name(train_nam
     again = remade(op_names(grad.lower(shapes_of(llama)).compile().as_text()))
     assert {sublayers.label(n) for n in again} == {"layers/attn_norm", "layers/mlp_norm", "layers/mlp", "final_norm"}
     assert any(n.endswith("mlp/checkpoint/rematted_computation/jit(silu)/exp") for n in again)
+
+
+def test_the_routed_prefill_s_two_halves_have_names_of_their_own(serving):
+    """PR 40: around its grouped matmuls the ragged path gathers twice, and a
+    trace tells the two apart by name: ``moe_dispatch`` (the sort, the group
+    sizes, the tokens' rows into expert order) and ``moe_combine`` (the sort's
+    inverse, each token's k rows back, the sum), both under ``moe_experts``,
+    the matmuls and the activation directly under it. Nothing under ``moe_combine`` scatters. A
+    chain takes the dense path and has neither name."""
+    architecture, _, names = serving
+    halves = {half: {n for n in names["step"] if half in sublayers.components(n)}
+              for half in ("moe_dispatch", "moe_combine")}
+    if architecture != "glm4_moe_lite":
+        assert not halves["moe_dispatch"] and not halves["moe_combine"]
+        return
+    for half, mine in halves.items():
+        assert mine and all(sublayers.components(n)[sublayers.components(n).index(half) - 1] == "moe_experts"
+                            for n in mine), (half, mine)
+        assert any(n.endswith("/gather") for n in mine), (half, mine)
+    assert any(n.endswith("/sort") for n in halves["moe_combine"])  # the inverse permutation
+    assert not [n for n in halves["moe_combine"] if "scatter" in n.rpartition("/")[2]]
+    between = [n for n in names["step"] if sublayers.components(n)[-2:-1] == ["moe_experts"]]
+    assert between  # the grouped matmuls and the activation (the CPU lowers ``ragged_dot`` to plain ops)
+    assert not [n for n in names["chain"] if set(sublayers.components(n)) & set(halves)]
+
+
+def test_the_tool_prints_the_two_halves():
+    """``tools/traced_cell.py::moe_halves`` on rows as ``hlo_stats`` gives them:
+    a half's seconds, its layer-calls (its largest instruction's count) and its
+    largest instructions; an instruction under neither name is left out."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location("traced_cell", os.path.join(
+        os.path.dirname(__file__), "..", "..", "..", "tools", "traced_cell.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    row = lambda name, path, us, n: {"hlo_op_name": name, "tf_op_name": path + ":", "total_self_time": us,  # noqa: E731
+                                     "occurrences": n, "hlo_op_expression": f"%{name} = bf16[8,8] fusion()"}
+    lines = list(tool.moe_halves([
+        row("fusion.1", "jit(step)/layer/moe/moe_experts/moe_combine/gather", 30000.0, 30),
+        row("fusion.2", "jit(step)/layer/moe/moe_experts/moe_combine/convert_element_type", 15000.0, 30),
+        row("fusion.3", "jit(step)/layer/moe/moe_experts/moe_dispatch/gather", 90000.0, 30),
+        row("gmm.1", "jit(step)/layer/moe/moe_experts/jit(gmm)/pallas_call", 99000.0, 30)]))
+    assert lines[0].startswith("moe_half=moe_dispatch device_s=0.09 layer_calls=30 ms_a_layer_call=3.0")
+    assert [line.split()[1] for line in lines if line.startswith("moe_half_op=moe_combine")] == [
+        "instruction=fusion.1", "instruction=fusion.2"]
+    assert "moe_half=moe_combine device_s=0.045 layer_calls=30 ms_a_layer_call=1.5" in lines[2]
+    assert not [line for line in lines if "gmm.1" in line]
+

@@ -13,9 +13,15 @@
   says which two pinned counts keep them off the lists), so their readings
   in ``PERF.md`` section 5 are this tool's and no driver's run checks them;
 - in a cell of a routed, latent-attention architecture that is not listed for
-  them, the five readers of ``benchmarks/lib/routed.py`` (``ROUTED``) likewise.
+  them, the five readers of ``benchmarks/lib/routed.py`` (``ROUTED``) likewise;
+- in a cell with a routed prefill, the two halves around its grouped matmuls
+  by the scopes PR 40 gave them (``moe_dispatch``, ``moe_combine`` under
+  ``moe_experts``): a ``moe_half=`` line each (device seconds, layer-calls,
+  ms a layer-call) and a ``moe_half_op=`` line for each of its five largest
+  instructions, which the ten ``named_op=`` lines of a run are too few to
+  reach. No metric reads the two names; ``PERF.md`` section 5 reads these.
 
-Both are wrapped OUTSIDE the benchmark, before ``run.main`` runs; nothing
+All are wrapped OUTSIDE the benchmark, before ``run.main`` runs; nothing
 here is read by the program or the benchmark, and a cell's listed metrics
 read what they read without it.
 """
@@ -32,6 +38,21 @@ SERVING = ("layer_matmul_time_share.batch", "layer_matmul_roofline.batch", "unna
 # the glm cell's own only while its ``workloads`` is that cell alone; PERF.md, section 7)
 ROUTED = ("moe_time_share.batch", "moe_experts_roofline.batch", "moe_experts_touched.batch",
           "mla_paged_time_share.batch", "mla_paged_roofline.batch")
+MOE_HALVES = ("moe_dispatch", "moe_combine")
+
+
+def moe_halves(rows):
+    """The ``moe_half=`` and ``moe_half_op=`` lines of ``hlo_stats``' rows."""
+    for half in MOE_HALVES:
+        mine = sorted((r for r in rows if half in r["tf_op_name"].rstrip(":").split("/")),
+                      key=lambda r: -float(r["total_self_time"]))
+        if mine:
+            seconds, calls = 1e-6 * sum(float(r["total_self_time"]) for r in mine), int(mine[0]["occurrences"])
+            yield (f"moe_half={half} device_s={seconds} layer_calls={calls} "
+                   f"ms_a_layer_call={1e3 * seconds / calls} instructions={len(mine)}")
+        for r in mine[:5]:
+            yield (f"moe_half_op={half} instruction={r['hlo_op_name']} device_s={1e-6 * float(r['total_self_time'])} "
+                   f"count={r['occurrences']} op_name={r['tf_op_name']} expression={r['hlo_op_expression'][:300]}")
 
 
 def main(argv=None) -> int:
@@ -45,6 +66,8 @@ def main(argv=None) -> int:
         rows = hlo_stats(path)
         print(f"trace_cost= xplane_bytes={os.path.getsize(path)} "
               f"hlo_stats_s={time.perf_counter() - start:.3f} rows={len(rows)}", flush=True)
+        for line in moe_halves(rows):
+            print(line, flush=True)
         return rows
 
     def with_serving_readers(bench, group, workload_name):
