@@ -211,6 +211,56 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
             raise ValueError("glm4_moe_lite with rope_scaling is unsupported (type 'yarn' is taken for "
                              "xing4_0 alone)")
         return TransformerConfig(**kw)
+    if mt == "granitemoehybrid":
+        # a layer PATTERN (layer_types): Mamba-2 state-space mixers beside GQA
+        # attention with no positional term at all, every mixer followed by one
+        # dense silu-GLU (shared_intermediate_size), four scalar multipliers.
+        # The routed variants (num_local_experts > 0) are not built
+        refused = [(hf_config.get("num_local_experts", 0) > 0, "num_local_experts > 0 (routed experts beside "
+                    "the shared MLP)"),
+                   (hf_config.get("position_embedding_type", "nope") != "nope",
+                    f"position_embedding_type={hf_config.get('position_embedding_type')!r} (only 'nope')"),
+                   (bool(hf_config.get("rope_scaling")), "rope_scaling"),
+                   (bool(hf_config.get("attention_bias")) or bool(hf_config.get("mamba_proj_bias")),
+                    "attention_bias / mamba_proj_bias"),
+                   (not hf_config.get("mamba_conv_bias", True), "mamba_conv_bias=false"),
+                   (hf_config.get("hidden_act", "silu") != "silu", f"hidden_act={hf_config.get('hidden_act')!r}"),
+                   (hf_config.get("normalization_function", "rmsnorm") != "rmsnorm",
+                    f"normalization_function={hf_config.get('normalization_function')!r}"),
+                   (hf_config["mamba_n_heads"] * hf_config["mamba_d_head"]
+                    != hf_config["mamba_expand"] * hf_config["hidden_size"],
+                    "mamba_n_heads x mamba_d_head != mamba_expand x hidden_size")]
+        refused = [what for bad, what in refused if bad]
+        if refused:
+            raise ValueError("granitemoehybrid with " + "; ".join(refused) + " is unsupported")
+        from deepspeed_tpu.models.transformer import SSMConfig
+
+        dtype = hf_config.get("dtype", hf_config.get("torch_dtype"))
+        return TransformerConfig(
+            vocab_size=hf_config["vocab_size"],
+            hidden_size=hf_config["hidden_size"],
+            intermediate_size=hf_config.get("shared_intermediate_size", hf_config["intermediate_size"]),
+            num_layers=hf_config["num_hidden_layers"],
+            num_heads=hf_config["num_attention_heads"],
+            num_kv_heads=hf_config.get("num_key_value_heads"),
+            max_seq_len=hf_config.get("max_position_embeddings", 131072),
+            norm="rmsnorm",
+            activation="silu_glu",
+            position="none",
+            norm_eps=float(hf_config.get("rms_norm_eps", 1e-5)),
+            tie_embeddings=bool(hf_config.get("tie_word_embeddings", True)),
+            qkv_bias=False,
+            layer_types=tuple(hf_config["layer_types"]),
+            ssm=SSMConfig(n_heads=hf_config["mamba_n_heads"], head_dim=hf_config["mamba_d_head"],
+                          d_state=hf_config["mamba_d_state"], n_groups=hf_config.get("mamba_n_groups", 1),
+                          d_conv=hf_config.get("mamba_d_conv", 4),
+                          chunk_size=hf_config.get("mamba_chunk_size", 256)),
+            embedding_multiplier=float(hf_config.get("embedding_multiplier", 1.0)),
+            attention_multiplier=float(hf_config["attention_multiplier"]),
+            residual_multiplier=float(hf_config.get("residual_multiplier", 1.0)),
+            logits_scaling=float(hf_config.get("logits_scaling", 1.0)),
+            param_dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(dtype, jnp.float32),
+        )
     if mt == "opt":
         if not hf_config.get("do_layer_norm_before", True):
             raise ValueError("OPT post-layernorm variants (do_layer_norm_before=false) are unsupported")
@@ -410,7 +460,7 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
     raise ValueError(
         f"unsupported HF model_type {mt!r} (supported: llama/mistral/mixtral/"
         "qwen2/gpt2/opt/falcon/phi/gpt_neox/bloom/gptj/codegen/gpt_bigcode/"
-        "glm4_moe_lite/evabyte/xing4_0)")
+        "glm4_moe_lite/evabyte/xing4_0/granitemoehybrid)")
 
 
 def detect_family(state: Dict[str, np.ndarray]) -> str:

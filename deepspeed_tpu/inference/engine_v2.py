@@ -47,6 +47,7 @@ from deepspeed_tpu.config.config_utils import DeepSpeedConfigModel
 from deepspeed_tpu.inference.config import QuantConfig, ServingSLOConfig
 from deepspeed_tpu.inference.lifecycle import LifecycleTracker
 from deepspeed_tpu.inference.paged import (
+    HybridPools,
     MigrationBuffer,
     PagedKVPool,
     copy_pool_blocks,
@@ -340,6 +341,23 @@ class InferenceEngineV2:
                 "is a statistic and a product over the whole hidden width of every stream, which the "
                 "partition rules replicate and nothing has needed split yet")
 
+        # Recurrent state beside attention (a layer pattern with state-space
+        # layers): a sequence holds, beside its pages, ONE slot of a state pool
+        # (paged.StatePool) from its first token to its flush
+        self._hybrid = model_config.ssm_layers > 0
+        if self._hybrid:
+            missing = [
+                (config.prefix_cache, "prefix_cache: a prefix's pages without the recurrent state at its "
+                 "end are not a prefix, and no state is kept per block"),
+                (config.spec_decode > 0, "spec_decode: a rejected draft has already moved the state, and "
+                 "nothing rolls it back"),
+                (mesh.shape["tp"] > 1, f"tp={mesh.shape['tp']}: the state pool and the mixer's "
+                 "projections are not partitioned over heads"),
+            ]
+            missing = [what for bad, what in missing if bad]
+            if missing:
+                raise ValueError("recurrent state (state-space layers) does not serve with " + "; ".join(missing))
+
         from deepspeed_tpu.utils.hbm import kv_slot_bytes
 
         dtype = config.jax_dtype
@@ -349,7 +367,7 @@ class InferenceEngineV2:
         # The real (quantized or dense) per-token pool cost — ONE formula
         # shared with the pre-flight guard and the capacity benchmark.
         self.kv_bytes_per_token = kv_slot_bytes(
-            model_config.num_layers, model_config.kv_heads,
+            model_config.attention_layers, model_config.kv_heads,
             model_config.dims_per_head, kv_dtype_b, kv_quant)
         # a routed model's programs hand out the experts they sent each token
         # to, as one more output (fetched only by *_with_picks)
@@ -376,7 +394,8 @@ class InferenceEngineV2:
             num_blocks = config.num_kv_blocks
         self.num_kv_blocks = num_blocks
         self.state = StateManager(num_blocks, config.kv_block_size, config.max_seqs,
-                                  max_blocks_per_seq=self.max_pages, layout=self._layout)
+                                  max_blocks_per_seq=self.max_pages, layout=self._layout,
+                                  state_slots=config.max_seqs if self._hybrid else None)
         self._staging = BatchStaging(self.max_pages)
         self.prefix_cache: Optional[PrefixCache] = None
         if config.prefix_cache:
@@ -445,6 +464,10 @@ class InferenceEngineV2:
                     2 * model_config.hidden_size * 4
                     + 4 * model_config.num_heads * model_config.dims_per_head * dtype_b
                     + 2 * model_config.intermediate_size * dtype_b)
+            if self._hybrid:  # a slot a sequence a state-space layer: the float32 state and the conv tail
+                sizes = model_config.ssm
+                kv_bytes += config.max_seqs * model_config.ssm_layers * (
+                    sizes.d_inner * sizes.d_state * 4 + (sizes.d_conv - 1) * sizes.conv_dim * dtype_b)
             need = (param_bytes
                     + kv_bytes // (tp if kv_on_tp else 1)
                     + config.row_bucket * model_config.vocab_size * 4
@@ -498,6 +521,12 @@ class InferenceEngineV2:
             v=None if pool.v is None else jax.device_put(pool.v, kv_spec),
             k_scale=None if pool.k_scale is None else jax.device_put(pool.k_scale, replicated),
             v_scale=None if pool.v_scale is None else jax.device_put(pool.v_scale, replicated))
+        self.state_pool = None
+        if self._hybrid:
+            from deepspeed_tpu.inference.paged import init_state_pool
+
+            # (every sequence a slot: as many as seats, no new engine key)
+            self.state_pool = jax.device_put(init_state_pool(model_config, config.max_seqs, dtype), replicated)
         log_dist(
             f"InferenceEngineV2: {n_params/1e6:.1f}M params, "
             f"{num_blocks}x{config.kv_block_size} KV slots "
@@ -543,6 +572,32 @@ class InferenceEngineV2:
         self.cow_copies = 0            # copy-on-write block clones dispatched
         self.spec_model_steps = 0      # model forwards inside spec chains
         self.spec_tokens_emitted = 0   # tokens those forwards emitted
+
+    @property
+    def _pools(self):
+        """What the step programs take in the pool's place, donated, and hand
+        back: the page pool, with the state pool where the model has one."""
+        return self.pool if self.state_pool is None else HybridPools(self.pool, self.state_pool)
+
+    @_pools.setter
+    def _pools(self, pools) -> None:
+        if self.state_pool is None:
+            self.pool = pools
+        else:
+            self.pool, self.state_pool = pools
+
+    def _state_args(self, rows: int) -> Dict[str, int]:
+        """For a ``serve:dispatch`` span of a model with recurrent state, while
+        somebody records spans: ``state_rows``, the live rows x steps whose
+        state slots the call updates (a chain's as its budgets plan it)."""
+        if self.state_pool is None or not self._tracer.recording():
+            return {}
+        return {"state_rows": int(rows)}
+
+    @staticmethod
+    def _rows_at(a, at) -> np.ndarray:
+        """A program's padded output on the host, cut to the rows ``at``."""
+        return np.asarray(a[at]) if isinstance(at, slice) else np.asarray(a)[at]
 
     # ---------------------------------------------------------------- admission
     def query(self, uid: int) -> Tuple[int, int]:
@@ -887,6 +942,10 @@ class InferenceEngineV2:
             raise ValueError(
                 "KV-block migration of an EVA model: the wire format carries pages in position "
                 "order and knows one kind of row; summary and window pages are not told apart")
+        if self._hybrid:
+            raise ValueError(
+                "KV-block migration of a model with recurrent state: the wire format carries pages and "
+                "knows no state slot; a request's state would stay behind")
         seq = self.state.get(uid)
         if seq is None or seq.n_blocks == 0:
             raise ValueError(f"uid {uid} has no KV blocks to export")
@@ -924,6 +983,10 @@ class InferenceEngineV2:
         destination state unchanged — when capacity refuses; raises on a
         layout mismatch (pools that disagree on dtype/geometry are a
         deployment error, not a capacity condition)."""
+        if self._hybrid:
+            raise ValueError(
+                "KV-block migration into a model with recurrent state: the wire format carries pages and "
+                "knows no state slot")
         if export["block_size"] != self.config.kv_block_size or \
                 export["quant"] != self.pool.quant or \
                 export["kv_dtype"] != str(jnp.dtype(self.pool.k.dtype)):
@@ -1040,9 +1103,10 @@ class InferenceEngineV2:
         batch = self._build_batch(uids, token_lists)
         step = self._step_fn(batch.n_rows, batch.tokens.shape[1])
         with self._tracer.span("serve:dispatch", kind="put", rows=batch.n_rows,
-                               **self._eva_args(batch.positions, batch.new_lens)):
-            logits, self.pool, *picks = step(
-                self.params, self.pool,
+                               **self._eva_args(batch.positions, batch.new_lens),
+                               **self._state_args(len(uids))):
+            logits, self._pools, *picks = step(
+                self.params, self._pools,
                 jnp.asarray(batch.tokens), jnp.asarray(batch.positions),
                 jnp.asarray(batch.new_lens), jnp.asarray(batch.block_tables),
             )
@@ -1050,7 +1114,7 @@ class InferenceEngineV2:
         self._log_picks(picks, uids, None, token_lists)
         self.windows_closed += self._advance(uids, map(len, token_lists))
         self.host_sync_count += 1
-        return np.asarray(logits[: len(uids)])
+        return self._rows_at(logits, batch.at)
 
     def _log_picks(self, picks, uids, rids, token_lists=None, flight=None, emitted=None) -> None:
         """While somebody asked (``picks_log`` is a list), note one dispatch's
@@ -1133,11 +1197,12 @@ class InferenceEngineV2:
         with self._tracer.span("serve:dispatch", kind="prefill", rows=batch.n_rows,
                                live=len(uids), tokens=int(batch.new_lens.sum()),
                                rids=self._span_rids(rids),
-                               **self._eva_args(batch.positions, batch.new_lens)):
+                               **self._eva_args(batch.positions, batch.new_lens),
+                               **self._state_args(len(uids))):
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "prefill")
-            toks, rng, self.pool, *picks = step(
-                self.params, self.pool,
+            toks, rng, self._pools, *picks = step(
+                self.params, self._pools,
                 jnp.asarray(batch.tokens), jnp.asarray(batch.positions),
                 jnp.asarray(batch.new_lens), jnp.asarray(batch.block_tables),
                 rng,
@@ -1146,7 +1211,7 @@ class InferenceEngineV2:
         self._log_picks(picks, uids, rids, token_lists)
         self.windows_closed += self._advance(uids, map(len, token_lists))
         with self._tracer.span("serve:fetch", kind="prefill"):
-            out = np.asarray(toks[: len(uids)])
+            out = self._rows_at(toks, batch.at)
         self.host_sync_count += 1
         return out, rng
 
@@ -1210,7 +1275,8 @@ class InferenceEngineV2:
                 start[:, None] + np.arange(k)[None, :], share)
         chain = self._chain_fn(n_rows, k, eos_id, sample_kw)
         with self._tracer.span("serve:dispatch", kind="chain", rows=n_rows, live=len(uids),
-                               k=k, chain=chain_id, ahead=int(ahead), **eva_args):
+                               k=k, chain=chain_id, ahead=int(ahead), **eva_args,
+                               **self._state_args(share.sum())):
             dispatched_at = time.perf_counter()
             if ahead:
                 tokens, pos, active = before.carry
@@ -1220,8 +1286,8 @@ class InferenceEngineV2:
                     tracker.mark_dispatch(rids, "chain", now=dispatched_at)
                 tokens, pos, tables, active, chain_budgets = self._place(
                     buf, "tokens", "pos", "tables", "active", "budgets")
-            out, emitted, active, tok, pos, rng_out, self.pool, *routed = chain(
-                self.params, self.pool, tokens, pos, tables, active, chain_budgets, rng)
+            out, emitted, active, tok, pos, rng_out, self._pools, *routed = chain(
+                self.params, self._pools, tokens, pos, tables, active, chain_budgets, rng)
         self.dispatch_count += 1
         if ahead:
             self.chains_ahead += 1
@@ -1239,8 +1305,8 @@ class InferenceEngineV2:
         k = flight.k
         keep = budgets > k  # the rows that outlast this chain
         n = int(keep.sum())
-        bucket = self.config.row_bucket
-        if n == 0 or -(-n // bucket) * bucket != flight.n_rows:
+        if n == 0 or self.state.rows_of(np.asarray(flight.uids)[keep].tolist(),
+                                        self.config.row_bucket)[1] != flight.n_rows:
             return None  # nothing to decode, or a smaller program would
         # Such a row emits exactly k tokens in ``flight``, or ends in it at an
         # EOS and rides the next chain dead: where it stands afterwards, its
@@ -1322,10 +1388,9 @@ class InferenceEngineV2:
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "chain", now=flight.dispatched_at)
         else:
-            n = len(uids)
-            rows = -(-n // self.config.row_bucket) * self.config.row_bucket
+            at, rows = self.state.rows_of(uids, self.config.row_bucket)
             flight = self._dispatch_chain(
-                uids, np.arange(n), rows, budgets, k, rng, eos_id, sample_kw,
+                uids, at, rows, budgets, k, rng, eos_id, sample_kw,
                 self.chain_steps,  # shared by every span of this chain
                 last_tokens=last_tokens, tracker=tracker, rids=rids)
         if ahead:

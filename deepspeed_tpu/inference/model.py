@@ -31,6 +31,7 @@ from deepspeed_tpu.models.transformer import (
     _apply_norm,
     _embed_tokens,
     _norm_at,
+    _times,
     act_fn,
     reading,
 )
@@ -72,6 +73,12 @@ def init_cache(
             "hyper-connections (hc_mult > 0) and latent attention (kv_lora_rank > 0) in the v1 engine: "
             "its block step is the one-stream residual over plain keys and values; serve them "
             "through InferenceEngineV2, whose paged path reads ops/mhc.py and the latent pool")
+    if cfg.layer_types is not None or (cfg.residual_multiplier, cfg.attention_multiplier) != (1.0, None):
+        raise NotImplementedError(
+            "a layer pattern (layer_types) or a residual/attention multiplier in the v1 engine: its cache is "
+            "a row a position a layer and its block step one kind of layer with plain adds and scores; a "
+            "state-space layer keeps a recurrent state a sequence. Serve it through InferenceEngineV2, "
+            "which holds a state pool beside its page pool")
     hd = cfg.dims_per_head
     shape = (cfg.num_layers, batch_size, max_len, cfg.kv_heads, hd)
     return KVCache(
@@ -590,7 +597,9 @@ def _layer_stack(params, cfg, x, cache: KVCache, positions, write_start, kv_mask
 def _logits(params, cfg: TransformerConfig, x):
     x = _norm_at(params, "final_norm", cfg, x)
     if cfg.tie_embeddings:
-        return x @ params["embed"]["embedding"].T.astype(cfg.dtype)
+        return _times(1.0 / cfg.logits_scaling, x @ params["embed"]["embedding"].T.astype(cfg.dtype))
+    if cfg.logits_scaling != 1.0:
+        raise NotImplementedError("logits_scaling with an untied head: no model has needed it")
     if cfg.num_pred_heads > 1:
         # head 0 (the next token's) of a [hidden, heads * vocab] kernel, fp32 logits
         head = params["lm_head"]["kernel"][:, :cfg.vocab_size].astype(cfg.dtype)
@@ -617,7 +626,7 @@ def decode_inputs(params, cfg: TransformerConfig, cache: KVCache, tokens):
     """Shared pre-layer computation of the decode path: next-token embedding
     (in cfg.dtype), positions, and the kv_mask with the new slot marked."""
     positions = cache.lengths[:, None]  # [B,1]
-    x = jnp.take(params["embed"]["embedding"], tokens[:, None], axis=0).astype(cfg.dtype)
+    x = _times(cfg.embedding_multiplier, jnp.take(params["embed"]["embedding"], tokens[:, None], axis=0).astype(cfg.dtype))
     if cfg.embed_norm:
         x = _apply_norm(params["embed_norm"], cfg, x)
     if cfg.position == "learned":

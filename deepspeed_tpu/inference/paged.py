@@ -36,8 +36,8 @@ import jax.numpy as jnp
 from deepspeed_tpu.inference.model import (_apply_norm, _attn_out, _dense, _logits, _mlp,
                                            _moe_with_picks, _qkv)
 from deepspeed_tpu.inference.sampling import greedy_tokens, sample_logits
-from deepspeed_tpu.models.transformer import TransformerConfig, _norm_at, reading
-from deepspeed_tpu.ops import mhc
+from deepspeed_tpu.models.transformer import TransformerConfig, _norm_at, _times, reading
+from deepspeed_tpu.ops import mhc, ssm
 
 
 class PagedKVPool(NamedTuple):
@@ -88,6 +88,45 @@ class PagedKVPool(NamedTuple):
         return "fp8" if self.k.dtype == jnp.float8_e4m3fn else "int8"
 
 
+class StatePool(NamedTuple):
+    """What the state-space layers of a layer pattern (``TransformerConfig.
+    layer_types``) keep of a sequence, beside the page pool that its attention
+    layers write: not a row a token but ONE slot a sequence a layer, whatever
+    its length. ``ssm`` ``[state-space layers, slots, H P / 128, N, 128]``
+    float32 is the recurrent state, a tile 128 of a layer's ``H P`` channels on
+    the lanes and the state's ``N`` on the sublanes (``ops/ssm.py::to_pool``: the
+    layout the decode kernel reads and writes as it is), ``conv`` ``[state-space layers, slots, (d_conv - 1)
+    x (H P + 2 G N)]`` the convolution's last inputs, one lane-dense row a slot
+    (``ops/ssm.py``; with a dimension of 3 of its own the chip's compiler laid
+    it out 3-minor, padded to 128 lanes, and copied it: 2.4 GB). State-space
+    layer ``s`` (counted among its kind) owns row ``s``; a sequence owns slot
+    ``i`` of every row from its first token to its flush (``ragged.
+    StateManager``), and a program's ROW ``i`` is slot ``i``: a layer reads and
+    writes the first ``rows`` slots of its row of the pool as ONE slice, in
+    place, and never gathers or scatters by sequence. A slot is not cleared
+    when it changes hands: a row fed from position 0 starts from zeros."""
+
+    ssm: jax.Array
+    conv: jax.Array
+
+
+class HybridPools(NamedTuple):
+    """What the serving programs of a model with state-space layers are handed
+    in the pool's place, donated, and hand back: the page pool of its attention
+    layers and the state pool of the others."""
+
+    kv: "PagedKVPool"
+    state: StatePool
+
+
+def init_state_pool(cfg: TransformerConfig, slots: int, dtype: Any = jnp.bfloat16) -> StatePool:
+    sizes = cfg.ssm
+    tile = ssm.pool_tile(sizes.d_inner)
+    return StatePool(
+        ssm=jnp.zeros((cfg.ssm_layers, slots, sizes.d_inner // tile, sizes.d_state, tile), jnp.float32),
+        conv=jnp.zeros((cfg.ssm_layers, slots, (sizes.d_conv - 1) * sizes.conv_dim), dtype))
+
+
 _KV_QUANT_DTYPES = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
 _LANES = 128
 
@@ -112,7 +151,8 @@ def init_pool(
                 "form (one scale a token a layer is not carried); use a bf16/fp32 pool")
         return PagedKVPool(k=jnp.zeros(
             (cfg.num_layers * num_blocks, block_size, latent_pool_width(cfg)), dtype))
-    shape = (cfg.num_layers * num_blocks, block_size, cfg.kv_heads * cfg.dims_per_head)
+    # (of a layer pattern, the attention layers alone hold pages)
+    shape = (cfg.attention_layers * num_blocks, block_size, cfg.kv_heads * cfg.dims_per_head)
     if kv_quant is None:
         return PagedKVPool(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
     if kv_quant not in _KV_QUANT_DTYPES:
@@ -500,7 +540,10 @@ def _forward_hidden(
     """
     N, C = tokens.shape
     bs = block_size
-    L = cfg.num_layers
+    state = None
+    if isinstance(pool, HybridPools):
+        pool, state = pool
+    L = cfg.attention_layers  # the layers that hold pages: all, but in a layer pattern
     NB = pool.k.shape[0] // L
     valid = jnp.arange(C)[None, :] < new_lens[:, None]  # [N, C]
     eva = cfg.eva_window > 0
@@ -512,8 +555,8 @@ def _forward_hidden(
         w_slot = (positions % bs).reshape(-1)
 
     with jax.named_scope("embed"):
-        x = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(
-            jnp.float32 if cfg.fp32_residual else cfg.dtype)
+        x = _times(cfg.embedding_multiplier, jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(
+            jnp.float32 if cfg.fp32_residual else cfg.dtype))
         if cfg.embed_norm:
             x = _apply_norm(params["embed_norm"], cfg, x)
         if cfg.position == "learned":
@@ -565,6 +608,9 @@ def _forward_hidden(
             with jax.named_scope("rope"):
                 q, k = apply_qk_rope(cfg, q, k, positions)
         kvH, hd = k.shape[-2], k.shape[-1]
+        if cfg.attention_multiplier is not None:
+            # the paged kernels scale the scores by hd^-0.5 themselves: the rest goes into q
+            q = _times(cfg.attention_multiplier * hd ** 0.5, q)
         with jax.named_scope("kv_write"):
             if quant is not None:
                 # quantized KV write: the same one-scatter-per-array shape,
@@ -635,9 +681,48 @@ def _forward_hidden(
             # gpt-neox-style (parallel_mlp_norm): FFN reads its own ln2(x)
             out, picks = ffn(lp, _norm_at(lp, "mlp_norm", cfg, x) if cfg.parallel_mlp_norm else h, dense)
             return (x + attn_out + out, pk, pv, psk, psv), picks
-        x = x + attn_out
+        x = x + _times(cfg.residual_multiplier, attn_out)
         out, picks = ffn(lp, _norm_at(lp, "mlp_norm", cfg, x), dense)
-        return (x + out, pk, pv, psk, psv), picks
+        return (x + _times(cfg.residual_multiplier, out), pk, pv, psk, psv), picks
+
+    if state is not None:
+        # a row fed from position 0 starts a sequence: whatever its slot holds is another's
+        fresh = (positions[:, 0] == 0) & (new_lens > 0)
+
+    @jax.named_scope("layer")
+    def ssm_layer(carry, lp, s):
+        """A state-space layer, the ``s``-th of its kind: its mixer reads and
+        writes row ``s`` of the state pool, in place (``StatePool``)."""
+        x, *kv, sp, cp = carry
+        h = _norm_at(lp, "ssm_pre_norm", cfg, x)
+        with reading(lp, "ssm") as mp:
+            zxbcdt = _dense(mp, "ssm_in_proj", cfg, h)
+            tail = jax.lax.dynamic_slice(cp, (s, 0, 0), (1, N, cp.shape[2]))[0]
+            tail = jnp.where(fresh[:, None], 0, tail).reshape(N, cfg.ssm.d_conv - 1, -1)
+            y, sp, tail = ssm.mix(zxbcdt, mp, cfg.ssm, cfg.norm_eps, state=ssm.PoolRow(sp, s, fresh), tail=tail,
+                                  new_lens=new_lens)
+            cp = jax.lax.dynamic_update_slice(cp, tail.astype(cp.dtype).reshape(1, N, -1), (s, 0, 0))
+            out = _dense(mp, "ssm_out_proj", cfg, y)
+        x = x + _times(cfg.residual_multiplier, out)
+        out, _ = ffn(lp, _norm_at(lp, "mlp_norm", cfg, x), False)
+        return (x + _times(cfg.residual_multiplier, out), *kv, sp, cp)
+
+    def period(carry, xs):
+        """One period of a layer pattern, its layers unrolled: attention layer
+        ``a`` (counted among its kind) has the pages from ``a * NB``, state-space
+        layer ``s`` row ``s`` of the state pool."""
+        pp, first_a, first_s = xs
+        a = s = 0
+        for j, kind in enumerate(cfg.period):
+            lp = pp[f"layer_{j}"]
+            if kind == "mamba":
+                carry = ssm_layer(carry, lp, first_s + s)
+                s += 1
+            else:
+                (x, *kv), _ = layer(carry[:5], lp, (first_a + a) * NB)
+                carry = (x, *kv, *carry[5:])
+                a += 1
+        return carry, None
 
     carry = (x, *pool)
     for i in range(D):
@@ -645,10 +730,20 @@ def _forward_hidden(
         # pages (layer i's), outside the scan
         carry, _ = layer(carry, params[f"dense_{i}"], jnp.int32(i * NB), dense=True)
     with jax.named_scope("pool_scan"):
-        (x, *pool), picks = jax.lax.scan(
-            lambda c, xs: layer(c, *xs), carry,
-            (params["layers"], jnp.arange(D, L, dtype=jnp.int32) * NB))
-    pool = PagedKVPool(*pool)
+        if cfg.layer_types is not None:
+            kinds = cfg.period
+            periods = jnp.arange(cfg.num_layers // len(kinds), dtype=jnp.int32)
+            (x, *pool), picks = jax.lax.scan(
+                period, carry + tuple(state or ()),
+                (params["layers"], periods * kinds.count("attention"), periods * kinds.count("mamba")))
+        else:
+            (x, *pool), picks = jax.lax.scan(
+                lambda c, xs: layer(c, *xs), carry,
+                (params["layers"], jnp.arange(D, L, dtype=jnp.int32) * NB))
+    if state is not None:
+        pool = HybridPools(PagedKVPool(*pool[:4]), StatePool(*pool[4:]))
+    else:
+        pool = PagedKVPool(*pool)
     if cfg.hc_mult:
         x = mhc.collapse(x)  # the streams summed, before the last-token selection and the head
     # picks: [routed layers, N*C, k] -> [N, C, routed layers, k]

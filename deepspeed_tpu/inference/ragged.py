@@ -28,7 +28,8 @@ step), everything here is O(1)-per-item and vectorized:
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+import heapq
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -234,6 +235,9 @@ class SequenceDescriptor:
     layout: Optional[WindowLayout] = None
     n_summary: int = 0
     n_window: int = 0
+    # a model with recurrent state: the sequence's slot of the state pool
+    # (``paged.StatePool``), which is also its ROW in every program it is fed to
+    slot: Optional[int] = None
 
     @property
     def blocks(self) -> np.ndarray:
@@ -291,13 +295,19 @@ class StateManager:
 
     def __init__(self, num_blocks: int, block_size: int, max_seqs: int = 256,
                  max_blocks_per_seq: Optional[int] = None,
-                 layout: Optional[WindowLayout] = None):
+                 layout: Optional[WindowLayout] = None, state_slots: Optional[int] = None):
         self.allocator = BlockedAllocator(num_blocks)
         self.block_size = block_size
         self.max_seqs = max_seqs
         self.max_blocks_per_seq = max_blocks_per_seq
         self.layout = layout  # None: a row's page is position // block_size
         self._seqs: Dict[int, SequenceDescriptor] = {}
+        # Recurrent state (``paged.StatePool``): a sequence takes the LOWEST free
+        # slot with its descriptor and gives it back at its flush, finished or
+        # preempted. Its slot is its row in every program, so the lowest keeps
+        # the programs' row buckets small. None: the model keeps no such state.
+        self.state_slots = state_slots
+        self._free_slots: Optional[List[int]] = None if state_slots is None else list(range(state_slots))
 
     @property
     def n_active(self) -> int:
@@ -322,9 +332,29 @@ class StateManager:
             if len(self._seqs) >= self.max_seqs:
                 raise RuntimeError(f"max_seqs={self.max_seqs} active sequences reached")
             cap = self.layout.width if self.layout else self.max_blocks_per_seq or 8
+            slot = None
+            if self._free_slots is not None:
+                if not self._free_slots:
+                    raise RuntimeError(f"no free state slot: all {self.state_slots} hold a sequence")
+                slot = heapq.heappop(self._free_slots)
             self._seqs[uid] = SequenceDescriptor(uid, _table=np.zeros((cap,), np.int32),
-                                                 layout=self.layout)
+                                                 layout=self.layout, slot=slot)
         return self._seqs[uid]
+
+    @property
+    def state_slots_in_use(self) -> int:
+        return 0 if self._free_slots is None else self.state_slots - len(self._free_slots)
+
+    def rows_of(self, uids: Sequence[int], row_bucket: int) -> Tuple[np.ndarray, int]:
+        """(the program's row of each of ``uids``, the rows the program needs,
+        a multiple of ``row_bucket``). A sequence with a state slot is fed in
+        the row of that number, so that a state-space layer updates ONE slice of
+        the state pool; any other takes the rows in the order given."""
+        if self._free_slots is None:
+            at = np.arange(len(uids))
+        else:
+            at = np.fromiter((self._seqs[u].slot for u in uids), dtype=np.int64, count=len(uids))
+        return at, _round_up(int(at.max(initial=-1)) + 1, row_bucket)
 
     def _pages_short(self, seq: Optional[SequenceDescriptor], new_tokens: int) -> Tuple[int, int, int]:
         """Under a layout: (pages to allocate, summary pages, window pages)
@@ -345,7 +375,7 @@ class StateManager:
                     return False  # sequence would exceed engine max_seq_len
                 need += self._pages_short(seq, n)[0]
             return (len(self._seqs) + fresh <= self.max_seqs
-                    and need <= self.allocator.free_blocks)
+                    and need <= self.allocator.free_blocks)  # (no layout has state slots)
         for uid, n in zip(uids, token_counts):
             seq = self._seqs.get(uid)
             if seq is None:
@@ -359,6 +389,8 @@ class StateManager:
                 return False  # sequence would exceed engine max_seq_len
         if len(self._seqs) + fresh > self.max_seqs:
             return False
+        if self._free_slots is not None and fresh > len(self._free_slots):
+            return False  # pages for it, and no state slot
         return need <= self.allocator.free_blocks
 
     def extend(self, uid: int, new_tokens: int) -> SequenceDescriptor:
@@ -397,6 +429,8 @@ class StateManager:
         seq = self._seqs.pop(uid, None)
         if seq is not None and seq.n_blocks:
             self.allocator.release(seq.blocks)
+        if seq is not None and seq.slot is not None:
+            heapq.heappush(self._free_slots, seq.slot)  # as it stands: the next sequence starts from zeros
 
 
 # --------------------------------------------------------------- prefix cache
@@ -617,6 +651,9 @@ class RaggedBatch:
     new_lens: np.ndarray  # [N] int32
     block_tables: np.ndarray  # [N, P] int32
     seen: np.ndarray  # [N] int32 (tokens already in cache, before this step)
+    # the rows that hold ``uids``, in their order: the first ones, or each
+    # sequence's state slot (``StateManager.rows_of``)
+    at: Any = None
 
     @property
     def n_rows(self) -> int:
@@ -691,7 +728,12 @@ def build_ragged_batch(
     assert n == len(token_lists) and n > 0
     lens = np.fromiter((len(t) for t in token_lists), dtype=np.int64, count=n)
     chunk = _round_up(max(int(lens.max()), 1), chunk_bucket)
-    rows = _round_up(n, row_bucket)
+    # --- block allocation: one vectorized allocator call for the whole step
+    seqs = [manager.get_or_create(uid) for uid in uids]
+    at, rows = slice(0, n), _round_up(n, row_bucket)
+    if manager.state_slots is not None:
+        at, rows = manager.rows_of(uids, row_bucket)  # a sequence's row is its state slot
+    used = n if isinstance(at, slice) else int(at.max()) + 1
 
     if staging is not None:
         buf = staging.acquire(rows, chunk)
@@ -700,7 +742,7 @@ def build_ragged_batch(
                 f"staging max_pages={staging.max_pages} != requested {max_pages}")
         tokens, positions = buf["tokens"], buf["positions"]
         new_lens, block_tables, seen = buf["new_lens"], buf["block_tables"], buf["seen"]
-        staging.mark_dirty(rows, chunk, n)
+        staging.mark_dirty(rows, chunk, used)
     else:
         tokens = np.zeros((rows, chunk), np.int32)
         positions = np.zeros((rows, chunk), np.int32)
@@ -708,8 +750,6 @@ def build_ragged_batch(
         block_tables = np.zeros((rows, max_pages), np.int32)
         seen = np.zeros((rows,), np.int32)
 
-    # --- block allocation: one vectorized allocator call for the whole step
-    seqs = [manager.get_or_create(uid) for uid in uids]
     seen_v = np.fromiter((s.seen_tokens for s in seqs), dtype=np.int32, count=n)
     if manager.layout is not None:
         # two kinds of pages a row: the layout says how many of each
@@ -746,22 +786,23 @@ def build_ragged_batch(
                 s.append_blocks(fresh[ends[i] - need_v[i]: ends[i]])
 
     # --- vectorized fills (no per-token Python loops)
-    new_lens[:n] = lens
-    seen[:n] = seen_v
+    new_lens[at] = lens
+    seen[at] = seen_v
     if int(lens.max()) == 1:
         # decode fast path: one token per row, position == seen (a
         # zero-length row stays a pad: new_lens==0 masks it device-side)
-        positions[:n, 0] = seen_v
-        tokens[:n, 0] = np.fromiter(
+        positions[at, 0] = seen_v
+        tokens[at, 0] = np.fromiter(
             (t[0] if len(t) else 0 for t in token_lists), dtype=np.int64, count=n)
     else:
         col = np.arange(chunk)
         valid = col[None, :] < lens[:, None]  # [n, chunk]
-        positions[:n] = np.where(valid, seen_v[:, None] + col[None, :], 0)
+        positions[at] = np.where(valid, seen_v[:, None] + col[None, :], 0)
         # row-major boolean scatter == concatenation order of the ragged lists
-        tokens[:n][valid] = np.concatenate(
-            [np.asarray(t, np.int32) for t in token_lists])
-    for i, s in enumerate(seqs):
+        fed = np.zeros((n, chunk), np.int32)
+        fed[valid] = np.concatenate([np.asarray(t, np.int32) for t in token_lists])
+        tokens[at] = fed
+    for i, s in zip(range(n) if isinstance(at, slice) else at, seqs):
         if manager.layout is None:
             block_tables[i, : s.n_blocks] = s._table[: s.n_blocks]
         else:
@@ -769,5 +810,5 @@ def build_ragged_batch(
 
     return RaggedBatch(
         uids=list(uids), tokens=tokens, positions=positions,
-        new_lens=new_lens, block_tables=block_tables, seen=seen,
+        new_lens=new_lens, block_tables=block_tables, seen=seen, at=at,
     )

@@ -45,6 +45,43 @@ from deepspeed_tpu.runtime.model import ModelSpec
 
 
 @dataclasses.dataclass(frozen=True)
+class SSMConfig:
+    """The sizes of a state-space (Mamba-2) mixer, ``TransformerConfig.ssm``
+    (``ops/ssm.py`` has the mathematics): ``n_heads`` heads of ``head_dim``
+    channels, each with a state ``[head_dim, d_state]``; ``n_groups`` groups of
+    heads share ``B`` and ``C``; a causal depthwise convolution over ``d_conv``
+    inputs; the chunked scan's chunk."""
+
+    n_heads: int
+    head_dim: int
+    d_state: int
+    n_groups: int = 1
+    d_conv: int = 4
+    chunk_size: int = 256
+
+    def __post_init__(self):
+        if self.n_heads % self.n_groups or self.d_conv < 2:
+            raise ValueError(f"a state-space mixer needs n_heads a multiple of n_groups and d_conv >= 2, got {self}")
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels of the convolution: ``x``, ``B`` and ``C`` together."""
+        return self.d_inner + 2 * self.n_groups * self.d_state
+
+    @property
+    def proj_dim(self) -> int:
+        """Columns of the in-projection: ``[z | xBC | dt]``."""
+        return self.d_inner + self.conv_dim + self.n_heads
+
+
+LAYER_KINDS = ("attention", "mamba")
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     hidden_size: int = 512
@@ -68,7 +105,7 @@ class TransformerConfig:
     # GPT-NeoX-style parallel residual: like parallel_block but the MLP reads
     # its OWN norm of the block input (x + attn(ln1(x)) + mlp(ln2(x))).
     parallel_mlp_norm: bool = False
-    position: str = "rope"  # rope | learned | alibi (bloom-style score biases)
+    position: str = "rope"  # rope | learned | alibi (bloom-style score biases) | none
     rope_theta: float = 500000.0
     # Bloom-style LayerNorm applied to the token embeddings before layer 0.
     embed_norm: bool = False
@@ -224,8 +261,43 @@ class TransformerConfig:
     # a checkpoint's ``rope_scaling`` dict; type "yarn" alone is taken (the
     # DeepSeek-V2/V3 formulae, ``yarn_frequencies``), by latent attention alone
     rope_scaling: Optional[Any] = None
+    # A layer PATTERN: the kind of every layer's mixer, ``LAYER_KINDS``
+    # ("attention": ``Attention``; "mamba": ``Mamba2Mixer`` at the sizes
+    # ``ssm``), each followed by the dense MLP. The stack is scanned over whole
+    # periods of the pattern (``period``), a period's layers unrolled in the
+    # scan's body as ``layers/layer_<j>``. None is the homogeneous stack, the
+    # scan over ``Block`` as it always was.
+    layer_types: Optional[Tuple[str, ...]] = None
+    ssm: Optional[SSMConfig] = None
+    # scalars on the embedding, the attention scores (None: head_dim^-0.5),
+    # every residual add and the logits (divided by it); 1 is no multiply
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            kinds = set(self.layer_types)
+            if len(self.layer_types) != self.num_layers or not kinds <= set(LAYER_KINDS):
+                raise ValueError(
+                    f"layer_types has {len(self.layer_types)} entries of {sorted(kinds)} for num_layers="
+                    f"{self.num_layers}: one of {LAYER_KINDS} a layer")
+            if "mamba" in kinds and self.ssm is None:
+                raise ValueError("layer_types with a 'mamba' layer needs its sizes, ssm=SSMConfig(...)")
+            if (self.hc_mult or self.parallel_block or self.first_dense_layers or self.num_experts
+                    or self.moe_layer_experts or self.kv_lora_rank or self.eva_window or self.fp32_residual):
+                raise ValueError(
+                    "a layer pattern (layer_types) is built around plain attention and a dense MLP in a "
+                    "sequential one-stream block: no hyper-connections, parallel_block, routed or leading "
+                    "dense layers, latent or EVA attention, fp32 residual")
+        if self.residual_multiplier != 1.0 and (self.hc_mult or self.parallel_block):
+            raise ValueError("residual_multiplier scales the adds of a sequential one-stream block: not with "
+                             "hyper-connections or parallel_block")
+        if self.attention_multiplier is not None and self.attn_impl in ("sparse", "fpdt"):
+            raise ValueError(f"attention_multiplier with attn_impl={self.attn_impl!r}: that path scales its "
+                             "scores by head_dim^-0.5 itself")
         if isinstance(self.rope_scaling, dict):
             # frozen dataclass must stay hashable (configs are jit static args)
             object.__setattr__(self, "rope_scaling", tuple(sorted(self.rope_scaling.items())))
@@ -315,6 +387,27 @@ class TransformerConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def period(self) -> Optional[Tuple[str, ...]]:
+        """The shortest run of kinds that ``layer_types`` repeats whole (the
+        whole of it, if none does): what one step of the layer scan runs."""
+        types = self.layer_types
+        if types is None:
+            return None
+        L = len(types)
+        p = next(p for p in range(1, L + 1) if L % p == 0 and types == types[:p] * (L // p))
+        return types[:p]
+
+    @property
+    def attention_layers(self) -> int:
+        """Layers that hold keys and values: the page pool's."""
+        return self.num_layers if self.layer_types is None else self.layer_types.count("attention")
+
+    @property
+    def ssm_layers(self) -> int:
+        """Layers that hold a recurrent state: the state pool's."""
+        return 0 if self.layer_types is None else self.layer_types.count("mamba")
+
+    @property
     def latent_rotary(self) -> "LatentRotary":
         """What latent attention's scores are made with, in training and in
         serving: the rotary frequencies over ``qk_rope_head_dim`` (None: plain
@@ -352,7 +445,7 @@ class TransformerConfig:
 
         For MoE configs N is the ACTIVE parameter count (top-k experts)."""
         n = self.num_active_params()
-        attn = 12 * self.num_layers * self.hidden_size * seq_len  # score+value matmuls
+        attn = 12 * self.attention_layers * self.hidden_size * seq_len  # score+value matmuls
         return 6 * n + attn
 
     def _mlp_params(self, width: Optional[int] = None) -> int:
@@ -372,6 +465,13 @@ class TransformerConfig:
         eva = 2 * self.kv_heads * hd if self.eva_window else 0  # phi and mu
         return h * hd * (H + 2 * self.kv_heads) + hd * H * h + eva
 
+    def _ssm_params(self) -> int:
+        """One state-space mixer: both projections, the convolution and its
+        bias, ``A_log``, ``dt_bias``, ``D``, the gated norm."""
+        s = self.ssm
+        return (self.hidden_size * (s.proj_dim + s.d_inner) + (s.d_conv + 1) * s.conv_dim
+                + 3 * s.n_heads + s.d_inner)
+
     def num_params(self) -> int:
         h, v, l = self.hidden_size, self.vocab_size, self.num_layers
         qkv = self._attention_params()
@@ -390,7 +490,8 @@ class TransformerConfig:
                     layer_mlp += mlp + 2 * h + 2  # residual MLP + coefficient gate
             else:
                 layer_mlp = mlp
-            total += qkv + layer_mlp + (h if self.parallel_block else 2 * h) + 2 * self.hc_params
+            mixer = self._ssm_params() if self.layer_types and self.layer_types[i] == "mamba" else qkv
+            total += mixer + layer_mlp + (h if self.parallel_block else 2 * h) + 2 * self.hc_params
         return total
 
     def num_active_params(self) -> int:
@@ -640,8 +741,9 @@ class Attention(nn.Module):
             # CONSTRAINT (the program stays global SPMD), so the partitioner
             # splits the per-head slope bias along with the head axis.
             q, k, v = ulysses_shard(q), ulysses_shard(k), ulysses_shard(v)
+            scaled = {} if cfg.attention_multiplier is None else {"softmax_scale": cfg.attention_multiplier}
             out = causal_attention(q, k, v, mask=mask, impl=cfg.attn_impl,
-                                   alibi_slopes=slopes,
+                                   alibi_slopes=slopes, **scaled,
                                    **dict(cfg.attn_kwargs or ()))  # [B,S,H,hd]
             out = ulysses_unshard(out)
         dense_bias = cfg.dense_bias if cfg.dense_bias is not None else cfg.norm == "layernorm"
@@ -801,6 +903,82 @@ class LatentAttention(nn.Module):
         return dense(cfg.hidden_size, axis=(-2, -1), name="wo")(out)
 
 
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``A_log`` as the Mamba-2 reference draws it: ``A`` uniform in [1, 16]."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)).astype(dtype)
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """The inverse softplus of a step drawn log-uniform in [0.001, 0.1] (the
+    Mamba-2 reference's): with ``A`` in [1, 16] a head forgets over tens to a
+    thousand tokens."""
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, np.log(1e-3), np.log(1e-1)))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _d_init(key, shape, dtype=jnp.float32):
+    return jax.random.uniform(key, shape, jnp.float32, 0.5, 1.5).astype(dtype)
+
+
+class _ConvKernel(nn.Module):
+    """The depthwise convolution's ``kernel`` [taps, channels] and ``bias``,
+    where a flax convolution would put them; ``ops/ssm.py`` applies them."""
+
+    taps: int
+    channels: int
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self) -> dict:
+        from deepspeed_tpu.parallel.moe import _nonzero_normal
+
+        return {"kernel": self.param("kernel", nn.initializers.normal(self.taps ** -0.5),
+                                     (self.taps, self.channels), self.param_dtype),
+                "bias": self.param("bias", _nonzero_normal(0.2), (self.channels,), self.param_dtype)}
+
+
+class _Scale(nn.Module):
+    """A norm's ``scale`` alone (the gated norm of a state-space mixer)."""
+
+    width: int
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self) -> dict:
+        return {"scale": self.param("scale", nn.initializers.ones, (self.width,), self.param_dtype)}
+
+
+class Mamba2Mixer(nn.Module):
+    """A Mamba-2 state-space mixer over a full sequence from an empty state
+    (``TransformerConfig.ssm``; ``ops/ssm.py`` has the mathematics, which the
+    paged serving path calls with these parameters and a state it keeps):
+    ``ssm_in_proj`` [hidden, z | xBC | dt], ``ssm_conv`` (kernel, bias),
+    ``A_log``, ``dt_bias``, ``D`` a head, ``ssm_norm`` (the gated norm's scale),
+    ``ssm_out_proj``. ``A_log``, ``dt_bias`` and ``D`` are drawn NONZERO and
+    spread: left at a constant, nothing could tell whether they were read.
+    ``mask`` [B, S] marks the live tokens of right-padded rows."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, mask, positions, train: bool):
+        from deepspeed_tpu.ops import ssm
+
+        cfg, s = self.config, self.config.ssm
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype)
+        zxbcdt = dense(s.proj_dim, name="ssm_in_proj")(x)
+        heads = functools.partial(self.param, shape=(s.n_heads,), dtype=cfg.param_dtype)
+        leaves = {
+            "ssm_conv": _ConvKernel(s.d_conv, s.conv_dim, cfg.param_dtype, name="ssm_conv")(),
+            "A_log": heads("A_log", _a_log_init), "dt_bias": heads("dt_bias", _dt_bias_init),
+            "D": heads("D", _d_init),
+            "ssm_norm": _Scale(s.d_inner, cfg.param_dtype, name="ssm_norm")(),
+        }
+        new_lens = None if mask is None else (mask > 0).sum(axis=1).astype(jnp.int32)
+        y, _, _ = ssm.mix(zxbcdt, leaves, s, cfg.norm_eps, new_lens=new_lens)
+        return dense(cfg.hidden_size, name="ssm_out_proj")(y)
+
+
 class MLP(nn.Module):
     config: TransformerConfig
 
@@ -862,6 +1040,12 @@ class HyperConnection(nn.Module):
                        eps=cfg.hc_eps, clamp=cfg.hc_res_clamp)
 
 
+def _times(multiplier: float, x):
+    """``multiplier * x``; at 1, ``x`` itself: a model without the multiplier
+    traces the program it always did."""
+    return x if multiplier == 1.0 else x * jnp.asarray(multiplier, x.dtype)
+
+
 class Block(nn.Module):
     # ``train`` is a module attribute (not a call kwarg) because nn.scan does
     # not forward kwargs through the scanned call.
@@ -869,6 +1053,7 @@ class Block(nn.Module):
     train: bool = False
     layer_idx: int = 0  # selects the pyramid expert count (PR-MoE)
     dense: bool = False  # a leading dense layer of a routed model (first_dense_layers)
+    kind: str = "attention"  # the mixer, one of LAYER_KINDS (``TransformerConfig.layer_types``)
 
     @nn.compact
     def __call__(self, carry, _=None):
@@ -907,17 +1092,21 @@ class Block(nn.Module):
             with jax.named_scope("mlp_hc"):
                 u = mhc.read(x, mixed)
             h = _norm(cfg, "mlp_norm")(u)
+        elif self.kind == "mamba":
+            x = x + _times(cfg.residual_multiplier, Mamba2Mixer(cfg, name="ssm")(
+                _norm(cfg, "ssm_pre_norm")(x), mask, positions, self.train))
+            h = _norm(cfg, "mlp_norm")(x)
         else:
-            x = x + attn_cls(cfg, name="attn")(
+            x = x + _times(cfg.residual_multiplier, attn_cls(cfg, name="attn")(
                 _norm(cfg, "attn_norm")(x), mask, positions, self.train
-            )
+            ))
             h = _norm(cfg, "mlp_norm")(x)
 
         def add(out):  # the feed-forward's write-back
             if cfg.hc_mult:
                 with jax.named_scope("mlp_hc"):
                     return mhc.write(x, out, mixed)
-            return x + out
+            return x + _times(cfg.residual_multiplier, out)
 
         # the scanned stack is built with layer_idx 0, so a leading dense
         # layer says so itself and is not looked up by its index
@@ -972,6 +1161,25 @@ class Block(nn.Module):
         return (x, mask, positions, aux), None
 
 
+class Period(nn.Module):
+    """One period of a layer pattern (``TransformerConfig.period``): its
+    blocks, each of its own kind, as ``layer_<j>``. What ``CausalLM`` scans
+    where the stack is not homogeneous; a parameter's leading axis is then the
+    period, and layer ``i`` of the model is period ``i // len(period)``'s
+    ``layer_<i % len(period)>``."""
+
+    config: TransformerConfig
+    train: bool = False
+
+    @nn.compact
+    def __call__(self, carry, _=None):
+        cfg = self.config
+        block_cls = nn.remat(Block, prevent_cse=False) if cfg.remat else Block
+        for j, kind in enumerate(cfg.period):
+            carry, _ = block_cls(cfg, self.train, kind=kind, name=f"layer_{j}")(carry, None)
+        return carry, None
+
+
 class _HeadKernel(nn.Module):
     """Declares the untied LM-head kernel param without running the matmul —
     the fused-CE path reads the weight directly. Param path/shape/init match
@@ -1009,6 +1217,7 @@ class CausalLM(nn.Module):
         embed_cls = _SparseGradEmbed if cfg.sparse_embedding_grads else nn.Embed
         x = embed_cls(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                       param_dtype=cfg.param_dtype, name="embed")(ids)
+        x = _times(cfg.embedding_multiplier, x)
         if cfg.fp32_residual:
             x = x.astype(jnp.float32)
         if cfg.embed_norm:
@@ -1055,11 +1264,14 @@ class CausalLM(nn.Module):
         if cfg.scan_layers:
             for i in range(cfg.first_dense_layers):  # outside the scan, each its own tree
                 carry, _ = block_cls(cfg, train, dense=True, name=f"dense_{i}")(carry, None)
+            # a layer pattern is scanned a whole period a step (``Period`` applies ``remat`` a block)
+            pattern = cfg.layer_types is not None
             stack = nn.scan(
-                block_cls,
+                Period if pattern else block_cls,
                 variable_axes={"params": 0},
                 split_rngs={"params": True, "dropout": True},
-                length=cfg.num_layers - cfg.first_dense_layers,
+                length=(cfg.num_layers // len(cfg.period) if pattern
+                        else cfg.num_layers - cfg.first_dense_layers),
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(cfg, train, name="layers")
             # flax names the BODY ``layers``; what the scan itself does (the
@@ -1070,6 +1282,7 @@ class CausalLM(nn.Module):
         else:
             for i in range(cfg.num_layers):
                 carry, _ = block_cls(cfg, train, layer_idx=i, dense=i < cfg.first_dense_layers,
+                                     kind=cfg.layer_types[i] if cfg.layer_types else "attention",
                                      name=f"layer_{i}")(carry, None)
         x, aux = carry[0], carry[3]
         if cfg.hc_mult:
@@ -1081,7 +1294,8 @@ class CausalLM(nn.Module):
             n_moe = max(cfg.num_moe_layers, 1)
             moe_stats = {k: v / n_moe for k, v in stat_sums.items()}
 
-        x = _norm(cfg, "final_norm")(x)
+        # logits / logits_scaling, as the final hidden state times its inverse: the fused head never forms logits
+        x = _times(1.0 / cfg.logits_scaling, _norm(cfg, "final_norm")(x))
         labels = batch.get("labels")
         if labels is None:
             labels = jnp.concatenate([ids[:, 1:], jnp.full((B, 1), -100, dtype=ids.dtype)], axis=1)
@@ -1131,7 +1345,7 @@ class CausalLM(nn.Module):
 @jax.named_scope("embed")  # the scope flax's ``embed`` module gives CausalLM
 def _embed_tokens(params, cfg: TransformerConfig, ids):
     """Functional twin of the embedding front-end of ``CausalLM.__call__``."""
-    x = jnp.take(params["embed"]["embedding"], ids, axis=0).astype(cfg.dtype)
+    x = _times(cfg.embedding_multiplier, jnp.take(params["embed"]["embedding"], ids, axis=0).astype(cfg.dtype))
     if cfg.embed_norm:
         x = _apply_norm(params["embed_norm"], cfg, x)
     if cfg.position == "learned":
@@ -1175,7 +1389,7 @@ def _norm_at(tree, key: str, cfg: TransformerConfig, x):
 
 
 def _lm_head_and_loss(params, cfg: TransformerConfig, x, batch, aux):
-    x = _norm_at(params, "final_norm", cfg, x)
+    x = _times(1.0 / cfg.logits_scaling, _norm_at(params, "final_norm", cfg, x))
     ids = batch["input_ids"]
     labels = batch.get("labels")
     if labels is None:
@@ -1213,6 +1427,9 @@ def pipelined_causal_lm_loss(params, batch, rng, *, config: TransformerConfig,
     cfg = config
     if not cfg.scan_layers:
         raise ValueError("pipelined execution requires scan_layers=True (stacked layer params)")
+    if cfg.layer_types is not None:
+        raise ValueError("pipelined execution of a layer pattern (layer_types): a stage scans one Block "
+                         "over its share of a homogeneous stack, and a period's layers are not one Block")
     if cfg.moe_metrics and train and cfg.has_moe:
         raise ValueError(
             "moe_metrics is not wired through the pipelined loss path (the "

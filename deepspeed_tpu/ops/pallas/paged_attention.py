@@ -275,6 +275,14 @@ def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, 
             o_ref[0, g] = normalised(g).astype(o_ref.dtype)
 
 
+def _query_side_bytes(heads: int, rows: int, width: int, itemsize: int) -> int:
+    """VMEM of a grid step's query side: the blocks of q and of the output,
+    each double-buffered by the pipeline, the fp32 accumulator, and the
+    statistics; a minor dimension under 128 pads to 128 lanes."""
+    lanes = _cdiv(width, 128) * 128
+    return heads * rows * (4 * lanes * itemsize + lanes * 4 + 128 * 4)
+
+
 @register("paged_attention", "pallas")
 def flash_decode_paged(
     q: jax.Array,  # [N, C, H, hd]
@@ -303,6 +311,20 @@ def flash_decode_paged(
     # block-diagonal query for all heads where a head has under a sublane tile
     # of query rows and the (row, head) pairs fit one 128-row pass.
     dense = Cg < _SUBLANES and Cg * kvH <= 128
+    page_bytes = bs * D * pool_k.dtype.itemsize
+    if (not dense and C > 1
+            and _query_side_bytes(kvH, _cdiv(Cg, _SUBLANES) * _SUBLANES, hd, q.dtype.itemsize)
+            + 4 * page_bytes > _VMEM_BUDGET):
+        # A row's query block is too large for VMEM beside one page a slot (a
+        # 256-token chunk of 32 heads over 8 of head_dim 64 is [8, 1024, 64],
+        # and 64 lanes pad to 128): the chunk's first and second half are two
+        # calls, each over the row's pages up to its own last token.
+        h = _cdiv(C, 2)
+        lens = (None, None) if new_lens is None else (jnp.clip(new_lens, 0, h), jnp.clip(new_lens - h, 0, C - h))
+        return jnp.concatenate([
+            flash_decode_paged(q[:, at], pool_k, pool_v, block_tables, q_positions[:, at], bs, n,
+                               pages_per_block, alibi_slopes, k_scale, v_scale)
+            for at, n in zip((slice(0, h), slice(h, C)), lens)], axis=1)
     scale = jnp.asarray(hd ** -0.5, q.dtype)
     qg = (q * scale).reshape(N, C, kvH, G, hd)
     qpos_rows = jnp.broadcast_to(q_positions[:, :, None], (N, C, G)).reshape(N, Cg)
@@ -343,10 +365,7 @@ def flash_decode_paged(
     # side (its block and the output's, double-buffered by the pipeline; the
     # fp32 accumulator; the statistics, which pad to 128 lanes) leaves less of
     # the budget for the two slots of K and of V.
-    query_side = (4 * q_op[0].size * q.dtype.itemsize  # blocks of q and out, each twice
-                  + heads * rows * (D // heads) * 4  # the fp32 accumulator
-                  + heads * rows * 128 * 4)  # the statistics, padded to 128 lanes
-    page_bytes = bs * D * pool_k.dtype.itemsize
+    query_side = _query_side_bytes(heads, rows, D // heads, q.dtype.itemsize)
     ppcb = max(1, min(pages_per_block, P))
     while ppcb > 1 and query_side + 4 * ppcb * page_bytes > _VMEM_BUDGET:
         ppcb //= 2

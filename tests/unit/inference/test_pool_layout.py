@@ -312,3 +312,78 @@ def test_pages_equal_a_token_by_token_write(C, starts, lens):
                              jnp.int32(layer_first)))
         assert got.shape == shape and got.tobytes() == want.tobytes()
     assert (want != pool).any() and (want[:layer_first] == pool[:layer_first]).all()
+
+
+# ------------------------------------------------- the state pool beside it
+def _state_sized_products(jaxpr, state_pool):
+    """(primitive, shape) of every equation output of the state pool's whole
+    shape (either array's) other than the ways it is allowed through a
+    program: carried by a loop or a call, and updated in place, a layer's row
+    of it at a time (``dynamic_update_slice``). A scan may hold it in its carry
+    only."""
+    whole = {tuple(a.shape) for a in state_pool}
+    out = []
+    for eqn in _equations(jaxpr):
+        name = eqn.primitive.name
+        if name == "scan":
+            held = eqn.params["num_consts"] + eqn.params["num_carry"]
+            out += [("scan_xs", tuple(v.aval.shape)) for v in eqn.invars[held:] if tuple(v.aval.shape) in whole]
+            out += [("scan_ys", tuple(v.aval.shape)) for v in eqn.outvars[eqn.params["num_carry"]:]
+                    if tuple(v.aval.shape) in whole]
+        if name in CARRIERS or name == "dynamic_update_slice":
+            continue
+        out += [(name, tuple(v.aval.shape)) for v in eqn.outvars
+                if hasattr(v.aval, "shape") and tuple(v.aval.shape) in whole]
+    return out
+
+
+@pytest.mark.parametrize("which", ["step", "chain", "prefill"])
+def test_programs_update_the_state_pool_in_place_and_hand_both_pools_back(which):
+    """A model with state-space layers: its programs take the page pool AND the
+    state pool in the pool's place (``paged.HybridPools``), donated. Nothing of
+    the state pool's whole shape is produced but its in-place update, a layer's
+    row at a time, and the compiled program hands both pools back aliased."""
+    from .test_hybrid import toy_params
+
+    cfg, params = toy_params(jnp.float32)
+    eng = _engine(cfg, params, max_seqs=ROWS, row_bucket=ROWS, kv_cache_dtype="bf16")
+    pools = eng._pools
+    assert pools.state.ssm.shape == (cfg.ssm_layers, ROWS, 1, 16, 128) and len(pools) == 2
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
+    tables, chunk = i32(ROWS, eng.max_pages), eng.config.chunk_bucket
+    if which == "chain":
+        fn = eng._chain_fn(ROWS, K, None, (("do_sample", False),))
+        args = (eng.params, pools, i32(ROWS), i32(ROWS), tables, jnp.ones((ROWS,), bool),
+                jnp.full((ROWS,), K, jnp.int32), jax.random.PRNGKey(0))
+    else:
+        args = (eng.params, pools, i32(ROWS, chunk), i32(ROWS, chunk), i32(ROWS), tables)
+        fn = eng._step_fn(ROWS, chunk)
+        if which == "prefill":
+            fn, args = eng._sample_step_fn(ROWS, chunk, (("do_sample", False),)), args + (jax.random.PRNGKey(0),)
+
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    assert not _state_sized_products(jaxpr.jaxpr, pools.state)
+    assert not _pool_sized_products(jaxpr.jaxpr, pools.kv, cfg.attention_layers)
+    # the census sees the pool: every state-space layer of a period updates it, whole
+    updates = [e for e in _equations(jaxpr.jaxpr) if e.primitive.name == "dynamic_update_slice"
+               and e.outvars[0].aval.shape == pools.state.ssm.shape]
+    assert len(updates) == cfg.period.count("mamba")
+
+    compiled = fn.lower(*args).compile()
+    leaves = jax.tree_util.tree_leaves(pools)
+    first = len(jax.tree_util.tree_leaves(eng.params))  # the pools' leaves come next
+    aliased = {int(p) for p in re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)",
+                                          compiled.as_text().split("entry_computation_layout")[0])}
+    assert aliased == set(range(first, first + len(leaves))), aliased
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(a.nbytes for a in leaves)
+
+
+def test_census_flags_a_state_pool_sliced_by_layer():
+    """The teeth: a scan that takes the state pool as ``xs`` (a layer's row
+    sliced out) and stacks it back as ``ys`` is what the census refuses."""
+    from deepspeed_tpu.inference.paged import StatePool
+
+    pool = StatePool(jnp.zeros((4, 2, 3, 5, 7)), jnp.zeros((4, 2, 11), jnp.bfloat16))
+    jaxpr = jax.make_jaxpr(lambda a: jax.lax.scan(lambda c, row: (c, row + 1), 0, a)[1])(pool.ssm)
+    found = _state_sized_products(jaxpr.jaxpr, pool)
+    assert ("scan_xs", pool.ssm.shape) in found and ("scan_ys", pool.ssm.shape) in found

@@ -295,6 +295,84 @@ def test_evabyte_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, na
     assert not moved, moved
 
 
+@pytest.mark.parametrize("name", ["prefill_64x256", "chain_64"])
+def test_granite_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, name):
+    """``granite-4.0-h-micro.serve.long-output-batch``'s two programs whole, for
+    the described v5e at the cell's own shapes (all 40 layers at the published
+    widths, 64 state slots = 4.89 GB of recurrent state, a 0.5 GiB page pool of
+    the four attention layers): the ``(64, 256)`` prefill (the chunked scan a
+    state-space layer, the flash or paged kernel an attention layer) and the
+    chain of 8 steps at 64 rows (the one-token recurrence, ``ssm_update``, nine
+    calls a period). Each fits the chip
+    beside the weights and both pools, returns BOTH donated pools aliased, and
+    holds no instruction of the state pool's whole shape that is a copy: the
+    state is updated in place, a row of the pool a layer."""
+    import dataclasses
+    import json
+    import re
+
+    from benchmarks.lib import harness, program
+    from deepspeed_tpu.checkpoint.hf import config_from_hf
+    from deepspeed_tpu.inference import paged
+    from deepspeed_tpu.models import CausalLM
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import flash_attention as fa, norms, paged_attention as pa, ssm_update
+
+    for module in (pa, fa, norms, ssm_update):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")  # what 'auto' sees there
+    cfg = dataclasses.replace(config_from_hf(program.published(harness.load_config("granite-4.0-h-micro"))),
+                              dtype=jnp.bfloat16)
+    engine = harness.load_workload("granite-4.0-h-micro.serve.long-output-batch")["engine"]
+    bs, rows = engine["kv_block_size"], engine["max_seqs"]
+    NB, table = engine["kv_pool_bytes"] // (bs * 8192), engine["max_seq_len"] // bs
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda key: CausalLM(cfg).init({"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)},
+                                       train=False)["params"], jax.random.PRNGKey(0)))
+    pools = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: paged.HybridPools(
+        paged.init_pool(cfg, NB, bs, jnp.bfloat16), paged.init_state_pool(cfg, rows, jnp.bfloat16))))
+    assert pools.kv.k.shape == (4 * 4096, 16, 512) and pools.state.ssm.shape == (36, 64, 32, 128, 128)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    if name == "chain_64":
+        limit_gb = 1.5
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def program_(params, pools, tokens, start_pos, tables, active, budgets, rng):
+            return paged.ragged_decode_chain(params, cfg, pools, tokens, start_pos, tables, bs,
+                                             active, budgets, rng, engine["decode_chain"], None)
+
+        args = (i32(rows), i32(rows), i32(rows, table), jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip),
+                i32(rows), jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip))
+    else:
+        limit_gb = 3.0
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def program_(params, pools, tokens, positions, new_lens, tables):
+            return paged.ragged_forward(params, cfg, pools, tokens, positions, new_lens, tables, bs)
+
+        chunk = engine["chunk_bucket"]
+        args = (i32(rows, chunk), i32(rows, chunk), i32(rows), i32(rows, table))
+    compiled = program_.lower(params, pools, *args).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pools))
+    assert pool_bytes == 64 * 36 * (2097152 + 26112) + 2 ** 29
+    assert mem.alias_size_in_bytes >= pool_bytes
+    print(json.dumps({"program": name, "temp_gb": mem.temp_size_in_bytes / 1e9,
+                      "argument_gb": mem.argument_size_in_bytes / 1e9}))
+    assert mem.temp_size_in_bytes < limit_gb * 1e9, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    kernels = ("paged_attn", "ssm_update") if name == "chain_64" else ("paged_attn",)
+    for kernel in kernels:  # the one-token recurrence is a kernel of its own name, the pool aliased through it
+        assert any("tpu_custom_call" in line and kernel in line for line in text.splitlines()), kernel
+    # nothing copies or re-lays the state pool, a layer's row of it, or the rows of a row
+    state = r"f32\[(36,64|1,64|64),32,128,128\]"
+    moved = [line.strip()[:200] for line in text.splitlines()
+             if re.search(r"= %s\S* (copy|transpose)\(" % state, line)]
+    assert not moved, moved
+
+
 @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["bf16", "int8"])
 def test_decode_chain_updates_the_pool_in_place(one_chip, monkeypatch, kv_quant):
     """Whether a carried array is updated in place is the chip's compiler's

@@ -21,6 +21,19 @@
   instructions, which the ten ``named_op=`` lines of a run are too few to
   reach. No metric reads the two names; ``PERF.md`` section 5 reads these.
 
+- in a cell with state-space layers (an architecture file with
+  ``ssd_scan_cost``), the mixer's pieces by the scopes under ``ssm``: an
+  ``ssm_part=`` line a program and piece (``ssm_in_proj``, ``ssm_conv``,
+  ``ssm_scan`` in a prefill, ``ssm_update`` in a chain, ``ssm_norm``,
+  ``ssm_out_proj``; device seconds, layer-calls, ms a layer-call, and for
+  ``ssm_update`` the GB/s on its own bytes, a live row's state read once and
+  written once), an ``ssm_op=`` line for each of the five largest instructions
+  under ``ssm`` in each program, the chunked scan's share of its roofline in the
+  prefills that the window happens to hold (``ssd_scan_roofline=``: no listed
+  metric, because a window may hold none), and a ``state_pool_copy=`` line for
+  every instruction that makes an array of the state pool's whole shape and is
+  not its in-place update (there has to be none).
+
 All are wrapped OUTSIDE the benchmark, before ``run.main`` runs; nothing
 here is read by the program or the benchmark, and a cell's listed metrics
 read what they read without it.
@@ -55,6 +68,61 @@ def moe_halves(rows):
                    f"count={r['occurrences']} op_name={r['tf_op_name']} expression={r['hlo_op_expression'][:300]}")
 
 
+SSM_PARTS = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_update", "ssm_norm", "ssm_out_proj")
+
+
+def ssm_parts(rows, workload_name):
+    """The ``ssm_part=``, ``ssm_op=``, ``ssd_scan_roofline=`` and ``state_pool_copy=`` lines."""
+    import re
+
+    from benchmarks.lib import costs, harness, peaks, program
+
+    workload = harness.load_workload(workload_name)
+    config = harness.load_config(workload["config"])
+    arch, cfg = harness.load_architecture(config["architecture"]), program.published(config)
+    if not hasattr(arch, "ssd_scan_cost"):
+        return
+    rows_, chunk = workload["engine"]["max_seqs"], workload["engine"]["chunk_bucket"]
+    # a period's state-space layers are unrolled in the scan's body: each has instructions of its own
+    unrolled = harness.load_reference(config["architecture"]).period_of(cfg["layer_types"]).count("mamba")
+    state = rows_ * cfg["mamba_n_heads"] * cfg["mamba_d_head"] * cfg["mamba_d_state"] * 4
+    under = [(r, r["tf_op_name"].rstrip(":").split("/")) for r in rows]
+    under = [(r, path, path[0][4:-1] if path[0].startswith("jit(") else "?") for r, path in under if "ssm" in path]
+    for prog in sorted({p for _, _, p in under}):
+        mine = [(r, path) for r, path, p in under if p == prog]
+        # a layer-call makes ONE in-projection: its instruction's occurrences count the layer-calls
+        calls = unrolled * max((int(float(r["occurrences"])) for r, path in mine if "ssm_in_proj" in path), default=1)
+        for part in SSM_PARTS + ("(ssm alone)",):
+            of = [r for r, path in mine if (part in path if part in SSM_PARTS else not set(SSM_PARTS) & set(path))]
+            if not of:
+                continue
+            seconds = 1e-6 * sum(float(r["total_self_time"]) for r in of)
+            line = (f"ssm_part={part} program={prog} device_s={seconds} layer_calls={calls} "
+                    f"ms_a_layer_call={1e3 * seconds / calls} instructions={len(of)}")
+            if part == "ssm_update":
+                line += f" own_gb_per_s={2e-9 * state * calls / seconds} of_state_bytes={2 * state}"
+            if part == "ssm_scan":
+                flops, bytes_ = arch.ssd_scan_cost(cfg, rows_, chunk)
+                least, bound = costs.roofline_seconds(flops, bytes_, peaks.device_peaks("TPU v5 lite"))
+                yield (f"ssd_scan_roofline={100 * least * calls / seconds} bound={bound} least_ms_a_layer_call="
+                       f"{1e3 * least} rows={rows_} tokens={chunk} (every layer-call counted at the full shape)")
+            yield line
+        for r, _ in sorted(mine, key=lambda rp: -float(rp[0]["total_self_time"]))[:5]:
+            yield (f"ssm_op={r['hlo_op_name']} program={prog} device_s={1e-6 * float(r['total_self_time'])} "
+                   f"count={r['occurrences']} op_name={r['tf_op_name']} expression={r['hlo_op_expression'][:400]}")
+    channels = cfg["mamba_n_heads"] * cfg["mamba_d_head"]  # the pool keeps 128 of them a tile, on the lanes
+    whole = r"f32\[%d,%d,%d,%d,128\]" % (arch.ssm_layers(cfg), rows_, channels // 128, cfg["mamba_d_state"])
+    copies = 0
+    for r in rows:
+        made = re.match(r"\s*%?[\w.\-]+ = (?:\()?" + whole, r["hlo_op_expression"])
+        in_place = "dynamic-update-slice" in r["hlo_op_expression"] or r["hlo_op_name"].startswith(("ssm_update", "ssm_rows_in"))
+        if made and not in_place and r["category"] != "while":
+            copies += 1
+            yield (f"state_pool_copy={r['hlo_op_name']} category={r['category']} device_s="
+                   f"{1e-6 * float(r['total_self_time'])} count={r['occurrences']} expression={r['hlo_op_expression'][:300]}")
+    yield f"state_pool_copies={copies} of_shape={whole}"
+
+
 def main(argv=None) -> int:
     from benchmarks import run
     from benchmarks.lib import harness, scopes
@@ -68,6 +136,8 @@ def main(argv=None) -> int:
               f"hlo_stats_s={time.perf_counter() - start:.3f} rows={len(rows)}", flush=True)
         for line in moe_halves(rows):
             print(line, flush=True)
+        for line in ssm_parts(rows, argv[argv.index("--workload") + 1]):
+            print(line, flush=True)
         return rows
 
     def with_serving_readers(bench, group, workload_name):
@@ -80,6 +150,7 @@ def main(argv=None) -> int:
                 wanted = wanted + [m for m in bench["per_layer"] if m["name"] in ROUTED and m["name"] not in names]
         return wanted
 
+    argv = list(sys.argv[1:] if argv is None else argv)
     scopes._hlo_stats, harness.cell_metrics = timed, with_serving_readers
     return run.main(argv)
 
