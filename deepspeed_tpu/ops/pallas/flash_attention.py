@@ -16,15 +16,41 @@ Backward: a score block costs what its [block_q, block_k] passes cost,
 whatever the head size, so the backward visits each block ONCE while it can.
 ``flash_bwd_dkv`` walks key columns (outer) over query rows (inner), forms
 s, p, dp and ds once a block, accumulates dv and dk in scratch and adds
-ds @ k into the head's whole fp32 dq, which stays in VMEM over the walk and
-is written back once a head: five products and one exp2 a block. That dq
-slab is S x max(D, 128) x 4 bytes, twice; past ``_ONE_PASS_DQ_BYTES`` of it
-(S over 8,192 at head_dim up to 128) ``flash_bwd_dq`` makes dq on the
-row-major walk and ``flash_bwd_dkv`` only dk and dv, each forming the scores
-for itself (seven products, two exp2). ``_flash_bwd`` chooses from S and D
-alone; there is no option.
+ds @ k into the head's whole fp32 dq, which stays in VMEM scratch over the
+walk and is written out once a head: five products and one exp2 a block. That
+dq slab is S x max(D, 128) x 4 bytes, and its output block as much again in
+bf16 (double-buffered); past ``_ONE_PASS_DQ_BYTES`` of it (S over 8,192 at
+head_dim up to 128) ``flash_bwd_dq`` makes dq on the row-major walk and
+``flash_bwd_dkv`` only dk and dv, each forming the scores for itself (seven
+products, two exp2). ``_flash_bwd`` chooses from S, D and the dtype's size
+alone; there is no option. The gradients leave the kernels in their final
+form: the softmax scale (dq) and the base-2 softmax's ln2 (dk) are applied in
+fp32 inside, then the one rounding to the model's dtype; XLA only transposes
+them (with grouped heads dk and dv stay fp32 per query head for the sum over
+the group).
+
+Row statistics: lse (out of the forward) and delta (into the backward) cross
+the kernels' boundary as ``[B, H, 1, S]`` fp32 rows, the sequence on the
+lanes, in ``T(1,128)`` tiles: their numbers and no padding (a ``[B, H, S, 8]``
+column is stored 128 lanes wide, and a layer scan stacks, holds and slices
+the residual: 805 MB a micro-step for 50 MB of numbers at the 410M train
+cell). The forward turns its column into a row once a query block; the dkv
+kernel, whose query block changes every step, forms its scores transposed
+(``k q^T``) so the rows broadcast down sublanes with no relayout; the dq
+kernel turns a query block's rows into columns once for all its key blocks.
 
 Performance notes (measured on v5e):
+  - transposed scores in the dkv kernel, a key block taken whole, take 16%
+    off the one-pass backward at head_dim 64 and 8% at 128 (0.846 -> 0.710 ms
+    at [2, 2048, 16, 64], 0.387 -> 0.356 at [1, 2048, 16, 128]: one
+    transposed-lhs product where there were two, no lane reductions of the
+    statistics, gradients rounded inside; in chunks of 256 columns, which the
+    old form gained 4.5% from, 0.750 and 0.375); turning the rows into columns
+    every step instead costs 4-10% MORE than the padded columns did (0.877,
+    0.424 ms); the forward's once-a-block transpose costs 0.9% (0.391 ->
+    0.395, 0.190 -> 0.192), and reading m / l as lane 0 of their scratch
+    instead of reducing over its lanes DOUBLES the forward (0.78, 0.39)
+    (tools/flash_kernel_bench.py; PERF.md, PR 44)
   - the one-pass backward takes 30% less than the pair at the train cells'
     shapes (0.847 against 1.213 ms at [2, 2048, 16, 64], 0.386 against 0.549
     at [1, 2048, 16, 128]: tools/flash_kernel_bench.py; PERF.md, PR 32), the
@@ -70,8 +96,9 @@ _LOG2E = 1.4426950408889634
 _LN2 = 0.6931471805599453
 
 DEFAULT_BLOCK_Q = 512
-_LANES = 8  # lse/delta lane width in HBM (block last dim == array last dim satisfies Mosaic tiling); m/l scratch pad internally
 DEFAULT_BLOCK_K = 512
+_LANES = 8  # lane width of the forward's m / l scratch columns and of the alibi slopes' [H, 1, _LANES] (1 KB)
+_VREG_LANES = 128  # a column becomes a row (and back) through a [n, 128] <-> [128, n] transpose
 
 
 def _interpret() -> bool:
@@ -98,10 +125,21 @@ def _block_classes(qi, ki, block_q, block_k):
     return full_below, touches & ~full_below
 
 
-def _causal_keep(qi, ki, shape, block_q, block_k, col_off=0):
-    rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    cols = ki * block_k + col_off + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+def _causal_keep(qi, ki, shape, block_q, block_k, col_off=0, key_axis=1):
+    rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - key_axis)
+    cols = ki * block_k + col_off + jax.lax.broadcasted_iota(jnp.int32, shape, key_axis)
     return cols <= rows
+
+
+def _row_of(col):
+    """[n, 1] -> [1, n], the values unchanged: the column across a vreg row's
+    lanes, transposed, its first sublane."""
+    return jnp.broadcast_to(col, (col.shape[0], _VREG_LANES)).T[:1]
+
+
+def _cols_of(row):
+    """[1, n] -> [n, 128], every lane the row's value: ``[:, :1]`` is the column."""
+    return jnp.broadcast_to(row, (_VREG_LANES, row.shape[1])).T
 
 
 # The squashed grids ship their (qi, ki) enumeration as scalar-prefetch SMEM
@@ -157,11 +195,12 @@ def _spec(shape, f, dec):
 
 def _qkv_in_specs(dec, block_q, block_k, D, G, alibi=False):
     """mask, [slopes], q, k, v input specs (shared by fwd and both backward
-    kernels). The alibi slopes ride as a tiny [H, _LANES] fp32 array blocked
-    per query head."""
+    kernels). The alibi slopes ride as a tiny [H, 1, _LANES] fp32 array blocked
+    per query head (as [H, _LANES] Mosaic refuses the block of one head: its
+    last two dimensions have to be the array's own or multiples of (8, 128))."""
     specs = [_spec((1, 1, block_k), lambda b, h, qi, ki: (b, 0, ki), dec)]
     if alibi:
-        specs.append(_spec((1, _LANES), lambda b, h, qi, ki: (h, 0), dec))
+        specs.append(_spec((1, 1, _LANES), lambda b, h, qi, ki: (h, 0, 0), dec))
     specs += [
         _spec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0), dec),
         _spec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // G, ki, 0), dec),
@@ -170,22 +209,23 @@ def _qkv_in_specs(dec, block_q, block_k, D, G, alibi=False):
     return specs
 
 
-def _alibi_add(s, slopes_ref, ki, block_k, col_off=0):
+def _alibi_add(s, slopes_ref, ki, block_k, col_off=0, key_axis=1):
     """s += slope[h] * key-position, in the caller's softmax scale (the
     wrapper pre-folds log2e into the slopes for the base-2 kernels). The HF
     bloom convention (slopes * j); softmax cancels the per-row shift vs
     slopes * (j - i)."""
-    cols = ki * block_k + col_off + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return s + slopes_ref[0, 0] * cols.astype(jnp.float32)
+    cols = ki * block_k + col_off + jax.lax.broadcasted_iota(jnp.int32, s.shape, key_axis)
+    return s + slopes_ref[0, :, :1] * cols.astype(jnp.float32)
 
 
 def _qrow_specs(dec, block_q, D):
     """do, lse, delta input specs (backward) / o, lse output specs (forward)
-    — everything blocked along the query row."""
-    qrow = lambda b, h, qi, ki: (b, h, qi, 0)  # noqa: E731
+    — everything blocked along the query row. The statistics (lse, delta) are
+    ``[B, H, 1, S]`` in HBM, the sequence on the lanes: a ``[.., S, n]`` column
+    is stored n -> 128 lanes wide, which the train scan then stacks and slices."""
     return {
-        "qD": _spec((1, 1, block_q, D), qrow, dec),
-        "qL": _spec((1, 1, block_q, _LANES), qrow, dec),
+        "qD": _spec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0), dec),
+        "qL": _spec((1, 1, 1, block_q), lambda b, h, qi, ki: (b, h, 0, qi), dec),
     }
 
 
@@ -206,23 +246,26 @@ def _sub_slices(block_k: int, k_splits: int):
 
 
 def _sub_score(q, k, mask_ref, slopes_ref, qi, ki, off, c, *, block_q, block_k,
-               masked, mask_block, alibi):
-    """Masked scores for one sub-chunk: s = q @ k[off:off+c]^T (+alibi, +mask).
+               masked, mask_block, alibi, keys_first=False):
+    """Masked scores for one sub-chunk: s = q @ k[off:off+c]^T (+alibi, +mask),
+    ``[block_q, c]``; with ``keys_first`` the same scores transposed, s^T =
+    k[off:off+c] @ q^T, ``[c, block_q]``, so that a ROW of per-query statistics
+    broadcasts down its sublanes (the dkv kernel).
 
     The one scoring implementation shared by the forward and both backward
     kernels — the mask/bias math must never diverge between passes."""
-    s = jax.lax.dot_general(
-        q, k[off:off + c], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [block_q, c]
+    key_axis = 0 if keys_first else 1
+    a, b = (k[off:off + c], q) if keys_first else (q, k[off:off + c])
+    s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     if alibi:
-        s = _alibi_add(s, slopes_ref, ki, block_k, col_off=off)
+        s = _alibi_add(s, slopes_ref, ki, block_k, col_off=off, key_axis=key_axis)
     if mask_block or masked:
         keep = None
         if masked:
-            keep = jnp.broadcast_to(mask_ref[0, 0, off:off + c] > 0, s.shape)
+            kept = mask_ref[0, :, off:off + c]  # [1, c]: keys on the lanes
+            keep = jnp.broadcast_to((_cols_of(kept)[:, :1] if keys_first else kept) > 0, s.shape)
         if mask_block:
-            ck = _causal_keep(qi, ki, s.shape, block_q, block_k, col_off=off)
+            ck = _causal_keep(qi, ki, s.shape, block_q, block_k, col_off=off, key_axis=key_axis)
             keep = ck if keep is None else keep & ck
         s = jnp.where(keep, s, _NEG_INF)
     return s
@@ -303,9 +346,10 @@ def _fwd_kernel(*refs, block_q, block_k, causal, masked, squashed, alibi=False,
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
         m = jnp.max(m_ref[:], axis=-1, keepdims=True)
-        # base-2 logsumexp per row (lane-broadcast); fully-masked rows get -inf.
+        # base-2 logsumexp per row; fully-masked rows get -inf. The column
+        # becomes a lane-dense row here, once a query block.
         lse = jnp.where(l == 0.0, _NEG_INF, m + jnp.log2(l_safe))
-        lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+        lse_ref[0, 0] = _row_of(lse)
 
 
 _PARALLEL_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
@@ -314,8 +358,8 @@ _PARALLEL_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
 def _flash_fwd(q, k, v, mask, slopes, block_q: int, block_k: int, causal: bool,
                masked: bool, alibi: bool, k_splits: int = 1):
     """q,k,v: [B, H(q/kv), S, D] (q pre-scaled). mask: [B, S] int32.
-    slopes: [H, _LANES] fp32 (log2e-scaled; ignored unless alibi).
-    Returns (out, lse)."""
+    slopes: [H, 1, _LANES] fp32 (log2e-scaled; ignored unless alibi).
+    Returns (out, lse): lse fp32 ``[B, H, 1, S]``, base 2."""
     B, H, S, D = q.shape
     Hkv = k.shape[1]
     G = H // Hkv
@@ -324,7 +368,7 @@ def _flash_fwd(q, k, v, mask, slopes, block_q: int, block_k: int, causal: bool,
 
     out_shape = [
         _sds((B, H, S, D), q.dtype, q, k, v, mask),
-        _sds((B, H, S, _LANES), jnp.float32, q, k, v, mask),
+        _sds((B, H, 1, S), jnp.float32, q, k, v, mask),
     ]
     scratch_shapes = [
         pltpu.VMEM((block_q, D), jnp.float32),
@@ -378,34 +422,38 @@ def _flash_fwd(q, k, v, mask, slopes, block_q: int, block_k: int, causal: bool,
 # --------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(*refs, block_q, block_k, causal, masked, squashed, alibi=False,
-                   k_splits=1):
+def _bwd_dq_kernel(*refs, block_q, block_k, causal, masked, squashed, dq_scale,
+                   alibi=False, k_splits=1):
     if squashed:
         (qm_ref, km_ref, mask_ref, *rest) = refs
         slopes_ref = rest.pop(0) if alibi else None
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref) = rest
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref, lse_col, delta_col) = rest
         t = pl.program_id(2)
         qi, ki = qm_ref[t], km_ref[t]
         first, last = ki == 0, ki == qi
     else:
         (mask_ref, *rest) = refs
         slopes_ref = rest.pop(0) if alibi else None
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref) = rest
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref, lse_col, delta_col) = rest
         qi, ki = pl.program_id(2), pl.program_id(3)
         first, last = ki == 0, ki == pl.num_programs(3) - 1
 
     @pl.when(first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
+        # a query block's rows of statistics, turned into columns ONCE for all
+        # of its key blocks (this walk keeps qi while ki runs)
+        lse_col[:] = _cols_of(lse_ref[0, 0])
+        delta_col[:] = _cols_of(delta_ref[0, 0])
 
     def _compute(mask_block):
         q = q_ref[0, 0]
         k = k_ref[0, 0]
         v = v_ref[0, 0]
         do = do_ref[0, 0]
-        lse = jnp.max(lse_ref[0, 0], axis=-1, keepdims=True)  # [block_q, 1]
+        lse = lse_col[:, :1]  # [block_q, 1]
         lse_safe = jnp.where(lse == _NEG_INF, 0.0, lse)
-        delta = jnp.max(delta_ref[0, 0], axis=-1, keepdims=True)
+        delta = delta_col[:, :1]
         sub = _sub_slices(block_k, k_splits)
 
         def _score(off, c):
@@ -443,28 +491,38 @@ def _bwd_dq_kernel(*refs, block_q, block_k, causal, masked, squashed, alibi=Fals
 
     @pl.when(last)
     def _finalize():
-        dq_ref[0, 0] = acc_ref[:].astype(dq_ref.dtype)
+        dq_ref[0, 0] = (acc_ref[:] * dq_scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(*refs, block_q, block_k, causal, masked, squashed, nq_total,
-                    alibi=False, k_splits=1, one_pass=False):
+                    dq_scale, dk_scale, alibi=False, k_splits=1, one_pass=False):
     """dk and dv of a key column, accumulated over its query rows (the grid
-    runs key column outer, query rows inner). With ``one_pass`` the same
-    ``ds`` also makes dq: ``dq_ref`` is a whole head's fp32 ``[S, D]`` slab,
-    resident in VMEM over both inner grid axes, and each block adds its
-    ``ds @ k`` into the slab's rows. A row block's terms still arrive in the
-    order ki = 0, 1, ..., qi, as in ``_bwd_dq_kernel``."""
+    runs key column outer, query rows inner). The query block changes every
+    step here, so the scores are formed TRANSPOSED, ``s^T = k q^T``
+    ``[keys, queries]``: lse and delta arrive as rows and broadcast down the
+    sublanes with no relayout, ``dv += p^T @ do`` and ``dk += ds^T @ q`` are
+    plain products. With ``one_pass`` the same ``ds^T`` also makes dq
+    (``(ds^T)^T @ k``, the one transposed-lhs product): ``dq_acc`` is a whole
+    head's fp32 ``[S, D]`` slab in VMEM scratch over both inner grid axes,
+    each block adds into the slab's rows, and the head's last step writes it
+    out once. A row block's terms still arrive in the order ki = 0, 1, ...,
+    qi, as in ``_bwd_dq_kernel``. The gradients leave in their final form
+    (``dq_scale``, ``dk_scale`` applied in fp32, then the output's dtype): no
+    fp32 copy of them crosses HBM."""
     if squashed:
         (qm_ref, km_ref, mask_ref, *rest) = refs
         t = pl.program_id(2)
         qi, ki = qm_ref[t], km_ref[t]
         first, last, first_of_head = qi == ki, qi == nq_total - 1, t == 0
+        last_of_head = t == pl.num_programs(2) - 1
     else:
         (mask_ref, *rest) = refs
         ki, qi = pl.program_id(2), pl.program_id(3)
         first, last = qi == 0, qi == pl.num_programs(3) - 1
         first_of_head = first & (ki == 0)
+        last_of_head = last & (ki == pl.num_programs(2) - 1)
     slopes_ref = rest.pop(0) if alibi else None
+    dq_acc = rest.pop() if one_pass else None
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *outs, dk_acc, dv_acc) = rest
     dq_ref = outs.pop(0) if one_pass else None
     dk_ref, dv_ref = outs
@@ -472,7 +530,7 @@ def _bwd_dkv_kernel(*refs, block_q, block_k, causal, masked, squashed, nq_total,
     if one_pass:
         @pl.when(first_of_head)
         def _init_dq():
-            dq_ref[...] = jnp.zeros_like(dq_ref)
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
     @pl.when(first)
     def _init():
@@ -484,39 +542,39 @@ def _bwd_dkv_kernel(*refs, block_q, block_k, causal, masked, squashed, nq_total,
         k = k_ref[0, 0]
         v = v_ref[0, 0]
         do = do_ref[0, 0]
-        lse = jnp.max(lse_ref[0, 0], axis=-1, keepdims=True)
+        lse = lse_ref[0, 0]  # [1, block_q]
         lse_safe = jnp.where(lse == _NEG_INF, 0.0, lse)
-        delta = jnp.max(delta_ref[0, 0], axis=-1, keepdims=True)
+        delta = delta_ref[0, 0]
         sub = _sub_slices(block_k, k_splits)
 
         def _score(off, c):
             return _sub_score(q, k, mask_ref, slopes_ref, qi, ki, off, c,
                               block_q=block_q, block_k=block_k, masked=masked,
-                              mask_block=mask_block, alibi=alibi)
+                              mask_block=mask_block, alibi=alibi, keys_first=True)
 
         s_next = _score(*sub[0])
         for idx, (off, c) in enumerate(sub):
-            s = s_next
+            s = s_next  # [c, block_q]
             if idx + 1 < k_splits:
                 s_next = _score(*sub[idx + 1])  # MXU overlaps the VPU passes below
             p = jnp.exp2(s - lse_safe)
             # keep every matmul in the input dtype (bf16) with fp32 accumulation —
             # fp32 operands would cut the MXU rate ~4x (see _bwd_dq_kernel note)
             dv_acc[off:off + c] += jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            dp = jax.lax.dot_general(do, v[off:off + c], (((1,), (1,)), ((), ())),
+            dp = jax.lax.dot_general(v[off:off + c], do, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
             ds = (p * (dp - delta)).astype(q.dtype)
             dk_acc[off:off + c] += jax.lax.dot_general(
-                ds, q, (((0,), (0,)), ((), ())),
+                ds, q, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             if one_pass:
                 rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
-                dq_ref[0, 0, rows, :] += jax.lax.dot_general(
-                    ds, k[off:off + c], (((1,), (0,)), ((), ())),
+                dq_acc[rows, :] += jax.lax.dot_general(
+                    ds, k[off:off + c], (((0,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
 
@@ -532,54 +590,60 @@ def _bwd_dkv_kernel(*refs, block_q, block_k, causal, masked, squashed, nq_total,
 
     @pl.when(last)
     def _finalize():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0, 0] = (dk_acc[:] * dk_scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
+    if one_pass:
+        @pl.when(last_of_head)
+        def _finalize_dq():
+            dq_ref[0, 0] = (dq_acc[...] * dq_scale).astype(dq_ref.dtype)
 
-# The one-pass backward keeps a whole head's dq, fp32 [S, D] with D padded to
-# the 128 lanes of a vreg row, in VMEM, twice (the pipeline double-buffers an
-# output block). This much a buffer (S = 8,192 at head_dim 64 and 128, 4,096
+
+# The one-pass backward keeps a whole head's dq in VMEM, [S, D] with D padded
+# to the 128 lanes of a vreg row: once in fp32 (the scratch it adds into) and
+# twice in the output's dtype (the pipeline double-buffers an output block).
+# Twice this much in all (a bf16 dq of S = 8,192 at head_dim 64 and 128, 4,096
 # at 256) compiles for a v5e beside the [512, 512] temporaries in 12 MiB of
-# the 16 MiB a kernel may scope by default; 6 MiB is the most that does.
-# Longer sequences run the dq kernel and the dkv kernel.
+# the 16 MiB a kernel may scope by default. Longer sequences run the dq kernel
+# and the dkv kernel.
 _ONE_PASS_DQ_BYTES = 4 * 1024 * 1024
-# The one-pass kernel takes a key block in sub-chunks of this many columns
-# (k_splits: the next chunk's q k^T ahead of this one's exp2 and four other
-# products). On a v5e at blocks of 512 that is 4.2-4.8% off a call at head_dim
-# 64 and 128, at S = 2,048 and 8,192; at blocks of 1,024 it changes nothing;
-# chunks of 128 cost 30%, and the PAIR loses 5% to any split (PERF.md, PR 32).
-_ONE_PASS_CHUNK = 256
 
-
-def _one_pass_fits(S: int, D: int) -> bool:
-    return S * _cdiv(D, 128) * 128 * 4 <= _ONE_PASS_DQ_BYTES
+def _one_pass_fits(S: int, D: int, itemsize: int = 2) -> bool:
+    return S * _cdiv(D, 128) * 128 * (4 + 2 * itemsize) <= 2 * _ONE_PASS_DQ_BYTES
 
 
 def _flash_bwd(q, k, v, mask, slopes, out, lse, do, block_q: int, block_k: int,
-               causal: bool, masked: bool, alibi: bool, k_splits: int = 1):
-    """dq, dk, dv (fp32, dk and dv per QUERY head summed over the group here).
+               causal: bool, masked: bool, alibi: bool, k_splits: int = 1,
+               softmax_scale: Optional[float] = None):
+    """The gradients of the caller's q, k, v (``[B, H(q/kv), S, D]``, q's
+    dtype) from the kernels' base-2 quantities: dq times the softmax scale
+    (the log2e of the pre-scale and the ln2 of the base-2 softmax cancel to
+    1, exactly), dk times ln2 (it accumulates against the log2e-pre-scaled
+    q), both applied in fp32 INSIDE the kernels before the one rounding; with
+    grouped heads dk and dv leave the kernel in fp32 per QUERY head and are
+    summed over the group, scaled and rounded here.
     One kernel, ``flash_bwd_dkv``, makes all three from one set of scores
-    while a head's dq fits VMEM (``_one_pass_fits``: a choice from S and D
-    alone); past that ``flash_bwd_dq`` makes dq and ``flash_bwd_dkv`` dk and
-    dv, each forming the scores for itself."""
+    while a head's dq fits VMEM (``_one_pass_fits``: a choice from S, D and
+    the dtype's size alone); past that ``flash_bwd_dq`` makes dq and
+    ``flash_bwd_dkv`` dk and dv, each forming the scores for itself."""
     B, H, S, D = q.shape
     Hkv = k.shape[1]
     G = H // Hkv
     nq, nk = _cdiv(S, block_q), _cdiv(S, block_k)
     squashed = _squash_ok(nq, nk, block_q, block_k, causal)
-    one_pass = _one_pass_fits(S, D)
-    if one_pass and block_k % _ONE_PASS_CHUNK == 0:
-        k_splits = max(k_splits, block_k // _ONE_PASS_CHUNK)
+    one_pass = _one_pass_fits(S, D, q.dtype.itemsize)
 
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B,H,S]
-    delta = jnp.broadcast_to(delta[..., None], (*delta.shape, _LANES))
+    # [B, H, 1, S], as lse
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, :, None]
 
     static = dict(block_q=block_q, block_k=block_k, causal=causal, masked=masked,
-                  squashed=squashed, alibi=alibi, k_splits=k_splits)
+                  squashed=squashed, alibi=alibi, k_splits=k_splits,
+                  dq_scale=_scale(D, softmax_scale))
     extra = (slopes,) if alibi else ()
-    grad = _sds((B, H, S, D), jnp.float32, q, k, v, mask, do)
+    final = _sds((B, H, S, D), q.dtype, q, k, v, mask, do)
+    per_query_head = final if G == 1 else _sds((B, H, S, D), jnp.float32, q, k, v, mask, do)
 
-    def call(kernel, name, walk, dense_semantics, out_specs, scratch):
+    def call(kernel, name, walk, dense_semantics, out_specs, out_shape, scratch):
         """One backward kernel over ``walk`` = (the squashed grid's (qi, ki)
         enumeration, the dense grid's decoder to canonical (qi, ki) for the
         shared specs, the dense grid)."""
@@ -590,7 +654,7 @@ def _flash_bwd(q, k, v, mask, slopes, out, lse, do, block_q: int, block_k: int,
                     + [qrow["qD"], qrow["qL"], qrow["qL"]])
         out_specs = out_specs(dec)
         args = (mask, *extra, q, k, v, do, lse, delta)
-        common = dict(name=name, out_shape=[grad] * len(out_specs), interpret=_interpret())
+        common = dict(name=name, out_shape=out_shape, interpret=_interpret())
         if squashed:
             qm, km = maps(nq)
             return pl.pallas_call(
@@ -620,8 +684,9 @@ def _flash_bwd(q, k, v, mask, slopes, out, lse, do, block_q: int, block_k: int,
         (dq,) = call(
             functools.partial(_bwd_dq_kernel, **static), "flash_bwd_dq",
             (_tri_maps, _DEC_DENSE, (B, H, nq, nk)), _PARALLEL_SEMANTICS,
-            lambda dec: [_qrow_specs(dec, block_q, D)["qD"]],
-            [pltpu.VMEM((block_q, D), jnp.float32)])
+            lambda dec: [_qrow_specs(dec, block_q, D)["qD"]], [final],
+            [pltpu.VMEM((block_q, D), jnp.float32)]
+            + [pltpu.VMEM((block_q, _VREG_LANES), jnp.float32)] * 2)
 
     def dkv_out_specs(dec):
         slab = [_spec((1, 1, S, D), lambda b, h, qi, ki: (b, h, 0, 0), dec)] if one_pass else []
@@ -630,16 +695,18 @@ def _flash_bwd(q, k, v, mask, slopes, out, lse, do, block_q: int, block_k: int,
     # The dense dkv grid iterates (ki outer, qi inner), as the wedge does; a
     # dq slab gathers over the key columns too, so only b and h stay parallel.
     *dq_slab, dk, dv = call(
-        functools.partial(_bwd_dkv_kernel, nq_total=nq, one_pass=one_pass, **static),
+        functools.partial(_bwd_dkv_kernel, nq_total=nq, one_pass=one_pass,
+                          dk_scale=_LN2 if G == 1 else 1.0, **static),
         "flash_bwd_dkv", (_wedge_maps, _DEC_DENSE_KQ, (B, H, nk, nq)),
         ("parallel", "parallel", "arbitrary", "arbitrary") if one_pass else _PARALLEL_SEMANTICS,
-        dkv_out_specs, [pltpu.VMEM((block_k, D), jnp.float32)] * 2)
+        dkv_out_specs, [final] * one_pass + [per_query_head] * 2,
+        [pltpu.VMEM((block_k, D), jnp.float32)] * 2 + [pltpu.VMEM((S, D), jnp.float32)] * one_pass)
     if one_pass:
         (dq,) = dq_slab
 
     if G > 1:
-        dk = dk.reshape(B, Hkv, G, S, D).sum(axis=2)
-        dv = dv.reshape(B, Hkv, G, S, D).sum(axis=2)
+        dk = (dk.reshape(B, Hkv, G, S, D).sum(axis=2) * _LN2).astype(k.dtype)
+        dv = dv.reshape(B, Hkv, G, S, D).sum(axis=2).astype(v.dtype)
     return dq, dk, dv
 
 
@@ -683,16 +750,8 @@ def _flash_vjp_bwd(block_q, block_k, causal, masked, alibi, k_splits, softmax_sc
     qs, kt, vt, mask, slopes, lse, out_bhsd = res
     do = g.transpose(0, 2, 1, 3)
     dq, dk, dv = _flash_bwd(qs, kt, vt, mask, slopes, out_bhsd, lse, do,
-                            block_q, block_k, causal, masked, alibi, k_splits)
-    # Base-2 gradient bookkeeping (kernels compute the base-e ds = p*(dp-δ)):
-    # dq needs scale*log2e*ln2 == plain scale (exact — no ln2 rounding), and
-    # dk, accumulated against the log2e-pre-scaled q, needs ln2 applied here
-    # in fp32 before the downcast.
-    scale = _scale(qs.shape[-1], softmax_scale)
-    dq = (dq * scale).transpose(0, 2, 1, 3).astype(qs.dtype)
-    dk = (dk * _LN2).transpose(0, 2, 1, 3).astype(kt.dtype)
-    dv = dv.transpose(0, 2, 1, 3).astype(vt.dtype)
-    return dq, dk, dv, None, None
+                            block_q, block_k, causal, masked, alibi, k_splits, softmax_scale)
+    return (*(x.transpose(0, 2, 1, 3) for x in (dq, dk, dv)), None, None)
 
 
 _flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -716,12 +775,14 @@ def flash_causal_attention(
     # k_splits > 1 processes each block_k tile as k_splits sub-chunks with the
     # next sub-chunk's QK^T hoisted ahead of the previous one's softmax, so the
     # MXU matmul can overlap the VPU exp2/renormalize passes. Pure
-    # instruction-level restructuring: identical math. Measured on a v5e
-    # (PERF.md, PR 32) it pays only in the one-pass backward, which takes its
-    # chunks of _ONE_PASS_CHUNK columns by itself; the forward and the
-    # two-pass backward are not known to gain from it. A fixed k_splits must stay
-    # valid when short sequences clamp block_k, so degrade to the largest
-    # compatible divisor (sub-chunks divide block_k; >=128 lanes on hardware).
+    # instruction-level restructuring: identical math. Measured on a v5e it is
+    # not known to pay anywhere: the forward and the two-pass backward lose
+    # to any split (PERF.md, PR 32), and since the dkv kernel forms its scores
+    # transposed (PR 44) the one-pass backward loses 5% to chunks of 256 too,
+    # where the old form gained 4.5% and took them by itself. A fixed k_splits
+    # must stay valid when short sequences clamp block_k, so degrade to the
+    # largest compatible divisor (sub-chunks divide block_k; >=128 lanes on
+    # hardware).
     while k_splits > 1 and (block_k % k_splits != 0
                             or (not _interpret() and (block_k // k_splits) % 128 != 0)):
         k_splits -= 1
@@ -749,9 +810,9 @@ def flash_causal_attention(
         # learned per-head slopes, use causal_attention(..., impl='xla').
         slopes = jnp.broadcast_to(
             (jax.lax.stop_gradient(alibi_slopes).astype(jnp.float32)
-             * _LOG2E)[:, None], (H, _LANES))
+             * _LOG2E)[:, None, None], (H, 1, _LANES))
     else:
-        slopes = jnp.zeros((H, _LANES), jnp.float32)
+        slopes = jnp.zeros((H, 1, _LANES), jnp.float32)
 
     out = _flash_attention(q, k, v, keep[:, None, :], slopes,
                            block_q, block_k, True, masked, alibi, k_splits, softmax_scale)
@@ -773,6 +834,6 @@ def flash_causal_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     if Sp != S:  # padded keys reach padded queries alone (module header)
         q, k, v = (jnp.pad(a, ((0, 0), (0, Sp - S), (0, 0), (0, 0))) for a in (q, k, v))
     out, (_, _, _, lse, _) = _flash_core(
-        q, k, v, jnp.ones((B, 1, Sp), jnp.int32), jnp.zeros((H, _LANES), jnp.float32),
+        q, k, v, jnp.ones((B, 1, Sp), jnp.int32), jnp.zeros((H, 1, _LANES), jnp.float32),
         block_q, block_k, True, False, False)
-    return out[:, :S], (lse[..., 0] * _LN2).transpose(0, 2, 1)[:, :S]
+    return out[:, :S], (lse[:, :, 0] * _LN2).transpose(0, 2, 1)[:, :S]

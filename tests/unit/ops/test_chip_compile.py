@@ -92,6 +92,70 @@ def test_flash_backward_is_one_kernel_while_dq_fits_vmem(one_chip, monkeypatch, 
     assert _compiled_kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == kernels
 
 
+@pytest.mark.parametrize("shape,kernels,Hkv", [
+    ((2, 2048, 16, 64), 2, 16), ((1, 2048, 16, 128), 2, 4), ((1, 8192 + 512, 4, 128), 3, 4),
+], ids=["cell-410m", "cell-1.4b-gqa", "first-two-pass"])
+def test_flash_with_a_padding_mask_and_alibi_compiles(one_chip, monkeypatch, shape, kernels, Hkv):
+    """The variants share the statistics' specs: with a padding mask (in the
+    dkv kernel a key block's mask row becomes a column) and alibi slopes
+    (``[H, 1, 8]``, a head a block: as ``[H, 8]`` Mosaic refused the block of
+    one head, and the alibi path had never compiled for the chip)."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    B, S, H, D = shape
+    sds = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+
+    def loss(q, k, v, mask, slopes):
+        return fa.flash_causal_attention(q, k, v, mask=mask, alibi_slopes=slopes).astype(jnp.float32).sum()
+
+    assert _compiled_kernels(jax.grad(loss, argnums=(0, 1, 2)), sds(shape), sds((B, S, Hkv, D)),
+                             sds((B, S, Hkv, D)), sds((B, S), jnp.int32), sds((H,), jnp.float32)) == kernels
+
+
+@pytest.mark.parametrize("shape", [(2, 2048, 16, 64), (1, 2048, 16, 128)], ids=["cell-410m", "cell-1.4b"])
+def test_the_train_scan_stacks_the_flash_statistics_unpadded(one_chip, monkeypatch, shape):
+    """What the two train cells' layer scans keep of flash attention from a
+    micro-step's forward to its backward, and what its backward hands back:
+    the custom VJP's residual is the
+    kernel's own lse, ``[B, H, 1, S]`` rows in ``T(1,128)`` tiles, so the 24
+    layers' stack is its numbers and no more (as ``[B, H, S, 8]`` columns in
+    ``T(8,128)`` tiles it was sixteen times them, 805 MB at the 410M cell's
+    shape), and delta reaches the backward kernel as a row too."""
+    import re
+
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    layers, (B, S, H, D) = 24, shape
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((layers,), jnp.bfloat16, sharding=one_chip)
+
+    def loss(x, w):
+        def layer(h, wl):
+            return h + fa.flash_causal_attention(h * wl, h, h), None
+
+        return jax.lax.scan(layer, x, w)[0].astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile().as_text()
+    assert text.count("tpu_custom_call") == 2 and "flash_fwd" in text and "flash_bwd_dkv" in text
+    assert not re.search(r"f32\[[\d,]*2048,8\]", text)
+    # the backward kernel hands dq, dk and dv over in the model's dtype, scaled inside (no fp32 copy to HBM)
+    assert re.search(r"%flash_bwd_dkv[\w.]* = \(bf16\[", text) and not re.search(r"%flash_bwd_dkv[\w.]* = \(f32", text)
+
+    def stored(dims, order, sub, lanes):  # bytes of an f32 array as its layout's tile pads it
+        dims, (minor, second) = [int(d) for d in dims.split(",")], [int(i) for i in order.split(",")[:2]]
+        dims[minor] = -(-dims[minor] // int(lanes)) * int(lanes)
+        dims[second] = -(-dims[second] // int(sub)) * int(sub)
+        return 4 * int(np.prod(dims))
+
+    stacked = {m.group(0): stored(*m.groups()) for m in re.finditer(
+        r"f32\[(%d,[\d,]+)\]\{([\d,]+):T\((\d+),(\d+)\)" % layers, text)
+        if np.prod([int(d) for d in m.group(1).split(",")]) == layers * B * H * S}
+    assert stacked, "no stacked statistic found"
+    assert max(stacked.values()) <= 2 * layers * B * H * S * 4, stacked
+
+
 def test_flash_attention_partitions_over_four_chips(topo, monkeypatch):
     """GSPMD cannot partition a Mosaic kernel; ``ops.causal_attention`` runs
     it per shard. This is the program the fsdp=4 train step traces."""

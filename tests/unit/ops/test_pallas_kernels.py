@@ -228,17 +228,20 @@ class TestFlashOnePassBackward:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-6, atol=2e-6,
                                        err_msg=name)
 
-    def test_one_pass_sub_chunks_a_block_of_512_in_two(self, monkeypatch):
-        """The one pass takes a key block in chunks of 256 columns, from the
-        block's size alone; ``k_splits=2`` asks the pair for the same chunks."""
+    def test_one_pass_takes_a_block_of_512_whole(self, monkeypatch):
+        """Since the dkv kernel forms its scores transposed the one pass takes
+        a key block whole (in chunks of 256 columns it lost 5% on the chip,
+        where the old form gained as much and chunked by itself); ``k_splits``
+        is still the caller's, and asks the pair for the same chunks."""
         q, k, v, do = (_rand(i, (1, 1, 512, 8)) for i in range(4))
-        mask, slopes = jnp.ones((1, 1, 512), jnp.int32), jnp.zeros((1, fa._LANES))
+        mask, slopes = jnp.ones((1, 1, 512), jnp.int32), jnp.zeros((1, 1, fa._LANES))
         out, lse = fa._flash_fwd(q, k, v, mask, slopes, 512, 512, True, False, False)
         bwd = lambda k_splits: fa._flash_bwd(  # noqa: E731
             q, k, v, mask, slopes, out, lse, do, 512, 512, True, False, False, k_splits)
-        one = bwd(1)
-        # five products a chunk, two chunks, in the diagonal's and the plain block's code
-        assert str(jax.make_jaxpr(lambda: bwd(1))()).count("dot_general") == 5 * 2 * 2
+        # five products a block, in the diagonal's and the plain block's code; a chunk each when asked
+        assert str(jax.make_jaxpr(lambda: bwd(1))()).count("dot_general") == 5 * 2
+        assert str(jax.make_jaxpr(lambda: bwd(2))()).count("dot_general") == 5 * 2 * 2
+        one = bwd(2)
         monkeypatch.setattr(fa, "_ONE_PASS_DQ_BYTES", 0)
         for a, b in zip(one, bwd(2)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-6, atol=2e-6)
@@ -275,6 +278,119 @@ class TestFlashOnePassBackward:
         assert has("flash_bwd_dq") is (not fits)
 
 
+class TestFlashRowStatistics:
+    """lse and delta cross the kernels' boundary as ``[B, H, 1, S]`` rows, the
+    sequence on the lanes (a ``[B, H, S, 8]`` column is stored 128 lanes wide
+    on the chip, and the train scan stacks it). The dkv kernel forms its
+    scores transposed to read them so; the dq kernel turns a query block's
+    rows into columns once. At the cells' blocks of 512, in interpret mode."""
+
+    CASES = {
+        "d64-causal": dict(D=64),
+        "d128-causal": dict(D=128),
+        "d64-mask": dict(D=64, masked=True),
+        "d128-alibi": dict(D=128, alibi=True),
+        "d64-gqa": dict(D=64, H=4, Hkv=2),
+        # four heads' steepest slope is 1/4: at 2,048 keys the bias itself rounds by more than the tolerance
+        "d128-gqa-mask-alibi": dict(D=128, S=1024, H=4, Hkv=2, masked=True, alibi=True),
+        # past _one_pass_fits: flash_bwd_dq turns the rows into columns, flash_bwd_dkv makes dk and dv
+        "d128-two-pass": dict(D=128, S=8704, H=1),
+        "d64-two-pass-mask-alibi": dict(D=64, two_pass=True, masked=True, alibi=True),
+    }
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_lse_rows_and_gradients_match_the_reference(self, case, monkeypatch):
+        from deepspeed_tpu.models.transformer import alibi_slopes
+
+        c = self.CASES[case]
+        B, S, D, H = 1, c.get("S", 2048), c["D"], c.get("H", 2)
+        Hkv = c.get("Hkv", H)
+        q, k, v = (_rand(i, (B, S, h, D)) for i, h in enumerate((H, Hkv, Hkv)))
+        mask = (jnp.asarray(np.random.default_rng(4).integers(0, 2, (B, S)), jnp.int32).at[:, 0].set(1)
+                if c.get("masked") else None)
+        slopes = alibi_slopes(H) if c.get("alibi") else None
+        if c.get("two_pass"):  # the pair at S = 2,048, by the module's budget and no option
+            monkeypatch.setattr(fa, "_ONE_PASS_DQ_BYTES", 0)
+        assert fa._one_pass_fits(S, D) is not (c.get("two_pass") or S > 8192)
+
+        # the forward's statistic, as the kernel hands it over (base 2, [B, H, 1, S])
+        scale = D ** -0.5
+        heads = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+        keep3 = (jnp.ones((B, S), jnp.int32) if mask is None else mask)[:, None, :]
+        slopes2 = jnp.broadcast_to(((jnp.zeros((H,)) if slopes is None else slopes) * fa._LOG2E)[:, None, None],
+                                   (H, 1, fa._LANES)).astype(jnp.float32)
+        _, lse = fa._flash_fwd(heads(q) * (scale * fa._LOG2E), heads(k), heads(v), keep3, slopes2,
+                               512, 512, True, mask is not None, slopes is not None)
+        assert lse.shape == (B, H, 1, S) and lse.dtype == jnp.float32
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, H // Hkv, axis=2),
+                            precision="highest") * scale
+        if slopes is not None:
+            scores = scores + slopes[None, :, None, None] * jnp.arange(S, dtype=jnp.float32)
+        visible = jnp.tril(jnp.ones((S, S), bool))[None, None]
+        if mask is not None:
+            visible = visible & (mask[:, None, None, :] > 0)
+        ref_lse = jax.nn.logsumexp(jnp.where(visible, scores, -jnp.inf), axis=-1)
+        np.testing.assert_allclose(np.asarray(lse[:, :, 0] * fa._LN2), np.asarray(ref_lse),
+                                   atol=2e-5, rtol=2e-5)
+
+        keepq = 1.0 if mask is None else mask.astype(jnp.float32)[:, :, None, None]
+
+        def loss(fn):
+            def f(q, k, v):
+                out = fn(q, k, v)
+                return jnp.sum(keepq * out * jnp.cos(out))
+            return f
+
+        ref = jax.grad(loss(lambda q, k, v: ops.causal_attention(
+            q, k, v, mask=mask, alibi_slopes=slopes, impl="xla")), argnums=(0, 1, 2))(q, k, v)
+        got = jax.grad(loss(lambda q, k, v: fa.flash_causal_attention(
+            q, k, v, mask=mask, alibi_slopes=slopes)), argnums=(0, 1, 2))(q, k, v)
+        for name, r, g in zip(("dq", "dk", "dv"), ref, got):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=5e-5, rtol=5e-5, err_msg=name)
+
+    @pytest.mark.parametrize("Hkv,two_pass", [(4, False), (2, False), (4, True)], ids=["heads", "grouped", "two-pass"])
+    def test_gradients_leave_the_kernels_in_their_final_form(self, Hkv, two_pass, monkeypatch):
+        """dq times the softmax scale and dk times ln2 are applied in fp32
+        inside the kernels and rounded once there: no fp32 ``[B, H, S, D]``
+        gradient crosses HBM to be scaled, transposed and cast by XLA. With
+        grouped heads dk and dv stay fp32 per query head until the sum over
+        the group. The results agree with the float32 kernels' at bf16's tolerance."""
+        B, S, H, D = 1, 64, 4, 16
+        q, k, v, do = (_rand(i, (B, h, S, D), jnp.bfloat16) for i, h in enumerate((H, Hkv, Hkv, H)))
+        mask, slopes = jnp.ones((B, 1, S), jnp.int32), jnp.zeros((H, 1, fa._LANES))
+        if two_pass:
+            monkeypatch.setattr(fa, "_ONE_PASS_DQ_BYTES", 0)
+        out, lse = fa._flash_fwd(q, k, v, mask, slopes, 16, 16, True, False, False)
+        bwd = lambda do: fa._flash_bwd(q, k, v, mask, slopes, out, lse, do, 16, 16, True, False, False,  # noqa: E731
+                                       softmax_scale=0.3)
+        calls = [e for e in jax.make_jaxpr(bwd)(do).eqns if e.primitive.name == "pallas_call"]
+        made = [str(o.aval.dtype) for e in calls for o in e.outvars]
+        per_head = "bfloat16" if Hkv == H else "float32"
+        assert made == ["bfloat16", per_head, per_head], made
+        dq, dk, dv = bwd(do)
+        assert {g.dtype for g in (dq, dk, dv)} == {jnp.dtype(jnp.bfloat16)}
+        assert (dq.shape, dk.shape, dv.shape) == (q.shape, k.shape, v.shape)
+        # the same quantities in fp32, scaled and rounded outside the kernels
+        f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+        rq, rk, rv = fa._flash_bwd(f32(q), f32(k), f32(v), mask, slopes, f32(out), lse, f32(do), 16, 16,
+                                   True, False, False, softmax_scale=0.3)
+        for name, got, ref in (("dq", dq, rq), ("dk", dk, rk), ("dv", dv, rv)):
+            np.testing.assert_allclose(np.asarray(f32(got)), np.asarray(ref), rtol=2e-2, atol=2e-2, err_msg=name)
+
+    def test_the_serving_statistic_is_the_row_in_natural_units(self):
+        """``flash_causal_attention_lse`` (EVA's prefill) gives the kernel's row
+        back as ``[B, S, H]``, a length off the blocks padded and cut."""
+        B, S, H, D = 2, 100, 2, 16
+        q, k, v = (_rand(i, (B, S, H, D)) for i in range(3))
+        out, lse = fa.flash_causal_attention_lse(q, k, v, block_q=32, block_k=32)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") * D ** -0.5
+        ref = jax.nn.logsumexp(jnp.where(jnp.tril(jnp.ones((S, S), bool)), scores, -jnp.inf), axis=-1)
+        assert lse.shape == (B, S, H)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(ref.transpose(0, 2, 1)), atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ops.causal_attention(q, k, v, impl="xla")),
+                                   atol=2e-5, rtol=2e-5)
+
+
 @pytest.mark.parametrize("reading,kernels", [
     ("fwd", ["flash_fwd"]),
     ("bwd", ["flash_fwd", "flash_bwd_dkv"]),
@@ -299,6 +415,76 @@ def test_flash_kernel_bench_runs_each_reading(reading, kernels):
     assert got["us_per_block"] == pytest.approx(1e3 * got["ms_per_call"] / got["blocks"])
     assert got["roofline_pct"] == pytest.approx(100.0 * got["least_ms"] / got["ms_per_call"])
     assert set(bench.SHAPES.values()) == {(2, 2048, 16, 64), (1, 2048, 16, 128)}
+    # no Mosaic call in interpret mode: the stored bytes are read from a chip's compiled text alone
+    assert got["stat_bytes_per_call"] == {} and got["stat_numbers_bytes"] == 4 * 1 * 2 * 32
+
+
+@pytest.mark.parametrize("stat,tile,stored", [
+    ("f32[2,16,1,2048]{3,2,1,0", "T(1,128)", 2 * 16 * 2048 * 4),          # a row: its numbers
+    ("f32[2,16,2048,8]{3,2,1,0", "T(8,128)", 2 * 16 * 2048 * 128 * 4),    # a column of 8: sixteen-fold
+])
+def test_flash_kernel_bench_reads_the_statistics_as_the_program_stores_them(stat, tile, stored):
+    """``stat_bytes``: the float32 statistics on a kernel's own line of a
+    compiled program (results inline, operands by the lines that make them),
+    each padded as its layout's tile says; the gradients, q, k, v and the
+    mask are not statistics."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "..", "tools", "flash_kernel_bench.py")
+    spec = importlib.util.spec_from_file_location("flash_kernel_bench", path)
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    big = "2,16,2048,64]{3,2,1,0:T(8,128)"
+    text = "\n".join([
+        f"  %fusion.1 = {stat}:{tile}S(1)}} fusion(%p.1), kind=kLoop",
+        f"  %gte.2 = {stat}:{tile}}} get-tuple-element(%p.2), index=1",
+        f"  %do.3 = bf16[{big}(2,1)}} parameter(3)",
+        f"  %flash_fwd.4 = (bf16[{big}(2,1)S(1)}}, {stat}:{tile}S(1)}}) custom-call(%do.3, %do.3), "
+        'custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/while/body/flash_fwd/pallas_call"}',
+        f"  %flash_bwd_dkv.5 = (f32[{big}}}, f32[{big}}}) custom-call(%do.3, %gte.2, %fusion.1), "
+        'custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/while/body/flash_bwd_dkv/pallas_call"}',
+    ])
+    assert bench.stat_bytes(text, (2, 2048, 16, 64)) == {"flash_fwd": stored, "flash_bwd_dkv": 2 * stored}
+
+
+def test_train_step_tool_reads_the_backward_body_s_collectives():
+    """``tools/train_step_for_described_chip.py`` (ROADMAP S4 reads its
+    output): of a compiled text, the computation that holds the flash backward
+    kernel beside collectives, its collectives in schedule order, and the
+    memory space of each one's buffers (``S(1)`` is the chip's fast memory)."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "..", "tools", "train_step_for_described_chip.py")
+    spec = importlib.util.spec_from_file_location("train_step_tool", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    big = "bf16[8192,1,512]{2,0,1:T(8,128)(2,1)"
+    text = "\n".join([
+        "HloModule jit_train_step", "",
+        "%forward.1 (p: bf16[8]) -> bf16[8] {",
+        f"  %collective-permute-start.1 = ({big}}}, {big}}}) collective-permute-start(%p)",
+        "}", "",
+        "%backward.2 (p: bf16[8]) -> bf16[8] {",
+        "  %p = bf16[8]{0} parameter(0)",
+        f"  %collective-permute-start.23 = ({big}S(1)}}, {big}}}) collective-permute-start(%p), "
+        'metadata={op_name="jit(train_step)/while/body/layers/mlp/w_down/dot_general"}',
+        "  %flash_bwd_dkv.1 = (bf16[1,16,2048,128]{3,2,1,0}, bf16[1,16,2048,128]{3,2,1,0}) custom-call(%p), "
+        'custom_call_target="tpu_custom_call", metadata={op_name="a/flash_bwd_dkv/pallas_call"}',
+        f"  %fusion.5 = {big}}} fusion(%p), kind=kOutput",
+        f"  %collective-permute-done.23 = {big}}} collective-permute-done(%collective-permute-start.23)",
+        "  %all-reduce.101 = bf16[2048]{0:T(1024)(128)(2,1)S(1)} all-reduce(%p)",
+        "}",
+    ])
+    rows = tool.backward_body(text)
+    assert [r[0] for r in rows] == ["collective-permute-start.23", "flash_bwd_dkv.1", "fusion.5",
+                                    "collective-permute-done.23", "all-reduce.101"]
+    assert rows[0][3] == "mlp/w_down/dot_general"
+    assert tool.collectives(rows) == [("collective-permute-start.23", "VH"), ("flash_bwd_dkv.1", "HH"),
+                                      ("collective-permute-done.23", "H"), ("all-reduce.101", "V")]
+    with pytest.raises(ValueError):
+        tool.backward_body(text.replace("flash_bwd_dkv", "other_kernel"))
 
 
 class TestNorms:

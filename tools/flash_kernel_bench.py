@@ -11,14 +11,20 @@ chooses from the shape: one pass while a head's dq fits its VMEM budget) and
 and the dkv kernel run, as they do for sequences past the budget). The
 backward readings include the call's XLA glue (delta = sum(do * o), and the
 three adds that chain one call to the next: 0.25 us a block beside the
-trace's kernel seconds). Several
+trace's kernel seconds); since PR 44 the kernels hand dq, dk and dv over in
+the inputs' dtype, scaled inside. Several
 hundred calls under one jit (each call's input depends on the call before,
 so nothing is hoisted), timed on the host's clock around
 `block_until_ready`; one JSON line a reading with the time a call, the time a
 score block and the share of the roofline by the benchmark's own count and
 peaks (`benchmarks/lib/costs.py::flash_forward_cost`, `flash_backward_cost`:
-five products a block, however often a kernel forms the scores). A time comes
-only from a chip: without one this exits 1.
+five products a block, however often a kernel forms the scores). Beside each
+time, `stat_bytes_per_call`: what the per-query-row statistics (lse out of the
+forward; lse and delta into the backward) take in HBM a call AS THE COMPILED
+PROGRAM STORES THEM, by kernel, read from the compiled text's shapes and tiles
+(`[B, H, 1, S]` rows in `T(1,128)` tiles are B*H*S*4 bytes; a `[B, H, S, 8]`
+column in `T(8,128)` tiles was sixteen times its numbers). A time comes only
+from a chip: without one this exits 1.
 """
 
 from __future__ import annotations
@@ -40,6 +46,40 @@ SHAPES = {  # [B, S, H, D] of one call, by the cell that makes it
     "pythia-1.4b.train.zero3-4chip": (1, 2048, 16, 128),
 }
 READINGS = ("fwd", "bwd", "bwd_pair")
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+_MADE = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = ([a-z]\w*\[[\d,]*\]\S*) ")
+_F32 = re.compile(r"f32\[([\d,]+)\]\{([\d,]+)(?::T\((\d+),(\d+)\))?")
+
+
+def stat_bytes(compiled_text: str, shape) -> dict:
+    """{kernel: bytes of its float32 statistics as stored}, from a compiled
+    program's text: every f32 result or operand of a kernel's call with fewer
+    elements than a ``[B, H, S, D]`` gradient and at least ``B*H*S`` (the
+    slopes are smaller), each dimension padded as its layout's tile says."""
+    B, S, H, D = shape
+    lines = compiled_text.splitlines()
+    made = {m.group(1): m.group(2) for m in (_MADE.match(line) for line in lines) if m}
+    found = {}
+    for line in lines:
+        if "tpu_custom_call" not in line:
+            continue
+        name = next((k for k in KERNELS if re.search(r'[/("]%s[/)"]' % k, line)), None)
+        if name is None:
+            continue
+        results, _, rest = line.partition(" custom-call(")
+        operands = [made.get(o, "") for o in re.findall(r"%([\w.\-]+)", rest.split(")", 1)[0])]
+        total = 0
+        for dims, order, sub, lanes in _F32.findall(" ".join([results.split(" = ", 1)[1], *operands])):
+            dims = [int(d) for d in dims.split(",")]
+            if not B * H * S <= int(np.prod(dims)) < B * H * S * D:
+                continue
+            minor, second = (int(i) for i in order.split(",")[:2])
+            if lanes:
+                dims[minor] = -(-dims[minor] // int(lanes)) * int(lanes)
+                dims[second] = -(-dims[second] // int(sub)) * int(sub)
+            total += 4 * int(np.prod(dims))
+        found[name] = total
+    return found
 
 
 def measure(reading: str, shape, block: int = 512, seed: int = 0, calls: int = 200,
@@ -55,7 +95,7 @@ def measure(reading: str, shape, block: int = 512, seed: int = 0, calls: int = 2
     q, k, v, do = (jax.random.normal(kk, (B, H, S, D), jnp.bfloat16) for kk in keys)
     q = q * jnp.asarray(D ** -0.5 * fa._LOG2E, q.dtype)  # as _flash_core hands it over
     mask = jnp.ones((B, 1, S), jnp.int32)
-    slopes = jnp.zeros((H, fa._LANES), jnp.float32)
+    slopes = jnp.zeros((H, 1, fa._LANES), jnp.float32)
     small = jnp.asarray(1e-3, q.dtype)
 
     def fwd(q):
@@ -77,7 +117,10 @@ def measure(reading: str, shape, block: int = 512, seed: int = 0, calls: int = 2
     # the pair is what _flash_bwd runs when the dq slab is over its budget
     budget = 0 if reading == "bwd_pair" else fa._ONE_PASS_DQ_BYTES
     with mock.patch.object(fa, "_ONE_PASS_DQ_BYTES", budget):
-        lowered = many.lower(q, do).as_text(debug_info=True)
+        traced = many.lower(q, do)
+        lowered = traced.as_text(debug_info=True)
+        many = traced.compile()  # the one compile: its text is read and it is what runs
+        stats = stat_bytes(many.as_text(), shape)
         result = jax.block_until_ready(many(q, do))
         times = []
         for _ in range(repeats):
@@ -95,8 +138,8 @@ def measure(reading: str, shape, block: int = 512, seed: int = 0, calls: int = 2
             "calls": calls, "ms_per_call": 1e3 * call, "ms_per_call_min": 1e3 * min(times),
             "blocks": blocks, "us_per_block": 1e6 * call / blocks,
             "least_ms": 1e3 * least, "bound": bound, "roofline_pct": 100.0 * least / call,
-            "kernels": [name for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
-                        if re.search(r'[/("]%s[/)]' % name, lowered)],
+            "kernels": [name for name in KERNELS if re.search(r'[/("]%s[/)]' % name, lowered)],
+            "stat_bytes_per_call": stats, "stat_numbers_bytes": 4 * B * H * S,
             "finite": bool(jnp.isfinite(result.astype(jnp.float32)).all())}
 
 
