@@ -559,6 +559,15 @@ def _made_again(what):
     return (nn.remat if isinstance(what, type) else jax.checkpoint)(what, prevent_cse=False)
 
 
+def _gathered(module: nn.Module, name: str):
+    """``dot_general=`` of the product that reads ``module``'s ``name/kernel``:
+    None (flax's own) unless the engine published one for the step it traces
+    (under ZeRO-3 the weight's own gather, ``runtime/zero.py``)."""
+    from deepspeed_tpu.topology.mesh import dot_general_for
+
+    return dot_general_for(module.path + (name, "kernel"))
+
+
 def _mlp_activation(name: str):
     """``act_fn(name)`` as a dense MLP applies it between its products: made
     again from its input in the backward, but for the erf form, which keeps
@@ -665,11 +674,11 @@ class Attention(nn.Module):
         cfg = self.config
         hd = cfg.dims_per_head
         qkv_bias = cfg.qkv_bias if cfg.qkv_bias is not None else cfg.norm == "layernorm"
-        q = nn.DenseGeneral((cfg.num_heads, hd), use_bias=qkv_bias,
+        q = nn.DenseGeneral((cfg.num_heads, hd), use_bias=qkv_bias, dot_general=_gathered(self, "wq"),
                             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wq")(x)
-        k = nn.DenseGeneral((cfg.kv_heads, hd), use_bias=qkv_bias,
+        k = nn.DenseGeneral((cfg.kv_heads, hd), use_bias=qkv_bias, dot_general=_gathered(self, "wk"),
                             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wk")(x)
-        v = nn.DenseGeneral((cfg.kv_heads, hd), use_bias=qkv_bias,
+        v = nn.DenseGeneral((cfg.kv_heads, hd), use_bias=qkv_bias, dot_general=_gathered(self, "wv"),
                             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wv")(x)
 
         if cfg.position == "rope":
@@ -747,7 +756,7 @@ class Attention(nn.Module):
                                    **dict(cfg.attn_kwargs or ()))  # [B,S,H,hd]
             out = ulysses_unshard(out)
         dense_bias = cfg.dense_bias if cfg.dense_bias is not None else cfg.norm == "layernorm"
-        out = nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=dense_bias,
+        out = nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=dense_bias, dot_general=_gathered(self, "wo"),
                               dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wo")(out)
         if cfg.dropout > 0:
             out = nn.Dropout(cfg.dropout, deterministic=not train)(out)
@@ -837,7 +846,7 @@ class EvaAttention(nn.Module):
         hd, kvH = cfg.dims_per_head, cfg.kv_heads
 
         def dense(features, name, **kw):
-            return nn.DenseGeneral(features, use_bias=False, dtype=cfg.dtype,
+            return nn.DenseGeneral(features, use_bias=False, dtype=cfg.dtype, dot_general=_gathered(self, name),
                                    param_dtype=cfg.param_dtype, name=name, **kw)
 
         q, k, v = dense((cfg.num_heads, hd), "wq")(x), dense((kvH, hd), "wk")(x), dense((kvH, hd), "wv")(x)
@@ -875,18 +884,20 @@ class LatentAttention(nn.Module):
     def __call__(self, x, mask, positions, train: bool):
         cfg = self.config
         H, nope, rope_d, vd = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        dense = functools.partial(nn.DenseGeneral, use_bias=False, dtype=cfg.dtype,
-                                  param_dtype=cfg.param_dtype)
-        c_q = _norm(cfg, "q_norm")(dense(cfg.q_lora_rank, name="wq_a")(x))
-        q = dense((H, nope + rope_d), name="wq_b")(c_q)
-        kv = dense(cfg.kv_lora_rank + rope_d, name="wkv_a")(x)
+        def dense(features, name, **kw):
+            return nn.DenseGeneral(features, use_bias=False, dtype=cfg.dtype, dot_general=_gathered(self, name),
+                                   param_dtype=cfg.param_dtype, name=name, **kw)
+
+        c_q = _norm(cfg, "q_norm")(dense(cfg.q_lora_rank, "wq_a")(x))
+        q = dense((H, nope + rope_d), "wq_b")(c_q)
+        kv = dense(cfg.kv_lora_rank + rope_d, "wkv_a")(x)
         c_kv = _norm(cfg, "kv_norm")(kv[..., : cfg.kv_lora_rank])
         rot = cfg.latent_rotary
         rope = functools.partial(rope_at, positions=positions, theta=cfg.rope_theta,
                                  interleaved=cfg.rope_interleaved, inv_freq=rot.inv_freq)
         k_rope = rope(kv[..., None, cfg.kv_lora_rank:])  # ONE head, shared by all
         q_rope = rope(q[..., nope:])
-        kv_up = dense((H, nope + vd), name="wkv_b")(c_kv)
+        kv_up = dense((H, nope + vd), "wkv_b")(c_kv)
         q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
         k = jnp.concatenate([kv_up[..., :nope], jnp.broadcast_to(k_rope, q_rope.shape)], axis=-1)
         v = kv_up[..., nope:]
@@ -900,7 +911,7 @@ class LatentAttention(nn.Module):
             v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, width - vd)))
         out = causal_attention(q, k, v, mask=mask, impl=cfg.attn_impl, softmax_scale=rot.softmax_scale,
                                **dict(cfg.attn_kwargs or ()))[..., :vd]
-        return dense(cfg.hidden_size, axis=(-2, -1), name="wo")(out)
+        return dense(cfg.hidden_size, "wo", axis=(-2, -1))(out)
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -965,8 +976,11 @@ class Mamba2Mixer(nn.Module):
         from deepspeed_tpu.ops import ssm
 
         cfg, s = self.config, self.config.ssm
-        dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype)
-        zxbcdt = dense(s.proj_dim, name="ssm_in_proj")(x)
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                            dot_general=_gathered(self, name), name=name)
+
+        zxbcdt = dense(s.proj_dim, "ssm_in_proj")(x)
         heads = functools.partial(self.param, shape=(s.n_heads,), dtype=cfg.param_dtype)
         leaves = {
             "ssm_conv": _ConvKernel(s.d_conv, s.conv_dim, cfg.param_dtype, name="ssm_conv")(),
@@ -976,7 +990,7 @@ class Mamba2Mixer(nn.Module):
         }
         new_lens = None if mask is None else (mask > 0).sum(axis=1).astype(jnp.int32)
         y, _, _ = ssm.mix(zxbcdt, leaves, s, cfg.norm_eps, new_lens=new_lens)
-        return dense(cfg.hidden_size, name="ssm_out_proj")(y)
+        return dense(cfg.hidden_size, "ssm_out_proj")(y)
 
 
 class MLP(nn.Module):
@@ -987,19 +1001,17 @@ class MLP(nn.Module):
         cfg = self.config
         bias = cfg.mlp_bias if cfg.mlp_bias is not None else (
             cfg.dense_bias if cfg.dense_bias is not None else cfg.norm == "layernorm")
+        def dense(features, name):
+            return nn.Dense(features, use_bias=bias, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                            dot_general=_gathered(self, name), name=name)
+
         if cfg.activation == "silu_glu":
-            gate = nn.Dense(cfg.intermediate_size, use_bias=bias, dtype=cfg.dtype,
-                            param_dtype=cfg.param_dtype, name="w_gate")(x)
-            up = nn.Dense(cfg.intermediate_size, use_bias=bias, dtype=cfg.dtype,
-                            param_dtype=cfg.param_dtype, name="w_up")(x)
+            gate, up = dense(cfg.intermediate_size, "w_gate")(x), dense(cfg.intermediate_size, "w_up")(x)
             # ``gate``, ``up`` and ``h`` are kept (the products' own), the sigmoid is made again
             h = _made_again(lambda gate, up: nn.silu(gate) * up)(gate, up)
         else:
-            h = nn.Dense(cfg.intermediate_size, use_bias=bias, dtype=cfg.dtype,
-                            param_dtype=cfg.param_dtype, name="w_up")(x)
-            h = _mlp_activation(cfg.activation)(h)
-        out = nn.Dense(cfg.hidden_size, use_bias=bias, dtype=cfg.dtype,
-                            param_dtype=cfg.param_dtype, name="w_down")(h)
+            h = _mlp_activation(cfg.activation)(dense(cfg.intermediate_size, "w_up")(x))
+        out = dense(cfg.hidden_size, "w_down")(h)
         if cfg.dropout > 0:
             out = nn.Dropout(cfg.dropout, deterministic=not train)(out)
         return out

@@ -48,6 +48,7 @@ from deepspeed_tpu.runtime.precision import (
 from deepspeed_tpu.topology.mesh import (
     batch_pspec,
     build_mesh,
+    dot_general_context,
     get_data_parallel_world_size,
     set_mesh,
 )
@@ -1243,6 +1244,11 @@ class DeepSpeedTPUEngine:
         # Device placement of the bf16 COMPUTE params (also the master
         # placement unless offload moves the masters off-device).
         self._device_param_sharding = self.param_sharding
+        # ZeRO-3 over fsdp > 1: a scanned layer's products read their weight's
+        # placement here and gather it themselves (zero.ScanGathers)
+        self._scan_gathers = zero_mod.scan_gathers(
+            self._hpz_compute_sharding or self._device_param_sharding, param_shapes, mesh
+        ) if self.zero_config.stage >= 3 else None
         if self.offload_mode == "memories":
             # Masters + moments live in host memory inside the one compiled
             # step; XLA streams them (reference: CPU optimizer partition).
@@ -1445,6 +1451,12 @@ class DeepSpeedTPUEngine:
             return spec if spec is not None else PartitionSpec()
 
         return jax.tree_util.tree_map_with_path(one, param_shapes)
+
+    @property
+    def zero_gather_mb(self) -> int:
+        """MB a chip receives a micro-step from the gathers the layer scan
+        states itself under ZeRO-3, forward and backward; 0 where it states none."""
+        return round(self._scan_gathers.received_bytes / 1e6) if self._scan_gathers is not None else 0
 
     # ----------------------------------------------------------- train step
     def _loss_and_aux(self, params, batch, rng):
@@ -1808,7 +1820,9 @@ class DeepSpeedTPUEngine:
                     )
                     stats = None
                 else:
-                    (_, (loss, stats)), grads = grad_fn(compute_params, micro_batch, jax.random.fold_in(step_rng, i))
+                    with dot_general_context(self._scan_gathers):
+                        (_, (loss, stats)), grads = grad_fn(
+                            compute_params, micro_batch, jax.random.fold_in(step_rng, i))
                 with jax.named_scope("grad_accum"):  # the accumulator's casts and adds, in a device trace
                     if zpp_fn is None:
                         grads = cast_floating(grads, accum_dtype)
@@ -2424,8 +2438,10 @@ class DeepSpeedTPUEngine:
             # the fused program has no separable fwd/bwd/step phases — this
             # span is the whole optimizer step's dispatch; its device time
             # is in the device rows of the same jax.profiler trace
-            with self._tracer.span("step", fused=True):
+            with self._tracer.span("step", fused=True) as span:
                 self.state, metrics = self._train_step(self.state, placed)
+                # MB a chip receives a micro-step from the layer scan's own gathers (counted where they are traced)
+                span.set_metadata(zero_gather_mb=self.zero_gather_mb)
             self.throughput_timer.stop()
         with self._tracer.span("post_step"):
             self._post_step(metrics, diag_t0)
@@ -2614,7 +2630,8 @@ class DeepSpeedTPUEngine:
                         loss, _ = self._loss_and_aux(p, b, r)
                         return loss.astype(jnp.float32) * scale, loss
 
-                    (_, loss), grads = jax.value_and_grad(scaled, has_aux=True)(params, micro, rng)
+                    with dot_general_context(None if offload_split else self._scan_gathers):
+                        (_, loss), grads = jax.value_and_grad(scaled, has_aux=True)(params, micro, rng)
                     # same dtype rule as the compiled steps (Twin-Flow stays fp32)
                     acc_dt = (jnp.float32 if self._twin_ratio is not None
                               else self._accum_dtype)

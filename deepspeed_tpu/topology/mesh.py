@@ -21,7 +21,7 @@ also act as data-parallel for parameter purposes (reference
 from __future__ import annotations
 
 import contextlib
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -141,6 +141,28 @@ def mesh_context(mesh: Mesh):
         yield mesh
     finally:
         _ACTIVE_MESH = prev
+
+
+# What a model's products ask while the engine traces a step: ``path`` of the
+# weight -> a ``dot_general`` of the engine's own, or None for flax's. Nothing
+# is published outside such a trace (ZeRO-3 publishes ``runtime/zero.py``'s).
+_DOT_GENERALS: Optional[Callable[[Tuple[str, ...]], Optional[Callable]]] = None
+
+
+@contextlib.contextmanager
+def dot_general_context(provider: Optional[Callable[[Tuple[str, ...]], Optional[Callable]]]):
+    global _DOT_GENERALS
+    prev, _DOT_GENERALS = _DOT_GENERALS, provider
+    try:
+        yield provider
+    finally:
+        _DOT_GENERALS = prev
+
+
+def dot_general_for(path: Sequence[str]) -> Optional[Callable]:
+    """``dot_general=`` for the flax product that reads the parameter at
+    ``path``: None (flax's own) unless the trace under way published another."""
+    return None if _DOT_GENERALS is None else _DOT_GENERALS(tuple(path))
 
 
 # ---------------------------------------------------------------------------

@@ -156,6 +156,88 @@ def test_the_train_scan_stacks_the_flash_statistics_unpadded(one_chip, monkeypat
     assert max(stacked.values()) <= 2 * layers * B * H * S * 4, stacked
 
 
+def test_the_zero3_layer_scan_states_its_own_collectives(topo, monkeypatch):
+    """The ZeRO-3 cell's own ``train_step`` (``pythia-1.4b.train.zero3-4chip``,
+    real widths, cut to two layers), compiled for the described 2x2 with
+    ``tools/train_step_for_described_chip.py``: since PR 45 the products of
+    the scanned layer gather their weights themselves (``runtime/zero.py``),
+    so the scan's bodies hold the six large leaves as six whole all-gathers
+    each (alone or inside an ``async_collective_fusion``), the backward body
+    hands their gradients over under ``zero_scatter`` (a reduce-scatter, or
+    the hops of a ring the program wrote itself: 75.5 MB a layer, what a
+    reduce-scatter sends), and the partitioner's rings of
+    ``collective-permute`` (75.5 MB in the forward body, 192.9 MB in the
+    backward body at the parent) are gone. The gathered weight is never a
+    residual of the scan, and a shard dimension that is not the leaf's own
+    would show as an all-to-all of hundreds of MB. ``temp_gb`` at this cut was
+    1.446 at PR 44's tree (my compile for the described chip, PR 45)."""
+    import importlib.util
+    import json
+    import os
+    import pkgutil
+    import re
+
+    import deepspeed_tpu.ops.pallas as pallas_pkg
+    from benchmarks.lib import program
+    from deepspeed_tpu.models import causal_lm_spec
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.topology import mesh as mesh_mod
+
+    root = os.path.join(os.path.dirname(__file__), "..", "..", "..")
+    spec = importlib.util.spec_from_file_location(
+        "train_step_tool", os.path.join(root, "tools", "train_step_for_described_chip.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    # the tool tells the program it is on the chip; put back what it sets when the test ends
+    monkeypatch.setattr(registry, "_default_backend", registry._default_backend)
+    monkeypatch.setattr(mesh_mod, "_ACTIVE_MESH", mesh_mod._ACTIVE_MESH)
+    for info in pkgutil.iter_modules(pallas_pkg.__path__):
+        module = __import__(f"deepspeed_tpu.ops.pallas.{info.name}", fromlist=["_interpret"])
+        if hasattr(module, "_interpret"):
+            monkeypatch.setattr(module, "_interpret", module._interpret)
+
+    cell = "pythia-1.4b.train.zero3-4chip"
+    workload = json.load(open(os.path.join(root, "benchmarks", "workloads", cell + ".json")))
+    config = json.load(open(os.path.join(root, "benchmarks", "configs", workload["config"] + ".json")))
+    config["num_hidden_layers"] = layers = 2
+    sequences, seq_len = int(workload["traffic"]["sequences"]), int(workload["traffic"]["seq_len"])
+    compiled = tool.compile_train_step(
+        causal_lm_spec(program.model_config(config, jnp.bfloat16), example_seq_len=seq_len),
+        dict(workload["engine"]), {"input_ids": np.zeros((sequences, seq_len), np.int32)})
+    text = compiled.as_text()
+    found = tool.census(text)
+    forward, backward = (found[tool.name_of(tool.holding(text, kernel))] for kernel in ("flash_fwd", "flash_bwd_dkv"))
+
+    E, I = config["hidden_size"], config["intermediate_size"]
+    leaves = {"attn/wq": 2 * E * E, "attn/wk": 2 * E * E, "attn/wv": 2 * E * E, "attn/wo": 2 * E * E,
+              "mlp/w_up": 2 * E * I, "mlp/w_down": 2 * E * I}  # bytes of a layer's large leaves, bf16
+    leaf_of = lambda op: op.split("layers/")[-1].split("/shard_map")[0]  # noqa: E731
+    for body in (forward, backward):
+        gathers = sorted((leaf_of(op), round(mb * 1e6)) for kind, mb, op in body
+                         if kind == "all-gather" and "zero_gather" in op)
+        assert gathers == sorted(leaves.items())
+        # no ring of the partitioner's: what permutes it leaves are an overhang (2.4 MB at most)
+        assert sum(mb for kind, mb, op in body if kind == "collective-permute" and "zero_scatter" not in op) < 0.05 * 75.5
+    assert not any("zero_scatter" in op or kind == "reduce-scatter" for kind, _, op in forward)
+    # every leaf's gradient leaves under ``zero_scatter``, as a reduce-scatter or as hops the program wrote itself;
+    # a chip sends three quarters of a leaf and no more (a whole leaf's all-reduce would send twice that)
+    leaving = [(leaf_of(op), kind, mb) for kind, mb, op in backward if "zero_scatter" in op]
+    assert {leaf for leaf, _, _ in leaving} == set(leaves)
+    assert {kind for _, kind, _ in leaving} <= {"reduce-scatter", "collective-permute"}
+    hops = sum(mb for _, kind, mb in leaving if kind == "collective-permute")
+    assert hops <= 0.75 * sum(leaves.values()) / 1e6 + 0.1, hops
+    assert all(mb < 1 for kind, mb, _ in backward if kind == "all-reduce")
+
+    # no residual of the scan is a gathered weight: nothing stacks a whole leaf over the layers
+    whole = [(E, I), (I, E), (E, config["num_attention_heads"], E // config["num_attention_heads"])]
+    whole += [shape[1:] + shape[:1] for shape in whole]
+    for shape in whole:
+        assert not re.search(r"bf16\[%d,%s\]" % (layers, ",".join(str(d) for d in shape)), text), shape
+    assert compiled.memory_analysis().temp_size_in_bytes / 1e9 <= 1.446 + 0.3
+    largest = max(mb for body in found.values() for kind, mb, _ in body if kind == "all-to-all")
+    assert largest < 20, largest
+
+
 def test_flash_attention_partitions_over_four_chips(topo, monkeypatch):
     """GSPMD cannot partition a Mosaic kernel; ``ops.causal_attention`` runs
     it per shard. This is the program the fsdp=4 train step traces."""

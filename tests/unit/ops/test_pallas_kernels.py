@@ -448,11 +448,7 @@ def test_flash_kernel_bench_reads_the_statistics_as_the_program_stores_them(stat
     assert bench.stat_bytes(text, (2, 2048, 16, 64)) == {"flash_fwd": stored, "flash_bwd_dkv": 2 * stored}
 
 
-def test_train_step_tool_reads_the_backward_body_s_collectives():
-    """``tools/train_step_for_described_chip.py`` (ROADMAP S4 reads its
-    output): of a compiled text, the computation that holds the flash backward
-    kernel beside collectives, its collectives in schedule order, and the
-    memory space of each one's buffers (``S(1)`` is the chip's fast memory)."""
+def _train_step_tool():
     import importlib.util
     import os
 
@@ -460,6 +456,53 @@ def test_train_step_tool_reads_the_backward_body_s_collectives():
     spec = importlib.util.spec_from_file_location("train_step_tool", path)
     tool = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(tool)
+    return tool
+
+
+def test_train_step_tool_counts_a_body_of_gathers_and_reduce_scatters_without_a_ring():
+    """Since PR 45 the layer scan's backward body holds no
+    ``collective-permute``: the tool finds it by the flash kernel alone, and
+    its census counts every computation's collectives by kind and MB, a
+    gather once though three ``async_collective_fusion``s (start, step, end)
+    each hold its instruction, a ``-done`` with its ``-start``."""
+    tool = _train_step_tool()
+    shard, whole = "bf16[512,16,128]{2,0,1:T(8,128)(2,1)S(1)}", "bf16[2048,16,128]{2,0,1:T(8,128)(2,1)}"
+    gather = f"  %all-gather.7 = {whole} all-gather(%p), channel_id=103, dimensions={{0}}, " \
+             'metadata={op_name="a/layers/attn/wq/shard_map/zero_gather/all_gather"}'
+    text = "\n".join([
+        "HloModule jit_train_step", "",
+        *(line for i in (1, 2, 3) for line in (f"%async_collective_fusion.{i} (p: {shard}) -> {whole} {{", gather, "}", "")),
+        "%backward.2 (p: bf16[8]) -> bf16[8] {",
+        "  %p = bf16[8]{0} parameter(0)",
+        *(f"  %fusion.{i} = {whole} fusion(%p), kind=kCustom, calls=%async_collective_fusion.{i}" for i in (1, 2, 3)),
+        "  %flash_bwd_dkv.1 = (bf16[1,16,2048,128]{3,2,1,0}, bf16[1,16,2048,128]{3,2,1,0}) custom-call(%p), "
+        'custom_call_target="tpu_custom_call", metadata={op_name="a/flash_bwd_dkv/pallas_call"}',
+        f"  %reduce_scatter.61 = {shard} reduce-scatter(%p), channel_id=1, dimensions={{0}}, "
+        'metadata={op_name="a/layers/attn/wk/shard_map/zero_scatter/reduce_scatter"}',
+        f"  %reduce_scatter.63 = {shard} reduce-scatter(%p), channel_id=1, dimensions={{0}}, "
+        'metadata={op_name="a/layers/attn/wq/shard_map/zero_scatter/reduce_scatter"}',
+        "  %all-reduce-start.4 = bf16[2048]{0} all-reduce-start(%p), channel_id=13",
+        "  %all-reduce-done.4 = bf16[2048]{0} all-reduce-done(%all-reduce-start.4)",
+        "}",
+    ])
+    rows = tool.backward_body(text)
+    assert [r[0] for r in rows][3:6] == ["flash_bwd_dkv.1", "reduce_scatter.61", "reduce_scatter.63"]
+    assert [name for name, _ in tool.collectives(rows)] == [
+        "flash_bwd_dkv.1", "reduce_scatter.61", "reduce_scatter.63", "all-reduce-start.4", "all-reduce-done.4"]
+    found = tool.census(text)
+    assert list(found) == ["backward.2"]  # a fusion's computation is counted with its caller
+    assert tool.by_kind(found["backward.2"]) == {
+        "all-gather": (1, 8.4), "all-reduce": (1, 0.0), "reduce-scatter": (2, 4.2)}
+    assert found["backward.2"][0][2].endswith("wq/shard_map/zero_gather/all_gather")
+    assert tool.array_mb("(bf16[8192,1,512]{2,0,1}, f32[4]{0}, u32[]{:S(2)})") == pytest.approx(8.388608)
+
+
+def test_train_step_tool_reads_the_backward_body_s_collectives():
+    """``tools/train_step_for_described_chip.py`` (ROADMAP S4 reads its
+    output): of a compiled text, the computation that holds the flash backward
+    kernel, its collectives in schedule order, and the memory space of each
+    one's buffers (``S(1)`` is the chip's fast memory)."""
+    tool = _train_step_tool()
     big = "bf16[8192,1,512]{2,0,1:T(8,128)(2,1)"
     text = "\n".join([
         "HloModule jit_train_step", "",
