@@ -17,7 +17,7 @@ GPT-2-125M (seq 1024, bf16):
 ``python chip_smoke.py --chips 4`` needs four chips and runs ONLY the
 multi-chip phase: the same model and global batch on one device and then
 under ZeRO-3 over ``fsdp=4`` (losses agree, state spread over four devices),
-and one routed all-reduce per algorithm against ``lax.psum``.
+and one ``dist.all_reduce`` against NumPy.
 
 Weights and data come from ``--seed``. The last line of stdout is one JSON
 object, ``{"ok": ..., "device": {...}}``; a phase that raises or fails its
@@ -318,39 +318,23 @@ def multichip_phase(info: dict, seed: int) -> None:
     mesh = engine.mesh
     del engine
 
-    # one all-reduce per algorithm through the comm facade (which routes into
-    # deepspeed_tpu.collectives) at a gradient-sized payload
+    # one all-reduce through the comm facade at a gradient-sized payload,
+    # against NumPy's sum of the rows
     x = jax.device_put(
         jax.random.normal(jax.random.PRNGKey(seed), (n, COLLECTIVE_ELEMS), jnp.float32),
         NamedSharding(mesh, P("fsdp")))
-
-    def reducer(algorithm):
-        def body(row):
-            if algorithm is None:
-                return jax.lax.psum(row, "fsdp")
-            return dist.all_reduce(row, "fsdp", algorithm=algorithm)
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=P("fsdp"),
-                                 out_specs=P("fsdp"), check_vma=False))
-
-    want = np.asarray(reducer(None)(x))
-    for algorithm in ("lax", "ring", "pallas_ring"):
-        fn = reducer(algorithm)
-        got = jax.block_until_ready(fn(x))
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(x))
-        dt = time.perf_counter() - t0
-        say("multichip", device=info["kind"], all_reduce=algorithm,
-            bytes_per_device=COLLECTIVE_ELEMS * 4, smoke_seconds=round(dt, 5),
-            max_abs_diff_vs_psum=float(np.abs(np.asarray(got) - want).max()))
-        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-4,
-                                   err_msg=f"all_reduce[{algorithm}] != lax.psum")
-    from deepspeed_tpu.collectives import pallas_backend
-
-    check(not pallas_backend.compiled_ok("pallas_ring", "int8"),
-          "the fused int8 hop is marked supported but this smoke never ran it")
-    say("multichip", not_supported="pallas_ring + int8/fp8 fused hop: Mosaic refuses its "
-        "wire blocks; the selector never picks the pair and asking for it by name raises "
-        "(collectives/pallas_backend.py FUSED_CODECS)")
+    fn = jax.jit(shard_map(lambda row: dist.all_reduce(row, "fsdp"), mesh=mesh,
+                           in_specs=P("fsdp"), out_specs=P("fsdp"), check_vma=False))
+    want = np.tile(np.asarray(x).sum(axis=0, keepdims=True), (n, 1))
+    got = jax.block_until_ready(fn(x))
+    t0 = time.perf_counter()
+    jax.block_until_ready(fn(x))
+    dt = time.perf_counter() - t0
+    say("multichip", device=info["kind"], all_reduce="dist.all_reduce",
+        bytes_per_device=COLLECTIVE_ELEMS * 4, smoke_seconds=round(dt, 5),
+        max_abs_diff_vs_numpy=float(np.abs(np.asarray(got) - want).max()))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-4,
+                               err_msg="dist.all_reduce != the NumPy sum of the rows")
 
 
 def main(argv=None) -> int:

@@ -145,30 +145,6 @@ def _axis_size(axis) -> int:
     return axis_size(axis, default=1)
 
 
-def _axes_sig(axis):
-    """((name, size), ...) for the selector's decision-cache key and the
-    schedule compiler's search domain — two meshes with equal world size
-    but different axis factorizations must take different decisions. None
-    when any axis is unbound (size unknowable outside shard_map)."""
-    from deepspeed_tpu.utils.compat import axis_size
-
-    axes = tuple(axis) if isinstance(axis, (tuple, list)) else (axis,)
-    sig = []
-    for a in axes:
-        n = axis_size(a, default=0)
-        if n <= 0:
-            return None
-        sig.append((str(a), int(n)))
-    return tuple(sig)
-
-
-def _itemsize(x) -> int:
-    try:
-        return jnp.dtype(x.dtype).itemsize
-    except Exception:
-        return 4
-
-
 def _nbytes(x) -> int:
     try:
         return int(np.prod(x.shape)) * jnp.dtype(x.dtype).itemsize
@@ -176,7 +152,7 @@ def _nbytes(x) -> int:
         return 0
 
 
-def _record(op_name: str, axis, x, **tags):
+def _record(op_name: str, axis, x):
     """Record one collective into the comms logger AND the telemetry
     subsystem; returns a span context wrapping the ``jax.lax`` call.
 
@@ -184,20 +160,10 @@ def _record(op_name: str, axis, x, **tags):
     time: the span duration is host tracing time (one per compiled program,
     not per execution), while the (op, axis, dtype, bytes, world) tags are
     the exact per-execution collective workload of the traced step.
-    ``tags`` carries extra span attributes (algorithm/codec on the
-    algorithmic path) so routing decisions are visible in the trace.
     """
     axis_str = "+".join(axis) if isinstance(axis, (tuple, list)) else str(axis)
     nbytes, world = _nbytes(x), _axis_size(axis)
     comms_logger.record(op_name, axis_str, nbytes, world)
-    if op_name in ("ppermute", "remote_dma"):
-        # hop-wire census for the collective observatory: inside a routed
-        # collective's trace scope these ARE the wire bytes the selector's
-        # routing put on the interconnect (no-op outside a scope — pipeline
-        # ppermutes etc. are not routed wires)
-        from deepspeed_tpu.collectives import observatory as _coll_obs
-
-        _coll_obs.on_wire(nbytes)
     tracer = telemetry.get_tracer()
     if not tracer.enabled:
         return telemetry.NOOP_SPAN
@@ -206,129 +172,19 @@ def _record(op_name: str, axis, x, **tags):
     tracer.count(f"comm/bytes/{op_name}", nbytes)
     dtype = str(getattr(x, "dtype", "unknown"))
     return tracer.span(f"comm:{op_name}", cat="comm", op=op_name, axis=axis_str,
-                       bytes=nbytes, dtype=dtype, world=world, **tags)
+                       bytes=nbytes, dtype=dtype, world=world)
 
 
 # --------------------------------------------------------------------------
 # collectives (usable inside shard_map / jit with bound axis names)
 # --------------------------------------------------------------------------
 #
-# ``algorithm=`` / ``codec=`` route through deepspeed_tpu.collectives (the
-# hop-composed algorithmic library): algorithm None keeps the plain jax.lax
-# lowering (XLA picks the implementation), "auto" asks collectives.selector
-# for the best (algorithm, codec) per (op, bytes, axis size), and a concrete
-# name ("ring" / "bidir" / "rhd" / "ring2d", or "pallas_ring" /
-# "pallas_ring2d" for remote-DMA hop kernels with in-kernel fused int8/fp8
-# reduction — collectives/pallas_backend.py) forces it. The algorithmic
-# path must run inside FULL-MANUAL shard_map (see utils/compat.py).
+# Each op is a name, the trace-time record above and the ``jax.lax`` call: XLA
+# picks the implementation (docs/parallelism.md, "Which collectives run").
 
 
-def _algorithmic(op_name: str, x, axis, algorithm, codec, reduce_op: str = "sum"):
-    """Resolve (algorithm, codec) — consulting the selector for "auto" —
-    and tag the choice on the facade span.
-
-    A call with no explicit algorithm/codec first picks up the process
-    defaults the ``collectives`` config block installed
-    (``selector.SelectorConfig.facade_algorithm/codec``). Default-routed
-    calls stay on the lax lowering when the algorithmic path cannot serve
-    them (multi-axis tuples, max/min reductions) and never apply a lossy
-    codec to non-float payloads (token ids, the already-int8 zeropp wire);
-    an EXPLICIT algorithm/codec argument is honored verbatim and surfaces
-    the library's own errors instead."""
-    from deepspeed_tpu.collectives import selector
-
-    if isinstance(axis, (tuple, list)) and len(axis) == 0:
-        # an empty axis tuple is the native no-op reduction (lax.pmean(x, ())
-        # == x — e.g. grad means on a mesh with no >1 data axis): nothing
-        # crosses a wire, so there is nothing to route or quantize
-        return None, None
-    explicit = algorithm is not None or codec is not None
-    from_config = False
-    if not explicit:
-        cfg = selector.get_config()
-        if cfg.facade_algorithm is None:
-            return None, None
-        if isinstance(axis, (tuple, list)) and len(axis) > 1:
-            return None, None  # hierarchical tuples only when asked for
-        if reduce_op not in ("sum", "mean", "avg"):
-            return None, None  # algorithmic all_reduce has no max/min
-        if not jnp.issubdtype(getattr(x, "dtype", jnp.float32), jnp.floating):
-            # integer payloads (token ids, counters, the zeropp int8 wire)
-            # keep the native lowering under default routing
-            return None, None
-        algorithm, codec = cfg.facade_algorithm, cfg.facade_codec
-        from_config = True
-        if op_name == "all_to_all" and algorithm == "rhd":
-            # the configured default may be an algorithm this op has no
-            # form of (rhd: every block has exactly one destination);
-            # default routing keeps the lax lowering — only an EXPLICIT
-            # rhd request surfaces the library's error
-            return None, None
-    if algorithm == "lax":
-        return None, None
-    if algorithm in (None, "auto"):
-        if codec is None and not jnp.issubdtype(
-                getattr(x, "dtype", jnp.float32), jnp.floating):
-            codec = "none"
-        d = selector.select(op_name, _nbytes(x), _axis_size(axis), codec,
-                            itemsize=_itemsize(x), axes_sig=_axes_sig(axis))
-        if d.algorithm == "lax":
-            # measured mode's "don't bother" verdict: the baseline won
-            return None, None
-        return d.algorithm, d.codec
-    if codec is None and from_config:
-        # concrete configured algorithm + codec "auto": the selector still
-        # picks the wire among the configured candidates
-        codec = selector.pick_codec(op_name, _nbytes(x), _axis_size(axis),
-                                    algorithm, itemsize=_itemsize(x))
-    return algorithm, codec or "none"
-
-
-def _observe_route(op_name: str, x, axis, algorithm: str, codec: str,
-                   block_size: Optional[int]):
-    """Trace-time observatory registration of one ROUTED collective: the
-    returned context collects this trace's hop/wire census
-    (``collectives/observatory.py``). A nullcontext when the observatory is
-    disabled — the traced program is identical either way (the observatory
-    never adds operations; its timings come from standalone probe
-    dispatches)."""
-    from deepspeed_tpu.collectives import observatory as _coll_obs
-    from deepspeed_tpu.telemetry import numerics as _numerics_obs
-
-    # the numerics observatory registers the same signature for its
-    # wire-fidelity probes (lossy codecs only; a no-op when disabled)
-    _numerics_obs.note_route(
-        op_name, algorithm, codec, _nbytes(x), _itemsize(x),
-        _axis_size(axis), axis, str(getattr(x, "dtype", "unknown")),
-        block_size)
-    return _coll_obs.note_route(
-        op_name, algorithm, codec, _nbytes(x), _itemsize(x),
-        _axis_size(axis), axis, str(getattr(x, "dtype", "unknown")),
-        block_size)
-
-
-def _resolved_block_size(block_size: Optional[int]) -> Optional[int]:
-    """The configured quantization block for auto-routed collectives (the
-    caller's explicit block_size wins)."""
-    if block_size is not None:
-        return block_size
-    from deepspeed_tpu.collectives import selector
-
-    return selector.get_config().block_size
-
-
-def all_reduce(x, axis, op: str = "sum", *, algorithm: Optional[str] = None,
-               codec: Optional[str] = None, block_size: Optional[int] = None):
+def all_reduce(x, axis, op: str = "sum"):
     """psum/pmax/pmin over a named axis (reference ``all_reduce`` ``comm/comm.py``)."""
-    alg, cd = _algorithmic("all_reduce", x, axis, algorithm, codec, reduce_op=op)
-    if alg is not None:
-        from deepspeed_tpu import collectives
-
-        bs = _resolved_block_size(block_size)
-        with _record(f"all_reduce_{op}", axis, x, algorithm=alg, codec=cd), \
-                _observe_route("all_reduce", x, axis, alg, cd, bs):
-            return collectives.all_reduce(x, axis, algorithm=alg, codec=cd, op=op,
-                                          block_size=bs)
     with _record(f"all_reduce_{op}", axis, x):
         if op == "sum":
             return jax.lax.psum(x, axis)
@@ -341,90 +197,22 @@ def all_reduce(x, axis, op: str = "sum", *, algorithm: Optional[str] = None,
         raise ValueError(f"unsupported reduce op {op!r}")
 
 
-def all_gather(x, axis, *, concat_axis: int = 0, tiled: bool = True,
-               algorithm: Optional[str] = None, codec: Optional[str] = None,
-               block_size: Optional[int] = None):
+def all_gather(x, axis, *, concat_axis: int = 0, tiled: bool = True):
     """all_gather over a named axis (reference ``all_gather_into_tensor``)."""
-    if not tiled:
-        # untiled gathers have no algorithmic form: explicit requests get a
-        # clear error, default routing skips the selector entirely (no
-        # cached decision / coll:select event for a path never taken)
-        if algorithm is not None or codec is not None:
-            raise ValueError("algorithmic all_gather supports tiled=True only")
-        alg = cd = None
-    else:
-        alg, cd = _algorithmic("all_gather", x, axis, algorithm, codec)
-    if alg is not None:
-        from deepspeed_tpu import collectives
-
-        bs = _resolved_block_size(block_size)
-        with _record("all_gather", axis, x, algorithm=alg, codec=cd), \
-                _observe_route("all_gather", x, axis, alg, cd, bs):
-            return collectives.all_gather(x, axis, algorithm=alg, codec=cd,
-                                          concat_axis=concat_axis, block_size=bs)
     with _record("all_gather", axis, x):
         return jax.lax.all_gather(x, axis, axis=concat_axis, tiled=tiled)
 
 
-def reduce_scatter(x, axis, *, scatter_axis: int = 0, tiled: bool = True,
-                   algorithm: Optional[str] = None, codec: Optional[str] = None,
-                   block_size: Optional[int] = None):
+def reduce_scatter(x, axis, *, scatter_axis: int = 0, tiled: bool = True):
     """psum_scatter (reference ``reduce_scatter_tensor``)."""
-    if not tiled:
-        # untiled scatters have no algorithmic form (see all_gather above)
-        if algorithm is not None or codec is not None:
-            raise ValueError("algorithmic reduce_scatter supports tiled=True only")
-        alg = cd = None
-    else:
-        alg, cd = _algorithmic("reduce_scatter", x, axis, algorithm, codec)
-    if alg is not None:
-        from deepspeed_tpu import collectives
-
-        bs = _resolved_block_size(block_size)
-        with _record("reduce_scatter", axis, x, algorithm=alg, codec=cd), \
-                _observe_route("reduce_scatter", x, axis, alg, cd, bs):
-            return collectives.reduce_scatter(x, axis, algorithm=alg, codec=cd,
-                                              scatter_axis=scatter_axis,
-                                              block_size=bs)
     with _record("reduce_scatter", axis, x):
         return jax.lax.psum_scatter(x, axis, scatter_dimension=scatter_axis, tiled=tiled)
 
 
-def all_to_all(x, axis, *, split_axis: int, concat_axis: int, tiled: bool = True,
-               algorithm: Optional[str] = None, codec: Optional[str] = None,
-               block_size: Optional[int] = None):
-    """all_to_all (reference ``all_to_all_single``; backbone of Ulysses + MoE).
-
-    ``algorithm=``/``codec=`` route through the algorithmic collectives
-    library like every other facade op: ``None`` defers to the process
-    facade defaults the ``collectives`` config block installed (falling
-    back to the byte-identical ``jax.lax`` lowering when none are set —
-    callers moving already-encoded bytes must pin ``algorithm="lax"``,
-    see ``quant_collectives.exchange_wire``), "auto" consults the
-    selector, a concrete name
-    ("ring" / "bidir" / "ring2d", or "pallas_ring"/"pallas_ring2d" for
-    remote-DMA hops with the in-kernel fused int8/fp8 dispatch wire) forces
-    it. The MoE token dispatch/combine (``parallel/moe.py``) and the
-    expert-parallel inference path ride this entry point."""
-    if not tiled:
-        # untiled all_to_all has no algorithmic form (the block-exchange
-        # schedules are tiled by construction); explicit requests get a
-        # clear error, default routing skips the selector entirely
-        if algorithm is not None or codec is not None:
-            raise ValueError("algorithmic all_to_all supports tiled=True only")
-        alg = cd = None
-    else:
-        alg, cd = _algorithmic("all_to_all", x, axis, algorithm, codec)
-    if alg is not None:
-        from deepspeed_tpu import collectives
-
-        bs = _resolved_block_size(block_size)
-        with _record("all_to_all", axis, x, algorithm=alg, codec=cd), \
-                _observe_route("all_to_all", x, axis, alg, cd, bs):
-            return collectives.all_to_all(x, axis, split_axis=split_axis,
-                                          concat_axis=concat_axis,
-                                          algorithm=alg, codec=cd,
-                                          block_size=bs)
+def all_to_all(x, axis, *, split_axis: int, concat_axis: int, tiled: bool = True):
+    """all_to_all (reference ``all_to_all_single``; backbone of Ulysses + MoE:
+    the MoE token dispatch/combine of ``parallel/moe.py`` and the
+    expert-parallel inference path ride this entry point)."""
     with _record("all_to_all", axis, x):
         return jax.lax.all_to_all(x, axis, split_axis=split_axis, concat_axis=concat_axis, tiled=tiled)
 

@@ -246,9 +246,9 @@ class TelemetryConfig(DeepSpeedConfigModel):
     programs: bool = True
     # Fleet federation (telemetry/fleet.py + telemetry/collector.py): when
     # set, this process registers with the FleetCollector at this URL
-    # (identity + clock handshake) and pushes mergeable registry snapshots,
-    # heartbeats (step rate, HBM watermark, anomaly flags) and observatory
-    # table rows on the cadence below, from a daemon thread. None = no
+    # (identity + clock handshake) and pushes mergeable registry snapshots
+    # and heartbeats (step rate, HBM watermark, anomaly flags) on the
+    # cadence below, from a daemon thread. None = no
     # fleet client (single-process runs pay nothing).
     fleet_url: Optional[str] = None
     fleet_push_interval_s: float = 5.0
@@ -369,17 +369,15 @@ class DiagnosticsConfig(DeepSpeedConfigModel):
 
 class NumericsConfig(DeepSpeedConfigModel):
     """numerics section — the numerics observatory
-    (``telemetry/numerics.py``): sampled wire-fidelity probes over every
-    routed lossy codec, the in-jit cross-replica divergence sentinel
+    (``telemetry/numerics.py``): the in-jit cross-replica divergence sentinel
     (carried in ``TrainState.numerics`` like the health field), LoCo
     error-feedback residual gauges, and serving fidelity probes. Disabled
     (the default) the traced step program is jaxpr-identical to a build
     without the block (pinned by ``tests/unit/test_numerics.py``)."""
 
     enabled: bool = False
-    # 1-in-N train steps runs the standalone wire/serving fidelity probes
-    # (codec encode->decode round trips on deterministic payloads); <= 0
-    # keeps route registration live but never probes
+    # 1-in-N steps reads the EF-residual gauges (training) and runs the
+    # serving fidelity probes; <= 0 never does
     sample_every: int = 16
     # in-jit divergence sentinel: digests the params on sampled steps and
     # compares replicas across the mesh axes each leaf is replicated over
@@ -390,10 +388,6 @@ class NumericsConfig(DeepSpeedConfigModel):
     # TrainingHealthError through the diagnostics manager, dumping the
     # flight recorder when one is live)
     divergence_policy: str = "log"  # log | abort
-    max_probe_elems: int = 65536  # wire-probe payload cap (elements)
-    # wire rel-err beyond drift_ratio x the codec's pinned bound
-    # (numerics.WIRE_REL_ERR_BOUNDS) is a drift event
-    drift_ratio: float = 2.0
     # spec-decode acceptance-rate trend alarm (PR-2 median+MAD, low side)
     spec_accept_window: int = 64
     spec_accept_mads: float = 6.0
@@ -433,105 +427,6 @@ class RecoveryConfig(DeepSpeedConfigModel):
     max_total_rewinds: int = 8  # across the whole run
     backoff_base_s: float = 1.0  # first-rewind sleep; doubles per consecutive rewind
     backoff_max_s: float = 60.0
-
-
-class CollObserveConfig(DeepSpeedConfigModel):
-    """collectives.observe section — the collective performance observatory
-    (``collectives/observatory.py``): on sampled steps the routed hop-scope
-    programs are re-dispatched standalone and host-clocked, observations
-    EMA-merge into an on-disk decision table that warm-starts measured mode
-    on the next run, a least-squares refit calibrates the per-backend
-    alpha/beta constants live, and observed-vs-predicted drift warns loudly
-    and arms the diagnostics profiler capture. Disabled (the default) the
-    traced step programs and the facade are byte-identical to today's —
-    and they stay identical when enabled too: probes are separate
-    dispatches, never ops inside the step."""
-
-    enabled: bool = False
-    # 1-in-N train steps runs probe work (the steady-state path is untouched
-    # between samples); <= 0 disables sampling while keeping
-    # route registration + the trace-time census live
-    sample_every: int = 16
-    probes_per_sample: int = 1
-    iters: int = 1       # timed iterations per probe dispatch
-    warmup: int = 1      # probe warmup (the first pays the probe compile)
-    # also time candidate algorithms (lax baseline + the other families) so
-    # the online table can CHANGE a decision, not just confirm one
-    probe_alternatives: bool = True
-    # compile new probe programs on a background worker and only time them
-    # once warm — a multi-second XLA compile must never stall train_batch
-    async_compile: bool = True
-    # online table location (default <telemetry dir>/coll_table.json); the
-    # engine feeds it back as the measured-mode decision table on the next
-    # run when no explicit collectives.decision_table is configured
-    table_path: Optional[str] = None
-    persist: bool = True
-    ema: float = 0.25          # EMA weight folding new samples into rows
-    drift_ratio: float = 3.0   # observed/predicted beyond this (either way)
-    refit_every: int = 8       # alpha/beta refit cadence (merged samples)
-    # per-refit forgetting on the fit statistics (1.0 = never): lets the
-    # calibration track an interconnect regime change on long runs
-    fit_decay: float = 0.5
-    max_probe_mb: float = 64.0  # never time payloads above this
-    max_programs: int = 32     # probe program cache bound
-
-
-class CollectivesConfig(DeepSpeedConfigModel):
-    """collectives section — the algorithmic collective library
-    (``deepspeed_tpu/collectives``): hop-composed ring / bidirectional-ring /
-    recursive-halving-doubling / hierarchical-2D algorithms with per-hop wire
-    codecs, selected per (op, bytes, axis-size) by an alpha-beta cost model
-    or a measured decision table (``comm/benchmark.py --sweep``). Disabled
-    (the default), the ``comm`` facade keeps its plain ``jax.lax`` lowering
-    and the compiled program is unchanged."""
-
-    enabled: bool = False
-    # Facade default when a single-axis collective is issued without explicit
-    # arguments ("auto" consults the selector; a concrete name forces one
-    # algorithm). Installed process-wide by the engine when enabled, so ALL
-    # facade collectives — including the zeropp gathers — route through it.
-    # The pallas_* names run the same schedules over remote-DMA hop kernels
-    # (TPU; interpret mode elsewhere — see docs/collectives.md).
-    algorithm: str = "auto"  # auto | ring | bidir | rhd | ring2d | pallas_ring | pallas_ring2d | lax
-    # "auto" lets the selector pick among `codecs`; any concrete name —
-    # including "none" — FORCES that wire for every default-routed collective.
-    codec: str = "auto"  # auto | none | fp32 | bf16 | int8 | fp8
-    # Candidate codecs the selector may choose among in auto mode.
-    codecs: List[str] = Field(default_factory=lambda: ["none"])
-    # auto = measured when decision_table is set, alpha-beta model otherwise
-    mode: str = "auto"  # auto | model (alpha-beta) | measured (decision table)
-    decision_table: Optional[str] = None  # JSON from `benchmark --sweep`
-    alpha_us: float = 1.0  # per-hop latency for the cost model
-    beta_us_per_mb: float = 10.0  # inverse link bandwidth (~100 GB/s)
-    block_size: int = 2048  # quantization block for int8/fp8 wire codecs
-    # Payloads below this never auto-quantize (scale overhead dominates).
-    min_quant_bytes: int = 65536
-    # Payloads below this stay on the native lax lowering in model mode
-    # (tiny collectives are latency-bound; serial hops lose to XLA's own).
-    min_algorithmic_bytes: int = 4096
-    # Cost-model alpha discount for pallas remote-DMA hops (one fused kernel
-    # per hop vs encode+permute+decode programs); candidates enter the model
-    # only when the backend is actually available (a real TPU).
-    pallas_alpha_scale: float = 0.5
-    # T3-style double buffering of the zeropp qwZ gather wire: chunk count
-    # (1 = off). Chunk k's dequantize overlaps chunk k+1's gather.
-    overlap_chunks: int = 1
-    # Let model mode SYNTHESIZE hierarchical schedules (the GC3-style
-    # compiler, collectives/schedule.py) as candidates next to the
-    # hand-written menu, and accept `algorithm: "compiled"` /
-    # "compiled:<sig>" as facade defaults. Off by default: a multi-level
-    # schedule dominates ring on hop count under a flat alpha-beta model,
-    # so turning this on shifts auto routing across the board.
-    compiled_search: bool = False
-    # Fuse the ZeRO-3/zeropp weight-gather and tp-boundary matmuls with
-    # their collectives inside single Pallas kernels (all-gather+matmul /
-    # matmul+reduce-scatter, collectives/fused_gemm.py): grid step j
-    # computes output chunk j while chunk j-1's wire is in flight. Off by
-    # default; config-off leaves every hot path byte-identical.
-    fused_gemm_collectives: bool = False
-    # The performance observatory: live hop timing, online calibration,
-    # drift detection (active only when `enabled` above is too).
-    observe: CollObserveConfig = Field(default_factory=CollObserveConfig)
 
 
 class CommsLoggerConfig(DeepSpeedConfigModel):
@@ -626,7 +521,6 @@ class EngineConfig(DeepSpeedConfigModel):
     wandb: WandbConfig = Field(default_factory=WandbConfig)
     flops_profiler: FlopsProfilerConfig = Field(default_factory=FlopsProfilerConfig)
     comms_logger: CommsLoggerConfig = Field(default_factory=CommsLoggerConfig)
-    collectives: CollectivesConfig = Field(default_factory=CollectivesConfig)
     telemetry: TelemetryConfig = Field(default_factory=TelemetryConfig)
     diagnostics: DiagnosticsConfig = Field(default_factory=DiagnosticsConfig)
     numerics: NumericsConfig = Field(default_factory=NumericsConfig)

@@ -12,7 +12,7 @@ from typing import Any, Dict
 
 from pydantic import BaseModel, ConfigDict, model_validator
 
-from deepspeed_tpu.utils.logging import logger
+from deepspeed_tpu.utils.logging import logger, warning_once
 
 AUTO_VALUE = "auto"
 
@@ -59,6 +59,14 @@ class DeepSpeedConfigModel(BaseModel):
             if new_param and new_param not in values:
                 values[new_param] = values.pop(hit)
         return values
+
+    @model_validator(mode="after")
+    def _warn_unknown_keys(self):
+        # once a process and key: pydantic runs this again at every assignment
+        for key in self.extra_fields():
+            warning_once(f"{type(self).__name__}: unknown config key {key!r} has no field "
+                         "here and changes nothing")
+        return self
 
     def extra_fields(self) -> Dict[str, Any]:
         return dict(self.__pydantic_extra__ or {})

@@ -299,9 +299,7 @@ class InferenceEngineV2:
             log_dist(
                 f"expert-parallel serving: experts sharded over ep="
                 f"{mesh.shape['ep']}, MoE dispatch/combine through the "
-                "facade all_to_all (algorithm="
-                f"{model_config.moe_dispatch_algorithm or 'facade default'}, "
-                f"codec={model_config.moe_wire_codec or 'exact'})", ranks=[0])
+                "facade all_to_all", ranks=[0])
 
         max_len = config.max_seq_len or model_config.max_seq_len
         self.max_seq_len = max_len
@@ -1581,8 +1579,8 @@ class InferenceEngineV2:
         ``arrival_times`` (seconds relative to the call, one per prompt)
         turns the batch call into an open-loop workload: a prompt enters the
         admission queue only once its arrival time has passed — this is what
-        ``tools/bench_serving.py --slo`` drives to measure TTFT/queue-wait
-        under a synthetic arrival pattern. None (default) queues everything
+        ``benchmarks/runners/serve.py`` drives to measure TTFT/queue-wait
+        under a cell's arrival pattern. None (default) queues everything
         immediately, exactly the previous behavior.
 
         When the telemetry tracer is enabled (or ``flight_recorder`` is

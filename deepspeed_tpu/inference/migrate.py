@@ -13,16 +13,10 @@ memory instead of a wire. Two backends, one buffer format
   boundary streams while the host assembles and dispatches the NEXT
   prefill; the router caps in-flight exports per source at
   ``DEFAULT_MIGRATION_DEPTH`` slots (double-buffered: page streaming of
-  request N overlaps the prefill of request N+1, exactly the ``overlap.py``
-  T3 discipline at migration granularity).
-- **remote DMA** (real chip boundaries): :func:`remote_copy_pages` moves the
-  buffer leaves between two mesh ranks through the PR-8 hop kernel —
-  ``pallas_backend.permute_wire`` runs ONE ``make_async_remote_copy``
-  program per hop carrying every leaf (values + scales), under a
-  point-to-point permutation (:func:`transposition_perm`). Where the
-  interpreter cannot discharge remote DMA (multi-axis CPU meshes) the hop
-  falls back to ``lax.ppermute`` with identical semantics — the same
-  honest-transport story as the collective backend.
+  request N overlaps the prefill of request N+1).
+- **across chips**: :func:`remote_copy_pages` moves the buffer leaves
+  (values + scales) between two mesh ranks by ``lax.ppermute`` under a
+  point-to-point permutation (:func:`transposition_perm`).
 
 Failure contract (the router's side of it): a migration that cannot import
 (destination capacity, layout mismatch, any exception) leaves the request
@@ -70,8 +64,8 @@ class MigrationTicket:
 def transposition_perm(n: int, src: int, dst: int) -> List[Tuple[int, int]]:
     """Point-to-point migration as a full permutation of ``n`` ranks: the
     src<->dst transposition completed with identity self-edges — the shape
-    both ``lax.ppermute`` and the remote-DMA hop kernel accept (the hop
-    primitive is a permutation; a migration is the degenerate one)."""
+    ``lax.ppermute`` accepts (the hop primitive is a permutation; a
+    migration is the degenerate one)."""
     if not (0 <= src < n and 0 <= dst < n):
         raise ValueError(f"src={src}/dst={dst} out of range for {n} ranks")
     if src == dst:
@@ -84,19 +78,15 @@ def transposition_perm(n: int, src: int, dst: int) -> List[Tuple[int, int]]:
 def remote_copy_pages(leaves: Sequence[jax.Array], mesh, axis_name: str,
                       src: int, dst: int):
     """Move migration-buffer leaves from mesh rank ``src`` to rank ``dst``
-    over the PR-8 remote-DMA hop kernel.
+    by one ``lax.ppermute`` a leaf.
 
     ``leaves`` are [n, ...] arrays sharded over ``axis_name`` on their
     leading dim — rank r's shard is ITS local pages (for a migration only
     rank ``src`` carries payload; the others ride the permutation's
     identity edges). Returns leaves of the same shape where rank ``dst``'s
-    shard holds rank ``src``'s pages, bytes verbatim. On a real TPU every
-    hop is one ``make_async_remote_copy`` Pallas program carrying ALL
-    leaves (values + scale pages together); in interpret mode on meshes the
-    interpreter cannot discharge, the transport falls back to
-    ``lax.ppermute`` — same permutation, same bytes.
+    shard holds rank ``src``'s pages, bytes verbatim (values and scale pages
+    alike).
     """
-    from deepspeed_tpu.collectives import pallas_backend
     from deepspeed_tpu.utils.compat import shard_map
 
     n = mesh.shape[axis_name]
@@ -104,16 +94,9 @@ def remote_copy_pages(leaves: Sequence[jax.Array], mesh, axis_name: str,
     leaves = list(leaves)
 
     def hop(*shards):
-        if pallas_backend.remote_dma_supported():
-            moved = pallas_backend.remote_permute_leaves(
-                list(shards), axis_name, perm)
-        else:
-            moved = [lax.ppermute(s, axis_name, perm) for s in shards]
-        return tuple(moved)
+        return tuple(lax.ppermute(s, axis_name, perm) for s in shards)
 
     spec = P(axis_name)
-    # check_vma=False: jax 0.4.x has no replication rule for pallas_call
-    # (the PR-8 collective kernels disable it the same way)
     f = shard_map(hop, mesh=mesh,
                   in_specs=tuple(spec for _ in leaves),
                   out_specs=tuple(spec for _ in leaves),

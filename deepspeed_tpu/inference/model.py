@@ -182,10 +182,9 @@ def _experts(ep, cfg: TransformerConfig, tokens, top_p, top_i):
     if _moe_ep_size() > 1:
         # expert-parallel serving (ISSUE 15): the ep-sharded experts are
         # reached through the explicit collective dispatch — the SAME
-        # facade all_to_all the training path rides, so quantized token
-        # routing, hop spans and observatory signatures apply to serving
-        # MoE traffic too. Falls back to the replicated paths below (GSPMD
-        # reshards the ep-sharded kernels) only on non-divisible shapes.
+        # facade all_to_all the training path rides. Falls back to the
+        # replicated paths below (GSPMD reshards the ep-sharded kernels)
+        # only on non-divisible shapes.
         out = _moe_ep_collective(cfg, ep, tokens, top_p, top_i)
         if out is not None:
             return out
@@ -273,8 +272,7 @@ def _moe_ep_collective(cfg: TransformerConfig, ep, tokens, top_p, top_i):
                ep["w_down"].astype(cfg.dtype))
     return collective_moe_apply(
         tokens, combine, dispatch, kernels, activation=cfg.activation,
-        dtype=cfg.dtype, algorithm=cfg.moe_dispatch_algorithm,
-        codec=cfg.moe_wire_codec)
+        dtype=cfg.dtype)
 
 
 # A kernel may scope 16 MiB of the chip's VMEM unless it asks for more, and the

@@ -153,9 +153,10 @@ class TransformerConfig:
     sparse_embedding_grads: bool = False
     # Pallas attention scheduling knobs forwarded to the flash kernel when it
     # is the resolved impl (dropped on the XLA path — identical math either
-    # way): {"block_q": ..., "block_k": ..., "k_splits": ...}. The autotuner /
-    # profile_bench --stage attn-sweep pick these on hardware. Frozen to a tuple-of-pairs at
-    # construction (configs are jit static args).
+    # way): {"block_q": ..., "block_k": ..., "k_splits": ...}; the kernel
+    # chooses its own from the shapes when none is given
+    # (tools/flash_kernel_bench.py reads them on hardware). Frozen to a
+    # tuple-of-pairs at construction (configs are jit static args).
     attn_kwargs: Optional[Any] = None
     sp_impl: str = "ulysses"  # ulysses (all-to-all) | ring (ppermute) over sp
     dtype: Any = jnp.float32  # activation dtype inside the module
@@ -186,11 +187,8 @@ class TransformerConfig:
     # [E, C, M] dispatch/combine reshards onto ep — "auto" runs the explicit
     # shard_map + facade all_to_all path (cross-tp token gather/drop) on
     # ep x tp meshes and GSPMD constraints elsewhere; "collective"/"gspmd"
-    # force one. The algorithm/codec knobs route the dispatch wire
-    # (int8/fp8 = quantized token routing; None = facade defaults).
+    # force one.
     moe_dispatch: str = "auto"
-    moe_dispatch_algorithm: Optional[str] = None
-    moe_wire_codec: Optional[str] = None
     # Capacity-factor autotuning ceiling (runtime moe_autotune block): when
     # set, capacity arrays are sized by THIS factor and the enforced cutoff
     # follows a traced scalar (batch key "moe_capacity_factor", threaded by
@@ -1146,8 +1144,6 @@ class Block(nn.Module):
                 aux_loss_weight=cfg.moe_aux_loss_weight,
                 collect_metrics=collect,
                 dispatch=cfg.moe_dispatch,
-                dispatch_algorithm=cfg.moe_dispatch_algorithm,
-                dispatch_codec=cfg.moe_wire_codec,
                 max_capacity_factor=(cfg.moe_capacity_factor_max
                                      if cfg.moe_dynamic_capacity else None),
             )

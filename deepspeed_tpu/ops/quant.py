@@ -20,9 +20,8 @@ FP8_MAX = 448.0  # float8_e4m3fn max normal
 # -- shared block math -------------------------------------------------------
 # THE symmetric block-quant formulas, written on [nb, block] fp32 tiles so
 # the same code runs as the XLA fallback, inside the Pallas quantizer kernel
-# (ops/pallas/quantizer.py), in the wire codecs (collectives/codecs.py), and
-# in the fused collective hop kernel's VMEM body
-# (collectives/pallas_backend.py). One wire format everywhere.
+# (ops/pallas/quantizer.py) and in the wire codecs (parallel/codecs.py). One
+# wire format everywhere.
 
 
 def int8_block_math(x2: jax.Array):

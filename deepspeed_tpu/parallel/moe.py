@@ -16,7 +16,6 @@ so the [E, C, M] activation resharding onto ``ep`` IS the dispatch all-to-all.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -55,18 +54,8 @@ class MoEConfig:
     # gathered across the tp group at region entry (token specs never name
     # tp, so tp ranks see the full token set) and the duplicate outputs are
     # dropped at region exit; the dispatch and combine each cross the wire
-    # as ONE facade all_to_all over ep, so algorithm/codec routing, hop
-    # spans, and observatory signatures all apply to MoE token traffic.
+    # as ONE facade all_to_all over ep, recorded like any stated collective.
     dispatch: str = "auto"
-    # facade all_to_all routing of the collective dispatch: None = facade
-    # defaults / selector ("auto" when the collectives block is enabled);
-    # a concrete name ("ring" / "bidir" / "ring2d" / "pallas_ring" /
-    # "pallas_ring2d") forces that schedule
-    dispatch_algorithm: Optional[str] = None
-    # wire codec of the dispatch/combine all-to-all: "int8"/"fp8" quantize
-    # the token wire (EQuARX-style on the pallas backend: requantize ->
-    # remote DMA -> dequantize in one kernel per hop); None = exact wire
-    dispatch_codec: Optional[str] = None
     # Capacity-factor autotuning support (runtime moe_autotune block): when
     # set, the capacity ARRAYS are sized by this ceiling factor and the
     # factor actually enforced is a traced scalar clipped into
@@ -350,35 +339,6 @@ class Experts(nn.Module):
 # ------------------------------------------------- collective token dispatch
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
-def _routed_all_to_all(x, axis, split_axis, concat_axis, algorithm, codec):
-    """Facade all_to_all with the reference ``_AllToAll`` autograd contract
-    (``moe/sharded_moe.py:96``): the backward pass is the REVERSE exchange
-    through the same algorithm/codec — so a lossy dispatch wire quantizes
-    the gradient tokens exactly like the forward tokens, instead of AD
-    differentiating through the rounding (zero gradients)."""
-    from deepspeed_tpu.comm import comm as dist
-
-    return dist.all_to_all(x, axis, split_axis=split_axis,
-                           concat_axis=concat_axis, algorithm=algorithm,
-                           codec=codec)
-
-
-def _routed_a2a_fwd(x, axis, split_axis, concat_axis, algorithm, codec):
-    return _routed_all_to_all(x, axis, split_axis, concat_axis, algorithm, codec), None
-
-
-def _routed_a2a_bwd(axis, split_axis, concat_axis, algorithm, codec, _res, g):
-    from deepspeed_tpu.comm import comm as dist
-
-    return (dist.all_to_all(g, axis, split_axis=concat_axis,
-                            concat_axis=split_axis, algorithm=algorithm,
-                            codec=codec),)
-
-
-_routed_all_to_all.defvjp(_routed_a2a_fwd, _routed_a2a_bwd)
-
-
 def _token_axes(mesh) -> Tuple[str, ...]:
     """The mesh axes token shards split over inside the collective dispatch
     region: the batch axes, ep (the expert-data decomposition) AND tp.
@@ -471,8 +431,7 @@ def resolve_dispatch_mode(cfg: MoEConfig, num_tokens: int) -> str:
 
 def collective_moe_apply(tokens: jax.Array, combine: jax.Array,
                          dispatch: jax.Array, kernels, *, activation: str,
-                         dtype, algorithm: Optional[str] = None,
-                         codec: Optional[str] = None) -> jax.Array:
+                         dtype) -> jax.Array:
     """The explicit expert-parallel dispatch (reference ``moe/mappings.py``
     + ``_AllToAll``): one full-manual shard_map region where
 
@@ -482,13 +441,13 @@ def collective_moe_apply(tokens: jax.Array, combine: jax.Array,
        builds its PARTIAL ``[E, C, M]`` dispatch einsum — global capacity
        slots, so shard contributions are disjoint and all-zero elsewhere;
     2. ONE facade ``all_to_all`` over ep (split E, concat C) lands every
-       shard's slots on the owning expert rank — the quantized-routable
-       dispatch wire;
+       shard's slots on the owning expert rank;
     3. the local expert FFN runs on ``[E/ep, ep*C, M]`` (biasless: zero
        slots stay zero, so disjoint partials stay disjoint);
     4. the reverse ``all_to_all`` returns each shard its slots' outputs;
     5. the local combine einsum reads only the shard's own tokens' slots.
     """
+    from deepspeed_tpu.comm import comm as dist
     from deepspeed_tpu.utils.compat import shard_map
 
     mesh = get_mesh()
@@ -501,9 +460,9 @@ def collective_moe_apply(tokens: jax.Array, combine: jax.Array,
     def shard_fn(tok_l, comb_l, disp_l, *ws_l):
         wg, wu, wd = ws_l if n_ws == 3 else (None,) + ws_l
         expert_in = jnp.einsum("tec,tm->ecm", disp_l, tok_l)  # partial [E, C, M]
-        expert_in = _routed_all_to_all(expert_in, "ep", 0, 1, algorithm, codec)
+        expert_in = dist.all_to_all(expert_in, "ep", split_axis=0, concat_axis=1)
         h = experts_ffn(expert_in, wg, wu, wd, activation, dtype)  # [E/ep, ep*C, M]
-        expert_out = _routed_all_to_all(h, "ep", 1, 0, algorithm, codec)
+        expert_out = dist.all_to_all(h, "ep", split_axis=1, concat_axis=0)
         return jnp.einsum("tec,ecm->tm", comb_l, expert_out)  # [T_l, M]
 
     f = shard_map(
@@ -549,12 +508,10 @@ class MoELayer(nn.Module):
         mode = resolve_dispatch_mode(self.config, B * S)
         if mode == "collective":
             # explicit expert-parallel dispatch: cross-tp token gather/drop
-            # + facade all_to_all over ep (quantized routing, hop spans)
+            # + facade all_to_all over ep
             out = collective_moe_apply(
                 tokens, combine.astype(self.dtype), dispatch.astype(self.dtype),
-                experts.kernels(), activation=self.activation, dtype=self.dtype,
-                algorithm=self.config.dispatch_algorithm,
-                codec=self.config.dispatch_codec)
+                experts.kernels(), activation=self.activation, dtype=self.dtype)
         else:
             # dispatch: [T, E, C] x [T, M] -> [E, C, M], then shard E over ep
             expert_in = jnp.einsum("tec,tm->ecm", dispatch.astype(self.dtype), tokens)

@@ -6,12 +6,11 @@ dequant-reduce) and the qwZ quantized weight allgather
 (``zero/partition_parameters.py:1200`` ``all_gather_coalesced(quantize=True)``),
 backed by ``csrc/quantization/swizzled_quantize.cu`` / ``quant_reduce.cu``.
 
-These are now thin wrappers over the shared wire codec layer
-(``collectives/codecs.py``): the int8 blockwise format (values + per-block
-fp32 scales, blocks never straddling a shard boundary) is defined exactly
-once there and reused by the hop-composed algorithms, the zeropp custom-vjp
-gathers, and these all_to_all helpers. Comm volume: int8 values + one f32
-scale per block ~= 4x reduction vs f32, 2x vs bf16.
+These are thin wrappers over the shared wire codec (``parallel/codecs.py``):
+the int8 blockwise format (values + per-block fp32 scales, blocks never
+straddling a shard boundary) is defined exactly once there and reused by the
+zeropp custom-vjp gathers and these all_to_all helpers. Comm volume: int8
+values + one f32 scale per block ~= 4x reduction vs f32, 2x vs bf16.
 
 These functions must run inside ``shard_map`` (axis names bound).
 """
@@ -21,35 +20,27 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.collectives.codecs import get_codec
 from deepspeed_tpu.comm import comm as dist
+from deepspeed_tpu.parallel.codecs import DEFAULT_BLOCK, Int8BlockCodec
 from deepspeed_tpu.utils.compat import axis_size
-
-DEFAULT_BLOCK = 2048
 
 
 def gather_wire(wire, axis):
-    """All-gather every non-empty leaf of a wire pytree (concat axis 0),
-    pinned to the plain lowering: an already-encoded wire must never route
-    back through the algorithmic/codec path. THE wire-movement idiom shared
-    with the zeropp custom-vjp gathers."""
+    """All-gather every non-empty leaf of a wire pytree (concat axis 0).
+    THE wire-movement idiom shared with the zeropp custom-vjp gathers."""
     return jax.tree_util.tree_map(
-        lambda w: w if w.size == 0 else dist.all_gather(
-            w, axis, concat_axis=0, algorithm="lax"), wire)
+        lambda w: w if w.size == 0 else dist.all_gather(w, axis, concat_axis=0), wire)
 
 
 def exchange_wire(wire, axis):
     """All-to-all every non-empty leaf of a wire pytree (split/concat axis 0
-    — the qgZ destination-shard exchange), pinned to the plain lowering
-    like :func:`gather_wire`: an already-encoded wire must never route back
-    through the algorithmic/codec path."""
+    — the qgZ destination-shard exchange)."""
     return jax.tree_util.tree_map(
         lambda w: w if w.size == 0 else dist.all_to_all(
-            w, axis, split_axis=0, concat_axis=0, algorithm="lax"), wire)
+            w, axis, split_axis=0, concat_axis=0), wire)
 
 
-def quantized_reduce_scatter(grad: jax.Array, axis: str, block_size: int = DEFAULT_BLOCK,
-                             codec: str = "int8") -> jax.Array:
+def quantized_reduce_scatter(grad: jax.Array, axis: str, block_size: int = DEFAULT_BLOCK) -> jax.Array:
     """qgZ analog: quantized gradient reduce-scatter over ``axis``.
 
     Input: full local gradient [N] (N divisible by axis size). Output: this
@@ -61,7 +52,7 @@ def quantized_reduce_scatter(grad: jax.Array, axis: str, block_size: int = DEFAU
     N = flat.shape[0]
     assert N % n == 0, f"grad numel {N} not divisible by axis size {n}"
     shard = N // n
-    c = get_codec(codec, min(block_size, shard))
+    c = Int8BlockCodec(min(block_size, shard))
     wire = c.encode_rows(flat.reshape(n, shard))  # row-aligned blocks per dest shard
 
     # Each rank receives every peer's encoded copy of *its* shard (+ scales).
@@ -69,8 +60,7 @@ def quantized_reduce_scatter(grad: jax.Array, axis: str, block_size: int = DEFAU
     return jnp.mean(deq, axis=0).astype(grad.dtype)
 
 
-def quantized_all_gather(x: jax.Array, axis: str, block_size: int = DEFAULT_BLOCK,
-                         codec: str = "int8") -> jax.Array:
+def quantized_all_gather(x: jax.Array, axis: str, block_size: int = DEFAULT_BLOCK) -> jax.Array:
     """qwZ analog: quantized weight allgather over ``axis``.
 
     Input: local shard [M]; output: decoded full buffer [world * M] in
@@ -78,7 +68,7 @@ def quantized_all_gather(x: jax.Array, axis: str, block_size: int = DEFAULT_BLOC
     """
     flat = x.reshape(-1)
     M = flat.shape[0]
-    c = get_codec(codec, min(block_size, M))
+    c = Int8BlockCodec(min(block_size, M))
     wire = c.encode_rows(flat[None])  # [1, M] -> padded blocked wire
     # Gather the *padded* blocked wire so per-rank block boundaries survive.
     wire_g = gather_wire(wire, axis)

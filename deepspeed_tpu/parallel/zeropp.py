@@ -15,33 +15,25 @@ gradient computation inside a partial-manual ``shard_map`` over the data axes
 (dp/fsdp manual, tp/sp/... auto) so the collectives are addressable; XLA still
 schedules/overlaps them over ICI.
 
-Int8 block quantization is the shared wire codec
-(``collectives/codecs.py`` — one format across the hop algorithms, the
-all_to_all helpers, and these custom-vjp gathers); comm volume per
-gather/reduce is ~2x less than bf16, ~4x less than fp32 — the ZeRO++
-headline (``docs/_tutorials/zeropp.md:6-17``). The weight gather optionally
-splits its wire into chunks double-buffered through
-``collectives/overlap.py`` so dequantize of chunk k overlaps the gather of
-chunk k+1 (T3-style).
+Int8 block quantization is the shared wire codec (``parallel/codecs.py`` —
+one format across the all_to_all helpers and these custom-vjp gathers); comm
+volume per gather/reduce is ~2x less than bf16, ~4x less than fp32 — the
+ZeRO++ headline (``docs/_tutorials/zeropp.md:6-17``).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec
 
-from deepspeed_tpu.collectives.codecs import Int8BlockCodec
-from deepspeed_tpu.collectives.overlap import double_buffered
 from deepspeed_tpu.comm import comm as dist
+from deepspeed_tpu.parallel.codecs import DEFAULT_BLOCK, Int8BlockCodec
 from deepspeed_tpu.parallel.quant_collectives import exchange_wire, gather_wire
 from deepspeed_tpu.utils.compat import axis_size as _axis_size
-
-DEFAULT_BLOCK = 2048
 
 
 class CommPlan:
@@ -80,46 +72,16 @@ def leaf_comm_plan(spec: Optional[PartitionSpec], live_axes: Tuple[str, ...]) ->
     return CommPlan(None)
 
 
-def _int8_all_gather_dim(x: jax.Array, dim: int, axes, block: int,
-                         overlap_chunks: int = 1) -> jax.Array:
-    """Encode the local shard once, gather the int8 wire, decode.
-
-    ``overlap_chunks > 1`` splits the wire into that many chunks and runs
-    them through the T3-style double buffer (``collectives/overlap.py``):
-    the decode of chunk k and the gather of chunk k+1 have no data
-    dependence, so XLA may overlap them — hiding dequantize time behind the
-    next chunk's transfer on an async-collective backend."""
+def _int8_all_gather_dim(x: jax.Array, dim: int, axes, block: int) -> jax.Array:
+    """Encode the local shard once, gather the int8 wire, decode."""
     moved = jnp.moveaxis(x, dim, 0)
     rest = moved.shape[1:]
     flat = moved.reshape(-1)
     M = flat.shape[0]
     codec = Int8BlockCodec(block_size=min(block, M))
     n = _axis_size(axes)
-
-    chunks = max(int(overlap_chunks), 1)
-    blk = codec.block_size
-    blocks_total = -(-M // blk)
-    chunks = min(chunks, blocks_total)  # a chunk is a whole number of blocks
-    if chunks <= 1:
-        wire = codec.encode_rows(flat[None])
-        deq = codec.decode_rows(gather_wire(wire, axes), M, x.dtype)  # [n, M]
-    else:
-        wire = codec.encode_rows(flat[None])  # q [1, Mp], s [1, Mp//blk]
-        Mp = wire.q.shape[1]
-        blocks_per = -(-blocks_total // chunks)
-        per = blocks_per * blk
-        chunks = -(-Mp // per)
-        pieces = [
-            type(wire)(q=wire.q[:, k * per:(k + 1) * per],
-                       s=wire.s[:, k * blocks_per:(k + 1) * blocks_per])
-            for k in range(chunks)
-        ]
-        gathered = double_buffered(
-            pieces,
-            comm_fn=lambda w: gather_wire(w, axes),
-            compute_fn=lambda wg: codec.decode_rows(wg, wg.q.shape[1], x.dtype),
-        )
-        deq = jnp.concatenate(gathered, axis=1)[:, :M]  # [n, M]
+    wire = codec.encode_rows(flat[None])
+    deq = codec.decode_rows(gather_wire(wire, axes), M, x.dtype)  # [n, M]
     full = deq.reshape((n * moved.shape[0],) + rest)
     return jnp.moveaxis(full, 0, dim)
 
@@ -180,7 +142,7 @@ def _exact_reduce_scatter_dim(g: jax.Array, dim: int, axes) -> jax.Array:
     return dist.reduce_scatter(g, axes, scatter_axis=dim) / n
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
 def sharded_weight_gather(
     shard: jax.Array,
     dim: int,
@@ -189,7 +151,6 @@ def sharded_weight_gather(
     quantize_weights: bool,
     quantize_grads: bool,
     block: int,
-    overlap_chunks: int = 1,
 ) -> jax.Array:
     """Differentiable ZeRO weight gather (must run inside shard_map).
 
@@ -200,16 +161,15 @@ def sharded_weight_gather(
               (data axes the weight was replicated over).
     """
     if quantize_weights:
-        return _int8_all_gather_dim(shard, dim, gather_axes, block, overlap_chunks)
+        return _int8_all_gather_dim(shard, dim, gather_axes, block)
     return _exact_all_gather_dim(shard, dim, gather_axes)
 
 
-def _swg_fwd(shard, dim, gather_axes, other_axes, qw, qg, block, overlap_chunks):
-    return sharded_weight_gather(shard, dim, gather_axes, other_axes, qw, qg,
-                                 block, overlap_chunks), None
+def _swg_fwd(shard, dim, gather_axes, other_axes, qw, qg, block):
+    return sharded_weight_gather(shard, dim, gather_axes, other_axes, qw, qg, block), None
 
 
-def _swg_bwd(dim, gather_axes, other_axes, qw, qg, block, overlap_chunks, _res, g):
+def _swg_bwd(dim, gather_axes, other_axes, qw, qg, block, _res, g):
     if qg:
         gs = _int8_reduce_scatter_dim(g, dim, gather_axes, block)
     else:
@@ -222,7 +182,7 @@ def _swg_bwd(dim, gather_axes, other_axes, qw, qg, block, overlap_chunks, _res, 
 sharded_weight_gather.defvjp(_swg_fwd, _swg_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def sharded_weight_gather_loco(
     shard: jax.Array,
     err: jax.Array,
@@ -233,7 +193,6 @@ def sharded_weight_gather_loco(
     qw: bool,
     err_beta: float,
     block: int,
-    overlap_chunks: int = 1,
 ) -> jax.Array:
     """LoCo form of :func:`sharded_weight_gather`: same forward, but the
     backward's quantized reduce-scatter carries error feedback. The updated
@@ -246,18 +205,17 @@ def sharded_weight_gather_loco(
     dynamic loss-scale change between steps cannot corrupt the residuals
     (same invariant as the 1-bit path)."""
     if qw:
-        return _int8_all_gather_dim(shard, dim, gather_axes, block, overlap_chunks)
+        return _int8_all_gather_dim(shard, dim, gather_axes, block)
     return _exact_all_gather_dim(shard, dim, gather_axes)
 
 
-def _swgl_fwd(shard, err, inv, dim, gather_axes, other_axes, qw, err_beta, block,
-              overlap_chunks):
+def _swgl_fwd(shard, err, inv, dim, gather_axes, other_axes, qw, err_beta, block):
     out = sharded_weight_gather_loco(shard, err, inv, dim, gather_axes,
-                                     other_axes, qw, err_beta, block, overlap_chunks)
+                                     other_axes, qw, err_beta, block)
     return out, (err, inv)
 
 
-def _swgl_bwd(dim, gather_axes, other_axes, qw, err_beta, block, overlap_chunks, res, g):
+def _swgl_bwd(dim, gather_axes, other_axes, qw, err_beta, block, res, g):
     err_true, inv = res
     gs, new_err_wire = _int8_reduce_scatter_dim_loco(
         g, err_true / inv, dim, gather_axes, err_beta, block)
@@ -271,8 +229,7 @@ sharded_weight_gather_loco.defvjp(_swgl_fwd, _swgl_bwd)
 
 def gather_params_for_compute(params, plans, qw: bool, qg: bool, block: int = DEFAULT_BLOCK,
                               live_axes: Tuple[str, ...] = (),
-                              errors=None, err_beta: float = 0.8, inv=None,
-                              overlap_chunks: int = 1):
+                              errors=None, err_beta: float = 0.8, inv=None):
     """Map ``sharded_weight_gather`` over a param pytree inside shard_map.
 
     ``plans`` mirrors ``params`` with a ``CommPlan`` per leaf; replicated
@@ -288,8 +245,7 @@ def gather_params_for_compute(params, plans, qw: bool, qg: bool, block: int = DE
             if not plan.sharded:
                 return leaf
             other = tuple(a for a in live_axes if a not in plan.axes)
-            return sharded_weight_gather(leaf, plan.dim, plan.axes, other, qw, qg,
-                                         block, overlap_chunks)
+            return sharded_weight_gather(leaf, plan.dim, plan.axes, other, qw, qg, block)
 
         return jax.tree_util.tree_map(one, params, plans)
 
@@ -298,55 +254,6 @@ def gather_params_for_compute(params, plans, qw: bool, qg: bool, block: int = DE
             return leaf
         other = tuple(a for a in live_axes if a not in plan.axes)
         return sharded_weight_gather_loco(leaf, err, inv, plan.dim, plan.axes,
-                                          other, qw, err_beta, block, overlap_chunks)
+                                          other, qw, err_beta, block)
 
     return jax.tree_util.tree_map(one_loco, params, errors, plans)
-
-
-# --------------------------------------------------------- fused-gather GEMM
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def sharded_matmul(x: jax.Array, w_shard: jax.Array, axis: str,
-                   quantize: bool = False, block: int = DEFAULT_BLOCK) -> jax.Array:
-    """``x [M, K] @ W [K, N]`` with ``W`` row-sharded over ``axis`` and the
-    stage-3 weight gather fused INTO the GEMM (T3): the forward never
-    materializes the full weight — each fused ring hop contracts the held
-    shard against ``x`` while its wire is in flight
-    (:func:`deepspeed_tpu.collectives.fused_gemm.all_gather_matmul`).
-
-    backward: ``dw_shard`` comes back through the fused
-    matmul+reduce-scatter (``reduce_scatter(x^T @ g, rows)`` — SUM over the
-    axis, matching per-rank-batch partials), and ``dx = g @ W^T`` through
-    the fused gather's independent-column-block form — neither direction
-    materializes the full weight or the full gradient.
-
-    ``quantize`` puts the int8 block wire (qwZ/qgZ) on every fused hop.
-    With ``fused_gemm.configure(enabled=False)`` (the default; engine knob
-    ``collectives.fused_gemm_collectives``) every path lowers to the plain
-    lax composition — programs byte-identical to a build without the fused
-    kernels. Must run inside full-manual shard_map; returns fp32.
-    """
-    from deepspeed_tpu.collectives import fused_gemm
-
-    return fused_gemm.all_gather_matmul(
-        x, w_shard, axis, codec="int8" if quantize else None, block_size=block)
-
-
-def _smm_fwd(x, w_shard, axis, quantize, block):
-    return sharded_matmul(x, w_shard, axis, quantize, block), (x, w_shard)
-
-
-def _smm_bwd(axis, quantize, block, res, g):
-    from deepspeed_tpu.collectives import fused_gemm
-
-    x, w_shard = res
-    codec = "int8" if quantize else None
-    dx = fused_gemm.all_gather_matmul(g, w_shard, axis, codec=codec,
-                                      block_size=block, out_block=True)
-    dw = fused_gemm.matmul_reduce_scatter(
-        jnp.swapaxes(x, 0, 1), g, axis, codec=codec, block_size=block)
-    return dx.astype(x.dtype), dw.astype(w_shard.dtype)
-
-
-sharded_matmul.defvjp(_smm_fwd, _smm_bwd)

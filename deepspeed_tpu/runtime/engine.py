@@ -104,35 +104,12 @@ def _get_metrics_server(port: int):
 
 
 def _facade_grad_mean(g, live):
-    """Mean-reduce an unsharded gradient leaf over the data axes through the
-    comm facade: byte-identical ``lax.pmean`` lowering by default, but the
-    ``collectives`` config block's routing (algorithmic/quantized/pallas
-    remote-DMA backends) now reaches the shard_map grad paths (zeropp, LoCo,
-    1-bit) — the GSPMD main step has no explicit collective to route. The
-    loss pmean stays native: a scalar control value is never worth hops."""
+    """Mean-reduce an unsharded gradient leaf over the data axes of the
+    ZeRO++ / LoCo shard_map grad paths through the comm facade, so the mean
+    is recorded like every other stated collective. The loss pmean stays
+    native: a scalar control value is not worth a record."""
     from deepspeed_tpu.comm import comm as comm_mod
 
-    # A FORCED lossy wire reaches this path with NO error feedback (the
-    # zeropp/LoCo/1-bit paths carry residuals; the plain grad mean does
-    # not) — quantization error lands in the update every step. Warn once
-    # (trace time only) and let the numerics wire probes, which see this
-    # route via comm._observe_route, report the realized error.
-    from deepspeed_tpu.collectives import selector as _coll_sel
-    from deepspeed_tpu.telemetry import numerics as _numerics_mod
-
-    _cfg = _coll_sel.get_config()
-    _codec = getattr(_cfg, "facade_codec", None)
-    # codec alone never routes — a lossy wire is live only when a facade
-    # algorithm forces the grad mean off the native pmean lowering
-    if (_codec in _numerics_mod.LOSSY_CODECS
-            and getattr(_cfg, "facade_algorithm", None) not in (None, "lax")):
-        _numerics_mod.warn_once(
-            "facade_grad_mean_lossy",
-            f"collectives: forced lossy codec {_codec!r} routes the "
-            "shard_map grad mean-reductions WITHOUT error feedback "
-            "(docs/collectives.md): quantization error accumulates into "
-            "every update; enable numerics.enabled to measure the "
-            "realized wire error (numerics/wire_rel_err)")
     return comm_mod.all_reduce(g, live, op="mean")
 
 
@@ -194,8 +171,6 @@ class DeepSpeedTPUEngine:
             if bf16_cfg.enabled and not bf16_cfg.accumulate_grads_in_fp32
             else jnp.float32)
         seed = seed if seed is not None else self.config.model.seed
-        # resolved early: the step builders' closures read the overlap knob
-        self._collectives_cfg = self.config.model.collectives
         self._configure_offload()
 
         # ---- optimizer + schedule ----------------------------------------
@@ -382,8 +357,7 @@ class DeepSpeedTPUEngine:
                 trace_path=tcfg.trace_path, jsonl_path=tcfg.jsonl_path,
                 prometheus_path=tcfg.prometheus_path)
             # the process-global program registry follows the tracer unless
-            # pinned; honor this engine's knob (last-constructed wins, the
-            # collectives-selector convention)
+            # pinned; honor this engine's knob (last-constructed wins)
             from deepspeed_tpu.telemetry import programs as programs_mod
 
             programs_mod.configure(enabled=None if tcfg.programs else False)
@@ -434,113 +408,6 @@ class DeepSpeedTPUEngine:
             self._fleet_client = _get_fleet_client(
                 tcfg.fleet_url, tcfg.fleet_push_interval_s)
         self._tracer = telemetry_mod.get_tracer()
-        # Collectives (collectives/): install the selector tunables so comm
-        # facade calls with algorithm="auto" (and the zeropp overlap knob)
-        # follow this engine's config. Process-global like the tracer;
-        # disabled leaves the facade on the plain jax.lax lowering.
-        ccfg = self._collectives_cfg
-        from deepspeed_tpu.collectives import selector as coll_selector
-
-        self._coll_observatory = None
-        if not ccfg.enabled:
-            # the selector is process-global: a disabled engine must restore
-            # the plain-lax defaults or it would inherit a previous engine's
-            # facade routing (the config block promises "disabled => the
-            # compiled program is unchanged"). Last-constructed engine wins —
-            # warn when this strips routing a live enabled engine installed.
-            if coll_selector.get_config().facade_algorithm is not None:
-                logger.warning(
-                    "collectives: resetting process-global facade routing "
-                    "installed by a previously constructed engine; set "
-                    "collectives.enabled in this engine's config to keep it")
-            coll_selector.configure()
-            from deepspeed_tpu.collectives import fused_gemm as _fused_gemm
-
-            _fused_gemm.configure(enabled=False)
-        else:
-            # Facade defaults inject ppermute hops into EVERY default-routed
-            # collective — including ones traced inside partial-manual
-            # shard_map regions (data axes manual, model axes auto), where
-            # ppermute hard-fails on this jax 0.4.37/XLA (PartitionId
-            # unsupported — see utils/compat.py). With nontrivial model
-            # axes, keep the selector tunables (explicit algorithm= calls
-            # still work in full-manual regions) but leave default routing
-            # on the lax lowering.
-            model_axes = [a for a in self.mesh.axis_names
-                          if a not in ("dp", "fsdp") and self.mesh.shape[a] > 1]
-            facade_alg = ccfg.algorithm
-            if model_axes and facade_alg not in (None, "lax"):
-                logger.warning(
-                    f"collectives: mesh has nontrivial model axes {model_axes} "
-                    f"(partial-manual shard_map regions; ppermute unsupported "
-                    f"there on this jax/XLA) — facade default routing stays on "
-                    f"the lax lowering; pass algorithm= explicitly inside "
-                    f"full-manual regions instead")
-                facade_alg = None
-            ocfg = ccfg.observe
-            decision_table = ccfg.decision_table
-            if ocfg.enabled and not decision_table and ccfg.mode != "model":
-                # warm-start measured mode from the table a previous run's
-                # observatory persisted (collectives/observatory.py): the
-                # online rows ARE sweep-schema rows, so the selector consumes
-                # them exactly like a `benchmark --sweep` table
-                from deepspeed_tpu.collectives import observatory as coll_obs
-
-                # resolve THIS engine's path: the process-global observatory
-                # still holds the previous engine's config at this point
-                _table = ocfg.table_path or coll_obs.default_table_path()
-                if os.path.exists(_table):
-                    decision_table = _table
-                    log_dist(f"collectives: warm-starting measured mode from "
-                             f"the observatory table {_table}", ranks=[0])
-            coll_selector.configure(
-                mode=ccfg.mode, alpha_us=ccfg.alpha_us,
-                beta_us_per_mb=ccfg.beta_us_per_mb,
-                codecs=tuple(ccfg.codecs), block_size=ccfg.block_size,
-                decision_table=decision_table,
-                min_quant_bytes=ccfg.min_quant_bytes,
-                min_algorithmic_bytes=ccfg.min_algorithmic_bytes,
-                pallas_alpha_scale=ccfg.pallas_alpha_scale,
-                compiled_search=ccfg.compiled_search,
-                facade_algorithm=facade_alg,
-                # "auto" = no forced codec: the selector picks among `codecs`;
-                # a concrete name (incl. "none") pins that wire
-                facade_codec=ccfg.codec if ccfg.codec != "auto" else None)
-            # in-kernel compute-collective fusion (collectives/fused_gemm):
-            # process-global knob like the selector; the zeropp sharded
-            # matmuls and tp helpers consult it at trace time
-            from deepspeed_tpu.collectives import fused_gemm as _fused_gemm
-
-            _fused_gemm.configure(enabled=ccfg.fused_gemm_collectives)
-            if ocfg.enabled:
-                from deepspeed_tpu.collectives import observatory as coll_obs
-
-                obs = coll_obs.configure(
-                    enabled=True, sample_every=ocfg.sample_every,
-                    probes_per_sample=ocfg.probes_per_sample,
-                    iters=ocfg.iters, warmup=ocfg.warmup,
-                    probe_alternatives=ocfg.probe_alternatives,
-                    async_compile=ocfg.async_compile,
-                    table_path=ocfg.table_path, persist=ocfg.persist,
-                    ema=ocfg.ema, drift_ratio=ocfg.drift_ratio,
-                    refit_every=ocfg.refit_every, fit_decay=ocfg.fit_decay,
-                    max_probe_mb=ocfg.max_probe_mb,
-                    max_programs=ocfg.max_programs)
-                # drift arms the anomaly profiler capture when diagnostics
-                # wired one (diagnostics are built before this section)
-                pc = (self.diagnostics.profiler_capture
-                      if self.diagnostics is not None else None)
-                obs.install(mesh=self.mesh,
-                            profiler_arm=pc.arm if pc is not None else None)
-                self._coll_observatory = obs
-        if self._coll_observatory is None:
-            # observatory hygiene (process-global, like the selector reset
-            # above): an engine that does not enable it must not inherit a
-            # previous engine's probes/routes — but only when some earlier
-            # engine actually imported+enabled the module
-            _obs_mod = sys.modules.get("deepspeed_tpu.collectives.observatory")
-            if _obs_mod is not None and _obs_mod.enabled():
-                _obs_mod.configure(enabled=False)
         if self.config.model.dump_state:
             # reference engine.py dump_state: print the resolved config once
             log_dist(f"engine config: {self.config.model.model_dump()}", ranks=[0])
@@ -949,9 +816,8 @@ class DeepSpeedTPUEngine:
         self._numerics_sentinel = None
         ncfg = self.config.model.numerics
         if not ncfg.enabled:
-            # process-global hygiene (selector/observatory precedent): an
-            # engine that does not enable it must not inherit a previous
-            # engine's routes or alarms
+            # process-global hygiene: an engine that does not enable it must
+            # not inherit a previous engine's alarms
             _num_mod = sys.modules.get("deepspeed_tpu.telemetry.numerics")
             if _num_mod is not None and _num_mod.enabled():
                 _num_mod.configure(enabled=False)
@@ -963,8 +829,6 @@ class DeepSpeedTPUEngine:
             sentinel=ncfg.sentinel,
             sentinel_sample_every=ncfg.sentinel_sample_every,
             divergence_policy=ncfg.divergence_policy,
-            max_probe_elems=ncfg.max_probe_elems,
-            drift_ratio=ncfg.drift_ratio,
             spec_accept_window=ncfg.spec_accept_window,
             spec_accept_mads=ncfg.spec_accept_mads,
             spec_accept_min_n=ncfg.spec_accept_min_n)
@@ -980,8 +844,8 @@ class DeepSpeedTPUEngine:
             logger.warning(
                 "numerics.sentinel is not wired into the host-offload "
                 "update paths (offload device=cpu/nvme): divergence "
-                "sentinel disabled for this engine; wire/serving probes "
-                "stay on")
+                "sentinel disabled for this engine; the residual gauges and "
+                "serving probes stay on")
             sentinel_on = False
         if sentinel_on:
             specs = jax.tree_util.tree_map(
@@ -1004,13 +868,12 @@ class DeepSpeedTPUEngine:
             ranks=[0])
 
     def _numerics_on_step(self, step: int) -> None:
-        """Sampled host plane of the numerics observatory: standalone wire
-        probes, LoCo EF-residual gauges, and the sentinel's divergence fold
+        """Sampled host plane of the numerics observatory: LoCo
+        EF-residual gauges and the sentinel's divergence fold
         (policy ``log`` | ``abort``). The sentinel's event counter is
         LATCHED in the carried state, so a host check can never miss a
         detection — only see it a sample late."""
         nm = self._numerics
-        nm.on_step(step)
         ncfg = self.config.model.numerics
         st = self.state
         if st.numerics is not None:
@@ -1582,8 +1445,7 @@ class DeepSpeedTPUEngine:
                     shards, errs_ = shards_errs
                     full = zeropp.gather_params_for_compute(
                         shards, plans, qw, qg, live_axes=live,
-                        errors=errs_, err_beta=err_beta, inv=inv,
-                        overlap_chunks=self._overlap_chunks())
+                        errors=errs_, err_beta=err_beta, inv=inv)
                     loss, _aux = self._loss_and_aux(full, b, rr)
                     return (loss.astype(jnp.float32) * scale).astype(
                         self.compute_dtype if self.fp16 else jnp.float32), loss
@@ -1617,8 +1479,7 @@ class DeepSpeedTPUEngine:
 
             def scaled_loss(shards, b, rr):
                 full = zeropp.gather_params_for_compute(
-                    shards, plans, qw, qg, live_axes=live,
-                    overlap_chunks=self._overlap_chunks())
+                    shards, plans, qw, qg, live_axes=live)
                 loss, _aux = self._loss_and_aux(full, b, rr)
                 return (loss.astype(jnp.float32) * scale).astype(self.compute_dtype if self.fp16 else jnp.float32), loss
 
@@ -1637,12 +1498,6 @@ class DeepSpeedTPUEngine:
             axis_names=set(live),
             check_vma=False,
         )
-
-    def _overlap_chunks(self) -> int:
-        """zeropp gather chunking, honored only when the collectives block
-        is enabled (disabled must compile the identical program)."""
-        cfg = self._collectives_cfg
-        return cfg.overlap_chunks if cfg.enabled else 1
 
     def _onebit_config(self):
         """Live data axes when 1-bit compressed gradient allreduce is active.
@@ -2469,12 +2324,8 @@ class DeepSpeedTPUEngine:
             # AFTER the abort check: a step the health policy aborted must
             # never become the snapshot the recovery loop rewinds to
             self.snapshot_manager.after_step(step)
-        if self._coll_observatory is not None:
-            # sampled (1-in-N) timed probes of the routed collective
-            # signatures — standalone dispatches, the step program untouched
-            self._coll_observatory.on_step(step)
         if self._numerics is not None:
-            # sampled wire-fidelity probes + the divergence-sentinel fold
+            # sampled EF-residual gauges + the divergence-sentinel fold
             # (which may raise under the abort policy)
             self._numerics_on_step(step)
         if self.monitor is not None:

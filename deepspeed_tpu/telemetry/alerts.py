@@ -462,9 +462,6 @@ def default_rules() -> List[AlertRule]:
         AlertRule(name="numerics_divergence", metric="numerics/divergence_events",
                   op=">", value=0, severity="critical",
                   summary="cross-replica divergence events: {value}"),
-        AlertRule(name="collective_drift", metric="coll/drift_events",
-                  op=">", value=0, severity="warn",
-                  summary="collective observed-vs-predicted drift events: {value}"),
         AlertRule(name="replica_dead", kind="event_rate", subsystem="fabric",
                   event_kind="replica_dead", window_s=300.0, op=">", value=0,
                   severity="critical",

@@ -8,7 +8,7 @@ scrapes — every process of a run and merges them into ONE federated view:
                          merger can apply to this process's stream
   - ``POST /push``       full snapshot: identity, clock, ``registry`` (the
                          :func:`fleet.registry_dump` wire form), optional
-                         ``heartbeat`` and observatory ``coll_rows``
+                         ``heartbeat``
   - ``POST /heartbeat``  identity + heartbeat only (cheap liveness)
   - ``GET  /metrics``    FEDERATED Prometheus exposition (counters summed,
                          histograms merged bucket-wise, gauges
@@ -18,9 +18,6 @@ scrapes — every process of a run and merges them into ONE federated view:
   - ``GET  /fleet``      the health ledger: per-process identity, last-seen
                          age, heartbeat (step rate, HBM watermark, queue
                          depth), clock offset, straggler verdict
-  - ``GET  /coll_table`` the federated observatory decision table
-                         (versioned envelope — a fresh selector warm-starts
-                         measured mode from the whole mesh's measurements)
   - ``GET  /healthz``    the collector's own liveness
 
 The incident plane (ISSUE 20) rides the same transport:
@@ -183,20 +180,17 @@ class FleetCollector:
     def __init__(self, port: int = 0, host: str = "127.0.0.1",
                  stale_after_s: float = 60.0,
                  straggler_mads: float = 6.0,
-                 table_path: Optional[str] = None,
                  events_capacity: int = 4096,
                  incident_window_s: float = 30.0):
         self._host = host
         self._requested_port = port
         self.stale_after_s = float(stale_after_s)
         self.straggler_mads = float(straggler_mads)
-        self.table_path = table_path
         self.incident_window_s = float(incident_window_s)
         self._server = None  # exposition.RouteServer, built at start()
         self._lock = threading.Lock()
-        # proc key -> {"identity", "dump", "heartbeat", "coll_rows",
-        #              "last_seen", "clock_offset_s", "origin_unix",
-        #              "events_seq"}
+        # proc key -> {"identity", "dump", "heartbeat", "last_seen",
+        #              "clock_offset_s", "origin_unix", "events_seq"}
         self._procs: Dict[str, Dict[str, Any]] = {}
         # fleet-wide event ring: APPEND semantics (each push carries only
         # events past the sender's cursor; the per-proc seq guard below
@@ -232,13 +226,6 @@ class FleetCollector:
                 entry["dump"] = doc["registry"]
             if "heartbeat" in doc:
                 entry["heartbeat"] = dict(doc["heartbeat"])
-            if "coll_rows" in doc:
-                # REPLACE, like the registry dump: a push carries the
-                # process's full cumulative table, so re-folding it
-                # additively would inflate sample counts and re-apply the
-                # EMA to identical data on every cadence push — the
-                # cross-process fold happens once per READ (table_rows)
-                entry["coll_rows"] = list(doc["coll_rows"])
             if doc.get("events"):
                 # APPEND, unlike everything above: events are occurrences,
                 # not cumulative state. The per-proc high-seq guard makes a
@@ -259,8 +246,6 @@ class FleetCollector:
                     self._events.append(ev)
                     self.events_ingested += 1
                 entry["events_seq"] = high
-        if doc.get("coll_rows") and self.table_path:
-            self.persist_table()
         return {"ok": True, "proc": ident.key(),
                 **({"clock_offset_s": offset} if offset is not None else {})}
 
@@ -276,16 +261,6 @@ class FleetCollector:
         return self.ingest({"identity": dump.get("identity"),
                             "registry": dump,
                             "clock": {"time_unix": dump.get("time_unix")}})
-
-    def persist_table(self) -> None:
-        from deepspeed_tpu.collectives import table as table_mod
-
-        try:
-            table_mod.write_table(self.table_path, self.table_rows(),
-                                  source="fleet")
-        except OSError as e:  # pragma: no cover - disk trouble
-            logger.warning(f"fleet collector: cannot persist federated "
-                           f"table to {self.table_path!r}: {e}")
 
     # ------------------------------------------------------------- views
     def processes(self) -> List[str]:
@@ -312,24 +287,6 @@ class FleetCollector:
         return {k: (e["identity"].key() if e["identity"].proc in dupes
                     else e["identity"].proc)
                 for k, e in entries}
-
-    def table_rows(self) -> List[dict]:
-        """The federated observatory table: each process's LATEST rows,
-        folded at read time through the ONE table fold
-        (``collectives/table.py:merge_rows``, EMA mode — the online
-        semantics) in sorted-proc order, so repeated reads of the same
-        state are identical and a signature measured on several processes
-        lands in one row without per-push inflation."""
-        from deepspeed_tpu.collectives import table as table_mod
-
-        with self._lock:
-            per_proc = [(k, list(e["coll_rows"]))
-                        for k, e in sorted(self._procs.items())
-                        if e.get("coll_rows")]
-        rows: List[dict] = []
-        for _key, proc_rows in per_proc:
-            rows = table_mod.merge_rows(rows, proc_rows, ema=0.25)
-        return rows
 
     def federated_registry(self) -> MetricsRegistry:
         """Build the merged view from the latest dump per process —
@@ -413,8 +370,7 @@ class FleetCollector:
                 "heartbeat": entry.get("heartbeat"),
                 "straggler": bool(stragglers.get(labels[key], False)),
             })
-        return {"time_unix": now, "processes": rows,
-                "coll_table_rows": len(self.table_rows())}
+        return {"time_unix": now, "processes": rows}
 
     # ------------------------------------------------------------- events
     def events(self, proc: Optional[str] = None,
@@ -598,12 +554,6 @@ class FleetCollector:
         return "".join(parts).encode()
 
     # -------------------------------------------------------------- serve
-    def _coll_table_doc(self) -> bytes:
-        from deepspeed_tpu.collectives.table import SCHEMA_VERSION
-
-        return json.dumps({"schema": SCHEMA_VERSION, "source": "fleet",
-                           "rows": self.table_rows()}).encode()
-
     def _healthz_doc(self) -> bytes:
         return json.dumps({
             "ok": True, "role": "collector",
@@ -625,7 +575,6 @@ class FleetCollector:
                         self.render_json().encode(), js),
                     "/fleet": lambda: (
                         json.dumps(self.ledger()).encode(), js),
-                    "/coll_table": lambda: (self._coll_table_doc(), js),
                     "/healthz": lambda: (self._healthz_doc(), js),
                     # incident plane (ISSUE 20): query-taking handlers get
                     # the parsed query dict from RouteServer
@@ -664,7 +613,7 @@ class FleetCollector:
 
 class FleetClient:
     """One process's push side: registers (clock handshake), then pushes
-    registry dumps + heartbeats + observatory rows — on demand
+    registry dumps + heartbeats — on demand
     (:meth:`push`) or on a background cadence (:meth:`start`).
 
     Push failures NEVER raise into the caller (a dead collector must not
@@ -672,11 +621,10 @@ class FleetClient:
     and warn once."""
 
     def __init__(self, url: str, identity: Optional[fleet.ProcessIdentity] = None,
-                 registry=None, observatory=None, timeout_s: float = 2.0):
+                 registry=None, timeout_s: float = 2.0):
         self.url = url.rstrip("/")
         self._identity = identity
         self._registry = registry
-        self._observatory = observatory
         self.timeout_s = float(timeout_s)
         self.pushes = 0
         self.push_failures = 0
@@ -761,8 +709,7 @@ class FleetClient:
         return hb
 
     def _build_doc(self, heartbeat_extra: Optional[Dict[str, Any]],
-                   include_registry: bool, include_table: bool,
-                   coll_rows: Optional[List[dict]] = None) -> Dict[str, Any]:
+                   include_registry: bool) -> Dict[str, Any]:
         hb = self.heartbeat_doc()
         if heartbeat_extra:
             hb.update(heartbeat_extra)
@@ -783,49 +730,24 @@ class FleetClient:
         if tail:
             doc["events"] = tail
             doc["events_high_seq"] = tail[-1]["seq"]
-        if coll_rows is not None:
-            doc["coll_rows"] = list(coll_rows)
-        elif include_table:
-            obs = self._observatory
-            if obs is None:
-                from deepspeed_tpu.collectives import observatory as obs_mod
-
-                obs = obs_mod.get_observatory()
-                # CollectiveObservatory.enabled is a PROPERTY — calling it
-                # raised TypeError on the push worker thread whenever a
-                # live observatory existed, silently killing fleet pushes
-                if not obs.enabled:
-                    obs = None
-            if obs is not None:
-                rows = obs.table_rows()
-                if rows:
-                    doc["coll_rows"] = rows
         return doc
 
     def push(self, heartbeat_extra: Optional[Dict[str, Any]] = None,
-             include_registry: bool = True,
-             include_table: bool = True,
-             coll_rows: Optional[List[dict]] = None
-             ) -> Optional[Dict[str, Any]]:
+             include_registry: bool = True) -> Optional[Dict[str, Any]]:
         """One synchronous snapshot push (background-thread and shutdown
         callers). ``heartbeat_extra`` merges caller facts into the
-        heartbeat (the resilience supervisor stamps rewind counts);
-        ``coll_rows`` ships an explicit observatory-row list instead of
-        pulling from the process observatory (tools/tests)."""
-        return self._send(self._build_doc(heartbeat_extra, include_registry,
-                                          include_table, coll_rows))
+        heartbeat (the resilience supervisor stamps rewind counts)."""
+        return self._send(self._build_doc(heartbeat_extra, include_registry))
 
     def push_async(self, heartbeat_extra: Optional[Dict[str, Any]] = None,
-                   include_registry: bool = True,
-                   include_table: bool = True) -> None:
+                   include_registry: bool = True) -> None:
         """Hot-path push: snapshot NOW (sub-millisecond — dump + heartbeat
         are dict walks), pay the HTTP round-trip on the client's worker
         thread. One pending slot, latest-wins: snapshots are cumulative, so
         an unsent older one is strictly superseded — a slow collector
         back-pressures into dropped intermediate snapshots, never into the
         caller's step."""
-        doc = self._build_doc(heartbeat_extra, include_registry,
-                              include_table)
+        doc = self._build_doc(heartbeat_extra, include_registry)
         self._ensure_worker()
         with self._pending_lock:
             self._pending = doc

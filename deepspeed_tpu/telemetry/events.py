@@ -1,9 +1,8 @@
 """Process-local structured event stream — the incident plane's front door.
 
 Every detector the repo has grown (diagnostics health/anomaly/recompile, the
-collective observatory's drift alarm, the numerics wire-drift/divergence
-sentinel, the perf gate, the router's liveness/migration paths, the rewind
-supervisor) used to terminate in a warn-once log line on whichever process
+numerics divergence sentinel, the perf gate, the router's
+liveness/migration paths, the rewind supervisor) used to terminate in a warn-once log line on whichever process
 happened to notice. This module gives those warnings a second, *typed*
 destination: an :class:`Event` with severity / subsystem / kind / labels /
 dedup key / process identity, appended to a bounded ring, exportable as
@@ -22,9 +21,8 @@ is bumped and ``events/deduped`` counts the suppression. That is the
 warn-once discipline, applied to the typed stream.
 
 The shared warn-once helper (:class:`WarnOnceSet` / :func:`warn_once`)
-unifies the two historic ``_warn_once`` implementations
-(``utils/logging.py`` message-keyed, ``collectives/observatory.py``
-key-keyed) so warn-once coverage and event coverage cannot drift apart:
+unifies the message-keyed ``utils/logging.py`` one and the key-keyed one of
+the detectors so warn-once coverage and event coverage cannot drift apart:
 one call logs once AND emits the typed event.
 """
 
@@ -362,13 +360,13 @@ def emit_event(subsystem: str, kind: str, message: str, *,
 # ------------------------------------------------------- shared warn-once
 class WarnOnceSet:
     """THE warn-once implementation (satellite of ISSUE 20): one keyed set
-    behind its own lock (callers may hold other non-reentrant locks — the
-    observatory's ``note_route`` does), logging once per key AND emitting a
-    typed event on that first occurrence.
+    behind its own lock (callers may hold other non-reentrant locks),
+    logging once per key AND emitting a typed event on that first
+    occurrence.
 
     Returns True when this call was the first for ``key`` (and therefore
-    logged + emitted), False on every repeat — the observatory/numerics
-    call sites branch on that.
+    logged + emitted), False on every repeat — the numerics call sites
+    branch on that.
     """
 
     def __init__(self, subsystem: str = "telemetry",

@@ -6,12 +6,12 @@ one tracer with a private ``perf_counter`` origin, one ``/metrics`` port.
 This module is the layer that lets N such processes read as ONE system:
 
   - :class:`ProcessIdentity` — (run_id, process_index, host, role) stamped
-    onto every registry exposition, tracer stream, flight-recorder dump and
-    observatory table row, so artifacts from different processes can be
-    joined after the fact. Process-global like the tracer
-    (:func:`get_identity` / :func:`configure_identity`); defaults come from
-    ``DSTPU_RUN_ID`` / ``DSTPU_PROCESS_INDEX`` / ``DSTPU_ROLE`` (the
-    launcher's contract), then ``jax.process_index()``, then 0.
+    onto every registry exposition, tracer stream and flight-recorder dump,
+    so artifacts from different processes can be joined after the fact.
+    Process-global like the tracer (:func:`get_identity` /
+    :func:`configure_identity`); defaults come from ``DSTPU_RUN_ID`` /
+    ``DSTPU_PROCESS_INDEX`` / ``DSTPU_ROLE`` (the launcher's contract),
+    then ``jax.process_index()``, then 0.
   - :func:`registry_dump` / :func:`merge_dump_into` — the wire format and
     merge rules for metric federation (``telemetry/collector.py``). The
     merge is exact by construction: counters SUM, the log-bucket histograms
@@ -61,7 +61,7 @@ ROLES = ("train", "router", "replica", "prefill", "decode", "collector",
 @dataclasses.dataclass
 class ProcessIdentity:
     """Who a telemetry stream came from — the join key for every
-    cross-process artifact (dumps, tables, traces, ledger rows)."""
+    cross-process artifact (dumps, traces, ledger rows)."""
 
     run_id: str
     process_index: int = 0

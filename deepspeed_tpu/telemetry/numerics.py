@@ -1,23 +1,17 @@
 """Numerics observatory: live quantization-fidelity and replica-integrity.
 
-The system runs lossy numerics on nearly every wire — int8/fp8 wire codecs,
-LoCo error feedback in the ZeRO++ gathers, quantized KV / weight-only-quant
-serving, the MoE int8 dispatch wire, n-gram speculative decode — yet until
-this module the only evidence any of it stayed accurate was a fixed bound in
-a one-off test. The performance observatory (``collectives/observatory.py``
-+ the perf ledger/gate) closed the *performance* feedback loop; this module
-closes the *correctness* one. Three planes, all riding the same sampled,
+The system runs lossy numerics on several wires — the int8 wire with LoCo
+error feedback in the ZeRO++ gathers, quantized KV / weight-only-quant
+serving, n-gram speculative decode — yet until this module the only evidence
+any of it stayed accurate was a fixed bound in a one-off test. The perf
+ledger closed the *performance* feedback loop; this module closes the
+*correctness* one. Three planes, all riding the same sampled,
 jaxpr-identical-when-off discipline:
 
-1. **Wire-fidelity probes** — routed lossy collectives register their
-   ``(op, codec, algorithm, backend)`` signature at trace time (one call
-   from ``comm._observe_route``); on sampled steps the observatory re-runs
-   each codec's encode→decode against a deterministic payload of the routed
-   shape and publishes ``numerics/wire_rel_err{op,codec,algorithm,backend}``
-   histograms. Error beyond ``drift_ratio ×`` the codec's pinned bound
-   (:data:`WIRE_REL_ERR_BOUNDS`, the same numbers the codec tests pin)
-   warns once, bumps ``numerics/wire_drift_events``, and arms the PR-7
-   profiler capture so the offending step window leaves a trace.
+1. **Error-feedback residual gauges** — on sampled steps the L2 norms of the
+   LoCo / 1-bit residuals carried in ``TrainState.comm_error``, a group of
+   leaves a gauge (``numerics/ef_residual_norm``): a norm that trends up
+   means the wire drops more than the feedback loop re-captures.
 
 2. **Cross-replica divergence sentinel** (:class:`DivergenceSentinel`) —
    a cheap per-leaf-group digest (sum-of-squares + bit-level xor checksum)
@@ -62,26 +56,6 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.utils.compat import shard_map
 from deepspeed_tpu.utils.logging import logger
 
-#: codecs whose wire drops information on fp32 payloads (bf16 passthrough
-#: downcasts, so it is lossy here even though it ships "uncompressed")
-LOSSY_CODECS = frozenset({"bf16", "int8", "fp8"})
-
-#: pinned per-codec relative-error bounds on unit-gaussian payloads — the
-#: SAME numbers the codec equivalence tests pin (int8 absmax/127 blockwise
-#: ~1-2%, fp8 E4M3 3 mantissa bits ~5-6%, bf16 8 mantissa bits ~4e-3);
-#: exact codecs get a float32-roundoff allowance
-WIRE_REL_ERR_BOUNDS: Dict[str, float] = {
-    "none": 1e-6,
-    "fp32": 1e-6,
-    "bf16": 8e-3,
-    "int8": 2e-2,
-    "fp8": 6e-2,
-}
-
-#: wire signatures past this are registered-but-not-probed (same capacity
-#: discipline as the collectives observatory)
-_MAX_ROUTES = 64
-
 
 # --------------------------------------------------------------------- config
 @dataclass
@@ -89,33 +63,13 @@ class NumericsConfig:
     """Tunables (the engine's ``numerics`` config block mirrors these)."""
 
     enabled: bool = False
-    sample_every: int = 16           # 1-in-N steps runs wire/serving probes
+    sample_every: int = 16           # 1-in-N steps runs EF-residual/serving probes
     sentinel: bool = True            # in-jit divergence sentinel (when enabled)
     sentinel_sample_every: int = 16  # 1-in-N train steps digests the params
     divergence_policy: str = "log"   # "log" | "abort"
-    max_probe_elems: int = 65536     # wire-probe payload cap (elements)
-    drift_ratio: float = 2.0         # rel_err > ratio*pinned bound => drift
     spec_accept_window: int = 64     # acceptance-rate trend window
     spec_accept_mads: float = 6.0    # PR-2 discipline width
     spec_accept_min_n: int = 8       # min history before the alarm can fire
-
-
-@dataclass
-class WireRoute:
-    """One routed lossy-collective signature, registered at trace time."""
-
-    op: str
-    codec: str
-    algorithm: str
-    backend: str
-    nbytes: int
-    itemsize: int
-    world: int
-    dtype: str
-    block_size: Optional[int] = None
-    routes: int = 0           # how many traces registered this signature
-    probes: int = 0           # how many fidelity probes ran for it
-    last_rel_err: float = float("nan")
 
 
 # ----------------------------------------------------------- digest primitives
@@ -386,10 +340,7 @@ class NumericsObservatory:
 
         self._warn_once_set = WarnOnceSet(subsystem="numerics",
                                           default_kind="fidelity_warning")
-        self._routes: Dict[Tuple, WireRoute] = {}
-        self._probe_cache: Dict[Tuple, Callable] = {}
         self.profiler_arm: Optional[Callable[..., None]] = None
-        self.wire_drift_events = 0
         self.divergence_events_seen = 0  # host-side last-seen cumulative
         self.spec_accept_alarm = TrendAlarm()
 
@@ -404,10 +355,7 @@ class NumericsObservatory:
             cfg = (dc_replace(config, **kwargs) if config is not None
                    else NumericsConfig(**kwargs))
             self.config = cfg
-            self._routes.clear()
-            self._probe_cache.clear()
             self._warn_once_set.reset()
-            self.wire_drift_events = 0
             self.divergence_events_seen = 0
             self.spec_accept_alarm = TrendAlarm(
                 window=cfg.spec_accept_window, mads=cfg.spec_accept_mads,
@@ -423,140 +371,8 @@ class NumericsObservatory:
     def warn_once(self, key: str, msg: str) -> bool:
         """Log ``msg`` once per ``key`` per configure() epoch (shared
         warn-once helper: the first occurrence also lands on the typed
-        event stream). Active even when the observatory is disabled (the
-        forced-lossy-codec warning must fire regardless of whether anyone
-        is measuring)."""
+        event stream)."""
         return self._warn_once_set(key, msg, log=logger)
-
-    # ------------------------------------------------- trace-time registry
-    def note_route(self, op: str, algorithm: str, codec: str, nbytes: int,
-                   itemsize: int, world: int, axis, dtype: str,
-                   block_size: Optional[int] = None) -> None:
-        """Register one routed facade collective (called at trace time from
-        ``comm._observe_route`` next to the perf observatory's hook). Only
-        lossy codecs get fidelity probes; exact wires have nothing to
-        measure."""
-        if not self.config.enabled:
-            return
-        if codec is None:
-            codec = "none"
-        codec = str(codec)
-        if codec not in LOSSY_CODECS:
-            return
-        key = (op, codec, algorithm, str(dtype), block_size)
-        with self._lock:
-            info = self._routes.get(key)
-            if info is None:
-                if len(self._routes) >= _MAX_ROUTES:
-                    return
-                from deepspeed_tpu.collectives.observatory import _backend_of
-
-                try:
-                    backend = _backend_of(algorithm)
-                except Exception:
-                    backend = "xla"
-                info = self._routes[key] = WireRoute(
-                    op=op, codec=codec, algorithm=algorithm, backend=backend,
-                    nbytes=int(nbytes), itemsize=int(itemsize),
-                    world=int(world), dtype=str(dtype),
-                    block_size=block_size)
-            info.routes += 1
-            info.nbytes = max(info.nbytes, int(nbytes))
-
-    def routes(self) -> List[WireRoute]:
-        with self._lock:
-            return list(self._routes.values())
-
-    # ---------------------------------------------------------- wire probes
-    def _roundtrip_fn(self, codec: str, block: Optional[int], elems: int):
-        key = (codec, block, elems)
-        fn = self._probe_cache.get(key)
-        if fn is None:
-            from deepspeed_tpu.collectives.codecs import get_codec
-
-            c = get_codec(codec, block)
-
-            def roundtrip(x):
-                wire = c.encode_rows(x)
-                y = c.decode_rows(wire, x.shape[1], jnp.float32)
-                num = jnp.sqrt(jnp.sum((x - y) ** 2))
-                den = jnp.sqrt(jnp.sum(x * x))
-                return num / jnp.maximum(den, 1e-12)
-
-            fn = self._probe_cache[key] = jax.jit(roundtrip)
-            if len(self._probe_cache) > 4 * _MAX_ROUTES:
-                self._probe_cache.clear()
-                self._probe_cache[key] = fn
-        return fn
-
-    def _probe_route(self, route: WireRoute) -> float:
-        """One standalone encode→decode fidelity measurement against a
-        deterministic payload of the routed shape (byte-capped)."""
-        elems = max(16, route.nbytes // max(route.itemsize, 1))
-        elems = min(elems, int(self.config.max_probe_elems))
-        seed = abs(hash((route.op, route.codec, route.algorithm))) % (2**31)
-        x = np.asarray(
-            np.random.RandomState(seed).standard_normal((1, elems)),
-            np.float32)
-        rel = float(jax.device_get(
-            self._roundtrip_fn(route.codec, route.block_size, elems)(x)))
-        route.probes += 1
-        route.last_rel_err = rel
-        return rel
-
-    def sample_now(self) -> Dict[str, float]:
-        """Force a full wire-fidelity probe round over every registered
-        route; returns ``{op/codec: rel_err}``. The sampled-step path
-        (:meth:`on_step`) calls this 1-in-``sample_every`` steps."""
-        if not self.config.enabled:
-            return {}
-        out: Dict[str, float] = {}
-        reg = _registry()
-        for route in self.routes():
-            try:
-                rel = self._probe_route(route)
-            except Exception as e:  # a probe must never kill the step loop
-                self.warn_once(
-                    f"probe_fail:{route.op}/{route.codec}",
-                    f"numerics wire probe failed for {route.op}/"
-                    f"{route.codec}: {type(e).__name__}: {e}")
-                continue
-            out[f"{route.op}/{route.codec}"] = rel
-            reg.histogram("numerics/wire_rel_err", op=route.op,
-                          codec=route.codec, algorithm=route.algorithm,
-                          backend=route.backend).observe(rel)
-            bound = WIRE_REL_ERR_BOUNDS.get(route.codec)
-            if bound is not None and rel > bound * self.config.drift_ratio:
-                self.wire_drift_events += 1
-                reg.counter("numerics/wire_drift_events", op=route.op,
-                            codec=route.codec).add(1)
-                self._warn_once_set(
-                    f"drift:{route.op}/{route.codec}",
-                    f"numerics drift: {route.op}/{route.codec} wire rel err "
-                    f"{rel:.3e} exceeds {self.config.drift_ratio:g}x the "
-                    f"pinned bound {bound:.3e} "
-                    f"(algorithm={route.algorithm})",
-                    kind="wire_drift",
-                    labels={"op": route.op, "codec": route.codec,
-                            "algorithm": route.algorithm},
-                    log=logger)
-                if self.profiler_arm is not None:
-                    try:
-                        self.profiler_arm(
-                            reason=f"numerics_drift:{route.op}/{route.codec}")
-                    except Exception:
-                        pass
-        return out
-
-    def on_step(self, step: int) -> Dict[str, float]:
-        """Host-side sampled hook (engine step loop). Cheap when off or on
-        a non-sampled step: one attribute check + one modulo."""
-        cfg = self.config
-        if not cfg.enabled or cfg.sample_every <= 0:
-            return {}
-        if step % cfg.sample_every != 0:
-            return {}
-        return self.sample_now()
 
     # ----------------------------------------------------- EF residual gauges
     def note_ef_residuals(self, err_tree) -> Dict[str, float]:
@@ -696,11 +512,3 @@ def configure(config: Optional[NumericsConfig] = None,
 
 def enabled() -> bool:
     return _observatory.enabled
-
-
-def note_route(*args, **kwargs) -> None:
-    _observatory.note_route(*args, **kwargs)
-
-
-def warn_once(key: str, msg: str) -> bool:
-    return _observatory.warn_once(key, msg)

@@ -3,7 +3,7 @@
 Host-side observability (spans, SLO metrics, flight records) watches the
 *dispatch* of programs; this module watches the *programs themselves*. Every
 jitted callable the engines build — train/eval/grad/apply/offload steps, the
-v2 prefill/decode-chain programs, collectives probes — is captured once per
+v2 prefill/decode-chain programs — is captured once per
 compile at the same wrap point the recompile detector already owns, and per
 program the registry records:
 
@@ -13,8 +13,7 @@ program the registry records:
     peak HBM (argument + output − alias + temp: XLA's own live-set bound)
   - a donation/aliasing summary (aliased bytes + input→output alias pairs)
   - the collective ops in the compiled HLO text: op kind, tensor bytes,
-    replica groups — the measured per-program comm volume the cost models in
-    ``collectives/selector.py`` otherwise have to assume
+    replica groups — the measured per-program comm volume
   - an HLO fingerprint (content hash + instruction count) so a recompile
     report can say *what grew*, not just which argument shape changed
 
@@ -169,12 +168,6 @@ class ProgramRecord:
     custom_kernels: List[Dict[str, Any]] = field(default_factory=list)
     hbm_estimate_bytes: Optional[int] = None
     hbm_estimate_ratio: Optional[float] = None
-    # wire bytes the collectives observatory traced for the ROUTED facade
-    # collectives of this program, and the ratio of the HLO-extracted
-    # collective bytes to them (collectives/observatory.py reconciliation)
-    routed_wire_bytes: int = 0
-    wire_ratio: Optional[float] = None
-
     @property
     def collective_bytes(self) -> int:
         return sum(c["bytes"] for c in self.collectives)
@@ -209,8 +202,6 @@ class ProgramRecord:
             "custom_kernels": list(self.custom_kernels),
             "hbm_estimate_bytes": self.hbm_estimate_bytes,
             "hbm_estimate_ratio": self.hbm_estimate_ratio,
-            "routed_wire_bytes": self.routed_wire_bytes,
-            "wire_ratio": self.wire_ratio,
         }
 
 
@@ -459,30 +450,6 @@ class ProgramRegistry:
             collectives=colls, custom_kernels=kernels,
         )
 
-        # Reconcile the wire bytes the selector's routing traced (the
-        # observatory's per-trace census, drained since the last capture)
-        # against what the compiled HLO actually moves. HLO collective
-        # bytes include EVERY collective (loss psums, GSPMD resharding), so
-        # the ratio runs >= 1 on healthy programs; well below 1 means routed
-        # wires the extraction cannot see — the selector is costing bytes
-        # that never hit the interconnect.
-        try:
-            from deepspeed_tpu.collectives import observatory as _coll_obs
-
-            routed = _coll_obs.drain_program_wire()
-        except Exception:  # noqa: BLE001 — reconciliation is best-effort
-            routed = 0
-        if routed > 0:
-            record.routed_wire_bytes = routed
-            record.wire_ratio = record.collective_bytes / routed
-            if record.wire_ratio < 0.5:
-                logger.warning(
-                    f"collectives: program {label!r} lowered "
-                    f"{record.collective_bytes} collective bytes but the "
-                    f"selector's routing traced {routed} wire bytes "
-                    f"(ratio {record.wire_ratio:.2f}) — routed wires are "
-                    "not reaching the interconnect as costed")
-
         estimate = self.hbm_estimate(hbm_scope) if hbm_scope else None
         if estimate:
             from deepspeed_tpu.utils.hbm import record_calibration
@@ -517,8 +484,6 @@ class ProgramRegistry:
             ("program/custom_kernel_count", r.custom_kernel_count),
         ):
             reg.gauge(name, program=r.label).set(float(value))
-        if r.wire_ratio is not None:
-            reg.gauge("coll/wire_bytes_ratio", program=r.label).set(r.wire_ratio)
         reg.counter("compile/count", program=r.label).add(1.0)
         if r.compile_wall_s is not None:
             reg.gauge("compile/last_wall_ms", program=r.label).set(

@@ -6,8 +6,7 @@ Written for the ONE installation there is (jax/jaxlib 0.9.0, libtpu 0.0.34):
 versions. What remains is (a) one import point for the symbols that have
 moved before — ``shard_map`` and ``axis_size``; a tier-1 lint
 (tests/unit/test_no_bare_shard_map.py) keeps call sites from reaching past
-it — (b) the one private reach the Pallas collectives need
-(``axis_env_sizes``), and (c) helpers that are about BACK-ENDS, not versions
+it — and (b) helpers that are about BACK-ENDS, not versions
 (``with_memory_kind``, ``device_put_unaliased``, ``host_copy_unaliased``).
 """
 
@@ -22,8 +21,8 @@ shard_map = jax.shard_map
 
 def axis_size(axis, default: Optional[int] = None) -> int:
     """``jax.lax.axis_size`` over an axis name or a tuple of them. This is
-    THE axis-size helper — the comm facade, zeropp, and the collectives
-    algorithms all route here.
+    THE axis-size helper — the comm facade, zeropp and the quantized
+    collectives all route here.
 
     Outside a bound-axis context the size is unknowable; pass ``default`` to
     get it back instead of the NameError (the comm facade's record path uses
@@ -49,16 +48,6 @@ def shape_dtype_struct(shape, dtype, *like):
     for a in like:
         vma = vma | getattr(jax.typeof(a), "vma", frozenset())
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-
-
-def axis_env_sizes() -> "dict[str, int]":
-    """(name -> size) of every mesh axis bound in the trace-time axis env,
-    in binding order (full-manual shard_map binds them all); ``{}`` outside
-    any bound-axis context. The axis env has no public accessor — this is
-    the package's one reach into ``jax._src``."""
-    from jax._src import core as _core
-
-    return {str(k): int(v) for k, v in dict(_core.get_axis_env().axis_sizes).items()}
 
 
 def tpu_compiler_params(**kwargs):

@@ -14,8 +14,8 @@ Contract under test:
     flush after a migration releases only its own reference
   - import refusal (destination capacity) leaves the destination unchanged
     and — at the router level — the request on its source, never dropped
-  - the remote-DMA transport (PR-8 hop kernel shape) moves buffer leaves
-    rank-to-rank bit-identically on the CPU mesh
+  - the cross-chip transport (one ``lax.ppermute`` a leaf) moves buffer
+    leaves rank-to-rank bit-identically on the CPU mesh
   - router-level: disagg serving is greedy token-identical to a single
     engine, migration stamps land, thread-per-replica dispatch actually
     overlaps (the two-replica concurrency pin)
@@ -233,31 +233,25 @@ def test_transposition_perm_is_full_permutation():
         transposition_perm(2, 0, 5)
 
 
-def test_remote_copy_pages_moves_bytes_rank_to_rank():
-    """The PR-8 hop-kernel transport shape on the CPU mesh: rank dst's
-    shard ends up holding rank src's pages bit-identically — values and
-    fp32 scale pages in ONE permutation (interpret falls back to ppermute
-    where the interpreter cannot discharge remote DMA; compiled TPU runs
-    the make_async_remote_copy kernel — same permutation, same bytes)."""
-    from jax.sharding import Mesh
+@pytest.mark.parametrize("src,dst", [(0, 3), (2, 1), (1, 1)], ids=["first-to-last", "backwards", "to-itself"])
+def test_remote_copy_pages_moves_bytes_rank_to_rank(src, dst):
+    """The cross-chip transport on the CPU mesh: rank dst's shard ends up holding rank src's pages bit-identically —
+    values and fp32 scale pages under ONE permutation, each leaf by one ``collective_permute`` and no kernel."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    n = min(4, jax.device_count())
-    if n < 2:
-        pytest.skip("needs >= 2 devices")
+    n = 4
     mesh = Mesh(np.asarray(jax.devices()[:n]), ("mig",))
     rng = np.random.RandomState(5)
-    values = jnp.asarray(
-        rng.randint(-128, 127, (n, 2, 8, 2, 4)), jnp.int8)
+    values = jnp.asarray(rng.randint(-128, 127, (n, 2, 8, 2, 4)), jnp.int8)
     scales = jnp.asarray(rng.randn(n, 2, 8, 2, 1), jnp.float32)
-    src, dst = 0, n - 1
     out_v, out_s = remote_copy_pages([values, scales], mesh, "mig", src, dst)
-    np.testing.assert_array_equal(np.asarray(out_v)[dst],
-                                  np.asarray(values)[src])
-    np.testing.assert_array_equal(np.asarray(out_s)[dst],
-                                  np.asarray(scales)[src])
-    # the reverse edge of the transposition moved too
-    np.testing.assert_array_equal(np.asarray(out_v)[src],
-                                  np.asarray(values)[dst])
+    want = list(range(n))
+    want[dst], want[src] = src, dst  # the transposition's two edges; every other rank keeps its own
+    np.testing.assert_array_equal(np.asarray(out_v), np.asarray(values)[want])
+    np.testing.assert_array_equal(np.asarray(out_s), np.asarray(scales)[want])
+    placed = [jax.device_put(x, NamedSharding(mesh, P("mig"))) for x in (values, scales)]
+    text = jax.jit(lambda v, s: remote_copy_pages([v, s], mesh, "mig", src, dst)).lower(*placed).as_text()
+    assert text.count("stablehlo.collective_permute") == 2 and "custom_call @tpu_custom_call" not in text
 
 
 # --------------------------------------------------------------- router level

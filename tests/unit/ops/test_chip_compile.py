@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 GPT2 = dict(H=12, kvH=12, hd=64, E=768)
 
@@ -156,26 +156,25 @@ def test_the_train_scan_stacks_the_flash_statistics_unpadded(one_chip, monkeypat
     assert max(stacked.values()) <= 2 * layers * B * H * S * 4, stacked
 
 
-def test_the_zero3_layer_scan_states_its_own_collectives(topo, monkeypatch):
-    """The ZeRO-3 cell's own ``train_step`` (``pythia-1.4b.train.zero3-4chip``,
-    real widths, cut to two layers), compiled for the described 2x2 with
-    ``tools/train_step_for_described_chip.py``: since PR 45 the products of
-    the scanned layer gather their weights themselves (``runtime/zero.py``),
-    so the scan's bodies hold the six large leaves as six whole all-gathers
-    each (alone or inside an ``async_collective_fusion``), the backward body
-    hands their gradients over under ``zero_scatter`` (a reduce-scatter, or
-    the hops of a ring the program wrote itself: 75.5 MB a layer, what a
-    reduce-scatter sends), and the partitioner's rings of
-    ``collective-permute`` (75.5 MB in the forward body, 192.9 MB in the
-    backward body at the parent) are gone. The gathered weight is never a
-    residual of the scan, and a shard dimension that is not the leaf's own
-    would show as an all-to-all of hundreds of MB. ``temp_gb`` at this cut was
-    1.446 at PR 44's tree (my compile for the described chip, PR 45)."""
+def _large_leaves(config):
+    """Bytes of a layer's six large leaves in bf16, by the name their gathers and scatters carry."""
+    E, I = config["hidden_size"], config["intermediate_size"]
+    return {"attn/wq": 2 * E * E, "attn/wk": 2 * E * E, "attn/wv": 2 * E * E, "attn/wo": 2 * E * E,
+            "mlp/w_up": 2 * E * I, "mlp/w_down": 2 * E * I}
+
+
+def _leaf_of(op_name):
+    return op_name.split("layers/")[-1].split("/shard_map")[0]
+
+
+def _zero3_cell_compiled(monkeypatch, mesh_axes=None, layers=2):
+    """(the tool, the cell's config, its ``train_step`` compiled for the described 2x2 at real widths and ``layers``
+    layers): ``pythia-1.4b.train.zero3-4chip`` through ``tools/train_step_for_described_chip.py``, on the cell's own
+    mesh or on ``mesh_axes`` with the same sixteen sequences a step."""
     import importlib.util
     import json
     import os
     import pkgutil
-    import re
 
     import deepspeed_tpu.ops.pallas as pallas_pkg
     from benchmarks.lib import program
@@ -199,19 +198,43 @@ def test_the_zero3_layer_scan_states_its_own_collectives(topo, monkeypatch):
     cell = "pythia-1.4b.train.zero3-4chip"
     workload = json.load(open(os.path.join(root, "benchmarks", "workloads", cell + ".json")))
     config = json.load(open(os.path.join(root, "benchmarks", "configs", workload["config"] + ".json")))
-    config["num_hidden_layers"] = layers = 2
+    config["num_hidden_layers"] = layers
     sequences, seq_len = int(workload["traffic"]["sequences"]), int(workload["traffic"]["seq_len"])
+    engine = dict(workload["engine"])
+    if mesh_axes is not None:
+        replicas = mesh_axes.get("dp", 1) * mesh_axes.get("fsdp", 1)
+        engine.update(mesh=mesh_axes, gradient_accumulation_steps=sequences // replicas)
     compiled = tool.compile_train_step(
         causal_lm_spec(program.model_config(config, jnp.bfloat16), example_seq_len=seq_len),
-        dict(workload["engine"]), {"input_ids": np.zeros((sequences, seq_len), np.int32)})
+        engine, {"input_ids": np.zeros((sequences, seq_len), np.int32)})
+    return tool, config, compiled
+
+
+def test_the_zero3_layer_scan_states_its_own_collectives(topo, monkeypatch):
+    """The ZeRO-3 cell's own ``train_step`` (``pythia-1.4b.train.zero3-4chip``,
+    real widths, cut to two layers), compiled for the described 2x2 with
+    ``tools/train_step_for_described_chip.py``: since PR 45 the products of
+    the scanned layer gather their weights themselves (``runtime/zero.py``),
+    so the scan's bodies hold the six large leaves as six whole all-gathers
+    each (alone or inside an ``async_collective_fusion``), the backward body
+    hands their gradients over under ``zero_scatter`` (a reduce-scatter, or
+    the hops of a ring the program wrote itself: 75.5 MB a layer, what a
+    reduce-scatter sends), and the partitioner's rings of
+    ``collective-permute`` (75.5 MB in the forward body, 192.9 MB in the
+    backward body at the parent) are gone. The gathered weight is never a
+    residual of the scan, and a shard dimension that is not the leaf's own
+    would show as an all-to-all of hundreds of MB. ``temp_gb`` at this cut was
+    1.446 at PR 44's tree (my compile for the described chip, PR 45)."""
+    import re
+
+    layers = 2
+    tool, config, compiled = _zero3_cell_compiled(monkeypatch, layers=layers)
     text = compiled.as_text()
     found = tool.census(text)
     forward, backward = (found[tool.name_of(tool.holding(text, kernel))] for kernel in ("flash_fwd", "flash_bwd_dkv"))
 
     E, I = config["hidden_size"], config["intermediate_size"]
-    leaves = {"attn/wq": 2 * E * E, "attn/wk": 2 * E * E, "attn/wv": 2 * E * E, "attn/wo": 2 * E * E,
-              "mlp/w_up": 2 * E * I, "mlp/w_down": 2 * E * I}  # bytes of a layer's large leaves, bf16
-    leaf_of = lambda op: op.split("layers/")[-1].split("/shard_map")[0]  # noqa: E731
+    leaves, leaf_of = _large_leaves(config), _leaf_of
     for body in (forward, backward):
         gathers = sorted((leaf_of(op), round(mb * 1e6)) for kind, mb, op in body
                          if kind == "all-gather" and "zero_gather" in op)
@@ -236,6 +259,31 @@ def test_the_zero3_layer_scan_states_its_own_collectives(topo, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes / 1e9 <= 1.446 + 0.3
     largest = max(mb for body in found.values() for kind, mb, _ in body if kind == "all-to-all")
     assert largest < 20, largest
+
+
+@pytest.mark.parametrize("mesh_axes", [{"dp": 2, "fsdp": 2}, {"fsdp": 2, "tp": 2}], ids=["dp2-fsdp2", "fsdp2-tp2"])
+def test_the_zero3_cell_s_other_meshes_compile_for_the_chip(topo, monkeypatch, mesh_axes):
+    """The two other ways to lay the ZeRO-3 cell over four chips, which ran on the CPU's devices only (PR 45's review,
+    finding 4): both compile for the described 2x2 at the cell's widths, hold the flash kernels and fit a chip. With
+    two replicas of two shards the scanned layer still gathers its own weights, half a leaf a chip, and hands the
+    gradients over under ``zero_scatter``; with ``tp`` = 2 every collective of the scan stays the partitioner's
+    (``runtime/zero.py::scan_gathers``) and none carries a name of ``runtime/zero.py``."""
+    tool, config, compiled = _zero3_cell_compiled(monkeypatch, mesh_axes)
+    text = compiled.as_text()
+    found = tool.census(text)
+    forward, backward = (found[tool.name_of(tool.holding(text, kernel))] for kernel in ("flash_fwd", "flash_bwd_dkv"))
+    held = compiled.memory_analysis()
+    assert (held.temp_size_in_bytes + held.argument_size_in_bytes) / 1e9 < 16
+    if "tp" in mesh_axes:
+        assert "zero_gather" not in text and "zero_scatter" not in text
+        return
+    leaves, leaf_of = _large_leaves(config), _leaf_of
+    for body in (forward, backward):
+        gathers = sorted((leaf_of(op), round(mb * 1e6)) for kind, mb, op in body
+                         if kind == "all-gather" and "zero_gather" in op)
+        assert gathers == sorted(leaves.items())
+    leaving = {leaf_of(op) for kind, _, op in backward if "zero_scatter" in op}
+    assert leaving == set(leaves) and not any("zero_scatter" in op for _, _, op in forward)
 
 
 def test_flash_attention_partitions_over_four_chips(topo, monkeypatch):
@@ -604,34 +652,3 @@ def test_rms_norm_partitions_over_four_chips(topo, monkeypatch):
     assert _compiled_kernels(rms_norm, x, scale) == 1
 
 
-def _ring_all_reduce(topo, monkeypatch, codec):
-    """Lower+compile ``pallas_ring`` all-reduce of [4, 1Mi] fp32 on the
-    described 4-chip mesh; returns the compiled HLO text."""
-    from deepspeed_tpu import collectives
-    from deepspeed_tpu.collectives import pallas_backend as pb
-    from deepspeed_tpu.utils.compat import shard_map
-
-    monkeypatch.setattr(pb, "_interpret", lambda: False)
-    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("dp",))
-    x = jax.ShapeDtypeStruct((4, 1 << 20), jnp.float32, sharding=NamedSharding(mesh, P("dp")))
-    body = lambda row: collectives.all_reduce(row, "dp", algorithm="pallas_ring", codec=codec)
-    fn = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"), check_vma=False)
-    return jax.jit(fn).lower(x).compile().as_text()
-
-
-def test_pallas_ring_all_reduce_four_chips(topo, monkeypatch):
-    text = _ring_all_reduce(topo, monkeypatch, "none")
-    assert "tpu_custom_call" in text
-    assert "collective-permute" not in text  # every hop is a remote-DMA kernel
-
-
-def test_pallas_ring_fused_int8_hop_is_refused_by_name(topo, monkeypatch):
-    """The quantized fused hop does not compile on this Mosaic; until it is
-    rebuilt it must say so — not fall back — and the selector must not pick
-    the pair on its own."""
-    from deepspeed_tpu.collectives import pallas_backend as pb
-
-    with pytest.raises(NotImplementedError, match="fused int8 hop"):
-        _ring_all_reduce(topo, monkeypatch, "int8")
-    assert not pb.compiled_ok("pallas_ring", "int8")
-    assert pb.compiled_ok("pallas_ring", "none") and pb.compiled_ok("ring", "int8")

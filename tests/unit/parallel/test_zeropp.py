@@ -228,3 +228,51 @@ def test_zpp_composes_with_ulysses_sp(devices):
     batch = {"input_ids": rng.integers(0, 64, (eng.train_batch_size, 32), dtype=np.int32)}
     loss = float(eng.train_batch(batch)["loss"])
     assert np.isfinite(loss)
+
+
+# ------------------------------------------- the differentiable gather, against NumPy
+
+def _gather_and_grad(dim, qw, qg, world=8):
+    """(what went in, the forward's full weight a rank, a rank's shard gradient) of ``sharded_weight_gather`` over
+    ``dp``: the loss of rank r is ``sum(full * C_r)``, so the full weight's gradient there is ``C_r``."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from deepspeed_tpu.parallel.zeropp import sharded_weight_gather
+    from deepspeed_tpu.utils.compat import shard_map
+
+    mesh = Mesh(np.array(jax.devices()[:world]), ("dp",))
+    rng = np.random.default_rng(7)
+    shard_shape, full_shape = ((2, 6), (2 * world, 6)) if dim == 0 else ((3, 4), (3, 4 * world))
+    shards = jnp.asarray(rng.standard_normal((world, *shard_shape)), jnp.float32)
+    coeffs = jnp.asarray(rng.standard_normal((world, *full_shape)), jnp.float32)
+
+    def per_rank(shard, coeff):
+        gather = lambda s: sharded_weight_gather(s, dim, ("dp",), (), qw, qg, 16)  # noqa: E731
+        full, back = jax.vjp(gather, shard[0])
+        return full[None], back(coeff[0])[0][None]
+
+    full, grad = jax.jit(shard_map(per_rank, mesh=mesh, in_specs=(P("dp"), P("dp")), out_specs=(P("dp"), P("dp")),
+                                   check_vma=False))(shards, coeffs)
+    return np.asarray(shards), np.asarray(coeffs), np.asarray(full), np.asarray(grad)
+
+
+@pytest.mark.parametrize("dim", [0, 1])
+@pytest.mark.parametrize("qw", [False, True], ids=["exact", "qwZ"])
+def test_weight_gather_forward_is_numpy_s_concatenate(devices, qw, dim):
+    """Every rank holds the shards side by side along the leaf's own dimension: to the bit on the exact wire, within
+    half an int8 step of a 16-element block's largest value on the quantized one."""
+    shards, _, full, _ = _gather_and_grad(dim, qw, False)
+    want = np.concatenate(list(shards), dim)
+    tol = np.abs(shards).max() / 127 / 2 + 1e-6 if qw else 0.0
+    for rank in range(len(shards)):
+        assert np.abs(full[rank] - want).max() <= tol
+
+
+@pytest.mark.parametrize("dim", [0, 1])
+@pytest.mark.parametrize("qg", [False, True], ids=["exact", "qgZ"])
+def test_weight_gather_backward_is_a_rank_s_slice_of_the_mean_gradient(devices, qg, dim):
+    _, coeffs, _, grad = _gather_and_grad(dim, False, qg)
+    mean = coeffs.mean(0)
+    tol = np.abs(coeffs).max() / 127 / 2 + 1e-6 if qg else 1e-6
+    for rank, want in enumerate(np.split(mean, len(coeffs), dim)):
+        assert np.abs(grad[rank] - want).max() <= tol

@@ -130,18 +130,34 @@ def test_strict_key_not_swallowed():
     assert cfg.train_micro_batch_size_per_gpu == 2
 
 
-def test_collectives_section():
-    cfg = DeepSpeedTPUConfig({
-        "collectives": {
-            "enabled": True, "algorithm": "ring2d", "codec": "int8",
-            "codecs": ["none", "int8"], "mode": "measured",
-            "overlap_chunks": 4, "block_size": 512,
-        }
-    })
-    c = cfg.model.collectives
-    assert c.enabled and c.algorithm == "ring2d" and c.codec == "int8"
-    assert c.codecs == ["none", "int8"] and c.mode == "measured"
-    assert c.overlap_chunks == 4 and c.block_size == 512
-    # defaults: disabled, invisible
-    d = DeepSpeedTPUConfig({}).model.collectives
-    assert not d.enabled and d.algorithm == "auto" and d.overlap_chunks == 1
+_GONE = {"enabled": True, "algorithm": "ring2d", "overlap_chunks": 4}
+
+
+@pytest.mark.parametrize("config,owner,key", [
+    ({"collectives": _GONE}, "EngineConfig", "collectives"),
+    ({"numerics": {"enabled": True, "drift_ratio": 3.0}}, "NumericsConfig", "drift_ratio"),
+    ({"numerics": {"max_probe_elems": 1024}}, "NumericsConfig", "max_probe_elems"),
+    ({"zero_optimization": {"stage": 1, "no_such_zero_key": 3}}, "ZeroConfig", "no_such_zero_key"),
+], ids=["collectives-block", "numerics-drift_ratio", "numerics-max_probe_elems", "any-other"])
+def test_a_key_that_left_the_schema_is_an_unknown_key(caplog, config, owner, key):
+    """The ``collectives`` block and the wire probes' two settings left the schema with the code they configured: a
+    config that still carries one parses, keeps it among its section's extras, and is told so by the key's name, as
+    any unknown key is."""
+    import logging
+
+    from deepspeed_tpu.telemetry import events
+
+    events.reset_warn_once()
+    lg = logging.getLogger("deepspeed_tpu")
+    prev, lg.propagate = lg.propagate, True
+    try:
+        with caplog.at_level(logging.WARNING, logger="deepspeed_tpu"):
+            cfg = DeepSpeedTPUConfig({"train_micro_batch_size_per_gpu": 2, **config})
+    finally:
+        lg.propagate = prev
+    section = cfg.model if owner == "EngineConfig" else getattr(cfg.model, next(iter(config)))
+    assert type(section).__name__ == owner and key not in type(section).model_fields
+    assert key in section.extra_fields()
+    said = [r.getMessage() for r in caplog.records if "unknown config key" in r.getMessage()]
+    assert len(said) == 1 and repr(key) in said[0] and owner in said[0], said
+    assert cfg.train_micro_batch_size_per_gpu == 2

@@ -422,7 +422,7 @@ def test_default_rules_quiet_on_empty_state():
     eng, reg = _engine_with(alerts_mod.default_rules(), clk)
     assert eng.evaluate() == [] and eng.firing() == []
     names = {r.name for r in eng.rules}
-    assert {"numerics_divergence", "collective_drift", "replica_dead",
+    assert {"numerics_divergence", "replica_dead",
             "replica_unreachable", "rpc_failures", "health_abort",
             "recompile_storm"} <= names
     # and loud once a defect counter moves

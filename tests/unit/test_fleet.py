@@ -2,14 +2,13 @@
 
 Contract under test:
   - process identity: env/config resolution, stamps on expositions, JSONL
-    streams, flight-recorder dumps, observatory table rows
+    streams, flight-recorder dumps
   - metric federation is EXACT: merging K sharded registries equals
     observing the concatenated sample stream (property test — quantiles
     and bucket counts bit-identical; counter sum + gauge last-per-proc
     rules pinned alongside)
   - FleetCollector: push/scrape ingestion, federated render, fleet/*
-    rollups, cross-process straggler flags, health ledger, federated
-    observatory table round-trip into a fresh selector's measured mode
+    rollups, cross-process straggler flags, health ledger
   - distributed tracing: TraceContext wire round-trip, stable flow ids,
     dispatch_span emission, trace_merge joining per-process JSONL into one
     flow-linked Perfetto trace
@@ -93,32 +92,6 @@ def test_identity_stamped_on_flight_record(tmp_path):
     assert os.path.basename(rec._resolve_path(None)) == "flight_record.jsonl"
 
 
-def test_observatory_rows_and_table_stamped(tmp_path):
-    from deepspeed_tpu.collectives import observatory, table as table_mod
-
-    obs = observatory.CollectiveObservatory()
-    obs.configure(enabled=True, persist=False)
-    row = obs.record_sample(op="all_reduce", algorithm="ring", codec="none",
-                            backend="ppermute", world=8, size_mb=0.1,
-                            latency_ms=1.0, itemsize=4)
-    assert row["proc"] == "testrun/p0"
-    path = obs.persist(str(tmp_path / "t.json"))
-    payload = json.load(open(path))
-    assert payload["identity"]["run_id"] == "testrun"
-    # proc stamp does not participate in merge identity
-    other = dict(row, proc="testrun/p1", latency_ms=3.0)
-    merged = table_mod.merge_rows([row], [other], ema=0.5)
-    assert len(merged) == 1 and merged[0]["latency_ms"] == 2.0
-
-
-def test_observatory_default_table_path_is_per_process():
-    from deepspeed_tpu.collectives import observatory
-
-    assert observatory.default_table_path().endswith("coll_table.json")
-    fleet.configure_identity(process_index=4)
-    assert observatory.default_table_path().endswith("coll_table.p4.json")
-
-
 # ----------------------------------------------------- federation (exact)
 def test_histogram_merge_is_exact_property():
     """Merging K sharded registries == observing the concatenated stream:
@@ -177,11 +150,9 @@ def _push_worker(collector, k, step_rate=10.0, requests=3):
     reg.histogram("serving/ttft_ms").observe(5.0 * (k + 1))
     reg.gauge("serving/tokens_per_s").set(100.0)
     ident = fleet.ProcessIdentity("testrun", k, host="h", role="replica")
-    client = FleetClient(collector.url, identity=ident, registry=reg,
-                         observatory=None)
+    client = FleetClient(collector.url, identity=ident, registry=reg)
     assert client.register()["ok"]
-    ack = client.push(heartbeat_extra={"step_rate": step_rate},
-                      include_table=False)
+    ack = client.push(heartbeat_extra={"step_rate": step_rate})
     assert ack["ok"]
     return reg, client
 
@@ -219,11 +190,9 @@ def test_collector_per_role_rollups():
             reg = MetricsRegistry()
             reg.gauge("serving/tokens_per_s").set(100.0 * (k + 1))
             ident = fleet.ProcessIdentity("testrun", k, host="h", role=role)
-            client = FleetClient(col.url, identity=ident, registry=reg,
-                                 observatory=None)
+            client = FleetClient(col.url, identity=ident, registry=reg)
             assert client.register()["ok"]
-            assert client.push(heartbeat_extra={"step_rate": 10.0 * (k + 1)},
-                               include_table=False)["ok"]
+            assert client.push(heartbeat_extra={"step_rate": 10.0 * (k + 1)})["ok"]
         fed = col.federated_registry()
         assert fed.gauge("fleet/role_processes", role="prefill").value == 1.0
         assert fed.gauge("fleet/role_processes", role="decode").value == 2.0
@@ -274,7 +243,7 @@ def test_collector_replaces_not_adds_on_repush():
     try:
         reg, client = _push_worker(col, 0, requests=3)
         reg.counter("serving/requests").add(2.0)  # now 5 cumulative
-        client.push(include_table=False)
+        client.push()
         fed = col.federated_registry()
         assert fed.counter("serving/requests").value == 5.0  # not 8
     finally:
@@ -295,65 +264,6 @@ def test_collector_scrape_mode():
     finally:
         col.stop()
         srv.stop()
-
-
-def test_federated_observatory_table_round_trip(tmp_path):
-    """Rows pushed by two processes EMA-merge at the collector and a fresh
-    selector consumes the federated table in measured mode."""
-    from deepspeed_tpu.collectives import selector, table as table_mod
-
-    col = FleetCollector().start()
-    try:
-        row = {"op": "all_reduce", "world": 8, "size_mb": 0.125,
-               "algorithm": "ring", "codec": "none", "backend": "ppermute",
-               "latency_ms": 2.0, "busbw_gbps": 1.0, "itemsize": 4,
-               "samples": 1, "proc": "testrun/p1"}
-        col.ingest({"identity": {"run_id": "testrun", "process_index": 1},
-                    "coll_rows": [row]})
-        col.ingest({"identity": {"run_id": "testrun", "process_index": 2},
-                    "coll_rows": [dict(row, latency_ms=4.0,
-                                       proc="testrun/p2")]})
-        rows = col.table_rows()
-        assert len(rows) == 1  # same signature -> ONE federated row
-        assert 2.0 < rows[0]["latency_ms"] < 4.0  # EMA fold, not clobber
-        # the HTTP surface serves a loadable versioned envelope
-        tpath = tmp_path / "fleet_table.json"
-        tpath.write_bytes(urllib.request.urlopen(
-            col.url + "/coll_table", timeout=5).read())
-        loaded = table_mod.load_table(str(tpath))
-        assert len(loaded) == 1
-    finally:
-        col.stop()
-    selector.configure(decision_table=str(tpath), mode="measured",
-                       min_algorithmic_bytes=0)
-    try:
-        d = selector.select("all_reduce", int(0.125e6), 8, itemsize=4)
-        assert (d.source, d.algorithm) == ("measured", "ring")
-    finally:
-        selector.configure()
-
-
-def test_table_repush_replaces_not_inflates():
-    """Cadence pushes carry the process's full cumulative table: a re-push
-    must REPLACE that process's rows in the federation, never re-fold them
-    (sample counts would inflate and the EMA would re-apply on identical
-    data every interval)."""
-    col = FleetCollector().start()
-    try:
-        row = {"op": "all_reduce", "world": 8, "size_mb": 0.125,
-               "algorithm": "ring", "codec": "none", "backend": "ppermute",
-               "latency_ms": 2.0, "busbw_gbps": 1.0, "itemsize": 4,
-               "samples": 12, "proc": "testrun/p1"}
-        for _ in range(5):  # five identical cadence pushes
-            col.ingest({"identity": {"run_id": "testrun",
-                                     "process_index": 1},
-                        "coll_rows": [row]})
-        rows = col.table_rows()
-        assert len(rows) == 1
-        assert rows[0]["samples"] == 12  # not 60
-        assert rows[0]["latency_ms"] == 2.0  # EMA not re-applied
-    finally:
-        col.stop()
 
 
 def test_straggler_threshold_consistent_between_gauge_and_ledger():
@@ -443,10 +353,8 @@ def test_colliding_process_indices_get_distinct_labels():
             reg = MetricsRegistry()
             reg.gauge("serving/queue_depth").set(ord(run[-1]) * 1.0)
             ident = fleet.ProcessIdentity(run, 0, host="h", role="worker")
-            client = FleetClient(col.url, identity=ident, registry=reg,
-                                 observatory=None)
-            client.push(heartbeat_extra={"step_rate": rate},
-                        include_table=False)
+            client = FleetClient(col.url, identity=ident, registry=reg)
+            client.push(heartbeat_extra={"step_rate": rate})
         # a registered-but-never-heartbeating member still counts
         col.ingest({"identity": {"run_id": "runD", "process_index": 0}})
         fed = col.federated_registry()
@@ -484,11 +392,10 @@ def test_push_async_latest_wins_and_flushes():
     try:
         reg = MetricsRegistry()
         ident = fleet.ProcessIdentity("testrun", 1)
-        client = FleetClient(col.url, identity=ident, registry=reg,
-                             observatory=None)
+        client = FleetClient(col.url, identity=ident, registry=reg)
         for i in range(5):
             reg.counter("serving/requests").add(1.0)
-            client.push_async(include_table=False)
+            client.push_async()
         client.flush()
         fed = col.federated_registry()
         # the LAST snapshot (5 cumulative) landed, whatever was dropped
@@ -499,9 +406,8 @@ def test_push_async_latest_wins_and_flushes():
 
 
 def test_fleet_client_failures_never_raise():
-    client = FleetClient("http://127.0.0.1:1", timeout_s=0.2,
-                         observatory=None)
-    assert client.push(include_table=False) is None
+    client = FleetClient("http://127.0.0.1:1", timeout_s=0.2)
+    assert client.push() is None
     assert client.push_failures >= 1
 
 
@@ -650,7 +556,7 @@ def test_engine_fleet_url_config_wires_client_and_heartbeat():
         for _ in range(2):
             eng.train_batch(batch)
         # the interval is long; push explicitly (what the daemon would do)
-        ack = eng._fleet_client.push(include_table=False)
+        ack = eng._fleet_client.push()
         assert ack["ok"]
         led = col.ledger()
         row = next(r for r in led["processes"]
@@ -670,8 +576,7 @@ def test_three_process_fleet_smoke(tmp_path):
     """The acceptance gate: collector + 2 real CPU worker processes.
     Federated counters bit-exactly equal the per-process sums; the merged
     trace links router admission flows into both workers' serve:dispatch
-    spans; the federated observatory table round-trips into a fresh
-    selector's measured mode."""
+    spans."""
     out = subprocess.run(
         [sys.executable, os.path.join(REPO_ROOT, "tools", "fleet_smoke.py"),
          "--out", str(tmp_path), "--workers", "2", "--requests", "2"],
@@ -685,7 +590,6 @@ def test_three_process_fleet_smoke(tmp_path):
     assert doc["trace_linked"] and doc["cross_process_flow_links"] >= 1
     assert doc["dispatch_pids"] == [1, 2]
     assert doc["ledger_ok"] and doc["ledger_replicas"] == 2
-    assert doc["coll_table_round_trip"]
     # the merged trace artifact is a loadable Chrome trace with 3 processes
     merged = json.load(open(doc["merged_trace"]))
     pnames = [e["args"]["name"] for e in merged["traceEvents"]

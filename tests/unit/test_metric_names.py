@@ -131,16 +131,11 @@ def test_lint_scans_telemetry_and_serving_sources():
         # daemon mint the fabric/* RPC + liveness series
         os.path.join("deepspeed_tpu", "fabric", f)
         for f in ("remote.py", "replica_daemon.py")
-    } | {
-        # schedule compiler (ISSUE 19): compile_schedule mints the
-        # coll/schedule_* search census
-        os.path.join("deepspeed_tpu", "collectives", "schedule.py"),
     } | {os.path.join("tools", "alerts_smoke.py"),
          os.path.join("tools", "fabric_smoke.py"),
          os.path.join("tools", "incident_report.py"),
          os.path.join("tools", "fleet_smoke.py"),
          os.path.join("tools", "numerics_smoke.py"),
-         os.path.join("tools", "schedule_smoke.py"),
          os.path.join("tools", "trace_merge.py")}
     missing = expected - scanned
     assert not missing, f"metric-minting files escaped the lint walk: {sorted(missing)}"
@@ -165,13 +160,11 @@ def test_known_names_pass_and_bad_names_fail():
                  "serving/migration_failures", "router/migrations",
                  "fleet/role_processes",
                  # MoE at scale (ISSUE 15): capacity autotuning gauges next
-                 # to the PR-7 dispatch-health family; the all-to-all hop
-                 # timings ride the existing coll/* histograms
+                 # to the PR-7 dispatch-health family
                  "moe/capacity_factor_applied", "moe/capacity_factor_target",
-                 "moe/token_drop_rate", "coll/hop_ms", "coll/achieved_gbps",
-                 # numerics observatory (ISSUE 17): wire/serving fidelity,
+                 "moe/token_drop_rate",
+                 # numerics observatory (ISSUE 17): residual/serving fidelity,
                  # the divergence sentinel, and the fleet digest comparator
-                 "numerics/wire_rel_err", "numerics/wire_drift_events",
                  "numerics/ef_residual_norm", "numerics/divergence_events",
                  "numerics/digest_checksum", "numerics/digest_gap",
                  "numerics/kv_dequant_rel_err", "numerics/woq_matmul_rel_err",
@@ -182,10 +175,6 @@ def test_known_names_pass_and_bad_names_fail():
                  "fabric/dead_replicas", "fabric/wire_migration_ms",
                  "fabric/wire_bytes", "fabric/drains", "fabric/preempts",
                  "router/dead_replicas", "router/drains",
-                 # schedule compiler (ISSUE 19): per-compile search census
-                 # next to the observatory's coll/* calibration family
-                 "coll/schedule_compiles", "coll/schedule_candidates",
-                 "coll/schedule_pred_us", "coll/schedule_levels",
                  # incident plane (ISSUE 20): event-stream accounting, alert
                  # engine state, and the per-endpoint fabric RPC series
                  "events/emitted", "events/deduped", "events/buffered",

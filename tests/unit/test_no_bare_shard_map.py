@@ -68,20 +68,6 @@ def test_no_bare_shard_map_or_axis_size():
         "deepspeed_tpu.utils.compat instead):\n  " + "\n  ".join(offenders))
 
 
-def test_lint_scans_collectives_package():
-    """The collectives/ package (hop algorithms over ppermute — the most
-    likely place for a bare axis_size/shard_map to sneak back in) must be
-    inside the lint's walk; guards against a future src-layout move
-    silently dropping it from SCAN_DIRS."""
-    scanned = {os.path.relpath(p, REPO_ROOT) for p in _python_files()}
-    expected = {
-        os.path.join("deepspeed_tpu", "collectives", f)
-        for f in ("__init__.py", "algorithms.py", "codecs.py", "selector.py", "overlap.py")
-    }
-    missing = expected - scanned
-    assert not missing, f"collectives files escaped the lint walk: {sorted(missing)}"
-
-
 def test_compat_shard_map_resolves():
     """The import point resolves on the installed jax, and the retired
     ``check_rep`` spelling is refused rather than ignored."""

@@ -10,9 +10,6 @@ Pinned here:
     all trace to the same jaxpr (probes are standalone dispatches)
   - the whole-tree xor digest checksum is bit-stable across mesh shapes
     (the fleet heartbeat's cross-process comparator contract)
-  - wire-fidelity probes cover every routed lossy codec and sit under the
-    pinned per-codec bounds; drift vs those bounds warns + counts + arms
-  - the forced-lossy-codec grad-mean warning fires once at trace time
   - serving probes (KV dequant / WOQ matmul / spec-accept trend alarm)
   - the ``numerics`` perf-ledger suite is headline-gated by the PR-16 gate
 """
@@ -27,25 +24,21 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import deepspeed_tpu
-from deepspeed_tpu.collectives import selector
 from deepspeed_tpu.diagnostics.faultinject import FaultInjector
 from deepspeed_tpu.diagnostics.manager import TrainingHealthError
 from deepspeed_tpu.telemetry import get_tracer
 from deepspeed_tpu.telemetry import numerics
-from deepspeed_tpu.utils.compat import shard_map
 from tests.unit.simple_model import random_batch, simple_model_spec
 
 
 @pytest.fixture(autouse=True)
 def _reset():
     numerics.configure(enabled=False)
-    selector.configure()
     tr = get_tracer()
     tr.configure(enabled=False)
     tr.reset()
     yield
     numerics.configure(enabled=False)
-    selector.configure()
     get_tracer().configure(enabled=False)
     get_tracer().reset()
 
@@ -109,7 +102,6 @@ def test_clean_run_raises_zero_alarms():
         m = _step(eng, seed=s)
     obs = numerics.get_observatory()
     assert obs.divergence_events_seen == 0
-    assert obs.wire_drift_events == 0
     assert int(jax.device_get(m["numerics/divergence_events"])) == 0
     assert int(jax.device_get(m["numerics/checked"])) == 4
 
@@ -246,96 +238,14 @@ def test_sentinel_cond_skips_unsampled_steps():
     assert int(jax.device_get(st.checked)) == 2  # steps 0 and 4
 
 
-# ---------------------------------------------------------------- wire probes
-LOSSY = sorted(numerics.LOSSY_CODECS)
-
-
-def test_wire_probes_cover_every_routed_lossy_codec():
-    obs = numerics.configure(enabled=True, sample_every=1)
-    for codec in LOSSY:
-        obs.note_route("all_gather", "ring", codec, 4096 * 4, 4, 8, "dp",
-                       "float32", block_size=64)
-    out = obs.sample_now()
-    assert set(out) == {f"all_gather/{c}" for c in LOSSY}
-    for codec in LOSSY:
-        rel = out[f"all_gather/{codec}"]
-        assert 0.0 < rel < numerics.WIRE_REL_ERR_BOUNDS[codec], (codec, rel)
-    # the labelled histogram landed in the registry
-    snap = get_tracer().registry.snapshot()
-    assert any(k.startswith("numerics/wire_rel_err") for k in snap)
-
-
-def test_exact_codecs_are_not_probed():
-    obs = numerics.configure(enabled=True, sample_every=1)
-    obs.note_route("all_reduce", "ring", "none", 4096, 4, 8, "dp", "float32")
-    obs.note_route("all_reduce", "ring", "fp32", 4096, 4, 8, "dp", "float32")
-    assert obs.routes() == []
-    assert obs.sample_now() == {}
-
-
-def test_wire_drift_warns_counts_and_arms(dslog, caplog):
-    armed = []
-    obs = numerics.configure(enabled=True, sample_every=1,
-                             drift_ratio=1e-9)  # any real error drifts
-    obs.install(profiler_arm=lambda reason: armed.append(reason))
-    obs.note_route("all_gather", "ring", "int8", 4096 * 4, 4, 8, "dp",
-                   "float32", block_size=64)
-    with caplog.at_level(logging.WARNING, logger="deepspeed_tpu"):
-        obs.sample_now()
-        obs.sample_now()  # second round: counts again, warns ONCE
-    assert obs.wire_drift_events == 2
-    drift_warnings = [r for r in caplog.records
-                      if "numerics drift" in r.message]
-    assert len(drift_warnings) == 1
-    assert armed and armed[0].startswith("numerics_drift:")
-
-
-def test_route_registration_noop_when_disabled():
-    obs = numerics.configure(enabled=False)
-    obs.note_route("all_gather", "ring", "int8", 4096, 4, 8, "dp", "float32")
-    assert obs.routes() == []
-
-
-# ----------------------------------------------------- forced-lossy grad mean
-def test_facade_grad_mean_lossy_codec_warns_once(dslog, caplog):
-    from deepspeed_tpu.runtime.engine import _facade_grad_mean
-
-    mesh = Mesh(np.array(jax.devices()[:8]), ("dp",))
-    selector.configure(facade_algorithm="ring", facade_codec="int8",
-                       codecs=("int8",))
-
-    def make():
-        def f(g):
-            return _facade_grad_mean(g, "dp")
-
-        return shard_map(f, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-                         check_vma=False)
-
-    x = jnp.ones((8, 256), jnp.float32)
-    with caplog.at_level(logging.WARNING, logger="deepspeed_tpu"):
-        jax.make_jaxpr(make())(x)
-        jax.make_jaxpr(make())(x)  # retrace: still one warning
-    warns = [r for r in caplog.records
-             if "forced lossy codec" in r.message]
-    assert len(warns) == 1
-    # an exact wire stays quiet
-    numerics.configure(enabled=False)  # reset warn-once epoch
-    caplog.clear()
-    selector.configure(facade_algorithm="ring", facade_codec="fp32",
-                       codecs=("fp32",))
-    with caplog.at_level(logging.WARNING, logger="deepspeed_tpu"):
-        jax.make_jaxpr(make())(x)
-    assert not [r for r in caplog.records
-                if "forced lossy codec" in r.message]
-
-
 # -------------------------------------------------------------- serving plane
 def test_kv_dequant_probe_within_pinned_bounds():
     obs = numerics.configure(enabled=True)
     rel8 = obs.kv_dequant_probe("int8", head_dim=128)
     relf8 = obs.kv_dequant_probe("fp8", head_dim=128)
-    assert 0.0 < rel8 < numerics.WIRE_REL_ERR_BOUNDS["int8"]
-    assert 0.0 < relf8 < numerics.WIRE_REL_ERR_BOUNDS["fp8"]
+    # int8 absmax/127 blockwise ~1-2%, fp8 E4M3 (3 mantissa bits) ~5-6%
+    assert 0.0 < rel8 < 2e-2
+    assert 0.0 < relf8 < 6e-2
     assert obs.kv_dequant_probe(None) == 0.0
 
 

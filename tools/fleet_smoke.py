@@ -26,10 +26,7 @@ Exit gates (any failure => exit 1):
   2. ``tools/trace_merge.py`` joins the parent + worker JSONL streams into
      ONE trace in which at least one flow id links events from >= 2
      distinct pids, and every worker contributed a ``serve:dispatch`` span;
-  3. every worker registered (ledger rows with heartbeats + clock offsets);
-  4. a federated observatory table round-trips: rows pushed by the workers
-     merge at the collector and a fresh selector consumes them in
-     measured mode.
+  3. every worker registered (ledger rows with heartbeats + clock offsets).
 
 Prints one JSON line of evidence.
 """
@@ -51,14 +48,6 @@ sys.path.insert(0, REPO)
 REQUESTS_PER_WORKER = 5
 TOKENS_PER_WORKER = 40.0
 HIST_SAMPLES = [1.5, 3.0, 12.0, 55.0, 130.0]
-
-
-def _coll_row(world: int, latency_ms: float, proc: str) -> dict:
-    """A plausible observatory row (same schema the online table emits)."""
-    return {"op": "all_reduce", "world": world, "size_mb": 0.125,
-            "algorithm": "ring", "codec": "none", "backend": "ppermute",
-            "latency_ms": latency_ms, "busbw_gbps": 1.0, "itemsize": 4,
-            "samples": 1, "proc": proc}
 
 
 def worker_main(args) -> int:
@@ -84,16 +73,12 @@ def worker_main(args) -> int:
         ctx = fleet.TraceContext.from_wire(wire)
         with fleet.dispatch_span(ctx, replica=idx):
             time.sleep(0.002)
-    client = FleetClient(args.collector, identity=ident, registry=reg,
-                         observatory=None)
+    client = FleetClient(args.collector, identity=ident, registry=reg)
     ack = client.register()
     if not (ack and ack.get("ok")):
         print(json.dumps({"ok": False, "error": "register failed"}))
         return 1
-    # per-process observatory rows ride the same push (table federation);
-    # distinct latencies per worker so the collector's EMA fold is visible
-    client.push(include_table=False,
-                coll_rows=[_coll_row(8, 2.0 + idx, ident.key())])
+    client.push()
     out = os.path.join(args.out, f"events.p{idx}.jsonl")
     telemetry.export_jsonl(out, tracer=tr)
     print(json.dumps({"ok": True, "index": idx, "events": out}))
@@ -206,29 +191,9 @@ def main() -> int:
         and all(r["heartbeat"] is not None and r["clock_offset_s"] is not None
                 and not r["stale"] for r in replica_rows))
 
-    # gate 4: federated observatory table -> fresh selector measured mode
-    rows = collector.table_rows()
-    table_ok = False
-    if rows:
-        from deepspeed_tpu.collectives import selector
-        from deepspeed_tpu.collectives import table as table_mod
-
-        tpath = os.path.join(out_dir, "fleet_coll_table.json")
-        table_mod.write_table(tpath, rows, source="fleet")
-        # a FRESH selector (new-process analog) warm-starts measured mode
-        # from the FEDERATED table — the round-trip the ISSUE gates on
-        selector.configure(decision_table=tpath, mode="measured",
-                           min_algorithmic_bytes=0)
-        pick = selector.select("all_reduce", int(0.125 * 1e6), 8, itemsize=4)
-        table_ok = (pick.source == "measured" and pick.algorithm == "ring")
-        selector.configure()  # restore process-global defaults
-    gates["coll_table_rows"] = len(rows)
-    gates["coll_table_round_trip"] = bool(table_ok)
-
     collector.stop()
     ok = (not worker_fail and gates["counters_bit_exact"]
-          and gates["trace_linked"] and gates["ledger_ok"]
-          and gates["coll_table_round_trip"])
+          and gates["trace_linked"] and gates["ledger_ok"])
     print(json.dumps({"ok": ok, "workers": args.workers,
                       "worker_failures": worker_fail, **gates,
                       "merged_trace": merged_path, "out_dir": out_dir}))

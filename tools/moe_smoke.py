@@ -9,10 +9,7 @@ file):
      through the collective token dispatch, and a replay of its trained
      params through the plain GLOBAL math matches the mesh loss (the
      mis-routing gate).
-  2. **quantized dispatch wire** — the same mesh with
-     ``moe_wire_codec='int8'`` stays within a pinned bound of the exact
-     wire.
-  3. **expert-parallel v2 decode parity** — an ``ep_size=2`` v2 inference
+  2. **expert-parallel v2 decode parity** — an ``ep_size=2`` v2 inference
      engine decodes greedy TOKEN-IDENTICAL to the ep=1 engine on the same
      bf16 checkpoint, with the collective dispatch actually traced.
 """
@@ -37,29 +34,24 @@ def _train_gates() -> dict:
     from deepspeed_tpu.models import TransformerConfig, causal_lm_spec
     from deepspeed_tpu.topology import mesh as mesh_mod
 
-    base = dict(
+    cfg = TransformerConfig(
         vocab_size=256, hidden_size=32, intermediate_size=64, num_layers=2,
         num_heads=4, max_seq_len=32, num_experts=4, moe_top_k=2,
         moe_capacity_factor=2.0)
-
-    def build(**overrides):
-        cfg = TransformerConfig(**{**base, **overrides})
-        eng, *_ = deepspeed_tpu.initialize(
-            model=causal_lm_spec(cfg), config={
-                "train_micro_batch_size_per_gpu": 2,
-                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
-                "zero_optimization": {"stage": 0,
-                                      "param_persistence_threshold": 1},
-                "mesh": {"dp": 2, "ep": 2, "tp": 2},
-                "steps_per_print": 1000,
-            }, seed=21)
-        return eng
+    eng, *_ = deepspeed_tpu.initialize(
+        model=causal_lm_spec(cfg), config={
+            "train_micro_batch_size_per_gpu": 2,
+            "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+            "zero_optimization": {"stage": 0,
+                                  "param_persistence_threshold": 1},
+            "mesh": {"dp": 2, "ep": 2, "tp": 2},
+            "steps_per_print": 1000,
+        }, seed=21)
 
     def tokens(seed):
         rng = np.random.default_rng(seed)
         return {"input_ids": rng.integers(0, 256, size=(4, 16), dtype=np.int32)}
 
-    eng = build()
     losses = [float(eng.train_batch(tokens(90 + i))["loss"]) for i in range(6)]
     # mis-routing gate: replay the engine's own params through plain global
     # math; the collective dispatch must reproduce it (the GSPMD constraint
@@ -73,18 +65,12 @@ def _train_gates() -> dict:
     global_loss = float(jax.jit(eng.model.loss_fn)(host, tokens(99), rng)[0])
     parity_rel = abs(mesh_loss - global_loss) / max(abs(global_loss), 1e-9)
 
-    q = build(moe_dispatch_algorithm="ring", moe_wire_codec="int8")
-    q_losses = [float(q.train_batch(tokens(90 + i))["loss"]) for i in range(6)]
-    wire_rel = max(abs(a - b) / max(abs(a), 1e-9)
-                   for a, b in zip(losses, q_losses))
     return {
         "ep_tp_losses": [round(v, 4) for v in losses],
         "ep_tp_finite": bool(np.isfinite(losses).all()),
         "ep_tp_learns": losses[-1] < losses[0],
         "global_math_rel_err": parity_rel,
         "global_math_ok": parity_rel < 1e-5,
-        "int8_wire_rel_err": wire_rel,
-        "int8_wire_ok": bool(np.isfinite(q_losses).all()) and wire_rel < 0.05,
     }
 
 
@@ -147,7 +133,7 @@ def main(argv: Optional[list] = None) -> int:
 
     gates = {**_train_gates(), **_decode_gates()}
     ok = all(gates[k] for k in (
-        "ep_tp_finite", "ep_tp_learns", "global_math_ok", "int8_wire_ok",
+        "ep_tp_finite", "ep_tp_learns", "global_math_ok",
         "v2_ep_collective_traced", "v2_ep_weights_sharded",
         "v2_ep_decode_token_identical"))
     doc = {"moe_smoke": gates, "ok": ok}
