@@ -261,6 +261,72 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
             logits_scaling=float(hf_config.get("logits_scaling", 1.0)),
             param_dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(dtype, jnp.float32),
         )
+    if mt == "qwen3_next":
+        # a ROUTED layer pattern: three Gated DeltaNet (linear-attention) layers
+        # to every gated softmax-attention layer (per-head QK-norm, rotary on
+        # part of a head, the output times sigmoid of a gate the query
+        # projection carries), in every layer a softmax router over
+        # ``num_experts`` beside one shared expert behind a sigmoid gate; every
+        # norm but the DeltaNet's gated one multiplies by 1 + w. ``num_experts``
+        # are the experts HELD here: with ``expert_parallel: {size, rank}`` the
+        # router scores ``size`` times as many. The multi-token-prediction layer
+        # of the checkpoints is not built: no serving path runs it
+        L = hf_config["num_hidden_layers"]
+        interval = hf_config.get("full_attention_interval", 4)
+        kinds = tuple(hf_config.get("layer_types") or
+                      ("full_attention" if (i + 1) % interval == 0 else "linear_attention" for i in range(L)))
+        refused = [(bool(hf_config.get("mlp_only_layers")), "mlp_only_layers (dense layers among the routed)"),
+                   (hf_config.get("decoder_sparse_step", 1) != 1, "decoder_sparse_step != 1"),
+                   (bool(hf_config.get("rope_scaling")), "rope_scaling"),
+                   (bool(hf_config.get("attention_bias")), "attention_bias"),
+                   (bool(hf_config.get("use_sliding_window")), "use_sliding_window"),
+                   (hf_config.get("hidden_act", "silu") != "silu", f"hidden_act={hf_config.get('hidden_act')!r}"),
+                   (not set(kinds) <= {"full_attention", "linear_attention"}, f"layer_types of {sorted(set(kinds))}"),
+                   (hf_config.get("shared_expert_intermediate_size", 0) % hf_config["moe_intermediate_size"] != 0,
+                    "shared_expert_intermediate_size not a multiple of moe_intermediate_size")]
+        refused = [what for bad, what in refused if bad]
+        if refused:
+            raise ValueError("qwen3_next with " + "; ".join(refused) + " is unsupported")
+        from deepspeed_tpu.models.transformer import ExpertParallel, GDNConfig
+
+        hd = hf_config.get("head_dim") or hf_config["hidden_size"] // hf_config["num_attention_heads"]
+        share, shared_width = hf_config.get("expert_parallel"), hf_config.get("shared_expert_intermediate_size", 0)
+        dtype = hf_config.get("dtype", hf_config.get("torch_dtype"))
+        return TransformerConfig(
+            vocab_size=hf_config["vocab_size"],
+            hidden_size=hf_config["hidden_size"],
+            intermediate_size=hf_config["intermediate_size"],
+            num_layers=L,
+            num_heads=hf_config["num_attention_heads"],
+            num_kv_heads=hf_config.get("num_key_value_heads"),
+            head_dim=hd,
+            max_seq_len=hf_config.get("max_position_embeddings", 262144),
+            norm="rmsnorm",
+            activation="silu_glu",
+            position="rope",
+            rope_theta=float(hf_config.get("rope_theta", 10000000.0)),
+            rotary_dim=int(hf_config.get("partial_rotary_factor", 0.25) * hd),
+            norm_eps=float(hf_config.get("rms_norm_eps", 1e-6)),
+            norm_unit_offset=True,
+            tie_embeddings=bool(hf_config.get("tie_word_embeddings", False)),
+            qkv_bias=False,
+            attn_output_gate=True,
+            qk_norm=True,
+            layer_types=tuple("attention" if kind == "full_attention" else kind for kind in kinds),
+            gdn=GDNConfig(n_k_heads=hf_config["linear_num_key_heads"], n_v_heads=hf_config["linear_num_value_heads"],
+                          head_k_dim=hf_config["linear_key_head_dim"], head_v_dim=hf_config["linear_value_head_dim"],
+                          d_conv=hf_config.get("linear_conv_kernel_dim", 4)),
+            num_experts=hf_config["num_experts"],
+            expert_parallel=ExpertParallel(int(share["size"]), int(share.get("rank", 0))) if share else None,
+            moe_top_k=hf_config["num_experts_per_tok"],
+            moe_intermediate_size=hf_config["moe_intermediate_size"],
+            moe_shared_experts=shared_width // hf_config["moe_intermediate_size"],
+            moe_shared_gate=shared_width > 0,
+            moe_router="softmax",
+            moe_renormalize=bool(hf_config.get("norm_topk_prob", True)),
+            moe_drop_tokens=False,
+            param_dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(dtype, jnp.float32),
+        )
     if mt == "opt":
         if not hf_config.get("do_layer_norm_before", True):
             raise ValueError("OPT post-layernorm variants (do_layer_norm_before=false) are unsupported")
@@ -460,7 +526,7 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
     raise ValueError(
         f"unsupported HF model_type {mt!r} (supported: llama/mistral/mixtral/"
         "qwen2/gpt2/opt/falcon/phi/gpt_neox/bloom/gptj/codegen/gpt_bigcode/"
-        "glm4_moe_lite/evabyte/xing4_0/granitemoehybrid)")
+        "glm4_moe_lite/evabyte/xing4_0/granitemoehybrid/qwen3_next)")
 
 
 def detect_family(state: Dict[str, np.ndarray]) -> str:
@@ -469,6 +535,8 @@ def detect_family(state: Dict[str, np.ndarray]) -> str:
         return "glm4_moe_lite"
     if any("adaptive_phi" in k for k in keys):
         return "evabyte"
+    if any("linear_attn.in_proj_qkvz" in k for k in keys):
+        return "qwen3_next"
     if any("block_sparse_moe" in k for k in keys):
         return "mixtral"
     if any("decoder.embed_positions" in k for k in keys) and not any("encoder." in k for k in keys):
@@ -967,6 +1035,12 @@ def _set(tree, path, value):
     tree[path[-1]] = value
 
 
+def _at(tree, path):
+    for key in path:
+        tree = tree[key]
+    return tree
+
+
 def _convert_glm4_moe_lite(state, cfg: TransformerConfig) -> Dict[str, Any]:
     """Leading dense layers as ``dense_<i>``, the routed stack stacked under
     ``layers``; the keys of layers past ``num_layers`` (the next-token-
@@ -1081,19 +1155,137 @@ def evabyte_hf_state(params, cfg: TransformerConfig) -> Dict[str, np.ndarray]:
             return a.reshape(shape)
         return _from_leaf(a, shape)
 
-    def at(tree, path):
-        for key in path:
-            tree = tree[key]
-        return tree
-
-    state = {hf: stored(hf, path, at(params, path), shape) for hf, path, shape in top}
+    state = {hf: stored(hf, path, _at(params, path), shape) for hf, path, shape in top}
     for i in range(cfg.num_layers):
         for hf, path, shape in per_layer:
-            state[hf.format(i=i)] = stored(hf, path, at(params["layers"], path)[i], shape)
+            state[hf.format(i=i)] = stored(hf, path, _at(params["layers"], path)[i], shape)
+    return state
+
+
+# --- qwen3_next: a routed pattern of Gated DeltaNet and gated attention layers ---
+#
+# The checkpoint's ``linear_attn.in_proj_qkvz`` and ``in_proj_ba`` INTERLEAVE
+# their output rows by key-head group (a group's q, k, then its value heads' v,
+# then their z; a group's b, then a); the program and the plain reference keep
+# them plain, ``[q | k | v | z]`` and ``[b | a]`` (``ops/gdn.py``). The two
+# functions below give, for each row of the plain order, its row in the
+# checkpoint. Layer ``i`` of the model is ``layers/layer_<i % P>`` at index
+# ``i // P`` of its stacked leaves (``P`` the period). A chip's share
+# (``expert_parallel``) takes experts ``first_expert ..`` of the checkpoint's;
+# the multi-token-prediction layer (``mtp.*``) is not read.
+
+def _qkvz_rows(g) -> np.ndarray:
+    rep = g.n_v_heads // g.n_k_heads
+    group = 2 * g.head_k_dim + 2 * rep * g.head_v_dim
+    starts = np.arange(g.n_k_heads)[:, None] * group
+    q = starts + np.arange(g.head_k_dim)[None, :]
+    k = q + g.head_k_dim
+    v = starts + 2 * g.head_k_dim + np.arange(rep * g.head_v_dim)[None, :]
+    z = v + rep * g.head_v_dim
+    return np.concatenate([a.reshape(-1) for a in (q, k, v, z)])
+
+
+def _ba_rows(g) -> np.ndarray:
+    rep = g.n_v_heads // g.n_k_heads
+    b = np.arange(g.n_k_heads)[:, None] * 2 * rep + np.arange(rep)[None, :]
+    return np.concatenate([b.reshape(-1), (b + rep).reshape(-1)])
+
+
+def _interleaved_rows(hf: str, g) -> Optional[np.ndarray]:
+    """For a checkpoint tensor whose rows are interleaved, the checkpoint's row of each row of the plain order."""
+    if hf.endswith("in_proj_qkvz.weight"):
+        return _qkvz_rows(g)
+    return _ba_rows(g) if hf.endswith("in_proj_ba.weight") else None
+
+
+def _qwen3_next_names(cfg: TransformerConfig, kind: str):
+    """A layer of ``kind`` under its HF names as ``(HF name, path in our tree,
+    shape of the leaf)``, the routed experts apart."""
+    h, hd, H, Hkv, g = cfg.hidden_size, cfg.dims_per_head, cfg.num_heads, cfg.kv_heads, cfg.gdn
+    fs = cfg.expert_width * cfg.moe_shared_experts
+    names = [("post_attention_layernorm.weight", ("mlp_norm", "scale"), (h,)),
+             ("mlp.gate.weight", ("moe", "gate", "wg", "kernel"), (h, cfg.router_experts)),
+             ("mlp.shared_expert_gate.weight", ("moe", "shared_gate", "kernel"), (h, 1))]
+    names += [(f"mlp.shared_expert.{hf}.weight", ("moe", "shared", ours, "kernel"),
+               (fs, h) if ours == "w_down" else (h, fs)) for hf, ours in _GLU_MATRICES]
+    if kind == "attention":
+        return names + [
+            ("input_layernorm.weight", ("attn_norm", "scale"), (h,)),
+            ("self_attn.q_proj.weight", ("attn", "wq", "kernel"), (h, H, 2 * hd)),
+            ("self_attn.k_proj.weight", ("attn", "wk", "kernel"), (h, Hkv, hd)),
+            ("self_attn.v_proj.weight", ("attn", "wv", "kernel"), (h, Hkv, hd)),
+            ("self_attn.o_proj.weight", ("attn", "wo", "kernel"), (H * hd, h)),  # (split into heads by the caller)
+            ("self_attn.q_norm.weight", ("attn", "q_norm", "scale"), (hd,)),
+            ("self_attn.k_norm.weight", ("attn", "k_norm", "scale"), (hd,))]
+    return names + [
+        ("input_layernorm.weight", ("gdn_pre_norm", "scale"), (h,)),
+        ("linear_attn.in_proj_qkvz.weight", ("gdn", "gdn_in_proj", "kernel"), (h, g.proj_dim)),
+        ("linear_attn.in_proj_ba.weight", ("gdn", "gdn_ba_proj", "kernel"), (h, 2 * g.n_v_heads)),
+        ("linear_attn.conv1d.weight", ("gdn", "gdn_conv"), (g.d_conv, g.conv_dim)),
+        ("linear_attn.A_log", ("gdn", "A_log"), (g.n_v_heads,)),
+        ("linear_attn.dt_bias", ("gdn", "dt_bias"), (g.n_v_heads,)),
+        ("linear_attn.norm.weight", ("gdn", "gdn_norm", "scale"), (g.head_v_dim,)),
+        ("linear_attn.out_proj.weight", ("gdn", "gdn_out_proj", "kernel"), (g.value_dim, h))]
+
+
+def _convert_qwen3_next(state, cfg: TransformerConfig) -> Dict[str, Any]:
+    get = _getter(state, ("",))
+    P, first = len(cfg.period), cfg.first_expert
+
+    def layer(i):
+        p, kind = f"model.layers.{i}.", cfg.layer_types[i]
+        blk: Dict[str, Any] = {}
+        for hf, path, shape in _qwen3_next_names(cfg, kind):
+            w, rows = get(p + hf), _interleaved_rows(hf, cfg.gdn)
+            if rows is not None:
+                w = w[rows]
+            elif hf.endswith("conv1d.weight"):
+                w = w[:, 0, :]  # torch's depthwise [channels, 1, taps]
+            _set(blk, path, _to_leaf(w, shape))
+        if kind == "attention":
+            wo = blk["attn"]["wo"]
+            wo["kernel"] = wo["kernel"].reshape(cfg.num_heads, cfg.dims_per_head, cfg.hidden_size)
+        for hf, ours in _GLU_MATRICES:
+            blk["moe"].setdefault("experts", {})[ours] = np.stack(
+                [get(f"{p}mlp.experts.{first + e}.{hf}.weight").T for e in range(cfg.num_experts)])
+        return blk
+
+    params: Dict[str, Any] = {
+        "embed": {"embedding": get("model.embed_tokens.weight")},
+        "final_norm": {"scale": get("model.norm.weight")},
+        "layers": {f"layer_{j}": _stack(lambda n, j=j: layer(n * P + j), cfg.num_layers // P) for j in range(P)},
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = {"kernel": get("lm_head.weight").T}
+    return params
+
+
+def qwen3_next_hf_state(params, cfg: TransformerConfig) -> Dict[str, np.ndarray]:
+    """The way back: a ``qwen3_next`` parameter tree under its HF names (a
+    chip's share writes its experts under their numbers in the router)."""
+    P, first = len(cfg.period), cfg.first_expert
+    state = {"model.embed_tokens.weight": np.asarray(params["embed"]["embedding"]),
+             "model.norm.weight": np.asarray(params["final_norm"]["scale"])}
+    if not cfg.tie_embeddings:
+        state["lm_head.weight"] = np.asarray(params["lm_head"]["kernel"]).T
+    for i in range(cfg.num_layers):
+        p, kind, blk, n = f"model.layers.{i}.", cfg.layer_types[i], params["layers"][f"layer_{i % P}"], i // P
+        for hf, path, shape in _qwen3_next_names(cfg, kind):
+            w, rows = _from_leaf(np.asarray(_at(blk, path))[n], shape), _interleaved_rows(hf, cfg.gdn)
+            if rows is not None:
+                w = w[np.argsort(rows)]
+            elif hf.endswith("conv1d.weight"):
+                w = w[:, None, :]
+            state[p + hf] = w
+        for hf, ours in _GLU_MATRICES:
+            stacked = np.asarray(blk["moe"]["experts"][ours])[n]
+            for e in range(cfg.num_experts):
+                state[f"{p}mlp.experts.{first + e}.{hf}.weight"] = stacked[e].T
     return state
 
 
 _CONVERTERS = {
+    "qwen3_next": _convert_qwen3_next,
     "evabyte": _convert_evabyte,
     "glm4_moe_lite": _convert_glm4_moe_lite,
     "llama": _convert_llama,

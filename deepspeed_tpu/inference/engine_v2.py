@@ -342,7 +342,7 @@ class InferenceEngineV2:
         # Recurrent state beside attention (a layer pattern with state-space
         # layers): a sequence holds, beside its pages, ONE slot of a state pool
         # (paged.StatePool) from its first token to its flush
-        self._hybrid = model_config.ssm_layers > 0
+        self._hybrid = model_config.state_layers > 0
         if self._hybrid:
             missing = [
                 (config.prefix_cache, "prefix_cache: a prefix's pages without the recurrent state at its "
@@ -372,6 +372,11 @@ class InferenceEngineV2:
         self._routed = model_config.num_experts > 0
         self.picks_log: Optional[List[Dict[str, Any]]] = None
         self.last_experts_touched: Optional[float] = None
+        self.last_held_visits: Optional[float] = None  # of a chip's share: visits a step to its experts, a layer
+        if model_config.expert_parallel is not None and mesh.shape.get("ep", 1) > 1:
+            raise ValueError(
+                f"expert_parallel={model_config.expert_parallel} on a mesh with ep={mesh.shape['ep']}: the "
+                "model is ONE chip's share of its layer, and the exchange between the chips is not built")
         if model_config.latent_attention:
             from deepspeed_tpu.inference.paged import latent_pool_width
 
@@ -462,10 +467,15 @@ class InferenceEngineV2:
                     2 * model_config.hidden_size * 4
                     + 4 * model_config.num_heads * model_config.dims_per_head * dtype_b
                     + 2 * model_config.intermediate_size * dtype_b)
-            if self._hybrid:  # a slot a sequence a state-space layer: the float32 state and the conv tail
+            if model_config.ssm_layers:  # a slot a sequence a state-space layer: the float32 state and the conv tail
                 sizes = model_config.ssm
                 kv_bytes += config.max_seqs * model_config.ssm_layers * (
                     sizes.d_inner * sizes.d_state * 4 + (sizes.d_conv - 1) * sizes.conv_dim * dtype_b)
+            if model_config.gdn_layers:  # likewise a Gated DeltaNet layer's: a [Dk, Dv] state a value head
+                sizes = model_config.gdn
+                kv_bytes += config.max_seqs * model_config.gdn_layers * (
+                    sizes.n_v_heads * sizes.head_k_dim * sizes.head_v_dim * 4
+                    + (sizes.d_conv - 1) * sizes.conv_dim * dtype_b)
             need = (param_bytes
                     + kv_bytes // (tp if kv_on_tp else 1)
                     + config.row_bucket * model_config.vocab_size * 4
@@ -1109,26 +1119,28 @@ class InferenceEngineV2:
                 jnp.asarray(batch.new_lens), jnp.asarray(batch.block_tables),
             )
         self.dispatch_count += 1
-        self._log_picks(picks, uids, None, token_lists)
+        self._log_picks(picks, uids, None, token_lists, at=batch.at)
         self.windows_closed += self._advance(uids, map(len, token_lists))
         self.host_sync_count += 1
         return self._rows_at(logits, batch.at)
 
-    def _log_picks(self, picks, uids, rids, token_lists=None, flight=None, emitted=None) -> None:
+    def _log_picks(self, picks, uids, rids, token_lists=None, flight=None, emitted=None, at=None) -> None:
         """While somebody asked (``picks_log`` is a list), note one dispatch's
         picks, still on the device, with what places them: the program's row
         of each uid, its first position and how many tokens it fed (a chain's
         picks are ``[K, rows, routed layers, k]``; its rows and where they
-        start are ``flight``'s, what they fed is ``emitted``). A ``put`` calls
-        it before ``seen_tokens`` advances. Costs the serving loop one
-        comparison."""
+        start are ``flight``'s, what they fed is ``emitted``; a ``put``'s rows
+        are its batch's ``at``: with state slots a sequence's row is its slot).
+        A ``put`` calls it before ``seen_tokens`` advances. Costs the serving
+        loop one comparison."""
         if self.picks_log is None or not picks:
             return
         chain = flight is not None
+        where = flight.at if chain else at  # (a put without state slots: a slice, the rows in the order given)
         self.picks_log.append({
             "picks": picks[-1], "chain": chain,
             "rids": list(range(len(uids))) if rids is None else list(rids),
-            "rows": [int(i) for i in flight.at] if chain else list(range(len(uids))),
+            "rows": [int(i) for i in where] if isinstance(where, np.ndarray) else list(range(len(uids))),
             "starts": [int(p) for p in flight.start] if chain
             else [self.state.get(u).seen_tokens for u in uids],
             "counts": [int(e) for e in emitted] if chain else [len(t) for t in token_lists]})
@@ -1206,7 +1218,7 @@ class InferenceEngineV2:
                 rng,
             )
         self.dispatch_count += 1
-        self._log_picks(picks, uids, rids, token_lists)
+        self._log_picks(picks, uids, rids, token_lists, at=batch.at)
         self.windows_closed += self._advance(uids, map(len, token_lists))
         with self._tracer.span("serve:fetch", kind="prefill"):
             out = self._rows_at(toks, batch.at)
@@ -1403,7 +1415,10 @@ class InferenceEngineV2:
                 # [K, routed layers] beside the tokens: the steps some row was
                 # live at read that many distinct experts a layer, on average
                 live_steps = max(int(emitted.max(initial=0)), 1)
-                self.last_experts_touched = float(np.asarray(routed[0])[:live_steps].mean())
+                touched = np.asarray(routed[0])[:live_steps]
+                if touched.ndim == 3:  # a chip's share: [.., (held experts read, visits to them)]
+                    touched, self.last_held_visits = touched[..., 0], float(touched[..., 1].mean())
+                self.last_experts_touched = float(touched.mean())
         self.host_sync_count += 1
         self._log_picks(routed, uids, rids, flight=flight, emitted=emitted)
         # a row moved already stands k further; one an EOS ended goes back
@@ -1859,6 +1874,8 @@ class InferenceEngineV2:
             n_emitted = int(emitted.sum())
             routed_args = ({"experts_touched": self.last_experts_touched}
                            if self._routed and n_spec == 0 else {})
+            if routed_args and self.last_held_visits is not None:
+                routed_args["held_visits"] = self.last_held_visits
             with span("serve:accept", kind="chain", emitted=n_emitted, chain=chain_id,
                       **routed_args):
                 self.tokens_decoded += n_emitted

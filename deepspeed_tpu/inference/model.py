@@ -90,12 +90,16 @@ def init_cache(
 
 
 # ------------------------------------------------------------------ layers
-def _dense(lp, key: str, cfg: TransformerConfig, x, einsum: Optional[str] = None):
+def _dense(lp, key: str, cfg: TransformerConfig, x, einsum: Optional[str] = None, sums=None):
     """``x`` through the projection ``lp[key]`` (kernel, and bias where it has
-    one), under the device-trace scope of that key (``reading``)."""
+    one), under the device-trace scope of that key (``reading``). ``sums``: the
+    dtype the product's sums are handed over in (None: the operands')."""
     with reading(lp, key) as p:
         w = p["kernel"].astype(cfg.dtype)
-        out = x @ w if einsum is None else jnp.einsum(einsum, x, w)
+        if sums is not None:
+            out = jnp.matmul(x, w, preferred_element_type=sums)
+        else:
+            out = x @ w if einsum is None else jnp.einsum(einsum, x, w)
         return out + p["bias"].astype(cfg.dtype) if "bias" in p else out
 
 
@@ -136,7 +140,16 @@ def _moe_with_picks(lp, cfg: TransformerConfig, x):
     renormalised, or sigmoid scores chosen by score + ``gate/e_bias`` and
     weighed by the unbiased scores, renormalised and scaled, as the config
     says. A shared expert (``lp["shared"]``), where the layer has one, takes
-    every token and is added unweighted.
+    every token and is added unweighted, or times ``sigmoid(x . shared_gate)``
+    where the layer has that gate (``lp["shared_gate"]``).
+
+    A chip's share of the layer (``cfg.expert_parallel``): the router scores
+    and picks among all ``cfg.router_experts``, weights renormalised over all
+    the picks, and the picks come back in that numbering; of the sum over a
+    token's picks the terms of the ``cfg.num_experts`` experts held here are
+    computed, the others' left out. Nothing stands in for the other chips. The
+    regime below is then chosen as the uncut layer would choose it (``T >= 2 x``
+    the ROUTER's experts: a held expert's mean group is the uncut layer's).
 
     Two dispatch regimes, chosen by the (static) token count:
 
@@ -163,7 +176,7 @@ def _moe_with_picks(lp, cfg: TransformerConfig, x):
 
     B, S, M = x.shape
     tokens = x.reshape(B * S, M)
-    T, E, k = tokens.shape[0], cfg.num_experts, cfg.moe_top_k
+    k = cfg.moe_top_k
     with jax.named_scope("moe_router"):
         logits = tokens.astype(jnp.float32) @ lp["gate"]["wg"]["kernel"].astype(jnp.float32)
         top_p, top_i = route(logits, k, kind=cfg.moe_router, bias=lp["gate"].get("e_bias"),
@@ -172,13 +185,37 @@ def _moe_with_picks(lp, cfg: TransformerConfig, x):
         out = _experts(lp["experts"], cfg, tokens, top_p, top_i)
     if "shared" in lp:
         with jax.named_scope("moe_shared"):
-            out = out + _mlp(lp["shared"], cfg, tokens)
+            shared = _mlp(lp["shared"], cfg, tokens)
+            if "shared_gate" in lp:
+                with jax.named_scope("moe_shared_gate"):
+                    gate = tokens.astype(jnp.float32) @ lp["shared_gate"]["kernel"].astype(jnp.float32)
+                    shared = shared * jax.nn.sigmoid(gate).astype(shared.dtype)
+            out = out + shared
     return out.reshape(B, S, M), top_i
 
 
 def _experts(ep, cfg: TransformerConfig, tokens, top_p, top_i):
-    """The picked experts' weighted sum for ``tokens`` [T, M]."""
+    """The picked experts' weighted sum for ``tokens`` [T, M]: of a chip's
+    share (``cfg.expert_parallel``) the terms of the experts held here."""
     T, E = tokens.shape[0], cfg.num_experts
+    if cfg.expert_parallel is not None:
+        # by the held experts' own numbers 0..E-1; a pick that lives on another
+        # chip becomes E, which no group, one-hot or scatter takes, at weight 0
+        local = top_i - cfg.first_expert
+        held = (local >= 0) & (local < E)
+        top_i, top_p = jnp.where(held, local, E), jnp.where(held, top_p, 0.0)
+        if T >= 2 * cfg.router_experts:  # the uncut layer's rule: the mean group is the same, a chip's share of it
+            # every pair has a row of the sorted gather whether its expert is here or not, so a long
+            # prefill's tokens go a group at a time (a (128, 256) call: 1.3 GB a gather at once)
+            n = 1
+            while T * cfg.moe_top_k > n * _SHARE_GROUP_PAIRS and T % (2 * n) == 0 and T // (2 * n) >= 2 * E:
+                n *= 2
+            if n == 1:
+                return _moe_ragged(cfg, ep, tokens, top_p, top_i, held)
+            grouped = tuple(a.reshape((n, T // n) + a.shape[1:]) for a in (tokens, top_p, top_i, held))
+            return jax.lax.map(lambda g: _moe_ragged(cfg, ep, *g), grouped).reshape(tokens.shape)
+        gate = jnp.zeros((T, E), jnp.float32).at[jnp.arange(T)[:, None], top_i].set(top_p, mode="drop")
+        return _all_experts(ep, cfg, tokens, gate)
     if _moe_ep_size() > 1:
         # expert-parallel serving (ISSUE 15): the ep-sharded experts are
         # reached through the explicit collective dispatch — the SAME
@@ -192,6 +229,11 @@ def _experts(ep, cfg: TransformerConfig, tokens, top_p, top_i):
         return _moe_ragged(cfg, ep, tokens, top_p, top_i)
 
     gate = jnp.zeros((T, E), jnp.float32).at[jnp.arange(T)[:, None], top_i].set(top_p)
+    return _all_experts(ep, cfg, tokens, gate)
+
+
+def _all_experts(ep, cfg: TransformerConfig, tokens, gate):
+    """Every expert for every token, combined by ``gate`` [T, E]."""
     h1 = jnp.einsum("tm,emh->teh", tokens, ep["w_up"].astype(cfg.dtype))
     if cfg.activation == "silu_glu":
         h1 = jax.nn.silu(jnp.einsum("tm,emh->teh", tokens, ep["w_gate"].astype(cfg.dtype))) * h1
@@ -209,6 +251,10 @@ def _experts(ep, cfg: TransformerConfig, tokens, top_p, top_i):
 # replicated ragged/dense paths (GSPMD reshards the ep-sharded kernels —
 # same math, no collective wire).
 _MOE_EP_COLLECTIVE_MAX_TOKENS = 1024
+
+
+# (token, pick) pairs of one grouped dispatch of a chip's share of a routed layer
+_SHARE_GROUP_PAIRS = 2 ** 17
 
 
 def _moe_ep_size() -> int:
@@ -453,14 +499,17 @@ def _grouped_matmul(lhs, rhs, group_sizes):
     return jax.lax.ragged_dot(lhs, rhs, group_sizes)
 
 
-def _moe_ragged(cfg: TransformerConfig, ep, tokens, top_p, top_i):
+def _moe_ragged(cfg: TransformerConfig, ep, tokens, top_p, top_i, held=None):
     """Grouped-GEMM expert dispatch: sort the [T*k] (token, expert) pairs by
     expert, gather the tokens' rows into that order, run the
     expert-contiguous matmuls (:func:`_grouped_matmul`), gather each token's
     k rows back and sum them over k (:func:`_gather_combine`). Exact same
     math as the dense-combine path (sum reordering only). Device-trace
     scopes: ``moe_dispatch`` and ``moe_combine`` (the caller opens
-    ``moe_experts`` around both and the matmuls between them)."""
+    ``moe_experts`` around both and the matmuls between them). ``held`` [T, k]
+    bool (a chip's share): the pairs whose expert is here; the others carry
+    expert ``E``, sort behind every group and belong to none, so no product is
+    made for them, and the combine leaves their rows out."""
     E, k = cfg.num_experts, cfg.moe_top_k
     with jax.named_scope("moe_dispatch"):
         e_flat = top_i.reshape(-1)                   # [T*k]
@@ -476,10 +525,10 @@ def _moe_ragged(cfg: TransformerConfig, ep, tokens, top_p, top_i):
         h = act_fn(cfg.activation)(up)
     out_g = _grouped_matmul(h, ep["w_down"].astype(cfg.dtype), group_sizes)
     with jax.named_scope("moe_combine"):
-        return _gather_combine(out_g, order, top_p)
+        return _gather_combine(out_g, order, top_p, held)
 
 
-def _gather_combine(out_g, order, top_p):
+def _gather_combine(out_g, order, top_p, held=None):
     """``out[t] = sum_j top_p[t, j] * out_g[inv[t*k + j]]`` for the experts'
     rows ``out_g`` [T*k, M] in sorted order, ``order`` the sort's permutation
     (sorted row -> pair) and ``inv`` its inverse, by a second sort: k gathers
@@ -491,7 +540,11 @@ def _gather_combine(out_g, order, top_p):
     T, k = top_p.shape
     inv = jnp.argsort(order).reshape(T, k)           # pair -> sorted row
     gates = top_p.astype(jnp.float32)
-    out = sum(gates[:, j:j + 1] * out_g[inv[:, j]].astype(jnp.float32) for j in range(k))
+    if held is None:
+        out = sum(gates[:, j:j + 1] * out_g[inv[:, j]].astype(jnp.float32) for j in range(k))
+    else:  # a row no group holds was never written: it is not read as a number
+        out = sum(jnp.where(held[:, j:j + 1], gates[:, j:j + 1] * out_g[inv[:, j]].astype(jnp.float32), 0.0)
+                  for j in range(k))
     return out.astype(out_g.dtype)
 
 

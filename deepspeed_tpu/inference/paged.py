@@ -37,7 +37,7 @@ from deepspeed_tpu.inference.model import (_apply_norm, _attn_out, _dense, _logi
                                            _moe_with_picks, _qkv)
 from deepspeed_tpu.inference.sampling import greedy_tokens, sample_logits
 from deepspeed_tpu.models.transformer import TransformerConfig, _norm_at, _times, reading
-from deepspeed_tpu.ops import mhc, ssm
+from deepspeed_tpu.ops import gdn, mhc, ssm
 
 
 class PagedKVPool(NamedTuple):
@@ -104,7 +104,12 @@ class StatePool(NamedTuple):
     StateManager``), and a program's ROW ``i`` is slot ``i``: a layer reads and
     writes the first ``rows`` slots of its row of the pool as ONE slice, in
     place, and never gathers or scatters by sequence. A slot is not cleared
-    when it changes hands: a row fed from position 0 starts from zeros."""
+    when it changes hands: a row fed from position 0 starts from zeros.
+
+    A pattern of Gated DeltaNet layers (``linear_attention``) keeps the same
+    two arrays: ``ssm`` ``[such layers, slots, Hv, Dk, Dv]`` float32, a value
+    head's state one ``[Dk, Dv]`` tile with the values on the lanes
+    (``ops/pallas/gdn_update.py``), ``conv`` the last inputs of ``[q | k | v]``."""
 
     ssm: jax.Array
     conv: jax.Array
@@ -120,6 +125,14 @@ class HybridPools(NamedTuple):
 
 
 def init_state_pool(cfg: TransformerConfig, slots: int, dtype: Any = jnp.bfloat16) -> StatePool:
+    if cfg.gdn_layers:
+        if cfg.ssm_layers:
+            raise ValueError("a layer pattern with both 'mamba' and 'linear_attention' layers: one state pool "
+                             "holds one kind of state")
+        g = cfg.gdn
+        return StatePool(
+            ssm=jnp.zeros((cfg.gdn_layers, slots, g.n_v_heads, g.head_k_dim, g.head_v_dim), jnp.float32),
+            conv=jnp.zeros((cfg.gdn_layers, slots, (g.d_conv - 1) * g.conv_dim), dtype))
     sizes = cfg.ssm
     tile = ssm.pool_tile(sizes.d_inner)
     return StatePool(
@@ -602,6 +615,10 @@ def _forward_hidden(
                                         bs, pk, put_values, first_page)
             return out, pk, pv, psk, psv
         q, k, v = _qkv(ap, cfg, h)
+        if cfg.attn_output_gate:  # a head's projection is [q | gate]
+            q, gate = q[..., :v.shape[-1]], q[..., v.shape[-1]:]
+        if cfg.qk_norm:
+            q, k = _norm_at(ap, "q_norm", cfg, q), _norm_at(ap, "k_norm", cfg, k)
         if cfg.position == "rope":
             from deepspeed_tpu.models.transformer import apply_qk_rope
 
@@ -628,6 +645,9 @@ def _forward_hidden(
         ctx = paged_attention(q, pk, pv, block_tables + first_page, positions, bs,
                               new_lens=new_lens, alibi_slopes=alibi,
                               k_scale=psk, v_scale=psv)
+        if cfg.attn_output_gate:
+            with jax.named_scope("attn_gate"):
+                ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(ctx.dtype)
         return _attn_out(ap, cfg, ctx), pk, pv, psk, psv
 
     def ffn(lp, h, dense):
@@ -690,39 +710,60 @@ def _forward_hidden(
         fresh = (positions[:, 0] == 0) & (new_lens > 0)
 
     @jax.named_scope("layer")
-    def ssm_layer(carry, lp, s):
-        """A state-space layer, the ``s``-th of its kind: its mixer reads and
-        writes row ``s`` of the state pool, in place (``StatePool``)."""
+    def state_layer(carry, lp, s, kind):
+        """A layer with recurrent state (Mamba-2 or Gated DeltaNet), the
+        ``s``-th of its kind: its mixer reads and writes row ``s`` of the state
+        pool, in place (``StatePool``)."""
         x, *kv, sp, cp = carry
-        h = _norm_at(lp, "ssm_pre_norm", cfg, x)
-        with reading(lp, "ssm") as mp:
-            zxbcdt = _dense(mp, "ssm_in_proj", cfg, h)
+        key, sizes = ("ssm", cfg.ssm) if kind == "mamba" else ("gdn", cfg.gdn)
+        h = _norm_at(lp, key + "_pre_norm", cfg, x)
+        with reading(lp, key) as mp:
             tail = jax.lax.dynamic_slice(cp, (s, 0, 0), (1, N, cp.shape[2]))[0]
-            tail = jnp.where(fresh[:, None], 0, tail).reshape(N, cfg.ssm.d_conv - 1, -1)
-            y, sp, tail = ssm.mix(zxbcdt, mp, cfg.ssm, cfg.norm_eps, state=ssm.PoolRow(sp, s, fresh), tail=tail,
-                                  new_lens=new_lens)
+            tail = jnp.where(fresh[:, None], 0, tail).reshape(N, sizes.d_conv - 1, -1)
+            row = ssm.PoolRow(sp, s, fresh)
+            if kind == "mamba":
+                y, sp, tail = ssm.mix(_dense(mp, "ssm_in_proj", cfg, h), mp, sizes, cfg.norm_eps, state=row,
+                                      tail=tail, new_lens=new_lens)
+            else:
+                def mixed(h, lens, tail, state):  # [q | k | v | z] as the product's float32 sums (ops/gdn.py)
+                    return gdn.mix(_dense(mp, "gdn_in_proj", cfg, h, sums=jnp.float32),
+                                   _dense(mp, "gdn_ba_proj", cfg, h), mp, sizes, cfg.norm_eps, state=state,
+                                   tail=tail, new_lens=lens)
+
+                group = gdn.group_rows(N, C, sizes.chunk_size, sizes.n_v_heads)
+                if group == N:
+                    y, sp, tail = mixed(h, new_lens, tail, row)
+                else:  # a group of rows at a time: a (128, 256) prefill's float32 [q | k | v | z] whole are 1.6 GB
+                    groups = jax.tree_util.tree_map(lambda a: a.reshape((N // group, group) + a.shape[1:]),
+                                                    (h, new_lens, tail, gdn.pool_rows(row, N)))
+                    y, left, tail = (a.reshape((N,) + a.shape[2:])
+                                     for a in jax.lax.map(lambda a: mixed(*a), groups))
+                    sp = gdn.put_pool_rows(row, left)
             cp = jax.lax.dynamic_update_slice(cp, tail.astype(cp.dtype).reshape(1, N, -1), (s, 0, 0))
-            out = _dense(mp, "ssm_out_proj", cfg, y)
+            out = _dense(mp, key + "_out_proj", cfg, y)
         x = x + _times(cfg.residual_multiplier, out)
-        out, _ = ffn(lp, _norm_at(lp, "mlp_norm", cfg, x), False)
-        return (x + _times(cfg.residual_multiplier, out), *kv, sp, cp)
+        out, picks = ffn(lp, _norm_at(lp, "mlp_norm", cfg, x), False)
+        return (x + _times(cfg.residual_multiplier, out), *kv, sp, cp), picks
 
     def period(carry, xs):
         """One period of a layer pattern, its layers unrolled: attention layer
-        ``a`` (counted among its kind) has the pages from ``a * NB``, state-space
-        layer ``s`` row ``s`` of the state pool."""
+        ``a`` (counted among its kind) has the pages from ``a * NB``, state
+        layer ``s`` (Mamba-2 or Gated DeltaNet) row ``s`` of the state pool. A
+        routed pattern's picks come out a layer of the period, in its order."""
         pp, first_a, first_s = xs
         a = s = 0
+        picked = []
         for j, kind in enumerate(cfg.period):
             lp = pp[f"layer_{j}"]
-            if kind == "mamba":
-                carry = ssm_layer(carry, lp, first_s + s)
-                s += 1
-            else:
-                (x, *kv), _ = layer(carry[:5], lp, (first_a + a) * NB)
+            if kind == "attention":
+                (x, *kv), picks = layer(carry[:5], lp, (first_a + a) * NB)
                 carry = (x, *kv, *carry[5:])
                 a += 1
-        return carry, None
+            else:
+                carry, picks = state_layer(carry, lp, first_s + s, kind)
+                s += 1
+            picked.append(picks)
+        return carry, jnp.stack(picked) if routed else None
 
     carry = (x, *pool)
     for i in range(D):
@@ -735,7 +776,10 @@ def _forward_hidden(
             periods = jnp.arange(cfg.num_layers // len(kinds), dtype=jnp.int32)
             (x, *pool), picks = jax.lax.scan(
                 period, carry + tuple(state or ()),
-                (params["layers"], periods * kinds.count("attention"), periods * kinds.count("mamba")))
+                (params["layers"], periods * kinds.count("attention"),
+                 periods * (len(kinds) - kinds.count("attention"))))
+            if routed:  # [periods, layers of a period, N*C, k]: the layers in the model's order
+                picks = picks.reshape((cfg.num_layers,) + picks.shape[2:])
         else:
             (x, *pool), picks = jax.lax.scan(
                 lambda c, xs: layer(c, *xs), carry,
@@ -747,7 +791,7 @@ def _forward_hidden(
     if cfg.hc_mult:
         x = mhc.collapse(x)  # the streams summed, before the last-token selection and the head
     # picks: [routed layers, N*C, k] -> [N, C, routed layers, k]
-    picks = None if picks is None else jnp.moveaxis(picks, 0, 1).reshape(N, C, L - D, -1)
+    picks = None if picks is None else jnp.moveaxis(picks, 0, 1).reshape(N, C, cfg.routed_layers, -1)
 
     if not all_positions:
         x = jnp.take_along_axis(
@@ -837,7 +881,9 @@ def ragged_decode_chain(
 
     A routed model asked ``with_picks`` returns two more: ``touched`` int32
     ``[K, routed layers]``, how many distinct experts the rows live at a step
-    picked in each routed layer (what a step has to read of the experts), and
+    picked in each routed layer (what a step has to read of the experts; of a
+    chip's share ``[K, routed layers, 2]``: the HELD experts picked, and the
+    visits they got), and
     ``picks`` int32 ``[K, N, routed layers, k]``, the experts each step's
     input token was sent to (rows not live at a step: garbage).
 
@@ -868,8 +914,12 @@ def ragged_decode_chain(
         if not picks:
             return carry, out
         picked = picks[0][:, 0]  # [N, routed layers, k]
-        hit = jax.nn.one_hot(picked, cfg.num_experts, dtype=jnp.bool_) & live[:, None, None, None]
+        # (of a chip's share, by the held experts' own numbers: a pick of another chip's is no row of the one-hot)
+        held = picked if cfg.expert_parallel is None else picked - cfg.first_expert
+        hit = jax.nn.one_hot(held, cfg.num_experts, dtype=jnp.bool_) & live[:, None, None, None]
         touched = hit.any(axis=(0, 2)).sum(axis=-1).astype(jnp.int32)  # [routed layers]
+        if cfg.expert_parallel is not None:  # beside the held experts read, the visits they got
+            touched = jnp.stack([touched, hit.sum(axis=(0, 2, 3)).astype(jnp.int32)], axis=-1)
         return carry, (out, touched, picked)
 
     carry0 = (pool, tokens, start_pos, active & (budgets > 0),

@@ -78,7 +78,61 @@ class SSMConfig:
         return self.d_inner + self.conv_dim + self.n_heads
 
 
-LAYER_KINDS = ("attention", "mamba")
+@dataclasses.dataclass(frozen=True)
+class GDNConfig:
+    """The sizes of a Gated DeltaNet (linear-attention) mixer, ``TransformerConfig.
+    gdn`` (``ops/gdn.py`` has the mathematics): ``n_k_heads`` query/key heads of
+    ``head_k_dim``, ``n_v_heads`` value heads of ``head_v_dim`` (value head ``h``
+    reads key head ``h // (n_v_heads / n_k_heads)``), each value head with a
+    state ``[head_k_dim, head_v_dim]``; a causal depthwise convolution over
+    ``d_conv`` inputs of ``[q | k | v]``; the chunked delta rule's chunk."""
+
+    n_k_heads: int
+    n_v_heads: int
+    head_k_dim: int
+    head_v_dim: int
+    d_conv: int = 4
+    chunk_size: int = 64
+
+    def __post_init__(self):
+        if self.n_v_heads % self.n_k_heads or self.d_conv < 2:
+            raise ValueError(f"a Gated DeltaNet mixer needs n_v_heads a multiple of n_k_heads and d_conv >= 2, got {self}")
+
+    @property
+    def key_dim(self) -> int:
+        return self.n_k_heads * self.head_k_dim
+
+    @property
+    def value_dim(self) -> int:
+        return self.n_v_heads * self.head_v_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels of the convolution: ``q``, ``k`` and ``v`` together."""
+        return 2 * self.key_dim + self.value_dim
+
+    @property
+    def proj_dim(self) -> int:
+        """Columns of the in-projection: ``[q | k | v | z]``."""
+        return self.conv_dim + self.value_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertParallel:
+    """One chip's share of a routed layer that ``size`` chips hold together,
+    ``TransformerConfig.expert_parallel``: this one is ``rank`` and holds
+    experts ``rank * num_experts .. (rank + 1) * num_experts - 1`` of the
+    ``size * num_experts`` the router scores."""
+
+    size: int
+    rank: int = 0
+
+    def __post_init__(self):
+        if self.size < 1 or not 0 <= self.rank < self.size:
+            raise ValueError(f"an expert-parallel share needs size >= 1 and 0 <= rank < size, got {self}")
+
+
+LAYER_KINDS = ("attention", "mamba", "linear_attention")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -267,6 +321,22 @@ class TransformerConfig:
     # scan over ``Block`` as it always was.
     layer_types: Optional[Tuple[str, ...]] = None
     ssm: Optional[SSMConfig] = None
+    # "linear_attention": ``GatedDeltaNet`` at the sizes ``gdn``. A pattern may
+    # have a routed MLP (``num_experts`` > 0) in every layer, drop-free
+    gdn: Optional[GDNConfig] = None
+    # Gated attention: the query projection is twice as wide, a head's
+    # ``[q | gate]``, and the attention's output is times ``sigmoid(gate)``
+    # before ``wo``; per-head RMSNorm of q and k (``q_norm``, ``k_norm``) before
+    # the rotary
+    attn_output_gate: bool = False
+    qk_norm: bool = False
+    # the shared expert's output times ``sigmoid(x . shared_gate)``, one scalar a token
+    moe_shared_gate: bool = False
+    # this chip's share of a routed layer spread over chips: ``num_experts`` are
+    # HELD here, the router scores ``router_experts`` and picks among them all;
+    # the layer computes the terms of its own experts (and the shared expert).
+    # None: every expert is here, and the routed layer is what it always was
+    expert_parallel: Optional[ExpertParallel] = None
     # scalars on the embedding, the attention scores (None: head_dim^-0.5),
     # every residual add and the logits (divided by it); 1 is no multiply
     embedding_multiplier: float = 1.0
@@ -284,12 +354,26 @@ class TransformerConfig:
                     f"{self.num_layers}: one of {LAYER_KINDS} a layer")
             if "mamba" in kinds and self.ssm is None:
                 raise ValueError("layer_types with a 'mamba' layer needs its sizes, ssm=SSMConfig(...)")
-            if (self.hc_mult or self.parallel_block or self.first_dense_layers or self.num_experts
+            if "linear_attention" in kinds and self.gdn is None:
+                raise ValueError("layer_types with a 'linear_attention' layer needs its sizes, gdn=GDNConfig(...)")
+            if (self.hc_mult or self.parallel_block or self.first_dense_layers
                     or self.moe_layer_experts or self.kv_lora_rank or self.eva_window or self.fp32_residual):
                 raise ValueError(
-                    "a layer pattern (layer_types) is built around plain attention and a dense MLP in a "
-                    "sequential one-stream block: no hyper-connections, parallel_block, routed or leading "
-                    "dense layers, latent or EVA attention, fp32 residual")
+                    "a layer pattern (layer_types) is built around plain attention and one MLP (dense, or "
+                    "routed in every layer) in a sequential one-stream block: no hyper-connections, "
+                    "parallel_block, leading dense or pyramid layers, latent or EVA attention, fp32 residual")
+        if isinstance(self.expert_parallel, dict):
+            object.__setattr__(self, "expert_parallel", ExpertParallel(**self.expert_parallel))
+        if self.expert_parallel is not None and self.expert_parallel.size == 1:
+            # one chip holds every expert: the routed layer as it always was, to the instruction
+            object.__setattr__(self, "expert_parallel", None)
+        if self.expert_parallel is not None and not self.drop_free_moe:
+            raise ValueError("expert_parallel (a chip's share of a routed layer) needs a drop-free routed "
+                             "layer: num_experts > 0 with a sigmoid router or a layer pattern")
+        if (self.attn_output_gate or self.qk_norm) and (self.latent_attention or self.eva_window
+                                                        or self.attn_impl in ("sparse", "fpdt")):
+            raise ValueError("attn_output_gate / qk_norm are plain attention's: not with latent or EVA "
+                             "attention, nor attn_impl sparse | fpdt")
         if self.residual_multiplier != 1.0 and (self.hc_mult or self.parallel_block):
             raise ValueError("residual_multiplier scales the adds of a sequential one-stream block: not with "
                              "hyper-connections or parallel_block")
@@ -377,6 +461,24 @@ class TransformerConfig:
                 and self.has_moe)
 
     @property
+    def drop_free_moe(self) -> bool:
+        """Whether a routed layer is ``DropFreeMoE`` (no capacity, no drops, a
+        shared expert where the config has one): a sigmoid router's, and a
+        layer pattern's whatever its router."""
+        return self.num_experts > 0 and (self.moe_router == "sigmoid" or self.layer_types is not None)
+
+    @property
+    def router_experts(self) -> int:
+        """Experts the router scores and numbers its picks by: those held here
+        times the chips that share the layer."""
+        return self.num_experts * (self.expert_parallel.size if self.expert_parallel else 1)
+
+    @property
+    def first_expert(self) -> int:
+        """The router's number of the first expert held here."""
+        return self.num_experts * self.expert_parallel.rank if self.expert_parallel else 0
+
+    @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
 
@@ -402,8 +504,18 @@ class TransformerConfig:
 
     @property
     def ssm_layers(self) -> int:
-        """Layers that hold a recurrent state: the state pool's."""
+        """Mamba-2 layers: each a row of the state pool."""
         return 0 if self.layer_types is None else self.layer_types.count("mamba")
+
+    @property
+    def gdn_layers(self) -> int:
+        """Gated DeltaNet layers: each a row of the state pool."""
+        return 0 if self.layer_types is None else self.layer_types.count("linear_attention")
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that hold a recurrent state: the state pool's rows."""
+        return self.ssm_layers + self.gdn_layers
 
     @property
     def latent_rotary(self) -> "LatentRotary":
@@ -461,7 +573,8 @@ class TransformerConfig:
             return q + kv + H * self.v_head_dim * h
         hd = self.dims_per_head
         eva = 2 * self.kv_heads * hd if self.eva_window else 0  # phi and mu
-        return h * hd * (H + 2 * self.kv_heads) + hd * H * h + eva
+        gate = h * hd * H if self.attn_output_gate else 0  # the query projection's second half
+        return h * hd * (H + 2 * self.kv_heads) + hd * H * h + eva + gate + (2 * hd if self.qk_norm else 0)
 
     def _ssm_params(self) -> int:
         """One state-space mixer: both projections, the convolution and its
@@ -469,6 +582,13 @@ class TransformerConfig:
         s = self.ssm
         return (self.hidden_size * (s.proj_dim + s.d_inner) + (s.d_conv + 1) * s.conv_dim
                 + 3 * s.n_heads + s.d_inner)
+
+    def _gdn_params(self) -> int:
+        """One Gated DeltaNet mixer: the three projections, the convolution,
+        ``A_log``, ``dt_bias``, the gated norm."""
+        g = self.gdn
+        return (self.hidden_size * (g.proj_dim + 2 * g.n_v_heads + g.value_dim) + g.d_conv * g.conv_dim
+                + 2 * g.n_v_heads + g.head_v_dim)
 
     def num_params(self) -> int:
         h, v, l = self.hidden_size, self.vocab_size, self.num_layers
@@ -480,15 +600,16 @@ class TransformerConfig:
         for i in range(l):
             n_exp = self.experts_for_layer(i)
             if n_exp > 0:
-                layer_mlp = n_exp * expert + h * n_exp  # experts + router
-                layer_mlp += self.moe_shared_experts * expert
+                layer_mlp = n_exp * expert + h * self.router_experts  # experts (held here) + router
+                layer_mlp += self.moe_shared_experts * expert + (h if self.moe_shared_gate else 0)
                 if self.moe_router == "sigmoid":
                     layer_mlp += n_exp  # the correction bias
                 if self.moe_use_residual:
                     layer_mlp += mlp + 2 * h + 2  # residual MLP + coefficient gate
             else:
                 layer_mlp = mlp
-            mixer = self._ssm_params() if self.layer_types and self.layer_types[i] == "mamba" else qkv
+            kind = self.layer_types[i] if self.layer_types else "attention"
+            mixer = {"mamba": self._ssm_params, "linear_attention": self._gdn_params}.get(kind, lambda: qkv)()
             total += mixer + layer_mlp + (h if self.parallel_block else 2 * h) + 2 * self.hc_params
         return total
 
@@ -501,7 +622,10 @@ class TransformerConfig:
         for i in range(self.num_layers):
             n_exp = self.experts_for_layer(i)
             if n_exp > 0:
-                dead += (n_exp - min(self.moe_top_k, n_exp)) * mlp
+                # (of a chip's share a token visits top_k / size of the experts held here, on average)
+                visits = min(self.moe_top_k, n_exp) if self.expert_parallel is None else (
+                    self.moe_top_k / self.expert_parallel.size)
+                dead += int((n_exp - visits) * mlp)
         return self.num_params() - dead
 
 
@@ -600,6 +724,14 @@ def _norm(config: TransformerConfig, name: str):
     return _made_again(nn.LayerNorm)(epsilon=config.norm_eps, param_dtype=config.param_dtype, name=name)
 
 
+# ``apply_qk_rope`` has TWO paths to the same numbers, and the first is kept for one reason alone: a table over
+# every declared position is what each serving program pinned by ``tests/unit/inference/fixtures`` (the census) was
+# recorded with, and PR 48 was not to re-record them. Past this many declared positions (no configuration before
+# PR 48 declares more than 32,768) the angles are computed from the call's own positions, which is no more work
+# for any configuration. ROADMAP S18: the second path for all, the census re-recorded, this constant gone.
+_ROPE_TABLE_POSITIONS = 2 ** 17
+
+
 def rope_tables(seq_len: int, dim: int, theta: float) -> Tuple[jax.Array, jax.Array]:
     freqs = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
     t = jnp.arange(seq_len, dtype=jnp.float32)
@@ -616,7 +748,15 @@ def apply_qk_rope(cfg: "TransformerConfig", q, k, positions):
     decode paths so the three sites cannot drift."""
     hd = q.shape[-1]
     rd = cfg.rotary_dim or hd
-    cos, sin = rope_tables(cfg.max_seq_len, rd, cfg.rope_theta)
+    if cfg.max_seq_len > _ROPE_TABLE_POSITIONS:
+        # the angles of the call's own positions, the table's numbers: a table over every position such a
+        # config declares is made anew by every call (262,144 x 32: 0.9 ms a layer-step on the v5e, PR 48)
+        freqs = 1.0 / (cfg.rope_theta ** (jnp.arange(0, rd, 2, dtype=jnp.float32) / rd))
+        angles = positions.reshape(-1, 1).astype(jnp.float32) * freqs[None, :]
+        cos, sin = jnp.cos(angles), jnp.sin(angles)
+        positions = jnp.arange(positions.size, dtype=positions.dtype).reshape(positions.shape)
+    else:
+        cos, sin = rope_tables(cfg.max_seq_len, rd, cfg.rope_theta)
     ap = lambda x: apply_rope(x, cos, sin, positions, interleaved=cfg.rope_interleaved)  # noqa: E731
     if rd < hd:
         q = jnp.concatenate([ap(q[..., :rd]), q[..., rd:]], -1)
@@ -672,12 +812,18 @@ class Attention(nn.Module):
         cfg = self.config
         hd = cfg.dims_per_head
         qkv_bias = cfg.qkv_bias if cfg.qkv_bias is not None else cfg.norm == "layernorm"
-        q = nn.DenseGeneral((cfg.num_heads, hd), use_bias=qkv_bias, dot_general=_gathered(self, "wq"),
+        # gated attention: a head's projection is ``[q | gate]``
+        q = nn.DenseGeneral((cfg.num_heads, hd * (2 if cfg.attn_output_gate else 1)), use_bias=qkv_bias,
+                            dot_general=_gathered(self, "wq"),
                             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wq")(x)
         k = nn.DenseGeneral((cfg.kv_heads, hd), use_bias=qkv_bias, dot_general=_gathered(self, "wk"),
                             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wk")(x)
         v = nn.DenseGeneral((cfg.kv_heads, hd), use_bias=qkv_bias, dot_general=_gathered(self, "wv"),
                             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wv")(x)
+        if cfg.attn_output_gate:
+            q, gate = q[..., :hd], q[..., hd:]
+        if cfg.qk_norm:
+            q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
 
         if cfg.position == "rope":
             q, k = apply_qk_rope(cfg, q, k, positions)
@@ -753,6 +899,9 @@ class Attention(nn.Module):
                                    alibi_slopes=slopes, **scaled,
                                    **dict(cfg.attn_kwargs or ()))  # [B,S,H,hd]
             out = ulysses_unshard(out)
+        if cfg.attn_output_gate:
+            with jax.named_scope("attn_gate"):
+                out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
         dense_bias = cfg.dense_bias if cfg.dense_bias is not None else cfg.norm == "layernorm"
         out = nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=dense_bias, dot_general=_gathered(self, "wo"),
                               dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wo")(out)
@@ -991,6 +1140,41 @@ class Mamba2Mixer(nn.Module):
         return dense(cfg.hidden_size, "ssm_out_proj")(y)
 
 
+class GatedDeltaNet(nn.Module):
+    """A Gated DeltaNet (linear-attention) mixer over a full sequence from an
+    empty state (``TransformerConfig.gdn``; ``ops/gdn.py`` has the mathematics,
+    which the paged serving path calls with these parameters and a state it
+    keeps): ``gdn_in_proj`` [hidden, q | k | v | z], ``gdn_ba_proj`` [hidden, b |
+    a], ``gdn_conv`` [taps, channels] (no bias), ``A_log`` and ``dt_bias`` a value head
+    (drawn spread, as ``Mamba2Mixer``'s), ``gdn_norm`` (the gated norm's scale,
+    one value head's width), ``gdn_out_proj``. ``mask`` [B, S] marks the live
+    tokens of right-padded rows."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, mask, positions, train: bool):
+        from deepspeed_tpu.ops import gdn
+
+        cfg, g = self.config, self.config.gdn
+        def dense(features, name, dtype=cfg.dtype):
+            return nn.Dense(features, use_bias=False, dtype=dtype, param_dtype=cfg.param_dtype,
+                            dot_general=_gathered(self, name), name=name)
+
+        # [q | k | v | z] as the product's float32 sums: nothing between the projections rounds them (ops/gdn.py)
+        qkvz, ba = dense(g.proj_dim, "gdn_in_proj", jnp.float32)(x), dense(2 * g.n_v_heads, "gdn_ba_proj")(x)
+        heads = functools.partial(self.param, shape=(g.n_v_heads,), dtype=cfg.param_dtype)
+        leaves = {
+            "gdn_conv": self.param("gdn_conv", nn.initializers.normal(g.d_conv ** -0.5),
+                                   (g.d_conv, g.conv_dim), cfg.param_dtype),
+            "A_log": heads("A_log", _a_log_init), "dt_bias": heads("dt_bias", _dt_bias_init),
+            "gdn_norm": _Scale(g.head_v_dim, cfg.param_dtype, name="gdn_norm")(),
+        }
+        new_lens = None if mask is None else (mask > 0).sum(axis=1).astype(jnp.int32)
+        y, _, _ = gdn.mix(qkvz, ba, leaves, g, cfg.norm_eps, new_lens=new_lens)
+        return dense(cfg.hidden_size, "gdn_out_proj")(y)
+
+
 class MLP(nn.Module):
     config: TransformerConfig
 
@@ -1106,6 +1290,10 @@ class Block(nn.Module):
             x = x + _times(cfg.residual_multiplier, Mamba2Mixer(cfg, name="ssm")(
                 _norm(cfg, "ssm_pre_norm")(x), mask, positions, self.train))
             h = _norm(cfg, "mlp_norm")(x)
+        elif self.kind == "linear_attention":
+            x = x + _times(cfg.residual_multiplier, GatedDeltaNet(cfg, name="gdn")(
+                _norm(cfg, "gdn_pre_norm")(x), mask, positions, self.train))
+            h = _norm(cfg, "mlp_norm")(x)
         else:
             x = x + _times(cfg.residual_multiplier, attn_cls(cfg, name="attn")(
                 _norm(cfg, "attn_norm")(x), mask, positions, self.train
@@ -1126,7 +1314,7 @@ class Block(nn.Module):
         # structure is decided once by CausalLM (dense layers pass it through
         # untouched, so the scan carry stays consistent across the stack)
         collect = cfg.moe_metrics and self.train and cfg.has_moe
-        if n_exp > 0 and cfg.moe_router == "sigmoid":
+        if n_exp > 0 and cfg.drop_free_moe:
             # drop-free by construction: no capacity, no auxiliary loss
             from deepspeed_tpu.parallel.moe import DropFreeMoE
 

@@ -59,24 +59,29 @@ def split_projection(zxbcdt, sizes):
     return zxbcdt[..., :d], zxbcdt[..., d:d + c], zxbcdt[..., d + c:]
 
 
-@jax.named_scope("ssm_conv")
-def causal_conv(xbc, tail, kernel, bias, new_lens=None):
+def conv_inputs(xbc, tail, kernel, bias, new_lens=None):
     """``silu(conv(xBC) + bias)`` of ``xbc`` [B, T, X] after the inputs ``tail``
     [B, K - 1, X] that came before it (None: none did, zeros), ``kernel`` [K, X]
-    with tap ``K - 1`` on the current token. Returns it and the tail the next
-    call starts from: the ``K - 1`` inputs that end at each row's last live
-    token, ``new_lens`` [B] of the ``T`` (None: all)."""
+    with tap ``K - 1`` on the current token, ``bias`` [X] or None. Returns it
+    and the tail the next call starts from: the ``K - 1`` inputs that end at
+    each row's last live token, ``new_lens`` [B] of the ``T`` (None: all).
+    Under no scope of its own (``ops/gdn.py`` opens ``gdn_conv`` around it)."""
     B, T, X = xbc.shape
     K = kernel.shape[0]
     if tail is None:
         tail = jnp.zeros((B, K - 1, X), xbc.dtype)
     seen = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)  # [B, K - 1 + T, X]
     out = sum(seen[:, j:j + T].astype(jnp.float32) * kernel[j].astype(jnp.float32) for j in range(K))
-    out = jax.nn.silu(out + bias.astype(jnp.float32)).astype(xbc.dtype)
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
+    out = jax.nn.silu(out).astype(xbc.dtype)
     if new_lens is None:
         return out, seen[:, T:]
     at = new_lens[:, None] + jnp.arange(K - 1)[None, :]  # a dead row: 0..K-2, the tail it came with
     return out, jnp.take_along_axis(seen, at[:, :, None], axis=1)
+
+
+causal_conv = jax.named_scope("ssm_conv")(conv_inputs)  # the state-space mixer's, under its own scope
 
 
 def scan_inputs(xbc, dt, dt_bias, sizes):

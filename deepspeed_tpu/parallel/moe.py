@@ -567,7 +567,8 @@ class _Router(nn.Module):
     @nn.compact
     def __call__(self) -> dict:
         cfg = self.config
-        out = {"wg": {"kernel": _Kernel((cfg.hidden_size, cfg.num_experts), cfg.param_dtype,
+        # (a chip's share of the layer scores every chip's experts: ``router_experts``)
+        out = {"wg": {"kernel": _Kernel((cfg.hidden_size, cfg.router_experts), cfg.param_dtype,
                                         name="wg")()}}
         if cfg.moe_router == "sigmoid":
             # sigmoid scores of lecun-normal logits spread by about a quarter
@@ -599,7 +600,8 @@ class DropFreeMoE(nn.Module):
     reaches its ``moe_top_k`` experts (:func:`route`) and the shared expert,
     if any. The flax twin of ``inference/model.py::_moe``: it declares the
     parameters under the names :class:`MoELayer` gives them (``gate/wg``,
-    ``experts/w_*``; beside them ``gate/e_bias`` and ``shared/w_*``), every
+    ``experts/w_*``; beside them ``gate/e_bias``, ``shared/w_*`` and
+    ``shared_gate``), every
     routing leaf drawn nonzero, and runs that one definition of the math, so
     the full-sequence forward and serving cannot drift. ``config`` is a
     TransformerConfig."""
@@ -620,6 +622,8 @@ class DropFreeMoE(nn.Module):
             lp["experts"]["w_gate"] = w_gate
         if cfg.moe_shared_experts:
             lp["shared"] = _SharedExpert(cfg, name="shared")()
+            if cfg.moe_shared_gate:  # sigmoid(x . w) a token, times the shared expert's output
+                lp["shared_gate"] = {"kernel": _Kernel((cfg.hidden_size, 1), cfg.param_dtype, name="shared_gate")()}
         return _moe(lp, cfg, x)
 
 

@@ -394,7 +394,10 @@ def test_latent_paged_attention_compiles_at_32_heads(one_chip, monkeypatch, N, C
     (32768, 14336, 4096, 8, jnp.bfloat16),  # and its down-projection, whose K does not fit whole: a k loop
     (40, 128, 256, 4, jnp.bfloat16),        # fewer rows than a tile: one tile of the rows, padded
     (65536, 2048, 1536, 64, jnp.float32),   # fp32 operands: blocks twice the bytes, sublanes of 8
-], ids=["cell-gate-up", "cell-down", "xing-gate-up", "xing-down", "m512-e64", "mixtral-up", "mixtral-down", "m40", "cell-gate-up-fp32"])
+    (81920, 2048, 512, 64, jnp.bfloat16),   # qwen3-next-80b-a3b: a group of its (128, 256) prefill's pairs over 64 HELD experts
+    (81920, 512, 2048, 64, jnp.bfloat16),   # and the down-projection
+], ids=["cell-gate-up", "cell-down", "xing-gate-up", "xing-down", "m512-e64", "mixtral-up", "mixtral-down", "m40", "cell-gate-up-fp32",
+        "share-prefill-gate-up", "share-prefill-down"])
 def test_grouped_matmul_compiles_at_the_chosen_tiles(one_chip, m, K, N, E, dtype, kernels):
     """The routed prefill's grouped matmul at the tiles ``_gmm_tiles`` picks
     from the call's shapes: Mosaic's VMEM refusal (16 MiB scoped, no limit of
@@ -565,6 +568,95 @@ def test_granite_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, na
     moved = [line.strip()[:200] for line in text.splitlines()
              if re.search(r"= %s\S* (copy|transpose)\(" % state, line)]
     assert not moved, moved
+
+
+@pytest.mark.parametrize("name", ["prefill_128x256", "chain_128"])
+def test_qwen3_next_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, name):
+    """``qwen3-next-80b-a3b.serve.long-output-wave128``'s two programs whole, for
+    the described v5e at the cell's own shapes (12 layers at the published
+    widths, 64 of 512 experts held, 128 state slots = 2.47 GB of delta-rule
+    state, a 0.625 GiB page pool of the three attention layers), with the picks
+    handed out as the timed path hands them: the ``(128, 256)`` prefill (the
+    chunked delta rule a DeltaNet layer, the paged kernel's query block at 16 x
+    256 over 2 KV heads, the share's sorted dispatch through megablox ``gmm``,
+    which the chip takes and this host's ``lax.ragged_dot`` stands in for
+    unless asked) and the chain of 8 steps at 128 rows (``gdn_update``, three
+    calls a period; a block table 64 pages wide; the held experts by one
+    product over all of them: a ``gmm`` call would have a layer's slice of the
+    stacked weights COPIED for it, 7.2 GB a step, PERF.md PR 48). Each fits the chip beside the
+    weights and both pools, returns BOTH donated pools aliased, and holds no
+    instruction of the state pool's whole shape that is a copy."""
+    import dataclasses
+    import json
+    import re
+
+    from benchmarks.lib import harness, program
+    from deepspeed_tpu.checkpoint.hf import config_from_hf
+    from deepspeed_tpu.inference import model, paged
+    from deepspeed_tpu.models import CausalLM
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import flash_attention as fa, gdn_update, norms, paged_attention as pa
+
+    for module in (pa, fa, norms, gdn_update):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")  # what 'auto' sees there
+    monkeypatch.setattr(model, "_grouped_matmul", lambda lhs, rhs, sizes: model._gmm_padded(lhs, rhs, sizes))
+    cfg = dataclasses.replace(config_from_hf(program.published(harness.load_config("qwen3-next-80b-a3b"))),
+                              dtype=jnp.bfloat16)
+    engine = harness.load_workload("qwen3-next-80b-a3b.serve.long-output-wave128")["engine"]
+    bs, rows = engine["kv_block_size"], engine["max_seqs"]
+    NB, table = engine["kv_pool_bytes"] // (bs * 6144), engine["max_seq_len"] // bs
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda key: CausalLM(cfg).init({"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)},
+                                       train=False)["params"], jax.random.PRNGKey(0)))
+    pools = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: paged.HybridPools(
+        paged.init_pool(cfg, NB, bs, jnp.bfloat16), paged.init_state_pool(cfg, rows, jnp.bfloat16))))
+    assert pools.kv.k.shape == (3 * 6826, 16, 512) and pools.state.ssm.shape == (9, 128, 32, 128, 128)
+    assert pools.state.conv.shape == (9, 128, 3 * 8192)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    if name == "chain_128":
+        limit_gb = 2.0  # (1.78: the product over all 64 held experts; 0.50 with the sorted dispatch)
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def program_(params, pools, tokens, start_pos, tables, active, budgets, rng):
+            return paged.ragged_decode_chain(params, cfg, pools, tokens, start_pos, tables, bs,
+                                             active, budgets, rng, engine["decode_chain"], None, with_picks=True)
+
+        args = (i32(rows), i32(rows), i32(rows, table), jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip),
+                i32(rows), jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip))
+    else:
+        limit_gb = 5.5
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def program_(params, pools, tokens, positions, new_lens, tables):
+            return paged.ragged_forward(params, cfg, pools, tokens, positions, new_lens, tables, bs, with_picks=True)
+
+        chunk = engine["chunk_bucket"]
+        args = (i32(rows, chunk), i32(rows, chunk), i32(rows), i32(rows, table))
+    compiled = program_.lower(params, pools, *args).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pools))
+    assert pool_bytes == 128 * 9 * (2097152 + 49152) + 3 * 6826 * 16 * 512 * 2 * 2
+    assert mem.alias_size_in_bytes >= pool_bytes
+    print(json.dumps({"program": name, "temp_gb": mem.temp_size_in_bytes / 1e9,
+                      "argument_gb": mem.argument_size_in_bytes / 1e9}))
+    assert mem.temp_size_in_bytes < limit_gb * 1e9, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    # (a decode step's 128 rows go through every held expert's product, as the uncut layer's would: no gmm there)
+    kernels = {"paged_attn": 1, "gdn_update": 3} if name == "chain_128" else {"paged_attn": 1, "gmm": 12}
+    assert name != "chain_128" or "gmm" not in text
+    for kernel, least in kernels.items():  # the one-token rule is a kernel of its own name, the pool aliased through it
+        assert sum("tpu_custom_call" in line and kernel in line for line in text.splitlines()) >= least, kernel
+    # nothing copies or re-lays the state pool, a layer's row of it, or the rows of a row
+    state = r"f32\[(9,128|1,128|128),32,128,128\]"
+    moved = [line.strip()[:200] for line in text.splitlines()
+             if re.search(r"= %s\S* (copy|transpose)\(" % state, line)]
+    assert not moved, moved
+    # nor does the compiler, short of room, make the pool's update twice (it did with 64 rows a group of the
+    # chunked rule: ``bitcast_dynamic-update-slice_fusion.21.remat``, a second pool)
+    assert not re.search(r"dynamic-update-slice\S*\.remat\S* = f32\[9,128,32,128,128\]", text)
 
 
 @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["bf16", "int8"])

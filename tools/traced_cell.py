@@ -34,6 +34,17 @@
   every instruction that makes an array of the state pool's whole shape and is
   not its in-place update (there has to be none).
 
+- in a cell with Gated DeltaNet layers (an architecture file with
+  ``gdn_chunk_cost``), the same of the scopes under ``gdn``: a ``gdn_part=``
+  line a program and piece (``gdn_in_proj``, ``gdn_ba_proj``, ``gdn_conv``,
+  ``gdn_chunk`` in a prefill, ``gdn_update`` in a chain with the GB/s on its own
+  bytes, ``gdn_norm``, ``gdn_out_proj``), ``gdn_op=`` lines, the chunked delta
+  rule's share of its roofline in the prefills the window happens to hold
+  (``gdn_chunk_roofline=``) and the ``state_pool_copy=`` lines;
+- in a cell that is one chip's share of a routed layer, a ``held_visits=``
+  line: the median over the traced chains of the visits a step and routed
+  layer that the experts held here got (the ``serve:accept`` spans' arg).
+
 All are wrapped OUTSIDE the benchmark, before ``run.main`` runs; nothing
 here is read by the program or the benchmark, and a cell's listed metrics
 read what they read without it.
@@ -123,6 +134,66 @@ def ssm_parts(rows, workload_name):
     yield f"state_pool_copies={copies} of_shape={whole}"
 
 
+GDN_PARTS = ("gdn_in_proj", "gdn_ba_proj", "gdn_conv", "gdn_chunk", "gdn_update", "gdn_norm", "gdn_out_proj")
+
+
+def gdn_parts(rows, workload_name):
+    """The ``gdn_part=``, ``gdn_op=``, ``gdn_chunk_roofline=`` and ``state_pool_copy=`` lines."""
+    import re
+
+    from benchmarks.lib import costs, harness, peaks, program
+    from deepspeed_tpu.ops import gdn
+
+    workload = harness.load_workload(workload_name)
+    config = harness.load_config(workload["config"])
+    arch, cfg = harness.load_architecture(config["architecture"]), program.published(config)
+    if not hasattr(arch, "gdn_chunk_cost"):
+        return
+    gdn_sizes = program.model_config(config, None).gdn
+    rows_, chunk = workload["engine"]["max_seqs"], workload["engine"]["chunk_bucket"]
+    # a period's DeltaNet layers are unrolled in the scan's body: each has instructions of its own
+    unrolled = cfg["full_attention_interval"] - 1
+    Hv, Dk, Dv = cfg["linear_num_value_heads"], cfg["linear_key_head_dim"], cfg["linear_value_head_dim"]
+    state = rows_ * Hv * Dk * Dv * 4
+    under = [(r, r["tf_op_name"].rstrip(":").split("/")) for r in rows]
+    under = [(r, path, path[0][4:-1] if path[0].startswith("jit(") else "?") for r, path in under if "gdn" in path]
+    for prog in sorted({p for _, _, p in under}):
+        mine = [(r, path) for r, path, p in under if p == prog]
+        # a layer-call makes ONE in-projection (a prefill's one a GROUP of its rows, ``ops/gdn.py::group_rows``):
+        # its instruction's occurrences count the layer-calls
+        calls = unrolled * max((int(float(r["occurrences"])) for r, path in mine if "gdn_in_proj" in path), default=1)
+        if prog == "step":
+            calls = max(calls // (rows_ // gdn.group_rows(rows_, chunk, gdn_sizes.chunk_size, Hv)), 1)
+        for part in GDN_PARTS + ("(gdn alone)",):
+            of = [r for r, path in mine if (part in path if part in GDN_PARTS else not set(GDN_PARTS) & set(path))]
+            if not of:
+                continue
+            seconds = 1e-6 * sum(float(r["total_self_time"]) for r in of)
+            line = (f"gdn_part={part} program={prog} device_s={seconds} layer_calls={calls} "
+                    f"ms_a_layer_call={1e3 * seconds / calls} instructions={len(of)}")
+            if part == "gdn_update":
+                line += f" own_gb_per_s={2e-9 * state * calls / seconds} of_state_bytes={2 * state}"
+            if part == "gdn_chunk":
+                flops, bytes_ = arch.gdn_chunk_cost(cfg, rows_, chunk)
+                least, bound = costs.roofline_seconds(flops, bytes_, peaks.device_peaks("TPU v5 lite"))
+                yield (f"gdn_chunk_roofline={100 * least * calls / seconds} bound={bound} least_ms_a_layer_call="
+                       f"{1e3 * least} rows={rows_} tokens={chunk} (every layer-call counted at the full shape)")
+            yield line
+        for r, _ in sorted(mine, key=lambda rp: -float(rp[0]["total_self_time"]))[:5]:
+            yield (f"gdn_op={r['hlo_op_name']} program={prog} device_s={1e-6 * float(r['total_self_time'])} "
+                   f"count={r['occurrences']} op_name={r['tf_op_name']} expression={r['hlo_op_expression'][:400]}")
+    whole = r"f32\[%d,%d,%d,%d,%d\]" % (arch.gdn_layers(cfg), rows_, Hv, Dk, Dv)  # values on the lanes
+    copies = 0
+    for r in rows:
+        made = re.match(r"\s*%?[\w.\-]+ = (?:\()?" + whole, r["hlo_op_expression"])
+        in_place = "dynamic-update-slice" in r["hlo_op_expression"] or r["hlo_op_name"].startswith("gdn_update")
+        if made and not in_place and r["category"] != "while":
+            copies += 1
+            yield (f"state_pool_copy={r['hlo_op_name']} category={r['category']} device_s="
+                   f"{1e-6 * float(r['total_self_time'])} count={r['occurrences']} expression={r['hlo_op_expression'][:300]}")
+    yield f"state_pool_copies={copies} of_shape={whole}"
+
+
 def main(argv=None) -> int:
     from benchmarks import run
     from benchmarks.lib import harness, scopes
@@ -136,8 +207,9 @@ def main(argv=None) -> int:
               f"hlo_stats_s={time.perf_counter() - start:.3f} rows={len(rows)}", flush=True)
         for line in moe_halves(rows):
             print(line, flush=True)
-        for line in ssm_parts(rows, argv[argv.index("--workload") + 1]):
-            print(line, flush=True)
+        for parts in (ssm_parts, gdn_parts):
+            for line in parts(rows, argv[argv.index("--workload") + 1]):
+                print(line, flush=True)
         return rows
 
     def with_serving_readers(bench, group, workload_name):
@@ -150,8 +222,20 @@ def main(argv=None) -> int:
                 wanted = wanted + [m for m in bench["per_layer"] if m["name"] in ROUTED and m["name"] not in names]
         return wanted
 
+    read_metrics = harness.read_metrics
+
+    def with_held_visits(entries, run_, trace, *rest):
+        from benchmarks.lib import spans, stats
+
+        visits = [float(s.args["held_visits"]) for s in spans.named(spans.of_run(run_), "serve:accept", kind="chain")
+                  if "held_visits" in s.args]
+        if visits:  # a chip's share of a routed layer: the load its experts got, which no metric reads
+            print(f"held_visits= median={stats.median(visits)} least={min(visits)} most={max(visits)} "
+                  f"chains={len(visits)} (visits a step and routed layer to the experts held here)", flush=True)
+        return read_metrics(entries, run_, trace, *rest)
+
     argv = list(sys.argv[1:] if argv is None else argv)
-    scopes._hlo_stats, harness.cell_metrics = timed, with_serving_readers
+    scopes._hlo_stats, harness.cell_metrics, harness.read_metrics = timed, with_serving_readers, with_held_visits
     return run.main(argv)
 
 
