@@ -713,17 +713,15 @@ def _forward_hidden(
     def state_layer(carry, lp, s, kind):
         """A layer with recurrent state (Mamba-2 or Gated DeltaNet), the
         ``s``-th of its kind: its mixer reads and writes row ``s`` of the state
-        pool, in place (``StatePool``)."""
+        pool and of the conv pool, in place (``StatePool``)."""
         x, *kv, sp, cp = carry
         key, sizes = ("ssm", cfg.ssm) if kind == "mamba" else ("gdn", cfg.gdn)
         h = _norm_at(lp, key + "_pre_norm", cfg, x)
         with reading(lp, key) as mp:
-            tail = jax.lax.dynamic_slice(cp, (s, 0, 0), (1, N, cp.shape[2]))[0]
-            tail = jnp.where(fresh[:, None], 0, tail).reshape(N, sizes.d_conv - 1, -1)
-            row = ssm.PoolRow(sp, s, fresh)
+            row, tail = ssm.PoolRow(sp, s, fresh), ssm.PoolRow(cp, s, fresh)
             if kind == "mamba":
-                y, sp, tail = ssm.mix(_dense(mp, "ssm_in_proj", cfg, h), mp, sizes, cfg.norm_eps, state=row,
-                                      tail=tail, new_lens=new_lens)
+                y, sp, cp = ssm.mix(_dense(mp, "ssm_in_proj", cfg, h), mp, sizes, cfg.norm_eps, state=row,
+                                    tail=tail, new_lens=new_lens)
             else:
                 def mixed(h, lens, tail, state):  # [q | k | v | z] as the product's float32 sums (ops/gdn.py)
                     return gdn.mix(_dense(mp, "gdn_in_proj", cfg, h, sums=jnp.float32),
@@ -732,14 +730,14 @@ def _forward_hidden(
 
                 group = gdn.group_rows(N, C, sizes.chunk_size, sizes.n_v_heads)
                 if group == N:
-                    y, sp, tail = mixed(h, new_lens, tail, row)
+                    y, sp, cp = mixed(h, new_lens, tail, row)
                 else:  # a group of rows at a time: a (128, 256) prefill's float32 [q | k | v | z] whole are 1.6 GB
-                    groups = jax.tree_util.tree_map(lambda a: a.reshape((N // group, group) + a.shape[1:]),
-                                                    (h, new_lens, tail, gdn.pool_rows(row, N)))
-                    y, left, tail = (a.reshape((N,) + a.shape[2:])
-                                     for a in jax.lax.map(lambda a: mixed(*a), groups))
-                    sp = gdn.put_pool_rows(row, left)
-            cp = jax.lax.dynamic_update_slice(cp, tail.astype(cp.dtype).reshape(1, N, -1), (s, 0, 0))
+                    groups = jax.tree_util.tree_map(
+                        lambda a: a.reshape((N // group, group) + a.shape[1:]),
+                        (h, new_lens, ssm.tail_rows(tail, N, sizes.d_conv), gdn.pool_rows(row, N)))
+                    y, left, tails = (a.reshape((N,) + a.shape[2:])
+                                      for a in jax.lax.map(lambda a: mixed(*a), groups))
+                    sp, cp = gdn.put_pool_rows(row, left), ssm.put_tail_rows(tail, tails)
             out = _dense(mp, key + "_out_proj", cfg, y)
         x = x + _times(cfg.residual_multiplier, out)
         out, picks = ffn(lp, _norm_at(lp, "mlp_norm", cfg, x), False)

@@ -257,11 +257,12 @@ def mix(qkvz, ba, p, sizes, norm_eps: float, state=None, tail=None, new_lens=Non
     token a row with a state takes the recurrence, anything else the chunked
     form. Returns ``(the normed, gated o [B, T, Hv Dv], state, tail)``. Where
     ``state`` is a :class:`PoolRow` the states are read from and written to the
-    pool, and the pool comes back in their place."""
+    pool, and the pool comes back in their place; ``tail`` likewise, a
+    :class:`PoolRow` of the conv pool (``ops/ssm.py::conv_inputs``)."""
     B, T = qkvz.shape[:2]
     X, Hv, Dv = sizes.conv_dim, sizes.n_v_heads, sizes.head_v_dim
     with jax.named_scope("gdn_conv"):
-        qkv, tail = ssm.conv_inputs(qkvz[..., :X], tail, p["gdn_conv"], None, new_lens)
+        qkv, tail = ssm.conv_inputs(qkvz, tail, p["gdn_conv"], None, new_lens)  # its first X columns
     q, k, v, g, beta = rule_inputs(qkv, ba, p["A_log"], p["dt_bias"], sizes)
     if T == 1 and state is not None:
         live = None if new_lens is None else new_lens > 0

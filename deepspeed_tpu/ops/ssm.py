@@ -59,15 +59,28 @@ def split_projection(zxbcdt, sizes):
     return zxbcdt[..., :d], zxbcdt[..., d:d + c], zxbcdt[..., d + c:]
 
 
-def conv_inputs(xbc, tail, kernel, bias, new_lens=None):
+def conv_inputs(xbc, tail, kernel, bias, new_lens=None, at: int = 0):
     """``silu(conv(xBC) + bias)`` of ``xbc`` [B, T, X] after the inputs ``tail``
     [B, K - 1, X] that came before it (None: none did, zeros), ``kernel`` [K, X]
     with tap ``K - 1`` on the current token, ``bias`` [X] or None. Returns it
     and the tail the next call starts from: the ``K - 1`` inputs that end at
     each row's last live token, ``new_lens`` [B] of the ``T`` (None: all).
+    ``xbc`` may be the whole of a projection's output [B, T, W]: the inputs are
+    its columns ``at .. at + X``. Where ``tail`` is a :class:`PoolRow` of the
+    conv pool the tails are read from and written to the pool, and the pool
+    comes back in their place: one token a row by :func:`conv_pool_step`, in
+    place, which takes the inputs where they lie.
     Under no scope of its own (``ops/gdn.py`` opens ``gdn_conv`` around it)."""
-    B, T, X = xbc.shape
-    K = kernel.shape[0]
+    B, T = xbc.shape[:2]
+    K, X = kernel.shape
+    if isinstance(tail, PoolRow):
+        if T == 1:
+            live = None if new_lens is None else new_lens > 0
+            out, pool = conv_pool_step(tail.pool, tail.layer, xbc[:, 0], kernel, bias, live, tail.fresh, at=at)
+            return out[:, None], pool
+        out, left = conv_inputs(xbc, tail_rows(tail, B, K), kernel, bias, new_lens, at)
+        return out, put_tail_rows(tail, left)
+    xbc = xbc[..., at:at + X]
     if tail is None:
         tail = jnp.zeros((B, K - 1, X), xbc.dtype)
     seen = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)  # [B, K - 1 + T, X]
@@ -77,8 +90,8 @@ def conv_inputs(xbc, tail, kernel, bias, new_lens=None):
     out = jax.nn.silu(out).astype(xbc.dtype)
     if new_lens is None:
         return out, seen[:, T:]
-    at = new_lens[:, None] + jnp.arange(K - 1)[None, :]  # a dead row: 0..K-2, the tail it came with
-    return out, jnp.take_along_axis(seen, at[:, :, None], axis=1)
+    last = new_lens[:, None] + jnp.arange(K - 1)[None, :]  # a dead row: 0..K-2, the tail it came with
+    return out, jnp.take_along_axis(seen, last[:, :, None], axis=1)
 
 
 causal_conv = jax.named_scope("ssm_conv")(conv_inputs)  # the state-space mixer's, under its own scope
@@ -146,7 +159,8 @@ class PoolRow(NamedTuple):
     row ``layer`` of ``pool`` [layers, slots, H P / W, N, W] float32
     (:func:`to_pool`), the call's rows in slots 0..rows-1, to be updated IN
     PLACE; ``fresh`` [rows] bool marks the rows that start a sequence, whose
-    slot still holds another's state."""
+    slot still holds another's state. A layer's convolution tails likewise:
+    row ``layer`` of the conv pool [layers, slots, (K - 1) X]."""
 
     pool: jax.Array
     layer: jax.Array
@@ -160,6 +174,44 @@ def _kernels(H: int, P: int, N: int, groups: int = 1) -> bool:
     from deepspeed_tpu.ops.pallas import ssm_update
 
     return registry._default_backend() == "tpu" and ssm_update.takes(H, P, groups, N)
+
+
+def tail_rows(row: PoolRow, rows: int, K: int):
+    """The call's rows of a layer's convolution tails, ``[rows, K - 1, X]`` of
+    row ``layer`` of the conv pool [layers, slots, (K - 1) X], a fresh row's as zeros."""
+    pool, layer, fresh = row
+    came = jax.lax.dynamic_slice(pool, (layer, 0, 0), (1, rows, pool.shape[2]))[0]
+    return jnp.where(fresh[:, None], 0, came).reshape(rows, K - 1, -1)
+
+
+def put_tail_rows(row: PoolRow, tail):
+    """``tail`` [rows, K - 1, X] into the call's slots of the layer's row of the conv pool: the pool."""
+    return jax.lax.dynamic_update_slice(row.pool, tail.astype(row.pool.dtype).reshape(1, tail.shape[0], -1),
+                                        (row.layer, 0, 0))
+
+
+@register("conv_pool_step", "xla")
+def _xla_conv_pool_step(pool, layer, x, taps, bias=None, live=None, fresh=None, at: int = 0):
+    row = PoolRow(pool, layer, jnp.zeros(x.shape[:1], bool) if fresh is None else fresh)
+    out, left = conv_inputs(x[:, None], tail_rows(row, x.shape[0], taps.shape[0]), taps, bias,
+                            None if live is None else live.astype(jnp.int32), at)
+    return out[:, 0], put_tail_rows(row, left)
+
+
+def conv_pool_step(pool, layer, x, taps, bias=None, live=None, fresh=None, at: int = 0, impl: str = "auto"):
+    """One token of :func:`conv_inputs` on row ``layer`` of the conv pool, in
+    place: ``(silu(conv(x) + bias) [rows, X], the pool)``, the inputs columns
+    ``at .. at + X`` of ``x`` [rows, W]. On the TPU, at sizes it takes, one
+    kernel that reads a row's tail once and writes it once
+    (``ops/pallas/conv_update.py``); XLA's form, the prompt's lines at one
+    token, makes six passes over it."""
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import conv_update  # (registers the kernel)
+
+    if impl == "auto":
+        takes = conv_update.takes(taps.shape[1], x.shape[0], at)
+        impl = "pallas" if registry._default_backend() == "tpu" and takes else "xla"
+    return dispatch("conv_pool_step", impl)(pool, layer, x, taps, bias, live=live, fresh=fresh, at=at)
 
 
 def pool_rows(row: PoolRow, rows: int, H: int, P: int):
@@ -291,10 +343,11 @@ def mix(zxbcdt, p, sizes, norm_eps: float, state=None, tail=None, new_lens=None
     token a row with a state takes the recurrence, anything else the chunked
     form. Returns ``(the gated, normed y [B, T, H P], state, tail)``. Where
     ``state`` is a :class:`PoolRow` the states are read from and written to the
-    pool, and the pool comes back in their place."""
+    pool, and the pool comes back in their place; ``tail`` likewise, a
+    :class:`PoolRow` of the conv pool (:func:`conv_inputs`)."""
     B, T = zxbcdt.shape[:2]
-    z, xbc, dt = split_projection(zxbcdt, sizes)
-    xbc, tail = causal_conv(xbc, tail, p["ssm_conv"]["kernel"], p["ssm_conv"]["bias"], new_lens)
+    z, _, dt = split_projection(zxbcdt, sizes)
+    xbc, tail = causal_conv(zxbcdt, tail, p["ssm_conv"]["kernel"], p["ssm_conv"]["bias"], new_lens, sizes.d_inner)
     x, b, c, dt = scan_inputs(xbc, dt, p["dt_bias"], sizes)
     if T == 1 and state is not None:
         live = None if new_lens is None else new_lens > 0

@@ -94,7 +94,7 @@ def _pool_sized_products(jaxpr, pool, layers):
             continue
         for v in eqn.outvars:
             a = v.aval
-            page_kept = name == "reshape" and eqn.invars[0].aval.shape[-1] == a.shape[-1]
+            page_kept = name == "reshape" and eqn.invars[0].aval.shape[-1:] == a.shape[-1:]  # (a kernel's scalars too)
             if pool_sized(a) and not page_kept:
                 out.append((name, tuple(a.shape)))
     return out
@@ -337,15 +337,29 @@ def _state_sized_products(jaxpr, state_pool):
     return out
 
 
-@pytest.mark.parametrize("which", ["step", "chain", "prefill"])
-def test_programs_update_the_state_pool_in_place_and_hand_both_pools_back(which):
+@pytest.mark.parametrize("which", ["step", "chain", "prefill", "chain_conv_kernel"])
+def test_programs_update_the_state_pool_in_place_and_hand_both_pools_back(which, monkeypatch):
     """A model with state-space layers: its programs take the page pool AND the
     state pool in the pool's place (``paged.HybridPools``), donated. Nothing of
-    the state pool's whole shape is produced but its in-place update, a layer's
-    row at a time, and the compiled program hands both pools back aliased."""
+    the state pool's whole shape (either array's) is produced but its in-place
+    update, a layer's row at a time, and the compiled program hands both pools
+    back aliased. The conv pool: the toy's 160 channels are no lane tile, so
+    off the TPU AND on it a decode step's convolution takes XLA's form there, a
+    ``dynamic_update_slice`` a layer as a prompt's; ``chain_conv_kernel`` makes
+    the chain take the kernel ``conv_update`` (interpret mode), whose aliased
+    output is then the only product of the conv pool's whole shape."""
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import conv_update
+
     from .test_hybrid import toy_params
 
     cfg, params = toy_params(jnp.float32)
+    kernel = which == "chain_conv_kernel"
+    if kernel:
+        assert not conv_update.takes(cfg.ssm.conv_dim, ROWS, cfg.ssm.d_inner)
+        monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")
+        monkeypatch.setattr(conv_update, "takes", lambda *sizes: True)
+        which = "chain"
     eng = _engine(cfg, params, max_seqs=ROWS, row_bucket=ROWS, kv_cache_dtype="bf16")
     pools = eng._pools
     assert pools.state.ssm.shape == (cfg.ssm_layers, ROWS, 1, 16, 128) and len(pools) == 2
@@ -362,12 +376,18 @@ def test_programs_update_the_state_pool_in_place_and_hand_both_pools_back(which)
             fn, args = eng._sample_step_fn(ROWS, chunk, (("do_sample", False),)), args + (jax.random.PRNGKey(0),)
 
     jaxpr = jax.make_jaxpr(fn)(*args)
-    assert not _state_sized_products(jaxpr.jaxpr, pools.state)
+    products = _state_sized_products(jaxpr.jaxpr, pools.state)
+    assert products == [("pallas_call", pools.state.conv.shape)] * (cfg.period.count("mamba") if kernel else 0)
     assert not _pool_sized_products(jaxpr.jaxpr, pools.kv, cfg.attention_layers)
-    # the census sees the pool: every state-space layer of a period updates it, whole
-    updates = [e for e in _equations(jaxpr.jaxpr) if e.primitive.name == "dynamic_update_slice"
-               and e.outvars[0].aval.shape == pools.state.ssm.shape]
-    assert len(updates) == cfg.period.count("mamba")
+    # the census sees the pools: every state-space layer of a period updates each, whole
+    for pool, by_kernel in ((pools.state.ssm, False), (pools.state.conv, kernel)):
+        updates = [e for e in _equations(jaxpr.jaxpr) if e.primitive.name == "dynamic_update_slice"
+                   and e.outvars[0].aval.shape == pool.shape]
+        assert len(updates) == (0 if by_kernel else cfg.period.count("mamba"))
+    kernels = [e for e in _equations(jaxpr.jaxpr) if e.primitive.name == "pallas_call"
+               and "conv_update" in str(e.params["name"])]
+    assert len(kernels) == (cfg.period.count("mamba") if kernel else 0)
+    assert all(dict(e.params["input_output_aliases"]) == {1: 0} for e in kernels)  # the pool, in place
 
     compiled = fn.lower(*args).compile()
     leaves = jax.tree_util.tree_leaves(pools)

@@ -474,6 +474,45 @@ def test_the_traced_tool_counts_a_prefill_s_layer_calls_by_the_group():
     assert abs(float(roofline.split(" ")[0].split("=")[1]) - 100 * 1.649e-3 * 9 / 0.9) < 0.01
 
 
+def test_the_traced_tool_looks_for_the_conv_pool_and_a_layer_s_row_of_it():
+    """``tools/traced_cell.py::pool_copies`` on rows as ``hlo_stats`` gives
+    them: the kernels that alias a pool and a prompt's ``dynamic-update-slice``
+    are its in-place updates; a copy of either pool's whole shape counts (the
+    chip's compiler moving the 57 MB conv pool into its fast memory and back, a
+    period: what ``conv_update`` pins its pool against), and in program
+    ``chain`` so does any instruction of a layer's row of the conv pool, which
+    a prompt's ``step`` makes by right."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location("traced_cell", os.path.join(
+        os.path.dirname(__file__), "..", "..", "..", "tools", "traced_cell.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+
+    def row(name, program, expression, category="fusion"):
+        return {"hlo_op_name": name, "tf_op_name": f"jit({program})/pool_scan/while/body/layer/gdn/gdn_conv/x:",
+                "occurrences": "8.0", "total_self_time": "100.0", "category": category,
+                "hlo_op_expression": f"%{name} = {expression}"}
+
+    sound = [row("conv_update.31", "chain", "(bf16[9,128,24576]{2,1,0}, f32[128,8192]{1,0}) custom-call()", "custom-call"),
+             row("gdn_update.3", "chain", "(f32[9,128,32,128,128]{4,3,2,1,0}, f32[128,32,128]) custom-call()", "custom-call"),
+             row("fusion.7", "step", "bf16[9,128,24576]{2,1,0} fusion(), calls=%dynamic-update-slice.3"),
+             row("fusion.8", "step", "bf16[1,128,24576]{2,1,0} fusion()"),
+             row("while.2", "chain", "(s32[], bf16[9,128,24576]{2,1,0}) while()", "while")]
+    state, conv = r"f32\[9,128,32,128,128\]", (9, 128, 3, 8192)
+    kernels = ("gdn_update", "conv_update")
+    assert list(tool.pool_copies(sound, state, conv, kernels))[-1].startswith("state_pool_copies=0 ")
+    for name, program, expression in (
+            ("copy-start.63", "chain", "(bf16[9,128,24576]{2,1,0:S(1)}, bf16[9,128,24576]{2,1,0}, u32[]) copy-start()"),
+            ("copy.5", "step", "f32[9,128,32,128,128]{4,3,2,1,0} copy()"),
+            ("fusion.9", "chain", "bf16[1,128,24576]{2,1,0} fusion()"),
+            ("select_convert_fusion", "chain", "f32[128,3,8192]{2,1,0} fusion()"),
+            ("copy.17", "chain", "bf16[128,3,8192]{2,0,1} copy()")):
+        lines = list(tool.pool_copies(sound + [row(name, program, expression)], state, conv, kernels))
+        assert lines[-1].startswith("state_pool_copies=1 ") and lines[0].startswith(f"state_pool_copy={name} "), name
+
+
 @pytest.mark.parametrize("published", [TOY, SHARE], ids=["whole", "share"])
 def test_hf_names_there_and_back(published):
     """The key map on a toy checkpoint: our tree under the family's names (the

@@ -513,9 +513,10 @@ def test_granite_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, na
     from deepspeed_tpu.inference import paged
     from deepspeed_tpu.models import CausalLM
     from deepspeed_tpu.ops import registry
-    from deepspeed_tpu.ops.pallas import flash_attention as fa, norms, paged_attention as pa, ssm_update
+    from deepspeed_tpu.ops.pallas import (conv_update, flash_attention as fa, norms, paged_attention as pa,
+                                          ssm_update)
 
-    for module in (pa, fa, norms, ssm_update):
+    for module in (pa, fa, norms, ssm_update, conv_update):
         monkeypatch.setattr(module, "_interpret", lambda: False)
     monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")  # what 'auto' sees there
     cfg = dataclasses.replace(config_from_hf(program.published(harness.load_config("granite-4.0-h-micro"))),
@@ -560,9 +561,23 @@ def test_granite_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, na
     assert mem.temp_size_in_bytes < limit_gb * 1e9, mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2 ** 30
     text = compiled.as_text()
-    kernels = ("paged_attn", "ssm_update") if name == "chain_64" else ("paged_attn",)
+    kernels = ("paged_attn", "ssm_update", "conv_update") if name == "chain_64" else ("paged_attn",)
     for kernel in kernels:  # the one-token recurrence is a kernel of its own name, the pool aliased through it
         assert any("tpu_custom_call" in line and kernel in line for line in text.splitlines()), kernel
+    # a decode step's convolution is one kernel, in place on the conv pool: the chain holds NO instruction of a
+    # layer's row of it, sliced out or as [rows, K - 1, X]; a prompt keeps conv_inputs' lines
+    assert pools.state.conv.shape == (36, 64, 3 * 4352)
+    if name == "chain_64":
+        rows_of_the_tail = [line.strip()[:200] for line in text.splitlines()
+                            if re.search(r"= bf16\[(1,64,13056|64,13056|64,3,4352)\]", line)]
+        assert not rows_of_the_tail, rows_of_the_tail
+        # nor is the pool itself moved: handed to a kernel whole, its 60 MB fit the chip's fast memory, and the
+        # compiler copied it there and back around every period (480 MB a step) until the kernel pinned it in HBM
+        moved = [line.strip()[:200] for line in text.splitlines()
+                 if re.search(r"= \(?bf16\[36,64,13056\]\S* (copy|copy-start|transpose)\(|bf16\[36,64,13056\]\S*S\(1\)", line)]
+        assert not moved, moved
+    else:
+        assert "conv_update" not in text
     # nothing copies or re-lays the state pool, a layer's row of it, or the rows of a row
     state = r"f32\[(36,64|1,64|64),32,128,128\]"
     moved = [line.strip()[:200] for line in text.splitlines()
@@ -595,9 +610,10 @@ def test_qwen3_next_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch,
     from deepspeed_tpu.inference import model, paged
     from deepspeed_tpu.models import CausalLM
     from deepspeed_tpu.ops import registry
-    from deepspeed_tpu.ops.pallas import flash_attention as fa, gdn_update, norms, paged_attention as pa
+    from deepspeed_tpu.ops.pallas import (conv_update, flash_attention as fa, gdn_update, norms,
+                                          paged_attention as pa)
 
-    for module in (pa, fa, norms, gdn_update):
+    for module in (pa, fa, norms, gdn_update, conv_update):
         monkeypatch.setattr(module, "_interpret", lambda: False)
     monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")  # what 'auto' sees there
     monkeypatch.setattr(model, "_grouped_matmul", lambda lhs, rhs, sizes: model._gmm_padded(lhs, rhs, sizes))
@@ -645,8 +661,21 @@ def test_qwen3_next_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2 ** 30
     text = compiled.as_text()
     # (a decode step's 128 rows go through every held expert's product, as the uncut layer's would: no gmm there)
-    kernels = {"paged_attn": 1, "gdn_update": 3} if name == "chain_128" else {"paged_attn": 1, "gmm": 12}
+    kernels = ({"paged_attn": 1, "gdn_update": 3, "conv_update": 3} if name == "chain_128"
+               else {"paged_attn": 1, "gmm": 12})
     assert name != "chain_128" or "gmm" not in text
+    # a decode step's convolution is one kernel, in place on the conv pool: the chain holds NO instruction of a
+    # layer's row of it, sliced out or as [rows, K - 1, X] in either dtype; a prompt keeps conv_inputs' lines
+    if name == "chain_128":
+        rows_of_the_tail = [line.strip()[:200] for line in text.splitlines()
+                            if re.search(r"= (bf16|f32)\[(1,128,24576|128,24576|128,3,8192)\]", line)]
+        assert not rows_of_the_tail, rows_of_the_tail
+        # nor is the pool itself moved into the chip's fast memory and back (57 MB: it would fit)
+        moved = [line.strip()[:200] for line in text.splitlines()
+                 if re.search(r"= \(?bf16\[9,128,24576\]\S* (copy|copy-start|transpose)\(|bf16\[9,128,24576\]\S*S\(1\)", line)]
+        assert not moved, moved
+    else:
+        assert "conv_update" not in text
     for kernel, least in kernels.items():  # the one-token rule is a kernel of its own name, the pool aliased through it
         assert sum("tpu_custom_call" in line and kernel in line for line in text.splitlines()) >= least, kernel
     # nothing copies or re-lays the state pool, a layer's row of it, or the rows of a row

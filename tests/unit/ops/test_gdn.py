@@ -270,3 +270,75 @@ def test_the_bench_tool_runs_both_forms_at_a_toy_shape(monkeypatch):
     for impl in ("xla", "pallas"):
         line = tool.measure(impl)
         assert line["impl"] == impl and line["own_bytes"] == 2 * 3 * 2 * 128 * 128 * 4 and line["ms_a_call"] > 0
+
+
+# --- a decode step's convolution, in place on the conv pool ---------------------
+
+def _conv_pool(rows, layers=2, slots=6, seed=12):
+    pool = jax.random.normal(jax.random.PRNGKey(seed), (layers, slots, (SIZES.d_conv - 1) * SIZES.conv_dim))
+    return pool.astype(jnp.bfloat16)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_a_decode_step_s_convolution_on_the_pool_is_the_mixer_s_on_an_array(impl, monkeypatch):
+    """``mix`` at one token a row with the tail as a row of the conv pool
+    (float32 inputs, no bias: the DeltaNet mixer's; ``conv_update`` in interpret
+    mode, and XLA's form) against ``mix`` on the rows' tails as an array: the
+    mixer's output to float32 rounding, the new tail to the bit, a dead row's
+    and the other layer's and slots' untouched."""
+    import functools
+
+    from deepspeed_tpu.ops import ssm
+
+    monkeypatch.setattr(ssm, "conv_pool_step", functools.partial(ssm.conv_pool_step, impl=impl))
+    hidden, p = _leaves()
+    rows, K, X = 4, SIZES.d_conv, SIZES.conv_dim
+    u = jax.random.normal(jax.random.PRNGKey(13), (rows, 1, hidden))
+    state = jax.random.normal(jax.random.PRNGKey(14), (rows, HV, DK, DV))
+    pool = _conv_pool(rows)
+    lens, fresh = jnp.asarray([1, 0, 1, 1]), jnp.asarray([False, False, True, False])
+    y, left, pool_after = gdn.mix(u @ p["w_qkvz"], u @ p["w_ba"], p, SIZES, 1e-6, state=state,
+                                  tail=gdn.PoolRow(pool, jnp.int32(1), fresh), new_lens=lens)
+    came = jnp.where(fresh[:, None], 0, pool[1, :rows]).reshape(rows, K - 1, X)
+    want_y, want_left, want_tail = gdn.mix(u @ p["w_qkvz"], u @ p["w_ba"], p, SIZES, 1e-6, state=state, tail=came,
+                                           new_lens=lens)
+    close(y, np.asarray(want_y, np.float64), tol=2e-6)
+    close(left, np.asarray(want_left, np.float64), tol=2e-6)
+    assert pool_after.dtype == jnp.bfloat16 and pool_after.shape == pool.shape
+    bits = lambda a: np.asarray(jax.lax.bitcast_convert_type(a, jnp.uint16))  # noqa: E731
+    assert np.array_equal(bits(pool_after[1, :rows]), bits(want_tail.astype(jnp.bfloat16).reshape(rows, -1)))
+    untouched = np.ones(pool.shape[:2], bool)
+    untouched[1, [0, 2, 3]] = False
+    assert np.array_equal(bits(pool_after)[untouched], bits(pool)[untouched])  # the dead row's too
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_a_prompt_then_single_steps_on_the_pools_give_what_one_longer_prompt_gives(impl, monkeypatch):
+    """Both pools' rows through ``mix``: a prompt of 9 tokens (``T > 1``: the
+    tail's slice and its write back are ``mix``'s now), then 4 single steps in
+    place, against all 13 tokens at once. The inputs are ones bfloat16 holds
+    exactly, so that what the conv pool keeps of them is what the longer prompt
+    reads."""
+    import functools
+
+    from deepspeed_tpu.ops import ssm
+
+    monkeypatch.setattr(ssm, "conv_pool_step", functools.partial(ssm.conv_pool_step, impl=impl))
+    hidden, p = _leaves()
+    rows, T, cut = 2, 13, 9
+    keys = jax.random.split(jax.random.PRNGKey(15), 2)
+    qkvz = jax.random.normal(keys[0], (rows, T, SIZES.proj_dim)).astype(jnp.bfloat16).astype(jnp.float32)
+    ba = jax.random.normal(keys[1], (rows, T, 2 * HV))
+    states, tails = jnp.ones((2, 3, HV, DK, DV)), _conv_pool(rows, slots=3)
+    fresh, ys = jnp.ones((rows,), bool), []
+    for lo, hi in [(0, cut)] + [(t, t + 1) for t in range(cut, T)]:
+        y, states, tails = gdn.mix(qkvz[:, lo:hi], ba[:, lo:hi], p, SIZES, 1e-6,
+                                   state=gdn.PoolRow(states, jnp.int32(0), fresh),
+                                   tail=gdn.PoolRow(tails, jnp.int32(0), fresh))
+        ys.append(y)
+        fresh = jnp.zeros((rows,), bool)
+    want_y, want_state, want_tail = gdn.mix(qkvz, ba, p, SIZES, 1e-6)
+    close(jnp.concatenate(ys, axis=1), np.asarray(want_y, np.float64), tol=1e-5)
+    close(states[0, :rows], np.asarray(want_state, np.float64), tol=1e-5)
+    assert np.array_equal(np.asarray(tails[0, :rows], np.float32), np.asarray(want_tail.reshape(rows, -1)))
+    assert np.array_equal(np.asarray(states[1]), np.ones((3, HV, DK, DV))) and tails.dtype == jnp.bfloat16

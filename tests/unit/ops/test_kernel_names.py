@@ -40,6 +40,26 @@ def _paged(q):
                               jnp.zeros((2, 1), jnp.int32), 8)
 
 
+def _conv(x):
+    from deepspeed_tpu.ops.pallas.conv_update import conv_pool_step
+
+    return conv_pool_step(jnp.zeros((2, 8, 3 * 128), jnp.bfloat16), 1, x, jnp.ones((4, 128), jnp.bfloat16))[0]
+
+
+def _ssm(x):
+    from deepspeed_tpu.ops.pallas.ssm_update import ssm_pool_step
+
+    return ssm_pool_step(jnp.zeros((2, 2, 1, 128, 128)), 1, x, jnp.ones((2, 2)), jnp.zeros((2,)),
+                         jnp.ones((2, 1, 128)), jnp.ones((2, 1, 128)), jnp.ones((2,)))[0]
+
+
+def _gdn(v):
+    from deepspeed_tpu.ops.pallas.gdn_update import gdn_pool_step
+
+    qk = jnp.ones((2, 1, 128))
+    return gdn_pool_step(jnp.zeros((2, 2, 1, 128, 128)), 1, qk, qk, v, -jnp.ones((2, 1)), jnp.ones((2, 1)))[0]
+
+
 KERNELS = [
     ("flash_fwd", _flash, (QKV,)),
     ("flash_bwd_dq", jax.grad(_flash), (LONG,)),
@@ -54,6 +74,9 @@ KERNELS = [
     ("quantize_int8", lambda x: pallas_quantize_int8(x, block_size=64), (jnp.ones((256,)),)),
     ("dequantize_int8", lambda v: pallas_dequantize_int8(v, jnp.ones((4,)), (256,), block_size=64),
      (jnp.ones((256,), jnp.int8),)),
+    ("conv_update", _conv, (jnp.ones((8, 128), jnp.bfloat16),)),
+    ("ssm_update", _ssm, (jnp.ones((2, 2, 64)),)),
+    ("gdn_update", _gdn, (jnp.ones((2, 1, 128)),)),
 ]
 
 

@@ -238,3 +238,175 @@ def test_a_prompt_s_states_go_into_and_out_of_the_pool_through_the_kernels():
     assert np.array_equal(np.asarray(got), np.asarray(want))
     back = ssm_update.rows_out(got, jnp.int32(1), rows).reshape(rows, heads, width, N)
     assert np.array_equal(np.asarray(back), np.asarray(states))
+
+
+# --- a decode step's convolution, in place on the conv pool ---------------------
+
+CONV_CASES = [  # the inputs' dtype, a bias, the projection's width, the inputs' first column
+    pytest.param(jnp.bfloat16, True, 704, 128, id="bf16_bias_inside_a_projection"),  # a state-space mixer's
+    pytest.param(jnp.float32, False, 384, 0, id="f32_no_bias_a_projection_s_head"),  # a DeltaNet mixer's
+    pytest.param(jnp.float32, True, 512, 256, id="f32_bias_a_block_of_its_own"),
+    pytest.param(jnp.bfloat16, False, 256, 0, id="bf16_no_bias_alone"),
+]
+
+
+def _conv_pool(rows, dtype, bias, W, X=256, K=4, layers=3, slots=12, seed=20):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return dict(pool=jax.random.normal(keys[0], (layers, slots, (K - 1) * X)).astype(jnp.bfloat16),
+                x=jax.random.normal(keys[1], (rows, W)).astype(dtype),
+                taps=(0.5 * jax.random.normal(keys[2], (K, X))).astype(jnp.bfloat16),
+                bias=(0.2 * jax.random.normal(keys[3], (X,))).astype(jnp.bfloat16) if bias else None)
+
+
+def _bits(a):
+    return np.asarray(jax.lax.bitcast_convert_type(a, jnp.uint16 if a.dtype == jnp.bfloat16 else jnp.uint32))
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("dtype, bias, W, at", CONV_CASES)
+def test_the_conv_pool_step_is_conv_inputs_at_one_token_on_the_layer_s_first_rows(impl, dtype, bias, W, at):
+    """``ops/pallas/conv_update.py`` in interpret mode, and XLA's form beside
+    it: eight rows of a pool of twelve slots and three layers (live, dead, one
+    that starts a sequence over another's tail), ``K = 4``, against
+    ``conv_inputs`` on the rows' tails as arrays: the outputs to float32
+    rounding, the new tail to the bit, a dead row's tail and every other
+    layer's and slot's untouched to the bit."""
+    X, K, rows = 256, 4, 8
+    a = _conv_pool(rows, dtype, bias, W)
+    live = jnp.asarray([True, False, True, True, False, True, True, True])
+    fresh = jnp.asarray([False, False, True, False, False, False, True, False])
+    y, pool = ssm.conv_pool_step(a["pool"], jnp.int32(1), a["x"], a["taps"], a["bias"], live, fresh, at=at, impl=impl)
+    came = jnp.where(fresh[:, None], 0, a["pool"][1, :rows]).reshape(rows, K - 1, X)
+    want_y, want_tail = ssm.conv_inputs(a["x"][:, None, at:at + X], came, a["taps"], a["bias"],
+                                        live.astype(jnp.int32))
+    assert y.shape == (rows, X) and y.dtype == dtype and pool.dtype == jnp.bfloat16
+    close(y, np.asarray(want_y[:, 0], np.float64), tol=1e-6 if dtype == jnp.float32 else 1e-2)
+    assert np.array_equal(_bits(pool[1, :rows]), _bits(want_tail.astype(jnp.bfloat16).reshape(rows, -1)))
+    # a live row's tail moved on by one input, which is the token's own, rounded as the pool keeps it
+    assert np.array_equal(_bits(pool[1, 0]), _bits(jnp.concatenate(
+        [a["pool"][1, 0, X:], a["x"][0, at:at + X].astype(jnp.bfloat16)])))
+    untouched = np.ones(a["pool"].shape[:2], bool)
+    untouched[1, np.flatnonzero(np.asarray(live))] = False
+    assert np.array_equal(_bits(pool)[untouched], _bits(a["pool"])[untouched])  # the dead rows' too
+
+
+def test_the_conv_kernel_over_several_blocks_of_rows_is_xla_s_form_to_the_bit():
+    """Twenty-four rows are three grid steps of eight: one all live, one with
+    dead rows, one with rows that start a sequence."""
+    rows = 24
+    a = _conv_pool(rows, jnp.bfloat16, True, 256, slots=32, seed=27)
+    live = jnp.ones((rows,), bool).at[jnp.asarray([9, 12])].set(False)
+    fresh = jnp.zeros((rows,), bool).at[jnp.asarray([17, 23])].set(True)
+    got = ssm.conv_pool_step(a["pool"], jnp.int32(2), a["x"], a["taps"], a["bias"], live, fresh, impl="pallas")
+    want = ssm.conv_pool_step(a["pool"], jnp.int32(2), a["x"], a["taps"], a["bias"], live, fresh, impl="xla")
+    for g, w in zip(got, want):
+        assert np.array_equal(_bits(g), _bits(w))
+    assert np.array_equal(_bits(got[1][2, 9]), _bits(a["pool"][2, 9]))
+    assert not np.any(np.asarray(got[1][2, 17, :512], np.float32))
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_a_fresh_row_s_convolution_starts_from_zeros_whatever_its_slot_held(impl):
+    X = 256
+    a = _conv_pool(8, jnp.float32, True, X, seed=21)
+    fresh = jnp.arange(8) % 2 == 0
+    y, pool = ssm.conv_pool_step(a["pool"], jnp.int32(2), a["x"], a["taps"], a["bias"], None, fresh, impl=impl)
+    want_y, want_tail = ssm.conv_inputs(a["x"][:, None], None, a["taps"], a["bias"])  # no tail: zeros
+    close(y[::2], np.asarray(want_y[::2, 0], np.float64), tol=1e-6)
+    assert np.array_equal(_bits(pool[2, :8:2]), _bits(want_tail[::2].astype(jnp.bfloat16).reshape(4, -1)))
+    assert not np.any(np.asarray(pool[2, :8:2, :2 * X], np.float32))  # [0, 0, x]
+    assert np.abs(np.asarray(y[1::2]) - np.asarray(want_y[1::2, 0])).max() > 1e-3  # the others from their own tails
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_a_prompt_s_tail_handed_to_single_steps_gives_what_one_longer_prompt_gives(impl):
+    """A prompt of 11 tokens through ``conv_inputs`` with a row of the pool as
+    its tail (``T > 1``: the slice and the update around today's lines), then
+    8 single steps in place on that row, against all 19 tokens at once: row 1
+    a prompt of 7 padded to 11, whose tail ends at its seventh token."""
+    X, K, rows, T, cut, short = 256, 4, 8, 19, 11, 7
+    a = _conv_pool(rows, jnp.float32, True, X, seed=22)
+    # (inputs that bfloat16 holds exactly: what the pool keeps of them is then what the longer prompt reads)
+    xs = jax.random.normal(jax.random.PRNGKey(23), (rows, T, X)).astype(jnp.bfloat16).astype(jnp.float32)
+    lens = jnp.full((rows,), cut).at[1].set(short)
+    prompt = xs[:, :cut].at[1, short:].set(9.0)  # row 1's pad tokens, whatever they hold
+    steps = xs[:, cut:].at[1].set(xs[1, short:short + T - cut])  # its tokens 7.. come as steps
+    y0, pool = ssm.conv_inputs(prompt, ssm.PoolRow(a["pool"], jnp.int32(0), jnp.ones((rows,), bool)), a["taps"],
+                               a["bias"], lens)
+    ys = []
+    for t in range(T - cut):
+        y, pool = ssm.conv_pool_step(pool, jnp.int32(0), steps[:, t], a["taps"], a["bias"], impl=impl)
+        ys.append(y[:, None])
+    ys = jnp.concatenate(ys, axis=1)
+    want, _ = ssm.conv_inputs(xs, None, a["taps"], a["bias"])
+    close(y0[0], np.asarray(want[0, :cut], np.float64), tol=1e-6)
+    close(y0[1, :short], np.asarray(want[1, :short], np.float64), tol=1e-6)
+    close(ys[0], np.asarray(want[0, cut:], np.float64), tol=1e-6)
+    close(ys[1], np.asarray(want[1, short:short + T - cut], np.float64), tol=1e-6)
+    assert np.array_equal(_bits(pool[0, :rows]), _bits(steps[:, -(K - 1):].astype(jnp.bfloat16).reshape(rows, -1)))
+    assert np.array_equal(_bits(pool[1:]), _bits(a["pool"][1:]))
+
+
+def test_the_mixer_s_tail_as_a_row_of_the_pool_is_its_tail_as_an_array():
+    """``mix`` with both the state and the tail as rows of their pools (what
+    ``inference/paged.py::state_layer`` hands it): a prompt, then two single
+    steps, against ``mix`` on arrays."""
+    leaves = _mixer_leaves()
+    rows, slots = 2, 3
+    fed = jax.random.normal(jax.random.PRNGKey(24), (rows, 7, SIZES.proj_dim))
+    fresh = jnp.ones((rows,), bool)
+    states = jnp.zeros((2, slots, SIZES.d_inner // ssm.pool_tile(SIZES.d_inner), N, ssm.pool_tile(SIZES.d_inner)))
+    tails = jax.random.normal(jax.random.PRNGKey(25), (2, slots, 3 * SIZES.conv_dim))  # float32: no rounding here
+    state = tail = None
+    for lo, hi in ((0, 5), (5, 6), (6, 7)):
+        y, states, tails = ssm.mix(fed[:, lo:hi], leaves, SIZES, 1e-5, state=ssm.PoolRow(states, jnp.int32(1), fresh),
+                                   tail=ssm.PoolRow(tails, jnp.int32(1), fresh))
+        want_y, state, tail = ssm.mix(fed[:, lo:hi], leaves, SIZES, 1e-5, state=state, tail=tail)
+        close(y, np.asarray(want_y, np.float64), tol=1e-6)
+        assert np.array_equal(np.asarray(tails[1, :rows]), np.asarray(tail.reshape(rows, -1)))
+        fresh = jnp.zeros((rows,), bool)
+    assert not np.any(np.asarray(tails[1, rows:] == 0)) and tails.shape == (2, slots, 3 * SIZES.conv_dim)
+
+
+def test_the_conv_kernel_takes_whole_lane_tiles_only():
+    from deepspeed_tpu.ops.pallas import conv_update
+
+    assert conv_update.takes(4352, 64, 4096) and conv_update.takes(8192, 128) and conv_update.takes(4352, 8, 4096)
+    assert not conv_update.takes(SIZES.conv_dim, 8) and not conv_update.takes(4352, 64, 4000)
+    assert not conv_update.takes(4352, 3, 4096)
+
+
+def test_auto_takes_xla_s_convolution_off_the_tpu():
+    a = _conv_pool(8, jnp.bfloat16, True, 256, seed=26)
+    got = ssm.conv_pool_step(a["pool"], jnp.int32(0), a["x"], a["taps"], a["bias"])
+    want = ssm.conv_pool_step(a["pool"], jnp.int32(0), a["x"], a["taps"], a["bias"], impl="xla")
+    for g, w in zip(got, want):
+        assert np.array_equal(_bits(g), _bits(w))
+    jaxpr = str(jax.make_jaxpr(lambda *args: ssm.conv_pool_step(*args))(
+        a["pool"], jnp.int32(0), a["x"], a["taps"], a["bias"]))
+    assert "pallas_call" not in jaxpr
+
+
+def test_the_conv_bench_tool_runs_both_forms_at_a_toy_shape(monkeypatch):
+    """``tools/conv_update_bench.py::measure`` (a time comes only from a chip:
+    here it is run for its shapes and its bytes alone)."""
+    import importlib.util
+    import os
+
+    from benchmarks.lib import peaks
+
+    spec = importlib.util.spec_from_file_location("conv_update_bench", os.path.join(
+        os.path.dirname(__file__), "..", "..", "..", "tools", "conv_update_bench.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    assert tool.SHAPES["granite"][:5] == (36, 64, 4352, 8512, 4096) and tool.SHAPES["qwen3_next"][:5] == (
+        9, 128, 8192, 12288, 0)
+    monkeypatch.setattr(tool, "SHAPES", {"toy": (2, 8, 256, 640, 128, "bfloat16", True),
+                                         "toy_f32": (2, 8, 256, 384, 0, "float32", False)})
+    for name, value in dict(UNROLLED=3, TURNS=2, REPEATS=1).items():
+        monkeypatch.setattr(tool, name, value)
+    monkeypatch.setitem(peaks.DEVICE_PEAKS, jax.devices()[0].device_kind, peaks.DEVICE_PEAKS["TPU v5 lite"])
+    for shape, own in (("toy", 8 * (2 * 3 * 256 * 2 + 2 * 256 * 2)), ("toy_f32", 8 * (2 * 3 * 256 * 2 + 2 * 256 * 4))):
+        for impl, dead_rows in (("xla", False), ("pallas", False), ("pallas", True)):
+            line = tool.measure(shape, impl, dead_rows)
+            assert line["impl"] == impl and line["own_bytes"] == own and line["ms_a_call"] > 0

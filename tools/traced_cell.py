@@ -31,8 +31,10 @@
   under ``ssm`` in each program, the chunked scan's share of its roofline in the
   prefills that the window happens to hold (``ssd_scan_roofline=``: no listed
   metric, because a window may hold none), and a ``state_pool_copy=`` line for
-  every instruction that makes an array of the state pool's whole shape and is
-  not its in-place update (there has to be none).
+  every instruction that makes an array of the state pool's whole shape, or of
+  the conv pool's, and is not its in-place update, and for every instruction of
+  program ``chain`` that makes a layer's row of the conv pool (``pool_copies``:
+  there has to be none).
 
 - in a cell with Gated DeltaNet layers (an architecture file with
   ``gdn_chunk_cost``), the same of the scopes under ``gdn``: a ``gdn_part=``
@@ -79,6 +81,33 @@ def moe_halves(rows):
                    f"count={r['occurrences']} op_name={r['tf_op_name']} expression={r['hlo_op_expression'][:300]}")
 
 
+def pool_copies(rows, state, conv, kernels):
+    """The ``state_pool_copy=`` lines and their count: every instruction that
+    makes an array of the state pool's whole shape ``state`` or of the conv
+    pool's ``conv`` = (layers, slots, K - 1, X) and is not its in-place update
+    (a ``dynamic-update-slice``, or one of ``kernels`` by its name), and in
+    program ``chain`` every instruction of a layer's ROW of the conv pool,
+    sliced out or as ``[rows, K - 1, X]``: a decode step's convolution is the
+    kernel ``conv_update`` on the pool itself, so there has to be none (a
+    prompt slices its rows' tails out and writes them back)."""
+    import re
+
+    layers, slots, taps, X = conv
+    whole = r"(?:%s|bf16\[%d,%d,%d\])" % (state, layers, slots, taps * X)
+    row = r"(?:bf16|f32)\[(?:1,%d,%d|%d,%d|%d,%d,%d)\]" % (slots, taps * X, slots, taps * X, slots, taps, X)
+    copies = 0
+    for r in rows:
+        made = re.match(r"\s*%?[\w.\-]+ = (?:\()?" + whole, r["hlo_op_expression"])
+        in_place = "dynamic-update-slice" in r["hlo_op_expression"] or r["hlo_op_name"].startswith(kernels)
+        in_chain = r["tf_op_name"].startswith("jit(chain)")
+        of_a_row = in_chain and re.match(r"\s*%?[\w.\-]+ = (?:\()?" + row, r["hlo_op_expression"])
+        if (made and not in_place or of_a_row) and r["category"] != "while":
+            copies += 1
+            yield (f"state_pool_copy={r['hlo_op_name']} category={r['category']} device_s="
+                   f"{1e-6 * float(r['total_self_time'])} count={r['occurrences']} expression={r['hlo_op_expression'][:300]}")
+    yield f"state_pool_copies={copies} of_shape={whole} or_in_chain={row}"
+
+
 SSM_PARTS = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_update", "ssm_norm", "ssm_out_proj")
 
 
@@ -123,15 +152,9 @@ def ssm_parts(rows, workload_name):
                    f"count={r['occurrences']} op_name={r['tf_op_name']} expression={r['hlo_op_expression'][:400]}")
     channels = cfg["mamba_n_heads"] * cfg["mamba_d_head"]  # the pool keeps 128 of them a tile, on the lanes
     whole = r"f32\[%d,%d,%d,%d,128\]" % (arch.ssm_layers(cfg), rows_, channels // 128, cfg["mamba_d_state"])
-    copies = 0
-    for r in rows:
-        made = re.match(r"\s*%?[\w.\-]+ = (?:\()?" + whole, r["hlo_op_expression"])
-        in_place = "dynamic-update-slice" in r["hlo_op_expression"] or r["hlo_op_name"].startswith(("ssm_update", "ssm_rows_in"))
-        if made and not in_place and r["category"] != "while":
-            copies += 1
-            yield (f"state_pool_copy={r['hlo_op_name']} category={r['category']} device_s="
-                   f"{1e-6 * float(r['total_self_time'])} count={r['occurrences']} expression={r['hlo_op_expression'][:300]}")
-    yield f"state_pool_copies={copies} of_shape={whole}"
+    sizes = program.model_config(config, None).ssm
+    yield from pool_copies(rows, whole, (arch.ssm_layers(cfg), rows_, sizes.d_conv - 1, sizes.conv_dim),
+                           ("ssm_update", "ssm_rows_in", "conv_update"))
 
 
 GDN_PARTS = ("gdn_in_proj", "gdn_ba_proj", "gdn_conv", "gdn_chunk", "gdn_update", "gdn_norm", "gdn_out_proj")
@@ -183,15 +206,8 @@ def gdn_parts(rows, workload_name):
             yield (f"gdn_op={r['hlo_op_name']} program={prog} device_s={1e-6 * float(r['total_self_time'])} "
                    f"count={r['occurrences']} op_name={r['tf_op_name']} expression={r['hlo_op_expression'][:400]}")
     whole = r"f32\[%d,%d,%d,%d,%d\]" % (arch.gdn_layers(cfg), rows_, Hv, Dk, Dv)  # values on the lanes
-    copies = 0
-    for r in rows:
-        made = re.match(r"\s*%?[\w.\-]+ = (?:\()?" + whole, r["hlo_op_expression"])
-        in_place = "dynamic-update-slice" in r["hlo_op_expression"] or r["hlo_op_name"].startswith("gdn_update")
-        if made and not in_place and r["category"] != "while":
-            copies += 1
-            yield (f"state_pool_copy={r['hlo_op_name']} category={r['category']} device_s="
-                   f"{1e-6 * float(r['total_self_time'])} count={r['occurrences']} expression={r['hlo_op_expression'][:300]}")
-    yield f"state_pool_copies={copies} of_shape={whole}"
+    yield from pool_copies(rows, whole, (arch.gdn_layers(cfg), rows_, gdn_sizes.d_conv - 1, gdn_sizes.conv_dim),
+                           ("gdn_update", "conv_update"))
 
 
 def main(argv=None) -> int:
