@@ -372,6 +372,7 @@ class InferenceEngineV2:
         self._routed = model_config.num_experts > 0
         self.picks_log: Optional[List[Dict[str, Any]]] = None
         self.last_experts_touched: Optional[float] = None
+        self.last_experts_read: Optional[float] = None  # what the decode product read: dead rows' picks too
         self.last_held_visits: Optional[float] = None  # of a chip's share: visits a step to its experts, a layer
         if model_config.expert_parallel is not None and mesh.shape.get("ep", 1) > 1:
             raise ValueError(
@@ -1412,13 +1413,14 @@ class InferenceEngineV2:
             out = np.asarray(flight.out)[flight.at]
             emitted = np.asarray(flight.emitted)[flight.at]
             if routed:
-                # [K, routed layers] beside the tokens: the steps some row was
-                # live at read that many distinct experts a layer, on average
+                # [K, routed layers, 3] beside the tokens: at the steps some row was live at, the distinct
+                # experts a layer the live rows picked, the visits those got, and the distinct experts the
+                # decode product read (every row's picks, dead or alive), each on average
                 live_steps = max(int(emitted.max(initial=0)), 1)
-                touched = np.asarray(routed[0])[:live_steps]
-                if touched.ndim == 3:  # a chip's share: [.., (held experts read, visits to them)]
-                    touched, self.last_held_visits = touched[..., 0], float(touched[..., 1].mean())
-                self.last_experts_touched = float(touched.mean())
+                touched, visits, read = np.asarray(routed[0])[:live_steps].reshape(-1, 3).mean(axis=0)
+                self.last_experts_touched, self.last_experts_read = float(touched), float(read)
+                if self.model_config.expert_parallel is not None:
+                    self.last_held_visits = float(visits)
         self.host_sync_count += 1
         self._log_picks(routed, uids, rids, flight=flight, emitted=emitted)
         # a row moved already stands k further; one an EOS ended goes back
@@ -1872,7 +1874,7 @@ class InferenceEngineV2:
                     uids, last, budgets, k, rng, eos_id=eos_token_id,
                     sample_kw=sample_kw, tracker=tracker, rids=chain_rids, ahead=ask)
             n_emitted = int(emitted.sum())
-            routed_args = ({"experts_touched": self.last_experts_touched}
+            routed_args = ({"experts_touched": self.last_experts_touched, "experts_read": self.last_experts_read}
                            if self._routed and n_spec == 0 else {})
             if routed_args and self.last_held_visits is not None:
                 routed_args["held_visits"] = self.last_held_visits

@@ -35,6 +35,7 @@ from deepspeed_tpu.models.transformer import (
     act_fn,
     reading,
 )
+from deepspeed_tpu.ops.registry import register
 
 
 class KVCache(NamedTuple):
@@ -153,11 +154,17 @@ def _moe_with_picks(lp, cfg: TransformerConfig, x):
 
     Two dispatch regimes, chosen by the (static) token count:
 
-    - decode (few tokens): compute every expert and combine with the gate
-      weights — one einsum over the stacked expert params (reference
-      ``moe/sharded_moe.py`` combine). At T ~ batch size, gathering by
-      expert costs more than the E/top_k extra FLOPs it saves, and the
-      weights of nearly every expert are read either way.
+    - decode (few tokens): every row through the experts SOME row picked,
+      combined by the gate weights (zero where a row did not pick). A step's
+      time is the experts' bytes, and the rows pick fewer experts than there
+      are (128 rows of the benchmark's share touch 33 of 64, 64 rows of top-4
+      touch 56, 8 rows about 25): on the TPU, where the layer scan hands the
+      stacked weights (:class:`ExpertStack`), the kernel ``moe_decode`` reads
+      each touched expert once from the stack where it lies
+      (``ops/pallas/moe_decode.py``); elsewhere one einsum over all the
+      stacked expert params (reference ``moe/sharded_moe.py`` combine).
+      Gathering ROWS by expert would save products the step does not wait
+      for.
     - prefill (T >= 2E tokens): RAGGED dispatch (round 5; reference FastGen's
       ``inference/v2/kernels/ragged_ops`` moe_gather/moe_scatter +
       ``cutlass_ops`` grouped GEMM) — sort the (token, expert) pairs by
@@ -194,10 +201,30 @@ def _moe_with_picks(lp, cfg: TransformerConfig, x):
     return out.reshape(B, S, M), top_i
 
 
+class ExpertStack(NamedTuple):
+    """A routed layer's experts as row ``index`` of the scanned layers'
+    STACKED leaves (``w_up`` and ``w_gate`` ``[layers, E, M, H]``, ``w_down``
+    ``[layers, E, H, M]``), where a layer scan closes over them
+    (``inference/paged.py::_forward_hidden``): the decode product's kernel reads
+    the picked experts from the stack where it lies, and a custom call handed a
+    layer's slice would have the slice copied for it. Every other path takes
+    :meth:`layer`, which XLA fuses into what reads it as it does a scan's own
+    slice."""
+
+    stack: Any
+    index: jax.Array
+
+    def layer(self):
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, self.index, 0, keepdims=False), self.stack)
+
+
 def _experts(ep, cfg: TransformerConfig, tokens, top_p, top_i):
     """The picked experts' weighted sum for ``tokens`` [T, M]: of a chip's
-    share (``cfg.expert_parallel``) the terms of the experts held here."""
+    share (``cfg.expert_parallel``) the terms of the experts held here.
+    ``ep``: the layer's experts, or an :class:`ExpertStack`."""
     T, E = tokens.shape[0], cfg.num_experts
+    stack, ep = (ep, ep.layer()) if isinstance(ep, ExpertStack) else (None, ep)
     if cfg.expert_parallel is not None:
         # by the held experts' own numbers 0..E-1; a pick that lives on another
         # chip becomes E, which no group, one-hot or scatter takes, at weight 0
@@ -215,7 +242,7 @@ def _experts(ep, cfg: TransformerConfig, tokens, top_p, top_i):
             grouped = tuple(a.reshape((n, T // n) + a.shape[1:]) for a in (tokens, top_p, top_i, held))
             return jax.lax.map(lambda g: _moe_ragged(cfg, ep, *g), grouped).reshape(tokens.shape)
         gate = jnp.zeros((T, E), jnp.float32).at[jnp.arange(T)[:, None], top_i].set(top_p, mode="drop")
-        return _all_experts(ep, cfg, tokens, gate)
+        return _decode_experts(stack, ep, cfg, tokens, gate)
     if _moe_ep_size() > 1:
         # expert-parallel serving (ISSUE 15): the ep-sharded experts are
         # reached through the explicit collective dispatch — the SAME
@@ -229,18 +256,43 @@ def _experts(ep, cfg: TransformerConfig, tokens, top_p, top_i):
         return _moe_ragged(cfg, ep, tokens, top_p, top_i)
 
     gate = jnp.zeros((T, E), jnp.float32).at[jnp.arange(T)[:, None], top_i].set(top_p)
-    return _all_experts(ep, cfg, tokens, gate)
+    return _decode_experts(stack, ep, cfg, tokens, gate)
 
 
-def _all_experts(ep, cfg: TransformerConfig, tokens, gate):
-    """Every expert for every token, combined by ``gate`` [T, E]."""
-    h1 = jnp.einsum("tm,emh->teh", tokens, ep["w_up"].astype(cfg.dtype))
-    if cfg.activation == "silu_glu":
-        h1 = jax.nn.silu(jnp.einsum("tm,emh->teh", tokens, ep["w_gate"].astype(cfg.dtype))) * h1
+def _decode_experts(stack: Optional[ExpertStack], ep, cfg: TransformerConfig, tokens, gate):
+    """The decode regime's product, ``gate`` [T, E] float32 zero where a row
+    did not pick: on the TPU, where the scan handed the stack and the kernel
+    takes the shapes, over the experts some row picked, each read once from the
+    stack where it lies (``ops/pallas/moe_decode.py``); else over every expert."""
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import moe_decode  # (registers the kernel)
+
+    glu = cfg.activation == "silu_glu"
+    if stack is not None and registry._default_backend() == "tpu":
+        w = stack.stack
+        (M, H), tokens = w["w_up"].shape[2:], tokens.astype(cfg.dtype)
+        # (a quantized leaf has no dtype of its own: it is dequantized a layer's slice at a time, below)
+        if moe_decode.takes(tokens.shape[0], M, H, tokens.dtype, getattr(w["w_up"], "dtype", None)):
+            return registry.dispatch("moe_decode", "pallas")(
+                tokens, gate, w["w_gate"] if glu else None, w["w_up"], w["w_down"], stack.index, cfg.activation)
+    return _all_experts(tokens, gate, ep["w_gate"].astype(cfg.dtype) if glu else None,
+                        ep["w_up"].astype(cfg.dtype), ep["w_down"].astype(cfg.dtype), None, cfg.activation)
+
+
+@register("moe_decode", "xla")
+def _all_experts(tokens, gate, w_gate, w_up, w_down, layer, activation: str):
+    """Every expert for every token, combined by ``gate`` [T, E]: ``w_up``
+    (and ``w_gate``, or None) ``[E, M, H]`` and ``w_down`` ``[E, H, M]``, or
+    row ``layer`` of them stacked."""
+    if layer is not None:
+        w_gate, w_up, w_down = (None if w is None else w[layer] for w in (w_gate, w_up, w_down))
+    h1 = jnp.einsum("tm,emh->teh", tokens, w_up)
+    if w_gate is not None:
+        h1 = jax.nn.silu(jnp.einsum("tm,emh->teh", tokens, w_gate)) * h1
     else:
-        h1 = act_fn(cfg.activation)(h1)
-    out_e = jnp.einsum("teh,ehm->tem", h1, ep["w_down"].astype(cfg.dtype))
-    return jnp.einsum("te,tem->tm", gate.astype(cfg.dtype), out_e)
+        h1 = act_fn(activation)(h1)
+    out_e = jnp.einsum("teh,ehm->tem", h1, w_down)
+    return jnp.einsum("te,tem->tm", gate.astype(w_down.dtype), out_e)
 
 
 # The no-drop collective dispatch materializes [T*k, E, T*k] routing

@@ -33,7 +33,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.model import (_apply_norm, _attn_out, _dense, _logits, _mlp,
+from deepspeed_tpu.inference.model import (ExpertStack, _apply_norm, _attn_out, _dense, _logits, _mlp,
                                            _moe_with_picks, _qkv)
 from deepspeed_tpu.inference.sampling import greedy_tokens, sample_logits
 from deepspeed_tpu.models.transformer import TransformerConfig, _norm_at, _times, reading
@@ -743,16 +743,29 @@ def _forward_hidden(
         out, picks = ffn(lp, _norm_at(lp, "mlp_norm", cfg, x), False)
         return (x + _times(cfg.residual_multiplier, out), *kv, sp, cp), picks
 
+    # The scanned stack WITHOUT its routed experts' leaves, which the body
+    # closes over whole and names by the scan's index (``ExpertStack``): the
+    # decode product's kernel reads the picked experts where they lie, and a
+    # custom call on the scan's slice would have the slice copied for it.
+    if cfg.layer_types is not None:
+        split = {key: _split_experts(lp) for key, lp in params["layers"].items()}
+        layers = {key: rest for key, (rest, _) in split.items()}
+        experts = {key: stack for key, (_, stack) in split.items()}
+        stacked = any(stack is not None for stack in experts.values())
+    else:
+        layers, experts = _split_experts(params["layers"])
+        stacked = experts is not None
+
     def period(carry, xs):
         """One period of a layer pattern, its layers unrolled: attention layer
         ``a`` (counted among its kind) has the pages from ``a * NB``, state
         layer ``s`` (Mamba-2 or Gated DeltaNet) row ``s`` of the state pool. A
         routed pattern's picks come out a layer of the period, in its order."""
-        pp, first_a, first_s = xs
+        pp, first_a, first_s, *index = xs
         a = s = 0
         picked = []
         for j, kind in enumerate(cfg.period):
-            lp = pp[f"layer_{j}"]
+            lp = _with_experts(pp[f"layer_{j}"], experts[f"layer_{j}"], *index)
             if kind == "attention":
                 (x, *kv), picks = layer(carry[:5], lp, (first_a + a) * NB)
                 carry = (x, *kv, *carry[5:])
@@ -774,14 +787,15 @@ def _forward_hidden(
             periods = jnp.arange(cfg.num_layers // len(kinds), dtype=jnp.int32)
             (x, *pool), picks = jax.lax.scan(
                 period, carry + tuple(state or ()),
-                (params["layers"], periods * kinds.count("attention"),
-                 periods * (len(kinds) - kinds.count("attention"))))
+                (layers, periods * kinds.count("attention"),
+                 periods * (len(kinds) - kinds.count("attention"))) + ((periods,) if stacked else ()))
             if routed:  # [periods, layers of a period, N*C, k]: the layers in the model's order
                 picks = picks.reshape((cfg.num_layers,) + picks.shape[2:])
         else:
             (x, *pool), picks = jax.lax.scan(
-                lambda c, xs: layer(c, *xs), carry,
-                (params["layers"], jnp.arange(D, L, dtype=jnp.int32) * NB))
+                lambda c, xs: layer(c, _with_experts(xs[0], experts, *xs[2:]), xs[1]), carry,
+                (layers, jnp.arange(D, L, dtype=jnp.int32) * NB)
+                + ((jnp.arange(L - D, dtype=jnp.int32),) if stacked else ()))
     if state is not None:
         pool = HybridPools(PagedKVPool(*pool[:4]), StatePool(*pool[4:]))
     else:
@@ -796,6 +810,21 @@ def _forward_hidden(
             x, jnp.maximum(new_lens - 1, 0)[:, None, None], axis=1
         )[:, 0]  # [N, E]
     return (x, pool, picks) if routed else (x, pool)
+
+
+def _split_experts(lp):
+    """A scanned layer's stacked parameters without its routed experts'
+    leaves, and those leaves (None: no routed layer)."""
+    if "moe" not in lp:
+        return lp, None
+    return {**lp, "moe": {key: p for key, p in lp["moe"].items() if key != "experts"}}, lp["moe"]["experts"]
+
+
+def _with_experts(lp, stack, index=None):
+    """``lp`` with its experts named as row ``index`` of ``stack``, where it has any."""
+    if stack is None:
+        return lp
+    return {**lp, "moe": {**lp["moe"], "experts": ExpertStack(stack, index)}}
 
 
 def ragged_forward(
@@ -878,10 +907,12 @@ def ragged_decode_chain(
     is one program.
 
     A routed model asked ``with_picks`` returns two more: ``touched`` int32
-    ``[K, routed layers]``, how many distinct experts the rows live at a step
-    picked in each routed layer (what a step has to read of the experts; of a
-    chip's share ``[K, routed layers, 2]``: the HELD experts picked, and the
-    visits they got), and
+    ``[K, routed layers, 3]``, of each step and routed layer how many distinct
+    experts the rows LIVE at the step picked (what a step has to read of the
+    experts), the visits those got, and how many distinct experts ANY row
+    picked, dead and pad rows too (what the decode product reads: the count
+    its kernel is handed, ``ops/pallas/moe_decode.py``), of a chip's share
+    each among the experts HELD here; and
     ``picks`` int32 ``[K, N, routed layers, k]``, the experts each step's
     input token was sent to (rows not live at a step: garbage).
 
@@ -914,10 +945,10 @@ def ragged_decode_chain(
         picked = picks[0][:, 0]  # [N, routed layers, k]
         # (of a chip's share, by the held experts' own numbers: a pick of another chip's is no row of the one-hot)
         held = picked if cfg.expert_parallel is None else picked - cfg.first_expert
-        hit = jax.nn.one_hot(held, cfg.num_experts, dtype=jnp.bool_) & live[:, None, None, None]
-        touched = hit.any(axis=(0, 2)).sum(axis=-1).astype(jnp.int32)  # [routed layers]
-        if cfg.expert_parallel is not None:  # beside the held experts read, the visits they got
-            touched = jnp.stack([touched, hit.sum(axis=(0, 2, 3)).astype(jnp.int32)], axis=-1)
+        fed = jax.nn.one_hot(held, cfg.num_experts, dtype=jnp.bool_)  # [N, routed layers, k, E]
+        hit = fed & live[:, None, None, None]
+        touched = jnp.stack([hit.any(axis=(0, 2)).sum(axis=-1), hit.sum(axis=(0, 2, 3)),
+                             fed.any(axis=(0, 2)).sum(axis=-1)], axis=-1).astype(jnp.int32)  # [routed layers, 3]
         return carry, (out, touched, picked)
 
     carry0 = (pool, tokens, start_pos, active & (budgets > 0),

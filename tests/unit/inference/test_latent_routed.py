@@ -217,6 +217,8 @@ def test_generate_with_picks_covers_every_token_fed(files, tokens):
         assert [int(want[i, len(p) + j - 1].argmax()) for j in range(len(o))] == list(o)
         assert shortfall[i, :len(picks[i])].max() <= 1e-5
     assert 2 <= engine.last_experts_touched <= 6  # 3 rows x 2 picks over 8 experts
+    # what the decode product read: the picks of the program's 8 rows, the five pad rows' too
+    assert engine.last_experts_touched <= engine.last_experts_read <= 8
 
 
 def test_generate_with_picks_with_chains_ahead_is_the_serial_drivers(tokens):
@@ -394,13 +396,18 @@ def test_gpt_neox_programs_are_the_parents(name):
 @pytest.mark.parametrize("name", ["step", "chain"])
 def test_glm4_moe_lite_programs_are_the_parents(name):
     """The routed, latent toy's two programs, picks and all, against the census
-    recorded on PR 40's commit (``step``: the toy's prefill takes the ragged
-    path, whose combine became k gathers and a sum where it was a scatter-add,
-    and whose sort is inverted by a second sort) and PR 36's (``chain``: the
-    carry handed back, as above): what PR 35 added for EVA attention is a
-    branch at trace time and reaches neither."""
+    recorded on PR 50's commit. Against PR 40's ``step`` (the toy's prefill takes
+    the ragged path, whose combine is k gathers and a sum and whose sort is
+    inverted by a second sort) and PR 36's ``chain`` (the carry handed back, as
+    above), both: the layer scan closes over the routed experts' three stacked
+    leaves and slices each by its own index where it scanned them (three
+    ``dynamic_slice`` with their index's clamp, ``lt`` ``add`` ``select_n``, and
+    ``squeeze``; one ``iota``, the index), no new operand; ``chain`` alone:
+    ``touched`` is ``[K, routed layers, 3]`` where it was ``[K, routed
+    layers]``, beside the live rows' distinct experts their visits and the
+    distinct experts ANY row picked, which is what the decode product reads. What
+    PR 35 added for EVA attention is a branch at trace time and reaches neither."""
     pool, programs = _programs(config_from_hf(TOY), with_picks=True)
     assert pool.v is None  # the latent pool
-    recorded = {"step": "glm4_moe_lite_programs_at_pr40.json", "chain": "glm4_moe_lite_programs_at_pr36.json"}[name]
-    with open(os.path.join(os.path.dirname(__file__), "data", recorded)) as f:
+    with open(os.path.join(os.path.dirname(__file__), "data", "glm4_moe_lite_programs_at_pr50.json")) as f:
         _same_as_recorded(programs[name], json.load(f)[name])

@@ -342,6 +342,7 @@ def test_spans_say_whose_state_they_move_and_which_experts_they_read():
     for a in accept:  # HELD experts a step reads (4 are here), and the visits they got: 2 rows x 4 picks / 4 chips
         assert 0 <= a["experts_touched"] <= 4 and 0 <= a["held_visits"] <= 8
         assert a["held_visits"] >= a["experts_touched"]
+        assert a["experts_touched"] <= a["experts_read"] <= 4  # what the decode product read: pad rows' picks too
     assert any(a["held_visits"] > 0 for a in accept)
 
 

@@ -18,6 +18,7 @@ second file could land on another worker, whose fixture would skip.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -596,9 +597,11 @@ def test_qwen3_next_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch,
     256 over 2 KV heads, the share's sorted dispatch through megablox ``gmm``,
     which the chip takes and this host's ``lax.ragged_dot`` stands in for
     unless asked) and the chain of 8 steps at 128 rows (``gdn_update``, three
-    calls a period; a block table 64 pages wide; the held experts by one
-    product over all of them: a ``gmm`` call would have a layer's slice of the
-    stacked weights COPIED for it, 7.2 GB a step, PERF.md PR 48). Each fits the chip beside the
+    calls a period; a block table 64 pages wide; the held experts some row
+    picked by the kernel ``moe_decode``, four calls a period, on the WHOLE
+    stacked weights, which the scan closes over: a custom call on the scan's
+    slice of them, ``gmm`` or this one, would have the slice COPIED for it, 7.2
+    GB a step, PERF.md PR 48 and PR 50). Each fits the chip beside the
     weights and both pools, returns BOTH donated pools aliased, and holds no
     instruction of the state pool's whole shape that is a copy."""
     import dataclasses
@@ -610,10 +613,10 @@ def test_qwen3_next_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch,
     from deepspeed_tpu.inference import model, paged
     from deepspeed_tpu.models import CausalLM
     from deepspeed_tpu.ops import registry
-    from deepspeed_tpu.ops.pallas import (conv_update, flash_attention as fa, gdn_update, norms,
+    from deepspeed_tpu.ops.pallas import (conv_update, flash_attention as fa, gdn_update, moe_decode, norms,
                                           paged_attention as pa)
 
-    for module in (pa, fa, norms, gdn_update, conv_update):
+    for module in (pa, fa, norms, gdn_update, conv_update, moe_decode):
         monkeypatch.setattr(module, "_interpret", lambda: False)
     monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")  # what 'auto' sees there
     monkeypatch.setattr(model, "_grouped_matmul", lambda lhs, rhs, sizes: model._gmm_padded(lhs, rhs, sizes))
@@ -632,7 +635,7 @@ def test_qwen3_next_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch,
     assert pools.state.conv.shape == (9, 128, 3 * 8192)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
     if name == "chain_128":
-        limit_gb = 2.0  # (1.78: the product over all 64 held experts; 0.50 with the sorted dispatch)
+        limit_gb = 0.3  # (0.11; 1.78 with the product over all 64 held experts, 0.50 with the sorted dispatch)
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         def program_(params, pools, tokens, start_pos, tables, active, budgets, rng):
@@ -660,10 +663,14 @@ def test_qwen3_next_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch,
     assert mem.temp_size_in_bytes < limit_gb * 1e9, mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2 ** 30
     text = compiled.as_text()
-    # (a decode step's 128 rows go through every held expert's product, as the uncut layer's would: no gmm there)
-    kernels = ({"paged_attn": 1, "gdn_update": 3, "conv_update": 3} if name == "chain_128"
+    # (a decode step's 128 rows go through every held expert some row picked, read from the stack: no gmm there)
+    kernels = ({"paged_attn": 1, "gdn_update": 3, "conv_update": 3, "moe_decode": 4} if name == "chain_128"
                else {"paged_attn": 1, "gmm": 12})
-    assert name != "chain_128" or "gmm" not in text
+    # (by the custom calls: the table of source files may name megablox's through a cached jax.numpy function)
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert not any(("gmm" if name == "chain_128" else "moe_decode") in line for line in calls)
+    if name == "chain_128":
+        assert not _experts_moved(text, 3, 64, 2048, 512)
     # a decode step's convolution is one kernel, in place on the conv pool: the chain holds NO instruction of a
     # layer's row of it, sliced out or as [rows, K - 1, X] in either dtype; a prompt keeps conv_inputs' lines
     if name == "chain_128":
@@ -677,7 +684,7 @@ def test_qwen3_next_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch,
     else:
         assert "conv_update" not in text
     for kernel, least in kernels.items():  # the one-token rule is a kernel of its own name, the pool aliased through it
-        assert sum("tpu_custom_call" in line and kernel in line for line in text.splitlines()) >= least, kernel
+        assert sum(kernel in line for line in calls) >= least, kernel
     # nothing copies or re-lays the state pool, a layer's row of it, or the rows of a row
     state = r"f32\[(9,128|1,128|128),32,128,128\]"
     moved = [line.strip()[:200] for line in text.splitlines()
@@ -686,6 +693,96 @@ def test_qwen3_next_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch,
     # nor does the compiler, short of room, make the pool's update twice (it did with 64 rows a group of the
     # chunked rule: ``bitcast_dynamic-update-slice_fusion.21.remat``, a second pool)
     assert not re.search(r"dynamic-update-slice\S*\.remat\S* = f32\[9,128,32,128,128\]", text)
+
+
+@pytest.mark.parametrize("T,M,H,layers,glu", [
+    (128, 2048, 512, 3, True), (64, 2048, 1536, 7, True), (64, 3584, 1024, 5, True),
+    (8, 2048, 1536, 7, True), (24, 3584, 1024, 5, False),
+], ids=["qwen-128-rows", "glm-64-rows", "xing-64-rows", "glm-8-rows", "xing-24-rows-no-w_gate"])
+def test_moe_decode_compiles_at_the_cells_shapes(one_chip, monkeypatch, T, M, H, layers, glu):
+    """The decode product's kernel alone at the three routed cells' shapes (64
+    experts, the stack of the cell's routed layers or periods whole), at the
+    tile of the hidden width it picks from them, and at ``row_bucket``'s least
+    and an odd multiple of it in bf16 (half a sublane tile of rows over)."""
+    from deepspeed_tpu.ops.pallas import moe_decode
+
+    monkeypatch.setattr(moe_decode, "_interpret", lambda: False)
+    assert moe_decode.takes(T, M, H, jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.bfloat16))
+    sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    up, down = sds((layers, 64, M, H)), sds((layers, 64, H, M))
+
+    def product(x, gate, w_gate, w_up, w_down, layer):
+        return moe_decode.moe_decode(x, gate, w_gate if glu else None, w_up, w_down, layer,
+                                     "silu_glu" if glu else "gelu")
+
+    assert _compiled_kernels(product, sds((T, M)), sds((T, 64), jnp.float32), up, up, down, sds((), jnp.int32)) == 1
+
+
+def _experts_moved(text, layers, E, M, H):
+    """The instructions of a compiled program that make a layer's experts
+    (``[E, M, H]`` or ``[E, H, M]``, with or without a leading 1), and those
+    that make the stack's whole shape and are not the program's own operand
+    handed on: a copy or a slice of either would be 0.4-1.2 GB a layer-step."""
+    one = r"bf16\[(1,)?%d,(%d,%d|%d,%d)\]" % (E, M, H, H, M)
+    whole = r"bf16\[%d,%d,(%d,%d|%d,%d)\]" % (layers, E, M, H, H, M)
+    return [line.strip()[:200] for line in text.splitlines()
+            if re.search(r"= \(?%s" % one, line)
+            or (re.search(r"= \(?%s" % whole, line)
+                and not re.search(r" (parameter|get-tuple-element|bitcast)\(", line))]
+
+
+@pytest.mark.parametrize("config,workload,stack", [
+    ("glm-4.7-flash", "glm-4.7-flash.serve.batch", (7, 64, 2048, 1536)),
+    ("xing4.0-29b-a4b", "xing4.0-29b-a4b.serve.long-prompt-batch", (5, 64, 3584, 1024)),
+], ids=["glm", "xing"])
+def test_a_routed_chain_reads_the_picked_experts_from_the_stack(one_chip, monkeypatch, config, workload, stack):
+    """The two latent, routed cells' chains whole (64 rows, 8 steps, the cell's
+    own pool and block table), for the described v5e: the decode product is the
+    kernel ``moe_decode``, one call in the scan's body, on the stacked expert
+    weights WHOLE, which the layer scan closes over and names by its index; the
+    program holds no instruction of a layer's experts' shape, sliced or copied
+    (1.2 GB a layer in glm, 1.4 in xing: the temporaries stay under a tenth of
+    that), and fits the chip beside the weights and the pool, which comes back
+    aliased."""
+    import dataclasses
+
+    from benchmarks.lib import harness, program
+    from deepspeed_tpu.checkpoint.hf import config_from_hf
+    from deepspeed_tpu.inference import paged
+    from deepspeed_tpu.models import CausalLM
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import moe_decode, norms, paged_attention as pa
+
+    for module in (pa, norms, moe_decode):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")  # what 'auto' sees there
+    cfg = dataclasses.replace(config_from_hf(program.published(harness.load_config(config))), dtype=jnp.bfloat16)
+    assert (cfg.routed_layers, cfg.num_experts, cfg.hidden_size, cfg.expert_width) == stack
+    engine = harness.load_workload(workload)["engine"]
+    bs, rows = engine["kv_block_size"], engine["max_seqs"]
+    NB = engine["kv_pool_bytes"] // (bs * cfg.num_layers * paged.latent_pool_width(cfg) * 2)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda key: CausalLM(cfg).init({"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)},
+                                       train=False)["params"], jax.random.PRNGKey(0)))
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: paged.init_pool(cfg, NB, bs, jnp.bfloat16)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def chain(params, pool, tokens, start_pos, tables, active, budgets, rng):
+        return paged.ragged_decode_chain(params, cfg, pool, tokens, start_pos, tables, bs,
+                                         active, budgets, rng, engine["decode_chain"], None, with_picks=True)
+
+    compiled = chain.lower(
+        params, pool, i32(rows), i32(rows), i32(rows, engine["max_seq_len"] // bs),
+        jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip), i32(rows),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pool))
+    assert mem.temp_size_in_bytes < 0.12e9, mem.temp_size_in_bytes  # (0.025 and 0.072)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2 ** 30
+    assert sum("tpu_custom_call" in line and "moe_decode" in line for line in text.splitlines()) == 1
+    assert not _experts_moved(text, *stack)
 
 
 @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["bf16", "int8"])

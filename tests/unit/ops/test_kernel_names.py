@@ -60,6 +60,13 @@ def _gdn(v):
     return gdn_pool_step(jnp.zeros((2, 2, 1, 128, 128)), 1, qk, qk, v, -jnp.ones((2, 1)), jnp.ones((2, 1)))[0]
 
 
+def _moe(x):
+    from deepspeed_tpu.ops.pallas.moe_decode import moe_decode
+
+    w = jnp.ones((2, 4, 128, 128))
+    return moe_decode(x, jnp.ones((8, 4)), w, w, w, 1, "silu_glu")
+
+
 KERNELS = [
     ("flash_fwd", _flash, (QKV,)),
     ("flash_bwd_dq", jax.grad(_flash), (LONG,)),
@@ -77,6 +84,7 @@ KERNELS = [
     ("conv_update", _conv, (jnp.ones((8, 128), jnp.bfloat16),)),
     ("ssm_update", _ssm, (jnp.ones((2, 2, 64)),)),
     ("gdn_update", _gdn, (jnp.ones((2, 1, 128)),)),
+    ("moe_decode", _moe, (jnp.ones((8, 128)),)),
 ]
 
 

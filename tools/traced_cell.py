@@ -45,7 +45,11 @@
   (``gdn_chunk_roofline=``) and the ``state_pool_copy=`` lines;
 - in a cell that is one chip's share of a routed layer, a ``held_visits=``
   line: the median over the traced chains of the visits a step and routed
-  layer that the experts held here got (the ``serve:accept`` spans' arg).
+  layer that the experts held here got (the ``serve:accept`` spans' arg);
+- in a routed cell (since PR 50), an ``experts_read=`` line: the median over
+  the traced chains of the experts a step and routed layer that the decode
+  product read (the count the kernel ``moe_decode`` was handed: every row's
+  picks, dead or alive), beside the live rows' ``experts_touched``.
 
 All are wrapped OUTSIDE the benchmark, before ``run.main`` runs; nothing
 here is read by the program or the benchmark, and a cell's listed metrics
@@ -243,11 +247,16 @@ def main(argv=None) -> int:
     def with_held_visits(entries, run_, trace, *rest):
         from benchmarks.lib import spans, stats
 
-        visits = [float(s.args["held_visits"]) for s in spans.named(spans.of_run(run_), "serve:accept", kind="chain")
-                  if "held_visits" in s.args]
+        accepts = spans.named(spans.of_run(run_), "serve:accept", kind="chain")
+        visits = [float(s.args["held_visits"]) for s in accepts if "held_visits" in s.args]
         if visits:  # a chip's share of a routed layer: the load its experts got, which no metric reads
             print(f"held_visits= median={stats.median(visits)} least={min(visits)} most={max(visits)} "
                   f"chains={len(visits)} (visits a step and routed layer to the experts held here)", flush=True)
+        read = [float(s.args["experts_read"]) for s in accepts if "experts_read" in s.args]
+        if read:  # what the decode product read: the count its kernel was handed, dead and pad rows' picks too
+            touched = [float(s.args["experts_touched"]) for s in accepts if "experts_read" in s.args]
+            print(f"experts_read= median={stats.median(read)} least={min(read)} most={max(read)} chains={len(read)} "
+                  f"experts_touched_median={stats.median(touched)} (experts a step and routed layer)", flush=True)
         return read_metrics(entries, run_, trace, *rest)
 
     argv = list(sys.argv[1:] if argv is None else argv)
