@@ -213,11 +213,23 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
         return TransformerConfig(**kw)
     if mt == "granitemoehybrid":
         # a layer PATTERN (layer_types): Mamba-2 state-space mixers beside GQA
-        # attention with no positional term at all, every mixer followed by one
-        # dense silu-GLU (shared_intermediate_size), four scalar multipliers.
-        # The routed variants (num_local_experts > 0) are not built
-        refused = [(hf_config.get("num_local_experts", 0) > 0, "num_local_experts > 0 (routed experts beside "
-                    "the shared MLP)"),
+        # attention with no positional term at all, four scalar multipliers,
+        # every mixer followed by one dense silu-GLU (shared_intermediate_size)
+        # and, in the routed variants (num_local_experts > 0), beside it by a
+        # softmax router over experts of width ``intermediate_size`` (the config
+        # has no key of its own for it: the family's code builds input_linear
+        # [E, 2 x intermediate_size, hidden]), top-k THEN softmax, which is the
+        # softmax over all renormalised over the picks; the shared MLP has no
+        # gate. ``num_local_experts`` are the experts HELD here: with
+        # ``expert_parallel: {size, rank}`` the router scores ``size`` times as many
+        held, width = hf_config.get("num_local_experts", 0), hf_config["intermediate_size"]
+        share = hf_config.get("expert_parallel") if held else None
+        picks, scored = hf_config.get("num_experts_per_tok", 0), held * int((share or {"size": 1})["size"])
+        shared_width = hf_config.get("shared_intermediate_size", width)
+        refused = [(held > 0 and not 1 <= picks <= scored,
+                    f"num_local_experts={held} and num_experts_per_tok={picks} (1 to the {scored} the router scores)"),
+                   (held > 0 and shared_width % width != 0,
+                    "shared_intermediate_size not a multiple of intermediate_size (an expert's width)"),
                    (hf_config.get("position_embedding_type", "nope") != "nope",
                     f"position_embedding_type={hf_config.get('position_embedding_type')!r} (only 'nope')"),
                    (bool(hf_config.get("rope_scaling")), "rope_scaling"),
@@ -233,13 +245,23 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
         refused = [what for bad, what in refused if bad]
         if refused:
             raise ValueError("granitemoehybrid with " + "; ".join(refused) + " is unsupported")
-        from deepspeed_tpu.models.transformer import SSMConfig
+        from deepspeed_tpu.models.transformer import ExpertParallel, SSMConfig
 
         dtype = hf_config.get("dtype", hf_config.get("torch_dtype"))
+        routed = dict(
+            num_experts=held,
+            expert_parallel=ExpertParallel(int(share["size"]), int(share.get("rank", 0))) if share else None,
+            moe_top_k=picks,
+            moe_intermediate_size=width,
+            moe_shared_experts=shared_width // width,
+            moe_router="softmax",
+            moe_renormalize=True,
+            moe_drop_tokens=False,
+        ) if held else {}
         return TransformerConfig(
             vocab_size=hf_config["vocab_size"],
             hidden_size=hf_config["hidden_size"],
-            intermediate_size=hf_config.get("shared_intermediate_size", hf_config["intermediate_size"]),
+            intermediate_size=shared_width,
             num_layers=hf_config["num_hidden_layers"],
             num_heads=hf_config["num_attention_heads"],
             num_kv_heads=hf_config.get("num_key_value_heads"),
@@ -260,6 +282,7 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
             residual_multiplier=float(hf_config.get("residual_multiplier", 1.0)),
             logits_scaling=float(hf_config.get("logits_scaling", 1.0)),
             param_dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(dtype, jnp.float32),
+            **routed,
         )
     if mt == "qwen3_next":
         # a ROUTED layer pattern: three Gated DeltaNet (linear-attention) layers

@@ -249,7 +249,7 @@ def main(argv=None) -> int:
 
         accepts = spans.named(spans.of_run(run_), "serve:accept", kind="chain")
         visits = [float(s.args["held_visits"]) for s in accepts if "held_visits" in s.args]
-        if visits:  # a chip's share of a routed layer: the load its experts got, which no metric reads
+        if visits:  # a chip's share of a routed layer: the load its experts got (``moe_held_visits.ep`` is its median)
             print(f"held_visits= median={stats.median(visits)} least={min(visits)} most={max(visits)} "
                   f"chains={len(visits)} (visits a step and routed layer to the experts held here)", flush=True)
         read = [float(s.args["experts_read"]) for s in accepts if "experts_read" in s.args]
