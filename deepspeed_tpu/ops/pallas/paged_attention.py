@@ -441,13 +441,31 @@ def flash_decode_paged(
 # multi-query attention whose values alias its keys. The walk over a row's
 # live pages and the two-slot prefetch are the kernel's above; what differs is
 # the one buffer, and the query side: all heads of a few query tokens are the
-# rows of one product, and a chunk of a prompt is cut into tiles of
-# ``_LATENT_Q_TILE`` tokens, each a grid step with its own context length
-# (what the tile's last live token may see), because ``C * H`` rows of a
-# 256-token chunk do not fit VMEM. A row's later tiles fetch its pages again:
-# at 1.25 KB a token that is noise beside a prompt's other work.
+# rows of one product, and a chunk of a prompt is cut into tiles of ``tq``
+# tokens, each a grid step with its own context length (what the tile's last
+# live token may see), because ``C * H`` rows of a 256-token chunk do not fit
+# VMEM. A row's later tiles fetch its pages again: at 1.25 KB a token that is
+# noise beside a prompt's other work.
+#
+# The tile and the page-chunk come from the call's shapes alone
+# (``_latent_form``). One token or a few (decode, a token and its drafts:
+# ``C`` under ``_LATENT_Q_TILE``) are one tile against chunks of
+# ``LATENT_PAGES_PER_BLOCK`` pages. A prompt takes the largest tile and then
+# the widest chunk that fit ``_LATENT_VMEM_BUDGET``: what a chunk-step costs
+# beside its two products (the page DMAs' scalar loops, the keys pushed to the
+# MXU as weights, the accumulator read, scaled and written) is then spread
+# over more scores, and a row's pages are fetched again by fewer tiles. The
+# kernel's body is the same at every shape.
 _LATENT_Q_TILE = 16
 LATENT_PAGES_PER_BLOCK = 16
+# Mosaic's default scope of VMEM, which a prompt's grid step has to fit by
+# ``_latent_vmem_bytes``' count. The kernel asks for no limit of its own: the
+# chip's 128 MiB are shared, and what a kernel scopes past the default the
+# compiler takes from the arrays it keeps in fast memory for the WHOLE program.
+# At 48 MiB here the xing prefill's dispatch gather lost its 112 MiB source's
+# place there and went from 0.72 to 3.55 ms a layer-call, most of what the
+# kernel had gained (PERF.md, PR 52).
+_LATENT_VMEM_BUDGET = 16 << 20
 
 
 def _latent_kernel(bt_ref, ctx_ref, q_ref, qpos_ref, k_hbm, o_ref, kbuf, acc_ref, m_ref, l_ref,
@@ -548,6 +566,48 @@ def _latent_context(q_positions, new_lens, tile: int):
     return seen.max(axis=-1).astype(jnp.int32)
 
 
+def _latent_vmem_bytes(rows: int, T: int, W: int, v_width: int, itemsize: int) -> int:
+    """VMEM of a grid step of ``rows`` query rows against chunks of ``T``
+    tokens, as the chip's compiler scopes it: the blocks of q, of the positions
+    and of the output, each double-buffered by the pipeline; the fp32
+    accumulator and the two statistics; q held across the walk, a chunk's
+    value product before it is added, and two columns of the update's
+    statistics (a column pads to 128 lanes); the two slots of pages; and ONE
+    float32 copy of a chunk-step's scores (the compiler reuses it). Fitted to
+    the compiler's own verdicts at 32 heads over tiles of 16 to 56 tokens and
+    chunks of 8 to 64 pages, all of which it reproduces
+    (``tests/unit/ops/test_chip_compile.py`` holds the ones at its edge)."""
+    column = 128 * 4
+    blocks = 2 * (W * itemsize + column + v_width * itemsize)
+    scratch = v_width * 4 + 2 * column
+    held = W * itemsize + v_width * 4 + 2 * column
+    return rows * (blocks + scratch + held) + 2 * T * W * itemsize + rows * T * 4
+
+
+def _latent_form(C: int, H: int, W: int, v_width: int, itemsize: int, P: int, bs: int) -> tuple[int, int]:
+    """(query tokens a tile, pages a chunk) from the call's shapes (the comment
+    above): at 32 heads and 2,048 tokens a row, tiles of 32 tokens (1,024 rows)
+    and chunks of 32 pages; at 20 heads and 256 tokens, tiles of 32 (640 rows),
+    chunks of 16."""
+    ppcb = max(1, min(LATENT_PAGES_PER_BLOCK, P))
+    if C < _LATENT_Q_TILE:  # decode, a token and its drafts
+        return C, ppcb
+
+    def fits(tq, ppcb):
+        return _latent_vmem_bytes(tq * H, ppcb * bs, W, v_width, itemsize) <= _LATENT_VMEM_BUDGET
+
+    # the most tokens a tile, by doubling, of the call's own: the fixed cost of a chunk-step is
+    # paid a tile, and a row's pages are fetched again a tile
+    tq = _LATENT_Q_TILE
+    while 2 * tq <= C and fits(2 * tq, ppcb):
+        tq *= 2
+    # then the widest chunk, no wider than the call's tokens: a whole prompt sees no more
+    # columns than it brings, and the last chunk's dead columns are multiplied all the same
+    while 2 * ppcb <= P and 2 * ppcb * bs <= C and fits(tq, 2 * ppcb):
+        ppcb *= 2
+    return tq, ppcb
+
+
 @register("latent_paged_attention", "pallas")
 def flash_decode_latent(
     q: jax.Array,  # [N, C, H, W]: a head's query against the whole slab, NOT yet scaled
@@ -558,13 +618,11 @@ def flash_decode_latent(
     scale: float,
     v_width: int,  # a token's value: the slab's first v_width columns
     new_lens: jax.Array = None,  # [N] live tokens
-    pages_per_block: int = LATENT_PAGES_PER_BLOCK,
 ) -> jax.Array:
     """-> [N, C, H, v_width]. One fetch of a page serves every head."""
     N, C, H, W = q.shape
-    P = block_tables.shape[1]
     bs = block_size
-    tq = min(C, _LATENT_Q_TILE)
+    tq, ppcb = _latent_form(C, H, W, v_width, q.dtype.itemsize, block_tables.shape[1], bs)
     Cp = _cdiv(C, tq) * tq
     tiles = Cp // tq
     rows = _cdiv(tq * H, 16) * 16  # whole sublane tiles of the 16-bit query
@@ -581,7 +639,6 @@ def flash_decode_latent(
     pad = rows - tq * H
     q_op = jnp.pad(q_op, ((0, 0), (0, pad), (0, 0)))
     qpos = jnp.pad(qpos, ((0, 0), (0, pad)), constant_values=-1)  # padded rows see nothing
-    ppcb = max(1, min(pages_per_block, P))
 
     out = pl.pallas_call(
         functools.partial(_latent_kernel, ppcb=ppcb, bs=bs, tiles=tiles, v_width=v_width),

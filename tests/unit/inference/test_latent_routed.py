@@ -130,30 +130,107 @@ def test_put_through_the_latent_cache_against_the_reference(files, tokens, monke
 
 
 # the kernel alone: a row without pages, a table wider than a row's pages,
-# a chunk cut into query tiles, a token and its drafts
+# a chunk cut into query tiles, a token and its drafts; and what the prompt
+# path's form (a tile and a page-chunk from the shapes) has to get right: a
+# context of five chunks under a tile that straddles a chunk's edge, lengths
+# that are no multiple of the tile, a row with no live token, and a tile whose
+# first live position is a chunk's LAST column (255) or the one before it (254)
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)], ids=["fp32", "bf16"])
-@pytest.mark.parametrize("C,lens,starts", [(1, (1, 1, 0, 1), (0, 37, 0, 63)),
-                                           (5, (5, 3, 0, 5), (11, 0, 0, 40)),
-                                           (32, (32, 7, 0, 20), (0, 16, 0, 30))],
-                         ids=["decode", "drafts", "chunk-in-tiles"])
-def test_latent_kernel_against_the_gather(dtype, tol, C, lens, starts):
-    from deepspeed_tpu.ops.pallas.paged_attention import flash_decode_latent
+@pytest.mark.parametrize("C,H,lens,starts", [
+    (1, 4, (1, 1, 0, 1), (0, 37, 0, 63)),
+    (5, 4, (5, 3, 0, 5), (11, 0, 0, 40)),
+    (32, 4, (32, 7, 0, 20), (0, 16, 0, 30)),
+    (80, 20, (80, 37, 0, 71), (1000, 300, 0, 771)),
+    (80, 32, (80, 37, 0, 71), (1000, 300, 0, 771)),
+    (80, 20, (64, 17, 0, 80), (255, 511, 0, 254)),
+    (80, 32, (64, 17, 0, 80), (255, 511, 0, 254)),
+], ids=["decode", "drafts", "chunk-in-tiles", "five-chunks-20-heads", "five-chunks-32-heads",
+        "chunk-edge-20-heads", "chunk-edge-32-heads"])
+def test_latent_kernel_against_the_gather(dtype, tol, C, H, lens, starts):
+    from deepspeed_tpu.ops.pallas.paged_attention import _latent_form, flash_decode_latent
 
-    N, H, W, V, bs, P = 4, 4, 128, 96, 16, 12  # 12 columns, rows hold at most 5 pages
+    N, W, V, bs = 4, 128, 96, 16
+    P = 12 if C <= 32 else 72  # 12 columns, rows hold at most 5 pages; or 72, up to 68
+    pages = 40 if C <= 32 else N * P
     rng = np.random.default_rng(C)
-    pool = jnp.asarray(rng.normal(size=(40, bs, W)), dtype)
+    pool = jnp.asarray(rng.normal(size=(pages, bs, W)), dtype)
     q = jnp.asarray(rng.normal(size=(N, C, H, W)), dtype)
-    tables = jnp.asarray(rng.permutation(40)[:N * P].reshape(N, P) if N * P <= 40
-                         else rng.integers(0, 40, (N, P)), jnp.int32)
+    tables = jnp.asarray(rng.permutation(pages)[:N * P].reshape(N, P) if N * P <= pages
+                         else rng.integers(0, pages, (N, P)), jnp.int32)
     positions = jnp.asarray(np.asarray(starts)[:, None] + np.arange(C)[None, :], jnp.int32)
     new_lens = jnp.asarray(lens, jnp.int32)
+    if C == 80:  # the cases are written for this form: tiles of 64 tokens, chunks of 256
+        assert _latent_form(C, H, W, V, q.dtype.itemsize, P, bs) == (64, 16)
     args = (q, pool, tables, positions, bs, 0.25, V)
     got = np.asarray(flash_decode_latent(*args, new_lens=new_lens), np.float32)
+    if C == 80:
+        # against the gather in float32 on the same (rounded) numbers: in bf16 the gather rounds
+        # its scores, and of these cases' 10^5 outputs one to three then lie 0.032-0.046 off
+        args = (q.astype(jnp.float32), pool.astype(jnp.float32), *args[2:])
     want = np.asarray(paged._xla_latent_paged_attention(*args, new_lens=new_lens), np.float32)
     assert np.isfinite(got).all()
     for n in range(N):  # live tokens only: a dead one attends to nothing in the kernel
         np.testing.assert_allclose(got[n, :lens[n]], want[n, :lens[n]], atol=tol, rtol=tol)
     assert not got[2].any()  # the row without pages writes zeros
+
+
+@pytest.mark.parametrize("N,C,H,P,form", [(8, 2048, 32, 256, (32, 32)), (64, 256, 20, 128, (32, 16)),
+                                          (8, 5, 20, 128, (5, 16)), (64, 1, 32, 256, (1, 16))],
+                         ids=["xing-prefill", "glm-prefill", "drafts", "decode"])
+def test_the_form_comes_from_the_shapes_and_asks_for_no_vmem_of_its_own(N, C, H, P, form):
+    """At both latent cells' shapes: the tile and the chunk ``_latent_form``
+    picks (the most that fit the 16 MiB Mosaic scopes by default, a chunk no
+    wider than the call's tokens), the grid and the query block that follow,
+    and NO ``vmem_limit_bytes``: at 48 MiB the xing prefill's dispatch gather
+    lost its source's place in fast memory (0.72 -> 3.55 ms a layer-call)."""
+    from deepspeed_tpu.ops.pallas.paged_attention import _latent_form, flash_decode_latent
+
+    assert _latent_form(C, H, 640, 512, 2, P, 16) == form
+    s = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(lambda q, pool, bt, pos, n: flash_decode_latent(q, pool, bt, pos, 16, 0.0625, 512, new_lens=n))(
+        s((N, C, H, 640), jnp.bfloat16), s((100, 16, 640), jnp.bfloat16), s((N, P), jnp.int32),
+        s((N, C), jnp.int32), s((N,), jnp.int32))
+    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    grid = call.params["grid_mapping"]
+    rows = -(-form[0] * H // 16) * 16
+    assert grid.grid == (N * -(-C // form[0]),) and grid.num_index_operands == 2
+    assert tuple(getattr(d, "block_size", d) for d in grid.block_mappings[0].block_shape) == (1, rows, 640)
+    assert call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes is None
+
+
+@pytest.mark.parametrize("H,rows", [(20, 32), (32, 32)], ids=["20-heads", "32-heads"])
+def test_one_token_a_row_lowers_to_the_kernel_it_was(H, rows):
+    """The decode path (``C == 1``) is not the prompt path's to change: the
+    ``pallas_call`` of a ``(64, 1)`` call at both latent cells' heads has the
+    grid, the scalar operands, the blocks, the scratch and the compiler's
+    parameters it had before PR 52 chose a prompt's form from its shapes, and
+    its body is the jaxpr it was, equation for equation and nested as it was
+    (the text as PR 51's tree printed it: what stands inside the walk's loop
+    and what outside it is part of that)."""
+    from deepspeed_tpu.ops.pallas.paged_attention import flash_decode_latent
+
+    N, W, V, bs, P, pages = 64, 640, 512, 16, 128, 100
+    s = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(lambda q, pool, bt, pos, n: flash_decode_latent(q, pool, bt, pos, bs, 0.0625, V, new_lens=n))(
+        s((N, 1, H, W), jnp.bfloat16), s((pages, bs, W), jnp.bfloat16), s((N, P), jnp.int32),
+        s((N, 1), jnp.int32), s((N,), jnp.int32))
+    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    grid = call.params["grid_mapping"]
+    assert grid.grid == (N,) and grid.num_index_operands == 2  # the table, the contexts
+    assert [tuple(getattr(d, "block_size", d) for d in b.block_shape) for b in grid.block_mappings] == [
+        (1, rows, W), (1, rows, 1), (pages, bs, W), (1, rows, V)]
+    scratch = call.params["jaxpr"].invars[-grid.num_scratch_operands:]
+    assert [str(v.aval) for v in scratch] == [
+        f"Ref<vmem>{{bfloat16[2,16,{bs},{W}]}}", f"Ref<vmem>{{float32[{rows},{V}]}}",
+        f"Ref<vmem>{{float32[{rows},128]}}", f"Ref<vmem>{{float32[{rows},128]}}",
+        "Ref<semaphore_mem>{dma_sem[2]}", "Ref<smem>{int32[1]}"]
+    assert [(v.aval.shape, str(v.aval.dtype)) for v in call.invars] == [
+        ((N, P), "int32"), ((N,), "int32"), ((N, rows, W), "bfloat16"), ((N, rows, 1), "int32"),
+        ((pages, bs, W), "bfloat16")]
+    mosaic = call.params["compiler_params"]["mosaic_tpu"]
+    assert mosaic.dimension_semantics == ("arbitrary",) and mosaic.vmem_limit_bytes is None
+    with open(os.path.join(os.path.dirname(__file__), "data", "latent_decode_kernel_at_pr51.txt")) as f:
+        assert str(call.params["jaxpr"]) == f.read()
 
 
 # (c) the picks

@@ -346,42 +346,71 @@ def test_paged_attention_compiles(one_chip, monkeypatch, N, C, kv_quant, H, kvH,
     assert _compiled_kernels(fn, *shapes) == 1
 
 
-@pytest.mark.parametrize("N,C", [(64, 1), (64, 256), (8, 5)],
+def _latent_kernel_compiled(one_chip, monkeypatch, N, C, H, pages, cols, scale):
+    """``flash_decode_latent`` compiled for the described chip at a cell's
+    shapes: the text of its one Mosaic kernel's instruction, after the checks
+    that it is ONE and that nothing of the pool's whole shape is a copy."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    bf16 = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)  # noqa: E731
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+
+    def fn(q, pool, bt, qpos, lens):
+        return pa.flash_decode_latent(q, pool, bt, qpos, 16, scale, 512, new_lens=lens)
+
+    text = jax.jit(fn).lower(bf16(N, C, H, 640), bf16(pages, 16, 640), i32(N, cols), i32(N, C),
+                             i32(N)).compile().as_text()
+    (kernel,) = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert not [line for line in text.splitlines()
+                if re.search(r"= bf16\[%d,16,640\]\S* (copy|copy-start|transpose)\(" % pages, line)]
+    return kernel
+
+
+@pytest.mark.parametrize("N,C,steps,rows", [(64, 1, 64, 32), (64, 256, 512, 640), (8, 5, 8, 112)],
                          ids=["cell-decode-64x1", "cell-prefill-64x256", "drafts-k4"])
-def test_latent_paged_attention_compiles(one_chip, monkeypatch, N, C):
+def test_latent_paged_attention_compiles(one_chip, monkeypatch, N, C, steps, rows):
     """``mla_paged_attn`` at glm-4.7-flash.serve.batch's own shapes: 20 heads
     against one 640-column slab a token (512 latent + 64 rotary + 64 of lane
     padding), values its first 512 columns, a table of 2048 / 16 pages; the
-    256-token chunk goes in query tiles of 16 tokens x 20 heads."""
-    from deepspeed_tpu.ops.pallas import paged_attention as pa
-
-    monkeypatch.setattr(pa, "_interpret", lambda: False)
-    bf16 = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)  # noqa: E731
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
-
-    def fn(q, pool, bt, qpos, lens):
-        return pa.flash_decode_latent(q, pool, bt, qpos, 16, 1 / 16, 512, new_lens=lens)
-
-    assert _compiled_kernels(fn, bf16(N, C, 20, 640), bf16(8 * 1024, 16, 640), i32(N, 128),
-                             i32(N, C), i32(N)) == 1
+    256-token chunk goes in query tiles of 32 tokens x 20 heads (16 before
+    PR 52: 1,024 grid steps of 320 rows), which is what the instruction's
+    shape says, the most by doubling that fits the 16 MiB Mosaic scopes by
+    default (the kernel asks for no more: what it took past that, the compiler
+    would take from the arrays it keeps in fast memory for the whole program);
+    one token and a token with its four drafts are one tile."""
+    kernel = _latent_kernel_compiled(one_chip, monkeypatch, N, C, 20, 8 * 1024, 128, 1 / 16)
+    assert re.search(r"%%mla_paged_attn\S* = bf16\[%d,%d,512\]" % (steps, rows), kernel), kernel
 
 
-@pytest.mark.parametrize("N,C", [(64, 1), (8, 2048)], ids=["cell-decode-64x1", "cell-prefill-8x2048"])
-def test_latent_paged_attention_compiles_at_32_heads(one_chip, monkeypatch, N, C):
+@pytest.mark.parametrize("N,C,steps,rows", [(64, 1, 64, 32), (8, 2048, 512, 1024), (8, 5, 8, 160)],
+                         ids=["cell-decode-64x1", "cell-prefill-8x2048", "drafts-k4"])
+def test_latent_paged_attention_compiles_at_32_heads(one_chip, monkeypatch, N, C, steps, rows):
     """``mla_paged_attn`` at xing4.0-29b-a4b.serve.long-prompt-batch's shapes:
-    32 heads (a query tile of 16 tokens is 512 rows, glm's 320), a table of
-    4096 / 16 pages, a 1.5 GiB pool of 7 layers, a whole 2,048-token prompt a row."""
+    32 heads, a table of 4096 / 16 pages, a 1.5 GiB pool of 7 layers, a whole
+    2,048-token prompt a row in query tiles of 32 tokens (1,024 rows; 16 tokens,
+    512 rows and 1,024 grid steps before PR 52) against chunks of 32 pages."""
+    kernel = _latent_kernel_compiled(one_chip, monkeypatch, N, C, 32, 7 * 11234, 256, 192 ** -0.5 * 2.00474)
+    assert re.search(r"%%mla_paged_attn\S* = bf16\[%d,%d,512\]" % (steps, rows), kernel), kernel
+
+
+@pytest.mark.parametrize("tq,ppcb,fits", [(32, 32, True), (24, 64, True), (36, 8, True),
+                                          (36, 16, False), (40, 8, False), (32, 64, False)],
+                         ids=lambda v: str(v))
+def test_the_latent_form_s_count_of_vmem_is_the_compiler_s_verdict(one_chip, monkeypatch, tq, ppcb, fits):
+    """``_latent_vmem_bytes`` against ``_LATENT_VMEM_BUDGET`` says what the
+    chip's compiler says of a tile and a chunk at the xing prefill's shape, on
+    both sides of the edge: the form ``_latent_form`` picks there, (32, 32),
+    stands AT it, so a count that drifts from the compiler's shows here."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
-    monkeypatch.setattr(pa, "_interpret", lambda: False)
-    bf16 = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)  # noqa: E731
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
-
-    def fn(q, pool, bt, qpos, lens):
-        return pa.flash_decode_latent(q, pool, bt, qpos, 16, 192 ** -0.5 * 2.00474, 512, new_lens=lens)
-
-    assert _compiled_kernels(fn, bf16(N, C, 32, 640), bf16(7 * 11234, 16, 640), i32(N, 256),
-                             i32(N, C), i32(N)) == 1
+    assert (pa._latent_vmem_bytes(tq * 32, ppcb * 16, 640, 512, 2) <= pa._LATENT_VMEM_BUDGET) is fits
+    monkeypatch.setattr(pa, "_latent_form", lambda *shapes: (tq, ppcb))
+    if fits:
+        _latent_kernel_compiled(one_chip, monkeypatch, 8, 2048, 32, 7 * 11234, 256, 0.1)
+    else:
+        with pytest.raises(Exception, match="vmem"):
+            _latent_kernel_compiled(one_chip, monkeypatch, 8, 2048, 32, 7 * 11234, 256, 0.1)
 
 
 @pytest.mark.parametrize("kernels", ["forward", "gradient"])

@@ -1,0 +1,211 @@
+"""The latent kernel ALONE (`ops/pallas/paged_attention.py::flash_decode_latent`,
+the instruction `mla_paged_attn`), at the shapes the two latent cells run it:
+
+| shape | q | pool | table | contexts |
+|---|---|---|---|---|
+| `xing` (`xing4.0-29b-a4b.serve.long-prompt-batch`, `step`) | [8, 2048, 32, 640] | [7 x 11,234, 16, 640] | 256 | prompts uniform 1,024-2,048, a row's tokens from position 0 |
+| `glm` (`glm-4.7-flash.serve.batch`, `step`) | [64, 256, 20, 640] | [8 x 6,553, 16, 640] | 128 | prompts uniform 64-256 |
+| `xing-decode` (`chain`) | [64, 1, 32, 640] | xing's | 256 | 1,024-2,048 + 0-31 decoded |
+| `glm-decode` (`chain`) | [64, 1, 20, 640] | glm's | 128 | 64-256 + 0-191 decoded |
+
+    chiprun -- python tools/latent_kernel_bench.py [--shapes xing glm ...] [--forms 16:16,32:16]
+
+Some tens of calls under one jit (each call's block table rests on the call
+before, so nothing is hoisted but what does not change: the query's
+pre-scale), timed on the host's clock around `block_until_ready` (`host_ms_per_call`: the
+pre-scale's pass over q is in it, which a model's program fuses into q's
+producer), and once more under the profiler for the instruction's own device
+time, what a traced cell reads as a layer-call of `mla_paged_attn`: that is
+`ms_per_call`, and the line's other numbers rest on it; one JSON line a shape
+and form with the ms a call, the chunk-steps a call (a query
+tile's page-chunks, summed), the us a `[512, 256]` of scores COMPUTED (dead
+columns of a last chunk and padded rows included: what a chunk-step costs),
+and the share of the bf16 peak on LIVE work: for each live query token its
+context x `H` x 2 x (`W` + `v_width`) FLOPs, counted here.
+
+`--forms` times other forms than the one the kernel picks from the shapes,
+each `tokens a tile : pages a chunk`, by standing in for the module's
+`_latent_form`; `16:16` is the form every call had before PR 52. Where the
+trace holds no `mla_paged_attn` instruction, `ms_per_call` and what rests on
+it are null: the host's clock is never written under that name. A time comes
+only from a chip: without one this exits 1.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+W, V, BS = 640, 512, 16
+BF16_PEAK = 197e12  # TPU v5e (benchmarks/lib/peaks.py)
+# N, C, H, pages a layer, layers, table columns, prompt lengths, decoded tokens, softmax scale
+SHAPES = {
+    "xing": (8, 2048, 32, 11234, 7, 256, (1024, 2048), 0, 192 ** -0.5 * 2.00474),
+    "glm": (64, 256, 20, 6553, 8, 128, (64, 256), 0, 256 ** -0.5),
+    "xing-decode": (64, 1, 32, 11234, 7, 256, (1024, 2048), 31, 192 ** -0.5 * 2.00474),
+    "glm-decode": (64, 1, 20, 6553, 8, 128, (64, 256), 191, 256 ** -0.5),
+}
+
+
+def draw(shape: str, seed: int, layer: int = 3):
+    """Lengths, positions and a block table as the engine hands them over: a
+    row's live pages are distinct pages of `layer`, its dead entries the
+    layer's page 0; a prompt's tokens stand at 0 .. C-1, the first `lens` live,
+    a decode row's one token at its context's end."""
+    N, C, _, pages, _, cols, (lo, hi), decoded, _ = SHAPES[shape]
+    rng = np.random.default_rng(seed)
+    ctx = rng.integers(lo, hi + 1, N) + (rng.integers(0, decoded + 1, N) if decoded else 0)
+    table = np.zeros((N, cols), np.int32)
+    free = rng.permutation(np.arange(1, pages))
+    for n, c in enumerate(ctx):
+        live = -(-int(c) // BS)
+        table[n, :live], free = free[:live], free[live:]
+    if C == 1:
+        positions, lens = (ctx - 1)[:, None], np.ones(N)
+    else:
+        positions, lens = np.tile(np.arange(C), (N, 1)), ctx
+    return positions.astype(np.int32), lens.astype(np.int32), table + layer * pages
+
+
+def counts(shape: str, positions, lens, tq: int, ppcb: int) -> dict:
+    """Chunk-steps, scores computed and live FLOPs of a call, from its shapes."""
+    N, C, H = SHAPES[shape][:3]
+    T = ppcb * BS
+    rows = -(-tq * H // 16) * 16
+    live = np.arange(C)[None, :] < lens[:, None]
+    seen = np.where(live, positions + 1, 0)
+    pad = -C % tq
+    tiles = np.pad(seen, ((0, 0), (0, pad))).reshape(N, -1, tq).max(-1)
+    steps = int((-(-tiles // T)).sum())
+    return {"chunk_steps": steps, "scores": steps * rows * T,
+            "live_flops": int(seen.sum()) * H * 2 * (W + V), "grid_steps": int(tiles.size), "rows": rows}
+
+
+def instruction_seconds(run, name: str = "mla_paged_attn"):
+    """(the instruction's text up to its operands, its device seconds) in one
+    traced `run`: the kernel without the pre-scale and the slices around it."""
+    import glob
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            run()
+        events = [e for path in glob.glob(os.path.join(d, "plugins", "profile", "*", "*.xplane.pb"))
+                  for plane in ProfileData.from_file(path).planes if plane.name.startswith("/device:TPU")
+                  for line in plane.lines if line.name == "XLA Ops"
+                  for e in line.events if e.name.startswith("%" + name)]
+    return (events[0].name.split(" custom-call(")[0] if events else None), sum(e.duration_ns for e in events) / 1e9
+
+
+def measure(shape: str, form, seed: int, calls: int, repeats: int = 5) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    N, C, H, pages, layers, _, _, _, scale = SHAPES[shape]
+    positions, lens, table = draw(shape, seed)
+    key = jax.random.PRNGKey(seed)
+    q = jax.random.normal(key, (N, C, H, W), jnp.bfloat16)
+    pool = jnp.tile(jax.random.normal(jax.random.fold_in(key, 1), (pages, BS, W), jnp.bfloat16), (layers, 1, 1))
+    # a tree before PR 52 has no `_latent_form`: tiles of 16 tokens, chunks of 16 pages, and no other
+    picks = getattr(pa, "_latent_form", lambda C, H, W, V, itemsize, P, bs: (min(C, 16), min(P, 16)))
+    if form is not None:
+        assert hasattr(pa, "_latent_form"), "a tree before PR 52 has one form"
+        pa._latent_form = lambda *shapes: form  # every call below is traced in here; `main` puts it back
+    tq, ppcb = form or picks(C, H, W, V, 2, table.shape[1], BS)
+
+    def kernel(q, pool, table, pos, n):
+        return pa.flash_decode_latent(q, pool, table, pos, BS, scale, V, new_lens=n)
+
+    @jax.jit
+    def many(q, pool, table, pos, n):
+        def one(_, table):
+            out = kernel(q, pool, table, pos, n)
+            # never true, and the compiler cannot know: the next call waits for this one
+            return table + (out[0, 0, 0, 0].astype(jnp.float32) > 1e30).astype(jnp.int32)
+
+        return jax.lax.fori_loop(0, calls, one, table)
+
+    args = (q, pool, jnp.asarray(table), jnp.asarray(positions), jnp.asarray(lens))
+    out = jax.jit(kernel)(*args)
+    finite = bool(jnp.isfinite(out.astype(jnp.float32)).all())
+    # the first row against the gather, its live tokens: the largest difference over the largest entry
+    from deepspeed_tpu.inference import paged
+
+    want = jax.jit(lambda q, pool, table, pos, n: paged._xla_latent_paged_attention(
+        q, pool, table, pos, BS, scale, V, new_lens=n))(*(a[:1] if a is not pool else a for a in args))
+    live = int(lens[0])
+    want, got = want[0, :live].astype(jnp.float32), out[0, :live].astype(jnp.float32)
+    err = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    jax.block_until_ready(many(*args))
+    times = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        jax.block_until_ready(many(*args))
+        times.append((time.perf_counter() - t0) / calls)
+    traced = instruction_seconds(lambda: jax.block_until_ready(many(*args)))
+    c = counts(shape, positions, lens, tq, ppcb)
+    host = float(np.median(times))
+    s = traced[1] / calls if traced[0] else None  # the instruction's own time, or nothing: never the host's clock
+
+    def of_s(f):
+        return None if s is None else f(s)
+
+    return {"shape": shape, "form": "chosen" if form is None else ":".join(str(f) for f in form),
+            "tile_tokens": tq, "pages_a_chunk": ppcb, "seed": seed, "calls": calls,
+            "ms_per_call": of_s(lambda s: 1e3 * s), "host_ms_per_call": 1e3 * host, "instruction": traced[0],
+            "grid_steps": c["grid_steps"], "rows": c["rows"], "chunk_steps": c["chunk_steps"],
+            "us_per_512x256_scores": of_s(lambda s: 1e6 * s / (c["scores"] / (512 * 256))),
+            "live_tflop": c["live_flops"] / 1e12,
+            "bf16_peak_pct_on_live_work": of_s(lambda s: 100 * c["live_flops"] / s / BF16_PEAK),
+            "finite": finite, "row0_err_against_the_gather": err}
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--shapes", nargs="+", default=list(SHAPES), choices=list(SHAPES))
+    ap.add_argument("--forms", default="", help="tokens a tile:pages a chunk[,...]; default: what the kernel picks")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--calls", type=int, default=0, help="calls a timing; default 24 a prompt shape, 200 a decode shape")
+    ap.add_argument("--out", default="chiprun_out/latent_kernel_bench.jsonl")
+    a = ap.parse_args()
+
+    import jax
+
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    if jax.default_backend() != "tpu":
+        print("no chip: a kernel's time comes only from a chip run", file=sys.stderr)
+        return 1
+    forms = [tuple(int(x) for x in f.split(":")) for f in a.forms.split(",") if f] or [None]
+    os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
+    with open(a.out, "a") as f:
+        for shape in a.shapes:
+            for form in forms:
+                calls = a.calls or (200 if SHAPES[shape][1] == 1 else 24)
+                picks = getattr(pa, "_latent_form", None)
+                try:
+                    line = measure(shape, form, a.seed, calls)
+                except Exception as e:  # a form the compiler refuses (VMEM) is a reading too
+                    line = {"shape": shape, "form": form, "refused": f"{type(e).__name__}: {str(e)[:300]}"}
+                finally:
+                    if picks is not None:
+                        pa._latent_form = picks
+                print(json.dumps(line), flush=True)
+                f.write(json.dumps(line) + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
