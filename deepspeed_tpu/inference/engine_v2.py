@@ -44,6 +44,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from pydantic import Field
 
 from deepspeed_tpu.config.config_utils import DeepSpeedConfigModel
+from deepspeed_tpu.diagnostics.anomaly import CallLog, CallRecord, StallRecord
 from deepspeed_tpu.inference.config import QuantConfig, ServingSLOConfig
 from deepspeed_tpu.inference.lifecycle import LifecycleTracker
 from deepspeed_tpu.inference.paged import (
@@ -72,7 +73,7 @@ from deepspeed_tpu.parallel.autotp import place_parameters
 from deepspeed_tpu.telemetry import get_tracer
 from deepspeed_tpu.telemetry.fleet import note_step as _fleet_note_step
 from deepspeed_tpu.topology.mesh import build_mesh, set_mesh
-from deepspeed_tpu.utils.logging import log_dist
+from deepspeed_tpu.utils.logging import log_dist, logger
 
 
 class RaggedInferenceConfig(DeepSpeedConfigModel):
@@ -233,7 +234,7 @@ class _ChainInFlight:
     eos_id: Optional[int]
     sample_kw: Tuple
     rng_in: Any             # the key it was dispatched with, the caller's own object
-    dispatched_at: float    # perf_counter inside its serve:dispatch span
+    call: CallRecord        # its record in ``engine.calls``: the stamps of its serve:dispatch span
     # the program's outputs, on the device
     out: jax.Array          # [n_rows, k] tokens
     emitted: jax.Array      # [n_rows]
@@ -245,6 +246,65 @@ class _ChainInFlight:
     moved: np.ndarray       # [n] bool
     # ahead only, set once the chain before it is fetched:
     last: Optional[np.ndarray] = None   # [n] the token each row starts from
+
+
+class _CallSpan:
+    """A call's ``serve:dispatch`` or ``serve:fetch`` span with the host's
+    stamps where it opens and closes (``CallRecord``): the one place that
+    writes either. A fetch that closes gives the call its cadence, on the span
+    as ``cadence_ms``, and hands a verdict on the slow call before it, if one
+    is due, to ``InferenceEngineV2._stalled`` once the span is closed."""
+
+    __slots__ = ("_engine", "_rec", "_fetch", "_span", "_inner")
+
+    def __init__(self, engine: "InferenceEngineV2", rec: CallRecord, fetch: bool, args: Dict[str, Any]):
+        self._engine, self._rec, self._fetch = engine, rec, fetch
+        self._span = engine._tracer.span("serve:fetch" if fetch else "serve:dispatch", kind=rec.kind, **args)
+
+    def __enter__(self):
+        self._inner = self._span.__enter__()
+        stamp = self._engine._log.stamp()
+        if self._fetch:
+            self._rec.fetch_open = stamp
+        else:
+            self._rec.dispatch_open = stamp
+        return self._inner
+
+    def __exit__(self, *exc):
+        log, rec = self._engine._log, self._rec
+        stamp = log.stamp()
+        if not self._fetch:
+            rec.dispatch_close = stamp
+            log.host_span("serve:dispatch", stamp.wall - rec.dispatch_open.wall)
+            return self._span.__exit__(*exc)
+        rec.fetch_close = stamp
+        stall = None
+        if exc[0] is None:
+            stall = log.fetched(rec)
+            self._inner.set_metadata(cadence_ms=round(rec.cadence_s * 1e3, 3))
+        out = self._span.__exit__(*exc)
+        if stall is not None:
+            self._engine._stalled(stall)
+        return out
+
+
+class _HostSpan:
+    """A host span of the loop that the call log times (two clock reads): of
+    those between two fetches the longest is named where the host was busy."""
+
+    __slots__ = ("_log", "_name", "_span", "_t0")
+
+    def __init__(self, log: CallLog, name: str, span):
+        self._log, self._name, self._span = log, name, span
+
+    def __enter__(self):
+        inner = self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return inner
+
+    def __exit__(self, *exc):
+        self._log.host_span(self._name, time.perf_counter() - self._t0)
+        return self._span.__exit__(*exc)
 
 
 def build_hf_engine(
@@ -574,6 +634,12 @@ class InferenceEngineV2:
         self.tokens_decoded = 0        # decode tokens produced by generate()
         self.chain_steps = 0           # decode-chain dispatches (fleet liveness)
         self.chains_ahead = 0          # of the chains, dispatched while the one before was unfetched
+        # The call log (always on, like the counters above: a few clock reads
+        # a call of 80-200 ms): every device call's host stamps in
+        # ``self.calls``, and a slow call with its cause in ``self.stalls``
+        # (diagnostics/anomaly.py; docs/diagnostics.md, "A slow call").
+        self._log = CallLog()
+        self._clock = time.perf_counter  # of the loop's open-loop arrivals
         # prefix-cache + speculative accounting (plain int adds; the serving
         # benchmark and the router smoke read these)
         self.prefill_tokens_total = 0  # prompt tokens submitted for prefill
@@ -607,6 +673,37 @@ class InferenceEngineV2:
     def _rows_at(a, at) -> np.ndarray:
         """A program's padded output on the host, cut to the rows ``at``."""
         return np.asarray(a[at]) if isinstance(at, slice) else np.asarray(a)[at]
+
+    # ---------------------------------------------------------------- the call log
+    @property
+    def calls(self):
+        """The last few thousand device calls (``CallRecord``), oldest first."""
+        return self._log.calls
+
+    @property
+    def stalls(self):
+        """The slow calls among them, each with its cause (``StallRecord``)."""
+        return self._log.stalls
+
+    def _dispatching(self, rec: CallRecord, **args) -> _CallSpan:
+        return _CallSpan(self, rec, False, args)
+
+    def _fetching(self, rec: CallRecord, **args) -> _CallSpan:
+        return _CallSpan(self, rec, True, args)
+
+    def _host_span(self, name: str, **args) -> _HostSpan:
+        return _HostSpan(self._log, name, self._tracer.span(name, **args))
+
+    def _stalled(self, stall: StallRecord) -> None:
+        """A slow call has its verdict: the line an untraced run's output
+        keeps, the flight recorder's ring, and the ``serve:stall`` span, which
+        in a traced run stands beside the device plane that can confirm it."""
+        logger.warning(stall.line())
+        if self._recorder is not None:
+            self._recorder.record(stall.chain, stall._asdict(), stall=stall.cause)
+        with self._tracer.span("serve:stall", **stall.span_args()):
+            self._tracer.count("serving/stalls", 1.0)
+            self._tracer.count("serving/stall_s", stall.excess_s)
 
     # ---------------------------------------------------------------- admission
     def query(self, uid: int) -> Tuple[int, int]:
@@ -1091,7 +1188,7 @@ class InferenceEngineV2:
 
     # ---------------------------------------------------------------- put
     def _build_batch(self, uids, token_lists) -> RaggedBatch:
-        with self._tracer.span("serve:assemble", rows=len(uids)):
+        with self._host_span("serve:assemble", rows=len(uids)):
             return build_ragged_batch(
                 self.state, uids, token_lists, self.max_pages,
                 self.config.row_bucket, self.config.chunk_bucket,
@@ -1111,7 +1208,8 @@ class InferenceEngineV2:
             raise RuntimeError("insufficient KV blocks/slots; call can_schedule first")
         batch = self._build_batch(uids, token_lists)
         step = self._step_fn(batch.n_rows, batch.tokens.shape[1])
-        with self._tracer.span("serve:dispatch", kind="put", rows=batch.n_rows,
+        call = self._log.open("put", -1, batch.n_rows, batch.tokens.shape[1])
+        with self._dispatching(call, rows=batch.n_rows,
                                **self._eva_args(batch.positions, batch.new_lens),
                                **self._state_args(len(uids))):
             logits, self._pools, *picks = step(
@@ -1122,8 +1220,10 @@ class InferenceEngineV2:
         self.dispatch_count += 1
         self._log_picks(picks, uids, None, token_lists, at=batch.at)
         self.windows_closed += self._advance(uids, map(len, token_lists))
+        with self._fetching(call):
+            out = self._rows_at(logits, batch.at)
         self.host_sync_count += 1
-        return self._rows_at(logits, batch.at)
+        return out
 
     def _log_picks(self, picks, uids, rids, token_lists=None, flight=None, emitted=None, at=None) -> None:
         """While somebody asked (``picks_log`` is a list), note one dispatch's
@@ -1205,7 +1305,8 @@ class InferenceEngineV2:
         logits transfer."""
         batch = self._build_batch(uids, token_lists)
         step = self._sample_step_fn(batch.n_rows, batch.tokens.shape[1], sample_kw)
-        with self._tracer.span("serve:dispatch", kind="prefill", rows=batch.n_rows,
+        call = self._log.open("prefill", -1, batch.n_rows, batch.tokens.shape[1])
+        with self._dispatching(call, rows=batch.n_rows,
                                live=len(uids), tokens=int(batch.new_lens.sum()),
                                rids=self._span_rids(rids),
                                **self._eva_args(batch.positions, batch.new_lens),
@@ -1221,7 +1322,7 @@ class InferenceEngineV2:
         self.dispatch_count += 1
         self._log_picks(picks, uids, rids, token_lists, at=batch.at)
         self.windows_closed += self._advance(uids, map(len, token_lists))
-        with self._tracer.span("serve:fetch", kind="prefill"):
+        with self._fetching(call):
             out = self._rows_at(toks, batch.at)
         self.host_sync_count += 1
         return out, rng
@@ -1263,7 +1364,7 @@ class InferenceEngineV2:
         chain: its outputs are the same, and the ``active`` it hands back then
         means "goes on after this chain", which is what the next one needs."""
         ahead = before is not None
-        with self._tracer.span("serve:assemble", kind="chain", rows=n_rows, chain=chain_id):
+        with self._host_span("serve:assemble", kind="chain", rows=n_rows, chain=chain_id):
             # pre-extend every row's block table for its share of the K-token
             # window (capped by the row's remaining budget — no KV slots are
             # reserved past max_new_tokens) so the compiled program never
@@ -1285,16 +1386,16 @@ class InferenceEngineV2:
             eva_args = {} if self._layout is None else self._eva_args(
                 start[:, None] + np.arange(k)[None, :], share)
         chain = self._chain_fn(n_rows, k, eos_id, sample_kw)
-        with self._tracer.span("serve:dispatch", kind="chain", rows=n_rows, live=len(uids),
+        call = self._log.open("chain", chain_id, n_rows, k)
+        with self._dispatching(call, rows=n_rows, live=len(uids),
                                k=k, chain=chain_id, ahead=int(ahead), **eva_args,
                                **self._state_args(share.sum())):
-            dispatched_at = time.perf_counter()
             if ahead:
                 tokens, pos, active = before.carry
                 tables, chain_budgets = self._place(buf, "tables", "budgets")
             else:
                 if tracker is not None and rids is not None:
-                    tracker.mark_dispatch(rids, "chain", now=dispatched_at)
+                    tracker.mark_dispatch(rids, "chain", now=call.dispatched_at)
                 tokens, pos, tables, active, chain_budgets = self._place(
                     buf, "tokens", "pos", "tables", "active", "budgets")
             out, emitted, active, tok, pos, rng_out, self._pools, *routed = chain(
@@ -1306,7 +1407,7 @@ class InferenceEngineV2:
         return _ChainInFlight(
             chain_id=chain_id, n_rows=n_rows, uids=list(uids), at=np.asarray(at), start=start,
             budgets=np.asarray(budgets), k=k, eos_id=eos_id, sample_kw=sample_kw, rng_in=rng,
-            dispatched_at=dispatched_at, out=out, emitted=emitted, carry=(tok, pos, active),
+            call=call, out=out, emitted=emitted, carry=(tok, pos, active),
             rng_out=rng_out, routed=tuple(routed), moved=np.zeros(len(uids), bool))
 
     def _chain_ahead(self, flight: _ChainInFlight, budgets: np.ndarray) -> Optional[_ChainInFlight]:
@@ -1397,7 +1498,7 @@ class InferenceEngineV2:
             self._take_ahead(flight, uids, last_tokens, budgets, k, rng, eos_id, sample_kw)
             self._ahead = None
             if tracker is not None and rids is not None:
-                tracker.mark_dispatch(rids, "chain", now=flight.dispatched_at)
+                tracker.mark_dispatch(rids, "chain", now=flight.call.dispatched_at)
         else:
             at, rows = self.state.rows_of(uids, self.config.row_bucket)
             flight = self._dispatch_chain(
@@ -1407,7 +1508,7 @@ class InferenceEngineV2:
         if ahead:
             self._ahead = self._chain_ahead(flight, budgets)
         routed = flight.routed
-        with self._tracer.span("serve:fetch", kind="chain", chain=flight.chain_id):
+        with self._fetching(flight.call, chain=flight.chain_id):
             # the padded outputs themselves, cut down in numpy: a slice on the
             # device is a program, and would wait behind the chain ahead
             out = np.asarray(flight.out)[flight.at]
@@ -1469,8 +1570,8 @@ class InferenceEngineV2:
         m = 1 + n_spec
         rows = -(-n // self.config.row_bucket) * self.config.row_bucket
         chain_id = self.chain_steps
-        with self._tracer.span("serve:assemble", kind="spec_chain", rows=rows,
-                               chain=chain_id):
+        with self._host_span("serve:assemble", kind="spec_chain", rows=rows,
+                             chain=chain_id):
             buf = self._chain_arrays(rows)
             sb = self._spec_buf.get(rows)
             if sb is None:
@@ -1492,7 +1593,8 @@ class InferenceEngineV2:
             buf["active"][:n] = True
             buf["budgets"][:n] = np.minimum(budgets, k * m)
         chain = self._spec_chain_fn(rows, k, eos_id)
-        with self._tracer.span("serve:dispatch", kind="spec_chain", rows=rows,
+        call = self._log.open("spec_chain", chain_id, rows, k)
+        with self._dispatching(call, rows=rows,
                                live=n, k=k, n_spec=n_spec, chain=chain_id):
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "chain")
@@ -1504,7 +1606,7 @@ class InferenceEngineV2:
                 jnp.asarray(sb["hist"]), jnp.asarray(sb["hist_len"]),
             )
         self.dispatch_count += 1
-        with self._tracer.span("serve:fetch", kind="spec_chain", chain=chain_id):
+        with self._fetching(call, chain=chain_id):
             out = np.asarray(out[:n])
             emitted = np.asarray(emitted[:n])
             steps = np.asarray(steps[:n])
@@ -1654,7 +1756,7 @@ class InferenceEngineV2:
                     )
             sample_kw = (("do_sample", do_sample), ("temperature", temperature),
                          ("top_k", top_k), ("top_p", top_p))
-            t_start = time.perf_counter()
+            t_start = self._clock()
             arr: Optional[List[float]] = None
             if arrival_times is not None:
                 if len(arrival_times) != len(prompts):
@@ -1732,7 +1834,7 @@ class InferenceEngineV2:
                     tracker.finish(idx)
 
         pc = self.prefix_cache
-        span = self._tracer.span
+        span = self._host_span
         token_budget = self.config.max_ragged_batch_size
         while queue or active or self._ahead is not None:
             # ---- admit pending prompts (fused prefill + first-token sample): one
@@ -1741,6 +1843,7 @@ class InferenceEngineV2:
             # Not while a chain is in flight ahead: its rows are settled, and a
             # seat that an EOS freed is seen one chain later.
             admitted = False
+            not_due = False  # the queue's head had not arrived when the admission pass looked
             while self._ahead is None:
                 adm_uids: List[int] = []
                 adm_tokens: List[np.ndarray] = []
@@ -1752,7 +1855,8 @@ class InferenceEngineV2:
                     call_full = False
                     while queue and len(active) < self.config.max_seqs:
                         idx = queue[0]
-                        if arr is not None and time.perf_counter() - t_start < arr[idx]:
+                        if arr is not None and self._clock() - t_start < arr[idx]:
+                            not_due = True
                             break  # open-loop workload: not arrived yet
                         cand = context(idx)
                         if token_budget is not None and adm_uids:
@@ -1799,12 +1903,12 @@ class InferenceEngineV2:
                     break
             if not active and self._ahead is None:
                 if queue and not admitted:
-                    if arr is not None:
-                        wait = t_start + arr[queue[0]] - time.perf_counter()
+                    if not_due:
+                        wait = t_start + arr[queue[0]] - self._clock()
                         if wait > 0:  # idle until the next synthetic arrival
-                            with span("serve:idle_wait", queue_len=len(queue)):
+                            with self._tracer.span("serve:idle_wait", queue_len=len(queue)):
                                 time.sleep(min(wait, 0.05))
-                            continue
+                        continue  # (it may have fallen due since the pass looked: go round again)
                     raise RuntimeError(
                         f"KV pool too small for a single sequence "
                         f"({self.num_kv_blocks} blocks x {self.config.kv_block_size})"
@@ -1912,7 +2016,10 @@ class InferenceEngineV2:
                     for t in out[i, : emitted[i]]:
                         if u in active:
                             accept(u, t)
-        with span("serve:finish", requests=len(prompts)):
+        with self._tracer.span("serve:finish", requests=len(prompts)):
+            stall = self._log.settle()  # of the last call, if it was slow: none follows to witness it
+            if stall is not None:
+                self._stalled(stall)
             if tracker is not None:
                 # final refresh: the last finishes land after the last chain
                 # boundary's sample, so goodput/tokens-per-s see them here

@@ -28,6 +28,7 @@ breakdowns come from the same source of truth as the trace.
 
 from __future__ import annotations
 
+import gc
 import os
 import threading
 import time
@@ -427,6 +428,44 @@ def env_enabled() -> bool:
 
 
 _tracer = Tracer(enabled=env_enabled())
+
+
+class _Collector:
+    """The garbage collector as a span: one ``gc.callbacks`` entry for the
+    process. A collection is a ``dstpu:gc`` annotation of the ``jax.profiler``
+    trace (``generation``; ``collected`` once it is over), so a traced run's
+    idle table puts a gap it caused down to ``gc``, and its seconds add up
+    process-wide whether anybody traces or not: the serving loop's call log
+    reads them at every edge of a call (``diagnostics/anomaly.py``). It is the
+    BARE annotation, never ``Tracer.span``: a collection runs on whichever
+    thread trips it, at any allocation, so also inside a section that holds
+    ``Tracer._lock``, which is not reentrant. The collector runs one
+    collection at a time, so one open span is all there is to keep."""
+
+    __slots__ = ("seconds", "_span", "_t0")
+
+    def __init__(self):
+        self.seconds, self._span = 0.0, None
+
+    def __call__(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            self._span = TraceAnnotation(SPAN_PREFIX + "gc", generation=info["generation"])
+            self._span.__enter__()
+            self._t0 = time.perf_counter()
+        elif self._span is not None:
+            self.seconds += time.perf_counter() - self._t0
+            span, self._span = self._span, None
+            span.set_metadata(collected=info["collected"])
+            span.__exit__(None, None, None)
+
+
+_collector = _Collector()
+gc.callbacks.append(_collector)
+
+
+def gc_seconds() -> float:
+    """Seconds this process has spent in garbage collections so far."""
+    return _collector.seconds
 
 
 def get_tracer() -> Tracer:

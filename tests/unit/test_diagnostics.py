@@ -297,6 +297,228 @@ def test_step_time_straggler_and_regression_flags():
     assert gauges["anomaly/t_regression"] == 1.0
 
 
+def test_the_rule_is_written_once_and_the_detector_uses_it(monkeypatch):
+    """``beyond`` is the median + MAD test: past the median by more than
+    ``mads`` MADs, a MAD of at least 1% of the median. The detector's verdict
+    is its verdict."""
+    from deepspeed_tpu.diagnostics import anomaly
+
+    assert anomaly.beyond([0.10, 0.11, 0.09, 0.10, 0.12], 0.17, 6.0) == (True, 0.10, pytest.approx(0.01))
+    assert anomaly.beyond([0.10, 0.11, 0.09, 0.10, 0.12], 0.159, 6.0)[0] is False
+    slow, med, mad = anomaly.beyond([0.1] * 8, 0.1061, 6.0)  # identical timings: the floor, 1% of the median
+    assert (slow, med, mad) == (True, 0.1, pytest.approx(0.001))
+    assert anomaly.beyond([0.1] * 8, 0.1059, 6.0)[0] is False
+    asked = []
+    monkeypatch.setattr(anomaly, "beyond", lambda prior, value, mads: asked.append((len(prior), value, mads))
+                        or (True, 0.1, 0.001))
+    det = StepTimeAnomalyDetector(min_samples=8, straggler_mads=5.0, name="u", tracer=get_tracer())
+    for _ in range(9):
+        flags = det.observe(0.1)
+    assert flags["straggler"] and asked == [(8, 0.1, 5.0)]
+
+
+# ------------------------------------------------------------- a slow call
+class _Host:
+    """The injected clock: what ``host_stamp`` would read, moved by hand."""
+
+    def __init__(self):
+        self.wall = self.cpu = self.gc_s = 0.0
+
+    def stamp(self):
+        from deepspeed_tpu.diagnostics.anomaly import Stamp
+
+        return Stamp(self.wall, self.cpu, self.gc_s)
+
+    def work(self, s):       # the thread runs
+        self.wall, self.cpu = self.wall + s, self.cpu + s
+
+    def wait(self, s):       # it waits for the device, or does not run and does not know why: no CPU either way
+        self.wall += s
+
+    away = wait
+
+    def collect(self, s):
+        self.wall, self.cpu, self.gc_s = self.wall + s, self.cpu + s, self.gc_s + s
+
+
+class _Loop:
+    """``decode_chain`` as the serving loop drives it with a chain ahead: call
+    N dispatches chain N+1, then fetches chain N; between two calls the host
+    accepts and schedules."""
+
+    def __init__(self):
+        from deepspeed_tpu.diagnostics.anomaly import CallLog
+
+        self.host = _Host()
+        self.log = CallLog(stamp=self.host.stamp)
+        self.stalls = []
+        self.flight = self._dispatch(0)
+
+    def _dispatch(self, chain):
+        rec = self.log.open("chain", chain, 64, 8)
+        rec.dispatch_open = self.host.stamp()
+        self.host.work(0.001)
+        rec.dispatch_close = self.host.stamp()
+        self.log.host_span("serve:dispatch", 0.001)
+        return rec
+
+    def call(self, in_fetch=None, ahead=True):
+        """One ``decode_chain``; ``in_fetch()`` is what happens while the host
+        is inside ``serve:fetch`` (by default it waits 0.1 s for the chain)."""
+        cur = self.flight
+        self.flight = self._dispatch(cur.chain + 1) if ahead else None
+        cur.fetch_open = self.host.stamp()
+        (in_fetch or (lambda: self.host.wait(0.1)))()
+        cur.fetch_close = self.host.stamp()
+        stall = self.log.fetched(cur)
+        if stall is not None:
+            self.stalls.append(stall)
+        self.host.work(0.002)
+        self.log.host_span("serve:accept", 0.002)
+        return cur
+
+    def steady(self, n=12):
+        for _ in range(n):
+            self.call()
+        assert not self.stalls and self.log.settle() is None
+        return self
+
+
+def test_a_steady_loop_stalls_nowhere_and_a_small_excess_is_no_stall():
+    loop = _Loop().steady(80)
+    assert len(loop.log.calls) == 81 and loop.log.calls[5].cadence_s == pytest.approx(0.103)
+    loop.call(lambda: loop.host.wait(0.1 + 0.24))  # past 6 MADs, under a quarter of a second
+    loop.steady(3)
+    assert not loop.log.stalls
+
+
+def test_a_class_is_judged_from_its_fourth_call_on():
+    loop = _Loop()
+    for _ in range(3):
+        loop.call()
+    loop.call(lambda: loop.host.wait(3.0))  # the class has three cadences: judged
+    loop.call()
+    assert [s.chain for s in loop.stalls] == [3]
+    early = _Loop()
+    early.call(), early.call()
+    early.call(lambda: early.host.wait(3.0))  # two cadences: not yet
+    early.call()
+    assert not early.stalls
+
+
+def test_a_late_device_makes_the_chain_queued_behind_it_wait_its_usual_time():
+    loop = _Loop().steady()
+    slow = loop.call(lambda: loop.host.wait(2.1))
+    assert not loop.stalls  # decided when the NEXT call is fetched
+    loop.call()
+    (stall,) = loop.stalls
+    assert (stall.cause, stall.chain, stall.kind, stall.rows, stall.k) == ("device_late", slow.chain, "chain", 64, 8)
+    assert stall.seconds == pytest.approx(2.103) and stall.excess_s == pytest.approx(2.0)
+    assert stall.in_fetch_s == pytest.approx(2.1) and stall.usual_fetch_s == pytest.approx(0.1)
+    assert stall.cpu_s == pytest.approx(0.003) and stall.fetch_cpu_s == 0.0 and stall.gc_s == 0.0
+    assert stall.next_wait_s == pytest.approx(0.1) and stall.usual_next_wait_s == pytest.approx(0.1)
+    assert slow.cadence_s == stall.seconds and list(loop.log.stalls) == [stall]
+
+
+def test_a_host_that_froze_in_the_fetch_finds_the_next_chain_ready_at_once():
+    loop = _Loop().steady()
+    slow = loop.call(lambda: loop.host.away(2.1))  # stopped from outside while it waited: no switch is counted
+    loop.call(lambda: loop.host.wait(0.0004))      # the device went on while the host did not
+    (stall,) = loop.stalls
+    assert (stall.cause, stall.chain) == ("host_not_running", slow.chain)
+    assert stall.in_fetch_s == pytest.approx(2.1) and stall.next_wait_s == pytest.approx(0.0004)
+
+
+def test_a_host_that_slept_between_two_calls_finds_its_own_chain_ready_at_once():
+    loop = _Loop().steady()
+    loop.host.away(2.0)  # between two calls, the chain ahead in flight
+    slow = loop.call(lambda: loop.host.wait(0.0004))
+    loop.call()
+    (stall,) = loop.stalls
+    assert (stall.cause, stall.chain) == ("host_not_running", slow.chain)
+    assert stall.excess_s == pytest.approx(1.9, abs=0.01) and stall.in_fetch_s == pytest.approx(0.0004)
+    assert stall.next_wait_s == stall.in_fetch_s and stall.cpu_s == pytest.approx(0.003)
+
+
+def test_a_serial_call_has_no_witness_and_reads_unknown():
+    """Nothing in flight, so no chain to witness: a host that lost its core
+    inside a serial call's dispatch, or a device that was late under its
+    fetch, leaves the same stamps as the other would."""
+    for where in ("dispatch", "fetch"):
+        loop = _Loop().steady()
+        loop.call(ahead=False)
+        loop.flight = loop.log.open("chain", 99, 64, 8)
+        loop.flight.dispatch_open = loop.host.stamp()
+        loop.host.away(1.5 if where == "dispatch" else 0.001)
+        loop.flight.dispatch_close = loop.host.stamp()
+        loop.call((lambda: loop.host.wait(1.6)) if where == "fetch" else None, ahead=False)
+        stall = loop.log.settle()
+        assert (stall.cause, stall.chain, stall.next_wait_s) == ("unknown", 99, -1.0), where
+        assert stall.cpu_s == pytest.approx(0.0) and stall.excess_s == pytest.approx(1.5, abs=0.01)
+
+
+def test_the_collector_s_seconds_name_it():
+    loop = _Loop().steady()
+    loop.host.collect(1.0)
+    slow = loop.call(lambda: loop.host.wait(0.0004))
+    loop.call()
+    (stall,) = loop.stalls
+    assert (stall.cause, stall.chain, stall.gc_s) == ("collector", slow.chain, pytest.approx(1.0))
+    assert stall.cpu_s == pytest.approx(1.003)
+
+
+def test_a_busy_host_is_named_with_the_span_it_was_busy_in():
+    loop = _Loop().steady()
+    loop.host.work(1.2)
+    loop.log.host_span("serve:schedule", 1.2)  # a compilation, a long loop: the program's own host code
+    slow = loop.call(lambda: loop.host.wait(0.0004))
+    loop.call()
+    (stall,) = loop.stalls
+    assert (stall.cause, stall.span, stall.chain) == ("host_busy", "serve:schedule", slow.chain)
+    assert "span=serve:schedule cause=host_busy" in stall.line()
+
+
+def test_what_the_evidence_does_not_decide_reads_unknown_with_its_numbers():
+    # the last call of a generate: nothing follows to witness it (settled at serve:finish)
+    loop = _Loop().steady()
+    loop.call(lambda: loop.host.wait(2.1), ahead=False)
+    stall = loop.log.settle()
+    assert (stall.cause, stall.next_wait_s, stall.in_fetch_s) == ("unknown", -1.0, pytest.approx(2.1))
+    assert loop.log.settle() is None
+    # the next chain waited neither its usual time nor none
+    loop = _Loop().steady()
+    loop.call(lambda: loop.host.wait(2.1))
+    loop.call(lambda: loop.host.wait(0.04))
+    assert [s.cause for s in loop.stalls] == ["unknown"]
+    # the excess lay in the fetch, and the thread was busy there
+    loop = _Loop().steady()
+    loop.call(lambda: loop.host.work(2.1))
+    loop.call()
+    assert [s.cause for s in loop.stalls] == ["unknown"] and loop.stalls[0].fetch_cpu_s == pytest.approx(2.1)
+    line = loop.stalls[0].line()
+    assert line.startswith("[serving] stall: chain=12 kind=chain rows=64 k=8 seconds=2.10")
+    for name in ("excess_s", "in_fetch_s", "usual_fetch_s", "cpu_s", "fetch_cpu_s", "gc_s",
+                 "next_wait_s", "usual_next_wait_s", "span=-", "cause=unknown"):
+        assert name in line
+    assert set(loop.stalls[0].span_args()) == {"chain", "kind", "rows", "seconds", "excess_s", "in_fetch_s", "cpu_s",
+                                               "gc_s", "next_wait_s", "cause"}
+
+
+def test_the_host_s_own_stamp_reads_this_thread():
+    import gc
+
+    from deepspeed_tpu.diagnostics.anomaly import CAUSES, host_stamp
+    from deepspeed_tpu.telemetry import tracer as tracer_mod
+
+    a = host_stamp()
+    sum(i * i for i in range(200_000))
+    gc.collect()
+    b = host_stamp()
+    assert b.wall > a.wall and b.cpu > a.cpu and b.gc_s > a.gc_s and b.gc_s == tracer_mod.gc_seconds()
+    assert a._fields == ("wall", "cpu", "gc_s")  # two clock reads and an attribute: no system call beyond them
+    assert b.cpu - a.cpu <= (b.wall - a.wall) * 1.5 and CAUSES[-1] == "unknown" and len(CAUSES) == 5
+
+
 # ----------------------------------------------------------- flight recorder
 def test_flight_recorder_ring_and_dump_schema(tmp_path):
     """≥8 step records with health verdicts survive in the dump; the ring
