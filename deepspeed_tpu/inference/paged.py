@@ -661,15 +661,15 @@ def _forward_hidden(
             return _mlp(p, cfg, h), None
 
     def mixed_at(lp, key, streams):
-        """A sublayer's hyper-connection ``lp[key]``: its mix of every token and its read."""
+        """A sublayer's hyper-connection ``lp[key]``: its mix of every token and its read
+        (on the chip, at sizes it takes, the kernel ``mhc_mix_read``: one pass over the streams)."""
         with reading(lp, key) as p:
-            mixed = mhc.mix(streams, p["phi"], p["b"], p["alpha"], norm_eps=cfg.norm_eps,
-                            iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps, clamp=cfg.hc_res_clamp)
-            return mixed, mhc.read(streams, mixed)
+            return mhc.mix_read(streams, p["phi"], p["b"], p["alpha"], norm_eps=cfg.norm_eps,
+                                iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps, clamp=cfg.hc_res_clamp)
 
     def written(lp, key, streams, out, mixed):
-        with reading(lp, key):
-            return mhc.write(streams, out, mixed)
+        with reading(lp, key):  # (the kernel ``mhc_write`` where ``mhc_mix_read`` made the mix: over the streams)
+            return mhc.write_back(streams, out, mixed)
 
     # Scopes for a device trace (HLO metadata only). ``pool_scan`` encloses
     # the layer scan; everything the body computes sits under ``layer`` (or

@@ -39,6 +39,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.ops.registry import dispatch, register
+
 F32 = jnp.float32
 
 
@@ -99,6 +101,39 @@ def write(streams: jax.Array, y: jax.Array, mixed: Mix) -> jax.Array:
         return jnp.stack([
             (sum(mixed.res[i, j][..., None] * xf[j] for j in range(n))
              + mixed.post[i][..., None] * yf).astype(streams.dtype) for i in range(n)])
+
+
+@register("mhc_mix_read", "xla")
+def _xla_mix_read(streams, phi, b, alpha, **sizes):
+    mixed = mix(streams, phi, b, alpha, **sizes)
+    return mixed, read(streams, mixed)
+
+
+register("mhc_write", "xla")(write)
+
+
+def mix_read(streams: jax.Array, phi: jax.Array, b: jax.Array, alpha: jax.Array, *, norm_eps: float, iters: int,
+             eps: float, clamp: Tuple[float, float], impl: str = "auto"):
+    """A sublayer's :func:`mix` and its :func:`read`: ``(mixed, u)``, ``mixed``
+    for :func:`write_back` alone. On the TPU, at sizes it takes, one kernel
+    that reads the streams once (``ops/pallas/mhc.py``, whose ``mixed`` is the
+    coefficients as its write-back reads them); else the two functions above
+    and their :class:`Mix`."""
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import mhc as kernels  # (registers the kernels)
+
+    if impl == "auto":
+        n, C = streams.shape[0], streams.shape[-1]
+        # (the wider of the streams' dtype and ``phi``'s: the kernel holds ``phi`` in the product's operand type)
+        takes = kernels.takes(n, streams.size // (n * C), C, jnp.promote_types(streams.dtype, phi.dtype))
+        impl = "pallas" if registry._default_backend() == "tpu" and takes else "xla"
+    return dispatch("mhc_mix_read", impl)(streams, phi, b, alpha, norm_eps=norm_eps, iters=iters, eps=eps, clamp=clamp)
+
+
+def write_back(streams: jax.Array, y: jax.Array, mixed) -> jax.Array:
+    """:func:`write` with the ``mixed`` of :func:`mix_read`, by the
+    implementation that made it: the kernel's writes over ``streams``."""
+    return dispatch("mhc_write", "xla" if isinstance(mixed, Mix) else "pallas")(streams, y, mixed)
 
 
 def spread(x: jax.Array, n: int) -> jax.Array:

@@ -915,6 +915,93 @@ def test_a_routed_chain_reads_the_picked_experts_from_the_stack(one_chip, monkey
     assert not _experts_moved(text, *stack)
 
 
+@pytest.mark.parametrize("n,tokens,C,dtype", [
+    (4, 8 * 2048, 3584, jnp.bfloat16),  # xing4.0-29b-a4b.serve.long-prompt-batch's prefill: 128 tiles of 128 tokens
+    (4, 64, 3584, jnp.bfloat16),        # its chain's step: under one tile (``takes`` leaves it to XLA; it compiles)
+    (4, 8240, 1792, jnp.float32),       # float32 streams at a width ``takes`` admits, a last tile of 48 tokens
+], ids=["xing-prefill", "xing-chain-step", "fp32-ragged"])
+def test_the_hyper_connections_kernels_compile_within_the_default_scope(one_chip, monkeypatch, n, tokens, C, dtype):
+    """``mhc_mix_read`` and ``mhc_write`` (``ops/pallas/mhc.py``) for the
+    described v5e: a 128-token tile of the streams, ``phi`` held once, ``u`` and
+    the mix out; the streams in and out of the write-back a half of ``C`` a
+    step, aliased. Neither sets ``vmem_limit_bytes``: a refusal here is the
+    default 16 MiB scope's (PR 52: a kernel scoped past it takes fast memory
+    from what the compiler keeps there for the whole program)."""
+    from deepspeed_tpu.ops.pallas import mhc
+
+    monkeypatch.setattr(mhc, "_interpret", lambda: False)
+    K = n * n + 2 * n
+    sds = lambda s, dt=dtype: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+    sizes = dict(norm_eps=1e-6, iters=20, eps=1e-6, clamp=(-30.0, 30.0))
+    assert mhc.takes(n, tokens, C, dtype) == (tokens > 64)
+    mix_read = jax.jit(lambda x, phi, b, alpha: mhc.mhc_mix_read(x, phi, b, alpha, **sizes)).lower(
+        sds((n, tokens, C)), sds((n * C, K)), sds((K,)), sds((3,))).compile()
+    write = jax.jit(mhc.mhc_write, donate_argnums=0).lower(
+        sds((n, tokens, C)), sds((tokens, C)), sds((K, -(-tokens // 128) * 128), jnp.float32)).compile()
+    for compiled, name in ((mix_read, "mhc_mix_read"), (write, "mhc_write")):
+        text = compiled.as_text()
+        assert sum("tpu_custom_call" in line and name in line for line in text.splitlines()) == 1
+        assert "vmem_limit_bytes" not in text
+    # the streams come back in the buffer they came in: no second array of them
+    assert write.memory_analysis().alias_size_in_bytes == n * tokens * C * jnp.dtype(dtype).itemsize
+
+
+def test_the_xing_prefill_holds_no_copy_of_the_streams(one_chip, monkeypatch):
+    """``xing4.0-29b-a4b.serve.long-prompt-batch``'s ``(8, 2048)`` ``step``
+    whole, for the described v5e: two dense layers and the scan's body hold six
+    ``mhc_mix_read`` and six ``mhc_write`` (a sublayer each), the write-back on
+    the scan's own carry, and no instruction copies, transposes or widens to
+    float32 an array of the streams' shape ``[4, 8, 2048, 3584]`` (a kernel on a
+    scan's slice has had its operand copied four times in this repo; the
+    ``jax.numpy`` form kept a float32 copy of a stream, 1.8 ms a layer-call)."""
+    import dataclasses
+
+    from benchmarks.lib import harness, program
+    from deepspeed_tpu.checkpoint.hf import config_from_hf
+    from deepspeed_tpu.inference import paged
+    from deepspeed_tpu.models import CausalLM
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import mhc, moe_decode, norms, paged_attention as pa
+
+    for module in (pa, norms, moe_decode, mhc):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")  # what 'auto' sees there
+    cfg = dataclasses.replace(config_from_hf(program.published(harness.load_config("xing4.0-29b-a4b"))),
+                              dtype=jnp.bfloat16)
+    engine = harness.load_workload("xing4.0-29b-a4b.serve.long-prompt-batch")["engine"]
+    bs, N, C = engine["kv_block_size"], engine["row_bucket"], engine["chunk_bucket"]
+    assert (cfg.hc_mult, N, C, cfg.hidden_size) == (4, 8, 2048, 3584)
+    NB = engine["kv_pool_bytes"] // (bs * cfg.num_layers * paged.latent_pool_width(cfg) * 2)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda key: CausalLM(cfg).init({"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)},
+                                       train=False)["params"], jax.random.PRNGKey(0)))
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: paged.init_pool(cfg, NB, bs, jnp.bfloat16)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def step(params, pool, tokens, positions, new_lens, tables):
+        return paged.ragged_forward(params, cfg, pool, tokens, positions, new_lens, tables, bs, with_picks=True)
+
+    compiled = step.lower(params, pool, i32(N, C), i32(N, C), i32(N), i32(N, engine["max_seq_len"] // bs)).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pool))
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2 ** 30
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    for kernel in ("mhc_mix_read", "mhc_write"):
+        made = [line for line in calls if re.match(r"\s*%?" + kernel + r"[.\d]* = ", line)]
+        assert len(made) == 6, (kernel, len(made))
+        assert all("/mhc/" in line for line in made)  # under the scope the cell's two readers read
+    streams = r"(bf16|f32)\[(4,8,2048|4,16384),3584\]"
+    moved = [line.strip()[:200] for line in text.splitlines()
+             if re.search(r"= \(?%s\S* (copy|copy-start|transpose|convert)\(" % streams, line)]
+    assert not moved, moved
+    # nor does anything under ``mhc`` make a float32 array of ONE stream's shape (the parent's ``slice_convert_fusion``)
+    widened = [line.strip()[:200] for line in text.splitlines()
+               if "/mhc/" in line and re.search(r"= f32\[(1,)?(8,2048|16384),3584\]", line)]
+    assert not widened, widened
+
+
 @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["bf16", "int8"])
 def test_decode_chain_updates_the_pool_in_place(one_chip, monkeypatch, kv_quant):
     """Whether a carried array is updated in place is the chip's compiler's

@@ -67,6 +67,17 @@ def _moe(x):
     return moe_decode(x, jnp.ones((8, 4)), w, w, w, 1, "silu_glu")
 
 
+def _mhc(write):
+    from deepspeed_tpu.ops.pallas import mhc
+
+    def sublayer(x):
+        mixed, u = mhc.mhc_mix_read(x, jnp.ones((2 * 128, 8)), jnp.ones((8,)), jnp.ones((3,)), norm_eps=1e-6, iters=2,
+                                    eps=1e-6, clamp=(-30.0, 30.0))
+        return mhc.mhc_write(x, u, mixed) if write else u
+
+    return sublayer
+
+
 KERNELS = [
     ("flash_fwd", _flash, (QKV,)),
     ("flash_bwd_dq", jax.grad(_flash), (LONG,)),
@@ -85,6 +96,8 @@ KERNELS = [
     ("ssm_update", _ssm, (jnp.ones((2, 2, 64)),)),
     ("gdn_update", _gdn, (jnp.ones((2, 1, 128)),)),
     ("moe_decode", _moe, (jnp.ones((8, 128)),)),
+    ("mhc_mix_read", _mhc(False), (jnp.ones((2, 16, 128)),)),
+    ("mhc_write", _mhc(True), (jnp.ones((2, 16, 128)),)),
 ]
 
 

@@ -51,6 +51,15 @@
   product read (the count the kernel ``moe_decode`` was handed: every row's
   picks, dead or alive), beside the live rows' ``experts_touched``.
 
+- in a cell with hyper-connections (an instruction under the scope ``mhc``;
+  since PR 54), an ``mhc_part=`` line a program and piece (``mhc_mix``,
+  ``mhc_pre``, ``mhc_post``: device seconds, instructions, and the ms a
+  run of the program, the runs being the occurrences of an instruction
+  outside the layer scan), an ``mhc_kernel=`` line for each of the kernels
+  ``mhc_mix_read`` and ``mhc_write`` (instructions, calls, ms a call: 14 of each
+  a prefill of the xing cell's 7 layers) and an ``mhc_op=`` line for each of the
+  five largest instructions under ``mhc`` in each program;
+
 All are wrapped OUTSIDE the benchmark, before ``run.main`` runs; nothing
 here is read by the program or the benchmark, and a cell's listed metrics
 read what they read without it.
@@ -214,6 +223,35 @@ def gdn_parts(rows, workload_name):
                            ("gdn_update", "conv_update"))
 
 
+MHC_PARTS = ("mhc_mix", "mhc_pre", "mhc_post")
+MHC_KERNELS = ("mhc_mix_read", "mhc_write")
+
+
+def mhc_parts(rows):
+    """The ``mhc_part=``, ``mhc_kernel=`` and ``mhc_op=`` lines of ``hlo_stats``' rows."""
+    for prog in ("step", "chain"):
+        mine = [(r, r["tf_op_name"].rstrip(":").split("/")) for r in rows
+                if r["tf_op_name"].startswith(f"jit({prog})") and r["category"] != "while"
+                and "mhc" in r["tf_op_name"].split("/")]
+        # an instruction outside the layer scan (a leading dense layer's) runs once a run of the program
+        runs = min((int(r["occurrences"]) for r, _ in mine), default=0)
+        for part in MHC_PARTS:
+            of = [r for r, path in mine if part in path]
+            if of:
+                seconds = 1e-6 * sum(float(r["total_self_time"]) for r in of)
+                yield (f"mhc_part={part} program={prog} device_s={seconds} runs={runs} "
+                       f"ms_a_run={1e3 * seconds / runs} instructions={len(of)}")
+        for kernel in MHC_KERNELS:
+            of = [r for r, _ in mine if r["hlo_op_name"].startswith(kernel)]
+            if of:
+                seconds, calls = 1e-6 * sum(float(r["total_self_time"]) for r in of), sum(int(r["occurrences"]) for r in of)
+                yield (f"mhc_kernel={kernel} program={prog} device_s={seconds} instructions={len(of)} calls={calls} "
+                       f"ms_a_call={1e3 * seconds / calls}")
+        for r, _ in sorted(mine, key=lambda rp: -float(rp[0]["total_self_time"]))[:5]:
+            yield (f"mhc_op={r['hlo_op_name']} program={prog} device_s={1e-6 * float(r['total_self_time'])} "
+                   f"count={r['occurrences']} op_name={r['tf_op_name']} expression={r['hlo_op_expression'][:300]}")
+
+
 def main(argv=None) -> int:
     from benchmarks import run
     from benchmarks.lib import harness, scopes
@@ -225,7 +263,7 @@ def main(argv=None) -> int:
         rows = hlo_stats(path)
         print(f"trace_cost= xplane_bytes={os.path.getsize(path)} "
               f"hlo_stats_s={time.perf_counter() - start:.3f} rows={len(rows)}", flush=True)
-        for line in moe_halves(rows):
+        for line in list(moe_halves(rows)) + list(mhc_parts(rows)):
             print(line, flush=True)
         for parts in (ssm_parts, gdn_parts):
             for line in parts(rows, argv[argv.index("--workload") + 1]):

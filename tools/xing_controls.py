@@ -13,7 +13,8 @@ The reference always runs the configuration as it is written.
 - ``sinkhorn_2``: the program runs 2 Sinkhorn rounds where the configuration
   says 20 (``hc_sinkhorn_iters`` replaced in the TransformerConfig it is given).
 - ``post_without_2``: ``H_post = sigmoid(.)`` without its 2 (``ops/mhc.py::mix``
-  wrapped: what the sublayers write back is halved).
+  wrapped, and ``mix_read`` for the mix the kernel ``mhc_mix_read`` makes: what
+  the sublayers write back is halved).
 - ``plain_rotary``: the program drops ``rope_scaling``: plain frequencies and
   the plain softmax scale where YaRN's are configured.
 - ``e4m3_latent`` (the nearest precision below bf16 for what the latent pool
@@ -52,6 +53,14 @@ def plant_post_without_2():
         return mixed._replace(post=0.5 * mixed.post)
 
     mhc.mix = mix
+    read = mhc.mix_read
+
+    def mix_read(streams, *args, **kw):  # where the kernels make the mix (a prefill on the chip), rows n .. 2n of it
+        mixed, u = read(streams, *args, **kw)
+        n = streams.shape[0]
+        return (mixed if isinstance(mixed, mhc.Mix) else mixed.at[n:2 * n].multiply(0.5)), u
+
+    mhc.mix_read = mix_read
 
 
 def main():
