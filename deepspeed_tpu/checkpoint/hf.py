@@ -77,6 +77,18 @@ def _latent_routed(hf_config: Dict[str, Any], mt: str) -> Dict[str, Any]:
     if hf_config.get("partial_rotary_factor", 1) != 1:
         raise ValueError(f"{mt} with partial_rotary_factor != 1 is unsupported")
     dtype = hf_config.get("dtype", hf_config.get("torch_dtype"))
+    # newer files of the family keep the rotary's base under ``rope_parameters``
+    rope = hf_config.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default":
+        raise ValueError(f"{mt} with rope_parameters.rope_type={rope['rope_type']!r} is unsupported (a scaled "
+                         "rotary is taken as rope_scaling of type 'yarn', for xing4_0 alone)")
+    # ``n_routed_experts`` are the experts HELD here: with ``expert_parallel: {size, rank}`` the router scores
+    # ``size`` times as many (a chip's share of a routed layer, ``TransformerConfig.expert_parallel``)
+    share = hf_config.get("expert_parallel")
+    if share:
+        from deepspeed_tpu.models.transformer import ExpertParallel
+
+        share = ExpertParallel(int(share["size"]), int(share.get("rank", 0)))
     return dict(
         vocab_size=hf_config["vocab_size"],
         hidden_size=hf_config["hidden_size"],
@@ -87,7 +99,8 @@ def _latent_routed(hf_config: Dict[str, Any], mt: str) -> Dict[str, Any]:
         norm="rmsnorm",
         activation="silu_glu",
         position="rope",
-        rope_theta=float(hf_config.get("rope_theta", 10000.0)),
+        rope_theta=float(rope.get("rope_theta", hf_config.get("rope_theta", 10000.0))),
+        expert_parallel=share or None,
         # the family's stored layout rotates adjacent pairs (its modelling
         # code de-interleaves q and k alike before a half-split rotation:
         # the same scores)
@@ -191,15 +204,34 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
             num_pred_heads=int(hf_config.get("num_pred_heads", 1)),
             param_dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(dtype, jnp.float32),
         )
-    if mt in ("glm4_moe_lite", "xing4_0"):
+    if mt in ("glm4_moe_lite", "xing4_0", "glm_moe_dsa"):
         # latent attention, leading dense layers before the routed stack, a
         # sigmoid router with a correction bias and a shared expert
         # (``_latent_routed``); xing4_0 besides wraps every sublayer in a
         # hyper-connection over hc_mult residual streams (mHC) and scales its
-        # rotary frequencies (YaRN). The next-token-prediction layers
+        # rotary frequencies (YaRN); glm_moe_dsa besides has in EVERY layer a
+        # learned indexer that keeps index_topk cached tokens a query
+        # (``ops/dsa.py``). The next-token-prediction layers
         # (num_nextn_predict_layers) are not built: no serving path runs them
         kw = _latent_routed(hf_config, mt)
         scaling = hf_config.get("rope_scaling")
+        if mt == "glm_moe_dsa":
+            refused = [(bool(scaling), "rope_scaling"),
+                       (hf_config.get("index_topk", 0) <= 0, f"index_topk={hf_config.get('index_topk')} (no indexer: "
+                        "that is glm4_moe_lite)"),
+                       (not hf_config.get("indexer_rope_interleave", True), "indexer_rope_interleave=false"),
+                       (hf_config.get("index_key_dtype", "bfloat16") not in ("bfloat16", "float32"),
+                        f"index_key_dtype={hf_config.get('index_key_dtype')!r} (an fp8 or int8 index key: the "
+                        "pool keeps the keys in the cache's own type)"),
+                       (hf_config.get("hidden_act", "silu") != "silu", f"hidden_act={hf_config.get('hidden_act')!r}"),
+                       (hf_config.get("scoring_func", "sigmoid") != "sigmoid",
+                        f"scoring_func={hf_config.get('scoring_func')!r}")]
+            refused = [what for bad, what in refused if bad]
+            if refused:
+                raise ValueError("glm_moe_dsa with " + "; ".join(refused) + " is unsupported")
+            kw.update(index_heads=hf_config["index_n_heads"], index_head_dim=hf_config["index_head_dim"],
+                      index_topk=hf_config["index_topk"])
+            return TransformerConfig(**kw)
         if mt == "xing4_0":
             kw.update(  # a rope_scaling of another type than yarn: TransformerConfig refuses it by name
                 hc_mult=hf_config["hc_mult"],
@@ -549,13 +581,14 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
     raise ValueError(
         f"unsupported HF model_type {mt!r} (supported: llama/mistral/mixtral/"
         "qwen2/gpt2/opt/falcon/phi/gpt_neox/bloom/gptj/codegen/gpt_bigcode/"
-        "glm4_moe_lite/evabyte/xing4_0/granitemoehybrid/qwen3_next)")
+        "glm4_moe_lite/evabyte/xing4_0/granitemoehybrid/qwen3_next/glm_moe_dsa)")
 
 
 def detect_family(state: Dict[str, np.ndarray]) -> str:
     keys = state.keys()
     if any("kv_a_proj_with_mqa" in k for k in keys) and any("e_score_correction_bias" in k for k in keys):
-        return "glm4_moe_lite"
+        # (the same latent attention and router; the indexer's leaves tell them apart)
+        return "glm_moe_dsa" if any("self_attn.indexer." in k for k in keys) else "glm4_moe_lite"
     if any("adaptive_phi" in k for k in keys):
         return "evabyte"
     if any("linear_attn.in_proj_qkvz" in k for k in keys):
@@ -1024,14 +1057,21 @@ def _latent_moe_layer_names(cfg: TransformerConfig, dense: bool):
         ("self_attn.q_a_layernorm.weight", ("attn", "q_norm", "scale"), (cfg.q_lora_rank,)),
         ("self_attn.q_b_proj.weight", ("attn", "wq_b", "kernel"), (cfg.q_lora_rank, H, qk)),
     ]
+    if cfg.index_topk:  # the indexer's four leaves (``glm_moe_dsa``)
+        Hi, Di = cfg.index_heads, cfg.index_head_dim
+        names += [("self_attn.indexer.wq_b.weight", ("attn", "idx_wq", "kernel"), (cfg.q_lora_rank, Hi, Di)),
+                  ("self_attn.indexer.wk.weight", ("attn", "idx_wk", "kernel"), (h, Di)),
+                  ("self_attn.indexer.k_norm.weight", ("attn", "idx_k_norm", "scale"), (Di,)),
+                  ("self_attn.indexer.k_norm.bias", ("attn", "idx_k_norm", "bias"), (Di,)),
+                  ("self_attn.indexer.weights_proj.weight", ("attn", "idx_w", "kernel"), (h, Hi))]
     if dense:
         f = cfg.intermediate_size
         return names + [(f"mlp.{hf}.weight", ("mlp", ours, "kernel"), (f, h) if ours == "w_down" else (h, f))
                         for hf, ours in _GLU_MATRICES]
     f = cfg.expert_width
     fs = f * cfg.moe_shared_experts
-    names += [("mlp.gate.weight", ("moe", "gate", "wg", "kernel"), (h, cfg.num_experts)),
-              ("mlp.gate.e_score_correction_bias", ("moe", "gate", "e_bias"), (cfg.num_experts,))]
+    names += [("mlp.gate.weight", ("moe", "gate", "wg", "kernel"), (h, cfg.router_experts)),
+              ("mlp.gate.e_score_correction_bias", ("moe", "gate", "e_bias"), (cfg.router_experts,))]
     if fs:
         names += [(f"mlp.shared_experts.{hf}.weight", ("moe", "shared", ours, "kernel"),
                    (fs, h) if ours == "w_down" else (h, fs)) for hf, ours in _GLU_MATRICES]
@@ -1079,8 +1119,8 @@ def _convert_glm4_moe_lite(state, cfg: TransformerConfig) -> Dict[str, Any]:
         wo["kernel"] = wo["kernel"].reshape(cfg.num_heads, cfg.v_head_dim, cfg.hidden_size)
         if not dense:
             for hf, ours in _GLU_MATRICES:
-                blk["moe"].setdefault("experts", {})[ours] = np.stack(
-                    [g(f"{p}mlp.experts.{e}.{hf}.weight").T for e in range(cfg.num_experts)])
+                blk["moe"].setdefault("experts", {})[ours] = np.stack(  # (of a chip's share: the experts held here)
+                    [g(f"{p}mlp.experts.{cfg.first_expert + e}.{hf}.weight").T for e in range(cfg.num_experts)])
         return blk
 
     D = cfg.first_dense_layers
@@ -1115,8 +1155,14 @@ def latent_moe_hf_state(params, cfg: TransformerConfig) -> Dict[str, np.ndarray]
             for hf, ours in _GLU_MATRICES:
                 stacked = np.asarray(params["layers"]["moe"]["experts"][ours][i - D])
                 for e in range(cfg.num_experts):
-                    state[f"{p}mlp.experts.{e}.{hf}.weight"] = stacked[e].T
+                    state[f"{p}mlp.experts.{cfg.first_expert + e}.{hf}.weight"] = stacked[e].T
     return state
+
+
+# glm_moe_dsa under its published leaf names: glm4_moe_lite's, and the indexer's four leaves a layer, which
+# ``_latent_moe_layer_names`` lists where the config has an indexer (``self_attn.indexer.{wq_b, wk, k_norm,
+# weights_proj}``); a chip's share reads experts ``first_expert ..`` of the checkpoint's
+_convert_glm_moe_dsa = _convert_glm4_moe_lite
 
 
 def _evabyte_names(cfg: TransformerConfig):
@@ -1311,6 +1357,7 @@ _CONVERTERS = {
     "qwen3_next": _convert_qwen3_next,
     "evabyte": _convert_evabyte,
     "glm4_moe_lite": _convert_glm4_moe_lite,
+    "glm_moe_dsa": _convert_glm_moe_dsa,
     "llama": _convert_llama,
     "mistral": _convert_llama,
     "mixtral": _convert_llama,

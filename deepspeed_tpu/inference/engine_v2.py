@@ -430,24 +430,48 @@ class InferenceEngineV2:
         # a routed model's programs hand out the experts they sent each token
         # to, as one more output (fetched only by *_with_picks)
         self._routed = model_config.num_experts > 0
+        # ``put_with_selected``: a ``put`` whose program hands out what an indexed model's queries kept
+        self._hand_selected = False
+        self._selected_log: Optional[List[Any]] = None
         self.picks_log: Optional[List[Dict[str, Any]]] = None
         self.last_experts_touched: Optional[float] = None
         self.last_experts_read: Optional[float] = None  # what the decode product read: dead rows' picks too
         self.last_held_visits: Optional[float] = None  # of a chip's share: visits a step to its experts, a layer
+        # under a learned indexer: cached tokens a live row's query scored and kept, a layer, in the newest chain
+        self.last_tokens_scored: Optional[float] = None
+        self.last_tokens_kept: Optional[float] = None
+        # and in the serving loop's newest prefill of a chunk: (queries fed, scored and kept a query and layer)
+        self.last_prefill_kept: Optional[Tuple[int, float, float]] = None
         if model_config.expert_parallel is not None and mesh.shape.get("ep", 1) > 1:
             raise ValueError(
                 f"expert_parallel={model_config.expert_parallel} on a mesh with ep={mesh.shape['ep']}: the "
                 "model is ONE chip's share of its layer, and the exchange between the chips is not built")
         if model_config.latent_attention:
-            from deepspeed_tpu.inference.paged import latent_pool_width
+            from deepspeed_tpu.inference.paged import index_pool_width, latent_pool_width
 
             if kv_quant is not None:
                 raise ValueError(
                     f"kv_cache_dtype={config.kv_dtype_name!r} with latent attention: the latent "
-                    "pool has no quantized form; use a bf16 or fp32 pool")
-            # one slab a token a layer, shared by all heads (PagedKVPool)
-            self.kv_bytes_per_token = (
-                model_config.num_layers * latent_pool_width(model_config) * kv_dtype_b)
+                    "pool has no quantized form" + (" and the index keys are kept in the cache's own type, not "
+                                                    "fp8 or int8" if model_config.index_topk else "")
+                    + "; use a bf16 or fp32 pool")
+            # one slab a token a layer, shared by all heads (PagedKVPool), and
+            # under an indexer its one index key beside it
+            self.kv_bytes_per_token = model_config.num_layers * kv_dtype_b * (
+                latent_pool_width(model_config) + index_pool_width(model_config))
+        if model_config.index_topk:
+            missing = [
+                (mesh.shape["tp"] > 1, f"tp={mesh.shape['tp']}: the indexer's heads and the selection are not "
+                 "partitioned over heads"),
+                (config.spec_decode > 0, "spec_decode: the one proposer is the n-gram lookup, and the model's "
+                 "multi-token-prediction layer, which would propose here, is not built"),
+                (config.prefix_cache, "prefix_cache: a shared prefix's pages hold its index keys too, but no "
+                 "test has fed a suffix through an indexer yet"),
+            ]
+            missing = [what for bad, what in missing if bad]
+            if missing:
+                raise ValueError("a sparse-attention indexer (index_topk > 0) does not serve with "
+                                 + "; ".join(missing))
         if config.kv_pool_bytes is not None:
             # byte-budget sizing: admission capacity follows the REAL block
             # bytes, so an int8 pool at the same budget admits ~1.9x the
@@ -743,18 +767,18 @@ class InferenceEngineV2:
 
     def _step_fn(self, rows: int, chunk: int):
         """Mixed prefill/decode step -> last-token logits (the v2 ``put``)."""
-        key = ("logits", rows, chunk)
+        key = ("logits", rows, chunk, self._hand_selected)
         if key not in self._step_cache:
             cfg = self.model_config
             bs = self.config.kv_block_size
-            picks = self._routed
+            picks, selected = self._routed, self._hand_selected
 
             @functools.partial(jax.jit, donate_argnums=(1,))
             def step(params, pool, tokens, positions, new_lens, block_tables):
                 return ragged_forward(params, cfg, pool, tokens, positions, new_lens, block_tables, bs,
-                                      with_picks=picks)
+                                      with_picks=picks, with_selected=selected)
 
-            self._step_cache[key] = self._watch(step, "step", f"r{rows}", f"c{chunk}")
+            self._step_cache[key] = self._watch(step, "step", f"r{rows}", f"c{chunk}", "sel" * selected)
         return self._step_cache[key]
 
     def _sample_step_fn(self, rows: int, chunk: int, sample_kw: Tuple):
@@ -1219,11 +1243,43 @@ class InferenceEngineV2:
             )
         self.dispatch_count += 1
         self._log_picks(picks, uids, None, token_lists, at=batch.at)
+        if self._selected_log is not None and len(picks) > 1:
+            self._selected_log.append((picks[0], batch.at))
         self.windows_closed += self._advance(uids, map(len, token_lists))
         with self._fetching(call):
             out = self._rows_at(logits, batch.at)
         self.host_sync_count += 1
         return out
+
+    def put_with_selected(self, uids: Sequence[int], token_lists: Sequence[np.ndarray]):
+        """``put_with_picks`` of a model with a learned indexer, and what every
+        query fed kept: ``(logits, picks, selected)``, ``selected[i]`` int32
+        ``[len(token_lists[i]), layers, ceil(max_pages * block / 32)]``, a
+        query's mask over its row's positions packed 32 a word
+        (``ops/dsa.py::pack_mask``); None where the block table holds no more
+        than a query keeps. The same mathematics as ``put`` in a program of its
+        own (a chunk's masks are 100 MB a call, which the serving loop's
+        programs do not write)."""
+        if not (self._routed and self.model_config.index_topk):
+            raise ValueError("the selection asked of a model with no learned indexer (index_topk == 0) or no "
+                             "routed layer")
+        self._hand_selected, self._selected_log = True, []
+        try:
+            logits, picks = self.put_with_picks(uids, token_lists)
+            if not self._selected_log:
+                return logits, picks, None
+            kept, at = self._selected_log[-1]
+            kept = np.asarray(kept)[at]
+            if kept.ndim == 3:  # a program of one token a row hands out positions, [rows, layers, index_topk]
+                from deepspeed_tpu.ops import dsa
+
+                mask = np.zeros(kept.shape[:2] + (-(-self.max_pages * self.config.kv_block_size // 32) * 32,), bool)
+                rows, layers, _ = np.nonzero(kept >= 0)
+                mask[rows, layers, kept[kept >= 0]] = True
+                kept = np.asarray(dsa.pack_mask(mask))[:, None]
+            return logits, picks, [k[:len(t)] for k, t in zip(kept, token_lists)]
+        finally:
+            self._hand_selected, self._selected_log = False, None
 
     def _log_picks(self, picks, uids, rids, token_lists=None, flight=None, emitted=None, at=None) -> None:
         """While somebody asked (``picks_log`` is a list), note one dispatch's
@@ -1297,6 +1353,14 @@ class InferenceEngineV2:
             return ""
         return " ".join(map(str, rids))
 
+    def _span_fed(self, uids, token_lists) -> str:
+        """Each row's first position and the tokens it is fed, ``start:count``, as one span arg of a prefill
+        under a learned indexer (what a query scores and keeps follows from its position); formatted only
+        while somebody records spans, before ``seen_tokens`` advances."""
+        if not self.model_config.index_topk or not self._tracer.recording():
+            return {}
+        return {"fed": " ".join(f"{self.state.get(u).seen_tokens}:{len(t)}" for u, t in zip(uids, token_lists))}
+
     def _put_sample(self, uids, token_lists, rng, sample_kw: Tuple,
                     tracker: Optional[LifecycleTracker] = None,
                     rids: Optional[Sequence[int]] = None) -> Tuple[np.ndarray, jax.Array]:
@@ -1305,10 +1369,11 @@ class InferenceEngineV2:
         logits transfer."""
         batch = self._build_batch(uids, token_lists)
         step = self._sample_step_fn(batch.n_rows, batch.tokens.shape[1], sample_kw)
+        self.last_prefill_kept = None
         call = self._log.open("prefill", -1, batch.n_rows, batch.tokens.shape[1])
         with self._dispatching(call, rows=batch.n_rows,
                                live=len(uids), tokens=int(batch.new_lens.sum()),
-                               rids=self._span_rids(rids),
+                               rids=self._span_rids(rids), **self._span_fed(uids, token_lists),
                                **self._eva_args(batch.positions, batch.new_lens),
                                **self._state_args(len(uids))):
             if tracker is not None and rids is not None:
@@ -1324,6 +1389,12 @@ class InferenceEngineV2:
         self.windows_closed += self._advance(uids, map(len, token_lists))
         with self._fetching(call):
             out = self._rows_at(toks, batch.at)
+            if len(picks) > 1 and batch.tokens.shape[1] > 1:
+                # an indexed model's chunk: [rows, layers, 2], what each row's queries scored and kept (dead rows: 0)
+                queries = int(batch.new_lens.sum())
+                scored, kept = np.asarray(picks[0], np.float64).sum(axis=(0, 1)) / (
+                    max(queries, 1) * self.model_config.num_layers)
+                self.last_prefill_kept = (queries, float(scored), float(kept))
         self.host_sync_count += 1
         return out, rng
 
@@ -1522,6 +1593,10 @@ class InferenceEngineV2:
                 self.last_experts_touched, self.last_experts_read = float(touched), float(read)
                 if self.model_config.expert_parallel is not None:
                     self.last_held_visits = float(visits)
+                if len(routed) > 2:  # [K, 2] between the two: tokens scored and kept a step, over live rows and layers
+                    row_layers = max(int(emitted.sum()), 1) * self.model_config.num_layers
+                    scored, kept = np.asarray(routed[1])[:live_steps].sum(axis=0) / row_layers
+                    self.last_tokens_scored, self.last_tokens_kept = float(scored), float(kept)
         self.host_sync_count += 1
         self._log_picks(routed, uids, rids, flight=flight, emitted=emitted)
         # a row moved already stands k further; one an EOS ended goes back
@@ -1889,7 +1964,11 @@ class InferenceEngineV2:
                     admitted = True
                     toks, rng = self._put_sample(adm_uids, adm_tokens, rng, sample_kw,
                                                  tracker=tracker, rids=adm_rids)
-                    with span("serve:accept", kind="prefill", emitted=len(adm_uids)):
+                    kept_args = {}
+                    if self.last_prefill_kept is not None:
+                        queries, scored, kept = self.last_prefill_kept
+                        kept_args = dict(queries=queries, tokens_scored=scored, tokens_kept=kept)
+                    with span("serve:accept", kind="prefill", emitted=len(adm_uids), **kept_args):
                         if pc is not None:
                             # index the freshly written full blocks (quantized bytes
                             # are in the pool now — hashes snapshot them as written)
@@ -1982,6 +2061,8 @@ class InferenceEngineV2:
                            if self._routed and n_spec == 0 else {})
             if routed_args and self.last_held_visits is not None:
                 routed_args["held_visits"] = self.last_held_visits
+            if routed_args and self.last_tokens_scored is not None:
+                routed_args.update(tokens_scored=self.last_tokens_scored, tokens_kept=self.last_tokens_kept)
             with span("serve:accept", kind="chain", emitted=n_emitted, chain=chain_id,
                       **routed_args):
                 self.tokens_decoded += n_emitted

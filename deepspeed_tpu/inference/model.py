@@ -235,7 +235,9 @@ def _experts(ep, cfg: TransformerConfig, tokens, top_p, top_i):
             # every pair has a row of the sorted gather whether its expert is here or not, so a long
             # prefill's tokens go a group at a time (a (128, 256) call: 1.3 GB a gather at once)
             n = 1
-            while T * cfg.moe_top_k > n * _SHARE_GROUP_PAIRS and T % (2 * n) == 0 and T // (2 * n) >= 2 * E:
+            # (no more pairs a group than 1 GiB of gathered rows: at a hidden width of 6,144, 87,381)
+            pairs = min(_SHARE_GROUP_PAIRS, _SHARE_GROUP_BYTES // (tokens.shape[1] * tokens.dtype.itemsize))
+            while T * cfg.moe_top_k > n * pairs and T % (2 * n) == 0 and T // (2 * n) >= 2 * E:
                 n *= 2
             if n == 1:
                 return _moe_ragged(cfg, ep, tokens, top_p, top_i, held)
@@ -307,6 +309,8 @@ _MOE_EP_COLLECTIVE_MAX_TOKENS = 1024
 
 # (token, pick) pairs of one grouped dispatch of a chip's share of a routed layer
 _SHARE_GROUP_PAIRS = 2 ** 17
+# and the bytes of its gathered rows (2 ** 17 pairs of 4,096 columns in bf16: what the cells before PR 55 reach at most)
+_SHARE_GROUP_BYTES = 2 ** 30
 
 
 def _moe_ep_size() -> int:

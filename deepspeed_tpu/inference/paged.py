@@ -28,6 +28,8 @@ Static shapes everywhere: (rows, chunk, pages) are bucketed by the host layer
 
 from __future__ import annotations
 
+import functools
+
 from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
@@ -68,7 +70,12 @@ class PagedKVPool(NamedTuple):
     rounded up to whole 128-lane tiles (512 + 64 -> 640: a minor dim of 576
     pads to 640 in HBM either way, so the padding is said, not hidden). The
     keys are the slab, the values its first ``kv_lora_rank`` columns; nothing
-    else of a token is cached. It has no quantized form."""
+    else of a token is cached. It has no quantized form. Under a learned
+    indexer (``TransformerConfig.index_topk > 0``) ``v`` is the INDEX pool,
+    ``[L*NB, bs, index_pool_width(cfg)]``: a token's index key after its norm
+    and RoPE, in the page and slot its latent has, so one block table a row
+    places everything a token caches and the two arrays are read by two
+    different kernels, each the bytes it needs."""
 
     k: jax.Array
     v: Optional[jax.Array] = None
@@ -149,6 +156,11 @@ def latent_pool_width(cfg: TransformerConfig) -> int:
     return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // _LANES) * _LANES
 
 
+def index_pool_width(cfg: TransformerConfig) -> int:
+    """Columns of an index pool's row: the indexer's one key a token, in whole lane tiles (0: no indexer)."""
+    return -(-cfg.index_head_dim // _LANES) * _LANES if cfg.index_topk else 0
+
+
 def init_pool(
     cfg: TransformerConfig, num_blocks: int, block_size: int, dtype: Any = jnp.bfloat16,
     kv_quant: Optional[str] = None,
@@ -162,8 +174,9 @@ def init_pool(
             raise ValueError(
                 f"kv_quant={kv_quant!r} with latent attention: a latent pool has no quantized "
                 "form (one scale a token a layer is not carried); use a bf16/fp32 pool")
-        return PagedKVPool(k=jnp.zeros(
-            (cfg.num_layers * num_blocks, block_size, latent_pool_width(cfg)), dtype))
+        pages = (cfg.num_layers * num_blocks, block_size)
+        return PagedKVPool(k=jnp.zeros(pages + (latent_pool_width(cfg),), dtype),
+                           v=jnp.zeros(pages + (index_pool_width(cfg),), dtype) if cfg.index_topk else None)
     # (of a layer pattern, the attention layers alone hold pages)
     shape = (cfg.attention_layers * num_blocks, block_size, cfg.kv_heads * cfg.dims_per_head)
     if kv_quant is None:
@@ -291,27 +304,51 @@ def paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_size,
 
 @register("latent_paged_attention", "xla")
 def _xla_latent_paged_attention(q, pool, block_tables, q_positions, block_size, scale, v_width,
-                                new_lens=None):
+                                new_lens=None, mask=None):
     """Dense-gather fallback of the latent kernel (``mla_paged_attn`` in
     ``ops/pallas/paged_attention.py``). q: [N, C, H, W] against the whole
     slab; pool: [pages, bs, W]; a token's value is its slab's first
-    ``v_width`` columns. Returns [N, C, H, v_width]."""
+    ``v_width`` columns. ``mask`` bool [N, C, P*bs]: the positions a query
+    attends, where an indexer chose them. Returns [N, C, H, v_width]."""
     N, C, H, W = q.shape
     P = block_tables.shape[1]
     slab = pool[block_tables].reshape(N, P * block_size, W)  # slot index == position
     scores = jnp.einsum("nchw,ntw->nhct", q, slab).astype(jnp.float32) * scale
     ok = jnp.arange(P * block_size)[None, None, :] <= q_positions[:, :, None]
+    if mask is not None:
+        ok = ok & mask[..., :P * block_size]  # (the index kernel's scores come in whole tiles of columns)
     scores = jnp.where(ok[:, None, :, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(slab.dtype)
     return jnp.einsum("nhct,ntv->nchv", probs, slab[..., :v_width])
 
 
 def latent_paged_attention(q, pool, block_tables, q_positions, block_size, scale, v_width,
-                           new_lens=None, impl: str = "auto"):
+                           new_lens=None, impl: str = "auto", mask=None):
     import deepspeed_tpu.ops.pallas.paged_attention  # noqa: F401  (registers the kernel)
 
+    if mask is not None and impl == "auto" and q.shape[1] < _MASKED_KERNEL_MIN_QUERIES:
+        impl = "xla"  # a token and its drafts: the kernel's masked form takes whole tiles of queries
     return dispatch("latent_paged_attention", impl)(
-        q, pool, block_tables, q_positions, block_size, scale, v_width, new_lens=new_lens)
+        q, pool, block_tables, q_positions, block_size, scale, v_width, new_lens=new_lens, mask=mask)
+
+
+_MASKED_KERNEL_MIN_QUERIES = 16
+
+
+def latent_selected_attention(q, pool, block_tables, selected, block_size, scale, v_width):
+    """ONE query a row against the cached tokens an indexer chose for it,
+    gathered by position: q [N, 1, H, W]; ``selected`` int32 [N, K] positions
+    of the row, -1 for none; pool and block_tables as the latent kernel takes
+    them (the layer's offset added). 2,048 rows of 1,280 B where the walk
+    would read every page up to the query. Returns [N, 1, H, v_width]."""
+    live = selected >= 0
+    at = jnp.maximum(selected, 0)
+    page = jnp.take_along_axis(block_tables, at // block_size, axis=1)
+    rows = pool[page, at % block_size]  # [N, K, W]
+    scores = jnp.einsum("nchw,nkw->nhck", q, rows).astype(jnp.float32) * scale
+    scores = jnp.where(live[:, None, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(rows.dtype)
+    return jnp.einsum("nhck,nkv->nchv", probs, rows[..., :v_width])
 
 
 def _rms(x, scale, eps):
@@ -320,6 +357,124 @@ def _rms(x, scale, eps):
     # XLA's, which fuses into its neighbours: a kernel of its own for a
     # [rows, 512] norm costs a decode step more than the norm
     return rms_norm(x, scale, eps=eps, impl="xla")
+
+
+# rows of a call go through an indexed layer's attention a group of this many tokens at a time
+_ATTEND_GROUP_TOKENS = 8192
+
+
+def _indexed_latent_attention(ap, cfg: TransformerConfig, h, positions, new_lens, block_tables, bs,
+                              pk, pv, put_values, first_page, hand_mask: bool = False):
+    """``_latent_attention`` under a learned indexer (``cfg.index_topk > 0``;
+    ``ops/dsa.py``): beside its latent a token caches its index key, in the
+    index pool ``pv`` at the page and slot the latent has in ``pk``; a query
+    scores every cached key of its row, keeps the ``index_topk`` positions of
+    largest score, and attends those and no others: a chunk by the causal walk
+    under a per-query mask, one token a row over its chosen rows gathered by
+    position. A block table that holds no more tokens than a query keeps has
+    every candidate taken: the dense walk, and no scores.
+
+    Everything after the latents (the query's up-projection, the indexer's
+    scores, the choice, the absorbed query, the attention, the value
+    up-projection and the output's) goes a group of rows at a time where a call brings more than ``_ATTEND_GROUP_TOKENS``: a
+    row of 8,192 queries holds 1.3 GB of absorbed queries and as much of
+    scores and mask. Returns (attention output [N, C, E], ``pk``, ``pv``, the
+    choice; None where every candidate is taken): int32 [N, index_topk]
+    positions (-1: none) for one token a row; for a chunk, asked by
+    ``hand_mask``, the mask packed 32 positions a word (``dsa.pack_mask``),
+    else int32 [N, 2], the cached tokens a row's queries scored and kept."""
+    from deepspeed_tpu.models.transformer import rope_at
+    from deepspeed_tpu.ops import dsa
+
+    rank, nope, rope_d = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    Hi, Di = cfg.index_heads, cfg.index_head_dim
+    N, C = positions.shape
+    W, Wi = pk.shape[-1], pv.shape[-1]
+    S = block_tables.shape[1] * bs
+    dt = cfg.dtype
+    rot = cfg.latent_rotary
+    turn = functools.partial(dsa.rotate, rope_dim=rope_d, theta=cfg.rope_theta, interleaved=cfg.rope_interleaved)
+
+    def slab(latent, rope):  # [latent | rotary | zeros up to the pool's width]
+        pad = [jnp.zeros(latent.shape[:-1] + (W - rank - rope_d,), dt)] * (W > rank + rope_d)
+        return jnp.concatenate([latent, rope] + pad, axis=-1)
+
+    with jax.named_scope("mla"):  # and inside it the parameter keys read (``reading``)
+        c_q = _dense(ap, "wq_a", cfg, h)
+        with reading(ap, "q_norm") as p:
+            c_q = _rms(c_q, p["scale"], cfg.norm_eps)
+        kv = _dense(ap, "wkv_a", cfg, h)  # [N, C, rank + rope]
+        with reading(ap, "kv_norm") as p:
+            c_kv = _rms(kv[..., :rank], p["scale"], cfg.norm_eps)
+        with jax.named_scope("rope"):
+            k_rope = rope_at(kv[..., None, rank:], positions, cfg.rope_theta, cfg.rope_interleaved)[..., 0, :]
+        with reading(ap, "wkv_b") as p:
+            w_kvb = p["kernel"].astype(dt)  # [rank, H, nope + v], kept whole
+        with jax.named_scope("dsa_index"):  # the one index key a token: projection, LayerNorm, rotary
+            k_idx = _dense(ap, "idx_wk", cfg, h)
+            with reading(ap, "idx_k_norm") as p:
+                k_idx = dsa.key_norm(k_idx, p["scale"], p["bias"])
+            with jax.named_scope("rope"):
+                k_idx = turn(k_idx[..., None, :], positions)[..., 0, :]
+            if Wi > Di:
+                k_idx = jnp.concatenate([k_idx, jnp.zeros(k_idx.shape[:-1] + (Wi - Di,), k_idx.dtype)], axis=-1)
+    with jax.named_scope("kv_write"):
+        pk = put_values(pk, slab(c_kv, k_rope).astype(pk.dtype).reshape(-1, W), first_page)
+        pv = put_values(pv, k_idx.astype(pv.dtype).reshape(-1, Wi), first_page)
+
+    def attend(rows):
+        """A group of rows, from the query's latent on: (the attention's output [g, C, E], the choice or None)."""
+        c_q, h, positions, new_lens, tables = rows
+        chosen = handed = None
+        with jax.named_scope("mla"):
+            q = _dense(ap, "wq_b", cfg, c_q, "ncr,rhd->nchd")
+            with jax.named_scope("rope"):
+                q_rope = rope_at(q[..., nope:], positions, cfg.rope_theta, cfg.rope_interleaved)
+            if S > cfg.index_topk:
+                with jax.named_scope("dsa_index"):
+                    q_idx = _dense(ap, "idx_wq", cfg, c_q, "ncr,rhd->nchd")
+                    with jax.named_scope("rope"):
+                        q_idx = turn(q_idx, positions)
+                    w = dsa.head_weights(_dense(ap, "idx_w", cfg, h, sums=jnp.float32), Hi, Di)
+                    keys = pv[tables].reshape(-1, S, Wi)[..., :Di]  # slot index == position
+                    scores = dsa.index_scores(q_idx, keys.astype(q_idx.dtype), w, positions)
+                with jax.named_scope("dsa_select"):
+                    if C == 1:
+                        chosen = handed = dsa.select_positions(scores[:, 0], cfg.index_topk)
+                    else:
+                        chosen = dsa.select_mask(scores, cfg.index_topk)
+                        if hand_mask:
+                            handed = dsa.pack_mask(chosen[..., :S])
+                        else:  # a row's (cached tokens scored, cached tokens kept), counted off the selection itself
+                            live = jnp.arange(C)[None, :] < new_lens[:, None]  # (a pad query stands at position 0)
+                            handed = jnp.stack([jnp.where(live, positions + 1, 0).sum(axis=1),
+                                                (chosen & live[..., None]).sum(axis=(1, 2), dtype=jnp.int32)], axis=-1)
+            with reading(ap, "wkv_b"):
+                q_lat = jnp.einsum("nchd,rhd->nchr", q[..., :nope], w_kvb[..., :nope])
+            q_slab = slab(q_lat, q_rope)
+        if chosen is None:
+            o_lat = latent_paged_attention(q_slab, pk, tables, positions, bs, rot.softmax_scale, rank,
+                                           new_lens=new_lens)
+        else:
+            with jax.named_scope("mla"), jax.named_scope("dsa_attend"):
+                if C == 1:
+                    o_lat = latent_selected_attention(q_slab, pk, tables, chosen, bs, rot.softmax_scale, rank)
+                else:
+                    o_lat = latent_paged_attention(q_slab, pk, tables, positions, bs, rot.softmax_scale, rank,
+                                                   new_lens=new_lens, mask=chosen)
+        with jax.named_scope("mla"):
+            with reading(ap, "wkv_b"):  # the value half of the kernel read above
+                o = jnp.einsum("nchr,rhv->nchv", o_lat, w_kvb[..., nope:])
+            return _dense(ap, "wo", cfg, o, "nchv,hve->nce"), handed
+
+    rows = (c_q, h, positions, new_lens, block_tables + first_page)
+    group = max(1, _ATTEND_GROUP_TOKENS // C)
+    if N > group and N % group == 0:
+        grouped = jax.tree_util.tree_map(lambda a: a.reshape((N // group, group) + a.shape[1:]), rows)
+        out, handed = jax.tree_util.tree_map(lambda a: a.reshape((N,) + a.shape[2:]), jax.lax.map(attend, grouped))
+    else:
+        out, handed = attend(rows)
+    return out, pk, pv, handed
 
 
 def _latent_attention(ap, cfg: TransformerConfig, h, positions, new_lens, block_tables, bs,
@@ -522,6 +677,7 @@ def _forward_hidden(
     block_size: int,
     all_positions: bool = False,
     with_picks: bool = False,
+    with_selected: bool = False,
 ) -> Tuple[jax.Array, ...]:
     """One mixed prefill/decode layer-stack pass -> (last-token hidden [N, E],
     pool). Shared by the single-step ``ragged_forward`` and the K-step
@@ -532,6 +688,16 @@ def _forward_hidden(
     each token fed was sent to in each routed layer, leading dense layers not
     counted, by the experts' own numbers (pad tokens' entries are garbage).
     A model with no routed layer returns the pair whatever is asked.
+
+    A model with a learned indexer (``index_topk > 0``) asked ``with_picks``
+    hands out BEFORE the picks what each query kept, where its block table
+    holds more tokens than a query keeps: for one token a row (``C == 1``)
+    ``selected`` int32 ``[N, layers, index_topk]``, the positions, -1 for
+    none; for a chunk ``[N, layers, 2]`` int32, the cached tokens a row's
+    queries scored and the ones they kept, and where ``with_selected`` asks (a
+    reader outside the serving loop) ``[N, C, layers, ceil(P * bs / 32)]``
+    int32 in their place, the mask packed 32 positions a word
+    (``ops/dsa.py::pack_mask``).
 
     A model with ``first_dense_layers`` runs those first, each from its own
     ``params["dense_<i>"]``, then scans the routed stack ``params["layers"]``
@@ -596,6 +762,7 @@ def _forward_hidden(
     # Scales are a lane-dense row a PAGE, so they always go a page at a time.
     by_page = C >= bs
     put_pages = None
+    selected = []  # what an indexed layer's queries kept, noted by ``attention`` as a layer is traced
     if eva:
         eva_attend = _eva_attention(cfg, positions, new_lens, block_tables, bs, L * NB)
     elif by_page or quant is not None:
@@ -609,6 +776,11 @@ def _forward_hidden(
     def attention(ap, h, pk, pv, psk, psv, first_page):
         if eva:
             out, pk, pv = eva_attend(ap, h, pk, pv, first_page)
+            return out, pk, pv, psk, psv
+        if cfg.index_topk:
+            out, pk, pv, kept = _indexed_latent_attention(ap, cfg, h, positions, new_lens, block_tables, bs,
+                                                          pk, pv, put_values, first_page, hand_mask=with_selected)
+            selected.append(kept)
             return out, pk, pv, psk, psv
         if latent:
             out, pk = _latent_attention(ap, cfg, h, positions, new_lens, block_tables,
@@ -703,6 +875,8 @@ def _forward_hidden(
             return (x + attn_out + out, pk, pv, psk, psv), picks
         x = x + _times(cfg.residual_multiplier, attn_out)
         out, picks = ffn(lp, _norm_at(lp, "mlp_norm", cfg, x), dense)
+        if cfg.index_topk:  # (a static branch: beside the picks, what this layer's queries kept)
+            picks = (picks, selected.pop())
         return (x + _times(cfg.residual_multiplier, out), pk, pv, psk, psv), picks
 
     if state is not None:
@@ -777,10 +951,13 @@ def _forward_hidden(
         return carry, jnp.stack(picked) if routed else None
 
     carry = (x, *pool)
+    kept_dense = []
     for i in range(D):
         # a leading dense layer of a routed model: its own parameters, its own
         # pages (layer i's), outside the scan
-        carry, _ = layer(carry, params[f"dense_{i}"], jnp.int32(i * NB), dense=True)
+        carry, out = layer(carry, params[f"dense_{i}"], jnp.int32(i * NB), dense=True)
+        if cfg.index_topk:
+            kept_dense.append(out[1])
     with jax.named_scope("pool_scan"):
         if cfg.layer_types is not None:
             kinds = cfg.period
@@ -796,6 +973,12 @@ def _forward_hidden(
                 lambda c, xs: layer(c, _with_experts(xs[0], experts, *xs[2:]), xs[1]), carry,
                 (layers, jnp.arange(D, L, dtype=jnp.int32) * NB)
                 + ((jnp.arange(L - D, dtype=jnp.int32),) if stacked else ()))
+    kept = None
+    if cfg.index_topk:
+        picks, kept = picks  # the scanned layers': [layers - D, N, ...]
+        if kept is not None:  # every layer's, a row: [N, (C,) layers, ...]
+            kept = jnp.concatenate([jnp.stack(kept_dense), kept]) if D else kept
+            kept = jnp.moveaxis(kept, 0, 2 if kept.ndim == 4 else 1)  # (a chunk's masks: [layers, N, C, words])
     if state is not None:
         pool = HybridPools(PagedKVPool(*pool[:4]), StatePool(*pool[4:]))
     else:
@@ -809,6 +992,8 @@ def _forward_hidden(
         x = jnp.take_along_axis(
             x, jnp.maximum(new_lens - 1, 0)[:, None, None], axis=1
         )[:, 0]  # [N, E]
+    if routed and kept is not None:
+        return x, pool, kept, picks
     return (x, pool, picks) if routed else (x, pool)
 
 
@@ -837,10 +1022,12 @@ def ragged_forward(
     block_tables: jax.Array,  # [N, P] int32
     block_size: int,
     with_picks: bool = False,
+    with_selected: bool = False,
 ) -> Tuple[jax.Array, ...]:
     """One mixed prefill/decode step -> (last-token logits [N, V], pool), and
     for a routed model asked ``with_picks`` the picks ``[N, C, routed layers,
-    k]`` as a third value (``_forward_hidden``).
+    k]`` as the LAST value, after what an indexed model's queries kept where
+    it hands that out (``_forward_hidden``).
 
     Reference analog: the whole FastGen model forward over a
     ``RaggedBatchWrapper`` (``inference/v2/engine_v2.py:107`` → model
@@ -850,7 +1037,7 @@ def ragged_forward(
     """
     last, pool, *picks = _forward_hidden(
         params, cfg, pool, tokens, positions, new_lens, block_tables, block_size,
-        with_picks=with_picks)
+        with_picks=with_picks, with_selected=with_selected)
     return (_logits(params, cfg, last), pool, *picks)
 
 
@@ -914,7 +1101,10 @@ def ragged_decode_chain(
     its kernel is handed, ``ops/pallas/moe_decode.py``), of a chip's share
     each among the experts HELD here; and
     ``picks`` int32 ``[K, N, routed layers, k]``, the experts each step's
-    input token was sent to (rows not live at a step: garbage).
+    input token was sent to (rows not live at a step: garbage). A model with a
+    learned indexer hands out between the two ``kept`` int32 ``[K, 2]``: of
+    each step, summed over the live rows and the layers, the cached tokens
+    their queries scored and the ones they kept.
 
     Observability contract: the chain boundary is the host's ONLY visibility
     quantum — the K in-scan tokens carry no host timestamps by design, so
@@ -942,13 +1132,19 @@ def ragged_decode_chain(
         carry = (pool, jnp.where(live, nxt, tok), pos + new_lens, still, emitted, key)
         if not picks:
             return carry, out
-        picked = picks[0][:, 0]  # [N, routed layers, k]
+        picked = picks[-1][:, 0]  # [N, routed layers, k]
         # (of a chip's share, by the held experts' own numbers: a pick of another chip's is no row of the one-hot)
         held = picked if cfg.expert_parallel is None else picked - cfg.first_expert
         fed = jax.nn.one_hot(held, cfg.num_experts, dtype=jnp.bool_)  # [N, routed layers, k, E]
         hit = fed & live[:, None, None, None]
         touched = jnp.stack([hit.any(axis=(0, 2)).sum(axis=-1), hit.sum(axis=(0, 2, 3)),
                              fed.any(axis=(0, 2)).sum(axis=-1)], axis=-1).astype(jnp.int32)  # [routed layers, 3]
+        if len(picks) > 1:
+            # an indexed model: the cached tokens the live rows' queries scored (every one up to their own
+            # position, in every layer) and the ones they kept, as the selection itself says
+            kept = ((picks[0] >= 0) & live[:, None, None]).sum()
+            scored = (jnp.where(live, pos + 1, 0) * picks[0].shape[1]).sum()
+            return carry, (out, touched, jnp.stack([scored, kept]).astype(jnp.int32), picked)
         return carry, (out, touched, picked)
 
     carry0 = (pool, tokens, start_pos, active & (budgets > 0),
@@ -956,8 +1152,8 @@ def ragged_decode_chain(
     (pool, tok, pos, active, emitted, rng), outs = jax.lax.scan(
         step, carry0, None, length=k_steps)
     if isinstance(outs, tuple):
-        outs, touched, picks = outs
-        return outs.T, emitted, active, tok, pos, rng, pool, touched, picks
+        outs, *routed = outs  # touched, (an indexed model's tokens scored and kept a step,) picks
+        return (outs.T, emitted, active, tok, pos, rng, pool, *routed)
     return outs.T, emitted, active, tok, pos, rng, pool
 
 

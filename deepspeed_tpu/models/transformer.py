@@ -279,6 +279,15 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # A learned sparse-attention indexer beside latent attention (index_topk >
+    # 0; ``ops/dsa.py``): index_heads heads of index_head_dim score every cached
+    # token against ONE index key a token (cached beside the latent), and a
+    # query attends the index_topk positions of largest score alone; a context
+    # of no more than index_topk tokens takes every candidate. 0 says none: a
+    # static branch, such a model compiles as it did.
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # EVA attention (eva_window > 0; EvaByte): token t attends, under ONE
     # softmax, to the exact keys of its own window of eva_window positions
     # (causally) and to one SUMMARY a chunk of eva_chunk positions of every
@@ -395,6 +404,17 @@ class TransformerConfig:
                 "sequential block (no parallel_block) with a one-dtype residual (no EVA attention)")
         if self.moe_router not in ("softmax", "sigmoid"):
             raise ValueError(f"moe_router must be softmax|sigmoid, got {self.moe_router!r}")
+        if self.index_topk and not (self.latent_attention and self.index_heads > 0
+                                    and self.index_head_dim >= self.qk_rope_head_dim > 0):
+            raise ValueError(
+                f"a sparse-attention indexer (index_topk={self.index_topk}) selects for latent attention "
+                f"(kv_lora_rank > 0) with index_heads > 0 heads of index_head_dim >= qk_rope_head_dim columns, "
+                f"got index_heads={self.index_heads}, index_head_dim={self.index_head_dim}, "
+                f"kv_lora_rank={self.kv_lora_rank}")
+        if self.index_topk and (self.rope_scaling is not None or self.hc_mult or self.parallel_block):
+            raise ValueError("a sparse-attention indexer (index_topk > 0) with rope_scaling, hyper-connections or "
+                             "parallel_block is not built: its keys rotate by plain frequencies in a sequential "
+                             "one-stream block")
         if self.kv_lora_rank and not self.q_lora_rank:
             raise ValueError("latent attention (kv_lora_rank > 0) needs q_lora_rank > 0: a plain "
                              "query projection beside latent keys and values is not built")
@@ -570,11 +590,18 @@ class TransformerConfig:
             q = h * self.q_lora_rank + self.q_lora_rank * (H * qk + 1)
             kv = (h * (self.kv_lora_rank + self.qk_rope_head_dim) + self.kv_lora_rank
                   + self.kv_lora_rank * H * (self.qk_nope_head_dim + self.v_head_dim))
-            return q + kv + H * self.v_head_dim * h
+            return q + kv + H * self.v_head_dim * h + self._indexer_params()
         hd = self.dims_per_head
         eva = 2 * self.kv_heads * hd if self.eva_window else 0  # phi and mu
         gate = h * hd * H if self.attn_output_gate else 0  # the query projection's second half
         return h * hd * (H + 2 * self.kv_heads) + hd * H * h + eva + gate + (2 * hd if self.qk_norm else 0)
+
+    def _indexer_params(self) -> int:
+        """The indexer of one layer: its query and key projections, the key's LayerNorm, the heads' weights."""
+        if not self.index_topk:
+            return 0
+        Hi, Di = self.index_heads, self.index_head_dim
+        return self.q_lora_rank * Hi * Di + self.hidden_size * (Di + Hi) + 2 * Di
 
     def _ssm_params(self) -> int:
         """One state-space mixer: both projections, the convolution and its
@@ -603,7 +630,7 @@ class TransformerConfig:
                 layer_mlp = n_exp * expert + h * self.router_experts  # experts (held here) + router
                 layer_mlp += self.moe_shared_experts * expert + (h if self.moe_shared_gate else 0)
                 if self.moe_router == "sigmoid":
-                    layer_mlp += n_exp  # the correction bias
+                    layer_mlp += self.router_experts  # the correction bias
                 if self.moe_use_residual:
                     layer_mlp += mlp + 2 * h + 2  # residual MLP + coefficient gate
             else:
@@ -1016,6 +1043,23 @@ class EvaAttention(nn.Module):
         return dense(cfg.hidden_size, "wo", axis=(-2, -1))(out)
 
 
+class _IndexKeyNorm(nn.Module):
+    """The index key's LayerNorm (``ops/dsa.py::key_norm``): ``scale`` and a ``bias`` drawn off zero, because a
+    bias left at zero is a leaf no check can see."""
+
+    width: int
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        from deepspeed_tpu.ops import dsa
+        from deepspeed_tpu.parallel.moe import _nonzero_normal
+
+        scale = self.param("scale", nn.initializers.ones, (self.width,), self.param_dtype)
+        bias = self.param("bias", _nonzero_normal(0.05), (self.width,), self.param_dtype)
+        return dsa.key_norm(x, scale, bias)
+
+
 class LatentAttention(nn.Module):
     """Latent attention over a full sequence, the plain (non-absorbed) way:
     keys and values are up-projected per head from the normed latent and the
@@ -1023,7 +1067,13 @@ class LatentAttention(nn.Module):
     the latent and the shared rotary key alone and absorbs the up-projections
     into the query and the output (``inference/paged.py``); both read these
     parameters: ``wq_a``/``q_norm``/``wq_b``, ``wkv_a``/``kv_norm``,
-    ``wkv_b`` [rank, H, nope + v] kept whole, ``wo``."""
+    ``wkv_b`` [rank, H, nope + v] kept whole, ``wo``.
+
+    With an indexer (``index_topk > 0``; ``ops/dsa.py``) a query attends the
+    positions it selects and no others, here as a pair bias on the plain
+    attention: ``idx_wq`` [q rank, heads, D] from the query's latent,
+    ``idx_wk`` [hidden, D] and ``idx_k_norm`` (scale, bias) for the one key a
+    token, ``idx_w`` [hidden, heads] for the heads' weights."""
 
     config: TransformerConfig
 
@@ -1056,9 +1106,30 @@ class LatentAttention(nn.Module):
         width = nope + rope_d
         if vd < width:
             v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, width - vd)))
+        kept = {}
+        if cfg.index_topk:
+            kept["bias"] = self.selection_bias(x, c_q, positions, dense)
         out = causal_attention(q, k, v, mask=mask, impl=cfg.attn_impl, softmax_scale=rot.softmax_scale,
-                               **dict(cfg.attn_kwargs or ()))[..., :vd]
+                               **kept, **dict(cfg.attn_kwargs or ()))[..., :vd]
         return dense(cfg.hidden_size, "wo", axis=(-2, -1))(out)
+
+    def selection_bias(self, x, c_q, positions, dense):
+        """``[B, H, S, S]`` float32: 0 where query ``t`` attends ``s``, -1e30 elsewhere (the indexer's choice)."""
+        from deepspeed_tpu.ops import dsa
+
+        cfg = self.config
+        Hi, Di, rope_d = cfg.index_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+        q = dense((Hi, Di), "idx_wq")(c_q)
+        k = dense(Di, "idx_wk")(x)
+        k = _IndexKeyNorm(Di, cfg.param_dtype, name="idx_k_norm")(k)
+        w = dsa.head_weights(dense(Hi, "idx_w")(x), Hi, Di)
+        turn = functools.partial(dsa.rotate, positions=positions, rope_dim=rope_d, theta=cfg.rope_theta,
+                                 interleaved=cfg.rope_interleaved)
+        q, k = turn(q), turn(k[..., None, :])[..., 0, :]
+        within = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])  # a key's slot in the sequence given
+        chosen = dsa.select_mask(dsa.index_scores(q, k, w, within), cfg.index_topk)
+        bias = jnp.where(chosen, 0.0, -1e30).astype(jnp.float32)
+        return jnp.broadcast_to(bias[:, None], (x.shape[0], cfg.num_heads) + bias.shape[1:])
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -1411,8 +1482,13 @@ class CausalLM(nn.Module):
         pad_mask = batch.get("attention_mask")  # [B, S] 1=keep
 
         embed_cls = _SparseGradEmbed if cfg.sparse_embedding_grads else nn.Embed
+        # Under a learned indexer the embedding is drawn at unit variance. flax's own draw (hidden^-1/2 a
+        # column) leaves the first layer's attention output nine tenths of the stream its MLP reads, so the
+        # handful of boundary tokens that two roundings of one index score choose differently there move
+        # every later layer's selection (benchmarks/configs/glm-5.json, assumed.weights).
+        drawn = {"embedding_init": nn.initializers.normal(1.0)} if cfg.index_topk else {}
         x = embed_cls(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-                      param_dtype=cfg.param_dtype, name="embed")(ids)
+                      param_dtype=cfg.param_dtype, name="embed", **drawn)(ids)
         x = _times(cfg.embedding_multiplier, x)
         if cfg.fp32_residual:
             x = x.astype(jnp.float32)

@@ -44,13 +44,22 @@ def _ln_fwd_kernel(x_ref, scale_ref, bias_ref, o_ref, *, eps):
     o_ref[:] = (y * scale_ref[:].astype(jnp.float32) + bias_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def _row_blocks(n_rows: int) -> int:
-    return min(_BLOCK_ROWS, n_rows)
+# what a block of _BLOCK_ROWS rows may hold: at 256 x 4,096 the step's two blocks and its float32 copies
+# stand under Mosaic's 16 MiB scope; at a hidden width of 6,144 they do not (18.1 MiB), so a wider row
+# takes fewer rows a block, by halves
+_BLOCK_ELEMENTS = _BLOCK_ROWS * 4096
+
+
+def _row_blocks(n_rows: int, width: int = 0) -> int:
+    rows = _BLOCK_ROWS
+    while rows > 8 and rows * width > _BLOCK_ELEMENTS:
+        rows //= 2
+    return min(rows, n_rows)
 
 
 def _rms_fwd(x2, scale, eps):
     R, Dm = x2.shape
-    br = _row_blocks(R)
+    br = _row_blocks(R, Dm)
     return pl.pallas_call(
         functools.partial(_rms_fwd_kernel, eps=eps),
         name="rms_norm",
@@ -101,7 +110,7 @@ def pallas_rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Ar
 
 def _ln_fwd(x2, scale, bias, eps):
     R, Dm = x2.shape
-    br = _row_blocks(R)
+    br = _row_blocks(R, Dm)
     return pl.pallas_call(
         functools.partial(_ln_fwd_kernel, eps=eps),
         name="layer_norm",

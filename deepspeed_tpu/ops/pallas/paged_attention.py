@@ -466,10 +466,14 @@ LATENT_PAGES_PER_BLOCK = 16
 # place there and went from 0.72 to 3.55 ms a layer-call, most of what the
 # kernel had gained (PERF.md, PR 52).
 _LATENT_VMEM_BUDGET = 16 << 20
+# under a per-query mask (an indexer's choice) a prompt's step is the smallest tile against chunks no wider than
+# this: at 64 heads the unmasked step at 16 pages stands 1.7 MiB under the scope, and the masked one holds a
+# float32 [rows, chunk] more beside the mask's tile and the spread matrix
+_MASKED_PAGES_PER_BLOCK = 8
 
 
 def _latent_kernel(bt_ref, ctx_ref, q_ref, qpos_ref, k_hbm, o_ref, kbuf, acc_ref, m_ref, l_ref,
-                   sems, slot_ref, *, ppcb, bs, tiles, v_width):
+                   sems, slot_ref, *, ppcb, bs, tiles, v_width, chosen=None):
     i = pl.program_id(0)
     steps = pl.num_programs(0)
     T = ppcb * bs
@@ -521,6 +525,14 @@ def _latent_kernel(bt_ref, ctx_ref, q_ref, qpos_ref, k_hbm, o_ref, kbuf, acc_ref
                                 preferred_element_type=jnp.float32)  # [rows, T]
         j = c * T + jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
         s = jnp.where(j <= qpos_ref[0], s, _NEG_INF)
+        if chosen is not None:
+            # a query's chosen positions, one row a TOKEN: spread over its heads' rows by a product with the
+            # 0/1 matrix that says which token a row is, which the MXU does beside the scores
+            mask_ref, spread_ref = chosen
+            mask = mask_ref[0, :, pl.ds(pl.multiple_of(c * T, T), T)]  # [tq, T]
+            allowed = jax.lax.dot_general(spread_ref[...], mask, (((1,), (0,)), ((), ())),
+                                          preferred_element_type=jnp.float32)
+            s = jnp.where(allowed > 0.5, s, _NEG_INF)
         m_prev = m_ref[:, :1]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         m_safe = jnp.where(m_cur == _NEG_INF, 0.0, m_cur)
@@ -555,6 +567,12 @@ def _latent_kernel(bt_ref, ctx_ref, q_ref, qpos_ref, k_hbm, o_ref, kbuf, acc_ref
     slot_ref[0] = (slot0 + nc) % 2
     l = l_ref[:, :1]
     o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _masked_latent_kernel(bt_ref, ctx_ref, q_ref, qpos_ref, mask_ref, spread_ref, k_hbm, o_ref, *scratch, **form):
+    """``_latent_kernel`` under a per-query mask (an indexer's choice): ``mask_ref`` [1, tq, columns] 0/1 of the
+    tile's tokens over the row's positions, ``spread_ref`` [rows, tq] 0/1, row ``r`` token ``r // H``."""
+    _latent_kernel(bt_ref, ctx_ref, q_ref, qpos_ref, k_hbm, o_ref, *scratch, chosen=(mask_ref, spread_ref), **form)
 
 
 def _latent_context(q_positions, new_lens, tile: int):
@@ -618,11 +636,18 @@ def flash_decode_latent(
     scale: float,
     v_width: int,  # a token's value: the slab's first v_width columns
     new_lens: jax.Array = None,  # [N] live tokens
+    mask: jax.Array = None,  # [N, C, >= P*bs] bool: the positions a query attends (an indexer's choice)
 ) -> jax.Array:
-    """-> [N, C, H, v_width]. One fetch of a page serves every head."""
+    """-> [N, C, H, v_width]. One fetch of a page serves every head. Under
+    ``mask`` a query attends the positions it marks, at or before its own, and
+    no others: the same walk over the row's pages up to the tile's last query,
+    the mask's tile fetched beside the queries' (kernel ``dsa_paged_attn``)."""
     N, C, H, W = q.shape
     bs = block_size
     tq, ppcb = _latent_form(C, H, W, v_width, q.dtype.itemsize, block_tables.shape[1], bs)
+    if mask is not None and C >= _LATENT_Q_TILE:
+        # the masked step holds besides: the mask's tile, the spread matrix and the spread mask
+        tq, ppcb = _LATENT_Q_TILE, min(ppcb, _MASKED_PAGES_PER_BLOCK)
     Cp = _cdiv(C, tq) * tq
     tiles = Cp // tq
     rows = _cdiv(tq * H, 16) * 16  # whole sublane tiles of the 16-bit query
@@ -640,15 +665,28 @@ def flash_decode_latent(
     q_op = jnp.pad(q_op, ((0, 0), (0, pad), (0, 0)))
     qpos = jnp.pad(qpos, ((0, 0), (0, pad)), constant_values=-1)  # padded rows see nothing
 
+    kernel, name, chosen, chosen_specs = _latent_kernel, "mla_paged_attn", (), []
+    if mask is not None:
+        T = ppcb * bs
+        columns = _cdiv(block_tables.shape[1], ppcb) * T  # every chunk the walk can reach
+        mask = mask.astype(q.dtype)
+        if mask.shape[-1] < columns or Cp != C:
+            mask = jnp.pad(mask, ((0, 0), (0, Cp - C), (0, max(columns - mask.shape[-1], 0))))
+        spread = (jnp.arange(rows)[:, None] // H == jnp.arange(tq)[None, :]).astype(q.dtype)
+        kernel, name = _masked_latent_kernel, "dsa_paged_attn"
+        chosen = (mask.reshape(N * tiles, tq, mask.shape[-1]), spread)
+        chosen_specs = [pl.BlockSpec((1, tq, mask.shape[-1]), lambda i, bt, cl: (i, 0, 0)),
+                        pl.BlockSpec((rows, tq), lambda i, bt, cl: (0, 0))]
     out = pl.pallas_call(
-        functools.partial(_latent_kernel, ppcb=ppcb, bs=bs, tiles=tiles, v_width=v_width),
-        name="mla_paged_attn",
+        functools.partial(kernel, ppcb=ppcb, bs=bs, tiles=tiles, v_width=v_width),
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # block_tables, the tiles' contexts
             grid=(N * tiles,),
             in_specs=[
                 pl.BlockSpec((1, rows, W), lambda i, bt, cl: (i, 0, 0)),
                 pl.BlockSpec((1, rows, 1), lambda i, bt, cl: (i, 0, 0)),
+                *chosen_specs,
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((1, rows, v_width), lambda i, bt, cl: (i, 0, 0)),
@@ -665,5 +703,5 @@ def flash_decode_latent(
         # steps in order: each starts the next one's first fetch
         compiler_params=tpu_compiler_params(dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
-    )(block_tables, ctx, q_op, qpos[:, :, None], pool)
+    )(block_tables, ctx, q_op, qpos[:, :, None], *chosen, pool)
     return out[:, : tq * H].reshape(N, Cp, H, v_width)[:, :C]

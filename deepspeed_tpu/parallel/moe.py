@@ -573,8 +573,15 @@ class _Router(nn.Module):
         if cfg.moe_router == "sigmoid":
             # sigmoid scores of lecun-normal logits spread by about a quarter
             # over the experts: a bias of a fifth of that changes picks
-            # without taking the choice over
-            out["e_bias"] = self.param("e_bias", _nonzero_normal(0.05), (cfg.num_experts,),
+            # without taking the choice over, among 64 experts or fewer. The
+            # gap between neighbouring scores at the cut shrinks as 1/experts,
+            # and the bias with it: at 256 experts and 8 picks one of 0.05 makes
+            # an expert 2.4 times or a third as popular as its neighbour, where
+            # a trained correction bias is what BALANCES them, and a chip that
+            # holds 16 of them carries a load that follows the draw (a third
+            # more rows in its grouped products from one seed to the next)
+            spread = min(0.05, 3.2 / cfg.router_experts)
+            out["e_bias"] = self.param("e_bias", _nonzero_normal(spread), (cfg.router_experts,),
                                        cfg.param_dtype)
         return out
 

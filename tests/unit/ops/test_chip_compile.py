@@ -1002,6 +1002,153 @@ def test_the_xing_prefill_holds_no_copy_of_the_streams(one_chip, monkeypatch):
     assert not widened, widened
 
 
+
+# ----------------------------------------------------------- a learned indexer (glm_moe_dsa: GLM-5)
+def test_the_index_kernel_compiles_at_the_cell_s_shapes(one_chip, monkeypatch):
+    """``dsa_index`` at glm-5.serve.long-prompt-wave8's own shapes: one row of
+    8,192 queries of 32 index heads of 128 against the 8,256 keys its block
+    table holds: ONE Mosaic kernel, its scores in whole key tiles (8,704
+    columns, float32), within the default 16 MiB scope."""
+    from deepspeed_tpu.ops.pallas import dsa as kernel
+
+    monkeypatch.setattr(kernel, "_interpret", lambda: False)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    text = jax.jit(kernel.index_scores).lower(
+        sds((1, 8192, 32, 128), jnp.bfloat16), sds((1, 8256, 128), jnp.bfloat16), sds((1, 8192, 32), jnp.float32),
+        sds((1, 8192), jnp.int32)).compile().as_text()
+    (call,) = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert re.search(r"%dsa_index\S* = f32\[1,8192,8704\]", call), call[:300]
+
+
+@pytest.mark.parametrize("N,C,steps,rows", [(1, 8192, 512, 1024), (2, 64, 8, 1024)], ids=["cell-row-8192", "two-rows-64"])
+def test_the_latent_kernel_under_a_mask_compiles_at_64_heads(one_chip, monkeypatch, N, C, steps, rows):
+    """``dsa_paged_attn`` at the glm-5 cell's shapes: 64 heads against one
+    640-column slab a token, a table of 516 pages, a 1 GiB pool of 6 layers, the
+    mask [N, C, 8,704] of the index kernel's width: tiles of 16 tokens (1,024
+    rows) against chunks of 8 pages, ONE kernel, no copy of the pool, within
+    the default Mosaic scope (no ``vmem_limit_bytes``)."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    bf16 = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)  # noqa: E731
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+
+    def fn(q, pool, bt, qpos, lens, mask):
+        return pa.flash_decode_latent(q, pool, bt, qpos, 16, 1 / 16, 512, new_lens=lens, mask=mask)
+
+    text = jax.jit(fn).lower(bf16(N, C, 64, 640), bf16(43686, 16, 640), i32(N, 516), i32(N, C), i32(N),
+                             jax.ShapeDtypeStruct((N, C, 8704), jnp.bool_, sharding=one_chip)).compile().as_text()
+    (call,) = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert re.search(r"%%dsa_paged_attn\S* = bf16\[%d,%d,512\]" % (steps, rows), call), call[:300]
+    assert "vmem_limit" not in call.split("custom_call_config")[0]
+    assert not [line for line in text.splitlines()
+                if re.search(r"= bf16\[43686,16,640\]\S* (copy|copy-start|transpose)\(", line)]
+
+
+@pytest.mark.parametrize("name", ["prefill_2x8192", "check_4x8192", "check_4x1", "chain_8"])
+def test_glm_5_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, name):
+    """``glm-5.serve.long-prompt-wave8``'s programs whole, for the described v5e
+    at the cell's own shapes (1 dense + 5 routed layers at the published
+    widths, 64 heads, the indexer's 32 heads of 128 keeping 2,048, 16 of 256
+    experts held, a 1 GiB pool of latents AND index keys on one block table of
+    516 pages), with the picks handed out as the timed path hands them: the
+    ``(2, 8192)`` prefill (``dsa_index`` and ``dsa_paged_attn`` once in the
+    dense layer and once in the scan's body, the share's sorted dispatch
+    through megablox ``gmm``), the check's ``(4, 8192)`` and ``(4, 1)`` steps
+    (``runners/serve.py::check`` feeds four prompts through ``put``: their
+    attention goes a row of 8,192 at a time, ``_ATTEND_GROUP_TOKENS``, or the
+    four rows' absorbed queries alone are 2.7 GB) and the chain of 8 steps at 8
+    rows (one token a row: XLA's index scores, ``lax.top_k``, the kept rows
+    gathered; ``moe_decode`` on the stacked experts). Each fits the chip
+    beside the weights and the pool with room to spare, returns the donated
+    pool aliased (both arrays), and copies neither."""
+    import dataclasses
+    import json
+
+    from benchmarks.lib import harness, program
+    from deepspeed_tpu.checkpoint.hf import config_from_hf
+    from deepspeed_tpu.inference import model, paged
+    from deepspeed_tpu.models import CausalLM
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import dsa as dsa_kernel, flash_attention as fa, moe_decode, norms, paged_attention as pa
+
+    for module in (pa, fa, norms, moe_decode, dsa_kernel):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")  # what 'auto' sees there
+    monkeypatch.setattr(model, "_grouped_matmul", lambda lhs, rhs, sizes: model._gmm_padded(lhs, rhs, sizes))
+    cfg = dataclasses.replace(config_from_hf(program.published(harness.load_config("glm-5"))), dtype=jnp.bfloat16)
+    assert (cfg.num_experts, cfg.router_experts, cfg.index_topk, cfg.num_heads) == (16, 256, 2048, 64)
+    engine = harness.load_workload("glm-5.serve.long-prompt-wave8")["engine"]
+    bs = engine["kv_block_size"]
+    per_token = cfg.num_layers * 2 * (paged.latent_pool_width(cfg) + paged.index_pool_width(cfg))
+    assert per_token == 9216
+    NB, table = engine["kv_pool_bytes"] // (bs * per_token), -(-engine["max_seq_len"] // bs)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda key: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), CausalLM(cfg).init(
+            {"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)}, train=False)["params"]),
+        jax.random.PRNGKey(0)))
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: paged.init_pool(cfg, NB, bs, jnp.bfloat16)))
+    assert pool.k.shape == (43686, 16, 640) and pool.v.shape == (43686, 16, 128) and table == 516
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    if name == "chain_8":
+        rows = engine["max_seqs"]
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def program_(params, pool, tokens, start_pos, tables, active, budgets, rng):
+            return paged.ragged_decode_chain(params, cfg, pool, tokens, start_pos, tables, bs,
+                                             active, budgets, rng, engine["decode_chain"], None, with_picks=True)
+
+        args = (i32(rows), i32(rows), i32(rows, table), jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip),
+                i32(rows), jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip))
+    else:
+        rows, chunk = map(int, name.partition("_")[2].split("x"))
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def program_(params, pool, tokens, positions, new_lens, tables):
+            return paged.ragged_forward(params, cfg, pool, tokens, positions, new_lens, tables, bs, with_picks=True)
+
+        args = (i32(rows, chunk), i32(rows, chunk), i32(rows), i32(rows, table))
+    compiled = program_.lower(params, pool, *args).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pool))
+    assert pool_bytes == 43686 * 16 * 768 * 2 and mem.alias_size_in_bytes >= pool_bytes
+    peak_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30
+    print(json.dumps({"program": name, "temp_gb": mem.temp_size_in_bytes / 1e9,
+                      "argument_gb": mem.argument_size_in_bytes / 1e9, "peak_gib": peak_gib}))
+    assert peak_gib < 14.5  # of 15.75: the check's four rows stand at 14.1, the timed prefill at 13.3
+    text = compiled.as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+    count = lambda kernel: sum(kernel in name for name in calls)  # noqa: E731
+    if chunk_of(name) > 1:
+        assert (count("dsa_index"), count("dsa_paged_attn")) == (2, 2) and count("gmm") >= 3
+        assert not count("mla_paged_attn") and not count("moe_decode")
+    else:  # one token a row: no kernel of the indexer's, the kept rows gathered
+        assert not count("dsa_index") and not count("dsa_paged_attn") and not count("mla_paged_attn")
+        assert "dsa_select" in text and "dsa_attend" in text
+        if name == "chain_8":
+            assert count("moe_decode") == 1
+    moved = [line.strip()[:200] for line in text.splitlines()
+             if re.search(r"= \(?bf16\[43686,16,(640|128)\]\S* (copy|copy-start|transpose)\(", line)
+             or re.search(r"bf16\[43686,16,(640|128)\]\S*S\(1\)", line)]
+    assert not moved, moved
+
+
+def chunk_of(name: str) -> int:
+    return 1 if name.startswith("chain") else int(name.rpartition("x")[2])
+
+
+def test_the_norm_kernel_takes_fewer_rows_a_block_at_a_hidden_width_of_6144(one_chip, monkeypatch):
+    """``rms_norm`` at [16384, 6144] in bf16: 256 rows a block stood 2.1 MiB over Mosaic's 16 MiB scope (the first
+    thing the glm-5 prefill's compile refused); 128 fit. At 4,096 columns and under the block is the 256 it was."""
+    from deepspeed_tpu.ops.pallas import norms
+
+    assert [norms._row_blocks(16384, w) for w in (2048, 4096, 6144, 8192)] == [256, 256, 128, 128]
+    monkeypatch.setattr(norms, "_interpret", lambda: False)
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)  # noqa: E731
+    assert _compiled_kernels(lambda x, s: norms.pallas_rms_norm(x, s, 1e-5), sds((16384, 6144)), sds((6144,))) == 1
+
+
 @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["bf16", "int8"])
 def test_decode_chain_updates_the_pool_in_place(one_chip, monkeypatch, kv_quant):
     """Whether a carried array is updated in place is the chip's compiler's
