@@ -309,7 +309,10 @@ def _xla_latent_paged_attention(q, pool, block_tables, q_positions, block_size, 
     ``ops/pallas/paged_attention.py``). q: [N, C, H, W] against the whole
     slab; pool: [pages, bs, W]; a token's value is its slab's first
     ``v_width`` columns. ``mask`` bool [N, C, P*bs]: the positions a query
-    attends, where an indexer chose them. Returns [N, C, H, v_width]."""
+    attends, where an indexer chose them; it marks none past the query's own,
+    and ``ok & mask`` below is the statement of that: the kernel's step under
+    a mask makes no causal compare of its own (``dsa_paged_attn``, since
+    PR 56). Returns [N, C, H, v_width]."""
     N, C, H, W = q.shape
     P = block_tables.shape[1]
     slab = pool[block_tables].reshape(N, P * block_size, W)  # slot index == position

@@ -456,6 +456,13 @@ def flash_decode_paged(
 # MXU as weights, the accumulator read, scaled and written) is then spread
 # over more scores, and a row's pages are fetched again by fewer tiles. The
 # kernel's body is the same at every shape.
+#
+# Under a per-query mask (an indexer's choice; ``dsa_paged_attn``) the walk
+# and the softmax are the same code (``_latent_walk``) and the step is its
+# own: its rows go head by head, so that the mask's tile, one row a TOKEN,
+# lies over every head's block of scores as it is, and it makes no causal
+# compare, which the mask carries. Its tile and chunk come from
+# ``_masked_latent_form``, under a count of its own bytes.
 _LATENT_Q_TILE = 16
 LATENT_PAGES_PER_BLOCK = 16
 # Mosaic's default scope of VMEM, which a prompt's grid step has to fit by
@@ -466,19 +473,22 @@ LATENT_PAGES_PER_BLOCK = 16
 # place there and went from 0.72 to 3.55 ms a layer-call, most of what the
 # kernel had gained (PERF.md, PR 52).
 _LATENT_VMEM_BUDGET = 16 << 20
-# under a per-query mask (an indexer's choice) a prompt's step is the smallest tile against chunks no wider than
-# this: at 64 heads the unmasked step at 16 pages stands 1.7 MiB under the scope, and the masked one holds a
-# float32 [rows, chunk] more beside the mask's tile and the spread matrix
-_MASKED_PAGES_PER_BLOCK = 8
 
 
-def _latent_kernel(bt_ref, ctx_ref, q_ref, qpos_ref, k_hbm, o_ref, kbuf, acc_ref, m_ref, l_ref,
-                   sems, slot_ref, *, ppcb, bs, tiles, v_width, chosen=None):
+def _latent_walk(bt_ref, ctx_ref, k_hbm, kbuf, acc_ref, m_ref, l_ref, sems, slot_ref, *,
+                 ppcb, bs, tiles, v_width, cdt, step):
+    """A grid step of the latent kernels, all but the scores: the walk over the
+    page-chunks the step's queries may see (two slots, the next chunk in
+    flight, the dead slots of a last chunk zeroed) and the online softmax over
+    what ``step()``'s ``scores(c, k)`` gives for chunk ``c``, float32
+    ``[rows, T]`` with ``_NEG_INF`` where a row does not attend (``step`` is
+    called once, after the scratch is reset: there the causal step loads its
+    query). Returns the normalised output, float32 ``[rows, v_width]``; which
+    query a row is, is the caller's."""
     i = pl.program_id(0)
     steps = pl.num_programs(0)
     T = ppcb * bs
     W = kbuf.shape[-1]
-    cdt = q_ref.dtype
 
     def pages_of(step):  # the pages a grid step's queries may see
         return _cdiv(ctx_ref[step], bs)
@@ -507,8 +517,7 @@ def _latent_kernel(bt_ref, ctx_ref, q_ref, qpos_ref, k_hbm, o_ref, kbuf, acc_ref
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
-    q = q_ref[0]  # [rows, W], pre-scaled
-    rows = q.shape[0]
+    scores = step()
 
     def compute(c, slot):
         # past the context the scores are masked, but 0 * NaN is NaN in p @ v:
@@ -521,18 +530,7 @@ def _latent_kernel(bt_ref, ctx_ref, q_ref, qpos_ref, k_hbm, o_ref, kbuf, acc_ref
                 kbuf[slot, j] = jnp.where(pos < ctx, kbuf[slot, j], jnp.zeros((), kbuf.dtype))
 
         k = kbuf[slot].reshape(T, W).astype(cdt)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [rows, T]
-        j = c * T + jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
-        s = jnp.where(j <= qpos_ref[0], s, _NEG_INF)
-        if chosen is not None:
-            # a query's chosen positions, one row a TOKEN: spread over its heads' rows by a product with the
-            # 0/1 matrix that says which token a row is, which the MXU does beside the scores
-            mask_ref, spread_ref = chosen
-            mask = mask_ref[0, :, pl.ds(pl.multiple_of(c * T, T), T)]  # [tq, T]
-            allowed = jax.lax.dot_general(spread_ref[...], mask, (((1,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32)
-            s = jnp.where(allowed > 0.5, s, _NEG_INF)
+        s = scores(c, k)
         m_prev = m_ref[:, :1]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         m_safe = jnp.where(m_cur == _NEG_INF, 0.0, m_cur)
@@ -566,13 +564,51 @@ def _latent_kernel(bt_ref, ctx_ref, q_ref, qpos_ref, k_hbm, o_ref, kbuf, acc_ref
 
     slot_ref[0] = (slot0 + nc) % 2
     l = l_ref[:, :1]
-    o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+    return acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
 
 
-def _masked_latent_kernel(bt_ref, ctx_ref, q_ref, qpos_ref, mask_ref, spread_ref, k_hbm, o_ref, *scratch, **form):
-    """``_latent_kernel`` under a per-query mask (an indexer's choice): ``mask_ref`` [1, tq, columns] 0/1 of the
-    tile's tokens over the row's positions, ``spread_ref`` [rows, tq] 0/1, row ``r`` token ``r // H``."""
-    _latent_kernel(bt_ref, ctx_ref, q_ref, qpos_ref, k_hbm, o_ref, *scratch, chosen=(mask_ref, spread_ref), **form)
+def _latent_kernel(bt_ref, ctx_ref, q_ref, qpos_ref, k_hbm, o_ref, *scratch, **form):
+    """The causal step: rows token by token (row ``t * H + h``), each against
+    every position at or before its own (``qpos_ref`` [1, rows, 1]; -1 on a
+    padded row)."""
+
+    def step():
+        q = q_ref[0]  # [rows, W], pre-scaled; held across the walk
+        rows = q.shape[0]
+
+        def scores(c, k):
+            T = k.shape[0]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)  # [rows, T]
+            j = c * T + jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
+            return jnp.where(j <= qpos_ref[0], s, _NEG_INF)
+
+        return scores
+
+    out = _latent_walk(bt_ref, ctx_ref, k_hbm, *scratch, cdt=q_ref.dtype, step=step, **form)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _masked_latent_kernel(bt_ref, ctx_ref, q_ref, mask_ref, k_hbm, o_ref, *scratch, **form):
+    """The step under a per-query mask (an indexer's choice): rows HEAD BY HEAD
+    (row ``h * tq + t``; ``q_ref`` [1, H, tq, W]), so the scores are ``H``
+    blocks of ``[tq, T]`` and the mask's tile ``[tq, T]`` (0/1, one row a TOKEN)
+    lies over each as it is, a bias broadcast over the leading dimension. No
+    causal compare: the mask marks no position past its query's own. The query
+    is read from its block every chunk, not held across the walk: the room
+    that frees is the chunk's."""
+    H, tq, W = q_ref.shape[1:]
+
+    def scores(c, k):
+        T = k.shape[0]
+        s = jax.lax.dot_general(q_ref[0].reshape(H * tq, W), k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # [H * tq, T]
+        kept = mask_ref[0, :, pl.ds(pl.multiple_of(c * T, T), T)]  # [tq, T]
+        bias = (1.0 - kept.astype(jnp.float32)) * _NEG_INF  # (a score + _NEG_INF rounds to _NEG_INF)
+        return (s.reshape(H, tq, T) + bias[None]).reshape(H * tq, T)
+
+    out = _latent_walk(bt_ref, ctx_ref, k_hbm, *scratch, cdt=q_ref.dtype, step=lambda: scores, **form)
+    o_ref[0] = out.astype(o_ref.dtype).reshape(H, tq, out.shape[-1])
 
 
 def _latent_context(q_positions, new_lens, tile: int):
@@ -582,6 +618,33 @@ def _latent_context(q_positions, new_lens, tile: int):
     live = jnp.ones((N, C), bool) if new_lens is None else jnp.arange(C)[None, :] < new_lens[:, None]
     seen = jnp.where(live, q_positions + 1, 0).reshape(N, C // tile, tile)
     return seen.max(axis=-1).astype(jnp.int32)
+
+
+def _whole_tiles(q, q_positions, new_lens, Cp: int):
+    """A call's queries [N, C, H, W], positions and live counts with the queries
+    padded to ``Cp``, whole tiles: a padded query stands at position -1 and is
+    not live."""
+    N, C = q_positions.shape
+    if Cp != C:
+        q = jnp.pad(q, ((0, 0), (0, Cp - C), (0, 0), (0, 0)))
+        q_positions = jnp.pad(q_positions, ((0, 0), (0, Cp - C)), constant_values=-1)
+        if new_lens is None:
+            new_lens = jnp.full((N,), C, jnp.int32)
+    return q, q_positions, new_lens
+
+
+def _latent_scratch(rows: int, ppcb: int, bs: int, W: int, v_width: int, pool_dtype) -> list:
+    """``_latent_walk``'s scratch: the pages' two slots (one computes, one
+    fills), the accumulator, the running max and sum, the slots' semaphores
+    and where the next step finds its first chunk."""
+    return [
+        pltpu.VMEM((2, ppcb, bs, W), pool_dtype),
+        pltpu.VMEM((rows, v_width), jnp.float32),
+        pltpu.VMEM((rows, 128), jnp.float32),
+        pltpu.VMEM((rows, 128), jnp.float32),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SMEM((1,), jnp.int32),
+    ]
 
 
 def _latent_vmem_bytes(rows: int, T: int, W: int, v_width: int, itemsize: int) -> int:
@@ -626,6 +689,47 @@ def _latent_form(C: int, H: int, W: int, v_width: int, itemsize: int, P: int, bs
     return tq, ppcb
 
 
+def _masked_latent_vmem_bytes(H: int, tq: int, T: int, W: int, v_width: int, itemsize: int, columns: int) -> int:
+    """``_latent_vmem_bytes`` of the step under a mask, ``H * tq`` rows against
+    chunks of ``T`` tokens under a mask ``columns`` wide: the blocks of q and of
+    the output as there, no block of positions and no q held across the walk;
+    the mask's tile, double-buffered; and what a chunk-step holds besides its
+    float32 scores comes to a quarter more of them and three and a half
+    columns a row. Fitted to the compiler's verdicts at 16 to 64 heads over
+    tiles of 16 to 128 tokens and chunks of 8 to 256 pages of a row of 8,192
+    tokens, 42 forms, all of which it reproduces
+    (``tests/unit/ops/test_chip_compile.py`` holds the ones at its edge); a
+    shorter row leaves the compiler a little more room, which this leaves
+    unused."""
+    rows, column = H * tq, 128 * 4
+    blocks = 2 * (W * itemsize + v_width * itemsize)
+    scratch = v_width * 4 + 2 * column
+    held = 7 * column // 2
+    return (rows * (blocks + scratch + held) + 2 * tq * columns * itemsize + 2 * T * W * itemsize
+            + rows * T * 5)
+
+
+def _masked_latent_form(C: int, H: int, W: int, v_width: int, itemsize: int, P: int, bs: int,
+                        columns: int = 0) -> tuple[int, int]:
+    """``_latent_form`` of the step under a mask ``columns`` wide: (query tokens
+    a tile, a whole number of the mask's 16-row tiles; pages a chunk). At 64
+    heads and 8,192 tokens a row, tiles of 16 tokens (1,024 rows) against
+    chunks of 32 pages."""
+    # (a chunk is whole 128-lane tiles of the mask's columns whatever the table holds)
+    tq, ppcb = _LATENT_Q_TILE, LATENT_PAGES_PER_BLOCK
+
+    def fits(tq, ppcb):
+        reach = _cdiv(P, ppcb) * ppcb * bs  # every chunk the walk can reach: the mask is padded to it
+        return _masked_latent_vmem_bytes(H, tq, ppcb * bs, W, v_width, itemsize,
+                                         max(columns, reach)) <= _LATENT_VMEM_BUDGET
+
+    while 2 * tq <= C and fits(2 * tq, ppcb):
+        tq *= 2
+    while 2 * ppcb <= P and 2 * ppcb * bs <= C and fits(tq, 2 * ppcb):
+        ppcb *= 2
+    return tq, ppcb
+
+
 @register("latent_paged_attention", "pallas")
 def flash_decode_latent(
     q: jax.Array,  # [N, C, H, W]: a head's query against the whole slab, NOT yet scaled
@@ -639,24 +743,19 @@ def flash_decode_latent(
     mask: jax.Array = None,  # [N, C, >= P*bs] bool: the positions a query attends (an indexer's choice)
 ) -> jax.Array:
     """-> [N, C, H, v_width]. One fetch of a page serves every head. Under
-    ``mask`` a query attends the positions it marks, at or before its own, and
-    no others: the same walk over the row's pages up to the tile's last query,
-    the mask's tile fetched beside the queries' (kernel ``dsa_paged_attn``)."""
+    ``mask`` a query attends the positions it marks and no others, and the mask
+    marks none past the query's own (an indexer scores no later position:
+    ``ops/dsa.py``): the same walk over the row's pages up to the tile's last
+    query, the mask's tile fetched beside the queries' (``dsa_paged_attn``)."""
     N, C, H, W = q.shape
     bs = block_size
+    if mask is not None:
+        return _flash_decode_latent_masked(q, pool, block_tables, q_positions, bs, scale, v_width, new_lens, mask)
     tq, ppcb = _latent_form(C, H, W, v_width, q.dtype.itemsize, block_tables.shape[1], bs)
-    if mask is not None and C >= _LATENT_Q_TILE:
-        # the masked step holds besides: the mask's tile, the spread matrix and the spread mask
-        tq, ppcb = _LATENT_Q_TILE, min(ppcb, _MASKED_PAGES_PER_BLOCK)
     Cp = _cdiv(C, tq) * tq
     tiles = Cp // tq
     rows = _cdiv(tq * H, 16) * 16  # whole sublane tiles of the 16-bit query
-    q = (q * jnp.asarray(scale, q.dtype))
-    if Cp != C:
-        q = jnp.pad(q, ((0, 0), (0, Cp - C), (0, 0), (0, 0)))
-        q_positions = jnp.pad(q_positions, ((0, 0), (0, Cp - C)), constant_values=-1)
-        if new_lens is None:
-            new_lens = jnp.full((N,), C, jnp.int32)
+    q, q_positions, new_lens = _whole_tiles(q * jnp.asarray(scale, q.dtype), q_positions, new_lens, Cp)
     ctx = _latent_context(q_positions, new_lens, tq).reshape(N * tiles)
     q_op = q.reshape(N * tiles, tq * H, W)
     qpos = jnp.broadcast_to(q_positions.reshape(N * tiles, tq, 1), (N * tiles, tq, H))
@@ -665,43 +764,66 @@ def flash_decode_latent(
     q_op = jnp.pad(q_op, ((0, 0), (0, pad), (0, 0)))
     qpos = jnp.pad(qpos, ((0, 0), (0, pad)), constant_values=-1)  # padded rows see nothing
 
-    kernel, name, chosen, chosen_specs = _latent_kernel, "mla_paged_attn", (), []
-    if mask is not None:
-        T = ppcb * bs
-        columns = _cdiv(block_tables.shape[1], ppcb) * T  # every chunk the walk can reach
-        mask = mask.astype(q.dtype)
-        if mask.shape[-1] < columns or Cp != C:
-            mask = jnp.pad(mask, ((0, 0), (0, Cp - C), (0, max(columns - mask.shape[-1], 0))))
-        spread = (jnp.arange(rows)[:, None] // H == jnp.arange(tq)[None, :]).astype(q.dtype)
-        kernel, name = _masked_latent_kernel, "dsa_paged_attn"
-        chosen = (mask.reshape(N * tiles, tq, mask.shape[-1]), spread)
-        chosen_specs = [pl.BlockSpec((1, tq, mask.shape[-1]), lambda i, bt, cl: (i, 0, 0)),
-                        pl.BlockSpec((rows, tq), lambda i, bt, cl: (0, 0))]
     out = pl.pallas_call(
-        functools.partial(kernel, ppcb=ppcb, bs=bs, tiles=tiles, v_width=v_width),
-        name=name,
+        functools.partial(_latent_kernel, ppcb=ppcb, bs=bs, tiles=tiles, v_width=v_width),
+        name="mla_paged_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # block_tables, the tiles' contexts
             grid=(N * tiles,),
             in_specs=[
                 pl.BlockSpec((1, rows, W), lambda i, bt, cl: (i, 0, 0)),
                 pl.BlockSpec((1, rows, 1), lambda i, bt, cl: (i, 0, 0)),
-                *chosen_specs,
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((1, rows, v_width), lambda i, bt, cl: (i, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, ppcb, bs, W), pool.dtype),  # two slots: one computes, one fills
-                pltpu.VMEM((rows, v_width), jnp.float32),
-                pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SMEM((1,), jnp.int32),
-            ],
+            scratch_shapes=_latent_scratch(rows, ppcb, bs, W, v_width, pool.dtype),
         ),
         out_shape=jax.ShapeDtypeStruct((N * tiles, rows, v_width), q.dtype),
         # steps in order: each starts the next one's first fetch
         compiler_params=tpu_compiler_params(dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
-    )(block_tables, ctx, q_op, qpos[:, :, None], *chosen, pool)
+    )(block_tables, ctx, q_op, qpos[:, :, None], pool)
     return out[:, : tq * H].reshape(N, Cp, H, v_width)[:, :C]
+
+
+def _flash_decode_latent_masked(q, pool, block_tables, q_positions, bs, scale, v_width, new_lens, mask):
+    """``flash_decode_latent`` under ``mask``: the kernel takes the queries head
+    by head, ``[N, H, C, W]`` in blocks of ``(1, H, tq, W)``, and gives
+    ``[N, H, C, v_width]`` back (the layouts the absorbed products around it
+    have: both are batched over heads), so a step's rows are ``h * tq + t``
+    and the mask's ``[tq, T]`` tile lies over every head's block of scores."""
+    N, C, H, W = q.shape
+    P = block_tables.shape[1]
+    tq, ppcb = _masked_latent_form(C, H, W, v_width, q.dtype.itemsize, P, bs, mask.shape[-1])
+    Cp = _cdiv(C, tq) * tq
+    tiles = Cp // tq
+    T = ppcb * bs
+    columns = _cdiv(P, ppcb) * T  # every chunk the walk can reach
+    q, q_positions, new_lens = _whole_tiles(q * jnp.asarray(scale, q.dtype), q_positions, new_lens, Cp)
+    q = q.transpose(0, 2, 1, 3)
+    mask = mask.astype(q.dtype)
+    if mask.shape[-1] < columns or Cp != C:  # a padded query attends nothing
+        mask = jnp.pad(mask, ((0, 0), (0, Cp - C), (0, max(columns - mask.shape[-1], 0))))
+    ctx = _latent_context(q_positions, new_lens, tq).reshape(N * tiles)
+    rows = H * tq
+    tile = lambda i, bt, cl: (i // tiles, 0, i % tiles, 0)  # noqa: E731
+    out = pl.pallas_call(
+        functools.partial(_masked_latent_kernel, ppcb=ppcb, bs=bs, tiles=tiles, v_width=v_width),
+        name="dsa_paged_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # block_tables, the tiles' contexts
+            grid=(N * tiles,),
+            in_specs=[
+                pl.BlockSpec((1, H, tq, W), tile),
+                pl.BlockSpec((1, tq, mask.shape[-1]), lambda i, bt, cl: (i // tiles, i % tiles, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, H, tq, v_width), tile),
+            scratch_shapes=_latent_scratch(rows, ppcb, bs, W, v_width, pool.dtype),
+        ),
+        out_shape=jax.ShapeDtypeStruct((N, H, Cp, v_width), q.dtype),
+        # steps in order: each starts the next one's first fetch
+        compiler_params=tpu_compiler_params(dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+    )(block_tables, ctx, q, mask, pool)
+    return out[:, :, :C].transpose(0, 2, 1, 3)

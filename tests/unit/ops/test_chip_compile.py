@@ -346,21 +346,23 @@ def test_paged_attention_compiles(one_chip, monkeypatch, N, C, kv_quant, H, kvH,
     assert _compiled_kernels(fn, *shapes) == 1
 
 
-def _latent_kernel_compiled(one_chip, monkeypatch, N, C, H, pages, cols, scale):
+def _latent_kernel_compiled(one_chip, monkeypatch, N, C, H, pages, cols, scale, mask_columns=0):
     """``flash_decode_latent`` compiled for the described chip at a cell's
-    shapes: the text of its one Mosaic kernel's instruction, after the checks
-    that it is ONE and that nothing of the pool's whole shape is a copy."""
+    shapes, under a mask ``[N, C, mask_columns]`` where that is given: the text
+    of its one Mosaic kernel's instruction, after the checks that it is ONE
+    and that nothing of the pool's whole shape is a copy."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
     monkeypatch.setattr(pa, "_interpret", lambda: False)
     bf16 = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)  # noqa: E731
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    mask = [jax.ShapeDtypeStruct((N, C, mask_columns), jnp.bool_, sharding=one_chip)] * (mask_columns > 0)
 
-    def fn(q, pool, bt, qpos, lens):
-        return pa.flash_decode_latent(q, pool, bt, qpos, 16, scale, 512, new_lens=lens)
+    def fn(q, pool, bt, qpos, lens, mask=None):
+        return pa.flash_decode_latent(q, pool, bt, qpos, 16, scale, 512, new_lens=lens, mask=mask)
 
     text = jax.jit(fn).lower(bf16(N, C, H, 640), bf16(pages, 16, 640), i32(N, cols), i32(N, C),
-                             i32(N)).compile().as_text()
+                             i32(N), *mask).compile().as_text()
     (kernel,) = [line for line in text.splitlines() if "tpu_custom_call" in line]
     assert not [line for line in text.splitlines()
                 if re.search(r"= bf16\[%d,16,640\]\S* (copy|copy-start|transpose)\(" % pages, line)]
@@ -1020,29 +1022,52 @@ def test_the_index_kernel_compiles_at_the_cell_s_shapes(one_chip, monkeypatch):
     assert re.search(r"%dsa_index\S* = f32\[1,8192,8704\]", call), call[:300]
 
 
-@pytest.mark.parametrize("N,C,steps,rows", [(1, 8192, 512, 1024), (2, 64, 8, 1024)], ids=["cell-row-8192", "two-rows-64"])
-def test_the_latent_kernel_under_a_mask_compiles_at_64_heads(one_chip, monkeypatch, N, C, steps, rows):
+def _masked_latent_kernel_compiled(one_chip, monkeypatch, N, C, H):
+    """``dsa_paged_attn`` at the glm-5 cell's pool (1 GiB of 6 layers), table (516 pages) and mask width."""
+    return _latent_kernel_compiled(one_chip, monkeypatch, N, C, H, 43686, 516, 1 / 16, mask_columns=8704)
+
+
+@pytest.mark.parametrize("N,C,steps,rows,pages", [(1, 8192, 512, 1024, 32), (2, 64, 8, 1024, 16)],
+                         ids=["cell-row-8192", "two-rows-64"])
+def test_the_latent_kernel_under_a_mask_compiles_at_64_heads(one_chip, monkeypatch, N, C, steps, rows, pages):
     """``dsa_paged_attn`` at the glm-5 cell's shapes: 64 heads against one
     640-column slab a token, a table of 516 pages, a 1 GiB pool of 6 layers, the
-    mask [N, C, 8,704] of the index kernel's width: tiles of 16 tokens (1,024
-    rows) against chunks of 8 pages, ONE kernel, no copy of the pool, within
-    the default Mosaic scope (no ``vmem_limit_bytes``)."""
+    mask [N, C, 8,704] of the index kernel's width: tiles of 16 tokens, the
+    rows head by head (1,024 of them; the queries go in and the output comes
+    out ``[N, heads, C, .]``, which is what the instruction's shape says since
+    PR 56), against chunks of 32 pages where a row brings 8,192 tokens (8
+    before PR 56) and of 16 where it brings 64; ONE kernel, no copy of the
+    pool, within the default Mosaic scope (no ``vmem_limit_bytes``)."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
-    monkeypatch.setattr(pa, "_interpret", lambda: False)
-    bf16 = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)  # noqa: E731
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
-
-    def fn(q, pool, bt, qpos, lens, mask):
-        return pa.flash_decode_latent(q, pool, bt, qpos, 16, 1 / 16, 512, new_lens=lens, mask=mask)
-
-    text = jax.jit(fn).lower(bf16(N, C, 64, 640), bf16(43686, 16, 640), i32(N, 516), i32(N, C), i32(N),
-                             jax.ShapeDtypeStruct((N, C, 8704), jnp.bool_, sharding=one_chip)).compile().as_text()
-    (call,) = [line for line in text.splitlines() if "tpu_custom_call" in line]
-    assert re.search(r"%%dsa_paged_attn\S* = bf16\[%d,%d,512\]" % (steps, rows), call), call[:300]
+    tq, ppcb = pa._masked_latent_form(C, 64, 640, 512, 2, 516, 16, 8704)
+    assert (N * C // tq, 64 * tq, ppcb) == (steps, rows, pages)
+    call = _masked_latent_kernel_compiled(one_chip, monkeypatch, N, C, 64)
+    assert re.search(r"%%dsa_paged_attn\S* = bf16\[%d,64,%d,512\]" % (N, C), call), call[:300]
     assert "vmem_limit" not in call.split("custom_call_config")[0]
-    assert not [line for line in text.splitlines()
-                if re.search(r"= bf16\[43686,16,640\]\S* (copy|copy-start|transpose)\(", line)]
+
+
+@pytest.mark.parametrize("H,tq,ppcb,fits", [(64, 16, 32, True), (64, 16, 48, True), (64, 16, 56, False),
+                                            (40, 32, 24, True), (40, 32, 32, False)], ids=lambda v: str(v))
+def test_the_masked_form_s_count_of_vmem_is_the_compiler_s_verdict(one_chip, monkeypatch, H, tq, ppcb, fits):
+    """``_masked_latent_vmem_bytes`` against ``_LATENT_VMEM_BUDGET`` says what
+    the chip's compiler says of a tile and a chunk under the glm-5 cell's mask,
+    on both sides of the edge at two head counts and the cell's 8,192 tokens
+    a row (a shorter row leaves the compiler more room: (16, 56) fits at
+    4,096, so there the count errs to the safe side): the form
+    ``_masked_latent_form`` picks at 64 heads, (16, 32), is the last doubling
+    that fits; (16, 48) fits too and was no faster on the chip (PERF.md,
+    PR 56)."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    columns = max(8704, -(-516 // ppcb) * ppcb * 16)
+    assert (pa._masked_latent_vmem_bytes(H, tq, ppcb * 16, 640, 512, 2, columns) <= pa._LATENT_VMEM_BUDGET) is fits
+    monkeypatch.setattr(pa, "_masked_latent_form", lambda *shapes: (tq, ppcb))
+    if fits:
+        _masked_latent_kernel_compiled(one_chip, monkeypatch, 1, 8192, H)
+    else:
+        with pytest.raises(Exception, match="vmem"):
+            _masked_latent_kernel_compiled(one_chip, monkeypatch, 1, 8192, H)
 
 
 @pytest.mark.parametrize("name", ["prefill_2x8192", "check_4x8192", "check_4x1", "chain_8"])

@@ -93,29 +93,80 @@ def test_a_prompt_s_queries_go_through_the_xla_form_a_tile_at_a_time():
     np.testing.assert_allclose(tiled, plain, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("C,H,lens", [(48, 4, (48, 30)), (32, 64, (32, 17))], ids=["toy", "64-heads"])
-def test_the_latent_kernel_under_a_mask_against_the_gather(C, H, lens):
+# C, H, live queries a row, each row's first position, pages a row, (tokens a tile, pages a chunk) or None for
+# the form the shapes choose, whether the mask is every position at or before
+_MASKED_CASES = {
+    "toy": (48, 4, (48, 30), (100, 0), 12, None, False),
+    "64-heads": (32, 64, (32, 17), (100, 0), 12, None, False),
+    "a-tile-straddles-a-prompt-s-end": (64, 4, (64, 37), (0, 0), 8, (16, 2), False),
+    "a-row-of-pad-queries-alone": (32, 4, (32, 0), (40, 0), 8, (16, 2), False),
+    "under-index-topk-every-position-at-or-before": (48, 4, (48, 20), (0, 0), 8, (16, 2), True),
+    "a-context-ends-in-a-chunk-s-first-page": (48, 4, (35, 48), (0, 16), 8, (16, 2), False),
+    "64-heads-five-chunks-of-two-pages": (32, 64, (32, 17), (100, 0), 12, (16, 2), False),
+    "6-heads-tiles-of-32": (70, 6, (70, 33), (57, 0), 8, (32, 3), False),
+}
+
+
+@pytest.mark.parametrize("case", list(_MASKED_CASES))
+def test_the_latent_kernel_under_a_mask_against_the_gather(case, monkeypatch):
     """Interpret mode: ``dsa_paged_attn`` attends the positions a query's mask marks and no others (a row from
     position 100, a row with dead queries), against the dense-gather form under the same mask; and unmasked, the
-    kernel's output moves: the mask is not decoration."""
+    kernel's output moves: the mask is not decoration. The mask marks no position past its query's own, which
+    the kernel's contract is since PR 56 (its step makes no causal compare). At forms with several chunks a
+    row: a tile of 16 queries that straddles a prompt's end (37 live: 5 of its 16), a row with no live query
+    beside a whole one, a mask that is every position at or before (a prompt under ``index_topk``), a context
+    of 35 = 32 + 3 positions that ends in the first page of a 32-column chunk, 64 heads, and 6 heads (no
+    multiple of 8) with 70 queries in tiles of 32 against chunks of 3 pages (the queries padded to 96)."""
     from deepspeed_tpu.inference.paged import _xla_latent_paged_attention
-    from deepspeed_tpu.ops.pallas.paged_attention import flash_decode_latent
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
 
+    C, H, lens, first, P, form, whole = _MASKED_CASES[case]
+    if form is not None:
+        monkeypatch.setattr(pa, "_masked_latent_form", lambda *shapes: form)
     rng = np.random.default_rng(0)
-    N, W, vw, bs, P = 2, 256, 128, 16, 12
+    N, W, vw, bs = 2, 256, 128, 16
     pool = jnp.asarray(rng.normal(size=(40, bs, W)), jnp.float32)
     tables = jnp.asarray(rng.permutation(40)[:N * P].reshape(N, P), jnp.int32)
     q = jnp.asarray(rng.normal(size=(N, C, H, W)), jnp.float32)
-    pos = jnp.asarray(np.stack([np.arange(C) + 100, np.arange(C)]), jnp.int32)
+    pos = jnp.asarray(np.stack([np.arange(C) + at for at in first]), jnp.int32)
     new_lens = jnp.asarray(lens, jnp.int32)
     # (every query keeps its own position, as a selection of index_topk >= 1 does; the kernel's columns come in tiles)
-    mask = jnp.asarray(rng.random((N, C, 256)) < 0.3) | (jnp.arange(256)[None, None] == pos[..., None])
+    at = jnp.arange(256)[None, None]
+    seen = at <= pos[..., None]
+    mask = seen if whole else (jnp.asarray(rng.random((N, C, 256)) < 0.3) & seen) | (at == pos[..., None])
     want = _xla_latent_paged_attention(q, pool, tables, pos, bs, 0.1, vw, new_lens=new_lens, mask=mask)
-    got = flash_decode_latent(q, pool, tables, pos, bs, 0.1, vw, new_lens=new_lens, mask=mask)
+    got = pa.flash_decode_latent(q, pool, tables, pos, bs, 0.1, vw, new_lens=new_lens, mask=mask)
     live = np.arange(C)[None] < np.asarray(new_lens)[:, None]
+    assert np.isfinite(np.asarray(got)).all()
     assert np.abs(np.asarray(want - got))[live].max() < 2e-5
-    dense = flash_decode_latent(q, pool, tables, pos, bs, 0.1, vw, new_lens=new_lens)
-    assert np.abs(np.asarray(dense - got))[live].max() > 1e-2
+    dense = pa.flash_decode_latent(q, pool, tables, pos, bs, 0.1, vw, new_lens=new_lens)
+    assert whole or np.abs(np.asarray(dense - got))[live].max() > 1e-2
+    assert not whole or np.abs(np.asarray(dense - got))[live].max() < 2e-5
+
+
+@pytest.mark.parametrize("S,topk", [(40, 64), (300, 64)], ids=["under-index-topk", "over-index-topk"])
+def test_the_mask_the_model_hands_over_marks_nothing_past_a_query_s_position(S, topk):
+    """What the masked kernel's step rests on since PR 56 (it makes no causal compare of its own): the choice
+    of ``index_scores``' scores marks no position past its query's and none for a pad query (position -1), at
+    prompts under ``index_topk`` (every candidate is taken) and over it, through the XLA form and through the
+    index kernel (whose columns past the keys are ``-inf`` too); a pad query that stands at position 0, as the
+    serving engine's do, marks position 0 and no other."""
+    from deepspeed_tpu.ops.pallas import dsa as kernel
+
+    rng = np.random.default_rng(5)
+    N, C, H, D = 2, 128, 4, 32
+    q, k = (jnp.asarray(rng.normal(size=s), jnp.float32) for s in ((N, C, H, D), (N, S, D)))
+    w = jnp.asarray(np.abs(rng.normal(size=(N, C, H))), jnp.float32)
+    live = np.arange(C)[None] < np.asarray([[min(C, S)], [min(C, S) - 9]])
+    first = np.asarray([[S - min(C, S)], [0]])
+    for pad_at in (-1, 0):
+        pos = jnp.asarray(np.where(live, np.arange(C)[None] + first, pad_at), jnp.int32)
+        for scores in (dsa.index_scores(q, k, w, pos, impl="xla"), kernel.index_scores(q, k, w, pos)):
+            chosen = np.asarray(dsa.select_mask(scores, topk))
+            past = np.arange(chosen.shape[-1])[None, None] > np.asarray(pos)[..., None]
+            assert not (chosen & past).any()
+            assert (chosen[live].sum(-1) == np.minimum(np.asarray(pos)[live] + 1, topk)).all()
+            assert (chosen[~live].sum(-1) == (0 if pad_at < 0 else 1)).all()
 
 
 def test_one_token_a_row_attends_its_kept_rows_gathered_by_position():

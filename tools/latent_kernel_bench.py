@@ -7,8 +7,19 @@ the instruction `mla_paged_attn`), at the shapes the two latent cells run it:
 | `glm` (`glm-4.7-flash.serve.batch`, `step`) | [64, 256, 20, 640] | [8 x 6,553, 16, 640] | 128 | prompts uniform 64-256 |
 | `xing-decode` (`chain`) | [64, 1, 32, 640] | xing's | 256 | 1,024-2,048 + 0-31 decoded |
 | `glm-decode` (`chain`) | [64, 1, 20, 640] | glm's | 128 | 64-256 + 0-191 decoded |
+| `glm5-masked` (`glm-5.serve.long-prompt-wave8`, `step`; NOT in the default list) | [1, 8192, 64, 640] | [6 x 7,281, 16, 640] | 516 | one prompt uniform 4,096-8,192, under a MASK: each query's 2,048 largest of seeded random scores at or before it, all of them under 2,048 |
 
     chiprun -- python tools/latent_kernel_bench.py [--shapes xing glm ...] [--forms 16:16,32:16]
+
+`glm5-masked` is the kernel under a per-query mask, the instruction
+`dsa_paged_attn` (the walk still visits every position at or before a query:
+2,048 kept of 6,000 leave no chunk that a whole tile dropped). Its line gives
+the share of the bf16 peak on the WALKED positions (`bf16_peak_pct_on_live_work`:
+what the kernel multiplies for live queries) and on the KEPT ones
+(`bf16_peak_pct_on_kept_work`: what the benchmark's `dsa_attend_roofline.batch`
+counts), and `us_per_chunk_step`. There `--forms` stands in for
+`_masked_latent_form`; a tree before PR 56 has neither and runs its one masked
+form, 16 tokens against 8 pages, which the line calls `16:8`.
 
 Some tens of calls under one jit (each call's block table rests on the call
 before, so nothing is hoisted but what does not change: the query's
@@ -51,7 +62,9 @@ SHAPES = {
     "glm": (64, 256, 20, 6553, 8, 128, (64, 256), 0, 256 ** -0.5),
     "xing-decode": (64, 1, 32, 11234, 7, 256, (1024, 2048), 31, 192 ** -0.5 * 2.00474),
     "glm-decode": (64, 1, 20, 6553, 8, 128, (64, 256), 191, 256 ** -0.5),
+    "glm5-masked": (1, 8192, 64, 7281, 6, 516, (4096, 8192), 0, 256 ** -0.5),
 }
+KEPT = {"glm5-masked": 2048}  # shapes under a per-query mask: the positions a query keeps
 
 
 def draw(shape: str, seed: int, layer: int = 3):
@@ -84,11 +97,26 @@ def counts(shape: str, positions, lens, tq: int, ppcb: int) -> dict:
     pad = -C % tq
     tiles = np.pad(seen, ((0, 0), (0, pad))).reshape(N, -1, tq).max(-1)
     steps = int((-(-tiles // T)).sum())
+    kept = np.minimum(seen, KEPT.get(shape, C))
     return {"chunk_steps": steps, "scores": steps * rows * T,
-            "live_flops": int(seen.sum()) * H * 2 * (W + V), "grid_steps": int(tiles.size), "rows": rows}
+            "live_flops": int(seen.sum()) * H * 2 * (W + V), "kept_flops": int(kept.sum()) * H * 2 * (W + V),
+            "grid_steps": int(tiles.size), "rows": rows}
 
 
-def instruction_seconds(run, name: str = "mla_paged_attn"):
+def chosen_mask(shape: str, seed: int, positions, columns: int):
+    """bool [N, C, columns]: each query's `KEPT[shape]` largest of seeded random
+    scores at or before its position (every one of them while there are no more)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops import dsa
+
+    scores = jax.random.uniform(jax.random.PRNGKey(seed + 7), positions.shape + (columns,), jnp.float32)
+    seen = jnp.arange(columns)[None, None, :] <= jnp.asarray(positions)[..., None]
+    return jax.jit(lambda s: dsa.select_mask(s, KEPT[shape]))(jnp.where(seen, scores, -jnp.inf))
+
+
+def instruction_seconds(run, name: str):
     """(the instruction's text up to its operands, its device seconds) in one
     traced `run`: the kernel without the pre-scale and the slices around it."""
     import glob
@@ -118,35 +146,46 @@ def measure(shape: str, form, seed: int, calls: int, repeats: int = 5) -> dict:
     key = jax.random.PRNGKey(seed)
     q = jax.random.normal(key, (N, C, H, W), jnp.bfloat16)
     pool = jnp.tile(jax.random.normal(jax.random.fold_in(key, 1), (pages, BS, W), jnp.bfloat16), (layers, 1, 1))
-    # a tree before PR 52 has no `_latent_form`: tiles of 16 tokens, chunks of 16 pages, and no other
-    picks = getattr(pa, "_latent_form", lambda C, H, W, V, itemsize, P, bs: (min(C, 16), min(P, 16)))
+    masked = shape in KEPT
+    chooser, name = ("_masked_latent_form", "dsa_paged_attn") if masked else ("_latent_form", "mla_paged_attn")
+    # a tree before PR 52 has no `_latent_form`: tiles of 16 tokens, chunks of 16 pages, and no other; one
+    # before PR 56 no `_masked_latent_form`: 16 tokens against 8 pages
+    picks = getattr(pa, chooser, lambda C, H, W, V, itemsize, P, bs: (min(C, 16), min(P, 8 if masked else 16)))
     if form is not None:
-        assert hasattr(pa, "_latent_form"), "a tree before PR 52 has one form"
-        pa._latent_form = lambda *shapes: form  # every call below is traced in here; `main` puts it back
+        assert hasattr(pa, chooser), "a tree without `%s` has one form" % chooser
+        setattr(pa, chooser, lambda *shapes: form)  # every call below is traced in here; `main` puts it back
     tq, ppcb = form or picks(C, H, W, V, 2, table.shape[1], BS)
+    # (the index kernel's scores, and so the model's mask, come 512 columns a tile: 8,704 for 516 pages)
+    mask = (chosen_mask(shape, seed, positions, -(-table.shape[1] * BS // 512) * 512),) if masked else ()
 
-    def kernel(q, pool, table, pos, n):
-        return pa.flash_decode_latent(q, pool, table, pos, BS, scale, V, new_lens=n)
+    def kernel(q, pool, table, pos, n, *mask):
+        return pa.flash_decode_latent(q, pool, table, pos, BS, scale, V, new_lens=n, mask=mask[0] if mask else None)
 
     @jax.jit
-    def many(q, pool, table, pos, n):
+    def many(q, pool, table, pos, n, *mask):
         def one(_, table):
-            out = kernel(q, pool, table, pos, n)
+            out = kernel(q, pool, table, pos, n, *mask)
             # never true, and the compiler cannot know: the next call waits for this one
             return table + (out[0, 0, 0, 0].astype(jnp.float32) > 1e30).astype(jnp.int32)
 
         return jax.lax.fori_loop(0, calls, one, table)
 
-    args = (q, pool, jnp.asarray(table), jnp.asarray(positions), jnp.asarray(lens))
+    args = (q, pool, jnp.asarray(table), jnp.asarray(positions), jnp.asarray(lens)) + mask
     out = jax.jit(kernel)(*args)
     finite = bool(jnp.isfinite(out.astype(jnp.float32)).all())
-    # the first row against the gather, its live tokens: the largest difference over the largest entry
+    # the first row against the gather, its live tokens (under a mask 128 of them, from the first that
+    # keeps fewer than it sees: the gather's scores of 8,192 queries are 17 GB): the largest difference
+    # over the largest entry
     from deepspeed_tpu.inference import paged
 
-    want = jax.jit(lambda q, pool, table, pos, n: paged._xla_latent_paged_attention(
-        q, pool, table, pos, BS, scale, V, new_lens=n))(*(a[:1] if a is not pool else a for a in args))
-    live = int(lens[0])
-    want, got = want[0, :live].astype(jnp.float32), out[0, :live].astype(jnp.float32)
+    live = slice(KEPT[shape], KEPT[shape] + 128) if masked else slice(0, int(lens[0]))
+
+    def gather(q, pool, table, pos, n, *mask):
+        return paged._xla_latent_paged_attention(q[:, live], pool, table, pos[:, live], BS, scale, V,
+                                                 mask=mask[0][:, live] if mask else None)
+
+    want = jax.jit(gather)(*(a[:1] if a is not pool else a for a in args))
+    want, got = want[0].astype(jnp.float32), out[0, live].astype(jnp.float32)
     err = float(jnp.abs(got - want).max() / jnp.abs(want).max())
     jax.block_until_ready(many(*args))
     times = []
@@ -154,7 +193,7 @@ def measure(shape: str, form, seed: int, calls: int, repeats: int = 5) -> dict:
         t0 = time.perf_counter()
         jax.block_until_ready(many(*args))
         times.append((time.perf_counter() - t0) / calls)
-    traced = instruction_seconds(lambda: jax.block_until_ready(many(*args)))
+    traced = instruction_seconds(lambda: jax.block_until_ready(many(*args)), name)
     c = counts(shape, positions, lens, tq, ppcb)
     host = float(np.median(times))
     s = traced[1] / calls if traced[0] else None  # the instruction's own time, or nothing: never the host's clock
@@ -166,15 +205,17 @@ def measure(shape: str, form, seed: int, calls: int, repeats: int = 5) -> dict:
             "tile_tokens": tq, "pages_a_chunk": ppcb, "seed": seed, "calls": calls,
             "ms_per_call": of_s(lambda s: 1e3 * s), "host_ms_per_call": 1e3 * host, "instruction": traced[0],
             "grid_steps": c["grid_steps"], "rows": c["rows"], "chunk_steps": c["chunk_steps"],
+            "us_per_chunk_step": of_s(lambda s: 1e6 * s / c["chunk_steps"]),
             "us_per_512x256_scores": of_s(lambda s: 1e6 * s / (c["scores"] / (512 * 256))),
             "live_tflop": c["live_flops"] / 1e12,
             "bf16_peak_pct_on_live_work": of_s(lambda s: 100 * c["live_flops"] / s / BF16_PEAK),
+            "bf16_peak_pct_on_kept_work": of_s(lambda s: 100 * c["kept_flops"] / s / BF16_PEAK),
             "finite": finite, "row0_err_against_the_gather": err}
 
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shapes", nargs="+", default=list(SHAPES), choices=list(SHAPES))
+    ap.add_argument("--shapes", nargs="+", default=[s for s in SHAPES if s not in KEPT], choices=list(SHAPES))
     ap.add_argument("--forms", default="", help="tokens a tile:pages a chunk[,...]; default: what the kernel picks")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--calls", type=int, default=0, help="calls a timing; default 24 a prompt shape, 200 a decode shape")
@@ -194,14 +235,15 @@ def main() -> int:
         for shape in a.shapes:
             for form in forms:
                 calls = a.calls or (200 if SHAPES[shape][1] == 1 else 24)
-                picks = getattr(pa, "_latent_form", None)
+                chooser = "_masked_latent_form" if shape in KEPT else "_latent_form"
+                picks = getattr(pa, chooser, None)
                 try:
                     line = measure(shape, form, a.seed, calls)
                 except Exception as e:  # a form the compiler refuses (VMEM) is a reading too
                     line = {"shape": shape, "form": form, "refused": f"{type(e).__name__}: {str(e)[:300]}"}
                 finally:
                     if picks is not None:
-                        pa._latent_form = picks
+                        setattr(pa, chooser, picks)
                 print(json.dumps(line), flush=True)
                 f.write(json.dumps(line) + "\n")
     return 0
