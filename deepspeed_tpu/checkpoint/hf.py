@@ -382,6 +382,80 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
             moe_drop_tokens=False,
             param_dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(dtype, jnp.float32),
         )
+    if mt == "cohere2_moe":
+        # a PATTERN of two attention kinds in ONE parallel block with a routed MLP: ``sliding_attention`` layers
+        # (a band of ``sliding_window`` keys, rotary in adjacent pairs: ``rope_gptj``) beside ``full_attention``
+        # layers with no position term at all; attention, the routed experts (sigmoid scores, the largest k,
+        # renormalised, no correction bias) and ``num_shared_experts`` shared experts AVERAGED all read the one
+        # bias-free LayerNorm of the layer's input; a tied embedding, logits times ``logit_scale``.
+        # ``intermediate_size`` is the width of ONE expert, routed or shared. ``num_experts`` are the experts
+        # HELD here: with ``expert_parallel: {size, rank}`` the router scores ``size`` times as many. The
+        # config alone is mapped: no checkpoint's key names are in the repository, so no state dict is converted
+        L = hf_config["num_hidden_layers"]
+        switch = hf_config.get("layer_switch", 4)
+        kinds = tuple(hf_config.get("layer_types") or
+                      ("full_attention" if (i + 1) % switch == 0 else "sliding_attention" for i in range(L)))
+        refused = [
+            (hf_config.get("first_k_dense_replace", 0) != 0, "first_k_dense_replace != 0 (leading dense layers)"),
+            (bool(hf_config.get("attention_bias")), "attention_bias"),
+            (bool(hf_config.get("use_qk_norm")), "use_qk_norm"),
+            (not hf_config.get("use_parallel_block", True), "use_parallel_block false (a sequential block)"),
+            (not hf_config.get("use_gated_activation", True), "use_gated_activation false"),
+            (hf_config.get("hidden_act", "silu") != "silu", f"hidden_act={hf_config.get('hidden_act')!r}"),
+            (hf_config.get("expert_selection_fn", "sigmoid") != "sigmoid",
+             f"expert_selection_fn={hf_config.get('expert_selection_fn')!r}"),
+            (hf_config.get("shared_expert_combination_strategy", "average") != "average",
+             f"shared_expert_combination_strategy={hf_config.get('shared_expert_combination_strategy')!r}"),
+            (hf_config.get("position_embedding_type", "rope_gptj") != "rope_gptj",
+             f"position_embedding_type={hf_config.get('position_embedding_type')!r}"),
+            (hf_config.get("rotary_pct", 1) != 1, "rotary_pct != 1 (a partial rotary)"),
+            ((hf_config.get("rope_parameters") or {}).get("rope_type", "default") != "default", "rope scaling"),
+            (not set(kinds) <= {"full_attention", "sliding_attention"}, f"layer_types of {sorted(set(kinds))}"),
+            (not hf_config.get("sliding_window"), "no sliding_window"),
+        ]
+        refused = [what for bad, what in refused if bad]
+        if refused:
+            raise ValueError("cohere2_moe with " + "; ".join(refused) + " is unsupported")
+        from deepspeed_tpu.models.transformer import ExpertParallel, SlidingConfig
+
+        share = hf_config.get("expert_parallel")
+        theta = (hf_config.get("rope_parameters") or {}).get("rope_theta", hf_config.get("rope_theta", 50000.0))
+        dtype = hf_config.get("dtype", hf_config.get("torch_dtype"))
+        return TransformerConfig(
+            vocab_size=hf_config["vocab_size"],
+            hidden_size=hf_config["hidden_size"],
+            intermediate_size=hf_config["intermediate_size"],
+            num_layers=L,
+            num_heads=hf_config["num_attention_heads"],
+            num_kv_heads=hf_config.get("num_key_value_heads"),
+            head_dim=hf_config.get("head_dim"),
+            max_seq_len=hf_config.get("max_position_embeddings", 200000),
+            norm="layernorm",
+            norm_bias=False,
+            norm_eps=float(hf_config.get("layer_norm_eps", 1e-5)),
+            activation="silu_glu",
+            qkv_bias=False,
+            dense_bias=False,
+            parallel_block=True,
+            position="rope",
+            rope_theta=float(theta),
+            rope_interleaved=True,
+            tie_embeddings=bool(hf_config.get("tie_word_embeddings", True)),
+            logits_scaling=1.0 / float(hf_config.get("logit_scale", 1.0)),
+            layer_types=tuple("attention" if kind == "full_attention" else kind for kind in kinds),
+            sliding=SlidingConfig(window=int(hf_config["sliding_window"]), global_rope=False),
+            num_experts=hf_config["num_experts"],
+            expert_parallel=ExpertParallel(int(share["size"]), int(share.get("rank", 0))) if share else None,
+            moe_top_k=hf_config["num_experts_per_tok"],
+            moe_intermediate_size=hf_config["intermediate_size"],
+            moe_shared_experts=hf_config.get("num_shared_experts", 0),
+            moe_shared_average=hf_config.get("num_shared_experts", 0) > 1,
+            moe_router="sigmoid",
+            moe_router_bias=False,
+            moe_renormalize=bool(hf_config.get("norm_topk_prob", True)),
+            moe_drop_tokens=False,
+            param_dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(dtype, jnp.float32),
+        )
     if mt == "opt":
         if not hf_config.get("do_layer_norm_before", True):
             raise ValueError("OPT post-layernorm variants (do_layer_norm_before=false) are unsupported")
@@ -581,7 +655,7 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
     raise ValueError(
         f"unsupported HF model_type {mt!r} (supported: llama/mistral/mixtral/"
         "qwen2/gpt2/opt/falcon/phi/gpt_neox/bloom/gptj/codegen/gpt_bigcode/"
-        "glm4_moe_lite/evabyte/xing4_0/granitemoehybrid/qwen3_next/glm_moe_dsa)")
+        "glm4_moe_lite/evabyte/xing4_0/granitemoehybrid/qwen3_next/glm_moe_dsa/cohere2_moe)")
 
 
 def detect_family(state: Dict[str, np.ndarray]) -> str:

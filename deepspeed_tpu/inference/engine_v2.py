@@ -49,6 +49,7 @@ from deepspeed_tpu.inference.config import QuantConfig, ServingSLOConfig
 from deepspeed_tpu.inference.lifecycle import LifecycleTracker
 from deepspeed_tpu.inference.paged import (
     HybridPools,
+    RingPools,
     MigrationBuffer,
     PagedKVPool,
     copy_pool_blocks,
@@ -393,6 +394,30 @@ class InferenceEngineV2:
             self._layout = WindowLayout(model_config.eva_window, config.kv_block_size, max_len)
             self.max_pages = self._layout.width
         self.windows_closed = 0  # EVA: windows pooled into summaries so far
+        # A sliding kind (TransformerConfig.sliding): a row's table is [global columns | ring columns],
+        # two classes of page in two arrays (paged.RingPools, ragged.RingLayout)
+        self._ring = None
+        self.ring_pages_overwritten = 0  # ring pages a later block of the same row has been written over
+        if model_config.sliding is not None:
+            from deepspeed_tpu.inference.ragged import RingLayout
+
+            missing = [
+                (config.prefix_cache, "prefix_cache: a sliding layer keeps a prompt's LAST window alone, so a "
+                 "shared prefix's pages hold no sliding layer's keys for the suffix to read"),
+                (config.spec_decode > 0, "spec_decode: drafts are a chunk of several tokens past position 0, "
+                 "which would have to read the ring and the global pages (ROADMAP R3b)"),
+                (config.kv_quant is not None, f"kv_cache_dtype={config.kv_dtype_name!r}: the ring pool has no "
+                 "scale pages, and a fresh prompt's bulk write quantizes nothing"),
+                (mesh.shape["tp"] > 1, f"tp={mesh.shape['tp']}: the two classes of page and the ring's roll are "
+                 "not partitioned over heads"),
+                (config.chunk_bucket % config.kv_block_size != 0,
+                 f"chunk_bucket={config.chunk_bucket}: a fresh prompt writes whole pages of {config.kv_block_size}"),
+            ]
+            missing = [what for bad, what in missing if bad]
+            if missing:
+                raise ValueError("a sliding kind (sliding_attention layers) does not serve with " + "; ".join(missing))
+            self._ring = RingLayout(model_config.sliding.window, config.kv_block_size, max_len)
+            self.max_pages = self._ring.width
         if model_config.hc_mult and mesh.shape["tp"] > 1:
             raise ValueError(
                 f"hyper-connections (hc_mult={model_config.hc_mult}) with tp={mesh.shape['tp']}: the mix "
@@ -472,18 +497,27 @@ class InferenceEngineV2:
             if missing:
                 raise ValueError("a sparse-attention indexer (index_topk > 0) does not serve with "
                                  + "; ".join(missing))
+        # The ring's class of page: every seat its whole ring (a row takes its pages as it grows to a window
+        # and keeps them to its flush, so no seat can be short of one), at a token's bytes a sliding layer
+        self.ring_blocks = self.ring_bytes = 0
+        if self._ring is not None:
+            self.ring_blocks = config.max_seqs * self._ring.window_pages
+            self.ring_bytes = self.ring_blocks * config.kv_block_size * kv_slot_bytes(
+                model_config.sliding_layers, model_config.kv_heads, model_config.dims_per_head, kv_dtype_b)
         if config.kv_pool_bytes is not None:
             # byte-budget sizing: admission capacity follows the REAL block
             # bytes, so an int8 pool at the same budget admits ~1.9x the
-            # concurrent requests of a bf16 one
-            num_blocks = max(int(config.kv_pool_bytes)
+            # concurrent requests of a bf16 one (of two classes of page, the
+            # ring's come off the budget first, the global class takes the rest)
+            num_blocks = max((int(config.kv_pool_bytes) - self.ring_bytes)
                              // (config.kv_block_size * self.kv_bytes_per_token), 1)
         else:
             num_blocks = config.num_kv_blocks
         self.num_kv_blocks = num_blocks
         self.state = StateManager(num_blocks, config.kv_block_size, config.max_seqs,
-                                  max_blocks_per_seq=self.max_pages, layout=self._layout,
-                                  state_slots=config.max_seqs if self._hybrid else None)
+                                  max_blocks_per_seq=self.max_pages, layout=self._layout or self._ring,
+                                  state_slots=config.max_seqs if self._hybrid else None,
+                                  ring_blocks=self.ring_blocks or None)
         self._staging = BatchStaging(self.max_pages)
         self.prefix_cache: Optional[PrefixCache] = None
         if config.prefix_cache:
@@ -614,17 +648,26 @@ class InferenceEngineV2:
             v=None if pool.v is None else jax.device_put(pool.v, kv_spec),
             k_scale=None if pool.k_scale is None else jax.device_put(pool.k_scale, replicated),
             v_scale=None if pool.v_scale is None else jax.device_put(pool.v_scale, replicated))
+        self.ring_pool = None
+        if self._ring is not None:
+            from deepspeed_tpu.inference.paged import init_ring_pool
+
+            self.ring_pool = jax.device_put(
+                init_ring_pool(model_config, self.ring_blocks, config.kv_block_size, kv_dtype), replicated)
         self.state_pool = None
         if self._hybrid:
             from deepspeed_tpu.inference.paged import init_state_pool
 
             # (every sequence a slot: as many as seats, no new engine key)
             self.state_pool = jax.device_put(init_state_pool(model_config, config.max_seqs, dtype), replicated)
+        by_class = "" if self._ring is None else (
+            f"; two classes of page: global {num_blocks * config.kv_block_size * self.kv_bytes_per_token} B, "
+            f"ring {self.ring_blocks}x{config.kv_block_size} slots a sliding layer {self.ring_bytes} B")
         log_dist(
             f"InferenceEngineV2: {n_params/1e6:.1f}M params, "
             f"{num_blocks}x{config.kv_block_size} KV slots "
             f"[{config.kv_dtype_name}, {self.kv_bytes_per_token} B/token], "
-            f"mesh={dict(mesh.shape)}"
+            f"mesh={dict(mesh.shape)}" + by_class
         )
         self._step_cache: Dict[Tuple, Any] = {}
         self._chain_buf: Dict[int, Dict[str, np.ndarray]] = {}
@@ -676,11 +719,15 @@ class InferenceEngineV2:
     def _pools(self):
         """What the step programs take in the pool's place, donated, and hand
         back: the page pool, with the state pool where the model has one."""
+        if self.ring_pool is not None:
+            return RingPools(self.pool, self.ring_pool)
         return self.pool if self.state_pool is None else HybridPools(self.pool, self.state_pool)
 
     @_pools.setter
     def _pools(self, pools) -> None:
-        if self.state_pool is None:
+        if self.ring_pool is not None:
+            self.pool, self.ring_pool = pools
+        elif self.state_pool is None:
             self.pool = pools
         else:
             self.pool, self.state_pool = pools
@@ -692,6 +739,17 @@ class InferenceEngineV2:
         if self.state_pool is None or not self._tracer.recording():
             return {}
         return {"state_rows": int(rows)}
+
+    def stats(self) -> Dict[str, int]:
+        """The pool's bytes by class of page, and the pages of each the live rows hold: ``kv_global_bytes``
+        (the page pool every model has) and, under a sliding kind, ``kv_ring_bytes`` beside it."""
+        seqs = [self.state.get(u) for u in list(self.state._seqs)]
+        out = {"kv_global_bytes": self.num_kv_blocks * self.config.kv_block_size * self.kv_bytes_per_token,
+               "kv_global_pages_held": sum(s.n_summary if self._ring is not None else s.n_blocks for s in seqs)}
+        if self._ring is not None:
+            out.update(kv_ring_bytes=self.ring_bytes, kv_ring_pages_held=sum(s.n_window for s in seqs),
+                       ring_pages_overwritten=self.ring_pages_overwritten)
+        return out
 
     @staticmethod
     def _rows_at(a, at) -> np.ndarray:
@@ -1076,6 +1134,10 @@ class InferenceEngineV2:
             raise ValueError(
                 "KV-block migration of a model with recurrent state: the wire format carries pages and "
                 "knows no state slot; a request's state would stay behind")
+        if self._ring is not None:
+            raise ValueError(
+                "KV-block migration of a model with a sliding kind: the wire format carries one class of page "
+                "in position order; a row's ring pages, rolled by its position, would stay behind")
         seq = self.state.get(uid)
         if seq is None or seq.n_blocks == 0:
             raise ValueError(f"uid {uid} has no KV blocks to export")
@@ -1206,7 +1268,12 @@ class InferenceEngineV2:
         windows that closed on the way (EVA), for ``windows_closed``."""
         if self._layout is None:
             for uid, n in zip(uids, counts):
-                self.state.get(uid).seen_tokens += int(n)
+                seq = self.state.get(uid)
+                if self._ring is not None and seq.seen_tokens:  # (a fresh prompt writes its last window alone)
+                    over = self._ring.overwritten(seq.seen_tokens, int(n)) * self.model_config.sliding_layers
+                    self.ring_pages_overwritten += over
+                    self._tracer.count("serving/ring_pages_overwritten", float(over))
+                seq.seen_tokens += int(n)
             return 0
         return sum(self.state.advance(uid, int(n)) for uid, n in zip(uids, counts))
 
@@ -1230,19 +1297,26 @@ class InferenceEngineV2:
         """
         if not self.can_schedule(uids, [len(t) for t in token_lists]):
             raise RuntimeError("insufficient KV blocks/slots; call can_schedule first")
+        per_call = self._fresh_rows_a_call(token_lists)
+        outs = [self._put_call(uids[i:i + per_call], token_lists[i:i + per_call], range(i, i + per_call))
+                for i in range(0, max(len(uids), 1), per_call)]
+        return outs[0] if len(outs) == 1 else np.concatenate(outs)
+
+    def _put_call(self, uids, token_lists, rids) -> np.ndarray:
+        """One program call of ``put``; ``rids``: the rows' places in ``put``'s own lists, for the picks' log."""
         batch = self._build_batch(uids, token_lists)
         step = self._step_fn(batch.n_rows, batch.tokens.shape[1])
         call = self._log.open("put", -1, batch.n_rows, batch.tokens.shape[1])
         with self._dispatching(call, rows=batch.n_rows,
                                **self._eva_args(batch.positions, batch.new_lens),
-                               **self._state_args(len(uids))):
+                               **self._state_args(len(uids)), **self._ring_args(uids)):
             logits, self._pools, *picks = step(
                 self.params, self._pools,
                 jnp.asarray(batch.tokens), jnp.asarray(batch.positions),
                 jnp.asarray(batch.new_lens), jnp.asarray(batch.block_tables),
             )
         self.dispatch_count += 1
-        self._log_picks(picks, uids, None, token_lists, at=batch.at)
+        self._log_picks(picks, uids, rids[:len(uids)], token_lists, at=batch.at)
         if self._selected_log is not None and len(picks) > 1:
             self._selected_log.append((picks[0], batch.at))
         self.windows_closed += self._advance(uids, map(len, token_lists))
@@ -1250,6 +1324,21 @@ class InferenceEngineV2:
             out = self._rows_at(logits, batch.at)
         self.host_sync_count += 1
         return out
+
+    def _fresh_rows_a_call(self, token_lists) -> int:
+        """Rows one ``put`` call takes. Under a sliding kind a fresh prompt computes its attention from the
+        chunk's own q, k, v, every row's at once, so a call is held to the serving loop's own token budget
+        (``max_ragged_batch_size`` padded tokens, one row at the least) and ``put`` feeds the rest in further
+        calls of the same program; every other model takes all its rows in one call, as it always did."""
+        budget = self.config.max_ragged_batch_size
+        if self._ring is None or not budget or not token_lists:
+            return max(len(token_lists), 1)
+        longest = max(map(len, token_lists))
+        if longest <= 1:  # (one token a row: the ``(rows, 1)`` program, ``ragged.build_ragged_batch``)
+            return len(token_lists)
+        chunk = -(-longest // self.config.chunk_bucket) * self.config.chunk_bucket
+        bucket = self.config.row_bucket
+        return max(budget // chunk // bucket * bucket, 1)
 
     def put_with_selected(self, uids: Sequence[int], token_lists: Sequence[np.ndarray]):
         """``put_with_picks`` of a model with a learned indexer, and what every
@@ -1357,9 +1446,33 @@ class InferenceEngineV2:
         """Each row's first position and the tokens it is fed, ``start:count``, as one span arg of a prefill
         under a learned indexer (what a query scores and keeps follows from its position); formatted only
         while somebody records spans, before ``seen_tokens`` advances."""
-        if not self.model_config.index_topk or not self._tracer.recording():
+        if not (self.model_config.index_topk or self._ring is not None) or not self._tracer.recording():
             return {}
         return {"fed": " ".join(f"{self.state.get(u).seen_tokens}:{len(t)}" for u, t in zip(uids, token_lists))}
+
+    def _ring_args(self, uids, start=None, share=None) -> Dict[str, int]:
+        """For a ``serve:dispatch`` span of a model with a sliding kind, while somebody records spans: the pages
+        the call's rows hold by class, summed over their layers (``ring_pages``: the sliding layers' rings,
+        ``global_pages``: the full layers' pages), and ``one_class_pages``, what one class of page would hold
+        for the same rows (every layer a page a block of positions). The same numbers go to the gauges
+        ``serving/kv_pages_held_ring`` and ``serving/kv_pages_held_global``. A chain (``start``: its rows' first
+        positions, ``share``: the steps its budgets plan for each) says ``ring_tokens`` beside them: the sum over
+        its rows and steps of the tokens a sliding layer's ring holds for the query, ``min(position + 1, window)``."""
+        if self._ring is None or not self._tracer.recording():
+            return {}
+        cfg = self.model_config
+        seqs = [self.state.get(u) for u in uids]
+        blocks = sum(s.n_summary for s in seqs)  # a page a block of positions: what a full layer holds
+        ring, held = sum(s.n_window for s in seqs) * cfg.sliding_layers, blocks * cfg.attention_layers
+        if self._tracer.enabled:
+            self._tracer.registry.gauge("serving/kv_pages_held_ring").set(float(ring))
+            self._tracer.registry.gauge("serving/kv_pages_held_global").set(float(held))
+        args = {"ring_pages": ring, "global_pages": held, "one_class_pages": blocks * cfg.num_layers}
+        if start is not None:
+            fed = np.arange(int(np.max(share, initial=0)))[None, :] < np.asarray(share)[:, None]
+            seen = np.minimum(np.asarray(start)[:, None] + np.arange(fed.shape[1])[None, :] + 1, self._ring.window)
+            args["ring_tokens"] = int(seen[fed].sum())
+        return args
 
     def _put_sample(self, uids, token_lists, rng, sample_kw: Tuple,
                     tracker: Optional[LifecycleTracker] = None,
@@ -1375,7 +1488,7 @@ class InferenceEngineV2:
                                live=len(uids), tokens=int(batch.new_lens.sum()),
                                rids=self._span_rids(rids), **self._span_fed(uids, token_lists),
                                **self._eva_args(batch.positions, batch.new_lens),
-                               **self._state_args(len(uids))):
+                               **self._state_args(len(uids)), **self._ring_args(uids)):
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "prefill")
             toks, rng, self._pools, *picks = step(
@@ -1444,7 +1557,7 @@ class InferenceEngineV2:
             share = np.minimum(budgets, k)
             for i, uid, b in zip(at, uids, share):
                 seq = self.state.extend(uid, int(b))
-                if self._layout is None:
+                if self.state.layout is None:
                     buf["tables"][i, : seq.n_blocks] = seq.blocks
                 else:
                     seq.table_into(buf["tables"][i])
@@ -1460,7 +1573,7 @@ class InferenceEngineV2:
         call = self._log.open("chain", chain_id, n_rows, k)
         with self._dispatching(call, rows=n_rows, live=len(uids),
                                k=k, chain=chain_id, ahead=int(ahead), **eva_args,
-                               **self._state_args(share.sum())):
+                               **self._state_args(share.sum()), **self._ring_args(uids, start, share)):
             if ahead:
                 tokens, pos, active = before.carry
                 tables, chain_budgets = self._place(buf, "tables", "budgets")

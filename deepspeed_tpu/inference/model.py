@@ -74,6 +74,11 @@ def init_cache(
             "hyper-connections (hc_mult > 0) and latent attention (kv_lora_rank > 0) in the v1 engine: "
             "its block step is the one-stream residual over plain keys and values; serve them "
             "through InferenceEngineV2, whose paged path reads ops/mhc.py and the latent pool")
+    if cfg.sliding is not None:
+        raise NotImplementedError(
+            "a sliding kind (sliding_attention layers) in the v1 engine: its dense cache holds a row a position "
+            "a layer at every layer and knows no window; serve it through InferenceEngineV2, whose sliding "
+            "layers write a ring of pages beside the global ones")
     if cfg.layer_types is not None or (cfg.residual_multiplier, cfg.attention_multiplier) != (1.0, None):
         raise NotImplementedError(
             "a layer pattern (layer_types) or a residual/attention multiplier in the v1 engine: its cache is "
@@ -142,7 +147,8 @@ def _moe_with_picks(lp, cfg: TransformerConfig, x):
     weighed by the unbiased scores, renormalised and scaled, as the config
     says. A shared expert (``lp["shared"]``), where the layer has one, takes
     every token and is added unweighted, or times ``sigmoid(x . shared_gate)``
-    where the layer has that gate (``lp["shared_gate"]``).
+    where the layer has that gate (``lp["shared_gate"]``), or as the mean of
+    the shared experts (``cfg.moe_shared_average``).
 
     A chip's share of the layer (``cfg.expert_parallel``): the router scores
     and picks among all ``cfg.router_experts``, weights renormalised over all
@@ -197,6 +203,8 @@ def _moe_with_picks(lp, cfg: TransformerConfig, x):
                 with jax.named_scope("moe_shared_gate"):
                     gate = tokens.astype(jnp.float32) @ lp["shared_gate"]["kernel"].astype(jnp.float32)
                     shared = shared * jax.nn.sigmoid(gate).astype(shared.dtype)
+            if cfg.moe_shared_average:  # the mean of the shared experts: the one GLU over their widths, divided
+                shared = shared * jnp.asarray(1.0 / cfg.moe_shared_experts, shared.dtype)
             out = out + shared
     return out.reshape(B, S, M), top_i
 

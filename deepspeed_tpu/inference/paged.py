@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.inference.model import (ExpertStack, _apply_norm, _attn_out, _dense, _logits, _mlp,
                                            _moe_with_picks, _qkv)
+from deepspeed_tpu.inference.ragged import ring_columns
 from deepspeed_tpu.inference.sampling import greedy_tokens, sample_logits
 from deepspeed_tpu.models.transformer import TransformerConfig, _norm_at, _times, reading
 from deepspeed_tpu.ops import gdn, mhc, ssm
@@ -129,6 +130,30 @@ class HybridPools(NamedTuple):
 
     kv: "PagedKVPool"
     state: StatePool
+
+
+class RingPools(NamedTuple):
+    """What the serving programs of a model with a sliding kind
+    (``TransformerConfig.sliding``) are handed in the pool's place, donated, and
+    hand back: TWO CLASSES OF PAGE of one geometry ``[bs, kvH*hd]``. ``kv``
+    ``[full layers * NB, bs, X]`` holds the pages of the pattern's ``attention``
+    layers, a page a block of positions, as many as the context has; ``ring``
+    ``[sliding layers * NR, bs, X]`` holds the pages of its ``sliding_attention``
+    layers, of which a row has ``ring_columns(window, bs)`` at most whatever
+    its context: block ``b`` of a sliding layer lives in the row's ring column
+    ``b % R`` and is written over when block ``b + R`` arrives, so nothing is
+    freed and nothing moves. A row's block table is ``[global columns | R ring
+    columns]`` (``ragged.RingLayout`` is the host's account of the same
+    columns): the first index ``kv``'s pages of a layer, the last ``ring``'s."""
+
+    kv: "PagedKVPool"
+    ring: "PagedKVPool"
+
+
+def init_ring_pool(cfg: TransformerConfig, ring_blocks: int, block_size: int, dtype: Any = jnp.bfloat16):
+    """The sliding layers' class of page (``RingPools.ring``): ``ring_blocks`` pages a layer."""
+    shape = (cfg.sliding_layers * ring_blocks, block_size, cfg.kv_heads * cfg.dims_per_head)
+    return PagedKVPool(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
 def init_state_pool(cfg: TransformerConfig, slots: int, dtype: Any = jnp.bfloat16) -> StatePool:
@@ -244,7 +269,7 @@ from deepspeed_tpu.ops.registry import dispatch, register
 
 @register("paged_attention", "xla")
 def _xla_paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_size,
-                         new_lens=None, alibi_slopes=None, k_scale=None, v_scale=None):
+                         new_lens=None, alibi_slopes=None, k_scale=None, v_scale=None, first_live=None):
     """Masked GQA attention of new queries against paged caches (dense-gather
     fallback; the Pallas flash-decode kernel in
     ``ops/pallas/paged_attention.py`` wins dispatch on TPU).
@@ -256,6 +281,12 @@ def _xla_paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_siz
     ``k_scale``/``v_scale`` ([pages, bs*kvH] fp32) mark a quantized pool:
     dequantization happens on the GATHERED blocks ([N, P*bs, ...], bounded by
     the batch's block tables) — the full-precision pool is never materialized.
+
+    ``first_live`` [N, C] (a ring of pages rolled so its oldest live page comes
+    first: the Pallas kernel's docstring) masks the slots before it too. A
+    ring's first page is dead slots every day, and a masked score times a
+    value that is not a number is not a number, so under ``first_live`` the
+    values no query of the row sees are zeroed before the product.
     """
     N, C, H, hd = q.shape
     P = block_tables.shape[1]
@@ -279,6 +310,9 @@ def _xla_paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_siz
         scores = scores + (alibi_slopes.reshape(kvH, G)[None, :, :, None, None]
                            * t_idx.astype(jnp.float32)[None, None, None, None, :])
     ok = t_idx[None, None, :] <= q_positions[:, :, None]  # causal over positions
+    if first_live is not None:
+        ok = ok & (t_idx[None, None, :] >= first_live[:, :, None])
+        cv = jnp.where(ok.any(axis=1)[:, :, None, None], cv, jnp.zeros((), cv.dtype))
     scores = jnp.where(ok[:, None, None, :, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
     ctx = jnp.einsum("nkgct,ntkd->nckgd", probs, cv)
@@ -287,7 +321,7 @@ def _xla_paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_siz
 
 def paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_size,
                     new_lens=None, impl: str = "auto", alibi_slopes=None,
-                    k_scale=None, v_scale=None):
+                    k_scale=None, v_scale=None, first_live=None):
     import deepspeed_tpu.ops.pallas.paged_attention  # noqa: F401  (registers the kernel)
 
     # alibi is fused in BOTH implementations (the Pallas flash-decode kernel
@@ -298,7 +332,7 @@ def paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_size,
     return dispatch("paged_attention", impl)(
         q, pool_k, pool_v, block_tables, q_positions, block_size,
         new_lens=new_lens, alibi_slopes=alibi_slopes,
-        k_scale=k_scale, v_scale=v_scale,
+        k_scale=k_scale, v_scale=v_scale, first_live=first_live,
     )
 
 
@@ -669,6 +703,101 @@ def _eva_attention(cfg: TransformerConfig, positions, new_lens, block_tables, bs
     return attend
 
 
+def _windowed_attention(cfg: TransformerConfig, positions, new_lens, block_tables, bs: int,
+                        full_rows: int, ring_rows: int):
+    """``attend(ap, h, kind, pk, pv, first_page) -> (attention output [N, C, E],
+    pk, pv)`` for a pattern with a sliding kind: ``pk`` / ``pv`` are the class
+    of page the layer's ``kind`` writes (``RingPools``), ``first_page`` the
+    layer's first page in it. Everything but the layer's offset is computed
+    here, once for all layers.
+
+    A call feeds either ONE token a row, anywhere (``C == 1``: decode, ``put``),
+    or chunks that each start at position 0 (fresh prompts; the host refuses
+    anything else, ``ragged.build_ragged_batch``):
+
+    - one token at ``t``: its key and value go to the page of block ``t //
+      bs``, a global column for an ``attention`` layer, ring column ``(t // bs)
+      % R`` for a sliding one; the ``attention`` layer runs the paged kernel
+      over the global columns as every model does, the sliding layer over its
+      ring ROLLED so that the oldest live block comes first (a gather of ``R``
+      ints a row, on the device from the row's position: a chain never comes
+      back to the host for it), with positions counted from that block's first
+      slot and ``first_live`` the slots of it the window has left behind.
+    - a chunk: attention is computed from the chunk's own ``q, k, v``
+      (``causal_attention``: on the chip the flash forward, under the band for
+      a sliding layer, GQA in its index maps), never through the pages; an
+      ``attention`` layer then writes every page of the prompt in one bulk
+      write (``_page_writer``), a sliding layer the pages of the prompt's LAST
+      window alone, whole pages, each into its ring column.
+
+    Device-trace scopes: ``swa`` around a sliding layer's attention,
+    ``attn_full`` around the other kind's, each with ``kv_write`` and the
+    kernel's own name inside."""
+    from deepspeed_tpu.models.transformer import apply_qk_rope, sliding_kind
+    from deepspeed_tpu.ops.attention import causal_attention, first_live
+
+    N, C = positions.shape
+    W = cfg.sliding.window
+    R = ring_columns(W, bs)
+    Pg = block_tables.shape[1] - R
+    global_cols, ring_cols = block_tables[:, :Pg], block_tables[:, Pg:]
+    X = cfg.kv_heads * cfg.dims_per_head
+
+    if C == 1:
+        fed = new_lens == 1
+        t = positions[:, 0]
+        block, slot = t // bs, t % bs
+        g_page = jnp.where(fed, jnp.take_along_axis(global_cols, jnp.clip(block, 0, Pg - 1)[:, None], axis=1)[:, 0],
+                           full_rows)  # a pad row's writes drop
+        r_page = jnp.where(fed, jnp.take_along_axis(ring_cols, (block % R)[:, None], axis=1)[:, 0], ring_rows)
+        low = first_live(t, W)
+        oldest = low // bs  # the oldest block with a live slot: the rolled ring's first column
+        rolled = jnp.take_along_axis(ring_cols, (oldest[:, None] + jnp.arange(R)) % R, axis=1)
+        rel_pos, rel_low = (t - oldest * bs)[:, None], (low - oldest * bs)[:, None]
+        ones = fed.astype(jnp.int32)
+
+        def attention(q, k, v, sliding, window, pk, pv, first_page):
+            with jax.named_scope("kv_write"):
+                page = first_page + (r_page if sliding else g_page)
+                pk = pk.at[page, slot].set(k.astype(pk.dtype).reshape(N, X), mode="drop")
+                pv = pv.at[page, slot].set(v.astype(pv.dtype).reshape(N, X), mode="drop")
+            if sliding:
+                return paged_attention(q, pk, pv, rolled + first_page, rel_pos, bs, new_lens=ones,
+                                       first_live=rel_low), pk, pv
+            return paged_attention(q, pk, pv, global_cols + first_page, t[:, None], bs, new_lens=ones), pk, pv
+    else:
+        put_full = _page_writer(global_cols, positions, new_lens, bs, full_rows)
+        # the blocks that hold a prompt's last window, oldest first, and the chunk's token of each of their slots
+        blocks = (jnp.maximum(new_lens - W, 0) // bs)[:, None] + jnp.arange(R)  # [N, R]
+        to = jnp.where(blocks * bs < new_lens[:, None], jnp.take_along_axis(ring_cols, blocks % R, axis=1), ring_rows)
+        tok = jnp.clip(blocks[:, :, None] * bs + jnp.arange(bs), 0, C - 1).reshape(N, R * bs, 1)
+
+        def put_ring(a, new, first_page):
+            laid = jnp.take_along_axis(new.reshape(N, C, X), tok, axis=1)
+            return a.at[(first_page + to).reshape(-1)].set(laid.reshape(N * R, bs, X), mode="drop")
+
+        def attention(q, k, v, sliding, window, pk, pv, first_page):
+            ctx = causal_attention(q, k, v, impl=cfg.attn_impl, window=window)
+            with jax.named_scope("kv_write"):
+                put = put_ring if sliding else put_full
+                pk = put(pk, k.astype(pk.dtype).reshape(-1, X), first_page)
+                pv = put(pv, v.astype(pv.dtype).reshape(-1, X), first_page)
+            return ctx, pk, pv
+
+    def attend(ap, h, kind, pk, pv, first_page):
+        sliding = kind == "sliding_attention"
+        how = sliding_kind(cfg, kind)
+        q, k, v = _qkv(ap, cfg, h)
+        if cfg.position == "rope" and how["rotates"]:
+            with jax.named_scope("rope"):
+                q, k = apply_qk_rope(cfg, q, k, positions)
+        with jax.named_scope("swa" if sliding else "attn_full"):
+            ctx, pk, pv = attention(q, k, v, sliding, how["window"], pk, pv, first_page)
+        return _attn_out(ap, cfg, ctx), pk, pv
+
+    return attend
+
+
 def _forward_hidden(
     params,
     cfg: TransformerConfig,
@@ -722,9 +851,11 @@ def _forward_hidden(
     """
     N, C = tokens.shape
     bs = block_size
-    state = None
+    state = ring = None
     if isinstance(pool, HybridPools):
         pool, state = pool
+    elif isinstance(pool, RingPools):
+        pool, ring = pool
     L = cfg.attention_layers  # the layers that hold pages: all, but in a layer pattern
     NB = pool.k.shape[0] // L
     valid = jnp.arange(C)[None, :] < new_lens[:, None]  # [N, C]
@@ -768,6 +899,9 @@ def _forward_hidden(
     selected = []  # what an indexed layer's queries kept, noted by ``attention`` as a layer is traced
     if eva:
         eva_attend = _eva_attention(cfg, positions, new_lens, block_tables, bs, L * NB)
+    elif ring is not None:
+        NR = ring.k.shape[0] // cfg.sliding_layers  # ring pages a sliding layer
+        windowed = _windowed_attention(cfg, positions, new_lens, block_tables, bs, L * NB, ring.k.shape[0])
     elif by_page or quant is not None:
         put_pages = _page_writer(block_tables, positions, new_lens, bs, L * NB)
 
@@ -776,9 +910,12 @@ def _forward_hidden(
             return put_pages(a, new, first_page)
         return a.at[first_page + w_page, w_slot].set(new, mode="drop")
 
-    def attention(ap, h, pk, pv, psk, psv, first_page):
+    def attention(ap, h, pk, pv, psk, psv, first_page, kind="attention"):
         if eva:
             out, pk, pv = eva_attend(ap, h, pk, pv, first_page)
+            return out, pk, pv, psk, psv
+        if ring is not None:  # ``pk`` / ``pv``: the class of page this layer's kind writes
+            out, pk, pv = windowed(ap, h, kind, pk, pv, first_page)
             return out, pk, pv, psk, psv
         if cfg.index_topk:
             out, pk, pv, kept = _indexed_latent_attention(ap, cfg, h, positions, new_lens, block_tables, bs,
@@ -855,7 +992,7 @@ def _forward_hidden(
     # (``reading``): ``attn_norm``, ``attn`` > ``wq`` ..., ``mlp`` > ``w_up`` ...,
     # the names flax gives the same modules in training.
     @jax.named_scope("layer")
-    def layer(carry, lp, first_page, dense=False):
+    def layer(carry, lp, first_page, dense=False, kind="attention"):
         x, pk, pv, psk, psv = carry
         if cfg.hc_mult:
             # ``x`` is the streams: the two adds of a one-stream block become a
@@ -870,7 +1007,7 @@ def _forward_hidden(
             return (written(lp, "mlp_hc", x, out, mixed), pk, pv, psk, psv), picks
         h = _norm_at(lp, "attn_norm", cfg, x)
         with reading(lp, "attn") as ap:
-            attn_out, pk, pv, psk, psv = attention(ap, h, pk, pv, psk, psv, first_page)
+            attn_out, pk, pv, psk, psv = attention(ap, h, pk, pv, psk, psv, first_page, kind)
         if cfg.parallel_block:
             # falcon/phi-style: attn and FFN read the shared input norm;
             # gpt-neox-style (parallel_mlp_norm): FFN reads its own ln2(x)
@@ -947,6 +1084,11 @@ def _forward_hidden(
                 (x, *kv), picks = layer(carry[:5], lp, (first_a + a) * NB)
                 carry = (x, *kv, *carry[5:])
                 a += 1
+            elif kind == "sliding_attention":  # the ``s``-th sliding layer: its pages are the ring pool's
+                x, *kv, rk, rv = carry
+                (x, rk, rv, _, _), picks = layer((x, rk, rv, None, None), lp, (first_s + s) * NR, kind=kind)
+                carry = (x, *kv, rk, rv)
+                s += 1
             else:
                 carry, picks = state_layer(carry, lp, first_s + s, kind)
                 s += 1
@@ -966,7 +1108,7 @@ def _forward_hidden(
             kinds = cfg.period
             periods = jnp.arange(cfg.num_layers // len(kinds), dtype=jnp.int32)
             (x, *pool), picks = jax.lax.scan(
-                period, carry + tuple(state or ()),
+                period, carry + tuple(state or ()) + (tuple(ring[:2]) if ring is not None else ()),
                 (layers, periods * kinds.count("attention"),
                  periods * (len(kinds) - kinds.count("attention"))) + ((periods,) if stacked else ()))
             if routed:  # [periods, layers of a period, N*C, k]: the layers in the model's order
@@ -984,6 +1126,8 @@ def _forward_hidden(
             kept = jnp.moveaxis(kept, 0, 2 if kept.ndim == 4 else 1)  # (a chunk's masks: [layers, N, C, words])
     if state is not None:
         pool = HybridPools(PagedKVPool(*pool[:4]), StatePool(*pool[4:]))
+    elif ring is not None:
+        pool = RingPools(PagedKVPool(*pool[:4]), PagedKVPool(*pool[4:]))
     else:
         pool = PagedKVPool(*pool)
     if cfg.hc_mult:

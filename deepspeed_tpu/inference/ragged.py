@@ -216,6 +216,58 @@ class WindowLayout:
                 + position % self.window + 1)
 
 
+def ring_columns(window: int, block_size: int) -> int:
+    """Pages that hold ``window`` consecutive positions wherever they start: the ring a row keeps of a sliding
+    layer (``RingLayout`` on the host, ``paged._windowed_attention`` on the device)."""
+    return -(-window // block_size) + 1
+
+
+@dataclasses.dataclass(frozen=True)
+class RingLayout:
+    """A row's block table under a sliding kind (``paged.RingPools`` and
+    ``paged._windowed_attention`` are the device's reading of the same
+    columns): ``summary_cols`` GLOBAL columns, one a block of positions, whose
+    pages are the full-attention layers' and grow with the context, then
+    ``window_pages`` RING columns, whose pages are the sliding layers': block
+    ``b`` in ring column ``b % window_pages``, written over when block ``b +
+    window_pages`` arrives. The two classes of page come from two free lists
+    (``StateManager.allocator`` and ``.ring_allocator``) and index two arrays.
+    A row takes ring pages as its context grows to a window and keeps them to
+    its flush: nothing is freed behind a ring and nothing moves.
+
+    The names ``summary_cols`` / ``window_pages`` are :class:`WindowLayout`'s for
+    the same two places of a row's table, so :class:`SequenceDescriptor` holds
+    both layouts with one set of fields."""
+
+    window: int
+    block_size: int
+    max_seq_len: int
+
+    @property
+    def window_pages(self) -> int:
+        """Ring columns (``ring_columns``)."""
+        return ring_columns(self.window, self.block_size)
+
+    @property
+    def summary_cols(self) -> int:
+        return -(-self.max_seq_len // self.block_size)
+
+    @property
+    def width(self) -> int:
+        return self.summary_cols + self.window_pages
+
+    def pages(self, seen: int, new: int) -> Tuple[int, int]:
+        """(global pages, ring pages) a row holds once ``new`` tokens are fed after ``seen``."""
+        blocks = -(-(seen + new) // self.block_size)
+        return blocks, min(blocks, self.window_pages)
+
+    def overwritten(self, seen: int, new: int) -> int:
+        """Ring pages that ``new`` tokens after ``seen`` start writing over: the
+        blocks they open past the ring's first round."""
+        past = [max(-(-n // self.block_size) - self.window_pages, 0) for n in (seen, seen + new)]
+        return past[1] - past[0]
+
+
 @dataclasses.dataclass
 class SequenceDescriptor:
     """Per-sequence tracking (reference ``DSSequenceDescriptor``).
@@ -295,8 +347,11 @@ class StateManager:
 
     def __init__(self, num_blocks: int, block_size: int, max_seqs: int = 256,
                  max_blocks_per_seq: Optional[int] = None,
-                 layout: Optional[WindowLayout] = None, state_slots: Optional[int] = None):
+                 layout: Optional[WindowLayout] = None, state_slots: Optional[int] = None,
+                 ring_blocks: Optional[int] = None):
         self.allocator = BlockedAllocator(num_blocks)
+        # the second class of page, under a RingLayout: the sliding layers' (``paged.RingPools.ring``)
+        self.ring_allocator = BlockedAllocator(ring_blocks) if isinstance(layout, RingLayout) else None
         self.block_size = block_size
         self.max_seqs = max_seqs
         self.max_blocks_per_seq = max_blocks_per_seq
@@ -356,17 +411,34 @@ class StateManager:
             at = np.fromiter((self._seqs[u].slot for u in uids), dtype=np.int64, count=len(uids))
         return at, _round_up(int(at.max(initial=-1)) + 1, row_bucket)
 
-    def _pages_short(self, seq: Optional[SequenceDescriptor], new_tokens: int) -> Tuple[int, int, int]:
-        """Under a layout: (pages to allocate, summary pages, window pages)
-        for feeding ``new_tokens`` to ``seq`` (None: a fresh one)."""
+    def _short_by_class(self, seq: Optional[SequenceDescriptor], new_tokens: int) -> Tuple[int, int, int, int]:
+        """Under a layout: (summary or global pages to allocate, window or ring
+        pages to allocate, and how many of each the row then holds) for feeding
+        ``new_tokens`` to ``seq`` (None: a fresh one)."""
         seen, have_s, have_w = (seq.seen_tokens, seq.n_summary, seq.n_window) if seq else (0, 0, 0)
         want_s, want_w = self.layout.pages(seen, new_tokens)
-        return max(want_s - have_s, 0) + max(want_w - have_w, 0), want_s, want_w
+        return max(want_s - have_s, 0), max(want_w - have_w, 0), want_s, want_w
+
+    def _pages_short(self, seq: Optional[SequenceDescriptor], new_tokens: int) -> Tuple[int, int, int]:
+        """Under a layout of ONE class of page: (pages to allocate, summary pages, window pages)."""
+        short_s, short_w, want_s, want_w = self._short_by_class(seq, new_tokens)
+        return short_s + short_w, want_s, want_w
 
     def can_schedule(self, uids: Sequence[int], token_counts: Sequence[int]) -> bool:
         """Admission check (reference ``InferenceEngineV2.can_schedule`` :184)."""
         need = 0
         fresh = 0
+        if self.ring_allocator is not None:
+            need_ring = 0
+            for uid, n in zip(uids, token_counts):
+                seq = self._seqs.get(uid)
+                fresh += seq is None
+                if (seq.seen_tokens if seq else 0) + n > self.layout.max_seq_len:
+                    return False  # sequence would exceed engine max_seq_len
+                short = self._short_by_class(seq, n)
+                need, need_ring = need + short[0], need_ring + short[1]
+            return (len(self._seqs) + fresh <= self.max_seqs and need <= self.allocator.free_blocks
+                    and need_ring <= self.ring_allocator.free_blocks)
         if self.layout is not None:
             for uid, n in zip(uids, token_counts):
                 seq = self._seqs.get(uid)
@@ -396,6 +468,13 @@ class StateManager:
     def extend(self, uid: int, new_tokens: int) -> SequenceDescriptor:
         """Ensure blocks exist for ``new_tokens`` more tokens of ``uid``."""
         seq = self.get_or_create(uid)
+        if self.ring_allocator is not None:
+            short_g, short_r, want_g, want_r = self._short_by_class(seq, new_tokens)
+            if short_g:  # (``hold`` lays fresh pages over the columns of the class that is short)
+                seq.hold(self.allocator.allocate(short_g), want_g, seq.n_window)
+            if short_r:
+                seq.hold(self.ring_allocator.allocate(short_r), seq.n_summary, want_r)
+            return seq
         if self.layout is not None:
             need, summary, window = self._pages_short(seq, new_tokens)
             if need:
@@ -427,7 +506,11 @@ class StateManager:
         (the sequence drops its reference); exclusively-owned blocks return
         to the free stack — identical to ``free`` when nothing is shared."""
         seq = self._seqs.pop(uid, None)
-        if seq is not None and seq.n_blocks:
+        if seq is not None and self.ring_allocator is not None:
+            first = self.layout.summary_cols  # both classes back, each to its own free list
+            self.allocator.release(seq._table[: seq.n_summary])
+            self.ring_allocator.release(seq._table[first: first + seq.n_window])
+        elif seq is not None and seq.n_blocks:
             self.allocator.release(seq.blocks)
         if seq is not None and seq.slot is not None:
             heapq.heappush(self._free_slots, seq.slot)  # as it stands: the next sequence starts from zeros
@@ -728,6 +811,8 @@ def build_ragged_batch(
     assert n == len(token_lists) and n > 0
     lens = np.fromiter((len(t) for t in token_lists), dtype=np.int64, count=n)
     chunk = _round_up(max(int(lens.max()), 1), chunk_bucket)
+    if isinstance(manager.layout, RingLayout) and lens.max() <= 1:
+        chunk = 1  # a call of single tokens reads the ring and the table; a wider call attends inside its chunks alone
     # --- block allocation: one vectorized allocator call for the whole step
     seqs = [manager.get_or_create(uid) for uid in uids]
     at, rows = slice(0, n), _round_up(n, row_bucket)
@@ -759,6 +844,13 @@ def build_ragged_batch(
             raise RuntimeError(
                 f"uid {uids[i]}: {int(seen_v[i] + lens[i])} tokens exceeds engine "
                 f"max_seq_len={manager.layout.max_seq_len}")
+        if isinstance(manager.layout, RingLayout) and lens.max(initial=0) > 1 and (seen_v > 0).any():
+            i = int(np.argmax(seen_v > 0))
+            raise ValueError(
+                f"uid {uids[i]}: {int(lens[i])} token(s) after {int(seen_v[i])} in a call that feeds a chunk: with a "
+                "sliding kind a call of more than one token a row takes fresh prompts alone (a fresh prompt attends "
+                "inside the chunk and writes its last window; a row past position 0 would have to read the ring and "
+                "the global pages: ROADMAP R3b); feed the whole context at once, or one token a row in a call")
         if ((lens > 1) & (seen_v > 0)).any():
             i = int(np.argmax((lens > 1) & (seen_v > 0)))
             raise ValueError(

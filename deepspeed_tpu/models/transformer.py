@@ -132,7 +132,25 @@ class ExpertParallel:
             raise ValueError(f"an expert-parallel share needs size >= 1 and 0 <= rank < size, got {self}")
 
 
-LAYER_KINDS = ("attention", "mamba", "linear_attention")
+@dataclasses.dataclass(frozen=True)
+class SlidingConfig:
+    """What the ``sliding_attention`` kind of a layer pattern is, beside plain
+    attention's shapes (``TransformerConfig.sliding``): a query at position
+    ``t`` sees the keys ``j`` with ``0 <= t - j < window`` (its own counted).
+    Where ``position == "rope"`` the sliding layers rotate their queries and
+    keys; ``global_rope`` says whether the pattern's ``attention`` layers,
+    which see every key, rotate too (False: no position term at all there, the
+    ``cohere2`` family's way)."""
+
+    window: int
+    global_rope: bool = True
+
+    def __post_init__(self):
+        if self.window < 1:
+            raise ValueError(f"a sliding window holds at least the query's own key, got window={self.window}")
+
+
+LAYER_KINDS = ("attention", "mamba", "linear_attention", "sliding_attention")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -333,6 +351,11 @@ class TransformerConfig:
     # "linear_attention": ``GatedDeltaNet`` at the sizes ``gdn``. A pattern may
     # have a routed MLP (``num_experts`` > 0) in every layer, drop-free
     gdn: Optional[GDNConfig] = None
+    # "sliding_attention": ``Attention`` under a band of ``sliding.window`` keys;
+    # the record also says which attention kinds of the pattern rotate. A pattern
+    # of the two attention kinds alone may be a ``parallel_block`` with a routed
+    # MLP in every layer
+    sliding: Optional[SlidingConfig] = None
     # Gated attention: the query projection is twice as wide, a head's
     # ``[q | gate]``, and the attention's output is times ``sigmoid(gate)``
     # before ``wo``; per-head RMSNorm of q and k (``q_norm``, ``k_norm``) before
@@ -352,6 +375,13 @@ class TransformerConfig:
     attention_multiplier: Optional[float] = None
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # a LayerNorm's bias (False: scale alone), a sigmoid router's correction
+    # bias ``e_bias`` (False: the picks are the largest scores themselves), and
+    # the shared GLU's output times 1 / moe_shared_experts (the MEAN of the
+    # shared experts, where they are added unweighted otherwise)
+    norm_bias: bool = True
+    moe_router_bias: bool = True
+    moe_shared_average: bool = False
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -365,12 +395,28 @@ class TransformerConfig:
                 raise ValueError("layer_types with a 'mamba' layer needs its sizes, ssm=SSMConfig(...)")
             if "linear_attention" in kinds and self.gdn is None:
                 raise ValueError("layer_types with a 'linear_attention' layer needs its sizes, gdn=GDNConfig(...)")
-            if (self.hc_mult or self.parallel_block or self.first_dense_layers
+            if isinstance(self.sliding, dict):
+                object.__setattr__(self, "sliding", SlidingConfig(**self.sliding))
+            if ("sliding_attention" in kinds) != (self.sliding is not None):
+                raise ValueError("a 'sliding_attention' layer and its window go together: layer_types of "
+                                 f"{sorted(kinds)} with sliding={self.sliding}")
+            if self.sliding is not None and (self.position not in ("rope", "none") or self.state_layers
+                                             or self.attn_impl in ("sparse", "fpdt")):
+                raise ValueError("a 'sliding_attention' layer is plain attention under a band, beside plain "
+                                 "attention alone, with rotary positions or none: not with learned or alibi "
+                                 "positions, state-space or linear-attention layers, attn_impl sparse | fpdt")
+            # the one parallel block a pattern is built in: the two attention kinds, a routed MLP in every layer
+            parallel = self.parallel_block and not (kinds <= {"attention", "sliding_attention"}
+                                                    and self.num_experts > 0)
+            if (self.hc_mult or parallel or self.first_dense_layers
                     or self.moe_layer_experts or self.kv_lora_rank or self.eva_window or self.fp32_residual):
                 raise ValueError(
                     "a layer pattern (layer_types) is built around plain attention and one MLP (dense, or "
                     "routed in every layer) in a sequential one-stream block: no hyper-connections, "
                     "parallel_block, leading dense or pyramid layers, latent or EVA attention, fp32 residual")
+        elif self.sliding is not None:
+            raise ValueError("sliding=SlidingConfig(...) is the data of a layer pattern's 'sliding_attention' "
+                             "kind: give layer_types")
         if isinstance(self.expert_parallel, dict):
             object.__setattr__(self, "expert_parallel", ExpertParallel(**self.expert_parallel))
         if self.expert_parallel is not None and self.expert_parallel.size == 1:
@@ -533,6 +579,11 @@ class TransformerConfig:
         return 0 if self.layer_types is None else self.layer_types.count("linear_attention")
 
     @property
+    def sliding_layers(self) -> int:
+        """Layers that hold a window of keys and values: the ring pool's."""
+        return 0 if self.layer_types is None else self.layer_types.count("sliding_attention")
+
+    @property
     def state_layers(self) -> int:
         """Layers that hold a recurrent state: the state pool's rows."""
         return self.ssm_layers + self.gdn_layers
@@ -629,7 +680,7 @@ class TransformerConfig:
             if n_exp > 0:
                 layer_mlp = n_exp * expert + h * self.router_experts  # experts (held here) + router
                 layer_mlp += self.moe_shared_experts * expert + (h if self.moe_shared_gate else 0)
-                if self.moe_router == "sigmoid":
+                if self.moe_router == "sigmoid" and self.moe_router_bias:
                     layer_mlp += self.router_experts  # the correction bias
                 if self.moe_use_residual:
                     layer_mlp += mlp + 2 * h + 2  # residual MLP + coefficient gate
@@ -748,7 +799,8 @@ def _norm(config: TransformerConfig, name: str):
         return _made_again(RMSNorm)(
             eps=config.norm_eps, param_dtype=config.param_dtype, unit_offset=config.norm_unit_offset,
             out_dtype=config.dtype if config.fp32_residual else None, name=name)
-    return _made_again(nn.LayerNorm)(epsilon=config.norm_eps, param_dtype=config.param_dtype, name=name)
+    return _made_again(nn.LayerNorm)(epsilon=config.norm_eps, param_dtype=config.param_dtype,
+                                     use_bias=config.norm_bias, name=name)
 
 
 # ``apply_qk_rope`` has TWO paths to the same numbers, and the first is kept for one reason alone: a table over
@@ -833,6 +885,11 @@ class _SparseGradEmbed(nn.Embed):
 
 class Attention(nn.Module):
     config: TransformerConfig
+    # of a pattern with a sliding kind (``TransformerConfig.sliding``): the band
+    # this layer attends under (None: every key up to the query) and whether it
+    # rotates (None: as ``config.position`` says)
+    window: Optional[int] = None
+    rotates: Optional[bool] = None
 
     @nn.compact
     def __call__(self, x, mask, positions, train: bool):
@@ -852,9 +909,10 @@ class Attention(nn.Module):
         if cfg.qk_norm:
             q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
 
-        if cfg.position == "rope":
+        if cfg.position == "rope" and self.rotates is not False:
             q, k = apply_qk_rope(cfg, q, k, positions)
         slopes = alibi_slopes(cfg.num_heads) if cfg.position == "alibi" else None
+        banded = {} if self.window is None else {"window": self.window}
 
         from deepspeed_tpu.ops import causal_attention
         from deepspeed_tpu.parallel.ulysses import sp_active, ulysses_shard, ulysses_unshard
@@ -906,7 +964,7 @@ class Attention(nn.Module):
                                  alibi_slopes=slopes,
                                  offload=cfg.fpdt_offload)
             out = ulysses_unshard(out)
-        elif cfg.sp_impl == "ring" and sp_active() and mask is None:
+        elif cfg.sp_impl == "ring" and sp_active() and mask is None and self.window is None:
             # ring attention: K/V rotate over the sp ring (ppermute), queries
             # stay seq-sharded — O(S/P) memory, neighbor-link comm. ALiBi
             # rides the hops (each block's global k offset feeds the bias).
@@ -923,7 +981,7 @@ class Attention(nn.Module):
             q, k, v = ulysses_shard(q), ulysses_shard(k), ulysses_shard(v)
             scaled = {} if cfg.attention_multiplier is None else {"softmax_scale": cfg.attention_multiplier}
             out = causal_attention(q, k, v, mask=mask, impl=cfg.attn_impl,
-                                   alibi_slopes=slopes, **scaled,
+                                   alibi_slopes=slopes, **scaled, **banded,
                                    **dict(cfg.attn_kwargs or ()))  # [B,S,H,hd]
             out = ulysses_unshard(out)
         if cfg.attn_output_gate:
@@ -935,6 +993,14 @@ class Attention(nn.Module):
         if cfg.dropout > 0:
             out = nn.Dropout(cfg.dropout, deterministic=not train)(out)
         return out
+
+
+def sliding_kind(cfg: TransformerConfig, kind: str) -> dict:
+    """``window`` and ``rotates`` of an attention layer of ``kind`` in a pattern with a sliding kind: one
+    statement for the flax module and the paged path."""
+    if kind == "sliding_attention":
+        return {"window": cfg.sliding.window, "rotates": True}
+    return {"window": None, "rotates": cfg.sliding.global_rope}
 
 
 class LatentRotary(NamedTuple):
@@ -1327,6 +1393,8 @@ class Block(nn.Module):
         cfg = self.config
         attn_cls = (LatentAttention if cfg.latent_attention
                     else EvaAttention if cfg.eva_window else Attention)
+        if cfg.sliding is not None:  # this layer's attention kind: its band, and whether it rotates
+            attn_cls = functools.partial(Attention, **sliding_kind(cfg, self.kind))
         cap_scale = None
         if cfg.moe_dynamic_capacity:
             # dynamic capacity rides the carry as a traced fp32 scalar (the
@@ -1389,6 +1457,8 @@ class Block(nn.Module):
             # drop-free by construction: no capacity, no auxiliary loss
             from deepspeed_tpu.parallel.moe import DropFreeMoE
 
+            if cfg.norm == "layernorm":  # flax's LayerNorm hands its float32 sums on; a Dense casts them, this does not
+                h = h.astype(cfg.dtype)
             with jax.named_scope("moe"):
                 x = add(DropFreeMoE(cfg, name="moe")(h))  # the add reads ``moe``, as it did
         elif n_exp > 0:
@@ -1650,7 +1720,9 @@ def _apply_norm(norm_params, cfg: TransformerConfig, x):
     mean = xf.mean(-1, keepdims=True)
     var = ((xf - mean) ** 2).mean(-1, keepdims=True)
     y = (xf - mean) * jax.lax.rsqrt(var + cfg.norm_eps)
-    y = y * norm_params["scale"].astype(jnp.float32) + norm_params["bias"].astype(jnp.float32)
+    y = y * norm_params["scale"].astype(jnp.float32)
+    if "bias" in norm_params:
+        y = y + norm_params["bias"].astype(jnp.float32)
     return y.astype(cfg.dtype)
 
 

@@ -29,6 +29,7 @@ def _xla_causal_attention(
     bias: Optional[jax.Array] = None,  # [H, S, S] or [B, H, S, S] additive
     causal: bool = True,
     softmax_scale: Optional[float] = None,  # None: D^-0.5
+    window: Optional[int] = None,  # a band: query t sees keys j with 0 <= t - j < window
 ) -> jax.Array:
     B, S, H, D = q.shape
     Hkv = k.shape[2]
@@ -55,6 +56,9 @@ def _xla_causal_attention(
     keep = None
     if causal:
         keep = jnp.tril(jnp.ones((S, S), bool))[None, None, None]
+    if window is not None:
+        band = band_keep(jnp.arange(S)[:, None], jnp.arange(S)[None, :], window)[None, None, None]
+        keep = band if keep is None else keep & band
     if mask is not None:
         m = mask[:, None, None, None, :] > 0
         keep = m if keep is None else keep & m
@@ -63,6 +67,18 @@ def _xla_causal_attention(
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
     return out.reshape(B, S, H, D)
+
+
+def band_keep(q_positions, k_positions, window: int):
+    """THE statement of a sliding window: a query at ``t`` sees the key at ``j`` only while ``t - j <
+    window`` (its own key counted; causality is the caller's). The dense fallback, the banded flash
+    forward's edge cells and the paged path's first live slot all say this."""
+    return q_positions - k_positions < window
+
+
+def first_live(q_positions, window: int):
+    """The oldest position a query at ``q_positions`` still sees under ``band_keep``."""
+    return jnp.maximum(q_positions - (window - 1), 0)
 
 
 def resolves_to_flash(impl: str = "auto") -> bool:
@@ -80,7 +96,7 @@ def resolves_to_flash(impl: str = "auto") -> bool:
 
 
 def causal_attention(q, k, v, mask=None, impl: str = "auto",
-                     alibi_slopes=None, bias=None, softmax_scale=None, **kernel_kwargs):
+                     alibi_slopes=None, bias=None, softmax_scale=None, window=None, **kernel_kwargs):
     """Grouped-query causal attention with optional ALiBi slopes and additive
     pair bias. ALiBi is fused into the Pallas flash kernels (slope * column
     iota — no bias tiles) so bloom-style training keeps the flash path; the
@@ -93,14 +109,24 @@ def causal_attention(q, k, v, mask=None, impl: str = "auto",
     states its own: ``TransformerConfig.latent_rotary``); it goes to whichever
     implementation runs, and is handed over only where it is given.
 
+    ``window`` (static) lays a band under the causal mask (``band_keep``): on the
+    Pallas path the forward runs no grid cell wholly under the band
+    (``flash_banded_forward``: a forward alone, its backward refused by name);
+    with a padding mask, ALiBi or a pair bias beside it the dense path runs.
+
     kernel_kwargs (block_q / block_k / k_splits) are Pallas scheduling knobs
     with identical math — they are forwarded only when dispatch resolves to
     the pallas kernel and dropped on the XLA path (which has no blocking)."""
     scaled = {} if softmax_scale is None else {"softmax_scale": softmax_scale}
     if bias is not None:
         return _xla_causal_attention(q, k, v, mask=mask,
-                                     alibi_slopes=alibi_slopes, bias=bias, **scaled)
+                                     alibi_slopes=alibi_slopes, bias=bias, window=window, **scaled)
     fn = dispatch("causal_attention", impl)
+    if window is not None:
+        if fn is available_impls("causal_attention").get("pallas") and mask is None and alibi_slopes is None:
+            kw = {key: val for key, val in kernel_kwargs.items() if key in ("block_q", "block_k")}
+            return _per_shard_flash(fn, q, k, v, None, None, dict(kw, window=window, **scaled))
+        return _xla_causal_attention(q, k, v, mask=mask, alibi_slopes=alibi_slopes, window=window, **scaled)
     if fn is available_impls("causal_attention").get("pallas"):
         return _per_shard_flash(fn, q, k, v, mask, alibi_slopes, dict(kernel_kwargs, **scaled))
     if alibi_slopes is not None:

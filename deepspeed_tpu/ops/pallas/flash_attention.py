@@ -131,6 +131,34 @@ def _causal_keep(qi, ki, shape, block_q, block_k, col_off=0, key_axis=1):
     return cols <= rows
 
 
+def _band_keep(qi, ki, shape, block_q, block_k, window, col_off=0, key_axis=1):
+    """``ops/attention.py::band_keep`` on a cell's own rows and columns."""
+    from deepspeed_tpu.ops.attention import band_keep
+
+    rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - key_axis)
+    cols = ki * block_k + col_off + jax.lax.broadcasted_iota(jnp.int32, shape, key_axis)
+    return band_keep(rows, cols, window)
+
+
+def _band_first(qi, block: int, window: int):
+    """The first key block a query block still sees under a band of ``window`` keys."""
+    return jnp.maximum(qi * block - (window - 1), 0) // block
+
+
+def _band_maps(n: int, block: int, window: int):
+    """``_tri_maps`` with a lower bound: for each query row qi the key columns
+    from the first one any of its queries still sees (``_band_first``) to qi.
+    Cells wholly under the band are no part of the grid: no DMA, no compute."""
+    import numpy as np
+
+    first = np.maximum(np.arange(n) * block - (window - 1), 0) // block
+    counts = np.arange(n) - first + 1
+    qs = np.repeat(np.arange(n), counts)
+    starts = np.repeat(np.cumsum(counts) - counts, counts)
+    ks = np.arange(qs.size) - starts + np.repeat(first, counts)
+    return jnp.asarray(qs, jnp.int32), jnp.asarray(ks, jnp.int32)
+
+
 def _row_of(col):
     """[n, 1] -> [1, n], the values unchanged: the column across a vreg row's
     lanes, transposed, its first sublane."""
@@ -246,7 +274,7 @@ def _sub_slices(block_k: int, k_splits: int):
 
 
 def _sub_score(q, k, mask_ref, slopes_ref, qi, ki, off, c, *, block_q, block_k,
-               masked, mask_block, alibi, keys_first=False):
+               masked, mask_block, alibi, keys_first=False, band=None):
     """Masked scores for one sub-chunk: s = q @ k[off:off+c]^T (+alibi, +mask),
     ``[block_q, c]``; with ``keys_first`` the same scores transposed, s^T =
     k[off:off+c] @ q^T, ``[c, block_q]``, so that a ROW of per-query statistics
@@ -259,11 +287,14 @@ def _sub_score(q, k, mask_ref, slopes_ref, qi, ki, off, c, *, block_q, block_k,
     s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     if alibi:
         s = _alibi_add(s, slopes_ref, ki, block_k, col_off=off, key_axis=key_axis)
-    if mask_block or masked:
+    if mask_block or masked or band is not None:
         keep = None
+        if band is not None:  # a cell on the band's lower edge (forward alone)
+            keep = _band_keep(qi, ki, s.shape, block_q, block_k, band, col_off=off, key_axis=key_axis)
         if masked:
             kept = mask_ref[0, :, off:off + c]  # [1, c]: keys on the lanes
-            keep = jnp.broadcast_to((_cols_of(kept)[:, :1] if keys_first else kept) > 0, s.shape)
+            user = jnp.broadcast_to((_cols_of(kept)[:, :1] if keys_first else kept) > 0, s.shape)
+            keep = user if keep is None else keep & user
         if mask_block:
             ck = _causal_keep(qi, ki, s.shape, block_q, block_k, col_off=off, key_axis=key_axis)
             keep = ck if keep is None else keep & ck
@@ -272,14 +303,14 @@ def _sub_score(q, k, mask_ref, slopes_ref, qi, ki, off, c, *, block_q, block_k,
 
 
 def _fwd_kernel(*refs, block_q, block_k, causal, masked, squashed, alibi=False,
-                k_splits=1):
+                k_splits=1, window=None):
     if squashed:
         (qm_ref, km_ref, mask_ref, *rest) = refs
         slopes_ref = rest.pop(0) if alibi else None
         (q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref) = rest
         t = pl.program_id(2)
         qi, ki = qm_ref[t], km_ref[t]
-        first, last = ki == 0, ki == qi
+        first, last = ki == (0 if window is None else _band_first(qi, block_q, window)), ki == qi
     else:
         (mask_ref, *rest) = refs
         slopes_ref = rest.pop(0) if alibi else None
@@ -293,7 +324,7 @@ def _fwd_kernel(*refs, block_q, block_k, causal, masked, squashed, alibi=False,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def _compute(mask_block):
+    def _compute(mask_block, band=None):
         q = q_ref[0, 0]  # [block_q, D]  (pre-scaled by 1/sqrt(D))
         k = k_ref[0, 0]  # [block_k, D]
         v = v_ref[0, 0]
@@ -302,7 +333,7 @@ def _fwd_kernel(*refs, block_q, block_k, causal, masked, squashed, alibi=False,
         def _score(off, c):
             return _sub_score(q, k, mask_ref, slopes_ref, qi, ki, off, c,
                               block_q=block_q, block_k=block_k, masked=masked,
-                              mask_block=mask_block, alibi=alibi)
+                              mask_block=mask_block, alibi=alibi, band=band)
 
         s_next = _score(*sub[0])
         for idx, (off, c) in enumerate(sub):
@@ -329,7 +360,16 @@ def _fwd_kernel(*refs, block_q, block_k, causal, masked, squashed, alibi=False,
                 preferred_element_type=jnp.float32,
             )
 
-    if causal and squashed:
+    if window is not None:
+        # the grid enumerates the band's cells alone (``_band_maps``): the diagonal
+        # cell masks causally, a cell that reaches under the band masks its lower
+        # edge (one cell in eight where the window is eight blocks), the rest nothing
+        edge = qi * block_q + block_q - 1 - ki * block_k >= window
+        pl.when((ki < qi) & ~edge)(lambda: _compute(False))
+        pl.when((ki == qi) & ~edge)(lambda: _compute(True))
+        pl.when((ki < qi) & edge)(lambda: _compute(False, window))
+        pl.when((ki == qi) & edge)(lambda: _compute(True, window))
+    elif causal and squashed:
         # the grid enumerates only ki <= qi; the diagonal cell masks in-block
         pl.when(ki < qi)(lambda: _compute(False))
         pl.when(ki == qi)(lambda: _compute(True))
@@ -356,15 +396,20 @@ _PARALLEL_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
 
 
 def _flash_fwd(q, k, v, mask, slopes, block_q: int, block_k: int, causal: bool,
-               masked: bool, alibi: bool, k_splits: int = 1):
+               masked: bool, alibi: bool, k_splits: int = 1, window: Optional[int] = None):
     """q,k,v: [B, H(q/kv), S, D] (q pre-scaled). mask: [B, S] int32.
     slopes: [H, 1, _LANES] fp32 (log2e-scaled; ignored unless alibi).
-    Returns (out, lse): lse fp32 ``[B, H, 1, S]``, base 2."""
+    Returns (out, lse): lse fp32 ``[B, H, 1, S]``, base 2. ``window`` (a band
+    under the causal mask, ``_band_maps``) names the kernel ``swa_flash_fwd``."""
     B, H, S, D = q.shape
     Hkv = k.shape[1]
     G = H // Hkv
     nq, nk = _cdiv(S, block_q), _cdiv(S, block_k)
     squashed = _squash_ok(nq, nk, block_q, block_k, causal)
+    if window is not None and not (squashed and not masked and not alibi):
+        raise NotImplementedError(
+            f"flash attention under a band (window={window}) runs the squashed causal grid alone: equal "
+            f"blocks (got {block_q}, {block_k}), no padding mask, no ALiBi; the dense path takes the rest")
 
     out_shape = [
         _sds((B, H, S, D), q.dtype, q, k, v, mask),
@@ -377,7 +422,7 @@ def _flash_fwd(q, k, v, mask, slopes, block_q: int, block_k: int, causal: bool,
     ]
     kernel = functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k,
                                causal=causal, masked=masked, squashed=squashed,
-                               alibi=alibi, k_splits=k_splits)
+                               alibi=alibi, k_splits=k_splits, window=window)
     dec = _DEC_SQUASHED if squashed else _DEC_DENSE
     in_specs = _qkv_in_specs(dec, block_q, block_k, D, G, alibi=alibi)
     qrow = _qrow_specs(dec, block_q, D)
@@ -385,10 +430,10 @@ def _flash_fwd(q, k, v, mask, slopes, block_q: int, block_k: int, causal: bool,
     extra = (slopes,) if alibi else ()
 
     if squashed:
-        qm, km = _tri_maps(nq)
+        qm, km = _tri_maps(nq) if window is None else _band_maps(nq, block_q, window)
         out, lse = pl.pallas_call(
             kernel,
-            name="flash_fwd",
+            name="flash_fwd" if window is None else "swa_flash_fwd",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,  # qmap, kmap
                 grid=(B, H, qm.shape[0]),
@@ -757,6 +802,33 @@ def _flash_vjp_bwd(block_q, block_k, causal, masked, alibi, k_splits, softmax_sc
 _flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_banded_forward(q, k, v, window: int, block: int, softmax_scale: Optional[float] = None):
+    """Causal attention under a band of ``window`` keys (``ops/attention.py::
+    band_keep``), the FORWARD alone: q ``[B, S, H, D]``, k, v ``[B, S, Hkv, D]``,
+    ``S`` whole blocks of ``block``. The kernel is ``_fwd_kernel`` on a grid of
+    the band's cells (``_band_maps``), named ``swa_flash_fwd``."""
+    scale = _scale(q.shape[-1], softmax_scale) * _LOG2E
+    B, S, H, _ = q.shape
+    out, _ = _flash_fwd((q * jnp.asarray(scale, q.dtype)).transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                        v.transpose(0, 2, 1, 3), jnp.ones((B, 1, S), jnp.int32),
+                        jnp.zeros((H, 1, _LANES), jnp.float32), block, block, True, False, False, 1, window)
+    return out.transpose(0, 2, 1, 3)
+
+
+def _banded_fwd(q, k, v, window, block, softmax_scale):
+    return flash_banded_forward(q, k, v, window, block, softmax_scale), None
+
+
+def _banded_bwd(window, block, softmax_scale, res, g):
+    raise NotImplementedError(
+        f"flash attention under a band (window={window}) has a forward alone: no backward kernel skips the "
+        "cells under the band yet; train a sliding layer with attn_impl='xla'")
+
+
+flash_banded_forward.defvjp(_banded_fwd, _banded_bwd)
+
+
 @register("causal_attention", "pallas")
 def flash_causal_attention(
     q: jax.Array,  # [B, S, H, D]
@@ -768,10 +840,19 @@ def flash_causal_attention(
     alibi_slopes: Optional[jax.Array] = None,  # [H] fp32 (bloom ALiBi)
     k_splits: int = 1,
     softmax_scale: Optional[float] = None,  # None: D^-0.5
+    window: Optional[int] = None,  # a band under the causal mask: the forward alone (``flash_banded_forward``)
 ) -> jax.Array:
     B, S, H, D = q.shape
     block_q = min(block_q, max(S, 8))
     block_k = min(block_k, max(S, 8))
+    if window is not None:
+        if mask is not None or alibi_slopes is not None:
+            raise NotImplementedError("flash attention under a band takes no padding mask and no ALiBi slopes")
+        block = min(block_q, block_k)
+        pad = _cdiv(S, block) * block - S  # padded keys reach padded queries alone (module header)
+        if pad:
+            q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v))
+        return flash_banded_forward(q, k, v, window, block, softmax_scale)[:, :S]
     # k_splits > 1 processes each block_k tile as k_splits sub-chunks with the
     # next sub-chunk's QK^T hoisted ahead of the previous one's softmax, so the
     # MXU matmul can overlap the VPU exp2/renormalize passes. Pure

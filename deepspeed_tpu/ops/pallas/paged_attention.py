@@ -89,9 +89,11 @@ def _cdiv(a: int, b: int) -> int:
     return (a + b - 1) // b
 
 
-def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, quantized):
+def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, quantized, banded=False):
     refs = list(refs)
+    low_ref = refs.pop(0) if banded else None  # (a third scalar operand: a row's first slot live to ANY of its queries)
     q_ref, qpos_ref = refs.pop(0), refs.pop(0)
+    qlow_ref = refs.pop(0) if banded else None  # a query row's first live slot (a ring of pages)
     slopes_ref = refs.pop(0) if alibi else None
     k_hbm, v_hbm = refs.pop(0), refs.pop(0)
     ks_hbm = vs_hbm = ksbuf = vsbuf = None
@@ -210,6 +212,16 @@ def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, 
                 pos = c * T + i * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, D), 0)
                 vbuf[slot, i] = jnp.where(pos < ctx, vbuf[slot, i], jnp.zeros((), vbuf.dtype))
 
+        if banded:
+            # likewise the slots of the walk's first pages that the window has left behind: masked out of the
+            # scores, and whatever they hold (an older block of the ring) must not reach p @ v as 0 * NaN
+            @pl.when(c == 0)
+            def _():
+                @pl.loop(0, jnp.minimum(_cdiv(low_ref[n], bs), ppcb))
+                def _(i):
+                    pos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, D), 0)
+                    vbuf[slot, i] = jnp.where(pos >= low_ref[n], vbuf[slot, i], jnp.zeros((), vbuf.dtype))
+
         k_all, v_all = kbuf[slot], vbuf[slot]  # [ppcb, bs, kvH*hd]
         if quantized:
             # widen before the page-merge reshape: a 1-byte tile is 32 rows,
@@ -223,6 +235,8 @@ def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, 
         rows = qpos_ref.shape[1]
         j = c * T + jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
         visible = j <= qpos_ref[0]  # qpos: [rows, 1] column
+        if banded:  # the slots of the walk's first page that the window has left behind
+            visible = visible & (j >= qlow_ref[0])
         for g in range(acc_ref.shape[0]):
             if dense:
                 # row r*kvH + kh takes head kh's scales
@@ -296,7 +310,14 @@ def flash_decode_paged(
     alibi_slopes: jax.Array = None,  # [H] fp32 (bloom ALiBi, fused in-kernel)
     k_scale: jax.Array = None,  # [pages, bs*kvH] fp32 — quantized pool scales
     v_scale: jax.Array = None,
+    first_live: jax.Array = None,  # [N, C] int32: slots before it are dead (a ring of pages; kernel ``swa_paged_attn``)
 ) -> jax.Array:
+    """``first_live`` is for a row whose table is a RING of pages rolled so that
+    its oldest live page comes first (``inference/paged.py``, a sliding layer):
+    slot index is then position less the first page's first position,
+    ``q_positions`` are given in those units, and of the first page the slots
+    before ``first_live`` hold positions the window has left behind: masked,
+    as the slots past the query are. None is the kernel as it always was."""
     N, C, H, hd = q.shape
     D = pool_k.shape[2]
     kvH = D // hd
@@ -323,7 +344,8 @@ def flash_decode_paged(
         lens = (None, None) if new_lens is None else (jnp.clip(new_lens, 0, h), jnp.clip(new_lens - h, 0, C - h))
         return jnp.concatenate([
             flash_decode_paged(q[:, at], pool_k, pool_v, block_tables, q_positions[:, at], bs, n,
-                               pages_per_block, alibi_slopes, k_scale, v_scale)
+                               pages_per_block, alibi_slopes, k_scale, v_scale,
+                               None if first_live is None else first_live[:, at])
             for at, n in zip((slice(0, h), slice(h, C)), lens)], axis=1)
     scale = jnp.asarray(hd ** -0.5, q.dtype)
     qg = (q * scale).reshape(N, C, kvH, G, hd)
@@ -351,6 +373,10 @@ def flash_decode_paged(
     pad = rows - qpos_rows.shape[1]
     # padded rows see nothing (position -1 masks every token)
     qpos_rows = jnp.pad(qpos_rows, ((0, 0), (0, pad)), constant_values=-1)
+    banded = first_live is not None
+    if banded:  # laid out as the positions are: a row a (query, group member[, kv head]) pair
+        qlow_rows = jnp.broadcast_to(first_live[:, :, None], (N, C, G)).reshape(N, Cg)
+        qlow_rows = jnp.pad(jnp.repeat(qlow_rows, kvH, axis=1) if dense else qlow_rows, ((0, 0), (0, pad)))
     zeros = (0,) * (len(q_block) - 1)
 
     # a row's context, in tokens: positions are ascending within the live prefix
@@ -373,16 +399,19 @@ def flash_decode_paged(
 
     operands = [q_op, qpos_rows[:, :, None]]
     in_specs = [
-        pl.BlockSpec(q_block, lambda n, bt, cl: (n,) + zeros),
+        pl.BlockSpec(q_block, lambda n, *_: (n,) + zeros),
         # per-row scalars ride as [.., rows, 1] COLUMNS: a (1, rows) row block
         # breaks Mosaic's (8, 128) block rule; (rows, 1) has rows % 8 == 0 and
         # a full last dim, and is already the broadcast shape the mask needs
-        pl.BlockSpec((1, rows, 1), lambda n, bt, cl: (n, 0, 0)),
+        pl.BlockSpec((1, rows, 1), lambda n, *_: (n, 0, 0)),
     ]
+    if banded:
+        operands.append(qlow_rows[:, :, None])
+        in_specs.append(pl.BlockSpec((1, rows, 1), lambda n, *_: (n, 0, 0)))
     if alibi:
         srows = jnp.pad(srows, ((0, 0), (0, rows - srows.shape[1])))
         operands.append(srows[:, :, None])
-        in_specs.append(pl.BlockSpec((heads, rows, 1), lambda n, bt, cl: (0, 0, 0)))
+        in_specs.append(pl.BlockSpec((heads, rows, 1), lambda n, *_: (0, 0, 0)))
     operands += [pool_k, pool_v]
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
     scratch = [
@@ -402,15 +431,15 @@ def flash_decode_paged(
             scratch.append(pltpu.VMEM((2, kvH, T), jnp.float32))
 
     kernel = functools.partial(_decode_kernel, ppcb=ppcb, bs=bs, kvH=kvH, hd=hd, Cg=Cg,
-                               dense=dense, alibi=alibi, quantized=quantized)
+                               dense=dense, alibi=alibi, quantized=quantized, banded=banded)
     out = pl.pallas_call(
         kernel,
-        name="paged_attn",
+        name="swa_paged_attn" if banded else "paged_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # block_tables, ctx_lens
+            num_scalar_prefetch=3 if banded else 2,  # block_tables, ctx_lens(, a row's first live slot)
             grid=(N,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(q_block, lambda n, bt, cl: (n,) + zeros),
+            out_specs=pl.BlockSpec(q_block, lambda n, *_: (n,) + zeros),
             scratch_shapes=scratch + [
                 pltpu.VMEM((heads, rows, D // heads), jnp.float32),
                 pltpu.VMEM((heads, rows, _SUBLANES), jnp.float32),
@@ -422,7 +451,7 @@ def flash_decode_paged(
         # rows in order: each starts the next one's first fetch
         compiler_params=tpu_compiler_params(dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
-    )(block_tables, ctx_lens, *operands)
+    )(block_tables, ctx_lens, *((jnp.min(first_live, axis=1).astype(jnp.int32),) if banded else ()), *operands)
 
     if dense:
         out = out.reshape(N, C, G, kvH, hd).transpose(0, 1, 3, 2, 4)

@@ -570,7 +570,7 @@ class _Router(nn.Module):
         # (a chip's share of the layer scores every chip's experts: ``router_experts``)
         out = {"wg": {"kernel": _Kernel((cfg.hidden_size, cfg.router_experts), cfg.param_dtype,
                                         name="wg")()}}
-        if cfg.moe_router == "sigmoid":
+        if cfg.moe_router == "sigmoid" and cfg.moe_router_bias:
             # sigmoid scores of lecun-normal logits spread by about a quarter
             # over the experts: a bias of a fifth of that changes picks
             # without taking the choice over, among 64 experts or fewer. The

@@ -1159,6 +1159,135 @@ def test_glm_5_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, name
     assert not moved, moved
 
 
+@pytest.mark.parametrize("window,cells", [(4096, 252), (None, 528)], ids=["band-4096", "causal"])
+def test_the_banded_flash_forward_compiles_at_128_heads_over_8(one_chip, monkeypatch, window, cells):
+    """``command-a-plus-05-2026.serve.long-prompt-wave8``'s prefill attention: a
+    ``(1, 16384)`` prompt, 128 query heads over 8 key-value heads of 128 (GQA in
+    the index maps), under a band of 4,096 keys (kernel ``swa_flash_fwd``, a
+    grid of the band's 252 cells a head) and under the causal mask alone
+    (``flash_fwd``, the triangle's 528): ONE Mosaic kernel each."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((1, 16384, 128, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 16384, 8, 128), jnp.bfloat16, sharding=one_chip)
+    banded = {} if window is None else {"window": window}
+    text = jax.jit(lambda q, k, v: fa.flash_causal_attention(q, k, v, **banded)).lower(q, kv, kv).compile().as_text()
+    (kernel,) = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert ("swa_flash_fwd" in kernel) == (window is not None)
+    maps = fa._tri_maps(32) if window is None else fa._band_maps(32, 512, window)
+    assert len(maps[0]) == cells and f"s32[{cells}]" in kernel  # the grid's enumeration is the kernel's operand
+
+
+@pytest.mark.parametrize("columns,banded", [(257, True), (1032, False)], ids=["ring-257", "global-1032"])
+def test_the_paged_kernel_walks_a_ring_and_a_global_table_at_8_rows(one_chip, monkeypatch, columns, banded):
+    """The same cell's decode attention at 8 rows, one token a row, 128 heads
+    over 8: a sliding layer's ring of 257 columns with a first live slot a row
+    (kernel ``swa_paged_attn``: a third scalar operand, a second mask) and the
+    full layer's 1,032 global columns (``paged_attn`` as every model runs it)."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    shapes, bs = _paged_shapes(one_chip, 8, 1, False, 128, 8, 128, pages=columns, num_blocks=2056, layers=3)
+    low = [jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)] * banded
+
+    def fn(q, pk, pv, bt, qpos, lens, *low):
+        return pa.flash_decode_paged(q, pk, pv, bt, qpos, bs, new_lens=lens, **dict(zip(("first_live",), low)))
+
+    text = jax.jit(fn).lower(*shapes, *low).compile().as_text()
+    (kernel,) = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert ("swa_paged_attn" in kernel) == banded and ("paged_attn" in kernel)
+
+
+@pytest.mark.parametrize("name", ["prefill_1x16384", "chain_8"])
+def test_command_a_plus_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, name):
+    """``command-a-plus-05-2026.serve.long-prompt-wave8``'s programs whole, for
+    the described v5e at the cell's own shapes (one period of 3 sliding + 1
+    full layers at the published widths, 128 heads over 8, a window of 4,096,
+    16 of 128 experts held, 1 GiB of pages in two classes: 10,216 global pages
+    a full layer and a ring of 8 x 257 a sliding layer, a block table of 1,032
+    + 257 columns), with the picks handed out as the timed path hands them:
+    the ``(1, 16384)`` prefill (``swa_flash_fwd`` three times and ``flash_fwd``
+    once in the period's body, each beside the one-token rows' paged kernels;
+    the share's sorted dispatch through megablox ``gmm``) and the chain of 8
+    steps at 8 rows (``swa_paged_attn`` x 3, ``paged_attn`` x 1, ``moe_decode``
+    x 4). Each fits the chip beside the weights and both pools, returns both
+    donated pools aliased, and copies neither."""
+    import dataclasses
+    import json
+
+    from benchmarks.lib import harness, program
+    from deepspeed_tpu.checkpoint.hf import config_from_hf
+    from deepspeed_tpu.inference import model, paged
+    from deepspeed_tpu.models import CausalLM
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import flash_attention as fa, moe_decode, norms, paged_attention as pa
+
+    for module in (pa, fa, norms, moe_decode):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")  # what 'auto' sees there
+    monkeypatch.setattr(model, "_grouped_matmul", lambda lhs, rhs, sizes: model._gmm_padded(lhs, rhs, sizes))
+    cfg = dataclasses.replace(config_from_hf(program.published(harness.load_config("command-a-plus-05-2026"))),
+                              dtype=jnp.bfloat16)
+    assert (cfg.num_experts, cfg.router_experts, cfg.sliding.window, cfg.num_heads, cfg.kv_heads) == (16, 128, 4096, 128, 8)
+    engine = harness.load_workload("command-a-plus-05-2026.serve.long-prompt-wave8")["engine"]
+    bs, rows = engine["kv_block_size"], engine["max_seqs"]
+    ring = paged.ring_columns(cfg.sliding.window, bs)
+    token = 2 * cfg.kv_heads * cfg.dims_per_head * 2  # a token's key and value a layer, bf16
+    ring_blocks = rows * ring
+    NB = (engine["kv_pool_bytes"] - ring_blocks * bs * cfg.sliding_layers * token) // (bs * cfg.attention_layers * token)
+    table = -(-engine["max_seq_len"] // bs) + ring
+    assert (ring, ring_blocks, NB, table, token) == (257, 2056, 10216, 1289, 4096)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda key: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), CausalLM(cfg).init(
+            {"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)}, train=False)["params"]),
+        jax.random.PRNGKey(0)))
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: paged.RingPools(
+        paged.init_pool(cfg, NB, bs, jnp.bfloat16), paged.init_ring_pool(cfg, ring_blocks, bs, jnp.bfloat16))))
+    assert pool.kv.k.shape == (10216, 16, 1024) and pool.ring.k.shape == (3 * 2056, 16, 1024)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    if name == "chain_8":
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def program_(params, pool, tokens, start_pos, tables, active, budgets, rng):
+            return paged.ragged_decode_chain(params, cfg, pool, tokens, start_pos, tables, bs,
+                                             active, budgets, rng, engine["decode_chain"], None, with_picks=True)
+
+        args = (i32(rows), i32(rows), i32(rows, table), jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip),
+                i32(rows), jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip))
+    else:
+        n, chunk = map(int, name.partition("_")[2].split("x"))
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def program_(params, pool, tokens, positions, new_lens, tables):
+            return paged.ragged_forward(params, cfg, pool, tokens, positions, new_lens, tables, bs, with_picks=True)
+
+        args = (i32(n, chunk), i32(n, chunk), i32(n), i32(n, table))
+    compiled = program_.lower(params, pool, *args).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pool))
+    assert pool_bytes == (10216 + 3 * 2056) * 16 * 1024 * 2 * 2 <= 2 ** 30 and mem.alias_size_in_bytes >= pool_bytes
+    peak_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30
+    print(json.dumps({"program": name, "temp_gb": mem.temp_size_in_bytes / 1e9,
+                      "argument_gb": mem.argument_size_in_bytes / 1e9, "peak_gib": peak_gib}))
+    assert peak_gib < 14.8  # of 15.75: the prefill stands at 14.59 (5.12 GB of temporaries beside 10.54 GB held)
+    text = compiled.as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+    count = lambda kernel: sum(kernel in name for name in calls)  # noqa: E731
+    if name == "chain_8":
+        assert (count("swa_paged_attn"), count("paged_attn") - count("swa_paged_attn")) == (3, 1)
+        assert count("moe_decode") == 4 and not count("flash_fwd")
+    else:  # (a call of fresh prompts attends inside its chunks alone: no paged kernel, no one-token path)
+        assert (count("swa_flash_fwd"), count("flash_fwd") - count("swa_flash_fwd")) == (3, 1) and count("gmm") >= 3
+        assert not count("paged_attn")
+    assert all("/swa/" in name for name in calls if "/swa_" in name)  # the new kernels under the new scope
+    moved = [line.strip()[:200] for line in text.splitlines()
+             if re.search(r"= \(?bf16\[(10216|6168),16,1024\]\S* (copy|copy-start|transpose)\(", line)
+             or re.search(r"bf16\[(10216|6168),16,1024\]\S*S\(1\)", line)]
+    assert not moved, moved
+
+
 def chunk_of(name: str) -> int:
     return 1 if name.startswith("chain") else int(name.rpartition("x")[2])
 
