@@ -1309,7 +1309,8 @@ class InferenceEngineV2:
         call = self._log.open("put", -1, batch.n_rows, batch.tokens.shape[1])
         with self._dispatching(call, rows=batch.n_rows,
                                **self._eva_args(batch.positions, batch.new_lens),
-                               **self._state_args(len(uids)), **self._ring_args(uids)):
+                               **self._state_args(len(uids)), **self._ring_args(uids),
+                               **self._flash_args(batch.new_lens, batch.tokens.shape[1])):
             logits, self._pools, *picks = step(
                 self.params, self._pools,
                 jnp.asarray(batch.tokens), jnp.asarray(batch.positions),
@@ -1474,6 +1475,23 @@ class InferenceEngineV2:
             args["ring_tokens"] = int(seen[fed].sum())
         return args
 
+    def _flash_args(self, new_lens, chunk: int) -> Dict[str, int]:
+        """For the ``serve:dispatch`` span of a call of fresh prompts of a model with a sliding kind, while
+        somebody records spans and the chunks' attention is the flash forward: ``flash_cells_live``, the cells
+        of its grids a head that hold a live query (the ones it runs since it is told the rows' lengths,
+        ``ops/pallas/flash_attention.py::_flash_fwd``), and ``flash_cells_grid``, the grids' own, both summed
+        over the call's rows and the pattern's layers by the kernels' own maps."""
+        from deepspeed_tpu.ops.attention import resolves_to_flash
+        from deepspeed_tpu.ops.pallas.flash_attention import forward_cells
+
+        cfg = self.model_config
+        if self._ring is None or chunk == 1 or not self._tracer.recording() or not resolves_to_flash(cfg.attn_impl):
+            return {}
+        by_kind = ((cfg.attention_layers, forward_cells(new_lens, chunk)),
+                   (cfg.sliding_layers, forward_cells(new_lens, chunk, self._ring.window)))
+        live, grid = (sum(layers * cells[i] for layers, cells in by_kind) for i in (0, 1))
+        return {"flash_cells_live": live, "flash_cells_grid": grid}
+
     def _put_sample(self, uids, token_lists, rng, sample_kw: Tuple,
                     tracker: Optional[LifecycleTracker] = None,
                     rids: Optional[Sequence[int]] = None) -> Tuple[np.ndarray, jax.Array]:
@@ -1488,7 +1506,8 @@ class InferenceEngineV2:
                                live=len(uids), tokens=int(batch.new_lens.sum()),
                                rids=self._span_rids(rids), **self._span_fed(uids, token_lists),
                                **self._eva_args(batch.positions, batch.new_lens),
-                               **self._state_args(len(uids)), **self._ring_args(uids)):
+                               **self._state_args(len(uids)), **self._ring_args(uids),
+                               **self._flash_args(batch.new_lens, batch.tokens.shape[1])):
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "prefill")
             toks, rng, self._pools, *picks = step(

@@ -725,7 +725,9 @@ def _windowed_attention(cfg: TransformerConfig, positions, new_lens, block_table
       slot and ``first_live`` the slots of it the window has left behind.
     - a chunk: attention is computed from the chunk's own ``q, k, v``
       (``causal_attention``: on the chip the flash forward, under the band for
-      a sliding layer, GQA in its index maps), never through the pages; an
+      a sliding layer, GQA in its index maps, told the rows' ``new_lens`` so
+      that it runs no cell past a prompt's last token and a pad's row is
+      zeros), never through the pages; an
       ``attention`` layer then writes every page of the prompt in one bulk
       write (``_page_writer``), a sliding layer the pages of the prompt's LAST
       window alone, whole pages, each into its ring column.
@@ -777,7 +779,7 @@ def _windowed_attention(cfg: TransformerConfig, positions, new_lens, block_table
             return a.at[(first_page + to).reshape(-1)].set(laid.reshape(N * R, bs, X), mode="drop")
 
         def attention(q, k, v, sliding, window, pk, pv, first_page):
-            ctx = causal_attention(q, k, v, impl=cfg.attn_impl, window=window)
+            ctx = causal_attention(q, k, v, impl=cfg.attn_impl, window=window, lengths=new_lens)
             with jax.named_scope("kv_write"):
                 put = put_ring if sliding else put_full
                 pk = put(pk, k.astype(pk.dtype).reshape(-1, X), first_page)

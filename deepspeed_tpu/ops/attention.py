@@ -30,6 +30,7 @@ def _xla_causal_attention(
     causal: bool = True,
     softmax_scale: Optional[float] = None,  # None: D^-0.5
     window: Optional[int] = None,  # a band: query t sees keys j with 0 <= t - j < window
+    lengths: Optional[jax.Array] = None,  # [B] int32: each row's live tokens, which come first; a pad's row is zeros
 ) -> jax.Array:
     B, S, H, D = q.shape
     Hkv = k.shape[2]
@@ -65,8 +66,10 @@ def _xla_causal_attention(
     if keep is not None:
         scores = jnp.where(keep, scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-    return out.reshape(B, S, H, D)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v).reshape(B, S, H, D)
+    if lengths is not None:  # what the flash forward hands back for a pad (``_flash_fwd``)
+        out = jnp.where((jnp.arange(S) < lengths[:, None])[:, :, None, None], out, jnp.zeros((), out.dtype))
+    return out
 
 
 def band_keep(q_positions, k_positions, window: int):
@@ -96,7 +99,7 @@ def resolves_to_flash(impl: str = "auto") -> bool:
 
 
 def causal_attention(q, k, v, mask=None, impl: str = "auto",
-                     alibi_slopes=None, bias=None, softmax_scale=None, window=None, **kernel_kwargs):
+                     alibi_slopes=None, bias=None, softmax_scale=None, window=None, lengths=None, **kernel_kwargs):
     """Grouped-query causal attention with optional ALiBi slopes and additive
     pair bias. ALiBi is fused into the Pallas flash kernels (slope * column
     iota — no bias tiles) so bloom-style training keeps the flash path; the
@@ -114,19 +117,28 @@ def causal_attention(q, k, v, mask=None, impl: str = "auto",
     (``flash_banded_forward``: a forward alone, its backward refused by name);
     with a padding mask, ALiBi or a pair bias beside it the dense path runs.
 
+    ``lengths`` (int32 ``[B]``) says that a row's live tokens come FIRST and how
+    many they are: a fresh prompt padded to its bucket. It is a word of the
+    forward alone, as the band is (differentiating through it is refused by
+    name): the pads' rows come back as zeros from every implementation, and
+    the Pallas forward runs no grid cell whose queries are all pads
+    (``flash_attention.py::_flash_fwd``), under the band as under the causal
+    mask alone. Beside a padding mask, ALiBi or a pair bias the dense path runs.
+
     kernel_kwargs (block_q / block_k / k_splits) are Pallas scheduling knobs
     with identical math — they are forwarded only when dispatch resolves to
     the pallas kernel and dropped on the XLA path (which has no blocking)."""
     scaled = {} if softmax_scale is None else {"softmax_scale": softmax_scale}
     if bias is not None:
-        return _xla_causal_attention(q, k, v, mask=mask,
-                                     alibi_slopes=alibi_slopes, bias=bias, window=window, **scaled)
+        return _xla_causal_attention(q, k, v, mask=mask, alibi_slopes=alibi_slopes, bias=bias, window=window,
+                                     lengths=lengths, **scaled)
     fn = dispatch("causal_attention", impl)
-    if window is not None:
+    if window is not None or lengths is not None:
         if fn is available_impls("causal_attention").get("pallas") and mask is None and alibi_slopes is None:
             kw = {key: val for key, val in kernel_kwargs.items() if key in ("block_q", "block_k")}
-            return _per_shard_flash(fn, q, k, v, None, None, dict(kw, window=window, **scaled))
-        return _xla_causal_attention(q, k, v, mask=mask, alibi_slopes=alibi_slopes, window=window, **scaled)
+            return _per_shard_flash(fn, q, k, v, None, None, dict(kw, window=window, **scaled), lengths)
+        return _xla_causal_attention(q, k, v, mask=mask, alibi_slopes=alibi_slopes, window=window,
+                                     lengths=lengths, **scaled)
     if fn is available_impls("causal_attention").get("pallas"):
         return _per_shard_flash(fn, q, k, v, mask, alibi_slopes, dict(kernel_kwargs, **scaled))
     if alibi_slopes is not None:
@@ -146,29 +158,32 @@ def evoformer_attention(q, k, v, pair_bias=None, mask=None):
     return _xla_causal_attention(q, k, v, mask=mask, bias=pair_bias, causal=False)
 
 
-def _per_shard_flash(fn, q, k, v, mask, alibi_slopes, kernel_kwargs):
+def _per_shard_flash(fn, q, k, v, mask, alibi_slopes, kernel_kwargs, lengths=None):
     """The flash kernel under GSPMD (``ops/partition.py``): attention is
     independent per (batch row, kv-head group), so batch splits over the
-    data axes and heads over sp (the Ulysses head shard) and tp."""
+    data axes (the rows' live ``lengths`` with it) and heads over sp (the
+    Ulysses head shard) and tp."""
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.ops.partition import kernel_mesh, live_axes, per_shard
     from deepspeed_tpu.topology.mesh import BATCH_AXES
 
-    def call(q, k, v, mask, slopes):
+    def call(q, k, v, mask, slopes, lengths):
         kw = dict(kernel_kwargs)
         if slopes is not None:
             kw["alibi_slopes"] = slopes
+        if lengths is not None:
+            kw["lengths"] = lengths
         return fn(q, k, v, mask=mask, **kw)
 
     ctx = kernel_mesh()
     if ctx is None:
-        return call(q, k, v, mask, alibi_slopes)
+        return call(q, k, v, mask, alibi_slopes, lengths)
     mesh, free = ctx
     b = live_axes(mesh, free, BATCH_AXES, q.shape[0])
     # kv heads must split like q heads: GQA groups stay whole on a device
     h = live_axes(mesh, free, ("sp", "tp"), q.shape[2], k.shape[2])
     qkv = P(b, None, h, None)
     in_specs = (qkv, qkv, qkv, None if mask is None else P(b, None),
-                None if alibi_slopes is None else P(h))
-    return per_shard(call, mesh, free, in_specs, qkv)(q, k, v, mask, alibi_slopes)
+                None if alibi_slopes is None else P(h), None if lengths is None else P(b))
+    return per_shard(call, mesh, free, in_specs, qkv)(q, k, v, mask, alibi_slopes, lengths)

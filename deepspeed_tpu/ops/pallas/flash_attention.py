@@ -145,8 +145,8 @@ def _band_first(qi, block: int, window: int):
     return jnp.maximum(qi * block - (window - 1), 0) // block
 
 
-def _band_maps(n: int, block: int, window: int):
-    """``_tri_maps`` with a lower bound: for each query row qi the key columns
+def _band_cells(n: int, block: int, window: int):
+    """``_tri_cells`` with a lower bound: for each query row qi the key columns
     from the first one any of its queries still sees (``_band_first``) to qi.
     Cells wholly under the band are no part of the grid: no DMA, no compute."""
     import numpy as np
@@ -156,7 +156,13 @@ def _band_maps(n: int, block: int, window: int):
     qs = np.repeat(np.arange(n), counts)
     starts = np.repeat(np.cumsum(counts) - counts, counts)
     ks = np.arange(qs.size) - starts + np.repeat(first, counts)
-    return jnp.asarray(qs, jnp.int32), jnp.asarray(ks, jnp.int32)
+    return qs.astype(np.int32), ks.astype(np.int32)
+
+
+def _band_maps(n: int, block: int, window: int):
+    """``_band_cells`` as the kernel's scalar operands."""
+    qs, ks = _band_cells(n, block, window)
+    return jnp.asarray(qs), jnp.asarray(ks)
 
 
 def _row_of(col):
@@ -183,7 +189,7 @@ def _squash_ok(nq: int, nk: int, block_q: int, block_k: int, causal: bool) -> bo
             and nq * (nq + 1) // 2 <= _MAX_SQUASHED_CELLS)
 
 
-def _tri_maps(n: int):
+def _tri_cells(n: int):
     """Row-major lower-triangle enumeration: for each query row qi, the active
     key columns ki in [0, qi]. The causal grid runs ONLY these n(n+1)/2 cells
     (vs n^2): above-diagonal cells would DMA K/V and then skip all compute.
@@ -194,7 +200,13 @@ def _tri_maps(n: int):
     qs = np.repeat(np.arange(n), counts)
     starts = np.repeat(np.cumsum(counts) - counts, counts)
     ks = np.arange(qs.size) - starts
-    return jnp.asarray(qs, jnp.int32), jnp.asarray(ks, jnp.int32)
+    return qs.astype(np.int32), ks.astype(np.int32)
+
+
+def _tri_maps(n: int):
+    """``_tri_cells`` as the kernel's scalar operands."""
+    qs, ks = _tri_cells(n)
+    return jnp.asarray(qs), jnp.asarray(ks)
 
 
 def _wedge_maps(n: int):
@@ -212,9 +224,57 @@ def _wedge_maps(n: int):
 # Grid-argument decoders: every BlockSpec index map below is written against
 # canonical (b, h, qi, ki) and composed with the decoder for the grid in use,
 # so the squashed (scalar-prefetch) and dense variants share one spec list.
-_DEC_SQUASHED = lambda b, h, t, qm, km: (b, h, qm[t], km[t])  # noqa: E731
+_DEC_SQUASHED = lambda b, h, t, qm, km, *_: (b, h, qm[t], km[t])  # noqa: E731
 _DEC_DENSE = lambda b, h, qi, ki: (b, h, qi, ki)  # noqa: E731
 _DEC_DENSE_KQ = lambda b, h, ki, qi: (b, h, qi, ki)  # noqa: E731  (dkv grid order)
+
+
+def _live_blocks(lengths, block: int):
+    """The query blocks of each row that hold a live token, where a row's live tokens come first."""
+    return (lengths + block - 1) // block
+
+
+def forward_cells(lengths, S: int, window: Optional[int] = None, block: int = DEFAULT_BLOCK_Q):
+    """(live, grid): the cells a head that ``flash_causal_attention(lengths=)`` runs over rows of ``S`` tokens
+    with these live lengths, and the cells of the rows' whole grids (what it ran before it was told), by the
+    kernels' own enumerations; NumPy alone, on the host, for a counter."""
+    import numpy as np
+
+    block = min(block, max(S, 8))
+    n = _cdiv(S, block)
+    qm = (_tri_cells(n) if window is None else _band_cells(n, block, window))[0]
+    live = _live_blocks(np.asarray(lengths), block)
+    return int((qm[None, :] < live[:, None]).sum()), int(qm.size * live.size)
+
+
+def _live_grid(lengths, qm, km, nq: int, block: int):
+    """A squashed forward's grid for rows whose live ``lengths`` it is told ->
+    (its third extent, a traced scalar; its five scalar-prefetch operands).
+
+    Both enumerations are row-major by query block, so the cells of the
+    LONGEST row's live blocks are a prefix of ``(qm, km)``: the grid runs them
+    and then ONE step for each query block past them (the tail: ``ki = qi``,
+    so it is its block's first step and its last), which writes the block as
+    zeros. ``qo, ko`` is that enumeration, what a step writes. What a step
+    FETCHES (q, k, v) is row by row ``qf, kf`` (``[B * cells]``): the same,
+    clamped to the row's last live cell (the diagonal one of its last live
+    block), so a run of dead steps fetches nothing after its first. ``rows``
+    is each row's live blocks, then each row's live tokens. All of it is made
+    here, once a call, so that an index map is one read of SMEM as it was."""
+    live = _live_blocks(lengths, block)
+    longest = jnp.max(live)
+    cells = jnp.sum(qm < longest)
+    t = jnp.arange(qm.shape[0])
+    qo = jnp.where(t >= cells, jnp.minimum(longest + t - cells, nq - 1), qm)
+    ko = jnp.where(t >= cells, qo, km)
+    last = jnp.maximum(live - 1, 0)[:, None]
+    return cells + nq - longest, (qo, ko, jnp.minimum(qo, last).reshape(-1), jnp.minimum(ko, last).reshape(-1),
+                                  jnp.concatenate([live, lengths]))
+
+
+# ``_DEC_SQUASHED`` for what a step of ``_live_grid`` fetches
+_DEC_LIVE_FETCH = lambda b, h, t, qo, ko, qf, kf, rows: (  # noqa: E731
+    b, h, qf[b * qo.shape[0] + t], kf[b * qo.shape[0] + t])
 
 
 def _spec(shape, f, dec):
@@ -303,14 +363,23 @@ def _sub_score(q, k, mask_ref, slopes_ref, qi, ki, off, c, *, block_q, block_k,
 
 
 def _fwd_kernel(*refs, block_q, block_k, causal, masked, squashed, alibi=False,
-                k_splits=1, window=None):
+                k_splits=1, window=None, lengths=False):
+    alive = None  # with ``lengths``: whether the step's query block holds a live token of its row
     if squashed:
-        (qm_ref, km_ref, mask_ref, *rest) = refs
+        (qm_ref, km_ref, *rest) = refs
+        if lengths:  # (``_live_grid``: what a step fetches is the index maps' alone)
+            _, _, rows_ref, *rest = rest
+        mask_ref = rest.pop(0)
         slopes_ref = rest.pop(0) if alibi else None
         (q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref) = rest
         t = pl.program_id(2)
         qi, ki = qm_ref[t], km_ref[t]
         first, last = ki == (0 if window is None else _band_first(qi, block_q, window)), ki == qi
+        if lengths:
+            b = pl.program_id(0)
+            alive = qi < rows_ref[b]
+            fed = rows_ref[rows_ref.shape[0] // 2 + b] - qi * block_q  # the block's live rows come first: this many
+            first |= last & ~alive  # (a step of the tail is its block's first and last)
     else:
         (mask_ref, *rest) = refs
         slopes_ref = rest.pop(0) if alibi else None
@@ -360,19 +429,24 @@ def _fwd_kernel(*refs, block_q, block_k, causal, masked, squashed, alibi=False,
                 preferred_element_type=jnp.float32,
             )
 
+    def live(cell):
+        """A dead cell (``lengths``) computes nothing: ``_init`` and ``_finalize`` alone run for its query
+        block, which is written as zeros (``acc`` 0 over ``l_safe`` 1) with an lse of -inf."""
+        return cell if alive is None else cell & alive
+
     if window is not None:
         # the grid enumerates the band's cells alone (``_band_maps``): the diagonal
         # cell masks causally, a cell that reaches under the band masks its lower
         # edge (one cell in eight where the window is eight blocks), the rest nothing
         edge = qi * block_q + block_q - 1 - ki * block_k >= window
-        pl.when((ki < qi) & ~edge)(lambda: _compute(False))
-        pl.when((ki == qi) & ~edge)(lambda: _compute(True))
-        pl.when((ki < qi) & edge)(lambda: _compute(False, window))
-        pl.when((ki == qi) & edge)(lambda: _compute(True, window))
+        pl.when(live((ki < qi) & ~edge))(lambda: _compute(False))
+        pl.when(live((ki == qi) & ~edge))(lambda: _compute(True))
+        pl.when(live((ki < qi) & edge))(lambda: _compute(False, window))
+        pl.when(live((ki == qi) & edge))(lambda: _compute(True, window))
     elif causal and squashed:
         # the grid enumerates only ki <= qi; the diagonal cell masks in-block
-        pl.when(ki < qi)(lambda: _compute(False))
-        pl.when(ki == qi)(lambda: _compute(True))
+        pl.when(live(ki < qi))(lambda: _compute(False))
+        pl.when(live(ki == qi))(lambda: _compute(True))
     elif causal:
         full_below, diag = _block_classes(qi, ki, block_q, block_k)
         pl.when(full_below)(lambda: _compute(False))
@@ -383,8 +457,11 @@ def _fwd_kernel(*refs, block_q, block_k, causal, masked, squashed, alibi=False,
     @pl.when(last)
     def _finalize():
         l = jnp.max(l_ref[:], axis=-1, keepdims=True)
+        if alive is not None:  # the pads beside a row's last token: zeros, as the dead blocks after them
+            l = jnp.where(jax.lax.broadcasted_iota(jnp.int32, l.shape, 0) < fed, l, 0.0)
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+        out = acc_ref[:] / l_safe
+        o_ref[0, 0] = (out if alive is None else jnp.where(l == 0.0, 0.0, out)).astype(o_ref.dtype)
         m = jnp.max(m_ref[:], axis=-1, keepdims=True)
         # base-2 logsumexp per row; fully-masked rows get -inf. The column
         # becomes a lane-dense row here, once a query block.
@@ -396,11 +473,30 @@ _PARALLEL_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
 
 
 def _flash_fwd(q, k, v, mask, slopes, block_q: int, block_k: int, causal: bool,
-               masked: bool, alibi: bool, k_splits: int = 1, window: Optional[int] = None):
+               masked: bool, alibi: bool, k_splits: int = 1, window: Optional[int] = None, lengths=None):
     """q,k,v: [B, H(q/kv), S, D] (q pre-scaled). mask: [B, S] int32.
     slopes: [H, 1, _LANES] fp32 (log2e-scaled; ignored unless alibi).
     Returns (out, lse): lse fp32 ``[B, H, 1, S]``, base 2. ``window`` (a band
-    under the causal mask, ``_band_maps``) names the kernel ``swa_flash_fwd``."""
+    under the causal mask, ``_band_maps``) names the kernel ``swa_flash_fwd``.
+
+    ``lengths`` (int32 ``[B]``): a row's live tokens, which come FIRST in the
+    row: a fresh prompt padded to its bucket. That says more than ``mask``
+    does, which may have holes and only ever masks elements: every row at or
+    past ``lengths[b]`` is a pad whose output nobody reads, and under the
+    causal mask no live query sees a pad's key. The pads come back as zeros
+    with an lse of -inf (not whatever the buffer held: a NaN in a pad's value
+    would reach live rows through the next layer's ``p @ v`` at weight 0), and
+    on the squashed grids the kernel neither fetches nor computes a cell whose
+    query block lies wholly past ``lengths[b]`` (``_live_grid``): the grid's
+    third extent is a traced scalar, the longest row's live cells and one step
+    for each query block past them, a dead step's q, k and v maps point at the
+    row's last live cell, and the rows' lengths ride beside the maps as scalar
+    operands. A shorter row's dead cells run ``_init`` and ``_finalize``
+    alone. The block that holds a row's last token and pads beside it runs
+    whole. The choice is the kernel's from its operands: ``lengths=None``
+    builds the kernel as it was; the dense grid, and a call whose rows' maps
+    would pass ``_MAX_SQUASHED_CELLS`` entries of SMEM, compute the pads'
+    cells as ever and zero their rows after."""
     B, H, S, D = q.shape
     Hkv = k.shape[1]
     G = H // Hkv
@@ -420,23 +516,30 @@ def _flash_fwd(q, k, v, mask, slopes, block_q: int, block_k: int, causal: bool,
         pltpu.VMEM((block_q, _LANES), jnp.float32),
         pltpu.VMEM((block_q, _LANES), jnp.float32),
     ]
+    if lengths is not None:
+        lengths = jnp.clip(lengths.astype(jnp.int32), 0, S)
+    cells = nq * (nq + 1) // 2  # (the band's are fewer)
+    skips = squashed and lengths is not None and B * cells <= _MAX_SQUASHED_CELLS
     kernel = functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k,
                                causal=causal, masked=masked, squashed=squashed,
-                               alibi=alibi, k_splits=k_splits, window=window)
+                               alibi=alibi, k_splits=k_splits, window=window, lengths=skips)
     dec = _DEC_SQUASHED if squashed else _DEC_DENSE
-    in_specs = _qkv_in_specs(dec, block_q, block_k, D, G, alibi=alibi)
+    in_specs = _qkv_in_specs(_DEC_LIVE_FETCH if skips else dec, block_q, block_k, D, G, alibi=alibi)
     qrow = _qrow_specs(dec, block_q, D)
     out_specs = [qrow["qD"], qrow["qL"]]
     extra = (slopes,) if alibi else ()
 
     if squashed:
         qm, km = _tri_maps(nq) if window is None else _band_maps(nq, block_q, window)
+        prefetch, cells = (qm, km), qm.shape[0]
+        if skips:
+            cells, prefetch = _live_grid(lengths, qm, km, nq, block_q)
         out, lse = pl.pallas_call(
             kernel,
             name="flash_fwd" if window is None else "swa_flash_fwd",
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,  # qmap, kmap
-                grid=(B, H, qm.shape[0]),
+                num_scalar_prefetch=len(prefetch),  # qmap, kmap[, what a step fetches, the rows' lengths]
+                grid=(B, H, cells),
                 in_specs=in_specs,
                 out_specs=out_specs,
                 scratch_shapes=scratch_shapes,
@@ -445,20 +548,23 @@ def _flash_fwd(q, k, v, mask, slopes, block_q: int, block_k: int, causal: bool,
             compiler_params=tpu_compiler_params(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_interpret(),
-        )(qm, km, mask, *extra, q, k, v)
-        return out, lse
-
-    out, lse = pl.pallas_call(
-        kernel,
-        name="flash_fwd",
-        grid=(B, H, nq, nk),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch_shapes,
-        compiler_params=tpu_compiler_params(dimension_semantics=_PARALLEL_SEMANTICS),
-        interpret=_interpret(),
-    )(mask, *extra, q, k, v)
+        )(*prefetch, mask, *extra, q, k, v)
+    else:
+        out, lse = pl.pallas_call(
+            kernel,
+            name="flash_fwd",
+            grid=(B, H, nq, nk),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch_shapes,
+            compiler_params=tpu_compiler_params(dimension_semantics=_PARALLEL_SEMANTICS),
+            interpret=_interpret(),
+        )(mask, *extra, q, k, v)
+    if lengths is not None and not skips:
+        fed = jnp.arange(S) < lengths[:, None]
+        out = jnp.where(fed[:, None, :, None], out, jnp.zeros((), out.dtype))
+        lse = jnp.where(fed[:, None, None, :], lse, _NEG_INF)
     return out, lse
 
 
@@ -803,27 +909,33 @@ _flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_banded_forward(q, k, v, window: int, block: int, softmax_scale: Optional[float] = None):
+def flash_banded_forward(q, k, v, window: Optional[int], block: int, softmax_scale: Optional[float] = None,
+                         lengths=None):
     """Causal attention under a band of ``window`` keys (``ops/attention.py::
     band_keep``), the FORWARD alone: q ``[B, S, H, D]``, k, v ``[B, S, Hkv, D]``,
     ``S`` whole blocks of ``block``. The kernel is ``_fwd_kernel`` on a grid of
-    the band's cells (``_band_maps``), named ``swa_flash_fwd``."""
+    the band's cells (``_band_maps``), named ``swa_flash_fwd``. With ``lengths``
+    (int32 ``[B]``: each row's live tokens, which come first in it; ``_flash_fwd``)
+    no cell past a row's last token runs and the pads' rows are zeros; with
+    ``lengths`` and no ``window`` the grid is the causal triangle's, ``flash_fwd``."""
     scale = _scale(q.shape[-1], softmax_scale) * _LOG2E
     B, S, H, _ = q.shape
     out, _ = _flash_fwd((q * jnp.asarray(scale, q.dtype)).transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                         v.transpose(0, 2, 1, 3), jnp.ones((B, 1, S), jnp.int32),
-                        jnp.zeros((H, 1, _LANES), jnp.float32), block, block, True, False, False, 1, window)
+                        jnp.zeros((H, 1, _LANES), jnp.float32), block, block, True, False, False, 1, window, lengths)
     return out.transpose(0, 2, 1, 3)
 
 
-def _banded_fwd(q, k, v, window, block, softmax_scale):
-    return flash_banded_forward(q, k, v, window, block, softmax_scale), None
+def _banded_fwd(q, k, v, window, block, softmax_scale, lengths=None):
+    return flash_banded_forward(q, k, v, window, block, softmax_scale, lengths), lengths is not None
 
 
-def _banded_bwd(window, block, softmax_scale, res, g):
+def _banded_bwd(window, block, softmax_scale, with_lengths, g):
     raise NotImplementedError(
-        f"flash attention under a band (window={window}) has a forward alone: no backward kernel skips the "
-        "cells under the band yet; train a sliding layer with attn_impl='xla'")
+        "flash attention " + " and ".join([f"under a band (window={window})"] * (window is not None)
+                                          + ["over rows' live lengths (lengths=)"] * with_lengths)
+        + " has a forward alone: no backward kernel skips the cells under the band or past a row's last token yet; "
+        "train a sliding layer with attn_impl='xla', and a padded batch with a mask")
 
 
 flash_banded_forward.defvjp(_banded_fwd, _banded_bwd)
@@ -841,18 +953,20 @@ def flash_causal_attention(
     k_splits: int = 1,
     softmax_scale: Optional[float] = None,  # None: D^-0.5
     window: Optional[int] = None,  # a band under the causal mask: the forward alone (``flash_banded_forward``)
+    lengths: Optional[jax.Array] = None,  # [B] int32: the rows' live tokens, which come first: the forward alone
 ) -> jax.Array:
     B, S, H, D = q.shape
     block_q = min(block_q, max(S, 8))
     block_k = min(block_k, max(S, 8))
-    if window is not None:
+    if window is not None or lengths is not None:
         if mask is not None or alibi_slopes is not None:
-            raise NotImplementedError("flash attention under a band takes no padding mask and no ALiBi slopes")
+            raise NotImplementedError(
+                "flash attention under a band or over rows' live lengths takes no padding mask and no ALiBi slopes")
         block = min(block_q, block_k)
         pad = _cdiv(S, block) * block - S  # padded keys reach padded queries alone (module header)
         if pad:
             q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v))
-        return flash_banded_forward(q, k, v, window, block, softmax_scale)[:, :S]
+        return flash_banded_forward(q, k, v, window, block, softmax_scale, lengths)[:, :S]
     # k_splits > 1 processes each block_k tile as k_splits sub-chunks with the
     # next sub-chunk's QK^T hoisted ahead of the previous one's softmax, so the
     # MXU matmul can overlap the VPU exp2/renormalize passes. Pure

@@ -395,3 +395,102 @@ def test_wave_parts_counts_a_wave_s_held_pairs_and_touched_experts_and_leaves_it
         assert float(said["touched"]) >= k
     else:
         assert 0 < int(said["real_pairs"]) < sum(LENGTHS) * k * layers
+
+
+def blocks_of_8(monkeypatch):
+    """The flash forward at blocks of 8 behind the registry's name, so that a toy bucket has blocks past a prompt."""
+    import functools
+
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setitem(registry._REGISTRY["causal_attention"], "pallas",
+                        functools.partial(fa.flash_causal_attention, block_q=8, block_k=8))
+
+
+@pytest.mark.parametrize("impl", ["xla", "flash"])
+@pytest.mark.parametrize("length", [48, 41, 8], ids=lambda n: f"prompt-{n}")
+def test_a_padded_prompt_s_logits_and_pages_are_the_unpadded_prompt_s(toy, impl, length, monkeypatch):
+    """A fresh prompt through ``_windowed_attention`` in a bucket of 112 (pads after it, whole blocks of them
+    that the flash forward, told ``new_lens``, neither fetches nor computes) against the same prompt in the
+    tightest bucket that holds it: the same logits, and the same pages in both classes (a pad writes none)."""
+    import dataclasses
+
+    _, cfg, params = toy
+    if impl == "flash":
+        blocks_of_8(monkeypatch)
+    cfg = dataclasses.replace(cfg, attn_impl=impl)
+    prompt = sequences(64, seed=5)[0, :length]
+    tight = InferenceEngineV2(cfg, params, dict(ENGINE, chunk_bucket=8))
+    loose = InferenceEngineV2(cfg, params, dict(ENGINE, chunk_bucket=112))
+    got, want = loose.put([3], [prompt]), tight.put([3], [prompt])
+    assert rel(got, want) < 1e-5
+    assert np.array_equal(loose.state.get(3).blocks, tight.state.get(3).blocks)
+    for a, b in zip(jax.tree_util.tree_leaves(loose._pools), jax.tree_util.tree_leaves(tight._pools)):
+        assert float(jnp.abs(a - b).max()) < 1e-5 * max(float(jnp.abs(b).max()), 1.0)
+
+
+def test_a_prefill_s_span_counts_the_flash_forward_s_live_cells_by_the_kernels_own_maps(toy, monkeypatch):
+    """``flash_cells_live`` / ``flash_cells_grid`` on a call of fresh prompts' ``serve:dispatch`` span: summed
+    over the call's rows and the pattern's layers; absent where the chunks' attention is not the flash forward."""
+    import dataclasses
+
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    from deepspeed_tpu.telemetry import get_tracer
+
+    _, cfg, params = toy
+    seqs = sequences(64, seed=7)
+    tracer = get_tracer()
+    tracer.configure(enabled=True)
+    tracer.reset()
+    try:
+        said = {}
+        for impl in ("xla", "flash"):
+            eng = InferenceEngineV2(dataclasses.replace(cfg, attn_impl=impl), params, dict(ENGINE, chunk_bucket=112))
+            eng.put([1, 2], [seqs[0, :41], seqs[1, :8]])
+            said[impl] = [e["args"] for e in tracer.events() if e["kind"] == "span" and e["name"] == "serve:dispatch"]
+            tracer.reset()
+        # a bucket past the kernel's block of 512: rows of 700, 1,300 and no tokens in a bucket of 2,048
+        lengths, S = np.array([700, 1300, 0]), 2048
+        args = eng._flash_args(lengths, S)
+        assert eng._flash_args(lengths, 1) == {}  # (one token a row goes through the pages)
+    finally:
+        tracer.configure(enabled=False)
+        tracer.reset()
+    assert eng._flash_args(lengths, S) == {}  # nobody records: nothing is counted
+    assert all("flash_cells_live" not in a for a in said["xla"])
+    (call,) = said["flash"]
+    assert (cfg.sliding_layers, cfg.attention_layers) == (3, 1)
+    assert (call["flash_cells_live"], call["flash_cells_grid"]) == (2 * 4, 2 * 4)  # a bucket of 112 is one block a row
+    maps = {None: np.asarray(fa._tri_maps(4)[0]), WINDOW: np.asarray(fa._band_maps(4, 512, WINDOW)[0])}
+    live = {w: sum(int((qs * 512 < n).sum()) for n in lengths) for w, qs in maps.items()}
+    assert (live[None], live[WINDOW]) == (3 + 6, 3 + 5) and (len(maps[None]), len(maps[WINDOW])) == (10, 7)
+    assert args == {"flash_cells_live": live[None] + 3 * live[WINDOW], "flash_cells_grid": 3 * (10 + 3 * 7)}
+
+
+def test_swa_controls_prints_the_live_share_of_the_flash_forwards_cells(monkeypatch, capsys):
+    """``--control flash_cells``: one more metric on the traced line, from the spans' two counts; nothing
+    where no span of the window says them (the parent of PR 58, a window of chains)."""
+    import collections
+
+    from benchmarks.lib import spans
+
+    tool = swa_controls(monkeypatch)
+    Span = collections.namedtuple("Span", "name args")
+    window = (Span("serve:dispatch", {"kind": "prefill", "flash_cells_live": 1000, "flash_cells_grid": 1284}),
+              Span("serve:dispatch", {"kind": "chain", "ring_tokens": 7}),
+              Span("serve:fetch", {"kind": "prefill", "flash_cells_live": 5, "flash_cells_grid": 5}),
+              Span("serve:dispatch", {"kind": "prefill", "flash_cells_live": 284, "flash_cells_grid": 1284}))
+    monkeypatch.setattr(spans, "of_run", lambda run: window)
+    assert tool.flash_cells_live_share({}) == pytest.approx(50.0)
+    assert "flash_cells_calls=2 flash_cells_live=1284.0 flash_cells_grid=2568.0" in capsys.readouterr().out
+    monkeypatch.setattr(spans, "of_run", lambda run: window[1:3])
+    assert tool.flash_cells_live_share({}) is None
+    for name in ("cell_metrics", "load_reader"):
+        monkeypatch.setattr(harness, name, getattr(harness, name))
+    tool.PLANTS["flash_cells"]()
+    bench = harness.load_json(harness.BENCH_DIR + "/../BENCHMARK.json")
+    cell = "command-a-plus-05-2026.serve.long-prompt-wave8"
+    assert harness.cell_metrics(bench, "per_layer", cell)[-1] == {"name": "flash_cells_live_share.batch", "unit": "%"}
+    assert all(m["name"] != "flash_cells_live_share.batch" for m in harness.cell_metrics(bench, "end_to_end", cell))
+    assert harness.load_reader("flash_cells_live_share.batch")({}, None) is None

@@ -306,6 +306,11 @@ def test_flash_attention_partitions_over_four_chips(topo, monkeypatch):
         return causal_attention(q, k, v).astype(jnp.float32).sum()
 
     assert _compiled_kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) >= 2
+    # the forward told its rows' live lengths: they split as the batch does, two rows a chip (PR 58)
+    lengths = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=NamedSharding(mesh, P(("dp", "fsdp"))))
+    text = jax.jit(lambda q, k, v, n: causal_attention(q, k, v, lengths=n)).lower(x, x, x, lengths).compile().as_text()
+    (kernel,) = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert "flash_fwd" in kernel and "bf16[2,12,1024,64]" in kernel and "s32[4]{0}" in kernel  # (2 rows' blocks, tokens)
 
 
 def _paged_shapes(sh, N, C, kv_quant, H, kvH, hd, pages=64, bs=16, num_blocks=512, layers=12):
@@ -1159,24 +1164,32 @@ def test_glm_5_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, name
     assert not moved, moved
 
 
+@pytest.mark.parametrize("told", [False, True], ids=["bucket", "lengths"])
 @pytest.mark.parametrize("window,cells", [(4096, 252), (None, 528)], ids=["band-4096", "causal"])
-def test_the_banded_flash_forward_compiles_at_128_heads_over_8(one_chip, monkeypatch, window, cells):
+def test_the_banded_flash_forward_compiles_at_128_heads_over_8(one_chip, monkeypatch, window, cells, told):
     """``command-a-plus-05-2026.serve.long-prompt-wave8``'s prefill attention: a
     ``(1, 16384)`` prompt, 128 query heads over 8 key-value heads of 128 (GQA in
     the index maps), under a band of 4,096 keys (kernel ``swa_flash_fwd``, a
     grid of the band's 252 cells a head) and under the causal mask alone
-    (``flash_fwd``, the triangle's 528): ONE Mosaic kernel each."""
+    (``flash_fwd``, the triangle's 528): ONE Mosaic kernel each. Told the row's
+    live length (PR 58) the kernel has a traced third extent, its first
+    operand, and after the grid's enumeration what a step fetches (two more
+    maps) and the row's live blocks and tokens; not told, the operands it had."""
     from deepspeed_tpu.ops.pallas import flash_attention as fa
 
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     q = jax.ShapeDtypeStruct((1, 16384, 128, 128), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((1, 16384, 8, 128), jnp.bfloat16, sharding=one_chip)
+    lengths = [jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)] * told
     banded = {} if window is None else {"window": window}
-    text = jax.jit(lambda q, k, v: fa.flash_causal_attention(q, k, v, **banded)).lower(q, kv, kv).compile().as_text()
+    text = jax.jit(lambda q, k, v, *told: fa.flash_causal_attention(q, k, v, **banded, **dict(zip(("lengths",), told)))
+                   ).lower(q, kv, kv, *lengths).compile().as_text()
     (kernel,) = [line for line in text.splitlines() if "tpu_custom_call" in line]
     assert ("swa_flash_fwd" in kernel) == (window is not None)
     maps = fa._tri_maps(32) if window is None else fa._band_maps(32, 512, window)
     assert len(maps[0]) == cells and f"s32[{cells}]" in kernel  # the grid's enumeration is the kernel's operand
+    scalars = re.search(r"operand_layout_constraints=\{(.*?)bf16\[", kernel).group(1)
+    assert re.findall(r"s32\[(\d*)\]", scalars) == ([""] + [str(cells)] * 4 + ["2"] if told else [str(cells)] * 2)
 
 
 @pytest.mark.parametrize("columns,banded", [(257, True), (1032, False)], ids=["ring-257", "global-1032"])
@@ -1208,7 +1221,7 @@ def test_command_a_plus_programs_compile_at_the_cell_s_shapes(one_chip, monkeypa
     a full layer and a ring of 8 x 257 a sliding layer, a block table of 1,032
     + 257 columns), with the picks handed out as the timed path hands them:
     the ``(1, 16384)`` prefill (``swa_flash_fwd`` three times and ``flash_fwd``
-    once in the period's body, each beside the one-token rows' paged kernels;
+    once in the period's body, each told the row's live length, ``new_lens``;
     the share's sorted dispatch through megablox ``gmm``) and the chain of 8
     steps at 8 rows (``swa_paged_attn`` x 3, ``paged_attn`` x 1, ``moe_decode``
     x 4). Each fits the chip beside the weights and both pools, returns both
@@ -1281,6 +1294,16 @@ def test_command_a_plus_programs_compile_at_the_cell_s_shapes(one_chip, monkeypa
     else:  # (a call of fresh prompts attends inside its chunks alone: no paged kernel, no one-token path)
         assert (count("swa_flash_fwd"), count("flash_fwd") - count("swa_flash_fwd")) == (3, 1) and count("gmm") >= 3
         assert not count("paged_attn")
+        # PR 58: the four flash forwards are told ``new_lens``: a traced extent, then the grid's enumeration, what
+        # a step fetches and the row's live blocks and tokens (``_live_grid``), under the names they had; the
+        # program stands where the parent's stood (14.5865 GiB) and makes no layout copy of q, k, v or the
+        # attention output that the parent did not make
+        flash = [line for line in text.splitlines() if "tpu_custom_call" in line and "flash_fwd" in line]
+        assert len(flash) == 4 and all(re.search(r"constraints=\{s32\[\], (?:s32\[(252|528)\]\{0\}, ){4}s32\[2\]\{0\}, ", line)
+                                       and len(set(re.findall(r"s32\[(252|528)\]", line))) == 1 for line in flash)
+        assert peak_gib < 14.59
+        made = lambda shape, ops: len(re.findall(r"= bf16\[%s\]\S* (?:%s)\(" % (shape, ops), text))  # noqa: E731
+        assert made("1,(?:128,16384|16384,128),128", "copy|fusion") <= 7 and made("1,(?:8,16384|16384,8),128", "copy") <= 3
     assert all("/swa/" in name for name in calls if "/swa_" in name)  # the new kernels under the new scope
     moved = [line.strip()[:200] for line in text.splitlines()
              if re.search(r"= \(?bf16\[(10216|6168),16,1024\]\S* (copy|copy-start|transpose)\(", line)

@@ -724,3 +724,56 @@ class TestFlashAlibi:
         keep = np.asarray(mask, bool)
         np.testing.assert_allclose(np.asarray(out)[keep], np.asarray(ref)[keep],
                                    atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("lengths", [(0,), (1,), (15,), (16,), (17,), (37,), (64,), (37, 64), (16, 0), (1, 50), (48, 17)],
+                         ids=lambda a: "-".join(map(str, a)))
+def test_the_forward_told_its_rows_lengths_is_the_kernel_s_own_on_live_rows_and_zeros_on_pads(lengths):
+    """``_flash_fwd(lengths=)`` under the causal mask alone (``tests/unit/ops/test_swa.py`` has the band's cases
+    and the helper): blocks of 16 in rows of 64, GQA 4:1, one row and two rows of different lengths."""
+    from tests.unit.ops.test_swa import live_rows_are_the_kernel_s_own_and_pads_are_zeros
+
+    live_rows_are_the_kernel_s_own_and_pads_are_zeros(lengths, window=None)
+
+
+@pytest.mark.parametrize("lengths", [(1,), (16,), (37,), (48, 17)], ids=lambda a: "-".join(map(str, a)))
+def test_nan_in_the_blocks_past_a_row_s_last_token_is_never_read(lengths):
+    from tests.unit.ops.test_swa import live_rows_are_the_kernel_s_own_and_pads_are_zeros
+
+    live_rows_are_the_kernel_s_own_and_pads_are_zeros(lengths, window=None, plant=jnp.nan)
+
+
+def test_the_dense_grid_told_its_rows_lengths_zeroes_the_pads_after():
+    """Past ``_MAX_SQUASHED_CELLS`` the dense grid runs: it computes the pads' cells as ever and their rows are
+    zeroed outside the kernel, so a caller reads the same thing from either grid."""
+    from unittest import mock
+
+    from tests.unit.ops.test_swa import flash_fwd_told
+
+    with mock.patch.object(fa, "_MAX_SQUASHED_CELLS", 0):
+        (out, lse), (whole, whole_lse) = flash_fwd_told((37, 16), None)
+    for b, n in enumerate((37, 16)):
+        assert np.array_equal(out[b, :, :n], whole[b, :, :n]) and np.array_equal(lse[b, :, :, :n], whole_lse[b, :, :, :n])
+        assert not np.asarray(out[b, :, n:]).any() and (np.asarray(lse[b, :, :, n:]) == fa._NEG_INF).all()
+
+
+@pytest.mark.parametrize("window", [None, 24], ids=["flash_fwd", "swa_flash_fwd"])
+def test_flash_kernel_bench_times_the_prefill_s_two_forms(window):
+    """``tools/flash_kernel_bench.py``'s ``prefill`` reading at a toy shape in interpret mode: both forms run, the
+    live form's live rows are the parent form's with the pads zeros, and the cells are the kernels' own count."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "..", "tools", "flash_kernel_bench.py")
+    spec = importlib.util.spec_from_file_location("flash_kernel_bench", path)
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    toy = dict(shape=(64, 4, 1, 16), block=16, calls=2, repeats=1, device_kind="TPU v5 lite")
+    parent = bench.measure_prefill(window, (64,), "parent", **toy)
+    live = bench.measure_prefill(window, (64, 33), "live", **toy)
+    cells = 10 if window is None else 9  # of a row of four blocks: the triangle's 1 + 2 + 3 + 4, the band's 1 + 2 + 3 + 3
+    assert (parent["kernel"], parent["cells_live"], parent["cells_grid"]) == (live["kernel"], cells, cells)
+    assert (live["cells_live"], live["cells_grid"]) == (cells + 6, 2 * cells)  # 33 tokens: three blocks, 1 + 2 + 3
+    assert live["live_rows_equal"] and live["pads_zero"] and parent["live_rows_equal"]
+    assert live["roofline_pct"] == pytest.approx(100.0 * live["least_ms"] / live["ms_per_call"])
+    assert bench.PREFILL_SHAPE == (16384, 128, 8, 128) and bench.PREFILL_LENGTHS == (8192, 12288, 16384)

@@ -174,3 +174,82 @@ def test_the_fallback_takes_a_wholly_dead_page_of_nan():
     want = _xla_paged_attention(q, jnp.asarray(pk), jnp.nan_to_num(jnp.asarray(pv)), table[:, 1:],
                                 jnp.asarray([[12]], jnp.int32), bs)
     assert float(jnp.abs(got - want).max()) < 2e-6
+
+
+# --- a fresh prompt padded to its bucket: the forward told its rows' live lengths (``_flash_fwd(lengths=)``) ---
+
+LIVE_S, LIVE_BLOCK = 64, 16
+LIVE_LENGTHS = [(0,), (1,), (LIVE_BLOCK - 1,), (LIVE_BLOCK,), (LIVE_BLOCK + 1,), (37,), (LIVE_S,),
+                (37, LIVE_S), (LIVE_BLOCK, 0), (1, 50), (48, 17)]
+
+
+def flash_fwd_told(lengths, window, plant=None, S=LIVE_S, block=LIVE_BLOCK):
+    """``_flash_fwd`` at GQA 4:1 on ``len(lengths)`` rows of ``S`` -> ((out, lse) told the lengths, (out, lse) of
+    the same kernel not told); ``plant`` is written over q, k and v in every block that holds no live token."""
+    q, k, v = (a.transpose(0, 2, 1, 3) for a in qkv(S, B=len(lengths), seed=len(lengths)))
+    told = jnp.asarray(lengths, jnp.int32)
+    mask, slopes = jnp.ones((len(lengths), 1, S), jnp.int32), jnp.zeros((q.shape[1], 1, fa._LANES), jnp.float32)
+    whole = fa._flash_fwd(q, k, v, mask, slopes, block, block, True, False, False, 1, window)
+    if plant is not None:
+        dead = (jnp.arange(S) >= -(-told // block)[:, None] * block)[:, None, :, None]
+        q, k, v = (jnp.where(dead, plant, a) for a in (q, k, v))
+    return fa._flash_fwd(q, k, v, mask, slopes, block, block, True, False, False, 1, window, lengths=told), whole
+
+
+def live_rows_are_the_kernel_s_own_and_pads_are_zeros(lengths, window, plant=None):
+    (out, lse), (whole, whole_lse) = flash_fwd_told(lengths, window, plant)
+    for b, n in enumerate(lengths):
+        assert np.array_equal(out[b, :, :n], whole[b, :, :n]) and np.array_equal(lse[b, :, :, :n], whole_lse[b, :, :, :n])
+        assert not np.asarray(out[b, :, n:]).any() and (np.asarray(lse[b, :, :, n:]) == fa._NEG_INF).all()
+    assert np.isfinite(np.asarray(out)).all()
+
+
+@pytest.mark.parametrize("lengths", LIVE_LENGTHS, ids=lambda a: "-".join(map(str, a)))
+def test_under_the_band_a_row_told_its_length_is_the_kernel_s_own_and_its_pads_are_zeros(lengths):
+    live_rows_are_the_kernel_s_own_and_pads_are_zeros(lengths, window=24)
+
+
+@pytest.mark.parametrize("lengths", [(1,), (LIVE_BLOCK,), (37,), (48, 17)], ids=lambda a: "-".join(map(str, a)))
+def test_under_the_band_nan_in_the_blocks_past_a_row_s_last_token_is_never_read(lengths):
+    """What a dead block of q, k and v holds is neither fetched nor computed (the pads BESIDE a row's last token,
+    in its block, are: they have to be finite, as a pad token's projections are)."""
+    live_rows_are_the_kernel_s_own_and_pads_are_zeros(lengths, window=24, plant=jnp.nan)
+
+
+@pytest.mark.parametrize("window", [None, 24], ids=["causal", "band"])
+def test_every_implementation_hands_back_zeros_for_a_pad_and_the_kernel_has_no_backward(window):
+    q, k, v = qkv(LIVE_S)
+    lengths = jnp.asarray([37, LIVE_BLOCK], jnp.int32)
+    dense = causal_attention(q, k, v, window=window, lengths=lengths, impl="xla")
+    flash = causal_attention(q, k, v, window=window, lengths=lengths, impl="pallas", block_q=LIVE_BLOCK, block_k=LIVE_BLOCK)
+    assert float(jnp.abs(flash - dense).max()) < 2e-6
+    whole = _xla_causal_attention(q, k, v, window=window)
+    for b, n in enumerate((37, LIVE_BLOCK)):
+        assert float(jnp.abs(dense[b, :n] - whole[b, :n]).max()) == 0.0
+        assert not np.asarray(dense[b, n:]).any() and not np.asarray(flash[b, n:]).any()
+    with pytest.raises(NotImplementedError, match=r"lengths=.*forward alone"):
+        jax.grad(lambda q: causal_attention(q, k, v, window=window, lengths=lengths, impl="pallas",
+                                            block_q=LIVE_BLOCK, block_k=LIVE_BLOCK).sum())(q)
+    with pytest.raises(NotImplementedError, match="no padding mask"):
+        fa.flash_causal_attention(q, k, v, mask=jnp.ones((2, LIVE_S), jnp.int32), lengths=lengths)
+    # the dense path is differentiable, and a pad's row takes no gradient
+    grad = jax.grad(lambda q: _xla_causal_attention(q, k, v, window=window, lengths=lengths).sum())(q)
+    assert jnp.isfinite(grad).all() and not np.asarray(grad[1, LIVE_BLOCK:]).any()
+
+
+@pytest.mark.parametrize("S,window,block", [(16384, 4096, 512), (16384, None, 512), (64, 24, 16), (100, None, 8)])
+def test_the_cells_a_length_leaves_are_a_prefix_of_the_grid_s_enumeration(S, window, block, monkeypatch):
+    """``forward_cells`` (what the engine's span says) against the maps themselves, cell by cell; at the
+    benchmark cell's bucket the mean over its traffic's lengths is ISSUE 58's 323.0 of 528 and 184.5 of 252.
+    It counts in NumPy: a device array made between two dispatches would be a compile in a traced window."""
+    n = -(-S // block)
+    qs = np.asarray((fa._tri_maps(n) if window is None else fa._band_maps(n, block, window))[0])
+    monkeypatch.setattr(fa, "jnp", None)
+    assert (np.diff(qs) >= 0).all()  # row-major by query block: the cells of the first blocks come first
+    rng = np.random.default_rng(0)
+    for lengths in ([0], [1], [S], [block, block + 1], rng.integers(0, S + 1, 5).tolist()):
+        live = sum(sum(1 for q in qs.tolist() if q * block < length) for length in lengths)
+        assert fa.forward_cells(lengths, S, window, block) == (live, len(lengths) * len(qs))
+    if S == 16384:
+        mean = np.mean([fa.forward_cells([length], S, window)[0] for length in range(8192, 16385, 7)])
+        assert mean == pytest.approx(323.0 if window is None else 184.5, abs=0.6)

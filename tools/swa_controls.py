@@ -55,9 +55,17 @@ The reference is always the published model.
 - ``decode_roofline``: no fault: the cell's traced run with ``swa_decode_roofline``
   printed beside its listed metrics (pass ``--trace 1``), for the decode kernel's
   share where the window held a whole chain.
+- ``flash_cells``: no fault: the cell's traced run (pass ``--trace 1``) with
+  ``flash_cells_live_share.batch`` beside its listed metrics: of the cells of
+  the flash forwards' grids in the window's prefills (a head, summed over rows
+  and layers: ``flash_cells_grid`` on a prefill's ``serve:dispatch`` span), the
+  share that holds a live query and so runs since PR 58 (``flash_cells_live``);
+  100 says every prompt fills its bucket, and nothing is printed where no
+  span says them (a parent of PR 58, or a model with no such call).
 
 The last line is ``run.py``'s: for every control but ``band_as_mask``,
-``wave_parts`` and ``decode_roofline``, ``correct`` has to read false.
+``wave_parts``, ``decode_roofline`` and ``flash_cells``, ``correct`` has to
+read false.
 """
 
 import argparse
@@ -224,20 +232,38 @@ def plant_wave_parts():
     Engine._log_picks, Engine.generate = _log_picks, wave
 
 
-def plant_decode_roofline():
-    """(No file under ``benchmarks/metrics/``: a reader there has to be listed, ``tests/benchmarks/test_contract.py``.)"""
-    from benchmarks.lib import harness, swa
+def plant_unlisted(name: str, unit: str, reader):
+    """``reader(run)`` as one more per-layer metric of the cell's traced line.
+    (No file under ``benchmarks/metrics/``: a reader there has to be listed, ``tests/benchmarks/test_contract.py``.)"""
+    from benchmarks.lib import harness
 
-    name = "swa_decode_roofline.batch"
     wanted, readers = harness.cell_metrics, harness.load_reader
 
     def cell_metrics(bench, group, workload_name):
         out = wanted(bench, group, workload_name)
-        return out + [{"name": name, "unit": "%"}] if group == "per_layer" else out
+        return out + [{"name": name, "unit": unit}] if group == "per_layer" else out
 
     harness.cell_metrics = cell_metrics
     harness.load_reader = lambda metric, *args: (
-        (lambda run, trace: swa.decode_roofline(run)) if metric == name else readers(metric, *args))
+        (lambda run, trace: reader(run)) if metric == name else readers(metric, *args))
+
+
+def plant_decode_roofline():
+    from benchmarks.lib import swa
+
+    plant_unlisted("swa_decode_roofline.batch", "%", swa.decode_roofline)
+
+
+def flash_cells_live_share(run):
+    from benchmarks.lib import harness, spans
+
+    said = [(float(s.args["flash_cells_live"]), float(s.args["flash_cells_grid"]))
+            for s in spans.named(spans.of_run(run), "serve:dispatch") if "flash_cells_grid" in s.args]
+    live, grid = (sum(a[i] for a in said) for i in (0, 1))
+    if not grid:
+        return None
+    harness.say(flash_cells_calls=len(said), flash_cells_live=live, flash_cells_grid=grid)
+    return 100.0 * live / grid
 
 
 PLANTS = {
@@ -248,6 +274,7 @@ PLANTS = {
     "e4m3_ring": plant_e4m3_ring, "e4m3_swa_prefill": plant_e4m3_swa_prefill, "e4m3_shared": plant_e4m3_shared,
     "wave_parts": plant_wave_parts, "ranks_2_to_k1": plant_ranks_2_to_k1, "band_as_mask": plant_band_as_mask,
     "decode_roofline": plant_decode_roofline,
+    "flash_cells": lambda: plant_unlisted("flash_cells_live_share.batch", "%", flash_cells_live_share),
 }
 
 
