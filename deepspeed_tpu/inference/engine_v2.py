@@ -45,18 +45,15 @@ from pydantic import Field
 
 from deepspeed_tpu.config.config_utils import DeepSpeedConfigModel
 from deepspeed_tpu.diagnostics.anomaly import CallLog, CallRecord, StallRecord
-from deepspeed_tpu.inference.config import QuantConfig, ServingSLOConfig
+from deepspeed_tpu.inference.config import _DTYPES, QuantConfig, ServingSLOConfig
 from deepspeed_tpu.inference.lifecycle import LifecycleTracker
+from deepspeed_tpu.inference.cache import PagedKVPool, Pools, cache_plan
 from deepspeed_tpu.inference.paged import (
-    HybridPools,
-    RingPools,
     MigrationBuffer,
-    PagedKVPool,
     copy_pool_blocks,
     export_pool_blocks,
     fetch_pool_block,
     import_pool_blocks,
-    init_pool,
     ragged_decode_chain,
     ragged_forward,
     ragged_spec_decode_chain,
@@ -179,8 +176,6 @@ class RaggedInferenceConfig(DeepSpeedConfigModel):
 
     @property
     def jax_dtype(self):
-        from deepspeed_tpu.inference.config import _DTYPES
-
         return _DTYPES[self.dtype.lower()]
 
     @property
@@ -196,8 +191,6 @@ class RaggedInferenceConfig(DeepSpeedConfigModel):
         name = (self.kv_cache_dtype or "").lower()
         if name in ("int8", "fp8"):
             return name
-        from deepspeed_tpu.inference.config import _DTYPES
-
         if name and name not in _DTYPES:
             raise ValueError(
                 f"kv_cache_dtype must be a float dtype name or 'int8'|'fp8', "
@@ -207,8 +200,6 @@ class RaggedInferenceConfig(DeepSpeedConfigModel):
     @property
     def kv_jax_dtype(self):
         """Pool storage dtype when NOT block-quantized (default: compute)."""
-        from deepspeed_tpu.inference.config import _DTYPES
-
         if self.kv_quant is not None or not self.kv_cache_dtype:
             return self.jax_dtype
         return _DTYPES[self.kv_cache_dtype.lower()]
@@ -348,110 +339,36 @@ class InferenceEngineV2:
             mesh = build_mesh(axis_sizes=axes)
         self.mesh = mesh
         set_mesh(mesh)
-        if mesh.shape.get("ep", 1) > 1:
+        ep = mesh.shape.get("ep", 1)
+        if ep > 1:
             if model_config.num_experts <= 0:
-                raise ValueError(
-                    f"ep_size={mesh.shape['ep']} on a dense model: expert "
-                    "parallelism needs num_experts > 0")
-            if model_config.num_experts % mesh.shape["ep"]:
-                raise ValueError(
-                    f"num_experts={model_config.num_experts} not divisible "
-                    f"by ep_size={mesh.shape['ep']}")
-            log_dist(
-                f"expert-parallel serving: experts sharded over ep="
-                f"{mesh.shape['ep']}, MoE dispatch/combine through the "
-                "facade all_to_all", ranks=[0])
+                raise ValueError(f"ep_size={ep} on a dense model: expert parallelism needs num_experts > 0")
+            if model_config.num_experts % ep:
+                raise ValueError(f"num_experts={model_config.num_experts} not divisible by ep_size={ep}")
+            log_dist(f"expert-parallel serving: experts sharded over ep={ep}, MoE dispatch/combine through the "
+                     "facade all_to_all", ranks=[0])
 
         max_len = config.max_seq_len or model_config.max_seq_len
         self.max_seq_len = max_len
-        self.max_pages = -(-max_len // config.kv_block_size)
-        # EVA attention: a row's table holds summary pages and window pages of
-        # the one pool (ragged.WindowLayout), not a page a block of positions
-        self._layout = None
-        if model_config.eva_window:
-            from deepspeed_tpu.inference.ragged import WindowLayout
-
-            chunk, bs = model_config.eva_chunk, config.kv_block_size
-            missing = [
-                (chunk != bs, f"kv_block_size={bs}: a page of exact rows closes into ONE summary "
-                 f"row, which needs kv_block_size={chunk}, the model's chunk"),
-                (model_config.eva_window % (chunk * chunk) != 0, f"eva_window={model_config.eva_window}: "
-                 f"a closed window's summaries fill whole pages (a multiple of {chunk} x {chunk})"),
-                (config.spec_decode > 0, "spec_decode: drafts are a chunk of several tokens in the "
-                 "middle of a window, which the chunk path does not take"),
-                (config.kv_quant is not None, f"kv_cache_dtype={config.kv_dtype_name!r}: a summary row "
-                 "has no per-token scale"),
-                (config.prefix_cache, "prefix_cache: a page's content is not a function of a block "
-                 "of the prompt's tokens once windows close into summaries"),
-                (mesh.shape["tp"] > 1, f"tp={mesh.shape['tp']}: the pooling vectors and the "
-                 "closing are not partitioned over heads"),
-                (config.chunk_bucket % config.kv_block_size != 0,
-                 f"chunk_bucket={config.chunk_bucket}: a chunk is whole pages of {config.kv_block_size}"),
-            ]
-            missing = [what for bad, what in missing if bad]
-            if missing:
-                raise ValueError("EVA attention (eva_window > 0) does not serve with " + "; ".join(missing))
-            self._layout = WindowLayout(model_config.eva_window, config.kv_block_size, max_len)
-            self.max_pages = self._layout.width
+        # What this model keeps of a sequence (inference/cache.py): the classes of page a row holds, the
+        # row's table layout, the state slot, their bytes, and what the kind does not serve with
+        self.plan = plan = cache_plan(model_config, config.kv_block_size, max_len)
+        for refused in plan.refusals(config, mesh):
+            raise ValueError(refused)
+        self.max_pages = plan.max_pages
         self.windows_closed = 0  # EVA: windows pooled into summaries so far
-        # A sliding kind (TransformerConfig.sliding): a row's table is [global columns | ring columns],
-        # two classes of page in two arrays (paged.RingPools, ragged.RingLayout)
-        self._ring = None
         self.ring_pages_overwritten = 0  # ring pages a later block of the same row has been written over
-        if model_config.sliding is not None:
-            from deepspeed_tpu.inference.ragged import RingLayout
-
-            missing = [
-                (config.prefix_cache, "prefix_cache: a sliding layer keeps a prompt's LAST window alone, so a "
-                 "shared prefix's pages hold no sliding layer's keys for the suffix to read"),
-                (config.spec_decode > 0, "spec_decode: drafts are a chunk of several tokens past position 0, "
-                 "which would have to read the ring and the global pages (ROADMAP R3b)"),
-                (config.kv_quant is not None, f"kv_cache_dtype={config.kv_dtype_name!r}: the ring pool has no "
-                 "scale pages, and a fresh prompt's bulk write quantizes nothing"),
-                (mesh.shape["tp"] > 1, f"tp={mesh.shape['tp']}: the two classes of page and the ring's roll are "
-                 "not partitioned over heads"),
-                (config.chunk_bucket % config.kv_block_size != 0,
-                 f"chunk_bucket={config.chunk_bucket}: a fresh prompt writes whole pages of {config.kv_block_size}"),
-            ]
-            missing = [what for bad, what in missing if bad]
-            if missing:
-                raise ValueError("a sliding kind (sliding_attention layers) does not serve with " + "; ".join(missing))
-            self._ring = RingLayout(model_config.sliding.window, config.kv_block_size, max_len)
-            self.max_pages = self._ring.width
         if model_config.hc_mult and mesh.shape["tp"] > 1:
-            raise ValueError(
-                f"hyper-connections (hc_mult={model_config.hc_mult}) with tp={mesh.shape['tp']}: the mix "
-                "is a statistic and a product over the whole hidden width of every stream, which the "
-                "partition rules replicate and nothing has needed split yet")
-
-        # Recurrent state beside attention (a layer pattern with state-space
-        # layers): a sequence holds, beside its pages, ONE slot of a state pool
-        # (paged.StatePool) from its first token to its flush
-        self._hybrid = model_config.state_layers > 0
-        if self._hybrid:
-            missing = [
-                (config.prefix_cache, "prefix_cache: a prefix's pages without the recurrent state at its "
-                 "end are not a prefix, and no state is kept per block"),
-                (config.spec_decode > 0, "spec_decode: a rejected draft has already moved the state, and "
-                 "nothing rolls it back"),
-                (mesh.shape["tp"] > 1, f"tp={mesh.shape['tp']}: the state pool and the mixer's "
-                 "projections are not partitioned over heads"),
-            ]
-            missing = [what for bad, what in missing if bad]
-            if missing:
-                raise ValueError("recurrent state (state-space layers) does not serve with " + "; ".join(missing))
-
-        from deepspeed_tpu.utils.hbm import kv_slot_bytes
+            raise ValueError(f"hyper-connections (hc_mult={model_config.hc_mult}) with tp={mesh.shape['tp']}: the mix "
+                             "is a statistic and a product over the whole hidden width of every stream, which the "
+                             "partition rules replicate and nothing has needed split yet")
 
         dtype = config.jax_dtype
         kv_quant = config.kv_quant
         kv_dtype = config.kv_jax_dtype
-        kv_dtype_b = jnp.dtype(kv_dtype).itemsize
         # The real (quantized or dense) per-token pool cost — ONE formula
         # shared with the pre-flight guard and the capacity benchmark.
-        self.kv_bytes_per_token = kv_slot_bytes(
-            model_config.attention_layers, model_config.kv_heads,
-            model_config.dims_per_head, kv_dtype_b, kv_quant)
+        self.kv_bytes_per_token = plan.bytes_per_token(kv_dtype, kv_quant)
         # a routed model's programs hand out the experts they sent each token
         # to, as one more output (fetched only by *_with_picks)
         self._routed = model_config.num_experts > 0
@@ -467,208 +384,102 @@ class InferenceEngineV2:
         self.last_tokens_kept: Optional[float] = None
         # and in the serving loop's newest prefill of a chunk: (queries fed, scored and kept a query and layer)
         self.last_prefill_kept: Optional[Tuple[int, float, float]] = None
-        if model_config.expert_parallel is not None and mesh.shape.get("ep", 1) > 1:
-            raise ValueError(
-                f"expert_parallel={model_config.expert_parallel} on a mesh with ep={mesh.shape['ep']}: the "
-                "model is ONE chip's share of its layer, and the exchange between the chips is not built")
-        if model_config.latent_attention:
-            from deepspeed_tpu.inference.paged import index_pool_width, latent_pool_width
-
-            if kv_quant is not None:
-                raise ValueError(
-                    f"kv_cache_dtype={config.kv_dtype_name!r} with latent attention: the latent "
-                    "pool has no quantized form" + (" and the index keys are kept in the cache's own type, not "
-                                                    "fp8 or int8" if model_config.index_topk else "")
-                    + "; use a bf16 or fp32 pool")
-            # one slab a token a layer, shared by all heads (PagedKVPool), and
-            # under an indexer its one index key beside it
-            self.kv_bytes_per_token = model_config.num_layers * kv_dtype_b * (
-                latent_pool_width(model_config) + index_pool_width(model_config))
-        if model_config.index_topk:
-            missing = [
-                (mesh.shape["tp"] > 1, f"tp={mesh.shape['tp']}: the indexer's heads and the selection are not "
-                 "partitioned over heads"),
-                (config.spec_decode > 0, "spec_decode: the one proposer is the n-gram lookup, and the model's "
-                 "multi-token-prediction layer, which would propose here, is not built"),
-                (config.prefix_cache, "prefix_cache: a shared prefix's pages hold its index keys too, but no "
-                 "test has fed a suffix through an indexer yet"),
-            ]
-            missing = [what for bad, what in missing if bad]
-            if missing:
-                raise ValueError("a sparse-attention indexer (index_topk > 0) does not serve with "
-                                 + "; ".join(missing))
+        if model_config.expert_parallel is not None and ep > 1:
+            raise ValueError(f"expert_parallel={model_config.expert_parallel} on a mesh with ep={ep}: the "
+                             "model is ONE chip's share of its layer, and the exchange between the chips is not built")
         # The ring's class of page: every seat its whole ring (a row takes its pages as it grows to a window
         # and keeps them to its flush, so no seat can be short of one), at a token's bytes a sliding layer
-        self.ring_blocks = self.ring_bytes = 0
-        if self._ring is not None:
-            self.ring_blocks = config.max_seqs * self._ring.window_pages
-            self.ring_bytes = self.ring_blocks * config.kv_block_size * kv_slot_bytes(
-                model_config.sliding_layers, model_config.kv_heads, model_config.dims_per_head, kv_dtype_b)
+        self.ring_blocks = config.max_seqs * plan.ring_columns
+        self.ring_bytes = plan.ring_bytes(self.ring_blocks, kv_dtype)
         if config.kv_pool_bytes is not None:
             # byte-budget sizing: admission capacity follows the REAL block
             # bytes, so an int8 pool at the same budget admits ~1.9x the
             # concurrent requests of a bf16 one (of two classes of page, the
-            # ring's come off the budget first, the global class takes the rest)
+            # ring's come off the budget first, the first class takes the rest)
             num_blocks = max((int(config.kv_pool_bytes) - self.ring_bytes)
                              // (config.kv_block_size * self.kv_bytes_per_token), 1)
         else:
             num_blocks = config.num_kv_blocks
         self.num_kv_blocks = num_blocks
-        self.state = StateManager(num_blocks, config.kv_block_size, config.max_seqs,
-                                  max_blocks_per_seq=self.max_pages, layout=self._layout or self._ring,
-                                  state_slots=config.max_seqs if self._hybrid else None,
-                                  ring_blocks=self.ring_blocks or None)
+        self.state = StateManager(num_blocks, config.kv_block_size, config.max_seqs, layout=plan.layout,
+                                  state_slots=config.max_seqs if plan.state else None, ring_blocks=self.ring_blocks)
         self._staging = BatchStaging(self.max_pages)
         self.prefix_cache: Optional[PrefixCache] = None
         if config.prefix_cache:
             from deepspeed_tpu.utils.hbm import prefix_cache_capacity_blocks
 
-            self.prefix_cache = PrefixCache(
-                self.state.allocator, config.kv_block_size,
-                capacity_blocks=prefix_cache_capacity_blocks(
-                    num_blocks, config.prefix_cache_fraction))
+            self.prefix_cache = PrefixCache(self.state.allocator, config.kv_block_size, capacity_blocks=(
+                prefix_cache_capacity_blocks(num_blocks, config.prefix_cache_fraction)))
 
         n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
-        # a latent pool's row is one slab for all heads: nothing to split over tp
-        kv_on_tp = (model_config.kv_heads % mesh.shape["tp"] == 0
-                    and not model_config.latent_attention)
-        # Compiled-program registry (telemetry/programs.py): the v2 step
-        # programs are wrapped at build time when capture is live, and the
-        # pre-flight byte estimate below doubles as the serving-scope
+        tp = max(mesh.shape["tp"], 1)
+        heads = plan.classes[0].heads  # (a row that is one slab for all heads has nothing to split over tp)
+        kv_on_tp = heads > 0 and heads % tp == 0
+        quantize = None  # weight-only quantization of the serving weights (inference/woq.py)
+        if config.quant.enabled:
+            from deepspeed_tpu.inference.woq import quantize_params, quantized_bytes_estimate, woq_format
+
+            woq = dict(min_size=config.quant.min_leaf_size, classes=config.quant.tensor_classes)
+            quantize = functools.partial(quantize_params, fmt=woq_format(config.quant), **woq)
+        # Compiled-program registry (telemetry/programs.py): the v2 step programs are wrapped at build time
+        # when capture is live, and the pre-flight byte estimate below doubles as the serving-scope
         # calibration baseline for hbm/estimate_ratio.
         from deepspeed_tpu.telemetry.programs import get_program_registry
 
         self._programs = get_program_registry()
         if config.hbm_check != "off" or self._programs.enabled:
-            # Refuse/warn BEFORE any device materialization: PER-DEVICE bytes
-            # — params shard over tp (autotp partition rules), the KV pool
-            # shards over tp only when kv_heads divides — plus a
-            # [rows, vocab] logits buffer. Quantized storage enters with its
-            # REAL byte formulas: a pool/model that only fits quantized is
-            # admitted, an over-budget one refused before placement.
+            # Refuse/warn BEFORE any device materialization: PER-DEVICE bytes — params shard over tp (autotp
+            # partition rules), the KV pool shards over tp only when kv_heads divides — plus a [rows, vocab]
+            # logits buffer and a step's attention workspace. Quantized storage enters with its REAL byte
+            # formulas: a pool/model that only fits quantized is admitted, an over-budget one refused before
+            # placement.
             from deepspeed_tpu.utils.hbm import check_hbm_fit
 
-            tp = max(mesh.shape["tp"], 1)
             dtype_b = jnp.dtype(dtype).itemsize
-            if config.quant.enabled and tp == 1:
-                from deepspeed_tpu.inference.woq import (
-                    quantized_bytes_estimate,
-                    woq_format,
-                )
-
-                param_bytes = quantized_bytes_estimate(
-                    params, woq_format(config.quant),
-                    min_size=config.quant.min_leaf_size,
-                    classes=config.quant.tensor_classes, dense_itemsize=dtype_b)
+            if quantize is not None and tp == 1:
+                param_bytes = quantized_bytes_estimate(params, woq_format(config.quant), dense_itemsize=dtype_b, **woq)
             else:
-                # tp>1 places dense shards first (WOQ quantizes in place
-                # after — see below), so the dense tp-shard bytes ARE the
-                # placement peak
+                # tp>1 places dense shards first (WOQ quantizes in place after — see below), so the dense
+                # tp-shard bytes ARE the placement peak
                 param_bytes = n_params * dtype_b // tp
-            kv_bytes = num_blocks * config.kv_block_size * self.kv_bytes_per_token
-            # per-step attention workspace of the gather fallback: one
-            # layer's gathered (dequantized) KV blocks + fp32 score/prob
-            # arrays for a bucketed step (round-10 calibration: without it
-            # the serving estimate under-counted 2-3.5x on configs whose
-            # pool doesn't dominate; the Pallas path needs less — estimates
-            # must cover the worst dispatching path)
-            gathered = self.max_pages * config.kv_block_size
-            workspace = config.row_bucket * gathered * (
-                2 * model_config.kv_heads * model_config.dims_per_head * dtype_b
-                + 2 * model_config.num_heads * config.chunk_bucket * 4)
-            if self._layout is not None:
-                # the chunk program attends inside the chunk, a window at a
-                # time: its temporaries are a call's tokens x (two fp32
-                # residuals, q/k/v and the attention's fp32 merge, the GLU's
-                # pair), not a gathered context
-                tokens = config.max_ragged_batch_size or config.row_bucket * config.chunk_bucket
-                workspace = tokens * (
-                    2 * model_config.hidden_size * 4
-                    + 4 * model_config.num_heads * model_config.dims_per_head * dtype_b
-                    + 2 * model_config.intermediate_size * dtype_b)
-            if model_config.ssm_layers:  # a slot a sequence a state-space layer: the float32 state and the conv tail
-                sizes = model_config.ssm
-                kv_bytes += config.max_seqs * model_config.ssm_layers * (
-                    sizes.d_inner * sizes.d_state * 4 + (sizes.d_conv - 1) * sizes.conv_dim * dtype_b)
-            if model_config.gdn_layers:  # likewise a Gated DeltaNet layer's: a [Dk, Dv] state a value head
-                sizes = model_config.gdn
-                kv_bytes += config.max_seqs * model_config.gdn_layers * (
-                    sizes.n_v_heads * sizes.head_k_dim * sizes.head_v_dim * 4
-                    + (sizes.d_conv - 1) * sizes.conv_dim * dtype_b)
-            need = (param_bytes
-                    + kv_bytes // (tp if kv_on_tp else 1)
-                    + config.row_bucket * model_config.vocab_size * 4
-                    + workspace)
+            kv_bytes = (num_blocks * config.kv_block_size * self.kv_bytes_per_token
+                        + plan.state_bytes(config.max_seqs, dtype))
+            need = (param_bytes + kv_bytes // (tp if kv_on_tp else 1)
+                    + config.row_bucket * model_config.vocab_size * 4 + plan.workspace_bytes(config, dtype_b))
             if config.hbm_check != "off":
-                check_hbm_fit(need, what="InferenceEngineV2 init (params + KV pool)",
-                              mode=config.hbm_check)
+                check_hbm_fit(need, what="InferenceEngineV2 init (params + KV pool)", mode=config.hbm_check)
             self._programs.set_hbm_estimate(need, scope="serving")
-        woq_pre = config.quant.enabled and max(mesh.shape["tp"], 1) == 1
-        if woq_pre:
-            # WOQ before placement (the dense weights never hit the device):
-            # int8/int4/fp8 values + fp32 scales, dequant at each matmul
-            # boundary with compute-dtype accumulation (inference/woq.py).
-            # tp>1 instead places the dense shards and quantizes after — the
-            # pre-quantized flat layout would place replicated, costing MORE
-            # per device than a dense tp shard for tp>2.
-            from deepspeed_tpu.inference.woq import quantize_params, woq_format
-
-            params = quantize_params(
-                params, woq_format(config.quant),
-                min_size=config.quant.min_leaf_size,
-                classes=config.quant.tensor_classes)
+        # WOQ before placement where tp == 1 (the dense weights never hit the device): int8/int4/fp8 values +
+        # fp32 scales, dequant at each matmul boundary with compute-dtype accumulation. tp>1 instead places the
+        # dense shards and quantizes after — the pre-quantized flat layout would place replicated, costing MORE
+        # per device than a dense tp shard for tp>2.
+        if quantize is not None and tp == 1:
+            params = quantize(params)
         self.params = place_parameters(params, mesh, causal_lm_partition_rules, dtype)
-        if config.quant.enabled and not woq_pre:
-            from deepspeed_tpu.inference.woq import quantize_params, woq_format
-
-            fmt = woq_format(config.quant)
-            min_size = config.quant.min_leaf_size
-            classes = config.quant.tensor_classes
-            self.params = jax.jit(lambda p: quantize_params(
-                p, fmt, min_size=min_size, classes=classes))(self.params)
-        # KV pool: the merged kvH*hd dim over tp (contiguous head groups, so
-        # each rank holds its own heads' lanes), pages replicated over dp
-        pool = init_pool(model_config, num_blocks, config.kv_block_size, kv_dtype,
-                         kv_quant=kv_quant)
-        if not kv_on_tp and mesh.shape["tp"] > 1:
-            # correct but a quiet perf/memory cliff: each tp rank holds the
-            # FULL pool instead of 1/tp of it (round-3 verdict weak item 8)
-            log_dist(
-                f"KV pool REPLICATED over tp={mesh.shape['tp']}: kv_heads="
-                f"{model_config.kv_heads} not divisible — expect tp-times the "
-                "per-chip KV memory; pick tp dividing kv_heads to shard it",
-                ranks=[0],
-            )
+        if quantize is not None and tp > 1:
+            self.params = jax.jit(quantize)(self.params)
+        # The pools. The first class: the merged kvH*hd dim over tp (contiguous head groups, so each rank
+        # holds its own heads' lanes), pages replicated over dp; every sequence a state slot: as many as seats
+        pools = plan.init(num_blocks, self.ring_blocks, config.max_seqs, kv_dtype, kv_quant, state_dtype=dtype)
+        if not kv_on_tp and tp > 1:
+            # correct but a quiet perf/memory cliff: each tp rank holds the FULL pool instead of 1/tp of it
+            log_dist(f"KV pool REPLICATED over tp={tp}: kv_heads={model_config.kv_heads} not "
+                     "divisible — expect tp-times the per-chip KV memory; pick tp dividing kv_heads to shard it",
+                     ranks=[0])
         kv_spec = NamedSharding(mesh, P(None, None, "tp" if kv_on_tp else None))
         # scales: 4/hd of the values, slot-major rows. Also where every step
         # program's small outputs land, so the host's operands are placed there too
         self._replicated = replicated = NamedSharding(mesh, P())
-        self.pool = PagedKVPool(
-            k=jax.device_put(pool.k, kv_spec),
-            v=None if pool.v is None else jax.device_put(pool.v, kv_spec),
-            k_scale=None if pool.k_scale is None else jax.device_put(pool.k_scale, replicated),
-            v_scale=None if pool.v_scale is None else jax.device_put(pool.v_scale, replicated))
-        self.ring_pool = None
-        if self._ring is not None:
-            from deepspeed_tpu.inference.paged import init_ring_pool
-
-            self.ring_pool = jax.device_put(
-                init_ring_pool(model_config, self.ring_blocks, config.kv_block_size, kv_dtype), replicated)
-        self.state_pool = None
-        if self._hybrid:
-            from deepspeed_tpu.inference.paged import init_state_pool
-
-            # (every sequence a slot: as many as seats, no new engine key)
-            self.state_pool = jax.device_put(init_state_pool(model_config, config.max_seqs, dtype), replicated)
-        by_class = "" if self._ring is None else (
+        # what every step program takes, donated, and its result is assigned to
+        self.pools = Pools(
+            PagedKVPool(*(a if a is None else jax.device_put(a, kv_spec if i < 2 else replicated)
+                          for i, a in enumerate(pools.kv))),
+            *jax.device_put((pools.state, pools.ring), replicated))
+        by_class = "" if plan.ring is None else (
             f"; two classes of page: global {num_blocks * config.kv_block_size * self.kv_bytes_per_token} B, "
             f"ring {self.ring_blocks}x{config.kv_block_size} slots a sliding layer {self.ring_bytes} B")
-        log_dist(
-            f"InferenceEngineV2: {n_params/1e6:.1f}M params, "
-            f"{num_blocks}x{config.kv_block_size} KV slots "
-            f"[{config.kv_dtype_name}, {self.kv_bytes_per_token} B/token], "
-            f"mesh={dict(mesh.shape)}" + by_class
-        )
+        log_dist(f"InferenceEngineV2: {n_params/1e6:.1f}M params, {num_blocks}x{config.kv_block_size} KV slots "
+                 f"[{config.kv_dtype_name}, {self.kv_bytes_per_token} B/token], mesh={dict(mesh.shape)}" + by_class)
         self._step_cache: Dict[Tuple, Any] = {}
         self._chain_buf: Dict[int, Dict[str, np.ndarray]] = {}
         self._ahead: Optional[_ChainInFlight] = None  # the chain dispatched ahead, if any
@@ -678,37 +489,28 @@ class InferenceEngineV2:
         # names the in-flight requests even with the tracer disabled.
         self._recorder = None
         if config.flight_recorder:
-            from deepspeed_tpu.diagnostics.flight_recorder import (
-                FlightRecorder,
-                install_process_hooks,
-            )
+            from deepspeed_tpu.diagnostics.flight_recorder import FlightRecorder, install_process_hooks
 
-            self._recorder = FlightRecorder(
-                request_capacity=max(2 * config.max_seqs, 32))
-            self._recorder.set_context(
-                kind="serving", max_seqs=config.max_seqs,
-                decode_chain=config.decode_chain,
-                kv_blocks=self.num_kv_blocks)
+            self._recorder = FlightRecorder(request_capacity=max(2 * config.max_seqs, 32))
+            self._recorder.set_context(kind="serving", max_seqs=config.max_seqs, decode_chain=config.decode_chain,
+                                       kv_blocks=self.num_kv_blocks)
             install_process_hooks()
-        # Most recent generate()'s per-request tracker (None when telemetry
-        # is disabled and no recorder is configured — no records allocated).
+        # Most recent generate()'s per-request tracker (None when telemetry is disabled and no recorder is
+        # configured — no records allocated).
         self.lifecycle: Optional[LifecycleTracker] = None
-        # Serving-loop accounting (always on — plain int adds). The parity
-        # tests assert the dispatch/sync contract on these; the serving
-        # benchmark and telemetry gauges read them too.
+        # Serving-loop accounting (always on — plain int adds). The parity tests assert the dispatch/sync
+        # contract on these; the serving benchmark and telemetry gauges read them too.
         self.dispatch_count = 0        # compiled programs dispatched
         self.host_sync_count = 0       # host blocking fetches
         self.tokens_decoded = 0        # decode tokens produced by generate()
         self.chain_steps = 0           # decode-chain dispatches (fleet liveness)
         self.chains_ahead = 0          # of the chains, dispatched while the one before was unfetched
-        # The call log (always on, like the counters above: a few clock reads
-        # a call of 80-200 ms): every device call's host stamps in
-        # ``self.calls``, and a slow call with its cause in ``self.stalls``
+        # The call log (always on, like the counters above: a few clock reads a call of 80-200 ms): every device
+        # call's host stamps in ``self.calls``, and a slow call with its cause in ``self.stalls``
         # (diagnostics/anomaly.py; docs/diagnostics.md, "A slow call").
         self._log = CallLog()
         self._clock = time.perf_counter  # of the loop's open-loop arrivals
-        # prefix-cache + speculative accounting (plain int adds; the serving
-        # benchmark and the router smoke read these)
+        # prefix-cache + speculative accounting (plain int adds; the serving benchmark and the router smoke read these)
         self.prefill_tokens_total = 0  # prompt tokens submitted for prefill
         self.prefill_tokens_cached = 0  # of those, served from the prefix cache
         self.cow_copies = 0            # copy-on-write block clones dispatched
@@ -716,38 +518,30 @@ class InferenceEngineV2:
         self.spec_tokens_emitted = 0   # tokens those forwards emitted
 
     @property
-    def _pools(self):
-        """What the step programs take in the pool's place, donated, and hand
-        back: the page pool, with the state pool where the model has one."""
-        if self.ring_pool is not None:
-            return RingPools(self.pool, self.ring_pool)
-        return self.pool if self.state_pool is None else HybridPools(self.pool, self.state_pool)
-
-    @_pools.setter
-    def _pools(self, pools) -> None:
-        if self.ring_pool is not None:
-            self.pool, self.ring_pool = pools
-        elif self.state_pool is None:
-            self.pool = pools
-        else:
-            self.pool, self.state_pool = pools
+    def pool(self) -> PagedKVPool:
+        """The first class of page, ``pools.kv``: read-only, for what reads the engine from outside
+        (``benchmarks/runners/serve.py`` reads ``engine.pool.k.shape``; the router compares replicas' pools)."""
+        return self.pools.kv
 
     def _state_args(self, rows: int) -> Dict[str, int]:
         """For a ``serve:dispatch`` span of a model with recurrent state, while
         somebody records spans: ``state_rows``, the live rows x steps whose
         state slots the call updates (a chain's as its budgets plan it)."""
-        if self.state_pool is None or not self._tracer.recording():
+        if self.pools.state is None or not self._tracer.recording():
             return {}
         return {"state_rows": int(rows)}
 
     def stats(self) -> Dict[str, int]:
         """The pool's bytes by class of page, and the pages of each the live rows hold: ``kv_global_bytes``
         (the page pool every model has) and, under a sliding kind, ``kv_ring_bytes`` beside it."""
-        seqs = [self.state.get(u) for u in list(self.state._seqs)]
+        held = [0] * len(self.state.allocators)
+        for seq in self.state._seqs.values():
+            for cls, pages in zip(self.plan.layout.classes, (seq.n_summary, seq.n_window)):
+                held[cls] += pages
         out = {"kv_global_bytes": self.num_kv_blocks * self.config.kv_block_size * self.kv_bytes_per_token,
-               "kv_global_pages_held": sum(s.n_summary if self._ring is not None else s.n_blocks for s in seqs)}
-        if self._ring is not None:
-            out.update(kv_ring_bytes=self.ring_bytes, kv_ring_pages_held=sum(s.n_window for s in seqs),
+               "kv_global_pages_held": held[0]}
+        if self.plan.ring is not None:
+            out.update(kv_ring_bytes=self.ring_bytes, kv_ring_pages_held=held[1],
                        ring_pages_overwritten=self.ring_pages_overwritten)
         return out
 
@@ -988,20 +782,12 @@ class InferenceEngineV2:
         very blocks the hit is about to share, and the attach would raise
         mid-serving. Pinned blocks survive eviction (the entry goes, the
         bytes stay) and the pin is dropped by ``_unpin_hit`` either way."""
-        if hit is None:
-            return
-        blocks = list(hit.blocks)
-        if hit.cow_block is not None:
-            blocks.append(hit.cow_block)
-        self.state.allocator.share(blocks)
+        if hit is not None:
+            self.state.allocator.share(hit.blocks + [hit.cow_block] * (hit.cow_block is not None))
 
     def _unpin_hit(self, hit) -> None:
-        if hit is None:
-            return
-        blocks = list(hit.blocks)
-        if hit.cow_block is not None:
-            blocks.append(hit.cow_block)
-        self.state.allocator.release(blocks)
+        if hit is not None:
+            self.state.allocator.release(hit.blocks + [hit.cow_block] * (hit.cow_block is not None))
 
     def _attach_prefix(self, uid: int, hit) -> int:
         """Wire a PrefixHit into a fresh sequence: share the full cached
@@ -1023,8 +809,8 @@ class InferenceEngineV2:
             alloc.share([hit.cow_block])
             dst = self._ensure_blocks(1)
             with self._tracer.span("serve:cow", src=hit.cow_block, dst=int(dst[0])):
-                self.pool = self._cow_fn()(
-                    self.pool, jnp.int32(hit.cow_block), jnp.int32(dst[0]))
+                self.pools = self.pools._replace(kv=self._cow_fn()(
+                    self.pool, jnp.int32(hit.cow_block), jnp.int32(dst[0])))
             self.dispatch_count += 1
             alloc.release([hit.cow_block])
             seq.append_blocks(dst)
@@ -1036,11 +822,16 @@ class InferenceEngineV2:
     def _ensure_blocks(self, n: int) -> np.ndarray:
         """Allocate ``n`` blocks, evicting LRU prefix-cache entries if the
         free stack runs short."""
-        pc = self.prefix_cache
-        while (self.state.free_blocks < n and pc is not None
-               and pc.evict_one()):
-            pass
+        self._evict_until(lambda: self.state.free_blocks >= n)
         return self.state.allocator.allocate(n)
+
+    def _evict_until(self, fits) -> bool:
+        """Whether ``fits()``, once LRU prefix entries have released their references until it does or the cache
+        is dry (cache-only blocks are reclaimed under pressure: cached prefixes never starve live traffic)."""
+        while not fits():
+            if self.prefix_cache is None or not self.prefix_cache.evict_one():
+                return False
+        return True
 
     def _insert_prefix(self, uid: int, full_tokens: np.ndarray) -> None:
         """Index the finished prefill's full blocks (values already in the
@@ -1126,18 +917,9 @@ class InferenceEngineV2:
         holders; the source releases its OWN reference only at ``flush``
         after the import commits). The dispatch is asynchronous: the pages
         stream out while the host assembles the next prefill."""
-        if self._layout is not None:
-            raise ValueError(
-                "KV-block migration of an EVA model: the wire format carries pages in position "
-                "order and knows one kind of row; summary and window pages are not told apart")
-        if self._hybrid:
-            raise ValueError(
-                "KV-block migration of a model with recurrent state: the wire format carries pages and "
-                "knows no state slot; a request's state would stay behind")
-        if self._ring is not None:
-            raise ValueError(
-                "KV-block migration of a model with a sliding kind: the wire format carries one class of page "
-                "in position order; a row's ring pages, rolled by its position, would stay behind")
+        refused = self.plan.migration_refusal()
+        if refused:
+            raise ValueError(refused)
         seq = self.state.get(uid)
         if seq is None or seq.n_blocks == 0:
             raise ValueError(f"uid {uid} has no KV blocks to export")
@@ -1148,24 +930,20 @@ class InferenceEngineV2:
         with self._tracer.span("serve:export", uid=uid, blocks=n):
             buf = self._export_fn(pages)(self.pool, jnp.asarray(padded))
         self.dispatch_count += 1
-        return {"buffer": buf, "n_blocks": n, "pages": pages,
-                "seen_tokens": seq.seen_tokens,
-                "kv_dtype": str(jnp.dtype(self.pool.k.dtype)),
-                "quant": self.pool.quant,
-                "block_size": self.config.kv_block_size}
+        return {"buffer": buf, "n_blocks": n, "pages": pages, "seen_tokens": seq.seen_tokens, **self._wire_layout()}
+
+    def _wire_layout(self) -> Dict[str, Any]:
+        """What two pools must agree on for pages to move between them verbatim."""
+        return {"block_size": self.config.kv_block_size, "quant": self.pool.quant,
+                "kv_dtype": str(jnp.dtype(self.pool.k.dtype))}
 
     def can_import(self, n_blocks: int) -> bool:
         """Whether an ``n_blocks`` migration could be admitted right now
         (seq slot + free blocks after LRU cache eviction) — the refusal
         path the router consults so a rejected import leaves the request
         on its source instead of dropping it."""
-        if self.state.n_active >= self.config.max_seqs:
-            return False
-        pc = self.prefix_cache
-        while self.state.free_blocks < n_blocks and pc is not None \
-                and pc.evict_one():
-            pass
-        return self.state.free_blocks >= n_blocks
+        return (self.state.n_active < self.config.max_seqs
+                and self._evict_until(lambda: self.state.free_blocks >= n_blocks))
 
     def import_request(self, uid: int, export: Dict[str, Any]) -> bool:
         """Import an ``export_request`` ticket as a fresh sequence ``uid``:
@@ -1175,27 +953,18 @@ class InferenceEngineV2:
         destination state unchanged — when capacity refuses; raises on a
         layout mismatch (pools that disagree on dtype/geometry are a
         deployment error, not a capacity condition)."""
-        if self._hybrid:
-            raise ValueError(
-                "KV-block migration into a model with recurrent state: the wire format carries pages and "
-                "knows no state slot")
-        if export["block_size"] != self.config.kv_block_size or \
-                export["quant"] != self.pool.quant or \
-                export["kv_dtype"] != str(jnp.dtype(self.pool.k.dtype)):
-            raise ValueError(
-                f"migration layout mismatch: source "
-                f"(bs={export['block_size']}, quant={export['quant']}, "
-                f"dtype={export['kv_dtype']}) vs destination "
-                f"(bs={self.config.kv_block_size}, quant={self.pool.quant}, "
-                f"dtype={jnp.dtype(self.pool.k.dtype)})")
+        refused = self.plan.migration_refusal(importing=True)
+        if refused:
+            raise ValueError(refused)
+        said = "(bs={block_size}, quant={quant}, dtype={kv_dtype})".format
+        if said(**export) != said(**self._wire_layout()):
+            raise ValueError(f"migration layout mismatch: source {said(**export)} vs "
+                             f"destination {said(**self._wire_layout())}")
         buf: MigrationBuffer = export["buffer"]
         mc = self.model_config
-        if buf.k.shape[0] != mc.num_layers or \
-                tuple(buf.k.shape[2:]) != (mc.kv_heads, mc.dims_per_head):
-            raise ValueError(
-                f"migration layout mismatch: buffer pages {buf.k.shape} vs "
-                f"pool of {mc.num_layers} layers x {mc.kv_heads} heads x "
-                f"{mc.dims_per_head}")
+        if buf.k.shape[0] != mc.num_layers or tuple(buf.k.shape[2:]) != (mc.kv_heads, mc.dims_per_head):
+            raise ValueError(f"migration layout mismatch: buffer pages {buf.k.shape} vs pool of "
+                             f"{mc.num_layers} layers x {mc.kv_heads} heads x {mc.dims_per_head}")
         n = export["n_blocks"]
         if not self.can_import(n):
             return False
@@ -1205,10 +974,10 @@ class InferenceEngineV2:
         padded[:n] = dst_blocks
         try:
             with self._tracer.span("serve:import", uid=uid, blocks=n):
-                self.pool = self._import_fn(pages)(
-                    self.pool, buf, jnp.asarray(padded), jnp.int32(n))
+                self.pools = self.pools._replace(kv=self._import_fn(pages)(
+                    self.pool, buf, jnp.asarray(padded), jnp.int32(n)))
         except BaseException:
-            # the scatter never committed (self.pool rebinds only on
+            # the scatter never committed (self.pools rebinds only on
             # success): return the allocation so a failed import — which
             # the router degrades, not drops — cannot leak destination
             # capacity attempt over attempt
@@ -1231,18 +1000,8 @@ class InferenceEngineV2:
         return [min(k * m, b) + self.config.spec_decode for b in budgets]
 
     def _can_schedule_evicting(self, uids, counts) -> bool:
-        """``can_schedule`` that reclaims cache-only blocks under pressure:
-        LRU prefix entries release their references until admission fits or
-        the cache is dry — cached prefixes never starve live traffic."""
-        if self.state.can_schedule(uids, counts):
-            return True
-        pc = self.prefix_cache
-        if pc is None:
-            return False
-        while pc.evict_one():
-            if self.state.can_schedule(uids, counts):
-                return True
-        return False
+        """``can_schedule`` that reclaims cache-only blocks under pressure (``_evict_until``)."""
+        return self._evict_until(lambda: self.state.can_schedule(uids, counts))
 
     # ---------------------------------------------------------------- EVA
     def _eva_args(self, positions: np.ndarray, lens: np.ndarray) -> Dict[str, Any]:
@@ -1254,9 +1013,9 @@ class InferenceEngineV2:
         (closed windows' summaries + the open window up to the token),
         ``context_tokens`` what full attention would read, ``row_steps`` the
         tokens, ``windows_closed`` those that end a window."""
-        if self._layout is None or not self._tracer.recording():
+        lay = self.plan.windows
+        if lay is None or not self._tracer.recording():
             return {}
-        lay = self._layout
         pos = positions.astype(np.int64)
         fed = np.arange(pos.shape[1])[None, :] < np.asarray(lens)[:, None]
         return {"attended_rows": int(lay.attended(pos)[fed].sum()), "context_tokens": int((pos + 1)[fed].sum()),
@@ -1266,16 +1025,15 @@ class InferenceEngineV2:
     def _advance(self, uids, counts) -> int:
         """``seen_tokens`` of each uid forward by its count. Returns the
         windows that closed on the way (EVA), for ``windows_closed``."""
-        if self._layout is None:
-            for uid, n in zip(uids, counts):
-                seq = self.state.get(uid)
-                if self._ring is not None and seq.seen_tokens:  # (a fresh prompt writes its last window alone)
-                    over = self._ring.overwritten(seq.seen_tokens, int(n)) * self.model_config.sliding_layers
-                    self.ring_pages_overwritten += over
-                    self._tracer.count("serving/ring_pages_overwritten", float(over))
-                seq.seen_tokens += int(n)
-            return 0
-        return sum(self.state.advance(uid, int(n)) for uid, n in zip(uids, counts))
+        rings, closed = self.plan.rings, 0
+        for uid, n in zip(uids, counts):
+            # (a fresh prompt writes its last window alone)
+            if rings is not None and (seen := self.state.get(uid).seen_tokens):
+                over = rings.overwritten(seen, int(n)) * self.plan.ring.layers
+                self.ring_pages_overwritten += over
+                self._tracer.count("serving/ring_pages_overwritten", float(over))
+            closed += self.state.advance(uid, int(n))
+        return closed
 
     # ---------------------------------------------------------------- put
     def _build_batch(self, uids, token_lists) -> RaggedBatch:
@@ -1311,8 +1069,8 @@ class InferenceEngineV2:
                                **self._eva_args(batch.positions, batch.new_lens),
                                **self._state_args(len(uids)), **self._ring_args(uids),
                                **self._flash_args(batch.new_lens, batch.tokens.shape[1])):
-            logits, self._pools, *picks = step(
-                self.params, self._pools,
+            logits, self.pools, *picks = step(
+                self.params, self.pools,
                 jnp.asarray(batch.tokens), jnp.asarray(batch.positions),
                 jnp.asarray(batch.new_lens), jnp.asarray(batch.block_tables),
             )
@@ -1332,7 +1090,7 @@ class InferenceEngineV2:
         (``max_ragged_batch_size`` padded tokens, one row at the least) and ``put`` feeds the rest in further
         calls of the same program; every other model takes all its rows in one call, as it always did."""
         budget = self.config.max_ragged_batch_size
-        if self._ring is None or not budget or not token_lists:
+        if self.plan.rings is None or not budget or not token_lists:
             return max(len(token_lists), 1)
         longest = max(map(len, token_lists))
         if longest <= 1:  # (one token a row: the ``(rows, 1)`` program, ``ragged.build_ragged_batch``)
@@ -1447,7 +1205,7 @@ class InferenceEngineV2:
         """Each row's first position and the tokens it is fed, ``start:count``, as one span arg of a prefill
         under a learned indexer (what a query scores and keeps follows from its position); formatted only
         while somebody records spans, before ``seen_tokens`` advances."""
-        if not (self.model_config.index_topk or self._ring is not None) or not self._tracer.recording():
+        if not (self.model_config.index_topk or self.plan.rings is not None) or not self._tracer.recording():
             return {}
         return {"fed": " ".join(f"{self.state.get(u).seen_tokens}:{len(t)}" for u, t in zip(uids, token_lists))}
 
@@ -1459,7 +1217,7 @@ class InferenceEngineV2:
         ``serving/kv_pages_held_ring`` and ``serving/kv_pages_held_global``. A chain (``start``: its rows' first
         positions, ``share``: the steps its budgets plan for each) says ``ring_tokens`` beside them: the sum over
         its rows and steps of the tokens a sliding layer's ring holds for the query, ``min(position + 1, window)``."""
-        if self._ring is None or not self._tracer.recording():
+        if self.plan.rings is None or not self._tracer.recording():
             return {}
         cfg = self.model_config
         seqs = [self.state.get(u) for u in uids]
@@ -1471,7 +1229,7 @@ class InferenceEngineV2:
         args = {"ring_pages": ring, "global_pages": held, "one_class_pages": blocks * cfg.num_layers}
         if start is not None:
             fed = np.arange(int(np.max(share, initial=0)))[None, :] < np.asarray(share)[:, None]
-            seen = np.minimum(np.asarray(start)[:, None] + np.arange(fed.shape[1])[None, :] + 1, self._ring.window)
+            seen = np.minimum(np.asarray(start)[:, None] + np.arange(fed.shape[1])[None, :] + 1, self.plan.rings.window)
             args["ring_tokens"] = int(seen[fed].sum())
         return args
 
@@ -1485,10 +1243,11 @@ class InferenceEngineV2:
         from deepspeed_tpu.ops.pallas.flash_attention import forward_cells
 
         cfg = self.model_config
-        if self._ring is None or chunk == 1 or not self._tracer.recording() or not resolves_to_flash(cfg.attn_impl):
+        rings = self.plan.rings
+        if rings is None or chunk == 1 or not self._tracer.recording() or not resolves_to_flash(cfg.attn_impl):
             return {}
         by_kind = ((cfg.attention_layers, forward_cells(new_lens, chunk)),
-                   (cfg.sliding_layers, forward_cells(new_lens, chunk, self._ring.window)))
+                   (cfg.sliding_layers, forward_cells(new_lens, chunk, rings.window)))
         live, grid = (sum(layers * cells[i] for layers, cells in by_kind) for i in (0, 1))
         return {"flash_cells_live": live, "flash_cells_grid": grid}
 
@@ -1510,8 +1269,8 @@ class InferenceEngineV2:
                                **self._flash_args(batch.new_lens, batch.tokens.shape[1])):
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "prefill")
-            toks, rng, self._pools, *picks = step(
-                self.params, self._pools,
+            toks, rng, self.pools, *picks = step(
+                self.params, self.pools,
                 jnp.asarray(batch.tokens), jnp.asarray(batch.positions),
                 jnp.asarray(batch.new_lens), jnp.asarray(batch.block_tables),
                 rng,
@@ -1534,18 +1293,15 @@ class InferenceEngineV2:
     def _chain_arrays(self, rows: int) -> Dict[str, np.ndarray]:
         buf = self._chain_buf.get(rows)
         if buf is None:
-            buf = {
+            buf = self._chain_buf[rows] = {
                 "tokens": np.zeros((rows,), np.int32),
                 "pos": np.zeros((rows,), np.int32),
                 "tables": np.zeros((rows, self.max_pages), np.int32),
                 "active": np.zeros((rows,), bool),
                 "budgets": np.zeros((rows,), np.int32),
             }
-            self._chain_buf[rows] = buf
-        else:
-            buf["tables"][:] = 0
-            buf["active"][:] = False
-            buf["budgets"][:] = 0
+        for name in ("tables", "active", "budgets"):
+            buf[name][:] = 0
         return buf
 
     def _place(self, buf: Dict[str, np.ndarray], *names: str) -> Tuple[jax.Array, ...]:
@@ -1576,17 +1332,14 @@ class InferenceEngineV2:
             share = np.minimum(budgets, k)
             for i, uid, b in zip(at, uids, share):
                 seq = self.state.extend(uid, int(b))
-                if self.state.layout is None:
-                    buf["tables"][i, : seq.n_blocks] = seq.blocks
-                else:
-                    seq.table_into(buf["tables"][i])
+                seq.table_into(buf["tables"][i])
                 buf["pos"][i] = seq.seen_tokens
             buf["budgets"][at] = budgets
             start = buf["pos"][at]
             if not ahead:
                 buf["tokens"][at] = last_tokens
                 buf["active"][at] = True
-            eva_args = {} if self._layout is None else self._eva_args(
+            eva_args = {} if self.plan.windows is None else self._eva_args(
                 start[:, None] + np.arange(k)[None, :], share)
         chain = self._chain_fn(n_rows, k, eos_id, sample_kw)
         call = self._log.open("chain", chain_id, n_rows, k)
@@ -1601,8 +1354,8 @@ class InferenceEngineV2:
                     tracker.mark_dispatch(rids, "chain", now=call.dispatched_at)
                 tokens, pos, tables, active, chain_budgets = self._place(
                     buf, "tokens", "pos", "tables", "active", "budgets")
-            out, emitted, active, tok, pos, rng_out, self._pools, *routed = chain(
-                self.params, self._pools, tokens, pos, tables, active, chain_budgets, rng)
+            out, emitted, active, tok, pos, rng_out, self.pools, *routed = chain(
+                self.params, self.pools, tokens, pos, tables, active, chain_budgets, rng)
         self.dispatch_count += 1
         if ahead:
             self.chains_ahead += 1
@@ -1736,8 +1489,8 @@ class InferenceEngineV2:
         for uid, short in zip(np.asarray(uids)[moved], (k - emitted)[moved]):
             self.state.get(int(uid)).seen_tokens -= int(short)
         self._advance([u for u, m in zip(uids, moved) if not m], emitted[~moved])
-        if self._layout is not None:
-            window = self._layout.window
+        if self.plan.windows is not None:
+            window = self.plan.windows.window
             self.windows_closed += int(((flight.start + emitted) // window - flight.start // window).sum())
         if self._ahead is not None:
             # the rows of the chain ahead that hold a request are those this
@@ -1791,7 +1544,7 @@ class InferenceEngineV2:
             for i, uid in enumerate(uids):
                 window = min(k * m, int(budgets[i]))
                 seq = self.state.extend(uid, window + n_spec)
-                buf["tables"][i, : seq.n_blocks] = seq.blocks
+                seq.table_into(buf["tables"][i])
                 buf["pos"][i] = seq.seen_tokens
                 h = histories[i]
                 sb["hist"][i, : len(h)] = h
@@ -1805,8 +1558,8 @@ class InferenceEngineV2:
                                live=n, k=k, n_spec=n_spec, chain=chain_id):
             if tracker is not None and rids is not None:
                 tracker.mark_dispatch(rids, "chain")
-            out, emitted, _, steps, rng, self.pool = chain(
-                self.params, self.pool,
+            out, emitted, _, steps, rng, self.pools = chain(
+                self.params, self.pools,
                 jnp.asarray(buf["tokens"]), jnp.asarray(buf["pos"]),
                 jnp.asarray(buf["tables"]), jnp.asarray(buf["active"]),
                 jnp.asarray(buf["budgets"]), rng,
@@ -1947,11 +1700,12 @@ class InferenceEngineV2:
                         f"(+{margin} speculative slack) exceeds engine "
                         f"max_seq_len={self.max_seq_len}"
                     )
-                if self._layout is not None:
+                lay = self.plan.windows
+                if lay is not None:
                     # summaries of the windows it closes + one window's rows, at the most
                     total = len(p) + max_new_tokens
-                    held = (total // self._layout.window * self._layout.per_closed
-                            + min(self._layout.window_pages, -(-total // self.config.kv_block_size)))
+                    held = (total // lay.window * lay.per_closed
+                            + min(lay.window_pages, -(-total // self.config.kv_block_size)))
                     fits = held <= self.num_kv_blocks
                 else:
                     fits = len(p) + max_new_tokens + margin <= pool_tokens

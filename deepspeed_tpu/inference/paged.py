@@ -30,189 +30,17 @@ from __future__ import annotations
 
 import functools
 
-from typing import Any, NamedTuple, Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.inference.cache import PagedKVPool, Pools, StatePool, attention_kind, ring_columns
 from deepspeed_tpu.inference.model import (ExpertStack, _apply_norm, _attn_out, _dense, _logits, _mlp,
                                            _moe_with_picks, _qkv)
-from deepspeed_tpu.inference.ragged import ring_columns
 from deepspeed_tpu.inference.sampling import greedy_tokens, sample_logits
 from deepspeed_tpu.models.transformer import TransformerConfig, _norm_at, _times, reading
 from deepspeed_tpu.ops import gdn, mhc, ssm
-
-
-class PagedKVPool(NamedTuple):
-    """k/v: ``[L*NB, bs, kvH*hd]`` page-major pool, layer ``l``'s block ``b``
-    at row ``l*NB + b`` (reference: FastGen preallocates the KV arena up front
-    from a memory budget, ``DSStateManager`` + ``KVCacheConfig``). Row-major
-    this is the byte order of ``[L, NB*bs, kvH, hd]``; what the shape fixes is
-    the TPU's tiling: the two minor dims ``(bs, kvH*hd)`` are a page, so a
-    page is addressed by the leading index alone and the kernel takes the
-    array as it is. The layers are NOT a dimension of their own: merging
-    ``[L, NB]`` is free for the values but re-lays the scales out (their
-    tiled second-minor dim would be ``NB``). This NamedTuple is a jit pytree
-    and holds only arrays; ``L`` comes from the model config.
-
-    Quantized storage (``kv_quant='int8'|'fp8'``): k/v hold int8/e4m3 values
-    and ``k_scale``/``v_scale`` carry one fp32 scale per (layer, slot, kv-head)
-    — the quantization block is the ``hd`` head vector, so a token's KV write
-    is one ``ops.quant`` block-math call and dequant needs only the slot's own
-    scale (fused into the paged-attention block loads). A page's scales are
-    ONE lane-dense row ``[bs*kvH]`` (slot-major, the values' own order): fp32
-    with a minor dim of ``kvH`` alone would pad to 128 lanes on the TPU.
-    ``None`` scales mean a full-precision pool.
-
-    A LATENT pool (latent attention, ``TransformerConfig.kv_lora_rank > 0``)
-    is ``k`` alone, ``[L*NB, bs, W]``, and ``v`` is ``None``: a token's row is
-    one slab shared by every head, ``[latent after its norm | rotary key after
-    RoPE | zeros]``, ``W = latent_pool_width(cfg)`` = rank + rope width
-    rounded up to whole 128-lane tiles (512 + 64 -> 640: a minor dim of 576
-    pads to 640 in HBM either way, so the padding is said, not hidden). The
-    keys are the slab, the values its first ``kv_lora_rank`` columns; nothing
-    else of a token is cached. It has no quantized form. Under a learned
-    indexer (``TransformerConfig.index_topk > 0``) ``v`` is the INDEX pool,
-    ``[L*NB, bs, index_pool_width(cfg)]``: a token's index key after its norm
-    and RoPE, in the page and slot its latent has, so one block table a row
-    places everything a token caches and the two arrays are read by two
-    different kernels, each the bytes it needs."""
-
-    k: jax.Array
-    v: Optional[jax.Array] = None
-    k_scale: Optional[jax.Array] = None  # [L*NB, bs*kvH] fp32, or None
-    v_scale: Optional[jax.Array] = None
-
-    @property
-    def block_size(self) -> int:
-        return self.k.shape[1]
-
-    @property
-    def quant(self) -> Optional[str]:
-        """Storage quantization mode, derived from the value dtype (trace-time
-        static): None | 'int8' | 'fp8'."""
-        if self.k_scale is None:
-            return None
-        return "fp8" if self.k.dtype == jnp.float8_e4m3fn else "int8"
-
-
-class StatePool(NamedTuple):
-    """What the state-space layers of a layer pattern (``TransformerConfig.
-    layer_types``) keep of a sequence, beside the page pool that its attention
-    layers write: not a row a token but ONE slot a sequence a layer, whatever
-    its length. ``ssm`` ``[state-space layers, slots, H P / 128, N, 128]``
-    float32 is the recurrent state, a tile 128 of a layer's ``H P`` channels on
-    the lanes and the state's ``N`` on the sublanes (``ops/ssm.py::to_pool``: the
-    layout the decode kernel reads and writes as it is), ``conv`` ``[state-space layers, slots, (d_conv - 1)
-    x (H P + 2 G N)]`` the convolution's last inputs, one lane-dense row a slot
-    (``ops/ssm.py``; with a dimension of 3 of its own the chip's compiler laid
-    it out 3-minor, padded to 128 lanes, and copied it: 2.4 GB). State-space
-    layer ``s`` (counted among its kind) owns row ``s``; a sequence owns slot
-    ``i`` of every row from its first token to its flush (``ragged.
-    StateManager``), and a program's ROW ``i`` is slot ``i``: a layer reads and
-    writes the first ``rows`` slots of its row of the pool as ONE slice, in
-    place, and never gathers or scatters by sequence. A slot is not cleared
-    when it changes hands: a row fed from position 0 starts from zeros.
-
-    A pattern of Gated DeltaNet layers (``linear_attention``) keeps the same
-    two arrays: ``ssm`` ``[such layers, slots, Hv, Dk, Dv]`` float32, a value
-    head's state one ``[Dk, Dv]`` tile with the values on the lanes
-    (``ops/pallas/gdn_update.py``), ``conv`` the last inputs of ``[q | k | v]``."""
-
-    ssm: jax.Array
-    conv: jax.Array
-
-
-class HybridPools(NamedTuple):
-    """What the serving programs of a model with state-space layers are handed
-    in the pool's place, donated, and hand back: the page pool of its attention
-    layers and the state pool of the others."""
-
-    kv: "PagedKVPool"
-    state: StatePool
-
-
-class RingPools(NamedTuple):
-    """What the serving programs of a model with a sliding kind
-    (``TransformerConfig.sliding``) are handed in the pool's place, donated, and
-    hand back: TWO CLASSES OF PAGE of one geometry ``[bs, kvH*hd]``. ``kv``
-    ``[full layers * NB, bs, X]`` holds the pages of the pattern's ``attention``
-    layers, a page a block of positions, as many as the context has; ``ring``
-    ``[sliding layers * NR, bs, X]`` holds the pages of its ``sliding_attention``
-    layers, of which a row has ``ring_columns(window, bs)`` at most whatever
-    its context: block ``b`` of a sliding layer lives in the row's ring column
-    ``b % R`` and is written over when block ``b + R`` arrives, so nothing is
-    freed and nothing moves. A row's block table is ``[global columns | R ring
-    columns]`` (``ragged.RingLayout`` is the host's account of the same
-    columns): the first index ``kv``'s pages of a layer, the last ``ring``'s."""
-
-    kv: "PagedKVPool"
-    ring: "PagedKVPool"
-
-
-def init_ring_pool(cfg: TransformerConfig, ring_blocks: int, block_size: int, dtype: Any = jnp.bfloat16):
-    """The sliding layers' class of page (``RingPools.ring``): ``ring_blocks`` pages a layer."""
-    shape = (cfg.sliding_layers * ring_blocks, block_size, cfg.kv_heads * cfg.dims_per_head)
-    return PagedKVPool(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
-
-
-def init_state_pool(cfg: TransformerConfig, slots: int, dtype: Any = jnp.bfloat16) -> StatePool:
-    if cfg.gdn_layers:
-        if cfg.ssm_layers:
-            raise ValueError("a layer pattern with both 'mamba' and 'linear_attention' layers: one state pool "
-                             "holds one kind of state")
-        g = cfg.gdn
-        return StatePool(
-            ssm=jnp.zeros((cfg.gdn_layers, slots, g.n_v_heads, g.head_k_dim, g.head_v_dim), jnp.float32),
-            conv=jnp.zeros((cfg.gdn_layers, slots, (g.d_conv - 1) * g.conv_dim), dtype))
-    sizes = cfg.ssm
-    tile = ssm.pool_tile(sizes.d_inner)
-    return StatePool(
-        ssm=jnp.zeros((cfg.ssm_layers, slots, sizes.d_inner // tile, sizes.d_state, tile), jnp.float32),
-        conv=jnp.zeros((cfg.ssm_layers, slots, (sizes.d_conv - 1) * sizes.conv_dim), dtype))
-
-
-_KV_QUANT_DTYPES = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
-_LANES = 128
-
-
-def latent_pool_width(cfg: TransformerConfig) -> int:
-    """Columns of a latent pool's row: latent + rotary key, in whole lane tiles."""
-    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // _LANES) * _LANES
-
-
-def index_pool_width(cfg: TransformerConfig) -> int:
-    """Columns of an index pool's row: the indexer's one key a token, in whole lane tiles (0: no indexer)."""
-    return -(-cfg.index_head_dim // _LANES) * _LANES if cfg.index_topk else 0
-
-
-def init_pool(
-    cfg: TransformerConfig, num_blocks: int, block_size: int, dtype: Any = jnp.bfloat16,
-    kv_quant: Optional[str] = None,
-) -> PagedKVPool:
-    if cfg.eva_window and kv_quant is not None:
-        raise ValueError(
-            f"kv_quant={kv_quant!r} with EVA attention: a summary row is a weighted sum of "
-            "keys and has no per-token scale; use a bf16/fp32 pool")
-    if cfg.latent_attention:
-        if kv_quant is not None:
-            raise ValueError(
-                f"kv_quant={kv_quant!r} with latent attention: a latent pool has no quantized "
-                "form (one scale a token a layer is not carried); use a bf16/fp32 pool")
-        pages = (cfg.num_layers * num_blocks, block_size)
-        return PagedKVPool(k=jnp.zeros(pages + (latent_pool_width(cfg),), dtype),
-                           v=jnp.zeros(pages + (index_pool_width(cfg),), dtype) if cfg.index_topk else None)
-    # (of a layer pattern, the attention layers alone hold pages)
-    shape = (cfg.attention_layers * num_blocks, block_size, cfg.kv_heads * cfg.dims_per_head)
-    if kv_quant is None:
-        return PagedKVPool(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
-    if kv_quant not in _KV_QUANT_DTYPES:
-        raise ValueError(f"kv_quant must be None|'int8'|'fp8', got {kv_quant!r}")
-    qdt = _KV_QUANT_DTYPES[kv_quant]
-    sshape = (shape[0], block_size * cfg.kv_heads)
-    return PagedKVPool(k=jnp.zeros(shape, qdt), v=jnp.zeros(shape, qdt),
-                       k_scale=jnp.zeros(sshape, jnp.float32),
-                       v_scale=jnp.zeros(sshape, jnp.float32))
 
 
 def _kv_block_quant(x: jax.Array, quant: str):
@@ -562,12 +390,11 @@ def _latent_attention(ap, cfg: TransformerConfig, h, positions, new_lens, block_
     return out, pk
 
 
-def _eva_attention(cfg: TransformerConfig, positions, new_lens, block_tables, bs: int, num_rows: int):
-    """``attend(ap, h, pk, pv, first_page) -> (attention output [N, C, E], pk,
-    pv)``: EVA attention of a call's new tokens against a pool whose pages are
-    of two kinds (``ragged.WindowLayout`` is the host's account of the same
-    columns). Everything but the layer's offset is
-    computed here, once for all layers.
+def _eva_attention(cfg: TransformerConfig, call: "_Call"):
+    """An attention builder (``_ATTENTION``): EVA attention of a call's new
+    tokens against a pool whose pages are of two kinds (``cache.WindowLayout``
+    is the host's account of the same columns). Everything but the layer's
+    offset is computed here, once for all layers.
 
     A row feeds either ONE token, anywhere (decode, ``put``), or a chunk that
     starts at position 0 (a prompt; the host refuses anything else):
@@ -591,6 +418,7 @@ def _eva_attention(cfg: TransformerConfig, positions, new_lens, block_tables, bs
     from deepspeed_tpu.models.transformer import rope_at
     from deepspeed_tpu.ops.eva import eva_attention, pool_chunks
 
+    positions, new_lens, block_tables, bs, num_rows = call[:5]
     N, C = positions.shape
     P = block_tables.shape[1]
     W, c = cfg.eva_window, cfg.eva_chunk
@@ -687,29 +515,28 @@ def _eva_attention(cfg: TransformerConfig, positions, new_lens, block_tables, bs
         return ctx[:, :C], pk, pv
 
     @jax.named_scope("eva")
-    def attend(ap, h, pk, pv, first_page):
+    def attend(ap, h, pages, first_page, kind):
         q, k, v = _qkv(ap, cfg, h)
         with jax.named_scope("rope"):
             q = rope_at(q, positions, cfg.rope_theta, cfg.rope_interleaved)
             k = rope_at(k, positions, cfg.rope_theta, cfg.rope_interleaved)
         phi, mu = ap["phi"], ap["mu"]
-        first, pk, pv = one_token(q[:, :1], k[:, :1], v[:, :1], phi, mu, pk, pv, first_page)
+        first, pk, pv = one_token(q[:, :1], k[:, :1], v[:, :1], phi, mu, pages.k, pages.v, first_page)
         if C == 1:
-            return _attn_out(ap, cfg, first), pk, pv
+            return _attn_out(ap, cfg, first), pages._replace(k=pk, v=pv), None
         ctx, pk, pv = chunk(q, k, v, phi, mu, pk, pv, first_page)
         ctx = ctx.at[:, :1].set(jnp.where(single[:, None, None, None], first.astype(ctx.dtype), ctx[:, :1]))
-        return _attn_out(ap, cfg, ctx), pk, pv
+        return _attn_out(ap, cfg, ctx), pages._replace(k=pk, v=pv), None
 
     return attend
 
 
-def _windowed_attention(cfg: TransformerConfig, positions, new_lens, block_tables, bs: int,
-                        full_rows: int, ring_rows: int):
-    """``attend(ap, h, kind, pk, pv, first_page) -> (attention output [N, C, E],
-    pk, pv)`` for a pattern with a sliding kind: ``pk`` / ``pv`` are the class
-    of page the layer's ``kind`` writes (``RingPools``), ``first_page`` the
-    layer's first page in it. Everything but the layer's offset is computed
-    here, once for all layers.
+def _windowed_attention(cfg: TransformerConfig, call: "_Call"):
+    """An attention builder (``_ATTENTION``) for a pattern with a sliding kind:
+    ``pages`` are the class of page the layer's ``kind`` writes (``Pools.ring``
+    for a ``sliding_attention`` layer, ``Pools.kv`` for an ``attention`` one),
+    ``first_page`` the layer's first page in it. Everything but the layer's
+    offset is computed here, once for all layers.
 
     A call feeds either ONE token a row, anywhere (``C == 1``: decode, ``put``),
     or chunks that each start at position 0 (fresh prompts; the host refuses
@@ -738,6 +565,7 @@ def _windowed_attention(cfg: TransformerConfig, positions, new_lens, block_table
     from deepspeed_tpu.models.transformer import apply_qk_rope, sliding_kind
     from deepspeed_tpu.ops.attention import causal_attention, first_live
 
+    positions, new_lens, block_tables, bs, full_rows, ring_rows = call[:6]
     N, C = positions.shape
     W = cfg.sliding.window
     R = ring_columns(W, bs)
@@ -786,7 +614,7 @@ def _windowed_attention(cfg: TransformerConfig, positions, new_lens, block_table
                 pv = put(pv, v.astype(pv.dtype).reshape(-1, X), first_page)
             return ctx, pk, pv
 
-    def attend(ap, h, kind, pk, pv, first_page):
+    def attend(ap, h, pages, first_page, kind):
         sliding = kind == "sliding_attention"
         how = sliding_kind(cfg, kind)
         q, k, v = _qkv(ap, cfg, h)
@@ -794,140 +622,59 @@ def _windowed_attention(cfg: TransformerConfig, positions, new_lens, block_table
             with jax.named_scope("rope"):
                 q, k = apply_qk_rope(cfg, q, k, positions)
         with jax.named_scope("swa" if sliding else "attn_full"):
-            ctx, pk, pv = attention(q, k, v, sliding, how["window"], pk, pv, first_page)
-        return _attn_out(ap, cfg, ctx), pk, pv
+            ctx, pk, pv = attention(q, k, v, sliding, how["window"], pages.k, pages.v, first_page)
+        return _attn_out(ap, cfg, ctx), pages._replace(k=pk, v=pv), None
 
     return attend
 
 
-def _forward_hidden(
-    params,
-    cfg: TransformerConfig,
-    pool: PagedKVPool,
-    tokens: jax.Array,  # [N, C] int32
-    positions: jax.Array,  # [N, C] int32
-    new_lens: jax.Array,  # [N] int32
-    block_tables: jax.Array,  # [N, P] int32
-    block_size: int,
-    all_positions: bool = False,
-    with_picks: bool = False,
-    with_selected: bool = False,
-) -> Tuple[jax.Array, ...]:
-    """One mixed prefill/decode layer-stack pass -> (last-token hidden [N, E],
-    pool). Shared by the single-step ``ragged_forward`` and the K-step
-    ``ragged_decode_chain`` — one definition of the serving transformer math.
+class _Call(NamedTuple):
+    """One call's geometry, the same for every layer: what an attention builder (``_ATTENTION``) computes its
+    indices from, once for all layers."""
 
-    ``with_picks=True`` on a routed model (``num_experts > 0``) returns a
-    third value, ``picks`` int32 ``[N, C, routed layers, k]``: the experts
-    each token fed was sent to in each routed layer, leading dense layers not
-    counted, by the experts' own numbers (pad tokens' entries are garbage).
-    A model with no routed layer returns the pair whatever is asked.
+    positions: jax.Array  # [N, C]
+    new_lens: jax.Array  # [N]
+    block_tables: jax.Array  # [N, P]
+    bs: int
+    kv_rows: int  # rows of the first class's arrays: a write that indexes this one drops
+    ring_rows: int  # likewise of the ring's (0: the model has none)
+    quant: Optional[str]  # the first class's storage (``PagedKVPool.quant``), static at trace time
+    hand_mask: bool  # an indexed layer hands out a chunk's mask in place of its counts
+    w_page: Optional[jax.Array]  # where each new token's row goes in a layer's pages of the first class, pad
+    w_slot: Optional[jax.Array]  # tokens at page ``kv_rows``; None under EVA, whose rows go by window
 
-    A model with a learned indexer (``index_topk > 0``) asked ``with_picks``
-    hands out BEFORE the picks what each query kept, where its block table
-    holds more tokens than a query keeps: for one token a row (``C == 1``)
-    ``selected`` int32 ``[N, layers, index_topk]``, the positions, -1 for
-    none; for a chunk ``[N, layers, 2]`` int32, the cached tokens a row's
-    queries scored and the ones they kept, and where ``with_selected`` asks (a
-    reader outside the serving loop) ``[N, C, layers, ceil(P * bs / 32)]``
-    int32 in their place, the mask packed 32 positions a word
-    (``ops/dsa.py::pack_mask``).
 
-    A model with ``first_dense_layers`` runs those first, each from its own
-    ``params["dense_<i>"]``, then scans the routed stack ``params["layers"]``
-    with the pool in the carry; layer ``l``'s pages are rows ``l*NB ...`` of
-    the pool either way. With latent attention the pool is the latent pool
-    (:class:`PagedKVPool`) and attention runs absorbed (``_latent_attention``).
+def _row_writers(call: _Call):
+    """``(put_values, put_pages)`` where a block of positions is a page: values go a token's row at a time when
+    a sequence brings fewer tokens than a page holds (decode, drafts: a 4 KB row against a 64 KB page), a whole
+    page at a time when it brings a chunk of a prompt; scales are a lane-dense row a PAGE, so they always go a
+    page at a time (``put_pages``: None where nothing goes so)."""
+    by_page = call.positions.shape[1] >= call.bs
+    put_pages = None
+    if by_page or call.quant is not None:
+        put_pages = _page_writer(call.block_tables, call.positions, call.new_lens, call.bs, call.kv_rows)
 
-    A model with hyper-connections (``hc_mult > 0``) carries its ``hc_mult``
-    residual streams ``[n, N, C, E]`` through the layers, each sublayer reading
-    a learned mix of them and writing back through ``lp["attn_hc"]`` /
-    ``lp["mlp_hc"]`` (``ops/mhc.py``); they are summed before the selection.
+    def put_values(a, new, first_page):
+        if by_page:
+            return put_pages(a, new, first_page)
+        return a.at[first_page + call.w_page, call.w_slot].set(new, mode="drop")
 
-    ``all_positions=True`` returns the full ``[N, C, E]`` hidden states
-    instead of the last-token selection — the speculative verify step needs
-    a logit at EVERY draft position to accept/reject in one pass.
+    return put_values, put_pages
 
-    A row's ``positions`` are consecutive from ``positions[:, 0]`` (a chunk of
-    a prompt, one decode token, or a token and its drafts).
-    """
-    N, C = tokens.shape
-    bs = block_size
-    state = ring = None
-    if isinstance(pool, HybridPools):
-        pool, state = pool
-    elif isinstance(pool, RingPools):
-        pool, ring = pool
-    L = cfg.attention_layers  # the layers that hold pages: all, but in a layer pattern
-    NB = pool.k.shape[0] // L
-    valid = jnp.arange(C)[None, :] < new_lens[:, None]  # [N, C]
-    eva = cfg.eva_window > 0
-    if not eva:
-        # where each new token's row goes: (page of its layer-0 pool, slot in the
-        # page). Pad tokens get page L*NB, out of range in every layer: dropped.
-        page = jnp.take_along_axis(block_tables, positions // bs, axis=1)
-        w_page = jnp.where(valid, page, L * NB).reshape(-1)
-        w_slot = (positions % bs).reshape(-1)
 
-    with jax.named_scope("embed"):
-        x = _times(cfg.embedding_multiplier, jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(
-            jnp.float32 if cfg.fp32_residual else cfg.dtype))
-        if cfg.embed_norm:
-            x = _apply_norm(params["embed_norm"], cfg, x)
-        if cfg.position == "learned":
-            x = x + jnp.take(params["pos_embed"], positions, axis=0).astype(cfg.dtype)
-        if cfg.hc_mult:
-            x = mhc.spread(x, cfg.hc_mult)  # [n, N, C, E]: the carry's x is the streams
+def _plain_attention(cfg: TransformerConfig, call: _Call):
+    """An attention builder (``_ATTENTION``): keys and values by head, a block of positions a page."""
+    positions, new_lens, block_tables, bs = call[:4]
+    quant = call.quant
+    put_values, put_pages = _row_writers(call)
     alibi = None
     if cfg.position == "alibi":
         from deepspeed_tpu.models.transformer import alibi_slopes
 
         alibi = alibi_slopes(cfg.num_heads)
 
-    if "layers" not in params:
-        raise ValueError("ragged inference requires scan_layers=True stacked params")
-
-    quant = pool.quant  # static at trace time (value dtype + scale presence)
-    latent = cfg.latent_attention
-    routed = with_picks and cfg.num_experts > 0
-    D = cfg.first_dense_layers  # leading dense layers, run before the scan
-
-    # Values go in a token's row at a time when a sequence brings fewer
-    # tokens than a page holds (decode, drafts: a 4 KB row against a 64 KB
-    # page), and a whole page at a time when it brings a chunk of a prompt.
-    # Scales are a lane-dense row a PAGE, so they always go a page at a time.
-    by_page = C >= bs
-    put_pages = None
-    selected = []  # what an indexed layer's queries kept, noted by ``attention`` as a layer is traced
-    if eva:
-        eva_attend = _eva_attention(cfg, positions, new_lens, block_tables, bs, L * NB)
-    elif ring is not None:
-        NR = ring.k.shape[0] // cfg.sliding_layers  # ring pages a sliding layer
-        windowed = _windowed_attention(cfg, positions, new_lens, block_tables, bs, L * NB, ring.k.shape[0])
-    elif by_page or quant is not None:
-        put_pages = _page_writer(block_tables, positions, new_lens, bs, L * NB)
-
-    def put_values(a, new, first_page):
-        if by_page:
-            return put_pages(a, new, first_page)
-        return a.at[first_page + w_page, w_slot].set(new, mode="drop")
-
-    def attention(ap, h, pk, pv, psk, psv, first_page, kind="attention"):
-        if eva:
-            out, pk, pv = eva_attend(ap, h, pk, pv, first_page)
-            return out, pk, pv, psk, psv
-        if ring is not None:  # ``pk`` / ``pv``: the class of page this layer's kind writes
-            out, pk, pv = windowed(ap, h, kind, pk, pv, first_page)
-            return out, pk, pv, psk, psv
-        if cfg.index_topk:
-            out, pk, pv, kept = _indexed_latent_attention(ap, cfg, h, positions, new_lens, block_tables, bs,
-                                                          pk, pv, put_values, first_page, hand_mask=with_selected)
-            selected.append(kept)
-            return out, pk, pv, psk, psv
-        if latent:
-            out, pk = _latent_attention(ap, cfg, h, positions, new_lens, block_tables,
-                                        bs, pk, put_values, first_page)
-            return out, pk, pv, psk, psv
+    def attend(ap, h, pages, first_page, kind):
+        pk, pv, psk, psv = pages
         q, k, v = _qkv(ap, cfg, h)
         if cfg.attn_output_gate:  # a head's projection is [q | gate]
             q, gate = q[..., :v.shape[-1]], q[..., v.shape[-1]:]
@@ -962,7 +709,129 @@ def _forward_hidden(
         if cfg.attn_output_gate:
             with jax.named_scope("attn_gate"):
                 ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(ctx.dtype)
-        return _attn_out(ap, cfg, ctx), pk, pv, psk, psv
+        return _attn_out(ap, cfg, ctx), PagedKVPool(pk, pv, psk, psv), None
+
+    return attend
+
+
+def _latent_attention_of(cfg: TransformerConfig, call: _Call):
+    """An attention builder (``_ATTENTION``): ``_latent_attention`` against the latent pool, ``pages.k`` alone."""
+    put_values, _ = _row_writers(call)
+
+    def attend(ap, h, pages, first_page, kind):
+        out, pk = _latent_attention(ap, cfg, h, *call[:4], pages.k, put_values, first_page)
+        return out, pages._replace(k=pk), None
+
+    return attend
+
+
+def _indexed_attention_of(cfg: TransformerConfig, call: _Call):
+    """An attention builder (``_ATTENTION``): ``_indexed_latent_attention``, the latents in ``pages.k``, the
+    index keys in ``pages.v`` (``cache.PageClass.second_holds``); the third value is what the queries kept."""
+    put_values, _ = _row_writers(call)
+
+    def attend(ap, h, pages, first_page, kind):
+        out, pk, pv, kept = _indexed_latent_attention(ap, cfg, h, *call[:4], pages.k, pages.v, put_values,
+                                                      first_page, hand_mask=call.hand_mask)
+        return out, pages._replace(k=pk, v=pv), kept
+
+    return attend
+
+
+# ``cache.attention_kind(cfg)`` -> ``(cfg, the call's geometry) -> attend(ap, h, pages, first_page, kind) ->
+# (attention output [N, C, E], pages, what an indexed layer's queries kept or None)``: ``pages`` the
+# ``PagedKVPool`` of the class the layer's ``kind`` writes, ``first_page`` the layer's first page in it
+_ATTENTION = {"plain": _plain_attention, "latent": _latent_attention_of, "indexed": _indexed_attention_of,
+              "eva": _eva_attention, "windowed": _windowed_attention}
+
+
+def _forward_hidden(
+    params,
+    cfg: TransformerConfig,
+    pools: Pools,
+    tokens: jax.Array,  # [N, C] int32
+    positions: jax.Array,  # [N, C] int32
+    new_lens: jax.Array,  # [N] int32
+    block_tables: jax.Array,  # [N, P] int32
+    block_size: int,
+    all_positions: bool = False,
+    with_picks: bool = False,
+    with_selected: bool = False,
+) -> Tuple[jax.Array, ...]:
+    """One mixed prefill/decode layer-stack pass -> (last-token hidden [N, E],
+    pools). Shared by the single-step ``ragged_forward`` and the K-step
+    ``ragged_decode_chain`` — one definition of the serving transformer math.
+
+    ``with_picks=True`` on a routed model (``num_experts > 0``) returns a
+    third value, ``picks`` int32 ``[N, C, routed layers, k]``: the experts
+    each token fed was sent to in each routed layer, leading dense layers not
+    counted, by the experts' own numbers (pad tokens' entries are garbage).
+    A model with no routed layer returns the pair whatever is asked.
+
+    A model with a learned indexer (``index_topk > 0``) asked ``with_picks``
+    hands out BEFORE the picks what each query kept, where its block table
+    holds more tokens than a query keeps: for one token a row (``C == 1``)
+    ``selected`` int32 ``[N, layers, index_topk]``, the positions, -1 for
+    none; for a chunk ``[N, layers, 2]`` int32, the cached tokens a row's
+    queries scored and the ones they kept, and where ``with_selected`` asks (a
+    reader outside the serving loop) ``[N, C, layers, ceil(P * bs / 32)]``
+    int32 in their place, the mask packed 32 positions a word
+    (``ops/dsa.py::pack_mask``).
+
+    A model with ``first_dense_layers`` runs those first, each from its own
+    ``params["dense_<i>"]``, then scans the routed stack ``params["layers"]``
+    with the pools in the carry; layer ``l``'s pages are rows ``l*NB ...`` of
+    its class's arrays either way. How the layers attend is ONE choice made
+    before any is traced (``_ATTENTION``); a layer takes the pages of the class
+    its kind writes by name and gives them back by name.
+
+    A model with hyper-connections (``hc_mult > 0``) carries its ``hc_mult``
+    residual streams ``[n, N, C, E]`` through the layers, each sublayer reading
+    a learned mix of them and writing back through ``lp["attn_hc"]`` /
+    ``lp["mlp_hc"]`` (``ops/mhc.py``); they are summed before the selection.
+
+    ``all_positions=True`` returns the full ``[N, C, E]`` hidden states
+    instead of the last-token selection — the speculative verify step needs
+    a logit at EVERY draft position to accept/reject in one pass.
+
+    A row's ``positions`` are consecutive from ``positions[:, 0]`` (a chunk of
+    a prompt, one decode token, or a token and its drafts).
+    """
+    N, C = tokens.shape
+    bs = block_size
+    attends = attention_kind(cfg)
+    L = cfg.attention_layers  # the layers that hold pages of the first class: all, but in a layer pattern
+    NB = pools.kv.k.shape[0] // L
+    ring_rows = 0 if pools.ring is None else pools.ring.k.shape[0]
+    NR = ring_rows // max(cfg.sliding_layers, 1)  # ring pages a sliding layer
+    valid = jnp.arange(C)[None, :] < new_lens[:, None]  # [N, C]
+    w_page = w_slot = None
+    if attends != "eva":
+        # where each new token's row goes: (page of its layer-0 pool, slot in the
+        # page). Pad tokens get page L*NB, out of range in every layer: dropped.
+        page = jnp.take_along_axis(block_tables, positions // bs, axis=1)
+        w_page = jnp.where(valid, page, L * NB).reshape(-1)
+        w_slot = (positions % bs).reshape(-1)
+
+    with jax.named_scope("embed"):
+        x = _times(cfg.embedding_multiplier, jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(
+            jnp.float32 if cfg.fp32_residual else cfg.dtype))
+        if cfg.embed_norm:
+            x = _apply_norm(params["embed_norm"], cfg, x)
+        if cfg.position == "learned":
+            x = x + jnp.take(params["pos_embed"], positions, axis=0).astype(cfg.dtype)
+        if cfg.hc_mult:
+            x = mhc.spread(x, cfg.hc_mult)  # [n, N, C, E]: the carry's x is the streams
+
+    if "layers" not in params:
+        raise ValueError("ragged inference requires scan_layers=True stacked params")
+
+    routed = with_picks and cfg.num_experts > 0
+    indexed = attends == "indexed"  # its layers hand out, beside the picks, what their queries kept
+    D = cfg.first_dense_layers  # leading dense layers, run before the scan
+    # the ONE choice of how this model's layers attend, made here for all of them
+    attend = _ATTENTION[attends](cfg, _Call(positions, new_lens, block_tables, bs, L * NB, ring_rows,
+                                         pools.kv.quant, with_selected, w_page, w_slot))
 
     def ffn(lp, h, dense):
         """(output, picks or None): the dense MLP, or the routed layer with
@@ -988,40 +857,44 @@ def _forward_hidden(
     # Scopes for a device trace (HLO metadata only). ``pool_scan`` encloses
     # the layer scan; everything the body computes sits under ``layer`` (or
     # under ``kv_write`` or the kernel's own name inside it), so what reads
-    # ``pool_scan`` innermost is the scan's own traffic. The pool rides in
-    # the carry and is updated in place, so that should be next to nothing.
+    # ``pool_scan`` innermost is the scan's own traffic. The pools ride in
+    # the carry and are updated in place, so that should be next to nothing.
     # Inside ``layer`` each piece sits under the parameter key it reads
     # (``reading``): ``attn_norm``, ``attn`` > ``wq`` ..., ``mlp`` > ``w_up`` ...,
     # the names flax gives the same modules in training.
     @jax.named_scope("layer")
     def layer(carry, lp, first_page, dense=False, kind="attention"):
-        x, pk, pv, psk, psv = carry
+        x, pools = carry
+        held = "ring" if kind == "sliding_attention" else "kv"  # the class of page a layer of this kind writes
+
+        def attention(h):
+            with reading(lp, "attn") as ap:
+                out, pages, kept = attend(ap, h, getattr(pools, held), first_page, kind)
+            return out, pools._replace(**{held: pages}), kept
+
         if cfg.hc_mult:
             # ``x`` is the streams: the two adds of a one-stream block become a
             # mixed read before each sublayer and a write-back after it (``ops/mhc.py``)
             mixed, u = mixed_at(lp, "attn_hc", x)
-            with reading(lp, "attn") as ap:
-                attn_out, pk, pv, psk, psv = attention(ap, _norm_at(lp, "attn_norm", cfg, u),
-                                                       pk, pv, psk, psv, first_page)
+            attn_out, pools, _ = attention(_norm_at(lp, "attn_norm", cfg, u))
             x = written(lp, "attn_hc", x, attn_out, mixed)
             mixed, u = mixed_at(lp, "mlp_hc", x)
             out, picks = ffn(lp, _norm_at(lp, "mlp_norm", cfg, u), dense)
-            return (written(lp, "mlp_hc", x, out, mixed), pk, pv, psk, psv), picks
+            return (written(lp, "mlp_hc", x, out, mixed), pools), picks
         h = _norm_at(lp, "attn_norm", cfg, x)
-        with reading(lp, "attn") as ap:
-            attn_out, pk, pv, psk, psv = attention(ap, h, pk, pv, psk, psv, first_page, kind)
+        attn_out, pools, kept = attention(h)
         if cfg.parallel_block:
             # falcon/phi-style: attn and FFN read the shared input norm;
             # gpt-neox-style (parallel_mlp_norm): FFN reads its own ln2(x)
             out, picks = ffn(lp, _norm_at(lp, "mlp_norm", cfg, x) if cfg.parallel_mlp_norm else h, dense)
-            return (x + attn_out + out, pk, pv, psk, psv), picks
+            return (x + attn_out + out, pools), picks
         x = x + _times(cfg.residual_multiplier, attn_out)
         out, picks = ffn(lp, _norm_at(lp, "mlp_norm", cfg, x), dense)
-        if cfg.index_topk:  # (a static branch: beside the picks, what this layer's queries kept)
-            picks = (picks, selected.pop())
-        return (x + _times(cfg.residual_multiplier, out), pk, pv, psk, psv), picks
+        if indexed:
+            picks = (picks, kept)
+        return (x + _times(cfg.residual_multiplier, out), pools), picks
 
-    if state is not None:
+    if pools.state is not None:
         # a row fed from position 0 starts a sequence: whatever its slot holds is another's
         fresh = (positions[:, 0] == 0) & (new_lens > 0)
 
@@ -1030,7 +903,8 @@ def _forward_hidden(
         """A layer with recurrent state (Mamba-2 or Gated DeltaNet), the
         ``s``-th of its kind: its mixer reads and writes row ``s`` of the state
         pool and of the conv pool, in place (``StatePool``)."""
-        x, *kv, sp, cp = carry
+        x, pools = carry
+        sp, cp = pools.state
         key, sizes = ("ssm", cfg.ssm) if kind == "mamba" else ("gdn", cfg.gdn)
         h = _norm_at(lp, key + "_pre_norm", cfg, x)
         with reading(lp, key) as mp:
@@ -1057,7 +931,7 @@ def _forward_hidden(
             out = _dense(mp, key + "_out_proj", cfg, y)
         x = x + _times(cfg.residual_multiplier, out)
         out, picks = ffn(lp, _norm_at(lp, "mlp_norm", cfg, x), False)
-        return (x + _times(cfg.residual_multiplier, out), *kv, sp, cp), picks
+        return (x + _times(cfg.residual_multiplier, out), pools._replace(state=StatePool(sp, cp))), picks
 
     # The scanned stack WITHOUT its routed experts' leaves, which the body
     # closes over whole and names by the scan's index (``ExpertStack``): the
@@ -1074,22 +948,20 @@ def _forward_hidden(
 
     def period(carry, xs):
         """One period of a layer pattern, its layers unrolled: attention layer
-        ``a`` (counted among its kind) has the pages from ``a * NB``, state
-        layer ``s`` (Mamba-2 or Gated DeltaNet) row ``s`` of the state pool. A
-        routed pattern's picks come out a layer of the period, in its order."""
+        ``a`` (counted among its kind) has the pages from ``a * NB``, the
+        ``s``-th other layer the ring's pages from ``s * NR`` (a sliding one) or
+        row ``s`` of the state pool (Mamba-2 or Gated DeltaNet). A routed
+        pattern's picks come out a layer of the period, in its order."""
         pp, first_a, first_s, *index = xs
         a = s = 0
         picked = []
         for j, kind in enumerate(cfg.period):
             lp = _with_experts(pp[f"layer_{j}"], experts[f"layer_{j}"], *index)
             if kind == "attention":
-                (x, *kv), picks = layer(carry[:5], lp, (first_a + a) * NB)
-                carry = (x, *kv, *carry[5:])
+                carry, picks = layer(carry, lp, (first_a + a) * NB)
                 a += 1
-            elif kind == "sliding_attention":  # the ``s``-th sliding layer: its pages are the ring pool's
-                x, *kv, rk, rv = carry
-                (x, rk, rv, _, _), picks = layer((x, rk, rv, None, None), lp, (first_s + s) * NR, kind=kind)
-                carry = (x, *kv, rk, rv)
+            elif kind == "sliding_attention":
+                carry, picks = layer(carry, lp, (first_s + s) * NR, kind=kind)
                 s += 1
             else:
                 carry, picks = state_layer(carry, lp, first_s + s, kind)
@@ -1097,41 +969,35 @@ def _forward_hidden(
             picked.append(picks)
         return carry, jnp.stack(picked) if routed else None
 
-    carry = (x, *pool)
+    carry = (x, pools)
     kept_dense = []
     for i in range(D):
         # a leading dense layer of a routed model: its own parameters, its own
         # pages (layer i's), outside the scan
         carry, out = layer(carry, params[f"dense_{i}"], jnp.int32(i * NB), dense=True)
-        if cfg.index_topk:
+        if indexed:
             kept_dense.append(out[1])
     with jax.named_scope("pool_scan"):
         if cfg.layer_types is not None:
             kinds = cfg.period
             periods = jnp.arange(cfg.num_layers // len(kinds), dtype=jnp.int32)
-            (x, *pool), picks = jax.lax.scan(
-                period, carry + tuple(state or ()) + (tuple(ring[:2]) if ring is not None else ()),
+            (x, pools), picks = jax.lax.scan(
+                period, carry,
                 (layers, periods * kinds.count("attention"),
                  periods * (len(kinds) - kinds.count("attention"))) + ((periods,) if stacked else ()))
             if routed:  # [periods, layers of a period, N*C, k]: the layers in the model's order
                 picks = picks.reshape((cfg.num_layers,) + picks.shape[2:])
         else:
-            (x, *pool), picks = jax.lax.scan(
+            (x, pools), picks = jax.lax.scan(
                 lambda c, xs: layer(c, _with_experts(xs[0], experts, *xs[2:]), xs[1]), carry,
                 (layers, jnp.arange(D, L, dtype=jnp.int32) * NB)
                 + ((jnp.arange(L - D, dtype=jnp.int32),) if stacked else ()))
     kept = None
-    if cfg.index_topk:
+    if indexed:
         picks, kept = picks  # the scanned layers': [layers - D, N, ...]
         if kept is not None:  # every layer's, a row: [N, (C,) layers, ...]
             kept = jnp.concatenate([jnp.stack(kept_dense), kept]) if D else kept
             kept = jnp.moveaxis(kept, 0, 2 if kept.ndim == 4 else 1)  # (a chunk's masks: [layers, N, C, words])
-    if state is not None:
-        pool = HybridPools(PagedKVPool(*pool[:4]), StatePool(*pool[4:]))
-    elif ring is not None:
-        pool = RingPools(PagedKVPool(*pool[:4]), PagedKVPool(*pool[4:]))
-    else:
-        pool = PagedKVPool(*pool)
     if cfg.hc_mult:
         x = mhc.collapse(x)  # the streams summed, before the last-token selection and the head
     # picks: [routed layers, N*C, k] -> [N, C, routed layers, k]
@@ -1142,8 +1008,8 @@ def _forward_hidden(
             x, jnp.maximum(new_lens - 1, 0)[:, None, None], axis=1
         )[:, 0]  # [N, E]
     if routed and kept is not None:
-        return x, pool, kept, picks
-    return (x, pool, picks) if routed else (x, pool)
+        return x, pools, kept, picks
+    return (x, pools, picks) if routed else (x, pools)
 
 
 def _split_experts(lp):
@@ -1164,7 +1030,7 @@ def _with_experts(lp, stack, index=None):
 def ragged_forward(
     params,
     cfg: TransformerConfig,
-    pool: PagedKVPool,
+    pools: Pools,
     tokens: jax.Array,  # [N, C] int32
     positions: jax.Array,  # [N, C] int32
     new_lens: jax.Array,  # [N] int32
@@ -1173,7 +1039,7 @@ def ragged_forward(
     with_picks: bool = False,
     with_selected: bool = False,
 ) -> Tuple[jax.Array, ...]:
-    """One mixed prefill/decode step -> (last-token logits [N, V], pool), and
+    """One mixed prefill/decode step -> (last-token logits [N, V], pools), and
     for a routed model asked ``with_picks`` the picks ``[N, C, routed layers,
     k]`` as the LAST value, after what an indexed model's queries kept where
     it hands that out (``_forward_hidden``).
@@ -1184,16 +1050,16 @@ def ragged_forward(
     LM head run on the [N, E] last-token hiddens only (norm is positionwise,
     so selecting first is the same math at 1/C the head cost).
     """
-    last, pool, *picks = _forward_hidden(
-        params, cfg, pool, tokens, positions, new_lens, block_tables, block_size,
+    last, pools, *picks = _forward_hidden(
+        params, cfg, pools, tokens, positions, new_lens, block_tables, block_size,
         with_picks=with_picks, with_selected=with_selected)
-    return (_logits(params, cfg, last), pool, *picks)
+    return (_logits(params, cfg, last), pools, *picks)
 
 
 def ragged_decode_chain(
     params,
     cfg: TransformerConfig,
-    pool: PagedKVPool,
+    pools: Pools,
     tokens: jax.Array,  # [N] int32 — last sampled token per row (next input)
     start_pos: jax.Array,  # [N] int32 — global position of that input token
     block_tables: jax.Array,  # [N, P] int32, pre-extended for the K-token window
@@ -1223,7 +1089,7 @@ def ragged_decode_chain(
     are -1.
 
     Returns ``(out_tokens [N, K], emitted [N], active [N], tok [N], pos [N],
-    rng, pool)`` where ``out_tokens[i, :emitted[i]]`` are valid and
+    rng, pools)`` where ``out_tokens[i, :emitted[i]]`` are valid and
     ``emitted[i]`` is also the number of KV slots row i consumed (==
     seen_tokens advance).
 
@@ -1264,10 +1130,10 @@ def ragged_decode_chain(
     """
 
     def step(carry, _):
-        pool, tok, pos, live, emitted, key = carry
+        pools, tok, pos, live, emitted, key = carry
         new_lens = live.astype(jnp.int32)
-        last, pool, *picks = _forward_hidden(
-            params, cfg, pool, tok[:, None], pos[:, None], new_lens,
+        last, pools, *picks = _forward_hidden(
+            params, cfg, pools, tok[:, None], pos[:, None], new_lens,
             block_tables, block_size, with_picks=with_picks)
         logits = _logits(params, cfg, last)
         key, sub = jax.random.split(key)
@@ -1278,7 +1144,7 @@ def ragged_decode_chain(
         still = live & (emitted < budgets)
         if eos_id is not None:
             still = still & (nxt != eos_id)
-        carry = (pool, jnp.where(live, nxt, tok), pos + new_lens, still, emitted, key)
+        carry = (pools, jnp.where(live, nxt, tok), pos + new_lens, still, emitted, key)
         if not picks:
             return carry, out
         picked = picks[-1][:, 0]  # [N, routed layers, k]
@@ -1296,14 +1162,14 @@ def ragged_decode_chain(
             return carry, (out, touched, jnp.stack([scored, kept]).astype(jnp.int32), picked)
         return carry, (out, touched, picked)
 
-    carry0 = (pool, tokens, start_pos, active & (budgets > 0),
+    carry0 = (pools, tokens, start_pos, active & (budgets > 0),
               jnp.zeros_like(start_pos), rng)
-    (pool, tok, pos, active, emitted, rng), outs = jax.lax.scan(
+    (pools, tok, pos, active, emitted, rng), outs = jax.lax.scan(
         step, carry0, None, length=k_steps)
     if isinstance(outs, tuple):
         outs, *routed = outs  # touched, (an indexed model's tokens scored and kept a step,) picks
-        return (outs.T, emitted, active, tok, pos, rng, pool, *routed)
-    return outs.T, emitted, active, tok, pos, rng, pool
+        return (outs.T, emitted, active, tok, pos, rng, pools, *routed)
+    return outs.T, emitted, active, tok, pos, rng, pools
 
 
 class MigrationBuffer(NamedTuple):
@@ -1431,7 +1297,7 @@ def _ngram_propose(hist: jax.Array, hist_len: jax.Array, n_spec: int,
 def ragged_spec_decode_chain(
     params,
     cfg: TransformerConfig,
-    pool: PagedKVPool,
+    pools: Pools,
     tokens: jax.Array,  # [N] int32 — last sampled token per row (next input)
     start_pos: jax.Array,  # [N] int32 — global position of that input token
     block_tables: jax.Array,  # [N, P], pre-extended for window + n_spec slack
@@ -1446,7 +1312,7 @@ def ragged_spec_decode_chain(
     *,
     n_spec: int,
     ngram: int = 2,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array, PagedKVPool]:
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array, Pools]:
     """Speculative K-step decode chain: greedy verify-and-accept over n-gram
     drafts, still ONE dispatch + ONE host sync per chain.
 
@@ -1466,7 +1332,7 @@ def ragged_spec_decode_chain(
     tokens (see ``InferenceEngineV2.decode_spec_chain``).
 
     Returns ``(out_tokens [N, k_steps*(1+n_spec)] compacted, emitted [N],
-    active [N], steps [N], rng, pool)`` — ``out_tokens[i, :emitted[i]]``
+    active [N], steps [N], rng, pools)`` — ``out_tokens[i, :emitted[i]]``
     valid, ``steps[i]`` = model forwards row i was live for (the
     accepted-tokens/forward telemetry denominator).
     """
@@ -1475,12 +1341,12 @@ def ragged_spec_decode_chain(
     idx = jnp.arange(m)[None, :]
 
     def step(carry, _):
-        pool, tok, pos, live, emitted, hist, hlen, steps, key = carry
+        pools, tok, pos, live, emitted, hist, hlen, steps, key = carry
         drafts = _ngram_propose(hist, hlen, n_spec, ngram)  # [N, n_spec]
         inputs = jnp.concatenate([tok[:, None], drafts], axis=1)  # [N, m]
         positions = pos[:, None] + jnp.arange(m)[None, :]
         new_lens = jnp.where(live, m, 0)
-        hs, pool = _forward_hidden(params, cfg, pool, inputs, positions,
+        hs, pools = _forward_hidden(params, cfg, pools, inputs, positions,
                                    new_lens, block_tables, block_size,
                                    all_positions=True)
         logits = _logits(params, cfg, hs)  # [N, m, V]
@@ -1505,13 +1371,13 @@ def ragged_spec_decode_chain(
         emitted = emitted + e
         still = live & (emitted < budgets) & ~has_eos
         steps = steps + live.astype(jnp.int32)
-        return (pool, jnp.where(live, nxt, tok), pos + e, still, emitted,
+        return (pools, jnp.where(live, nxt, tok), pos + e, still, emitted,
                 hist, hlen + e, steps, key), out
 
     zeros = jnp.zeros_like(start_pos)
-    carry0 = (pool, tokens, start_pos, active, zeros, history, hist_len,
+    carry0 = (pools, tokens, start_pos, active, zeros, history, hist_len,
               zeros, rng)
-    (pool, _, _, active, emitted, _, _, steps, rng), outs = jax.lax.scan(
+    (pools, _, _, active, emitted, _, _, steps, rng), outs = jax.lax.scan(
         step, carry0, None, length=k_steps)
     # compact: each iteration's emitted prefix packs to the row's front, so
     # the host contract stays out[i, :emitted[i]] exactly like the plain chain
@@ -1520,4 +1386,4 @@ def ragged_spec_decode_chain(
     tgt = jnp.where(valid, jnp.cumsum(valid, axis=1) - 1, k_steps * m)
     compact = jnp.full((N, k_steps * m), -1, jnp.int32)
     compact = compact.at[jnp.arange(N)[:, None], tgt].set(o, mode="drop")
-    return compact, emitted, active, steps, rng, pool
+    return compact, emitted, active, steps, rng, pools

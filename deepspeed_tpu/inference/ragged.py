@@ -33,6 +33,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from deepspeed_tpu.inference.cache import PlainLayout
+
 
 class BlockedAllocator:
     """O(1)-per-block free-list allocator for KV-cache blocks (reference
@@ -134,7 +136,6 @@ class BlockedAllocator:
             for b in lst[:i]:
                 refs[b] -= 1
             raise
-        return None
 
     def release(self, blocks: Sequence[int]) -> int:
         """Drop one holder from each block; blocks reaching zero holders
@@ -166,148 +167,44 @@ class BlockedAllocator:
         return len(freed)
 
 
-@dataclasses.dataclass(frozen=True)
-class WindowLayout:
-    """An EVA row's block table (``paged._eva_attention`` is the device's
-    reading of the same columns): ``summary_cols`` columns of summary pages,
-    ``per_closed`` a closed window in the windows' order, then
-    ``window_pages`` columns for the open window's exact rows, position ``t``
-    in page ``(t % window) // block_size``. A page of exact
-    rows pools into one summary row (``block_size`` is the model's chunk), so
-    a closing adds ``per_closed`` pages and frees the window's own behind it.
-    """
-
-    window: int
-    block_size: int
-    max_seq_len: int
-
-    @property
-    def window_pages(self) -> int:
-        return self.window // self.block_size
-
-    @property
-    def per_closed(self) -> int:
-        return self.window // self.block_size // self.block_size
-
-    @property
-    def summary_cols(self) -> int:
-        return -(-self.max_seq_len // self.window) * self.per_closed
-
-    @property
-    def width(self) -> int:
-        return self.summary_cols + self.window_pages
-
-    def pages(self, seen: int, new: int) -> Tuple[int, int]:
-        """(summary pages, window pages) a row must hold while ``new`` tokens
-        are fed to it after ``seen``. A fresh row's tokens are a chunk, which
-        stores the summaries of the windows it closes and the rows of the one
-        it leaves open; after that tokens come one at a time (a decode chain's
-        steps) and fill the open window to its end before the next one writes
-        over its pages."""
-        chunk = new > 1 and not seen
-        rows = new % self.window if chunk else min(seen % self.window + new, self.window)
-        return (seen + new) // self.window * self.per_closed, -(-rows // self.block_size)
-
-    def attended(self, position):
-        """Rows the token at ``position`` (a number or an array of them)
-        attends to: the summaries of the closed windows and its own window's
-        rows up to itself."""
-        return (position // self.window * (self.window // self.block_size)
-                + position % self.window + 1)
-
-
-def ring_columns(window: int, block_size: int) -> int:
-    """Pages that hold ``window`` consecutive positions wherever they start: the ring a row keeps of a sliding
-    layer (``RingLayout`` on the host, ``paged._windowed_attention`` on the device)."""
-    return -(-window // block_size) + 1
-
-
-@dataclasses.dataclass(frozen=True)
-class RingLayout:
-    """A row's block table under a sliding kind (``paged.RingPools`` and
-    ``paged._windowed_attention`` are the device's reading of the same
-    columns): ``summary_cols`` GLOBAL columns, one a block of positions, whose
-    pages are the full-attention layers' and grow with the context, then
-    ``window_pages`` RING columns, whose pages are the sliding layers': block
-    ``b`` in ring column ``b % window_pages``, written over when block ``b +
-    window_pages`` arrives. The two classes of page come from two free lists
-    (``StateManager.allocator`` and ``.ring_allocator``) and index two arrays.
-    A row takes ring pages as its context grows to a window and keeps them to
-    its flush: nothing is freed behind a ring and nothing moves.
-
-    The names ``summary_cols`` / ``window_pages`` are :class:`WindowLayout`'s for
-    the same two places of a row's table, so :class:`SequenceDescriptor` holds
-    both layouts with one set of fields."""
-
-    window: int
-    block_size: int
-    max_seq_len: int
-
-    @property
-    def window_pages(self) -> int:
-        """Ring columns (``ring_columns``)."""
-        return ring_columns(self.window, self.block_size)
-
-    @property
-    def summary_cols(self) -> int:
-        return -(-self.max_seq_len // self.block_size)
-
-    @property
-    def width(self) -> int:
-        return self.summary_cols + self.window_pages
-
-    def pages(self, seen: int, new: int) -> Tuple[int, int]:
-        """(global pages, ring pages) a row holds once ``new`` tokens are fed after ``seen``."""
-        blocks = -(-(seen + new) // self.block_size)
-        return blocks, min(blocks, self.window_pages)
-
-    def overwritten(self, seen: int, new: int) -> int:
-        """Ring pages that ``new`` tokens after ``seen`` start writing over: the
-        blocks they open past the ring's first round."""
-        past = [max(-(-n // self.block_size) - self.window_pages, 0) for n in (seen, seen + new)]
-        return past[1] - past[0]
-
-
 @dataclasses.dataclass
 class SequenceDescriptor:
     """Per-sequence tracking (reference ``DSSequenceDescriptor``).
 
-    The block table is a preallocated int32 row (``_table[:n_blocks]``) so
-    batch assembly copies it with one vectorized write. Under a
-    :class:`WindowLayout` the row is the layout's whole width, its live pages
+    The block table is a preallocated int32 row, the layout's whole width
+    (``cache.PlainLayout``, ``WindowLayout`` or ``RingLayout``): its live pages
     are the first ``n_summary`` columns and ``n_window`` columns from
-    ``summary_cols`` on, and ``n_blocks`` is their sum.
+    ``summary_cols`` on, and ``n_blocks`` is their sum. A row without a window
+    or a ring has the first group alone, a block of positions a column.
     """
 
     uid: int
+    layout: Any
+    _table: np.ndarray
     seen_tokens: int = 0
     n_blocks: int = 0
-    _table: np.ndarray = dataclasses.field(
-        default_factory=lambda: np.zeros((8,), np.int32))
-    layout: Optional[WindowLayout] = None
     n_summary: int = 0
     n_window: int = 0
     # a model with recurrent state: the sequence's slot of the state pool
-    # (``paged.StatePool``), which is also its ROW in every program it is fed to
+    # (``cache.StatePool``), which is also its ROW in every program it is fed to
     slot: Optional[int] = None
 
     @property
     def blocks(self) -> np.ndarray:
-        """Live block ids (view — do not mutate; a copy under a layout)."""
-        if self.layout is not None:
-            first = self.layout.summary_cols
-            return np.concatenate([self._table[: self.n_summary],
-                                   self._table[first: first + self.n_window]])
-        return self._table[: self.n_blocks]
+        """Live block ids (a view where the first group holds them all — do not mutate; else a copy)."""
+        if not self.n_window:
+            return self._table[: self.n_summary]
+        first = self.layout.summary_cols
+        return np.concatenate([self._table[: self.n_summary], self._table[first: first + self.n_window]])
 
     def table_into(self, row: np.ndarray) -> None:
-        """Under a layout: the block table as the device reads it, every
-        column of it, into ``row``."""
-        row[: len(self._table)] = self._table
+        """The block table as the device reads it, into ``row`` (zeros: no dead column holds a page)."""
+        first = self.layout.summary_cols
+        row[: self.n_summary] = self._table[: self.n_summary]
+        row[first: first + self.n_window] = self._table[first: first + self.n_window]
 
-    def hold(self, fresh: np.ndarray, summary: int, window: int) -> None:
-        """Under a layout: take ``fresh`` pages so that the row holds
-        ``summary`` summary pages and ``window`` window pages."""
+    def hold(self, fresh: np.ndarray, summary: int) -> None:
+        """Take ``fresh`` pages: the first group's up to ``summary`` columns, the rest the second's."""
         first = self.layout.summary_cols
         more = max(summary - self.n_summary, 0)
         self._table[self.n_summary: self.n_summary + more] = fresh[:more]
@@ -317,7 +214,7 @@ class SequenceDescriptor:
         self.n_blocks = self.n_summary + self.n_window
 
     def pages_behind(self, keep: int) -> np.ndarray:
-        """Under a layout: give up the window pages past the first ``keep``."""
+        """Give up the second group's pages past the first ``keep``."""
         first = self.layout.summary_cols
         gone = self._table[first + keep: first + self.n_window].copy()
         self._table[first + keep: first + self.n_window] = 0
@@ -326,43 +223,38 @@ class SequenceDescriptor:
         return gone
 
     def append_blocks(self, new: np.ndarray) -> None:
-        need = self.n_blocks + len(new)
-        if need > len(self._table):
-            cap = max(need, 2 * len(self._table))
-            table = np.zeros((cap,), np.int32)
-            table[: self.n_blocks] = self._table[: self.n_blocks]
-            self._table = table
-        self._table[self.n_blocks: need] = new
-        self.n_blocks = need
-
-    def blocks_needed(self, new_tokens: int, block_size: int) -> int:
-        total = self.seen_tokens + new_tokens
-        need = -(-total // block_size)  # ceil
-        return max(0, need - self.n_blocks)
+        """Pages somebody else filled, in position order (a prefix hit's, a migration's): the first group's."""
+        self.hold(new, self.n_summary + len(new))
 
 
 class StateManager:
     """uid -> sequence state + block accounting (reference ``DSStateManager``
-    inference/v2/ragged/ragged_manager.py:19)."""
+    inference/v2/ragged/ragged_manager.py:19): ONE path whatever the model
+    caches. The layout (``cache.CachePlan.layout``; none given: a block of
+    positions a column, ``max_blocks_per_seq`` or the pool's worth) says how
+    many pages of each class a row holds, and there is an allocator a class:
+    ``allocators[0]`` the one every model has (the prefix cache's and a
+    migration's), ``allocators[1]`` a ``RingLayout``'s ring, ``ring_blocks`` pages."""
 
-    def __init__(self, num_blocks: int, block_size: int, max_seqs: int = 256,
-                 max_blocks_per_seq: Optional[int] = None,
-                 layout: Optional[WindowLayout] = None, state_slots: Optional[int] = None,
-                 ring_blocks: Optional[int] = None):
-        self.allocator = BlockedAllocator(num_blocks)
-        # the second class of page, under a RingLayout: the sliding layers' (``paged.RingPools.ring``)
-        self.ring_allocator = BlockedAllocator(ring_blocks) if isinstance(layout, RingLayout) else None
+    def __init__(self, num_blocks: int, block_size: int, max_seqs: int = 256, max_blocks_per_seq: Optional[int] = None,
+                 layout: Any = None, state_slots: Optional[int] = None, ring_blocks: Optional[int] = None):
+        self.layout = layout or PlainLayout(block_size, (max_blocks_per_seq or num_blocks) * block_size)
+        sizes = (num_blocks, ring_blocks)[: max(self.layout.classes) + 1]
+        self.allocators = tuple(BlockedAllocator(n) for n in sizes)
         self.block_size = block_size
         self.max_seqs = max_seqs
-        self.max_blocks_per_seq = max_blocks_per_seq
-        self.layout = layout  # None: a row's page is position // block_size
         self._seqs: Dict[int, SequenceDescriptor] = {}
-        # Recurrent state (``paged.StatePool``): a sequence takes the LOWEST free
+        # Recurrent state (``cache.StatePool``): a sequence takes the LOWEST free
         # slot with its descriptor and gives it back at its flush, finished or
         # preempted. Its slot is its row in every program, so the lowest keeps
         # the programs' row buckets small. None: the model keeps no such state.
         self.state_slots = state_slots
         self._free_slots: Optional[List[int]] = None if state_slots is None else list(range(state_slots))
+
+    @property
+    def allocator(self) -> BlockedAllocator:
+        """The first class's: the pages every model has."""
+        return self.allocators[0]
 
     @property
     def n_active(self) -> int:
@@ -386,14 +278,12 @@ class StateManager:
         if uid not in self._seqs:
             if len(self._seqs) >= self.max_seqs:
                 raise RuntimeError(f"max_seqs={self.max_seqs} active sequences reached")
-            cap = self.layout.width if self.layout else self.max_blocks_per_seq or 8
             slot = None
             if self._free_slots is not None:
                 if not self._free_slots:
                     raise RuntimeError(f"no free state slot: all {self.state_slots} hold a sequence")
                 slot = heapq.heappop(self._free_slots)
-            self._seqs[uid] = SequenceDescriptor(uid, _table=np.zeros((cap,), np.int32),
-                                                 layout=self.layout, slot=slot)
+            self._seqs[uid] = SequenceDescriptor(uid, self.layout, np.zeros((self.layout.width,), np.int32), slot=slot)
         return self._seqs[uid]
 
     @property
@@ -405,114 +295,74 @@ class StateManager:
         a multiple of ``row_bucket``). A sequence with a state slot is fed in
         the row of that number, so that a state-space layer updates ONE slice of
         the state pool; any other takes the rows in the order given."""
-        if self._free_slots is None:
-            at = np.arange(len(uids))
-        else:
-            at = np.fromiter((self._seqs[u].slot for u in uids), dtype=np.int64, count=len(uids))
+        at = np.arange(len(uids)) if self._free_slots is None else np.fromiter(
+            (self._seqs[u].slot for u in uids), dtype=np.int64, count=len(uids))
         return at, _round_up(int(at.max(initial=-1)) + 1, row_bucket)
 
-    def _short_by_class(self, seq: Optional[SequenceDescriptor], new_tokens: int) -> Tuple[int, int, int, int]:
-        """Under a layout: (summary or global pages to allocate, window or ring
-        pages to allocate, and how many of each the row then holds) for feeding
-        ``new_tokens`` to ``seq`` (None: a fresh one)."""
-        seen, have_s, have_w = (seq.seen_tokens, seq.n_summary, seq.n_window) if seq else (0, 0, 0)
-        want_s, want_w = self.layout.pages(seen, new_tokens)
-        return max(want_s - have_s, 0), max(want_w - have_w, 0), want_s, want_w
-
-    def _pages_short(self, seq: Optional[SequenceDescriptor], new_tokens: int) -> Tuple[int, int, int]:
-        """Under a layout of ONE class of page: (pages to allocate, summary pages, window pages)."""
-        short_s, short_w, want_s, want_w = self._short_by_class(seq, new_tokens)
-        return short_s + short_w, want_s, want_w
-
     def can_schedule(self, uids: Sequence[int], token_counts: Sequence[int]) -> bool:
-        """Admission check (reference ``InferenceEngineV2.can_schedule`` :184)."""
-        need = 0
-        fresh = 0
-        if self.ring_allocator is not None:
-            need_ring = 0
-            for uid, n in zip(uids, token_counts):
-                seq = self._seqs.get(uid)
-                fresh += seq is None
-                if (seq.seen_tokens if seq else 0) + n > self.layout.max_seq_len:
-                    return False  # sequence would exceed engine max_seq_len
-                short = self._short_by_class(seq, n)
-                need, need_ring = need + short[0], need_ring + short[1]
-            return (len(self._seqs) + fresh <= self.max_seqs and need <= self.allocator.free_blocks
-                    and need_ring <= self.ring_allocator.free_blocks)
-        if self.layout is not None:
-            for uid, n in zip(uids, token_counts):
-                seq = self._seqs.get(uid)
-                fresh += seq is None
-                if (seq.seen_tokens if seq else 0) + n > self.layout.max_seq_len:
-                    return False  # sequence would exceed engine max_seq_len
-                need += self._pages_short(seq, n)[0]
-            return (len(self._seqs) + fresh <= self.max_seqs
-                    and need <= self.allocator.free_blocks)  # (no layout has state slots)
+        """Admission check (reference ``InferenceEngineV2.can_schedule`` :184). The serving loop asks it of a
+        growing list at every admission, so a row costs a dict lookup, one ``pages()`` and a few compares."""
+        need_s = need_w = fresh = 0  # pages short in each group of columns
+        seqs, pages, capacity = self._seqs, self.layout.pages, self.layout.capacity
         for uid, n in zip(uids, token_counts):
-            seq = self._seqs.get(uid)
+            seq = seqs.get(uid)
             if seq is None:
                 fresh += 1
-                total_blocks = -(-n // self.block_size)
-                need += total_blocks
+                seen = have_s = have_w = 0
             else:
-                total_blocks = seq.n_blocks + seq.blocks_needed(n, self.block_size)
-                need += seq.blocks_needed(n, self.block_size)
-            if self.max_blocks_per_seq is not None and total_blocks > self.max_blocks_per_seq:
+                seen, have_s, have_w = seq.seen_tokens, seq.n_summary, seq.n_window
+            if seen + n > capacity:
                 return False  # sequence would exceed engine max_seq_len
+            want_s, want_w = pages(seen, n)
+            if want_s > have_s:
+                need_s += want_s - have_s
+            if want_w > have_w:
+                need_w += want_w - have_w
+        need = [0] * len(self.allocators)
+        need[self.layout.classes[0]] += need_s
+        need[self.layout.classes[1]] += need_w
         if len(self._seqs) + fresh > self.max_seqs:
             return False
         if self._free_slots is not None and fresh > len(self._free_slots):
             return False  # pages for it, and no state slot
-        return need <= self.allocator.free_blocks
+        return all(n <= a.free_blocks for n, a in zip(need, self.allocators))
 
     def extend(self, uid: int, new_tokens: int) -> SequenceDescriptor:
-        """Ensure blocks exist for ``new_tokens`` more tokens of ``uid``."""
+        """Ensure blocks exist for ``new_tokens`` more tokens of ``uid``, each group's from its own class."""
         seq = self.get_or_create(uid)
-        if self.ring_allocator is not None:
-            short_g, short_r, want_g, want_r = self._short_by_class(seq, new_tokens)
-            if short_g:  # (``hold`` lays fresh pages over the columns of the class that is short)
-                seq.hold(self.allocator.allocate(short_g), want_g, seq.n_window)
-            if short_r:
-                seq.hold(self.ring_allocator.allocate(short_r), seq.n_summary, want_r)
-            return seq
-        if self.layout is not None:
-            need, summary, window = self._pages_short(seq, new_tokens)
-            if need:
-                seq.hold(self.allocator.allocate(need), summary, window)
-            return seq
-        need = seq.blocks_needed(new_tokens, self.block_size)
-        if need:
-            seq.append_blocks(self.allocator.allocate(need))
+        want_s, want_w = self.layout.pages(seq.seen_tokens, new_tokens)
+        first, second = self.layout.classes
+        if want_s > seq.n_summary:  # (``hold`` lays fresh pages over the columns of the group that is short)
+            seq.hold(self.allocators[first].allocate(want_s - seq.n_summary), want_s)
+        if want_w > seq.n_window:
+            seq.hold(self.allocators[second].allocate(want_w - seq.n_window), seq.n_summary)
         return seq
 
     def advance(self, uid: int, tokens: int) -> int:
-        """Under a layout: ``uid`` has been fed ``tokens`` more. Returns how
-        many windows that closed, and gives the allocator back the window
-        pages behind the last one: the pooled rows are summaries now, and the
-        open window holds only what came after."""
+        """``uid`` has been fed ``tokens`` more. Returns how many windows that closed (``WindowLayout``), and
+        gives their allocator back the pages behind the last one."""
         seq = self._seqs[uid]
         before = seq.seen_tokens
         seq.seen_tokens = before + tokens
-        closed = seq.seen_tokens // self.layout.window - before // self.layout.window
-        if closed:
-            keep = -(-(seq.seen_tokens % self.layout.window) // self.block_size)
-            if keep < seq.n_window:
-                self.allocator.release(seq.pages_behind(keep))
+        closed, keep = self.layout.closed(before, seq.seen_tokens)
+        if closed and keep < seq.n_window:
+            self.allocators[self.layout.classes[1]].release(seq.pages_behind(keep))
         return closed
 
     def flush(self, uid: int) -> None:
         """Release a finished sequence (reference ``flush_uid`` engine_v2.py).
         Refcount-aware: blocks the prefix cache still holds stay allocated
         (the sequence drops its reference); exclusively-owned blocks return
-        to the free stack — identical to ``free`` when nothing is shared."""
+        to the free stack — identical to ``free`` when nothing is shared.
+        Each group's pages go back to their own class's free list."""
         seq = self._seqs.pop(uid, None)
-        if seq is not None and self.ring_allocator is not None:
-            first = self.layout.summary_cols  # both classes back, each to its own free list
-            self.allocator.release(seq._table[: seq.n_summary])
-            self.ring_allocator.release(seq._table[first: first + seq.n_window])
-        elif seq is not None and seq.n_blocks:
-            self.allocator.release(seq.blocks)
-        if seq is not None and seq.slot is not None:
+        if seq is None:
+            return
+        first = self.layout.summary_cols
+        for cls, pages in zip(self.layout.classes, (seq._table[: seq.n_summary],
+                                                    seq._table[first: first + seq.n_window])):
+            self.allocators[cls].release(pages)
+        if seq.slot is not None:
             heapq.heappush(self._free_slots, seq.slot)  # as it stands: the next sequence starts from zeros
 
 
@@ -683,12 +533,6 @@ class PrefixCache:
             key = k
         return added
 
-    def entry_for_block(self, block: int) -> Optional[_PrefixEntry]:
-        for e in self._entries.values():
-            if e.block == block:
-                return e
-        return None
-
     # --------------------------------------------------------------- eviction
     def evict_one(self) -> bool:
         """Release the LRU entry's block reference. Returns False when
@@ -775,12 +619,8 @@ class BatchStaging:
         else:
             self.reuses += 1
             d = self._dirty_rows.get(key, rows)
-            if d:  # zero only the rows the previous step touched
-                b["tokens"][:d] = 0
-                b["positions"][:d] = 0
-                b["new_lens"][:d] = 0
-                b["block_tables"][:d] = 0
-                b["seen"][:d] = 0
+            for a in b.values():  # zero only the rows the previous step touched
+                a[:d] = 0
         return b
 
     def mark_dirty(self, rows: int, chunk: int, used_rows: int) -> None:
@@ -811,71 +651,32 @@ def build_ragged_batch(
     assert n == len(token_lists) and n > 0
     lens = np.fromiter((len(t) for t in token_lists), dtype=np.int64, count=n)
     chunk = _round_up(max(int(lens.max()), 1), chunk_bucket)
-    if isinstance(manager.layout, RingLayout) and lens.max() <= 1:
-        chunk = 1  # a call of single tokens reads the ring and the table; a wider call attends inside its chunks alone
-    # --- block allocation: one vectorized allocator call for the whole step
+    if manager.layout.one_token_program and lens.max() <= 1:
+        chunk = 1
     seqs = [manager.get_or_create(uid) for uid in uids]
     at, rows = slice(0, n), _round_up(n, row_bucket)
     if manager.state_slots is not None:
         at, rows = manager.rows_of(uids, row_bucket)  # a sequence's row is its state slot
     used = n if isinstance(at, slice) else int(at.max()) + 1
 
-    if staging is not None:
-        buf = staging.acquire(rows, chunk)
-        if staging.max_pages != max_pages:
-            raise ValueError(
-                f"staging max_pages={staging.max_pages} != requested {max_pages}")
-        tokens, positions = buf["tokens"], buf["positions"]
-        new_lens, block_tables, seen = buf["new_lens"], buf["block_tables"], buf["seen"]
-        staging.mark_dirty(rows, chunk, used)
-    else:
-        tokens = np.zeros((rows, chunk), np.int32)
-        positions = np.zeros((rows, chunk), np.int32)
-        new_lens = np.zeros((rows,), np.int32)
-        block_tables = np.zeros((rows, max_pages), np.int32)
-        seen = np.zeros((rows,), np.int32)
+    staging = staging or BatchStaging(max_pages)  # (none given: fresh arrays)
+    if staging.max_pages != max_pages:
+        raise ValueError(f"staging max_pages={staging.max_pages} != requested {max_pages}")
+    buf = staging.acquire(rows, chunk)
+    tokens, positions = buf["tokens"], buf["positions"]
+    new_lens, block_tables, seen = buf["new_lens"], buf["block_tables"], buf["seen"]
+    staging.mark_dirty(rows, chunk, used)
 
     seen_v = np.fromiter((s.seen_tokens for s in seqs), dtype=np.int32, count=n)
-    if manager.layout is not None:
-        # two kinds of pages a row: the layout says how many of each
-        over = seen_v.astype(np.int64) + lens > manager.layout.max_seq_len
-        if over.any():
-            i = int(np.argmax(over))
-            raise RuntimeError(
-                f"uid {uids[i]}: {int(seen_v[i] + lens[i])} tokens exceeds engine "
-                f"max_seq_len={manager.layout.max_seq_len}")
-        if isinstance(manager.layout, RingLayout) and lens.max(initial=0) > 1 and (seen_v > 0).any():
-            i = int(np.argmax(seen_v > 0))
-            raise ValueError(
-                f"uid {uids[i]}: {int(lens[i])} token(s) after {int(seen_v[i])} in a call that feeds a chunk: with a "
-                "sliding kind a call of more than one token a row takes fresh prompts alone (a fresh prompt attends "
-                "inside the chunk and writes its last window; a row past position 0 would have to read the ring and "
-                "the global pages: ROADMAP R3b); feed the whole context at once, or one token a row in a call")
-        if ((lens > 1) & (seen_v > 0)).any():
-            i = int(np.argmax((lens > 1) & (seen_v > 0)))
-            raise ValueError(
-                f"uid {uids[i]}: a chunk of {int(lens[i])} tokens after {int(seen_v[i])}: with EVA "
-                "attention a chunk of more than one token starts a sequence (the chunk path does not "
-                "read earlier windows' summaries from the pool); feed the whole context at once, or "
-                "one token at a time")
-        for uid, length in zip(uids, lens):
-            manager.extend(uid, int(length))
-    else:
-        have_v = np.fromiter((s.n_blocks for s in seqs), dtype=np.int64, count=n)
-        bs = manager.block_size
-        need_v = np.maximum(-(-(seen_v.astype(np.int64) + lens) // bs) - have_v, 0)
-        over = (have_v + need_v) > max_pages
-        if over.any():
-            i = int(np.argmax(over))
-            raise RuntimeError(
-                f"uid {uids[i]}: {int(have_v[i] + need_v[i])} blocks exceeds "
-                f"max_pages={max_pages} (sequence longer than engine max_seq_len)"
-            )
-        fresh = manager.allocator.allocate(int(need_v.sum()))
-        ends = np.cumsum(need_v)
-        for i, s in enumerate(seqs):
-            if need_v[i]:
-                s.append_blocks(fresh[ends[i] - need_v[i]: ends[i]])
+    over = seen_v.astype(np.int64) + lens > manager.layout.capacity
+    if over.any():
+        i = int(np.argmax(over))
+        raise RuntimeError(
+            f"uid {uids[i]}: {int(seen_v[i] + lens[i])} tokens exceeds engine "
+            f"max_seq_len={manager.layout.max_seq_len}")
+    manager.layout.check_fed(uids, lens, seen_v)
+    for uid, length in zip(uids, lens):  # the layout says how many pages of each class a row holds
+        manager.extend(uid, int(length))
 
     # --- vectorized fills (no per-token Python loops)
     new_lens[at] = lens
@@ -895,12 +696,7 @@ def build_ragged_batch(
         fed[valid] = np.concatenate([np.asarray(t, np.int32) for t in token_lists])
         tokens[at] = fed
     for i, s in zip(range(n) if isinstance(at, slice) else at, seqs):
-        if manager.layout is None:
-            block_tables[i, : s.n_blocks] = s._table[: s.n_blocks]
-        else:
-            s.table_into(block_tables[i])
+        s.table_into(block_tables[i])
 
-    return RaggedBatch(
-        uids=list(uids), tokens=tokens, positions=positions,
-        new_lens=new_lens, block_tables=block_tables, seen=seen, at=at,
-    )
+    return RaggedBatch(uids=list(uids), tokens=tokens, positions=positions, new_lens=new_lens,
+                       block_tables=block_tables, seen=seen, at=at)

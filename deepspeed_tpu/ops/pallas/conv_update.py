@@ -3,7 +3,7 @@
 
 A decode step of a Mamba-2 or Gated DeltaNet layer convolves ONE new input a
 row with the ``K - 1`` inputs before it, which the conv pool keeps
-(``inference/paged.StatePool.conv``: ``[state layers, slots, (K - 1) X]``, a
+(``inference/cache.StatePool.conv``: ``[state layers, slots, (K - 1) X]``, a
 slot's tail one lane-dense row). ``ops/ssm.py::conv_inputs`` was written for a
 prompt: it concatenates the tail before the inputs, sums ``K`` shifted slices
 and gathers the next tail at each row's last live token, around a slice of the

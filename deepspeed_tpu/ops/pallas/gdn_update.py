@@ -3,7 +3,7 @@
 A decode step of a Gated DeltaNet layer moves nothing but state: per live row
 ``Hv x Dk x Dv`` float32 (2 MiB at 32 x 128 x 128) read and written, against a
 few KB of inputs. XLA's form of ``ops/gdn.py::gdn_step`` on a row of the pool
-(``inference/paged.StatePool``) needs the decayed state twice (for ``S^T k``,
+(``inference/cache.StatePool``) needs the decayed state twice (for ``S^T k``,
 then for the rank-one update) and the updated state twice (to store it, and for
 ``S^T q``), in fusions of its own choosing. This kernel makes one read and one
 write: a grid step holds one row's heads in VMEM, a head ``S <- e^g S``, ``d =

@@ -3,7 +3,7 @@
 A decode step of a state-space layer moves nothing but state: per live row
 ``H x P x N`` float32 (2 MiB at 64 x 64 x 128) read and written, against a few
 KB of inputs. XLA's form of ``ops/ssm.py::ssm_step`` on a row of the pool
-(``inference/paged.StatePool``) is two fusions, one that reads the row, updates
+(``inference/cache.StatePool``) is two fusions, one that reads the row, updates
 it and reduces it to ``y``, and one that reads it AGAIN, updates it again and
 writes it: three passes over the state where the mathematics needs two (449
 GB/s on its own bytes, 55% of the v5e's 819: PERF.md, section 6, PR 42). This

@@ -155,7 +155,7 @@ def from_pool(tiles, H: int, P: int):
 
 
 class PoolRow(NamedTuple):
-    """A layer's states where serving keeps them (``inference/paged.StatePool``):
+    """A layer's states where serving keeps them (``inference/cache.StatePool``):
     row ``layer`` of ``pool`` [layers, slots, H P / W, N, W] float32
     (:func:`to_pool`), the call's rows in slots 0..rows-1, to be updated IN
     PLACE; ``fresh`` [rows] bool marks the rows that start a sequence, whose

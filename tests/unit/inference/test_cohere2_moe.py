@@ -21,7 +21,8 @@ import pytest
 from benchmarks.lib import harness, program
 from deepspeed_tpu.checkpoint.hf import config_from_hf
 from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
-from deepspeed_tpu.inference.ragged import RingLayout, StateManager
+from deepspeed_tpu.inference.cache import RingLayout
+from deepspeed_tpu.inference.ragged import StateManager
 from deepspeed_tpu.models import CausalLM
 
 WINDOW, BLOCK = 32, 8
@@ -201,7 +202,7 @@ def test_prefill_then_decode_through_the_ring_past_two_wraps_is_the_reference(fi
     assert stats["ring_pages_overwritten"] > 0
     for uid in uids:
         eng.flush(uid)
-    assert eng.state.ring_allocator.free_blocks == eng.ring_blocks and eng.state.free_blocks == eng.num_kv_blocks
+    assert eng.state.allocators[1].free_blocks == eng.ring_blocks and eng.state.free_blocks == eng.num_kv_blocks
 
 
 def test_generate_is_the_module_s_greedy_tokens(toy):
@@ -288,15 +289,15 @@ def test_no_ring_page_goes_to_two_rows_and_both_classes_come_back_at_flush():
         for held in (rings, globals_):
             pages = np.concatenate(held)
             assert len(set(pages.tolist())) == len(pages)  # no page of a class in two rows, or twice in one
-        assert state.ring_allocator.free_blocks == 12 - sum(len(r) for r in rings)
+        assert state.allocators[1].free_blocks == 12 - sum(len(r) for r in rings)
         assert state.free_blocks == 40 - sum(len(g) for g in globals_)
     # three rows hold the whole of the ring class: a fourth is not admitted though global pages are left
     for uid in list(state._seqs):
         state.flush(uid)
-    assert state.ring_allocator.free_blocks == 12 and state.free_blocks == 40
+    assert state.allocators[1].free_blocks == 12 and state.free_blocks == 40
     for uid in (0, 1):
         state.extend(uid, 40)
-    assert state.ring_allocator.free_blocks == 2 and not state.can_schedule([2], [40]) and state.can_schedule([2], [16])
+    assert state.allocators[1].free_blocks == 2 and not state.can_schedule([2], [40]) and state.can_schedule([2], [16])
     assert layout.overwritten(0, 40) == 0 and layout.overwritten(40, 1) == 1 and layout.overwritten(41, 7) == 0
 
 
@@ -426,7 +427,7 @@ def test_a_padded_prompt_s_logits_and_pages_are_the_unpadded_prompt_s(toy, impl,
     got, want = loose.put([3], [prompt]), tight.put([3], [prompt])
     assert rel(got, want) < 1e-5
     assert np.array_equal(loose.state.get(3).blocks, tight.state.get(3).blocks)
-    for a, b in zip(jax.tree_util.tree_leaves(loose._pools), jax.tree_util.tree_leaves(tight._pools)):
+    for a, b in zip(jax.tree_util.tree_leaves(loose.pools), jax.tree_util.tree_leaves(tight.pools)):
         assert float(jnp.abs(a - b).max()) < 1e-5 * max(float(jnp.abs(b).max()), 1.0)
 
 

@@ -138,12 +138,12 @@ def test_the_flax_forward_is_the_reference_s(files, toy, tokens):
 
 
 def test_pools_hold_a_latent_and_an_index_key_a_token_on_one_block_table(toy):
-    from deepspeed_tpu.inference import paged
+    from deepspeed_tpu.inference import cache
 
     eng = engine(toy)
     assert eng.pool.k.shape == (LAYERS * 64, 8, 128) and eng.pool.v.shape == (LAYERS * 64, 8, 128)
-    assert paged.index_pool_width(toy[1]) == 128 and eng.kv_bytes_per_token == LAYERS * (128 + 128) * 4
-    assert paged.index_pool_width(dataclasses.replace(toy[1], index_topk=0, index_heads=0, index_head_dim=0)) == 0
+    assert cache.index_pool_width(toy[1]) == 128 and eng.kv_bytes_per_token == LAYERS * (128 + 128) * 4
+    assert cache.index_pool_width(dataclasses.replace(toy[1], index_topk=0, index_heads=0, index_head_dim=0)) == 0
 
 
 def test_put_then_decode_through_both_pools_is_the_reference_s_full_forward(files, toy, tokens):
@@ -325,7 +325,7 @@ def test_the_scopes_of_the_indexer_are_in_the_compiled_programs_and_its_leaves_n
     eng = engine(toy)
     _, cfg, params = toy
     text = eng._step_fn(2, 64).lower(
-        params, eng.pool, jnp.zeros((2, 64), jnp.int32), jnp.zeros((2, 64), jnp.int32), jnp.zeros((2,), jnp.int32),
+        params, eng.pools, jnp.zeros((2, 64), jnp.int32), jnp.zeros((2, 64), jnp.int32), jnp.zeros((2,), jnp.int32),
         jnp.zeros((2, eng.max_pages), jnp.int32)).as_text(debug_info=True)
     for scope in ("mla/dsa_index/idx_wq", "mla/dsa_index/idx_wk", "mla/dsa_index/idx_k_norm", "mla/dsa_index/idx_w",
                   "mla/dsa_select", "mla/dsa_attend", "kv_write"):

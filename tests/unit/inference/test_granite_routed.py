@@ -211,8 +211,8 @@ def test_pools_are_sized_by_the_layers_that_use_them(toy):
     eng = engine(toy)
     cfg = eng.model_config
     assert eng.pool.k.shape == (2 * 96, 4, cfg.kv_heads * cfg.dims_per_head)  # the attention layers' pages
-    assert eng.state_pool.ssm.shape[:2] == (4, 8) and eng.state_pool.ssm.dtype == jnp.float32
-    assert eng.state_pool.conv.shape == (4, 8, 3 * 160)
+    assert eng.pools.state.ssm.shape[:2] == (4, 8) and eng.pools.state.ssm.dtype == jnp.float32
+    assert eng.pools.state.conv.shape == (4, 8, 3 * 160)
     assert eng.state.state_slots == 8
 
 
@@ -231,14 +231,14 @@ def test_put_through_the_slot_and_the_pages_is_the_reference_s_full_forward(file
     got, picks = [np.asarray(logits, np.float32)], [[p[i]] for i in range(3)]
     for i in range(3):
         assert p[i].shape == (lens[i], L, K) and p[i].min() >= 0 and p[i].max() < 12  # the router's numbering
-    before = (np.asarray(eng.state_pool.ssm[:, 1]), np.asarray(eng.state_pool.conv[:, 1]))
+    before = (np.asarray(eng.pools.state.ssm[:, 1]), np.asarray(eng.pools.state.conv[:, 1]))
     for s in range(3):  # rows 0 and 2 decode, row 1 rides the program dead
         logits, p = eng.put_with_picks([0, 2], [seqs[i, lens[i] + s:lens[i] + s + 1] for i in (0, 2)])
         got.append(np.asarray(logits, np.float32))
         picks[0].append(p[0])
         picks[2].append(p[1])
-    assert np.array_equal(before[0], np.asarray(eng.state_pool.ssm[:, 1]))  # bitwise what it was
-    assert np.array_equal(before[1], np.asarray(eng.state_pool.conv[:, 1]))
+    assert np.array_equal(before[0], np.asarray(eng.pools.state.ssm[:, 1]))  # bitwise what it was
+    assert np.array_equal(before[1], np.asarray(eng.pools.state.conv[:, 1]))
     logits, p = eng.put_with_picks([1], [seqs[1, 40:51]])  # eleven more tokens of the sequence that sat still
     picks[1].append(p[0])
     want, shortfall = pinned(files, toy, seqs, [np.concatenate(p) for p in picks], eng.params)
@@ -261,8 +261,8 @@ def test_a_pad_token_moves_no_state_and_no_logit(toy):
     a = alone.put([0], [seq])
     b = beside.put([0, 1], [seq, tokens(1, 22, seed=10)[0]])
     assert rel(b[0], a[0]) < 1e-5
-    for x, y in zip((alone.state_pool.ssm[:, 0], alone.state_pool.conv[:, 0]),
-                    (beside.state_pool.ssm[:, 0], beside.state_pool.conv[:, 0])):
+    for x, y in zip((alone.pools.state.ssm[:, 0], alone.pools.state.conv[:, 0]),
+                    (beside.pools.state.ssm[:, 0], beside.pools.state.conv[:, 0])):
         np.testing.assert_allclose(np.asarray(y), np.asarray(x), atol=1e-5 * float(jnp.abs(x).max()), rtol=0)
 
 

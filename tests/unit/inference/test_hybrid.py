@@ -20,7 +20,7 @@ import pytest
 
 from benchmarks.lib import harness, program
 from deepspeed_tpu.checkpoint.hf import config_from_hf
-from deepspeed_tpu.inference import paged
+from deepspeed_tpu.inference import cache, paged
 from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
 from deepspeed_tpu.models import CausalLM
 
@@ -186,8 +186,8 @@ def test_pools_are_sized_by_the_layers_that_use_them(toy):
     eng = engine(toy)
     cfg = eng.model_config
     assert eng.pool.k.shape == (2 * 96, 4, cfg.kv_heads * cfg.dims_per_head)  # the attention layers' pages
-    assert eng.state_pool.ssm.shape == (4, 8, 1, 16, 128) and eng.state_pool.ssm.dtype == jnp.float32  # channels on lanes
-    assert eng.state_pool.conv.shape == (4, 8, 3 * 160)
+    assert eng.pools.state.ssm.shape == (4, 8, 1, 16, 128) and eng.pools.state.ssm.dtype == jnp.float32  # channels on lanes
+    assert eng.pools.state.conv.shape == (4, 8, 3 * 160)
     assert eng.kv_bytes_per_token == 2 * 2 * cfg.kv_heads * cfg.dims_per_head * 4
 
 
@@ -252,7 +252,7 @@ def test_generate_keeps_a_chain_ahead_and_matches_the_reference(toy, want):
 
 def _slot(eng, uid):
     slot = eng.state.get(uid).slot
-    return np.asarray(eng.state_pool.ssm[:, slot]), np.asarray(eng.state_pool.conv[:, slot])
+    return np.asarray(eng.pools.state.ssm[:, slot]), np.asarray(eng.pools.state.conv[:, slot])
 
 
 def test_rows_that_end_inside_a_chain_stop_moving_their_slot(toy):
@@ -288,7 +288,7 @@ def test_a_slot_changes_hands_and_the_next_sequence_starts_from_zeros(toy, want)
     a, b = tokens(2, 15, seed=6)
     eng.put([7], [a])
     assert eng.state.get(7).slot == 0 and eng.state.state_slots_in_use == 1
-    assert np.abs(np.asarray(eng.state_pool.ssm[:, 0])).max() > 0
+    assert np.abs(np.asarray(eng.pools.state.ssm[:, 0])).max() > 0
     eng.flush(7)
     assert eng.state.state_slots_in_use == 0
     got = eng.put([8], [b])  # the lowest free slot: the one just given back, as it was left
@@ -372,7 +372,9 @@ def test_a_model_without_a_pattern_keeps_its_tree_and_is_handed_one_pool():
     assert cfg.layer_types is None and cfg.period is None and cfg.attention_layers == cfg.num_layers
     assert "layer_0" not in params["layers"] and "attn" in params["layers"]
     eng = InferenceEngineV2(cfg, params, dict(ENGINE, dtype="fp32"))
-    assert eng.state_pool is None and isinstance(eng._pools, paged.PagedKVPool)
+    assert eng.pools.state is None and eng.pools.ring is None  # its programs are handed the page pool's arrays
+    assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(eng.pools), jax.tree_util.tree_leaves(eng.pool)))
+    assert len(jax.tree_util.tree_leaves(eng.pools)) == 2
     assert eng.state.state_slots is None and eng.state.get_or_create(0).slot is None
 
 
@@ -385,8 +387,7 @@ def test_a_mixer_s_pieces_carry_the_same_names_in_serving_and_in_training(toy):
     import re
 
     cfg, params = toy
-    pools = jax.eval_shape(lambda: paged.HybridPools(paged.init_pool(cfg, 32, 4, jnp.float32),
-                                                     paged.init_state_pool(cfg, 4, jnp.float32)))
+    pools = jax.eval_shape(lambda: cache.Pools(cache.init_pool(cfg, 32, 4, jnp.float32), cache.init_state_pool(cfg, 4, jnp.float32)))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     step = jax.jit(lambda p, pool, t, pos, n, bt: paged.ragged_forward(p, cfg, pool, t, pos, n, bt, 4)).lower(
         params, pools, i32(4, 16), i32(4, 16), i32(4), i32(4, 8)).compile().as_text()

@@ -4,11 +4,12 @@ correction bias large enough to change picks, a shared expert), against the
 benchmark's plain reference ``benchmarks/reference/glm4_moe_lite.py``: the
 flax forward, ``InferenceEngineV2.put`` through the latent cache in the
 absorbed form, the picks the programs hand out, the router's two dispatch
-regimes, the HF mapping. And the pin on what must NOT have moved: a model
-with no routed layer and no latent rank runs the parent's programs."""
+regimes, the HF mapping. And the pin on what must NOT have moved: a toy of
+every shape of cache traces the programs it traced (``test_programs_are_the_parents``)."""
 
 import collections
 import dataclasses
+import importlib
 import json
 import os
 
@@ -19,7 +20,7 @@ import pytest
 
 from benchmarks.lib import harness, program
 from deepspeed_tpu.checkpoint.hf import config_from_hf, convert_hf_state, latent_moe_hf_state
-from deepspeed_tpu.inference import paged
+from deepspeed_tpu.inference import cache, paged
 from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
 from deepspeed_tpu.models import CausalLM
 
@@ -339,7 +340,7 @@ def test_absorbed_attention_is_the_plain_one(dtype, tol):
     x = jax.random.normal(jax.random.PRNGKey(3), (N, C, cfg.hidden_size), dtype)
     positions = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32), (N, C))
     plain = LatentAttention(cfg).apply({"params": attn}, x, None, positions, False)
-    pool = paged.init_pool(cfg, 8, bs, dtype)
+    pool = cache.init_pool(cfg, 8, bs, dtype)
     tables = jnp.asarray([[0, 1, 7, 7], [2, 3, 7, 7]], jnp.int32)
     new_lens = jnp.full((N,), C, jnp.int32)
     put = paged._page_writer(tables, positions, new_lens, bs, pool.k.shape[0])
@@ -393,7 +394,7 @@ def test_config_from_hf_on_the_published_config():
             cfg.intermediate_size) == (64, 4, 1, 1536, 10240)
     assert (cfg.moe_router, cfg.moe_renormalize, cfg.moe_routed_scale) == ("sigmoid", True, 1.8)
     assert cfg.param_dtype == jnp.bfloat16 and cfg.rope_theta == 1e6 and not cfg.tie_embeddings
-    assert paged.latent_pool_width(cfg) == 640
+    assert cache.latent_pool_width(cfg) == 640
     architecture = harness.load_architecture("glm4_moe_lite")
     assert cfg.num_params() == architecture.total_params(program.published(config)) == 5_166_248_384
 
@@ -420,7 +421,7 @@ def test_a_quantized_latent_pool_is_refused():
         engine_of(cfg, params, "bf16", kv_cache_dtype="int8")
 
 
-# (g) a model with no routed layer and no latent rank runs the parent's programs
+# (g) what must not have moved: every shape of cache traces the programs it traced
 def _census(jaxpr, counts):
     for eqn in jaxpr.eqns:
         counts[eqn.primitive.name] += 1
@@ -432,19 +433,47 @@ def _census(jaxpr, counts):
     return counts
 
 
-def _programs(cfg, **kw):
-    """The jaxprs of ``step`` and ``chain`` for ``cfg`` at fixed toy shapes."""
+GPT_NEOX = dict(
+    model_type="gpt_neox", vocab_size=256, hidden_size=64, intermediate_size=256, num_hidden_layers=2,
+    num_attention_heads=4, max_position_embeddings=128, rotary_pct=0.25, rotary_emb_base=10000,
+    layer_norm_eps=1e-5, use_parallel_residual=True, hidden_act="gelu", tie_word_embeddings=False)
+# a toy of every shape of cache: (the module that holds its published config (None: this one, "": GPT_NEOX),
+# the census' file, block size, max_seq_len, a prompt's chunk, with_picks, kv_quant)
+PROGRAM_TOYS = {
+    "gpt_neox": ("", "gpt_neox_programs_at_pr36.json", 16, 128, 32, False, None),
+    "gpt_neox_int8": ("", "gpt_neox_int8_programs_at_pr58.json", 16, 128, 32, False, "int8"),
+    "glm4_moe_lite": (None, "glm4_moe_lite_programs_at_pr50.json", 16, 128, 32, True, None),
+    "granitemoehybrid": ("test_hybrid", "granitemoehybrid_programs_at_pr58.json", 4, 128, 8, False, None),
+    "qwen3_next": ("test_qwen3_next", "qwen3_next_programs_at_pr58.json", 16, 256, 64, True, None),
+    "evabyte": ("test_eva", "evabyte_programs_at_pr58.json", 4, 256, 32, False, None),
+    "cohere2_moe": ("test_cohere2_moe", "cohere2_moe_programs_at_pr58.json", 8, 192, 16, True, None),
+    "glm_moe_dsa": ("test_glm_moe_dsa", "glm_moe_dsa_programs_at_pr58.json", 8, 64, 64, True, None),
+    "xing4_0": ("test_xing", "xing4_0_programs_at_pr58.json", 16, 256, 32, True, None),
+}
+PROGRAMS = [(toy, name) for toy in PROGRAM_TOYS for name in ("step", "chain") + ("spec",) * (toy == "gpt_neox_int8")]
+
+
+def _programs(cfg, bs=16, max_seq_len=128, chunk=32, kv_quant=None, rows=4, **kw):
+    """The jaxprs of ``step``, ``chain`` and (where no picks are asked) the speculative chain for ``cfg`` at
+    fixed toy shapes: 32 pages a layer, 8 state slots, a ring a row; the block table as the plan lays it out."""
     params = jax.eval_shape(lambda k: CausalLM(cfg).init(
         {"params": k}, {"input_ids": jnp.zeros((1, 8), jnp.int32)}, train=False)["params"], jax.random.PRNGKey(0))
-    pool = jax.eval_shape(lambda: paged.init_pool(cfg, 32, 16, jnp.float32))
+    plan = cache.cache_plan(cfg, bs, max_seq_len)
+    pools = jax.eval_shape(lambda: plan.init(32, rows * plan.ring_columns, 8, jnp.float32, kv_quant=kv_quant))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    step = jax.make_jaxpr(lambda p, pool, t, pos, n, bt: paged.ragged_forward(
-        p, cfg, pool, t, pos, n, bt, 16, **kw))(params, pool, i32(4, 32), i32(4, 32), i32(4), i32(4, 8))
-    chain = jax.make_jaxpr(lambda p, pool, t, pos, bt, a, b, r: paged.ragged_decode_chain(
-        p, cfg, pool, t, pos, bt, 16, a, b, r, 4, None, **kw))(
-        params, pool, i32(4), i32(4), i32(4, 8), jax.ShapeDtypeStruct((4,), jnp.bool_), i32(4),
-        jax.ShapeDtypeStruct((2,), jnp.uint32))
-    return pool, {"step": step, "chain": chain}
+    chain_args = (i32(rows, plan.max_pages), jax.ShapeDtypeStruct((rows,), jnp.bool_), i32(rows),
+                  jax.ShapeDtypeStruct((2,), jnp.uint32))
+    programs = {
+        "step": lambda: jax.make_jaxpr(lambda p, pool, t, pos, n, bt: paged.ragged_forward(
+            p, cfg, pool, t, pos, n, bt, bs, **kw))(
+            params, pools, i32(rows, chunk), i32(rows, chunk), i32(rows), i32(rows, plan.max_pages)),
+        "chain": lambda: jax.make_jaxpr(lambda p, pool, t, pos, bt, a, b, r: paged.ragged_decode_chain(
+            p, cfg, pool, t, pos, bt, bs, a, b, r, 4, None, **kw))(params, pools, i32(rows), i32(rows), *chain_args),
+        "spec": lambda: jax.make_jaxpr(lambda p, pool, t, pos, bt, a, b, r, h, n: paged.ragged_spec_decode_chain(
+            p, cfg, pool, t, pos, bt, bs, a, b, r, 4, None, h, n, n_spec=2))(
+            params, pools, i32(rows), i32(rows), *chain_args, i32(rows, 64), i32(rows)),
+    }
+    return pools, programs
 
 
 def _same_as_recorded(jaxpr, parent):
@@ -453,38 +482,38 @@ def _same_as_recorded(jaxpr, parent):
     assert [[list(v.aval.shape), str(v.aval.dtype)] for v in jaxpr.jaxpr.outvars] == parent["outputs"]
 
 
-@pytest.mark.parametrize("name", ["step", "chain"])
-def test_gpt_neox_programs_are_the_parents(name):
-    """The census of the jaxpr's primitives, the number of operands and the
-    outputs' shapes, recorded by this very code: ``step`` on PR 32's commit,
-    ``chain`` on PR 36's, which hands back the scan's carry (two more outputs,
-    each row's next token and position, and a row starts live only with a
-    budget: one ``gt``, one ``and``) and takes no new operand."""
-    cfg = config_from_hf(dict(
-        model_type="gpt_neox", vocab_size=256, hidden_size=64, intermediate_size=256, num_hidden_layers=2,
-        num_attention_heads=4, max_position_embeddings=128, rotary_pct=0.25, rotary_emb_base=10000,
-        layer_norm_eps=1e-5, use_parallel_residual=True, hidden_act="gelu", tie_word_embeddings=False))
-    pool, programs = _programs(cfg)
-    assert pool.k.shape == pool.v.shape == (64, 16, 64)  # keys AND values
-    with open(os.path.join(os.path.dirname(__file__), "data", "gpt_neox_programs_at_pr36.json")) as f:
-        _same_as_recorded(programs[name], json.load(f)[name])  # no picks among the outputs
-
-
-@pytest.mark.parametrize("name", ["step", "chain"])
-def test_glm4_moe_lite_programs_are_the_parents(name):
-    """The routed, latent toy's two programs, picks and all, against the census
-    recorded on PR 50's commit. Against PR 40's ``step`` (the toy's prefill takes
-    the ragged path, whose combine is k gathers and a sum and whose sort is
-    inverted by a second sort) and PR 36's ``chain`` (the carry handed back, as
-    above), both: the layer scan closes over the routed experts' three stacked
-    leaves and slices each by its own index where it scanned them (three
-    ``dynamic_slice`` with their index's clamp, ``lt`` ``add`` ``select_n``, and
-    ``squeeze``; one ``iota``, the index), no new operand; ``chain`` alone:
-    ``touched`` is ``[K, routed layers, 3]`` where it was ``[K, routed
-    layers]``, beside the live rows' distinct experts their visits and the
-    distinct experts ANY row picked, which is what the decode product reads. What
-    PR 35 added for EVA attention is a branch at trace time and reaches neither."""
-    pool, programs = _programs(config_from_hf(TOY), with_picks=True)
-    assert pool.v is None  # the latent pool
-    with open(os.path.join(os.path.dirname(__file__), "data", "glm4_moe_lite_programs_at_pr50.json")) as f:
-        _same_as_recorded(programs[name], json.load(f)[name])
+@pytest.mark.parametrize("toy,name", PROGRAMS, ids=[f"{toy}-{name}" for toy, name in PROGRAMS])
+def test_programs_are_the_parents(toy, name):
+    """The census of a program's jaxpr (its primitives counted through every
+    inner jaxpr, the number of operands, the outputs' shapes) against the one
+    recorded by this very code on the commit the file names, for a toy of every
+    shape of cache. ``gpt_neox``: ``step`` on PR 32's commit, ``chain`` on
+    PR 36's, which hands back the scan's carry (two more outputs, each row's
+    next token and position, and a row starts live only with a budget: one
+    ``gt``, one ``and``) and takes no new operand. ``glm4_moe_lite``, routed and
+    latent, picks and all, on PR 50's: against PR 40's ``step`` (the toy's
+    prefill takes the ragged path, whose combine is k gathers and a sum and
+    whose sort is inverted by a second sort) and PR 36's ``chain``, both: the
+    layer scan closes over the routed experts' three stacked leaves and slices
+    each by its own index where it scanned them (three ``dynamic_slice`` with
+    their index's clamp, ``lt`` ``add`` ``select_n``, and ``squeeze``; one
+    ``iota``, the index), no new operand; ``chain`` alone: ``touched`` is ``[K,
+    routed layers, 3]``. The others (a quantized pool and its speculative
+    chain, a Mamba-2 hybrid, a Gated DeltaNet hybrid, EVA, a sliding kind, an
+    indexed latent, hyper-connections) were recorded on PR 58's commit BEFORE
+    PR 59 moved what a model caches into ``inference/cache.py`` and handed
+    every program one ``Pools``: a ``None`` field has no leaves, so each takes
+    the operands it took, in their order. Re-record one only with a change that
+    means to move its program."""
+    module, recorded, bs, max_seq_len, chunk, picks, kv_quant = PROGRAM_TOYS[toy]
+    published = GPT_NEOX if module == "" else TOY if module is None else importlib.import_module(
+        f"tests.unit.inference.{module}").TOY
+    pools, programs = _programs(config_from_hf(published), bs, max_seq_len, chunk, kv_quant,
+                                **({"with_picks": True} if picks else {}))
+    if toy == "gpt_neox":
+        assert pools.kv.k.shape == pools.kv.v.shape == (64, 16, 64)  # keys AND values
+        assert jax.tree_util.tree_leaves(pools) == jax.tree_util.tree_leaves(pools.kv)  # and nothing beside them
+    if toy == "glm4_moe_lite":
+        assert pools.kv.v is None  # the latent pool
+    with open(os.path.join(os.path.dirname(__file__), "data", recorded)) as f:
+        _same_as_recorded(programs[name](), json.load(f)[name])

@@ -108,8 +108,8 @@ def _program(eng, which):
     if which == "step":
         chunk = eng.config.chunk_bucket
         return eng._step_fn(ROWS, chunk), (
-            eng.params, eng.pool, i32(ROWS, chunk), i32(ROWS, chunk), i32(ROWS), tables)
-    chain_args = (eng.params, eng.pool, i32(ROWS), i32(ROWS), tables, jnp.ones((ROWS,), bool),
+            eng.params, eng.pools, i32(ROWS, chunk), i32(ROWS, chunk), i32(ROWS), tables)
+    chain_args = (eng.params, eng.pools, i32(ROWS), i32(ROWS), tables, jnp.ones((ROWS,), bool),
                   jnp.full((ROWS,), K, jnp.int32), jax.random.PRNGKey(0))
     if which == "chain":
         return eng._chain_fn(ROWS, K, None, (("do_sample", False),)), chain_args
@@ -340,7 +340,7 @@ def _state_sized_products(jaxpr, state_pool):
 @pytest.mark.parametrize("which", ["step", "chain", "prefill", "chain_conv_kernel"])
 def test_programs_update_the_state_pool_in_place_and_hand_both_pools_back(which, monkeypatch):
     """A model with state-space layers: its programs take the page pool AND the
-    state pool in the pool's place (``paged.HybridPools``), donated. Nothing of
+    state pool in the pool's place (``cache.Pools``), donated. Nothing of
     the state pool's whole shape (either array's) is produced but its in-place
     update, a layer's row at a time, and the compiled program hands both pools
     back aliased. The conv pool: the toy's 160 channels are no lane tile, so
@@ -361,8 +361,8 @@ def test_programs_update_the_state_pool_in_place_and_hand_both_pools_back(which,
         monkeypatch.setattr(conv_update, "takes", lambda *sizes: True)
         which = "chain"
     eng = _engine(cfg, params, max_seqs=ROWS, row_bucket=ROWS, kv_cache_dtype="bf16")
-    pools = eng._pools
-    assert pools.state.ssm.shape == (cfg.ssm_layers, ROWS, 1, 16, 128) and len(pools) == 2
+    pools = eng.pools
+    assert pools.state.ssm.shape == (cfg.ssm_layers, ROWS, 1, 16, 128) and pools.ring is None
     i32 = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
     tables, chunk = i32(ROWS, eng.max_pages), eng.config.chunk_bucket
     if which == "chain":

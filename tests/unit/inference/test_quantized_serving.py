@@ -22,9 +22,9 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.inference import InferenceEngineV2
+from deepspeed_tpu.inference.cache import init_pool
 from deepspeed_tpu.inference.paged import (
     _kv_block_quant,
-    init_pool,
     paged_attention,
     ragged_decode_chain,
 )
@@ -219,7 +219,7 @@ def test_decode_program_never_materializes_fp_pool():
                                    tables, bs, active, budgets, rng, k, None)
 
     jaxpr = jax.make_jaxpr(chain)(
-        eng.params, eng.pool,
+        eng.params, eng.pools,
         jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
         jnp.zeros((rows, eng.max_pages), jnp.int32),
         jnp.ones((rows,), bool), jnp.full((rows,), k, jnp.int32),

@@ -24,7 +24,7 @@ import pytest
 
 from benchmarks.lib import harness, program
 from deepspeed_tpu.checkpoint.hf import config_from_hf
-from deepspeed_tpu.inference import paged
+from deepspeed_tpu.inference import cache, paged
 from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
 from deepspeed_tpu.models import CausalLM
 from deepspeed_tpu.models.transformer import ExpertParallel, GDNConfig, TransformerConfig
@@ -191,8 +191,8 @@ def test_pools_are_sized_by_the_layers_that_use_them(toy):
     eng = engine(toy)
     cfg = eng.model_config
     assert eng.pool.k.shape == (2 * 64, 16, cfg.kv_heads * cfg.dims_per_head)  # the attention layers' pages
-    assert eng.state_pool.ssm.shape == (6, 8, 4, 16, 16) and eng.state_pool.ssm.dtype == jnp.float32  # values on lanes
-    assert eng.state_pool.conv.shape == (6, 8, 3 * 128)
+    assert eng.pools.state.ssm.shape == (6, 8, 4, 16, 16) and eng.pools.state.ssm.dtype == jnp.float32  # values on lanes
+    assert eng.pools.state.conv.shape == (6, 8, 3 * 128)
     assert eng.state.state_slots == 8
 
 
@@ -239,7 +239,7 @@ def test_a_prefill_s_rows_go_through_a_mixer_a_group_at_a_time_to_the_same_numbe
         lens = [40, 17, 33]
         logits = [eng.put([0, 1, 2], [seqs[i, :n] for i, n in enumerate(lens)])]
         logits.append(eng.put([0, 1, 2], [seqs[i, n:n + 1] for i, n in enumerate(lens)]))
-        return [np.asarray(a, np.float32) for a in logits], jax.tree_util.tree_map(np.asarray, eng.state_pool)
+        return [np.asarray(a, np.float32) for a in logits], jax.tree_util.tree_map(np.asarray, eng.pools.state)
 
     whole, whole_state = fed(engine(toy))
     gdn_cfg = toy[1].gdn
@@ -288,13 +288,13 @@ def test_a_dead_row_s_slot_is_bitwise_what_it_was(toy):
     program dead: state and tail of its slot come out as they went in."""
     eng = engine(toy)
     eng.put([0, 1, 2], [tokens(1, n, seed=60 + n)[0] for n in (12, 7, 20)])
-    before = (np.asarray(eng.state_pool.ssm[:, 1]), np.asarray(eng.state_pool.conv[:, 1]))
+    before = (np.asarray(eng.pools.state.ssm[:, 1]), np.asarray(eng.pools.state.conv[:, 1]))
     eng.put([0, 2], [np.asarray([3], np.int32), np.asarray([5], np.int32)])
-    assert np.array_equal(before[0], np.asarray(eng.state_pool.ssm[:, 1]))
-    assert np.array_equal(before[1], np.asarray(eng.state_pool.conv[:, 1]))
+    assert np.array_equal(before[0], np.asarray(eng.pools.state.ssm[:, 1]))
+    assert np.array_equal(before[1], np.asarray(eng.pools.state.conv[:, 1]))
     eng.put([0, 2], [tokens(1, 9, seed=70)[0], tokens(1, 5, seed=71)[0]])  # and through the chunked form
-    assert np.array_equal(before[0], np.asarray(eng.state_pool.ssm[:, 1]))
-    assert np.array_equal(before[1], np.asarray(eng.state_pool.conv[:, 1]))
+    assert np.array_equal(before[0], np.asarray(eng.pools.state.ssm[:, 1]))
+    assert np.array_equal(before[1], np.asarray(eng.pools.state.conv[:, 1]))
 
 
 @pytest.mark.parametrize("over, said", [
@@ -411,8 +411,7 @@ def test_the_new_pieces_carry_their_names_in_serving_and_in_training():
     import re
 
     cfg, params = toy_params(SHARE, jnp.float32)
-    pools = jax.eval_shape(lambda: paged.HybridPools(paged.init_pool(cfg, 32, 4, jnp.float32),
-                                                     paged.init_state_pool(cfg, 4, jnp.float32)))
+    pools = jax.eval_shape(lambda: cache.Pools(cache.init_pool(cfg, 32, 4, jnp.float32), cache.init_state_pool(cfg, 4, jnp.float32)))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     step = jax.jit(lambda p, pool, t, pos, n, bt: paged.ragged_forward(p, cfg, pool, t, pos, n, bt, 4)).lower(
         params, pools, i32(4, 16), i32(4, 16), i32(4), i32(4, 8)).compile().as_text()

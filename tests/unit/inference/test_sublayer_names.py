@@ -20,7 +20,7 @@ import pytest
 import deepspeed_tpu
 from benchmarks.lib import scopes, sublayers
 from deepspeed_tpu.checkpoint.hf import config_from_hf
-from deepspeed_tpu.inference import paged
+from deepspeed_tpu.inference import cache, paged
 from deepspeed_tpu.models import CausalLM, causal_lm_spec
 from tests.unit.inference.test_eva import TOY as EVA_TOY
 from tests.unit.inference.test_latent_routed import TOY as GLM_TOY
@@ -60,7 +60,7 @@ def serving(request):
     toy, bs, cols, chunk, kw = TOYS[request.param]
     cfg = config_from_hf(toy)
     params = shapes_of(cfg)
-    pool = jax.eval_shape(lambda: paged.init_pool(cfg, 32, bs, jnp.float32))
+    pool = jax.eval_shape(lambda: cache.Pools(cache.init_pool(cfg, 32, bs, jnp.float32)))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     step = jax.jit(lambda p, pool, t, pos, n, bt: paged.ragged_forward(p, cfg, pool, t, pos, n, bt, bs, **kw)).lower(
         params, pool, i32(4, chunk), i32(4, chunk), i32(4), i32(4, cols))

@@ -22,7 +22,7 @@ import pytest
 
 from benchmarks.lib import harness, program
 from deepspeed_tpu.checkpoint.hf import config_from_hf
-from deepspeed_tpu.inference import paged
+from deepspeed_tpu.inference import cache, paged
 from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
 from deepspeed_tpu.models import CausalLM
 from deepspeed_tpu.models.transformer import TransformerConfig, yarn_frequencies
@@ -242,7 +242,7 @@ def test_yarn_changes_the_attention_and_absorbed_is_plain():
     unscaled = LatentAttention(dataclasses.replace(cfg, rope_scaling=None)).apply(
         {"params": attn}, x, None, positions, False)
     assert float(jnp.abs(plain - unscaled).max()) > 1e-2
-    pool = paged.init_pool(cfg, 16, bs, jnp.float32)
+    pool = cache.init_pool(cfg, 16, bs, jnp.float32)
     tables = jnp.asarray([[0, 1, 2, 3, 4, 5, 6, 7], [8, 9, 10, 11, 12, 13, 14, 15]], jnp.int32)
     new_lens = jnp.full((N,), C, jnp.int32)
     put = paged._page_writer(tables, positions - 100, new_lens, bs, pool.k.shape[0])
@@ -291,7 +291,7 @@ def test_config_from_hf_on_the_published_config():
     assert (cfg.hc_mult, cfg.hc_sinkhorn_iters, cfg.hc_eps, cfg.hc_res_clamp) == (4, 20, 1e-6, (-30.0, 30.0))
     assert dict(cfg.rope_scaling)["factor"] == 64 and cfg.rope_theta == 10000.0 and cfg.rope_interleaved
     assert cfg.param_dtype == jnp.bfloat16 and cfg.norm_eps == 1e-6 and not cfg.tie_embeddings
-    assert paged.latent_pool_width(cfg) == 640 and cfg.hc_params == 344_091
+    assert cache.latent_pool_width(cfg) == 640 and cfg.hc_params == 344_091
     architecture = harness.load_architecture("xing4_0")
     assert cfg.num_params() == architecture.total_params(program.published(config)) == 4_920_866_746
     hash(cfg)  # a jit static argument

@@ -474,7 +474,7 @@ def test_evabyte_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, na
     import re
 
     from deepspeed_tpu.checkpoint.hf import config_from_hf
-    from deepspeed_tpu.inference import paged
+    from deepspeed_tpu.inference import cache, paged
     from deepspeed_tpu.models import CausalLM
     from deepspeed_tpu.ops import registry
     from deepspeed_tpu.ops.pallas import flash_attention as fa, norms, paged_attention as pa
@@ -492,7 +492,7 @@ def test_evabyte_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, na
     params = jax.tree_util.tree_map(bf16, jax.eval_shape(
         lambda key: CausalLM(cfg).init({"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)},
                                        train=False)["params"], jax.random.PRNGKey(0)))
-    pool = jax.tree_util.tree_map(bf16, jax.eval_shape(lambda: paged.init_pool(cfg, NB, bs, jnp.bfloat16)))
+    pool = jax.tree_util.tree_map(bf16, jax.eval_shape(lambda: cache.Pools(cache.init_pool(cfg, NB, bs, jnp.bfloat16))))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
     if name == "chain_24":
         rows, limit_gb, kernels = 24, 2.6, ("paged_attn",)
@@ -547,7 +547,7 @@ def test_granite_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, na
 
     from benchmarks.lib import harness, program
     from deepspeed_tpu.checkpoint.hf import config_from_hf
-    from deepspeed_tpu.inference import paged
+    from deepspeed_tpu.inference import cache, paged
     from deepspeed_tpu.models import CausalLM
     from deepspeed_tpu.ops import registry
     from deepspeed_tpu.ops.pallas import (conv_update, flash_attention as fa, norms, paged_attention as pa,
@@ -565,8 +565,7 @@ def test_granite_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, na
     params = jax.tree_util.tree_map(sds, jax.eval_shape(
         lambda key: CausalLM(cfg).init({"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)},
                                        train=False)["params"], jax.random.PRNGKey(0)))
-    pools = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: paged.HybridPools(
-        paged.init_pool(cfg, NB, bs, jnp.bfloat16), paged.init_state_pool(cfg, rows, jnp.bfloat16))))
+    pools = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: cache.Pools(cache.init_pool(cfg, NB, bs, jnp.bfloat16), cache.init_state_pool(cfg, rows, jnp.bfloat16))))
     assert pools.kv.k.shape == (4 * 4096, 16, 512) and pools.state.ssm.shape == (36, 64, 32, 128, 128)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
     if name == "chain_64":
@@ -646,7 +645,7 @@ def test_qwen3_next_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch,
 
     from benchmarks.lib import harness, program
     from deepspeed_tpu.checkpoint.hf import config_from_hf
-    from deepspeed_tpu.inference import model, paged
+    from deepspeed_tpu.inference import cache, model, paged
     from deepspeed_tpu.models import CausalLM
     from deepspeed_tpu.ops import registry
     from deepspeed_tpu.ops.pallas import (conv_update, flash_attention as fa, gdn_update, moe_decode, norms,
@@ -665,8 +664,7 @@ def test_qwen3_next_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch,
     params = jax.tree_util.tree_map(sds, jax.eval_shape(
         lambda key: CausalLM(cfg).init({"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)},
                                        train=False)["params"], jax.random.PRNGKey(0)))
-    pools = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: paged.HybridPools(
-        paged.init_pool(cfg, NB, bs, jnp.bfloat16), paged.init_state_pool(cfg, rows, jnp.bfloat16))))
+    pools = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: cache.Pools(cache.init_pool(cfg, NB, bs, jnp.bfloat16), cache.init_state_pool(cfg, rows, jnp.bfloat16))))
     assert pools.kv.k.shape == (3 * 6826, 16, 512) and pools.state.ssm.shape == (9, 128, 32, 128, 128)
     assert pools.state.conv.shape == (9, 128, 3 * 8192)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
@@ -752,7 +750,7 @@ def test_granite_routed_programs_compile_at_the_cell_s_shapes(one_chip, monkeypa
 
     from benchmarks.lib import harness, program
     from deepspeed_tpu.checkpoint.hf import config_from_hf
-    from deepspeed_tpu.inference import model, paged
+    from deepspeed_tpu.inference import cache, model, paged
     from deepspeed_tpu.models import CausalLM
     from deepspeed_tpu.ops import registry
     from deepspeed_tpu.ops.pallas import (conv_update, flash_attention as fa, moe_decode, norms,
@@ -774,8 +772,7 @@ def test_granite_routed_programs_compile_at_the_cell_s_shapes(one_chip, monkeypa
         lambda key: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), CausalLM(cfg).init(
             {"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)}, train=False)["params"]),
         jax.random.PRNGKey(0)))
-    pools = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: paged.HybridPools(
-        paged.init_pool(cfg, NB, bs, jnp.bfloat16), paged.init_state_pool(cfg, rows, jnp.bfloat16))))
+    pools = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: cache.Pools(cache.init_pool(cfg, NB, bs, jnp.bfloat16), cache.init_state_pool(cfg, rows, jnp.bfloat16))))
     assert pools.kv.k.shape == (4096, 16, 1024) and pools.state.ssm.shape == (9, 64, 64, 128, 128)
     assert pools.state.conv.shape == (9, 64, 3 * 8448)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
@@ -885,7 +882,7 @@ def test_a_routed_chain_reads_the_picked_experts_from_the_stack(one_chip, monkey
 
     from benchmarks.lib import harness, program
     from deepspeed_tpu.checkpoint.hf import config_from_hf
-    from deepspeed_tpu.inference import paged
+    from deepspeed_tpu.inference import cache, paged
     from deepspeed_tpu.models import CausalLM
     from deepspeed_tpu.ops import registry
     from deepspeed_tpu.ops.pallas import moe_decode, norms, paged_attention as pa
@@ -897,12 +894,12 @@ def test_a_routed_chain_reads_the_picked_experts_from_the_stack(one_chip, monkey
     assert (cfg.routed_layers, cfg.num_experts, cfg.hidden_size, cfg.expert_width) == stack
     engine = harness.load_workload(workload)["engine"]
     bs, rows = engine["kv_block_size"], engine["max_seqs"]
-    NB = engine["kv_pool_bytes"] // (bs * cfg.num_layers * paged.latent_pool_width(cfg) * 2)
+    NB = engine["kv_pool_bytes"] // (bs * cfg.num_layers * cache.latent_pool_width(cfg) * 2)
     sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
     params = jax.tree_util.tree_map(sds, jax.eval_shape(
         lambda key: CausalLM(cfg).init({"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)},
                                        train=False)["params"], jax.random.PRNGKey(0)))
-    pool = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: paged.init_pool(cfg, NB, bs, jnp.bfloat16)))
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: cache.Pools(cache.init_pool(cfg, NB, bs, jnp.bfloat16))))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -965,7 +962,7 @@ def test_the_xing_prefill_holds_no_copy_of_the_streams(one_chip, monkeypatch):
 
     from benchmarks.lib import harness, program
     from deepspeed_tpu.checkpoint.hf import config_from_hf
-    from deepspeed_tpu.inference import paged
+    from deepspeed_tpu.inference import cache, paged
     from deepspeed_tpu.models import CausalLM
     from deepspeed_tpu.ops import registry
     from deepspeed_tpu.ops.pallas import mhc, moe_decode, norms, paged_attention as pa
@@ -978,12 +975,12 @@ def test_the_xing_prefill_holds_no_copy_of_the_streams(one_chip, monkeypatch):
     engine = harness.load_workload("xing4.0-29b-a4b.serve.long-prompt-batch")["engine"]
     bs, N, C = engine["kv_block_size"], engine["row_bucket"], engine["chunk_bucket"]
     assert (cfg.hc_mult, N, C, cfg.hidden_size) == (4, 8, 2048, 3584)
-    NB = engine["kv_pool_bytes"] // (bs * cfg.num_layers * paged.latent_pool_width(cfg) * 2)
+    NB = engine["kv_pool_bytes"] // (bs * cfg.num_layers * cache.latent_pool_width(cfg) * 2)
     sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
     params = jax.tree_util.tree_map(sds, jax.eval_shape(
         lambda key: CausalLM(cfg).init({"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)},
                                        train=False)["params"], jax.random.PRNGKey(0)))
-    pool = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: paged.init_pool(cfg, NB, bs, jnp.bfloat16)))
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: cache.Pools(cache.init_pool(cfg, NB, bs, jnp.bfloat16))))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -1097,7 +1094,7 @@ def test_glm_5_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, name
 
     from benchmarks.lib import harness, program
     from deepspeed_tpu.checkpoint.hf import config_from_hf
-    from deepspeed_tpu.inference import model, paged
+    from deepspeed_tpu.inference import cache, model, paged
     from deepspeed_tpu.models import CausalLM
     from deepspeed_tpu.ops import registry
     from deepspeed_tpu.ops.pallas import dsa as dsa_kernel, flash_attention as fa, moe_decode, norms, paged_attention as pa
@@ -1110,7 +1107,7 @@ def test_glm_5_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, name
     assert (cfg.num_experts, cfg.router_experts, cfg.index_topk, cfg.num_heads) == (16, 256, 2048, 64)
     engine = harness.load_workload("glm-5.serve.long-prompt-wave8")["engine"]
     bs = engine["kv_block_size"]
-    per_token = cfg.num_layers * 2 * (paged.latent_pool_width(cfg) + paged.index_pool_width(cfg))
+    per_token = cfg.num_layers * 2 * (cache.latent_pool_width(cfg) + cache.index_pool_width(cfg))
     assert per_token == 9216
     NB, table = engine["kv_pool_bytes"] // (bs * per_token), -(-engine["max_seq_len"] // bs)
     sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
@@ -1118,8 +1115,8 @@ def test_glm_5_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, name
         lambda key: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), CausalLM(cfg).init(
             {"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)}, train=False)["params"]),
         jax.random.PRNGKey(0)))
-    pool = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: paged.init_pool(cfg, NB, bs, jnp.bfloat16)))
-    assert pool.k.shape == (43686, 16, 640) and pool.v.shape == (43686, 16, 128) and table == 516
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: cache.Pools(cache.init_pool(cfg, NB, bs, jnp.bfloat16))))
+    assert pool.kv.k.shape == (43686, 16, 640) and pool.kv.v.shape == (43686, 16, 128) and table == 516
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
     if name == "chain_8":
         rows = engine["max_seqs"]
@@ -1231,7 +1228,7 @@ def test_command_a_plus_programs_compile_at_the_cell_s_shapes(one_chip, monkeypa
 
     from benchmarks.lib import harness, program
     from deepspeed_tpu.checkpoint.hf import config_from_hf
-    from deepspeed_tpu.inference import model, paged
+    from deepspeed_tpu.inference import cache, model, paged
     from deepspeed_tpu.models import CausalLM
     from deepspeed_tpu.ops import registry
     from deepspeed_tpu.ops.pallas import flash_attention as fa, moe_decode, norms, paged_attention as pa
@@ -1256,8 +1253,7 @@ def test_command_a_plus_programs_compile_at_the_cell_s_shapes(one_chip, monkeypa
         lambda key: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), CausalLM(cfg).init(
             {"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)}, train=False)["params"]),
         jax.random.PRNGKey(0)))
-    pool = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: paged.RingPools(
-        paged.init_pool(cfg, NB, bs, jnp.bfloat16), paged.init_ring_pool(cfg, ring_blocks, bs, jnp.bfloat16))))
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: cache.Pools(cache.init_pool(cfg, NB, bs, jnp.bfloat16), ring=cache.init_ring_pool(cfg, ring_blocks, bs, jnp.bfloat16))))
     assert pool.kv.k.shape == (10216, 16, 1024) and pool.ring.k.shape == (3 * 2056, 16, 1024)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
     if name == "chain_8":
@@ -1335,7 +1331,7 @@ def test_decode_chain_updates_the_pool_in_place(one_chip, monkeypatch, kv_quant)
     out of it, would not), and the kernel reads the pool's own rank-3 array."""
     import re
 
-    from deepspeed_tpu.inference import paged
+    from deepspeed_tpu.inference import cache, paged
     from deepspeed_tpu.models import CausalLM, TransformerConfig
     from deepspeed_tpu.ops import registry
     from deepspeed_tpu.ops.pallas import paged_attention as pa
@@ -1350,7 +1346,7 @@ def test_decode_chain_updates_the_pool_in_place(one_chip, monkeypatch, kv_quant)
         lambda key: CausalLM(cfg).init({"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)},
                                        train=False)["params"], jax.random.PRNGKey(0)))
     pool = jax.tree_util.tree_map(sds, jax.eval_shape(
-        lambda: paged.init_pool(cfg, NB, bs, jnp.bfloat16, kv_quant=kv_quant)))
+        lambda: cache.Pools(cache.init_pool(cfg, NB, bs, jnp.bfloat16, kv_quant=kv_quant))))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -1362,7 +1358,7 @@ def test_decode_chain_updates_the_pool_in_place(one_chip, monkeypatch, kv_quant)
         params, pool, i32(rows), i32(rows), i32(rows, cfg.max_seq_len // bs),
         jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip), i32(rows),
         jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)).compile()
-    values = pool.k.size * pool.k.dtype.itemsize  # one of the pool's two value arrays
+    values = pool.kv.k.size * pool.kv.k.dtype.itemsize  # one of the pool's two value arrays
     pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pool))
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes

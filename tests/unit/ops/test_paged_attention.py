@@ -105,7 +105,8 @@ def test_paged_pallas_gqa_grouping(layer):
 
 def test_ragged_forward_uses_kernel_consistently():
     """v2 ragged_forward parity between forced impls (engine path sanity)."""
-    from deepspeed_tpu.inference.paged import init_pool, ragged_forward
+    from deepspeed_tpu.inference.cache import Pools, init_pool
+    from deepspeed_tpu.inference.paged import ragged_forward
     from deepspeed_tpu.models import CausalLM, TransformerConfig
 
     cfg = TransformerConfig(vocab_size=64, hidden_size=32, intermediate_size=48,
@@ -114,7 +115,7 @@ def test_ragged_forward_uses_kernel_consistently():
     module = CausalLM(cfg)
     batch = {"input_ids": jnp.zeros((1, 8), jnp.int32)}
     params = module.init({"params": jax.random.PRNGKey(0)}, batch, train=False)["params"]
-    pool = init_pool(cfg, num_blocks=8, block_size=16, dtype=jnp.float32)
+    pool = Pools(init_pool(cfg, num_blocks=8, block_size=16, dtype=jnp.float32))
     tokens = jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, 8)), jnp.int32)
     positions = jnp.broadcast_to(jnp.arange(8), (2, 8)).astype(jnp.int32)
     new_lens = jnp.asarray([8, 5], jnp.int32)

@@ -137,7 +137,7 @@ def test_offload_bf16_grad_transfer_close_to_fp32():
 def test_twin_flow_checkpoint_restores_across_partitionings(tmp_path):
     """Checkpoints canonicalize the Twin-Flow opt_state (the two optax.masked
     partitions merge to ONE param-shaped moment tree on save, re-partition on
-    load — ADVICE round 5): a checkpoint saved under ratio=0.5 restores into
+    load): a checkpoint saved under ratio=0.5 restores into
     a non-Twin-Flow engine AND into a different-ratio (0.75) engine, with
     identical multi-step trajectories. (Restored engines step freely now:
     load_checkpoint's 'fresh' placement restores into newly allocated
@@ -210,7 +210,8 @@ def test_twin_flow_universal_checkpoint_canonical(tmp_path):
 def test_twin_flow_warns_on_bf16_grad_accumulation(caplog):
     """bf16.accumulate_grads_in_fp32=false is force-overridden to fp32 on the
     Twin-Flow path (its stats/partition programs need fp32 grads) — that must
-    warn, not silently lie (ADVICE round 5; the prescale_gradients stance)."""
+    warn, not silently lie (the prescale_gradients stance: a knob that lies is
+    worse than an error)."""
     import logging
 
     cfg = _cfg({"offload_optimizer": {"device": "cpu", "ratio": 0.5}})

@@ -111,7 +111,7 @@ def test_gathered_parameters_rejects_param_list():
     reference's GatheredParameters(params, modifier_rank=...) takes a
     parameter list, the TPU-native form takes the engine — passing anything
     without `.state` must not surface later as an opaque AttributeError
-    (ADVICE round 5; divergence documented in migrating-from-deepspeed.md)."""
+    (the divergence is documented in migrating-from-deepspeed.md)."""
     import pytest
 
     from deepspeed_tpu import zero

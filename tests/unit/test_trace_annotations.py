@@ -373,7 +373,7 @@ def _lowered_programs(trainer, server):
     step = trainer._train_step.lower(trainer.state, placed)
     rows, k = 4, 4
     chain = server._chain_fn(rows, k, None, (("do_sample", False),)).lower(
-        server.params, server.pool, jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
+        server.params, server.pools, jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
         jnp.zeros((rows, server.max_pages), jnp.int32), jnp.ones((rows,), bool),
         jnp.full((rows,), k, jnp.int32), jax.random.PRNGKey(0))
     return step, chain
