@@ -200,6 +200,15 @@ def latent_paged_attention(q, pool, block_tables, q_positions, block_size, scale
 _MASKED_KERNEL_MIN_QUERIES = 16
 
 
+def masked_walk_reads(queries: int, dtype):
+    """The type ``latent_paged_attention`` reads a chunk's ``mask`` in: the kernel's walk 0/1 in the queries' own
+    (handed that, it converts nothing), XLA's form bool."""
+    import deepspeed_tpu.ops.pallas.paged_attention  # noqa: F401  (registers the kernel)
+
+    kernel = queries >= _MASKED_KERNEL_MIN_QUERIES and dispatch("latent_paged_attention") is not _xla_latent_paged_attention
+    return dtype if kernel else jnp.bool_
+
+
 def latent_selected_attention(q, pool, block_tables, selected, block_size, scale, v_width):
     """ONE query a row against the cached tokens an indexer chose for it,
     gathered by position: q [N, 1, H, W]; ``selected`` int32 [N, K] positions
@@ -307,13 +316,15 @@ def _indexed_latent_attention(ap, cfg: TransformerConfig, h, positions, new_lens
                     if C == 1:
                         chosen = handed = dsa.select_positions(scores[:, 0], cfg.index_topk)
                     else:
-                        chosen = dsa.select_mask(scores, cfg.index_topk)
+                        # (in the type the walk below reads it in; the scores are -inf past a query's position)
+                        chosen = dsa.select_mask(scores, cfg.index_topk, masked_walk_reads(C, q.dtype), positions)
                         if hand_mask:
                             handed = dsa.pack_mask(chosen[..., :S])
                         else:  # a row's (cached tokens scored, cached tokens kept), counted off the selection itself
                             live = jnp.arange(C)[None, :] < new_lens[:, None]  # (a pad query stands at position 0)
                             handed = jnp.stack([jnp.where(live, positions + 1, 0).sum(axis=1),
-                                                (chosen & live[..., None]).sum(axis=(1, 2), dtype=jnp.int32)], axis=-1)
+                                                (chosen.astype(bool) & live[..., None]).sum(axis=(1, 2), dtype=jnp.int32)],
+                                               axis=-1)
             with reading(ap, "wkv_b"):
                 q_lat = jnp.einsum("nchd,rhd->nchr", q[..., :nope], w_kvb[..., :nope])
             q_slab = slab(q_lat, q_rope)

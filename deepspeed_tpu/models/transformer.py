@@ -1193,7 +1193,7 @@ class LatentAttention(nn.Module):
                                  interleaved=cfg.rope_interleaved)
         q, k = turn(q), turn(k[..., None, :])[..., 0, :]
         within = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])  # a key's slot in the sequence given
-        chosen = dsa.select_mask(dsa.index_scores(q, k, w, within), cfg.index_topk)
+        chosen = dsa.select_mask(dsa.index_scores(q, k, w, within), cfg.index_topk, q_positions=within)
         bias = jnp.where(chosen, 0.0, -1e30).astype(jnp.float32)
         return jnp.broadcast_to(bias[:, None], (x.shape[0], cfg.num_heads) + bias.shape[1:])
 

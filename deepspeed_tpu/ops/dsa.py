@@ -23,9 +23,13 @@ Three pieces, each with its plain XLA form here:
   memory, where XLA would write all 32 heads' before it sums them.
 - ``select_mask``: the choice for every query of a chunk, as a mask. No sort:
   the ``index_topk``-th largest score of a row is found by bisection on the
-  scores' bit patterns (31 passes of one compare and one count each, whatever
+  scores' bit patterns (31 steps of one compare and one count each, whatever
   ``index_topk``), and a tie at the threshold goes to the lower positions by a
-  running count that is computed only in a call that has such a tie.
+  running count that is computed only in a call that has such a tie. On the
+  chip a prompt's call is the kernel ``dsa_select`` (``ops/pallas/dsa.py``):
+  the 31 steps run on a tile of queries' keys in fast memory, over the columns
+  the tile can see, so HBM gives the scores once and takes the mask once, in
+  the type its reader asked for; XLA's form reads a row's keys every step.
 - ``select_positions``: the choice for ONE query a row (a decode step), as
   positions, by ``lax.top_k`` (lower index first among equals: the same set).
 
@@ -110,13 +114,12 @@ def _ordered(scores: jax.Array) -> jax.Array:
     return jnp.where(bits < 0, bits ^ np.int32(0x7FFFFFFF), bits)
 
 
-def select_mask(scores: jax.Array, topk: int) -> jax.Array:
-    """bool ``[..., S]``: the ``topk`` largest of each row of ``scores`` (``-inf``: no candidate), every
-    candidate of a row with no more than ``topk``, ties to the lower index."""
+@register("dsa_select", "xla")
+def _xla_select_mask(scores: jax.Array, topk: int, q_positions=None, dtype=jnp.bool_) -> jax.Array:
     key = _ordered(scores)
     candidate = scores > -jnp.inf
     if scores.shape[-1] <= topk:
-        return candidate
+        return candidate.astype(dtype)
 
     def enough(at):  # rows with topk keys or more at or over ``at``
         return (key >= at[..., None]).sum(-1) >= topk
@@ -133,7 +136,23 @@ def select_mask(scores: jax.Array, topk: int) -> jax.Array:
     left = topk - over.sum(-1, keepdims=True)  # what the tied positions may still take
     lower_first = lambda: over | (tied & (jnp.cumsum(tied, axis=-1, dtype=jnp.int32) <= left))
     chosen = jax.lax.cond((tied.sum(-1, keepdims=True) > left).any(), lower_first, lambda: over | tied)
-    return chosen & candidate
+    return (chosen & candidate).astype(dtype)  # (bool as bool: no conversion)
+
+
+def select_mask(scores: jax.Array, topk: int, dtype=jnp.bool_, q_positions: jax.Array = None,
+                impl: str = "auto") -> jax.Array:
+    """``[..., S]``, 1 (True) at the ``topk`` largest of each row of ``scores`` (``-inf``: no candidate), at
+    every candidate of a row with no more than ``topk``, ties to the lower index; in ``dtype``, the type the
+    mask's reader takes it in (bool; the latent kernel's walk the queries' own). ``q_positions`` ``[N, C]``, where
+    the caller has them, say that query ``c`` of row ``n`` of ``scores`` ``[N, C, S]`` has no candidate past that
+    column (``index_scores``' ``-inf``): the kernel then neither fetches nor counts what no query of a tile sees.
+    The kernel takes a chunk of whole tiles of queries with more columns than it keeps; fewer queries (a token
+    and its drafts) and ``S <= topk`` (every candidate) are XLA's."""
+    import deepspeed_tpu.ops.pallas.dsa  # noqa: F401  (registers the kernel)
+
+    if impl == "auto" and (scores.ndim != 3 or scores.shape[1] < _KERNEL_MIN_QUERIES or scores.shape[2] <= topk):
+        impl = "xla"
+    return dispatch("dsa_select", impl)(scores, topk, q_positions, dtype)
 
 
 def select_positions(scores: jax.Array, topk: int) -> jax.Array:

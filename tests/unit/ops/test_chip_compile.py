@@ -1024,6 +1024,27 @@ def test_the_index_kernel_compiles_at_the_cell_s_shapes(one_chip, monkeypatch):
     assert re.search(r"%dsa_index\S* = f32\[1,8192,8704\]", call), call[:300]
 
 
+def test_the_select_kernel_compiles_at_the_cell_s_shapes(one_chip, monkeypatch):
+    """``dsa_select`` at glm-5.serve.long-prompt-wave8's own shapes: a row's scores as ``dsa_index`` leaves them,
+    ``f32[1, 8192, 8704]``, 2,048 kept, the mask out in the walk's bf16: ONE Mosaic kernel that takes the scores
+    as they come (no copy, no slice of the columns) in tiles of 64 queries, 8.9 MiB by ``_select_form``'s count,
+    within the default 16 MiB scope (no ``vmem_limit_bytes``); asked for bool it is the same kernel and one
+    compare after it."""
+    from deepspeed_tpu.ops.pallas import dsa as kernel
+
+    monkeypatch.setattr(kernel, "_interpret", lambda: False)
+    assert kernel._select_form(8192, 8704, 2) == (64, 512)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    for dtype, out in ((jnp.bfloat16, "bf16"), (jnp.bool_, "pred")):
+        text = jax.jit(lambda s, p: kernel.select_mask(s, 2048, p, dtype)).lower(  # noqa: B023
+            sds((1, 8192, 8704), jnp.float32), sds((1, 8192), jnp.int32)).compile().as_text()
+        (call,) = [line for line in text.splitlines() if "tpu_custom_call" in line]
+        assert re.search(r"%dsa_select\S* = bf16\[1,8192,8704\]", call), call[:300]
+        assert "vmem_limit" not in call.split("custom_call_config")[0]
+        assert not re.search(r"= f32\[1,8192,8704\]\S* (copy|fusion|slice|pad)\(", text)
+        assert re.search(r"ROOT \S+ = %s\[1,8192,8704\]" % out, text)
+
+
 def _masked_latent_kernel_compiled(one_chip, monkeypatch, N, C, H):
     """``dsa_paged_attn`` at the glm-5 cell's pool (1 GiB of 6 layers), table (516 pages) and mask width."""
     return _latent_kernel_compiled(one_chip, monkeypatch, N, C, H, 43686, 516, 1 / 16, mask_columns=8704)
@@ -1079,9 +1100,11 @@ def test_glm_5_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, name
     widths, 64 heads, the indexer's 32 heads of 128 keeping 2,048, 16 of 256
     experts held, a 1 GiB pool of latents AND index keys on one block table of
     516 pages), with the picks handed out as the timed path hands them: the
-    ``(2, 8192)`` prefill (``dsa_index`` and ``dsa_paged_attn`` once in the
-    dense layer and once in the scan's body, the share's sorted dispatch
-    through megablox ``gmm``), the check's ``(4, 8192)`` and ``(4, 1)`` steps
+    ``(2, 8192)`` prefill (``dsa_index``, ``dsa_select`` and
+    ``dsa_paged_attn`` once in the dense layer and once in the scan's body,
+    the scores going from the first to the second and the bf16 mask from the
+    second to the third as they are, no copy and no conversion between; the
+    share's sorted dispatch through megablox ``gmm``), the check's ``(4, 8192)`` and ``(4, 1)`` steps
     (``runners/serve.py::check`` feeds four prompts through ``put``: their
     attention goes a row of 8,192 at a time, ``_ATTEND_GROUP_TOKENS``, or the
     four rows' absorbed queries alone are 2.7 GB) and the chain of 8 steps at 8
@@ -1148,10 +1171,17 @@ def test_glm_5_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, name
     calls = re.findall(r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
     count = lambda kernel: sum(kernel in name for name in calls)  # noqa: E731
     if chunk_of(name) > 1:
-        assert (count("dsa_index"), count("dsa_paged_attn")) == (2, 2) and count("gmm") >= 3
+        assert (count("dsa_index"), count("dsa_select"), count("dsa_paged_attn")) == (2, 2, 2) and count("gmm") >= 3
+        # the scores and the mask go from kernel to kernel as they are: nothing else makes an array of their shape
+        # (a fusion's inner instructions are no arrays: the counters' reduce reads the mask and writes two numbers)
+        unfused = re.sub(r"(?ms)^%fused_computation\S* \(.*?^}$", "", text)
+        between = [line.strip()[:200] for line in unfused.splitlines()
+                   if re.search(r"= (f32|s32|bf16|pred)\[1,8192,8704\]", line) and "tpu_custom_call" not in line]
+        assert not between, between
         assert not count("mla_paged_attn") and not count("moe_decode")
     else:  # one token a row: no kernel of the indexer's, the kept rows gathered
         assert not count("dsa_index") and not count("dsa_paged_attn") and not count("mla_paged_attn")
+        assert not any(name.endswith("dsa_select") for name in calls)  # (the scope is there: ``lax.top_k``)
         assert "dsa_select" in text and "dsa_attend" in text
         if name == "chain_8":
             assert count("moe_decode") == 1

@@ -1,7 +1,8 @@
 """``ops/dsa.py`` (a learned sparse-attention indexer's scores and its choice of
-the kept tokens) and its two kernels in interpret mode against their plain XLA
-forms: ``dsa_index`` (``ops/pallas/dsa.py``) and the latent kernel under a
-per-query mask (``dsa_paged_attn``, ``ops/pallas/paged_attention.py``)."""
+the kept tokens) and its three kernels in interpret mode against their plain
+XLA forms: ``dsa_index`` and ``dsa_select`` (``ops/pallas/dsa.py``) and the
+latent kernel under a per-query mask (``dsa_paged_attn``,
+``ops/pallas/paged_attention.py``)."""
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +44,117 @@ def test_the_choice_of_the_kept_is_top_k_s_as_sets(shape, topk, ties):
         assert set(at[row][at[row] >= 0]) == set(np.nonzero(want[row, -1])[0])
     packed = np.asarray(dsa.pack_mask(jnp.asarray(chosen)))
     assert (np.unpackbits(packed.view(np.uint8), axis=-1, bitorder="little")[..., :shape[-1]] == chosen).all()
+
+
+def _halves(rng, shape):
+    return np.round(rng.normal(size=shape) * 2) / 2  # many equal scores, 0.0 and -0.0 among them
+
+
+def _normal(rng, shape):
+    return rng.normal(size=shape) * 2
+
+
+def _zeros_of_both_signs(rng, shape):
+    return rng.choice(np.asarray([-0.0, 0.0, 1.0, -1.0], np.float32), size=shape, p=[0.4, 0.4, 0.1, 0.1])
+
+
+_FROM_0 = np.arange(128)
+# rows, columns, kept, the scores' draw, each query's position (one row's, or a row each): a tile is 64 queries
+# at these shapes, a column chunk 512 columns where they divide the row, else 256 or 128
+_SELECT_CASES = {
+    "ties": (2, 640, 64, _halves, _FROM_0 * 5),
+    "distinct": (2, 512, 32, _normal, _FROM_0 * 4),
+    "as-many-columns-as-kept": (1, 128, 128, _halves, _FROM_0),
+    "fewer-columns-than-kept": (1, 128, 200, _normal, _FROM_0),
+    "pad-queries-at-minus-1-among-live-ones": (1, 1024, 64, _halves, np.where(_FROM_0 < 100, _FROM_0 + 300, -1)),
+    "pad-queries-at-0-as-the-engine-has-them": (1, 1024, 64, _normal, np.where(_FROM_0 < 100, _FROM_0 + 300, 0)),
+    "a-tile-wholly-under-topk": (2, 256, 64, _halves, _FROM_0),
+    "a-tile-of-pads-alone": (2, 512, 32, _normal, np.stack([np.where(_FROM_0 < 64, _FROM_0 * 7, -1), _FROM_0 * 3])),
+    "a-last-position-ends-inside-a-column-chunk": (1, 1024, 64, _halves, np.where(_FROM_0 < 64, _FROM_0 + 237, _FROM_0 + 573)),
+    "columns-of-minus-inf-past-the-keys": (1, 1024, 64, _normal, np.minimum(_FROM_0 * 5, 599)),
+    "zeros-of-both-signs-at-the-threshold": (2, 384, 64, _zeros_of_both_signs, _FROM_0 * 3),
+    "queries-and-columns-not-in-whole-tiles": (1, 300, 32, _halves, np.arange(150) * 2),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bool_, jnp.bfloat16], ids=["bool", "bf16"])
+@pytest.mark.parametrize("case", list(_SELECT_CASES))
+def test_the_select_kernel_against_its_xla_form(case, dtype):
+    """Interpret mode: ``dsa_select`` gives the mask ``select_mask``'s XLA form gives, equal as arrays, in the
+    type asked for: the four cases of the test above at whole tiles of queries (ties AT the threshold, which the
+    kernel breaks by a bisection on the column index where XLA counts along the row; distinct scores; as many
+    columns as kept and fewer, where nothing is sought), pad queries at position -1 among live ones and at
+    position 0 as the serving engine has them, a tile wholly under ``topk`` beside one over it (the first bisects
+    nothing), a tile of pads alone (nothing fetched, a row's last tile before the next row's first), last
+    positions 300 and 700 inside 512-column chunks (the second chunk of the first tile is neither fetched nor
+    counted), columns of ``-inf`` past the row's keys as ``dsa_index`` leaves them, a threshold AT zero among
+    ``0.0`` and ``-0.0`` (one value), and a call of 150 queries against 300 columns (padded to whole tiles and
+    cut back)."""
+    from deepspeed_tpu.ops.pallas import dsa as kernel
+
+    N, S, topk, draw, positions = _SELECT_CASES[case]
+    positions = np.broadcast_to(positions, (N,) + positions.shape[-1:])
+    rng = np.random.default_rng(0)
+    seen = np.arange(S)[None, None] <= positions[..., None]
+    scores = jnp.asarray(np.where(seen, draw(rng, seen.shape), -np.inf), jnp.float32)
+    want = dsa.select_mask(scores, topk, dtype, impl="xla")
+    got = kernel.select_mask(scores, topk, jnp.asarray(positions, jnp.int32), dtype)
+    assert got.dtype == want.dtype == dtype and got.shape == want.shape == scores.shape
+    assert (np.asarray(got) == np.asarray(want)).all()
+    assert (np.asarray(got).astype(bool) == _as_sets(scores, topk)).all()
+    assert (np.asarray(got).astype(np.int32).sum(-1) == np.minimum(positions + 1, topk)).all()
+    if case == "ties":  # without the positions every tile looks at every column: the same mask
+        assert (np.asarray(kernel.select_mask(scores, topk, None, dtype)) == np.asarray(want)).all()
+
+
+def test_which_form_chooses_is_read_from_the_call_s_shapes(monkeypatch):
+    """On the chip a chunk of 128 queries or more with more columns than it keeps is the kernel's; fewer queries
+    (a token and its drafts), ``S <= topk`` (every candidate) and every call off the chip are XLA's. And the
+    type the model asks the mask in is the one its walk reads: the queries' own for the kernel's, bool for XLA's."""
+    from deepspeed_tpu.inference import paged
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import dsa as kernel  # noqa: F401  (registers the kernel)
+
+    taken = []
+    monkeypatch.setitem(registry._REGISTRY["dsa_select"], "pallas",
+                        lambda scores, *rest: taken.append(scores.shape) or "the kernel's")
+    scores = lambda C, S: jnp.zeros((1, C, S), jnp.float32)  # noqa: E731
+    assert dsa.select_mask(scores(128, 65), 64).dtype == jnp.bool_ and not taken  # off the chip
+    assert paged.masked_walk_reads(8192, jnp.bfloat16) == jnp.bool_
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")
+    assert dsa.select_mask(scores(128, 65), 64) == "the kernel's" and taken == [(1, 128, 65)]
+    for C, S in ((127, 300), (1, 300), (128, 64), (8192, 40)):
+        assert dsa.select_mask(scores(C, S), 64).shape == (1, C, S)
+    assert dsa.select_mask(jnp.zeros((300, 65), jnp.float32), 64).shape == (300, 65)  # no chunk of a row's queries
+    assert taken == [(1, 128, 65)]
+    assert paged.masked_walk_reads(8192, jnp.bfloat16) == jnp.bfloat16
+    assert paged.masked_walk_reads(8, jnp.bfloat16) == jnp.bool_  # a token and its drafts: XLA's walk
+
+
+def test_the_bench_tool_counts_the_cells_the_kernel_skips(monkeypatch):
+    """``tools/latent_kernel_bench.py --shapes glm5-select`` at a toy shape: a line a prompt length and form, the
+    two masks equal as arrays, the share of (query tile, column chunk) cells not fetched from the shapes (a
+    prompt of 100 in a bucket of 256 queries against four chunks of 256 columns: tiles of 64 whose last
+    positions are 63, 99, 0, 0 fetch one chunk each, 4 of 16), and no device time without a chip: the host's
+    clock is never written under ``ms_per_call``."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "..", "tools", "latent_kernel_bench.py")
+    spec = importlib.util.spec_from_file_location("latent_kernel_bench", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    from deepspeed_tpu.ops.pallas import dsa as kernel
+
+    monkeypatch.setitem(tool.SELECT, "toy", (256, 1024, 64, (100, 256)))
+    monkeypatch.setattr(kernel, "_select_form", kernel._select_form)  # (the tool stands in for it; put back here)
+    lines = list(tool.measure_select("toy", (64, 256), seed=1, calls=2, repeats=1))
+    assert [(line["prompt"], line["form"]) for line in lines] == [(100, "xla"), (100, "kernel"), (256, "xla"), (256, "kernel")]
+    short, whole = lines[1], lines[3]
+    assert short["equal_to_xla"] and whole["equal_to_xla"] and short["kept"] == lines[0]["kept"]
+    assert short["cells_skipped_share"] == 0.75 and short["score_bytes_read"] == 4 * 64 * 256 * 4
+    assert whole["cells_skipped_share"] == 1 - (1 + 1 + 1 + 1) / 16  # 256 columns hold every position of the bucket
+    assert short["ms_per_call"] is None and short["host_ms_per_call"] > 0
 
 
 def test_the_index_key_s_norm_is_a_layer_norm_with_bias():

@@ -66,10 +66,10 @@ def plant_recent():
     def by_position(scores):
         return jnp.where(scores > -jnp.inf, jnp.arange(scores.shape[-1], dtype=jnp.float32), -jnp.inf)
 
-    def select_mask(scores, topk):
+    def select_mask(scores, topk, dtype=jnp.bool_, q_positions=None):
         candidate = scores > -jnp.inf
         seen = candidate.sum(-1, keepdims=True)
-        return candidate & (jnp.arange(scores.shape[-1]) >= seen - topk)
+        return (candidate & (jnp.arange(scores.shape[-1]) >= seen - topk)).astype(dtype)
 
     dsa.select_mask = select_mask
     dsa.select_positions = lambda scores, topk: positions(by_position(scores), topk)

@@ -8,8 +8,18 @@ the instruction `mla_paged_attn`), at the shapes the two latent cells run it:
 | `xing-decode` (`chain`) | [64, 1, 32, 640] | xing's | 256 | 1,024-2,048 + 0-31 decoded |
 | `glm-decode` (`chain`) | [64, 1, 20, 640] | glm's | 128 | 64-256 + 0-191 decoded |
 | `glm5-masked` (`glm-5.serve.long-prompt-wave8`, `step`; NOT in the default list) | [1, 8192, 64, 640] | [6 x 7,281, 16, 640] | 516 | one prompt uniform 4,096-8,192, under a MASK: each query's 2,048 largest of seeded random scores at or before it, all of them under 2,048 |
+| `glm5-select` (the same cell's `step`; NOT in the default list) | the choice of the kept tokens ALONE, `ops/dsa.py::select_mask`: scores `f32[1, 8192, 8704]`, 2,048 kept, a bf16 mask out | | | one prompt of 4,096, of 6,144 and of 8,192 tokens from position 0, the bucket's other queries pads at position 0 as the engine has them |
 
     chiprun -- python tools/latent_kernel_bench.py [--shapes xing glm ...] [--forms 16:16,32:16]
+
+`glm5-select` prints one line a prompt length and form (`xla`: the bisection as
+31 reduce fusions over a row's keys in HBM; `kernel`: `dsa_select`,
+`ops/pallas/dsa.py`, a tile of queries' keys bisected in fast memory): the
+host's ms a call of some tens under one jit, for the kernel the instruction's
+own device ms (`ms_per_call`), the bytes of scores it reads, the share of
+(query tile, column chunk) cells it does not fetch, and whether the two masks
+are equal as arrays. There `--forms` stands in for `_select_form`, `queries a
+tile : columns a chunk`.
 
 `glm5-masked` is the kernel under a per-query mask, the instruction
 `dsa_paged_attn` (the walk still visits every position at or before a query:
@@ -65,6 +75,7 @@ SHAPES = {
     "glm5-masked": (1, 8192, 64, 7281, 6, 516, (4096, 8192), 0, 256 ** -0.5),
 }
 KEPT = {"glm5-masked": 2048}  # shapes under a per-query mask: the positions a query keeps
+SELECT = {"glm5-select": (8192, 8704, 2048, (4096, 6144, 8192))}  # queries, columns, kept, prompt lengths
 
 
 def draw(shape: str, seed: int, layer: int = 3):
@@ -213,9 +224,60 @@ def measure(shape: str, form, seed: int, calls: int, repeats: int = 5) -> dict:
             "finite": finite, "row0_err_against_the_gather": err}
 
 
+def measure_select(shape: str, form, seed: int, calls: int, repeats: int = 5):
+    """The choice of the kept tokens alone, both forms, a line a prompt length."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops import dsa
+    from deepspeed_tpu.ops.pallas import dsa as kernel
+
+    C, S, topk, lengths = SELECT[shape]
+    if form is not None:
+        kernel._select_form = lambda *shapes: form  # every call below is traced in here; `main` puts it back
+    tq, T = form or kernel._select_form(C, S, 2)
+    for length in lengths:
+        pos = jnp.asarray(np.where(np.arange(C) < length, np.arange(C), 0)[None], jnp.int32)
+        scores = jax.random.normal(jax.random.PRNGKey(seed + length), (1, C, S), jnp.float32)
+        scores = jnp.where(jnp.arange(S)[None, None] <= pos[..., None], scores, -jnp.inf)
+        last = np.asarray(pos).reshape(-1, tq).max(-1)
+        fetched = int((last // T + 1).sum())  # (query tile, column chunk) cells
+        masks = {}
+        for impl in ("xla", "pallas"):
+            choose = lambda s, p: dsa.select_mask(s, topk, jnp.bfloat16, p, impl=impl)  # noqa: E731
+
+            @jax.jit
+            def many(scores, pos):
+                def one(i, carry):
+                    s, _ = jax.lax.optimization_barrier((scores, i))  # nothing of a call is hoisted out of the loop
+                    return carry + choose(s, pos)[0, 0, :8].astype(jnp.float32)
+
+                return jax.lax.fori_loop(0, calls, one, jnp.zeros((8,), jnp.float32))
+
+            masks[impl] = jax.jit(choose)(scores, pos)
+            jax.block_until_ready(many(scores, pos))
+            times = []
+            for _ in range(repeats):
+                t0 = time.perf_counter()
+                jax.block_until_ready(many(scores, pos))
+                times.append((time.perf_counter() - t0) / calls)
+            traced = instruction_seconds(lambda: jax.block_until_ready(many(scores, pos)), "dsa_select")  # noqa: B023
+            line = {"shape": shape, "prompt": length, "form": "xla", "seed": seed, "calls": calls,
+                    "host_ms_per_call": 1e3 * float(np.median(times)), "kept": int(masks[impl].astype(jnp.int32).sum()),
+                    "score_bytes_read": 32 * C * S * 4}  # (the keys, once a step of the bisection)
+            if impl == "pallas":
+                line.update(form="kernel", tile_queries=tq, chunk_columns=T, instruction=traced[0],
+                            ms_per_call=1e3 * traced[1] / calls if traced[0] else None,  # never the host's clock
+                            score_bytes_read=fetched * tq * T * 4,
+                            cells_skipped_share=1 - fetched / (C // tq * (S // T)),
+                            equal_to_xla=bool((masks["pallas"] == masks["xla"]).all()))
+            yield line
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shapes", nargs="+", default=[s for s in SHAPES if s not in KEPT], choices=list(SHAPES))
+    ap.add_argument("--shapes", nargs="+", default=[s for s in SHAPES if s not in KEPT],
+                    choices=list(SHAPES) + list(SELECT))
     ap.add_argument("--forms", default="", help="tokens a tile:pages a chunk[,...]; default: what the kernel picks")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--calls", type=int, default=0, help="calls a timing; default 24 a prompt shape, 200 a decode shape")
@@ -234,6 +296,17 @@ def main() -> int:
     with open(a.out, "a") as f:
         for shape in a.shapes:
             for form in forms:
+                if shape in SELECT:
+                    from deepspeed_tpu.ops.pallas import dsa as select_kernel
+
+                    picks = select_kernel._select_form
+                    try:
+                        for line in measure_select(shape, form, a.seed, a.calls or 24):
+                            print(json.dumps(line), flush=True)
+                            f.write(json.dumps(line) + "\n")
+                    finally:
+                        select_kernel._select_form = picks
+                    continue
                 calls = a.calls or (200 if SHAPES[shape][1] == 1 else 24)
                 chooser = "_masked_latent_form" if shape in KEPT else "_latent_form"
                 picks = getattr(pa, chooser, None)
