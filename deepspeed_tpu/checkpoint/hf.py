@@ -456,6 +456,92 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
             moe_drop_tokens=False,
             param_dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(dtype, jnp.float32),
         )
+    if mt == "mimo_v2":
+        # a PATTERN of two attention kinds that differ in more than the band (``hybrid_layer_pattern``: 0 a global
+        # layer, 1 a sliding one): the sliding kind has its own kv heads, head widths and rotary base and a learned
+        # sink a head in its softmax (``swa_*``, ``add_swa_attention_sink_bias``); keys of ``head_dim`` beside values
+        # of ``v_head_dim`` in both; rotary over the first ``partial_rotary_factor`` of a head, in halves; values
+        # times ``attention_value_scale``; a sequential pre-norm block with RMSNorm; the leading layers that
+        # ``moe_layer_freq`` marks 0 carry a dense MLP, the others a sigmoid router with a correction bias choosing
+        # (``noaux_tc``) over ``n_routed_experts`` experts HELD here (``expert_parallel: {size, rank}``: the router
+        # scores ``size`` times as many), no shared expert. The config alone is mapped: no checkpoint's key names
+        # are in the repository, so no state dict is converted (``attention_projection_layout`` says how one
+        # stores q, k and v). The vision and audio towers and the multi-token-prediction layers have no key here.
+        L = hf_config["num_hidden_layers"]
+        pattern = list(hf_config.get("hybrid_layer_pattern") or [0] * L)
+        freq = list(hf_config.get("moe_layer_freq") or [1] * L)
+        dense = next((i for i, f in enumerate(freq) if f), L)
+        rope = hf_config.get("rope_scaling") or {}
+        refused = [
+            (len(pattern) != L or len(freq) != L, f"hybrid_layer_pattern / moe_layer_freq not of {L} entries"),
+            (not set(pattern) <= {0, 1}, f"hybrid_layer_pattern of {sorted(set(pattern))}"),
+            (1 not in pattern, "no sliding layer in hybrid_layer_pattern"),
+            (dense == L or not all(freq[dense:]), "moe_layer_freq that is not leading dense layers, then routed ones"),
+            (bool(hf_config.get("attention_bias")), "attention_bias"),
+            (bool(hf_config.get("add_full_attention_sink_bias")), "add_full_attention_sink_bias (a sink in the "
+             "global layers)"),
+            (hf_config.get("swa_num_attention_heads", hf_config["num_attention_heads"])
+             != hf_config["num_attention_heads"], "swa_num_attention_heads != num_attention_heads"),
+            (hf_config.get("hidden_act", "silu") != "silu", f"hidden_act={hf_config.get('hidden_act')!r}"),
+            (hf_config.get("scoring_func", "sigmoid") != "sigmoid", f"scoring_func={hf_config.get('scoring_func')!r}"),
+            (hf_config.get("topk_method", "noaux_tc") != "noaux_tc", f"topk_method={hf_config.get('topk_method')!r}"),
+            (hf_config.get("n_group", 1) != 1 or hf_config.get("topk_group", 1) != 1, "grouped expert choice "
+             "(n_group > 1)"),
+            (bool(hf_config.get("n_shared_experts")), "n_shared_experts"),
+            (rope.get("rope_type", rope.get("type", "default")) != "default", "rope scaling"),
+            (hf_config.get("attention_chunk_size") not in (None, hf_config.get("sliding_window")),
+             "attention_chunk_size apart from sliding_window"),
+            (not hf_config.get("sliding_window"), "no sliding_window"),
+        ]
+        refused = [what for bad, what in refused if bad]
+        if refused:
+            raise ValueError("mimo_v2 with " + "; ".join(refused) + " is unsupported")
+        from deepspeed_tpu.models.transformer import ExpertParallel, SlidingConfig
+
+        share = hf_config.get("expert_parallel")
+        hd = hf_config["head_dim"]
+        rotary = int(hf_config.get("partial_rotary_factor", 1.0) * hd) // 2 * 2
+        dtype = hf_config.get("dtype", hf_config.get("torch_dtype"))
+        return TransformerConfig(
+            vocab_size=hf_config["vocab_size"],
+            hidden_size=hf_config["hidden_size"],
+            intermediate_size=hf_config["intermediate_size"],
+            num_layers=L,
+            num_heads=hf_config["num_attention_heads"],
+            num_kv_heads=hf_config.get("num_key_value_heads"),
+            head_dim=hd,
+            v_head_dim=0 if hf_config.get("v_head_dim", hd) == hd else hf_config["v_head_dim"],
+            max_seq_len=hf_config.get("max_position_embeddings", 262144),
+            norm="rmsnorm",
+            # a norm's weight is kept as its offset from one, drawn off zero: none sits at a constant
+            norm_unit_offset=True,
+            norm_eps=float(hf_config.get("layernorm_epsilon", 1e-5)),
+            activation="silu_glu",
+            qkv_bias=False,
+            dense_bias=False,
+            position="rope",
+            rope_theta=float(hf_config.get("rope_theta", 10000000.0)),
+            rotary_dim=0 if rotary == hd else rotary,
+            value_multiplier=float(hf_config.get("attention_value_scale") or 1.0),
+            tie_embeddings=bool(hf_config.get("tie_word_embeddings", False)),
+            layer_types=tuple("sliding_attention" if kind else "attention" for kind in pattern),
+            sliding=SlidingConfig(
+                window=int(hf_config["sliding_window"]), global_rope=True,
+                num_kv_heads=hf_config.get("swa_num_key_value_heads"), head_dim=hf_config.get("swa_head_dim"),
+                v_head_dim=hf_config.get("swa_v_head_dim"),
+                rope_theta=float(hf_config.get("swa_rope_theta", hf_config.get("rope_theta", 10000.0))),
+                sink=bool(hf_config.get("add_swa_attention_sink_bias"))),
+            first_dense_layers=dense,
+            num_experts=hf_config["n_routed_experts"],
+            expert_parallel=ExpertParallel(int(share["size"]), int(share.get("rank", 0))) if share else None,
+            moe_top_k=hf_config["num_experts_per_tok"],
+            moe_intermediate_size=hf_config["moe_intermediate_size"],
+            moe_router="sigmoid",
+            moe_renormalize=bool(hf_config.get("norm_topk_prob", True)),
+            moe_routed_scale=float(hf_config.get("routed_scaling_factor") or 1.0),
+            moe_drop_tokens=False,
+            param_dtype={"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(dtype, jnp.float32),
+        )
     if mt == "opt":
         if not hf_config.get("do_layer_norm_before", True):
             raise ValueError("OPT post-layernorm variants (do_layer_norm_before=false) are unsupported")
@@ -655,7 +741,7 @@ def config_from_hf(hf_config: Dict[str, Any]) -> TransformerConfig:
     raise ValueError(
         f"unsupported HF model_type {mt!r} (supported: llama/mistral/mixtral/"
         "qwen2/gpt2/opt/falcon/phi/gpt_neox/bloom/gptj/codegen/gpt_bigcode/"
-        "glm4_moe_lite/evabyte/xing4_0/granitemoehybrid/qwen3_next/glm_moe_dsa/cohere2_moe)")
+        "glm4_moe_lite/evabyte/xing4_0/granitemoehybrid/qwen3_next/glm_moe_dsa/cohere2_moe/mimo_v2)")
 
 
 def detect_family(state: Dict[str, np.ndarray]) -> str:

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.models.transformer import TransformerConfig
+from deepspeed_tpu.models.transformer import TransformerConfig, sliding_kind
 from deepspeed_tpu.ops import ssm
 from deepspeed_tpu.utils.hbm import kv_slot_bytes
 
@@ -291,6 +291,17 @@ def index_pool_width(cfg: TransformerConfig) -> int:
     return -(-cfg.index_head_dim // _LANES) * _LANES if cfg.index_topk else 0
 
 
+def by_head(cfg: TransformerConfig, kind: str = "attention") -> Tuple[int, int, int]:
+    """(kv heads, a token's key columns, its value columns) in a page of the class that layers of ``kind`` write:
+    ``kvH * hd`` of each for every model but a pattern with a sliding kind, whose two kinds state their own heads
+    and widths (``transformer.sliding_kind``): a key of 192 columns a head beside a value of 128 lies in its page
+    as it is, ``kvH * 192`` columns of ``k`` and ``kvH * 128`` of ``v``, nothing padded."""
+    if cfg.sliding is None:
+        return cfg.kv_heads, cfg.kv_heads * cfg.dims_per_head, cfg.kv_heads * cfg.dims_per_head
+    own = sliding_kind(cfg, kind)
+    return own["kv_heads"], own["kv_heads"] * own["head_dim"], own["kv_heads"] * own["v_head_dim"]
+
+
 def init_pool(cfg: TransformerConfig, num_blocks: int, block_size: int, dtype: Any = jnp.bfloat16,
               kv_quant: Optional[str] = None) -> PagedKVPool:
     if cfg.eva_window and kv_quant is not None:
@@ -305,23 +316,26 @@ def init_pool(cfg: TransformerConfig, num_blocks: int, block_size: int, dtype: A
         pages = (cfg.num_layers * num_blocks, block_size)
         return PagedKVPool(k=jnp.zeros(pages + (latent_pool_width(cfg),), dtype),
                            v=jnp.zeros(pages + (index_pool_width(cfg),), dtype) if cfg.index_topk else None)
-    # (of a layer pattern, the attention layers alone hold pages)
-    shape = (cfg.attention_layers * num_blocks, block_size, cfg.kv_heads * cfg.dims_per_head)
+    # (of a layer pattern, the attention layers alone hold pages, at the geometry of their kind)
+    heads, keys, values = by_head(cfg)
+    pages = (cfg.attention_layers * num_blocks, block_size)
     if kv_quant is None:
-        return PagedKVPool(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+        return PagedKVPool(k=jnp.zeros(pages + (keys,), dtype), v=jnp.zeros(pages + (values,), dtype))
     if kv_quant not in _KV_QUANT_DTYPES:
         raise ValueError(f"kv_quant must be None|'int8'|'fp8', got {kv_quant!r}")
     qdt = _KV_QUANT_DTYPES[kv_quant]
-    sshape = (shape[0], block_size * cfg.kv_heads)
-    return PagedKVPool(k=jnp.zeros(shape, qdt), v=jnp.zeros(shape, qdt),
+    sshape = (pages[0], block_size * heads)
+    return PagedKVPool(k=jnp.zeros(pages + (keys,), qdt), v=jnp.zeros(pages + (values,), qdt),
                        k_scale=jnp.zeros(sshape, jnp.float32),
                        v_scale=jnp.zeros(sshape, jnp.float32))
 
 
 def init_ring_pool(cfg: TransformerConfig, ring_blocks: int, block_size: int, dtype: Any = jnp.bfloat16):
-    """The sliding layers' class of page (``Pools.ring``): ``ring_blocks`` pages a layer."""
-    shape = (cfg.sliding_layers * ring_blocks, block_size, cfg.kv_heads * cfg.dims_per_head)
-    return PagedKVPool(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+    """The sliding layers' class of page (``Pools.ring``): ``ring_blocks`` pages a layer, at the sliding kind's
+    own geometry."""
+    _, keys, values = by_head(cfg, "sliding_attention")
+    pages = (cfg.sliding_layers * ring_blocks, block_size)
+    return PagedKVPool(k=jnp.zeros(pages + (keys,), dtype), v=jnp.zeros(pages + (values,), dtype))
 
 
 def _state_shapes(cfg: TransformerConfig):
@@ -369,9 +383,13 @@ class PageClass:
     quantized: bool  # has the int8 / fp8 form: 1-byte values and a float32 scale a token a head
 
     def bytes_per_token(self, itemsize: int, kv_quant: Optional[str] = None) -> int:
-        if self.heads:  # the formula the pre-flight guard and the capacity benchmark share
+        if self.heads and self.second == self.width:  # the formula the pre-flight guard and the capacity benchmark share
             return kv_slot_bytes(self.layers, self.heads, self.width // self.heads, itemsize, kv_quant)
-        return self.layers * itemsize * (self.width + self.second)
+        return self.layers * itemsize * (self.width + self.second)  # (a class of two widths has no quantized form)
+
+    def page_bytes(self, block_size: int, itemsize: int) -> int:
+        """One page of ONE layer: ``block_size`` slots of a token's key and value."""
+        return block_size * itemsize * (self.width + self.second)
 
 
 def attention_kind(cfg: TransformerConfig) -> str:
@@ -493,19 +511,18 @@ class CachePlan:
 def cache_plan(cfg: TransformerConfig, block_size: int, max_seq_len: int) -> CachePlan:
     """The plan of ``cfg``'s cache at pages of ``block_size`` tokens and rows of ``max_seq_len`` at the most."""
     kind = attention_kind(cfg)
-    by_head = cfg.kv_heads * cfg.dims_per_head
     if kind in ("latent", "indexed"):  # one slab a token a layer, shared by all heads, and its one index key
         first = PageClass("kv", cfg.num_layers, 0, latent_pool_width(cfg), index_pool_width(cfg),
                           "index keys" if cfg.index_topk else "", quantized=False)
     else:  # (of a layer pattern, the attention layers alone hold these pages; EVA's rows have no per-token scale)
-        first = PageClass("kv", cfg.attention_layers, cfg.kv_heads, by_head, by_head, "values",
-                          quantized=kind == "plain")
+        first = PageClass("kv", cfg.attention_layers, *by_head(cfg), "values", quantized=kind == "plain")
     classes, layout = (first,), PlainLayout(block_size, max_seq_len)
     if kind == "eva":
         layout = WindowLayout(cfg.eva_window, block_size, max_seq_len)
-    elif kind == "windowed":
+    elif kind == "windowed":  # two classes of page, each at its kind's own geometry (heads, key and value widths)
         layout = RingLayout(cfg.sliding.window, block_size, max_seq_len)
-        classes += (PageClass("ring", cfg.sliding_layers, cfg.kv_heads, by_head, by_head, "values", quantized=False),)
+        classes += (PageClass("ring", cfg.sliding_layers, *by_head(cfg, "sliding_attention"), "values",
+                              quantized=False),)
     state = None if not cfg.state_layers else ("linear_attention" if cfg.gdn_layers else "mamba")
     return CachePlan(cfg, block_size, kind, classes, layout, state)
 

@@ -1214,23 +1214,33 @@ class InferenceEngineV2:
         the call's rows hold by class, summed over their layers (``ring_pages``: the sliding layers' rings,
         ``global_pages``: the full layers' pages), and ``one_class_pages``, what one class of page would hold
         for the same rows (every layer a page a block of positions). The same numbers go to the gauges
-        ``serving/kv_pages_held_ring`` and ``serving/kv_pages_held_global``. A chain (``start``: its rows' first
-        positions, ``share``: the steps its budgets plan for each) says ``ring_tokens`` beside them: the sum over
-        its rows and steps of the tokens a sliding layer's ring holds for the query, ``min(position + 1, window)``."""
+        ``serving/kv_pages_held_ring`` and ``serving/kv_pages_held_global``, and the two classes' BYTES, each page
+        at its own class's geometry, go beside them (``ring_bytes_held``, ``global_bytes_held``). A chain
+        (``start``: its rows' first positions, ``share``: the steps its budgets plan for each) says ``ring_tokens``
+        too: the sum over its rows and steps of the tokens a sliding layer's ring holds for the query,
+        ``min(position + 1, window)``; ``global_tokens``, the same sum of what a global layer's table holds,
+        ``position + 1``; ``row_steps``, the (row, step) pairs both sum over; and ``ring_turns``, the ring pages a
+        sliding layer of its rows starts writing over (``RingLayout.overwritten``: the blocks the chain opens
+        past a ring's first round)."""
         if self.plan.rings is None or not self._tracer.recording():
             return {}
-        cfg = self.model_config
+        cfg, rings = self.model_config, self.plan.rings
         seqs = [self.state.get(u) for u in uids]
         blocks = sum(s.n_summary for s in seqs)  # a page a block of positions: what a full layer holds
         ring, held = sum(s.n_window for s in seqs) * cfg.sliding_layers, blocks * cfg.attention_layers
         if self._tracer.enabled:
             self._tracer.registry.gauge("serving/kv_pages_held_ring").set(float(ring))
             self._tracer.registry.gauge("serving/kv_pages_held_global").set(float(held))
-        args = {"ring_pages": ring, "global_pages": held, "one_class_pages": blocks * cfg.num_layers}
+        page = [c.page_bytes(self.config.kv_block_size, jnp.dtype(self.config.kv_jax_dtype).itemsize)
+                for c in self.plan.classes]
+        args = {"ring_pages": ring, "global_pages": held, "one_class_pages": blocks * cfg.num_layers,
+                "ring_bytes_held": ring * page[1], "global_bytes_held": held * page[0]}
         if start is not None:
             fed = np.arange(int(np.max(share, initial=0)))[None, :] < np.asarray(share)[:, None]
-            seen = np.minimum(np.asarray(start)[:, None] + np.arange(fed.shape[1])[None, :] + 1, self.plan.rings.window)
-            args["ring_tokens"] = int(seen[fed].sum())
+            seen = np.asarray(start)[:, None] + np.arange(fed.shape[1])[None, :] + 1
+            args["ring_tokens"] = int(np.minimum(seen, rings.window)[fed].sum())
+            args["global_tokens"], args["row_steps"] = int(seen[fed].sum()), int(fed.sum())
+            args["ring_turns"] = sum(rings.overwritten(int(at), int(n)) for at, n in zip(start, share))
         return args
 
     def _flash_args(self, new_lens, chunk: int) -> Dict[str, int]:

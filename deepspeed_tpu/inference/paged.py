@@ -97,7 +97,7 @@ from deepspeed_tpu.ops.registry import dispatch, register
 
 @register("paged_attention", "xla")
 def _xla_paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_size,
-                         new_lens=None, alibi_slopes=None, k_scale=None, v_scale=None, first_live=None):
+                         new_lens=None, alibi_slopes=None, k_scale=None, v_scale=None, first_live=None, sink=None):
     """Masked GQA attention of new queries against paged caches (dense-gather
     fallback; the Pallas flash-decode kernel in
     ``ops/pallas/paged_attention.py`` wins dispatch on TPU).
@@ -115,6 +115,10 @@ def _xla_paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_siz
     ring's first page is dead slots every day, and a masked score times a
     value that is not a number is not a number, so under ``first_live`` the
     values no query of the row sees are zeroed before the product.
+
+    A value may be narrower than its key (``pool_v`` ``[pages, bs, kvH*hdv]``: the
+    output is ``[N, C, H, hdv]``), and ``sink`` ([H]) is a logit a query head
+    that joins the softmax's denominator and nothing else.
     """
     N, C, H, hd = q.shape
     P = block_tables.shape[1]
@@ -142,14 +146,18 @@ def _xla_paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_siz
         ok = ok & (t_idx[None, None, :] >= first_live[:, :, None])
         cv = jnp.where(ok.any(axis=1)[:, :, None, None], cv, jnp.zeros((), cv.dtype))
     scores = jnp.where(ok[:, None, None, :, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
+    if sink is not None:  # a column that weighs no value
+        column = jnp.broadcast_to(sink.astype(jnp.float32).reshape(1, kvH, G, 1, 1), scores.shape[:-1] + (1,))
+        probs = jax.nn.softmax(jnp.concatenate([scores, column], axis=-1), axis=-1)[..., :-1].astype(cv.dtype)
+    else:
+        probs = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
     ctx = jnp.einsum("nkgct,ntkd->nckgd", probs, cv)
-    return ctx.reshape(N, C, H, hd)
+    return ctx.reshape(N, C, H, cv.shape[-1])
 
 
 def paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_size,
                     new_lens=None, impl: str = "auto", alibi_slopes=None,
-                    k_scale=None, v_scale=None, first_live=None):
+                    k_scale=None, v_scale=None, first_live=None, sink=None):
     import deepspeed_tpu.ops.pallas.paged_attention  # noqa: F401  (registers the kernel)
 
     # alibi is fused in BOTH implementations (the Pallas flash-decode kernel
@@ -160,7 +168,7 @@ def paged_attention(q, pool_k, pool_v, block_tables, q_positions, block_size,
     return dispatch("paged_attention", impl)(
         q, pool_k, pool_v, block_tables, q_positions, block_size,
         new_lens=new_lens, alibi_slopes=alibi_slopes,
-        k_scale=k_scale, v_scale=v_scale, first_live=first_live,
+        k_scale=k_scale, v_scale=v_scale, first_live=first_live, sink=sink,
     )
 
 
@@ -570,6 +578,11 @@ def _windowed_attention(cfg: TransformerConfig, call: "_Call"):
       write (``_page_writer``), a sliding layer the pages of the prompt's LAST
       window alone, whole pages, each into its ring column.
 
+    The two kinds' shapes are their own (``transformer.sliding_kind``: kv heads,
+    the width of keys and of values, the rotary base, a sink a head in the
+    sliding kind's softmax): a token's row is as wide as its class's array, keys
+    and values apart, and the projections' shapes are the parameters'.
+
     Device-trace scopes: ``swa`` around a sliding layer's attention,
     ``attn_full`` around the other kind's, each with ``kv_write`` and the
     kernel's own name inside."""
@@ -582,7 +595,6 @@ def _windowed_attention(cfg: TransformerConfig, call: "_Call"):
     R = ring_columns(W, bs)
     Pg = block_tables.shape[1] - R
     global_cols, ring_cols = block_tables[:, :Pg], block_tables[:, Pg:]
-    X = cfg.kv_heads * cfg.dims_per_head
 
     if C == 1:
         fed = new_lens == 1
@@ -597,15 +609,16 @@ def _windowed_attention(cfg: TransformerConfig, call: "_Call"):
         rel_pos, rel_low = (t - oldest * bs)[:, None], (low - oldest * bs)[:, None]
         ones = fed.astype(jnp.int32)
 
-        def attention(q, k, v, sliding, window, pk, pv, first_page):
+        def attention(q, k, v, sliding, window, sink, pk, pv, first_page):
             with jax.named_scope("kv_write"):
                 page = first_page + (r_page if sliding else g_page)
-                pk = pk.at[page, slot].set(k.astype(pk.dtype).reshape(N, X), mode="drop")
-                pv = pv.at[page, slot].set(v.astype(pv.dtype).reshape(N, X), mode="drop")
+                pk = pk.at[page, slot].set(k.astype(pk.dtype).reshape(N, -1), mode="drop")
+                pv = pv.at[page, slot].set(v.astype(pv.dtype).reshape(N, -1), mode="drop")
             if sliding:
                 return paged_attention(q, pk, pv, rolled + first_page, rel_pos, bs, new_lens=ones,
-                                       first_live=rel_low), pk, pv
-            return paged_attention(q, pk, pv, global_cols + first_page, t[:, None], bs, new_lens=ones), pk, pv
+                                       first_live=rel_low, sink=sink), pk, pv
+            return paged_attention(q, pk, pv, global_cols + first_page, t[:, None], bs, new_lens=ones,
+                                   sink=sink), pk, pv
     else:
         put_full = _page_writer(global_cols, positions, new_lens, bs, full_rows)
         # the blocks that hold a prompt's last window, oldest first, and the chunk's token of each of their slots
@@ -614,26 +627,29 @@ def _windowed_attention(cfg: TransformerConfig, call: "_Call"):
         tok = jnp.clip(blocks[:, :, None] * bs + jnp.arange(bs), 0, C - 1).reshape(N, R * bs, 1)
 
         def put_ring(a, new, first_page):
+            X = a.shape[-1]
             laid = jnp.take_along_axis(new.reshape(N, C, X), tok, axis=1)
             return a.at[(first_page + to).reshape(-1)].set(laid.reshape(N * R, bs, X), mode="drop")
 
-        def attention(q, k, v, sliding, window, pk, pv, first_page):
-            ctx = causal_attention(q, k, v, impl=cfg.attn_impl, window=window, lengths=new_lens)
+        def attention(q, k, v, sliding, window, sink, pk, pv, first_page):
+            ctx = causal_attention(q, k, v, impl=cfg.attn_impl, window=window, lengths=new_lens, sink=sink)
             with jax.named_scope("kv_write"):
                 put = put_ring if sliding else put_full
-                pk = put(pk, k.astype(pk.dtype).reshape(-1, X), first_page)
-                pv = put(pv, v.astype(pv.dtype).reshape(-1, X), first_page)
+                pk = put(pk, k.astype(pk.dtype).reshape(N * C, -1), first_page)
+                pv = put(pv, v.astype(pv.dtype).reshape(N * C, -1), first_page)
             return ctx, pk, pv
 
     def attend(ap, h, pages, first_page, kind):
         sliding = kind == "sliding_attention"
         how = sliding_kind(cfg, kind)
         q, k, v = _qkv(ap, cfg, h)
+        v = _times(cfg.value_multiplier, v)
         if cfg.position == "rope" and how["rotates"]:
             with jax.named_scope("rope"):
-                q, k = apply_qk_rope(cfg, q, k, positions)
+                q, k = apply_qk_rope(cfg, q, k, positions, how["rope_theta"])
         with jax.named_scope("swa" if sliding else "attn_full"):
-            ctx, pk, pv = attention(q, k, v, sliding, how["window"], pages.k, pages.v, first_page)
+            ctx, pk, pv = attention(q, k, v, sliding, how["window"], ap["sink"] if how["sink"] else None,
+                                    pages.k, pages.v, first_page)
         return _attn_out(ap, cfg, ctx), pages._replace(k=pk, v=pv), None
 
     return attend
@@ -982,22 +998,28 @@ def _forward_hidden(
 
     carry = (x, pools)
     kept_dense = []
+    dense_a = dense_s = 0  # the leading dense layers of each kind: the first pages of their classes
     for i in range(D):
         # a leading dense layer of a routed model: its own parameters, its own
-        # pages (layer i's), outside the scan
-        carry, out = layer(carry, params[f"dense_{i}"], jnp.int32(i * NB), dense=True)
+        # pages (layer i's, counted among its kind in a pattern), outside the scan
+        kind = "attention" if cfg.layer_types is None else cfg.layer_types[i]
+        sliding = kind == "sliding_attention"
+        carry, out = layer(carry, params[f"dense_{i}"], jnp.int32(dense_s * NR if sliding else dense_a * NB),
+                           dense=True, kind=kind)
+        dense_s, dense_a = dense_s + sliding, dense_a + (not sliding)
         if indexed:
             kept_dense.append(out[1])
     with jax.named_scope("pool_scan"):
         if cfg.layer_types is not None:
             kinds = cfg.period
-            periods = jnp.arange(cfg.num_layers // len(kinds), dtype=jnp.int32)
+            periods = jnp.arange((cfg.num_layers - D) // len(kinds), dtype=jnp.int32)
+            first = lambda at, n: periods * n + at if at else periods * n  # noqa: E731  (past the leading layers')
             (x, pools), picks = jax.lax.scan(
                 period, carry,
-                (layers, periods * kinds.count("attention"),
-                 periods * (len(kinds) - kinds.count("attention"))) + ((periods,) if stacked else ()))
+                (layers, first(dense_a, kinds.count("attention")),
+                 first(dense_s, len(kinds) - kinds.count("attention"))) + ((periods,) if stacked else ()))
             if routed:  # [periods, layers of a period, N*C, k]: the layers in the model's order
-                picks = picks.reshape((cfg.num_layers,) + picks.shape[2:])
+                picks = picks.reshape((cfg.num_layers - D,) + picks.shape[2:])
         else:
             (x, pools), picks = jax.lax.scan(
                 lambda c, xs: layer(c, _with_experts(xs[0], experts, *xs[2:]), xs[1]), carry,

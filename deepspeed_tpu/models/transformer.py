@@ -140,10 +140,23 @@ class SlidingConfig:
     Where ``position == "rope"`` the sliding layers rotate their queries and
     keys; ``global_rope`` says whether the pattern's ``attention`` layers,
     which see every key, rotate too (False: no position term at all there, the
-    ``cohere2`` family's way)."""
+    ``cohere2`` family's way).
+
+    Where the two kinds differ in more than the band, the sliding kind's own
+    shapes stand here and the ``attention`` kind keeps the model's one-number
+    fields (``sliding_kind`` answers for both): ``num_kv_heads``, ``head_dim``
+    (queries and keys), ``v_head_dim`` and ``rope_theta`` (None: the model's),
+    and ``sink``: a learned logit a query head that joins the softmax's
+    denominator and nothing else, ``p_tj = exp(a_tj) / (exp(s_h) + sum_k
+    exp(a_tk))`` (the parameter ``attn/sink`` ``[num_heads]``)."""
 
     window: int
     global_rope: bool = True
+    num_kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    rope_theta: Optional[float] = None
+    sink: bool = False
 
     def __post_init__(self):
         if self.window < 1:
@@ -291,7 +304,9 @@ class TransformerConfig:
     # beside ONE rotary key of qk_rope_head_dim shared by all heads. A head's
     # query/key is [qk_nope_head_dim | qk_rope_head_dim], its value
     # v_head_dim; scores scale by the whole query width. Serving caches the
-    # latent and the rotary key alone (inference/paged.py).
+    # latent and the rotary key alone (inference/paged.py). Without a latent,
+    # in a pattern with a sliding kind, ``v_head_dim`` is the ``attention``
+    # kind's value width where it is not the key's (0: the key's)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -382,6 +397,9 @@ class TransformerConfig:
     norm_bias: bool = True
     moe_router_bias: bool = True
     moe_shared_average: bool = False
+    # plain attention's values times this, before they are cached and summed (the
+    # same number as the heads' output times it: the sum is linear in the values)
+    value_multiplier: float = 1.0
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -405,18 +423,25 @@ class TransformerConfig:
                 raise ValueError("a 'sliding_attention' layer is plain attention under a band, beside plain "
                                  "attention alone, with rotary positions or none: not with learned or alibi "
                                  "positions, state-space or linear-attention layers, attn_impl sparse | fpdt")
-            # the one parallel block a pattern is built in: the two attention kinds, a routed MLP in every layer
-            parallel = self.parallel_block and not (kinds <= {"attention", "sliding_attention"}
-                                                    and self.num_experts > 0)
-            if (self.hc_mult or parallel or self.first_dense_layers
+            # what a pattern of the two attention kinds alone with a routed MLP may be beside sequential and routed
+            # in every layer: ONE parallel block, or leading dense layers before the routed ones (outside the scan)
+            attends = kinds <= {"attention", "sliding_attention"} and self.num_experts > 0
+            parallel = self.parallel_block and not attends
+            leading = self.first_dense_layers and not (attends and not self.parallel_block)
+            if (self.hc_mult or parallel or leading
                     or self.moe_layer_experts or self.kv_lora_rank or self.eva_window or self.fp32_residual):
                 raise ValueError(
                     "a layer pattern (layer_types) is built around plain attention and one MLP (dense, or "
-                    "routed in every layer) in a sequential one-stream block: no hyper-connections, "
-                    "parallel_block, leading dense or pyramid layers, latent or EVA attention, fp32 residual")
+                    "routed in every layer) in a sequential one-stream block: no hyper-connections, no "
+                    "parallel_block and no leading dense layers but in a pattern of the two attention kinds "
+                    "alone with a routed MLP (and not both there), no pyramid layers, latent or EVA attention, "
+                    "fp32 residual")
         elif self.sliding is not None:
             raise ValueError("sliding=SlidingConfig(...) is the data of a layer pattern's 'sliding_attention' "
                              "kind: give layer_types")
+        if self.v_head_dim and not self.kv_lora_rank and self.sliding is None:
+            raise ValueError(f"v_head_dim={self.v_head_dim} without a latent (kv_lora_rank == 0): a value narrower "
+                             "than its key is latent attention's, or the kinds' of a pattern with a sliding kind")
         if isinstance(self.expert_parallel, dict):
             object.__setattr__(self, "expert_parallel", ExpertParallel(**self.expert_parallel))
         if self.expert_parallel is not None and self.expert_parallel.size == 1:
@@ -554,11 +579,12 @@ class TransformerConfig:
 
     @property
     def period(self) -> Optional[Tuple[str, ...]]:
-        """The shortest run of kinds that ``layer_types`` repeats whole (the
-        whole of it, if none does): what one step of the layer scan runs."""
-        types = self.layer_types
-        if types is None:
+        """The shortest run of kinds that ``layer_types`` repeats whole after
+        its leading dense layers (the whole of it, if none does): what one step
+        of the layer scan runs."""
+        if self.layer_types is None:
             return None
+        types = self.layer_types[self.first_dense_layers:]  # (leading dense layers run outside the scan)
         L = len(types)
         p = next(p for p in range(1, L + 1) if L % p == 0 and types == types[:p] * (L // p))
         return types[:p]
@@ -634,8 +660,12 @@ class TransformerConfig:
         proj = 3 if self.activation == "silu_glu" else 2
         return proj * self.hidden_size * (width or self.intermediate_size)
 
-    def _attention_params(self) -> int:
+    def _attention_params(self, kind: str = "attention") -> int:
         h, H = self.hidden_size, self.num_heads
+        if self.sliding is not None:  # the kind's own shapes: q and k at its key width, v and o at its value's
+            own = sliding_kind(self, kind)
+            return (h * own["head_dim"] * (H + own["kv_heads"]) + h * own["v_head_dim"] * (own["kv_heads"] + H)
+                    + (H if own["sink"] else 0))
         if self.latent_attention:
             qk = self.qk_nope_head_dim + self.qk_rope_head_dim
             q = h * self.q_lora_rank + self.q_lora_rank * (H * qk + 1)
@@ -687,7 +717,8 @@ class TransformerConfig:
             else:
                 layer_mlp = mlp
             kind = self.layer_types[i] if self.layer_types else "attention"
-            mixer = {"mamba": self._ssm_params, "linear_attention": self._gdn_params}.get(kind, lambda: qkv)()
+            mixer = {"mamba": self._ssm_params, "linear_attention": self._gdn_params,
+                     "sliding_attention": lambda: self._attention_params(kind)}.get(kind, lambda: qkv)()
             total += mixer + layer_mlp + (h if self.parallel_block else 2 * h) + 2 * self.hc_params
         return total
 
@@ -818,24 +849,26 @@ def rope_tables(seq_len: int, dim: int, theta: float) -> Tuple[jax.Array, jax.Ar
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def apply_qk_rope(cfg: "TransformerConfig", q, k, positions):
+def apply_qk_rope(cfg: "TransformerConfig", q, k, positions, theta: Optional[float] = None):
     """Apply (possibly partial) rotary embeddings per the config.
 
     Phi-style partial rotary ropes only the first ``rotary_dim`` of head_dim;
     the tail dims pass through. ``rope_interleaved`` selects the GPT-J
     pairwise rotation. Shared by the training attention and both inference
-    decode paths so the three sites cannot drift."""
+    decode paths so the three sites cannot drift. ``theta``: the base of a
+    pattern's kind that states its own (``sliding_kind``; None: ``rope_theta``)."""
     hd = q.shape[-1]
     rd = cfg.rotary_dim or hd
+    theta = cfg.rope_theta if theta is None else theta
     if cfg.max_seq_len > _ROPE_TABLE_POSITIONS:
         # the angles of the call's own positions, the table's numbers: a table over every position such a
         # config declares is made anew by every call (262,144 x 32: 0.9 ms a layer-step on the v5e, PR 48)
-        freqs = 1.0 / (cfg.rope_theta ** (jnp.arange(0, rd, 2, dtype=jnp.float32) / rd))
+        freqs = 1.0 / (theta ** (jnp.arange(0, rd, 2, dtype=jnp.float32) / rd))
         angles = positions.reshape(-1, 1).astype(jnp.float32) * freqs[None, :]
         cos, sin = jnp.cos(angles), jnp.sin(angles)
         positions = jnp.arange(positions.size, dtype=positions.dtype).reshape(positions.shape)
     else:
-        cos, sin = rope_tables(cfg.max_seq_len, rd, cfg.rope_theta)
+        cos, sin = rope_tables(cfg.max_seq_len, rd, theta)
     ap = lambda x: apply_rope(x, cos, sin, positions, interleaved=cfg.rope_interleaved)  # noqa: E731
     if rd < hd:
         q = jnp.concatenate([ap(q[..., :rd]), q[..., rd:]], -1)
@@ -890,29 +923,42 @@ class Attention(nn.Module):
     # rotates (None: as ``config.position`` says)
     window: Optional[int] = None
     rotates: Optional[bool] = None
+    # and the kind's own shapes (``sliding_kind``; None: the model's one-number
+    # fields): kv heads, the width of queries and keys, of values, the rotary
+    # base, and whether a learned logit a head (``sink``) joins the softmax's sum
+    kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    rope_theta: Optional[float] = None
+    sink: bool = False
 
     @nn.compact
     def __call__(self, x, mask, positions, train: bool):
         cfg = self.config
-        hd = cfg.dims_per_head
+        hd = self.head_dim or cfg.dims_per_head
+        kv_heads = self.kv_heads or cfg.kv_heads
         qkv_bias = cfg.qkv_bias if cfg.qkv_bias is not None else cfg.norm == "layernorm"
         # gated attention: a head's projection is ``[q | gate]``
         q = nn.DenseGeneral((cfg.num_heads, hd * (2 if cfg.attn_output_gate else 1)), use_bias=qkv_bias,
                             dot_general=_gathered(self, "wq"),
                             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wq")(x)
-        k = nn.DenseGeneral((cfg.kv_heads, hd), use_bias=qkv_bias, dot_general=_gathered(self, "wk"),
+        k = nn.DenseGeneral((kv_heads, hd), use_bias=qkv_bias, dot_general=_gathered(self, "wk"),
                             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wk")(x)
-        v = nn.DenseGeneral((cfg.kv_heads, hd), use_bias=qkv_bias, dot_general=_gathered(self, "wv"),
+        v = nn.DenseGeneral((kv_heads, self.v_head_dim or hd), use_bias=qkv_bias, dot_general=_gathered(self, "wv"),
                             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wv")(x)
+        v = _times(cfg.value_multiplier, v)
         if cfg.attn_output_gate:
             q, gate = q[..., :hd], q[..., hd:]
         if cfg.qk_norm:
             q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
 
         if cfg.position == "rope" and self.rotates is not False:
-            q, k = apply_qk_rope(cfg, q, k, positions)
+            q, k = apply_qk_rope(cfg, q, k, positions, self.rope_theta)
         slopes = alibi_slopes(cfg.num_heads) if cfg.position == "alibi" else None
         banded = {} if self.window is None else {"window": self.window}
+        if self.sink:
+            # drawn at unit variance: a sink left at a constant would be left unchecked
+            banded["sink"] = self.param("sink", nn.initializers.normal(1.0), (cfg.num_heads,), cfg.param_dtype)
 
         from deepspeed_tpu.ops import causal_attention
         from deepspeed_tpu.parallel.ulysses import sp_active, ulysses_shard, ulysses_unshard
@@ -996,11 +1042,19 @@ class Attention(nn.Module):
 
 
 def sliding_kind(cfg: TransformerConfig, kind: str) -> dict:
-    """``window`` and ``rotates`` of an attention layer of ``kind`` in a pattern with a sliding kind: one
-    statement for the flax module and the paged path."""
-    if kind == "sliding_attention":
-        return {"window": cfg.sliding.window, "rotates": True}
-    return {"window": None, "rotates": cfg.sliding.global_rope}
+    """What an attention layer of ``kind`` is in a pattern with a sliding kind, one statement for the flax
+    module, the cache plan and the paged path: ``window`` and ``rotates``, and its shapes: ``kv_heads``,
+    ``head_dim`` (queries and keys), ``v_head_dim``, ``rope_theta``, ``sink``. The ``attention`` kind's are the
+    model's one-number fields, the sliding kind's ``cfg.sliding``'s own where it states them."""
+    shape = {"kv_heads": cfg.kv_heads, "head_dim": cfg.dims_per_head,
+             "v_head_dim": cfg.v_head_dim or cfg.dims_per_head, "rope_theta": cfg.rope_theta, "sink": False}
+    if kind != "sliding_attention":
+        return {"window": None, "rotates": cfg.sliding.global_rope, **shape}
+    own = cfg.sliding
+    return {"window": own.window, "rotates": True, "kv_heads": own.num_kv_heads or shape["kv_heads"],
+            "head_dim": own.head_dim or shape["head_dim"],
+            "v_head_dim": own.v_head_dim or own.head_dim or shape["v_head_dim"],
+            "rope_theta": shape["rope_theta"] if own.rope_theta is None else own.rope_theta, "sink": own.sink}
 
 
 class LatentRotary(NamedTuple):
@@ -1604,16 +1658,16 @@ class CausalLM(nn.Module):
                    else jnp.asarray(cap, jnp.float32).reshape(()))
             carry = carry + (cap,)
         if cfg.scan_layers:
-            for i in range(cfg.first_dense_layers):  # outside the scan, each its own tree
-                carry, _ = block_cls(cfg, train, dense=True, name=f"dense_{i}")(carry, None)
+            for i in range(cfg.first_dense_layers):  # outside the scan, each its own tree (and of its own kind)
+                kind = {"kind": cfg.layer_types[i]} if cfg.layer_types else {}
+                carry, _ = block_cls(cfg, train, dense=True, name=f"dense_{i}", **kind)(carry, None)
             # a layer pattern is scanned a whole period a step (``Period`` applies ``remat`` a block)
             pattern = cfg.layer_types is not None
             stack = nn.scan(
                 Period if pattern else block_cls,
                 variable_axes={"params": 0},
                 split_rngs={"params": True, "dropout": True},
-                length=(cfg.num_layers // len(cfg.period) if pattern
-                        else cfg.num_layers - cfg.first_dense_layers),
+                length=(cfg.num_layers - cfg.first_dense_layers) // (len(cfg.period) if pattern else 1),
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(cfg, train, name="layers")
             # flax names the BODY ``layers``; what the scan itself does (the

@@ -31,6 +31,7 @@ def _xla_causal_attention(
     softmax_scale: Optional[float] = None,  # None: D^-0.5
     window: Optional[int] = None,  # a band: query t sees keys j with 0 <= t - j < window
     lengths: Optional[jax.Array] = None,  # [B] int32: each row's live tokens, which come first; a pad's row is zeros
+    sink: Optional[jax.Array] = None,  # [H]: a logit a query head in the softmax's denominator alone
 ) -> jax.Array:
     B, S, H, D = q.shape
     Hkv = k.shape[2]
@@ -65,8 +66,12 @@ def _xla_causal_attention(
         keep = m if keep is None else keep & m
     if keep is not None:
         scores = jnp.where(keep, scores, _NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v).reshape(B, S, H, D)
+    if sink is None:
+        probs = jax.nn.softmax(scores, axis=-1)
+    else:  # p_tj = exp(a_tj) / (exp(s_h) + sum_k exp(a_tk)): the sink is a column that weighs no value
+        column = jnp.broadcast_to(sink.astype(jnp.float32).reshape(1, Hkv, G, 1, 1), scores.shape[:-1] + (1,))
+        probs = jax.nn.softmax(jnp.concatenate([scores, column], axis=-1), axis=-1)[..., :-1]
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(v.dtype), v).reshape(B, S, H, v.shape[-1])
     if lengths is not None:  # what the flash forward hands back for a pad (``_flash_fwd``)
         out = jnp.where((jnp.arange(S) < lengths[:, None])[:, :, None, None], out, jnp.zeros((), out.dtype))
     return out
@@ -99,7 +104,8 @@ def resolves_to_flash(impl: str = "auto") -> bool:
 
 
 def causal_attention(q, k, v, mask=None, impl: str = "auto",
-                     alibi_slopes=None, bias=None, softmax_scale=None, window=None, lengths=None, **kernel_kwargs):
+                     alibi_slopes=None, bias=None, softmax_scale=None, window=None, lengths=None, sink=None,
+                     **kernel_kwargs):
     """Grouped-query causal attention with optional ALiBi slopes and additive
     pair bias. ALiBi is fused into the Pallas flash kernels (slope * column
     iota — no bias tiles) so bloom-style training keeps the flash path; the
@@ -125,20 +131,25 @@ def causal_attention(q, k, v, mask=None, impl: str = "auto",
     (``flash_attention.py::_flash_fwd``), under the band as under the causal
     mask alone. Beside a padding mask, ALiBi or a pair bias the dense path runs.
 
+    ``sink`` ([H], a learned logit a query head that joins the softmax's
+    denominator and nothing else) and a value narrower than its key (``v``'s
+    last dimension apart from ``k``'s) are words of the forward alone too: on
+    the Pallas path the kernels of the band and the lengths take them.
+
     kernel_kwargs (block_q / block_k / k_splits) are Pallas scheduling knobs
     with identical math — they are forwarded only when dispatch resolves to
     the pallas kernel and dropped on the XLA path (which has no blocking)."""
     scaled = {} if softmax_scale is None else {"softmax_scale": softmax_scale}
     if bias is not None:
         return _xla_causal_attention(q, k, v, mask=mask, alibi_slopes=alibi_slopes, bias=bias, window=window,
-                                     lengths=lengths, **scaled)
+                                     lengths=lengths, sink=sink, **scaled)
     fn = dispatch("causal_attention", impl)
-    if window is not None or lengths is not None:
+    if window is not None or lengths is not None or sink is not None or v.shape[-1] != k.shape[-1]:
         if fn is available_impls("causal_attention").get("pallas") and mask is None and alibi_slopes is None:
             kw = {key: val for key, val in kernel_kwargs.items() if key in ("block_q", "block_k")}
-            return _per_shard_flash(fn, q, k, v, None, None, dict(kw, window=window, **scaled), lengths)
+            return _per_shard_flash(fn, q, k, v, None, None, dict(kw, window=window, **scaled), lengths, sink)
         return _xla_causal_attention(q, k, v, mask=mask, alibi_slopes=alibi_slopes, window=window,
-                                     lengths=lengths, **scaled)
+                                     lengths=lengths, sink=sink, **scaled)
     if fn is available_impls("causal_attention").get("pallas"):
         return _per_shard_flash(fn, q, k, v, mask, alibi_slopes, dict(kernel_kwargs, **scaled))
     if alibi_slopes is not None:
@@ -158,32 +169,35 @@ def evoformer_attention(q, k, v, pair_bias=None, mask=None):
     return _xla_causal_attention(q, k, v, mask=mask, bias=pair_bias, causal=False)
 
 
-def _per_shard_flash(fn, q, k, v, mask, alibi_slopes, kernel_kwargs, lengths=None):
+def _per_shard_flash(fn, q, k, v, mask, alibi_slopes, kernel_kwargs, lengths=None, sink=None):
     """The flash kernel under GSPMD (``ops/partition.py``): attention is
     independent per (batch row, kv-head group), so batch splits over the
     data axes (the rows' live ``lengths`` with it) and heads over sp (the
-    Ulysses head shard) and tp."""
+    Ulysses head shard) and tp (a head's ``sink`` with them)."""
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.ops.partition import kernel_mesh, live_axes, per_shard
     from deepspeed_tpu.topology.mesh import BATCH_AXES
 
-    def call(q, k, v, mask, slopes, lengths):
+    def call(q, k, v, mask, slopes, lengths, sink):
         kw = dict(kernel_kwargs)
         if slopes is not None:
             kw["alibi_slopes"] = slopes
         if lengths is not None:
             kw["lengths"] = lengths
+        if sink is not None:
+            kw["sink"] = sink
         return fn(q, k, v, mask=mask, **kw)
 
     ctx = kernel_mesh()
     if ctx is None:
-        return call(q, k, v, mask, alibi_slopes, lengths)
+        return call(q, k, v, mask, alibi_slopes, lengths, sink)
     mesh, free = ctx
     b = live_axes(mesh, free, BATCH_AXES, q.shape[0])
     # kv heads must split like q heads: GQA groups stay whole on a device
     h = live_axes(mesh, free, ("sp", "tp"), q.shape[2], k.shape[2])
     qkv = P(b, None, h, None)
     in_specs = (qkv, qkv, qkv, None if mask is None else P(b, None),
-                None if alibi_slopes is None else P(h), None if lengths is None else P(b))
-    return per_shard(call, mesh, free, in_specs, qkv)(q, k, v, mask, alibi_slopes, lengths)
+                None if alibi_slopes is None else P(h), None if lengths is None else P(b),
+                None if sink is None else P(h))
+    return per_shard(call, mesh, free, in_specs, qkv)(q, k, v, mask, alibi_slopes, lengths, sink)

@@ -363,14 +363,16 @@ def _sub_score(q, k, mask_ref, slopes_ref, qi, ki, off, c, *, block_q, block_k,
 
 
 def _fwd_kernel(*refs, block_q, block_k, causal, masked, squashed, alibi=False,
-                k_splits=1, window=None, lengths=False):
+                k_splits=1, window=None, lengths=False, sink=False):
     alive = None  # with ``lengths``: whether the step's query block holds a live token of its row
+    sink_ref = None  # with ``sink``: the head's logit in the softmax's denominator, [1, 1, _LANES] (log2e-scaled)
     if squashed:
         (qm_ref, km_ref, *rest) = refs
         if lengths:  # (``_live_grid``: what a step fetches is the index maps' alone)
             _, _, rows_ref, *rest = rest
         mask_ref = rest.pop(0)
         slopes_ref = rest.pop(0) if alibi else None
+        sink_ref = rest.pop(0) if sink else None
         (q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref) = rest
         t = pl.program_id(2)
         qi, ki = qm_ref[t], km_ref[t]
@@ -460,9 +462,20 @@ def _fwd_kernel(*refs, block_q, block_k, causal, masked, squashed, alibi=False,
         if alive is not None:  # the pads beside a row's last token: zeros, as the dead blocks after them
             l = jnp.where(jax.lax.broadcasted_iota(jnp.int32, l.shape, 0) < fed, l, 0.0)
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = acc_ref[:] / l_safe
+        row_max = lambda: jnp.max(m_ref[:], axis=-1, keepdims=True)  # noqa: E731
+        acc, l_out, m = acc_ref[:], l_safe, None
+        if sink_ref is not None:
+            # the sink joins the sum and weighs no value: the output over l + 2^(sink - m), both terms taken
+            # against the larger of m and the sink so that neither power overflows; a row that saw no key (l 0)
+            # stays zeros. The lse handed out is the keys' alone, as without a sink.
+            m, s2 = row_max(), sink_ref[0, :, :1]  # [block_q, 1], [1, 1]
+            top = jnp.maximum(jnp.where(m == _NEG_INF, s2, m), s2)
+            shrink = jnp.where(m == _NEG_INF, 0.0, jnp.exp2(m - top))
+            acc, l_out = acc * shrink, l * shrink + jnp.exp2(s2 - top)
+        out = acc / l_out
         o_ref[0, 0] = (out if alive is None else jnp.where(l == 0.0, 0.0, out)).astype(o_ref.dtype)
-        m = jnp.max(m_ref[:], axis=-1, keepdims=True)
+        if m is None:  # (with no sink the kernel reads its refs in the order it always did: the program it was)
+            m = row_max()
         # base-2 logsumexp per row; fully-masked rows get -inf. The column
         # becomes a lane-dense row here, once a query block.
         lse = jnp.where(l == 0.0, _NEG_INF, m + jnp.log2(l_safe))
@@ -473,11 +486,17 @@ _PARALLEL_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
 
 
 def _flash_fwd(q, k, v, mask, slopes, block_q: int, block_k: int, causal: bool,
-               masked: bool, alibi: bool, k_splits: int = 1, window: Optional[int] = None, lengths=None):
-    """q,k,v: [B, H(q/kv), S, D] (q pre-scaled). mask: [B, S] int32.
+               masked: bool, alibi: bool, k_splits: int = 1, window: Optional[int] = None, lengths=None,
+               sinks=None):
+    """q,k,v: [B, H(q/kv), S, D] (q pre-scaled); ``v`` may be narrower than
+    ``k`` (``[.., Dv]``: the output is then ``Dv`` wide, the key's ``D`` lies in
+    its blocks as it comes, whole 128-lane tiles or not). mask: [B, S] int32.
     slopes: [H, 1, _LANES] fp32 (log2e-scaled; ignored unless alibi).
     Returns (out, lse): lse fp32 ``[B, H, 1, S]``, base 2. ``window`` (a band
     under the causal mask, ``_band_maps``) names the kernel ``swa_flash_fwd``.
+    ``sinks`` ([H, 1, _LANES] fp32, log2e-scaled, the squashed grids alone): a
+    logit a query head that joins the softmax's denominator in ``_finalize``
+    and nothing else; None builds the kernel as it was.
 
     ``lengths`` (int32 ``[B]``): a row's live tokens, which come FIRST in the
     row: a fresh prompt padded to its bucket. That says more than ``mask``
@@ -499,20 +518,21 @@ def _flash_fwd(q, k, v, mask, slopes, block_q: int, block_k: int, causal: bool,
     cells as ever and zero their rows after."""
     B, H, S, D = q.shape
     Hkv = k.shape[1]
+    Dv = v.shape[-1]
     G = H // Hkv
     nq, nk = _cdiv(S, block_q), _cdiv(S, block_k)
     squashed = _squash_ok(nq, nk, block_q, block_k, causal)
-    if window is not None and not (squashed and not masked and not alibi):
+    if (window is not None or sinks is not None) and not (squashed and not masked and not alibi):
         raise NotImplementedError(
-            f"flash attention under a band (window={window}) runs the squashed causal grid alone: equal "
-            f"blocks (got {block_q}, {block_k}), no padding mask, no ALiBi; the dense path takes the rest")
+            f"flash attention under a band (window={window}) or with a sink runs the squashed causal grid alone: "
+            f"equal blocks (got {block_q}, {block_k}), no padding mask, no ALiBi; the dense path takes the rest")
 
     out_shape = [
-        _sds((B, H, S, D), q.dtype, q, k, v, mask),
+        _sds((B, H, S, Dv), q.dtype, q, k, v, mask),
         _sds((B, H, 1, S), jnp.float32, q, k, v, mask),
     ]
     scratch_shapes = [
-        pltpu.VMEM((block_q, D), jnp.float32),
+        pltpu.VMEM((block_q, Dv), jnp.float32),
         pltpu.VMEM((block_q, _LANES), jnp.float32),
         pltpu.VMEM((block_q, _LANES), jnp.float32),
     ]
@@ -522,12 +542,19 @@ def _flash_fwd(q, k, v, mask, slopes, block_q: int, block_k: int, causal: bool,
     skips = squashed and lengths is not None and B * cells <= _MAX_SQUASHED_CELLS
     kernel = functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k,
                                causal=causal, masked=masked, squashed=squashed,
-                               alibi=alibi, k_splits=k_splits, window=window, lengths=skips)
+                               alibi=alibi, k_splits=k_splits, window=window, lengths=skips,
+                               sink=sinks is not None)
     dec = _DEC_SQUASHED if squashed else _DEC_DENSE
-    in_specs = _qkv_in_specs(_DEC_LIVE_FETCH if skips else dec, block_q, block_k, D, G, alibi=alibi)
-    qrow = _qrow_specs(dec, block_q, D)
+    fetch = _DEC_LIVE_FETCH if skips else dec
+    in_specs = _qkv_in_specs(fetch, block_q, block_k, D, G, alibi=alibi)
+    if Dv != D:  # the value's blocks at its own width
+        in_specs[-1] = _spec((1, 1, block_k, Dv), lambda b, h, qi, ki: (b, h // G, ki, 0), fetch)
+    qrow = _qrow_specs(dec, block_q, Dv)
     out_specs = [qrow["qD"], qrow["qL"]]
     extra = (slopes,) if alibi else ()
+    if sinks is not None:  # a head's, as the slopes ride: after them, before q
+        in_specs.insert(len(in_specs) - 3, _spec((1, 1, _LANES), lambda b, h, qi, ki: (h, 0, 0), fetch))
+        extra += (sinks,)
 
     if squashed:
         qm, km = _tri_maps(nq) if window is None else _band_maps(nq, block_q, window)
@@ -910,32 +937,42 @@ _flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_banded_forward(q, k, v, window: Optional[int], block: int, softmax_scale: Optional[float] = None,
-                         lengths=None):
+                         lengths=None, sink=None):
     """Causal attention under a band of ``window`` keys (``ops/attention.py::
-    band_keep``), the FORWARD alone: q ``[B, S, H, D]``, k, v ``[B, S, Hkv, D]``,
-    ``S`` whole blocks of ``block``. The kernel is ``_fwd_kernel`` on a grid of
+    band_keep``), the FORWARD alone: q ``[B, S, H, D]``, k ``[B, S, Hkv, D]``, v
+    ``[B, S, Hkv, Dv]`` (as wide as the key or not), ``S`` whole blocks of
+    ``block``. The kernel is ``_fwd_kernel`` on a grid of
     the band's cells (``_band_maps``), named ``swa_flash_fwd``. With ``lengths``
     (int32 ``[B]``: each row's live tokens, which come first in it; ``_flash_fwd``)
     no cell past a row's last token runs and the pads' rows are zeros; with
-    ``lengths`` and no ``window`` the grid is the causal triangle's, ``flash_fwd``."""
+    no ``window`` the grid is the causal triangle's, ``flash_fwd``. ``sink``
+    ([H]): a logit a query head in the softmax's denominator alone."""
     scale = _scale(q.shape[-1], softmax_scale) * _LOG2E
     B, S, H, _ = q.shape
+    sinks = None
+    if sink is not None:  # in the base-2 scale of the pre-scaled scores, as the slopes ride
+        sinks = jnp.broadcast_to((sink.astype(jnp.float32) * _LOG2E)[:, None, None], (H, 1, _LANES))
     out, _ = _flash_fwd((q * jnp.asarray(scale, q.dtype)).transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                         v.transpose(0, 2, 1, 3), jnp.ones((B, 1, S), jnp.int32),
-                        jnp.zeros((H, 1, _LANES), jnp.float32), block, block, True, False, False, 1, window, lengths)
+                        jnp.zeros((H, 1, _LANES), jnp.float32), block, block, True, False, False, 1, window, lengths,
+                        sinks)
     return out.transpose(0, 2, 1, 3)
 
 
-def _banded_fwd(q, k, v, window, block, softmax_scale, lengths=None):
-    return flash_banded_forward(q, k, v, window, block, softmax_scale, lengths), lengths is not None
+def _banded_fwd(q, k, v, window, block, softmax_scale, lengths=None, sink=None):
+    return (flash_banded_forward(q, k, v, window, block, softmax_scale, lengths, sink),
+            (lengths is not None, sink is not None, v.shape[-1] != k.shape[-1]))
 
 
-def _banded_bwd(window, block, softmax_scale, with_lengths, g):
+def _banded_bwd(window, block, softmax_scale, given, g):
+    with_lengths, with_sink, narrower = given
     raise NotImplementedError(
         "flash attention " + " and ".join([f"under a band (window={window})"] * (window is not None)
-                                          + ["over rows' live lengths (lengths=)"] * with_lengths)
-        + " has a forward alone: no backward kernel skips the cells under the band or past a row's last token yet; "
-        "train a sliding layer with attn_impl='xla', and a padded batch with a mask")
+                                          + ["over rows' live lengths (lengths=)"] * with_lengths
+                                          + ["with a sink in the softmax's sum"] * with_sink
+                                          + ["with a value narrower than its key"] * narrower)
+        + " has a forward alone: no backward kernel skips the cells under the band or past a row's last token, adds "
+        "a sink or takes two widths yet; train a sliding layer with attn_impl='xla', and a padded batch with a mask")
 
 
 flash_banded_forward.defvjp(_banded_fwd, _banded_bwd)
@@ -954,19 +991,21 @@ def flash_causal_attention(
     softmax_scale: Optional[float] = None,  # None: D^-0.5
     window: Optional[int] = None,  # a band under the causal mask: the forward alone (``flash_banded_forward``)
     lengths: Optional[jax.Array] = None,  # [B] int32: the rows' live tokens, which come first: the forward alone
+    sink: Optional[jax.Array] = None,  # [H]: a logit a query head in the softmax's denominator: the forward alone
 ) -> jax.Array:
     B, S, H, D = q.shape
     block_q = min(block_q, max(S, 8))
     block_k = min(block_k, max(S, 8))
-    if window is not None or lengths is not None:
+    if window is not None or lengths is not None or sink is not None or v.shape[-1] != D:
         if mask is not None or alibi_slopes is not None:
             raise NotImplementedError(
-                "flash attention under a band or over rows' live lengths takes no padding mask and no ALiBi slopes")
+                "flash attention under a band, over rows' live lengths, with a sink or with a value narrower than "
+                "its key takes no padding mask and no ALiBi slopes")
         block = min(block_q, block_k)
         pad = _cdiv(S, block) * block - S  # padded keys reach padded queries alone (module header)
         if pad:
             q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v))
-        return flash_banded_forward(q, k, v, window, block, softmax_scale, lengths)[:, :S]
+        return flash_banded_forward(q, k, v, window, block, softmax_scale, lengths, sink)[:, :S]
     # k_splits > 1 processes each block_k tile as k_splits sub-chunks with the
     # next sub-chunk's QK^T hoisted ahead of the previous one's softmax, so the
     # MXU matmul can overlap the VPU exp2/renormalize passes. Pure

@@ -89,12 +89,14 @@ def _cdiv(a: int, b: int) -> int:
     return (a + b - 1) // b
 
 
-def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, quantized, banded=False):
+def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, quantized, banded=False,
+                   hdv, sink=False):
     refs = list(refs)
     low_ref = refs.pop(0) if banded else None  # (a third scalar operand: a row's first slot live to ANY of its queries)
     q_ref, qpos_ref = refs.pop(0), refs.pop(0)
     qlow_ref = refs.pop(0) if banded else None  # a query row's first live slot (a ring of pages)
     slopes_ref = refs.pop(0) if alibi else None
+    sink_ref = refs.pop(0) if sink else None  # a query row's head's logit in the softmax's denominator
     k_hbm, v_hbm = refs.pop(0), refs.pop(0)
     ks_hbm = vs_hbm = ksbuf = vsbuf = None
     if quantized:
@@ -105,7 +107,7 @@ def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, 
     acc_ref, ml_ref, sems, slot_ref = refs
     n = pl.program_id(0)
     N = pl.num_programs(0)
-    D = kvH * hd
+    D, Dv = kvH * hd, kvH * hdv
     T = ppcb * bs
     cdt = q_ref.dtype
 
@@ -171,6 +173,11 @@ def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, 
             q_r = q_ref[0, r:r + 1, :].astype(jnp.float32)
             qbd = jnp.where(on, jnp.broadcast_to(q_r, (W, D)), qbd)
         qbd = qbd.astype(cdt)
+        if hdv != hd:  # the output's diagonal blocks are ``hdv`` wide, of ``Dv`` lanes
+            row = jax.lax.broadcasted_iota(jnp.int32, (W, Dv), 0)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (W, Dv), 1)
+            diag = [(row - r * kvH >= 0) & (row - r * kvH < kvH) & (lane >= (row - r * kvH) * hdv)
+                    & (lane < (row - r * kvH + 1) * hdv) for r in range(Cg)]
 
     def update(g, q, k, v, visible, j, scales, slopes):
         s = jax.lax.dot_general(
@@ -209,7 +216,7 @@ def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, 
         def _():
             @pl.loop((ctx - c * T) // bs, ppcb)
             def _(i):
-                pos = c * T + i * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, D), 0)
+                pos = c * T + i * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, Dv), 0)
                 vbuf[slot, i] = jnp.where(pos < ctx, vbuf[slot, i], jnp.zeros((), vbuf.dtype))
 
         if banded:
@@ -219,7 +226,7 @@ def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, 
             def _():
                 @pl.loop(0, jnp.minimum(_cdiv(low_ref[n], bs), ppcb))
                 def _(i):
-                    pos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, D), 0)
+                    pos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, Dv), 0)
                     vbuf[slot, i] = jnp.where(pos >= low_ref[n], vbuf[slot, i], jnp.zeros((), vbuf.dtype))
 
         k_all, v_all = kbuf[slot], vbuf[slot]  # [ppcb, bs, kvH*hd]
@@ -228,7 +235,7 @@ def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, 
             # a 16-slot page is not, fp32's 8-row tile divides any page
             k_all, v_all = k_all.astype(jnp.float32), v_all.astype(jnp.float32)
         k_all = k_all.reshape(T, D).astype(cdt)
-        v_all = v_all.reshape(T, D).astype(cdt)
+        v_all = v_all.reshape(T, Dv).astype(cdt)
 
         # causality over SEQUENCE positions: token j of this page-chunk is at
         # global position c*T + j; visible iff <= the query row's position
@@ -241,12 +248,12 @@ def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, 
             if dense:
                 # row r*kvH + kh takes head kh's scales
                 pad = [jnp.zeros((W - Cg * kvH, T), jnp.float32)] * (W > Cg * kvH)
-                q, lanes = qbd, slice(None)
+                q, lanes, vlanes = qbd, slice(None), slice(None)
                 tile = lambda b: jnp.concatenate([b[slot]] * Cg + pad)  # noqa: E731
             else:
-                q, lanes = q_ref[0, g], slice(g * hd, (g + 1) * hd)  # [Cgp, hd] (pre-scaled)
+                q, lanes, vlanes = q_ref[0, g], slice(g * hd, (g + 1) * hd), slice(g * hdv, (g + 1) * hdv)
                 tile = lambda b: b[slot, pl.ds(g, 1), :]  # noqa: E731
-            update(g, q, k_all[:, lanes], v_all[:, lanes], visible, j,
+            update(g, q, k_all[:, lanes], v_all[:, vlanes], visible, j,
                    [tile(b) for b in (ksbuf, vsbuf)] if quantized else None,
                    slopes_ref[g] if alibi else None)
 
@@ -274,8 +281,15 @@ def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, 
     slot_ref[0] = (slot0 + nc) % 2
 
     def normalised(g):
-        l = stats(g)[1]
-        return acc_ref[g] / jnp.where(l == 0.0, 1.0, l)
+        m, l = stats(g)
+        if sink_ref is None:
+            return acc_ref[g] / jnp.where(l == 0.0, 1.0, l)
+        # the sink joins the sum and weighs no value: acc / (l + e^(sink - m)), both terms taken against the
+        # larger of m and the sink so that neither power overflows; a row that saw no key stays zeros
+        s = sink_ref[g]  # [rows, 1]
+        top = jnp.maximum(jnp.where(m == _NEG_INF, s, m), s)
+        shrink = jnp.where(m == _NEG_INF, 0.0, jnp.exp(m - top))
+        return acc_ref[g] * shrink / (l * shrink + jnp.exp(s - top))
 
     if dense:
         # row w's output is its own head's lanes of out[w]: keep the diagonal
@@ -311,16 +325,27 @@ def flash_decode_paged(
     k_scale: jax.Array = None,  # [pages, bs*kvH] fp32 — quantized pool scales
     v_scale: jax.Array = None,
     first_live: jax.Array = None,  # [N, C] int32: slots before it are dead (a ring of pages; kernel ``swa_paged_attn``)
+    sink: jax.Array = None,  # [H]: a logit a query head in the softmax's denominator alone
 ) -> jax.Array:
     """``first_live`` is for a row whose table is a RING of pages rolled so that
     its oldest live page comes first (``inference/paged.py``, a sliding layer):
     slot index is then position less the first page's first position,
     ``q_positions`` are given in those units, and of the first page the slots
     before ``first_live`` hold positions the window has left behind: masked,
-    as the slots past the query are. None is the kernel as it always was."""
+    as the slots past the query are. None is the kernel as it always was.
+
+    A value may be narrower than its key: ``pool_v`` ``[pages, bs, kvH*hdv]``
+    beside ``pool_k`` ``[pages, bs, kvH*hd]``, each page a lane-dense slab of
+    its own width (a key of 192 lies in a page as it is, ``kvH * 192`` lanes,
+    whole 128-lane tiles for an even ``kvH``: nothing is padded), the output
+    ``[N, C, H, hdv]``. A head's lanes then start off a tile's edge, so every
+    (query row, kv head) pair is a row of the block-diagonal query whenever the
+    pairs fit one 128-row pass, and no product slices a head out of a page.
+    ``sink`` adds ``exp(sink_h)`` to the running denominator at the walk's end."""
     N, C, H, hd = q.shape
-    D = pool_k.shape[2]
+    D, Dv = pool_k.shape[2], pool_v.shape[2]
     kvH = D // hd
+    hdv = Dv // kvH
     G = H // kvH
     P = block_tables.shape[1]
     bs = block_size
@@ -331,8 +356,8 @@ def flash_decode_paged(
     # The compute's form, from the shapes alone (module docstring): one
     # block-diagonal query for all heads where a head has under a sublane tile
     # of query rows and the (row, head) pairs fit one 128-row pass.
-    dense = Cg < _SUBLANES and Cg * kvH <= 128
-    page_bytes = bs * D * pool_k.dtype.itemsize
+    dense = (Cg < _SUBLANES or hdv != hd) and Cg * kvH <= 128
+    page_bytes = bs * max(D, Dv) * pool_k.dtype.itemsize
     if (not dense and C > 1
             and _query_side_bytes(kvH, _cdiv(Cg, _SUBLANES) * _SUBLANES, hd, q.dtype.itemsize)
             + 4 * page_bytes > _VMEM_BUDGET):
@@ -345,7 +370,7 @@ def flash_decode_paged(
         return jnp.concatenate([
             flash_decode_paged(q[:, at], pool_k, pool_v, block_tables, q_positions[:, at], bs, n,
                                pages_per_block, alibi_slopes, k_scale, v_scale,
-                               None if first_live is None else first_live[:, at])
+                               None if first_live is None else first_live[:, at], sink)
             for at, n in zip((slice(0, h), slice(h, C)), lens)], axis=1)
     scale = jnp.asarray(hd ** -0.5, q.dtype)
     qg = (q * scale).reshape(N, C, kvH, G, hd)
@@ -355,21 +380,25 @@ def flash_decode_paged(
         # row-aligned slopes: row (c, g) of kv head kh uses slope[kh*G + g]
         srows = jnp.broadcast_to(
             alibi_slopes.astype(jnp.float32).reshape(kvH, 1, G), (kvH, C, G)).reshape(kvH, Cg)
+    if sink is not None:  # a head's sink, row-aligned as the slopes are
+        sink_rows = jnp.broadcast_to(sink.astype(jnp.float32).reshape(kvH, 1, G), (kvH, C, G)).reshape(kvH, Cg)
     if dense:
         # [N, Cg, kvH*hd]: rows are (c, g) pairs, lanes (kv head, dim): for
         # G == 1 the query as it comes
         rows = _cdiv(Cg * kvH, _SUBLANES) * _SUBLANES  # (r, kh) pairs, padded to sublanes
         q_op = qg.transpose(0, 1, 3, 2, 4).reshape(N, Cg, D)
-        q_block, heads = (1, Cg, D), 1
+        q_block, o_block, heads = (1, Cg, D), (1, Cg, Dv), 1
         qpos_rows = jnp.repeat(qpos_rows, kvH, axis=1)  # row r*kvH + kh
         if alibi:
             srows = srows.T.reshape(1, Cg * kvH)
+        if sink is not None:
+            sink_rows = sink_rows.T.reshape(1, Cg * kvH)
     else:
         # [N, kvH, Cg, hd]: rows are (c, g) pairs, padded to sublanes
         rows = _cdiv(Cg, _SUBLANES) * _SUBLANES
         q_op = qg.transpose(0, 2, 1, 3, 4).reshape(N, kvH, Cg, hd)
         q_op = jnp.pad(q_op, ((0, 0), (0, 0), (0, rows - Cg), (0, 0)))
-        q_block, heads = (1, kvH, rows, hd), kvH
+        q_block, o_block, heads = (1, kvH, rows, hd), (1, kvH, rows, hdv), kvH
     pad = rows - qpos_rows.shape[1]
     # padded rows see nothing (position -1 masks every token)
     qpos_rows = jnp.pad(qpos_rows, ((0, 0), (0, pad)), constant_values=-1)
@@ -391,7 +420,7 @@ def flash_decode_paged(
     # side (its block and the output's, double-buffered by the pipeline; the
     # fp32 accumulator; the statistics, which pad to 128 lanes) leaves less of
     # the budget for the two slots of K and of V.
-    query_side = _query_side_bytes(heads, rows, D // heads, q.dtype.itemsize)
+    query_side = _query_side_bytes(heads, rows, max(D, Dv) // heads, q.dtype.itemsize)
     ppcb = max(1, min(pages_per_block, P))
     while ppcb > 1 and query_side + 4 * ppcb * page_bytes > _VMEM_BUDGET:
         ppcb //= 2
@@ -412,11 +441,15 @@ def flash_decode_paged(
         srows = jnp.pad(srows, ((0, 0), (0, rows - srows.shape[1])))
         operands.append(srows[:, :, None])
         in_specs.append(pl.BlockSpec((heads, rows, 1), lambda n, *_: (0, 0, 0)))
+    if sink is not None:
+        sink_rows = jnp.pad(sink_rows, ((0, 0), (0, rows - sink_rows.shape[1])))
+        operands.append(sink_rows[:, :, None])
+        in_specs.append(pl.BlockSpec((heads, rows, 1), lambda n, *_: (0, 0, 0)))
     operands += [pool_k, pool_v]
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
     scratch = [
         pltpu.VMEM((2, ppcb, bs, D), pool_k.dtype),  # two slots: one computes, one fills
-        pltpu.VMEM((2, ppcb, bs, D), pool_v.dtype),
+        pltpu.VMEM((2, ppcb, bs, Dv), pool_v.dtype),
     ]
     if quantized:
         # scales are 1/hd of the pool: gather the rows' pages in XLA into
@@ -431,7 +464,8 @@ def flash_decode_paged(
             scratch.append(pltpu.VMEM((2, kvH, T), jnp.float32))
 
     kernel = functools.partial(_decode_kernel, ppcb=ppcb, bs=bs, kvH=kvH, hd=hd, Cg=Cg,
-                               dense=dense, alibi=alibi, quantized=quantized, banded=banded)
+                               dense=dense, alibi=alibi, quantized=quantized, banded=banded, hdv=hdv,
+                               sink=sink is not None)
     out = pl.pallas_call(
         kernel,
         name="swa_paged_attn" if banded else "paged_attn",
@@ -439,25 +473,25 @@ def flash_decode_paged(
             num_scalar_prefetch=3 if banded else 2,  # block_tables, ctx_lens(, a row's first live slot)
             grid=(N,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(q_block, lambda n, *_: (n,) + zeros),
+            out_specs=pl.BlockSpec(o_block, lambda n, *_: (n,) + zeros),
             scratch_shapes=scratch + [
-                pltpu.VMEM((heads, rows, D // heads), jnp.float32),
+                pltpu.VMEM((heads, rows, Dv // heads), jnp.float32),
                 pltpu.VMEM((heads, rows, _SUBLANES), jnp.float32),
                 pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)),
                 pltpu.SMEM((1,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct(q_op.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_op.shape[:-1] + o_block[-1:], q.dtype),
         # rows in order: each starts the next one's first fetch
         compiler_params=tpu_compiler_params(dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(block_tables, ctx_lens, *((jnp.min(first_live, axis=1).astype(jnp.int32),) if banded else ()), *operands)
 
     if dense:
-        out = out.reshape(N, C, G, kvH, hd).transpose(0, 1, 3, 2, 4)
+        out = out.reshape(N, C, G, kvH, hdv).transpose(0, 1, 3, 2, 4)
     else:
-        out = out[:, :, :Cg].reshape(N, kvH, C, G, hd).transpose(0, 2, 1, 3, 4)
-    return out.reshape(N, C, H, hd)
+        out = out[:, :, :Cg].reshape(N, kvH, C, G, hdv).transpose(0, 2, 1, 3, 4)
+    return out.reshape(N, C, H, hdv)
 
 
 # --------------------------------------------------------------- latent pages

@@ -1337,6 +1337,135 @@ def test_command_a_plus_programs_compile_at_the_cell_s_shapes(one_chip, monkeypa
     assert not moved, moved
 
 
+@pytest.mark.parametrize("kind", ["sliding", "global"])
+def test_the_two_width_kernels_compile_at_mimo_s_shapes(one_chip, monkeypatch, kind):
+    """``mimo-v2.5.serve.long-output-wave128``'s attention kernels alone: keys of 192 columns beside values of 128,
+    64 query heads over 8 kv heads under a band of 128 with a sink a head (sliding), over 4 kv heads (global). The
+    flash forward over (8, 2048) prompts told their live lengths (a key block of 192 lanes, a value block of 128,
+    the sinks ``[64, 1, 8]`` a head a block) and the paged kernel at 128 rows, one token a row: a ring of 9 columns
+    rolled under a first live slot, the global table's 194; every (query row, kv head) pair a row of ONE
+    block-diagonal query (64 rows), so no product slices a head's 192 lanes out of a page."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa, paged_attention as pa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    sliding = kind == "sliding"
+    Hkv, columns = (8, 9) if sliding else (4, 194)
+    sds = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+    sink = [sds((64,), jnp.float32)] * sliding
+
+    def prefill(q, k, v, lengths, *sink):
+        return fa.flash_causal_attention(q, k, v, lengths=lengths, **({"window": 128} if sliding else {}),
+                                         **dict(zip(("sink",), sink)))
+
+    text = jax.jit(prefill).lower(sds((8, 2048, 64, 192)), sds((8, 2048, Hkv, 192)), sds((8, 2048, Hkv, 128)),
+                                  sds((8,), jnp.int32), *sink).compile().as_text()
+    (kernel,) = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert ("swa_flash_fwd" in kernel) == sliding and "bf16[8,64,2048,128]" in kernel  # the output at the value's width
+    assert ("f32[64,1,8]" in kernel) == sliding  # the sinks ride as the slopes do
+
+    def decode(q, pk, pv, bt, qpos, lens, *rest):
+        named = dict(zip(("first_live", "sink"), rest))
+        return pa.flash_decode_paged(q, pk, pv, bt, qpos, 16, new_lens=lens, **named)
+
+    pages = 128 * columns
+    rest = [sds((128, 1), jnp.int32), sds((64,), jnp.float32)] * sliding
+    text = jax.jit(decode).lower(sds((128, 1, 64, 192)), sds((pages, 16, Hkv * 192)), sds((pages, 16, Hkv * 128)),
+                                 sds((128, columns), jnp.int32), sds((128, 1), jnp.int32), sds((128,), jnp.int32),
+                                 *rest).compile().as_text()
+    (kernel,) = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert ("swa_paged_attn" in kernel) == sliding and "paged_attn" in kernel
+    assert f"bf16[128,{64 // Hkv},{Hkv * 128}]" in kernel  # [rows, (c, g) pairs, kv heads x the value's 128]
+
+
+@pytest.mark.parametrize("name", ["prefill_8x2048", "chain_128"])
+def test_mimo_v2_5_programs_compile_at_the_cell_s_shapes(one_chip, monkeypatch, name):
+    """``mimo-v2.5.serve.long-output-wave128``'s programs whole, for the described v5e at the cell's own shapes (the
+    leading dense global layer, then one period of 4 sliding + 1 global + 1 sliding routed layers at the published
+    widths, 16 of 256 experts held, 2.5 GB of pages in two classes of two geometries: 24,832 global pages of 40 KiB
+    a global layer and a ring of 128 x 9 pages of 80 KiB a sliding layer, a block table of 194 + 9 columns), with
+    the picks handed out as the timed path hands them: the ``(8, 2048)`` prefill (``swa_flash_fwd`` five times,
+    ``flash_fwd`` twice, each told the rows' live lengths) and the chain of 8 steps at 128 rows (``swa_paged_attn``
+    x 5, ``paged_attn`` x 2, ``moe_decode`` x 6). Each fits the chip beside the weights and both pools, returns
+    both donated pools aliased, and copies neither; the peaks are the workload file's ``assumed.compiled_peak``."""
+    import dataclasses
+    import json
+
+    from benchmarks.lib import harness, program
+    from deepspeed_tpu.checkpoint.hf import config_from_hf
+    from deepspeed_tpu.inference import cache, model, paged
+    from deepspeed_tpu.models import CausalLM
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import flash_attention as fa, moe_decode, norms, paged_attention as pa
+
+    for module in (pa, fa, norms, moe_decode):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")  # what 'auto' sees there
+    monkeypatch.setattr(model, "_grouped_matmul", lambda lhs, rhs, sizes: model._gmm_padded(lhs, rhs, sizes))
+    cfg = dataclasses.replace(config_from_hf(program.published(harness.load_config("mimo-v2.5"))), dtype=jnp.bfloat16)
+    workload = harness.load_workload("mimo-v2.5.serve.long-output-wave128")
+    engine = workload["engine"]
+    bs, rows = engine["kv_block_size"], engine["max_seqs"]
+    plan = cache.cache_plan(cfg, bs, engine["max_seq_len"])
+    ring_blocks = rows * plan.ring_columns
+    NB = (engine["kv_pool_bytes"] - plan.ring_bytes(ring_blocks, jnp.bfloat16)) // (bs * plan.bytes_per_token(jnp.bfloat16))
+    table = plan.max_pages
+    assert (plan.ring_columns, ring_blocks, NB, table) == (9, 1152, 24832, 203)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda key: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), CausalLM(cfg).init(
+            {"params": key}, {"input_ids": jnp.zeros((1, 8), jnp.int32)}, train=False)["params"]),
+        jax.random.PRNGKey(0)))
+    pool = jax.tree_util.tree_map(sds, jax.eval_shape(lambda: plan.init(NB, ring_blocks, rows, jnp.bfloat16)))
+    assert (pool.kv.k.shape, pool.kv.v.shape) == ((2 * 24832, 16, 768), (2 * 24832, 16, 512))
+    assert (pool.ring.k.shape, pool.ring.v.shape) == ((5 * 1152, 16, 1536), (5 * 1152, 16, 1024))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    if name == "chain_128":
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def program_(params, pool, tokens, start_pos, tables, active, budgets, rng):
+            return paged.ragged_decode_chain(params, cfg, pool, tokens, start_pos, tables, bs,
+                                             active, budgets, rng, engine["decode_chain"], None, with_picks=True)
+
+        args = (i32(rows), i32(rows), i32(rows, table), jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip),
+                i32(rows), jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip))
+    else:
+        n, chunk = map(int, name.partition("_")[2].split("x"))
+        assert [n, chunk] == workload["warm"]["prefill"][0] and n * chunk == engine["max_ragged_batch_size"]
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def program_(params, pool, tokens, positions, new_lens, tables):
+            return paged.ragged_forward(params, cfg, pool, tokens, positions, new_lens, tables, bs, with_picks=True)
+
+        args = (i32(n, chunk), i32(n, chunk), i32(n), i32(n, table))
+    compiled = program_.lower(params, pool, *args).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pool))
+    assert pool_bytes == engine["kv_pool_bytes"] == 2_506_096_640 and mem.alias_size_in_bytes >= pool_bytes
+    peak_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30
+    print(json.dumps({"program": name, "temp_gb": mem.temp_size_in_bytes / 1e9,
+                      "argument_gb": mem.argument_size_in_bytes / 1e9, "peak_gib": peak_gib}))
+    # of 15.75 GiB: the prefill stands at 11.14 (2.59 GB of temporaries beside 9.37 GB held), the chain at 8.75
+    assert peak_gib < (11.3 if name.startswith("prefill") else 8.9)
+    said = workload["assumed"]["compiled_peak"]
+    assert ("%.2f GiB" % peak_gib) in said, (peak_gib, said)  # the reading the workload file states
+    text = compiled.as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+    count = lambda kernel: sum(kernel in name for name in calls)  # noqa: E731
+    if name == "chain_128":
+        assert (count("swa_paged_attn"), count("paged_attn") - count("swa_paged_attn")) == (5, 2)
+        assert count("moe_decode") == 6 and not count("flash_fwd")
+    else:  # (a call of fresh prompts attends inside its chunks alone: no paged kernel, no one-token path)
+        assert (count("swa_flash_fwd"), count("flash_fwd") - count("swa_flash_fwd")) == (5, 2) and count("gmm") >= 3
+        assert not count("paged_attn")
+    assert all("/swa/" in name for name in calls if "/swa_" in name)  # the sliding kind's kernels under its scope
+    assert all("/attn_full/" in name for name in calls if name.endswith(("/flash_fwd", "/paged_attn")))
+    moved = [line.strip()[:200] for line in text.splitlines()
+             if re.search(r"= \(?bf16\[(49664|5760),16,(768|512|1536|1024)\]\S* (copy|copy-start|transpose)\(", line)
+             or re.search(r"bf16\[(49664|5760),16,(768|512|1536|1024)\]\S*S\(1\)", line)]
+    assert not moved, moved
+
+
 def chunk_of(name: str) -> int:
     return 1 if name.startswith("chain") else int(name.rpartition("x")[2])
 
