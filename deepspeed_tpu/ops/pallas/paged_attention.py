@@ -16,17 +16,36 @@ pages each sequence owns — no dense gather ever materializes.
 The walk: the grid is the rows, in order; inside a step a loop runs over the
 row's LIVE page-chunks, ``cdiv(pages of the row, pages a chunk)`` of them, so
 a row of 130 tokens costs two iterations under a table of 128 columns or of
-8,192, and a row with no page costs an empty step that writes zeros. A chunk
-is at most ``pages_per_block`` pages (fewer where the query block leaves less
-VMEM, ``_VMEM_BUDGET``), each page one DMA of a lane-dense ``[bs, kvH*hd]``
-slab with all kv heads. K and V have TWO slots each: before chunk ``i`` is
-waited for, chunk ``i + 1`` is started into the other slot, and at a row's
-last chunk the NEXT row's first chunk (its pages are in SMEM already), so one
-fetch a call is exposed and the rest hide behind compute. Of a partial last
-chunk only the pages the row holds are fetched; the values past the row's
-length (dead slots of its last page, whatever the slot's other pages held
-before) are zeroed in the buffer, because the scores there are masked but
-``0 * NaN`` in ``p @ v`` is not.
+8,192, and a row with no page costs an empty step that writes zeros. Each page
+is one DMA of a lane-dense ``[bs, kvH*hd]`` slab with all kv heads. K and V
+have TWO slots each: before chunk ``i`` is waited for, chunk ``i + 1`` is
+started into the other slot, and at a row's last chunk the NEXT row's first
+chunk (its pages are in SMEM already), so one fetch a call is exposed and the
+rest hide behind compute. Of a partial last chunk only the pages the row holds
+are fetched; the values past the row's length (dead slots of its last page,
+whatever the slot's other pages held before) are zeroed in the buffer, because
+the scores there are masked but ``0 * NaN`` in ``p @ v`` is not.
+
+A chunk is sized by its BYTES, from the call's shapes alone
+(``_pages_a_chunk``): what a chunk costs beside its bytes (the loops' glue,
+the accumulator read, scaled and written, the statistics: some 450 cycles of
+scalar and vector code) is the same whatever a page holds, so a chunk takes as
+many pages as make about 1 MiB of keys and values, never fewer than 8 nor more
+than 32, whole eights under a wider table, else the table's columns (a ring of
+9 pages is ONE chunk, not 8 + 1), fewer where the query side leaves less VMEM
+(``_VMEM_BUDGET``); ``pages_per_block`` given as a number is that number. What
+a PAGE costs beside its bytes is kept small: the table lies flat in SMEM (one
+sum finds a row's entry), a full chunk is waited for with one wait a buffer (a
+descriptor over the whole slot waits for the sum of its pages' bytes; a partial
+chunk waits page by page), and the copies carry NO BOUNDS CHECK
+(``disable_bounds_checks``: four halting compares a page were more scalar code
+than the two copies). What guards the pool's edge is then the table alone: the
+kernel fetches only a row's LIVE columns, which hold pages of the class's
+allocator (``ragged.StateManager``: below its ``num_blocks``; dead columns are
+0 and are not fetched) plus the layer's first page, and a class's array is its
+layers times those pages (``cache.PagedKVPool``). The host's test of that is
+``tests/unit/inference/test_mimo_v2.py::test_every_table_handed_to_a_step_...``;
+a caller with a table of its own keeps the same promise.
 
 The compute has two forms, chosen from the shapes alone. With a sublane tile
 or more of query rows a kv head (``C*G >= 8``: prompts, chunks) each chunk
@@ -74,7 +93,12 @@ from deepspeed_tpu.ops.registry import register
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 _SUBLANES = 8
-DEFAULT_PAGES_PER_BLOCK = 8
+DEFAULT_PAGES_PER_BLOCK = 8  # the fewest pages a chunk of the kernel's own choosing (``_pages_a_chunk``)
+# What a chunk of the kernel's own choosing holds of keys and values together, about, and the most pages it takes
+# to get there: 8 pages of 128 KiB, 16 heads of 128 a page of 16 slots, are 2 cycles of bytes an instruction of the
+# walk; a page of 40 KiB at 8 a chunk is 0.6 (PERF.md, PR 62).
+_CHUNK_BYTES = 1 << 20
+_MAX_PAGES_PER_BLOCK = 32
 # What the kernel's own buffers may take of Mosaic's 16 MiB of scoped VMEM, by
 # ``flash_decode_paged``'s count; the rest is the compiler's. The (64, 256)
 # prefill of a 16 x 128 model counts 10 MiB with two 8-page slots of K and V.
@@ -89,7 +113,7 @@ def _cdiv(a: int, b: int) -> int:
     return (a + b - 1) // b
 
 
-def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, quantized, banded=False,
+def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, P, bs, kvH, hd, Cg, dense, alibi, quantized, banded=False,
                    hdv, sink=False):
     refs = list(refs)
     low_ref = refs.pop(0) if banded else None  # (a third scalar operand: a row's first slot live to ANY of its queries)
@@ -119,16 +143,31 @@ def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, 
         ``slot``: one DMA per LIVE page (all kv heads at once: a page is a
         contiguous lane-dense [bs, kvH*hd] slab of the pool as it is stored, so
         the copy slices only the leading, untiled page dim), and for a
-        quantized pool the chunk's [kvH, T] scale rows."""
+        quantized pool the chunk's [kvH, T] scale rows. A FULL chunk is waited
+        for at once, a buffer: a descriptor over the whole slot waits for the
+        sum of its pages' bytes."""
         live = jnp.clip(pages_of(row) - chunk * ppcb, 0, ppcb)
+        buffers = ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
+        first = row * P + chunk * ppcb  # the chunk's first entry of the table, which lies flat: one sum a page
 
         def page(i):
-            p = bt_ref[row, chunk * ppcb + i]
-            for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+            # (a wait reads no table: its descriptor gives the bytes alone)
+            p = bt_ref[first + i] if start else 0
+            for hbm, buf, which in buffers:
                 c = pltpu.make_async_copy(hbm.at[p], buf.at[slot, i], sems.at[slot, which])
                 c.start() if start else c.wait()
 
-        pl.loop(0, live)(page)
+        if start:
+            pl.loop(0, live)(page)
+        else:
+            full = live == ppcb
+
+            @pl.when(full)
+            def _():
+                for _, buf, which in buffers:
+                    pltpu.make_async_copy(buf.at[slot], buf.at[slot], sems.at[slot, which]).wait()
+
+            pl.loop(0, jnp.where(full, 0, live))(page)
         if quantized:
             for hbm, buf, which in ((ks_hbm, ksbuf, 2), (vs_hbm, vsbuf, 3)):
                 c = pltpu.make_async_copy(hbm.at[row, chunk], buf.at[slot], sems.at[slot, which])
@@ -249,7 +288,7 @@ def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, 
                 # row r*kvH + kh takes head kh's scales
                 pad = [jnp.zeros((W - Cg * kvH, T), jnp.float32)] * (W > Cg * kvH)
                 q, lanes, vlanes = qbd, slice(None), slice(None)
-                tile = lambda b: jnp.concatenate([b[slot]] * Cg + pad)  # noqa: E731
+                tile = lambda b: jnp.concatenate([b[slot, :kvH]] * Cg + pad)  # noqa: E731
             else:
                 q, lanes, vlanes = q_ref[0, g], slice(g * hd, (g + 1) * hd), slice(g * hdv, (g + 1) * hdv)
                 tile = lambda b: b[slot, pl.ds(g, 1), :]  # noqa: E731
@@ -303,6 +342,24 @@ def _decode_kernel(bt_ref, ctx_ref, *refs, ppcb, bs, kvH, hd, Cg, dense, alibi, 
             o_ref[0, g] = normalised(g).astype(o_ref.dtype)
 
 
+def _pages_a_chunk(page_bytes: int, columns: int, query_side: int, pages_per_block: int = None) -> int:
+    """The pages of a chunk, from the call's shapes alone. Told none (``pages_per_block`` None), as many as hold
+    about ``_CHUNK_BYTES`` at ``page_bytes`` a page (its keys and values together, a quantized pool's scales beside
+    them): what a chunk costs beside its bytes (the glue around the loops, the accumulator read, scaled and
+    written, the statistics) is paid once however wide it is, and a narrow page brings few bytes to pay it with.
+    Never fewer than ``DEFAULT_PAGES_PER_BLOCK`` nor more than ``_MAX_PAGES_PER_BLOCK``; whole eights where the
+    table is wider than that (a chunk's ``T`` then stays whole 128-lane tiles at 16 slots a page), else all the
+    table's ``columns``, one chunk. A number keeps meaning that number. Either way no more than the table holds,
+    and halved while the query side and the two slots of the chunk's pages pass ``_VMEM_BUDGET``."""
+    if pages_per_block is None:
+        pages = min(max(_CHUNK_BYTES // page_bytes, DEFAULT_PAGES_PER_BLOCK), _MAX_PAGES_PER_BLOCK)
+        pages_per_block = pages // 8 * 8 if columns > pages else columns
+    ppcb = max(1, min(pages_per_block, columns))
+    while ppcb > 1 and query_side + 2 * ppcb * page_bytes > _VMEM_BUDGET:
+        ppcb //= 2
+    return ppcb
+
+
 def _query_side_bytes(heads: int, rows: int, width: int, itemsize: int) -> int:
     """VMEM of a grid step's query side: the blocks of q and of the output,
     each double-buffered by the pipeline, the fp32 accumulator, and the
@@ -320,7 +377,7 @@ def flash_decode_paged(
     q_positions: jax.Array,  # [N, C] int32
     block_size: int,
     new_lens: jax.Array = None,  # [N] live tokens (for page skipping)
-    pages_per_block: int = DEFAULT_PAGES_PER_BLOCK,
+    pages_per_block: int = None,  # None: ``_pages_a_chunk``'s rule on a page's bytes and the table's width
     alibi_slopes: jax.Array = None,  # [H] fp32 (bloom ALiBi, fused in-kernel)
     k_scale: jax.Array = None,  # [pages, bs*kvH] fp32 — quantized pool scales
     v_scale: jax.Array = None,
@@ -357,10 +414,10 @@ def flash_decode_paged(
     # block-diagonal query for all heads where a head has under a sublane tile
     # of query rows and the (row, head) pairs fit one 128-row pass.
     dense = (Cg < _SUBLANES or hdv != hd) and Cg * kvH <= 128
-    page_bytes = bs * max(D, Dv) * pool_k.dtype.itemsize
+    page_bytes = bs * (D + Dv) * pool_k.dtype.itemsize + (2 * bs * kvH * 4 if quantized else 0)  # keys, values(, scales)
     if (not dense and C > 1
             and _query_side_bytes(kvH, _cdiv(Cg, _SUBLANES) * _SUBLANES, hd, q.dtype.itemsize)
-            + 4 * page_bytes > _VMEM_BUDGET):
+            + 2 * page_bytes > _VMEM_BUDGET):
         # A row's query block is too large for VMEM beside one page a slot (a
         # 256-token chunk of 32 heads over 8 of head_dim 64 is [8, 1024, 64],
         # and 64 lanes pad to 128): the chunk's first and second half are two
@@ -416,14 +473,12 @@ def flash_decode_paged(
         max_pos = jnp.take_along_axis(q_positions, last[:, None], axis=1)[:, 0]
     ctx_lens = (max_pos + 1).astype(jnp.int32)  # [N]
 
-    # The page-chunk: at most ``pages_per_block`` pages, fewer where the query
-    # side (its block and the output's, double-buffered by the pipeline; the
-    # fp32 accumulator; the statistics, which pad to 128 lanes) leaves less of
-    # the budget for the two slots of K and of V.
+    # The page-chunk (``_pages_a_chunk``): fewer pages where the query side (its
+    # block and the output's, double-buffered by the pipeline; the fp32
+    # accumulator; the statistics, which pad to 128 lanes) leaves less of the
+    # budget for the two slots of K and of V.
     query_side = _query_side_bytes(heads, rows, max(D, Dv) // heads, q.dtype.itemsize)
-    ppcb = max(1, min(pages_per_block, P))
-    while ppcb > 1 and query_side + 4 * ppcb * page_bytes > _VMEM_BUDGET:
-        ppcb //= 2
+    ppcb = _pages_a_chunk(page_bytes, P, query_side, pages_per_block)
     T = ppcb * bs
 
     operands = [q_op, qpos_rows[:, :, None]]
@@ -456,14 +511,18 @@ def flash_decode_paged(
         # [N, chunks, kvH, T] ROWS (slot index == position); the kernel fetches
         # a chunk's [kvH, T] beside its pages and multiplies the scores /
         # probabilities by them, never the value tiles
+        # (the rows padded to whole sublane tiles: a chunk's [kvH, T] slab of 12 heads is no aligned slice of
+        # the operand once T passes a lane tile)
         npc = _cdiv(P, ppcb)
         bt_pad = jnp.pad(block_tables, ((0, 0), (0, npc * ppcb - P)))
+        heads_pad = _cdiv(kvH, _SUBLANES) * _SUBLANES - kvH
         for sc in (k_scale, v_scale):
-            operands.append(sc[bt_pad].reshape(N, npc, T, kvH).transpose(0, 1, 3, 2))
+            scale_rows = sc[bt_pad].reshape(N, npc, T, kvH).transpose(0, 1, 3, 2)
+            operands.append(jnp.pad(scale_rows, ((0, 0), (0, 0), (0, heads_pad), (0, 0))))
             in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-            scratch.append(pltpu.VMEM((2, kvH, T), jnp.float32))
+            scratch.append(pltpu.VMEM((2, kvH + heads_pad, T), jnp.float32))
 
-    kernel = functools.partial(_decode_kernel, ppcb=ppcb, bs=bs, kvH=kvH, hd=hd, Cg=Cg,
+    kernel = functools.partial(_decode_kernel, ppcb=ppcb, P=P, bs=bs, kvH=kvH, hd=hd, Cg=Cg,
                                dense=dense, alibi=alibi, quantized=quantized, banded=banded, hdv=hdv,
                                sink=sink is not None)
     out = pl.pallas_call(
@@ -482,10 +541,12 @@ def flash_decode_paged(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(q_op.shape[:-1] + o_block[-1:], q.dtype),
-        # rows in order: each starts the next one's first fetch
-        compiler_params=tpu_compiler_params(dimension_semantics=("arbitrary",)),
+        # rows in order: each starts the next one's first fetch. No bounds check on the pages' copies (four halts
+        # a page, more scalar code than the copies themselves): the pool's edge is the tables' to keep (module
+        # docstring)
+        compiler_params=tpu_compiler_params(dimension_semantics=("arbitrary",), disable_bounds_checks=True),
         interpret=_interpret(),
-    )(block_tables, ctx_lens, *((jnp.min(first_live, axis=1).astype(jnp.int32),) if banded else ()), *operands)
+    )(block_tables.reshape(N * P), ctx_lens, *((jnp.min(first_live, axis=1).astype(jnp.int32),) if banded else ()), *operands)
 
     if dense:
         out = out.reshape(N, C, G, kvH, hdv).transpose(0, 1, 3, 2, 4)

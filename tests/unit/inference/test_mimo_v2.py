@@ -235,6 +235,41 @@ def test_prefill_then_decode_through_the_ring_past_two_wraps_is_the_reference(fi
     assert eng.state.allocators[1].free_blocks == 12 and eng.state.free_blocks == eng.num_kv_blocks
 
 
+def test_every_table_handed_to_a_step_lies_inside_its_class_s_pool_under_every_layer_s_first_page(whole, monkeypatch):
+    """The host's edge of the pool, which is the only one since PR 62: the paged kernel's page copies carry no
+    bounds check (``disable_bounds_checks``), so a page index past the pool would read another array's memory
+    and say nothing. Every row ``SequenceDescriptor.table_into`` fills for a step program (prefills, single
+    tokens, chains: the serving loop over four prompts, 20 tokens each, through the ring's wraps) holds, in the
+    global columns, pages of the first allocator and, in the ring's, pages of the second, dead columns 0; and a
+    class's array is exactly its layers times its allocator's pages, so the LAST layer's first page plus the
+    largest entry is still a row of it."""
+    from deepspeed_tpu.inference import ragged
+
+    cfg, params = whole
+    eng = InferenceEngineV2(cfg, params, dict(ENGINE))
+    handed = []
+    table_into = ragged.SequenceDescriptor.table_into
+
+    def recorded(self, row):
+        table_into(self, row)
+        handed.append(row.copy())
+
+    monkeypatch.setattr(ragged.SequenceDescriptor, "table_into", recorded)
+    seqs = sequences()
+    eng.generate([seqs[i, :n] for i, n in enumerate(LENGTHS)], max_new_tokens=20)
+    layout, (plain, ring) = eng.state.layout, eng.state.allocators
+    first = layout.summary_cols
+    assert len(handed) > 20 and all(row.shape == (first + layout.window_pages,) for row in handed)
+    tables = np.stack(handed)
+    assert tables.min() >= 0 and tables[:, :first].max() < plain.num_blocks and tables[:, first:].max() < ring.num_blocks
+    assert tables[:, :first].max() > 0 and tables[:, first:].max() > 0  # (pages were handed in both classes)
+    kinds = cfg.layer_types
+    for pool, allocator, layers in ((eng.pools.kv, plain, kinds.count("attention")),
+                                    (eng.pools.ring, ring, len(kinds) - kinds.count("attention"))):
+        assert pool.k.shape[0] == pool.v.shape[0] == layers * allocator.num_blocks
+        assert (layers - 1) * allocator.num_blocks + allocator.num_blocks - 1 < pool.k.shape[0]
+
+
 def test_the_four_ranks_routed_parts_add_up_to_the_uncut_layer_and_a_row_with_no_held_pick_gets_zeros(files):
     """Over all four ranks of a four-way share of one routed layer (8 experts,
     2 held a chip, 2 a token, NO shared expert): the ranks' routed terms add up

@@ -1369,13 +1369,21 @@ def test_the_two_width_kernels_compile_at_mimo_s_shapes(one_chip, monkeypatch, k
         return pa.flash_decode_paged(q, pk, pv, bt, qpos, 16, new_lens=lens, **named)
 
     pages = 128 * columns
-    rest = [sds((128, 1), jnp.int32), sds((64,), jnp.float32)] * sliding
-    text = jax.jit(decode).lower(sds((128, 1, 64, 192)), sds((pages, 16, Hkv * 192)), sds((pages, 16, Hkv * 128)),
-                                 sds((128, columns), jnp.int32), sds((128, 1), jnp.int32), sds((128,), jnp.int32),
-                                 *rest).compile().as_text()
+    shapes = [sds((128, 1, 64, 192)), sds((pages, 16, Hkv * 192)), sds((pages, 16, Hkv * 128)),
+              sds((128, columns), jnp.int32), sds((128, 1), jnp.int32), sds((128,), jnp.int32),
+              *[sds((128, 1), jnp.int32), sds((64,), jnp.float32)] * sliding]
+    text = jax.jit(decode).lower(*shapes).compile().as_text()
     (kernel,) = [line for line in text.splitlines() if "tpu_custom_call" in line]
     assert ("swa_paged_attn" in kernel) == sliding and "paged_attn" in kernel
     assert f"bf16[128,{64 // Hkv},{Hkv * 128}]" in kernel  # [rows, (c, g) pairs, kv heads x the value's 128]
+    # the chunk the rule gives (PR 62), in the slots of K and of V: all 9 columns of the ring (80 KiB a page: one
+    # chunk, T = 144), 24 pages of the global table's 40 KiB; and no bounds check on a page's copies, whose four
+    # halts a page were more scalar code than the copies (the tables keep the pool's edge: test_ragged_state)
+    (call,) = [e for e in jax.make_jaxpr(decode)(*shapes).jaxpr.eqns if e.primitive.name == "pallas_call"]
+    chunk = 9 if sliding else 24
+    assert [v.aval.shape for v in call.params["jaxpr"].invars if len(v.aval.shape) == 4] == [
+        (2, chunk, 16, Hkv * 192), (2, chunk, 16, Hkv * 128)]
+    assert '"disable_bounds_checks":true' in kernel
 
 
 @pytest.mark.parametrize("name", ["prefill_8x2048", "chain_128"])

@@ -157,6 +157,61 @@ def test_the_cell_s_own_ring_of_257_pages_at_the_positions_about_its_edges(impl)
     ring_reads_the_live_positions(4096, 16, impl, (0, 17, 4095, 4096, 4097, 4111, 4112, 8191, 12345, 16400))
 
 
+NINE = dict(window=128, bs=16, H=4, Hkv=2, Dk=24, Dv=16)  # the MiMo cell's ring of 9 columns at toy widths, a key wider than its value
+
+
+@pytest.mark.parametrize("ts", [(142,), (143,), (127,), (128,), (15,), (0,), (142, None, 15, 143, 128, 0, None, 2000, 2014)],
+                         ids=["T-1", "eight-of-nine-pages", "the-window-fills", "nine-pages", "one-page", "one-token",
+                              "side-by-side"])
+def test_a_ring_of_nine_columns_is_one_chunk_under_the_default_with_a_sink(ts):
+    """The chunk the kernel takes when told none is all 9 columns of the ring (``T`` = 144): a query at 142 sees
+    143 slots (``T - 1``, the most a ring of 9 holds: its first page's 15 dead slots, its last page's one), at 143
+    eight pages of nine (the ninth is not fetched), at 15 one page, None no page (zeros). NaN lies in every slot
+    no query sees. Against the brute-force list of live positions with the sink in the denominator, and against
+    the XLA fallback."""
+    window, bs, H, Hkv, Dk, Dv = (NINE[k] for k in ("window", "bs", "H", "Hkv", "Dk", "Dv"))
+    R = ring_columns(window, bs)
+    assert R == 9
+    rng = np.random.default_rng(3)
+    total = max(t for t in ts if t is not None) + 1
+    keys = rng.normal(size=(total, Hkv * Dk)).astype(np.float32)
+    values = rng.normal(size=(total, Hkv * Dv)).astype(np.float32)
+    sink = rng.normal(size=(H,)).astype(np.float32)
+    q = rng.normal(size=(len(ts), 1, H, Dk)).astype(np.float32)
+    pools_k, pools_v, table, at, low, want = [], [], [], [], [], []
+    for n, t in enumerate(ts):
+        # a row's ring in its own 2 R pages of the pool; a row with no page points at pages of NaN
+        pk, _, cols = ring_of(keys, keys, t or 0, window, bs, 2 * R, seed=n)
+        _, pv, _ = ring_of(values, values, t or 0, window, bs, 2 * R, seed=n)
+        if t is None:
+            pk[:], pv[:] = np.nan, np.nan
+        first = max((t or 0) - window + 1, 0)
+        oldest = first // bs
+        pools_k.append(pk), pools_v.append(pv)
+        table.append(2 * R * n + cols[(oldest + np.arange(R)) % R])
+        at.append(-1 if t is None else t - oldest * bs), low.append(first - oldest * bs)
+        live = np.arange(first, (t or 0) + 1)
+        k = keys[live].reshape(-1, Hkv, Dk).repeat(H // Hkv, axis=1)
+        v = values[live].reshape(-1, Hkv, Dv).repeat(H // Hkv, axis=1)
+        e = np.exp(np.einsum("hd,jhd->hj", q[n, 0], k).astype(np.float64) * Dk ** -0.5)
+        want.append(np.einsum("hj,jhd->hd", e / (e.sum(-1, keepdims=True) + np.exp(sink)[:, None]), v)
+                    * (t is not None))
+    args = (jnp.asarray(q), jnp.asarray(np.concatenate(pools_k)), jnp.asarray(np.concatenate(pools_v)),
+            jnp.asarray(np.stack(table), jnp.int32), jnp.asarray(at, jnp.int32)[:, None], bs)
+    kw = dict(new_lens=jnp.asarray([t is not None for t in ts], jnp.int32), first_live=jnp.asarray(low, jnp.int32)[:, None],
+              sink=jnp.asarray(sink))
+    (call,) = [e for e in jax.make_jaxpr(lambda *a: flash_decode_paged(*a, bs, **kw))(*args[:-1]).jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "swa_paged_attn"
+    assert [v.aval.shape for v in call.params["jaxpr"].invars if len(v.aval.shape) == 4][:2] == [
+        (2, R, bs, Hkv * Dk), (2, R, bs, Hkv * Dv)]  # the slots of K and of V: all nine columns a chunk
+    got = np.asarray(flash_decode_paged(*args, **kw))[:, 0]
+    assert np.isfinite(got).all() and float(np.abs(got - np.stack(want)).max()) < 3e-6
+    rows = [n for n, t in enumerate(ts) if t is not None]
+    fallback = np.asarray(_xla_paged_attention(*args, **kw))[:, 0]
+    assert float(np.abs(got[rows] - fallback[rows]).max()) < 3e-6
+
+
 def test_the_fallback_takes_a_wholly_dead_page_of_nan():
     """``first_live`` past a whole page: every slot a query could see there is
     masked, the page is NaN, and the answer is the live slots' alone."""
